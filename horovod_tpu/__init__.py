@@ -16,48 +16,6 @@ Quick start (mirrors the reference's 4-step usage, ``README.md``)::
     # 4. broadcast initial parameters from rank 0
 """
 
-# Compatibility backfills for older jax (≤0.4.37) — this codebase targets
-# the newer public spellings.  Applied before any submodule binds them:
-#  * lax.axis_size: psum(1, axis) is semantically identical (a concrete
-#    int under a bound axis, NameError when unbound).
-#  * jax.shard_map: promoted from jax.experimental.shard_map.
-#  * jax.typeof: the abstract value; old avals carry no ``vma`` attribute,
-#    which callers already treat as "no varying-axes info" via getattr.
-import jax as _jax                         # noqa: E402
-import jax.lax as _lax                     # noqa: E402
-
-if not hasattr(_lax, "axis_size"):
-    def _axis_size_compat(axis_name, _psum=_lax.psum):
-        return _psum(1, axis_name)
-    _lax.axis_size = _axis_size_compat
-if not hasattr(_jax, "shard_map"):
-    import functools as _functools
-
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    @_functools.wraps(_shard_map)
-    def _shard_map_compat(*args, **kwargs):
-        # The promoted API renamed check_rep → check_vma — but the old
-        # replication checker is strictly weaker than vma inference (no
-        # pallas_call rule, cannot see through subset-axis psums), so it
-        # rejects programs the modern API accepts and checks.  Emulating
-        # the modern surface therefore means not checking at all.
-        kwargs.pop("check_vma", None)
-        kwargs["check_rep"] = False
-        return _shard_map(*args, **kwargs)
-    _jax.shard_map = _shard_map_compat
-if not hasattr(_jax, "typeof"):
-    _jax.typeof = _jax.core.get_aval
-try:
-    from jax.experimental.pallas import tpu as _pltpu
-    # Renamed TPUCompilerParams → CompilerParams on promotion.
-    if not hasattr(_pltpu, "CompilerParams"):
-        _pltpu.CompilerParams = _pltpu.TPUCompilerParams
-    del _pltpu
-except ImportError:          # pallas not built into this jax
-    pass
-del _jax, _lax
-
 from horovod_tpu.basics import (           # noqa: F401
     init, shutdown, is_initialized, size, local_size, rank, local_rank,
     process_index, process_count, devices, local_devices, ranks_mesh,

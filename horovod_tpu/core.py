@@ -1301,6 +1301,12 @@ class Controller:
 
     # ------------------------------------------------------------------ API
 
+    @property
+    def native(self) -> bool:
+        """True when the native core (cpp/htpu) runs the message table,
+        fusion planner and timeline; False on the pure-Python mirror."""
+        return self._use_cpp
+
     def mesh_async_hazard(self) -> int:
         """Outstanding async eager handles whose collective programs ride
         the SHARED multi-controller runtime — the count that makes

@@ -43,6 +43,12 @@ def _as_leaf(leaf):
             else np.asarray(leaf))
 
 
+class MeshAxisUnboundError(RuntimeError):
+    """A gradient reduction was traced under ``jit`` with its mesh axis
+    unbound, where the eager fallback cannot run.  ``make_train_step``
+    catches exactly this to pick its shard_map program on one chip."""
+
+
 def _in_spmd_context(axis_name) -> bool:
     """True when ``axis_name`` is bound (we are under shard_map/pmap)."""
     try:
@@ -232,8 +238,7 @@ def allreduce_gradients(grads, *, axis_name=RANKS_AXIS, average: bool = True,
             if _is_sparse(g):
                 return _sparse.allreduce(g, average=average,
                                          axis_name=axis_name)
-            vma_g = getattr(jax.typeof(g), "vma", None)
-            varied = vma_g is None or any(a in vma_g for a in axes)
+            varied = any(a in jax.typeof(g).vma for a in axes)
             if (varied and isinstance(axis_name, str) and _qc.is_int8(comp)
                     and _qc.int8_eligible(g.shape, g.dtype)):
                 # Bulk leaf under int8: the in-jit quantized ring — int8
@@ -245,8 +250,7 @@ def allreduce_gradients(grads, *, axis_name=RANKS_AXIS, average: bool = True,
             leaf_comp = (NoneCompressor if _qc.is_int8(comp)
                          else comp)
             c, ctx = leaf_comp.compress(g)
-            vma = getattr(jax.typeof(c), "vma", None)
-            unvaried = vma is not None and not any(a in vma for a in axes)
+            unvaried = not any(a in jax.typeof(c).vma for a in axes)
             if unvaried and grads_hint:
                 # Pre-summed gradient: dividing gives the mean; sum is c.
                 red = c / lax.axis_size(axis_name) if average else c
@@ -280,7 +284,7 @@ def allreduce_gradients(grads, *, axis_name=RANKS_AXIS, average: bool = True,
                    for a in ((l.values, l.indices) if _is_sparse(l) else (l,))]
     if any(isinstance(l, jax.core.Tracer) for l in flat_arrays):
         axis = axis_name if isinstance(axis_name, str) else tuple(axis_name)
-        raise RuntimeError(
+        raise MeshAxisUnboundError(
             f"DistributedOptimizer/allreduce_gradients was traced inside "
             f"jit without the mesh axis {axis!r} in scope: the eager "
             f"fallback cannot run on tracers.  Run the update step via "

@@ -134,10 +134,7 @@ _SMALL_VMEM_DEVICE_KINDS = ("v2", "v3")
 
 
 def _vmem_headroom_ok() -> bool:
-    try:
-        d = jax.local_devices()[0]
-    except Exception:   # noqa: BLE001 — uninitialized backend
-        return True
+    d = jax.local_devices()[0]
     if d.platform != "tpu":
         return True   # CPU/interpret: the limit is not enforced
     try:
@@ -160,10 +157,11 @@ def _struct(shape, dtype, *like):
     requires that declared explicitly."""
     vma = frozenset()
     for l in like:
-        vma |= getattr(jax.typeof(l), "vma", None) or frozenset()
-    if vma:
-        return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
-    return jax.ShapeDtypeStruct(shape, dtype)
+        vma |= jax.typeof(l).vma
+    # Always explicit, even when empty: an output of invariant inputs
+    # (a gathered tensor) is invariant, and under check_vma jax refuses
+    # a struct that does not say so.
+    return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
 
 
 def _block_mask(qi, kj, block_q, block_k, causal, seq_len):
@@ -510,7 +508,7 @@ def _fwd_packed(q, k, v, H, D, *, scale, causal, block_q, block_k,
     # interpreter cannot discharge this kernel's loads (its vma check
     # rejects the block dynamic_slices), so CPU tests take the
     # unrolled-KV form there; compiled Mosaic is unaffected.
-    in_vma = getattr(jax.typeof(q), "vma", None) or frozenset()
+    in_vma = jax.typeof(q).vma
     fb = min(_FULL_UNROLL_BLOCK, block_q, block_k, T)
     # Mosaic's stack for the unrolled body scales ~T² (f32 s/p
     # temporaries per live block pair): measured ≤16 MB at T=2048 but
@@ -1305,7 +1303,7 @@ def _bwd_pallas_packed(q, k, v, o, lse, do, H, D, *, scale, causal,
     # bench shape) — whatever binds the backward, it isn't matmul
     # count.  Kept behind an env knob so the recorded A/B stays
     # reproducible; the split pair stays the measured default.
-    in_vma = getattr(jax.typeof(q), "vma", None) or frozenset()
+    in_vma = jax.typeof(q).vma
     fbb = min(_FULL_UNROLL_BLOCK, block_q, block_k, T)
     # Tighter VMEM bound than the forward's: this kernel holds 4 input
     # + 3 output full rows PLUS three full-sequence f32 accumulator

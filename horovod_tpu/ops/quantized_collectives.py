@@ -44,7 +44,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from horovod_tpu.parallel.hierarchical import _gather_inv
+from horovod_tpu.ops.flash_attention import _struct
+from horovod_tpu.parallel.hierarchical import all_gather_invariant
 
 # Block geometry — MUST match cpp/htpu/quantize.h (kInt8BlockElems,
 # kSubChunkElems): one fp32 absmax scale per 1024 elements, wire images
@@ -147,12 +148,21 @@ def int8_eligible(shape, dtype, *, floor_bytes: int | None = None) -> bool:
 # ---------------------------------------------------------------- codec
 
 
-def _use_pallas() -> bool:
-    return os.environ.get(_ENV_PALLAS, "1") != "0"
-
-
 def _interpret() -> bool:
     return jax.default_backend() != "tpu"
+
+
+def _use_pallas(*operands) -> bool:
+    """Whether the codec runs as the Pallas kernels.  ``_ENV_PALLAS=0``
+    selects the jnp codec outright.  So does an *interpreted* kernel on
+    operands that vary over a ``shard_map`` axis: the generic interpreter
+    evaluates the kernel body op by op under the vma check, which refuses
+    a varying block meeting a constant.  Compiled Mosaic never evaluates
+    the body, so on TPU the ring always runs the kernels; the two codecs
+    are bit-identical (tests/test_quantized_collectives.py)."""
+    if os.environ.get(_ENV_PALLAS, "1") == "0":
+        return False
+    return not (_interpret() and any(jax.typeof(o).vma for o in operands))
 
 
 def _block_scale(absmax):
@@ -194,8 +204,8 @@ def _pallas_quantize(grid):
         in_specs=[pl.BlockSpec((_ROWS, BLOCK_ELEMS), lambda i: (i, 0))],
         out_specs=(pl.BlockSpec((_ROWS, BLOCK_ELEMS), lambda i: (i, 0)),
                    pl.BlockSpec((_ROWS, 1), lambda i: (i, 0))),
-        out_shape=(jax.ShapeDtypeStruct((blocks, BLOCK_ELEMS), jnp.int8),
-                   jax.ShapeDtypeStruct((blocks, 1), jnp.float32)),
+        out_shape=(_struct((blocks, BLOCK_ELEMS), jnp.int8, grid),
+                   _struct((blocks, 1), jnp.float32, grid)),
         interpret=_interpret(),
     )(grid)
 
@@ -209,7 +219,7 @@ def _pallas_dequantize(q, scales):
         in_specs=[pl.BlockSpec((_ROWS, BLOCK_ELEMS), lambda i: (i, 0)),
                   pl.BlockSpec((_ROWS, 1), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((_ROWS, BLOCK_ELEMS), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((blocks, BLOCK_ELEMS), jnp.float32),
+        out_shape=_struct((blocks, BLOCK_ELEMS), jnp.float32, q, scales),
         interpret=_interpret(),
     )(q, scales)
 
@@ -222,7 +232,7 @@ def quantize_blocks(flat):
     assert size % BLOCK_ELEMS == 0, size
     blocks = size // BLOCK_ELEMS
     grid = flat.reshape(blocks, BLOCK_ELEMS).astype(jnp.float32)
-    if not _use_pallas():
+    if not _use_pallas(grid):
         return _jnp_quantize(grid)
     rows = -(-blocks // _ROWS) * _ROWS
     if rows != blocks:
@@ -236,7 +246,7 @@ def dequantize_blocks(q, scales):
     """Inverse of :func:`quantize_blocks`: flat fp32 of size
     ``blocks * BLOCK_ELEMS`` (``float(q) * scale``, as DecodeWireChunk)."""
     blocks = q.shape[0]
-    if not _use_pallas():
+    if not _use_pallas(q, scales):
         return (q.astype(jnp.float32) * scales).reshape(-1)
     rows = -(-blocks // _ROWS) * _ROWS
     if rows != blocks:
@@ -268,8 +278,8 @@ def snap_to_grid(x):
 def _allgather(v, axis_name):
     # Varying -> Invariant gather where jax tracks VMA (same trick as
     # hierarchical_allreduce); plain all_gather otherwise.
-    if getattr(jax.typeof(v), "vma", frozenset()) and _gather_inv is not None:
-        return _gather_inv(v, axis_name, axis=0, tiled=False)
+    if jax.typeof(v).vma:
+        return all_gather_invariant(v, axis_name, axis=0, tiled=False)
     return lax.all_gather(v, axis_name, axis=0, tiled=False)
 
 

@@ -15,27 +15,13 @@ import jax
 from jax import lax
 
 
-def _to_varying(v, axis: str):
-    # jax >= 0.9 spells this lax.pcast(..., to='varying'); pvary is the
-    # deprecated spelling kept as a fallback.  Versions predating vma
-    # tracking altogether have neither — there the invariant/varying
-    # distinction does not exist and marking is a no-op.
-    try:
-        return lax.pcast(v, axis, to="varying")
-    except (AttributeError, TypeError):
-        if not hasattr(lax, "pvary"):
-            return v
-        return lax.pvary(v, axis)
-
-
 def ensure_varying(v, axis):
     """Mark ``v`` varying over manual ``axis`` (a name or tuple of names)
     if it isn't already."""
     axes = (axis,) if isinstance(axis, str) else tuple(axis)
-    vma = getattr(jax.typeof(v), "vma", frozenset())
-    missing = tuple(a for a in axes if a not in vma)
+    missing = tuple(a for a in axes if a not in jax.typeof(v).vma)
     if missing:
-        v = _to_varying(v, missing if len(missing) > 1 else missing[0])
+        v = lax.pcast(v, missing, to="varying")
     return v
 
 

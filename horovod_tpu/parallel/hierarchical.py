@@ -32,20 +32,12 @@ from jax import lax
 
 from horovod_tpu.parallel.mesh import DCN_AXIS, ICI_AXIS
 
-try:
-    # Varying → Invariant all_gather (transpose: dynamic_slice).  Exactly
-    # the op tier 3 wants under check_vma; not yet re-exported on the
-    # public lax namespace as of jax 0.9.  Being private, neither its
-    # presence NOR its signature is stable — verify the kwargs we pass
-    # still exist so a jax upgrade degrades to the psum fallback below
-    # instead of a trace-time TypeError.
-    import inspect as _inspect
-    from jax._src.lax.parallel import all_gather_invariant as _gather_inv
-    if not {"axis", "tiled"} <= set(
-            _inspect.signature(_gather_inv).parameters):
-        _gather_inv = None                            # pragma: no cover
-except Exception:                                     # pragma: no cover
-    _gather_inv = None
+# Varying → Invariant all_gather (transpose: dynamic_slice): exactly the
+# op tier 3 wants under check_vma, and what the int8 ring's final gather
+# uses.  jax 0.9.0 keeps it private.  If an upgrade moves it this import
+# fails loudly: the psum stand-in that would keep things running moves
+# about twice the ICI bytes.
+from jax._src.lax.parallel import all_gather_invariant
 
 
 def hierarchical_allreduce(x, *, average: bool = False,
@@ -71,19 +63,9 @@ def hierarchical_allreduce(x, *, average: bool = False,
     # check_vma=True a plain all_gather output is tracked as varying over
     # the gathered axis, which would poison every downstream out_spec;
     # ``all_gather_invariant`` is the sound Varying→Invariant gather —
-    # same ICI bytes as all_gather, provably-replicated type (VERDICT r4
-    # weak #4 closed).  If a future jax drops the private symbol, fall
-    # back to psum of the shard placed at its own offset in a zero buffer
-    # (identical value, ~2× ICI bytes).
-    if getattr(jax.typeof(shard), "vma", frozenset()):
-        if _gather_inv is not None:
-            full = _gather_inv(shard, ici_axis, axis=0, tiled=True)
-        else:                                         # pragma: no cover
-            shard_len = padded // n_ici
-            placed = lax.dynamic_update_slice(
-                jnp.zeros((padded,), shard.dtype), shard,
-                (lax.axis_index(ici_axis) * shard_len,))
-            full = lax.psum(placed, ici_axis)
+    # same ICI bytes as all_gather, provably-replicated type.
+    if jax.typeof(shard).vma:
+        full = all_gather_invariant(shard, ici_axis, axis=0, tiled=True)
     else:
         full = lax.all_gather(shard, ici_axis, axis=0, tiled=True)
     out = full[:size].reshape(x.shape)

@@ -70,8 +70,7 @@ def pipeline_apply(stage_fn: Callable, stage_params, x_microbatches,
     # The scan carry's variance must match the body's output: varying over
     # pp (per-stage state) and over every axis the input varies on (e.g.
     # dp when the batch is data-sharded on an outer mesh axis).
-    carry_axes = set(getattr(jax.typeof(x_microbatches), "vma",
-                             frozenset())) | {axis}
+    carry_axes = set(jax.typeof(x_microbatches).vma) | {axis}
     state0 = jnp.zeros(mb_shape, x_microbatches.dtype)
     out0 = jnp.zeros((M,) + mb_shape, x_microbatches.dtype)
     for ax in sorted(carry_axes):

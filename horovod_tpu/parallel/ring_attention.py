@@ -172,9 +172,7 @@ def _ring_scan(q, k, v, axis_name, hop):
     # tracking (check_vma=True) classifies them invariant while the loop
     # body produces varying values — the carry types would mismatch.  Cast
     # them to the axes the inputs actually vary over (no-op when unchecked).
-    vma = (getattr(jax.typeof(q), "vma", frozenset())
-           | getattr(jax.typeof(k), "vma", frozenset())
-           | getattr(jax.typeof(v), "vma", frozenset()))
+    vma = jax.typeof(q).vma | jax.typeof(k).vma | jax.typeof(v).vma
     o0 = jnp.zeros((B, T, H, D), jnp.float32)
     m0 = jnp.full((B, H, T), _NEG_BIG, jnp.float32)
     l0 = jnp.zeros((B, H, T), jnp.float32)

@@ -21,6 +21,7 @@ Env contract (what mpirun's ``-x`` propagation becomes):
 from __future__ import annotations
 
 import argparse
+import glob
 import os
 import random
 import signal
@@ -66,6 +67,35 @@ def free_port() -> int:
     port = s.getsockname()[1]
     s.close()
     return port
+
+
+def tpu_chips_on_host() -> int:
+    """TPU chips this host exposes, counted from the device files the
+    runtime itself opens.  The launcher never asks jax: a parent that
+    initialises a backend holds the chips its children need."""
+    return len(glob.glob("/dev/accel[0-9]*")
+               or glob.glob("/dev/vfio/[0-9]*"))
+
+
+# Chip-grid bounds of one process by its chip count — the table jax's own
+# multi-process TPU test launcher uses (jax/_src/test_multiprocess.py).
+_CHIPS_PER_PROCESS_BOUNDS = {1: "1,1,1", 2: "1,2,1", 4: "2,2,1", 8: "2,4,1"}
+
+
+def chip_env(slot: int, rpp: int) -> dict:
+    """The runtime's per-process visibility settings that give one child
+    the chips ``[slot*rpp, (slot+1)*rpp)`` and nothing else.  Each child
+    is a runtime of its own (process bounds 1,1,1): this launcher's jobs
+    are disjoint runtimes joined by the TCP control plane, not one slice
+    spread over processes."""
+    return {
+        "TPU_VISIBLE_CHIPS": ",".join(
+            str(c) for c in range(slot * rpp, (slot + 1) * rpp)),
+        "TPU_CHIPS_PER_PROCESS_BOUNDS": _CHIPS_PER_PROCESS_BOUNDS[rpp],
+        "TPU_PROCESS_BOUNDS": "1,1,1",
+        # Several runtimes on one host: skip libtpu's one-process lockfile.
+        "ALLOW_MULTIPLE_LIBTPU_LOAD": "1",
+    }
 
 
 def main(argv=None):
@@ -155,6 +185,39 @@ def main(argv=None):
     rpp = args.ranks_per_process
     size = nproc_total * rpp
 
+    # One process for each chip.  Where the children will run on this
+    # host's TPU chips, every child is confined to its own ``rpp`` chips;
+    # left alone each would see them all, and the second one to start
+    # would never get the device.  A slot is free again once the child
+    # that held it has exited (elastic relaunches reuse it).
+    platforms = os.environ.get("JAX_PLATFORMS", "")
+    n_chips = tpu_chips_on_host() if (
+        not platforms or "tpu" in platforms.split(",")) else 0
+    slot_owner: dict = {}
+    if n_chips:
+        wanted = (args.num_proc + args.num_standby) * rpp
+        if rpp not in _CHIPS_PER_PROCESS_BOUNDS or wanted > n_chips:
+            p.error(
+                f"{args.num_proc} process(es) + {args.num_standby} "
+                f"standby(s) x {rpp} chip(s) each cannot be given chips of "
+                f"their own on this host ({n_chips} TPU chip(s); "
+                f"--ranks-per-process must be one of "
+                f"{sorted(_CHIPS_PER_PROCESS_BOUNDS)}).  Set "
+                "JAX_PLATFORMS=cpu to run the children off the chips.")
+
+    def spawn(env: dict) -> subprocess.Popen:
+        if not n_chips:
+            return subprocess.Popen(cmd, env=env)
+        slot = next((s for s in range(n_chips // rpp)
+                     if s not in slot_owner
+                     or slot_owner[s].poll() is not None), None)
+        if slot is None:
+            raise RuntimeError(
+                "horovod_tpu.run: no free TPU chip for another child")
+        env.update(chip_env(slot, rpp))
+        slot_owner[slot] = subprocess.Popen(cmd, env=env)
+        return slot_owner[slot]
+
     def child_env(pidx: int, standby: bool = False) -> dict:
         env = dict(os.environ)
         env.update({
@@ -193,9 +256,8 @@ def main(argv=None):
                 env["HOROVOD_TPU_TIMELINE"], pidx * rpp, size)
         return env
 
-    procs = [
-        subprocess.Popen(cmd, env=child_env(args.process_index_base + i))
-        for i in range(args.num_proc)]
+    procs = [spawn(child_env(args.process_index_base + i))
+             for i in range(args.num_proc)]
 
     if args.elastic:
         # Standby process indices live above the worker range so each
@@ -208,7 +270,7 @@ def main(argv=None):
         def spawn_standby():
             pidx = next_standby_pidx[0]
             next_standby_pidx[0] += 1
-            sb = subprocess.Popen(cmd, env=child_env(pidx, standby=True))
+            sb = spawn(child_env(pidx, standby=True))
             standbys.append(sb)
             return sb
 

@@ -60,3 +60,81 @@ def test_run_np2_allreduce(tmp_path):
     assert proc.returncode == 0, combined
     assert "LAUNCH_OK rank=0 size=2" in combined, combined
     assert "LAUNCH_OK rank=1 size=2" in combined, combined
+
+
+class TestOneProcessPerChip:
+    """Where the children run on the host's TPU chips, child i is confined
+    to the chips [i*rpp, (i+1)*rpp) through the runtime's own visibility
+    settings; where that cannot be done the launcher refuses, once.  The
+    launcher's parent never asks jax (it would take the chips itself)."""
+
+    @pytest.fixture()
+    def spawned(self, monkeypatch):
+        """Run run.main with Popen replaced: the env of each child."""
+        from horovod_tpu import run as run_mod
+
+        envs = []
+
+        class Child:
+            def __init__(self, cmd, env=None):
+                envs.append(env)
+
+            def poll(self):
+                return None        # still running while the rest spawn
+
+        monkeypatch.setattr(run_mod.subprocess, "Popen", Child)
+        monkeypatch.setattr(run_mod, "_supervise", lambda procs, grace: 0)
+        monkeypatch.delenv("HOROVOD_TPU_TIMELINE", raising=False)
+        return run_mod, envs
+
+    def test_children_get_disjoint_chips(self, spawned, monkeypatch):
+        run_mod, envs = spawned
+        monkeypatch.setattr(run_mod, "tpu_chips_on_host", lambda: 4)
+        monkeypatch.setenv("JAX_PLATFORMS", "tpu,cpu")
+        assert run_mod.main(["-np", "4", "--", "true"]) == 0
+        assert [e["TPU_VISIBLE_CHIPS"] for e in envs] == ["0", "1", "2", "3"]
+        assert {e["TPU_PROCESS_BOUNDS"] for e in envs} == {"1,1,1"}
+        assert {e["TPU_CHIPS_PER_PROCESS_BOUNDS"] for e in envs} == {"1,1,1"}
+        assert [e["HOROVOD_TPU_RANK"] for e in envs] == ["0", "1", "2", "3"]
+
+    def test_two_chips_per_process(self, spawned, monkeypatch):
+        run_mod, envs = spawned
+        monkeypatch.setattr(run_mod, "tpu_chips_on_host", lambda: 4)
+        monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+        assert run_mod.main(["-np", "2", "--ranks-per-process", "2",
+                             "--", "true"]) == 0
+        assert [e["TPU_VISIBLE_CHIPS"] for e in envs] == ["0,1", "2,3"]
+        assert {e["TPU_CHIPS_PER_PROCESS_BOUNDS"] for e in envs} == {"1,2,1"}
+
+    def test_too_few_chips_refuses_before_spawning(self, spawned,
+                                                   monkeypatch, capsys):
+        run_mod, envs = spawned
+        monkeypatch.setattr(run_mod, "tpu_chips_on_host", lambda: 1)
+        monkeypatch.setenv("JAX_PLATFORMS", "tpu,cpu")
+        with pytest.raises(SystemExit) as exc:
+            run_mod.main(["-np", "4", "--", "true"])
+        assert exc.value.code != 0
+        assert "cannot be given chips of their own" in capsys.readouterr().err
+        assert envs == []
+
+    def test_cpu_children_are_left_alone(self, spawned, monkeypatch):
+        """bench.py's workers and the tests pin JAX_PLATFORMS=cpu: no
+        chip is assigned, and the host's chips are not even counted."""
+        run_mod, envs = spawned
+        monkeypatch.setattr(run_mod, "tpu_chips_on_host",
+                            lambda: pytest.fail("counted the chips"))
+        monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+        assert run_mod.main(["-np", "2", "--", "true"]) == 0
+        assert all("TPU_VISIBLE_CHIPS" not in e for e in envs)
+
+    def test_launcher_import_starts_no_backend(self):
+        """`python -m horovod_tpu.run` and `import horovod_tpu` leave
+        every jax backend uninitialised."""
+        out = subprocess.run(
+            [sys.executable, "-c",
+             "import horovod_tpu, horovod_tpu.run; "
+             "import jax._src.xla_bridge as xb; print(len(xb._backends))"],
+            capture_output=True, text=True, timeout=120,
+            cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+        assert out.returncode == 0, out.stderr[-2000:]
+        assert out.stdout.strip() == "0"

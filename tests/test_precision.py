@@ -565,6 +565,10 @@ def test_auto_spmd_routes_per_bucket_at_trace_time(hvd, monkeypatch):
              "b": jnp.full((4, 4), val, jnp.float32)}
 
     def reduce_fn(g):
+        # Per-shard gradients vary over the axis; a replicated input
+        # would be taken for a pre-summed gradient and only divided.
+        g = jax.tree.map(
+            lambda v: jax.lax.pcast(v, "ranks", to="varying"), g)
         return hvd_jax.allreduce_gradients(g, axis_name="ranks",
                                            compression="auto")
 

@@ -1,12 +1,18 @@
-"""Trace-parsing tests for horovod_tpu.profiling against a fabricated
-Chrome trace (the CPU platform emits no device spans, so the parsers are
-exercised on synthetic data shaped exactly like a real TPU trace)."""
+"""Trace-parsing tests for horovod_tpu.profiling: against a fabricated
+Chrome trace, and against one recorded on a TPU v5e (the CPU platform
+emits no device spans)."""
 
 import gzip
 import json
 import os
+import shutil
+
+import pytest
 
 from horovod_tpu import profiling
+
+RECORDED = os.path.join(os.path.dirname(__file__), "data",
+                        "tpu_v5e_matmul.trace.json.gz")
 
 
 def write_trace(tmp_path, events):
@@ -61,7 +67,7 @@ def test_device_time_none_without_device(tmp_path):
 
 def test_per_op_rooflines(tmp_path):
     d = write_trace(tmp_path, make_events())
-    rows = profiling.per_op_rooflines(d, peak_flops=2e12, peak_bytes=1e9)
+    rows = profiling.per_op_rooflines(d, profiling.DevicePeaks(2e12, 1e9))
     assert len(rows) == 1
     r = rows[0]
     # .N suffix stripped, both instances aggregated.
@@ -82,5 +88,45 @@ def test_capture_returns_dir():
     log_dir = profiling.capture(
         lambda: jnp.ones((8,)).sum().block_until_ready(), iters=1)
     assert os.path.isdir(log_dir)
-    # CPU platform: parsers must degrade gracefully, not crash.
-    assert profiling.per_op_rooflines(log_dir) == []
+    # CPU platform: no device process, so no rows and no device time.
+    assert profiling.per_op_rooflines(
+        log_dir, profiling.DevicePeaks(1.0, 1.0)) == []
+    assert profiling.device_time_ms(log_dir) is None
+
+
+def test_capture_raises_without_device_spans_off_cpu(monkeypatch):
+    """On an accelerator a trace with no device process is a failure of
+    the measurement, not an empty result."""
+    import jax
+    import jax.numpy as jnp
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="no device process"):
+        profiling.capture(
+            lambda: jnp.ones((8,)).sum().block_until_ready(),
+            warmup=0, iters=1)
+
+
+def test_recorded_v5e_trace(tmp_path):
+    """A trace recorded on the chip (jax 0.9.0, one TPU v5e, three calls
+    of a jitted 4096^3 bf16 matmul-and-sum): the module span is 0.7045 ms
+    and the matmul fusion runs at 99% of the published bf16 peak."""
+    d = tmp_path / "plugins" / "profile" / "recorded"
+    d.mkdir(parents=True)
+    shutil.copy(RECORDED, d)
+    assert profiling.device_time_ms(str(tmp_path)) == pytest.approx(
+        0.70454, abs=1e-4)
+    rows = profiling.per_op_rooflines(
+        str(tmp_path), profiling.device_peaks("TPU v5 lite"))
+    top = rows[0]
+    assert top["op"] == "convolution_reduce_fusion"
+    assert top["count"] == 3
+    assert top["ms"] == pytest.approx(2.114, abs=1e-3)
+    assert top["pct_of_peak_flops"] == 99.0
+
+
+def test_device_peaks_table():
+    v5e = profiling.device_peaks("TPU v5 lite")
+    assert (v5e.bf16_flops, v5e.hbm_bytes_per_s) == (197e12, 819e9)
+    with pytest.raises(LookupError, match="no published peaks"):
+        profiling.device_peaks("TPU v9 imaginary")

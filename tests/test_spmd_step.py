@@ -253,10 +253,6 @@ def test_single_chip_fast_path_keeps_aux_guard(hvd, single_chip_mesh):
     1-device fast path exactly as on a pod: a model whose aux is computed
     per-shard from the batch would silently diverge multi-chip, and the
     error must not wait for the first multi-chip trace to surface."""
-    if not hasattr(jax.lax, "pvary"):
-        pytest.skip("this jax predates VMA tracking; the varying-aux "
-                    "diagnostic depends on jax.typeof(...).vma")
-
     def bad_loss(params, aux, batch):
         x, y = batch
         err = jnp.mean((x @ params["w"] + params["b"] - y) ** 2)
@@ -338,11 +334,8 @@ def test_hierarchical_gather_is_allgather_under_vma(hvd):
     mean)."""
     if hvd.size() < 4:
         pytest.skip("needs a 2x2+ mesh")
-    from horovod_tpu.parallel.hierarchical import (_gather_inv,
-                                                   hierarchical_allreduce)
+    from horovod_tpu.parallel.hierarchical import hierarchical_allreduce
     from horovod_tpu.parallel.mesh import DCN_AXIS, ICI_AXIS
-    if _gather_inv is None:
-        pytest.skip("all_gather_invariant unavailable in this jax")
     devs = np.asarray(jax.devices()[:4]).reshape(2, 2)
     mesh = Mesh(devs, (DCN_AXIS, ICI_AXIS))
 

@@ -18,6 +18,7 @@ from jax.sharding import PartitionSpec as P
 
 import horovod_tpu.jax as hvd_jax
 from horovod_tpu.compression import Compression
+from horovod_tpu.parallel._vma import ensure_varying_tree
 
 
 def _init_params(key, sizes):
@@ -231,18 +232,38 @@ def test_int8_error_feedback_convergence(hvd, monkeypatch):
             optax.sgd(0.05), axis_name="ranks", compression=compression,
             error_feedback=error_feedback)
         state = opt.init(params)
+        state_spec = P()
+        if error_feedback:
+            # The residual is each rank's own quantization error, so it
+            # lives sharded: one slice per rank along a leading axis.
+            state = state._replace(residual=jax.tree.map(
+                lambda r: jnp.stack([r] * mesh.size), state.residual))
+            state_spec = hvd_jax.ErrorFeedbackState(
+                inner=P(), residual=P("ranks"))
 
         def train_step(params, state, xs, ys):
+            if error_feedback:
+                state = state._replace(residual=jax.tree.map(
+                    lambda r: r[0], state.residual))
+            # Differentiate a VARYING view of the replicated params (as
+            # make_train_step does): the cotangents are then the raw
+            # per-shard gradients the wire compresses.  Against the
+            # invariant params jax's own transpose-psum would hand the
+            # optimizer pre-summed gradients and no wire would engage.
             (_, mse), grads = jax.value_and_grad(
-                spike_loss, has_aux=True)(params, xs, ys)
+                spike_loss, has_aux=True)(
+                    ensure_varying_tree(params, "ranks"), xs, ys)
             updates, state = opt.update(grads, state, params)
             params = optax.apply_updates(params, updates)
+            if error_feedback:
+                state = state._replace(residual=jax.tree.map(
+                    lambda r: r[None], state.residual))
             return params, state, jax.lax.pmean(mse, "ranks")
 
         f = jax.jit(jax.shard_map(
             train_step, mesh=mesh,
-            in_specs=(P(), P(), P("ranks"), P("ranks")),
-            out_specs=(P(), P(), P())))
+            in_specs=(P(), state_spec, P("ranks"), P("ranks")),
+            out_specs=(P(), state_spec, P())))
         for _ in range(steps):
             params, state, mse = f(params, state, x, y)
         return float(mse)

@@ -1,52 +1,18 @@
-"""Roofline attribution from a captured step trace: per-op time, FLOP/s
-vs 197 TF/s peak, bytes vs 819 GB/s peak, grouped by (name-stem, source).
-Usage: python tools/roofline.py [trace_glob]"""
+"""Roofline attribution of a captured step trace: per-op time, FLOP/s
+and bytes/s against the published peaks of the chip that ran it, grouped
+by (name-stem, source).
+Usage: python tools/roofline.py TRACE_DIR DEVICE_KIND
+  e.g. python tools/roofline.py chiprun_out/stepprof "TPU v5 lite"
+(The trace does not record the device kind; the script that captured it
+prints it.)"""
 import os as _os, sys as _sys
 _sys.path.insert(0, _os.path.dirname(_os.path.dirname(_os.path.abspath(__file__))))
-import glob
-import gzip
-import json
-import re
 import sys
-from collections import defaultdict
 
-PEAK_F = 197e12
-PEAK_B = 819e9
+from horovod_tpu import profiling
 
-pat = sys.argv[1] if len(sys.argv) > 1 else "/tmp/stepprof*"
-paths = sorted(glob.glob(pat + "/plugins/profile/*/*.trace.json.gz"))
-path = paths[-1]
-print("trace:", path)
-with gzip.open(path) as fh:
-    t = json.load(fh)
-evts = t.get("traceEvents", [])
-tids = {(e["pid"], e["tid"]): e["args"].get("name", "") for e in evts
-        if e.get("ph") == "M" and e.get("name") == "thread_name"}
-
-agg = defaultdict(lambda: [0.0, 0.0, 0.0, 0])   # dur_us, flops, bytes, n
-for e in evts:
-    if e.get("ph") != "X":
-        continue
-    if tids.get((e.get("pid"), e.get("tid"))) != "XLA Ops":
-        continue
-    a = e.get("args", {})
-    stem = re.sub(r"\.\d+(\.remat)?$", r"\1", e.get("name", ""))
-    src = a.get("source", "?")
-    src = re.sub(r".*/(site-packages|repo)/", "", src)
-    key = (stem, src)
-    agg[key][0] += e.get("dur", 0.0)
-    agg[key][1] += float(a.get("model_flops", 0) or 0)
-    agg[key][2] += float(a.get("bytes_accessed", 0) or 0)
-    agg[key][3] += 1
-
-total = sum(v[0] for v in agg.values())
-print(f"total XLA-op time: {total/1e3:.2f} ms")
-print(f"{'ms':>9} {'%':>5} {'n':>5} {'TF/s':>6} {'%MXU':>5} {'GB/s':>6} "
-      f"{'%HBM':>5}  op @ source")
-for (stem, src), (dur, fl, by, n) in sorted(
-        agg.items(), key=lambda kv: -kv[1][0])[:35]:
-    tfs = fl / (dur * 1e-6) / 1e12 if dur else 0
-    gbs = by / (dur * 1e-6) / 1e9 if dur else 0
-    print(f"{dur/1e3:9.3f} {100*dur/total:5.1f} {n:5d} {tfs:6.1f} "
-          f"{100*tfs*1e12/PEAK_F:5.1f} {gbs:6.1f} "
-          f"{100*gbs*1e9/PEAK_B:5.1f}  {stem} @ {src}")
+log_dir, device_kind = sys.argv[1], sys.argv[2]
+rows = profiling.per_op_rooflines(log_dir, profiling.device_peaks(device_kind))
+print(f"device module span: {profiling.device_time_ms(log_dir)} ms; "
+      f"XLA-op time: {sum(r['ms'] for r in rows):.2f} ms")
+profiling.print_rooflines(rows, top=35)

@@ -1,21 +1,16 @@
-"""Long-context T-sweep: flash vs full attention fwd+grad on the real
-chip — device ms (profiler span), tokens/s, and compiled peak temp
-memory.  Emits a markdown table for docs/long-context.md."""
+"""Long-context T-sweep: flash vs full attention fwd+grad on the chip —
+device ms (profiler span), tokens/s, and compiled peak temp memory.
+Emits a markdown table for docs/long-context.md."""
 import os as _os, sys as _sys
 _sys.path.insert(0, _os.path.dirname(_os.path.dirname(_os.path.abspath(__file__))))
 import functools
-import glob
-import gzip
-import json
-import os
 import sys
-import tempfile
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax import lax
 
+from horovod_tpu import compile_cache, profiling
 from horovod_tpu.ops.flash_attention import flash_attention
 from horovod_tpu.parallel.ring_attention import full_attention
 
@@ -24,26 +19,9 @@ REPS = 8
 
 
 def device_ms(jfn, *args):
-    out = jfn(*args)
-    jax.block_until_ready(out)
-    tmp = tempfile.mkdtemp(prefix="tsweep")
-    with jax.profiler.trace(tmp):
-        out = jfn(*args)
-        jax.block_until_ready(out)
-    path = sorted(glob.glob(os.path.join(
-        tmp, "plugins/profile/*/*.trace.json.gz")))[-1]
-    with gzip.open(path) as fh:
-        trace = json.load(fh)
-    evts = trace.get("traceEvents", [])
-    pids = {e["pid"]: e["args"].get("name", "") for e in evts
-            if e.get("ph") == "M" and e.get("name") == "process_name"}
-    dev = {p for p, n in pids.items() if "TPU" in n}
-    best = 0.0
-    for e in evts:
-        if (e.get("ph") == "X" and e.get("pid") in dev
-                and e.get("name", "").startswith("jit_")):
-            best = max(best, e.get("dur", 0.0))
-    return best / 1e3 / REPS
+    log_dir = profiling.capture(
+        lambda: jax.block_until_ready(jfn(*args)), warmup=1, iters=1)
+    return profiling.device_time_ms(log_dir, per=REPS)
 
 
 def temp_gb(jfn, *args):
@@ -71,6 +49,7 @@ def grad_step(attn_fn):
 
 
 def main():
+    compile_cache.enable()
     Ts = [int(a) for a in sys.argv[1:]] or [2048, 4096, 8192, 16384]
     print("| T | impl | fwd+bwd ms | tokens/s (B*T/step) | peak temp GB |")
     print("|---|------|-----------:|--------------------:|-------------:|")
