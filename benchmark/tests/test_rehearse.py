@@ -1,0 +1,147 @@
+"""CPU rehearsals of every cell through the one command, and the proof
+that a cell, a configuration, a family and a per-layer metric dropped in
+as new files are found with no edit to a file that exists."""
+
+import json
+import os
+import shutil
+import subprocess
+import sys
+
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+CELLS = sorted(f[:-5] for f in os.listdir(
+    os.path.join(ROOT, "benchmark", "workloads")) if f.endswith(".json"))
+LAST_LINE_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+
+
+def run(root, *args, env=None, timeout=600):
+    clean = {k: v for k, v in os.environ.items()
+             if not k.startswith(("HOROVOD_TPU_", "BENCH_", "XLA_FLAGS"))}
+    clean.update(env or {})
+    return subprocess.run(
+        [sys.executable, os.path.join(root, "benchmark", "run.py"), *args],
+        cwd=root, env=clean, capture_output=True, text=True, timeout=timeout)
+
+
+def last_line(proc):
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    line = json.loads(proc.stdout.strip().splitlines()[-1])
+    assert LAST_LINE_KEYS <= set(line)
+    return line
+
+
+@pytest.mark.parametrize("trace", [0, 1])
+@pytest.mark.parametrize("cell", CELLS)
+def test_rehearsal(cell, trace):
+    proc = run(ROOT, "--workload", cell, "--seed", "3", "--seconds", "1",
+               "--trace", str(trace), "--rehearse")
+    line = last_line(proc)
+    assert line["correct"] is True and line["failed"] == 0
+    assert line["attempted"] >= 2
+    spec = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
+    chips = {w["name"]: w["chips"] for w in spec["workloads"]}[cell]
+    assert line["device"]["platform"] == "cpu"
+    assert line["device"]["count"] == chips
+    assert "busy_s" not in line["device"]        # no device trace off the chip
+    # Off the chip no number is written under a device metric's name.
+    assert line["metrics"]
+    assert all(m["value"] is None for m in line["metrics"].values())
+    group = "per_layer" if trace else "end_to_end"
+    allowed = {m["name"] for m in spec[group]
+               if cell in m.get("workloads", [cell])}
+    assert set(line["metrics"]) <= allowed
+    if not trace:
+        assert set(line["metrics"]) == allowed
+    earlier = [json.loads(l) for l in proc.stdout.splitlines()[:-1]
+               if l.startswith('{"bench"')]
+    window = next(l for l in earlier if l["bench"] == "window")
+    assert window["compiles_in_window"] == 0
+
+
+def test_without_a_tpu_the_command_fails_and_prints_no_result():
+    proc = run(ROOT, "--workload", CELLS[0], "--seed", "0", "--seconds", "1",
+               "--trace", "0", env={"JAX_PLATFORMS": "cpu"})
+    assert proc.returncode != 0
+    assert '"correct"' not in proc.stdout
+
+
+def test_a_knob_in_the_environment_is_taken_out():
+    """Whatever the caller's environment holds, the cell runs the
+    defaults; an argument the command does not know loses no run."""
+    proc = run(ROOT, "--workload", CELLS[0], "--seconds", "1", "--rehearse",
+               "--not-an-option", "7",
+               env={"HOROVOD_TPU_OVERLAP": "1", "BENCH_ANYTHING": "x"})
+    assert last_line(proc)["correct"] is True
+    job = next(json.loads(l) for l in proc.stdout.splitlines()
+               if l.startswith('{"bench": "job"'))
+    assert job["knobs_taken_out_of_environment"] == [
+        "BENCH_ANYTHING", "HOROVOD_TPU_OVERLAP"]
+    assert job["unknown_arguments"] == ["--not-an-option", "7"]
+
+
+def test_a_broken_command_line_has_its_own_exit_code():
+    proc = run(ROOT, "--seed", "x")
+    assert proc.returncode == 64 and '"correct"' not in proc.stdout
+
+
+def test_without_the_system_the_command_fails(tmp_path):
+    shutil.copytree(os.path.join(ROOT, "benchmark"), tmp_path / "benchmark",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), tmp_path)
+    proc = run(str(tmp_path), "--workload", CELLS[0], "--rehearse")
+    assert proc.returncode == 66
+    assert '"correct"' not in proc.stdout
+
+
+NEW_FAMILY = '''
+from benchmark.families.gpt2_lm import *      # noqa: F401,F403
+from benchmark.families import gpt2_lm as _base
+THROUGHPUT = _base.THROUGHPUT
+TINY = {**_base.TINY, "n_layer": 1}
+'''
+NEW_METRIC = '''
+UNIT = "steps"
+LAYER = "input pipeline"
+MOVES = "step_ms"
+
+
+def read(record, trace):
+    return float(record["steps"])
+'''
+
+
+def test_new_files_are_found_with_no_edit(tmp_path):
+    """A later PR's cell, configuration, family and per-layer metric: four
+    new files in a copy of the tree, nothing that exists touched."""
+    root = tmp_path / "repo"
+    root.mkdir()
+    bench = root / "benchmark"
+    shutil.copytree(os.path.join(ROOT, "benchmark"), bench,
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    for name in ("horovod_tpu", "cpp"):
+        os.symlink(os.path.join(ROOT, name), root / name)
+    before = {p: p.read_bytes() for p in bench.rglob("*") if p.is_file()}
+
+    cfg = json.load(open(bench / "configs" / "cerebras-gpt-1.3b.json"))
+    cfg.update(name="new-lm", family="new_family")
+    (bench / "configs" / "new-lm.json").write_text(json.dumps(cfg))
+    (bench / "families" / "new_family.py").write_text(NEW_FAMILY)
+    (bench / "workloads" / "new_cell.json").write_text(json.dumps(
+        {"config": "new-lm", "traffic": "dp1_b8", "why": "a test's cell"}))
+    (bench / "metrics" / "new_steps.py").write_text(NEW_METRIC)
+
+    proc = run(str(root), "--workload", "new_cell", "--seed", "5",
+               "--seconds", "1", "--trace", "1", "--rehearse")
+    line = last_line(proc)
+    assert line["correct"] is True
+    assert "new_steps" in line["metrics"]
+    assert line["metrics"]["new_steps"]["unit"] == "steps"
+    job = next(json.loads(l) for l in proc.stdout.splitlines()
+               if l.startswith('{"bench": "job"'))
+    assert (job["cell"], job["config"], job["family"]) == (
+        "new_cell", "new-lm", "new_family")
+    assert job["parameters"] < 543232          # one layer, not the tiny two
+    assert all(p.read_bytes() == data for p, data in before.items())
