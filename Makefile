@@ -24,9 +24,13 @@ asan:
 tsan:
 	$(MAKE) -C cpp tsan
 
-# Tier-1 test suite, same invocation ROADMAP.md documents.
+# Tier-1 test suite, as the driver runs it (six xdist workers, a file to a
+# worker, 1470 s for the whole; tests/conftest.py limits each test).
 test:
-	JAX_PLATFORMS=cpu $(PYTHON) -m pytest tests/ -q -m 'not slow'
+	timeout -k 10 1470 env JAX_PLATFORMS=cpu ALLOW_MULTIPLE_LIBTPU_LOAD=1 \
+		$(PYTHON) -m pytest tests/ -q -m 'not slow' \
+		--continue-on-collection-errors -p no:cacheprovider -p no:randomly \
+		-p xdist -n 6 --dist loadfile
 
 clean:
 	$(MAKE) -C cpp clean

@@ -123,6 +123,10 @@ def main():
             cbs.on_batch_begin(b)
             state.params, _, state.opt_state, loss = train_step(
                 state.params, {}, state.opt_state, batch)
+            if losses:
+                # Lagged read (see jax_mnist_advanced.py): at most one step
+                # queued behind the one that runs.
+                losses[-1].block_until_ready()
             losses.append(loss)
             cbs.on_batch_end(b)
         logs = {"loss": float(np.mean([np.asarray(l) for l in losses]))}

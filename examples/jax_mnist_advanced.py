@@ -146,6 +146,13 @@ def main():
             batch = shard_batch((train_x[idx], train_y[idx]), mesh)
             state.params, aux, state.opt_state, loss = train_step(
                 state.params, aux, state.opt_state, batch)
+            if losses:
+                # Lagged read: with step b queued, wait for step b-1.  The
+                # device never idles and the host never runs an epoch ahead.
+                # The CPU client (the 8-virtual-device mesh) deadlocks once
+                # 32 steps are in flight: launches that wait for a slot take
+                # the pool threads its all-reduce participants need.
+                losses[-1].block_until_ready()
             losses.append(loss)
             cbs.on_batch_end(b)
         logs = {"loss": float(np.mean([np.asarray(l) for l in losses]))}

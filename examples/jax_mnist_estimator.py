@@ -89,10 +89,16 @@ class Estimator:
         """Run ``steps // size`` optimizer steps (reference ``:178`` scales
         total work by world size); ``input_fn(step) -> global batch``."""
         local_steps = max(1, steps // hvd.size())
+        loss = None
         for _ in range(local_steps):
             batch = shard_batch(input_fn(self.global_step), self.mesh)
+            prev = loss
             self.params, _, self.opt_state, loss = self._train_step(
                 self.params, {}, self.opt_state, batch)
+            if prev is not None:
+                # Lagged read (see jax_mnist_advanced.py): at most one step
+                # queued behind the one that runs.
+                prev.block_until_ready()
             self.global_step += 1
             if (self.checkpoint_every
                     and self.global_step % self.checkpoint_every == 0):

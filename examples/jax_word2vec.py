@@ -127,6 +127,7 @@ def main():
         donate_argnums=(0, 1))
 
     t0 = time.perf_counter()
+    loss = None
     for i in range(args.steps):
         centers, contexts = skipgram_pairs(corpus, args.window, global_batch,
                                            rng)
@@ -134,8 +135,13 @@ def main():
                            (global_batch, args.neg)).astype(np.int32)
         centers, contexts, negs = shard_batch(
             (centers, contexts, negs), mesh)
+        prev = loss
         params, opt_state, loss = step(params, opt_state, centers, contexts,
                                        negs)
+        if prev is not None:
+            # Lagged read (see jax_mnist_advanced.py): at most one step
+            # queued behind the one that runs.
+            prev.block_until_ready()
         if i % 50 == 0 and hvd.rank() == 0:
             print(f"step {i}: loss={float(np.asarray(loss)):.4f}")
     if hvd.rank() == 0:

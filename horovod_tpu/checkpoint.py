@@ -318,7 +318,7 @@ def save(directory: str, state: Any, epoch: int) -> Optional[str]:
     directory = os.path.abspath(directory)
     path = checkpoint_path(directory, epoch)
     os.makedirs(directory, exist_ok=True)
-    _clean_stale(directory)
+    _clean_stale(directory, saving=epoch)
     # World-size sidecar lands BEFORE the checkpoint commits (same
     # ordering argument as the optimizer spec): an elastic resume that
     # sees checkpoint-N can always tell what world wrote it.  An orphan
@@ -345,17 +345,19 @@ def _write_atomic(path: str, text: str) -> None:
     os.replace(tmp, path)
 
 
-def _clean_stale(directory: str) -> None:
+def _clean_stale(directory: str, saving: Optional[int] = None) -> None:
     """Remove debris a mid-save crash can leave behind: uncommitted
     staging directories, half-written sidecar temp files, and orphan
     sidecars whose checkpoint never committed.  Runs in the single
     writer (rank 0) at save time.  Staging dirs registered by a live
     async writer (``_ACTIVE_STAGING``) are in flight, not stale — the
     background delta writer may be mid-commit while a synchronous
-    ``save()`` runs on the training thread."""
+    ``save()`` runs on the training thread.  Nor are the sidecars of the
+    epoch ``saving`` now: :func:`save_model` has just written its
+    optimizer spec, ahead of the commit."""
     entries = set(os.listdir(directory))
     active = {os.path.basename(p) for p in _ACTIVE_STAGING.values()}
-    active_epochs = {f"checkpoint-{e}" for e in _ACTIVE_STAGING}
+    active_epochs = {f"checkpoint-{e}" for e in (*_ACTIVE_STAGING, saving)}
     for entry in entries:
         p = os.path.join(directory, entry)
         if re.fullmatch(r"\.tmp-checkpoint-\d+-\d+", entry):

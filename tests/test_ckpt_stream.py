@@ -444,11 +444,19 @@ class TestRunElasticStream:
         monkeypatch.setenv("HOROVOD_TPU_CKPT_EVERY_STEPS", "3")
         d = str(tmp_path)
 
+        taken = []
+
         def train(state, epoch):
             for step in range(1, 7):
-                elastic.snapshot(_state(step), step)
+                if elastic.snapshot(_state(step), step):
+                    taken.append(step)
+                    # Latest wins: a snapshot still unwritten when the next
+                    # one comes is replaced by it.  Wait for the writer, so
+                    # that step 3 is a link of the chain and not a race.
+                    elastic.active_stream().flush()
             return None
         elastic.run_elastic(train, directory=d, like=_state(0))
+        assert taken == [3, 6]
         assert checkpoint.latest_epoch(d) == 6
         assert checkpoint._chain_manifest(d, 6)["prev"] == 3
 

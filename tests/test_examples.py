@@ -4,7 +4,9 @@ with steps 20000→100); same idea here with tiny configs."""
 
 import runpy
 import sys
+import types
 
+import numpy as np
 import pytest
 
 
@@ -13,15 +15,56 @@ def run_example(monkeypatch, path, argv):
     return runpy.run_path(path, run_name="__main__")
 
 
-def test_mnist_example(hvd, monkeypatch):
+@pytest.fixture()
+def run_ahead(monkeypatch):
+    """Counts how far an example's loop runs ahead of the device: the most
+    steps dispatched whose loss the host had not yet waited for.
+
+    The examples are what users copy onto a CPU host, and there the PjRt
+    client deadlocks once 32 steps are in flight on the 8-device mesh (the
+    launches waiting for a slot hold the pool threads that the all-reduce's
+    participants need), so their loops read the previous step's loss."""
+    from horovod_tpu.jax import spmd
+    seen = types.SimpleNamespace(dispatched=0, waited_for=0, most=0)
+
+    class Loss:
+        def __init__(self, value):
+            seen.dispatched += 1
+            self.value, self.number = value, seen.dispatched
+            seen.most = max(seen.most, seen.dispatched - seen.waited_for)
+
+        def block_until_ready(self):
+            seen.waited_for = max(seen.waited_for, self.number)
+            return self.value.block_until_ready()
+
+        def __array__(self, *args, **kwargs):
+            return np.asarray(self.block_until_ready(), *args, **kwargs)
+
+    make = spmd.make_train_step
+
+    def make_train_step(*args, **kwargs):
+        step = make(*args, **kwargs)
+
+        def counted(*a, **k):
+            *state, loss = step(*a, **k)
+            return (*state, Loss(loss))
+        return counted
+
+    monkeypatch.setattr(spmd, "make_train_step", make_train_step)
+    return seen
+
+
+def test_mnist_example(hvd, monkeypatch, run_ahead):
     monkeypatch.setattr(sys, "argv", ["x", "--epochs", "1",
                                       "--batch-size", "16"])
     ns = runpy.run_path("examples/jax_mnist.py")
     acc = ns["main"]()
     assert acc > 0.9, f"synthetic MNIST should be learnable, got acc={acc}"
+    assert run_ahead.dispatched == 64 and run_ahead.most <= 2, run_ahead
 
 
-def test_mnist_advanced_example(hvd, monkeypatch, tmp_path, capsys):
+def test_mnist_advanced_example(hvd, monkeypatch, tmp_path, capsys,
+                                run_ahead):
     monkeypatch.setattr(sys, "argv", [
         "x", "--epochs", "2", "--batch-size", "16", "--warmup-epochs", "1",
         "--checkpoint-dir", str(tmp_path)])
@@ -30,9 +73,14 @@ def test_mnist_advanced_example(hvd, monkeypatch, tmp_path, capsys):
     assert acc > 0.9, f"augmented synthetic MNIST should learn, got {acc}"
     # Rank-0 checkpoint convention: one checkpoint per epoch was written.
     assert (tmp_path / "checkpoint-1").exists()
+    # The second epoch is past the warm-up, whose callbacks read the
+    # optimizer state every step: nothing but the loop's own read holds
+    # the host back there.
+    assert run_ahead.dispatched == 128 and run_ahead.most <= 2, run_ahead
 
 
-def test_mnist_estimator_example(hvd, monkeypatch, tmp_path, capsys):
+def test_mnist_estimator_example(hvd, monkeypatch, tmp_path, capsys,
+                                 run_ahead):
     # Total steps are divided by world size (reference estimator :178).
     first = 40 // hvd.size()
     args = ["--batch-size", "16", "--model-dir", str(tmp_path),
@@ -48,6 +96,8 @@ def test_mnist_estimator_example(hvd, monkeypatch, tmp_path, capsys):
     ns["main"]()
     out = capsys.readouterr().out
     assert f"global_step={first + 16 // hvd.size()}" in out
+    assert run_ahead.dispatched == first + 16 // hvd.size()
+    assert run_ahead.most <= 2, run_ahead
 
 
 def test_model_parallel_example(hvd, monkeypatch, capsys):
@@ -90,7 +140,12 @@ def test_word2vec_example(hvd, monkeypatch, capsys):
     assert "pairs/sec" in out
 
 
-def test_imagenet_example_resume(hvd, monkeypatch, tmp_path, capsys):
+@pytest.mark.time_limit(
+    600, "runs the ResNet-50 example twice on 8 virtual devices: 132 s "
+         "beside the five other workers of the driver's command on the "
+         "sandbox")
+def test_imagenet_example_resume(hvd, monkeypatch, tmp_path, capsys,
+                                 run_ahead):
     args = ["--batch-size", "2", "--steps-per-epoch", "2",
             "--image-size", "32", "--warmup-epochs", "1",
             "--checkpoint-dir", str(tmp_path)]
@@ -102,3 +157,4 @@ def test_imagenet_example_resume(hvd, monkeypatch, tmp_path, capsys):
     assert "epoch 0" in out and "epoch 1" in out
     # The resume run must not retrain epoch 0.
     assert out.count("epoch 0:") == 1
+    assert run_ahead.dispatched == 4 and run_ahead.most <= 2, run_ahead

@@ -82,9 +82,12 @@ def test_per_op_rooflines(tmp_path):
     assert r["source"] == "flax/linear.py:1"
 
 
-def test_capture_returns_dir():
+def test_capture_returns_dir(tmp_path, monkeypatch):
+    import tempfile
+
     import jax.numpy as jnp
 
+    monkeypatch.setattr(tempfile, "tempdir", str(tmp_path))
     log_dir = profiling.capture(
         lambda: jnp.ones((8,)).sum().block_until_ready(), iters=1)
     assert os.path.isdir(log_dir)
@@ -94,7 +97,7 @@ def test_capture_returns_dir():
     assert profiling.device_time_ms(log_dir) is None
 
 
-def test_capture_raises_without_device_spans_off_cpu(monkeypatch):
+def test_capture_raises_without_device_spans_off_cpu(monkeypatch, tmp_path):
     """On an accelerator a trace with no device process is a failure of
     the measurement, not an empty result."""
     import jax
@@ -104,7 +107,7 @@ def test_capture_raises_without_device_spans_off_cpu(monkeypatch):
     with pytest.raises(RuntimeError, match="no device process"):
         profiling.capture(
             lambda: jnp.ones((8,)).sum().block_until_ready(),
-            warmup=0, iters=1)
+            warmup=0, iters=1, log_dir=str(tmp_path))
 
 
 def test_recorded_v5e_trace(tmp_path):

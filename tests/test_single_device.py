@@ -78,7 +78,7 @@ _EXAMPLES = [
      ["--epochs", "1", "--batch-size", "16"]),
     ("examples/jax_mnist_advanced.py",
      ["--epochs", "1", "--batch-size", "16", "--warmup-epochs", "1",
-      "--checkpoint-dir", "/tmp/single_dev_ckpt"]),
+      "--checkpoint-dir", "{tmp_path}"]),
 ]
 
 
@@ -102,10 +102,10 @@ def test_train_step_and_eager_on_one_device_mesh():
 
 @pytest.mark.parametrize("path,argv", _EXAMPLES,
                          ids=[p.split("/")[-1] for p, _ in _EXAMPLES])
-def test_example_on_one_device_mesh(path, argv):
+def test_example_on_one_device_mesh(path, argv, tmp_path):
     if not os.path.exists(os.path.join(
             os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
             path)):
         pytest.skip(f"{path} not present")
-    out = _run([path] + argv)
+    out = _run([path] + [a.format(tmp_path=tmp_path) for a in argv])
     assert out.returncode == 0, f"{out.stdout[-3000:]}\n{out.stderr[-3000:]}"

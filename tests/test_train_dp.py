@@ -168,6 +168,9 @@ def test_broadcast_optimizer_state(hvd):
         np.asarray(a), np.asarray(b)), out, state)
 
 
+@pytest.mark.time_limit(
+    600, "compiles ResNet-50 twice, with and without remat: 101 s beside "
+         "the five other workers of the driver's command on the sandbox")
 def test_resnet_remat_is_semantics_preserving(hvd):
     """ResNet(remat=True) must share the param tree with remat=False (the
     knob trades HBM traffic for recompute, nothing else) — forward and
