@@ -18,7 +18,8 @@ through the entry points a user calls (``hvd.init()`` →
 ``--chips 4`` runs instead, and only, what exists across chips: the
 launcher giving four children a chip each, the mesh order, eager and
 in-jit collectives against numpy, and the ``shard_map`` step (fp32 and
-int8 wire) against the same step on a one-device mesh.
+int8 wire) against the same step on a one-device mesh, with the share of
+the fp32 step's all-reduced bytes that the compiler fused with compute.
 
 Every phase prints one JSON line; a phase that fails raises, and the
 script exits non-zero without a result.  Without a TPU it fails at once:
@@ -262,16 +263,23 @@ def put(tree, mesh, spec):
     return jax.device_put(tree, NamedSharding(mesh, spec))
 
 
-def run_steps(step, state, batch, steps: int, events) -> dict:
+def run_steps(step, state, batch, steps: int, events,
+              fused_share: bool = False) -> dict:
     """Call ``step`` ``steps`` times on the same batch, each call ended by
     ``block_until_ready``.  Returns the final state and what the calls
     showed: the lowered text's program and kernels, first-call seconds
     (trace + compile or cache read + run), whether the persistent cache
-    was hit, per-step seconds and losses."""
+    was hit, per-step seconds and losses.  ``fused_share`` compiles the
+    lowered step as well (a read of the program the first call wrote) and
+    reports the share of its all-reduced bytes that the compiler put
+    inside async collective fusions."""
     import jax
 
+    from horovod_tpu.jax.spmd import fused_all_reduce_share
+
     params, aux, opt_state = state
-    text = step.lower(params, aux, opt_state, batch).as_text()
+    lowered = step.lower(params, aux, opt_state, batch)
+    text = lowered.as_text()
     hits, writes = events.hits, events.writes
     losses, seconds = [], []
     for _ in range(steps):
@@ -294,6 +302,9 @@ def run_steps(step, state, batch, steps: int, events) -> dict:
         "step_s": seconds[1:],
         "losses": [round(l, 5) for l in losses],
     }
+    if fused_share:
+        report["fused_all_reduce_share"] = round(fused_all_reduce_share(
+            lowered.compile().as_text()), 4)
     return (params, aux, opt_state), report
 
 
@@ -554,23 +565,28 @@ def data_parallel_phase(hvd, events, *, vocab, dim, depth, heads, seq,
         vocab=vocab, dim=dim, depth=depth, heads=heads, seq=seq, seed=seed)
     tx = optax.sgd(lr, momentum=0.9)
 
-    def run(on, batch_size, n_steps, **kw):
+    def run(on, batch_size, n_steps, fused_share=False, **kw):
         params = put(init(), on, P())
         state = (params, {}, tx.init(params))
         tokens = put(make_tokens(batch_size), on, P(on.axis_names))
         step = make_train_step(loss_fn, tx, on, sync_aux_state=False, **kw)
-        state, report = run_steps(step, state, tokens, n_steps, events)
+        state, report = run_steps(step, state, tokens, n_steps, events,
+                                  fused_share=fused_share)
         return state, tokens, report
 
     single = run(one, batch, steps)[2]
     check(single["program"] == "plain_jit",
           "the one-device mesh did not run the plain program")
 
-    state, tokens, fp32 = run(mesh, batch, steps)
+    state, tokens, fp32 = run(mesh, batch, steps, fused_share=True)
     check(fp32["program"] == "shard_map"
           and "all_reduce" in fp32["collectives"],
           f"the {n}-device step is not the shard_map program with an "
           f"all-reduce: {fp32['program']}, {fp32['collectives']}")
+    if jax.default_backend() == "tpu" and n > 1:
+        check(fp32["fused_all_reduce_share"] > 0,
+              "no gradient all-reduce of the compiled step is inside an "
+              "async collective fusion: every one waits for the wire")
     _check_losses(fp32["losses"], single["losses"], LOSS_TOL,
                   f"{n}-device fp32 wire", "one device")
     # Nothing sits on the first chip alone.

@@ -188,11 +188,16 @@ def staged_bucket_allreduce(leaves, reduce_flat, *, bucket_bytes=None,
     leaves ride alone) and ``reduce_flat`` runs once per bucket on the
     concatenated payload, staged in the scheduler's issue order.  Under
     ``overlap`` that order is reversed registration order: backward
-    materializes the LAST layer's gradients first, so emitting the tail
-    bucket's collective first gives XLA's latency-hiding scheduler a
-    collective whose inputs are ready while earlier layers are still
-    differentiating.  Bucket contents do not depend on the issue order,
-    so overlap changes scheduling, never math.
+    materializes the LAST layer's gradients first, so the tail bucket's
+    collective is emitted first, with its inputs ready while earlier
+    layers are still differentiating.  That orders the collectives and
+    no more: XLA's scheduler does not run one under the remaining
+    backward pass by itself (on the v5e every all-reduce of a step
+    compiled with default options was synchronous, ``PERF.md`` section 6);
+    what does is the compile options ``make_train_step`` hands its
+    multi-device TPU program (``spmd._step_compiler_options``).  Bucket
+    contents do not depend on the issue order, so overlap changes
+    scheduling, never math.
 
     Returns the reduced payload re-split per leaf (flat; caller
     reshapes).  ``reduce_flat`` must be shape-polymorphic over 1-D
