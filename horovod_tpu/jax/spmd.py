@@ -41,6 +41,7 @@ from horovod_tpu.ops import quantized_collectives as _qc
 from horovod_tpu.parallel._vma import ensure_varying_tree
 from horovod_tpu.parallel.hierarchical import hierarchical_allreduce
 from horovod_tpu.parallel.mesh import DCN_AXIS, ICI_AXIS
+from horovod_tpu.parallel.moe import noting_expert_layers
 
 
 def reduce_gradients(grads, axis_names: Tuple[str, ...], *,
@@ -533,6 +534,25 @@ def _wire_metrics(fn, mesh, compression, steps_per_call: int):
     return _wrap_with_stages(fn, around)
 
 
+def _moe_metrics(fn, expert_layers: dict, steps_per_call: int):
+    """Per-dispatch ``moe.assignments`` (token-to-expert assignments a
+    rank routes) and ``moe.expert_bytes`` (expert parameter bytes its
+    expert layers hold, each read by every step) counters.
+    ``expert_layers`` is what the ``DroplessMoE`` layers of the step's
+    ``loss_fn`` noted of their static sizes while it was traced
+    (:func:`noting_expert_layers`); a model without them bumps nothing."""
+    from horovod_tpu.metrics import registry
+
+    def around(target, args, kwargs):
+        out = target(*args, **kwargs)
+        for assignments, nbytes in expert_layers.values():
+            registry.inc("moe.assignments", assignments * steps_per_call)
+            registry.inc("moe.expert_bytes", nbytes * steps_per_call)
+        return out
+
+    return _wrap_with_stages(fn, around)
+
+
 def _wire_observe(fn, steps_per_call: int):
     """Observatory step decomposition for the in-jit path.  Dispatch is
     async — the host call returns before the device finishes — so the
@@ -759,6 +779,8 @@ def make_train_step(
     axes = tuple(mesh.axis_names)
     compression = _qc.resolve_injit_compression(compression)
     overlap = _sched.overlap_enabled(overlap)
+    expert_layers: dict = {}
+    loss_fn = noting_expert_layers(loss_fn, expert_layers)
     if steps_per_call < 1:
         raise ValueError(f"steps_per_call must be >= 1, got "
                          f"{steps_per_call}")
@@ -817,10 +839,16 @@ def make_train_step(
         spmd_step = _wire_metrics(spmd_step, mesh, compression,
                                   steps_per_call)
     spans = _StepSpans("train_step")
+
+    def instrumented(fn):
+        return spans.instrument(_wire_observe(
+            _moe_metrics(fn, expert_layers, steps_per_call),
+            steps_per_call))
+
     wire_identity = (compression is NoneCompressor
                      or isinstance(compression, NoneCompressor))
     if mesh.size > 1 or not wire_identity:
-        return spans.instrument(_wire_observe(spmd_step, steps_per_call))
+        return instrumented(spmd_step)
 
     # Single-chip fast path: on a 1-device mesh every collective is the
     # identity, so compile the body as a plain jit program instead —
@@ -876,7 +904,7 @@ def make_train_step(
         return _resolve(args)(*args)
 
     dispatch.lower = lambda *args: _resolve(args).lower(*args)
-    return spans.instrument(_wire_observe(dispatch, steps_per_call))
+    return instrumented(dispatch)
 
 
 def _sync_or_check_aux(new_aux, axes, sync_aux_state: bool):

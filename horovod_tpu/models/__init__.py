@@ -6,4 +6,4 @@ from horovod_tpu.models.resnet import (                   # noqa: F401
     ResNet, ResNet50, ResNet101, ResNet152,
 )
 from horovod_tpu.models.transformer import (               # noqa: F401
-    BlockStack, TransformerLM)
+    BlockStack, OLMoELM, TransformerLM, apply_rotary)

@@ -25,6 +25,14 @@ With ``attn != "full"`` the module must run inside shard_map with the
 sequence dimension sharded on ``sp_axis``; position embeddings are computed
 from the global position of each shard (rank offset, or the zigzag chunk
 positions under ``ring_zigzag``).
+
+The defaults are the GPT-2 block (LayerNorm, learned positions, a 4x GELU
+MLP).  The block of today's sparse-expert LMs is options on the same
+modules: ``norm="rms"``, ``pos="rotary"``, ``qk_norm=True`` (RMSNorm over
+the whole q and k vectors before the split into heads) and
+``moe_experts > 0`` (the MLP becomes
+:class:`~horovod_tpu.parallel.moe.DroplessMoE`: SwiGLU experts, top-k, no
+dropped token).  :func:`OLMoELM` is OLMoE-1B-7B's setting of them.
 """
 
 from __future__ import annotations
@@ -39,9 +47,35 @@ from jax import lax
 from horovod_tpu.ops.flash_attention import (
     auto_block, flash_attention_auto, flash_qkv_proj)
 from horovod_tpu.parallel.mesh import RANKS_AXIS
+from horovod_tpu.parallel.moe import DroplessMoE
 from horovod_tpu.parallel.ring_attention import (
     full_attention, ring_attention, zigzag_shard_positions)
 from horovod_tpu.parallel.ulysses import ulysses_attention
+
+
+def _norm(kind: str, eps: float, dtype, name: str):
+    """The block's normalisation: ``"layer"`` (LayerNorm, GPT-2) or
+    ``"rms"`` (RMSNorm, no mean and no bias)."""
+    if kind == "layer":
+        return nn.LayerNorm(epsilon=eps, dtype=dtype, name=name)
+    if kind == "rms":
+        return nn.RMSNorm(epsilon=eps, dtype=dtype, name=name)
+    raise ValueError(f"unknown norm: {kind!r}")
+
+
+def apply_rotary(x, pos, theta: float = 10000.0):
+    """Rotary position embedding, rotate-half form, on ``x`` (B, T, H, D)
+    at positions ``pos`` (T,): the pair ``(x[i], x[i + D/2])`` is turned by
+    ``pos · theta^(-2i/D)``.  Angles and the rotation in float32."""
+    half = x.shape[-1] // 2
+    freq = 1.0 / theta ** (jnp.arange(half, dtype=jnp.float32) / half)
+    angle = pos.astype(jnp.float32)[:, None] * freq[None]       # (T, D/2)
+    cos = jnp.cos(angle)[None, :, None, :]
+    sin = jnp.sin(angle)[None, :, None, :]
+    x1 = x[..., :half].astype(jnp.float32)
+    x2 = x[..., half:].astype(jnp.float32)
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                           axis=-1).astype(x.dtype)
 
 
 class _QKVKernel(nn.Module):
@@ -62,14 +96,22 @@ class Attention(nn.Module):
     attn: str = "full"
     sp_axis: Any = RANKS_AXIS
     dtype: Any = jnp.bfloat16
+    # RMSNorm over the whole (C-wide) q and k vectors, before the heads
+    # are split (OLMoE's QK-norm), with this epsilon.
+    qk_norm: bool = False
+    norm_eps: float = 1e-6
+    # Rotary positions on q and k with this base; None: none here (the
+    # model adds learned position embeddings).
+    rope_theta: Optional[float] = None
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, pos=None):
         B, T, C = x.shape
         D = C // self.num_heads
         blk = auto_block(T)
         if (self.attn == "flash" and D % 128 == 0
-                and (blk == T or blk >= 64)):
+                and (blk == T or blk >= 64)
+                and not self.qk_norm and self.rope_theta is None):
             # Fused-projection fast path: one op computes qkv and runs
             # the kernels straight off it through head-offset BlockSpecs
             # — no split slice, no (B, T, H, D) transpose (measured ~25
@@ -85,9 +127,16 @@ class Attention(nn.Module):
         qkv = nn.Dense(3 * C, use_bias=False, dtype=self.dtype,
                        param_dtype=jnp.float32, name="qkv")(x)
         q, k, v = jnp.split(qkv, 3, axis=-1)
+        if self.qk_norm:
+            q = _norm("rms", self.norm_eps, self.dtype, "q_norm")(q)
+            k = _norm("rms", self.norm_eps, self.dtype, "k_norm")(k)
         q = q.reshape(B, T, self.num_heads, D)
         k = k.reshape(B, T, self.num_heads, D)
         v = v.reshape(B, T, self.num_heads, D)
+        if self.rope_theta is not None:
+            pos = jnp.arange(T) if pos is None else pos
+            q = apply_rotary(q, pos, self.rope_theta)
+            k = apply_rotary(k, pos, self.rope_theta)
         if self.attn == "ring":
             out = ring_attention(q, k, v, axis_name=self.sp_axis,
                                  causal=True)
@@ -126,11 +175,23 @@ class Block(nn.Module):
     # residual stream out of f32 round-trips (~2x LN HBM traffic) at the
     # usual bf16-training precision trade (stats over d_model elements).
     ln_dtype: Any = jnp.float32
+    norm: str = "layer"
+    norm_eps: float = 1e-6
+    qk_norm: bool = False
+    rope_theta: Optional[float] = None
+    # moe_experts > 0: the MLP is a DroplessMoE named "moe" (moe_top_k of
+    # moe_experts SwiGLU experts, each moe_hidden wide); its router
+    # losses are sown as intermediates (parallel.moe.router_losses).
+    moe_experts: int = 0
+    moe_top_k: int = 0
+    moe_hidden: int = 0
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, pos=None):
+        """``pos`` (T,): the tokens' global positions, for rotary
+        attention on a sequence shard; default ``arange(T)``."""
         C = x.shape[-1]
-        h = nn.LayerNorm(dtype=self.ln_dtype, name="ln1")(x)
+        h = _norm(self.norm, self.norm_eps, self.ln_dtype, "ln1")(x)
         if self.tp_axis:
             # Megatron layout: heads and MLP hidden sharded over tp_axis,
             # one psum per sub-block (see parallel/tensor_parallel.py).
@@ -138,12 +199,19 @@ class Block(nn.Module):
                 TPMlp, TPSelfAttention)
             x = x + TPSelfAttention(self.num_heads, axis=self.tp_axis,
                                     dtype=self.dtype, name="attn")(h)
-            h = nn.LayerNorm(dtype=self.ln_dtype, name="ln2")(x)
+            h = _norm(self.norm, self.norm_eps, self.ln_dtype, "ln2")(x)
             return x + TPMlp(self.mlp_ratio * C, C, axis=self.tp_axis,
                              dtype=self.dtype, name="mlp")(h)
         x = x + Attention(self.num_heads, self.attn, self.sp_axis,
-                          self.dtype, name="attn")(h)
-        h = nn.LayerNorm(dtype=self.ln_dtype, name="ln2")(x)
+                          self.dtype, qk_norm=self.qk_norm,
+                          norm_eps=self.norm_eps,
+                          rope_theta=self.rope_theta, name="attn")(h, pos)
+        h = _norm(self.norm, self.norm_eps, self.ln_dtype, "ln2")(x)
+        if self.moe_experts:
+            y, _, _ = DroplessMoE(self.moe_experts, self.moe_hidden,
+                                  self.moe_top_k, dtype=self.dtype,
+                                  name="moe")(h)
+            return x + y
         h = nn.Dense(self.mlp_ratio * C, dtype=self.dtype,
                      param_dtype=jnp.float32, name="fc1")(h)
         h = nn.gelu(h)
@@ -153,13 +221,15 @@ class Block(nn.Module):
 
 
 def _apply_block_stack(x, *, num_heads, depth, mlp_ratio, attn, sp_axis,
-                       tp_axis, dtype, ln_dtype=jnp.float32):
+                       tp_axis, dtype, ln_dtype=jnp.float32, pos=None,
+                       **block_options):
     """Run ``depth`` Blocks named ``block_{i}`` in the caller's flax scope
     (shared by TransformerLM and BlockStack so their param trees agree)."""
     for i in range(depth):
         x = Block(num_heads, mlp_ratio=mlp_ratio, attn=attn,
                   sp_axis=sp_axis, tp_axis=tp_axis, dtype=dtype,
-                  ln_dtype=ln_dtype, name=f"block_{i}")(x)
+                  ln_dtype=ln_dtype, name=f"block_{i}",
+                  **block_options)(x, pos)
     return x
 
 
@@ -217,6 +287,15 @@ class TransformerLM(nn.Module):
     head_dtype: Any = jnp.float32
     # LayerNorm compute dtype (see Block.ln_dtype); bf16 for max MFU.
     ln_dtype: Any = jnp.float32
+    # The block's other choices (module docstring; Block's fields).
+    norm: str = "layer"              # "layer" | "rms"
+    norm_eps: float = 1e-6
+    pos: str = "learned"             # "learned" | "rotary"
+    rope_theta: float = 10000.0
+    qk_norm: bool = False
+    moe_experts: int = 0
+    moe_top_k: int = 0
+    moe_hidden: int = 0
 
     @nn.compact
     def __call__(self, tokens, return_hidden=False):
@@ -230,6 +309,13 @@ class TransformerLM(nn.Module):
             raise ValueError(
                 "tp_axis composes with attn='full' only (TP attention "
                 f"computes the full sequence locally); got {self.attn!r}")
+        if self.tp_axis and (self.moe_experts or self.qk_norm
+                             or self.pos != "learned"):
+            raise ValueError("tp_axis runs the GPT-2 block only: no "
+                             "experts, QK-norm or rotary positions")
+        if self.pos not in ("learned", "rotary"):
+            raise ValueError(f"unknown pos: {self.pos!r}")
+        rotary = self.pos == "rotary"
         B, T = tokens.shape
         if self.attn in ("full", "flash"):
             pos = jnp.arange(T)
@@ -238,17 +324,69 @@ class TransformerLM(nn.Module):
                 lax.axis_index(self.sp_axis), lax.axis_size(self.sp_axis), T)
         else:
             pos = lax.axis_index(self.sp_axis) * T + jnp.arange(T)
-        tok_emb = nn.Embed(self.vocab, self.dim, param_dtype=jnp.float32,
-                           dtype=self.dtype, name="tok_emb")(tokens)
-        pos_emb = nn.Embed(self.max_len, self.dim, param_dtype=jnp.float32,
-                           dtype=self.dtype, name="pos_emb")(pos)
-        x = tok_emb + pos_emb[None]
+        x = nn.Embed(self.vocab, self.dim, param_dtype=jnp.float32,
+                     dtype=self.dtype, name="tok_emb")(tokens)
+        if not rotary:
+            pos_emb = nn.Embed(self.max_len, self.dim,
+                               param_dtype=jnp.float32, dtype=self.dtype,
+                               name="pos_emb")(pos)
+            x = x + pos_emb[None]
         x = _apply_block_stack(
             x, num_heads=self.num_heads, depth=self.depth, mlp_ratio=4,
             attn=self.attn, sp_axis=self.sp_axis, tp_axis=self.tp_axis,
-            dtype=self.dtype, ln_dtype=self.ln_dtype)
-        x = nn.LayerNorm(dtype=self.ln_dtype, name="ln_f")(x)
+            dtype=self.dtype, ln_dtype=self.ln_dtype,
+            pos=pos if rotary else None, norm=self.norm,
+            norm_eps=self.norm_eps, qk_norm=self.qk_norm,
+            rope_theta=self.rope_theta if rotary else None,
+            moe_experts=self.moe_experts, moe_top_k=self.moe_top_k,
+            moe_hidden=self.moe_hidden)
+        x = _norm(self.norm, self.norm_eps, self.ln_dtype, "ln_f")(x)
         if return_hidden:
             return x
         return nn.Dense(self.vocab, use_bias=False, dtype=self.head_dtype,
                         param_dtype=jnp.float32, name="head")(x)
+
+
+def OLMoELM(**overrides) -> TransformerLM:
+    """OLMoE-1B-7B (Muennighoff et al., arXiv:2409.02060; the widths of
+    ``allenai/OLMoE-1B-7B-0125-Instruct``'s config.json) as a
+    :class:`TransformerLM`: 16 pre-norm blocks of RMSNorm (eps 1e-5),
+    d 2048, 16 heads of 128 with QK-norm and rotary positions (theta
+    10000), 64 SwiGLU experts 1024 wide with top-8 routing (gates not
+    renormalised, no dropped token), vocab 50304, untied head.
+    ``overrides`` replace any field (``depth=1``, ``attn="flash"``, the
+    dtypes...).
+
+    Trained through :func:`horovod_tpu.jax.spmd.make_train_step` with the
+    router's two auxiliary terms (0.01 and 0.001 are OLMoE's)::
+
+        model = OLMoELM(depth=1, attn="flash")
+
+        def loss_fn(params, aux, tokens):
+            h, state = model.apply({"params": params}, tokens[:, :-1],
+                                   return_hidden=True,
+                                   mutable=["intermediates"])
+            ce = fused_softmax_xent(h.reshape(-1, model.dim),
+                                    params["head"]["kernel"],
+                                    tokens[:, 1:].reshape(-1)).mean()
+            balance, z = router_losses(state["intermediates"])
+            return ce + 0.01 * balance + 0.001 * z, aux
+
+        step = make_train_step(loss_fn, optax.adamw(4e-4), hvd.ranks_mesh())
+        previous = None
+        for batch in loader:
+            params, aux, opt_state, loss = step(params, aux, opt_state, batch)
+            if previous is not None:
+                previous.block_until_ready()    # the loss of the step before
+            previous = loss
+
+    Reading the previous step's loss keeps the host one step ahead of the
+    device and no more (an unread loop deadlocks the 8-device CPU mesh,
+    PERF.md section 7).
+    """
+    fields = dict(vocab=50304, dim=2048, depth=16, num_heads=16,
+                  max_len=4096, norm="rms", norm_eps=1e-5, pos="rotary",
+                  rope_theta=10000.0, qk_norm=True, moe_experts=64,
+                  moe_top_k=8, moe_hidden=1024)
+    fields.update(overrides)
+    return TransformerLM(**fields)

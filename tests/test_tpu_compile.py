@@ -179,3 +179,41 @@ def test_train_step_all_reduces_fused_with_backward(v5e, monkeypatch):
     assert fused.count(kernels) == plain.count(kernels)
     assert spmd.fused_all_reduce_share(plain) == 0.0
     assert spmd.fused_all_reduce_share(fused) >= 0.5
+
+
+def test_dropless_expert_layer_fwd_bwd_at_olmoe_widths(v5e):
+    """``DroplessMoE`` as the ``olmoe_1chip`` cell calls it: 16,384 tokens
+    of width 2048, 64 experts of 1024, top-8 — forward and backward on one
+    described chip.  The three grouped matmuls and their six transposes
+    compile to TPU custom calls (``ragged-dot``), nothing is a dense
+    tokens x experts product, and the layer with its gradients fits the
+    chip several times over."""
+    from horovod_tpu.parallel.moe import DroplessMoE
+
+    tokens, d, hidden, experts, top_k = 16_384, 2048, 1024, 64, 8
+    one = SingleDeviceSharding(v5e[0])
+    layer = DroplessMoE(num_experts=experts, hidden=hidden, top_k=top_k)
+    x = jax.ShapeDtypeStruct((tokens, d), jnp.bfloat16, sharding=one)
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one),
+        jax.eval_shape(lambda key: layer.init(
+            key, jnp.zeros((8, d), jnp.bfloat16))["params"],
+            jax.random.PRNGKey(0)))
+    assert params["w_gate"].shape == (experts, d, hidden)
+
+    def loss(p, x):
+        out, balance, z = layer.apply({"params": p}, x)
+        return out.astype(jnp.float32).sum() + balance + z
+
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+        params, x).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') >= 9
+    assert "ragged-dot" in text
+    m = compiled.memory_analysis()
+    plan = (m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes - m.alias_size_in_bytes)
+    # 1.6 GB of float32 expert weights and as much of gradients, and
+    # under 3 GB of bf16 rows: a dense (tokens, experts, capacity)
+    # dispatch would be 10.7 GB a tensor.
+    assert plan < 8 * 2 ** 30, plan / 2 ** 30
