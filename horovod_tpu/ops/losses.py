@@ -14,17 +14,32 @@ pattern in every large-LM codebase):
   default); each chunk computes its logits tile, reduces it to ``lse``
   and the label logit, and DISCARDS the tile — residuals are just
   ``(hidden, W, labels, lse)``;
-* backward: revisit the chunks, recompute each logits tile, form
-  ``softmax - onehot`` in place and contract it immediately into
-  ``d hidden`` and ``dW``.
+* ``lse`` takes no second read of the tile.  ``sum exp(logits - max)``
+  has to wait for the row maximum; ``sum exp(logits - shift)``, with a
+  shift known before the tile exists (the row's maximum over a few
+  columns, one small matmul), is reduced in the epilogue of the matmul
+  that makes the tile and is as exact, unless a logit lies so far above
+  the shift that the sum overflows.  The rule sees that on the device
+  (``lax.cond``): such a chunk gets its ``lse`` from the usual shifted
+  sum over tiles made again a few hundred rows at a time
+  (:func:`_tile_lse`);
+* backward: revisit the chunks, form ``softmax - onehot`` from each
+  logits tile and contract it immediately into ``d hidden`` and ``dW``.
 
-Cost: one extra head matmul (the backward recompute) in exchange for
-never holding O(N x vocab) residuals; ``HOROVOD_TPU_XENT_MODE`` selects
-alternative schedules (see :func:`_xent_mode`), including a
-save-the-logits form that trades the recompute back for a compact bf16
-residual.  All matmuls run in the input dtype (bf16 on TPU) with f32
-accumulation, so precision matches the f32-logits reference within bf16
-rounding.
+The backward is WRITTEN as a recompute of each tile, one extra head
+matmul, in exchange for never holding O(N x vocab) residuals.  Where the
+backward directly follows the forward (``jax.grad`` of ``.mean()``, as
+every step in this repo is) XLA merges that matmul with the forward's
+identical one and the tile lives from one to the other: three head-sized
+matmuls a chunk run, not four (PERF.md section 6, PR 26).
+``HOROVOD_TPU_XENT_MODE`` selects alternative schedules (see
+:func:`_xent_mode`), including a save-the-logits form with a compact
+bf16 residual.  All matmuls run in the input dtype (bf16 on TPU) with
+f32 accumulation, so precision matches the f32-logits reference within
+bf16 rounding.  The ops carry the trace scopes ``xent/loss`` and
+``xent/grad``; device time under ``xent/loss/overflowed`` counts the
+chunks whose sum overflowed (docs/observability.md).  Under ``vmap`` the
+conditional becomes a select that runs both branches: as exact, slower.
 
 No reference analogue (the reference's models predate large-vocab LM
 heads); cited by SURVEY §5.7's long-context mandate.
@@ -135,16 +150,75 @@ def _pick_chunk(n: int, target: int) -> int:
     return chunk
 
 
+# The columns whose row maximum is the shift of ``sum exp`` (one lane
+# tile of the head weight), and the rows of the tiles that a chunk whose
+# sum overflowed is made of again (103 MB of f32 at a vocabulary of
+# 50,257).
+_SHIFT_COLUMNS = 128
+_OVERFLOWED_ROWS = 512
+
+
+def _logits_tile(h_c, w):
+    return jax.lax.dot_general(
+        h_c, w, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)              # (C, V) f32
+
+
+def _max_shifted_lse(logits):
+    m = jnp.max(logits, axis=-1)
+    return m + jnp.log(jnp.sum(jnp.exp(logits - m[:, None]), axis=-1))
+
+
+def _tile_lse(logits, h_c, w):
+    """``log sum exp`` of each row of the tile ``h_c @ w``, in one pass.
+
+    ``sum exp(logits - max)`` has to wait for the row maximum, so it is a
+    second read of the whole f32 tile: 2.2 ms of 8,192 x 50,257 on the
+    v5e, a chunk (PERF.md section 5).  A shift that is known before the
+    tile lets XLA reduce the sum in the epilogue of the matmul that makes
+    the tile.  The shift is the row's maximum over the first
+    ``_SHIFT_COLUMNS`` columns, which is never above the row's maximum:
+    the sum is at least 1, so nothing that matters is flushed, and it is
+    as exact as the usual sum unless a logit lies some 80 above the
+    shift and the sum overflows.  Softmax does not see an offset common
+    to a row, and neither does this: what counts is the spread inside a
+    row (a few units at initialisation, 10-20 in a trained GPT-2, whose
+    logits sit near -100), not where the logits lie.  Whether a sum
+    overflowed the rule sees on the device (``lax.cond``); the chunk's
+    ``lse`` then comes from the other branch, which makes the tile again
+    ``_OVERFLOWED_ROWS`` rows at a time: the tile in hand stays out of the
+    conditional, whose branch would want it in a layout of its own
+    (1.5 GiB more in the compiled plan of the head alone).  Device time
+    under ``xent/loss/overflowed`` is the count of chunks that took it."""
+    shift = jnp.max(_logits_tile(h_c, w[:, :_SHIFT_COLUMNS]), axis=-1)
+    total = jnp.sum(jnp.exp(logits - shift[:, None]), axis=-1)
+
+    def overflowed():
+        rows = _pick_chunk(h_c.shape[0], _OVERFLOWED_ROWS)
+        with jax.named_scope("overflowed"):
+            return lax.map(
+                lambda h_r: _max_shifted_lse(_logits_tile(h_r, w)),
+                h_c.reshape(-1, rows, h_c.shape[1])).reshape(-1)
+
+    return lax.cond(jnp.all(jnp.isfinite(total)),
+                    lambda: shift + jnp.log(total), overflowed)
+
+
 def _chunk_fwd(h_c, w, labels_c, want_logits=False):
     """One chunk's (loss, lse) from its logits tile; the tile dies here —
     unless ``want_logits`` asks for it back as a compact bf16 residual
     (the save schedule)."""
-    logits = jax.lax.dot_general(
-        h_c, w, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)              # (C, V) f32
-    m = jnp.max(logits, axis=-1, keepdims=True)
-    lse = (m[:, 0] + jnp.log(jnp.sum(jnp.exp(logits - m), axis=-1)))
-    correct = jnp.take_along_axis(logits, labels_c[:, None], axis=-1)[:, 0]
+    with jax.named_scope("xent/loss"):
+        logits = _logits_tile(h_c, w)
+        lse = _tile_lse(logits, h_c, w)
+        correct = jnp.take_along_axis(
+            logits, labels_c[:, None], axis=-1)[:, 0]
+        # Held where they are, for what the compiler otherwise does
+        # around a conditional (sandbox compiles of whole steps, PR 26):
+        # it moves the first use of ``lse``, ``logits - lse``, into both
+        # branches, so that the conditional takes the tile in and hands a
+        # second one out (the plan of ``gpt13b_1chip`` 13.87 -> 15.58 GiB).
+        lse, correct = lax.optimization_barrier((lse, correct))
     if want_logits:
         return lse - correct, lse, logits.astype(jnp.bfloat16)
     return lse - correct, lse
@@ -154,22 +228,19 @@ def _chunk_bwd(h_c, w, labels_c, lse_c, g_c, logits_c=None):
     """Contract one chunk's ``softmax - onehot`` straight into
     (dh_c, dw_c); the logits tile is recomputed unless a saved bf16 tile
     (``logits_c``) is supplied."""
-    if logits_c is None:
-        logits = jax.lax.dot_general(
-            h_c, w, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)          # (C, V) f32
-    else:
-        logits = logits_c.astype(jnp.float32)
-    p = jnp.exp(logits - lse_c[:, None])
-    cols = lax.broadcasted_iota(jnp.int32, p.shape, 1)
-    dlogits = ((p - (cols == labels_c[:, None]))
-               * g_c[:, None]).astype(h_c.dtype)         # (C, V)
-    dh_c = jax.lax.dot_general(
-        dlogits, w, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)              # (C, d)
-    dw_c = jax.lax.dot_general(
-        h_c, dlogits, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)              # (d, V)
+    with jax.named_scope("xent/grad"):
+        logits = (_logits_tile(h_c, w) if logits_c is None
+                  else logits_c.astype(jnp.float32))
+        p = jnp.exp(logits - lse_c[:, None])
+        cols = lax.broadcasted_iota(jnp.int32, p.shape, 1)
+        dlogits = ((p - (cols == labels_c[:, None]))
+                   * g_c[:, None]).astype(h_c.dtype)     # (C, V)
+        dh_c = jax.lax.dot_general(
+            dlogits, w, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)          # (C, d)
+        dw_c = jax.lax.dot_general(
+            h_c, dlogits, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)          # (d, V)
     return dh_c, dw_c
 
 

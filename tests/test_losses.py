@@ -162,3 +162,285 @@ class TestModeLayoutDegrade:
 
         save, k, _ = losses._mode_layout("save2", 4096, 2048)
         assert save and k == 2
+
+
+# ------------------------------------------- the default schedule's rules
+
+
+def head_problem(n=24, d=8, v=50, dtype=jnp.float32, seed=7):
+    rng = np.random.RandomState(seed)
+    h = jnp.asarray(rng.randn(n, d), dtype)
+    w = jnp.asarray(rng.randn(d, v) * 0.1, jnp.float32)
+    labels = jnp.asarray(rng.randint(0, v, n), jnp.int32)
+    mask = jnp.asarray(rng.rand(n) > 0.3, jnp.float32)
+    return h, w, labels, mask
+
+
+# How a caller reduces the per-token losses: the cotangent that the
+# backward rule gets is the same on every row under the first two, and a
+# row mask under the third.
+REDUCTIONS = {
+    "mean": lambda x, m: x.mean(),
+    "sum": lambda x, m: x.sum(),
+    "masked": lambda x, m: (x * m).sum() / m.sum(),
+}
+
+
+def fused_and_naive(reduction, labels, chunk=16384):
+    reduce = REDUCTIONS[reduction]
+
+    def fused(h, w, m):
+        return reduce(fused_softmax_xent(h, w, labels, chunk), m)
+
+    def naive(h, w, m):
+        return reduce(naive_loss(h, w, labels), m)
+
+    return fused, naive
+
+
+def assert_trees_close(got, want, **tol):
+    for g, wv in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(np.asarray(g, np.float32),
+                                   np.asarray(wv, np.float32), **tol)
+
+
+class TestGradsUnderEveryReduction:
+    @pytest.mark.parametrize("reduction", sorted(REDUCTIONS))
+    @pytest.mark.parametrize("mode", ["unroll2", "unroll3", "recompute",
+                                      "save2"])
+    def test_grads_match_reference(self, mode, reduction, monkeypatch):
+        monkeypatch.setenv("HOROVOD_TPU_XENT_MODE", mode)
+        h, w, labels, mask = head_problem()
+        fused, naive = fused_and_naive(reduction, labels)
+        got = jax.jit(jax.value_and_grad(fused, argnums=(0, 1, 2)))(
+            h, w, mask)
+        want = jax.value_and_grad(naive, argnums=(0, 1, 2))(h, w, mask)
+        # save modes round the stored logits to bf16.
+        tol = (dict(rtol=2e-2, atol=2e-3) if mode.startswith("save")
+               else dict(rtol=1e-5, atol=1e-6))
+        assert_trees_close(got, want, **tol)
+
+    @pytest.mark.parametrize("reduction", sorted(REDUCTIONS))
+    def test_bf16_activations_grads(self, reduction):
+        h, w, labels, mask = head_problem(n=32, d=16, dtype=jnp.bfloat16)
+        fused, naive = fused_and_naive(reduction, labels)
+        got = jax.value_and_grad(fused, argnums=(0, 1))(h, w, mask)
+        want = jax.value_and_grad(naive, argnums=(0, 1))(
+            h.astype(jnp.float32), w, mask)
+        assert got[1][0].dtype == jnp.bfloat16
+        assert got[1][1].dtype == jnp.float32
+        assert_trees_close(got, want, rtol=3e-2, atol=3e-2)
+
+    @pytest.mark.parametrize("wrap", ["checkpoint", "vmap"])
+    @pytest.mark.parametrize("reduction", ["mean", "masked"])
+    def test_under_checkpoint_and_vmap(self, wrap, reduction):
+        """``vmap`` turns the ``lse`` conditional into a select of both
+        branches: slower, and as exact."""
+        h, w, labels, mask = head_problem()
+        fused, naive = fused_and_naive(reduction, labels)
+        if wrap == "checkpoint":
+            got = jax.grad(jax.checkpoint(fused), argnums=(0, 1))(h, w, mask)
+            want = jax.grad(naive, argnums=(0, 1))(h, w, mask)
+        else:
+            hs = jnp.stack([h, 2 * h])
+            got = jax.vmap(jax.grad(fused, argnums=(0, 1)),
+                           in_axes=(0, None, None))(hs, w, mask)
+            want = jax.vmap(jax.grad(naive, argnums=(0, 1)),
+                            in_axes=(0, None, None))(hs, w, mask)
+        assert_trees_close(got, want, rtol=1e-5, atol=1e-6)
+
+    @pytest.mark.parametrize("mode,chunks,differentiated", [
+        # As written, a chunk's backward makes the tile again: logits in
+        # the forward rule, then logits, dh, dW.  (Compiled where the
+        # backward follows the forward, XLA merges the two logits
+        # matmuls: PERF.md section 6, PR 26.)
+        ("unroll2", 2, 8),
+        ("unroll4", 4, 16),
+        ("recompute", 1, 4),
+        # The saved bf16 tile stands in for the recompute.
+        ("save2", 2, 6),
+    ])
+    def test_head_matmuls_in_the_jaxpr(self, mode, chunks, differentiated,
+                                       monkeypatch):
+        monkeypatch.setenv("HOROVOD_TPU_XENT_MODE", mode)
+        n, v = 32, 200
+        h, w, labels, _ = head_problem(n=n, v=v)
+
+        def loss(h, w):
+            return fused_softmax_xent(h, w, labels).mean()
+
+        # Every chunk's ``lse`` conditional holds one more, in the branch
+        # of a sum that overflowed: the tile again, a few rows at a time.
+        lse_conds = [[1, 0]] * chunks
+        assert head_dots(jax.make_jaxpr(loss)(h, w).jaxpr, v) == (
+            chunks, lse_conds)
+        assert head_dots(
+            jax.make_jaxpr(jax.grad(loss, argnums=(0, 1)))(h, w).jaxpr,
+            v) == (differentiated, lse_conds)
+
+
+# What is added to every logit of a row, and to those beyond the columns
+# that the shift looks at; with them, whether ``sum exp(logits - shift)``
+# stays finite.  An offset common to a row changes nothing (a trained
+# GPT-2's logits sit near -100); a logit far above the shift overflows.
+LOGIT_CASES = {"plain": (0.0, 0.0, True), "all_down": (-100.0, 0.0, True),
+               "all_up": (100.0, 0.0, True), "spike": (0.0, 150.0, False),
+               "spike_down": (-100.0, 150.0, False)}
+
+
+class TestOnePassLse:
+    """``lse`` comes from ``sum exp(logits - shift)``, the shift being the
+    row's maximum over the first columns, while that sum is finite in
+    every row of a chunk, and from the sum shifted by the row maximum
+    over tiles made again where it is not."""
+
+    @staticmethod
+    def problem(offset, spike, spiked_rows=32, dtype=jnp.float32):
+        from horovod_tpu.ops import losses
+
+        n, v = 32, 300
+        h, w, labels, mask = head_problem(n=n, d=8, v=v, dtype=dtype)
+        # Two more columns of ``hidden`` against two more rows of ``w``:
+        # ones against ``offset`` everywhere, and the spiked rows' ones
+        # against ``spike`` beyond the shift's columns.
+        spiked = (jnp.arange(n) < spiked_rows).astype(dtype)
+        h = jnp.concatenate([h, jnp.ones((n, 1), dtype), spiked[:, None]],
+                            axis=1)
+        beyond = jnp.arange(v) >= losses._SHIFT_COLUMNS
+        w = jnp.concatenate([w, jnp.full((1, v), offset),
+                             spike * beyond[None, :]], axis=0)
+        return h, w, labels, mask
+
+    @pytest.fixture
+    def nan_where_overflowed(self, monkeypatch):
+        from horovod_tpu.ops import losses
+
+        monkeypatch.setattr(
+            losses, "_max_shifted_lse",
+            lambda logits: jnp.full(logits.shape[:1], jnp.nan))
+
+    # (The save schedule's bf16 residual cannot hold logits of a hundred.)
+    @pytest.mark.parametrize("case,mode", [
+        (case, mode) for case in sorted(LOGIT_CASES)
+        for mode in ("unroll2", "recompute", "save2")
+        if case == "plain" or mode != "save2"])
+    def test_value_and_grads_match_reference(self, case, mode, monkeypatch):
+        monkeypatch.setenv("HOROVOD_TPU_XENT_MODE", mode)
+        offset, spike, _ = LOGIT_CASES[case]
+        h, w, labels, mask = self.problem(offset, spike)
+        fused, naive = fused_and_naive("mean", labels)
+        got = jax.jit(jax.value_and_grad(fused, argnums=(0, 1)))(h, w, mask)
+        want = jax.value_and_grad(naive, argnums=(0, 1))(h, w, mask)
+        # Logits of a hundred or so cost float32 four decimal places of
+        # ``x - lse``, in the reference as here.
+        tol = (dict(rtol=2e-2, atol=2e-3) if mode.startswith("save")
+               else dict(rtol=1e-5, atol=1e-6) if case == "plain"
+               else dict(rtol=3e-4, atol=1e-4))
+        assert_trees_close(got, want, **tol)
+
+    @pytest.mark.parametrize("case", sorted(LOGIT_CASES))
+    def test_branch_follows_the_sum(self, case, nan_where_overflowed):
+        """With the other branch made to return NaN, exactly the chunks
+        whose sum overflowed lose their losses."""
+        offset, spike, finite = LOGIT_CASES[case]
+        h, w, labels, _ = self.problem(offset, spike)
+        got = np.asarray(jax.jit(fused_softmax_xent)(h, w, labels))
+        if finite:
+            np.testing.assert_allclose(
+                got, np.asarray(naive_loss(h, w, labels)), rtol=1e-5,
+                atol=1e-5)
+        else:
+            assert np.isnan(got).all()
+
+    def test_one_chunk_that_overflowed_falls_back_alone(
+            self, nan_where_overflowed):
+        # One row of the first chunk (of 16 rows) holds the spike.
+        h, w, labels, _ = self.problem(0.0, 150.0, spiked_rows=1)
+        got = np.asarray(jax.jit(fused_softmax_xent)(h, w, labels))
+        assert np.isnan(got[:16]).all()
+        np.testing.assert_allclose(
+            got[16:], np.asarray(naive_loss(h, w, labels))[16:], rtol=1e-5,
+            atol=1e-5)
+
+    @pytest.mark.parametrize("rows,tiles", [(1024, 2), (30, 1), (1026, 3)])
+    def test_overflowed_tiles_divide_the_chunk(self, rows, tiles):
+        """The other branch tiles a chunk by the largest divisor of its
+        rows up to ``_OVERFLOWED_ROWS``: its transient stays bounded
+        whatever the chunk."""
+        from horovod_tpu.ops import losses
+
+        h = jnp.ones((rows, 4), jnp.float32)
+        w = jnp.ones((4, 7), jnp.float32)
+        jaxpr = jax.make_jaxpr(
+            lambda h, w: losses._tile_lse(losses._logits_tile(h, w), h, w))(
+                h, w)
+        cond, = (e for e in jaxpr.eqns if e.primitive.name == "cond")
+        scans = [e for branch in cond.params["branches"]
+                 for e in branch.jaxpr.eqns if e.primitive.name == "scan"]
+        assert [e.params["length"] for e in scans] == [tiles]
+        assert rows // tiles <= losses._OVERFLOWED_ROWS
+
+
+def head_dots(jaxpr, v):
+    """(``dot_general``s with a dimension of ``v`` outside any
+    conditional, [their counts in the branches of each ``cond``])."""
+    from jax.extend import core as jcore
+
+    top, conds = 0, []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "cond":
+            conds.append([head_dots(branch.jaxpr, v)[0]
+                          for branch in eqn.params["branches"]])
+            continue
+        if eqn.primitive.name == "dot_general" and any(
+                v in var.aval.shape for var in (*eqn.invars, *eqn.outvars)):
+            top += 1
+        for param in eqn.params.values():
+            for sub in (param if isinstance(param, (tuple, list))
+                        else (param,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if isinstance(sub, jcore.Jaxpr):
+                    sub_top, sub_conds = head_dots(sub, v)
+                    top += sub_top
+                    conds += sub_conds
+    return top, conds
+
+
+class TestThroughTrainStep:
+    def test_shard_map_step_matches_reference(self):
+        """One SGD step through ``make_train_step`` on the 8-device CPU
+        mesh (the ``shard_map`` program) moves the parameters as the
+        plain reference's gradients of the global batch would."""
+        from jax.sharding import Mesh
+
+        from horovod_tpu.jax.spmd import make_train_step
+
+        devices = jax.devices()
+        assert len(devices) == 8
+        mesh = Mesh(np.asarray(devices), ("ranks",))
+        rng = np.random.RandomState(11)
+        vocab, d, n = 40, 8, 8 * 6
+        params = {"emb": jnp.asarray(rng.randn(vocab, d), jnp.float32),
+                  "head": jnp.asarray(rng.randn(d, vocab) * 0.1,
+                                      jnp.float32)}
+        batch = {"x": jnp.asarray(rng.randint(0, vocab, n), jnp.int32),
+                 "y": jnp.asarray(rng.randint(0, vocab, n), jnp.int32)}
+
+        def loss_fn(params, aux, batch):
+            h = params["emb"][batch["x"]]
+            return fused_softmax_xent(h, params["head"],
+                                      batch["y"]).mean(), aux
+
+        def reference(params):
+            return naive_loss(params["emb"][batch["x"]], params["head"],
+                              batch["y"]).mean()
+
+        tx = optax.sgd(1.0)
+        step = make_train_step(loss_fn, tx, mesh, sync_aux_state=False,
+                               donate=False)
+        new, _, _, loss = step(params, {}, tx.init(params), batch)
+        jax.block_until_ready(new)
+        want_loss, want = jax.value_and_grad(reference)(params)
+        np.testing.assert_allclose(float(loss), float(want_loss), rtol=1e-6)
+        assert_trees_close(jax.tree.map(lambda a, b: a - b, params, new),
+                           want, rtol=1e-5, atol=1e-6)
