@@ -1,26 +1,49 @@
 """Pallas flash attention — the fused single-chip attention hot path.
 
 The transformer family's attention math (`full_attention`) leaves XLA to
-materialize the (T, T) logits in HBM.  This kernel computes the same
+materialize the (T, T) logits in HBM.  These kernels compute the same
 causal softmax-attention with the flash schedule instead: Q blocks stay
-resident in VMEM while K/V blocks stream through, the online-softmax
+resident in VMEM while K/V stream through, the online-softmax
 accumulators (running max / sum / output, all f32) never leave VMEM, and
 the MXU sees back-to-back (block_q x d) @ (d x block_k) matmuls.  HBM
 traffic drops from O(T^2) to O(T·d).
 
-Layout: grid ``(batch*heads, T/block_q, T/block_k)`` with the KV axis
-innermost ("arbitrary" semantics — accumulators persist across it);
-causal Q/KV block pairs that are entirely masked are skipped with
-``pl.when``, halving the work like the zigzag ring layout does across
-chips.
+One family, and one place that chooses: :func:`_plan` picks the forward
+form, the backward form and the scoped-VMEM limit each is compiled with,
+from what the op can observe at trace time (shapes, item size, blocks,
+head bases, ``interpret``, manual mesh axes, the device generation).
+There is no option to set: every form below is the one a supported shape
+selects.
+
+Forward, on head-packed (B, T, H*D) views (lane-aligned heads; the head
+is a grid axis, so no (B, T, H, D) transpose ever copies in HBM), in
+order of preference:
+
+* fully unrolled (:func:`_fwd_kernel_fullunroll`): both loops unrolled
+  inside one (B, H) grid step, dead causal blocks skipped at trace time —
+  the fastest form to T 4096 (what every benchmark cell runs);
+* unrolled KV (:func:`_fwd_kernel_unrollkv`): the whole K/V row resident,
+  the KV loop unrolled inside a (B, H, T/block_q) grid — where the fully
+  unrolled form stands down (no VMEM head-room past T 2048, interpret
+  mode under ``shard_map``, many small blocks);
+* grid (:func:`_fwd_kernel`): grid ``(B, H, T/block_q, T/block_k)`` with
+  the KV axis innermost ("arbitrary" semantics — accumulators persist
+  across it), causal block pairs that are entirely masked skipped with
+  ``pl.when`` — once a K/V row no longer fits in VMEM, and for heads
+  off the lane width (``flash_attention`` merges those into the batch:
+  packed rows of one head).
 
 Backward: ``jax.custom_vjp`` saving (o, logsumexp); gradients use the
 standard flash-backward identities (dS = P * (dP - rowsum(dO*o))) as two
-Pallas kernels with the same VMEM-resident blockwise schedule as the
-forward — one accumulating dk/dv per KV block while Q blocks stream, one
+Pallas kernels with the grid forward's VMEM-resident blockwise schedule —
+one accumulating dk/dv per KV block while Q blocks stream, one
 accumulating dq per Q block while KV blocks stream (the FlashAttention-2
-split).  A chunked XLA backward remains as the ``bwd_impl="xla"``
-fallback.
+split).  The pair comes per head (:func:`_dkdv_kernel`,
+:func:`_dq_kernel`) and, at 1024² blocks with D 128, blocked over two
+adjacent heads (:func:`_dkdv_kernel_grouped`, :func:`_dq_kernel_grouped`).
+The forms that lost to these on the chip (a fused one-pass backward, a
+fully-unrolled backward, a transpose-to-merged backward, a chunked XLA
+backward) left at PR 27; docs/benchmarks.md keeps their measurements.
 
 Composition: this is the *single-chip* block; for sequences sharded
 across chips use :mod:`horovod_tpu.parallel.ring_attention`, which
@@ -34,8 +57,7 @@ fall back to ``full_attention``).
 from __future__ import annotations
 
 import functools
-import os
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -48,88 +70,11 @@ from jax.experimental.pallas import tpu as pltpu
 from horovod_tpu.parallel.ring_attention import _NEG_BIG, full_attention
 
 
-def _flash_vmem_mb() -> int:
-    """Per-kernel VMEM budget (MB) for the head-group blocked backward
-    pair — the single parse point for ``HOROVOD_TPU_FLASH_VMEM_MB`` so
-    the auto-select guard and the applied budget cannot drift apart.
-    Default 32 (measured sufficient for g2 at 1024² blocks, D=128);
-    0 restores Mosaic's compiler default; a malformed value warns and
-    falls back rather than raising mid-backward."""
-    raw = os.environ.get("HOROVOD_TPU_FLASH_VMEM_MB")
-    if raw is None:
-        # The raised default only applies where the hardware can back it
-        # (v2/v3 have 16 MB of physical VMEM per core): an explicit
-        # HOROVOD_TPU_FLASH_BWD_GROUP opt-in at small blocks compiled
-        # fine under Mosaic's default budget there, and must keep doing
-        # so without the user also discovering the VMEM knob.  Computed
-        # only on this branch — _vmem_headroom_ok touches the device
-        # list, which an explicit valid value never needs.
-        return 32 if _vmem_headroom_ok() else 0
-    try:
-        val = int(raw)
-        if val < 0:
-            raise ValueError
-        return val
-    except ValueError:
-        import warnings
-        default = 32 if _vmem_headroom_ok() else 0
-        warnings.warn(
-            f"HOROVOD_TPU_FLASH_VMEM_MB={raw!r} is not a non-negative "
-            f"integer; using the default {default}",
-            RuntimeWarning, stacklevel=2)
-        return default
 
-
-# The fully-unrolled forward's Mosaic stack crosses the default scoped-VMEM
-# budget past T=2048 (measured 44.4 MB at T=4096) — it needs at least this
-# much or it stands down to the unrolled-KV form.
-_FWD_MIN_VMEM_MB = 64
-
-
-def _flash_fwd_vmem_mb() -> int:
-    """VMEM budget (MB) for the fully-unrolled forward at 2048<T.
-
-    ``HOROVOD_TPU_FLASH_FWD_VMEM_MB`` rules when set (the forward's own
-    knob, honored as given).  Otherwise an explicitly set shared
-    ``HOROVOD_TPU_FLASH_VMEM_MB`` rules — but its documented default
-    (32) targets the grouped backward, so pinning that value would stand
-    the forward down as a side effect the user never asked for: warn
-    when that happens (an explicit 0 = compiler default stays silent —
-    that is a deliberate opt-out).  With neither set, auto-grant 64
-    where the hardware backs it."""
-    raw = os.environ.get("HOROVOD_TPU_FLASH_FWD_VMEM_MB")
-    if raw is not None:
-        try:
-            val = int(raw)
-            if val < 0:
-                raise ValueError
-            return val
-        except ValueError:
-            import warnings
-            default = _FWD_MIN_VMEM_MB if _vmem_headroom_ok() else 0
-            warnings.warn(
-                f"HOROVOD_TPU_FLASH_FWD_VMEM_MB={raw!r} is not a "
-                f"non-negative integer; using the default {default}",
-                RuntimeWarning, stacklevel=3)
-            return default
-    if os.environ.get("HOROVOD_TPU_FLASH_VMEM_MB") is None:
-        return _FWD_MIN_VMEM_MB if _vmem_headroom_ok() else 0
-    val = _flash_vmem_mb()
-    if 0 < val < _FWD_MIN_VMEM_MB:
-        import warnings
-        warnings.warn(
-            f"HOROVOD_TPU_FLASH_VMEM_MB={val} is below the "
-            f"{_FWD_MIN_VMEM_MB} MB the fully-unrolled forward needs "
-            "past T=2048, so that form stands down (the unrolled-KV "
-            "form takes over). Set HOROVOD_TPU_FLASH_FWD_VMEM_MB to "
-            "budget the forward separately from the grouped backward.",
-            RuntimeWarning, stacklevel=3)
-    return val
-
-
-# TPU generations with only 16 MB of physical VMEM per core — the raised
-# grouped-kernel budget cannot be backed there, so auto-selection stands
-# down (explicit HOROVOD_TPU_FLASH_BWD_GROUP still applies as given).
+# TPU generations with only 16 MB of physical VMEM per core — a scoped
+# budget above Mosaic's default cannot be backed there, so the forms that
+# need one (the fully-unrolled forward past T 2048, the grouped backward
+# pair) stand down in _plan.
 _SMALL_VMEM_DEVICE_KINDS = ("v2", "v3")
 
 
@@ -148,6 +93,12 @@ def _vmem_headroom_ok() -> bool:
         # whole compile.
         return False
     return not any(g in kind for g in _SMALL_VMEM_DEVICE_KINDS)
+
+
+def _vmem_limit(mb: int) -> dict:
+    """``CompilerParams`` keyword for a scoped-VMEM budget of ``mb`` MB;
+    0 leaves Mosaic's default (16 MB) in place."""
+    return {"vmem_limit_bytes": mb * 1024 * 1024} if mb else {}
 
 
 def _struct(shape, dtype, *like):
@@ -252,13 +203,11 @@ def _live_block(qi, kj, block_q, block_k, causal, seq_len):
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
                 m_scr, l_scr, acc_scr, *, scale, causal, block_q, block_k,
-                seq_len, axes=(1, 2)):
-    qi = pl.program_id(axes[0])
-    kj = pl.program_id(axes[1])
-    nk = pl.num_programs(axes[1])
-    # Packed layout: refs are 4-D blocks (1, 1, block, w) with the head
-    # as its own grid axis; legacy merged layout is 3-D (1, block, w).
-    row8 = (0, 0) if lse_ref.ndim == 4 else (0,)
+                seq_len):
+    # Grid (B, H, T/block_q, T/block_k): the head is its own grid axis.
+    qi = pl.program_id(2)
+    kj = pl.program_id(3)
+    nk = pl.num_programs(3)
 
     @pl.when(kj == 0)
     def _init():
@@ -305,45 +254,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         o_ref[0] = (acc_scr[...] / l).astype(o_ref.dtype)
         # lse laid out (BQ, 8) — the minimal last-dim tile the TPU block
         # constraints allow for this narrow per-row scalar.
-        lse_ref[row8] = jnp.broadcast_to(m_scr[:, :1] + jnp.log(l),
+        lse_ref[0, 0] = jnp.broadcast_to(m_scr[:, :1] + jnp.log(l),
                                          (block_q, 8))
-
-
-def _fwd(q, k, v, *, scale, causal, block_q, block_k, interpret,
-         seq_len=None):
-    BH, T, D = q.shape
-    nq = T // block_q
-    nk = T // block_k
-    grid = (BH, nq, nk)
-    kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
-                               block_q=block_q, block_k=block_k,
-                               seq_len=seq_len)
-    out, lse = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, j, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_q, 8), lambda b, i, j: (b, i, 0)),
-        ],
-        out_shape=[
-            _struct((BH, T, D), q.dtype, q, k, v),
-            _struct((BH, T, 8), jnp.float32, q, k, v),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, 128), jnp.float32),
-            pltpu.VMEM((block_q, 128), jnp.float32),
-            pltpu.VMEM((block_q, D), jnp.float32),
-        ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-    )(q, k, v)
-    return out, lse[..., 0]
 
 
 def _fwd_kernel_unrollkv(q_ref, k_ref, v_ref, o_ref, lse_ref,
@@ -470,9 +382,13 @@ def _fwd_kernel_fullunroll(q_ref, k_ref, v_ref, o_ref, lse_ref, *,
                      else jnp.concatenate(lses, axis=0))
 
 
-# VMEM row bound for the opt-in fully-unrolled BACKWARD (see the
-# selection comment in _bwd_pallas_packed).
-_FULL_UNROLL_BWD_MAX_BYTES = 512 << 10
+# The fully-unrolled forward's Mosaic stack scales ~T² (f32 s/p
+# temporaries per live block pair): measured ≤16 MB at T=2048 but 44.4 MB
+# at T=4096, which overflows the default scoped-VMEM budget.  Past 2048
+# the form is compiled with this budget (MB) where the device backs it,
+# and stands down to the unrolled-KV form where it does not.
+_DEFAULT_VMEM_MAX_T = 2048
+_FULL_UNROLL_VMEM_MB = 64
 
 # Full unrolling emits ~nq*nk/2 bodies and holds whole Q/K/V/O rows in
 # VMEM; past these bounds the unrolled-KV and grid forms take over.
@@ -486,7 +402,7 @@ _FULL_UNROLL_BLOCK = 512
 _FULL_UNROLL_MAX_NQ = 8
 
 
-def _fwd_packed(q, k, v, H, D, *, scale, causal, block_q, block_k,
+def _fwd_packed(q, k, v, H, D, plan, *, scale, causal, block_q, block_k,
                 interpret, seq_len=None, head_base=(0, 0, 0)):
     """Forward on head-packed (B, T, C) views (C = H*D): the head is a
     grid axis and every BlockSpec offsets its last dim by ``h*D``, so no
@@ -494,42 +410,18 @@ def _fwd_packed(q, k, v, H, D, *, scale, causal, block_q, block_k,
     (measured ~25 ms/step of pure layout copies at the bench shape —
     docs/benchmarks.md).  ``head_base`` shifts each operand's head-block
     offset, letting q/k/v be three regions of ONE fused (B, T, 3*H*D)
-    projection (so the qkv split never copies either).  lse comes back
-    as (B, H, T)."""
+    projection (so the qkv split never copies either).  ``plan`` is
+    :func:`_plan`'s: which of the three forms runs.  lse comes back as
+    (B, H, T)."""
     B, T, _ = q.shape
     nq = T // block_q
     nk = T // block_k
     oq, ok_, ov = head_base
-    # The fully-unrolled form re-tiles internally (the tile size is a
-    # schedule detail — flash results are block-size independent up to
-    # f32 reassociation); fb divides T whenever T is a multiple of 8
-    # beyond the tile, else fall through to the other forms.  Under
-    # shard_map manual axes IN INTERPRET MODE the generic HLO
-    # interpreter cannot discharge this kernel's loads (its vma check
-    # rejects the block dynamic_slices), so CPU tests take the
-    # unrolled-KV form there; compiled Mosaic is unaffected.
-    in_vma = jax.typeof(q).vma
-    fb = min(_FULL_UNROLL_BLOCK, block_q, block_k, T)
-    # Mosaic's stack for the unrolled body scales ~T² (f32 s/p
-    # temporaries per live block pair): measured ≤16 MB at T=2048 but
-    # 44.4 MB at T=4096, which overflows the default scoped-VMEM budget.
-    # Past 2048 the kernel therefore needs a raised budget — resolution
-    # order and stand-down semantics live in _flash_fwd_vmem_mb (its
-    # own knob, then the shared one with a warning, then the hardware
-    # auto-grant).  A budget below the floor stands this form down
-    # instead of silently requesting more than asked; the unrolled-KV
-    # form below takes over when this one is refused.
-    if T <= 2048:
-        _fwd_vmem_mb = 0                 # default budget suffices
-        _fwd_ok = True
-    else:
-        _fwd_vmem_mb = _flash_fwd_vmem_mb()
-        _fwd_ok = _fwd_vmem_mb >= _FWD_MIN_VMEM_MB
-    if (T <= _FULL_UNROLL_MAX_T and T % fb == 0
-            and T // fb <= _FULL_UNROLL_MAX_NQ
-            and not (interpret and in_vma)
-            and T * D * q.dtype.itemsize <= _UNROLL_KV_MAX_BYTES
-            and _fwd_ok):
+    if plan.fwd == "fullunroll":
+        # This form re-tiles internally (the tile size is a schedule
+        # detail — flash results are block-size independent up to f32
+        # reassociation).
+        fb = plan.fwd_tile
         out, lse = pl.pallas_call(
             functools.partial(_fwd_kernel_fullunroll, scale=scale,
                               causal=causal, block=fb, seq_len=seq_len,
@@ -550,13 +442,11 @@ def _fwd_packed(q, k, v, H, D, *, scale, causal, block_q, block_k,
             ],
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel"),
-                **({"vmem_limit_bytes": _fwd_vmem_mb * 1024 * 1024}
-                   if _fwd_vmem_mb else {})),
+                **_vmem_limit(plan.fwd_vmem_mb)),
             interpret=interpret,
         )(q, k, v)
         return out, lse[..., 0]
-    if (nk <= _UNROLL_KV_MAX_NK
-            and T * D * q.dtype.itemsize <= _UNROLL_KV_MAX_BYTES):
+    if plan.fwd == "unrollkv":
         out, lse = pl.pallas_call(
             functools.partial(_fwd_kernel_unrollkv, scale=scale,
                               causal=causal, block_q=block_q,
@@ -590,7 +480,7 @@ def _fwd_packed(q, k, v, H, D, *, scale, causal, block_q, block_k,
     grid = (B, H, nq, nk)
     kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
                                block_q=block_q, block_k=block_k,
-                               seq_len=seq_len, axes=(2, 3))
+                               seq_len=seq_len)
     out, lse = pl.pallas_call(
         kernel,
         grid=grid,
@@ -624,63 +514,16 @@ def _fwd_packed(q, k, v, H, D, *, scale, causal, block_q, block_k,
     return out, lse[..., 0]
 
 
-def _bwd_xla(q, k, v, o, lse, do, *, scale, causal, chunk, seq_len=None):
-    """Flash backward with blockwise XLA einsums over KV chunks: linear
-    memory, uses the saved logsumexp (no softmax recompute instability)."""
-    BH, T, D = q.shape
-    qf = q.astype(jnp.float32)
-    kf = k.astype(jnp.float32)
-    vf = v.astype(jnp.float32)
-    dof = do.astype(jnp.float32)
-    delta = jnp.sum(dof * o.astype(jnp.float32), axis=-1)     # (BH, T)
-    rows = jnp.arange(T)
-
-    def one_chunk(dq_acc, start):
-        ks = lax.dynamic_slice_in_dim(kf, start, chunk, axis=1)
-        vs = lax.dynamic_slice_in_dim(vf, start, chunk, axis=1)
-        cols = start + jnp.arange(chunk)
-        s = jnp.einsum("btd,bcd->btc", qf, ks) * scale
-        mask = None
-        if causal:
-            mask = cols[None, :] <= rows[:, None]             # (T, chunk)
-        if seq_len is not None:
-            lim = jnp.logical_and(rows[:, None] < seq_len,
-                                  cols[None, :] < seq_len)
-            mask = lim if mask is None else jnp.logical_and(mask, lim)
-        if mask is not None:
-            s = jnp.where(mask[None], s, _NEG_BIG)
-        p = jnp.exp(s - lse[..., None])                       # (BH, T, c)
-        if mask is not None:
-            p = jnp.where(mask[None], p, 0.0)
-        dp = jnp.einsum("btd,bcd->btc", dof, vs)
-        ds = p * (dp - delta[..., None]) * scale
-        # dq accumulates across chunks in the scan carry (keeping per-chunk
-        # dq stacked would be the O(T^2) buffer this path exists to avoid);
-        # dk/dv tile the T axis, so stacking them is linear.
-        dq_acc = dq_acc + jnp.einsum("btc,bcd->btd", ds, ks)
-        dk_c = jnp.einsum("btc,btd->bcd", ds, qf)
-        dv_c = jnp.einsum("btc,btd->bcd", p, dof)
-        return dq_acc, (dk_c, dv_c)
-
-    starts = jnp.arange(0, T, chunk)
-    dq, (dk_chunks, dv_chunks) = lax.scan(
-        one_chunk, jnp.zeros_like(qf), starts)
-    dk = dk_chunks.transpose(1, 0, 2, 3).reshape(BH, T, D)
-    dv = dv_chunks.transpose(1, 0, 2, 3).reshape(BH, T, D)
-    return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
-
-
 def _dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dta_ref,
                  dk_ref, dv_ref, dk_scr, dv_scr, *,
-                 scale, causal, block_q, block_k, seq_len, axes=(1, 2)):
+                 scale, causal, block_q, block_k, seq_len):
     """Accumulate dk/dv for one KV block while Q blocks stream through
     (grid innermost axis).  The flash-backward identities:
     p = exp(s - lse);  dv += p^T dO;  dS = p * (dO V^T - delta) * scale;
     dk += dS^T Q."""
-    kj = pl.program_id(axes[0])
-    qi = pl.program_id(axes[1])
-    nq = pl.num_programs(axes[1])
-    row8 = (0, 0) if lse_ref.ndim == 4 else (0,)
+    kj = pl.program_id(2)
+    qi = pl.program_id(3)
+    nq = pl.num_programs(3)
 
     @pl.when(qi == 0)
     def _init():
@@ -692,8 +535,8 @@ def _dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dta_ref,
         k = k_ref[0]                                   # (BK, D)
         v = v_ref[0]                                   # (BK, D)
         do = do_ref[0]                                 # (BQ, D)
-        lse = lse_ref[row8][:, :1]                     # (BQ, 1)
-        delta = dta_ref[row8][:, :1]                   # (BQ, 1)
+        lse = lse_ref[0, 0][:, :1]                     # (BQ, 1)
+        delta = dta_ref[0, 0][:, :1]                   # (BQ, 1)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale    # (BQ, BK)
@@ -727,13 +570,12 @@ def _dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dta_ref,
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dta_ref,
                dq_ref, dq_scr, *, scale, causal, block_q, block_k,
-               seq_len, axes=(1, 2)):
+               seq_len):
     """Accumulate dq for one Q block while KV blocks stream through:
     dq += dS @ K with dS = p * (dO V^T - delta) * scale."""
-    qi = pl.program_id(axes[0])
-    kj = pl.program_id(axes[1])
-    nk = pl.num_programs(axes[1])
-    row8 = (0, 0) if lse_ref.ndim == 4 else (0,)
+    qi = pl.program_id(2)
+    kj = pl.program_id(3)
+    nk = pl.num_programs(3)
 
     @pl.when(kj == 0)
     def _init():
@@ -744,8 +586,8 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dta_ref,
         k = k_ref[0]
         v = v_ref[0]
         do = do_ref[0]
-        lse = lse_ref[row8][:, :1]
-        delta = dta_ref[row8][:, :1]
+        lse = lse_ref[0, 0][:, :1]
+        delta = dta_ref[0, 0][:, :1]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale
@@ -769,185 +611,6 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dta_ref,
     @pl.when(kj == nk - 1)
     def _finalize():
         dq_ref[0] = dq_scr[...].astype(dq_ref.dtype)
-
-
-def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dta_ref,
-                      dk_ref, dv_ref, dq_ref, dk_scr, dv_scr, dq_scr, *,
-                      scale, causal, block_q, block_k, seq_len):
-    """Single-pass flash backward: dk/dv accumulate per KV block while Q
-    blocks stream (inner grid axis), and dq accumulates into a
-    full-sequence f32 VMEM scratch, so the ``s``/``p``/``dp`` recompute
-    the two-kernel split pays twice is computed once — 5 block matmuls
-    per pair instead of 7:
-    p = exp(s - lse);  dv += p^T dO;  dp = dO V^T;
-    dS = p * (dp - delta) * scale;  dk += dS^T Q;  dq += dS K."""
-    kj = pl.program_id(1)
-    qi = pl.program_id(2)
-    nq = pl.num_programs(2)
-
-    @pl.when(qi == 0)
-    def _init_kv():
-        dk_scr[...] = jnp.zeros_like(dk_scr)
-        dv_scr[...] = jnp.zeros_like(dv_scr)
-
-    # dq scratch is (nq, block_q, D) — dynamic indexing stays on the
-    # leading (tile) dim, which Mosaic lowers to plain tile addressing
-    # (a dynamic sublane slice of a flat (T, D) scratch lowered ~2x
-    # slower on v5e).
-    # The dq slice for this Q block is zeroed on the first KV pass even
-    # when the block pair is dead (padding tail), so the unconditional
-    # output write below never flushes stale scratch.
-    @pl.when(kj == 0)
-    def _init_dq():
-        dq_scr[qi] = jnp.zeros_like(dq_scr[qi])
-
-    def _compute(masked: bool):
-        q = q_ref[0]                                   # (BQ, D)
-        k = k_ref[0]                                   # (BK, D)
-        v = v_ref[0]                                   # (BK, D)
-        do = do_ref[0]                                 # (BQ, D)
-        lse = lse_ref[0][:, :1]                        # (BQ, 1)
-        delta = dta_ref[0][:, :1]                      # (BQ, 1)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale    # (BQ, BK)
-        p = jnp.exp(s - lse)
-        ok = (_block_mask(qi, kj, block_q, block_k, causal, seq_len)
-              if masked else None)
-        if ok is not None:
-            p = jnp.where(ok, p, 0.0)
-        # Operands cast to the input dtype so the MXU runs at native
-        # rate; every accumulator stays f32.
-        dv_scr[...] += jax.lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)            # (BQ, BK)
-        ds = p * (dp - delta) * scale
-        dk_scr[...] += jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dq_scr[qi] += jax.lax.dot_general(
-            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-
-    live = _live_block(qi, kj, block_q, block_k, causal, seq_len)
-    _masked_dispatch(_compute, live, qi, kj, block_q, block_k, causal,
-                     seq_len)
-
-    @pl.when(qi == nq - 1)
-    def _finalize_kv():
-        dk_ref[0] = dk_scr[...].astype(dk_ref.dtype)
-        dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
-
-    # dq is only complete after the last KV pass; earlier writes flush
-    # partial sums that the final pass overwrites (a (BQ, D) VMEM copy
-    # per step — noise next to the block matmuls).
-    dq_ref[0] = dq_scr[qi].astype(dq_ref.dtype)
-
-
-# Widest dq scratch the fused backward may allocate: f32 full-sequence
-# accumulator.  4 MB = T 8192 at D=128 — past that the split two-kernel
-# path takes over (ring/Ulysses shard T across chips long before then).
-_FUSED_DQ_SCRATCH_BYTES = 4 << 20
-
-
-def _bwd_pallas_fused(q, k, v, o, lse, do, *, scale, causal, block_q,
-                      block_k, interpret, seq_len=None):
-    """Fused one-pass flash backward (see :func:`_bwd_fused_kernel`)."""
-    BH, T, D = q.shape
-    nq = T // block_q
-    nk = T // block_k
-    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
-                    axis=-1)                                   # (BH, T)
-    lse8 = jnp.broadcast_to(lse[..., None], (BH, T, 8))
-    delta8 = jnp.broadcast_to(delta[..., None], (BH, T, 8))
-
-    specs = dict(
-        q=pl.BlockSpec((1, block_q, D), lambda b, j, i: (b, i, 0)),
-        kv=pl.BlockSpec((1, block_k, D), lambda b, j, i: (b, j, 0)),
-        row8=pl.BlockSpec((1, block_q, 8), lambda b, j, i: (b, i, 0)),
-    )
-    dk, dv, dq = pl.pallas_call(
-        functools.partial(_bwd_fused_kernel, scale=scale, causal=causal,
-                          block_q=block_q, block_k=block_k,
-                          seq_len=seq_len),
-        grid=(BH, nk, nq),
-        in_specs=[specs["q"], specs["kv"], specs["kv"],
-                  specs["q"], specs["row8"], specs["row8"]],
-        out_specs=[specs["kv"], specs["kv"], specs["q"]],
-        out_shape=[_struct((BH, T, D), k.dtype, q, k, v, do),
-                   _struct((BH, T, D), v.dtype, q, k, v, do),
-                   _struct((BH, T, D), q.dtype, q, k, v, do)],
-        scratch_shapes=[pltpu.VMEM((block_k, D), jnp.float32),
-                        pltpu.VMEM((block_k, D), jnp.float32),
-                        pltpu.VMEM((nq, block_q, D), jnp.float32)],
-        # The KV axis carries the dq accumulator across steps, so it is
-        # "arbitrary" here (it was "parallel" in the split dkdv kernel).
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary", "arbitrary")),
-        interpret=interpret,
-    )(q, k, v, do, lse8, delta8)
-    return dq, dk, dv
-
-
-def _bwd_pallas(q, k, v, o, lse, do, *, scale, causal, block_q, block_k,
-                interpret, seq_len=None):
-    """Flash backward as two Pallas kernels with the forward's
-    VMEM-resident blockwise schedule (FlashAttention-2 backward split)."""
-    BH, T, D = q.shape
-    nq = T // block_q
-    nk = T // block_k
-    # Per-row delta = rowsum(dO * O) and lse, broadcast to the (BQ, 8)
-    # narrow-tile layout the forward uses for its lse output.
-    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
-                    axis=-1)                                   # (BH, T)
-    lse8 = jnp.broadcast_to(lse[..., None], (BH, T, 8))
-    delta8 = jnp.broadcast_to(delta[..., None], (BH, T, 8))
-
-    row_specs = dict(
-        q=pl.BlockSpec((1, block_q, D), lambda b, j, i: (b, i, 0)),
-        kv=pl.BlockSpec((1, block_k, D), lambda b, j, i: (b, j, 0)),
-        row8=pl.BlockSpec((1, block_q, 8), lambda b, j, i: (b, i, 0)),
-    )
-    dk, dv = pl.pallas_call(
-        functools.partial(_dkdv_kernel, scale=scale, causal=causal,
-                          block_q=block_q, block_k=block_k,
-                          seq_len=seq_len),
-        grid=(BH, nk, nq),
-        in_specs=[row_specs["q"], row_specs["kv"], row_specs["kv"],
-                  row_specs["q"], row_specs["row8"], row_specs["row8"]],
-        out_specs=[row_specs["kv"], row_specs["kv"]],
-        out_shape=[_struct((BH, T, D), k.dtype, q, k, v, do),
-                   _struct((BH, T, D), v.dtype, q, k, v, do)],
-        scratch_shapes=[pltpu.VMEM((block_k, D), jnp.float32),
-                        pltpu.VMEM((block_k, D), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-    )(q, k, v, do, lse8, delta8)
-
-    q_specs = dict(
-        q=pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
-        kv=pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, j, 0)),
-        row8=pl.BlockSpec((1, block_q, 8), lambda b, i, j: (b, i, 0)),
-    )
-    dq, = pl.pallas_call(
-        functools.partial(_dq_kernel, scale=scale, causal=causal,
-                          block_q=block_q, block_k=block_k,
-                          seq_len=seq_len),
-        grid=(BH, nq, nk),
-        in_specs=[q_specs["q"], q_specs["kv"], q_specs["kv"],
-                  q_specs["q"], q_specs["row8"], q_specs["row8"]],
-        out_specs=[q_specs["q"]],
-        out_shape=[_struct((BH, T, D), q.dtype, q, k, v, do)],
-        scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-    )(q, k, v, do, lse8, delta8)
-    return dq, dk, dv
 
 
 def _dkdv_kernel_grouped(q_ref, k_ref, v_ref, do_ref, lse_ref, dta_ref,
@@ -1057,9 +720,19 @@ def _dq_kernel_grouped(q_ref, k_ref, v_ref, do_ref, lse_ref, dta_ref,
             [dq_scr[g] for g in range(group)], axis=1).astype(dq_ref.dtype)
 
 
+# The grouped pair at its shape (two heads, 1024² blocks, D 128) needs
+# 18.11 MB of scoped VMEM — the f32 score temporaries double with two
+# heads live — against Mosaic's default 16.  v4 and later have 128 MB of
+# VMEM, so the limit is policy, not hardware: 32 MB (measured sufficient,
+# and the margin of the win) where the device backs it; where it does not
+# the per-head pair runs.
+_GROUPED_HEADS = 2
+_GROUPED_VMEM_MB = 32
+
+
 def _bwd_pallas_packed_grouped(q, k, v, o, lse, do, H, D, group, *, scale,
                                causal, block_q, block_k, interpret,
-                               seq_len, head_base):
+                               seq_len, head_base, vmem_mb=0):
     """Head-group blocked split backward on head-packed (B, T, C) views:
     the strided 256-byte-row tax of the per-head packed kernels
     (measured ~12 ms/step at the bench shape, docs/benchmarks.md) is
@@ -1090,19 +763,10 @@ def _bwd_pallas_packed_grouped(q, k, v, o, lse, do, H, D, group, *, scale,
         row8=pl.BlockSpec((1, group, block_q, 8),
                           lambda b, h, j, i: (b, h, i, 0)),
     )
-    # The r4 A/B's block-1024 grouped configs died on Mosaic's default
-    # scoped-VMEM budget (18.11 M > 16 M) — the f32 score temporaries
-    # double with two heads live.  v5e has 128 MB of VMEM, so the limit
-    # is policy, not hardware: the grouped pair defaults to a 32 MB
-    # per-kernel budget (measured sufficient for g2 at 1024² blocks and
-    # the margin of the win); HOROVOD_TPU_FLASH_VMEM_MB overrides, 0
-    # restores the compiler default.
-    _vmem_mb = _flash_vmem_mb()
-    _sem_kw = {"dimension_semantics": ("parallel", "parallel", "parallel",
-                                       "arbitrary")}
-    if _vmem_mb:
-        _sem_kw["vmem_limit_bytes"] = _vmem_mb * 1024 * 1024
-    sem4 = pltpu.CompilerParams(**_sem_kw)
+    sem4 = pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "parallel",
+                             "arbitrary"),
+        **_vmem_limit(vmem_mb))
     dk, dv = pl.pallas_call(
         functools.partial(_dkdv_kernel_grouped, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k,
@@ -1147,146 +811,24 @@ def _bwd_pallas_packed_grouped(q, k, v, o, lse, do, H, D, group, *, scale,
     return dq, dk, dv
 
 
-def _bwd_kernel_fullunroll(q_ref, k_ref, v_ref, do_ref, lse_ref, dta_ref,
-                           dq_ref, dk_ref, dv_ref, *, scale, causal,
-                           block, seq_len, nq, nk):
-    """One-pass flash backward with BOTH loops unrolled on a (B, H)
-    grid: every (qi, kj) is a python int, so each live pair's
-    s/p/dp/ds are computed ONCE and contracted into dq AND dk/dv — the
-    5-matmul fused schedule that the grid-looped fused kernel could not
-    make fast (its loop-carried dq scratch serialized Mosaic's
-    pipeline; here everything is independent SSA, nothing carries).
-    Dead causal/padding pairs are skipped at trace time and boundary
-    masks are static, like :func:`_fwd_kernel_fullunroll`."""
-    qfull = q_ref[0]
-    kfull = k_ref[0]
-    vfull = v_ref[0]
-    dofull = do_ref[0]
-    lse_rows = lse_ref[0, 0][:, :1]                       # (T, 1)
-    dta_rows = dta_ref[0, 0][:, :1]                       # (T, 1)
-    D = qfull.shape[1]
-    dq_parts = [jnp.zeros((block, D), jnp.float32) for _ in range(nq)]
-    dk_parts = [jnp.zeros((block, D), jnp.float32) for _ in range(nk)]
-    dv_parts = [jnp.zeros((block, D), jnp.float32) for _ in range(nk)]
-    for kj in range(nk):
-        k = lax.slice_in_dim(kfull, kj * block, (kj + 1) * block, axis=0)
-        v = lax.slice_in_dim(vfull, kj * block, (kj + 1) * block, axis=0)
-        for qi in range(nq):
-            if _static_dead(qi, kj, block, causal, seq_len):
-                continue
-            q = lax.slice_in_dim(qfull, qi * block, (qi + 1) * block,
-                                 axis=0)
-            do = lax.slice_in_dim(dofull, qi * block, (qi + 1) * block,
-                                  axis=0)
-            lse = lax.slice_in_dim(lse_rows, qi * block,
-                                   (qi + 1) * block, axis=0)
-            delta = lax.slice_in_dim(dta_rows, qi * block,
-                                     (qi + 1) * block, axis=0)
-            s = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale
-            p = jnp.exp(s - lse)
-            interior = _static_interior(qi, kj, block, causal, seq_len)
-            if not interior:
-                ok = _block_mask(qi, kj, block, block, causal, seq_len)
-                p = jnp.where(ok, p, 0.0)
-            dv_parts[kj] = dv_parts[kj] + jax.lax.dot_general(
-                p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            dp = jax.lax.dot_general(
-                do, v, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            ds = p * (dp - delta) * scale
-            dk_parts[kj] = dk_parts[kj] + jax.lax.dot_general(
-                ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            dq_parts[qi] = dq_parts[qi] + jax.lax.dot_general(
-                ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-
-    def cat(parts, dtype):
-        parts = [p.astype(dtype) for p in parts]
-        return parts[0] if len(parts) == 1 else jnp.concatenate(parts, 0)
-
-    dq_ref[0] = cat(dq_parts, dq_ref.dtype)
-    dk_ref[0] = cat(dk_parts, dk_ref.dtype)
-    dv_ref[0] = cat(dv_parts, dv_ref.dtype)
-
-
-def _bwd_pallas_packed(q, k, v, o, lse, do, H, D, *, scale, causal,
+def _bwd_pallas_packed(q, k, v, o, lse, do, H, D, plan, *, scale, causal,
                        block_q, block_k, interpret, seq_len=None,
                        head_base=(0, 0, 0)):
     """Split flash backward on head-packed (B, T, C) views (see
     :func:`_fwd_packed`); ``lse`` arrives as (B, H, T) and ``o``/``do``
-    are head-merged (B, T, H*D).
+    are head-merged (B, T, H*D).  ``plan`` is :func:`_plan`'s: the pair
+    blocked over two heads, or the per-head pair below.
 
-    The packed kernels read strided 256-byte rows (measured ~+1 ms/layer
-    over contiguous tiles on v5e at the bench shape, vs ~+0.8 ms/layer
-    of transpose copies for the merged layout) — the strided form stays
-    the default; ``HOROVOD_TPU_FLASH_PACKED_BWD=0`` switches to
-    transpose-to-merged + the contiguous kernel pair for A/B."""
-    B, T, _ = q.shape
-    if os.environ.get("HOROVOD_TPU_FLASH_PACKED_BWD", "1") == "0":
-        oq, ok_, ov = head_base
-
-        def pick(x, off):   # (B, T, C*) head range -> merged (B*H, T, D)
-            x = x[..., off * D:(off + H) * D]
-            return (x.reshape(B, T, H, D).transpose(0, 2, 1, 3)
-                    .reshape(B * H, T, D))
-
-        qm, km, vm = pick(q, oq), pick(k, ok_), pick(v, ov)
-        om, dom = pick(o, 0), pick(do, 0)
-        dqm, dkm, dvm = _bwd_pallas(
-            qm, km, vm, om, lse.reshape(B * H, T), dom, scale=scale,
-            causal=causal, block_q=block_q, block_k=block_k,
-            interpret=interpret, seq_len=seq_len)
-
-        def unpick(g):
-            return (g.reshape(B, H, T, D).transpose(0, 2, 1, 3)
-                    .reshape(B, T, H * D))
-
-        return unpick(dqm), unpick(dkm), unpick(dvm)
-    # Head-group blocked variant (VERDICT r4 weak #3): tiles span
-    # `group` adjacent heads so the HBM rows are group× wider than the
-    # per-head 256-byte strided reads.  Requires group | H and
-    # group-aligned head bases (the fused-qkv bases 0/H/2H qualify
-    # whenever group | H).  The r4 A/B that rejected it hit Mosaic's
-    # default 16 MB scoped-VMEM budget at block 1024; with the budget
-    # raised (HOROVOD_TPU_FLASH_VMEM_MB, default 32 for grouped) g2 at
-    # 1024² measures 11.97 vs 12.18 ms/layer-iter on v5e — so g2 is the
-    # DEFAULT at exactly that proven shape (both blocks 1024, D=128);
-    # everywhere else per-head remains default and the env opts in.
-    # Auto-selection stands down when (a) HOROVOD_TPU_FLASH_BWD names an
-    # explicit backward impl (the fullunroll A/B would be silently
-    # shadowed by the early grouped return), or (b) the device
-    # generation cannot back the ~18 MB budget (v2/v3 have 16 MB of
-    # physical VMEM per core; v4+ have 128 MB).
-    group_env = os.environ.get("HOROVOD_TPU_FLASH_BWD_GROUP")
-    if group_env is not None:
-        try:
-            group = int(group_env)
-            if group < 1:
-                raise ValueError
-        except ValueError:
-            import warnings
-            warnings.warn(
-                f"HOROVOD_TPU_FLASH_BWD_GROUP={group_env!r} is not a "
-                "positive integer; using the per-head default (1)",
-                RuntimeWarning, stacklevel=2)
-            group = 1
-    elif (block_q == 1024 and block_k == 1024 and D == 128
-          and H % 2 == 0 and all(b % 2 == 0 for b in head_base)
-          and os.environ.get("HOROVOD_TPU_FLASH_BWD") is None
-          and _flash_vmem_mb() >= 32 and _vmem_headroom_ok()):
-        group = 2
-    else:
-        group = 1
-    if (group > 1 and H % group == 0
-            and all(b % group == 0 for b in head_base)):
+    The per-head kernels read strided 256-byte rows (measured ~+1 ms/layer
+    over contiguous tiles on v5e at the bench shape, vs ~+0.8 ms/layer of
+    transpose copies had the operands been laid out merged first)."""
+    if plan.bwd == "grouped":
         return _bwd_pallas_packed_grouped(
-            q, k, v, o, lse, do, H, D, group, scale=scale, causal=causal,
-            block_q=block_q, block_k=block_k, interpret=interpret,
-            seq_len=seq_len, head_base=head_base)
+            q, k, v, o, lse, do, H, D, _GROUPED_HEADS, scale=scale,
+            causal=causal, block_q=block_q, block_k=block_k,
+            interpret=interpret, seq_len=seq_len, head_base=head_base,
+            vmem_mb=plan.bwd_vmem_mb)
+    B, T, _ = q.shape
     C = H * D
     nq = T // block_q
     nk = T // block_k
@@ -1297,50 +839,6 @@ def _bwd_pallas_packed(q, k, v, o, lse, do, H, D, *, scale, causal,
                     axis=-1).transpose(0, 2, 1)               # (B, H, T)
     lse8 = jnp.broadcast_to(lse[..., None], (B, H, T, 8))
     delta8 = jnp.broadcast_to(delta[..., None], (B, H, T, 8))
-
-    # The fused one-pass form (5 matmuls/pair instead of the split
-    # pair's 7) measured a WASH on v5e (5.24 vs 5.19 ms f+b at the
-    # bench shape) — whatever binds the backward, it isn't matmul
-    # count.  Kept behind an env knob so the recorded A/B stays
-    # reproducible; the split pair stays the measured default.
-    in_vma = jax.typeof(q).vma
-    fbb = min(_FULL_UNROLL_BLOCK, block_q, block_k, T)
-    # Tighter VMEM bound than the forward's: this kernel holds 4 input
-    # + 3 output full rows PLUS three full-sequence f32 accumulator
-    # part-sets, several times the forward's residency — 512 KB rows
-    # (T=2048 at D=128 bf16, the measured-working shape) is the limit.
-    if (os.environ.get("HOROVOD_TPU_FLASH_BWD") == "fullunroll"
-            and T <= _FULL_UNROLL_MAX_T and T % fbb == 0
-            and T // fbb <= _FULL_UNROLL_MAX_NQ
-            and not (interpret and in_vma)
-            and T * D * q.dtype.itemsize <= _FULL_UNROLL_BWD_MAX_BYTES):
-        n = T // fbb
-        dq, dk, dv = pl.pallas_call(
-            functools.partial(_bwd_kernel_fullunroll, scale=scale,
-                              causal=causal, block=fbb, seq_len=seq_len,
-                              nq=n, nk=n),
-            grid=(B, H),
-            in_specs=[
-                pl.BlockSpec((1, T, D), lambda b, h: (b, 0, h + oq)),
-                pl.BlockSpec((1, T, D), lambda b, h: (b, 0, h + ok_)),
-                pl.BlockSpec((1, T, D), lambda b, h: (b, 0, h + ov)),
-                pl.BlockSpec((1, T, D), lambda b, h: (b, 0, h)),
-                pl.BlockSpec((1, 1, T, 8), lambda b, h: (b, h, 0, 0)),
-                pl.BlockSpec((1, 1, T, 8), lambda b, h: (b, h, 0, 0)),
-            ],
-            out_specs=[
-                pl.BlockSpec((1, T, D), lambda b, h: (b, 0, h)),
-                pl.BlockSpec((1, T, D), lambda b, h: (b, 0, h)),
-                pl.BlockSpec((1, T, D), lambda b, h: (b, 0, h)),
-            ],
-            out_shape=[_struct((B, T, C), q.dtype, q, k, v, do),
-                       _struct((B, T, C), k.dtype, q, k, v, do),
-                       _struct((B, T, C), v.dtype, q, k, v, do)],
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "parallel")),
-            interpret=interpret,
-        )(q, k, v, do, lse8, delta8)
-        return dq, dk, dv
 
     kv_specs = dict(
         q=pl.BlockSpec((1, block_q, D),
@@ -1360,7 +858,7 @@ def _bwd_pallas_packed(q, k, v, o, lse, do, H, D, *, scale, causal,
     dk, dv = pl.pallas_call(
         functools.partial(_dkdv_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k,
-                          seq_len=seq_len, axes=(2, 3)),
+                          seq_len=seq_len),
         grid=(B, H, nk, nq),
         in_specs=[kv_specs["q"], kv_specs["k"], kv_specs["v"],
                   kv_specs["do"], kv_specs["row8"], kv_specs["row8"]],
@@ -1388,7 +886,7 @@ def _bwd_pallas_packed(q, k, v, o, lse, do, H, D, *, scale, causal,
     dq, = pl.pallas_call(
         functools.partial(_dq_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k,
-                          seq_len=seq_len, axes=(2, 3)),
+                          seq_len=seq_len),
         grid=(B, H, nq, nk),
         in_specs=[q_specs["q"], q_specs["k"], q_specs["v"],
                   q_specs["do"], q_specs["row8"], q_specs["row8"]],
@@ -1401,21 +899,98 @@ def _bwd_pallas_packed(q, k, v, o, lse, do, H, D, *, scale, causal,
     return dq, dk, dv
 
 
+class _Plan(NamedTuple):
+    """What :func:`_plan` decides for one call of the op."""
+    fwd: str            # "fullunroll" | "unrollkv" | "grid"
+    fwd_tile: int       # the fully-unrolled form's own tile, else 0
+    fwd_vmem_mb: int    # scoped-VMEM budget, MB; 0 = Mosaic's default
+    bwd: str            # "grouped" | "per_head"
+    bwd_vmem_mb: int
+
+
+def _plan(*, T, D, H, head_base, itemsize, block_q, block_k, bwd_block_q,
+          bwd_block_k, interpret, manual_axes, vmem_headroom) -> _Plan:
+    """Which forward form and which backward pair run, and the VMEM limit
+    each is compiled with — the one place that chooses, from what the op
+    observes at trace time and nothing else.
+
+    ``T``, ``D``, ``H``: sequence length, head width, heads; ``head_base``:
+    the head offsets of q, k, v inside their packed rows; ``itemsize``:
+    bytes an operand element; the four resolved blocks; ``manual_axes``:
+    whether the operands vary over manual mesh axes (``shard_map``);
+    ``vmem_headroom``: :func:`_vmem_headroom_ok` — whether the device
+    backs a scoped budget above Mosaic's default."""
+    if D % 128:
+        # Heads off the lane width arrive merged into the batch (H is 1,
+        # see flash_attention).  Only these two forms have run on a chip
+        # at such a D.
+        return _Plan("grid", 0, 0, "per_head", 0)
+
+    row_fits = T * D * itemsize <= _UNROLL_KV_MAX_BYTES
+    # The fully-unrolled form's tile divides T whenever T is a multiple
+    # of 8 beyond the tile, else the other forms take over.
+    tile = min(_FULL_UNROLL_BLOCK, block_q, block_k, T)
+    fwd_vmem_mb = 0 if T <= _DEFAULT_VMEM_MAX_T else _FULL_UNROLL_VMEM_MB
+    if (T <= _FULL_UNROLL_MAX_T and T % tile == 0
+            and T // tile <= _FULL_UNROLL_MAX_NQ
+            # Under shard_map manual axes IN INTERPRET MODE the generic
+            # HLO interpreter cannot discharge this kernel's loads (its
+            # vma check rejects the block dynamic_slices), so CPU tests
+            # take the unrolled-KV form there; compiled Mosaic is
+            # unaffected.
+            and not (interpret and manual_axes)
+            and row_fits
+            # A budget the device cannot back stands this form down
+            # instead of failing the whole compile.
+            and (fwd_vmem_mb == 0 or vmem_headroom)):
+        fwd = ("fullunroll", tile, fwd_vmem_mb)
+    elif T // block_k <= _UNROLL_KV_MAX_NK and row_fits:
+        fwd = ("unrollkv", 0, 0)
+    else:
+        fwd = ("grid", 0, 0)
+
+    # Tiles spanning two adjacent heads make the HBM rows twice as wide
+    # as the per-head pair's 256-byte strided reads: 11.97 vs 12.18
+    # ms/layer-iter on v5e at exactly the proven shape (both blocks 1024,
+    # D=128), so there and only there.  Needs an even head count and even
+    # head bases (the fused-qkv bases 0/H/2H qualify whenever H is even),
+    # and a device that backs the ~18 MB budget.
+    if (bwd_block_q == 1024 and bwd_block_k == 1024 and D == 128
+            and H % _GROUPED_HEADS == 0
+            and all(b % _GROUPED_HEADS == 0 for b in head_base)
+            and vmem_headroom):
+        bwd = ("grouped", _GROUPED_VMEM_MB)
+    else:
+        bwd = ("per_head", 0)
+    return _Plan(*fwd, *bwd)
+
+
+def _plan_for(q, H, D, head_base, block_q, block_k, bwd_block_q,
+              bwd_block_k, interpret) -> _Plan:
+    """:func:`_plan` for the operand ``q`` of a custom-VJP rule."""
+    return _plan(T=q.shape[1], D=D, H=H, head_base=head_base,
+                 itemsize=q.dtype.itemsize, block_q=block_q,
+                 block_k=block_k, bwd_block_q=bwd_block_q,
+                 bwd_block_k=bwd_block_k, interpret=interpret,
+                 manual_axes=bool(jax.typeof(q).vma),
+                 vmem_headroom=_vmem_headroom_ok())
+
+
 @functools.partial(jax.custom_vjp,
                    nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10, 11))
 def _flash_packed(q, k, v, H, scale, causal, block_q, block_k,
                   bwd_block_q, bwd_block_k, interpret, seq_len):
-    D = q.shape[2] // H
-    out, _ = _fwd_packed(q, k, v, H, D, scale=scale, causal=causal,
-                         block_q=block_q, block_k=block_k,
-                         interpret=interpret, seq_len=seq_len)
-    return out
+    return _flash_packed_fwd(q, k, v, H, scale, causal, block_q, block_k,
+                             bwd_block_q, bwd_block_k, interpret,
+                             seq_len)[0]
 
 
 def _flash_packed_fwd(q, k, v, H, scale, causal, block_q, block_k,
                       bwd_block_q, bwd_block_k, interpret, seq_len):
     D = q.shape[2] // H
-    out, lse = _fwd_packed(q, k, v, H, D, scale=scale, causal=causal,
+    plan = _plan_for(q, H, D, (0, 0, 0), block_q, block_k, bwd_block_q,
+                     bwd_block_k, interpret)
+    out, lse = _fwd_packed(q, k, v, H, D, plan, scale=scale, causal=causal,
                            block_q=block_q, block_k=block_k,
                            interpret=interpret, seq_len=seq_len)
     return out, (q, k, v, out, lse)
@@ -1425,7 +1000,9 @@ def _flash_packed_bwd(H, scale, causal, block_q, block_k, bwd_block_q,
                       bwd_block_k, interpret, seq_len, res, do):
     q, k, v, o, lse = res
     D = q.shape[2] // H
-    return _bwd_pallas_packed(q, k, v, o, lse, do, H, D, scale=scale,
+    plan = _plan_for(q, H, D, (0, 0, 0), block_q, block_k, bwd_block_q,
+                     bwd_block_k, interpret)
+    return _bwd_pallas_packed(q, k, v, o, lse, do, H, D, plan, scale=scale,
                               causal=causal, block_q=bwd_block_q,
                               block_k=bwd_block_k, interpret=interpret,
                               seq_len=seq_len)
@@ -1434,37 +1011,55 @@ def _flash_packed_bwd(H, scale, causal, block_q, block_k, bwd_block_q,
 _flash_packed.defvjp(_flash_packed_fwd, _flash_packed_bwd)
 
 
+def _qkv_fwd(qkv, H, D, scale, causal, block_q, block_k, bwd_block_q,
+             bwd_block_k, interpret, seq_len):
+    """(out, lse) with q | k | v read as three regions of one
+    (B, T, 3*H*D) projection."""
+    base = (0, H, 2 * H)
+    plan = _plan_for(qkv, H, D, base, block_q, block_k, bwd_block_q,
+                     bwd_block_k, interpret)
+    return _fwd_packed(qkv, qkv, qkv, H, D, plan, scale=scale,
+                       causal=causal, block_q=block_q, block_k=block_k,
+                       interpret=interpret, seq_len=seq_len,
+                       head_base=base)
+
+
+def _qkv_bwd(qkv, o, lse, do, H, D, scale, causal, block_q, block_k,
+             bwd_block_q, bwd_block_k, interpret, seq_len):
+    """The cotangent of :func:`_qkv_fwd`'s projection: one concatenate
+    of dq | dk | dv."""
+    base = (0, H, 2 * H)
+    plan = _plan_for(qkv, H, D, base, block_q, block_k, bwd_block_q,
+                     bwd_block_k, interpret)
+    dq, dk, dv = _bwd_pallas_packed(
+        qkv, qkv, qkv, o, lse, do, H, D, plan, scale=scale, causal=causal,
+        block_q=bwd_block_q, block_k=bwd_block_k, interpret=interpret,
+        seq_len=seq_len, head_base=base)
+    return jnp.concatenate([dq, dk, dv], axis=-1)          # (B, T, 3C)
+
+
 @functools.partial(jax.custom_vjp,
                    nondiff_argnums=(1, 2, 3, 4, 5, 6, 7, 8, 9))
 def _flash_qkv(qkv, H, scale, causal, block_q, block_k, bwd_block_q,
                bwd_block_k, interpret, seq_len):
-    D = qkv.shape[2] // (3 * H)
-    out, _ = _fwd_packed(qkv, qkv, qkv, H, D, scale=scale, causal=causal,
-                         block_q=block_q, block_k=block_k,
-                         interpret=interpret, seq_len=seq_len,
-                         head_base=(0, H, 2 * H))
-    return out
+    return _flash_qkv_fwd(qkv, H, scale, causal, block_q, block_k,
+                          bwd_block_q, bwd_block_k, interpret, seq_len)[0]
 
 
 def _flash_qkv_fwd(qkv, H, scale, causal, block_q, block_k, bwd_block_q,
                    bwd_block_k, interpret, seq_len):
-    D = qkv.shape[2] // (3 * H)
-    out, lse = _fwd_packed(qkv, qkv, qkv, H, D, scale=scale,
-                           causal=causal, block_q=block_q,
-                           block_k=block_k, interpret=interpret,
-                           seq_len=seq_len, head_base=(0, H, 2 * H))
+    out, lse = _qkv_fwd(qkv, H, qkv.shape[2] // (3 * H), scale, causal,
+                        block_q, block_k, bwd_block_q, bwd_block_k,
+                        interpret, seq_len)
     return out, (qkv, out, lse)
 
 
 def _flash_qkv_bwd(H, scale, causal, block_q, block_k, bwd_block_q,
                    bwd_block_k, interpret, seq_len, res, do):
     qkv, o, lse = res
-    D = qkv.shape[2] // (3 * H)
-    dq, dk, dv = _bwd_pallas_packed(
-        qkv, qkv, qkv, o, lse, do, H, D, scale=scale, causal=causal,
-        block_q=bwd_block_q, block_k=bwd_block_k, interpret=interpret,
-        seq_len=seq_len, head_base=(0, H, 2 * H))
-    return (jnp.concatenate([dq, dk, dv], axis=-1),)
+    return (_qkv_bwd(qkv, o, lse, do, H, qkv.shape[2] // (3 * H), scale,
+                     causal, block_q, block_k, bwd_block_q, bwd_block_k,
+                     interpret, seq_len),)
 
 
 _flash_qkv.defvjp(_flash_qkv_fwd, _flash_qkv_bwd)
@@ -1474,21 +1069,18 @@ _flash_qkv.defvjp(_flash_qkv_fwd, _flash_qkv_bwd)
                    nondiff_argnums=(2, 3, 4, 5, 6, 7, 8, 9, 10))
 def _flash_qkv_proj(x, w, H, scale, causal, block_q, block_k,
                     bwd_block_q, bwd_block_k, interpret, seq_len):
-    out, _ = _flash_qkv_proj_fwd(x, w, H, scale, causal, block_q,
-                                 block_k, bwd_block_q, bwd_block_k,
-                                 interpret, seq_len)
-    return out
+    return _flash_qkv_proj_fwd(x, w, H, scale, causal, block_q, block_k,
+                               bwd_block_q, bwd_block_k, interpret,
+                               seq_len)[0]
 
 
 def _flash_qkv_proj_fwd(x, w, H, scale, causal, block_q, block_k,
                         bwd_block_q, bwd_block_k, interpret, seq_len):
-    D = w.shape[1] // (3 * H)
     qkv = jax.lax.dot_general(
         x, w.astype(x.dtype), (((2,), (0,)), ((), ())))   # (B, T, 3C)
-    out, lse = _fwd_packed(qkv, qkv, qkv, H, D, scale=scale,
-                           causal=causal, block_q=block_q,
-                           block_k=block_k, interpret=interpret,
-                           seq_len=seq_len, head_base=(0, H, 2 * H))
+    out, lse = _qkv_fwd(qkv, H, w.shape[1] // (3 * H), scale, causal,
+                        block_q, block_k, bwd_block_q, bwd_block_k,
+                        interpret, seq_len)
     # qkv is NOT saved: the backward recomputes it from (x, w) — one
     # extra (B*T, C) @ (C, 3C) matmul in exchange for never holding the
     # (B, T, 3C) projection as a residual (201 MB/layer at the bench
@@ -1500,14 +1092,11 @@ def _flash_qkv_proj_fwd(x, w, H, scale, causal, block_q, block_k,
 def _flash_qkv_proj_bwd(H, scale, causal, block_q, block_k, bwd_block_q,
                         bwd_block_k, interpret, seq_len, res, do):
     x, w, o, lse = res
-    D = w.shape[1] // (3 * H)
     wc = w.astype(x.dtype)
     qkv = jax.lax.dot_general(x, wc, (((2,), (0,)), ((), ())))
-    dq, dk, dv = _bwd_pallas_packed(
-        qkv, qkv, qkv, o, lse, do, H, D, scale=scale, causal=causal,
-        block_q=bwd_block_q, block_k=bwd_block_k, interpret=interpret,
-        seq_len=seq_len, head_base=(0, H, 2 * H))
-    dqkv = jnp.concatenate([dq, dk, dv], axis=-1)          # (B, T, 3C)
+    dqkv = _qkv_bwd(qkv, o, lse, do, H, w.shape[1] // (3 * H), scale,
+                    causal, block_q, block_k, bwd_block_q, bwd_block_k,
+                    interpret, seq_len)
     dx = jax.lax.dot_general(
         dqkv, wc, (((2,), (1,)), ((), ()))).astype(x.dtype)
     dw = jax.lax.dot_general(
@@ -1553,56 +1142,6 @@ def flash_qkv_proj(x, w, num_heads: int, *, causal: bool = True,
                            bool(causal), block_q, block_k,
                            bwd_block_q, bwd_block_k,
                            bool(interpret), seq_len)
-
-
-@functools.partial(jax.custom_vjp,
-                   nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10, 11))
-def _flash(q, k, v, scale, causal, block_q, block_k, bwd_block_q,
-           bwd_block_k, interpret, bwd_impl, seq_len):
-    out, _ = _fwd(q, k, v, scale=scale, causal=causal, block_q=block_q,
-                  block_k=block_k, interpret=interpret, seq_len=seq_len)
-    return out
-
-
-def _flash_fwd(q, k, v, scale, causal, block_q, block_k, bwd_block_q,
-               bwd_block_k, interpret, bwd_impl, seq_len):
-    out, lse = _fwd(q, k, v, scale=scale, causal=causal, block_q=block_q,
-                    block_k=block_k, interpret=interpret, seq_len=seq_len)
-    return out, (q, k, v, out, lse)
-
-
-def _flash_bwd(scale, causal, block_q, block_k, bwd_block_q, bwd_block_k,
-               interpret, bwd_impl, seq_len, res, do):
-    q, k, v, o, lse = res
-    if bwd_impl == "pallas":
-        # The split pair is the measured default on v5e: its shorter
-        # kernel bodies software-pipeline to ~96% MXU on their 7 block
-        # matmuls, while the fused kernel's loop-carried dq scratch
-        # (dynamic per-step slice) defeats Mosaic's cross-step overlap —
-        # 5 matmuls at ~49% lost to 7 at ~96% (docs/benchmarks.md).
-        bwd_impl = "pallas_split"
-    if bwd_impl == "pallas_fused":
-        # The fused kernel keeps a full-sequence f32 dq accumulator in
-        # VMEM ((T, D) = nq*block_q*D floats); past the scratch budget it
-        # would fail Mosaic allocation at compile time, so hand off to the
-        # split two-kernel path instead (ring/Ulysses shard T across chips
-        # long before this bound matters on one chip).
-        T, D = q.shape[-2], q.shape[-1]
-        if T * D * 4 <= _FUSED_DQ_SCRATCH_BYTES:
-            return _bwd_pallas_fused(q, k, v, o, lse, do, scale=scale,
-                                     causal=causal, block_q=bwd_block_q,
-                                     block_k=bwd_block_k, interpret=interpret,
-                                     seq_len=seq_len)
-        bwd_impl = "pallas_split"
-    if bwd_impl == "pallas_split":
-        return _bwd_pallas(q, k, v, o, lse, do, scale=scale, causal=causal,
-                           block_q=bwd_block_q, block_k=bwd_block_k,
-                           interpret=interpret, seq_len=seq_len)
-    return _bwd_xla(q, k, v, o, lse, do, scale=scale, causal=causal,
-                    chunk=bwd_block_k, seq_len=seq_len)
-
-
-_flash.defvjp(_flash_fwd, _flash_bwd)
 
 
 def auto_block(T: int) -> int:
@@ -1720,7 +1259,6 @@ def flash_attention(q, k, v, *, causal: bool = True,
                     bwd_block_q: Optional[int] = None,
                     bwd_block_k: Optional[int] = None,
                     interpret: bool = False,
-                    bwd_impl: str = "pallas",
                     seq_len: Optional[int] = None):
     """Fused flash attention for ``(B, T, H, D)`` inputs (same contract as
     :func:`~horovod_tpu.parallel.ring_attention.full_attention`).
@@ -1728,44 +1266,40 @@ def flash_attention(q, k, v, *, causal: bool = True,
     Block sizes default to :func:`auto_block` (the largest multiple-of-8
     divisor of ``T`` up to 1024 — the largest square block whose f32
     scores tile fits v5e's 16 MB scoped VMEM); explicit blocks must
-    divide ``T`` and be multiples of 8 (Mosaic's sublane constraint).  Differentiable via the flash-backward identities
-    (``bwd_impl="pallas"`` — VMEM-resident blockwise kernels; ``"xla"`` —
-    the chunked-einsum fallback).  ``seq_len``: real length when the
-    inputs are zero-padded to a tileable ``T`` — positions past it are
-    masked statically in forward and backward.  Set ``interpret=True`` to
-    run off-TPU (tests).
+    divide ``T`` and be multiples of 8 (Mosaic's sublane constraint).
+    Differentiable via the flash-backward identities as VMEM-resident
+    blockwise Pallas kernels; which forward form and which backward pair
+    run follows the shapes (:func:`_plan`) and is not an option.
+    ``seq_len``: real length when the inputs are zero-padded to a
+    tileable ``T`` — positions past it are masked statically in forward
+    and backward.  Set ``interpret=True`` to run off-TPU (tests).
     """
     B, T, H, D = q.shape
     if scale is None:
         scale = 1.0 / (D ** 0.5)
-    if bwd_impl not in ("pallas", "pallas_fused", "pallas_split", "xla"):
-        raise ValueError(f"bwd_impl must be 'pallas' (auto fused/split), "
-                         f"'pallas_fused', 'pallas_split' or 'xla', got "
-                         f"{bwd_impl!r}")
     block_q, block_k, bwd_block_q, bwd_block_k, seq_len = _resolve_blocks(
         T, "flash_attention", block_q, block_k, bwd_block_q, bwd_block_k,
         seq_len, "T divisible by the blocks is required — use "
         "flash_attention_auto (pads and masks) or full_attention for "
         "ragged lengths")
 
-    # Head-packed path: lane-aligned head dims run the kernels directly
-    # on (B, T, H*D) views via head-offset BlockSpecs — the reshape is
-    # free (contiguous), so no transpose copy ever hits HBM.  Unaligned
-    # D (or the opt-in fused/xla backwards) use the legacy merged layout.
-    if D % 128 == 0 and bwd_impl in ("pallas", "pallas_split"):
-        out = _flash_packed(
-            q.reshape(B, T, H * D), k.reshape(B, T, H * D),
-            v.reshape(B, T, H * D), int(H), float(scale), bool(causal),
-            int(block_q), int(block_k), int(bwd_block_q),
-            int(bwd_block_k), bool(interpret), seq_len)
+    static = (float(scale), bool(causal), block_q, block_k, bwd_block_q,
+              bwd_block_k, bool(interpret), seq_len)
+    # Lane-aligned head dims run the kernels directly on (B, T, H*D)
+    # views via head-offset BlockSpecs — the reshape is free
+    # (contiguous), so no transpose copy ever hits HBM.
+    if D % 128 == 0:
+        out = _flash_packed(q.reshape(B, T, H * D), k.reshape(B, T, H * D),
+                            v.reshape(B, T, H * D), int(H), *static)
         return out.reshape(B, T, H, D)
 
+    # A head off the lane width cannot be addressed inside a packed row:
+    # the heads are merged into the batch — a transpose copy each way —
+    # and every (T, D) slab is a packed row of one head.
     def merge(x):   # (B, T, H, D) -> (B*H, T, D)
         return x.transpose(0, 2, 1, 3).reshape(B * H, T, D)
 
-    out = _flash(merge(q), merge(k), merge(v), float(scale), bool(causal),
-                 int(block_q), int(block_k), int(bwd_block_q),
-                 int(bwd_block_k), bool(interpret), bwd_impl, seq_len)
+    out = _flash_packed(merge(q), merge(k), merge(v), 1, *static)
     return out.reshape(B, H, T, D).transpose(0, 2, 1, 3)
 
 
@@ -1786,8 +1320,8 @@ def flash_attention_qkv(qkv, num_heads: int, *, causal: bool = True,
     so neither the qkv split nor any (B, T, H, D) transpose ever copies
     in HBM — at the bench shape those layout copies were ~25 ms/step
     (docs/benchmarks.md).  Requires lane-aligned heads (``D % 128 ==
-    0``); use :func:`flash_attention` otherwise.  Backward is always the
-    split Pallas pair; the qkv cotangent is one concatenate.
+    0``); use :func:`flash_attention` otherwise.  The qkv cotangent is
+    one concatenate.
     """
     B, T, C3 = qkv.shape
     if C3 % (3 * num_heads):

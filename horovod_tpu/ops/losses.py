@@ -31,10 +31,10 @@ matmul, in exchange for never holding O(N x vocab) residuals.  Where the
 backward directly follows the forward (``jax.grad`` of ``.mean()``, as
 every step in this repo is) XLA merges that matmul with the forward's
 identical one and the tile lives from one to the other: three head-sized
-matmuls a chunk run, not four (PERF.md section 6, PR 26).
-``HOROVOD_TPU_XENT_MODE`` selects alternative schedules (see
-:func:`_xent_mode`), including a save-the-logits form with a compact
-bf16 residual.  All matmuls run in the input dtype (bf16 on TPU) with
+matmuls a chunk run, not four (PERF.md section 6, PR 26): a form that
+saved the tile as a bf16 residual saved what XLA already keeps, and left
+at PR 27.  The schedule follows ``n`` and ``chunk`` (:func:`_schedule`)
+and is not an option.  All matmuls run in the input dtype (bf16 on TPU) with
 f32 accumulation, so precision matches the f32-logits reference within
 bf16 rounding.  The ops carry the trace scopes ``xent/loss`` and
 ``xent/grad``; device time under ``xent/loss/overflowed`` counts the
@@ -48,90 +48,34 @@ heads); cited by SURVEY §5.7's long-context mandate.
 from __future__ import annotations
 
 import functools
-import os
-import re
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
-_DEFAULT_MODE = "unroll2"
-# Beyond this many python-unrolled chunks the HLO growth outweighs the
-# unrolled form's advantages and the lax.scan schedule takes over.
+# Beyond this many python-unrolled chunks the HLO growth (each body ~3
+# large matmuls in the backward) outweighs the unrolled form's advantages
+# and the constant-size lax.scan schedule takes over.
 _MAX_UNROLL_CHUNKS = 8
 
 
-def _xent_mode() -> str:
-    """CE schedule variant from ``HOROVOD_TPU_XENT_MODE`` (trace time):
+def _schedule(n: int, chunk: int):
+    """(rows a chunk, python-unrolled?) for ``n`` rows under the caller's
+    transient bound of ``chunk`` rows (chunk x V f32).
 
-    * ``unroll2`` (default) — python-unrolled 2-way row chunking of the
-      streamed-head schedule: the logits transient halves, with none of
-      the ``lax.scan`` while-loop/stacking overhead that made the
-      scanned form slower.  At the bench shape the halved transient
-      (1 GB instead of 2 GB) drops peak HBM below the point where XLA
-      auto-rematerializes one convolution fusion per layer — measured
-      547 → 518 ms/step, MFU 0.704 → 0.744 on v5e
-      (docs/benchmarks.md).  ``unrollK`` generalizes (K clamped to a
-      divisor of N; K=1 == one tile).
-    * ``recompute`` — the single-tile streamed-head schedule (or a
-      ``lax.scan`` when the ``chunk`` argument is below N): no logits
-      residual, one extra head matmul in the backward.
-    * ``save`` / ``saveK`` — keep the logits as a compact bf16 residual
-      (N × vocab × 2 bytes, K-way chunked) and skip the backward
-      recompute matmul; ``save2`` measured ~0.5 ms ≤ ``unroll2`` at the
-      bench shape but holds a 1 GB residual, so it stays opt-in.
-
-    An unrecognized value warns and falls back to the default rather
-    than raising mid-trace.
-    """
-    raw = os.environ.get("HOROVOD_TPU_XENT_MODE", _DEFAULT_MODE)
-    if not re.fullmatch(r"recompute|save\d*|unroll\d+", raw):
-        import warnings
-        warnings.warn(
-            f"HOROVOD_TPU_XENT_MODE={raw!r} is not one of 'recompute', "
-            f"'saveK', 'unrollK'; using the default {_DEFAULT_MODE!r}",
-            RuntimeWarning, stacklevel=3)
-        return _DEFAULT_MODE
-    return raw
-
-
-def _mode_layout(mode: str, n: int, chunk: int):
-    """(save_logits, n_chunks, scan_chunk) for a validated mode string.
-
-    ``n_chunks`` is ``None`` when the schedule should be the
-    ``lax.scan``/single-tile ``recompute`` form, tiled by ``scan_chunk``
-    rows; otherwise it is the python-unroll count, clamped to a divisor
-    of ``n``.  An explicitly small ``chunk`` is honored in every mode —
-    the caller's transient bound (chunk × V f32) RAISES the chunk count
-    past the mode's minimum when n/k would exceed it — but once that
-    would unroll more than ``_MAX_UNROLL_CHUNKS`` bodies into the HLO
-    (each ~3 large matmuls in the backward), the constant-size scan
-    schedule takes over at the same transient bound (losing a
-    save-mode's residual is fine — at that many chunks the transient is
-    tiny anyway)."""
-    if mode == "recompute":
-        return False, None, chunk
-    save = mode.startswith("save")
-    k = int((mode[len("save"):] if save else mode[len("unroll"):]) or 1)
-    k = max(1, k)
-    while n % k:
-        k -= 1
+    Two chunks where the bound allows: the halved logits transient (1 GB
+    instead of 2 GB at the bench shape) drops peak HBM below the point
+    where XLA auto-rematerializes one convolution fusion per layer —
+    measured 547 -> 518 ms/step on v5e (docs/benchmarks.md) — with none
+    of the while-loop and ``dh``-stacking overhead that made a scanned
+    loop slower than one tile.  A smaller ``chunk`` is honoured: it
+    RAISES the chunk count to the smallest that tiles ``n`` exactly
+    within the bound, unrolled up to ``_MAX_UNROLL_CHUNKS`` bodies and
+    scanned past that."""
+    k = 2 if n % 2 == 0 else 1
     if n // k > chunk:
         k = n // _pick_chunk(n, chunk)
-    if k > _MAX_UNROLL_CHUNKS:
-        if save:
-            import warnings
-            warnings.warn(
-                f"HOROVOD_TPU_XENT_MODE={mode!r}: the chunk bound "
-                f"({chunk} rows over n={n} tokens) needs {k} unrolled "
-                f"bodies, past the limit of {_MAX_UNROLL_CHUNKS}; "
-                "falling back to the scan recompute schedule — the "
-                "save-logits residual is dropped and the backward "
-                "recomputes the head matmul. Raise the chunk bound or "
-                "use fewer chunks to keep the residual.",
-                RuntimeWarning, stacklevel=3)
-        return False, None, min(chunk, n // k)
-    return save, k, chunk
+    return n // k, k <= _MAX_UNROLL_CHUNKS
 
 
 def _pick_chunk(n: int, target: int) -> int:
@@ -204,10 +148,8 @@ def _tile_lse(logits, h_c, w):
                     lambda: shift + jnp.log(total), overflowed)
 
 
-def _chunk_fwd(h_c, w, labels_c, want_logits=False):
-    """One chunk's (loss, lse) from its logits tile; the tile dies here —
-    unless ``want_logits`` asks for it back as a compact bf16 residual
-    (the save schedule)."""
+def _chunk_fwd(h_c, w, labels_c):
+    """One chunk's (loss, lse) from its logits tile; the tile dies here."""
     with jax.named_scope("xent/loss"):
         logits = _logits_tile(h_c, w)
         lse = _tile_lse(logits, h_c, w)
@@ -219,18 +161,14 @@ def _chunk_fwd(h_c, w, labels_c, want_logits=False):
         # branches, so that the conditional takes the tile in and hands a
         # second one out (the plan of ``gpt13b_1chip`` 13.87 -> 15.58 GiB).
         lse, correct = lax.optimization_barrier((lse, correct))
-    if want_logits:
-        return lse - correct, lse, logits.astype(jnp.bfloat16)
     return lse - correct, lse
 
 
-def _chunk_bwd(h_c, w, labels_c, lse_c, g_c, logits_c=None):
+def _chunk_bwd(h_c, w, labels_c, lse_c, g_c):
     """Contract one chunk's ``softmax - onehot`` straight into
-    (dh_c, dw_c); the logits tile is recomputed unless a saved bf16 tile
-    (``logits_c``) is supplied."""
+    (dh_c, dw_c) from its logits tile, made again."""
     with jax.named_scope("xent/grad"):
-        logits = (_logits_tile(h_c, w) if logits_c is None
-                  else logits_c.astype(jnp.float32))
+        logits = _logits_tile(h_c, w)
         p = jnp.exp(logits - lse_c[:, None])
         cols = lax.broadcasted_iota(jnp.int32, p.shape, 1)
         dlogits = ((p - (cols == labels_c[:, None]))
@@ -254,100 +192,62 @@ def fused_softmax_xent(hidden, w, labels, chunk=16384):
         dtype with f32 accumulation).
       w: (d, V) head weight (cast to ``hidden.dtype`` for the matmuls).
       labels: (N,) int32 target ids in [0, V).
-      chunk: target rows per logits tile for the ``lax.scan`` fallback
-        schedule (``HOROVOD_TPU_XENT_MODE=recompute`` with chunk < N);
-        clamped to the largest divisor of N.  The DEFAULT schedule is
-        ``unroll2`` (see :func:`_xent_mode`): python-unrolled 2-way
-        chunking, which halves the logits transient with no loop
-        overhead — at the bench shape that freed enough peak HBM to stop
-        XLA auto-rematerializing a convolution per layer (−29 ms/step on
-        v5e).  A *scanned* loop measured slower than one tile
-        (while-loop + dh stacking, docs/benchmarks.md); the unrolled
-        form is how to shrink the transient.
+      chunk: most rows a logits tile may hold (the transient is chunk x
+        V f32).  The rows are split in two where that allows, else into
+        the fewest equal chunks within it (a divisor of N); see
+        :func:`_schedule`.
 
     Returns: (N,) f32 per-token losses (``lse - logit[label]``) — take
     ``.mean()`` for the usual reduction.
     """
-    # Primal-only call (no VJP): a save-mode residual would be computed
-    # and thrown away — suppress it.
-    loss, _ = _xent_fwd(hidden, w, labels, chunk, _save_ok=False)
-    return loss
+    return _xent_fwd(hidden, w, labels, chunk)[0]
 
 
-def _xent_fwd_impl(hidden, w, labels, chunk):
+def _xent_fwd(hidden, w, labels, chunk):
     n = hidden.shape[0]
-    c = _pick_chunk(n, chunk)
+    c, unrolled = _schedule(n, chunk)
     wc = w.astype(hidden.dtype)
-    if c == n:
-        loss, lse = _chunk_fwd(hidden, wc, labels)
-        return loss, lse
-    hs = hidden.reshape(n // c, c, -1)
-    ls = labels.reshape(n // c, c)
+    if unrolled:
+        parts = [_chunk_fwd(hidden[i:i + c], wc, labels[i:i + c])
+                 for i in range(0, n, c)]
+        loss = jnp.concatenate([p[0] for p in parts])
+        lse = jnp.concatenate([p[1] for p in parts])
+    else:
+        def body(_, hl):
+            h_c, l_c = hl
+            return None, _chunk_fwd(h_c, wc, l_c)
 
-    def body(_, hl):
-        h_c, l_c = hl
-        return None, _chunk_fwd(h_c, wc, l_c)
-
-    _, (loss, lse) = lax.scan(body, None, (hs, ls))
-    return loss.reshape(n), lse.reshape(n)
-
-
-def _xent_fwd(hidden, w, labels, chunk, _save_ok=True):
-    save, k, scan_chunk = _mode_layout(_xent_mode(), hidden.shape[0], chunk)
-    save = save and _save_ok
-    if k is None:
-        loss, lse = _xent_fwd_impl(hidden, w, labels, scan_chunk)
-        return loss, (hidden, w, labels, lse, None)
-    wc = w.astype(hidden.dtype)
-    n = hidden.shape[0]
-    c = n // k
-    parts = [_chunk_fwd(hidden[i * c:(i + 1) * c], wc,
-                        labels[i * c:(i + 1) * c], want_logits=save)
-             for i in range(k)]
-    loss = jnp.concatenate([p[0] for p in parts])
-    lse = jnp.concatenate([p[1] for p in parts])
-    logits_bf16 = (jnp.concatenate([p[2] for p in parts]) if save else None)
-    return loss, (hidden, w, labels, lse, logits_bf16)
+        _, (loss, lse) = lax.scan(
+            body, None, (hidden.reshape(n // c, c, -1),
+                         labels.reshape(n // c, c)))
+        loss, lse = loss.reshape(n), lse.reshape(n)
+    return loss, (hidden, w, labels, lse)
 
 
 def _xent_bwd(chunk, res, g):
-    # Whether logits were saved is read off the residual itself (not the
-    # env), so a mode change between the forward and backward trace
-    # cannot desynchronize the schedule from the saved state.
-    hidden, w, labels, lse, logits_bf16 = res
+    hidden, w, labels, lse = res
     n, d = hidden.shape
+    c, unrolled = _schedule(n, chunk)
     wc = w.astype(hidden.dtype)
     g = g.astype(jnp.float32)
-    _, k, scan_chunk = _mode_layout(_xent_mode(), n, chunk)
-    if k is not None or logits_bf16 is not None:
-        k = k or 1
-        c = n // k
+    if unrolled:
         dhs, dw = [], jnp.zeros_like(w, jnp.float32)
-        for i in range(k):
-            s = slice(i * c, (i + 1) * c)
-            dh_c, dw_c = _chunk_bwd(
-                hidden[s], wc, labels[s], lse[s], g[s],
-                None if logits_bf16 is None else logits_bf16[s])
+        for i in range(0, n, c):
+            s = slice(i, i + c)
+            dh_c, dw_c = _chunk_bwd(hidden[s], wc, labels[s], lse[s], g[s])
             dhs.append(dh_c)
             dw = dw + dw_c
-        return (jnp.concatenate(dhs).astype(hidden.dtype),
-                dw.astype(w.dtype), None)
-    c = _pick_chunk(n, scan_chunk)
-    if c == n:
-        dh, dw = _chunk_bwd(hidden, wc, labels, lse, g)
+        dh = jnp.concatenate(dhs)
     else:
-        hs = hidden.reshape(n // c, c, d)
-        ls = labels.reshape(n // c, c)
-        lses = lse.reshape(n // c, c)
-        gs = g.reshape(n // c, c)
-
         def body(dw_acc, args):
             h_c, l_c, lse_c, g_c = args
             dh_c, dw_c = _chunk_bwd(h_c, wc, l_c, lse_c, g_c)
             return dw_acc + dw_c, dh_c
 
-        dw, dhs = lax.scan(body, jnp.zeros_like(w, jnp.float32),
-                           (hs, ls, lses, gs))
+        dw, dhs = lax.scan(
+            body, jnp.zeros_like(w, jnp.float32),
+            (hidden.reshape(n // c, c, d), labels.reshape(n // c, c),
+             lse.reshape(n // c, c), g.reshape(n // c, c)))
         dh = dhs.reshape(n, d)
     return dh.astype(hidden.dtype), dw.astype(w.dtype), None
 

@@ -105,99 +105,6 @@ class TestPackedLayout:
             np.testing.assert_allclose(np.asarray(g), np.asarray(w),
                                        rtol=2e-4, atol=2e-4)
 
-    def test_fullunroll_bwd_ab_matches_oracle(self, hvd, monkeypatch):
-        """HOROVOD_TPU_FLASH_BWD=fullunroll selects the fused one-pass
-        backward (5 matmuls/pair, SSA, (B, H) grid) — oracle-exact
-        gradients through the packed path."""
-        monkeypatch.setenv("HOROVOD_TPU_FLASH_BWD", "fullunroll")
-        q, k, v = make_qkv(jax.random.PRNGKey(27), 2, 32, 2, 128)
-
-        def loss(q, k, v):
-            return (flash_attention(q, k, v, causal=True, block_q=8,
-                                    block_k=8, interpret=True) ** 2).sum()
-
-        def loss_full(q, k, v):
-            return (full_attention(q, k, v, causal=True) ** 2).sum()
-
-        got = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
-        want = jax.grad(loss_full, argnums=(0, 1, 2))(q, k, v)
-        for g, w in zip(got, want):
-            np.testing.assert_allclose(np.asarray(g), np.asarray(w),
-                                       rtol=2e-4, atol=2e-4)
-
-    def test_fullunroll_bwd_ab_padded_seq_len(self, hvd, monkeypatch):
-        monkeypatch.setenv("HOROVOD_TPU_FLASH_BWD", "fullunroll")
-        T, T_pad = 24, 32
-        q, k, v = make_qkv(jax.random.PRNGKey(28), 1, T, 2, 128)
-        pad = [(0, 0), (0, T_pad - T), (0, 0), (0, 0)]
-
-        def loss(q, k, v):
-            out = flash_attention(
-                jnp.pad(q, pad), jnp.pad(k, pad), jnp.pad(v, pad),
-                causal=True, block_q=8, block_k=8, interpret=True,
-                seq_len=T)
-            return (out[:, :T] ** 2).sum()
-
-        def loss_full(q, k, v):
-            return (full_attention(q, k, v, causal=True) ** 2).sum()
-
-        got = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
-        want = jax.grad(loss_full, argnums=(0, 1, 2))(q, k, v)
-        for g, w in zip(got, want):
-            np.testing.assert_allclose(np.asarray(g), np.asarray(w),
-                                       rtol=2e-4, atol=2e-4)
-
-    def test_merged_bwd_ab_matches_oracle(self, hvd, monkeypatch):
-        """HOROVOD_TPU_FLASH_PACKED_BWD=0 routes the packed backward
-        through the contiguous merged-layout kernel pair (the recorded
-        A/B in docs/benchmarks.md) — its pick/unpick head-range and
-        B*H ordering must produce oracle-exact gradients."""
-        monkeypatch.setenv("HOROVOD_TPU_FLASH_PACKED_BWD", "0")
-        q, k, v = make_qkv(jax.random.PRNGKey(24), 2, 32, 2, 128)
-
-        def loss(q, k, v):
-            return (flash_attention(q, k, v, causal=True, block_q=8,
-                                    block_k=8, interpret=True) ** 2).sum()
-
-        def loss_full(q, k, v):
-            return (full_attention(q, k, v, causal=True) ** 2).sum()
-
-        got = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
-        want = jax.grad(loss_full, argnums=(0, 1, 2))(q, k, v)
-        for g, w in zip(got, want):
-            np.testing.assert_allclose(np.asarray(g), np.asarray(w),
-                                       rtol=2e-4, atol=2e-4)
-
-    def test_merged_bwd_ab_qkv_proj(self, hvd, monkeypatch):
-        """Same A/B through flash_qkv_proj (head_base offsets into the
-        packed (B, T, 3C) tensor are the layout-sensitive part)."""
-        from horovod_tpu.ops.flash_attention import flash_qkv_proj
-
-        monkeypatch.setenv("HOROVOD_TPU_FLASH_PACKED_BWD", "0")
-        B, T, H, D = 1, 24, 2, 128
-        C = H * D
-        x = jax.random.normal(jax.random.PRNGKey(25), (B, T, C))
-        w = jax.random.normal(jax.random.PRNGKey(26), (C, 3 * C)) * 0.1
-
-        def loss(x, w):
-            return (flash_qkv_proj(x, w, H, causal=True, block_q=8,
-                                   block_k=8, interpret=True) ** 2).sum()
-
-        def loss_full(x, w):
-            qkv = x @ w
-            q, k, v = (t.reshape(B, T, H, D)
-                       for t in jnp.split(qkv, 3, axis=-1))
-            return (full_attention(q, k, v, causal=True) ** 2).sum()
-
-        got = jax.grad(loss, argnums=(0, 1))(x, w)
-        want = jax.grad(loss_full, argnums=(0, 1))(x, w)
-        # Slightly wider than the sibling tests: the projection matmul
-        # re-runs inside the op, so f32 reassociation differs from the
-        # oracle's separate matmul on a handful of elements.
-        for g, w_ in zip(got, want):
-            np.testing.assert_allclose(np.asarray(g), np.asarray(w_),
-                                       rtol=1e-3, atol=5e-4)
-
     def test_padded_seq_len_grads(self, hvd):
         T, T_pad = 24, 32
         q, k, v = make_qkv(jax.random.PRNGKey(23), 1, T, 2, 128)
@@ -364,16 +271,16 @@ class TestAutoBlock:
 
 
 class TestPallasBackward:
+    """D off the lane width: the merged layout's grid forward and
+    per-head pair."""
+
     @pytest.mark.parametrize("causal", [True, False])
-    @pytest.mark.parametrize("bwd_impl",
-                             ["pallas_fused", "pallas_split", "xla"])
-    def test_grads_match_dense_oracle(self, hvd, causal, bwd_impl):
+    def test_grads_match_dense_oracle(self, hvd, causal):
         q, k, v = make_qkv(jax.random.PRNGKey(11), 2, 64, 2, 16)
 
         def loss(q, k, v):
             out = flash_attention(q, k, v, causal=causal, block_q=16,
-                                  block_k=16, interpret=True,
-                                  bwd_impl=bwd_impl)
+                                  block_k=16, interpret=True)
             return (out ** 2).sum()
 
         def loss_full(q, k, v):
@@ -405,14 +312,12 @@ class TestPallasBackward:
                 np.asarray(g, np.float32), np.asarray(w, np.float32),
                 rtol=1e-2, atol=1e-2)
 
-    @pytest.mark.parametrize("bwd_impl", ["pallas_fused", "pallas_split"])
-    def test_uneven_blocks_pallas_bwd(self, hvd, bwd_impl):
+    def test_uneven_blocks_pallas_bwd(self, hvd):
         q, k, v = make_qkv(jax.random.PRNGKey(13), 1, 48, 2, 8)
 
         def loss(q, k, v):
             return (flash_attention(q, k, v, causal=True, block_q=16,
-                                    block_k=8, interpret=True,
-                                    bwd_impl=bwd_impl) ** 2).sum()
+                                    block_k=8, interpret=True) ** 2).sum()
 
         def loss_full(q, k, v):
             return (full_attention(q, k, v, causal=True) ** 2).sum()
@@ -423,11 +328,9 @@ class TestPallasBackward:
             np.testing.assert_allclose(np.asarray(g), np.asarray(w),
                                        rtol=1e-5, atol=1e-5)
 
-    @pytest.mark.parametrize("bwd_impl", ["pallas_fused", "pallas_split"])
-    def test_padded_seq_len_grads(self, hvd, bwd_impl):
-        """Zero-padded inputs with seq_len masking: fused and split
-        backward must both mask the padding tail (the fused kernel's
-        unconditional dq write must flush zeros, not stale scratch)."""
+    def test_padded_seq_len_grads(self, hvd):
+        """Zero-padded inputs with seq_len masking: the backward pair
+        must mask the padding tail."""
         T, T_pad = 40, 64
         q, k, v = make_qkv(jax.random.PRNGKey(14), 1, T, 2, 8)
         pad = [(0, 0), (0, T_pad - T), (0, 0), (0, 0)]
@@ -436,7 +339,7 @@ class TestPallasBackward:
             out = flash_attention(
                 jnp.pad(q, pad), jnp.pad(k, pad), jnp.pad(v, pad),
                 causal=True, block_q=16, block_k=16, interpret=True,
-                bwd_impl=bwd_impl, seq_len=T)
+                seq_len=T)
             return (out[:, :T] ** 2).sum()
 
         def loss_full(q, k, v):
@@ -491,98 +394,195 @@ class TestFlashUnderShardMap:
         assert losses[-1] < losses[0]
 
 
+def packed_problem(seed, B, T, H, D, qkv, seq_len=None):
+    """Operands of the packed backward drivers as the custom-VJP rules
+    hand them over: (q, k, v, o, lse, do), head bases."""
+    from horovod_tpu.ops import flash_attention as fa
+
+    scale = 1.0 / D ** 0.5
+    ks = jax.random.split(jax.random.PRNGKey(seed), 2)
+    if qkv:
+        base = (0, H, 2 * H)
+        q = k = v = jax.random.normal(ks[0], (B, T, 3 * H * D))
+    else:
+        base = (0, 0, 0)
+        q, k, v = (x.reshape(B, T, H * D)
+                   for x in make_qkv(ks[0], B, T, H, D))
+    plan = fa._Plan("grid", 0, 0, "per_head", 0)
+    o, lse = fa._fwd_packed(q, k, v, H, D, plan, scale=scale, causal=True,
+                            block_q=8, block_k=8, interpret=True,
+                            seq_len=seq_len, head_base=base)
+    do = jax.random.normal(ks[1], o.shape)
+    return (q, k, v, o, lse, do), base, plan, scale
+
+
 class TestHeadGroupBwd:
-    """HOROVOD_TPU_FLASH_BWD_GROUP=G routes the packed backward through
-    the head-group blocked kernel pair (contiguous group*D-wide tiles,
-    VERDICT r4 weak #3) — gradients must be oracle-exact for every
-    layout the packed path serves."""
+    """The pair blocked over adjacent heads (contiguous group*D-wide
+    tiles) against the per-head pair, which the classes above hold to the
+    dense oracle: per-head math is identical, so the gradients must match
+    EXACTLY.  _plan selects the grouped pair only at 1024² blocks, which
+    no interpreted test can afford, so the driver is called directly at
+    the small shapes."""
 
-    def test_grouped_matches_oracle_flash_attention(self, hvd, monkeypatch):
-        monkeypatch.setenv("HOROVOD_TPU_FLASH_BWD_GROUP", "2")
-        q, k, v = make_qkv(jax.random.PRNGKey(41), 2, 32, 4, 128)
+    @pytest.mark.parametrize("qkv,seq_len,H,group", [
+        (False, None, 4, 2), (False, 24, 2, 2), (True, None, 4, 2),
+        (True, 24, 4, 2), (True, None, 4, 4)],
+        ids=["qkv_apart", "qkv_apart-padded", "fused_qkv",
+             "fused_qkv-padded", "fused_qkv-group4"])
+    def test_grouped_matches_per_head_exactly(self, hvd, qkv, seq_len, H,
+                                              group):
+        from horovod_tpu.ops import flash_attention as fa
 
-        def loss(q, k, v):
-            return (flash_attention(q, k, v, causal=True, block_q=8,
-                                    block_k=8, interpret=True) ** 2).sum()
+        ops, base, plan, scale = packed_problem(41, 2, 32, H, 128, qkv,
+                                                seq_len)
+        kw = dict(scale=scale, causal=True, block_q=8, block_k=8,
+                  interpret=True, seq_len=seq_len, head_base=base)
+        want = fa._bwd_pallas_packed(*ops, H, 128, plan, **kw)
+        got = fa._bwd_pallas_packed_grouped(*ops, H, 128, group, **kw)
+        for g, w in zip(got, want):
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+    @pytest.mark.parametrize("seq_len", [None, 24], ids=["whole", "padded"])
+    def test_grouped_matches_oracle(self, hvd, seq_len):
+        """And against ``full_attention`` itself."""
+        from horovod_tpu.ops import flash_attention as fa
+
+        B, T, H, D = 1, 32, 2, 128
+        (q, k, v, o, lse, _), base, _, scale = packed_problem(
+            45, B, T, H, D, False, seq_len)
+        n = seq_len or T
 
         def loss_full(q, k, v):
-            return (full_attention(q, k, v, causal=True) ** 2).sum()
+            return (full_attention(*(x.reshape(B, T, H, D)[:, :n]
+                                     for x in (q, k, v)),
+                                   causal=True) ** 2).sum()
 
-        got = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
         want = jax.grad(loss_full, argnums=(0, 1, 2))(q, k, v)
+        valid = (jnp.arange(T) < n)[None, :, None]
+        do = jnp.where(valid, 2 * o, 0.0)      # d(sum o^2) on real rows
+        got = fa._bwd_pallas_packed_grouped(
+            q, k, v, o, lse, do, H, D, 2, scale=scale, causal=True,
+            block_q=8, block_k=8, interpret=True, seq_len=seq_len,
+            head_base=base)
         for g, w in zip(got, want):
             np.testing.assert_allclose(np.asarray(g), np.asarray(w),
                                        rtol=2e-4, atol=2e-4)
 
-    def test_grouped_matches_ungrouped_qkv_proj(self, hvd, monkeypatch):
-        """Fused-qkv head bases (0, H, 2H) with group=2: the grouped
-        index maps divide the bases by the group size.  Per-head math is
-        identical to the per-head packed kernels, so the gradients must
-        match them EXACTLY (the per-head path is itself oracle-checked
-        in test_merged_bwd_ab_qkv_proj)."""
-        from horovod_tpu.ops.flash_attention import flash_qkv_proj
 
-        B, T, H, D = 1, 24, 4, 128
-        C = H * D
-        x = jax.random.normal(jax.random.PRNGKey(42), (B, T, C))
-        w = jax.random.normal(jax.random.PRNGKey(43), (C, 3 * C)) * 0.1
+# One row of the selection table: what the op observes, and what _plan
+# must answer — each answer read off the conditionals of the parent of
+# PR 27 (where forms were chosen in three places and five environment
+# variables), by tracing its ops at these shapes.
+def observed(T, D=128, H=16, itemsize=2, blocks=1024, base=None,
+             interpret=False, manual_axes=False, vmem_headroom=True):
+    blocks = min(blocks, T)
+    return dict(T=T, D=D, H=H, head_base=base or (0, H, 2 * H),
+                itemsize=itemsize, block_q=blocks, block_k=blocks,
+                bwd_block_q=blocks, bwd_block_k=blocks, interpret=interpret,
+                manual_axes=manual_axes, vmem_headroom=vmem_headroom)
 
-        def loss(x, w):
-            return (flash_qkv_proj(x, w, H, causal=True, block_q=8,
-                                   block_k=8, interpret=True) ** 2).sum()
 
-        monkeypatch.setenv("HOROVOD_TPU_FLASH_BWD_GROUP", "1")
-        want = jax.grad(loss, argnums=(0, 1))(x, w)
-        monkeypatch.setenv("HOROVOD_TPU_FLASH_BWD_GROUP", "2")
-        got = jax.grad(loss, argnums=(0, 1))(x, w)
-        for g, w_ in zip(got, want):
-            np.testing.assert_array_equal(np.asarray(g), np.asarray(w_))
+FULL, KV, GRID = "fullunroll", "unrollkv", "grid"
+PLAN_TABLE = {
+    # The two shapes every benchmark cell runs.
+    "cell_T2048": (observed(2048), (FULL, 512, 0, "grouped", 32)),
+    "cell_T4096": (observed(4096), (FULL, 512, 64, "grouped", 32)),
+    # A v2/v3 or a TPU whose kind cannot be read: no raised budget.
+    "T4096_no_headroom": (observed(4096, vmem_headroom=False),
+                          (KV, 0, 0, "per_head", 0)),
+    "T2048_no_headroom": (observed(2048, vmem_headroom=False),
+                          (FULL, 512, 0, "per_head", 0)),
+    # Past a 1 MB K/V row (T 4096 at D 128 bf16) only the grid streams.
+    "T8192": (observed(8192), (GRID, 0, 0, "grouped", 32)),
+    "T32768": (observed(32768), (GRID, 0, 0, "grouped", 32)),
+    "T4096_f32": (observed(4096, itemsize=4), (GRID, 0, 0, "grouped", 32)),
+    # Heads off the lane width, merged into the batch: rows of one head.
+    "D64": (observed(2048, D=64, H=1, base=(0, 0, 0)),
+            (GRID, 0, 0, "per_head", 0)),
+    "D256": (observed(2048, D=256, H=8), (FULL, 512, 0, "per_head", 0)),
+    # The grouped pair wants an even head count and even head bases ...
+    "odd_H": (observed(2048, H=15), (FULL, 512, 0, "per_head", 0)),
+    "odd_head_base": (observed(2048, H=2, base=(0, 1, 2)),
+                      (FULL, 512, 0, "per_head", 0)),
+    # ... and 1024² blocks.
+    "blocks_512": (observed(2048, blocks=512),
+                   (FULL, 512, 0, "per_head", 0)),
+    "T1024_one_block": (observed(1024), (FULL, 512, 0, "grouped", 32)),
+    # A tile that does not divide T; too many small blocks to unroll.
+    "T2304_blocks_768": (observed(2304, blocks=768),
+                         (KV, 0, 0, "per_head", 0)),
+    "T4096_blocks_8": (observed(4096, blocks=8),
+                       (GRID, 0, 0, "per_head", 0)),
+    # Interpreted (CPU tests): alone, and under shard_map.
+    "interpret": (observed(64, H=2, itemsize=4, blocks=16, interpret=True),
+                  (FULL, 16, 0, "per_head", 0)),
+    "interpret_shard_map": (
+        observed(64, H=2, itemsize=4, blocks=16, interpret=True,
+                 manual_axes=True), (KV, 0, 0, "per_head", 0)),
+    "compiled_shard_map": (observed(4096, manual_axes=True),
+                           (FULL, 512, 64, "grouped", 32)),
+}
 
-    def test_nondividing_group_falls_back(self, hvd, monkeypatch):
-        """group=3 with H=2 cannot tile; the per-head path must serve
-        the gradient unchanged rather than erroring."""
-        monkeypatch.setenv("HOROVOD_TPU_FLASH_BWD_GROUP", "3")
-        q, k, v = make_qkv(jax.random.PRNGKey(44), 1, 16, 2, 128)
 
-        def loss(q, k, v):
-            return (flash_attention(q, k, v, causal=True, block_q=8,
-                                    block_k=8, interpret=True) ** 2).sum()
+class TestPlan:
+    """The one function that chooses the forward form, the backward pair
+    and their VMEM limits: a pure table, no kernel, no device."""
 
-        def loss_full(q, k, v):
-            return (full_attention(q, k, v, causal=True) ** 2).sum()
+    @pytest.mark.parametrize("case", sorted(PLAN_TABLE))
+    def test_plan_table(self, case):
+        from horovod_tpu.ops import flash_attention as fa
 
-        got = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
-        want = jax.grad(loss_full, argnums=(0, 1, 2))(q, k, v)
-        for g, w in zip(got, want):
-            np.testing.assert_allclose(np.asarray(g), np.asarray(w),
-                                       rtol=2e-4, atol=2e-4)
+        seen, want = PLAN_TABLE[case]
+        assert fa._plan(**seen) == fa._Plan(*want)
 
-    def test_padded_seq_len_grouped(self, hvd, monkeypatch):
-        monkeypatch.setenv("HOROVOD_TPU_FLASH_BWD_GROUP", "2")
-        T, T_pad = 24, 32
-        q, k, v = make_qkv(jax.random.PRNGKey(45), 1, T, 2, 128)
-        pad = [(0, 0), (0, T_pad - T), (0, 0), (0, 0)]
+    def test_bwd_impl_is_refused(self, hvd):
+        q, k, v = make_qkv(jax.random.PRNGKey(0), 1, 16, 1, 8)
+        with pytest.raises(TypeError, match="bwd_impl"):
+            flash_attention(q, k, v, interpret=True, bwd_impl="xla")
 
-        def loss(q, k, v):
-            out = flash_attention(
-                jnp.pad(q, pad), jnp.pad(k, pad), jnp.pad(v, pad),
-                causal=True, block_q=8, block_k=8, interpret=True,
-                seq_len=T)
-            return (out[:, :T] ** 2).sum()
+    def test_module_reads_no_environment(self):
+        import inspect
 
-        def loss_full(q, k, v):
-            return (full_attention(q, k, v, causal=True) ** 2).sum()
+        from horovod_tpu.ops import flash_attention as fa
 
-        got = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
-        want = jax.grad(loss_full, argnums=(0, 1, 2))(q, k, v)
-        for g, w in zip(got, want):
-            np.testing.assert_allclose(np.asarray(g), np.asarray(w),
-                                       rtol=2e-4, atol=2e-4)
+        source = inspect.getsource(fa)
+        assert "environ" not in source and "getenv" not in source
+        assert "HOROVOD_TPU_" not in source
+
+    @pytest.mark.parametrize("entry", ["flash_attention", "merged_layout",
+                                       "flash_attention_qkv",
+                                       "flash_qkv_proj"])
+    def test_every_entry_point_asks_the_plan(self, hvd, monkeypatch, entry):
+        """Forward rule and backward rule of each of the four custom-VJP
+        functions go through _plan, with what they observe."""
+        from horovod_tpu.ops import flash_attention as fa
+
+        asked = []
+        plan = fa._plan
+        monkeypatch.setattr(
+            fa, "_plan", lambda **seen: asked.append(seen) or plan(**seen))
+        B, T, H, D = 1, 16, 2, (8 if entry == "merged_layout" else 128)
+        x = jax.random.normal(jax.random.PRNGKey(3), (B, T, 3 * H * D))
+        kw = dict(block_q=8, block_k=8, interpret=True)
+        if entry == "flash_qkv_proj":
+            w = jnp.eye(H * D, 3 * H * D)
+            f = lambda x: fa.flash_qkv_proj(x[..., :H * D], w, H, **kw)
+        elif entry == "flash_attention_qkv":
+            f = lambda x: fa.flash_attention_qkv(x, H, **kw)
+        else:
+            f = lambda x: fa.flash_attention(
+                *(t.reshape(B, T, H, D) for t in jnp.split(x, 3, -1)), **kw)
+        jax.grad(lambda x: (f(x) ** 2).sum())(x)
+        assert len(asked) == 2                    # forward rule, backward
+        base = (0, H, 2 * H) if "qkv" in entry else (0, 0, 0)
+        for seen in asked:
+            assert (seen["T"], seen["D"], seen["head_base"]) == (T, D, base)
+            assert seen["interpret"] and not seen["manual_axes"]
 
 
 class TestVmemGates:
-    """Budget-resolution policy for the raised flash VMEM budgets —
-    pure env/probe logic, no kernel launch."""
+    """Whether the device backs a scoped-VMEM budget above Mosaic's
+    default — pure probe logic, no kernel launch."""
 
     class _Dev:
         def __init__(self, platform, kind):
@@ -614,37 +614,18 @@ class TestVmemGates:
         assert self._probe(monkeypatch, self._Dev("tpu", "TPU v4"))
         assert self._probe(monkeypatch, self._Dev("cpu", ""))
 
-    def test_fwd_budget_own_knob_rules(self, monkeypatch):
+    @pytest.mark.parametrize("kind,fwd,bwd", [
+        ("TPU v5 lite", "fullunroll", "grouped"),
+        ("TPU v3", "unrollkv", "per_head"),
+        ("", "unrollkv", "per_head")])
+    def test_plan_follows_the_device_kind(self, monkeypatch, kind, fwd,
+                                          bwd):
+        """What the rules hand _plan at T 4096 on each kind of TPU."""
         from horovod_tpu.ops import flash_attention as fa
-        monkeypatch.setenv("HOROVOD_TPU_FLASH_FWD_VMEM_MB", "128")
-        monkeypatch.setenv("HOROVOD_TPU_FLASH_VMEM_MB", "32")
-        assert fa._flash_fwd_vmem_mb() == 128
 
-    def test_fwd_budget_shared_substandard_warns(self, monkeypatch):
-        """Pinning the shared knob to its documented default (32, the
-        grouped-backward figure) stands the fully-unrolled forward down
-        past T=2048 — that side effect must be audible."""
-        from horovod_tpu.ops import flash_attention as fa
-        monkeypatch.delenv("HOROVOD_TPU_FLASH_FWD_VMEM_MB", raising=False)
-        monkeypatch.setenv("HOROVOD_TPU_FLASH_VMEM_MB", "32")
-        with pytest.warns(RuntimeWarning, match="stands down"):
-            assert fa._flash_fwd_vmem_mb() == 32
-
-    def test_fwd_budget_explicit_zero_is_silent(self, monkeypatch):
-        import warnings
-
-        from horovod_tpu.ops import flash_attention as fa
-        monkeypatch.delenv("HOROVOD_TPU_FLASH_FWD_VMEM_MB", raising=False)
-        monkeypatch.setenv("HOROVOD_TPU_FLASH_VMEM_MB", "0")
-        with warnings.catch_warnings():
-            warnings.simplefilter("error")
-            assert fa._flash_fwd_vmem_mb() == 0
-
-    def test_fwd_budget_auto_grant_follows_headroom(self, monkeypatch):
-        from horovod_tpu.ops import flash_attention as fa
-        monkeypatch.delenv("HOROVOD_TPU_FLASH_FWD_VMEM_MB", raising=False)
-        monkeypatch.delenv("HOROVOD_TPU_FLASH_VMEM_MB", raising=False)
-        monkeypatch.setattr(fa, "_vmem_headroom_ok", lambda: True)
-        assert fa._flash_fwd_vmem_mb() == fa._FWD_MIN_VMEM_MB
-        monkeypatch.setattr(fa, "_vmem_headroom_ok", lambda: False)
-        assert fa._flash_fwd_vmem_mb() == 0
+        monkeypatch.setattr(fa.jax, "local_devices",
+                            lambda: [self._Dev("tpu", kind)])
+        qkv = jax.ShapeDtypeStruct((1, 4096, 3 * 16 * 128), jnp.bfloat16)
+        plan = fa._plan_for(qkv, 16, 128, (0, 16, 32), 1024, 1024, 1024,
+                            1024, False)
+        assert (plan.fwd, plan.bwd) == (fwd, bwd)

@@ -16,12 +16,9 @@ def naive_loss(h, w, labels):
 
 
 class TestFusedXent:
-    @pytest.mark.parametrize("chunk", [4096, 8, 5])
-    def test_matches_reference(self, chunk, monkeypatch):
-        # Pin the recompute mode so small `chunk` values exercise the
-        # lax.scan tiling (the default unroll2 mode honors chunk by
-        # raising its chunk count instead, covered separately below).
-        monkeypatch.setenv("HOROVOD_TPU_XENT_MODE", "recompute")
+    # 2, 5 and 8 unrolled chunks of the 40 rows, then 10 scanned.
+    @pytest.mark.parametrize("chunk", [4096, 8, 5, 4])
+    def test_matches_reference(self, chunk):
         rng = np.random.RandomState(0)
         n, d, v = 40, 16, 97
         h = jnp.asarray(rng.randn(n, d), jnp.float32)
@@ -32,9 +29,9 @@ class TestFusedXent:
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=1e-5, atol=1e-5)
 
-    @pytest.mark.parametrize("chunk", [4096, 10])
-    def test_grads_match_reference(self, chunk, monkeypatch):
-        monkeypatch.setenv("HOROVOD_TPU_XENT_MODE", "recompute")
+    # 2 and 3 unrolled chunks of the 30 rows, then 15 scanned.
+    @pytest.mark.parametrize("chunk", [4096, 10, 2])
+    def test_grads_match_reference(self, chunk):
         rng = np.random.RandomState(1)
         n, d, v = 30, 8, 64
         h = jnp.asarray(rng.randn(n, d), jnp.float32)
@@ -66,43 +63,37 @@ class TestFusedXent:
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=3e-2, atol=3e-2)
 
-    @pytest.mark.parametrize("mode", ["recompute", "save", "save2",
-                                      "unroll2", "unroll3", "unroll16"])
-    def test_schedule_modes_match_reference(self, mode, monkeypatch):
-        """Every HOROVOD_TPU_XENT_MODE schedule (default unroll2, the
-        save/saveK residual forms, the single-tile recompute) computes
-        identical loss and gradients; N=30 also exercises the divisor
-        clamping for K that does not divide N (unroll3 -> 3 | 30)."""
-        monkeypatch.setenv("HOROVOD_TPU_XENT_MODE", mode)
+    @pytest.mark.parametrize("n,chunk,chunks", [
+        (15, 16384, 1), (30, 16384, 2), (30, 10, 3), (32, 8, 4),
+        (32, 4, 8), (32, 2, 16)])
+    def test_every_chunk_count_matches_reference(self, n, chunk, chunks):
+        """One tile (an odd row count), the default two, more because
+        ``chunk`` asks for them, the last unrolled count and the scan:
+        the same loss and gradients."""
+        from horovod_tpu.ops import losses
+
+        rows, unrolled = losses._schedule(n, chunk)
+        assert n // rows == chunks
+        assert unrolled == (chunks <= losses._MAX_UNROLL_CHUNKS)
         rng = np.random.RandomState(5)
-        n, d, v = 30, 8, 64
+        d, v = 8, 64
         h = jnp.asarray(rng.randn(n, d), jnp.float32)
         w = jnp.asarray(rng.randn(d, v) * 0.1, jnp.float32)
         labels = jnp.asarray(rng.randint(0, v, n), jnp.int32)
 
         def loss_fused(h, w):
-            return fused_softmax_xent(h, w, labels).mean()
+            return fused_softmax_xent(h, w, labels, chunk).mean()
 
         def loss_naive(h, w):
             return naive_loss(h, w, labels).mean()
 
         got_l, got_g = jax.value_and_grad(loss_fused, argnums=(0, 1))(h, w)
         want_l, want_g = jax.value_and_grad(loss_naive, argnums=(0, 1))(h, w)
-        # An explicit small chunk must be honored in every mode (the
-        # caller's transient bound raises the chunk count): same values.
-        def loss_chunked(h, w):
-            return fused_softmax_xent(h, w, labels, 10).mean()
-        got_l2 = loss_chunked(h, w)
-        np.testing.assert_allclose(np.asarray(got_l2), np.asarray(want_l),
-                                   rtol=1e-4, atol=1e-5)
-        # save modes round the stored logits to bf16; grads tolerance
-        # widens accordingly.
-        tol = dict(rtol=2e-2, atol=2e-3) if mode.startswith("save") \
-            else dict(rtol=1e-5, atol=1e-6)
         np.testing.assert_allclose(np.asarray(got_l), np.asarray(want_l),
                                    rtol=1e-4, atol=1e-5)
         for g, wv in zip(got_g, want_g):
-            np.testing.assert_allclose(np.asarray(g), np.asarray(wv), **tol)
+            np.testing.assert_allclose(np.asarray(g), np.asarray(wv),
+                                       rtol=1e-5, atol=1e-6)
 
     def test_model_hidden_path_matches_full_apply(self):
         """TransformerLM(return_hidden=True) + fused head == the model's
@@ -131,37 +122,42 @@ class TestFusedXent:
                                    rtol=1e-5, atol=1e-5)
 
 
-class TestModeLayoutDegrade:
-    def test_save_degrade_to_scan_warns(self):
-        """A saveK request whose chunk bound forces more than
-        _MAX_UNROLL_CHUNKS unrolled bodies degrades to the scan
-        recompute schedule — audibly, since the caller opted into
-        keeping the logits residual and is not getting it."""
-        from horovod_tpu.ops import losses
-
-        # n=4096 at chunk=64 needs 64 bodies > _MAX_UNROLL_CHUNKS.
-        with pytest.warns(RuntimeWarning,
-                          match="scan recompute.*residual is dropped"):
-            save, k, scan_chunk = losses._mode_layout("save2", 4096, 64)
-        assert (save, k) == (False, None)
-
-    def test_unroll_degrade_stays_silent(self):
-        """The same degrade from an unrollK mode loses nothing the user
-        asked for (no residual in that mode) — no warning."""
+class TestSchedule:
+    @pytest.mark.parametrize("n,chunk,rows,unrolled", [
+        # Unrolled under the limit: the two chunks of every LM cell.
+        (16384, 16384, 8192, True),
+        (4096, 2048, 2048, True),
+        # Scanned above it, at the same transient bound.
+        (4096, 64, 64, False),
+        # An explicitly small chunk is honoured: four bodies, not two.
+        (4096, 1024, 1024, True),
+        # The last unrolled count, and the first scanned.
+        (4096, 512, 512, True),
+        (4608, 512, 512, False),
+        # An odd row count is one tile while the bound allows.
+        (4097, 16384, 4097, True),
+        # The largest divisor within the bound, not the bound itself.
+        (30, 8, 6, True),
+    ])
+    def test_schedule_follows_n_and_chunk(self, n, chunk, rows, unrolled):
+        """Which schedule for which ``(n, chunk)``: nothing else decides
+        (no mode, no environment), and no warning comes with either."""
         import warnings
 
         from horovod_tpu.ops import losses
 
         with warnings.catch_warnings():
             warnings.simplefilter("error")
-            save, k, scan_chunk = losses._mode_layout("unroll2", 4096, 64)
-        assert (save, k) == (False, None)
+            assert losses._schedule(n, chunk) == (rows, unrolled)
 
-    def test_save_within_limit_keeps_residual(self):
+    def test_module_reads_no_environment(self):
+        import inspect
+
         from horovod_tpu.ops import losses
 
-        save, k, _ = losses._mode_layout("save2", 4096, 2048)
-        assert save and k == 2
+        source = inspect.getsource(losses)
+        assert "environ" not in source and "getenv" not in source
+        assert "XENT_MODE" not in source
 
 
 # ------------------------------------------- the default schedule's rules
@@ -206,19 +202,16 @@ def assert_trees_close(got, want, **tol):
 
 class TestGradsUnderEveryReduction:
     @pytest.mark.parametrize("reduction", sorted(REDUCTIONS))
-    @pytest.mark.parametrize("mode", ["unroll2", "unroll3", "recompute",
-                                      "save2"])
-    def test_grads_match_reference(self, mode, reduction, monkeypatch):
-        monkeypatch.setenv("HOROVOD_TPU_XENT_MODE", mode)
-        h, w, labels, mask = head_problem()
-        fused, naive = fused_and_naive(reduction, labels)
+    # The default two chunks, three, one tile (odd rows), twelve scanned.
+    @pytest.mark.parametrize("n,chunk", [(24, 16384), (24, 8), (25, 16384),
+                                         (24, 2)])
+    def test_grads_match_reference(self, n, chunk, reduction):
+        h, w, labels, mask = head_problem(n=n)
+        fused, naive = fused_and_naive(reduction, labels, chunk)
         got = jax.jit(jax.value_and_grad(fused, argnums=(0, 1, 2)))(
             h, w, mask)
         want = jax.value_and_grad(naive, argnums=(0, 1, 2))(h, w, mask)
-        # save modes round the stored logits to bf16.
-        tol = (dict(rtol=2e-2, atol=2e-3) if mode.startswith("save")
-               else dict(rtol=1e-5, atol=1e-6))
-        assert_trees_close(got, want, **tol)
+        assert_trees_close(got, want, rtol=1e-5, atol=1e-6)
 
     @pytest.mark.parametrize("reduction", sorted(REDUCTIONS))
     def test_bf16_activations_grads(self, reduction):
@@ -249,31 +242,30 @@ class TestGradsUnderEveryReduction:
                             in_axes=(0, None, None))(hs, w, mask)
         assert_trees_close(got, want, rtol=1e-5, atol=1e-6)
 
-    @pytest.mark.parametrize("mode,chunks,differentiated", [
+    @pytest.mark.parametrize("n,chunk,bodies,differentiated", [
         # As written, a chunk's backward makes the tile again: logits in
         # the forward rule, then logits, dh, dW.  (Compiled where the
         # backward follows the forward, XLA merges the two logits
         # matmuls: PERF.md section 6, PR 26.)
-        ("unroll2", 2, 8),
-        ("unroll4", 4, 16),
-        ("recompute", 1, 4),
-        # The saved bf16 tile stands in for the recompute.
-        ("save2", 2, 6),
+        (32, 16384, 2, 8),
+        (32, 8, 4, 16),
+        (33, 16384, 1, 4),
+        # Sixteen chunks are scanned: one body in the text of each rule.
+        (32, 2, 1, 4),
     ])
-    def test_head_matmuls_in_the_jaxpr(self, mode, chunks, differentiated,
-                                       monkeypatch):
-        monkeypatch.setenv("HOROVOD_TPU_XENT_MODE", mode)
-        n, v = 32, 200
+    def test_head_matmuls_in_the_jaxpr(self, n, chunk, bodies,
+                                       differentiated):
+        v = 200
         h, w, labels, _ = head_problem(n=n, v=v)
 
         def loss(h, w):
-            return fused_softmax_xent(h, w, labels).mean()
+            return fused_softmax_xent(h, w, labels, chunk).mean()
 
         # Every chunk's ``lse`` conditional holds one more, in the branch
         # of a sum that overflowed: the tile again, a few rows at a time.
-        lse_conds = [[1, 0]] * chunks
+        lse_conds = [[1, 0]] * bodies
         assert head_dots(jax.make_jaxpr(loss)(h, w).jaxpr, v) == (
-            chunks, lse_conds)
+            bodies, lse_conds)
         assert head_dots(
             jax.make_jaxpr(jax.grad(loss, argnums=(0, 1)))(h, w).jaxpr,
             v) == (differentiated, lse_conds)
@@ -319,22 +311,20 @@ class TestOnePassLse:
             losses, "_max_shifted_lse",
             lambda logits: jnp.full(logits.shape[:1], jnp.nan))
 
-    # (The save schedule's bf16 residual cannot hold logits of a hundred.)
-    @pytest.mark.parametrize("case,mode", [
-        (case, mode) for case in sorted(LOGIT_CASES)
-        for mode in ("unroll2", "recompute", "save2")
-        if case == "plain" or mode != "save2"])
-    def test_value_and_grads_match_reference(self, case, mode, monkeypatch):
-        monkeypatch.setenv("HOROVOD_TPU_XENT_MODE", mode)
+    # Of the 32 rows: the default two chunks and sixteen scanned in every
+    # case, four unrolled in the plain one.
+    @pytest.mark.parametrize("case,chunk", [
+        (case, chunk) for case in sorted(LOGIT_CASES)
+        for chunk in (16384, 2, 8) if case == "plain" or chunk != 8])
+    def test_value_and_grads_match_reference(self, case, chunk):
         offset, spike, _ = LOGIT_CASES[case]
         h, w, labels, mask = self.problem(offset, spike)
-        fused, naive = fused_and_naive("mean", labels)
+        fused, naive = fused_and_naive("mean", labels, chunk)
         got = jax.jit(jax.value_and_grad(fused, argnums=(0, 1)))(h, w, mask)
         want = jax.value_and_grad(naive, argnums=(0, 1))(h, w, mask)
         # Logits of a hundred or so cost float32 four decimal places of
         # ``x - lse``, in the reference as here.
-        tol = (dict(rtol=2e-2, atol=2e-3) if mode.startswith("save")
-               else dict(rtol=1e-5, atol=1e-6) if case == "plain"
+        tol = (dict(rtol=1e-5, atol=1e-6) if case == "plain"
                else dict(rtol=3e-4, atol=1e-4))
         assert_trees_close(got, want, **tol)
 

@@ -51,7 +51,7 @@ class TestShippedTree:
         assert not findings, "\n".join(str(f) for f in findings)
         # The audited contract surface; update these alongside a
         # deliberate knob/symbol addition.
-        assert stats["knobs_total"] == 75
+        assert stats["knobs_total"] == 69
         assert stats["symbols_total"] == 116
 
     def test_every_knob_has_a_read_site_count(self):

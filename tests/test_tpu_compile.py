@@ -56,15 +56,24 @@ def compile_text(fn, *shapes):
     return compiled.as_text()
 
 
-def test_flash_attention_fwd_bwd(v5e):
+# The benchmark cells' shapes (fully-unrolled forward, the pair grouped
+# over two heads; T 4096 is OLMoE's and needs the raised VMEM budgets),
+# then what else _plan can choose: the unrolled-KV forward with the
+# per-head pair, the grid forward past a 1 MB K/V row, and heads off the
+# lane width (GPT-2 small's 12 of 64), merged into the batch.
+@pytest.mark.parametrize("b,t,h,d,blocks", [
+    (B, T, H, D, None), (4, 4096, H, D, None), (2, 2304, H, D, None),
+    (1, 8192, H, D, None), (8, 1024, 12, 64, 512)],
+    ids=["cell_T2048", "cell_T4096", "unrollkv", "grid", "D64"])
+def test_flash_attention_fwd_bwd(v5e, b, t, h, d, blocks):
     from horovod_tpu.ops.flash_attention import flash_attention
 
     one = SingleDeviceSharding(v5e[0])
-    q = jax.ShapeDtypeStruct((B, T, H, D), jnp.bfloat16, sharding=one)
+    q = jax.ShapeDtypeStruct((b, t, h, d), jnp.bfloat16, sharding=one)
 
     def loss(q, k, v):
-        return flash_attention(q, k, v, causal=True).astype(
-            jnp.float32).sum()
+        return flash_attention(q, k, v, causal=True, block_q=blocks,
+                               block_k=blocks).astype(jnp.float32).sum()
 
     text = compile_text(jax.value_and_grad(loss, argnums=(0, 1, 2)),
                         q, q, q)
