@@ -130,6 +130,23 @@ def kernels_in(lowered_text: str) -> list:
     return sorted(set(re.findall(r'kernel_name = "([^"]+)"', lowered_text)))
 
 
+def flash_plan(seq: int, heads: int, head_dim: int) -> dict:
+    """What ``flash_attention._plan`` decides on this device for the model's
+    attention call (``flash_qkv_proj``, bfloat16, causal, default blocks):
+    the forms behind the kernels a step lists, the sub-tile of the
+    backward's diagonal blocks and the live share of what it computes."""
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.ops import flash_attention as fa
+
+    blocks = fa._resolve_blocks(seq, "chip_smoke", None, None, None, None,
+                                None, "")[:4]
+    qkv = jax.ShapeDtypeStruct((1, seq, 3 * heads * head_dim), jnp.bfloat16)
+    return fa._plan_for(qkv, heads, head_dim, (0, heads, 2 * heads), True,
+                        *blocks, jax.default_backend() != "tpu")._asdict()
+
+
 def step_program(lowered_text: str) -> str:
     """Which of make_train_step's two programs a lowered step is."""
     return ("shard_map" if "sdy.manual_computation" in lowered_text
@@ -351,6 +368,7 @@ def transformer_phase(mesh, events, *, vocab, dim, depth, heads, seq,
                   f"{report['kernels']}")
     return {"params": sum(p.size for p in jax.tree.leaves(state[0])),
             "batch": batch, "seq": seq, "init_s": init_s,
+            "flash_plan": flash_plan(seq, heads, dim // heads),
             "steps_per_call_1": single,
             f"steps_per_call_{scan_steps}": scanned,
             "memory": memory_peaks(mesh.devices.flat)}

@@ -40,10 +40,14 @@ one accumulating dk/dv per KV block while Q blocks stream, one
 accumulating dq per Q block while KV blocks stream (the FlashAttention-2
 split).  The pair comes per head (:func:`_dkdv_kernel`,
 :func:`_dq_kernel`) and, at 1024² blocks with D 128, blocked over two
-adjacent heads (:func:`_dkdv_kernel_grouped`, :func:`_dq_kernel_grouped`).
-The forms that lost to these on the chip (a fused one-pass backward, a
-fully-unrolled backward, a transpose-to-merged backward, a chunked XLA
-backward) left at PR 27; docs/benchmarks.md keeps their measurements.
+adjacent heads (:func:`_dkdv_kernel_grouped`, :func:`_dq_kernel_grouped`);
+under the causal mask the grouped pair cuts a block pair on the diagonal
+into 256-wide sub-tiles and leaves those above the diagonal out
+(:func:`_diag_sub`, :func:`_diag_regions`), and its dead grid steps fetch
+nothing.  The forms that lost to these on the chip (a fused one-pass
+backward, a fully-unrolled backward, a transpose-to-merged backward, a
+chunked XLA backward) left at PR 27; docs/benchmarks.md keeps their
+measurements.
 
 Composition: this is the *single-chip* block; for sequences sharded
 across chips use :mod:`horovod_tpu.parallel.ring_attention`, which
@@ -613,14 +617,92 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dta_ref,
         dq_ref[0] = dq_scr[...].astype(dq_ref.dtype)
 
 
+def _p_ds(q, k, v, do, lse, delta, scale, ok):
+    """``p = exp(s - lse)`` and ``dS = p * (dO V^T - delta) * scale`` of one
+    tile; ``ok`` is its validity mask, or None where every position is
+    valid.  bf16 operands, f32 accumulation."""
+    s = jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32) * scale
+    p = jnp.exp(s - lse)
+    if ok is not None:
+        p = jnp.where(ok, p, 0.0)
+    dp = jax.lax.dot_general(
+        do, v, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    return p, p * (dp - delta) * scale
+
+
+def _diag_regions(n):
+    """The sub-tiles at or under the diagonal of an ``n`` x ``n`` square of
+    them, as products ``(row0, row1, col, on_diagonal)`` over the sub-tile
+    rows ``[row0, row1)`` of column ``col``: column by column, the sub-tile
+    on the diagonal (it alone needs a mask) and everything under it as one
+    tall product — 2n - 1 products for n (n + 1) / 2 sub-tiles.  On the chip
+    (v5e, PR 29) the tall products read 0.16 ms a layer under one product a
+    sub-tile at n = 4, and a rolled loop over sub-tiles 1.3 ms over it."""
+    out = []
+    for c in range(n):
+        out.append((c, c + 1, c, True))
+        if c + 1 < n:
+            out.append((c + 1, n, c, False))
+    return out
+
+
+def _grouped_dispatch(region, qi, kj, block_q, block_k, causal, seq_len,
+                      sub):
+    """Which products of a grouped kernel run on this block pair.
+    ``region(rows, cols, ok)`` does the kernel's work on the static slices
+    ``rows`` x ``cols`` of the pair under the mask ``ok`` (None: every
+    position valid).  Without a sub-tile (``sub`` 0): the whole pair as
+    :func:`_masked_dispatch` has it.  With one (causal, square blocks): a
+    pair on the diagonal that the padding does not cut is taken as a
+    square of ``sub``-wide sub-tiles, and those above the diagonal are left
+    out (:func:`_diag_regions`); every other live pair runs whole, masked
+    only where the padding cuts it — without padding no masked whole-block
+    body is emitted at all."""
+    everything = slice(None)
+
+    def whole(masked: bool):
+        region(everything, everything,
+               _block_mask(qi, kj, block_q, block_k, causal, seq_len)
+               if masked else None)
+
+    live = _live_block(qi, kj, block_q, block_k, causal, seq_len)
+    if not sub:
+        _masked_dispatch(whole, live, qi, kj, block_q, block_k, causal,
+                         seq_len)
+        return
+
+    def triangle():
+        on_diagonal = _block_mask(0, 0, sub, sub, True, None)
+        for row0, row1, col, diag in _diag_regions(block_q // sub):
+            region(slice(row0 * sub, row1 * sub),
+                   slice(col * sub, (col + 1) * sub),
+                   on_diagonal if diag else None)
+
+    diag = qi == kj
+    if seq_len is not None:
+        diag = jnp.logical_and(diag, (qi + 1) * block_q <= seq_len)
+    pl.when(diag)(triangle)
+    rest = jnp.logical_and(live, jnp.logical_not(diag))
+    if seq_len is None:
+        pl.when(rest)(functools.partial(whole, masked=False))
+    else:
+        _masked_dispatch(whole, rest, qi, kj, block_q, block_k, causal,
+                         seq_len)
+
+
 def _dkdv_kernel_grouped(q_ref, k_ref, v_ref, do_ref, lse_ref, dta_ref,
                          dk_ref, dv_ref, dk_scr, dv_scr, *, scale, causal,
-                         block_q, block_k, seq_len, group, head_dim):
+                         block_q, block_k, seq_len, group, head_dim, sub):
     """Head-GROUP blocked dk/dv: each tile spans ``group`` adjacent heads
     ((block, group*D) — HBM rows ``group``× wider than the per-head
     packed kernel's 256-byte strided reads), with per-head math on
     128-aligned lane slices inside VMEM.  Same schedule as
-    :func:`_dkdv_kernel` otherwise."""
+    :func:`_dkdv_kernel` otherwise, but for ``sub``: of a block pair on
+    the causal diagonal only the ``sub``-wide sub-tiles at or under the
+    diagonal are computed (see :func:`_grouped_dispatch`)."""
     kj = pl.program_id(2)
     qi = pl.program_id(3)
     nq = pl.num_programs(3)
@@ -631,37 +713,25 @@ def _dkdv_kernel_grouped(q_ref, k_ref, v_ref, do_ref, lse_ref, dta_ref,
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
-    def _compute(masked: bool):
-        ok = (_block_mask(qi, kj, block_q, block_k, causal, seq_len)
-              if masked else None)
+    def _region(rows, cols, ok):
         for g in range(group):
             sl = slice(g * D, (g + 1) * D)
-            q = q_ref[0][:, sl]                        # (BQ, D)
-            k = k_ref[0][:, sl]                        # (BK, D)
-            v = v_ref[0][:, sl]                        # (BK, D)
-            do = do_ref[0][:, sl]                      # (BQ, D)
-            lse = lse_ref[0, g][:, :1]                 # (BQ, 1)
-            delta = dta_ref[0, g][:, :1]               # (BQ, 1)
-            s = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale
-            p = jnp.exp(s - lse)
-            if ok is not None:
-                p = jnp.where(ok, p, 0.0)
-            dv_scr[g] += jax.lax.dot_general(
+            q = q_ref[0, rows, sl]
+            do = do_ref[0, rows, sl]
+            p, ds = _p_ds(q, k_ref[0, cols, sl], v_ref[0, cols, sl], do,
+                          lse_ref[0, g, rows, :1], dta_ref[0, g, rows, :1],
+                          scale, ok)
+            # dv += p^T @ dO — p cast to the input dtype so the MXU runs
+            # at native rate; all accumulation stays f32.
+            dv_scr[g, cols, :] += jax.lax.dot_general(
                 p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
-            dp = jax.lax.dot_general(
-                do, v, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            ds = p * (dp - delta) * scale
-            dk_scr[g] += jax.lax.dot_general(
+            dk_scr[g, cols, :] += jax.lax.dot_general(
                 ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
 
-    live = _live_block(qi, kj, block_q, block_k, causal, seq_len)
-    _masked_dispatch(_compute, live, qi, kj, block_q, block_k, causal,
-                     seq_len)
+    _grouped_dispatch(_region, qi, kj, block_q, block_k, causal, seq_len,
+                      sub)
 
     @pl.when(qi == nq - 1)
     def _finalize():
@@ -673,7 +743,7 @@ def _dkdv_kernel_grouped(q_ref, k_ref, v_ref, do_ref, lse_ref, dta_ref,
 
 def _dq_kernel_grouped(q_ref, k_ref, v_ref, do_ref, lse_ref, dta_ref,
                        dq_ref, dq_scr, *, scale, causal, block_q, block_k,
-                       seq_len, group, head_dim):
+                       seq_len, group, head_dim, sub):
     """Head-group blocked dq accumulation (see
     :func:`_dkdv_kernel_grouped`)."""
     qi = pl.program_id(2)
@@ -685,34 +755,19 @@ def _dq_kernel_grouped(q_ref, k_ref, v_ref, do_ref, lse_ref, dta_ref,
     def _init():
         dq_scr[...] = jnp.zeros_like(dq_scr)
 
-    def _compute(masked: bool):
-        ok = (_block_mask(qi, kj, block_q, block_k, causal, seq_len)
-              if masked else None)
+    def _region(rows, cols, ok):
         for g in range(group):
             sl = slice(g * D, (g + 1) * D)
-            q = q_ref[0][:, sl]
-            k = k_ref[0][:, sl]
-            v = v_ref[0][:, sl]
-            do = do_ref[0][:, sl]
-            lse = lse_ref[0, g][:, :1]
-            delta = dta_ref[0, g][:, :1]
-            s = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale
-            p = jnp.exp(s - lse)
-            if ok is not None:
-                p = jnp.where(ok, p, 0.0)
-            dp = jax.lax.dot_general(
-                do, v, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            ds = p * (dp - delta) * scale
-            dq_scr[g] += jax.lax.dot_general(
+            k = k_ref[0, cols, sl]
+            _, ds = _p_ds(q_ref[0, rows, sl], k, v_ref[0, cols, sl],
+                          do_ref[0, rows, sl], lse_ref[0, g, rows, :1],
+                          dta_ref[0, g, rows, :1], scale, ok)
+            dq_scr[g, rows, :] += jax.lax.dot_general(
                 ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
 
-    live = _live_block(qi, kj, block_q, block_k, causal, seq_len)
-    _masked_dispatch(_compute, live, qi, kj, block_q, block_k, causal,
-                     seq_len)
+    _grouped_dispatch(_region, qi, kj, block_q, block_k, causal, seq_len,
+                      sub)
 
     @pl.when(kj == nk - 1)
     def _finalize():
@@ -732,12 +787,14 @@ _GROUPED_VMEM_MB = 32
 
 def _bwd_pallas_packed_grouped(q, k, v, o, lse, do, H, D, group, *, scale,
                                causal, block_q, block_k, interpret,
-                               seq_len, head_base, vmem_mb=0):
+                               seq_len, head_base, vmem_mb=0, sub=0):
     """Head-group blocked split backward on head-packed (B, T, C) views:
     the strided 256-byte-row tax of the per-head packed kernels
     (measured ~12 ms/step at the bench shape, docs/benchmarks.md) is
     removed by reading ``group`` adjacent heads per tile — contiguous
-    ``group*D``-wide rows — while keeping the copies-free packed layout."""
+    ``group*D``-wide rows — while keeping the copies-free packed layout.
+    ``sub`` is :func:`_diag_sub`'s: the sub-tile that diagonal block pairs
+    are cut into, 0 for none."""
     B, T, _ = q.shape
     C = H * D
     nq = T // block_q
@@ -750,18 +807,29 @@ def _bwd_pallas_packed_grouped(q, k, v, o, lse, do, H, D, group, *, scale,
     lse8 = jnp.broadcast_to(lse[..., None], (B, H, T, 8))
     delta8 = jnp.broadcast_to(delta[..., None], (B, H, T, 8))
     GD = group * D
+    # A grid step whose pair lies wholly in the causal future computes
+    # nothing, so its index maps name the block the nearest live step
+    # holds and the pipeline issues no copy for it: the dk/dv kernel's
+    # first live Q block, the dq kernel's last live KV block.
+    def live_q(i, j):
+        return jnp.maximum(i, (j * block_k) // block_q) if causal else i
+
+    def live_k(i, j):
+        return (jnp.minimum(j, ((i + 1) * block_q - 1) // block_k)
+                if causal else j)
 
     kv_specs = dict(
         q=pl.BlockSpec((1, block_q, GD),
-                       lambda b, h, j, i: (b, i, h + oq)),
+                       lambda b, h, j, i: (b, live_q(i, j), h + oq)),
         k=pl.BlockSpec((1, block_k, GD),
                        lambda b, h, j, i: (b, j, h + ok_)),
         v=pl.BlockSpec((1, block_k, GD),
                        lambda b, h, j, i: (b, j, h + ov)),
-        do=pl.BlockSpec((1, block_q, GD), lambda b, h, j, i: (b, i, h)),
+        do=pl.BlockSpec((1, block_q, GD),
+                        lambda b, h, j, i: (b, live_q(i, j), h)),
         out=pl.BlockSpec((1, block_k, GD), lambda b, h, j, i: (b, j, h)),
         row8=pl.BlockSpec((1, group, block_q, 8),
-                          lambda b, h, j, i: (b, h, i, 0)),
+                          lambda b, h, j, i: (b, h, live_q(i, j), 0)),
     )
     sem4 = pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "parallel",
@@ -770,7 +838,8 @@ def _bwd_pallas_packed_grouped(q, k, v, o, lse, do, H, D, group, *, scale,
     dk, dv = pl.pallas_call(
         functools.partial(_dkdv_kernel_grouped, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k,
-                          seq_len=seq_len, group=group, head_dim=D),
+                          seq_len=seq_len, group=group, head_dim=D,
+                          sub=sub),
         grid=(B, HG, nk, nq),
         in_specs=[kv_specs["q"], kv_specs["k"], kv_specs["v"],
                   kv_specs["do"], kv_specs["row8"], kv_specs["row8"]],
@@ -787,9 +856,9 @@ def _bwd_pallas_packed_grouped(q, k, v, o, lse, do, H, D, group, *, scale,
         q=pl.BlockSpec((1, block_q, GD),
                        lambda b, h, i, j: (b, i, h + oq)),
         k=pl.BlockSpec((1, block_k, GD),
-                       lambda b, h, i, j: (b, j, h + ok_)),
+                       lambda b, h, i, j: (b, live_k(i, j), h + ok_)),
         v=pl.BlockSpec((1, block_k, GD),
-                       lambda b, h, i, j: (b, j, h + ov)),
+                       lambda b, h, i, j: (b, live_k(i, j), h + ov)),
         do=pl.BlockSpec((1, block_q, GD), lambda b, h, i, j: (b, i, h)),
         out=pl.BlockSpec((1, block_q, GD), lambda b, h, i, j: (b, i, h)),
         row8=pl.BlockSpec((1, group, block_q, 8),
@@ -798,7 +867,8 @@ def _bwd_pallas_packed_grouped(q, k, v, o, lse, do, H, D, group, *, scale,
     dq, = pl.pallas_call(
         functools.partial(_dq_kernel_grouped, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k,
-                          seq_len=seq_len, group=group, head_dim=D),
+                          seq_len=seq_len, group=group, head_dim=D,
+                          sub=sub),
         grid=(B, HG, nq, nk),
         in_specs=[q_specs["q"], q_specs["k"], q_specs["v"],
                   q_specs["do"], q_specs["row8"], q_specs["row8"]],
@@ -827,7 +897,7 @@ def _bwd_pallas_packed(q, k, v, o, lse, do, H, D, plan, *, scale, causal,
             q, k, v, o, lse, do, H, D, _GROUPED_HEADS, scale=scale,
             causal=causal, block_q=block_q, block_k=block_k,
             interpret=interpret, seq_len=seq_len, head_base=head_base,
-            vmem_mb=plan.bwd_vmem_mb)
+            vmem_mb=plan.bwd_vmem_mb, sub=plan.bwd_sub)
     B, T, _ = q.shape
     C = H * D
     nq = T // block_q
@@ -906,25 +976,64 @@ class _Plan(NamedTuple):
     fwd_vmem_mb: int    # scoped-VMEM budget, MB; 0 = Mosaic's default
     bwd: str            # "grouped" | "per_head"
     bwd_vmem_mb: int
+    bwd_sub: int        # sub-tile of the diagonal block pairs, else 0
+    bwd_live_share: float   # of the scores the backward computes
 
 
-def _plan(*, T, D, H, head_base, itemsize, block_q, block_k, bwd_block_q,
-          bwd_block_k, interpret, manual_axes, vmem_headroom) -> _Plan:
+# The side of the sub-tiles a diagonal block pair of the grouped backward
+# is cut into: of a 1024² block's 16 sub-tiles of 256², the 6 above the
+# diagonal are never computed.
+_DIAG_SUB = 256
+
+
+def _diag_sub(causal, block_q, block_k, sub=_DIAG_SUB) -> int:
+    """``sub`` where the grouped pair can cut its diagonal blocks into
+    sub-tiles of that side, else 0: causal, square blocks (so that the
+    diagonal pairs are those with ``qi == kj``) that ``sub`` divides into
+    at least two.  Only the interpreted tests, at their small blocks, ask
+    for another ``sub`` than the one timed on the chip."""
+    fits = (causal and block_q == block_k and block_q % sub == 0
+            and block_q > sub)
+    return sub if fits else 0
+
+
+def _bwd_live_share(T, causal, block_q, block_k, sub) -> float:
+    """Share of the score elements the backward pair computes that the
+    mask leaves standing (padding not counted): 1 without a mask; under
+    the causal one, ``T (T + 1) / 2`` over the area of the block pairs not
+    wholly in the future — a diagonal pair cut into ``sub``-wide sub-tiles
+    counting only those at or under the diagonal."""
+    if not causal:
+        return 1.0
+    computed = 0
+    for qi in range(T // block_q):
+        pairs = min(T // block_k, ((qi + 1) * block_q - 1) // block_k + 1)
+        computed += pairs * block_q * block_k
+        if sub:
+            n = block_q // sub
+            computed -= (n * n - n * (n + 1) // 2) * sub * sub
+    return round(T * (T + 1) / 2 / computed, 3)
+
+
+def _plan(*, T, D, H, head_base, itemsize, causal, block_q, block_k,
+          bwd_block_q, bwd_block_k, interpret, manual_axes,
+          vmem_headroom) -> _Plan:
     """Which forward form and which backward pair run, and the VMEM limit
     each is compiled with — the one place that chooses, from what the op
     observes at trace time and nothing else.
 
     ``T``, ``D``, ``H``: sequence length, head width, heads; ``head_base``:
     the head offsets of q, k, v inside their packed rows; ``itemsize``:
-    bytes an operand element; the four resolved blocks; ``manual_axes``:
-    whether the operands vary over manual mesh axes (``shard_map``);
-    ``vmem_headroom``: :func:`_vmem_headroom_ok` — whether the device
-    backs a scoped budget above Mosaic's default."""
+    bytes an operand element; ``causal``; the four resolved blocks;
+    ``manual_axes``: whether the operands vary over manual mesh axes
+    (``shard_map``); ``vmem_headroom``: :func:`_vmem_headroom_ok` —
+    whether the device backs a scoped budget above Mosaic's default."""
     if D % 128:
         # Heads off the lane width arrive merged into the batch (H is 1,
         # see flash_attention).  Only these two forms have run on a chip
         # at such a D.
-        return _Plan("grid", 0, 0, "per_head", 0)
+        return _Plan("grid", 0, 0, "per_head", 0, 0, _bwd_live_share(
+            T, causal, bwd_block_q, bwd_block_k, sub=0))
 
     row_fits = T * D * itemsize <= _UNROLL_KV_MAX_BYTES
     # The fully-unrolled form's tile divides T whenever T is a multiple
@@ -959,17 +1068,21 @@ def _plan(*, T, D, H, head_base, itemsize, block_q, block_k, bwd_block_q,
             and H % _GROUPED_HEADS == 0
             and all(b % _GROUPED_HEADS == 0 for b in head_base)
             and vmem_headroom):
-        bwd = ("grouped", _GROUPED_VMEM_MB)
+        # Only the grouped pair has been timed with its diagonal blocks
+        # cut into sub-tiles.
+        bwd = ("grouped", _GROUPED_VMEM_MB,
+               _diag_sub(causal, bwd_block_q, bwd_block_k))
     else:
-        bwd = ("per_head", 0)
-    return _Plan(*fwd, *bwd)
+        bwd = ("per_head", 0, 0)
+    return _Plan(*fwd, *bwd, _bwd_live_share(T, causal, bwd_block_q,
+                                             bwd_block_k, sub=bwd[-1]))
 
 
-def _plan_for(q, H, D, head_base, block_q, block_k, bwd_block_q,
+def _plan_for(q, H, D, head_base, causal, block_q, block_k, bwd_block_q,
               bwd_block_k, interpret) -> _Plan:
     """:func:`_plan` for the operand ``q`` of a custom-VJP rule."""
     return _plan(T=q.shape[1], D=D, H=H, head_base=head_base,
-                 itemsize=q.dtype.itemsize, block_q=block_q,
+                 itemsize=q.dtype.itemsize, causal=causal, block_q=block_q,
                  block_k=block_k, bwd_block_q=bwd_block_q,
                  bwd_block_k=bwd_block_k, interpret=interpret,
                  manual_axes=bool(jax.typeof(q).vma),
@@ -988,8 +1101,8 @@ def _flash_packed(q, k, v, H, scale, causal, block_q, block_k,
 def _flash_packed_fwd(q, k, v, H, scale, causal, block_q, block_k,
                       bwd_block_q, bwd_block_k, interpret, seq_len):
     D = q.shape[2] // H
-    plan = _plan_for(q, H, D, (0, 0, 0), block_q, block_k, bwd_block_q,
-                     bwd_block_k, interpret)
+    plan = _plan_for(q, H, D, (0, 0, 0), causal, block_q, block_k,
+                     bwd_block_q, bwd_block_k, interpret)
     out, lse = _fwd_packed(q, k, v, H, D, plan, scale=scale, causal=causal,
                            block_q=block_q, block_k=block_k,
                            interpret=interpret, seq_len=seq_len)
@@ -1000,8 +1113,8 @@ def _flash_packed_bwd(H, scale, causal, block_q, block_k, bwd_block_q,
                       bwd_block_k, interpret, seq_len, res, do):
     q, k, v, o, lse = res
     D = q.shape[2] // H
-    plan = _plan_for(q, H, D, (0, 0, 0), block_q, block_k, bwd_block_q,
-                     bwd_block_k, interpret)
+    plan = _plan_for(q, H, D, (0, 0, 0), causal, block_q, block_k,
+                     bwd_block_q, bwd_block_k, interpret)
     return _bwd_pallas_packed(q, k, v, o, lse, do, H, D, plan, scale=scale,
                               causal=causal, block_q=bwd_block_q,
                               block_k=bwd_block_k, interpret=interpret,
@@ -1011,26 +1124,44 @@ def _flash_packed_bwd(H, scale, causal, block_q, block_k, bwd_block_q,
 _flash_packed.defvjp(_flash_packed_fwd, _flash_packed_bwd)
 
 
+# Every layer of a model calls the two functions below with the same
+# shapes and the same static arguments.  Under ``jax.jit`` those calls
+# share one trace, so a trace of the step pays for each distinct kernel
+# body once and not once a layer (``gpt13b_1chip``'s 7 layers, the step
+# traced three times: 42 kernel traces, 2.0 of ``step.lower``'s 4.3 s in
+# the sandbox, now 6 and 0.6 of 3.0); ``inline`` leaves no call behind, so
+# the lowered step is the one without it.  The rules of the split q, k, v
+# entry call the drivers as they are: its one cell has a single layer, and
+# there a kernel traced inside the jit's own trace cost 9 s of set-up on
+# the chip's host (PERF.md §6, PR 29).
+_one_trace_a_shape = functools.partial(
+    jax.jit, inline=True,
+    static_argnames=("H", "D", "scale", "causal", "block_q", "block_k",
+                     "bwd_block_q", "bwd_block_k", "interpret", "seq_len"))
+
+
+@_one_trace_a_shape
 def _qkv_fwd(qkv, H, D, scale, causal, block_q, block_k, bwd_block_q,
              bwd_block_k, interpret, seq_len):
     """(out, lse) with q | k | v read as three regions of one
     (B, T, 3*H*D) projection."""
     base = (0, H, 2 * H)
-    plan = _plan_for(qkv, H, D, base, block_q, block_k, bwd_block_q,
-                     bwd_block_k, interpret)
+    plan = _plan_for(qkv, H, D, base, causal, block_q, block_k,
+                     bwd_block_q, bwd_block_k, interpret)
     return _fwd_packed(qkv, qkv, qkv, H, D, plan, scale=scale,
                        causal=causal, block_q=block_q, block_k=block_k,
                        interpret=interpret, seq_len=seq_len,
                        head_base=base)
 
 
+@_one_trace_a_shape
 def _qkv_bwd(qkv, o, lse, do, H, D, scale, causal, block_q, block_k,
              bwd_block_q, bwd_block_k, interpret, seq_len):
     """The cotangent of :func:`_qkv_fwd`'s projection: one concatenate
     of dq | dk | dv."""
     base = (0, H, 2 * H)
-    plan = _plan_for(qkv, H, D, base, block_q, block_k, bwd_block_q,
-                     bwd_block_k, interpret)
+    plan = _plan_for(qkv, H, D, base, causal, block_q, block_k,
+                     bwd_block_q, bwd_block_k, interpret)
     dq, dk, dv = _bwd_pallas_packed(
         qkv, qkv, qkv, o, lse, do, H, D, plan, scale=scale, causal=causal,
         block_q=bwd_block_q, block_k=bwd_block_k, interpret=interpret,

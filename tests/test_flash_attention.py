@@ -394,7 +394,8 @@ class TestFlashUnderShardMap:
         assert losses[-1] < losses[0]
 
 
-def packed_problem(seed, B, T, H, D, qkv, seq_len=None):
+def packed_problem(seed, B, T, H, D, qkv, seq_len=None, block=8,
+                   causal=True):
     """Operands of the packed backward drivers as the custom-VJP rules
     hand them over: (q, k, v, o, lse, do), head bases."""
     from horovod_tpu.ops import flash_attention as fa
@@ -408,9 +409,9 @@ def packed_problem(seed, B, T, H, D, qkv, seq_len=None):
         base = (0, 0, 0)
         q, k, v = (x.reshape(B, T, H * D)
                    for x in make_qkv(ks[0], B, T, H, D))
-    plan = fa._Plan("grid", 0, 0, "per_head", 0)
-    o, lse = fa._fwd_packed(q, k, v, H, D, plan, scale=scale, causal=True,
-                            block_q=8, block_k=8, interpret=True,
+    plan = fa._Plan("grid", 0, 0, "per_head", 0, 0, 0.0)
+    o, lse = fa._fwd_packed(q, k, v, H, D, plan, scale=scale, causal=causal,
+                            block_q=block, block_k=block, interpret=True,
                             seq_len=seq_len, head_base=base)
     do = jax.random.normal(ks[1], o.shape)
     return (q, k, v, o, lse, do), base, plan, scale
@@ -469,58 +470,202 @@ class TestHeadGroupBwd:
                                        rtol=2e-4, atol=2e-4)
 
 
+# The grouped pair with its diagonal block pairs cut into sub-tiles
+# (block, requested sub-tile, T, seq_len): 2, 4 and 8 sub-tiles a block
+# side, one and several blocks a row, and the padding's end inside a
+# sub-tile on the diagonal, inside an interior block, and on a block edge.
+SUB_TILE_CASES = {
+    "2_a_side-one_block": (32, 16, 32, None),
+    "4_a_side-one_block": (32, 8, 32, None),
+    "4_a_side-three_blocks": (32, 8, 96, None),
+    "8_a_side-two_blocks": (64, 8, 128, None),
+    "ends_in_diagonal_sub_tile": (32, 8, 96, 90),
+    "ends_in_interior_block": (32, 8, 96, 50),
+    "ends_on_block_edge": (32, 8, 96, 64),
+    # 16 does not divide 24: no sub-tile, the whole-block bodies.
+    "sub_tile_does_not_divide": (24, 16, 72, None),
+}
+
+
+class TestDiagonalSubTiles:
+    """Only products whose every element the causal mask sets to zero are
+    left out, so the gradients are those of the per-head pair and of
+    ``full_attention`` up to the order of the float32 sums."""
+
+    @pytest.mark.parametrize("case", sorted(SUB_TILE_CASES))
+    @pytest.mark.parametrize("qkv", [False, True],
+                             ids=["qkv_apart", "fused_qkv"])
+    def test_matches_per_head_and_oracle(self, hvd, case, qkv):
+        from horovod_tpu.ops import flash_attention as fa
+
+        block, want_sub, T, seq_len = SUB_TILE_CASES[case]
+        sub = fa._diag_sub(True, block, block, want_sub)
+        assert sub == (0 if "not_divide" in case else want_sub)
+        B, H, D = 1, 2, 128
+        ops, base, plan, scale = packed_problem(51, B, T, H, D, qkv,
+                                                seq_len, block=block)
+        kw = dict(scale=scale, causal=True, block_q=block, block_k=block,
+                  interpret=True, seq_len=seq_len, head_base=base)
+        per_head = fa._bwd_pallas_packed(*ops, H, D, plan, **kw)
+        whole = fa._bwd_pallas_packed_grouped(*ops, H, D, 2, **kw)
+        got = fa._bwd_pallas_packed_grouped(*ops, H, D, 2, sub=sub, **kw)
+        for g, w, p in zip(got, whole, per_head):
+            np.testing.assert_array_equal(np.asarray(w), np.asarray(p))
+            if sub:
+                np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                           rtol=2e-5, atol=2e-5)
+            else:
+                np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+        if qkv:
+            return
+        # Against the oracle: forward value, then dq, dk, dv of sum(o^2).
+        q, k, v, o, lse, _ = ops
+        n = seq_len or T
+
+        def heads(x):
+            return x.reshape(B, T, H, D)[:, :n]
+
+        dense = full_attention(heads(q), heads(k), heads(v), causal=True)
+        np.testing.assert_allclose(np.asarray(heads(o)), np.asarray(dense),
+                                   rtol=2e-5, atol=2e-5)
+        want = jax.grad(lambda q, k, v: (full_attention(
+            heads(q), heads(k), heads(v), causal=True) ** 2).sum(),
+            argnums=(0, 1, 2))(q, k, v)
+        valid = (jnp.arange(T) < n)[None, :, None]
+        got = fa._bwd_pallas_packed_grouped(
+            q, k, v, o, lse, jnp.where(valid, 2 * o, 0.0), H, D, 2, sub=sub,
+            **kw)
+        for g, w in zip(got, want):
+            np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                       rtol=2e-4, atol=2e-4)
+
+    def test_non_causal_is_unchanged(self, hvd):
+        """No mask, no diagonal: ``_diag_sub`` answers 0 and the pair is
+        the per-head pair's, bit for bit."""
+        from horovod_tpu.ops import flash_attention as fa
+
+        assert fa._diag_sub(False, 32, 32, 8) == 0
+        ops, base, plan, scale = packed_problem(52, 1, 64, 2, 128, True,
+                                                block=32, causal=False)
+        kw = dict(scale=scale, causal=False, block_q=32, block_k=32,
+                  interpret=True, seq_len=None, head_base=base)
+        want = fa._bwd_pallas_packed(*ops, 2, 128, plan, **kw)
+        got = fa._bwd_pallas_packed_grouped(*ops, 2, 128, 2, sub=0, **kw)
+        for g, w in zip(got, want):
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+    @pytest.mark.parametrize("causal,block_q,block_k,sub,want", [
+        (True, 1024, 1024, 256, 256), (True, 1024, 1024, 512, 512),
+        (False, 1024, 1024, 256, 0),      # nothing is masked
+        (True, 1024, 512, 256, 0),        # the diagonal is not qi == kj
+        (True, 768, 768, 512, 0),         # 512 does not divide the block
+        (True, 256, 256, 256, 0)],        # one sub-tile is the block
+        ids=["cell", "sub_512", "non_causal", "oblong_blocks",
+             "does_not_divide", "one_sub_tile"])
+    def test_diag_sub_rule(self, causal, block_q, block_k, sub, want):
+        from horovod_tpu.ops import flash_attention as fa
+
+        assert fa._diag_sub(causal, block_q, block_k, sub) == want
+
+    def test_whole_model_through_the_sub_tile_pair(self, hvd, monkeypatch):
+        """The rules hand the plan's sub-tile to the pair: with the plan
+        steered to the grouped pair at a size the interpreter can afford,
+        ``jax.grad`` of ``flash_attention_qkv`` is the oracle's."""
+        from horovod_tpu.ops import flash_attention as fa
+
+        plan = fa._plan
+        monkeypatch.setattr(fa, "_plan", lambda **seen: plan(**seen)._replace(
+            bwd="grouped", bwd_sub=8))
+        B, T, H, D = 1, 64, 2, 128
+        qkv = jax.random.normal(jax.random.PRNGKey(53), (B, T, 3 * H * D))
+
+        def loss(qkv):
+            return (fa.flash_attention_qkv(qkv, H, causal=True, block_q=32,
+                                           block_k=32, interpret=True)
+                    ** 2).sum()
+
+        def loss_full(qkv):
+            q, k, v = (x.reshape(B, T, H, D)
+                       for x in jnp.split(qkv, 3, axis=-1))
+            return (full_attention(q, k, v, causal=True) ** 2).sum()
+
+        jax.clear_caches()        # the steered plan must be asked
+        np.testing.assert_allclose(np.asarray(jax.grad(loss)(qkv)),
+                                   np.asarray(jax.grad(loss_full)(qkv)),
+                                   rtol=2e-4, atol=2e-4)
+
+
 # One row of the selection table: what the op observes, and what _plan
 # must answer — each answer read off the conditionals of the parent of
 # PR 27 (where forms were chosen in three places and five environment
 # variables), by tracing its ops at these shapes.
 def observed(T, D=128, H=16, itemsize=2, blocks=1024, base=None,
-             interpret=False, manual_axes=False, vmem_headroom=True):
+             interpret=False, manual_axes=False, vmem_headroom=True,
+             causal=True):
     blocks = min(blocks, T)
     return dict(T=T, D=D, H=H, head_base=base or (0, H, 2 * H),
-                itemsize=itemsize, block_q=blocks, block_k=blocks,
+                itemsize=itemsize, causal=causal, block_q=blocks,
+                block_k=blocks,
                 bwd_block_q=blocks, bwd_block_k=blocks, interpret=interpret,
                 manual_axes=manual_axes, vmem_headroom=vmem_headroom)
 
 
 FULL, KV, GRID = "fullunroll", "unrollkv", "grid"
+# (forward, its tile, its VMEM MB, backward pair, its VMEM MB, the
+# sub-tile of its diagonal blocks, the live share of what it computes).
 PLAN_TABLE = {
-    # The two shapes every benchmark cell runs.
-    "cell_T2048": (observed(2048), (FULL, 512, 0, "grouped", 32)),
-    "cell_T4096": (observed(4096), (FULL, 512, 64, "grouped", 32)),
-    # A v2/v3 or a TPU whose kind cannot be read: no raised budget.
+    # The two shapes every benchmark cell runs: 10 of a diagonal block's
+    # 16 sub-tiles computed, so 2.25 blocks for 2 live at T 2048 (3 before
+    # the sub-tile: 0.667) and 8.5 for 8 at T 4096 (10 before: 0.8).
+    "cell_T2048": (observed(2048), (FULL, 512, 0, "grouped", 32, 256, 0.889)),
+    "cell_T4096": (observed(4096),
+                   (FULL, 512, 64, "grouped", 32, 256, 0.941)),
+    # Nothing is masked without the causal mask: no sub-tile.
+    "cell_T2048_non_causal": (observed(2048, causal=False),
+                              (FULL, 512, 0, "grouped", 32, 0, 1.0)),
+    "cell_T4096_non_causal": (observed(4096, causal=False),
+                              (FULL, 512, 64, "grouped", 32, 0, 1.0)),
+    # A v2/v3 or a TPU whose kind cannot be read: no raised budget, the
+    # per-head pair, whole blocks.
     "T4096_no_headroom": (observed(4096, vmem_headroom=False),
-                          (KV, 0, 0, "per_head", 0)),
+                          (KV, 0, 0, "per_head", 0, 0, 0.8)),
     "T2048_no_headroom": (observed(2048, vmem_headroom=False),
-                          (FULL, 512, 0, "per_head", 0)),
+                          (FULL, 512, 0, "per_head", 0, 0, 0.667)),
     # Past a 1 MB K/V row (T 4096 at D 128 bf16) only the grid streams.
-    "T8192": (observed(8192), (GRID, 0, 0, "grouped", 32)),
-    "T32768": (observed(32768), (GRID, 0, 0, "grouped", 32)),
-    "T4096_f32": (observed(4096, itemsize=4), (GRID, 0, 0, "grouped", 32)),
+    "T8192": (observed(8192), (GRID, 0, 0, "grouped", 32, 256, 0.97)),
+    "T32768": (observed(32768), (GRID, 0, 0, "grouped", 32, 256, 0.992)),
+    "T4096_f32": (observed(4096, itemsize=4),
+                  (GRID, 0, 0, "grouped", 32, 256, 0.941)),
     # Heads off the lane width, merged into the batch: rows of one head.
     "D64": (observed(2048, D=64, H=1, base=(0, 0, 0)),
-            (GRID, 0, 0, "per_head", 0)),
-    "D256": (observed(2048, D=256, H=8), (FULL, 512, 0, "per_head", 0)),
+            (GRID, 0, 0, "per_head", 0, 0, 0.667)),
+    "D64_non_causal": (observed(2048, D=64, H=1, base=(0, 0, 0),
+                                causal=False),
+                       (GRID, 0, 0, "per_head", 0, 0, 1.0)),
+    "D256": (observed(2048, D=256, H=8),
+             (FULL, 512, 0, "per_head", 0, 0, 0.667)),
     # The grouped pair wants an even head count and even head bases ...
-    "odd_H": (observed(2048, H=15), (FULL, 512, 0, "per_head", 0)),
+    "odd_H": (observed(2048, H=15), (FULL, 512, 0, "per_head", 0, 0, 0.667)),
     "odd_head_base": (observed(2048, H=2, base=(0, 1, 2)),
-                      (FULL, 512, 0, "per_head", 0)),
+                      (FULL, 512, 0, "per_head", 0, 0, 0.667)),
     # ... and 1024² blocks.
     "blocks_512": (observed(2048, blocks=512),
-                   (FULL, 512, 0, "per_head", 0)),
-    "T1024_one_block": (observed(1024), (FULL, 512, 0, "grouped", 32)),
+                   (FULL, 512, 0, "per_head", 0, 0, 0.8)),
+    "T1024_one_block": (observed(1024),
+                        (FULL, 512, 0, "grouped", 32, 256, 0.801)),
     # A tile that does not divide T; too many small blocks to unroll.
     "T2304_blocks_768": (observed(2304, blocks=768),
-                         (KV, 0, 0, "per_head", 0)),
+                         (KV, 0, 0, "per_head", 0, 0, 0.75)),
     "T4096_blocks_8": (observed(4096, blocks=8),
-                       (GRID, 0, 0, "per_head", 0)),
+                       (GRID, 0, 0, "per_head", 0, 0, 0.998)),
     # Interpreted (CPU tests): alone, and under shard_map.
     "interpret": (observed(64, H=2, itemsize=4, blocks=16, interpret=True),
-                  (FULL, 16, 0, "per_head", 0)),
+                  (FULL, 16, 0, "per_head", 0, 0, 0.812)),
     "interpret_shard_map": (
         observed(64, H=2, itemsize=4, blocks=16, interpret=True,
-                 manual_axes=True), (KV, 0, 0, "per_head", 0)),
+                 manual_axes=True), (KV, 0, 0, "per_head", 0, 0, 0.812)),
     "compiled_shard_map": (observed(4096, manual_axes=True),
-                           (FULL, 512, 64, "grouped", 32)),
+                           (FULL, 512, 64, "grouped", 32, 256, 0.941)),
 }
 
 
@@ -562,6 +707,9 @@ class TestPlan:
         monkeypatch.setattr(
             fa, "_plan", lambda **seen: asked.append(seen) or plan(**seen))
         B, T, H, D = 1, 16, 2, (8 if entry == "merged_layout" else 128)
+        # The fused-qkv rules share one trace a shape, and a shared trace
+        # asks nothing: start from none.
+        jax.clear_caches()
         x = jax.random.normal(jax.random.PRNGKey(3), (B, T, 3 * H * D))
         kw = dict(block_q=8, block_k=8, interpret=True)
         if entry == "flash_qkv_proj":
@@ -626,6 +774,6 @@ class TestVmemGates:
         monkeypatch.setattr(fa.jax, "local_devices",
                             lambda: [self._Dev("tpu", kind)])
         qkv = jax.ShapeDtypeStruct((1, 4096, 3 * 16 * 128), jnp.bfloat16)
-        plan = fa._plan_for(qkv, 16, 128, (0, 16, 32), 1024, 1024, 1024,
-                            1024, False)
+        plan = fa._plan_for(qkv, 16, 128, (0, 16, 32), True, 1024, 1024,
+                            1024, 1024, False)
         assert (plan.fwd, plan.bwd) == (fwd, bwd)

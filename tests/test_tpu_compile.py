@@ -61,23 +61,37 @@ def compile_text(fn, *shapes):
 # then what else _plan can choose: the unrolled-KV forward with the
 # per-head pair, the grid forward past a 1 MB K/V row, and heads off the
 # lane width (GPT-2 small's 12 of 64), merged into the batch.
-@pytest.mark.parametrize("b,t,h,d,blocks", [
-    (B, T, H, D, None), (4, 4096, H, D, None), (2, 2304, H, D, None),
-    (1, 8192, H, D, None), (8, 1024, 12, 64, 512)],
-    ids=["cell_T2048", "cell_T4096", "unrollkv", "grid", "D64"])
-def test_flash_attention_fwd_bwd(v5e, b, t, h, d, blocks):
-    from horovod_tpu.ops.flash_attention import flash_attention
+# Since PR 29 the grouped pair cuts its diagonal blocks into 256-wide
+# sub-tiles at the two cell shapes and at T 8192; without the causal mask
+# it stands down to whole blocks ("T1024_non_causal": at T 2048 the
+# fully-unrolled forward, all 16 of its tiles live, wants 20.4 MB of scoped
+# VMEM against the default 16 — at the parent of PR 29 too; no cell runs
+# attention without the mask).
+@pytest.mark.parametrize("b,t,h,d,blocks,causal,sub", [
+    (B, T, H, D, None, True, 256), (4, 4096, H, D, None, True, 256),
+    (B, 1024, H, D, None, False, 0), (2, 2304, H, D, None, True, 0),
+    (1, 8192, H, D, None, True, 256), (8, 1024, 12, 64, 512, True, 0)],
+    ids=["cell_T2048", "cell_T4096", "T1024_non_causal", "unrollkv",
+         "grid", "D64"])
+def test_flash_attention_fwd_bwd(v5e, monkeypatch, b, t, h, d, blocks,
+                                 causal, sub):
+    from horovod_tpu.ops import flash_attention as fa
 
     one = SingleDeviceSharding(v5e[0])
     q = jax.ShapeDtypeStruct((b, t, h, d), jnp.bfloat16, sharding=one)
+    plans = []
+    plan = fa._plan
+    monkeypatch.setattr(
+        fa, "_plan", lambda **seen: plans.append(plan(**seen)) or plans[-1])
 
     def loss(q, k, v):
-        return flash_attention(q, k, v, causal=True, block_q=blocks,
-                               block_k=blocks).astype(jnp.float32).sum()
+        return fa.flash_attention(q, k, v, causal=causal, block_q=blocks,
+                                  block_k=blocks).astype(jnp.float32).sum()
 
     text = compile_text(jax.value_and_grad(loss, argnums=(0, 1, 2)),
                         q, q, q)
     assert text.count("tpu_custom_call") >= 3    # forward, dq, dk/dv
+    assert {p.bwd_sub for p in plans} == {sub}
 
 
 def test_flash_qkv_proj_fwd_bwd(v5e):
@@ -95,6 +109,86 @@ def test_flash_qkv_proj_fwd_bwd(v5e):
 
     text = compile_text(jax.value_and_grad(loss, argnums=(0, 1)), x, w)
     assert text.count("tpu_custom_call") >= 3
+
+
+# Equations in the two grouped backward kernels' jaxprs at the parent of
+# PR 29 (T 2048, two heads a tile, whole blocks, a masked and an unmasked
+# body each).
+HEAD_KERNEL_EQUATIONS = {"_dkdv_kernel_grouped": 176,
+                         "_dq_kernel_grouped": 149}
+
+
+def test_each_kernel_is_traced_once_a_step_not_once_a_layer(monkeypatch):
+    """The set-up guard, no chip and no compile: tracing ``jax.grad`` of a
+    three-layer ``TransformerLM`` at the cell's widths runs each kernel
+    body of the flash family once, the layers' calls sharing the traces of
+    ``_qkv_fwd`` / ``_qkv_bwd`` (three times each, the forward six, before
+    PR 29: a kernel body's cost was paid 14 times a set-up on one chip).
+    And the bodies
+    stay of a size: the pair's jaxprs hold at most three times the
+    equations they held with whole blocks only — a whole-block body and
+    seven products of the diagonal's triangle for a masked and an unmasked
+    whole-block body; the form that emits one sub-tile body, a rolled
+    loop, lost 9 ms a step on the chip (PERF.md, PR 29)."""
+    import collections
+    import functools
+
+    from horovod_tpu.models import TransformerLM
+    from horovod_tpu.ops import flash_attention as fa
+
+    calls = collections.Counter()
+
+    def counted(name, body):
+        @functools.wraps(body)
+        def call(*args, **kwargs):
+            calls[name] += 1
+            return body(*args, **kwargs)
+        return call
+
+    for name in ("_fwd_kernel", "_fwd_kernel_unrollkv",
+                 "_fwd_kernel_fullunroll", "_dq_kernel", "_dkdv_kernel",
+                 "_dq_kernel_grouped", "_dkdv_kernel_grouped"):
+        monkeypatch.setattr(fa, name, counted(name, getattr(fa, name)))
+
+    batch = 3      # no other test's: a trace made earlier would be shared
+    model = TransformerLM(vocab=512, dim=H * D, depth=3, num_heads=H,
+                          max_len=T, attn="flash", dtype=jnp.bfloat16)
+    params = jax.eval_shape(
+        lambda key: model.init(key, jnp.zeros((1, T), jnp.int32))["params"],
+        jax.random.PRNGKey(0))
+    calls.clear()
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda p, tokens: model.apply({"params": p}, tokens).astype(
+            jnp.float32).sum()))(
+        params, jax.ShapeDtypeStruct((batch, T), jnp.int32))
+    assert dict(calls) == {"_fwd_kernel_fullunroll": 1,
+                           "_dq_kernel_grouped": 1,
+                           "_dkdv_kernel_grouped": 1}
+
+    def sub_jaxprs(eqn):
+        for value in eqn.params.values():
+            for v in value if isinstance(value, (list, tuple)) else [value]:
+                v = getattr(v, "jaxpr", v)
+                if hasattr(v, "eqns"):
+                    yield v
+
+    def equations(jaxpr):
+        return sum(1 + sum(equations(j) for j in sub_jaxprs(e))
+                   for e in jaxpr.eqns)
+
+    def kernels(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                yield (eqn.params["jaxpr"].debug_info.func_name,
+                       equations(eqn.params["jaxpr"]))
+            else:
+                for j in sub_jaxprs(eqn):
+                    yield from kernels(j)
+
+    sizes = dict(kernels(jaxpr.jaxpr))
+    assert set(HEAD_KERNEL_EQUATIONS) < set(sizes)
+    for name, at_head in HEAD_KERNEL_EQUATIONS.items():
+        assert sizes[name] <= 3 * at_head, (name, sizes[name], at_head)
 
 
 def test_int8_codec_1mi(v5e, monkeypatch):
