@@ -536,18 +536,22 @@ def _wire_metrics(fn, mesh, compression, steps_per_call: int):
 
 def _moe_metrics(fn, expert_layers: dict, steps_per_call: int):
     """Per-dispatch ``moe.assignments`` (token-to-expert assignments a
-    rank routes) and ``moe.expert_bytes`` (expert parameter bytes its
-    expert layers hold, each read by every step) counters.
-    ``expert_layers`` is what the ``DroplessMoE`` layers of the step's
-    ``loss_fn`` noted of their static sizes while it was traced
-    (:func:`noting_expert_layers`); a model without them bumps nothing."""
+    rank routes), ``moe.expert_bytes`` (expert parameter bytes its
+    expert layers hold, each read by every step), ``moe.held_assignments``
+    (of the assignments, what uniform routing sends to the experts a
+    share of a layer holds), ``ssm.scan_chunks`` and ``ssm.state_bytes``
+    (chunks a state-space mixer scans, bytes of float32 state passed
+    between them) counters.  ``expert_layers`` is what the ``DroplessMoE``
+    and ``Mamba2Mixer`` layers of the step's ``loss_fn`` noted of their
+    static sizes while it was traced (:func:`noting_expert_layers`); a
+    model without them bumps nothing."""
     from horovod_tpu.metrics import registry
 
     def around(target, args, kwargs):
         out = target(*args, **kwargs)
-        for assignments, nbytes in expert_layers.values():
-            registry.inc("moe.assignments", assignments * steps_per_call)
-            registry.inc("moe.expert_bytes", nbytes * steps_per_call)
+        for counters in expert_layers.values():
+            for name, count in counters.items():
+                registry.inc(name, count * steps_per_call)
         return out
 
     return _wrap_with_stages(fn, around)

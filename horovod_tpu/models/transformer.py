@@ -33,6 +33,15 @@ the whole q and k vectors before the split into heads) and
 ``moe_experts > 0`` (the MLP becomes
 :class:`~horovod_tpu.parallel.moe.DroplessMoE`: SwiGLU experts, top-k, no
 dropped token).  :func:`OLMoELM` is OLMoE-1B-7B's setting of them.
+
+``pattern`` replaces the stack of blocks by a hybrid one, a letter a
+layer, each layer ONE sub-layer behind a pre-norm residual
+(``x + f(norm(x))``): ``M`` a Mamba-2 mixer
+(:class:`~horovod_tpu.models.ssm.Mamba2Mixer`), ``*`` grouped-query
+attention (:class:`GroupedQueryAttention`), ``E`` a ``DroplessMoE``.
+:func:`NemotronHLM` is the Nemotron-H setting.  One tower, causal,
+trained under next-token cross-entropy: no denoising objective and no
+conditioning between towers.
 """
 
 from __future__ import annotations
@@ -162,6 +171,74 @@ class Attention(nn.Module):
         out = out.reshape(B, T, C)
         return nn.Dense(C, use_bias=False, dtype=self.dtype,
                         param_dtype=jnp.float32, name="proj")(out)
+
+
+class GroupedQueryAttention(nn.Module):
+    """Causal attention of ``num_heads`` query heads over ``kv_heads``
+    key-value heads of ``head_dim`` (query head ``h`` reads KV head
+    ``h // (num_heads / kv_heads)``), no bias, no position encoding; the
+    heads' total width need not be the model's.  Parameters ``q``, ``kv``
+    (keys | values) and ``proj``.  ``attn="flash"`` reads the grouped keys
+    and values in place (:func:`~horovod_tpu.ops.flash_attention.
+    flash_attention`); ``"full"`` repeats them for the dense oracle."""
+    num_heads: int
+    kv_heads: int
+    head_dim: int
+    attn: str = "flash"
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x):
+        B, T, C = x.shape
+        H, Hkv, D = self.num_heads, self.kv_heads, self.head_dim
+
+        def dense(features, name):
+            return nn.Dense(features, use_bias=False, dtype=self.dtype,
+                            param_dtype=jnp.float32, name=name)
+
+        q = dense(H * D, "q")(x).reshape(B, T, H, D)
+        k, v = jnp.split(dense(2 * Hkv * D, "kv")(x), 2, axis=-1)
+        k, v = k.reshape(B, T, Hkv, D), v.reshape(B, T, Hkv, D)
+        if self.attn == "flash":
+            out = flash_attention_auto(q, k, v, causal=True)
+        elif self.attn == "full":
+            out = full_attention(q, jnp.repeat(k, H // Hkv, axis=2),
+                                 jnp.repeat(v, H // Hkv, axis=2), causal=True)
+        else:
+            raise ValueError("grouped-query attention runs attn='flash' or "
+                             f"'full', not {self.attn!r}")
+        return dense(C, "proj")(out.reshape(B, T, H * D))
+
+
+class PatternLayer(nn.Module):
+    """One layer of a pattern stack: ``x + f(norm(x))`` with ``f`` the one
+    sub-layer ``kind`` names — ``"M"`` (submodule ``ssm``), ``"*"``
+    (``attn``) or ``"E"`` (``moe``).  ``sub`` holds that sub-layer's
+    fields."""
+    kind: str
+    sub: Any
+    dtype: Any = jnp.bfloat16
+    ln_dtype: Any = jnp.float32
+    norm: str = "rms"
+    norm_eps: float = 1e-5
+
+    @nn.compact
+    def __call__(self, x):
+        h = _norm(self.norm, self.norm_eps, self.ln_dtype, "norm")(x)
+        if self.kind == "M":
+            from horovod_tpu.models.ssm import Mamba2Mixer
+            y = Mamba2Mixer(**self.sub, norm_eps=self.norm_eps,
+                            dtype=self.dtype, name="ssm")(h)
+        elif self.kind == "*":
+            y = GroupedQueryAttention(**self.sub, dtype=self.dtype,
+                                      name="attn")(h)
+        elif self.kind == "E":
+            y, _, _ = DroplessMoE(**self.sub, dtype=self.dtype,
+                                  name="moe")(h)
+        else:
+            raise ValueError(f"unknown layer {self.kind!r} in a pattern: "
+                             "'M', '*' or 'E'")
+        return x + y
 
 
 class Block(nn.Module):
@@ -296,6 +373,17 @@ class TransformerLM(nn.Module):
     moe_experts: int = 0
     moe_top_k: int = 0
     moe_hidden: int = 0
+    # A hybrid stack (module docstring): one letter a layer, ``depth`` is
+    # then the pattern's length whatever it says, and ``pos`` must be
+    # "none" (the mixers carry the order).  ``ssm``: the fields of
+    # Mamba2Mixer; ``*`` layers have num_heads query heads over kv_heads
+    # KV heads of head_dim; ``moe``: DroplessMoE's further fields (router,
+    # renormalize, gate_scale, activation, shared_hidden, held).
+    pattern: Optional[str] = None
+    ssm: Any = None
+    kv_heads: Optional[int] = None
+    head_dim: Optional[int] = None
+    moe: Any = None
 
     @nn.compact
     def __call__(self, tokens, return_hidden=False):
@@ -310,11 +398,19 @@ class TransformerLM(nn.Module):
                 "tp_axis composes with attn='full' only (TP attention "
                 f"computes the full sequence locally); got {self.attn!r}")
         if self.tp_axis and (self.moe_experts or self.qk_norm
-                             or self.pos != "learned"):
+                             or self.pos != "learned" or self.pattern
+                             or self.moe):
             raise ValueError("tp_axis runs the GPT-2 block only: no "
-                             "experts, QK-norm or rotary positions")
-        if self.pos not in ("learned", "rotary"):
+                             "experts (whole or a held share), QK-norm, "
+                             "rotary positions or pattern stack")
+        if self.pos not in ("learned", "rotary", "none"):
             raise ValueError(f"unknown pos: {self.pos!r}")
+        if self.pattern is not None:
+            return self._pattern_stack(tokens, return_hidden)
+        if self.pos == "none" or self.moe or self.ssm:
+            raise ValueError("pos='none', ssm= and moe= belong to a "
+                             "pattern stack; the block stack takes learned "
+                             "or rotary positions and moe_experts")
         rotary = self.pos == "rotary"
         B, T = tokens.shape
         if self.attn in ("full", "flash"):
@@ -345,6 +441,62 @@ class TransformerLM(nn.Module):
             return x
         return nn.Dense(self.vocab, use_bias=False, dtype=self.head_dtype,
                         param_dtype=jnp.float32, name="head")(x)
+
+    def _pattern_stack(self, tokens, return_hidden):
+        if self.attn not in ("full", "flash") or self.pos != "none":
+            raise ValueError("a pattern stack runs whole sequences "
+                             "(attn='full' or 'flash') with pos='none'; got "
+                             f"attn={self.attn!r}, pos={self.pos!r}")
+        subs = {
+            "M": dict(self.ssm or {}),
+            "*": dict(num_heads=self.num_heads, kv_heads=self.kv_heads,
+                      head_dim=self.head_dim, attn=self.attn),
+            "E": dict(num_experts=self.moe_experts, hidden=self.moe_hidden,
+                      top_k=self.moe_top_k, **dict(self.moe or {})),
+        }
+        x = nn.Embed(self.vocab, self.dim, param_dtype=jnp.float32,
+                     dtype=self.dtype, name="tok_emb")(tokens)
+        for i, kind in enumerate(self.pattern):
+            x = PatternLayer(kind, subs.get(kind), dtype=self.dtype,
+                             ln_dtype=self.ln_dtype, norm=self.norm,
+                             norm_eps=self.norm_eps, name=f"layer_{i}")(x)
+        x = _norm(self.norm, self.norm_eps, self.ln_dtype, "ln_f")(x)
+        if return_hidden:
+            return x
+        return nn.Dense(self.vocab, use_bias=False, dtype=self.head_dtype,
+                        param_dtype=jnp.float32, name="head")(x)
+
+
+def NemotronHLM(**overrides) -> TransformerLM:
+    """The Nemotron-H stack that ``nvidia/Nemotron-Labs-TwoTower-30B-A3B-
+    Base-BF16``'s config.json describes, as a :class:`TransformerLM` with
+    a ``pattern``: 52 layers ``MEMEM*E...`` at d 2688, RMSNorm eps 1e-5;
+    ``M`` Mamba-2 mixers of 64 heads of 64, 8 groups, state 128, conv 4,
+    chunks of 128; ``*`` attention of 32 query heads over 2 KV heads of
+    128 without rotary positions; ``E`` 128 relu² experts 1856 wide,
+    top-6 by sigmoid scores renormalised and scaled by 2.5, and one shared
+    expert 3712 wide; vocab 131072, untied head.  ``overrides`` replace
+    any field: a cut takes the first letters of the pattern, and
+    ``moe={..., "held": (first, count)}`` keeps one chip's share of every
+    layer's experts (``parallel.moe.DroplessMoE``).
+
+    What is NOT here: the model card's second, denoising tower, its
+    conditioning on this one, in-block bidirectional attention and the
+    block-diffusion objective (config.json holds no key of theirs).  This
+    is one causal tower under next-token cross-entropy, trained like
+    :func:`OLMoELM` through ``make_train_step`` and ``fused_softmax_xent``.
+    """
+    fields = dict(
+        vocab=131072, dim=2688, num_heads=32, kv_heads=2, head_dim=128,
+        max_len=262144, norm="rms", norm_eps=1e-5, pos="none",
+        pattern="MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME",
+        ssm=dict(num_heads=64, head_dim=64, n_groups=8, state_size=128,
+                 conv_kernel=4, chunk=128),
+        moe_experts=128, moe_top_k=6, moe_hidden=1856,
+        moe=dict(router="sigmoid", renormalize=True, gate_scale=2.5,
+                 activation="relu2", shared_hidden=3712))
+    fields.update(overrides)
+    return TransformerLM(**fields)
 
 
 def OLMoELM(**overrides) -> TransformerLM:

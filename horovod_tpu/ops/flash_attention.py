@@ -49,6 +49,13 @@ backward, a fully-unrolled backward, a transpose-to-merged backward, a
 chunked XLA backward) left at PR 27; docs/benchmarks.md keeps their
 measurements.
 
+Grouped-query attention: ``k`` and ``v`` may hold fewer heads than ``q``.
+At lane-aligned heads nothing is repeated in HBM: the forward's and the dq
+kernel's index maps send query head ``h`` to KV head ``h // kv_rep``, and
+the per-head dk/dv kernel's innermost grid axis runs the Q blocks of the
+``kv_rep`` query heads of a KV head one after another, so that their sums
+form in its scratch.  The grouped pair stands down there.
+
 Composition: this is the *single-chip* block; for sequences sharded
 across chips use :mod:`horovod_tpu.parallel.ring_attention`, which
 streams K/V between chips with the same online-softmax math.
@@ -406,8 +413,16 @@ _FULL_UNROLL_BLOCK = 512
 _FULL_UNROLL_MAX_NQ = 8
 
 
+def _kv_head(kv_rep: int):
+    """Query head -> the head whose keys and values it reads: itself, or,
+    where ``kv_rep`` query heads share one KV head (grouped-query
+    attention), ``h // kv_rep`` — an index map's arithmetic, so the keys
+    and values are read where they lie and never repeated in HBM."""
+    return (lambda h: h) if kv_rep == 1 else (lambda h: h // kv_rep)
+
+
 def _fwd_packed(q, k, v, H, D, plan, *, scale, causal, block_q, block_k,
-                interpret, seq_len=None, head_base=(0, 0, 0)):
+                interpret, seq_len=None, head_base=(0, 0, 0), kv_rep=1):
     """Forward on head-packed (B, T, C) views (C = H*D): the head is a
     grid axis and every BlockSpec offsets its last dim by ``h*D``, so no
     (B, T, H, D) -> (B*H, T, D) transpose copy ever materializes in HBM
@@ -415,12 +430,14 @@ def _fwd_packed(q, k, v, H, D, plan, *, scale, causal, block_q, block_k,
     docs/benchmarks.md).  ``head_base`` shifts each operand's head-block
     offset, letting q/k/v be three regions of ONE fused (B, T, 3*H*D)
     projection (so the qkv split never copies either).  ``plan`` is
-    :func:`_plan`'s: which of the three forms runs.  lse comes back as
-    (B, H, T)."""
+    :func:`_plan`'s: which of the three forms runs.  ``kv_rep`` query
+    heads read each KV head (``k``, ``v`` hold ``H // kv_rep`` heads).
+    lse comes back as (B, H, T)."""
     B, T, _ = q.shape
     nq = T // block_q
     nk = T // block_k
     oq, ok_, ov = head_base
+    kvh = _kv_head(kv_rep)
     if plan.fwd == "fullunroll":
         # This form re-tiles internally (the tile size is a schedule
         # detail — flash results are block-size independent up to f32
@@ -433,8 +450,8 @@ def _fwd_packed(q, k, v, H, D, plan, *, scale, causal, block_q, block_k,
             grid=(B, H),
             in_specs=[
                 pl.BlockSpec((1, T, D), lambda b, h: (b, 0, h + oq)),
-                pl.BlockSpec((1, T, D), lambda b, h: (b, 0, h + ok_)),
-                pl.BlockSpec((1, T, D), lambda b, h: (b, 0, h + ov)),
+                pl.BlockSpec((1, T, D), lambda b, h: (b, 0, kvh(h) + ok_)),
+                pl.BlockSpec((1, T, D), lambda b, h: (b, 0, kvh(h) + ov)),
             ],
             out_specs=[
                 pl.BlockSpec((1, T, D), lambda b, h: (b, 0, h)),
@@ -459,8 +476,10 @@ def _fwd_packed(q, k, v, H, D, plan, *, scale, causal, block_q, block_k,
             in_specs=[
                 pl.BlockSpec((1, block_q, D),
                              lambda b, h, i: (b, i, h + oq)),
-                pl.BlockSpec((1, T, D), lambda b, h, i: (b, 0, h + ok_)),
-                pl.BlockSpec((1, T, D), lambda b, h, i: (b, 0, h + ov)),
+                pl.BlockSpec((1, T, D),
+                             lambda b, h, i: (b, 0, kvh(h) + ok_)),
+                pl.BlockSpec((1, T, D),
+                             lambda b, h, i: (b, 0, kvh(h) + ov)),
             ],
             out_specs=[
                 pl.BlockSpec((1, block_q, D), lambda b, h, i: (b, i, h)),
@@ -492,9 +511,9 @@ def _fwd_packed(q, k, v, H, D, plan, *, scale, causal, block_q, block_k,
             pl.BlockSpec((1, block_q, D),
                          lambda b, h, i, j: (b, i, h + oq)),
             pl.BlockSpec((1, block_k, D),
-                         lambda b, h, i, j: (b, j, h + ok_)),
+                         lambda b, h, i, j: (b, j, kvh(h) + ok_)),
             pl.BlockSpec((1, block_k, D),
-                         lambda b, h, i, j: (b, j, h + ov)),
+                         lambda b, h, i, j: (b, j, kvh(h) + ov)),
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, D), lambda b, h, i, j: (b, i, h)),
@@ -520,16 +539,19 @@ def _fwd_packed(q, k, v, H, D, plan, *, scale, causal, block_q, block_k,
 
 def _dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dta_ref,
                  dk_ref, dv_ref, dk_scr, dv_scr, *,
-                 scale, causal, block_q, block_k, seq_len):
+                 scale, causal, block_q, block_k, seq_len, kv_rep=1):
     """Accumulate dk/dv for one KV block while Q blocks stream through
     (grid innermost axis).  The flash-backward identities:
     p = exp(s - lse);  dv += p^T dO;  dS = p * (dO V^T - delta) * scale;
-    dk += dS^T Q."""
+    dk += dS^T Q.  Where ``kv_rep`` query heads read this KV head, the
+    innermost axis runs the Q blocks of one of them after another's, and
+    the sums over them are formed here, in the scratch."""
     kj = pl.program_id(2)
-    qi = pl.program_id(3)
+    step = pl.program_id(3)
     nq = pl.num_programs(3)
+    qi = step if kv_rep == 1 else lax.rem(step, nq // kv_rep)
 
-    @pl.when(qi == 0)
+    @pl.when(step == 0)
     def _init():
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
@@ -566,7 +588,7 @@ def _dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dta_ref,
     _masked_dispatch(_compute, live, qi, kj, block_q, block_k, causal,
                      seq_len)
 
-    @pl.when(qi == nq - 1)
+    @pl.when(step == nq - 1)
     def _finalize():
         dk_ref[0] = dk_scr[...].astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
@@ -883,7 +905,7 @@ def _bwd_pallas_packed_grouped(q, k, v, o, lse, do, H, D, group, *, scale,
 
 def _bwd_pallas_packed(q, k, v, o, lse, do, H, D, plan, *, scale, causal,
                        block_q, block_k, interpret, seq_len=None,
-                       head_base=(0, 0, 0)):
+                       head_base=(0, 0, 0), kv_rep=1):
     """Split flash backward on head-packed (B, T, C) views (see
     :func:`_fwd_packed`); ``lse`` arrives as (B, H, T) and ``o``/``do``
     are head-merged (B, T, H*D).  ``plan`` is :func:`_plan`'s: the pair
@@ -903,6 +925,21 @@ def _bwd_pallas_packed(q, k, v, o, lse, do, H, D, plan, *, scale, causal,
     nq = T // block_q
     nk = T // block_k
     oq, ok_, ov = head_base
+    kvh = _kv_head(kv_rep)
+    # The dk/dv kernel's grid runs over the KV heads; its innermost axis
+    # holds the Q blocks of each of the kv_rep query heads that read one.
+    if kv_rep == 1:
+        def q_head(h, i):
+            return h
+
+        def q_block(i):
+            return i
+    else:
+        def q_head(h, i):
+            return h * kv_rep + i // nq
+
+        def q_block(i):
+            return i % nq
     # Per-head delta = rowsum(dO * O): reduce D inside each head.
     delta = jnp.sum((do.astype(jnp.float32)
                      * o.astype(jnp.float32)).reshape(B, T, H, D),
@@ -912,15 +949,17 @@ def _bwd_pallas_packed(q, k, v, o, lse, do, H, D, plan, *, scale, causal,
 
     kv_specs = dict(
         q=pl.BlockSpec((1, block_q, D),
-                       lambda b, h, j, i: (b, i, h + oq)),
+                       lambda b, h, j, i: (b, q_block(i), q_head(h, i) + oq)),
         k=pl.BlockSpec((1, block_k, D),
                        lambda b, h, j, i: (b, j, h + ok_)),
         v=pl.BlockSpec((1, block_k, D),
                        lambda b, h, j, i: (b, j, h + ov)),
-        do=pl.BlockSpec((1, block_q, D), lambda b, h, j, i: (b, i, h)),
+        do=pl.BlockSpec((1, block_q, D),
+                        lambda b, h, j, i: (b, q_block(i), q_head(h, i))),
         out=pl.BlockSpec((1, block_k, D), lambda b, h, j, i: (b, j, h)),
         row8=pl.BlockSpec((1, 1, block_q, 8),
-                          lambda b, h, j, i: (b, h, i, 0)),
+                          lambda b, h, j, i: (b, q_head(h, i), q_block(i),
+                                              0)),
     )
     sem4 = pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "parallel",
@@ -928,13 +967,13 @@ def _bwd_pallas_packed(q, k, v, o, lse, do, H, D, plan, *, scale, causal,
     dk, dv = pl.pallas_call(
         functools.partial(_dkdv_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k,
-                          seq_len=seq_len),
-        grid=(B, H, nk, nq),
+                          seq_len=seq_len, kv_rep=kv_rep),
+        grid=(B, H // kv_rep, nk, kv_rep * nq),
         in_specs=[kv_specs["q"], kv_specs["k"], kv_specs["v"],
                   kv_specs["do"], kv_specs["row8"], kv_specs["row8"]],
         out_specs=[kv_specs["out"], kv_specs["out"]],
-        out_shape=[_struct((B, T, C), k.dtype, q, k, v, do),
-                   _struct((B, T, C), v.dtype, q, k, v, do)],
+        out_shape=[_struct((B, T, C // kv_rep), k.dtype, q, k, v, do),
+                   _struct((B, T, C // kv_rep), v.dtype, q, k, v, do)],
         scratch_shapes=[pltpu.VMEM((block_k, D), jnp.float32),
                         pltpu.VMEM((block_k, D), jnp.float32)],
         compiler_params=sem4,
@@ -945,9 +984,9 @@ def _bwd_pallas_packed(q, k, v, o, lse, do, H, D, plan, *, scale, causal,
         q=pl.BlockSpec((1, block_q, D),
                        lambda b, h, i, j: (b, i, h + oq)),
         k=pl.BlockSpec((1, block_k, D),
-                       lambda b, h, i, j: (b, j, h + ok_)),
+                       lambda b, h, i, j: (b, j, kvh(h) + ok_)),
         v=pl.BlockSpec((1, block_k, D),
-                       lambda b, h, i, j: (b, j, h + ov)),
+                       lambda b, h, i, j: (b, j, kvh(h) + ov)),
         do=pl.BlockSpec((1, block_q, D), lambda b, h, i, j: (b, i, h)),
         out=pl.BlockSpec((1, block_q, D), lambda b, h, i, j: (b, i, h)),
         row8=pl.BlockSpec((1, 1, block_q, 8),
@@ -1017,7 +1056,7 @@ def _bwd_live_share(T, causal, block_q, block_k, sub) -> float:
 
 def _plan(*, T, D, H, head_base, itemsize, causal, block_q, block_k,
           bwd_block_q, bwd_block_k, interpret, manual_axes,
-          vmem_headroom) -> _Plan:
+          vmem_headroom, kv_rep=1) -> _Plan:
     """Which forward form and which backward pair run, and the VMEM limit
     each is compiled with — the one place that chooses, from what the op
     observes at trace time and nothing else.
@@ -1027,7 +1066,8 @@ def _plan(*, T, D, H, head_base, itemsize, causal, block_q, block_k,
     bytes an operand element; ``causal``; the four resolved blocks;
     ``manual_axes``: whether the operands vary over manual mesh axes
     (``shard_map``); ``vmem_headroom``: :func:`_vmem_headroom_ok` —
-    whether the device backs a scoped budget above Mosaic's default."""
+    whether the device backs a scoped budget above Mosaic's default;
+    ``kv_rep``: query heads a KV head (1: multi-head attention)."""
     if D % 128:
         # Heads off the lane width arrive merged into the batch (H is 1,
         # see flash_attention).  Only these two forms have run on a chip
@@ -1067,6 +1107,10 @@ def _plan(*, T, D, H, head_base, itemsize, causal, block_q, block_k,
     if (bwd_block_q == 1024 and bwd_block_k == 1024 and D == 128
             and H % _GROUPED_HEADS == 0
             and all(b % _GROUPED_HEADS == 0 for b in head_base)
+            # The grouped pair reads the K and V of its two query heads
+            # as adjacent lanes; under grouped KV heads the two read the
+            # same ones, which only the per-head pair's index maps do.
+            and kv_rep == 1
             and vmem_headroom):
         # Only the grouped pair has been timed with its diagonal blocks
         # cut into sub-tiles.
@@ -1079,14 +1123,14 @@ def _plan(*, T, D, H, head_base, itemsize, causal, block_q, block_k,
 
 
 def _plan_for(q, H, D, head_base, causal, block_q, block_k, bwd_block_q,
-              bwd_block_k, interpret) -> _Plan:
+              bwd_block_k, interpret, kv_rep=1) -> _Plan:
     """:func:`_plan` for the operand ``q`` of a custom-VJP rule."""
     return _plan(T=q.shape[1], D=D, H=H, head_base=head_base,
                  itemsize=q.dtype.itemsize, causal=causal, block_q=block_q,
                  block_k=block_k, bwd_block_q=bwd_block_q,
                  bwd_block_k=bwd_block_k, interpret=interpret,
                  manual_axes=bool(jax.typeof(q).vma),
-                 vmem_headroom=_vmem_headroom_ok())
+                 vmem_headroom=_vmem_headroom_ok(), kv_rep=kv_rep)
 
 
 @functools.partial(jax.custom_vjp,
@@ -1101,11 +1145,13 @@ def _flash_packed(q, k, v, H, scale, causal, block_q, block_k,
 def _flash_packed_fwd(q, k, v, H, scale, causal, block_q, block_k,
                       bwd_block_q, bwd_block_k, interpret, seq_len):
     D = q.shape[2] // H
+    kv_rep = q.shape[2] // k.shape[2]
     plan = _plan_for(q, H, D, (0, 0, 0), causal, block_q, block_k,
-                     bwd_block_q, bwd_block_k, interpret)
+                     bwd_block_q, bwd_block_k, interpret, kv_rep)
     out, lse = _fwd_packed(q, k, v, H, D, plan, scale=scale, causal=causal,
                            block_q=block_q, block_k=block_k,
-                           interpret=interpret, seq_len=seq_len)
+                           interpret=interpret, seq_len=seq_len,
+                           kv_rep=kv_rep)
     return out, (q, k, v, out, lse)
 
 
@@ -1113,12 +1159,13 @@ def _flash_packed_bwd(H, scale, causal, block_q, block_k, bwd_block_q,
                       bwd_block_k, interpret, seq_len, res, do):
     q, k, v, o, lse = res
     D = q.shape[2] // H
+    kv_rep = q.shape[2] // k.shape[2]
     plan = _plan_for(q, H, D, (0, 0, 0), causal, block_q, block_k,
-                     bwd_block_q, bwd_block_k, interpret)
+                     bwd_block_q, bwd_block_k, interpret, kv_rep)
     return _bwd_pallas_packed(q, k, v, o, lse, do, H, D, plan, scale=scale,
                               causal=causal, block_q=bwd_block_q,
                               block_k=bwd_block_k, interpret=interpret,
-                              seq_len=seq_len)
+                              seq_len=seq_len, kv_rep=kv_rep)
 
 
 _flash_packed.defvjp(_flash_packed_fwd, _flash_packed_bwd)
@@ -1392,7 +1439,11 @@ def flash_attention(q, k, v, *, causal: bool = True,
                     interpret: bool = False,
                     seq_len: Optional[int] = None):
     """Fused flash attention for ``(B, T, H, D)`` inputs (same contract as
-    :func:`~horovod_tpu.parallel.ring_attention.full_attention`).
+    :func:`~horovod_tpu.parallel.ring_attention.full_attention`).  ``k`` and
+    ``v`` may hold fewer heads, ``(B, T, Hkv, D)`` with ``Hkv`` dividing
+    ``H`` (grouped-query attention: query head ``h`` reads KV head
+    ``h // (H / Hkv)``); at lane-aligned ``D`` they are read in place, and
+    ``dk``, ``dv`` come back summed over the query heads of a group.
 
     Block sizes default to :func:`auto_block` (the largest multiple-of-8
     divisor of ``T`` up to 1024 — the largest square block whose f32
@@ -1406,6 +1457,17 @@ def flash_attention(q, k, v, *, causal: bool = True,
     and backward.  Set ``interpret=True`` to run off-TPU (tests).
     """
     B, T, H, D = q.shape
+    Hkv = k.shape[2]
+    if k.shape != v.shape or H % Hkv:
+        raise ValueError(
+            f"flash_attention: k {k.shape} and v {v.shape} must agree, in "
+            f"heads that divide q's {H}")
+    if D % 128 and Hkv != H:
+        # Heads off the lane width are merged into the batch below, one
+        # (T, D) slab a head: only there are grouped keys and values
+        # repeated.
+        k, v = (jnp.repeat(a, H // Hkv, axis=2) for a in (k, v))
+        Hkv = H
     if scale is None:
         scale = 1.0 / (D ** 0.5)
     block_q, block_k, bwd_block_q, bwd_block_k, seq_len = _resolve_blocks(
@@ -1420,8 +1482,9 @@ def flash_attention(q, k, v, *, causal: bool = True,
     # views via head-offset BlockSpecs — the reshape is free
     # (contiguous), so no transpose copy ever hits HBM.
     if D % 128 == 0:
-        out = _flash_packed(q.reshape(B, T, H * D), k.reshape(B, T, H * D),
-                            v.reshape(B, T, H * D), int(H), *static)
+        out = _flash_packed(q.reshape(B, T, H * D),
+                            k.reshape(B, T, Hkv * D),
+                            v.reshape(B, T, Hkv * D), int(H), *static)
         return out.reshape(B, T, H, D)
 
     # A head off the lane width cannot be addressed inside a packed row:

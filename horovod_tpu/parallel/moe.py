@@ -31,17 +31,18 @@ model-parallel modules; expert params are VMA-varying over ``ep``.
 shard, top-k of them per token, no capacity and no dropped token —
 assignments sorted by expert and a grouped matmul over the sorted rows.
 It is what a model with more experts than chips runs data-parallel today
-(experts replicated), and the local half an expert-parallel layer will
-wrap between two all-to-alls.  Until the two are folded into one
-(ROADMAP R2): :class:`MoELayer` where each chip of an ``ep`` axis holds
-one GELU expert and a capacity is acceptable, :class:`DroplessMoE`
-everywhere else.
+(experts replicated) and, told which experts it ``held``, the local half
+an expert-parallel layer will wrap between two all-to-alls (one chip's
+share of a layer, without its exchange; ROADMAP R1).  Until the two are
+folded into one (ROADMAP D11): :class:`MoELayer` where each chip of an
+``ep`` axis holds one GELU expert and a capacity is acceptable,
+:class:`DroplessMoE` everywhere else.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Any, Callable
+from typing import Any, Callable, Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -170,6 +171,30 @@ class MoELayer(nn.Module):
 # ------------------------------------------------------------ dropless
 
 
+# Rows in a window of a layer that holds a share of its experts, as a
+# multiple of what uniform routing sends to that share: what the layer
+# provisions for, as an expert-parallel layer sizes its receive buffer.
+# Random weights put up to 1.7 times the uniform load on a share, and
+# training without a balancing term moved one between 0 and 3.6 times
+# within 40 steps (PERF.md section 6, PR 30): at 2 a fifth of those steps
+# overflowed, at 3 one in forty.  Memory is the other side: the window's
+# rows live in HBM while a layer runs.
+_HELD_WINDOW = 3
+
+# The held experts' hidden width is padded with zero columns to a multiple
+# of this inside the grouped matmuls (the parameters keep their width; a
+# width that is a multiple already, as OLMoE's 1024, is left alone): on the
+# v5e ``lax.ragged_dot`` ran at 33 TFLOP/s at a hidden width of 1856 or
+# 1920 and at 63-93 at 2048 (PERF.md section 6, PR 30).
+_HIDDEN_TILE = 256
+
+
+def _pad_hidden(a, axis: int):
+    pad = -a.shape[axis] % _HIDDEN_TILE
+    if not pad:
+        return a
+    return jnp.pad(a, [(0, pad if i == axis else 0) for i in range(a.ndim)])
+
 # What make_train_step wants to know of the expert layers its loss_fn
 # holds: dicts that a DroplessMoE traced meanwhile writes its static sizes
 # into, keyed by its module path (so a second trace of the same layer
@@ -178,9 +203,10 @@ _NOTING: list = []
 
 
 def noting_expert_layers(fn: Callable, into: dict) -> Callable:
-    """``fn``, with every :class:`DroplessMoE` traced inside a call of it
-    written into ``into`` as ``{module path: (assignments, expert
-    parameter bytes)}`` — one step's, per shard, from shapes alone."""
+    """``fn``, with every :class:`DroplessMoE` (and every state-space
+    mixer) traced inside a call of it written into ``into`` as ``{module
+    path: {counter name: one step's count}}`` — per shard, from shapes
+    alone (``moe.assignments``, ``moe.expert_bytes``, ...)."""
 
     @functools.wraps(fn)
     def noting(*args, **kwargs):
@@ -191,6 +217,13 @@ def noting_expert_layers(fn: Callable, into: dict) -> Callable:
             _NOTING.pop()
 
     return noting
+
+
+def note_layer(path, counters: dict) -> None:
+    """What a layer being traced tells :func:`noting_expert_layers`'s
+    callers of one step's static counts (``{counter name: count}``)."""
+    for noted in _NOTING:
+        noted[path] = counters
 
 
 @jax.custom_vjp
@@ -233,28 +266,73 @@ def _to_token_order_bwd(order, g):
 _to_token_order.defvjp(_to_token_order_fwd, _to_token_order_bwd)
 
 
-class DroplessMoE(nn.Module):
-    """Top-k of ``num_experts`` SwiGLU experts, all on this shard; no
-    capacity, so no token is dropped whatever the imbalance.
+class _SharedExpert(nn.Module):
+    """One relu² expert that every token runs: ``W_down relu(W_up x)²``."""
+    hidden: int
+    dtype: Any = jnp.bfloat16
+    param_dtype: Any = jnp.float32
 
-    ``y = Σ_{e ∈ topk} p_e · W_down,e (silu(W_gate,e x) ⊙ W_up,e x)`` with
-    ``p`` the router's softmax over all experts, not renormalised over
-    the chosen k.  The router and its softmax run in float32 at full
-    matmul precision (a TPU's default float32 matmul rounds its operands
-    to bfloat16, which moves the k-th choice of many tokens); the experts
-    run in ``dtype``.  The k·N assignments are sorted by expert and each
-    projection is one grouped matmul over the sorted rows
-    (``lax.ragged_dot``: FLOPs follow the assignments, N·k, not N·E), all
-    shapes static.
+    @nn.compact
+    def __call__(self, x):
+        d = x.shape[-1]
+        init = nn.initializers.lecun_normal()
+        w_up = self.param("w_up", init, (d, self.hidden), self.param_dtype)
+        w_down = self.param("w_down", init, (self.hidden, d),
+                            self.param_dtype)
+        h = jnp.square(nn.relu(x @ w_up.astype(self.dtype)))
+        return h @ w_down.astype(self.dtype)
+
+
+class DroplessMoE(nn.Module):
+    """Top-k of ``num_experts`` experts on this shard; no capacity, so no
+    token is dropped whatever the imbalance.
+
+    The defaults are OLMoE's layer: ``y = Σ_{e ∈ topk} p_e · W_down,e
+    (silu(W_gate,e x) ⊙ W_up,e x)`` with ``p`` the router's softmax over all
+    experts, not renormalised over the chosen k.  The router and its
+    scores run in float32 at full matmul precision (a TPU's default
+    float32 matmul rounds its operands to bfloat16, which moves the k-th
+    choice of many tokens); the experts run in ``dtype``.  The k·N
+    assignments are sorted by expert and each projection is one grouped
+    matmul over the sorted rows (``lax.ragged_dot``: FLOPs follow the
+    assignments, N·k, not N·E), all shapes static.
+
+    The other settings (Nemotron-H's layer sets all of them):
+
+    * ``router="sigmoid"``: scores ``s = sigmoid(W_r x)``, the k largest
+      chosen (a correction bias added for the choice alone would go here;
+      none is kept: it is zero until something trains it);
+      ``renormalize``: gates ``s_e / Σ_chosen s``; ``gate_scale``
+      multiplies them.
+    * ``activation="relu2"``: experts of two matrices,
+      ``W_down,e relu(W_up,e x)²``.
+    * ``shared_hidden > 0``: one more relu² expert that wide, run by every
+      token with gate 1 (submodule and trace scope ``shared``).
+    * ``held=(first, count)``: this shard HOLDS experts ``first ..
+      first + count - 1`` of the ``num_experts`` it routes over — one
+      chip's share of an expert-parallel layer, without its exchange.
+      The k are chosen and the gates normalised over all experts;
+      assignments to experts held elsewhere add nothing here, and the
+      parameters are the held experts' alone.  Still no capacity: the
+      held experts' assignments are sorted to the front and the grouped
+      matmuls run over the first ``R`` sorted rows, ``R`` three times what
+      uniform routing would send here (rows past the last assignment are
+      zeros: a step costs the same wherever its tokens go); a step whose
+      routing sends more (the device sees the count) runs the further
+      windows of ``R`` rows in turn (scope ``overflowed``; their empty
+      rows are skipped), so none is lost.  The expert block is recomputed in the backward
+      pass, so no window's rows are kept.  Sown beside the rest:
+      ``held_assignments``, the number that landed here.
 
     Input ``(..., d)``.  Returns ``(output, load_balance, router_z)``:
     the load-balancing term ``E · Σ_e f_e · P_e`` (``f_e`` = assignments
-    to ``e`` ÷ tokens, ``P_e`` = mean router probability; ``top_k`` when
-    routing is uniform) and the router z-loss ``mean(logsumexp(logits)²)``
-    of this shard's tokens, unweighted.  Both are also sown as
-    intermediates ``aux_load_balance`` / ``aux_router_z``
-    (:func:`router_losses` sums them over a model's layers), beside
-    ``tokens_per_expert`` (E,) and ``expert_index`` (N, k).
+    to ``e`` ÷ tokens, ``P_e`` = mean router score; ``top_k`` when
+    routing is uniform under a softmax) and the router z-loss
+    ``mean(logsumexp(logits)²)`` of this shard's tokens, unweighted.
+    Both are also sown as intermediates ``aux_load_balance`` /
+    ``aux_router_z`` (:func:`router_losses` sums them over a model's
+    layers), beside ``tokens_per_expert`` (E,) and ``expert_index``
+    (N, k).
     """
 
     num_experts: int
@@ -262,12 +340,22 @@ class DroplessMoE(nn.Module):
     top_k: int
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
+    router: str = "softmax"              # "softmax" | "sigmoid"
+    renormalize: bool = False
+    gate_scale: float = 1.0
+    activation: str = "swiglu"           # "swiglu" | "relu2"
+    shared_hidden: int = 0
+    held: Optional[Tuple[int, int]] = None
 
     @nn.compact
     def __call__(self, x):
         E, k = self.num_experts, self.top_k
         if not 1 <= k <= E:
             raise ValueError(f"top_k={k} out of range for {E} experts")
+        if self.router not in ("softmax", "sigmoid") or (
+                self.activation not in ("swiglu", "relu2")):
+            raise ValueError(f"unknown router {self.router!r} or "
+                             f"activation {self.activation!r}")
         lead, d = x.shape[:-1], x.shape[-1]
         x = x.reshape(-1, d)
         n = x.shape[0]
@@ -277,32 +365,35 @@ class DroplessMoE(nn.Module):
                               param_dtype=self.param_dtype,
                               precision=lax.Precision.HIGHEST,
                               name="router")(x.astype(jnp.float32))
-            probs = jax.nn.softmax(logits, axis=-1)               # (N, E)
+            if self.router == "softmax":
+                probs = jax.nn.softmax(logits, axis=-1)           # (N, E)
+            else:
+                probs = jax.nn.sigmoid(logits)
             gate, expert = lax.top_k(probs, k)                    # (N, k)
+            if self.renormalize:
+                gate = gate / (gate.sum(axis=-1, keepdims=True) + 1e-20)
+            if self.gate_scale != 1.0:
+                gate = gate * self.gate_scale
 
-        with jax.named_scope("dispatch"):
-            flat = expert.reshape(-1)              # assignment a: token a // k
-            order = jnp.argsort(flat, stable=True)
-            inverse = jnp.argsort(order)
-            tokens_per_expert = jnp.bincount(flat, length=E).astype(
-                jnp.int32)
-            rows = _to_expert_order(x.astype(self.dtype), order, inverse)
+        names = (("w_gate", "w_up", "w_down") if self.activation == "swiglu"
+                 else ("w_up", "w_down"))
+        if self.held is None:
+            out, tokens_per_expert = self._all_experts(x, gate, expert,
+                                                       names)
+            n_held = E
+        else:
+            first, n_held = self.held
+            if not (0 <= first and n_held >= 1 and first + n_held <= E):
+                raise ValueError(f"held={self.held} is not a range of the "
+                                 f"{E} experts")
+            out, tokens_per_expert, held_assignments = self._held_experts(
+                x, gate, expert, names, first, n_held)
+            self.sow("intermediates", "held_assignments", held_assignments)
 
-        with jax.named_scope("experts"):
-            init = nn.initializers.lecun_normal(batch_axis=(0,))
-            w_gate, w_up, w_down = (
-                self.param(name, init, shape, self.param_dtype).astype(
-                    self.dtype)
-                for name, shape in (("w_gate", (E, d, self.hidden)),
-                                    ("w_up", (E, d, self.hidden)),
-                                    ("w_down", (E, self.hidden, d))))
-            h = (nn.silu(lax.ragged_dot(rows, w_gate, tokens_per_expert))
-                 * lax.ragged_dot(rows, w_up, tokens_per_expert))
-            y = lax.ragged_dot(h, w_down, tokens_per_expert)      # (k·N, d)
-
-        with jax.named_scope("combine"):
-            y = _to_token_order(y, order, inverse).reshape(n, k, d)
-            out = jnp.einsum("nkd,nk->nd", y.astype(jnp.float32), gate)
+        if self.shared_hidden:
+            out = out + _SharedExpert(
+                self.shared_hidden, self.dtype, self.param_dtype,
+                name="shared")(x.astype(self.dtype)).astype(jnp.float32)
 
         with jax.named_scope("router_losses"):
             f = lax.stop_gradient(tokens_per_expert / n)
@@ -313,10 +404,137 @@ class DroplessMoE(nn.Module):
         self.sow("intermediates", "aux_router_z", z_loss)
         self.sow("intermediates", "tokens_per_expert", tokens_per_expert)
         self.sow("intermediates", "expert_index", expert)
-        for noted in _NOTING:
-            noted[self.path] = (n * k, 3 * E * d * self.hidden
-                                * jnp.dtype(self.param_dtype).itemsize)
+        counters = {
+            "moe.assignments": n * k,
+            "moe.expert_bytes": (len(names) * n_held * d * self.hidden
+                                 * jnp.dtype(self.param_dtype).itemsize)}
+        if self.held is not None:
+            # What uniform routing sends to the held experts; the number
+            # a step's routing did send is on the device (sown as
+            # ``held_assignments``).
+            counters["moe.held_assignments"] = n * k * n_held // E
+        note_layer(self.path, counters)
         return out.astype(x.dtype).reshape(*lead, d), balance, z_loss
+
+    def _weights(self, names, n_experts, d):
+        init = nn.initializers.lecun_normal(batch_axis=(0,))
+        return {
+            name: self.param(
+                name, init, (n_experts, self.hidden, d) if name == "w_down"
+                else (n_experts, d, self.hidden), self.param_dtype)
+            for name in names}
+
+    def _ffn(self, rows, w, group_sizes):
+        """The experts on sorted ``rows``: grouped matmuls in ``dtype``."""
+        w = {name: a.astype(self.dtype) for name, a in w.items()}
+        if self.activation == "swiglu":
+            h = (nn.silu(lax.ragged_dot(rows, w["w_gate"], group_sizes))
+                 * lax.ragged_dot(rows, w["w_up"], group_sizes))
+        else:
+            h = jnp.square(nn.relu(
+                lax.ragged_dot(rows, w["w_up"], group_sizes)))
+        return h, w["w_down"]
+
+    def _all_experts(self, x, gate, expert, names):
+        """Every expert is here: all k·N assignments, in expert order."""
+        E, k = self.num_experts, self.top_k
+        n, d = x.shape
+        with jax.named_scope("dispatch"):
+            flat = expert.reshape(-1)              # assignment a: token a // k
+            order = jnp.argsort(flat, stable=True)
+            inverse = jnp.argsort(order)
+            tokens_per_expert = jnp.bincount(flat, length=E).astype(
+                jnp.int32)
+            rows = _to_expert_order(x.astype(self.dtype), order, inverse)
+
+        with jax.named_scope("experts"):
+            w = self._weights(names, E, d)
+            h, w_down = self._ffn(rows, w, tokens_per_expert)
+            y = lax.ragged_dot(h, w_down, tokens_per_expert)      # (k·N, d)
+
+        with jax.named_scope("combine"):
+            y = _to_token_order(y, order, inverse).reshape(n, k, d)
+            out = jnp.einsum("nkd,nk->nd", y.astype(jnp.float32), gate)
+        return out, tokens_per_expert
+
+    def _held_experts(self, x, gate, expert, names, first, n_held):
+        """``n_held`` of the experts are here (class docstring)."""
+        E, k = self.num_experts, self.top_k
+        n, d = x.shape
+        with jax.named_scope("dispatch"):
+            flat = expert.reshape(-1)
+            tokens_per_expert = jnp.bincount(flat, length=E).astype(
+                jnp.int32)
+            # Held experts 0 .. n_held - 1; everything else sorts last.
+            local = jnp.where((flat >= first) & (flat < first + n_held),
+                              flat - first, n_held)
+            order = jnp.argsort(local, stable=True)
+            group_sizes = lax.dynamic_slice_in_dim(
+                tokens_per_expert, first, n_held)
+            ends = jnp.cumsum(group_sizes)
+            landed = ends[-1]
+        w = self._weights(names, n_held, d)
+        R = min(n * k, -(-_HELD_WINDOW * n * k * n_held // E // 8) * 8)
+        windows = -(-n * k // R)
+        order = jnp.pad(order, (0, windows * R - n * k))
+
+        def nothing(x):
+            # Zeros that vary over the mesh axes the tokens vary over.
+            return jnp.zeros_like(x, dtype=jnp.float32)
+
+        def window(i, x, gate, w, level: bool):
+            """What the sorted assignments ``[i R, (i + 1) R)`` add to the
+            output: the grouped matmuls over those rows, each expert's
+            group cut to the window.  A row past ``landed`` belongs to no
+            expert here and is masked to nothing on its way in and out."""
+            lo = i * R
+            with jax.named_scope("dispatch"):
+                a = lax.dynamic_slice_in_dim(order, lo, R)
+                token = a // k
+                here = ((lo + jnp.arange(R)) < landed)[:, None]
+                rows = jnp.where(here, x[token].astype(self.dtype), 0)
+                g = jnp.where(here[:, 0], gate.reshape(-1)[a], 0.0)
+                sizes = jnp.clip(
+                    jnp.minimum(ends, lo + R)
+                    - jnp.maximum(ends - group_sizes, lo), 0, R)
+                if level:
+                    # The window's empty rows go to the last expert (zeros
+                    # in, zeros out): the grouped matmuls then always run
+                    # over R rows, and a step's time does not follow where
+                    # its router sends the tokens.
+                    sizes = sizes.at[-1].add(R - sizes.sum())
+            with jax.named_scope("experts"):
+                h, w_down = self._ffn(rows, {
+                    name: _pad_hidden(a.astype(self.dtype),
+                                      1 if name == "w_down" else 2)
+                    for name, a in w.items()}, sizes)
+                h = jnp.where(here, h, 0)
+                y = jnp.where(here, lax.ragged_dot(h, w_down, sizes), 0)
+            with jax.named_scope("combine"):
+                return nothing(x).at[token].add(
+                    y.astype(jnp.float32) * g[:, None])
+
+        @jax.checkpoint
+        def run(x, gate, w):
+            out = window(0, x, gate, w, level=True)
+            if windows == 1:
+                return out
+
+            def every_other_window():
+                # Also those past ``landed`` (all masked, their rows
+                # skipped by the grouped matmuls): a branch a window would
+                # keep a copy of the rows and the weights each for the
+                # backward pass.
+                def body(out, i):
+                    return out + jax.checkpoint(window, static_argnums=4)(
+                        i, x, gate, w, False), None
+                return lax.scan(body, nothing(x), jnp.arange(1, windows))[0]
+
+            return out + lax.cond(
+                landed > R, jax.named_scope("overflowed")(every_other_window),
+                lambda: nothing(x))
+
+        return run(x, gate, w), tokens_per_expert, landed
 
 
 def router_losses(intermediates) -> tuple:

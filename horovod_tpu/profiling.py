@@ -87,9 +87,12 @@ def _device_pids(events) -> set:
     pids = {e["pid"]: e["args"].get("name", "") for e in events
             if e.get("ph") == "M" and e.get("name") == "process_name"}
     # '/device:TPU:0' etc.; the python host shows as '/host:CPU'.  The
-    # CPU platform emits NO device process at all (host-only trace).
+    # CPU platform emits NO device process at all (host-only trace) -- or,
+    # once libtpu is loaded in the process (a compile for a described TPU),
+    # an empty device plane: a device process is one that ran something.
+    ran = {e["pid"] for e in events if e.get("ph") == "X"}
     return {p for p, n in pids.items()
-            if n.startswith("/device:") and "CPU" not in n}
+            if n.startswith("/device:") and "CPU" not in n and p in ran}
 
 
 def _thread_names(events) -> Dict[tuple, str]:

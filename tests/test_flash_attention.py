@@ -601,13 +601,13 @@ class TestDiagonalSubTiles:
 # variables), by tracing its ops at these shapes.
 def observed(T, D=128, H=16, itemsize=2, blocks=1024, base=None,
              interpret=False, manual_axes=False, vmem_headroom=True,
-             causal=True):
+             causal=True, **more):
     blocks = min(blocks, T)
     return dict(T=T, D=D, H=H, head_base=base or (0, H, 2 * H),
                 itemsize=itemsize, causal=causal, block_q=blocks,
                 block_k=blocks,
                 bwd_block_q=blocks, bwd_block_k=blocks, interpret=interpret,
-                manual_axes=manual_axes, vmem_headroom=vmem_headroom)
+                manual_axes=manual_axes, vmem_headroom=vmem_headroom, **more)
 
 
 FULL, KV, GRID = "fullunroll", "unrollkv", "grid"
@@ -634,6 +634,14 @@ PLAN_TABLE = {
     # Past a 1 MB K/V row (T 4096 at D 128 bf16) only the grid streams.
     "T8192": (observed(8192), (GRID, 0, 0, "grouped", 32, 256, 0.97)),
     "T32768": (observed(32768), (GRID, 0, 0, "grouped", 32, 256, 0.992)),
+    # Grouped KV heads (twotower_1chip: 32 query heads over 2): two query
+    # heads of a tile would read the same KV head, which only the per-head
+    # pair's index maps do; at T 8192 its whole blocks waste a ninth.
+    "cell_T8192_16Q_per_KV": (
+        observed(8192, H=32, base=(0, 0, 0), kv_rep=16),
+        (GRID, 0, 0, "per_head", 0, 0, 0.889)),
+    "T2048_2Q_per_KV": (observed(2048, base=(0, 0, 0), kv_rep=2),
+                        (FULL, 512, 0, "per_head", 0, 0, 0.667)),
     "T4096_f32": (observed(4096, itemsize=4),
                   (GRID, 0, 0, "grouped", 32, 256, 0.941)),
     # Heads off the lane width, merged into the batch: rows of one head.

@@ -1,0 +1,544 @@
+"""The hybrid (pattern) stack and its three kinds of layer against plain
+references, at small sizes on the CPU.
+
+* ``ops/ssd.py``'s chunked scan against the recurrence it computes, and
+  the ``Mamba2Mixer`` module against the benchmark family's plain mixer;
+* the flash kernels with grouped KV heads against the dense oracle with
+  the keys and values repeated;
+* ``DroplessMoE`` told which experts it holds: the shares add up to the
+  uncut layer, and a skewed router loses no assignment;
+* the nine-layer tiny preset through ``make_train_step`` against the
+  family's ``reference_loss``, with its trace scopes and counters.
+"""
+
+import json
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax import shard_map
+from jax.sharding import Mesh, PartitionSpec as P
+
+from benchmark.families import nemotron_h_lm
+from horovod_tpu.jax.spmd import make_train_step
+from horovod_tpu.metrics import registry
+from horovod_tpu.models import NemotronHLM, TransformerLM
+from horovod_tpu.models.ssm import Mamba2Mixer
+from horovod_tpu.ops.flash_attention import flash_attention
+from horovod_tpu.ops.ssd import scan_sizes, ssd_recurrence, ssd_scan
+from horovod_tpu.parallel.moe import (
+    _HELD_WINDOW, DroplessMoE, _SharedExpert)
+from horovod_tpu.parallel.ring_attention import full_attention
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def rel(got, want):
+    return float(jnp.linalg.norm(got - want)
+                 / jnp.maximum(jnp.linalg.norm(want), 1e-30))
+
+
+# ------------------------------------------------------------ the scan
+
+
+def scan_inputs(T, b=2, H=4, P_=8, G=2, N=16, seed=0):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 6)
+    return (jax.random.normal(ks[0], (b, T, H, P_)),
+            jax.nn.softplus(jax.random.normal(ks[1], (b, T, H)) - 2.0),
+            -jnp.exp(jax.random.uniform(ks[2], (H,), minval=0.0, maxval=2.7)),
+            jax.random.normal(ks[3], (b, T, G, N)),
+            jax.random.normal(ks[4], (b, T, G, N)),
+            jax.random.normal(ks[5], (H,)))
+
+
+@pytest.mark.parametrize("T,chunk", [(37, 8), (128, 128), (200, 128)],
+                         ids=["T_not_a_multiple", "one_chunk_of_128",
+                              "a_chunk_and_a_tail"])
+def test_chunked_scan_equals_the_recurrence(T, chunk):
+    """float32 on both sides: forward to 1e-5 of the largest output and
+    every gradient (x, dt, A, B, C, D) to 1e-4 of its norm (observed
+    6e-5 / 14 and 8e-6)."""
+    args = scan_inputs(T)
+    with jax.default_matmul_precision("highest"):
+        got = ssd_scan(*args, chunk=chunk)
+        want = ssd_recurrence(*args)
+        assert got.shape == want.shape == args[0].shape
+        assert float(jnp.abs(got - want).max()) <= 1e-5 * float(
+            jnp.abs(want).max())
+        weight = jnp.cos(jnp.arange(want.size, dtype=jnp.float32)).reshape(
+            want.shape)
+        grads = [jax.grad(lambda *a: (f(*a) * weight).sum(),
+                          argnums=tuple(range(6)))(*args)
+                 for f in (lambda *a: ssd_scan(*a, chunk=chunk),
+                           ssd_recurrence)]
+    for g, w in zip(*grads):
+        assert rel(g, w) <= 1e-4
+
+
+def test_scan_sizes_and_groups():
+    assert scan_sizes(2, 8192, 64, 64, 128, 128) == {
+        "chunks": 128, "state_bytes": 128 * 64 * 64 * 128 * 4}
+    assert scan_sizes(1, 130, 2, 4, 8, 128)["chunks"] == 2
+    x, dt, A, B, C, D = scan_inputs(16, H=3, G=2)
+    with pytest.raises(ValueError, match="groups"):
+        ssd_scan(x, dt, A, B, C, D, chunk=8)
+
+
+def family_cfg(compute_dtype="float32", **override):
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           "nemotron-twotower-30b-a3b.json")) as fh:
+        cfg = {**json.load(fh), **nemotron_h_lm.TINY, **override}
+    cfg["training"] = {**cfg["training"], "compute_dtype": compute_dtype}
+    return cfg
+
+
+def mixer_and_params(cfg, T, seed=0):
+    mixer = Mamba2Mixer(
+        num_heads=cfg["mamba_num_heads"], head_dim=cfg["mamba_head_dim"],
+        n_groups=cfg["n_groups"], state_size=cfg["ssm_state_size"],
+        conv_kernel=cfg["conv_kernel"], chunk=cfg["chunk_size"],
+        norm_eps=cfg["layer_norm_epsilon"], dtype=jnp.float32)
+    u = jax.random.normal(jax.random.PRNGKey(seed),
+                          (2, T, cfg["hidden_size"]))
+    params = mixer.init(jax.random.PRNGKey(seed + 1), u)["params"]
+    # Move the one-initialised leaves off one, so a wrong use shows.
+    keys = jax.random.split(jax.random.PRNGKey(seed + 2), 2)
+    params = {**params,
+              "D": 1.0 + 0.5 * jax.random.normal(keys[0], params["D"].shape),
+              "gate_norm": 1.0 + 0.2 * jax.random.normal(
+                  keys[1], params["gate_norm"].shape)}
+    return mixer, params, u
+
+
+@pytest.mark.parametrize("T,chunk", [(40, 16), (128, 128)],
+                         ids=["T_not_a_multiple", "chunk_128_exactly"])
+def test_mixer_module_equals_the_reference_recurrence(T, chunk):
+    """``Mamba2Mixer`` (float32) against the family's plain mixer in its
+    recurrence form, same parameter tree: output to 1e-5 of its largest,
+    every parameter's gradient and the input's to 2e-4."""
+    cfg = family_cfg(chunk_size=chunk)
+    mixer, params, u = mixer_and_params(cfg, T)
+    reference = nemotron_h_lm.reference_mixer(cfg, "recurrence")
+
+    def ours(p, u):
+        return mixer.apply({"params": p}, u)
+
+    def theirs(p, u):
+        return jax.vmap(lambda s: reference(p, s))(u)
+
+    with jax.default_matmul_precision("highest"):
+        got, want = ours(params, u), theirs(params, u)
+        assert float(jnp.abs(got - want).max()) <= 1e-5 * float(
+            jnp.abs(want).max())
+        weight = jnp.sin(jnp.arange(want.size, dtype=jnp.float32)).reshape(
+            want.shape)
+        g = jax.grad(lambda p, u: (ours(p, u) * weight).sum(), (0, 1))(
+            params, u)
+        w = jax.grad(lambda p, u: (theirs(p, u) * weight).sum(), (0, 1))(
+            params, u)
+    errors = {jax.tree_util.keystr(path): rel(a, b) for (path, a), b in zip(
+        jax.tree_util.tree_leaves_with_path(g), jax.tree.leaves(w))}
+    assert max(errors.values()) <= 2e-4, errors
+    assert {"['A_log']", "['dt_bias']", "['D']", "['conv']['kernel']",
+            "['conv']['bias']", "['gate_norm']"} <= {
+                k[3:] if k.startswith("[0]") else k for k in errors}
+
+
+def test_the_two_reference_forms_agree():
+    """The quadratic dual ``(L o C B^T) (dt x)``, head by head, against the
+    recurrence: two independent readings of the same equations."""
+    cfg = family_cfg()
+    _, params, u = mixer_and_params(cfg, 48, seed=3)
+    dual = nemotron_h_lm.reference_mixer(cfg, "dual")
+    step = nemotron_h_lm.reference_mixer(cfg, "recurrence")
+    with jax.default_matmul_precision("highest"):
+        a, b = dual(params, u[0]), step(params, u[0])
+        assert float(jnp.abs(a - b).max()) <= 1e-5 * float(jnp.abs(b).max())
+        ga = jax.grad(lambda p: (dual(p, u[0]) ** 2).sum())(params)
+        gb = jax.grad(lambda p: (step(p, u[0]) ** 2).sum())(params)
+    for x, y in zip(jax.tree.leaves(ga), jax.tree.leaves(gb)):
+        assert rel(x, y) <= 1e-4
+
+
+# ------------------------------------------------ grouped-query attention
+
+
+# (B, T, H, Hkv, D, block): the fully-unrolled forward; 17 KV blocks, so
+# the grid forward; a head off the lane width (repeated, then merged into
+# the batch); and multi-head attention through the same entry.
+@pytest.mark.parametrize("B,T,H,Hkv,D,block", [
+    (1, 256, 4, 2, 128, 128), (2, 256, 4, 1, 128, 64),
+    (1, 1088, 2, 1, 128, 64), (1, 64, 4, 2, 32, 32),
+    (1, 256, 2, 2, 128, 128)],
+    ids=["16Q_per_KV_shape_small", "one_KV_head", "grid_forward", "D32",
+         "multi_head"])
+def test_grouped_kv_flash_equals_full_attention(B, T, H, Hkv, D, block):
+    """Forward, dQ, and dK / dV summed over the query heads of a group,
+    against ``full_attention`` on keys and values repeated H / Hkv times."""
+    ks = jax.random.split(jax.random.PRNGKey(T + H), 4)
+    q = jax.random.normal(ks[0], (B, T, H, D))
+    k = jax.random.normal(ks[1], (B, T, Hkv, D))
+    v = jax.random.normal(ks[2], (B, T, Hkv, D))
+    weight = jax.random.normal(ks[3], (B, T, H, D))
+
+    def ours(q, k, v):
+        return (flash_attention(q, k, v, block_q=block, block_k=block,
+                                interpret=True) * weight).sum()
+
+    def oracle(q, k, v):
+        rep = H // Hkv
+        return (full_attention(q, jnp.repeat(k, rep, 2),
+                               jnp.repeat(v, rep, 2), causal=True)
+                * weight).sum()
+
+    with jax.default_matmul_precision("highest"):
+        got, got_g = jax.value_and_grad(ours, (0, 1, 2))(q, k, v)
+        want, want_g = jax.value_and_grad(oracle, (0, 1, 2))(q, k, v)
+    assert abs(float(got) - float(want)) <= 1e-3
+    for g, w in zip(got_g, want_g):
+        assert g.shape == w.shape
+        np.testing.assert_allclose(g, w, rtol=1e-4, atol=2e-5)
+
+
+def test_grouped_kv_heads_must_divide():
+    q = jnp.zeros((1, 64, 3, 128))
+    with pytest.raises(ValueError, match="divide"):
+        flash_attention(q, q[:, :, :2], q[:, :, :2], interpret=True)
+
+
+# ---------------------------------------------------------- held experts
+
+N, D_, HID, E, K = 96, 16, 24, 16, 3
+NEMOTRON = dict(num_experts=E, hidden=HID, top_k=K, dtype=jnp.float32,
+                router="sigmoid", renormalize=True, gate_scale=2.5,
+                activation="relu2", shared_hidden=40)
+
+
+def uncut_layer(skewed: bool, experts: int = E):
+    layer = DroplessMoE(**{**NEMOTRON, "num_experts": experts})
+    x = jax.random.normal(jax.random.PRNGKey(0), (N, D_))
+    params = layer.init(jax.random.PRNGKey(1), x)["params"]
+    if skewed:
+        # Experts 0 and 1 are in every token's top-3, whatever the token.
+        x = x.at[:, 0].set(3.0)
+        kernel = params["router"]["kernel"].at[0, :2].set(10.0)
+        params = {**params, "router": {"kernel": kernel}}
+    return layer, params, x
+
+
+def share_of(params, first, count):
+    return {**params, "w_up": params["w_up"][first:first + count],
+            "w_down": params["w_down"][first:first + count]}
+
+
+def nemotron_oracle(params, x):
+    """Every expert on every token, weighted by the top-k mask."""
+    E = params["router"]["kernel"].shape[1]
+    with jax.default_matmul_precision("highest"):
+        s = jax.nn.sigmoid(x @ params["router"]["kernel"])
+        kth = jnp.sort(s, axis=-1)[:, -K]
+        gates = jnp.where(s >= kth[:, None], s, 0.0)
+        gates = 2.5 * gates / gates.sum(-1, keepdims=True)
+        out = jnp.square(jax.nn.relu(x @ params["shared"]["w_up"])) @ params[
+            "shared"]["w_down"]
+        for e in range(E):
+            out = out + gates[:, e:e + 1] * (jnp.square(jax.nn.relu(
+                x @ params["w_up"][e])) @ params["w_down"][e])
+    return out
+
+
+@pytest.mark.parametrize("skewed,experts", [
+    (False, 16), (True, 16), (True, 32)],
+    ids=["balanced", "two_experts_take_every_token_two_windows",
+         "two_of_32_take_every_token_every_window"])
+def test_the_shares_add_up_to_the_uncut_layer(skewed, experts):
+    """Shares of two experts each: what they give, with the shared expert
+    (every share computes it alike) counted once, is the uncut layer's
+    output, which is the loop over all experts.  The counts of
+    assignments that landed on the shares add up to k N — none is lost.
+    In the skewed cases share 0 takes 2 N = 192 of them: of 16 experts
+    that is over its window of ``_HELD_WINDOW`` times the uniform load
+    (108 rows) and inside two, of 32 (54 rows) over two windows, so the
+    ``overflowed`` loop runs: one further window filled, then three."""
+    whole, params, x = uncut_layer(skewed, experts)
+    shares = experts // 2
+    with jax.default_matmul_precision("highest"):
+        want, _, _ = whole.apply({"params": params}, x)
+        np.testing.assert_allclose(want, nemotron_oracle(params, x),
+                                   rtol=1e-5, atol=1e-5)
+        parts, landed = [], []
+        for i in range(shares):
+            layer = DroplessMoE(**{**NEMOTRON, "num_experts": experts},
+                                held=(2 * i, 2))
+            (out, _, _), state = layer.apply(
+                {"params": share_of(params, 2 * i, 2)}, x,
+                mutable=["intermediates"])
+            parts.append(out)
+            landed.append(int(state["intermediates"]["held_assignments"][0]))
+        shared = _SharedExpert(40, jnp.float32).apply(
+            {"params": params["shared"]}, x)
+    # The shared expert's output is in the sum once a share and is taken
+    # out again all but once: with 16 shares that costs float32 a digit.
+    np.testing.assert_allclose(sum(parts) - (shares - 1) * shared, want,
+                               rtol=5e-5, atol=5e-5)
+    assert sum(landed) == N * K
+    if skewed:
+        window = _HELD_WINDOW * N * K * 2 // experts
+        assert landed[0] == 2 * N > window
+        assert (2 * N > 2 * window) == (experts == 32)
+
+
+@pytest.mark.parametrize("experts", [16, 32],
+                         ids=["second_window", "every_window"])
+def test_a_share_s_gradients_equal_the_masked_loop_s(experts):
+    """One share under the skewed router (the overflow loop): gradients
+    of its own experts, the router, the shared expert and the input
+    against the oracle restricted to the held experts."""
+    E = experts
+    _, params, x = uncut_layer(True, experts)
+    layer = DroplessMoE(**{**NEMOTRON, "num_experts": experts}, held=(0, 2))
+    mine = share_of(params, 0, 2)
+
+    def ours(p, x):
+        out = layer.apply({"params": p}, x)[0]
+        return (out * jnp.cos(out)).sum()
+
+    def oracle(p, x):
+        zeros = jnp.zeros((E - 2,) + p["w_up"].shape[1:])
+        full = {**p, "w_up": jnp.concatenate([p["w_up"], zeros]),
+                "w_down": jnp.concatenate(
+                    [p["w_down"], zeros.transpose(0, 2, 1)])}
+        out = nemotron_oracle(full, x)
+        return (out * jnp.cos(out)).sum()
+
+    with jax.default_matmul_precision("highest"):
+        got = jax.grad(ours, (0, 1))(mine, x)
+        want = jax.grad(oracle, (0, 1))(mine, x)
+    # Leaves of up to 100 in size, summed in another order: 2e-4 of that.
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(g, w, rtol=2e-4, atol=2e-4)
+
+
+def test_a_share_traces_under_shard_map_with_vma_checks():
+    """Tokens split over the data-parallel axis, the share replicated: the
+    windows' zeros and the scan's carry vary as the tokens do."""
+    _, params, x = uncut_layer(True)
+    layer = DroplessMoE(**NEMOTRON, held=(0, 2))
+    mine = share_of(params, 0, 2)
+    mesh = Mesh(np.asarray(jax.devices()[:2]), ("ranks",))
+    out = jax.jit(shard_map(
+        lambda p, x: layer.apply({"params": p}, x)[0], mesh=mesh,
+        in_specs=(P(), P("ranks")), out_specs=P("ranks"),
+        check_vma=True))(mine, x)
+    halves = [layer.apply({"params": mine}, h)[0]
+              for h in (x[:N // 2], x[N // 2:])]
+    np.testing.assert_allclose(out, jnp.concatenate(halves), rtol=1e-5,
+                               atol=1e-5)
+
+
+def test_held_must_be_a_range_of_the_experts():
+    _, params, x = uncut_layer(False)
+    with pytest.raises(ValueError, match="held"):
+        DroplessMoE(**NEMOTRON, held=(14, 4)).init(jax.random.PRNGKey(0), x)
+    with pytest.raises(ValueError, match="router"):
+        DroplessMoE(**{**NEMOTRON, "router": "tanh"}).init(
+            jax.random.PRNGKey(0), x)
+
+
+# ------------------------------------------------------- the whole model
+
+
+def model_inputs(cfg, n=2, seed=5):
+    params, aux = nemotron_h_lm.init(cfg, jax.random.PRNGKey(seed))
+    tokens = nemotron_h_lm.host_batch(cfg, np.random.default_rng(seed), n)
+    return params, aux, tokens
+
+
+# float32 compute: the routers agree exactly and every leaf of the
+# gradient is the reference's to summation order.  bfloat16 compute on 128
+# tokens of a 64-wide model: the tiny preset's own, looser tolerances
+# (the reference breaks near-ties as the program did, so the routers'
+# differing choices no longer set the floor: 0.1-0.4 a leaf without).
+@pytest.mark.parametrize("compute_dtype,loss_tol,grad_tol", [
+    ("float32", 1e-5, 2e-4), ("bfloat16", 5e-3, 0.2)])
+def test_model_against_reference_loss(compute_dtype, loss_tol, grad_tol,
+                                      capsys):
+    cfg = family_cfg(compute_dtype)
+    assert nemotron_h_lm.pattern(cfg) == "MEMEM*EME"
+    params, aux, tokens = model_inputs(cfg)
+    loss_fn = nemotron_h_lm.loss_fn(cfg)
+    ref_fn = nemotron_h_lm.reference_loss(cfg)
+    with jax.default_matmul_precision("highest"):
+        got, got_g = jax.value_and_grad(
+            lambda p: loss_fn(p, aux, tokens)[0])(params)
+    want, want_g = jax.value_and_grad(
+        lambda p: ref_fn(p, aux, tokens))(params)
+    assert abs(float(got) - float(want)) / float(want) <= loss_tol
+    flat_got = dict(jax.tree_util.tree_leaves_with_path(got_g))
+    flat_want = dict(jax.tree_util.tree_leaves_with_path(want_g))
+    named = [tuple(jax.tree_util.DictKey(k) for k in path)
+             for path in nemotron_h_lm.grad_leaves(cfg)]
+    assert set(named) <= set(flat_got)
+    errors = {jax.tree_util.keystr(path): rel(flat_got[path],
+                                              flat_want[path])
+              for path in (flat_got if compute_dtype == "float32"
+                           else named)}
+    assert max(errors.values()) <= grad_tol, errors
+    jax.effects_barrier()
+    lines = [json.loads(l) for l in capsys.readouterr().out.splitlines()
+             if l.startswith('{"bench": "routing"')]
+    assert lines and all(l["assignments"] == 4 * 2 * 64 * 3 for l in lines)
+    assert all(l["beyond_margin_share"] == 0.0 for l in lines)
+    if compute_dtype == "float32":
+        assert all(l["disagreeing_share"] == 0.0 for l in lines)
+    else:
+        assert all(0 < l["largest_gap"] <= cfg["tolerances"]["tie_margin"]
+                   for l in lines)
+
+
+def test_reference_takes_the_program_s_choice_only_where_it_is_a_tie():
+    """The reference given OTHER choices than its own: inside the margin it
+    follows them (another loss than its own choice gives), beyond the
+    margin, or where they are not k distinct experts, it keeps its own."""
+    cfg = family_cfg("float32")
+    params, aux, tokens = model_inputs(cfg)
+    given = jax.jit(nemotron_h_lm.reference_given_choices(cfg))
+    own = nemotron_h_lm.program_expert_choices(cfg, params, tokens)
+    want = float(given(params, tokens, own, 0.0))
+    # The sixth-and-lower choice of every token pushed one expert on.
+    E = cfg["experts_routed_over"]
+    other = own.at[..., -1].set((own[..., -1] + 1) % E)
+    assert float(given(params, tokens, other, 0.0)) == want
+    assert float(given(params, tokens, other, 1.0)) != want
+    # One expert chosen twice is k - 1 experts: no tie at any margin.
+    fewer = own.at[..., -1].set(own[..., 0])
+    assert float(given(params, tokens, fewer, 1.0)) == want
+    # The comparison's precision control: the same mathematics in bfloat16
+    # is another number (on the chip, at T 8192, not even a finite one).
+    low = nemotron_h_lm.reference_given_choices(cfg, dtype="bfloat16")
+    assert abs(float(low(params, tokens, own, 0.0)) - want) > 1e-4 * want
+
+
+def _equations(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for value in eqn.params.values():
+            for inner in (value if isinstance(value, (list, tuple))
+                          else [value]):
+                inner = getattr(inner, "jaxpr", inner)
+                if hasattr(inner, "eqns"):
+                    yield from _equations(inner)
+
+
+def test_the_float32_parts_are_float32_in_the_traced_program():
+    """What the comparison with the reference cannot see (on the chip a
+    chunk state carried in bfloat16 and a router at the default matmul
+    precision read each seed's own floor: they perturb less than the
+    recipe's bfloat16 arithmetic does) is held here, in the program's
+    jaxpr: under bfloat16 compute the state passed from chunk to chunk is
+    float32, and the router's matmul takes float32 operands at HIGHEST."""
+    cfg = family_cfg("bfloat16")
+    params, aux, tokens = model_inputs(cfg)
+    loss_fn = nemotron_h_lm.loss_fn(cfg)
+    eqns = list(_equations(jax.make_jaxpr(
+        lambda p: loss_fn(p, aux, tokens)[0])(params).jaxpr))
+    H, P_, N = (cfg["mamba_num_heads"], cfg["mamba_head_dim"],
+                cfg["ssm_state_size"])
+    carried = [v.aval for e in eqns if e.primitive.name == "scan"
+               for v in e.outvars[:e.params["num_carry"]]
+               if v.aval.shape[-2:] == (P_, N) and v.aval.size % (H * P_ * N)
+               == 0]
+    assert len(carried) == 4 and all(a.dtype == jnp.float32 for a in carried)
+    routers = [e for e in eqns if e.primitive.name == "dot_general"
+               and e.outvars[0].aval.shape[-1] == cfg["experts_routed_over"]
+               and e.invars[1].aval.shape == (cfg["hidden_size"],
+                                              cfg["experts_routed_over"])]
+    assert len(routers) == 4
+    for e in routers:
+        assert all(v.aval.dtype == jnp.float32 for v in e.invars)
+        assert e.params["precision"] == (jax.lax.Precision.HIGHEST,
+                                         jax.lax.Precision.HIGHEST)
+
+
+def test_tiny_stack_trains_through_make_train_step(hvd):
+    """The nine-layer preset through the normal path on the 8-device mesh:
+    the first step's loss is the reference's on the global batch, the loss
+    falls, the state stays float32, and each dispatch bumps the mixers'
+    and the expert layers' counters from the shapes they noted."""
+    cfg = family_cfg("bfloat16")
+    params, aux, _ = model_inputs(cfg)
+    tokens = nemotron_h_lm.host_batch(cfg, np.random.default_rng(7), 8)
+    tx = nemotron_h_lm.optimizer(cfg)
+    opt_state = tx.init(params)
+    want = float(nemotron_h_lm.reference_loss(cfg)(params, aux, tokens))
+    step = make_train_step(nemotron_h_lm.loss_fn(cfg), tx, hvd.ranks_mesh())
+    names = ("ssm.scan_chunks", "ssm.state_bytes", "moe.assignments",
+             "moe.held_assignments", "moe.expert_bytes")
+    before = {n: registry.snapshot()["counters"].get(n, 0) for n in names}
+    losses = []
+    for _ in range(4):
+        params, aux, opt_state, loss = step(params, aux, opt_state, tokens)
+        losses.append(float(loss))
+    assert abs(losses[0] - want) / want <= 5e-3
+    assert losses[-1] < losses[0]
+    assert all(a.dtype == jnp.float32 for a in jax.tree.leaves(params))
+    after = registry.snapshot()["counters"]
+    got = {n: after.get(n, 0) - before[n] for n in names}
+    # A shard's step, four dispatches: one sequence of 64 tokens through 4
+    # mixers (4 chunks of 16; 4 heads x 16 x 16 float32 a state) and 4
+    # expert layers (3 of 8 experts a token, 4 held, 2 matrices of 64x32).
+    assert got == {"ssm.scan_chunks": 4 * 4 * 4,
+                   "ssm.state_bytes": 4 * 4 * 4 * 4 * 16 * 16 * 4,
+                   "moe.assignments": 4 * 4 * 64 * 3,
+                   "moe.held_assignments": 4 * 4 * 64 * 3 // 2,
+                   "moe.expert_bytes": 4 * 4 * 2 * 4 * 64 * 32 * 4}
+
+
+def test_trace_scopes_name_the_mixer_s_parts_and_the_shared_expert():
+    cfg = family_cfg("bfloat16")
+    params, aux, tokens = model_inputs(cfg)
+    loss_fn = nemotron_h_lm.loss_fn(cfg)
+    text = jax.jit(jax.grad(lambda p: loss_fn(p, aux, tokens)[0])).lower(
+        params).as_text(debug_info=True)
+    for scope in ("ssm/in_proj", "ssm/conv", "ssm/scan", "ssm/scan/intra",
+                  "ssm/scan/states", "ssm/scan/pass", "ssm/scan/inter",
+                  "ssm/gate_norm", "ssm/out_proj", "moe/route",
+                  "moe/shared", "layer_5/attn", "layer_8/moe"):
+        assert scope in text, scope
+    # The cell's own readers find them under those names.
+    from benchmark.metrics import moe_ms, ssm_ms
+    assert ssm_ms.in_scan("jvp(TransformerLM)/layer_*/ssm/scan/intra/mul")
+    assert ssm_ms.in_mixer("params['layer_*']['ssm']['in_proj']['kernel']")
+    assert not ssm_ms.in_scan("jvp(TransformerLM)/layer_*/ssm/conv/mul")
+    assert moe_ms.in_expert_layer(
+        "transpose(jvp(TransformerLM))/layer_*/moe/shared/dot_general")
+
+
+def test_options_that_do_not_compose_are_refused():
+    tokens = jnp.zeros((1, 16), jnp.int32)
+    tiny = dict(vocab=64, dim=32, num_heads=2, kv_heads=1, head_dim=16,
+                ssm=dict(num_heads=2, head_dim=8, n_groups=1, state_size=8,
+                         chunk=8),
+                moe_experts=4, moe_top_k=2, moe_hidden=16, attn="full")
+    model = NemotronHLM(**tiny, pattern="M*E")
+    params = model.init(jax.random.PRNGKey(0), tokens)["params"]
+    assert set(params) == {"tok_emb", "layer_0", "layer_1", "layer_2",
+                           "ln_f", "head"}
+    assert set(params["layer_1"]["attn"]) == {"q", "kv", "proj"}
+    with pytest.raises(ValueError, match="pattern stack"):
+        NemotronHLM(**tiny, pattern="M", tp_axis="tp").init(
+            jax.random.PRNGKey(0), tokens)
+    with pytest.raises(ValueError, match="held share"):
+        TransformerLM(vocab=64, dim=32, num_heads=2, tp_axis="tp",
+                      moe={"held": (0, 2)}).init(jax.random.PRNGKey(0),
+                                                 tokens)
+    with pytest.raises(ValueError, match="pos='none'"):
+        NemotronHLM(**{**tiny, "pos": "rotary"}, pattern="M").init(
+            jax.random.PRNGKey(0), tokens)
+    with pytest.raises(ValueError, match="belong to a pattern stack"):
+        TransformerLM(vocab=64, dim=32, num_heads=2, pos="none").init(
+            jax.random.PRNGKey(0), tokens)
+    with pytest.raises(ValueError, match="unknown layer"):
+        NemotronHLM(**tiny, pattern="MX").init(jax.random.PRNGKey(0), tokens)

@@ -110,6 +110,22 @@ def test_capture_raises_without_device_spans_off_cpu(monkeypatch, tmp_path):
             warmup=0, iters=1, log_dir=str(tmp_path))
 
 
+def test_empty_device_plane_is_no_device_process():
+    """A process with libtpu loaded (it compiled for a described TPU) names
+    a device plane in its CPU traces and runs nothing on it: only a plane
+    with a complete event counts as a device process."""
+    def named(pid, name):
+        return {"ph": "M", "pid": pid, "name": "process_name",
+                "args": {"name": name}}
+
+    events = [named(1, "/host:CPU"), named(2, "/device:TPU:0"),
+              named(3, "/device:TPU:1"),
+              {"ph": "X", "pid": 1, "tid": 1, "name": "host", "dur": 1.0},
+              {"ph": "X", "pid": 3, "tid": 1, "name": "jit_f", "dur": 1.0}]
+    assert profiling._device_pids(events) == {3}
+    assert profiling._device_pids(events[:4]) == set()
+
+
 def test_recorded_v5e_trace(tmp_path):
     """A trace recorded on the chip (jax 0.9.0, one TPU v5e, three calls
     of a jitted 4096^3 bf16 matmul-and-sum): the module span is 0.7045 ms

@@ -320,3 +320,105 @@ def test_dropless_expert_layer_fwd_bwd_at_olmoe_widths(v5e):
     # under 3 GB of bf16 rows: a dense (tokens, experts, capacity)
     # dispatch would be 10.7 GB a tensor.
     assert plan < 8 * 2 ** 30, plan / 2 ** 30
+
+
+# ------------------------------------------- the hybrid stack's own parts
+# (the twotower_1chip cell: 2 sequences of 8,192, Nemotron-H's widths)
+
+
+def test_grouped_kv_flash_fwd_bwd_at_nemotron_widths(v5e, monkeypatch):
+    """32 query heads over 2 KV heads of 128 at T 8192: a K/V row is 2 MB,
+    so the grid forward, and the per-head pair, whose dk/dv kernel's
+    innermost axis runs the 16 query heads of a KV head one after another
+    (the pair grouped over two heads would read two KV heads side by
+    side).  dk and dv come back at the KV heads' width."""
+    from horovod_tpu.ops import flash_attention as fa
+
+    one = SingleDeviceSharding(v5e[0])
+    q = jax.ShapeDtypeStruct((2, 8192, 32, 128), jnp.bfloat16, sharding=one)
+    kv = jax.ShapeDtypeStruct((2, 8192, 2, 128), jnp.bfloat16, sharding=one)
+    plans = []
+    plan = fa._plan
+    monkeypatch.setattr(
+        fa, "_plan", lambda **seen: plans.append(plan(**seen)) or plans[-1])
+
+    def loss(q, k, v):
+        return fa.flash_attention(q, k, v, causal=True).astype(
+            jnp.float32).sum()
+
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
+        q, kv, kv).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= 3
+    assert {(p.fwd, p.bwd, p.bwd_sub) for p in plans} == {
+        ("grid", "per_head", 0)}
+    _, (dq, dk, dv) = compiled.out_info
+    assert dq.shape == (2, 8192, 32, 128)
+    assert dk.shape == dv.shape == (2, 8192, 2, 128)
+
+
+def test_chunked_scan_fwd_bwd_at_nemotron_widths(v5e):
+    """``ssd_scan`` as the mixer calls it: 64 heads of 64, 8 groups, state
+    128, chunks of 128 — plain XLA, so no custom call; under a
+    ``jax.checkpoint``, as the mixer runs it, it fits the chip with its
+    gradients several times over, because the chunk-square tiles are
+    recomputed and not kept."""
+    from horovod_tpu.ops.ssd import ssd_scan
+
+    one = SingleDeviceSharding(v5e[0])
+
+    def s(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    b, t, h, p, g, n = 2, 8192, 64, 64, 8, 128
+    args = (s((b, t, h, p)), s((b, t, h), jnp.float32), s((h,), jnp.float32),
+            s((b, t, g, n)), s((b, t, g, n)), s((h,), jnp.float32))
+
+    @jax.checkpoint
+    def loss(*a):
+        return ssd_scan(*a, chunk=128).astype(jnp.float32).sum()
+
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=tuple(range(6)))
+                       ).lower(*args).compile()
+    m = compiled.memory_analysis()
+    plan = (m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes - m.alias_size_in_bytes)
+    assert plan < 6 * 2 ** 30, plan / 2 ** 30
+
+
+def test_held_expert_layer_fwd_bwd_at_nemotron_widths(v5e):
+    """``DroplessMoE`` as the cell calls it: 16,384 tokens of width 2688
+    routed over 128 experts, top-6, 8 of them held, a shared expert 3712
+    wide.  The grouped matmuls run over a window of 18,432 sorted rows
+    (three times the 6,144 that uniform routing sends here), not over the
+    98,304 assignments (0.5 GiB a tensor of their rows), with the experts'
+    hidden width padded from 1856 to 2048 inside them; the plan, with 0.8
+    GiB of float32 weights and gradients, stays under 4.5."""
+    from horovod_tpu.parallel.moe import DroplessMoE
+
+    tokens, d = 16_384, 2688
+    one = SingleDeviceSharding(v5e[0])
+    layer = DroplessMoE(num_experts=128, hidden=1856, top_k=6,
+                        router="sigmoid", renormalize=True, gate_scale=2.5,
+                        activation="relu2", shared_hidden=3712, held=(0, 8))
+    x = jax.ShapeDtypeStruct((tokens, d), jnp.bfloat16, sharding=one)
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one),
+        jax.eval_shape(lambda key: layer.init(
+            key, jnp.zeros((8, d), jnp.bfloat16))["params"],
+            jax.random.PRNGKey(0)))
+    assert params["w_up"].shape == (8, d, 1856)
+    assert params["router"]["kernel"].shape == (d, 128)
+
+    def loss(p, x):
+        return layer.apply({"params": p}, x)[0].astype(jnp.float32).sum()
+
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+        params, x).compile()
+    text = compiled.as_text()
+    assert "ragged-dot" in text
+    assert "18432,2688" in text and "98304,2688" not in text
+    assert "18432,2048" in text and "18432,1856" not in text
+    m = compiled.memory_analysis()
+    plan = (m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes - m.alias_size_in_bytes)
+    assert plan < 4.5 * 2 ** 30, plan / 2 ** 30
