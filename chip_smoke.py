@@ -9,6 +9,9 @@ through the entry points a user calls (``hvd.init()`` →
   first) and requires it to be the controller in use;
 * checks the flash kernels, as the model calls them and at the model's
   shape, against ``full_attention`` — forward and gradients;
+* checks the state-space scan's kernels, as the mixer calls them at
+  Nemotron-H's widths, against the scan's XLA form — forward and the
+  gradients of all four operands — and prints the scan's plan;
 * takes optimizer steps with the d=2048/T=2048 TransformerLM (one step
   per call, then four scanned steps per call) and with ResNet-50 at
   batch 128, parameters from each model's own ``init`` under ``--seed``,
@@ -49,6 +52,8 @@ ROOT = os.path.dirname(os.path.abspath(__file__))
 # steps.  Depth is the benchmark's own; nothing is cut.
 TRANSFORMER = dict(vocab=32768, dim=2048, depth=12, heads=16, seq=2048)
 FLASH_REFERENCE = dict(batch=8, seq=2048, heads=16, head_dim=128)
+SCAN_REFERENCE = dict(batch=2, seq=2048, heads=64, head_dim=64, groups=8,
+                      state=128, chunk=128)
 ONE_CHIP_LM = dict(**TRANSFORMER, batch=8, steps=3, scan_steps=4)
 ONE_CHIP_RESNET = dict(stage_sizes=(3, 4, 6, 3), num_filters=64,
                        num_classes=1000, image=224, batch=128, steps=3)
@@ -59,6 +64,11 @@ FOUR_CHIP_LM = dict(**TRANSFORMER, batch=8, big_batch=32, steps=3)
 # promise: 8 mantissa bits per rounding, accumulated over T=2048 terms.
 FLASH_FWD_TOL = 2e-2
 FLASH_GRAD_TOL = 4e-2
+# The scan's kernels against its XLA form at the same precisions (bf16
+# operands, float32 decays, sums and states): the forward rounds the same
+# tiles; the backward's sums run in another order.
+SCAN_FWD_TOL = 1e-2
+SCAN_GRAD_TOL = 4e-2
 LOSS_TOL = 2e-2          # 4-device vs 1-device loss, same step
 INT8_LOSS_TOL = 2e-2     # int8 wire vs fp32 wire loss, same step
 
@@ -227,6 +237,88 @@ def flash_reference_phase(*, batch: int, seq: int, heads: int,
                   f"(bound {FLASH_GRAD_TOL})")
         result[name] = {k_: round(e, 5) for k_, e in errs.items()}
     return result
+
+
+def ssd_plan(seq: int, heads: int, head_dim: int, groups: int, state: int,
+             chunk: int) -> dict:
+    """What ``ssd._plan`` decides on this device for the mixer's scan
+    (``ssd_scan_packed``, bfloat16): kernels or the XLA form, the grid a
+    sequence and the VMEM the kernels ask."""
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.ops import ssd
+
+    width = heads * head_dim + 2 * groups * state
+    return ssd.scan_plan(
+        jax.ShapeDtypeStruct((1, seq, width), jnp.bfloat16),
+        jax.ShapeDtypeStruct((1, seq, heads), jnp.float32), heads=heads,
+        head_dim=head_dim, groups=groups, state=state, chunk=chunk,
+        interpret=jax.default_backend() != "tpu")._asdict()
+
+
+def scan_reference_phase(*, batch: int, seq: int, heads: int, head_dim: int,
+                         groups: int, state: int, chunk: int,
+                         seed: int) -> dict:
+    """The scan as the mixer calls it (``ssd_scan_packed``: x | B | C one
+    array, under a ``jax.checkpoint``) against its XLA form,
+    ``_ssd_chunked``, on the same inputs: ``y`` and the gradients of the
+    packed array, ``dt``, ``A`` and ``D``."""
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.ops import ssd
+
+    interpret = jax.default_backend() != "tpu"
+    b, T, H, P_, G, N = batch, seq, heads, head_dim, groups, state
+    inner = H * P_
+    ks = jax.random.split(jax.random.PRNGKey(seed), 5)
+    xbc = jax.random.normal(ks[0], (b, T, inner + 2 * G * N), jnp.bfloat16)
+    dt = jax.nn.softplus(jax.random.normal(ks[1], (b, T, H)) - 2.0)
+    A = -jnp.exp(jax.random.uniform(ks[2], (H,), minval=0.0, maxval=2.7))
+    D = jax.random.normal(ks[3], (H,))
+    dy = jax.random.normal(ks[4], (b, T, inner), jnp.bfloat16)
+    plan = ssd_plan(T, H, P_, G, N, chunk)
+    check(plan["form"] == "kernels",
+          f"the scan's plan at the mixer's shape is {plan}")
+
+    def kernels(xbc, dt, A, D):
+        return ssd.ssd_scan_packed(xbc, dt, A, D, heads=H, groups=G,
+                                   state=N, chunk=chunk, interpret=interpret)
+
+    def xla_form(xbc, dt, A, D):
+        x, B, C = jnp.split(xbc, [inner, inner + G * N], axis=-1)
+        return ssd._ssd_chunked(
+            x.reshape(b, T, H, P_), dt, A, B.reshape(b, T, G, N),
+            C.reshape(b, T, G, N), D, chunk).reshape(b, T, inner)
+
+    def value_out_grads(fn):
+        def weighted(dy, *a):
+            out = jax.checkpoint(fn)(*a)
+            return (out.astype(jnp.float32)
+                    * dy.astype(jnp.float32)).sum(), out
+        return jax.jit(jax.value_and_grad(weighted, argnums=(1, 2, 3, 4),
+                                          has_aux=True))
+
+    got_fn = value_out_grads(kernels)
+    if not interpret:
+        names = kernels_in(got_fn.lower(dy, xbc, dt, A, D).as_text())
+        check(names == ["ssd_bwd", "ssd_fwd", "ssd_states"],
+              f"the scan lowered to the kernels {names}")
+    (_, got_out), got_grads = got_fn(dy, xbc, dt, A, D)
+    (_, want_out), want_grads = value_out_grads(xla_form)(dy, xbc, dt, A, D)
+    errs = {"out": _rel_err(got_out, want_out)}
+    check(errs["out"] <= SCAN_FWD_TOL,
+          f"the scan's kernels differ from its XLA form by "
+          f"{errs['out']:.3g} of its largest value (bound {SCAN_FWD_TOL})")
+    for name, g, r in zip(("xBC", "dt", "A", "D"), got_grads, want_grads):
+        errs[f"grad_{name}"] = _rel_err(g, r)
+        check(errs[f"grad_{name}"] <= SCAN_GRAD_TOL,
+              f"the scan kernels' gradient of {name} differs from the XLA "
+              f"form's by {errs[f'grad_{name}']:.3g} of its largest value "
+              f"(bound {SCAN_GRAD_TOL})")
+    return {"shape": [b, T, H, P_, G, N], "interpret": interpret,
+            "ssd_plan": plan, **{k: round(e, 5) for k, e in errs.items()}}
 
 
 def _rel_err(got, want) -> float:
@@ -710,6 +802,8 @@ def main(argv=None) -> int:
     if args.chips == 1:
         emit("flash_reference", **flash_reference_phase(
             **FLASH_REFERENCE, seed=args.seed))
+        emit("scan_reference", **scan_reference_phase(
+            **SCAN_REFERENCE, seed=args.seed))
         emit("transformer_lm", **transformer_phase(
             mesh, events, **ONE_CHIP_LM, seed=args.seed))
         emit("resnet50", **resnet_phase(

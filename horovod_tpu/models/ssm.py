@@ -11,11 +11,15 @@ stack (:class:`~horovod_tpu.models.transformer.TransformerLM` with a
     y   = RMSNorm_grouped(y * silu(z)) * scale     (groups of inner / G)
     out = W_out y
 
-The recurrence is :func:`horovod_tpu.ops.ssd.ssd_scan` (chunks of
-``chunk`` tokens, float32 states passed between them).  In a trace the
+The recurrence is :func:`horovod_tpu.ops.ssd.ssd_scan_packed` (chunks of
+``chunk`` tokens, float32 states passed between them; it reads x, B, C
+out of the convolution's one array, and runs as Pallas kernels where the
+shapes tile, as XLA otherwise: ``ops/ssd.py`` chooses).  In a trace the
 module's scopes are ``in_proj``, ``conv``, ``scan``, ``gate_norm`` and
-``out_proj``; ``make_train_step`` counts ``ssm.scan_chunks`` and
-``ssm.state_bytes`` from what the module notes of its shapes while traced.
+``out_proj``; ``make_train_step`` counts ``ssm.scan_chunks``,
+``ssm.state_bytes`` (the float32 states passed between chunks, in VMEM
+where the kernels run) and ``ssm.fused_scans`` (scans that took the
+kernels) from what the module notes of its shapes while traced.
 """
 
 from __future__ import annotations
@@ -27,7 +31,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from horovod_tpu.ops.ssd import scan_sizes, ssd_scan
+from horovod_tpu.ops.ssd import scan_plan, scan_sizes, ssd_scan_packed
 from horovod_tpu.parallel.moe import note_layer
 
 
@@ -124,24 +128,23 @@ class Mamba2Mixer(nn.Module):
         scale = self.param("gate_norm", nn.initializers.ones, (inner,),
                            self.param_dtype)
 
+        interpret = jax.default_backend() != "tpu"
+
         @jax.checkpoint
         def conv_and_scan(xBC, dt, conv_w, conv_b, dt_bias, A_log, D):
             with jax.named_scope("conv"):
                 xBC = nn.silu(causal_conv(xBC, conv_w, conv_b))
-            x, B, C = jnp.split(xBC, [inner, inner + gn], axis=-1)
             with jax.named_scope("scan"):
                 dt = jax.nn.softplus(dt.astype(jnp.float32) + dt_bias)
-                return ssd_scan(
-                    x.reshape(Bsz, T, H, P), dt,
-                    -jnp.exp(A_log.astype(jnp.float32)),
-                    B.reshape(Bsz, T, G, N), C.reshape(Bsz, T, G, N), D,
-                    chunk=self.chunk)
+                return ssd_scan_packed(
+                    xBC, dt, -jnp.exp(A_log.astype(jnp.float32)), D,
+                    heads=H, groups=G, state=N, chunk=self.chunk,
+                    interpret=interpret)
 
         @jax.checkpoint
         def gate_norm(y, z, scale):
             with jax.named_scope("gate_norm"):
-                y = (y.reshape(Bsz, T, inner).astype(jnp.float32)
-                     * nn.silu(z.astype(jnp.float32)))
+                y = y.astype(jnp.float32) * nn.silu(z.astype(jnp.float32))
                 y = y.reshape(Bsz, T, G, inner // G)
                 y = y * jax.lax.rsqrt(
                     jnp.mean(y * y, axis=-1, keepdims=True) + self.norm_eps)
@@ -150,6 +153,10 @@ class Mamba2Mixer(nn.Module):
         y = gate_norm(conv_and_scan(xBC, dt, conv_w, conv_b, dt_bias, A_log,
                                     D), z, scale)
         sizes = scan_sizes(Bsz, T, H, P, N, self.chunk)
+        fused = scan_plan(xBC, dt, heads=H, head_dim=P, groups=G, state=N,
+                          chunk=self.chunk, interpret=interpret
+                          ).form == "kernels"
         note_layer(self.path, {"ssm.scan_chunks": sizes["chunks"],
-                               "ssm.state_bytes": sizes["state_bytes"]})
+                               "ssm.state_bytes": sizes["state_bytes"],
+                               "ssm.fused_scans": int(fused)})
         return dense(d, "out_proj")(y)

@@ -63,6 +63,25 @@ def test_flash_reference_phase(smoke):
     assert set(out["flash_attention"]) == {"out", "grad0", "grad1", "grad2"}
 
 
+def test_scan_reference_phase(smoke):
+    """The scan's kernels against its XLA form at a shape that tiles, and
+    the ``ssd_plan`` line: the cell's shape takes the kernels, on any
+    device that runs them."""
+    out = smoke.scan_reference_phase(batch=1, seq=256, heads=4, head_dim=64,
+                                     groups=2, state=128, chunk=128, seed=0)
+    assert out["interpret"]
+    assert out["ssd_plan"] == {"form": "kernels", "grid": (2, 2),
+                               "vmem_bytes": 1966080, "vmem_mb": 0}
+    assert {"out", "grad_xBC", "grad_dt", "grad_A", "grad_D"} < set(out)
+    assert smoke.ssd_plan(8192, 64, 64, 8, 128, 128) == {
+        "form": "kernels", "grid": (8, 64), "vmem_bytes": 5505024,
+        "vmem_mb": 0}
+    assert smoke.ssd_plan(8192, 64, 64, 8, 128, 16)["form"] == "xla"
+    with pytest.raises(RuntimeError, match="plan at the mixer's shape"):
+        smoke.scan_reference_phase(batch=1, seq=32, heads=4, head_dim=16,
+                                   groups=2, state=16, chunk=16, seed=0)
+
+
 def test_transformer_phase(smoke, one_device_mesh):
     out = smoke.transformer_phase(one_device_mesh, NoCache(), **TINY_LM,
                                   batch=2, steps=2, scan_steps=2, seed=0,

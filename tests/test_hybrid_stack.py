@@ -27,7 +27,9 @@ from horovod_tpu.metrics import registry
 from horovod_tpu.models import NemotronHLM, TransformerLM
 from horovod_tpu.models.ssm import Mamba2Mixer
 from horovod_tpu.ops.flash_attention import flash_attention
-from horovod_tpu.ops.ssd import scan_sizes, ssd_recurrence, ssd_scan
+from horovod_tpu.ops import ssd
+from horovod_tpu.ops.ssd import (
+    scan_sizes, ssd_recurrence, ssd_scan, ssd_scan_packed)
 from horovod_tpu.parallel.moe import (
     _HELD_WINDOW, DroplessMoE, _SharedExpert)
 from horovod_tpu.parallel.ring_attention import full_attention
@@ -84,6 +86,172 @@ def test_scan_sizes_and_groups():
     x, dt, A, B, C, D = scan_inputs(16, H=3, G=2)
     with pytest.raises(ValueError, match="groups"):
         ssd_scan(x, dt, A, B, C, D, chunk=8)
+
+
+# ------------------------------------------------- the scan's kernels
+# (interpreted: the forward kernel, and the backward's two — the states
+# entering every chunk, then the sweep from the last chunk to the first)
+
+# (T, b, H, P, G, N), all in chunks of 128: what tiles.
+KERNEL_SHAPES = {
+    "one_chunk": (128, 1, 2, 64, 1, 128),
+    "three_chunks_batch_2_G_lt_H": (384, 2, 4, 64, 2, 128),
+    "T_not_a_multiple": (300, 1, 2, 64, 1, 128),
+    "G_equals_H": (256, 1, 2, 128, 2, 128),
+}
+
+
+def kernel_inputs(case, dtype):
+    T, b, H, P_, G, N_ = KERNEL_SHAPES[case]
+    x, dt, A, B, C, D = scan_inputs(T, b=b, H=H, P_=P_, G=G, N=N_,
+                                    seed=len(case))
+    return (x.astype(dtype), dt, A, B.astype(dtype), C.astype(dtype), D)
+
+
+def xla_form(x, dt, A, B, C, D):
+    """``_ssd_chunked`` as ``ssd_scan`` calls it where the shape does not
+    tile: the kernels' second oracle."""
+    T = x.shape[1]
+    x, dt, B, C = ssd._padded((x, dt, B, C), T, 128)
+    return ssd._ssd_chunked(x, dt, A, B, C, D, 128)[:, :T]
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", sorted(KERNEL_SHAPES))
+def test_scan_kernels_equal_their_oracles(case, dtype):
+    """Values and the gradients of all six inputs.  float32 against the
+    recurrence: float32 rounding (observed 2.4e-6 of the norm forward,
+    7e-6 the gradients, ``A``'s — a sum of terms of both signs over every
+    position — 5.7e-5, the XLA form's 1.5e-4).  bfloat16 against the XLA
+    form at the same precisions: the forward rounds the same tiles
+    (observed 2.1e-5: a rounding flipped here and there), the backward
+    rounds its cotangent operands to bfloat16 where autodiff on the CPU
+    keeps them float32 (observed 3.6e-3, ``A``'s 7.5e-3; against the
+    recurrence the kernels' ``dt`` and ``A`` read 1.7e-3 and 7.8e-3 where
+    the XLA form's read 2.4e-3 and 7.8e-3 — the row and the column sums of
+    a decay tile's cotangent cancel in the running sum, and have to be
+    taken from one float32 tile: taken from a product with the rounded
+    tile, ``A``'s read 0.69)."""
+    args = kernel_inputs(case, dtype)
+    assert ssd.scan_plan(args[0], args[1], heads=args[0].shape[2],
+                         head_dim=args[0].shape[3],
+                         groups=args[3].shape[2], state=128, chunk=128,
+                         interpret=True).form == "kernels"
+    oracle, value_tol, grad_tol, a_tol = (
+        (ssd_recurrence, 1e-5, 1e-4, 2e-4) if dtype == "float32"
+        else (xla_form, 2e-3, 1e-2, 2e-2))
+    weight = jnp.cos(jnp.arange(args[0].size, dtype=jnp.float32)).reshape(
+        args[0].shape)
+
+    def loss(f):
+        return lambda *a: (f(*a).astype(jnp.float32) * weight).sum()
+
+    with jax.default_matmul_precision("highest"):
+        got = ssd_scan(*args, chunk=128, interpret=True)
+        want = oracle(*args)
+        assert got.shape == want.shape and got.dtype == args[0].dtype
+        assert rel(got.astype(jnp.float32),
+                   want.astype(jnp.float32)) <= value_tol
+        grads = [jax.grad(loss(f), argnums=tuple(range(6)))(*args)
+                 for f in (lambda *a: ssd_scan(*a, chunk=128,
+                                               interpret=True), oracle)]
+    for i, (g, w) in enumerate(zip(*grads)):
+        assert g.dtype == w.dtype and g.shape == w.shape
+        assert rel(g.astype(jnp.float32), w.astype(jnp.float32)) <= (
+            a_tol if i == 2 else grad_tol), "x dt A B C D".split()[i]
+
+
+def test_packed_entry_reads_x_B_C_out_of_one_array():
+    """The mixer's entry: ``x | B | C`` as the convolution leaves them,
+    kernels and XLA form alike, gradient of the one array included."""
+    for case, chunk, form in (("three_chunks_batch_2_G_lt_H", 128,
+                               "kernels"),
+                              ("T_not_a_multiple", 64, "xla")):
+        x, dt, A, B, C, D = kernel_inputs(case, "float32")
+        b, T, H, P_ = x.shape
+        G, N_ = B.shape[2:]
+        packed = jnp.concatenate([x.reshape(b, T, -1), B.reshape(b, T, -1),
+                                  C.reshape(b, T, -1)], axis=-1)
+        kw = dict(heads=H, groups=G, state=N_, chunk=chunk, interpret=True)
+        assert ssd.scan_plan(packed, dt, head_dim=P_, **kw).form == form
+
+        def ours(p):
+            return ssd_scan_packed(p, dt, A, D, **kw)
+
+        def split(p):
+            x, B, C = jnp.split(p, [H * P_, H * P_ + G * N_], axis=-1)
+            return ssd_recurrence(x.reshape(b, T, H, P_), dt, A,
+                                  B.reshape(b, T, G, N_),
+                                  C.reshape(b, T, G, N_), D).reshape(b, T, -1)
+
+        with jax.default_matmul_precision("highest"):
+            assert rel(ours(packed), split(packed)) <= 1e-5
+            got = jax.grad(lambda p: (ours(p) ** 2).sum())(packed)
+            want = jax.grad(lambda p: (split(p) ** 2).sum())(packed)
+        assert rel(got, want) <= 1e-4
+    with pytest.raises(ValueError, match="groups"):
+        ssd_scan_packed(packed, dt, A, D, heads=H, groups=3, state=N_)
+
+
+def seen(T=8192, H=64, P=64, G=8, N=128, chunk=128, itemsize=2,
+         interpret=False, manual_axes=False, vmem_headroom=True):
+    return dict(T=T, H=H, P=P, G=G, N=N, chunk=chunk, itemsize=itemsize,
+                interpret=interpret, manual_axes=manual_axes,
+                vmem_headroom=vmem_headroom)
+
+
+KERNELS, XLA = "kernels", ("xla", (), 0, 0)
+# What ``ssd._plan`` observes -> (form, (groups, chunks) a sequence, VMEM
+# bytes by shapes, scoped-VMEM MB asked: 0 is Mosaic's default).
+PLAN_TABLE = {
+    # twotower_1chip: 8 groups of 8 heads of 64, 64 chunks a sequence.
+    "cell": (seen(), (KERNELS, (8, 64), 5505024, 0)),
+    "cell_float32": (seen(itemsize=4), (KERNELS, (8, 64), 6553600, 0)),
+    "cell_T_not_a_multiple": (seen(T=8200), (KERNELS, (8, 65), 5505024, 0)),
+    "cell_compiled_under_shard_map": (seen(manual_axes=True),
+                                      (KERNELS, (8, 64), 5505024, 0)),
+    "cell_no_headroom": (seen(vmem_headroom=False),
+                         (KERNELS, (8, 64), 5505024, 0)),
+    # Interpreted Pallas cannot run under manual mesh axes (jax 0.9.0).
+    "interpreted_under_shard_map": (seen(interpret=True, manual_axes=True),
+                                    XLA),
+    "interpreted": (seen(T=384, H=4, G=2, itemsize=4, interpret=True),
+                    (KERNELS, (2, 3), 2424832, 0)),
+    "one_head_of_128_a_group": (seen(T=256, H=2, P=128, G=2),
+                                (KERNELS, (2, 2), 1966080, 0)),
+    # The tiny preset of the CPU tests, and every way of not tiling.
+    "tiny_preset": (seen(T=64, H=4, P=16, G=2, N=16, chunk=16, itemsize=4,
+                         interpret=True), XLA),
+    "chunk_16": (seen(chunk=16), XLA),
+    "chunk_64": (seen(chunk=64), XLA),
+    "state_64": (seen(N=64), XLA),
+    "head_of_96": (seen(P=96), XLA),
+    "a_group_of_one_head_of_64": (seen(G=64), XLA),
+    "three_heads_of_64_a_group": (seen(H=48, G=16), XLA),
+    "channels_not_in_blocks_of_state": (seen(H=6, G=3, N=256), XLA),
+    "groups_do_not_divide": (seen(H=64, G=7), XLA),
+    # 64 heads of 64 in one group: blocks past the default budget.
+    "one_group_of_4096": (seen(G=1), (KERNELS, (1, 64), 38535168, 49)),
+    "one_group_of_4096_no_headroom": (seen(G=1, vmem_headroom=False), XLA),
+    "one_group_of_8192": (seen(H=128, G=1), XLA),
+}
+
+
+@pytest.mark.parametrize("case", sorted(PLAN_TABLE))
+def test_scan_plan_table(case):
+    """The one function that chooses kernels or the XLA form: a pure
+    table, no kernel, no device."""
+    observed, want = PLAN_TABLE[case]
+    assert ssd._plan(**observed) == ssd.ScanPlan(*want)
+
+
+def test_the_scan_has_no_knob():
+    import inspect
+
+    source = inspect.getsource(ssd)
+    assert "environ" not in source and "getenv" not in source
+    assert list(inspect.signature(ssd_scan).parameters) == [
+        "x", "dt", "A", "B", "C", "D", "chunk", "interpret"]
 
 
 def family_cfg(compute_dtype="float32", **override):
@@ -462,6 +630,53 @@ def test_the_float32_parts_are_float32_in_the_traced_program():
                                          jax.lax.Precision.HIGHEST)
 
 
+def test_the_float32_parts_are_float32_in_the_kernels_too():
+    """The same parts where the scan runs as kernels (bfloat16 operands at
+    a tiling shape): the state scratch of the forward and of the states
+    pass, the transient of entering states between the backward's two
+    kernels and the carried gradient of the state are float32; the
+    running-sum product (and its transpose in the sweep) takes float32
+    operands at HIGHEST; every other product takes its operands in
+    ``x.dtype`` — not narrower — and accumulates in float32."""
+    x, dt, A, B, C, D = kernel_inputs("three_chunks_batch_2_G_lt_H",
+                                      "bfloat16")
+    b, T, H, P_ = x.shape
+    G, N_ = B.shape[2:]
+    RP = H // G * P_
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda *a: ssd_scan(*a, chunk=128, interpret=True).astype(
+            jnp.float32).sum(), argnums=tuple(range(6))))(x, dt, A, B, C, D)
+    calls = {e.params["jaxpr"].debug_info.func_name: e
+             for e in _equations(jaxpr.jaxpr)
+             if e.primitive.name == "pallas_call"}
+    assert set(calls) == {"ssd_fwd", "ssd_states", "ssd_bwd"}
+    highest = (jax.lax.Precision.HIGHEST, jax.lax.Precision.HIGHEST)
+    for name, call in calls.items():
+        kernel = call.params["jaxpr"]
+        carried = kernel.invars[-1].aval         # the one scratch buffer
+        assert (carried.shape, carried.dtype) == ((N_, RP), jnp.float32)
+        dots = [e for e in _equations(kernel)
+                if e.primitive.name == "dot_general"]
+        sums = [e for e in dots if e.params["precision"] in (
+            highest, jax.lax.Precision.HIGHEST)]
+        assert len(sums) == {"ssd_fwd": 1, "ssd_states": 1,
+                             "ssd_bwd": 2}[name]
+        for e in sums:
+            assert all(v.aval.dtype == jnp.float32 for v in e.invars)
+            assert 128 in e.invars[1].aval.shape     # the triangle
+        products = [e for e in dots if e not in sums]
+        assert len(products) >= {"ssd_fwd": 4, "ssd_states": 1,
+                                 "ssd_bwd": 12}[name]
+        for e in products:
+            assert all(v.aval.dtype == jnp.bfloat16 for v in e.invars)
+            assert e.params["preferred_element_type"] == jnp.float32
+    entering = calls["ssd_states"].outvars[0].aval
+    assert (entering.shape, entering.dtype) == ((b, G, 3, N_, RP),
+                                                jnp.float32)
+    assert any(v.aval.shape == entering.shape and v.aval.dtype
+               == jnp.float32 for v in calls["ssd_bwd"].invars)
+
+
 def test_tiny_stack_trains_through_make_train_step(hvd):
     """The nine-layer preset through the normal path on the 8-device mesh:
     the first step's loss is the reference's on the global batch, the loss
@@ -474,8 +689,8 @@ def test_tiny_stack_trains_through_make_train_step(hvd):
     opt_state = tx.init(params)
     want = float(nemotron_h_lm.reference_loss(cfg)(params, aux, tokens))
     step = make_train_step(nemotron_h_lm.loss_fn(cfg), tx, hvd.ranks_mesh())
-    names = ("ssm.scan_chunks", "ssm.state_bytes", "moe.assignments",
-             "moe.held_assignments", "moe.expert_bytes")
+    names = ("ssm.scan_chunks", "ssm.state_bytes", "ssm.fused_scans",
+             "moe.assignments", "moe.held_assignments", "moe.expert_bytes")
     before = {n: registry.snapshot()["counters"].get(n, 0) for n in names}
     losses = []
     for _ in range(4):
@@ -489,11 +704,65 @@ def test_tiny_stack_trains_through_make_train_step(hvd):
     # A shard's step, four dispatches: one sequence of 64 tokens through 4
     # mixers (4 chunks of 16; 4 heads x 16 x 16 float32 a state) and 4
     # expert layers (3 of 8 experts a token, 4 held, 2 matrices of 64x32).
+    # The preset's scans do not tile (chunks of 16): the XLA form, no
+    # fused scan.
     assert got == {"ssm.scan_chunks": 4 * 4 * 4,
                    "ssm.state_bytes": 4 * 4 * 4 * 4 * 16 * 16 * 4,
+                   "ssm.fused_scans": 0,
                    "moe.assignments": 4 * 4 * 64 * 3,
                    "moe.held_assignments": 4 * 4 * 64 * 3 // 2,
                    "moe.expert_bytes": 4 * 4 * 2 * 4 * 64 * 32 * 4}
+
+
+def test_one_mixer_at_a_tiling_shape_counts_a_fused_scan(hvd):
+    """A one-mixer stack whose scan tiles (2 heads of 64 in one group,
+    state 128, one chunk of 128) through ``make_train_step`` on one device
+    — the plain program, so no manual mesh axis stands the interpreted
+    kernels down: it trains, each dispatch counts one fused scan, and the
+    lowered step names the three kernels under ``ssm/scan``, where the
+    cell's reader looks and nowhere else."""
+    import re
+
+    import optax
+
+    from benchmark.metrics import ssm_ms
+    from horovod_tpu.parallel.mesh import RANKS_AXIS
+
+    model = NemotronHLM(vocab=64, dim=32, pattern="M", max_len=128,
+                        dtype=jnp.float32,
+                        ssm=dict(num_heads=2, head_dim=64, n_groups=1,
+                                 state_size=128, chunk=128))
+    tokens = jax.random.randint(jax.random.PRNGKey(0), (1, 129), 0, 64)
+    params = model.init(jax.random.PRNGKey(1), tokens[:, :-1])["params"]
+
+    def loss_fn(p, aux, tokens):
+        logits = model.apply({"params": p}, tokens[:, :-1])
+        return optax.softmax_cross_entropy_with_integer_labels(
+            logits, tokens[:, 1:]).mean(), aux
+
+    text = jax.jit(jax.grad(lambda p: loss_fn(p, {}, tokens)[0])).lower(
+        params).as_text(debug_info=True)
+    stacks = set(re.findall(r'"([^"]*/ssd_(?:fwd|states|bwd))[/"]', text))
+    assert {s.rsplit("/", 1)[1] for s in stacks} == {
+        "ssd_fwd", "ssd_states", "ssd_bwd"}
+    assert all(ssm_ms.in_scan(s) for s in stacks), stacks
+    for part in ("intra", "states", "pass", "inter"):
+        assert f"ssm/scan/{part}" not in text
+
+    tx = optax.sgd(0.5)
+    step = make_train_step(loss_fn, tx, Mesh(np.asarray(jax.devices()[:1]),
+                                             (RANKS_AXIS,)))
+    names = ("ssm.fused_scans", "ssm.scan_chunks", "ssm.state_bytes")
+    before = {n: registry.snapshot()["counters"].get(n, 0) for n in names}
+    aux, opt_state, losses = {}, tx.init(params), []
+    for _ in range(3):
+        params, aux, opt_state, loss = step(params, aux, opt_state, tokens)
+        losses.append(float(loss))
+    assert losses[-1] < losses[0]
+    after = registry.snapshot()["counters"]
+    assert {n: after.get(n, 0) - before[n] for n in names} == {
+        "ssm.fused_scans": 3, "ssm.scan_chunks": 3,
+        "ssm.state_bytes": 3 * 2 * 64 * 128 * 4}
 
 
 def test_trace_scopes_name_the_mixer_s_parts_and_the_shared_expert():

@@ -118,23 +118,37 @@ HEAD_KERNEL_EQUATIONS = {"_dkdv_kernel_grouped": 176,
                          "_dq_kernel_grouped": 149}
 
 
-def test_each_kernel_is_traced_once_a_step_not_once_a_layer(monkeypatch):
+@pytest.mark.parametrize("family", ["flash", "scan"])
+def test_each_kernel_is_traced_once_a_step_not_once_a_layer(monkeypatch,
+                                                            family):
     """The set-up guard, no chip and no compile: tracing ``jax.grad`` of a
-    three-layer ``TransformerLM`` at the cell's widths runs each kernel
-    body of the flash family once, the layers' calls sharing the traces of
-    ``_qkv_fwd`` / ``_qkv_bwd`` (three times each, the forward six, before
-    PR 29: a kernel body's cost was paid 14 times a set-up on one chip).
-    And the bodies
+    stack at the cell's widths runs each kernel body once.
+
+    ``flash``: a three-layer ``TransformerLM``; the layers' calls share the
+    traces of ``_qkv_fwd`` / ``_qkv_bwd`` (three times each, the forward
+    six, before PR 29: a kernel body's cost was paid 14 times a set-up on
+    one chip).  And the bodies
     stay of a size: the pair's jaxprs hold at most three times the
     equations they held with whole blocks only — a whole-block body and
     seven products of the diagonal's triangle for a masked and an unmasked
     whole-block body; the form that emits one sub-tile body, a rolled
-    loop, lost 9 ms a step on the chip (PERF.md, PR 29)."""
+    loop, lost 9 ms a step on the chip (PERF.md, PR 29).
+
+    ``scan``: the pattern stack's four mixers at Nemotron-H's widths (a
+    sequence of 256); each mixer calls the forward kernel, its
+    ``jax.checkpoint`` replays it, its backward rule calls the states pass
+    and the sweep.  The four mixers share the traces of ``_fused_fwd`` /
+    ``_fused_bwd`` (``ops/ssd.py``): the states pass and the sweep are
+    traced once, the forward body twice — once as the forward that runs,
+    once as the checkpoint's replay (a rule traced while the checkpoint's
+    jaxpr is evaluated sees another trace context than the step's own, so
+    the two do not share; 0.2 s, and the replay leaves no kernel)."""
     import collections
     import functools
 
-    from horovod_tpu.models import TransformerLM
+    from horovod_tpu.models import NemotronHLM, TransformerLM
     from horovod_tpu.ops import flash_attention as fa
+    from horovod_tpu.ops import ssd
 
     calls = collections.Counter()
 
@@ -149,21 +163,32 @@ def test_each_kernel_is_traced_once_a_step_not_once_a_layer(monkeypatch):
                  "_fwd_kernel_fullunroll", "_dq_kernel", "_dkdv_kernel",
                  "_dq_kernel_grouped", "_dkdv_kernel_grouped"):
         monkeypatch.setattr(fa, name, counted(name, getattr(fa, name)))
+    for name in ("_fwd_kernel", "_states_kernel", "_bwd_kernel"):
+        monkeypatch.setattr(ssd, name,
+                            counted("ssd." + name, getattr(ssd, name)))
 
     batch = 3      # no other test's: a trace made earlier would be shared
-    model = TransformerLM(vocab=512, dim=H * D, depth=3, num_heads=H,
-                          max_len=T, attn="flash", dtype=jnp.bfloat16)
+    if family == "flash":
+        seq = T
+        model = TransformerLM(vocab=512, dim=H * D, depth=3, num_heads=H,
+                              max_len=T, attn="flash", dtype=jnp.bfloat16)
+        want = {"_fwd_kernel_fullunroll": 1, "_dq_kernel_grouped": 1,
+                "_dkdv_kernel_grouped": 1}
+    else:
+        seq = 256
+        model = NemotronHLM(vocab=512, dim=256, pattern="MMMM", max_len=seq,
+                            dtype=jnp.bfloat16)
+        want = {"ssd._fwd_kernel": 2, "ssd._states_kernel": 1,
+                "ssd._bwd_kernel": 1}
     params = jax.eval_shape(
-        lambda key: model.init(key, jnp.zeros((1, T), jnp.int32))["params"],
+        lambda key: model.init(key, jnp.zeros((1, seq), jnp.int32))["params"],
         jax.random.PRNGKey(0))
     calls.clear()
     jaxpr = jax.make_jaxpr(jax.grad(
         lambda p, tokens: model.apply({"params": p}, tokens).astype(
             jnp.float32).sum()))(
-        params, jax.ShapeDtypeStruct((batch, T), jnp.int32))
-    assert dict(calls) == {"_fwd_kernel_fullunroll": 1,
-                           "_dq_kernel_grouped": 1,
-                           "_dkdv_kernel_grouped": 1}
+        params, jax.ShapeDtypeStruct((batch, seq), jnp.int32))
+    assert dict(calls) == want
 
     def sub_jaxprs(eqn):
         for value in eqn.params.values():
@@ -185,7 +210,14 @@ def test_each_kernel_is_traced_once_a_step_not_once_a_layer(monkeypatch):
                 for j in sub_jaxprs(eqn):
                     yield from kernels(j)
 
-    sizes = dict(kernels(jaxpr.jaxpr))
+    found = list(kernels(jaxpr.jaxpr))
+    sizes = dict(found)
+    if family == "scan":
+        # Four mixers: the forward, and in the backward the states pass
+        # and the sweep; the replayed forwards are gone with their ``y``.
+        names = collections.Counter(name for name, _ in found)
+        assert names == {"ssd_fwd": 4, "ssd_states": 4, "ssd_bwd": 4}
+        return
     assert set(HEAD_KERNEL_EQUATIONS) < set(sizes)
     for name, at_head in HEAD_KERNEL_EQUATIONS.items():
         assert sizes[name] <= 3 * at_head, (name, sizes[name], at_head)
@@ -357,12 +389,19 @@ def test_grouped_kv_flash_fwd_bwd_at_nemotron_widths(v5e, monkeypatch):
 
 
 def test_chunked_scan_fwd_bwd_at_nemotron_widths(v5e):
-    """``ssd_scan`` as the mixer calls it: 64 heads of 64, 8 groups, state
-    128, chunks of 128 — plain XLA, so no custom call; under a
-    ``jax.checkpoint``, as the mixer runs it, it fits the chip with its
-    gradients several times over, because the chunk-square tiles are
-    recomputed and not kept."""
-    from horovod_tpu.ops.ssd import ssd_scan
+    """``ssd_scan_packed`` as the mixer calls it — 2 sequences of 8,192, 64
+    heads of 64, 8 groups, state 128, chunks of 128, x | B | C as the
+    convolution's one array, under a ``jax.checkpoint`` — with the kernels
+    asked for compiled.  The value and its gradients are three kernels
+    (``ssd_fwd``; ``ssd_states`` and ``ssd_bwd``: the forward replayed by
+    the checkpoint leaves none, nothing reads its ``y``); nothing copies
+    or transposes a (2, 8192, ...) bfloat16 array on its way in or out —
+    only ``dt`` (4 MB, float32) is turned time-minor —; and the plan is
+    0.76 GiB where the XLA form's, with its chunk-square tiles and its
+    chunk states in HBM, is 1.73."""
+    import re
+
+    from horovod_tpu.ops import ssd
 
     one = SingleDeviceSharding(v5e[0])
 
@@ -370,19 +409,73 @@ def test_chunked_scan_fwd_bwd_at_nemotron_widths(v5e):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
 
     b, t, h, p, g, n = 2, 8192, 64, 64, 8, 128
-    args = (s((b, t, h, p)), s((b, t, h), jnp.float32), s((h,), jnp.float32),
-            s((b, t, g, n)), s((b, t, g, n)), s((h,), jnp.float32))
+    args = (s((b, t, h * p + 2 * g * n)), s((b, t, h), jnp.float32),
+            s((h,), jnp.float32), s((h,), jnp.float32))
+    assert ssd.scan_plan(*args[:2], heads=h, head_dim=p, groups=g, state=n,
+                         chunk=128, interpret=False) == ssd.ScanPlan(
+                             "kernels", (8, 64), 5505024, 0)
 
     @jax.checkpoint
     def loss(*a):
-        return ssd_scan(*a, chunk=128).astype(jnp.float32).sum()
+        return ssd.ssd_scan_packed(*a, heads=h, groups=g, state=n,
+                                   chunk=128).astype(jnp.float32).sum()
 
-    compiled = jax.jit(jax.value_and_grad(loss, argnums=tuple(range(6)))
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3))
                        ).lower(*args).compile()
+    text = compiled.as_text()
+    kernels = [line for line in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(kernels) == 3
+    for name in ("ssd_fwd", "ssd_states", "ssd_bwd"):
+        assert sum(name in line.split(" = ")[0] for line in kernels) == 1
+    moved = [line for line in text.splitlines()
+             if re.search(r"= bf16\[2,8192,\d+\]\S* (copy|transpose)\(", line)]
+    assert not moved, moved
+    _, (dxbc, ddt, dA, dD) = compiled.out_info
+    assert dxbc.shape == args[0].shape and dxbc.dtype == jnp.bfloat16
+    assert (ddt.shape, dA.shape, dD.shape) == ((b, t, h), (h,), (h,))
     m = compiled.memory_analysis()
     plan = (m.argument_size_in_bytes + m.output_size_in_bytes
             + m.temp_size_in_bytes - m.alias_size_in_bytes)
-    assert plan < 6 * 2 ** 30, plan / 2 ** 30
+    assert plan < 1.0 * 2 ** 30, plan / 2 ** 30
+
+
+# (b, T, H, P, G, N, chunk, dtype): what else ``ssd._plan`` hands to the
+# kernels, one case a way of tiling — a head of 128 alone in its group,
+# two heads of 64 to a tile, a head wider than a tile, float32 operands at
+# the cell's shape, a wider state, a longer chunk.
+@pytest.mark.parametrize("b,t,h,p,g,n,chunk,dtype", [
+    (2, 1024, 2, 128, 2, 128, 128, "bfloat16"),
+    (2, 1024, 4, 64, 2, 128, 128, "bfloat16"),
+    (1, 1024, 4, 256, 2, 128, 128, "bfloat16"),
+    (2, 8192, 64, 64, 8, 128, 128, "float32"),
+    (1, 1024, 16, 64, 2, 256, 128, "bfloat16"),
+    (1, 1024, 16, 64, 2, 128, 256, "bfloat16")],
+    ids=["one_head_of_128_a_group", "two_heads_of_64_a_group",
+         "heads_of_256", "cell_float32", "state_256", "chunk_256"])
+def test_chunked_scan_compiles_wherever_the_plan_takes_the_kernels(
+        v5e, b, t, h, p, g, n, chunk, dtype):
+    """A shape ``_plan`` gives the kernels has to compile: interpret mode
+    refuses nothing of what Mosaic refuses (a (1, 1) value broadcast over
+    a tile was refused at one head a group)."""
+    from horovod_tpu.ops import ssd
+
+    one = SingleDeviceSharding(v5e[0])
+    args = tuple(jax.ShapeDtypeStruct(shape, kind, sharding=one)
+                 for shape, kind in (((b, t, h * p + 2 * g * n), dtype),
+                                     ((b, t, h), "float32"),
+                                     ((h,), "float32"), ((h,), "float32")))
+    assert ssd.scan_plan(*args[:2], heads=h, head_dim=p, groups=g, state=n,
+                         chunk=chunk, interpret=False).form == "kernels"
+
+    @jax.checkpoint
+    def loss(*a):
+        return ssd.ssd_scan_packed(*a, heads=h, groups=g, state=n,
+                                   chunk=chunk).astype(jnp.float32).sum()
+
+    text = compile_text(jax.value_and_grad(loss, argnums=(0, 1, 2, 3)),
+                        *args)
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
 
 
 def test_held_expert_layer_fwd_bwd_at_nemotron_widths(v5e):
