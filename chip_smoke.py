@@ -54,6 +54,8 @@ TRANSFORMER = dict(vocab=32768, dim=2048, depth=12, heads=16, seq=2048)
 FLASH_REFERENCE = dict(batch=8, seq=2048, heads=16, head_dim=128)
 SCAN_REFERENCE = dict(batch=2, seq=2048, heads=64, head_dim=64, groups=8,
                       state=128, chunk=128)
+DELTA_REFERENCE = dict(batch=1, seq=2048, heads=30, key_dim=96,
+                       value_dim=192, chunk=64)
 ONE_CHIP_LM = dict(**TRANSFORMER, batch=8, steps=3, scan_steps=4)
 ONE_CHIP_RESNET = dict(stage_sizes=(3, 4, 6, 3), num_filters=64,
                        num_classes=1000, image=224, batch=128, steps=3)
@@ -69,6 +71,8 @@ FLASH_GRAD_TOL = 4e-2
 # tiles; the backward's sums run in another order.
 SCAN_FWD_TOL = 1e-2
 SCAN_GRAD_TOL = 4e-2
+# The chunked delta rule in bfloat16 against its recurrence in float32.
+DELTA_TOL = 4e-2
 LOSS_TOL = 2e-2          # 4-device vs 1-device loss, same step
 INT8_LOSS_TOL = 2e-2     # int8 wire vs fp32 wire loss, same step
 
@@ -319,6 +323,57 @@ def scan_reference_phase(*, batch: int, seq: int, heads: int, head_dim: int,
               f"(bound {SCAN_GRAD_TOL})")
     return {"shape": [b, T, H, P_, G, N], "interpret": interpret,
             "ssd_plan": plan, **{k: round(e, 5) for k, e in errs.items()}}
+
+
+def delta_reference_phase(*, batch: int, seq: int, heads: int, key_dim: int,
+                          value_dim: int, chunk: int, seed: int) -> dict:
+    """The gated delta rule as the linear-attention mixer calls it
+    (``gated_delta_rule``: bfloat16 operands, under a ``jax.checkpoint``)
+    against the recurrence it computes, token by token in float32, on the
+    same inputs: ``o`` and the gradients of q, k, v, g and beta."""
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.ops import gated_delta as gd
+
+    b, T, H = batch, seq, heads
+    ks = jax.random.split(jax.random.PRNGKey(seed), 6)
+
+    def unit(key, width):
+        u = jax.random.normal(key, (b, T, H, width))
+        return u / jnp.linalg.norm(u, axis=-1, keepdims=True)
+
+    q, k = unit(ks[0], key_dim) * key_dim ** -0.5, unit(ks[1], key_dim)
+    v = jax.random.normal(ks[2], (b, T, H, value_dim))
+    g = -jnp.exp(jax.random.uniform(ks[3], (H,), minval=0.0, maxval=2.7)
+                 ) * jax.nn.softplus(jax.random.normal(ks[4], (b, T, H)) - 4.0)
+    beta = 2.0 * jax.nn.sigmoid(jax.random.normal(ks[5], (b, T, H)))
+    do = jax.random.normal(ks[0], (b, T, H, value_dim))
+    low = tuple(a.astype(jnp.bfloat16) for a in (q, k, v)) + (g, beta)
+
+    def value_out_grads(fn):
+        def weighted(*a):
+            out = jax.checkpoint(fn)(*a)
+            return (out.astype(jnp.float32) * do).sum(), out
+        return jax.jit(jax.value_and_grad(weighted, argnums=range(5),
+                                          has_aux=True))
+
+    (_, got_out), got_grads = value_out_grads(
+        lambda *a: gd.gated_delta_rule(*a, chunk=chunk))(*low)
+    (_, want_out), want_grads = value_out_grads(gd.gated_delta_recurrence)(
+        q, k, v, g, beta)
+    errs = {"out": _rel_err(got_out, want_out)}
+    for name, got, want in zip(("q", "k", "v", "g", "beta"), got_grads,
+                               want_grads):
+        errs[f"grad_{name}"] = _rel_err(got, want)
+    for name, err in errs.items():
+        check(err <= DELTA_TOL,
+              f"the chunked delta rule's {name} differs from the "
+              f"recurrence's by {err:.3g} of its largest value "
+              f"(bound {DELTA_TOL})")
+    return {"shape": [b, T, H, key_dim, value_dim],
+            "delta_plan": gd.delta_plan(chunk)._asdict(),
+            **{k_: round(e, 5) for k_, e in errs.items()}}
 
 
 def _rel_err(got, want) -> float:
@@ -804,6 +859,8 @@ def main(argv=None) -> int:
             **FLASH_REFERENCE, seed=args.seed))
         emit("scan_reference", **scan_reference_phase(
             **SCAN_REFERENCE, seed=args.seed))
+        emit("delta_reference", **delta_reference_phase(
+            **DELTA_REFERENCE, seed=args.seed))
         emit("transformer_lm", **transformer_phase(
             mesh, events, **ONE_CHIP_LM, seed=args.seed))
         emit("resnet50", **resnet_phase(

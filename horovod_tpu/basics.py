@@ -15,7 +15,7 @@ from __future__ import annotations
 
 import atexit
 import threading
-from typing import Optional, Sequence
+from typing import Any, NamedTuple, Optional, Sequence
 
 from horovod_tpu import topology as _topology_mod
 
@@ -49,10 +49,22 @@ class _GlobalState:
 _state = _GlobalState()
 
 
-def _require_init() -> _GlobalState:
+class _Live(NamedTuple):
+    """What an initialized framework holds, read at one moment."""
+    topology: _topology_mod.Topology
+    controller: Any                     # horovod_tpu.core.Controller
+    mesh: Any
+
+
+def _require_init() -> _Live:
+    """The initialized state, or :class:`NotInitializedError`.  The fields
+    are read BEFORE the flag and ``shutdown()`` lowers the flag before it
+    clears them, so a thread that races a shutdown gets whole fields or
+    the documented error, never a ``None``."""
+    live = _Live(_state.topology, _state.controller, _state.mesh)
     if not _state.initialized:
         raise NotInitializedError()
-    return _state
+    return live
 
 
 def init(ranks: Optional[Sequence[int]] = None) -> None:
@@ -120,10 +132,10 @@ def shutdown() -> None:
             # re-seeds the registry from HOROVOD_TPU_PROCESS_SETS.
             from horovod_tpu import process_set as _process_set_mod
             _process_set_mod.reset()
+            _state.initialized = False      # before the fields: _require_init
             _state.controller = None
             _state.topology = None
             _state.mesh = None
-            _state.initialized = False
             _state.shut_down = True
 
 

@@ -35,7 +35,7 @@ from horovod_tpu.ops.ssd import scan_plan, scan_sizes, ssd_scan_packed
 from horovod_tpu.parallel.moe import note_layer
 
 
-def _dt_bias_init(dt_min: float, dt_max: float, dt_floor: float):
+def dt_bias_init(dt_min: float, dt_max: float, dt_floor: float):
     """Mamba's: ``dt`` log-uniform in [dt_min, dt_max], floored, and the
     bias its inverse softplus."""
     def init(key, shape, dtype):
@@ -47,17 +47,19 @@ def _dt_bias_init(dt_min: float, dt_max: float, dt_floor: float):
     return init
 
 
-def _a_log_init(key, shape, dtype):
+def a_log_init(key, shape, dtype):
     return jnp.log(jax.random.uniform(key, shape, jnp.float32, 1.0, 16.0)
                    ).astype(dtype)
 
 
 class CausalConv(nn.Module):
     """The parameters of a depthwise causal convolution over time,
-    ``kernel`` taps a channel and a bias; :func:`causal_conv` applies
-    them (the mixer does, inside the block it recomputes)."""
+    ``kernel`` taps a channel and, unless ``use_bias`` is off, a bias
+    (``None`` then); :func:`causal_conv` applies them (the mixer does,
+    inside the block it recomputes)."""
     kernel: int = 4
     param_dtype: Any = jnp.float32
+    use_bias: bool = True
 
     @nn.compact
     def __call__(self, channels: int):
@@ -68,16 +70,18 @@ class CausalConv(nn.Module):
 
         return (self.param("kernel", uniform, (self.kernel, channels),
                            self.param_dtype),
-                self.param("bias", uniform, (channels,), self.param_dtype))
+                self.param("bias", uniform, (channels,), self.param_dtype)
+                if self.use_bias else None)
 
 
-def causal_conv(x, w, b):
+def causal_conv(x, w, b=None):
     """``y_t = b + sum_j w_j x_{t - (K - 1) + j}`` on ``x`` (..., T, c) with
-    ``w`` (K, c): K shifted multiply-adds, in ``x.dtype``."""
+    ``w`` (K, c): K shifted multiply-adds, in ``x.dtype``; no ``b``, no
+    bias."""
     K, T = w.shape[0], x.shape[-2]
     padded = jnp.pad(x, [(0, 0)] * (x.ndim - 2) + [(K - 1, 0), (0, 0)])
     w = w.astype(x.dtype)
-    y = b.astype(x.dtype)
+    y = 0 if b is None else b.astype(x.dtype)
     for j in range(K):
         y = y + w[j] * padded[..., j:j + T, :]
     return y
@@ -121,9 +125,9 @@ class Mamba2Mixer(nn.Module):
         z, xBC, dt = jnp.split(zxbcdt, [inner, 2 * inner + 2 * gn], axis=-1)
         conv_w, conv_b = CausalConv(self.conv_kernel, self.param_dtype,
                                     name="conv")(inner + 2 * gn)
-        dt_bias = self.param("dt_bias", _dt_bias_init(
+        dt_bias = self.param("dt_bias", dt_bias_init(
             self.dt_min, self.dt_max, self.dt_floor), (H,), self.param_dtype)
-        A_log = self.param("A_log", _a_log_init, (H,), self.param_dtype)
+        A_log = self.param("A_log", a_log_init, (H,), self.param_dtype)
         D = self.param("D", nn.initializers.ones, (H,), self.param_dtype)
         scale = self.param("gate_norm", nn.initializers.ones, (inner,),
                            self.param_dtype)

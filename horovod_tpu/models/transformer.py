@@ -35,13 +35,19 @@ the whole q and k vectors before the split into heads) and
 dropped token).  :func:`OLMoELM` is OLMoE-1B-7B's setting of them.
 
 ``pattern`` replaces the stack of blocks by a hybrid one, a letter a
-layer, each layer ONE sub-layer behind a pre-norm residual
-(``x + f(norm(x))``): ``M`` a Mamba-2 mixer
+layer.  ``M``, ``*`` and ``E`` are ONE sub-layer behind a pre-norm
+residual (``x + f(norm(x))``): ``M`` a Mamba-2 mixer
 (:class:`~horovod_tpu.models.ssm.Mamba2Mixer`), ``*`` grouped-query
 attention (:class:`GroupedQueryAttention`), ``E`` a ``DroplessMoE``.
 :func:`NemotronHLM` is the Nemotron-H setting.  One tower, causal,
 trained under next-token cross-entropy: no denoising objective and no
-conditioning between towers.
+conditioning between towers.  ``L`` and ``F`` are TWO sub-layers, a
+mixer and then a dense SwiGLU MLP (:class:`SwiGLU`), each with the norm
+on its OUTPUT (OLMo 2's residual form: ``h = x + norm(mixer(x))``,
+``y = h + norm(mlp(h))``): ``L`` a Gated DeltaNet linear-attention mixer
+(:class:`~horovod_tpu.models.linear_attention.GatedDeltaNet`), ``F`` full
+multi-head attention (:class:`Attention`, with the model's ``qk_norm``,
+no positions).  :func:`OlmoHybridLM` is the Olmo-Hybrid setting.
 """
 
 from __future__ import annotations
@@ -210,21 +216,55 @@ class GroupedQueryAttention(nn.Module):
         return dense(C, "proj")(out.reshape(B, T, H * D))
 
 
+class SwiGLU(nn.Module):
+    """The dense gated MLP: ``W_down(silu(W_gate h) * W_up h)``, ``hidden``
+    wide, no bias.  Parameters ``gate``, ``up``, ``down``."""
+    hidden: int
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, h):
+        def dense(features, name):
+            return nn.Dense(features, use_bias=False, dtype=self.dtype,
+                            param_dtype=jnp.float32, name=name)
+
+        return dense(h.shape[-1], "down")(
+            nn.silu(dense(self.hidden, "gate")(h))
+            * dense(self.hidden, "up")(h))
+
+
 class PatternLayer(nn.Module):
-    """One layer of a pattern stack: ``x + f(norm(x))`` with ``f`` the one
-    sub-layer ``kind`` names — ``"M"`` (submodule ``ssm``), ``"*"``
-    (``attn``) or ``"E"`` (``moe``).  ``sub`` holds that sub-layer's
-    fields."""
+    """One layer of a pattern stack.  ``"M"`` (submodule ``ssm``), ``"*"``
+    (``attn``) and ``"E"`` (``moe``): ``x + f(norm(x))`` with ``f`` the one
+    sub-layer ``kind`` names.  ``"L"`` (``lin``) and ``"F"`` (``attn``):
+    ``h = x + mixer_norm(f(x))``, then ``h + mlp_norm(mlp(h))`` with
+    ``mlp`` a :class:`SwiGLU` ``mlp_hidden`` wide.  ``sub`` holds the
+    fields of ``f``."""
     kind: str
     sub: Any
     dtype: Any = jnp.bfloat16
     ln_dtype: Any = jnp.float32
     norm: str = "rms"
     norm_eps: float = 1e-5
+    mlp_hidden: int = 0
 
     @nn.compact
     def __call__(self, x):
-        h = _norm(self.norm, self.norm_eps, self.ln_dtype, "norm")(x)
+        def normed(y, name):
+            return _norm(self.norm, self.norm_eps, self.ln_dtype, name)(y)
+
+        if self.kind in ("L", "F"):
+            if self.kind == "L":
+                from horovod_tpu.models.linear_attention import GatedDeltaNet
+                y = GatedDeltaNet(**self.sub, norm_eps=self.norm_eps,
+                                  dtype=self.dtype, name="lin")(x)
+            else:
+                y = Attention(**self.sub, dtype=self.dtype,
+                              norm_eps=self.norm_eps, name="attn")(x)
+            h = x + normed(y, "mixer_norm")
+            return h + normed(SwiGLU(self.mlp_hidden, self.dtype,
+                                     name="mlp")(h), "mlp_norm")
+        h = normed(x, "norm")
         if self.kind == "M":
             from horovod_tpu.models.ssm import Mamba2Mixer
             y = Mamba2Mixer(**self.sub, norm_eps=self.norm_eps,
@@ -237,7 +277,7 @@ class PatternLayer(nn.Module):
                                   name="moe")(h)
         else:
             raise ValueError(f"unknown layer {self.kind!r} in a pattern: "
-                             "'M', '*' or 'E'")
+                             "'M', '*', 'E', 'L' or 'F'")
         return x + y
 
 
@@ -378,12 +418,17 @@ class TransformerLM(nn.Module):
     # "none" (the mixers carry the order).  ``ssm``: the fields of
     # Mamba2Mixer; ``*`` layers have num_heads query heads over kv_heads
     # KV heads of head_dim; ``moe``: DroplessMoE's further fields (router,
-    # renormalize, gate_scale, activation, shared_hidden, held).
+    # renormalize, gate_scale, activation, shared_hidden, held); ``lin``:
+    # the fields of GatedDeltaNet; ``F`` layers have num_heads heads of
+    # dim / num_heads with the model's qk_norm; ``L`` and ``F`` layers end
+    # in a SwiGLU mlp_hidden wide.
     pattern: Optional[str] = None
     ssm: Any = None
     kv_heads: Optional[int] = None
     head_dim: Optional[int] = None
     moe: Any = None
+    lin: Any = None
+    mlp_hidden: int = 0
 
     @nn.compact
     def __call__(self, tokens, return_hidden=False):
@@ -407,10 +452,12 @@ class TransformerLM(nn.Module):
             raise ValueError(f"unknown pos: {self.pos!r}")
         if self.pattern is not None:
             return self._pattern_stack(tokens, return_hidden)
-        if self.pos == "none" or self.moe or self.ssm:
-            raise ValueError("pos='none', ssm= and moe= belong to a "
-                             "pattern stack; the block stack takes learned "
-                             "or rotary positions and moe_experts")
+        if (self.pos == "none" or self.moe or self.ssm or self.lin
+                or self.mlp_hidden):
+            raise ValueError("pos='none', ssm=, moe=, lin= and mlp_hidden= "
+                             "belong to a pattern stack; the block stack "
+                             "takes learned or rotary positions and "
+                             "moe_experts")
         rotary = self.pos == "rotary"
         B, T = tokens.shape
         if self.attn in ("full", "flash"):
@@ -453,13 +500,18 @@ class TransformerLM(nn.Module):
                       head_dim=self.head_dim, attn=self.attn),
             "E": dict(num_experts=self.moe_experts, hidden=self.moe_hidden,
                       top_k=self.moe_top_k, **dict(self.moe or {})),
+            "L": dict(self.lin or {}),
+            "F": dict(num_heads=self.num_heads, attn=self.attn,
+                      qk_norm=self.qk_norm),
         }
         x = nn.Embed(self.vocab, self.dim, param_dtype=jnp.float32,
                      dtype=self.dtype, name="tok_emb")(tokens)
         for i, kind in enumerate(self.pattern):
             x = PatternLayer(kind, subs.get(kind), dtype=self.dtype,
                              ln_dtype=self.ln_dtype, norm=self.norm,
-                             norm_eps=self.norm_eps, name=f"layer_{i}")(x)
+                             norm_eps=self.norm_eps,
+                             mlp_hidden=self.mlp_hidden,
+                             name=f"layer_{i}")(x)
         x = _norm(self.norm, self.norm_eps, self.ln_dtype, "ln_f")(x)
         if return_hidden:
             return x
@@ -495,6 +547,30 @@ def NemotronHLM(**overrides) -> TransformerLM:
         moe_experts=128, moe_top_k=6, moe_hidden=1856,
         moe=dict(router="sigmoid", renormalize=True, gate_scale=2.5,
                  activation="relu2", shared_hidden=3712))
+    fields.update(overrides)
+    return TransformerLM(**fields)
+
+
+def OlmoHybridLM(**overrides) -> TransformerLM:
+    """The stack that ``allenai/Olmo-Hybrid-7B``'s config.json describes
+    (``model_type`` ``olmo_hybrid``), as a :class:`TransformerLM` with a
+    ``pattern``: 32 layers ``LLLF`` eight times at d 3840, RMSNorm eps
+    1e-6 on every sub-layer's output; ``L`` Gated DeltaNet mixers of 30
+    heads with keys 96 and values 192 wide, conv 4, beta in (0, 2); ``F``
+    attention of 30 heads of 128 with QK-norm over the whole q and k; a
+    SwiGLU MLP 11008 wide after each; vocab 100352, untied head.  What
+    config.json does not give is set by the family's convention: the
+    norms' place (OLMo 2's), chunks of 64 for the delta rule, no rotary
+    embedding (``rope_theta`` is null: the linear layers carry the
+    order).  ``overrides`` replace any field: a cut takes the first
+    letters of the pattern.  Trained like :func:`OLMoELM` through
+    ``make_train_step`` and ``fused_softmax_xent``."""
+    fields = dict(
+        vocab=100352, dim=3840, num_heads=30, max_len=65536, norm="rms",
+        norm_eps=1e-6, pos="none", qk_norm=True, pattern="LLLF" * 8,
+        lin=dict(num_heads=30, key_dim=96, value_dim=192, conv_kernel=4,
+                 chunk=64, allow_neg_eigval=True),
+        mlp_hidden=11008)
     fields.update(overrides)
     return TransformerLM(**fields)
 

@@ -82,6 +82,18 @@ def test_scan_reference_phase(smoke):
                                    groups=2, state=16, chunk=16, seed=0)
 
 
+def test_delta_reference_phase(smoke):
+    """The chunked delta rule against its recurrence, and the
+    ``delta_plan`` line: one form, plain XLA, at the chunk it was given."""
+    out = smoke.delta_reference_phase(batch=1, seq=40, heads=2, key_dim=16,
+                                      value_dim=32, chunk=16, seed=0)
+    assert out["delta_plan"] == {"form": "xla_chunked", "chunk": 16}
+    assert out["shape"] == [1, 40, 2, 16, 32]
+    assert {"out", "grad_q", "grad_k", "grad_v", "grad_g",
+            "grad_beta"} < set(out)
+    assert smoke.DELTA_REFERENCE["chunk"] == 64
+
+
 def test_transformer_phase(smoke, one_device_mesh):
     out = smoke.transformer_phase(one_device_mesh, NoCache(), **TINY_LM,
                                   batch=2, steps=2, scan_steps=2, seed=0,

@@ -515,3 +515,72 @@ def test_held_expert_layer_fwd_bwd_at_nemotron_widths(v5e):
     plan = (m.argument_size_in_bytes + m.output_size_in_bytes
             + m.temp_size_in_bytes - m.alias_size_in_bytes)
     assert plan < 4.5 * 2 ** 30, plan / 2 ** 30
+
+
+# ------------------------------------- the linear-attention hybrid's parts
+# (the olmohybrid_1chip cell: 1 sequence of 8,192, Olmo-Hybrid's widths)
+
+
+def test_flash_fwd_bwd_at_thirty_heads_of_olmo_hybrid(v5e, monkeypatch):
+    """30 heads of 128 — no power of two — at T 8192 through the split q,
+    k, v entry, as ``Attention`` with QK-norm calls it: a K/V row is 2 MB,
+    so the grid forward; 30 is even, so the pair grouped over two heads,
+    its diagonal blocks cut into 256-wide sub-tiles."""
+    from horovod_tpu.ops import flash_attention as fa
+
+    one = SingleDeviceSharding(v5e[0])
+    q = jax.ShapeDtypeStruct((1, 8192, 30, 128), jnp.bfloat16, sharding=one)
+    plans = []
+    plan = fa._plan
+    monkeypatch.setattr(
+        fa, "_plan", lambda **seen: plans.append(plan(**seen)) or plans[-1])
+
+    def loss(q, k, v):
+        return fa.flash_attention(q, k, v, causal=True).astype(
+            jnp.float32).sum()
+
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
+        q, q, q).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= 3
+    assert {(p.fwd, p.bwd, p.bwd_sub) for p in plans} == {
+        ("grid", "grouped", 256)}
+    _, grads = compiled.out_info
+    assert all(g.shape == (1, 8192, 30, 128) for g in grads)
+
+
+def test_chunked_delta_rule_fwd_bwd_at_olmo_hybrid_widths(v5e):
+    """``gated_delta_rule`` as the mixer calls it — 1 sequence of 8,192, 30
+    heads, keys 96 and values 192 wide, chunks of 64, under a
+    ``jax.checkpoint`` — compiles for the chip as plain XLA (no custom
+    call), the one sequential part a ``while`` of 128 steps each way.
+    Alone, with nothing else wanting the memory, it plans 2.94 GiB —
+    float32 (64, 64) tiles of 63 MB each, 360 MB of padded float32 states
+    entering the chunks — of the 5 the cell's step has for temporaries: a
+    fused kernel's second measure, beside ``delta_roofline``."""
+    from horovod_tpu.ops.gated_delta import gated_delta_rule
+
+    one = SingleDeviceSharding(v5e[0])
+
+    def s(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    b, t, h, dk, dv = 1, 8192, 30, 96, 192
+    args = (s((b, t, h, dk)), s((b, t, h, dk)), s((b, t, h, dv)),
+            s((b, t, h), jnp.float32), s((b, t, h), jnp.float32))
+
+    @jax.checkpoint
+    def loss(*a):
+        return gated_delta_rule(*a, chunk=64).astype(jnp.float32).sum()
+
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=range(5))).lower(
+        *args).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" not in text
+    assert text.count(" while(") == 3          # forward, replayed, backward
+    _, grads = compiled.out_info
+    assert [g.shape for g in grads] == [a.shape for a in args]
+    assert [g.dtype for g in grads] == [a.dtype for a in args]
+    m = compiled.memory_analysis()
+    plan = (m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes - m.alias_size_in_bytes)
+    assert plan < 3.25 * 2 ** 30, plan / 2 ** 30
