@@ -12,6 +12,10 @@ through the entry points a user calls (``hvd.init()`` →
 * checks the state-space scan's kernels, as the mixer calls them at
   Nemotron-H's widths, against the scan's XLA form — forward and the
   gradients of all four operands — and prints the scan's plan;
+* checks the mixer's convolution and gated norm as kernels, at the same
+  widths and read out of the projection's one array as the mixer reads
+  them, against their XLA forms — forward and every gradient — and
+  prints their plan;
 * takes optimizer steps with the d=2048/T=2048 TransformerLM (one step
   per call, then four scanned steps per call) and with ResNet-50 at
   batch 128, parameters from each model's own ``init`` under ``--seed``,
@@ -54,7 +58,11 @@ TRANSFORMER = dict(vocab=32768, dim=2048, depth=12, heads=16, seq=2048)
 FLASH_REFERENCE = dict(batch=8, seq=2048, heads=16, head_dim=128)
 SCAN_REFERENCE = dict(batch=2, seq=2048, heads=64, head_dim=64, groups=8,
                       state=128, chunk=128)
-DELTA_REFERENCE = dict(batch=1, seq=2048, heads=30, key_dim=96,
+PASSES_REFERENCE = dict(batch=2, seq=2048, heads=64, head_dim=64, groups=8,
+                        state=128, conv_kernel=4)
+# A sequence of 512: the float32 recurrence's backward keeps three (192, 96)
+# states a head a token, 4.3 GB there and 17.2 of the chip's 15.75 at 2048.
+DELTA_REFERENCE = dict(batch=1, seq=512, heads=30, key_dim=96,
                        value_dim=192, chunk=64)
 ONE_CHIP_LM = dict(**TRANSFORMER, batch=8, steps=3, scan_steps=4)
 ONE_CHIP_RESNET = dict(stage_sizes=(3, 4, 6, 3), num_filters=64,
@@ -71,6 +79,13 @@ FLASH_GRAD_TOL = 4e-2
 # tiles; the backward's sums run in another order.
 SCAN_FWD_TOL = 1e-2
 SCAN_GRAD_TOL = 4e-2
+# The mixer's passes as kernels against their XLA forms on the same
+# bfloat16 operands taken up to float32 — what the kernels hold inside
+# (``causal_conv`` in bfloat16 rounds every multiply-add: 2.8e-3 / 3.9e-3
+# of a norm from the kernels at the cell's shape, PERF.md section 6,
+# PR 33, and the further side from the definition).  What is left is the
+# one rounding of a bfloat16 result, 2^-9 of a value.
+PASSES_TOL = 1e-2
 # The chunked delta rule in bfloat16 against its recurrence in float32.
 DELTA_TOL = 4e-2
 LOSS_TOL = 2e-2          # 4-device vs 1-device loss, same step
@@ -323,6 +338,106 @@ def scan_reference_phase(*, batch: int, seq: int, heads: int, head_dim: int,
               f"(bound {SCAN_GRAD_TOL})")
     return {"shape": [b, T, H, P_, G, N], "interpret": interpret,
             "ssd_plan": plan, **{k: round(e, 5) for k, e in errs.items()}}
+
+
+def passes_plan(seq: int, heads: int, head_dim: int, groups: int, state: int,
+                conv_kernel: int) -> dict:
+    """What ``mixer_passes._plan`` decides on this device for the mixer's
+    convolution and gated norm (bfloat16): kernels or the XLA forms, the
+    rows a block and a strip, the channels a block of each pass."""
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.ops import mixer_passes
+
+    inner = heads * head_dim
+    return mixer_passes.passes_plan(
+        jax.ShapeDtypeStruct((1, seq, 1), jnp.bfloat16), inner=inner,
+        conv_dim=inner + 2 * groups * state, groups=groups,
+        kernel=conv_kernel,
+        interpret=jax.default_backend() != "tpu")._asdict()
+
+
+def passes_reference_phase(*, batch: int, seq: int, heads: int,
+                           head_dim: int, groups: int, state: int,
+                           conv_kernel: int, seed: int) -> dict:
+    """The mixer's two passes as it calls them (``conv_silu`` under a
+    ``jax.checkpoint`` and ``gated_norm``, ``xBC`` and ``z`` read out of
+    [z | xBC | dt] padded to whole tiles) against the XLA forms the mixer
+    keeps (``causal_conv`` with its activation, ``gated_group_norm``) on
+    the split arrays in float32: values and the gradients of every
+    operand."""
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.models.ssm import causal_conv, gated_group_norm
+    from horovod_tpu.ops import mixer_passes
+
+    interpret = jax.default_backend() != "tpu"
+    inner = heads * head_dim
+    conv_dim = inner + 2 * groups * state
+    width = inner + conv_dim + heads
+    eps = 1e-5
+    ks = jax.random.split(jax.random.PRNGKey(seed), 7)
+    packed = jax.random.normal(
+        ks[0], (batch, seq, width + -width % 128), jnp.bfloat16)
+    w = jax.random.uniform(ks[1], (conv_kernel, conv_dim), minval=-0.5,
+                           maxval=0.5)
+    bias = jax.random.uniform(ks[2], (conv_dim,), minval=-0.5, maxval=0.5)
+    y = jax.random.normal(ks[3], (batch, seq, inner), jnp.bfloat16)
+    scale = 1.0 + 0.2 * jax.random.normal(ks[4], (inner,))
+    d_conv = jax.random.normal(ks[5], (batch, seq, conv_dim), jnp.bfloat16)
+    d_gate = jax.random.normal(ks[6], (batch, seq, inner), jnp.bfloat16)
+    plan_dict = passes_plan(seq, heads, head_dim, groups, state, conv_kernel)
+    check(plan_dict["form"] == "kernels",
+          f"the passes' plan at the mixer's shape is {plan_dict}")
+    plan = mixer_passes.PassPlan(**plan_dict)
+
+    def kernels(packed, w, bias, y, scale):
+        conv = jax.checkpoint(lambda p, w, b: mixer_passes.conv_silu(
+            p, w, b, first=inner, plan=plan, interpret=interpret))
+        return conv(packed, w, bias), mixer_passes.gated_norm(
+            y, packed, scale, groups=groups, eps=eps, plan=plan,
+            interpret=interpret)
+
+    def xla_forms(packed, w, bias, y, scale):
+        packed, y = packed.astype(jnp.float32), y.astype(jnp.float32)
+        z, xBC = packed[..., :inner], packed[..., inner:inner + conv_dim]
+        return (jax.nn.silu(causal_conv(xBC, w, bias)),
+                gated_group_norm(y, z, scale, groups=groups, eps=eps))
+
+    def value_out_grads(fn):
+        def weighted(*a):
+            conv, gate = fn(*a)
+            return ((conv.astype(jnp.float32)
+                     * d_conv.astype(jnp.float32)).sum()
+                    + (gate.astype(jnp.float32)
+                       * d_gate.astype(jnp.float32)).sum()), (conv, gate)
+        return jax.jit(jax.value_and_grad(weighted, argnums=(0, 1, 2, 3, 4),
+                                          has_aux=True))
+
+    args = (packed, w, bias, y, scale)
+    got_fn = value_out_grads(kernels)
+    if not interpret:
+        names = kernels_in(got_fn.lower(*args).as_text())
+        check(names == ["ssm_conv_bwd", "ssm_conv_fwd", "ssm_gate_bwd",
+                        "ssm_gate_fwd"],
+              f"the passes lowered to the kernels {names}")
+    (_, got_out), got_grads = got_fn(*args)
+    (_, want_out), want_grads = value_out_grads(xla_forms)(*args)
+    named = dict(zip(("conv", "gate"), zip(got_out, want_out)))
+    named.update(zip(("grad_packed", "grad_w", "grad_b", "grad_y",
+                      "grad_scale"), zip(got_grads, want_grads)))
+    errs = {}
+    for name, (g, r) in named.items():
+        errs[name] = _rel_err(g, r)
+        check(errs[name] <= PASSES_TOL,
+              f"the passes' kernels differ from their XLA forms in {name} "
+              f"by {errs[name]:.3g} of its largest value (bound "
+              f"{PASSES_TOL})")
+    return {"shape": [batch, seq, inner, conv_dim, groups],
+            "interpret": interpret, "passes_plan": plan_dict,
+            **{k: round(e, 5) for k, e in errs.items()}}
 
 
 def delta_reference_phase(*, batch: int, seq: int, heads: int, key_dim: int,
@@ -859,6 +974,8 @@ def main(argv=None) -> int:
             **FLASH_REFERENCE, seed=args.seed))
         emit("scan_reference", **scan_reference_phase(
             **SCAN_REFERENCE, seed=args.seed))
+        emit("passes_reference", **passes_reference_phase(
+            **PASSES_REFERENCE, seed=args.seed))
         emit("delta_reference", **delta_reference_phase(
             **DELTA_REFERENCE, seed=args.seed))
         emit("transformer_lm", **transformer_phase(

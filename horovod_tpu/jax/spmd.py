@@ -541,7 +541,9 @@ def _moe_metrics(fn, expert_layers: dict, steps_per_call: int):
     (of the assignments, what uniform routing sends to the experts a
     share of a layer holds), ``ssm.scan_chunks`` and ``ssm.state_bytes``
     (chunks a state-space mixer scans, bytes of float32 state passed
-    between them), ``lin.delta_chunks`` and ``lin.state_bytes`` (the same
+    between them), ``ssm.fused_scans`` and ``ssm.fused_passes`` (its scans
+    and its elementwise passes that took their Pallas kernels),
+    ``lin.delta_chunks`` and ``lin.state_bytes`` (the same
     of a linear-attention mixer's delta rule) counters.  ``expert_layers``
     is what the ``DroplessMoE``, ``Mamba2Mixer`` and ``GatedDeltaNet``
     layers of the step's ``loss_fn`` noted of their static sizes while it

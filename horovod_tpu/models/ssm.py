@@ -14,16 +14,25 @@ stack (:class:`~horovod_tpu.models.transformer.TransformerLM` with a
 The recurrence is :func:`horovod_tpu.ops.ssd.ssd_scan_packed` (chunks of
 ``chunk`` tokens, float32 states passed between them; it reads x, B, C
 out of the convolution's one array, and runs as Pallas kernels where the
-shapes tile, as XLA otherwise: ``ops/ssd.py`` chooses).  In a trace the
-module's scopes are ``in_proj``, ``conv``, ``scan``, ``gate_norm`` and
-``out_proj``; ``make_train_step`` counts ``ssm.scan_chunks``,
-``ssm.state_bytes`` (the float32 states passed between chunks, in VMEM
-where the kernels run) and ``ssm.fused_scans`` (scans that took the
-kernels) from what the module notes of its shapes while traced.
+shapes tile, as XLA otherwise: ``ops/ssd.py`` chooses).  The convolution
+with its activation and the gated norm are one pass each over their
+activation: Pallas kernels that read ``xBC`` and ``z`` as column ranges of
+the input projection's one array where rows and channels tile
+(:mod:`horovod_tpu.ops.mixer_passes` chooses; float32 inside, one
+rounding at the store), as XLA on the split arrays otherwise
+(:func:`causal_conv`, in the activations' dtype, and the gate in
+float32).  In a trace the module's scopes are ``in_proj``, ``conv``,
+``scan``, ``gate_norm`` and ``out_proj``; ``make_train_step`` counts
+``ssm.scan_chunks``, ``ssm.state_bytes`` (the float32 states passed
+between chunks, in VMEM where the kernels run), ``ssm.fused_scans``
+(scans that took the kernels) and ``ssm.fused_passes`` (of the two
+passes, those that took theirs) from what the module notes of its shapes
+while traced.
 """
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Any
 
@@ -31,6 +40,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from horovod_tpu.ops.mixer_passes import conv_silu, gated_norm, passes_plan
 from horovod_tpu.ops.ssd import scan_plan, scan_sizes, ssd_scan_packed
 from horovod_tpu.parallel.moe import note_layer
 
@@ -87,6 +97,68 @@ def causal_conv(x, w, b=None):
     return y
 
 
+def gated_group_norm(y, z, scale, *, groups: int, eps: float):
+    """``rmsnorm_group(y * silu(z)) * scale`` on (..., inner) arrays, a
+    group ``inner / groups`` channels: the gate, the mean square and the
+    scale in float32, the result in ``y.dtype``."""
+    inner = y.shape[-1]
+    g = y.astype(jnp.float32) * nn.silu(z.astype(jnp.float32))
+    g = g.reshape(*y.shape[:-1], groups, inner // groups)
+    g = g * jax.lax.rsqrt(jnp.mean(g * g, axis=-1, keepdims=True) + eps)
+    return (g.reshape(y.shape) * scale).astype(y.dtype)
+
+
+def _padded_product(x, kernel, pad, dtype):
+    x, kernel = nn.dtypes.promote_dtype(x, kernel, dtype=dtype)
+    kernel = jnp.pad(kernel, ((0, 0), (0, pad)))
+    return jax.lax.dot_general(x, kernel, (((x.ndim - 1,), (0,)), ((), ())))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def padded_product(x, kernel, pad, dtype):
+    """``x @ kernel`` in ``dtype`` with ``pad`` zero columns after the
+    kernel's own, and autodiff's gradients.  The backward pass casts and
+    pads the kernel again, behind a barrier that keeps the compiler from
+    sharing the forward's copy: a cast alone fuses into both products,
+    but the padded bfloat16 copy would be kept from the forward to the
+    backward pass, 55 MB a mixer at the cell's widths."""
+    return _padded_product(x, kernel, pad, dtype)
+
+
+def _padded_product_fwd(x, kernel, pad, dtype):
+    return _padded_product(x, kernel, pad, dtype), (x, kernel)
+
+
+def _padded_product_bwd(pad, dtype, res, dout):
+    x, kernel = res
+    kernel, dout = jax.lax.optimization_barrier((kernel, dout))
+    return jax.vjp(lambda x, k: _padded_product(x, k, pad, dtype), x,
+                   kernel)[1](dout)
+
+
+padded_product.defvjp(_padded_product_fwd, _padded_product_bwd)
+
+
+class PaddedDense(nn.Module):
+    """``nn.Dense`` without a bias whose output is ``pad`` zero columns
+    wider than its ``features``: the kernel (in, features) is the
+    parameter, padded as it is cast.  Why: the TPU compiler lays a
+    product's output out with its *rows* minor where the columns do not
+    fill whole 128-lane tiles and no XLA op reads it (2 x 8,192 x 10,304
+    in the cell: 0.8 ms a copy, three a layer, before a kernel could read
+    it)."""
+    features: int
+    pad: int
+    dtype: Any = jnp.bfloat16
+    param_dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        kernel = self.param("kernel", nn.initializers.lecun_normal(),
+                            (x.shape[-1], self.features), self.param_dtype)
+        return padded_product(x, kernel, self.pad, self.dtype)
+
+
 class Mamba2Mixer(nn.Module):
     """Module docstring.  ``num_heads`` heads of ``head_dim`` channels,
     ``n_groups`` groups of B and C with ``state_size`` columns each.
@@ -121,8 +193,42 @@ class Mamba2Mixer(nn.Module):
             return nn.Dense(features, use_bias=False, dtype=self.dtype,
                             param_dtype=self.param_dtype, name=name)
 
-        zxbcdt = dense(2 * inner + 2 * gn + H, "in_proj")(u)
-        z, xBC, dt = jnp.split(zxbcdt, [inner, 2 * inner + 2 * gn], axis=-1)
+        interpret = jax.default_backend() != "tpu"
+        passes = passes_plan(u, inner=inner, conv_dim=inner + 2 * gn,
+                             groups=G, kernel=self.conv_kernel,
+                             interpret=interpret)
+        fused_passes = passes.form == "kernels"
+        width = 2 * inner + 2 * gn + H
+        if fused_passes:
+            # The kernels read xBC and z out of the projection's one array,
+            # and keep their inputs alone for the backward pass.
+            zxbcdt = PaddedDense(width, -width % 128, self.dtype,
+                                 self.param_dtype, name="in_proj")(u)
+            z = xBC = zxbcdt
+            dt = zxbcdt[..., width - H:width]
+
+            def conv(xBC, w, b):
+                return conv_silu(xBC, w, b, first=inner, plan=passes,
+                                 interpret=interpret)
+
+            def gate(y, z, scale):
+                with jax.named_scope("gate_norm"):
+                    return gated_norm(y, z, scale, groups=G,
+                                      eps=self.norm_eps, plan=passes,
+                                      interpret=interpret)
+        else:
+            zxbcdt = dense(width, "in_proj")(u)
+            z, xBC, dt = jnp.split(zxbcdt, [inner, width - H], axis=-1)
+
+            def conv(xBC, w, b):
+                return nn.silu(causal_conv(xBC, w, b))
+
+            @jax.checkpoint
+            def gate(y, z, scale):
+                with jax.named_scope("gate_norm"):
+                    return gated_group_norm(y, z, scale, groups=G,
+                                            eps=self.norm_eps)
+
         conv_w, conv_b = CausalConv(self.conv_kernel, self.param_dtype,
                                     name="conv")(inner + 2 * gn)
         dt_bias = self.param("dt_bias", dt_bias_init(
@@ -132,12 +238,10 @@ class Mamba2Mixer(nn.Module):
         scale = self.param("gate_norm", nn.initializers.ones, (inner,),
                            self.param_dtype)
 
-        interpret = jax.default_backend() != "tpu"
-
         @jax.checkpoint
         def conv_and_scan(xBC, dt, conv_w, conv_b, dt_bias, A_log, D):
             with jax.named_scope("conv"):
-                xBC = nn.silu(causal_conv(xBC, conv_w, conv_b))
+                xBC = conv(xBC, conv_w, conv_b)
             with jax.named_scope("scan"):
                 dt = jax.nn.softplus(dt.astype(jnp.float32) + dt_bias)
                 return ssd_scan_packed(
@@ -145,22 +249,14 @@ class Mamba2Mixer(nn.Module):
                     heads=H, groups=G, state=N, chunk=self.chunk,
                     interpret=interpret)
 
-        @jax.checkpoint
-        def gate_norm(y, z, scale):
-            with jax.named_scope("gate_norm"):
-                y = y.astype(jnp.float32) * nn.silu(z.astype(jnp.float32))
-                y = y.reshape(Bsz, T, G, inner // G)
-                y = y * jax.lax.rsqrt(
-                    jnp.mean(y * y, axis=-1, keepdims=True) + self.norm_eps)
-                return (y.reshape(Bsz, T, inner) * scale).astype(self.dtype)
-
-        y = gate_norm(conv_and_scan(xBC, dt, conv_w, conv_b, dt_bias, A_log,
-                                    D), z, scale)
+        y = gate(conv_and_scan(xBC, dt, conv_w, conv_b, dt_bias, A_log, D),
+                 z, scale)
         sizes = scan_sizes(Bsz, T, H, P, N, self.chunk)
         fused = scan_plan(xBC, dt, heads=H, head_dim=P, groups=G, state=N,
                           chunk=self.chunk, interpret=interpret
                           ).form == "kernels"
         note_layer(self.path, {"ssm.scan_chunks": sizes["chunks"],
                                "ssm.state_bytes": sizes["state_bytes"],
-                               "ssm.fused_scans": int(fused)})
+                               "ssm.fused_scans": int(fused),
+                               "ssm.fused_passes": 2 * int(fused_passes)})
         return dense(d, "out_proj")(y)

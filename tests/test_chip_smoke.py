@@ -82,6 +82,28 @@ def test_scan_reference_phase(smoke):
                                    groups=2, state=16, chunk=16, seed=0)
 
 
+def test_passes_reference_phase(smoke):
+    """The mixer's convolution and gated norm as kernels against their XLA
+    forms at a shape that tiles, and the ``passes_plan`` line: the cell's
+    shape takes the kernels, on any device that runs them."""
+    out = smoke.passes_reference_phase(batch=2, seq=64, heads=4, head_dim=64,
+                                       groups=2, state=64, conv_kernel=4,
+                                       seed=0)
+    assert out["interpret"]
+    assert out["passes_plan"] == {"form": "kernels", "rows": 64, "strip": 32,
+                                  "conv_cols": 256, "gate_cols": 256}
+    assert {"conv", "gate", "grad_packed", "grad_w", "grad_b", "grad_y",
+            "grad_scale"} < set(out)
+    assert smoke.passes_plan(8192, 64, 64, 8, 128, 4) == {
+        "form": "kernels", "rows": 1024, "strip": 32, "conv_cols": 512,
+        "gate_cols": 512}
+    assert smoke.passes_plan(8200, 64, 64, 8, 128, 4)["form"] == "xla"
+    with pytest.raises(RuntimeError, match="plan at the mixer's shape"):
+        smoke.passes_reference_phase(batch=1, seq=32, heads=4, head_dim=16,
+                                     groups=2, state=16, conv_kernel=4,
+                                     seed=0)
+
+
 def test_delta_reference_phase(smoke):
     """The chunked delta rule against its recurrence, and the
     ``delta_plan`` line: one form, plain XLA, at the chunk it was given."""

@@ -118,7 +118,7 @@ HEAD_KERNEL_EQUATIONS = {"_dkdv_kernel_grouped": 176,
                          "_dq_kernel_grouped": 149}
 
 
-@pytest.mark.parametrize("family", ["flash", "scan"])
+@pytest.mark.parametrize("family", ["flash", "scan", "passes"])
 def test_each_kernel_is_traced_once_a_step_not_once_a_layer(monkeypatch,
                                                             family):
     """The set-up guard, no chip and no compile: tracing ``jax.grad`` of a
@@ -142,13 +142,20 @@ def test_each_kernel_is_traced_once_a_step_not_once_a_layer(monkeypatch,
     traced once, the forward body twice — once as the forward that runs,
     once as the checkpoint's replay (a rule traced while the checkpoint's
     jaxpr is evaluated sees another trace context than the step's own, so
-    the two do not share; 0.2 s, and the replay leaves no kernel)."""
+    the two do not share; 0.2 s, and the replay leaves no kernel).
+
+    ``passes``: the same four mixers' convolution and gated norm
+    (``ops/mixer_passes.py``).  The drivers ``_conv_fwd`` / ``_conv_bwd``
+    / ``_gate_fwd`` / ``_gate_bwd`` are shared likewise: each backward
+    body and the gate's forward are traced once, the convolution's forward
+    twice (the checkpoint's replay again) — and there the replay stays a
+    kernel, eight in all, because the scan's backward reads its output."""
     import collections
     import functools
 
     from horovod_tpu.models import NemotronHLM, TransformerLM
     from horovod_tpu.ops import flash_attention as fa
-    from horovod_tpu.ops import ssd
+    from horovod_tpu.ops import mixer_passes, ssd
 
     calls = collections.Counter()
 
@@ -163,11 +170,19 @@ def test_each_kernel_is_traced_once_a_step_not_once_a_layer(monkeypatch,
                  "_fwd_kernel_fullunroll", "_dq_kernel", "_dkdv_kernel",
                  "_dq_kernel_grouped", "_dkdv_kernel_grouped"):
         monkeypatch.setattr(fa, name, counted(name, getattr(fa, name)))
-    for name in ("_fwd_kernel", "_states_kernel", "_bwd_kernel"):
-        monkeypatch.setattr(ssd, name,
-                            counted("ssd." + name, getattr(ssd, name)))
+    if family == "scan":
+        for name in ("_fwd_kernel", "_states_kernel", "_bwd_kernel"):
+            monkeypatch.setattr(ssd, name,
+                                counted("ssd." + name, getattr(ssd, name)))
+    if family == "passes":
+        for name in ("_conv_fwd_kernel", "_conv_bwd_kernel",
+                     "_gate_fwd_kernel", "_gate_bwd_kernel"):
+            monkeypatch.setattr(mixer_passes, name,
+                                counted(name, getattr(mixer_passes, name)))
 
-    batch = 3      # no other test's: a trace made earlier would be shared
+    # No other test's, nor another case's: a trace made earlier would be
+    # shared.
+    batch = {"flash": 3, "scan": 3, "passes": 5}[family]
     if family == "flash":
         seq = T
         model = TransformerLM(vocab=512, dim=H * D, depth=3, num_heads=H,
@@ -178,8 +193,11 @@ def test_each_kernel_is_traced_once_a_step_not_once_a_layer(monkeypatch,
         seq = 256
         model = NemotronHLM(vocab=512, dim=256, pattern="MMMM", max_len=seq,
                             dtype=jnp.bfloat16)
-        want = {"ssd._fwd_kernel": 2, "ssd._states_kernel": 1,
-                "ssd._bwd_kernel": 1}
+        want = {"scan": {"ssd._fwd_kernel": 2, "ssd._states_kernel": 1,
+                         "ssd._bwd_kernel": 1},
+                "passes": {"_conv_fwd_kernel": 2, "_conv_bwd_kernel": 1,
+                           "_gate_fwd_kernel": 1, "_gate_bwd_kernel": 1}
+                }[family]
     params = jax.eval_shape(
         lambda key: model.init(key, jnp.zeros((1, seq), jnp.int32))["params"],
         jax.random.PRNGKey(0))
@@ -212,11 +230,15 @@ def test_each_kernel_is_traced_once_a_step_not_once_a_layer(monkeypatch,
 
     found = list(kernels(jaxpr.jaxpr))
     sizes = dict(found)
-    if family == "scan":
-        # Four mixers: the forward, and in the backward the states pass
-        # and the sweep; the replayed forwards are gone with their ``y``.
+    if family != "flash":
+        # Four mixers: the scan's forward, and in the backward its states
+        # pass and its sweep, the replayed forwards gone with their ``y``;
+        # the convolution twice forward (the replay feeds the scan's
+        # backward) and once backward, the gate once each way.
         names = collections.Counter(name for name, _ in found)
-        assert names == {"ssd_fwd": 4, "ssd_states": 4, "ssd_bwd": 4}
+        assert names == {"ssd_fwd": 4, "ssd_states": 4, "ssd_bwd": 4,
+                         "ssm_conv_fwd": 8, "ssm_conv_bwd": 4,
+                         "ssm_gate_fwd": 4, "ssm_gate_bwd": 4}
         return
     assert set(HEAD_KERNEL_EQUATIONS) < set(sizes)
     for name, at_head in HEAD_KERNEL_EQUATIONS.items():
@@ -476,6 +498,109 @@ def test_chunked_scan_compiles_wherever_the_plan_takes_the_kernels(
     text = compile_text(jax.value_and_grad(loss, argnums=(0, 1, 2, 3)),
                         *args)
     assert text.count('custom_call_target="tpu_custom_call"') == 3
+
+
+def passes_value_and_grads(args, *, inner, groups, plan):
+    """The two passes as the mixer holds them — the convolution under a
+    ``jax.checkpoint``, the gate on its own — compiled."""
+    from horovod_tpu.ops import mixer_passes
+
+    @jax.checkpoint
+    def conv(packed, w, b):
+        return mixer_passes.conv_silu(packed, w, b, first=inner, plan=plan)
+
+    def loss(packed, w, b, y, scale):
+        gated = mixer_passes.gated_norm(y, packed, scale, groups=groups,
+                                        eps=1e-5, plan=plan)
+        return (conv(packed, w, b).astype(jnp.float32).sum()
+                + gated.astype(jnp.float32).sum())
+
+    return jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        *args).compile()
+
+
+def passes_shapes(one, b, t, inner, bc, heads, dtype, taps=4):
+    conv_dim = inner + bc
+    width = inner + conv_dim + heads
+
+    def s(shape, kind):
+        return jax.ShapeDtypeStruct(shape, kind, sharding=one)
+
+    return (s((b, t, width + -width % 128), dtype),
+            s((taps, conv_dim), "float32"), s((conv_dim,), "float32"),
+            s((b, t, inner), dtype), s((inner,), "float32"))
+
+
+def test_mixer_passes_fwd_bwd_at_nemotron_widths(v5e):
+    """The mixer's convolution and gated norm at the cell's shape — 2
+    sequences of 8,192, 4,096 channels in 8 norm groups, 6,144 convolved
+    by 4 taps, both read out of the projection's [z | xBC | dt] padded to
+    10,368 columns — with the kernels asked for compiled.  Four kernels by
+    name (no scan reads the checkpoint's replay here, so it leaves none;
+    the backward kernels recompute from the inputs); nothing copies or
+    transposes a
+    (2, 8192, ...) bfloat16 array around them — the cotangents reach the
+    packed array's columns through two ``pad``s that XLA sums as it writes
+    them —; the parameters' gradients are float32."""
+    import re
+
+    from horovod_tpu.ops import mixer_passes
+
+    one = SingleDeviceSharding(v5e[0])
+    args = passes_shapes(one, 2, 8192, 4096, 2048, 64, "bfloat16")
+    assert args[0].shape == (2, 8192, 10368)
+    plan = mixer_passes.passes_plan(args[0], inner=4096, conv_dim=6144,
+                                    groups=8, kernel=4, interpret=False)
+    assert plan == mixer_passes.PassPlan("kernels", 1024, 32, 512, 512)
+    compiled = passes_value_and_grads(args, inner=4096, groups=8, plan=plan)
+    text = compiled.as_text()
+    kernels = [line.split(" = ")[0] for line in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(kernels) == 4, kernels
+    for name in ("ssm_conv_fwd", "ssm_conv_bwd", "ssm_gate_fwd",
+                 "ssm_gate_bwd"):
+        assert sum(name in k for k in kernels) == 1, (name, kernels)
+    moved = [line for line in text.splitlines()
+             if re.search(r"= bf16\[2,8192,\d+\]\S* (copy|transpose)\(", line)]
+    assert not moved, moved
+    _, (dpacked, dw, db, dy, dscale) = compiled.out_info
+    assert (dpacked.shape, dpacked.dtype) == (args[0].shape, jnp.bfloat16)
+    assert (dy.shape, dy.dtype) == (args[3].shape, jnp.bfloat16)
+    assert dw.dtype == db.dtype == dscale.dtype == jnp.float32
+
+
+# (b, T, inner, B | C columns, heads, norm groups, taps, dtype): what else
+# ``mixer_passes._plan`` hands to the kernels, one case a way of tiling —
+# float32 activations at the cell's shape (blocks of 512 rows), four norm
+# groups of 128 to a block, an odd count of groups of 256, channels that
+# only tile by 128, a sequence shorter than a block, one that ends inside
+# a block, two taps.
+@pytest.mark.parametrize("b,t,inner,bc,heads,groups,taps,dtype", [
+    (2, 8192, 4096, 2048, 64, 8, 4, "float32"),
+    (1, 2048, 4096, 2048, 64, 32, 4, "bfloat16"),
+    (1, 2048, 768, 256, 12, 3, 4, "bfloat16"),
+    (1, 2048, 384, 256, 6, 3, 4, "bfloat16"),
+    (2, 64, 256, 128, 4, 2, 4, "bfloat16"),
+    (2, 1056, 1024, 256, 16, 2, 4, "bfloat16"),
+    (1, 2048, 1024, 256, 16, 2, 2, "bfloat16")],
+    ids=["cell_float32", "groups_of_128", "three_groups_of_256",
+         "channels_in_tiles_of_128", "shorter_than_a_block",
+         "ends_inside_a_block", "two_taps"])
+def test_mixer_passes_compile_wherever_the_plan_takes_the_kernels(
+        v5e, b, t, inner, bc, heads, groups, taps, dtype):
+    """A shape ``_plan`` gives the kernels has to compile: interpret mode
+    refuses nothing of what Mosaic refuses."""
+    from horovod_tpu.ops import mixer_passes
+
+    one = SingleDeviceSharding(v5e[0])
+    args = passes_shapes(one, b, t, inner, bc, heads, dtype, taps)
+    plan = mixer_passes.passes_plan(args[0], inner=inner,
+                                    conv_dim=inner + bc, groups=groups,
+                                    kernel=taps, interpret=False)
+    assert plan.form == "kernels", plan
+    text = passes_value_and_grads(args, inner=inner, groups=groups,
+                                  plan=plan).as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 4
 
 
 def test_held_expert_layer_fwd_bwd_at_nemotron_widths(v5e):
