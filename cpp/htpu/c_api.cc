@@ -230,6 +230,15 @@ HTPU_API void htpu_timeline_activity_end(void* tl, const char* name) {
   static_cast<htpu::Timeline*>(tl)->ActivityEnd(name);
 }
 
+// A whole activity the caller timed itself (the span ring's
+// step/dispatch): it lasted dur_us and ended ended_ago_us before now.
+HTPU_API void htpu_timeline_activity_span(void* tl, const char* name,
+                                 const char* activity, long long dur_us,
+                                 long long ended_ago_us) {
+  static_cast<htpu::Timeline*>(tl)->ActivitySpan(name, activity, dur_us,
+                                                 ended_ago_us);
+}
+
 // Chrome-trace counter track sample ("ph": "C") — queue depth, bytes in
 // flight — plotted by Perfetto as rate graphs alongside the spans.
 HTPU_API void htpu_timeline_counter(void* tl, const char* name,

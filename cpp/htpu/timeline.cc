@@ -159,6 +159,18 @@ void Timeline::ActivityEnd(const std::string& tensor_name) {
   NegotiateEnd(tensor_name);
 }
 
+void Timeline::ActivitySpan(const std::string& tensor_name,
+                            const std::string& activity, int64_t dur_us,
+                            int64_t ended_ago_us) {
+  if (dur_us < 0) dur_us = 0;
+  std::ostringstream os;
+  os << "{\"ph\": \"X\", \"pid\": " << Pid(tensor_name)
+     << ", \"ts\": " << TsUs() - ended_ago_us - dur_us
+     << ", \"dur\": " << dur_us << ", \"name\": \""
+     << JsonEscape(activity) << "\"}";
+  Emit(os.str());
+}
+
 void Timeline::CacheHitTick(int64_t dur_us) {
   std::ostringstream os;
   os << "{\"ph\": \"X\", \"pid\": 0, \"ts\": " << TsUs() - dur_us

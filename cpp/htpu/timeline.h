@@ -38,6 +38,11 @@ class Timeline {
   void ActivityStart(const std::string& tensor_name,
                      const std::string& activity);
   void ActivityEnd(const std::string& tensor_name);
+  // Complete-event span ("ph": "X") on the tensor's lane for an activity
+  // the caller timed itself: `dur_us` long, ended `ended_ago_us` ago.
+  void ActivitySpan(const std::string& tensor_name,
+                    const std::string& activity, int64_t dur_us,
+                    int64_t ended_ago_us);
   // Chrome-trace counter track ("ph": "C") — plotted by Perfetto as a
   // rate graph alongside the spans (queue depth, bytes in flight).
   void Counter(const std::string& name, int64_t value);

@@ -18,6 +18,7 @@ import threading
 from typing import Any, NamedTuple, Optional, Sequence
 
 from horovod_tpu import topology as _topology_mod
+from horovod_tpu.timeline import ring
 
 
 class NotInitializedError(RuntimeError):
@@ -78,19 +79,30 @@ def init(ranks: Optional[Sequence[int]] = None) -> None:
     with _state.lock:
         if _state.initialized:
             return
+        # In the span ring: ``init`` over ``init/topology``, ``mesh``,
+        # ``init/controller`` and, below the controller or whoever asks
+        # for it first, ``init/native_core`` (``cpp_core.load``).
+        with ring.span("init"):
+            _init_locked(ranks)
+
+
+def _init_locked(ranks) -> None:
+    with ring.span("init/topology"):
         _state.topology = _topology_mod.resolve(ranks)
-        # Multi-controller pod without a TCP control plane: the in-jit SPMD
-        # path (make_train_step, injit ops, the global mesh) needs no
-        # negotiation at all — XLA's runtime carries the collectives — so
-        # init() succeeds and only the *eager* (negotiated) API is gated:
-        # its first call fails fast with a clear error instead of the
-        # silent 60 s stall-deadlock it would otherwise hit (each process
-        # would submit only its local ranks' requests while size() spans
-        # the whole pod).  The reference initializes unconditionally under
-        # its launcher (``operations.cc:1435-1532``); the control plane is
-        # likewise never optional-but-blocking here.
+    # Multi-controller pod without a TCP control plane: the in-jit SPMD
+    # path (make_train_step, injit ops, the global mesh) needs no
+    # negotiation at all — XLA's runtime carries the collectives — so
+    # init() succeeds and only the *eager* (negotiated) API is gated:
+    # its first call fails fast with a clear error instead of the
+    # silent 60 s stall-deadlock it would otherwise hit (each process
+    # would submit only its local ranks' requests while size() spans
+    # the whole pod).  The reference initializes unconditionally under
+    # its launcher (``operations.cc:1435-1532``); the control plane is
+    # likewise never optional-but-blocking here.
+    with ring.span("mesh"):
         from horovod_tpu.parallel import mesh as _mesh_mod
         _state.mesh = _mesh_mod.build_ranks_mesh(_state.topology)
+    with ring.span("init/controller"):
         from horovod_tpu import core as _core_mod
         _state.controller = _core_mod.Controller(_state.topology, _state.mesh)
         # Elastic standby: the controller adopted the identity the
@@ -107,18 +119,19 @@ def init(ranks: Optional[Sequence[int]] = None) -> None:
                 _state.topology,
                 local_rank_override=_state.controller.host_local_rank)
         _state.controller.start()
-        from horovod_tpu import metrics as _metrics_mod
-        _metrics_mod.start_exporters(_state.topology.rank)
-        if not _state.atexit_registered:
-            atexit.register(shutdown)
-            _state.atexit_registered = True
-        _state.shut_down = False
-        _state.initialized = True
+    from horovod_tpu import metrics as _metrics_mod
+    _metrics_mod.start_exporters(_state.topology.rank)
+    if not _state.atexit_registered:
+        atexit.register(shutdown)
+        _state.atexit_registered = True
+    _state.shut_down = False
+    _state.initialized = True
 
 
 def shutdown() -> None:
     """Shut the framework down (idempotent; registered with atexit, mirroring
-    reference ``horovod/common/__init__.py:69``)."""
+    reference ``horovod/common/__init__.py:69``).  The span ring stays as
+    it is: what a run recorded is read after it."""
     with _state.lock:
         if not _state.initialized:
             return

@@ -19,6 +19,7 @@ import ctypes
 import os
 import subprocess
 import threading
+import time
 import warnings
 from typing import List
 
@@ -94,6 +95,11 @@ def _configure(lib) -> None:
     lib.htpu_timeline_activity_start.restype = None
     lib.htpu_timeline_activity_start.argtypes = [
         ctypes.c_void_p, ctypes.c_char_p, ctypes.c_char_p]
+    if hasattr(lib, "htpu_timeline_activity_span"):   # PR 34
+        lib.htpu_timeline_activity_span.restype = None
+        lib.htpu_timeline_activity_span.argtypes = [
+            ctypes.c_void_p, ctypes.c_char_p, ctypes.c_char_p,
+            ctypes.c_longlong, ctypes.c_longlong]
     lib.htpu_timeline_counter.restype = None
     lib.htpu_timeline_counter.argtypes = [
         ctypes.c_void_p, ctypes.c_char_p, ctypes.c_longlong]
@@ -400,39 +406,48 @@ def load():
     with _lib_lock:
         if _lib is not None:
             return _lib
-        if os.path.isdir(_CPP_DIR):
-            # Run make even when the .so exists: it no-ops when up to date
-            # and rebuilds a stale library whose symbols predate this module.
-            try:
-                subprocess.run(["make", "-C", _CPP_DIR], check=True,
-                               capture_output=True, timeout=120)
-            except subprocess.CalledProcessError as e:
-                # Fall through: a prebuilt .so may still be usable — but say
-                # so, or the pure-Python fallback engages silently.
-                warnings.warn(
-                    "horovod_tpu: native core build failed; falling back to "
-                    "the pure-Python control path if no prebuilt library "
-                    "exists.\n--- make stderr ---\n"
-                    + e.stderr.decode(errors="replace")[-2000:],
-                    RuntimeWarning)
-            except (subprocess.SubprocessError, OSError) as e:
-                warnings.warn(
-                    f"horovod_tpu: native core build did not run ({e}); "
-                    "falling back to the pure-Python control path if no "
-                    "prebuilt library exists.", RuntimeWarning)
-        if not os.path.exists(_LIB_PATH):
-            return None
+        # The build (a no-op when up to date, half a minute in a new
+        # checkout) and the load, as one span of the ring.
+        from horovod_tpu.timeline import ring
+        with ring.span("init/native_core"):
+            return _build_and_load()
+
+
+def _build_and_load():
+    global _lib
+    if os.path.isdir(_CPP_DIR):
+        # Run make even when the .so exists: it no-ops when up to date
+        # and rebuilds a stale library whose symbols predate this module.
         try:
-            lib = ctypes.CDLL(_LIB_PATH)
-            _configure(lib)
-        except (OSError, AttributeError) as e:
-            # AttributeError = stale library missing newer symbols.
+            subprocess.run(["make", "-C", _CPP_DIR], check=True,
+                           capture_output=True, timeout=120)
+        except subprocess.CalledProcessError as e:
+            # Fall through: a prebuilt .so may still be usable — but say
+            # so, or the pure-Python fallback engages silently.
             warnings.warn(
-                f"horovod_tpu: native core library unusable ({e}); using "
-                "the pure-Python control path.", RuntimeWarning)
-            return None
-        _lib = lib
-        return _lib
+                "horovod_tpu: native core build failed; falling back to "
+                "the pure-Python control path if no prebuilt library "
+                "exists.\n--- make stderr ---\n"
+                + e.stderr.decode(errors="replace")[-2000:],
+                RuntimeWarning)
+        except (subprocess.SubprocessError, OSError) as e:
+            warnings.warn(
+                f"horovod_tpu: native core build did not run ({e}); "
+                "falling back to the pure-Python control path if no "
+                "prebuilt library exists.", RuntimeWarning)
+    if not os.path.exists(_LIB_PATH):
+        return None
+    try:
+        lib = ctypes.CDLL(_LIB_PATH)
+        _configure(lib)
+    except (OSError, AttributeError) as e:
+        # AttributeError = stale library missing newer symbols.
+        warnings.warn(
+            f"horovod_tpu: native core library unusable ({e}); using "
+            "the pure-Python control path.", RuntimeWarning)
+        return None
+    _lib = lib
+    return _lib
 
 
 def available() -> bool:
@@ -1458,6 +1473,25 @@ class CppTimeline:
         for e in entries:
             self._lib.htpu_timeline_activity_end(
                 self._ptr, e.name.encode("utf-8"))
+
+    def activity_span(self, tensor_name: str, activity: str,
+                      start_ns: int, end_ns: int) -> None:
+        """A whole activity the caller timed itself on
+        ``time.perf_counter_ns()``, as one complete event on the lane.  A
+        prebuilt library from before PR 34 marks the lane with an empty
+        begin / end pair instead."""
+        if not self._ptr:
+            return
+        name = tensor_name.encode("utf-8")
+        if hasattr(self._lib, "htpu_timeline_activity_span"):
+            self._lib.htpu_timeline_activity_span(
+                self._ptr, name, activity.encode("utf-8"),
+                (end_ns - start_ns) // 1000,
+                (time.perf_counter_ns() - end_ns) // 1000)
+        else:
+            self._lib.htpu_timeline_activity_start(
+                self._ptr, name, activity.encode("utf-8"))
+            self._lib.htpu_timeline_activity_end(self._ptr, name)
 
     def counter(self, name: str, value: int) -> None:
         """Chrome-trace counter sample ("ph": "C") — queue depth, bytes in

@@ -18,10 +18,21 @@ contract plus two TPU-specific needs:
 batches (pytrees with a common leading batch dim), get back an iterator
 of mesh-sharded device arrays, prefetched ``prefetch`` batches ahead on
 a background thread, optionally stacked for the multi-step scan.
+
+The loader traces itself into the span ring (:mod:`horovod_tpu.timeline`),
+every span keyed by the ordinal of the batch it serves.  On the producer
+thread: ``loader/source`` (``next(source)``), ``loader/stage`` (stack and
+``device_put``), ``loader/put_wait`` (blocked on a full queue: the thread
+is ahead).  On the consumer: ``loader/get_wait`` (blocked on an empty
+one).  The registry carries the same for a job too long for a ring:
+counters ``loader.batches``, ``loader.bytes`` (host bytes staged) and
+``loader.starved`` (gets that waited over a millisecond), gauge
+``loader.queue_depth`` (batches ready at each get).
 """
 
 from __future__ import annotations
 
+import itertools
 import queue
 import threading
 from typing import Any, Iterator, Optional
@@ -29,6 +40,11 @@ from typing import Any, Iterator, Optional
 import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+from horovod_tpu.metrics import registry
+from horovod_tpu.timeline import ring
+
+STARVED_NS = 1_000_000   # a get that waited longer counts in loader.starved
 
 
 def shard_for_process(batch, mesh: Mesh, spec=None):
@@ -115,35 +131,48 @@ class ShardedLoader:
         stop = threading.Event()
         _END = object()
 
-        def put(item) -> bool:
+        def put(item, ordinal=None) -> bool:
             # Bounded put that gives up when the consumer went away, so
             # an abandoned iteration can't wedge the producer thread
-            # holding device-resident batches forever.
-            while not stop.is_set():
-                try:
-                    q.put(item, timeout=0.1)
-                    return True
-                except queue.Full:
-                    continue
+            # holding device-resident batches forever.  One span over the
+            # whole wait, not one a poll.
+            with ring.span("loader/put_wait", key=ordinal):
+                while not stop.is_set():
+                    try:
+                        q.put(item, timeout=0.1)
+                        return True
+                    except queue.Full:
+                        continue
             return False
+
+        def stage_and_put(host, ordinal) -> bool:
+            with ring.span("loader/stage", key=ordinal):
+                staged = self._stage(host)
+            registry.inc("loader.batches")
+            registry.inc("loader.bytes", sum(
+                a.nbytes if hasattr(a, "nbytes") else np.asarray(a).nbytes
+                for a in jax.tree.leaves(host)))
+            return put(staged, ordinal)
 
         def produce():
             try:
-                group = []
-                for host_batch in source:
-                    if stop.is_set():
+                batches = iter(source)
+                for ordinal in itertools.count():
+                    group = []
+                    while len(group) < self._k:
+                        with ring.span("loader/source", key=ordinal):
+                            host_batch = next(batches, _END)
+                        if host_batch is _END:
+                            # trailing partial group dropped (see class
+                            # docstring)
+                            put(_END)
+                            return
+                        if stop.is_set():
+                            return
+                        group.append(host_batch)
+                    host = group[0] if self._k == 1 else tuple(group)
+                    if not stage_and_put(host, ordinal):
                         return
-                    if self._k == 1:
-                        if not put(self._stage(host_batch)):
-                            return
-                        continue
-                    group.append(host_batch)
-                    if len(group) == self._k:
-                        if not put(self._stage(tuple(group))):
-                            return
-                        group = []
-                # trailing partial group dropped (see class docstring)
-                put(_END)
             except BaseException as exc:   # noqa: BLE001 — re-raised below
                 put(exc)
 
@@ -151,8 +180,12 @@ class ShardedLoader:
                                   name="horovod_tpu-data-prefetch")
         thread.start()
         try:
-            while True:
-                item = q.get()
+            for ordinal in itertools.count():
+                registry.set_gauge("loader.queue_depth", q.qsize())
+                with ring.span("loader/get_wait", key=ordinal) as waited:
+                    item = q.get()
+                if waited.end_ns - waited.start_ns > STARVED_NS:
+                    registry.inc("loader.starved")
                 if item is _END:
                     return
                 if isinstance(item, BaseException):

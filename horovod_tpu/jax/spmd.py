@@ -19,11 +19,12 @@ hierarchical allreduce (``operations.cc:879-1029`` vs ``:1025-1177``):
 from __future__ import annotations
 
 import collections
+import contextlib
 import functools
+import itertools
 import math
 import os
 import re
-import time
 from typing import Callable, Tuple
 
 import jax
@@ -34,6 +35,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax import shard_map
 
 from horovod_tpu import scheduler as _sched
+from horovod_tpu import timeline as _timeline
 from horovod_tpu.compression import Compressor, NoneCompressor
 from horovod_tpu.jax import MeshAxisUnboundError
 from horovod_tpu.ops import injit as _injit
@@ -366,9 +368,11 @@ class _GuardedExecutable:
         return getattr(self._inner, name)
 
 
-def _wrap_with_stages(fn, around):
+def _wrap_with_stages(fn, around, lower_span=None):
     """Build the dispatch wrapper for ``fn`` plus ``lower``/``trace``
-    passthroughs that keep ``around`` attached through AOT compilation."""
+    passthroughs that keep ``around`` attached through AOT compilation.
+    ``lower_span`` names the span of the ring that ``lower`` runs under:
+    the wrapper next to the jit gives it."""
 
     def wrapped(*args, **kwargs):
         return around(fn, args, kwargs)
@@ -378,8 +382,12 @@ def _wrap_with_stages(fn, around):
 
     for attr in ("lower", "trace"):
         if hasattr(fn, attr):
-            def passthrough(*a, _m=getattr(fn, attr), **kw):
-                return _GuardedStage(_m(*a, **kw), rewrap)
+            span = lower_span if attr == "lower" else None
+
+            def passthrough(*a, _m=getattr(fn, attr), _span=span, **kw):
+                with (_timeline.ring.span(_span) if _span
+                      else contextlib.nullcontext()):
+                    return _GuardedStage(_m(*a, **kw), rewrap)
             setattr(wrapped, attr, passthrough)
     return wrapped
 
@@ -512,9 +520,11 @@ def _wire_metrics(fn, mesh, compression, steps_per_call: int):
     by wire dtype, folded into the process metrics registry next to the
     eager plane's ``ring.*`` series.  The plan is a pure function of the
     params tree's shapes and the wire policy, so it is computed once at
-    the first dispatch and replayed as a counter bump per call.  The first
-    dispatch also sets the ``injit.compile_options`` gauge: how many
-    compile options the step program carries (0 off the TPU)."""
+    the first dispatch and replayed as a counter bump per call
+    (``injit.steps`` is counted by :class:`_StepInstruments`, on every
+    mesh size).  The first dispatch also sets the
+    ``injit.compile_options`` gauge: how many compile options the step
+    program carries (0 off the TPU)."""
     from horovod_tpu.metrics import registry
 
     hierarchical = set(mesh.axis_names) == {DCN_AXIS, ICI_AXIS}
@@ -534,66 +544,6 @@ def _wire_metrics(fn, mesh, compression, steps_per_call: int):
     return _wrap_with_stages(fn, around)
 
 
-def _moe_metrics(fn, expert_layers: dict, steps_per_call: int):
-    """Per-dispatch ``moe.assignments`` (token-to-expert assignments a
-    rank routes), ``moe.expert_bytes`` (expert parameter bytes its
-    expert layers hold, each read by every step), ``moe.held_assignments``
-    (of the assignments, what uniform routing sends to the experts a
-    share of a layer holds), ``ssm.scan_chunks`` and ``ssm.state_bytes``
-    (chunks a state-space mixer scans, bytes of float32 state passed
-    between them), ``ssm.fused_scans`` and ``ssm.fused_passes`` (its scans
-    and its elementwise passes that took their Pallas kernels),
-    ``lin.delta_chunks`` and ``lin.state_bytes`` (the same
-    of a linear-attention mixer's delta rule) counters.  ``expert_layers``
-    is what the ``DroplessMoE``, ``Mamba2Mixer`` and ``GatedDeltaNet``
-    layers of the step's ``loss_fn`` noted of their static sizes while it
-    was traced (:func:`noting_expert_layers`); a model without them bumps
-    nothing."""
-    from horovod_tpu.metrics import registry
-
-    def around(target, args, kwargs):
-        out = target(*args, **kwargs)
-        for counters in expert_layers.values():
-            for name, count in counters.items():
-                registry.inc(name, count * steps_per_call)
-        return out
-
-    return _wrap_with_stages(fn, around)
-
-
-def _wire_observe(fn, steps_per_call: int):
-    """Observatory step decomposition for the in-jit path.  Dispatch is
-    async — the host call returns before the device finishes — so the
-    device-step wall time is the *inter-dispatch* delta: once the
-    pipeline is primed, the host re-enters dispatch exactly once per
-    executed call, and any time it spends blocked *inside* dispatch
-    (donation back-pressure, the runtime throttling enqueue) is stall
-    the device pipeline could not hide.  Compute is the remainder;
-    in-jit collectives are compiled into the program, so hidden/exposed
-    comm are not separable here and are reported as zero (the eager
-    overlap path owns those series)."""
-    from horovod_tpu import observe as _observe
-
-    t_prev = [0.0]
-
-    def around(target, args, kwargs):
-        t_in = time.perf_counter()
-        out = target(*args, **kwargs)
-        if not _observe.enabled():
-            t_prev[0] = 0.0
-            return out
-        t_out = time.perf_counter()
-        stall_s = (t_out - t_in) / steps_per_call
-        if t_prev[0] > 0.0:
-            step_s = max(0.0, (t_out - t_prev[0]) / steps_per_call)
-            _observe.note_step(step_s, max(0.0, step_s - stall_s),
-                               0.0, 0.0, stall_s)
-        t_prev[0] = t_out
-        return out
-
-    return _wrap_with_stages(fn, around)
-
-
 def _ordering_guard(fn, what: str = "make_train_step"):
     """Enforce the shared-runtime async-eager ordering contract at every
     dispatch: launching this jitted collective program while ``*_async``
@@ -602,7 +552,11 @@ def _ordering_guard(fn, what: str = "make_train_step"):
     (see :func:`horovod_tpu.basics.check_mesh_async_ordering`).  One
     attribute check + counter read per step when a controller exists.
     AOT compilation through the returned wrapper's ``lower``/``trace``
-    yields executables with the same guard."""
+    yields executables with the same guard.
+
+    This is the wrapper next to the jit, so the ring's ``step/enqueue``
+    (the call into the jitted function or the compiled executable) and
+    ``step/lower`` (the jit's ``lower``) open here."""
     from horovod_tpu import basics
 
     timeout_s = float(os.environ.get("HOROVOD_TPU_STEP_TIMEOUT_S", "0"))
@@ -610,7 +564,8 @@ def _ordering_guard(fn, what: str = "make_train_step"):
 
     def around(target, args, kwargs):
         basics.check_mesh_async_ordering(what)
-        out = target(*args, **kwargs)
+        with _timeline.ring.span("step/enqueue"):
+            out = target(*args, **kwargs)
         if watchdog is not None:
             # Watch the loss: other outputs are typically donated into
             # the next call; one executable's outputs become ready
@@ -618,39 +573,65 @@ def _ordering_guard(fn, what: str = "make_train_step"):
             watchdog.watch(out[-1] if isinstance(out, tuple) else out)
         return out
 
-    return _wrap_with_stages(fn, around)
+    return _wrap_with_stages(fn, around, lower_span="step/lower")
 
 
-class _StepSpans:
-    """In-jit hot-path spans for the Horovod-style timeline (SURVEY §7.4
-    item 6): the negotiated path traces itself in the executor, but the
-    jitted train step — the actual hot path — would otherwise be
-    invisible next to those spans.  Per step two lanes are emitted:
+class _StepInstruments:
+    """Everything a dispatch of the train step is timed and counted by,
+    in one wrapper around it.
 
-    * ``DISPATCH`` — the host call into XLA (trace + cache hit + enqueue;
-      async, returns before the device finishes);
-    * ``EXECUTE``  — dispatch-return until the step's outputs are ready,
-      stamped by a single watcher thread so the training loop never
-      blocks on instrumentation.
+    The call is timed once, as the span ring's ``step/dispatch`` (the
+    first one, which compiles or reads the cache, as
+    ``step/first_call``), keyed by the call ordinal; below it are the
+    guard's ``step/enqueue`` and whatever jax reports while it runs
+    (:func:`horovod_tpu.timeline.listen_to_jax`), so the span's self time
+    is the wrappers'.  That one pair of clock reads is what the others
+    read:
 
-    Active only when a timeline is configured (``HOROVOD_TPU_TIMELINE``,
-    rank 0); otherwise the per-call cost is one attribute check.
+    * the Horovod-style timeline, when one is configured
+      (``HOROVOD_TPU_TIMELINE``, rank 0): the ``DISPATCH`` lane
+      (``<name>/dispatch``) gets the span as a complete event, and the
+      ``EXECUTE`` lane (``<name>/execute``: dispatch-return until the
+      step's outputs are ready) is stamped by a single watcher thread so
+      the training loop never blocks on instrumentation;
+    * the observatory, when armed (``HOROVOD_TPU_OBSERVE``).  Dispatch is
+      async, so the device-step wall time is the *inter-dispatch* delta:
+      once the pipeline is primed the host re-enters dispatch exactly
+      once per executed call, and the time it spends blocked *inside*
+      dispatch (donation back-pressure, the runtime throttling enqueue)
+      is stall the device pipeline could not hide.  Compute is the
+      remainder; in-jit collectives are compiled into the program, so
+      hidden/exposed comm are reported as zero (the eager overlap path
+      owns those series).
+
+    The counters ride here too: ``injit.steps`` on every mesh size, and
+    what the ``DroplessMoE``, ``Mamba2Mixer`` and ``GatedDeltaNet``
+    layers of the step's ``loss_fn`` noted of their static sizes while it
+    was traced (:func:`noting_expert_layers`) — ``moe.assignments``,
+    ``moe.expert_bytes``, ``moe.held_assignments``, ``ssm.scan_chunks``,
+    ``ssm.state_bytes``, ``ssm.fused_scans``, ``ssm.fused_passes``,
+    ``lin.delta_chunks``, ``lin.state_bytes``; a model without such
+    layers bumps none of those.
     """
 
     _instances = 0
 
-    def __init__(self, name: str):
+    def __init__(self, name: str, expert_layers: dict, steps_per_call: int):
         import queue
         import types
         # Unique lane per instance: two instrumented steps sharing a lane
-        # would interleave their B/E pairs into garbage durations.
-        n = _StepSpans._instances
-        _StepSpans._instances += 1
+        # would interleave their events.
+        n = _StepInstruments._instances
+        _StepInstruments._instances += 1
         suffix = f"[{n}]" if n else ""
-        self._dispatch = types.SimpleNamespace(name=f"{name}{suffix}/dispatch")
+        self._dispatch_lane = f"{name}{suffix}/dispatch"
         self._execute = types.SimpleNamespace(name=f"{name}{suffix}/execute")
         self._queue: "queue.Queue" = queue.Queue()
         self._watcher = None
+        self._expert_layers = expert_layers
+        self._steps_per_call = steps_per_call
+        self._calls = itertools.count()
+        self._observed_end_ns = 0
 
     @staticmethod
     def _timeline():
@@ -673,32 +654,54 @@ class _StepSpans:
                 pass
             timeline.activity_end_all([self._execute])
 
-    def instrument(self, fn):
+    def _to_timeline(self, timeline, span, out):
         import threading
+        timeline.activity_span(self._dispatch_lane, "DISPATCH",
+                               span.start_ns, span.end_ns)
+        if self._watcher is None:
+            self._watcher = threading.Thread(
+                target=self._watch_loop, daemon=True,
+                name="horovod_tpu-step-timeline")
+            self._watcher.start()
+        # Wait on the LOSS only: the other outputs are typically fed
+        # straight back into the next call and donated there — the
+        # watcher racing that donation would see 'Array has been
+        # deleted' and stamp EXECUTE at next-dispatch time instead of
+        # completion.  Outputs of one executable become ready
+        # together, so the loss suffices.
+        self._queue.put((timeline, out[-1] if isinstance(out, tuple)
+                         else out))
+
+    def _to_observatory(self, span):
+        from horovod_tpu import observe as _observe
+        if not _observe.enabled():
+            self._observed_end_ns = 0
+            return
+        stall_s = (span.end_ns - span.start_ns) / 1e9 / self._steps_per_call
+        if self._observed_end_ns:
+            step_s = max(0.0, (span.end_ns - self._observed_end_ns) / 1e9
+                         / self._steps_per_call)
+            _observe.note_step(step_s, max(0.0, step_s - stall_s),
+                               0.0, 0.0, stall_s)
+        self._observed_end_ns = span.end_ns
+
+    def instrument(self, fn):
+        from horovod_tpu.metrics import registry
 
         def around(target, args, kwargs):
-            timeline = self._timeline()
-            if timeline is None:
-                return target(*args, **kwargs)
-            timeline.activity_start_all([self._dispatch], "DISPATCH")
-            try:
+            ordinal = next(self._calls)
+            with _timeline.ring.span(
+                    "step/dispatch" if ordinal else "step/first_call",
+                    key=ordinal) as span:
                 out = target(*args, **kwargs)
-            finally:
-                # A raising step must not leave an unbalanced B event.
-                timeline.activity_end_all([self._dispatch])
-            if self._watcher is None:
-                self._watcher = threading.Thread(
-                    target=self._watch_loop, daemon=True,
-                    name="horovod_tpu-step-timeline")
-                self._watcher.start()
-            # Wait on the LOSS only: the other outputs are typically fed
-            # straight back into the next call and donated there — the
-            # watcher racing that donation would see 'Array has been
-            # deleted' and stamp EXECUTE at next-dispatch time instead of
-            # completion.  Outputs of one executable become ready
-            # together, so the loss suffices.
-            watch = out[-1] if isinstance(out, tuple) else out
-            self._queue.put((timeline, watch))
+            registry.inc("injit.steps", self._steps_per_call)
+            for counters in self._expert_layers.values():
+                for name, count in counters.items():
+                    registry.inc(name, count * self._steps_per_call)
+            timeline = self._timeline()
+            if timeline is not None:
+                self._to_timeline(timeline, span, out)
+            self._to_observatory(span)
             return out
 
         return _wrap_with_stages(fn, around)
@@ -846,12 +849,9 @@ def make_train_step(
     if mesh.size > 1:
         spmd_step = _wire_metrics(spmd_step, mesh, compression,
                                   steps_per_call)
-    spans = _StepSpans("train_step")
-
-    def instrumented(fn):
-        return spans.instrument(_wire_observe(
-            _moe_metrics(fn, expert_layers, steps_per_call),
-            steps_per_call))
+    _timeline.listen_to_jax()
+    instrumented = _StepInstruments("train_step", expert_layers,
+                                    steps_per_call).instrument
 
     wire_identity = (compression is NoneCompressor
                      or isinstance(compression, NoneCompressor))
@@ -880,32 +880,37 @@ def make_train_step(
 
     def _resolve(args):
         if not chosen:
-            # Run the SPMD program's trace-time diagnostics even when the
-            # plain program will execute: sync_aux_state=False's
-            # varying-aux guard (_sync_or_check_aux) must fire on one
-            # chip exactly as it would on a pod — a model developed
-            # single-chip should not ship an aux bug that only surfaces
-            # at the first multi-chip trace.  Only that diagnostic
-            # propagates from this extra trace; any other ValueError is
-            # raised again, as itself, by the trace of the program that
-            # actually runs.
-            try:
-                jax.eval_shape(step, *args)
-            except ValueError as exc:
-                if "varies across mesh shards" in str(exc):
-                    raise
-            try:
-                # Trace without executing or donating.  Two failures mean
-                # "this step needs the mesh axes bound" and route to the
-                # shard_map program: loss_fn naming a mesh axis (NameError:
-                # unbound axis name) and DistributedOptimizer finding no
-                # axis bound for its reduction.  Anything else is the
-                # caller's bug and surfaces here as itself.
-                jax.eval_shape(plain_body, *args)
-                chosen.append(plain_step)
-            except (NameError, MeshAxisUnboundError):
-                chosen.append(spmd_step)
+            with _timeline.ring.span("step/resolve"):
+                chosen.append(_choose(args))
         return chosen[0]
+
+    def _choose(args):
+        # Run the SPMD program's trace-time diagnostics even when the
+        # plain program will execute: sync_aux_state=False's varying-aux
+        # guard (_sync_or_check_aux) must fire on one chip exactly as it
+        # would on a pod — a model developed single-chip should not ship
+        # an aux bug that only surfaces at the first multi-chip trace.
+        # Only that diagnostic propagates from this extra trace; any other
+        # ValueError is raised again, as itself, by the trace of the
+        # program that actually runs.
+        try:
+            with _timeline.ring.span("step/trace_spmd"):
+                jax.eval_shape(step, *args)
+        except ValueError as exc:
+            if "varies across mesh shards" in str(exc):
+                raise
+        try:
+            # Trace without executing or donating.  Two failures mean
+            # "this step needs the mesh axes bound" and route to the
+            # shard_map program: loss_fn naming a mesh axis (NameError:
+            # unbound axis name) and DistributedOptimizer finding no
+            # axis bound for its reduction.  Anything else is the
+            # caller's bug and surfaces here as itself.
+            with _timeline.ring.span("step/trace_plain"):
+                jax.eval_shape(plain_body, *args)
+            return plain_step
+        except (NameError, MeshAxisUnboundError):
+            return spmd_step
 
     def dispatch(params, aux_state, opt_state, batch):
         args = (params, aux_state, opt_state, batch)

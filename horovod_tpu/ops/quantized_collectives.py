@@ -401,13 +401,12 @@ def estimate_wire_plan(tree, n: int, compression,
 
 def record_wire_plan(plan: Dict[str, int], steps: int = 1) -> None:
     """Fold a wire plan into the process metrics registry (one call per
-    dispatched step batch)."""
-    if not plan:
-        return
+    dispatched step batch).  The steps themselves, ``injit.steps``, are
+    counted where the step is dispatched, on every mesh size
+    (``spmd._StepInstruments``)."""
     from horovod_tpu.metrics import registry
     for key, nbytes in plan.items():
         registry.inc(f"injit.bytes#wire_dtype={key}", nbytes * steps)
-    registry.inc("injit.steps", steps)
 
 
 # -------------------------------------------- host wire image (parity)

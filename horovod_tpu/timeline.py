@@ -21,15 +21,27 @@ cannot see.
 A C++ implementation with identical output lives in ``cpp/timeline.{h,cc}``
 and is used when the native core is loaded; this module is the fallback and
 the format specification.
+
+The jitted hot path and the host layers around it (``hvd.init()``,
+``ShardedLoader``, ``make_train_step``) trace themselves into :data:`ring`,
+a bounded in-memory :class:`SpanRing` that is always recording — the
+sibling, on this side, of the native flight recorder on the eager plane.
+The ``Timeline`` file is one of its readers: its ``train_step/dispatch``
+lane is fed from the ring's ``step/dispatch`` span
+(:meth:`Timeline.activity_span`).  ``docs/observability.md`` has the table
+of spans.
 """
 
 from __future__ import annotations
 
+import collections
+import gc
+import itertools
 import json
 import os
 import threading
 import time
-from typing import Dict, List, Optional
+from typing import Dict, List, NamedTuple, Optional
 
 
 def per_rank_trace_path(template: str, rank: int, size: int = None) -> str:
@@ -70,6 +82,7 @@ class Timeline:
         self._lock = threading.Lock()
         self._first_event = True
         self._t0 = time.monotonic()
+        self._t0_ns = time.perf_counter_ns()    # the span ring's clock
         t0_wall_us = int(time.time() * 1e6)
         self._tensor_pids: Dict[str, int] = {}
         self._next_pid = 1
@@ -160,6 +173,15 @@ class Timeline:
             self._emit({"ph": "E", "pid": self._pid(e.name),
                         "ts": self._ts_us()})
 
+    def activity_span(self, tensor_name: str, activity: str,
+                      start_ns: int, end_ns: int) -> None:
+        """A whole activity on ``tensor_name``'s lane as one complete
+        event, from a span the caller timed itself on
+        ``time.perf_counter_ns()`` (the span ring's ``step/dispatch``)."""
+        self._emit({"ph": "X", "pid": self._pid(tensor_name),
+                    "ts": (start_ns - self._t0_ns) // 1000,
+                    "dur": (end_ns - start_ns) // 1000, "name": activity})
+
     def cache_hit_tick(self, dur_us: int) -> None:
         """Complete-event span (``"ph": "X"``) marking a negotiation tick
         served entirely from the response cache — visually distinct from
@@ -205,3 +227,264 @@ class Timeline:
                 self._file.write("\n]\n")
                 self._file.close()
                 self._closed = True
+
+
+# ---------------------------------------------------------- the span ring
+
+
+class Span(NamedTuple):
+    """One closed span, as :meth:`SpanRing.snapshot` gives it."""
+    id: int
+    parent: int             # the span open on this thread at its start; 0: none
+    name: str
+    thread: int             # threading.get_ident() of the thread it ran on
+    start_ns: int           # time.perf_counter_ns()
+    end_ns: int
+    key: Optional[int]      # what spans of one unit of work share (below)
+
+
+class _ThreadSpans:
+    """One thread's open spans, innermost last, and the spans it added
+    after the fact most recently (:meth:`SpanRing.add`)."""
+    __slots__ = ("ident", "open", "late")
+
+    def __init__(self):
+        self.ident = threading.get_ident()
+        self.open: list = []
+        self.late: collections.deque = collections.deque(maxlen=64)
+
+
+class _OpenSpan:
+    """A span from ``with ring.span(name)`` on; ``start_ns`` and
+    ``end_ns`` are stamped whether or not the ring keeps it."""
+    __slots__ = ("_ring", "_thread", "id", "parent", "name", "key",
+                 "start_ns", "end_ns")
+
+    def __init__(self, ring, name, key):
+        self._ring = ring
+        self._thread = None
+        self.id = self.parent = 0
+        self.name = name
+        self.key = key
+
+    def __enter__(self):
+        ring = self._ring
+        if ring.enabled:
+            thread = self._thread = ring._thread()
+            if thread.open:
+                outer = thread.open[-1]
+                self.parent = outer.id
+                if self.key is None:
+                    self.key = outer.key
+            self.id = next(ring._ids)
+            thread.open.append(self)
+        self.start_ns = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc):
+        self.end_ns = time.perf_counter_ns()
+        thread = self._thread
+        if thread is not None:
+            if thread.open and thread.open[-1] is self:
+                thread.open.pop()
+            elif self in thread.open:       # closed out of order
+                thread.open.remove(self)
+            self._ring._keep([self.id, self.parent, self.name, thread.ident,
+                              self.start_ns, self.end_ns, self.key])
+        return False
+
+
+class SpanRing:
+    """The last ``maxlen`` spans of this process, in memory.
+
+    ``with ring.span(name, key=k):`` times a piece of host work on
+    ``time.perf_counter_ns()``.  Its ``parent`` is the span open on the
+    same thread when it opened; ``key`` is what the spans of one unit of
+    work share — the call ordinal for everything a dispatch of the train
+    step causes, the batch ordinal for everything the loader does to one
+    batch — and a span given none takes its parent's.  A span is kept
+    when it closes, so children stand before their parents.
+
+    Always recording and bounded: the oldest span makes room for the
+    newest and ``dropped`` counts those.  No file, no thread, no
+    environment variable, no lock beyond the deque's own.  Nothing leaves
+    memory until a reader asks: :meth:`snapshot` (tuples), :meth:`events`
+    (Chrome-trace ``X`` events for Perfetto).  ``enabled`` is an
+    attribute for an A/B of the ring's own cost and for tests, not a
+    knob: switched off, ``span()`` still stamps its two clock reads (the
+    timeline lane and the observatory read them) and keeps nothing.
+
+    The spans are not mirrored into ``jax.profiler``'s trace as
+    annotations: the host tracer's lowest level that keeps annotations
+    also keeps the runtime's per-tile ``Transpose`` spans, a million of
+    them in five steps of a 38.5 MB batch (``PERF.md`` section 6, PR 34).
+    :meth:`events` carries one ``(perf_counter_ns, time_ns)`` pair taken
+    together instead, which sets the spans on the wall clock.
+    """
+
+    SLACK_NS = 1_000_000    # between jax's clocks and ours, for add()
+
+    def __init__(self, maxlen: int = 16384):
+        self.enabled = True
+        self.dropped = 0
+        self._spans: collections.deque = collections.deque(maxlen=maxlen)
+        self._ids = itertools.count(1)
+        self._local = threading.local()
+        self._thread_names: Dict[int, str] = {}
+
+    def _thread(self) -> _ThreadSpans:
+        try:
+            return self._local.spans
+        except AttributeError:
+            thread = self._local.spans = _ThreadSpans()
+            self._thread_names[thread.ident] = threading.current_thread().name
+            return thread
+
+    def _keep(self, record: list) -> None:
+        spans = self._spans
+        if len(spans) == spans.maxlen:
+            self.dropped += 1
+        spans.append(record)
+
+    def span(self, name: str, key: Optional[int] = None) -> _OpenSpan:
+        return _OpenSpan(self, name, key)
+
+    def open_names(self) -> List[str]:
+        """Names of the calling thread's open spans, outermost first."""
+        return [s.name for s in self._thread().open]
+
+    def add(self, name: str, start_ns: int, end_ns: int) -> Optional[int]:
+        """Keep a span known only once it has ended (a duration jax
+        reports, a collection).  Its parent is the innermost span open on
+        this thread that had opened by ``start_ns``; spans added this way
+        before it that lie inside it become its children (jax reports an
+        inner ``jit``'s trace before the outer one's, and a cache read
+        before the compile it is part of).  Returns its id."""
+        if not self.enabled:
+            return None
+        thread = self._thread()
+        parent, key = 0, None
+        for outer in reversed(thread.open):
+            if outer.start_ns <= start_ns + self.SLACK_NS:
+                parent, key = outer.id, outer.key
+                break
+        span_id = next(self._ids)
+        for earlier in reversed(thread.late):
+            if earlier[5] <= start_ns:
+                break
+            if (earlier[1] == parent
+                    and earlier[4] >= start_ns - self.SLACK_NS):
+                earlier[1] = span_id
+        record = [span_id, parent, name, thread.ident, start_ns, end_ns, key]
+        thread.late.append(record)
+        self._keep(record)
+        return span_id
+
+    def snapshot(self) -> List[Span]:
+        """The kept spans, oldest first."""
+        return [Span(*record) for record in self._spans.copy()]
+
+    def events(self) -> List[dict]:
+        """The kept spans as Chrome-trace complete events (``ts`` and
+        ``dur`` in microseconds of ``time.perf_counter_ns()``), with their
+        threads' names: ``json.dump`` them for Perfetto.  The first event,
+        ``clock_pair``, holds one reading of that clock and of
+        ``time.time_ns()`` taken together: what moves the spans onto the
+        wall clock, beside a trace that is on it."""
+        pid = os.getpid()
+        spans = self.snapshot()
+        out = [{"ph": "M", "pid": pid, "name": "clock_pair",
+                "args": {"perf_counter_ns": time.perf_counter_ns(),
+                         "time_ns": time.time_ns()}}]
+        out.extend({"ph": "M", "pid": pid, "tid": ident,
+                    "name": "thread_name", "args": {
+                        "name": self._thread_names.get(ident, str(ident))}}
+                   for ident in sorted({s.thread for s in spans}))
+        out.extend({"ph": "X", "pid": pid, "tid": s.thread, "name": s.name,
+                    "ts": s.start_ns / 1e3,
+                    "dur": (s.end_ns - s.start_ns) / 1e3,
+                    "args": {"id": s.id, "parent": s.parent, "key": s.key}}
+                   for s in spans)
+        return out
+
+    def clear(self) -> None:
+        self._spans.clear()
+        self.dropped = 0
+
+
+def self_ns(spans: List[Span]) -> Dict[int, int]:
+    """Each span's self time: its length less the part of it its
+    children cover."""
+    children: Dict[int, list] = collections.defaultdict(list)
+    for s in spans:
+        children[s.parent].append(s)
+    out = {}
+    for s in spans:
+        covered, reached = 0, s.start_ns
+        for c in sorted(children.get(s.id, ()), key=lambda c: c.start_ns):
+            start = max(c.start_ns, reached)
+            end = min(c.end_ns, s.end_ns)
+            if end > start:
+                covered += end - start
+                reached = end
+        out[s.id] = s.end_ns - s.start_ns - covered
+    return out
+
+
+#: The process's ring.  ``hvd.shutdown()`` leaves it as it is.
+ring = SpanRing()
+
+# jax's own phases of building a program, as spans: each is a duration
+# jax reports when the phase ends.
+JAX_PHASES = {
+    "/jax/core/compile/jaxpr_trace_duration": "jax/trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jax/lower",
+    # compile_or_get_cached as a whole: on a hit of the persistent cache
+    # the read-back lies inside it, and is its child here.
+    "/jax/core/compile/backend_compile_duration": "jax/compile",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "jax/cache_read",
+}
+_listening_to_jax = False
+
+
+def listen_to_jax() -> None:
+    """Register, once, the listener that keeps jax's phases in the ring
+    and counts ``step.compiles``: backend compilations (or cache reads)
+    under a ``step/*`` span.  One after set-up; more is a recompilation,
+    and its ``jax/compile`` span carries the call ordinal."""
+    global _listening_to_jax
+    if _listening_to_jax:
+        return
+    _listening_to_jax = True
+    import jax.monitoring
+    from horovod_tpu.metrics import registry
+
+    def on_duration(event, seconds, **_):
+        name = JAX_PHASES.get(event)
+        if name is None:
+            return
+        end_ns = time.perf_counter_ns()
+        kept = ring.add(name, end_ns - int(seconds * 1e9), end_ns)
+        if (kept and name == "jax/compile"
+                and any(n.startswith("step/") for n in ring.open_names())):
+            registry.inc("step.compiles")
+
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+
+
+_full_gc_started = [0]
+
+
+def _on_gc(phase, info):
+    # Called for every collection: returns at once for all but the full
+    # ones, whose pauses nothing else in a trace accounts for.
+    if info["generation"] != 2:
+        return
+    if phase == "start":
+        _full_gc_started[0] = time.perf_counter_ns()
+    elif _full_gc_started[0]:
+        ring.add("host/gc", _full_gc_started[0], time.perf_counter_ns())
+        _full_gc_started[0] = 0
+
+
+gc.callbacks.append(_on_gc)

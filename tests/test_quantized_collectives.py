@@ -300,7 +300,9 @@ def test_estimate_wire_plan_and_counters(monkeypatch):
 
     assert delta("injit.bytes#wire_dtype=int8") == 3000
     assert delta("injit.bytes#wire_dtype=fp32") == 192
-    assert delta("injit.steps") == 3
+    # The steps are counted where the step is dispatched, on every mesh
+    # size (the next test; tests/test_spans.py on one device).
+    assert delta("injit.steps") == 0
 
 
 def test_make_train_step_records_injit_bytes(hvd, monkeypatch):

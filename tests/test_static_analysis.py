@@ -52,7 +52,7 @@ class TestShippedTree:
         # The audited contract surface; update these alongside a
         # deliberate knob/symbol addition.
         assert stats["knobs_total"] == 69
-        assert stats["symbols_total"] == 116
+        assert stats["symbols_total"] == 117
 
     def test_every_knob_has_a_read_site_count(self):
         _, stats = knobs.check(ROOT)
@@ -75,7 +75,7 @@ class TestShippedTree:
         report = json.loads(proc.stdout)
         assert report["ok"] is True
         assert report["findings"] == []
-        assert report["stats"]["symbols_total"] == 116
+        assert report["stats"]["symbols_total"] == 117
 
 
 # ---------------------------------------------------------------------------

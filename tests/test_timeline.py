@@ -187,3 +187,39 @@ class TestNativeTimeline:
         assert ticks[0]["dur"] == 250
         offs = [e for e in events if e.get("name") == "clock_offset"]
         assert offs and offs[0]["args"]["offset_us"] == -7.5
+
+
+@pytest.mark.parametrize("backend", ["python", "cpp"])
+def test_activity_span_is_a_complete_event_on_the_lane(tmp_path, backend):
+    """A span the caller timed itself (the ring's ``step/dispatch``) lands
+    on its lane as one ``X`` event of that length, ending when it ended,
+    in both writers."""
+    import time
+    path = tmp_path / "t.json"
+    if backend == "cpp":
+        if not cpp_core.available():
+            pytest.skip("native core not built")
+        tl = cpp_core.CppTimeline(str(path))
+    else:
+        tl = Timeline(str(path))
+    tl.activity_start_all([_Entry("train_step/execute")], "EXECUTE")
+    start_ns = time.perf_counter_ns()
+    time.sleep(0.02)
+    end_ns = time.perf_counter_ns()
+    time.sleep(0.01)
+    tl.activity_span("train_step/dispatch", "DISPATCH", start_ns, end_ns)
+    tl.activity_end_all([_Entry("train_step/execute")])
+    tl.close()
+    events = load_trace(path)
+    lanes = {e["args"]["name"]: e["pid"] for e in events
+             if e.get("name") == "process_name"}
+    (span,) = [e for e in events if e.get("name") == "DISPATCH"]
+    assert span["ph"] == "X" and span["pid"] == lanes["train_step/dispatch"]
+    assert span["dur"] == (end_ns - start_ns) // 1000
+    (begin,) = [e for e in events if e.get("name") == "EXECUTE"]
+    (end,) = [e for e in events if e.get("ph") == "E"
+              and e["pid"] == lanes["train_step/execute"]]
+    # On the writer's own clock: it began after EXECUTE began and ended
+    # 10 ms or more before EXECUTE ended.
+    assert begin["ts"] <= span["ts"] + 500
+    assert span["ts"] + span["dur"] <= end["ts"] - 9000
