@@ -16,6 +16,10 @@ through the entry points a user calls (``hvd.init()`` →
   widths and read out of the projection's one array as the mixer reads
   them, against their XLA forms — forward and every gradient — and
   prints their plan;
+* checks the experts' grouped matmuls as kernels, over a window of
+  sorted rows at Nemotron-H's widths and levelled as the layer levels it,
+  against ``lax.ragged_dot`` and its transposes — forward, input and
+  weight gradient — and prints their plan (``moe_plan``);
 * takes optimizer steps with the d=2048/T=2048 TransformerLM (one step
   per call, then four scanned steps per call) and with ResNet-50 at
   batch 128, parameters from each model's own ``init`` under ``--seed``,
@@ -60,6 +64,9 @@ SCAN_REFERENCE = dict(batch=2, seq=2048, heads=64, head_dim=64, groups=8,
                       state=128, chunk=128)
 PASSES_REFERENCE = dict(batch=2, seq=2048, heads=64, head_dim=64, groups=8,
                         state=128, conv_kernel=4)
+# One held layer's window of twotower_1chip: 18,432 sorted rows of width
+# 2688 against 8 experts 1856 wide (padded as the plan says).
+EXPERTS_REFERENCE = dict(rows=18432, groups=8, dim=2688, hidden=1856)
 # A sequence of 512: the float32 recurrence's backward keeps three (192, 96)
 # states a head a token, 4.3 GB there and 17.2 of the chip's 15.75 at 2048.
 DELTA_REFERENCE = dict(batch=1, seq=512, heads=30, key_dim=96,
@@ -86,6 +93,11 @@ SCAN_GRAD_TOL = 4e-2
 # PR 33, and the further side from the definition).  What is left is the
 # one rounding of a bfloat16 result, 2^-9 of a value.
 PASSES_TOL = 1e-2
+# The grouped matmuls' kernels against ``lax.ragged_dot`` and its
+# transposes on the same bfloat16 operands: both accumulate in float32 and
+# round once (chip, PR 35: the two products equal, the weight gradient
+# 3.3e-3 apart, one bfloat16 step of a sum over thousands of rows).
+EXPERTS_TOL = 1e-2
 # The chunked delta rule in bfloat16 against its recurrence in float32.
 DELTA_TOL = 4e-2
 LOSS_TOL = 2e-2          # 4-device vs 1-device loss, same step
@@ -437,6 +449,81 @@ def passes_reference_phase(*, batch: int, seq: int, heads: int,
               f"{PASSES_TOL})")
     return {"shape": [batch, seq, inner, conv_dim, groups],
             "interpret": interpret, "passes_plan": plan_dict,
+            **{k: round(e, 5) for k, e in errs.items()}}
+
+
+def moe_plan(rows: int, groups: int, dim: int, hidden: int) -> dict:
+    """What ``grouped_matmul._plan`` decides on this device for an expert
+    layer's grouped matmuls over ``rows`` sorted bfloat16 rows: the
+    kernels with their tiles, or ``lax.ragged_dot``, and the multiple the
+    hidden width is padded to."""
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.ops import grouped_matmul
+
+    return grouped_matmul.grouped_plan(
+        jax.ShapeDtypeStruct((rows, dim), jnp.bfloat16), groups,
+        hidden + -hidden % 128,
+        interpret=jax.default_backend() != "tpu")._asdict()
+
+
+def experts_reference_phase(*, rows: int, groups: int, dim: int, hidden: int,
+                            seed: int) -> dict:
+    """The grouped matmul as the expert layer calls it (``grouped_matmul``
+    under its custom VJP, the hidden width padded as the plan says, group
+    sizes uneven with an empty group and levelled: the last group takes
+    the window's empty rows) against ``lax.ragged_dot`` on the same
+    operands: the product and both gradients."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    from horovod_tpu.ops import grouped_matmul
+
+    interpret = jax.default_backend() != "tpu"
+    plan_dict = moe_plan(rows, groups, dim, hidden)
+    check(plan_dict["form"] == "kernels",
+          f"the grouped matmuls' plan at the layer's shape is {plan_dict}")
+    plan = grouped_matmul.GroupedPlan(**plan_dict)
+    width = hidden + -hidden % plan.lanes
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    x = jax.random.normal(ks[0], (rows, dim), jnp.bfloat16)
+    w = (jax.random.normal(ks[1], (groups, dim, width))
+         / math.sqrt(dim)).astype(jnp.bfloat16)
+    dy = jax.random.normal(ks[2], (rows, width), jnp.bfloat16)
+    # A third of the window's rows landed, unevenly, one group empty.
+    share = jax.random.dirichlet(ks[3], jnp.full((groups - 1,), 4.0))
+    sizes = jnp.concatenate([jnp.zeros((1,), jnp.int32), jnp.floor(
+        share * (rows // 3)).astype(jnp.int32)])
+    sizes = sizes.at[-1].add(rows - sizes.sum())
+
+    def value_and_grads(product):
+        def weighted(x, w):
+            y = product(x, w)
+            return (y.astype(jnp.float32) * dy.astype(jnp.float32)).sum(), y
+        return jax.jit(jax.value_and_grad(weighted, argnums=(0, 1),
+                                          has_aux=True))
+
+    got_fn = value_and_grads(lambda x, w: grouped_matmul.grouped_matmul(
+        x, w, sizes, plan, interpret=interpret))
+    if not interpret:
+        names = kernels_in(got_fn.lower(x, w).as_text())
+        check(names == ["moe_gmm", "moe_gmm_nt", "moe_tgmm"],
+              f"the grouped matmuls lowered to the kernels {names}")
+    (_, got_y), got_grads = got_fn(x, w)
+    (_, want_y), want_grads = value_and_grads(
+        lambda x, w: lax.ragged_dot(x, w, sizes))(x, w)
+    errs = {}
+    for name, g, r in zip(("out", "grad_rows", "grad_w"),
+                          (got_y, *got_grads), (want_y, *want_grads)):
+        errs[name] = _rel_err(g, r)
+        check(errs[name] <= EXPERTS_TOL,
+              f"the grouped matmuls' kernels differ from lax.ragged_dot in "
+              f"{name} by {errs[name]:.3g} of its largest value (bound "
+              f"{EXPERTS_TOL})")
+    return {"shape": [rows, groups, dim, width], "interpret": interpret,
+            "moe_plan": plan_dict,
             **{k: round(e, 5) for k, e in errs.items()}}
 
 
@@ -978,6 +1065,8 @@ def main(argv=None) -> int:
             **PASSES_REFERENCE, seed=args.seed))
         emit("delta_reference", **delta_reference_phase(
             **DELTA_REFERENCE, seed=args.seed))
+        emit("experts_reference", **experts_reference_phase(
+            **EXPERTS_REFERENCE, seed=args.seed))
         emit("transformer_lm", **transformer_phase(
             mesh, events, **ONE_CHIP_LM, seed=args.seed))
         emit("resnet50", **resnet_phase(

@@ -608,10 +608,10 @@ class _StepInstruments:
     what the ``DroplessMoE``, ``Mamba2Mixer`` and ``GatedDeltaNet``
     layers of the step's ``loss_fn`` noted of their static sizes while it
     was traced (:func:`noting_expert_layers`) — ``moe.assignments``,
-    ``moe.expert_bytes``, ``moe.held_assignments``, ``ssm.scan_chunks``,
-    ``ssm.state_bytes``, ``ssm.fused_scans``, ``ssm.fused_passes``,
-    ``lin.delta_chunks``, ``lin.state_bytes``; a model without such
-    layers bumps none of those.
+    ``moe.expert_bytes``, ``moe.held_assignments``, ``moe.fused_matmuls``,
+    ``ssm.scan_chunks``, ``ssm.state_bytes``, ``ssm.fused_scans``,
+    ``ssm.fused_passes``, ``lin.delta_chunks``, ``lin.state_bytes``; a
+    model without such layers bumps none of those.
     """
 
     _instances = 0

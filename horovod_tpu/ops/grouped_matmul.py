@@ -1,0 +1,355 @@
+"""Grouped matmuls over rows sorted by group: an expert layer's products.
+
+``rows`` (M, K) hold G groups of consecutive rows, ``group_sizes`` (G,)
+long, and every group has a matrix of its own, ``w`` (G, K, N).  Three
+products make an expert layer's forward and backward pass:
+
+* ``rows_g @ w_g`` for every group, (M, N) (:func:`grouped_matmul`);
+* its input gradient, ``dy_g @ w_g.T``, (M, K) — the same kernel, the
+  matrix read transposed;
+* its weight gradient, ``rows_g.T @ dy_g``, one (K, N) block a group.
+
+Operands are in the activations' dtype (bfloat16 in training); every
+product is accumulated in float32 and rounded once, at the store.  Group
+sizes arrive as data.  Rows past the last group belong to none: they read
+as zeros in the weight gradient and are written as zeros by the other two.
+
+Two forms, and one place that chooses (:func:`_plan`, a pure function of
+the shapes, the dtype's width, ``interpret``, manual mesh axes and whether
+the device backs a scoped-VMEM budget above Mosaic's default; no option
+picks a form):
+
+* **Pallas TPU kernels** where the widths are whole 128-lane tiles and the
+  rows whole tiles (``olmoe_1chip``, ``twotower_1chip``).  The rows are cut
+  into tiles of ``plan.rows``; a *visit* is one (row tile, group) pair
+  whose rows meet, listed in row order by :func:`_visits` from the group
+  sizes and read by the kernels' index maps (scalar prefetch): a tile that
+  holds a boundary is visited once for each of its groups, the other
+  group's rows masked.  ``moe_gmm`` / ``moe_gmm_nt`` hold a visit's row
+  tile and the group's (K, column block) in VMEM, the block staying put
+  while the visits are the same group's, and work through the tile a strip
+  of rows at a time — a strip the group has no row in is skipped, so a
+  boundary costs one strip twice, not a tile.  ``moe_tgmm`` keeps a group's
+  (K block, N block) of the weight gradient in float32 in VMEM over the
+  group's visits and writes it once, zeros for an empty group.  The
+  drivers are ``jax.jit(inline=True)``: the layers of a stack share one
+  trace of each kernel body.
+* **``lax.ragged_dot``** otherwise (the tiny shapes of the CPU tests,
+  interpreted Pallas under ``shard_map``'s manual axes): the caller's own
+  form.
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import NamedTuple
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from horovod_tpu.ops.flash_attention import (
+    _struct, _vmem_headroom_ok, _vmem_limit)
+
+_F32 = jnp.float32
+
+
+class GroupedPlan(NamedTuple):
+    """What :func:`_plan` decides for one grouped matmul and its two
+    transposes."""
+    form: str       # "kernels" | "ragged_dot"
+    rows: int       # rows a tile; 0 in the ragged_dot form
+    strip: int      # rows of a tile multiplied at a time
+    cols: int       # most columns a block of an output or a weight gradient
+    vmem_mb: int    # scoped-VMEM budget asked, MB
+    lanes: int      # what a width is padded to a multiple of, by zeros
+
+
+# Rows a tile, rows a strip and the most columns a block: the best of a
+# sweep on the chip at the two cells' shapes (PERF.md section 6, PR 35).
+_ROWS = 512
+_STRIP = 256
+_MOST_COLS = 1024
+# The scoped-VMEM budget the kernels ask, above Mosaic's default of 16 MB;
+# shapes whose blocks would take more than three quarters of it are
+# ``lax.ragged_dot``'s.  The cells' largest kernels take 15.7 MB by shapes
+# (OLMoE's forward: a (512, 2048) and a (2048, 1024) block twice over, the
+# pipeline's two buffers, beside the output's) and 16.8 (a weight
+# gradient's (1024, 1024) block in float32, its product and its output).
+_VMEM_MB = 48
+# On the v5e ``lax.ragged_dot`` ran at 33 TFLOP/s at a width of 1856 or
+# 1920 and at 63-93 at 2048 (PERF.md section 6, PR 30).
+_RAGGED_LANES = 256
+
+
+def _block(width: int, most: int) -> int:
+    """The widest whole-128-lane block no wider than ``most`` that cuts
+    ``width`` evenly."""
+    tiles = width // 128
+    return 128 * max(b for b in range(1, tiles + 1)
+                     if tiles % b == 0 and 128 * b <= max(most, 128))
+
+
+def _vmem_bytes(rows, strip, cols, k, n, itemsize) -> int:
+    """What the largest kernel's blocks, scratch and temporaries take, by
+    shapes, for a layer's products both ways (K to N and N to K)."""
+    kc, nc = _block(k, cols), _block(n, cols)
+    gmm = max(2 * (rows * whole + whole * block + rows * block) * itemsize
+              + strip * block * 4
+              for whole, block in ((k, nc), (n, kc)))
+    tgmm = (2 * rows * (kc + nc) + 2 * kc * nc) * itemsize + 2 * kc * nc * 4
+    return max(gmm, tgmm)
+
+
+def _plan(*, rows, groups, k, n, itemsize, interpret, manual_axes,
+          vmem_headroom) -> GroupedPlan:
+    """Kernels or ``lax.ragged_dot`` — the one place that chooses, a pure
+    function of what the op observes at trace time.
+
+    The kernels take widths in whole 128-lane tiles and rows in whole
+    tiles of ``_ROWS`` (two-byte operands: a float32 tile of as many rows
+    holds twice the bytes), the contraction whole in VMEM; they ask a
+    scoped-VMEM budget above Mosaic's default, which ``vmem_headroom``
+    says the device backs, and leave what would not fit it.  Interpreted
+    Pallas under ``shard_map``'s manual axes cannot run in jax 0.9.0 (as in
+    ``ssd._plan``)."""
+    ragged = GroupedPlan("ragged_dot", 0, 0, 0, 0, _RAGGED_LANES)
+    if (k % 128 or n % 128 or rows % _ROWS or groups < 1 or itemsize != 2
+            or not vmem_headroom or (interpret and manual_axes)):
+        return ragged
+    if (_vmem_bytes(_ROWS, _STRIP, _MOST_COLS, k, n, itemsize)
+            > _VMEM_MB * 2 ** 20 * 3 // 4):
+        return ragged
+    return GroupedPlan("kernels", _ROWS, _STRIP, _MOST_COLS, _VMEM_MB, 128)
+
+
+def grouped_plan(rows_like, groups: int, n: int, *,
+                 interpret: bool) -> GroupedPlan:
+    """:func:`_plan` for sorted rows that are, or are shaped like,
+    ``rows_like`` (M, K) against ``groups`` matrices (K, n): what the
+    expert layer, ``chip_smoke.py`` and the tests ask."""
+    vma = (rows_like.vma if isinstance(rows_like, jax.ShapeDtypeStruct)
+           else jax.typeof(rows_like).vma)
+    return _plan(rows=rows_like.shape[0], groups=groups,
+                 k=rows_like.shape[1], n=n,
+                 itemsize=jnp.dtype(rows_like.dtype).itemsize,
+                 interpret=interpret, manual_axes=bool(vma),
+                 vmem_headroom=_vmem_headroom_ok())
+
+
+# ------------------------------------------------------------- the visits
+
+
+def _visits(group_sizes, rows: int, tile: int):
+    """``(tiles, groups, offsets)``: the (row tile, group) pairs the
+    kernels walk, in row order, ``rows // tile + G`` of them, and the
+    groups' first rows (G + 1,).
+
+    A pair starts where a group or a tile starts, so every pair whose rows
+    meet is listed, every group is (an empty one with a tile it has no
+    row in: its weight gradient is written all the same), and a pair
+    listed twice — a group that starts where a tile does — stands twice
+    in a row, where the kernels pass over the second.  Tiles and groups
+    both never fall along the list."""
+    G = group_sizes.shape[0]
+    n_tiles = rows // tile
+    ends = jnp.cumsum(group_sizes.astype(jnp.int32))
+    offsets = jnp.concatenate([jnp.zeros((1,), jnp.int32), ends])
+    # A group's start sorts before the tile's that starts on the same row.
+    keys = jnp.concatenate([offsets[:-1] * 2,
+                            jnp.arange(n_tiles, dtype=jnp.int32) * tile * 2
+                            + 1])
+    by_row = jnp.argsort(keys, stable=True).astype(jnp.int32)
+    first_row = keys[by_row] // 2
+    holding = jnp.searchsorted(ends, first_row, side="right").astype(
+        jnp.int32)
+    groups = jnp.minimum(jnp.where(by_row < G, by_row, holding), G - 1)
+    tiles = jnp.minimum(first_row // tile, n_tiles - 1)
+    return tiles, groups, offsets
+
+
+def _visit(tiles_ref, groups_ref, offsets_ref, v, tile: int):
+    """Visit ``v``: its group, whether the visit before was the same
+    tile's and whether it was this very pair, and the rows ``[lo, hi)``
+    of the group inside the tile, counted from the tile's first."""
+    t, g = tiles_ref[v], groups_ref[v]
+    before = jnp.maximum(v - 1, 0)
+    same_tile = (v > 0) & (tiles_ref[before] == t)
+    again = same_tile & (groups_ref[before] == g)
+    lo = jnp.maximum(offsets_ref[g] - t * tile, 0)
+    hi = jnp.minimum(offsets_ref[g + 1] - t * tile, tile)
+    return g, same_tile, again, lo, hi
+
+
+# ------------------------------------------------------------ the kernels
+
+
+def _gmm_kernel(tiles_ref, groups_ref, offsets_ref, x_ref, w_ref, o_ref, *,
+                tile: int, strip: int, transposed: bool):
+    """One visit of ``rows_g @ w_g`` (``w_g.T`` if ``transposed``): the
+    strips of the tile that the group has rows in, the other groups' rows
+    of a strip left as they are."""
+    _, same_tile, again, lo, hi = _visit(tiles_ref, groups_ref, offsets_ref,
+                                         pl.program_id(1), tile)
+
+    @pl.when(jnp.logical_not(same_tile))
+    def _():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    contract = (((1,), (1 if transposed else 0,)), ((), ()))
+    for first in range(0, tile, strip):
+        rows = slice(first, first + strip)
+
+        # One store serves whole and cut strips alike: a branch for the
+        # whole ones, without the select, was no faster on the chip
+        # (PERF.md section 6, PR 35).
+        @pl.when(jnp.logical_not(again) & (lo < first + strip)
+                 & (hi > first))
+        def _(rows=rows, first=first):
+            row = first + lax.broadcasted_iota(jnp.int32, (strip, 1), 0)
+            o_ref[rows, :] = jnp.where(
+                (row >= lo) & (row < hi),
+                lax.dot_general(x_ref[rows, :], w_ref[...], contract,
+                                preferred_element_type=_F32),
+                o_ref[rows, :].astype(_F32)).astype(o_ref.dtype)
+
+
+def _tgmm_kernel(tiles_ref, groups_ref, offsets_ref, x_ref, dy_ref, o_ref,
+                 acc_ref, *, tile: int):
+    """One visit of ``rows_g.T @ dy_g``: the tile's share of the group's
+    block, summed in float32 over the group's visits."""
+    v = pl.program_id(2)
+    g, _, again, lo, hi = _visit(tiles_ref, groups_ref, offsets_ref, v, tile)
+    last = pl.num_programs(2) - 1
+
+    @pl.when((v == 0) | (groups_ref[jnp.maximum(v - 1, 0)] != g))
+    def _():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def add(dy):
+        acc_ref[...] += lax.dot_general(
+            x_ref[...], dy, (((0,), (0,)), ((), ())),
+            preferred_element_type=_F32)
+
+    whole = (lo == 0) & (hi == tile)
+
+    @pl.when(jnp.logical_not(again) & whole)
+    def _():
+        add(dy_ref[...])
+
+    @pl.when(jnp.logical_not(again | whole) & (hi > lo))
+    def _():
+        row = lax.broadcasted_iota(jnp.int32, (tile, 1), 0)
+        add(jnp.where((row >= lo) & (row < hi), dy_ref[...].astype(_F32),
+                      0.0).astype(dy_ref.dtype))
+
+    @pl.when((v == last) | (groups_ref[jnp.minimum(v + 1, last)] != g))
+    def _():
+        o_ref[...] = acc_ref[...].astype(o_ref.dtype)
+
+
+def _params(plan, interpret, semantics):
+    if interpret:
+        return {}
+    return {"compiler_params": pltpu.CompilerParams(
+        dimension_semantics=semantics, **_vmem_limit(plan.vmem_mb))}
+
+
+@functools.partial(jax.jit, inline=True,
+                   static_argnames=("transposed", "plan", "interpret"))
+def _gmm(x, w, group_sizes, *, transposed: bool, plan, interpret):
+    """``x_g @ w_g`` (M, N) for ``w`` (G, K, N) or, ``transposed``,
+    ``x_g @ w_g.T`` for ``w`` (G, N, K)."""
+    M, K = x.shape
+    N = w.shape[1] if transposed else w.shape[2]
+    cols = _block(N, plan.cols)
+    tiles, groups, offsets = _visits(group_sizes, M, plan.rows)
+    if transposed:
+        w_spec = pl.BlockSpec((None, cols, K),
+                              lambda j, v, t, g, o: (g[v], j, 0))
+    else:
+        w_spec = pl.BlockSpec((None, K, cols),
+                              lambda j, v, t, g, o: (g[v], 0, j))
+    return pl.pallas_call(
+        functools.partial(_gmm_kernel, tile=plan.rows, strip=plan.strip,
+                          transposed=transposed),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(N // cols, tiles.shape[0]),
+            in_specs=[
+                pl.BlockSpec((plan.rows, K),
+                             lambda j, v, t, g, o: (t[v], 0)),
+                w_spec],
+            out_specs=pl.BlockSpec((plan.rows, cols),
+                                   lambda j, v, t, g, o: (t[v], j))),
+        out_shape=_struct((M, N), x.dtype, x, w),
+        interpret=interpret, name="moe_gmm_nt" if transposed else "moe_gmm",
+        **_params(plan, interpret, ("parallel", "arbitrary")),
+    )(tiles, groups, offsets, x, w)
+
+
+@functools.partial(jax.jit, inline=True,
+                   static_argnames=("plan", "interpret"))
+def _tgmm(x, dy, group_sizes, *, plan, interpret):
+    """``x_g.T @ dy_g`` for every group: (G, K, N)."""
+    M, K = x.shape
+    N = dy.shape[1]
+    k_cols, n_cols = _block(K, plan.cols), _block(N, plan.cols)
+    tiles, group_of, offsets = _visits(group_sizes, M, plan.rows)
+    return pl.pallas_call(
+        functools.partial(_tgmm_kernel, tile=plan.rows),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(K // k_cols, N // n_cols, tiles.shape[0]),
+            in_specs=[
+                pl.BlockSpec((plan.rows, k_cols),
+                             lambda i, j, v, t, g, o: (t[v], i)),
+                pl.BlockSpec((plan.rows, n_cols),
+                             lambda i, j, v, t, g, o: (t[v], j))],
+            out_specs=pl.BlockSpec((None, k_cols, n_cols),
+                                   lambda i, j, v, t, g, o: (g[v], i, j)),
+            scratch_shapes=[pltpu.VMEM((k_cols, n_cols), _F32)]),
+        out_shape=_struct((group_sizes.shape[0], K, N), x.dtype, x, dy),
+        interpret=interpret, name="moe_tgmm",
+        **_params(plan, interpret, ("parallel", "parallel", "arbitrary")),
+    )(tiles, group_of, offsets, x, dy)
+
+
+# ---------------------------------------------------------------- the op
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _fused(x, w, group_sizes, plan, interpret):
+    return _gmm(x, w, group_sizes, transposed=False, plan=plan,
+                interpret=interpret)
+
+
+def _fused_fwd_rule(x, w, group_sizes, plan, interpret):
+    return _fused(x, w, group_sizes, plan, interpret), (x, w, group_sizes)
+
+
+def _fused_bwd_rule(plan, interpret, res, dy):
+    x, w, group_sizes = res
+    return (_gmm(dy, w, group_sizes, transposed=True, plan=plan,
+                 interpret=interpret),
+            _tgmm(x, dy, group_sizes, plan=plan, interpret=interpret), None)
+
+
+_fused.defvjp(_fused_fwd_rule, _fused_bwd_rule)
+
+
+def grouped_matmul(x, w, group_sizes, plan: GroupedPlan, *,
+                   interpret: bool = False):
+    """``x[rows of group g] @ w[g]`` for every group ``g``: ``x`` (M, K)
+    sorted by group, ``w`` (G, K, N), ``group_sizes`` (G,) integers whose
+    sum is at most M; rows past it come out as zeros.  Differentiable in
+    ``x`` and ``w``.  ``plan`` (:func:`grouped_plan`; the same for K to N
+    and N to K, so one serves a layer's products) says whether the
+    kernels or ``lax.ragged_dot`` run; ``interpret=True`` runs the kernels
+    off-TPU (tests)."""
+    if plan.form != "kernels":
+        return lax.ragged_dot(x, w, group_sizes)
+    return _fused(x, w.astype(x.dtype), group_sizes.astype(jnp.int32), plan,
+                  interpret)

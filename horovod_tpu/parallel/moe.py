@@ -49,6 +49,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from horovod_tpu.ops.grouped_matmul import grouped_matmul, grouped_plan
 from horovod_tpu.parallel._vma import per_shard_init as _expert_init
 
 EP_AXIS = "ep"
@@ -181,19 +182,23 @@ class MoELayer(nn.Module):
 # rows live in HBM while a layer runs.
 _HELD_WINDOW = 3
 
-# The held experts' hidden width is padded with zero columns to a multiple
-# of this inside the grouped matmuls (the parameters keep their width; a
-# width that is a multiple already, as OLMoE's 1024, is left alone): on the
-# v5e ``lax.ragged_dot`` ran at 33 TFLOP/s at a hidden width of 1856 or
-# 1920 and at 63-93 at 2048 (PERF.md section 6, PR 30).
-_HIDDEN_TILE = 256
-
-
-def _pad_hidden(a, axis: int):
-    pad = -a.shape[axis] % _HIDDEN_TILE
+def _pad_hidden(a, axis: int, lanes: int):
+    """``a`` with zeros behind its ``axis`` up to a multiple of ``lanes``:
+    the held experts' hidden width inside the grouped matmuls (the
+    parameters keep theirs; a width that is a multiple already is left
+    alone).  ``lanes`` is what the grouped matmuls' plan says they want:
+    whole 128-lane tiles for the kernels (1856 -> 1920), 256 for
+    ``lax.ragged_dot`` (2048)."""
+    pad = -a.shape[axis] % lanes
     if not pad:
         return a
     return jnp.pad(a, [(0, pad if i == axis else 0) for i in range(a.ndim)])
+
+
+def _interpret() -> bool:
+    """Off the TPU the grouped matmuls' kernels run interpreted."""
+    return jax.default_backend() != "tpu"
+
 
 # What make_train_step wants to know of the expert layers its loss_fn
 # holds: dicts that a DroplessMoE traced meanwhile writes its static sizes
@@ -294,8 +299,9 @@ class DroplessMoE(nn.Module):
     float32 matmul rounds its operands to bfloat16, which moves the k-th
     choice of many tokens); the experts run in ``dtype``.  The k·N
     assignments are sorted by expert and each projection is one grouped
-    matmul over the sorted rows (``lax.ragged_dot``: FLOPs follow the
-    assignments, N·k, not N·E), all shapes static.
+    matmul over the sorted rows (``ops/grouped_matmul.py``: Pallas kernels
+    where its plan takes them, ``lax.ragged_dot`` otherwise; FLOPs follow
+    the assignments, N·k, not N·E), all shapes static.
 
     The other settings (Nemotron-H's layer sets all of them):
 
@@ -378,16 +384,16 @@ class DroplessMoE(nn.Module):
         names = (("w_gate", "w_up", "w_down") if self.activation == "swiglu"
                  else ("w_up", "w_down"))
         if self.held is None:
-            out, tokens_per_expert = self._all_experts(x, gate, expert,
-                                                       names)
+            out, tokens_per_expert, fused = self._all_experts(
+                x, gate, expert, names)
             n_held = E
         else:
             first, n_held = self.held
             if not (0 <= first and n_held >= 1 and first + n_held <= E):
                 raise ValueError(f"held={self.held} is not a range of the "
                                  f"{E} experts")
-            out, tokens_per_expert, held_assignments = self._held_experts(
-                x, gate, expert, names, first, n_held)
+            out, tokens_per_expert, held_assignments, fused = (
+                self._held_experts(x, gate, expert, names, first, n_held))
             self.sow("intermediates", "held_assignments", held_assignments)
 
         if self.shared_hidden:
@@ -406,6 +412,7 @@ class DroplessMoE(nn.Module):
         self.sow("intermediates", "expert_index", expert)
         counters = {
             "moe.assignments": n * k,
+            "moe.fused_matmuls": fused * len(names),
             "moe.expert_bytes": (len(names) * n_held * d * self.hidden
                                  * jnp.dtype(self.param_dtype).itemsize)}
         if self.held is not None:
@@ -424,16 +431,19 @@ class DroplessMoE(nn.Module):
                 else (n_experts, d, self.hidden), self.param_dtype)
             for name in names}
 
-    def _ffn(self, rows, w, group_sizes):
-        """The experts on sorted ``rows``: grouped matmuls in ``dtype``."""
-        w = {name: a.astype(self.dtype) for name, a in w.items()}
+    def _grouped(self, rows, w, group_sizes, plan):
+        """``rows`` of every expert times its matrix of ``w``, in
+        ``dtype``."""
+        return grouped_matmul(rows, w, group_sizes, plan,
+                              interpret=_interpret())
+
+    def _hidden(self, rows, w, group_sizes, plan):
+        """The experts' hidden activations on sorted ``rows``."""
+        up = self._grouped(rows, w["w_up"], group_sizes, plan)
         if self.activation == "swiglu":
-            h = (nn.silu(lax.ragged_dot(rows, w["w_gate"], group_sizes))
-                 * lax.ragged_dot(rows, w["w_up"], group_sizes))
-        else:
-            h = jnp.square(nn.relu(
-                lax.ragged_dot(rows, w["w_up"], group_sizes)))
-        return h, w["w_down"]
+            return nn.silu(self._grouped(rows, w["w_gate"], group_sizes,
+                                         plan)) * up
+        return jnp.square(nn.relu(up))
 
     def _all_experts(self, x, gate, expert, names):
         """Every expert is here: all k·N assignments, in expert order."""
@@ -448,14 +458,18 @@ class DroplessMoE(nn.Module):
             rows = _to_expert_order(x.astype(self.dtype), order, inverse)
 
         with jax.named_scope("experts"):
-            w = self._weights(names, E, d)
-            h, w_down = self._ffn(rows, w, tokens_per_expert)
-            y = lax.ragged_dot(h, w_down, tokens_per_expert)      # (k·N, d)
+            plan = grouped_plan(rows, E, self.hidden,
+                                interpret=_interpret())
+            w = {name: a.astype(self.dtype)
+                 for name, a in self._weights(names, E, d).items()}
+            h = self._hidden(rows, w, tokens_per_expert, plan)
+            y = self._grouped(h, w["w_down"], tokens_per_expert,
+                              plan)                               # (k·N, d)
 
         with jax.named_scope("combine"):
             y = _to_token_order(y, order, inverse).reshape(n, k, d)
             out = jnp.einsum("nkd,nk->nd", y.astype(jnp.float32), gate)
-        return out, tokens_per_expert
+        return out, tokens_per_expert, plan.form == "kernels"
 
     def _held_experts(self, x, gate, expert, names, first, n_held):
         """``n_held`` of the experts are here (class docstring)."""
@@ -477,6 +491,10 @@ class DroplessMoE(nn.Module):
         R = min(n * k, -(-_HELD_WINDOW * n * k * n_held // E // 8) * 8)
         windows = -(-n * k // R)
         order = jnp.pad(order, (0, windows * R - n * k))
+        # The grouped matmuls' plan says what the hidden width is padded to.
+        plan = grouped_plan(
+            jax.ShapeDtypeStruct((R, d), self.dtype, vma=jax.typeof(x).vma),
+            n_held, self.hidden + -self.hidden % 128, interpret=_interpret())
 
         def nothing(x):
             # Zeros that vary over the mesh axes the tokens vary over.
@@ -504,12 +522,15 @@ class DroplessMoE(nn.Module):
                     # its router sends the tokens.
                     sizes = sizes.at[-1].add(R - sizes.sum())
             with jax.named_scope("experts"):
-                h, w_down = self._ffn(rows, {
+                padded = {
                     name: _pad_hidden(a.astype(self.dtype),
-                                      1 if name == "w_down" else 2)
-                    for name, a in w.items()}, sizes)
-                h = jnp.where(here, h, 0)
-                y = jnp.where(here, lax.ragged_dot(h, w_down, sizes), 0)
+                                      1 if name == "w_down" else 2,
+                                      plan.lanes)
+                    for name, a in w.items()}
+                h = jnp.where(here, self._hidden(rows, padded, sizes, plan),
+                              0)
+                y = jnp.where(here, self._grouped(h, padded["w_down"], sizes,
+                                                  plan), 0)
             with jax.named_scope("combine"):
                 return nothing(x).at[token].add(
                     y.astype(jnp.float32) * g[:, None])
@@ -534,7 +555,8 @@ class DroplessMoE(nn.Module):
                 landed > R, jax.named_scope("overflowed")(every_other_window),
                 lambda: nothing(x))
 
-        return run(x, gate, w), tokens_per_expert, landed
+        return (run(x, gate, w), tokens_per_expert, landed,
+                plan.form == "kernels")
 
 
 def router_losses(intermediates) -> tuple:

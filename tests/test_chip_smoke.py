@@ -104,6 +104,29 @@ def test_passes_reference_phase(smoke):
                                      seed=0)
 
 
+def test_experts_reference_phase(smoke):
+    """The grouped matmuls' kernels against ``lax.ragged_dot`` at a shape
+    that tiles, and the ``moe_plan`` line: both cells' shapes take the
+    kernels, with the hidden width padded to whole 128-lane tiles, on any
+    device that runs them."""
+    out = smoke.experts_reference_phase(rows=1024, groups=4, dim=128,
+                                        hidden=120, seed=0)
+    kernels = {"form": "kernels", "rows": 512, "strip": 256, "cols": 1024,
+               "vmem_mb": 48, "lanes": 128}
+    assert out["interpret"]
+    assert out["moe_plan"] == kernels
+    assert out["shape"] == [1024, 4, 128, 128]
+    assert {"out", "grad_rows", "grad_w"} < set(out)
+    assert smoke.moe_plan(18432, 8, 2688, 1856) == kernels
+    assert smoke.moe_plan(131072, 64, 2048, 1024) == kernels
+    assert smoke.moe_plan(56, 2, 16, 48) == {
+        "form": "ragged_dot", "rows": 0, "strip": 0, "cols": 0,
+        "vmem_mb": 0, "lanes": 256}
+    with pytest.raises(RuntimeError, match="plan at the layer's shape"):
+        smoke.experts_reference_phase(rows=56, groups=2, dim=16, hidden=48,
+                                      seed=0)
+
+
 def test_delta_reference_phase(smoke):
     """The chunked delta rule against its recurrence, and the
     ``delta_plan`` line: one form, plain XLA, at the chunk it was given."""

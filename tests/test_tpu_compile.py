@@ -118,7 +118,7 @@ HEAD_KERNEL_EQUATIONS = {"_dkdv_kernel_grouped": 176,
                          "_dq_kernel_grouped": 149}
 
 
-@pytest.mark.parametrize("family", ["flash", "scan", "passes"])
+@pytest.mark.parametrize("family", ["flash", "scan", "passes", "experts"])
 def test_each_kernel_is_traced_once_a_step_not_once_a_layer(monkeypatch,
                                                             family):
     """The set-up guard, no chip and no compile: tracing ``jax.grad`` of a
@@ -149,13 +149,22 @@ def test_each_kernel_is_traced_once_a_step_not_once_a_layer(monkeypatch,
     / ``_gate_fwd`` / ``_gate_bwd`` are shared likewise: each backward
     body and the gate's forward are traced once, the convolution's forward
     twice (the checkpoint's replay again) — and there the replay stays a
-    kernel, eight in all, because the scan's backward reads its output."""
+    kernel, eight in all, because the scan's backward reads its output.
+
+    ``experts``: four expert layers, each holding 8 of 16 relu² experts
+    (512 tokens, top-2: a window of 1,024 sorted rows; widths 256 and 128,
+    so ``grouped_matmul._plan`` takes the kernels).  Up and down are two
+    shapes of each product, and the drivers ``_gmm`` / ``_tgmm`` are
+    shared by the layers: the weight gradient's body is traced twice, the
+    other's six times — up and down as the forward that runs, as the
+    checkpoint's replay, and read transposed for the input gradients —
+    where a trace a layer would be eight and twenty-four."""
     import collections
     import functools
 
     from horovod_tpu.models import NemotronHLM, TransformerLM
     from horovod_tpu.ops import flash_attention as fa
-    from horovod_tpu.ops import mixer_passes, ssd
+    from horovod_tpu.ops import grouped_matmul, mixer_passes, ssd
 
     calls = collections.Counter()
 
@@ -180,15 +189,28 @@ def test_each_kernel_is_traced_once_a_step_not_once_a_layer(monkeypatch,
             monkeypatch.setattr(mixer_passes, name,
                                 counted(name, getattr(mixer_passes, name)))
 
+    if family == "experts":
+        for name in ("_gmm_kernel", "_tgmm_kernel"):
+            monkeypatch.setattr(grouped_matmul, name,
+                                counted(name, getattr(grouped_matmul, name)))
+
     # No other test's, nor another case's: a trace made earlier would be
     # shared.
-    batch = {"flash": 3, "scan": 3, "passes": 5}[family]
+    batch = {"flash": 3, "scan": 3, "passes": 5, "experts": 2}[family]
     if family == "flash":
         seq = T
         model = TransformerLM(vocab=512, dim=H * D, depth=3, num_heads=H,
                               max_len=T, attn="flash", dtype=jnp.bfloat16)
         want = {"_fwd_kernel_fullunroll": 1, "_dq_kernel_grouped": 1,
                 "_dkdv_kernel_grouped": 1}
+    elif family == "experts":
+        seq = 256
+        model = NemotronHLM(
+            vocab=512, dim=256, pattern="EEEE", max_len=seq,
+            dtype=jnp.bfloat16, moe_experts=16, moe_top_k=2, moe_hidden=128,
+            moe=dict(router="sigmoid", renormalize=True, activation="relu2",
+                     held=(0, 8)))
+        want = {"_gmm_kernel": 6, "_tgmm_kernel": 2}
     else:
         seq = 256
         model = NemotronHLM(vocab=512, dim=256, pattern="MMMM", max_len=seq,
@@ -230,6 +252,12 @@ def test_each_kernel_is_traced_once_a_step_not_once_a_layer(monkeypatch,
 
     found = list(kernels(jaxpr.jaxpr))
     sizes = dict(found)
+    if family == "experts":
+        # Four layers: up and down forward and replayed, their two input
+        # gradients, their two weight gradients.
+        assert collections.Counter(name for name, _ in found) == {
+            "moe_gmm": 16, "moe_gmm_nt": 8, "moe_tgmm": 8}
+        return
     if family != "flash":
         # Four mixers: the scan's forward, and in the backward its states
         # pass and its sweep, the replayed forwards gone with their ``y``;
@@ -338,14 +366,30 @@ def test_train_step_all_reduces_fused_with_backward(v5e, monkeypatch):
     assert spmd.fused_all_reduce_share(fused) >= 0.5
 
 
-def test_dropless_expert_layer_fwd_bwd_at_olmoe_widths(v5e):
+def kernels_by_name(lowered):
+    """How often each of the grouped matmuls' kernels stands in a lowered
+    program (a compiled one names a custom call after its scopes)."""
+    import collections
+    import re
+
+    found = collections.Counter(re.findall(r'kernel_name = "([^"]+)"',
+                                           lowered.as_text()))
+    return {name: found[name]
+            for name in ("moe_gmm", "moe_gmm_nt", "moe_tgmm")}
+
+
+def test_dropless_expert_layer_fwd_bwd_at_olmoe_widths(v5e, monkeypatch):
     """``DroplessMoE`` as the ``olmoe_1chip`` cell calls it: 16,384 tokens
     of width 2048, 64 experts of 1024, top-8 — forward and backward on one
-    described chip.  The three grouped matmuls and their six transposes
-    compile to TPU custom calls (``ragged-dot``), nothing is a dense
+    described chip.  ``grouped_matmul._plan`` takes the kernels there: the
+    three grouped matmuls and their six transposes compile to the family's
+    three kernels by name (no ``ragged-dot`` is left), nothing is a dense
     tokens x experts product, and the layer with its gradients fits the
     chip several times over."""
     from horovod_tpu.parallel.moe import DroplessMoE
+
+    # The layer asks jax.default_backend() whether to lower interpreted.
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
 
     tokens, d, hidden, experts, top_k = 16_384, 2048, 1024, 64, 8
     one = SingleDeviceSharding(v5e[0])
@@ -362,11 +406,14 @@ def test_dropless_expert_layer_fwd_bwd_at_olmoe_widths(v5e):
         out, balance, z = layer.apply({"params": p}, x)
         return out.astype(jnp.float32).sum() + balance + z
 
-    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
-        params, x).compile()
+    lowered = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+        params, x)
+    assert kernels_by_name(lowered) == {"moe_gmm": 3, "moe_gmm_nt": 3,
+                                        "moe_tgmm": 3}
+    compiled = lowered.compile()
     text = compiled.as_text()
-    assert text.count('custom_call_target="tpu_custom_call"') >= 9
-    assert "ragged-dot" in text
+    assert text.count('custom_call_target="tpu_custom_call"') == 9
+    assert "ragged-dot" not in text
     m = compiled.memory_analysis()
     plan = (m.argument_size_in_bytes + m.output_size_in_bytes
             + m.temp_size_in_bytes - m.alias_size_in_bytes)
@@ -603,15 +650,66 @@ def test_mixer_passes_compile_wherever_the_plan_takes_the_kernels(
     assert text.count('custom_call_target="tpu_custom_call"') == 4
 
 
-def test_held_expert_layer_fwd_bwd_at_nemotron_widths(v5e):
+# (rows, groups, K, N): what ``grouped_matmul._plan`` hands to the kernels,
+# one case a way of tiling — the two cells' products both ways, widths that
+# cut into blocks of 384 and 640 only, one group, more groups than row
+# tiles, and the widest contraction the plan still holds whole in VMEM.
+@pytest.mark.parametrize("rows,groups,k,n", [
+    (18_432, 8, 2688, 1920), (18_432, 8, 1920, 2688),
+    (131_072, 64, 2048, 1024), (131_072, 64, 1024, 2048),
+    (1024, 4, 1152, 640), (512, 1, 128, 128), (512, 64, 256, 384),
+    (1024, 2, 4096, 1024)],
+    ids=["twotower_up", "twotower_down", "olmoe_up", "olmoe_down",
+         "blocks_of_384_and_640", "one_group", "more_groups_than_tiles",
+         "widest_contraction"])
+def test_grouped_matmuls_compile_wherever_the_plan_takes_the_kernels(
+        v5e, rows, groups, k, n):
+    """A shape ``_plan`` gives the kernels has to compile, the product and
+    both of its gradients: interpret mode refuses nothing of what Mosaic
+    refuses (a block off the tiling, more scoped VMEM than was asked)."""
+    from horovod_tpu.ops import grouped_matmul as gm
+
+    one = SingleDeviceSharding(v5e[0])
+
+    def shape(*dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one)
+
+    x, w, dy = shape(rows, k), shape(groups, k, n), shape(rows, n)
+    plan = gm.grouped_plan(x, groups, n, interpret=False)
+    assert plan.form == "kernels", plan
+
+    def product_and_gradients(x, w, dy, sizes):
+        y, pull = jax.vjp(
+            lambda x, w: gm.grouped_matmul(x, w, sizes, plan), x, w)
+        return y, pull(dy)
+
+    lowered = jax.jit(product_and_gradients).lower(
+        x, w, dy, shape(groups, dtype=jnp.int32))
+    assert kernels_by_name(lowered) == {
+        "moe_gmm": 1, "moe_gmm_nt": 1, "moe_tgmm": 1}
+    compiled = lowered.compile()
+    y, (dx, dw) = compiled.out_info
+    assert (y.shape, dx.shape, dw.shape) == ((rows, n), (rows, k),
+                                             (groups, k, n))
+    assert y.dtype == dx.dtype == dw.dtype == jnp.bfloat16
+
+
+def test_held_expert_layer_fwd_bwd_at_nemotron_widths(v5e, monkeypatch):
     """``DroplessMoE`` as the cell calls it: 16,384 tokens of width 2688
     routed over 128 experts, top-6, 8 of them held, a shared expert 3712
     wide.  The grouped matmuls run over a window of 18,432 sorted rows
     (three times the 6,144 that uniform routing sends here), not over the
-    98,304 assignments (0.5 GiB a tensor of their rows), with the experts'
-    hidden width padded from 1856 to 2048 inside them; the plan, with 0.8
-    GiB of float32 weights and gradients, stays under 4.5."""
+    98,304 assignments (0.5 GiB a tensor of their rows), as the family's
+    kernels (``grouped_matmul._plan`` takes them: up and down forward, the
+    checkpoint's replay of both, two input and two weight gradients, and
+    the same again in the ``overflowed`` branch's windows), with the
+    experts' hidden width padded from 1856 to the kernels' 1920, not to
+    ``ragged_dot``'s 2048; the plan, with 0.8 GiB of float32 weights and
+    gradients, stays under 4.5."""
     from horovod_tpu.parallel.moe import DroplessMoE
+
+    # The layer asks jax.default_backend() whether to lower interpreted.
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
 
     tokens, d = 16_384, 2688
     one = SingleDeviceSharding(v5e[0])
@@ -630,12 +728,17 @@ def test_held_expert_layer_fwd_bwd_at_nemotron_widths(v5e):
     def loss(p, x):
         return layer.apply({"params": p}, x)[0].astype(jnp.float32).sum()
 
-    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
-        params, x).compile()
+    lowered = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+        params, x)
+    found = kernels_by_name(lowered)
+    assert found["moe_gmm"] >= 4 and found["moe_gmm_nt"] >= 2, found
+    assert found["moe_tgmm"] >= 2, found
+    compiled = lowered.compile()
     text = compiled.as_text()
-    assert "ragged-dot" in text
+    assert "ragged-dot" not in text
     assert "18432,2688" in text and "98304,2688" not in text
-    assert "18432,2048" in text and "18432,1856" not in text
+    assert "18432,1920" in text and "18432,1856" not in text
+    assert "18432,2048" not in text
     m = compiled.memory_analysis()
     plan = (m.argument_size_in_bytes + m.output_size_in_bytes
             + m.temp_size_in_bytes - m.alias_size_in_bytes)
