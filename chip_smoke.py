@@ -20,6 +20,10 @@ through the entry points a user calls (``hvd.init()`` →
   sorted rows at Nemotron-H's widths and levelled as the layer levels it,
   against ``lax.ragged_dot`` and its transposes — forward, input and
   weight gradient — and prints their plan (``moe_plan``);
+* checks learned sparse attention at Keye-VL-2.0's head widths — the
+  indexer's scores, the exact top-k and its int8 map, the flash kernels
+  under the map and the KL pass — against its dense float32 form: the
+  same keys, the output, ``L_I`` and the gradients of all six operands;
 * takes optimizer steps with the d=2048/T=2048 TransformerLM (one step
   per call, then four scanned steps per call) and with ResNet-50 at
   batch 128, parameters from each model's own ``init`` under ``--seed``,
@@ -71,6 +75,11 @@ EXPERTS_REFERENCE = dict(rows=18432, groups=8, dim=2688, hidden=1856)
 # states a head a token, 4.3 GB there and 17.2 of the chip's 15.75 at 2048.
 DELTA_REFERENCE = dict(batch=1, seq=512, heads=30, key_dim=96,
                        value_dim=192, chunk=64)
+# Learned sparse attention at Keye-VL-2.0's widths over a shorter sequence:
+# 8 query heads over one KV head of 128, an indexer of 16 heads of 64 that
+# keeps 512 of up to 2,048 keys a query (a dense float32 (T, T) oracle).
+SELECT_REFERENCE = dict(batch=1, seq=2048, heads=8, kv_heads=1, head_dim=128,
+                        index_heads=16, index_dim=64, topk=512)
 ONE_CHIP_LM = dict(**TRANSFORMER, batch=8, steps=3, scan_steps=4)
 ONE_CHIP_RESNET = dict(stage_sizes=(3, 4, 6, 3), num_filters=64,
                        num_classes=1000, image=224, batch=128, steps=3)
@@ -100,6 +109,11 @@ PASSES_TOL = 1e-2
 EXPERTS_TOL = 1e-2
 # The chunked delta rule in bfloat16 against its recurrence in float32.
 DELTA_TOL = 4e-2
+# The selected attention, the selection and the KL pass (bfloat16 operands,
+# float32 scores) against the dense float32 mathematics on the same
+# bfloat16 inputs: the same keys to the last one (chip, PR 36: 0 of
+# 917,760 differ), outputs 2.1e-3 and gradients up to 3.3e-3 of a norm.
+SELECT_TOL = 2e-2
 LOSS_TOL = 2e-2          # 4-device vs 1-device loss, same step
 INT8_LOSS_TOL = 2e-2     # int8 wire vs fp32 wire loss, same step
 
@@ -525,6 +539,80 @@ def experts_reference_phase(*, rows: int, groups: int, dim: int, hidden: int,
     return {"shape": [rows, groups, dim, width], "interpret": interpret,
             "moe_plan": plan_dict,
             **{k: round(e, 5) for k, e in errs.items()}}
+
+
+def select_reference_phase(*, batch: int, seq: int, heads: int,
+                           kv_heads: int, head_dim: int, index_heads: int,
+                           index_dim: int, topk: int, seed: int) -> dict:
+    """Learned sparse attention as ``GroupedQueryAttention(indexer=...)``
+    runs it — ``index_select`` (the scores' kernel, the exact top-k, the
+    int8 map), ``flash_attention(select=map)`` and ``index_kl`` — against
+    ``sparse_attention_reference`` (dense float32, ``highest``) on the same
+    bfloat16 operands: the selection itself, the output, ``L_I`` and the
+    gradients of a weighted sum on all six operands."""
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.ops import flash_attention as fa, sparse_select
+
+    interpret = jax.default_backend() != "tpu"
+    ks = jax.random.split(jax.random.PRNGKey(seed), 7)
+    shapes = ((batch, seq, heads, head_dim), (batch, seq, kv_heads, head_dim),
+              (batch, seq, kv_heads, head_dim),
+              (batch, seq, index_heads, index_dim), (batch, seq, index_dim))
+    q, k, v, qi, ki = (jax.random.normal(key, shape).astype(jnp.bfloat16)
+                       for key, shape in zip(ks, shapes))
+    w = jax.random.normal(ks[5], (batch, seq, index_heads))
+    weight = jax.random.normal(ks[6], shapes[0])
+
+    def program(q, k, v, qi, ki, w):
+        select, lse_i = sparse_select.index_select(qi, ki, w, topk,
+                                                   interpret=interpret)
+        out, lse = fa.flash_attention(q, k, v, causal=True, select=select,
+                                      interpret=interpret)
+        return out, sparse_select.index_kl(
+            qi, ki, w, q, k, lse, select, lse_i, interpret=interpret), select
+
+    def reference(*operands):
+        return sparse_select.sparse_attention_reference(
+            *(a.astype(jnp.float32) for a in operands), topk)
+
+    def scalar(fn):
+        def f(*operands):
+            out, kl, _ = fn(*operands)
+            return (out.astype(jnp.float32) * weight).sum() + 3.0 * kl
+        return jax.jit(jax.grad(f, argnums=range(6)))
+
+    operands = (q, k, v, qi, ki, w)
+    if not interpret:
+        names = kernels_in(jax.jit(jax.grad(
+            lambda *a: program(*a)[0].astype(jnp.float32).sum()
+            + program(*a)[1], argnums=range(6))).lower(*operands).as_text())
+        check(names == ["flash_select_dkdv", "flash_select_dq",
+                        "flash_select_fwd", "index_kl", "index_scores"],
+              f"sparse attention lowered to the kernels {names}")
+    got_out, got_kl, got_map = jax.jit(program)(*operands)
+    got_grads = scalar(program)(*operands)
+    with jax.default_matmul_precision("highest"):
+        want_out, want_kl, want_map = jax.jit(reference)(*operands)
+        want_grads = scalar(reference)(*operands)
+    differing = int((got_map != want_map).sum())
+    check(differing == 0, f"{differing} of {int(want_map.sum())} selected "
+          "pairs are not the float32 top-k's")
+    kl_err = abs(float(got_kl) - float(want_kl)) / abs(float(want_kl))
+    errs = {"out": _rel_err(got_out, want_out), "index_kl": kl_err}
+    for name, g, r in zip(("dq", "dk", "dv", "dqI", "dkI", "dw"), got_grads,
+                          want_grads):
+        errs[name] = _rel_err(g, r)
+    for name, err in errs.items():
+        check(err <= SELECT_TOL,
+              f"sparse attention differs from its dense float32 form in "
+              f"{name} by {err:.3g} (bound {SELECT_TOL})")
+    return {"shape": [batch, seq, heads, kv_heads, head_dim, index_heads,
+                      index_dim, topk], "interpret": interpret,
+            "selected_pairs": int(want_map.sum()),
+            "pairs_differing": differing,
+            **{name: round(err, 5) for name, err in errs.items()}}
 
 
 def delta_reference_phase(*, batch: int, seq: int, heads: int, key_dim: int,
@@ -1067,6 +1155,8 @@ def main(argv=None) -> int:
             **DELTA_REFERENCE, seed=args.seed))
         emit("experts_reference", **experts_reference_phase(
             **EXPERTS_REFERENCE, seed=args.seed))
+        emit("select_reference", **select_reference_phase(
+            **SELECT_REFERENCE, seed=args.seed))
         emit("transformer_lm", **transformer_phase(
             mesh, events, **ONE_CHIP_LM, seed=args.seed))
         emit("resnet50", **resnet_phase(

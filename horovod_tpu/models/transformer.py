@@ -35,11 +35,15 @@ the whole q and k vectors before the split into heads) and
 dropped token).  :func:`OLMoELM` is OLMoE-1B-7B's setting of them.
 
 ``pattern`` replaces the stack of blocks by a hybrid one, a letter a
-layer.  ``M``, ``*`` and ``E`` are ONE sub-layer behind a pre-norm
+layer.  ``M``, ``*``, ``S`` and ``E`` are ONE sub-layer behind a pre-norm
 residual (``x + f(norm(x))``): ``M`` a Mamba-2 mixer
 (:class:`~horovod_tpu.models.ssm.Mamba2Mixer`), ``*`` grouped-query
-attention (:class:`GroupedQueryAttention`), ``E`` a ``DroplessMoE``.
-:func:`NemotronHLM` is the Nemotron-H setting.  One tower, causal,
+attention (:class:`GroupedQueryAttention`) with no positions and no
+norm, ``S`` grouped-query attention with per-head QK-norm, rotary
+positions and the keys a learned indexer selects
+(:mod:`horovod_tpu.ops.sparse_select`), ``E`` a ``DroplessMoE``.
+:func:`NemotronHLM` is the Nemotron-H setting, :func:`KeyeLM` the
+Keye-VL-2.0 language tower's.  One tower, causal,
 trained under next-token cross-entropy: no denoising objective and no
 conditioning between towers.  ``L`` and ``F`` are TWO sub-layers, a
 mixer and then a dense SwiGLU MLP (:class:`SwiGLU`), each with the norm
@@ -62,7 +66,7 @@ from jax import lax
 from horovod_tpu.ops.flash_attention import (
     auto_block, flash_attention_auto, flash_qkv_proj)
 from horovod_tpu.parallel.mesh import RANKS_AXIS
-from horovod_tpu.parallel.moe import DroplessMoE
+from horovod_tpu.parallel.moe import DroplessMoE, note_layer
 from horovod_tpu.parallel.ring_attention import (
     full_attention, ring_attention, zigzag_shard_positions)
 from horovod_tpu.parallel.ulysses import ulysses_attention
@@ -182,16 +186,37 @@ class Attention(nn.Module):
 class GroupedQueryAttention(nn.Module):
     """Causal attention of ``num_heads`` query heads over ``kv_heads``
     key-value heads of ``head_dim`` (query head ``h`` reads KV head
-    ``h // (num_heads / kv_heads)``), no bias, no position encoding; the
-    heads' total width need not be the model's.  Parameters ``q``, ``kv``
-    (keys | values) and ``proj``.  ``attn="flash"`` reads the grouped keys
-    and values in place (:func:`~horovod_tpu.ops.flash_attention.
-    flash_attention`); ``"full"`` repeats them for the dense oracle."""
+    ``h // (num_heads / kv_heads)``), no bias; the heads' total width need
+    not be the model's.  Parameters ``q``, ``kv`` (keys | values) and
+    ``proj``.  ``attn="flash"`` reads the grouped keys and values in place
+    (:func:`~horovod_tpu.ops.flash_attention.flash_attention`); ``"full"``
+    repeats them for the dense oracle.
+
+    As it stands: no position encoding, no norm.  ``qk_norm``: RMSNorm
+    over each head's ``head_dim`` channels of q and of k (learned scales
+    ``q_norm``, ``k_norm``, epsilon ``norm_eps``), before the positions.
+    ``rope_theta``: rotary positions on q and k with this base.
+
+    ``indexer`` (``dict(num_heads=, head_dim=, topk=)``, optionally
+    ``tile=``): learned sparse attention (:mod:`horovod_tpu.ops.
+    sparse_select`).  From the layer's input, DETACHED, an indexer of that
+    many heads over one key head (parameters ``index_q``, ``index_k``,
+    ``index_w``; rotary positions on its q and k where the layer has them)
+    scores every causal key of a query; the ``topk`` best are the only keys
+    the query's heads read; and ``L_I``, the KL term that is the indexer's
+    only gradient, is sown as the intermediate ``index_kl``
+    (:func:`index_losses` sums a model's), beside ``selected_per_query``
+    and ``live_tiles``.  Trace scopes ``index/project``, ``index/scores``,
+    ``index/topk``, ``index/select``, ``index/kl`` and ``flash_select``."""
     num_heads: int
     kv_heads: int
     head_dim: int
     attn: str = "flash"
     dtype: Any = jnp.bfloat16
+    qk_norm: bool = False
+    norm_eps: float = 1e-6
+    rope_theta: Optional[float] = None
+    indexer: Any = None
 
     @nn.compact
     def __call__(self, x):
@@ -205,6 +230,15 @@ class GroupedQueryAttention(nn.Module):
         q = dense(H * D, "q")(x).reshape(B, T, H, D)
         k, v = jnp.split(dense(2 * Hkv * D, "kv")(x), 2, axis=-1)
         k, v = k.reshape(B, T, Hkv, D), v.reshape(B, T, Hkv, D)
+        if self.qk_norm:
+            q = _norm("rms", self.norm_eps, self.dtype, "q_norm")(q)
+            k = _norm("rms", self.norm_eps, self.dtype, "k_norm")(k)
+        if self.rope_theta is not None:
+            q = apply_rotary(q, jnp.arange(T), self.rope_theta)
+            k = apply_rotary(k, jnp.arange(T), self.rope_theta)
+        if self.indexer is not None:
+            out = self._selected(x, q, k, v, dense)
+            return dense(C, "proj")(out.reshape(B, T, H * D))
         if self.attn == "flash":
             out = flash_attention_auto(q, k, v, causal=True)
         elif self.attn == "full":
@@ -214,6 +248,66 @@ class GroupedQueryAttention(nn.Module):
             raise ValueError("grouped-query attention runs attn='flash' or "
                              f"'full', not {self.attn!r}")
         return dense(C, "proj")(out.reshape(B, T, H * D))
+
+    def _selected(self, x, q, k, v, dense):
+        """The heads' output over the keys the indexer selects, with
+        ``L_I`` and the selection's counters sown."""
+        from horovod_tpu.ops import sparse_select
+
+        if self.attn not in ("flash", "full"):
+            raise ValueError("grouped-query attention runs attn='flash' or "
+                             f"'full', not {self.attn!r}")
+        B, T, _ = x.shape
+        HI, DI, topk = (self.indexer[key] for key in
+                        ("num_heads", "head_dim", "topk"))
+        with jax.named_scope("index/project"):
+            xi = lax.stop_gradient(x)
+            qi = dense(HI * DI, "index_q")(xi).reshape(B, T, HI, DI)
+            ki = dense(DI, "index_k")(xi).reshape(B, T, 1, DI)
+            w = dense(HI, "index_w")(xi)
+            if self.rope_theta is not None:
+                qi = apply_rotary(qi, jnp.arange(T), self.rope_theta)
+                ki = apply_rotary(ki, jnp.arange(T), self.rope_theta)
+            ki = ki[:, :, 0]
+        if self.attn == "full":
+            out, kl, select = sparse_select.sparse_attention_reference(
+                q, k, v, qi, ki, w, topk)
+        else:
+            interpret = jax.default_backend() != "tpu"
+            tile = ({"tile": self.indexer["tile"]}
+                    if "tile" in self.indexer else {})
+            with jax.named_scope("index"):
+                select, lse_i = sparse_select.index_select(
+                    qi, ki, w, topk, interpret=interpret, **tile)
+            out, lse = flash_attention_auto(q, k, v, causal=True,
+                                            select=select)
+            with jax.named_scope("index"):
+                kl = sparse_select.index_kl(qi, ki, w, q, k, lse, select,
+                                            lse_i, interpret=interpret)
+        with jax.named_scope("index/counters"):
+            per_query, live = sparse_select.selection_counters(
+                select, auto_block(T) or T)
+        self.sow("intermediates", "index_kl", kl)
+        self.sow("intermediates", "selected_per_query", per_query)
+        self.sow("intermediates", "live_tiles", live)
+        self.sow("intermediates", "select", select)
+        pairs = sum(min(t + 1, topk) for t in range(T))
+        note_layer(self.path, {
+            "attn.causal_pairs": B * T * (T + 1) // 2,
+            "attn.selected_pairs": B * pairs,
+            "attn.index_flops": B * 2 * HI * DI * T * (T + 1) // 2,
+            "attn.select_bytes": B * T * T})
+        return out
+
+
+def index_losses(intermediates):
+    """``L_I`` summed over every sparse-attention layer that sowed it into
+    ``intermediates`` (what ``model.apply(..., mutable=["intermediates"])``
+    returns under that key)."""
+    return sum(value for path, value
+               in jax.tree_util.tree_leaves_with_path(intermediates)
+               if any(getattr(key, "key", None) == "index_kl"
+                      for key in path))
 
 
 class SwiGLU(nn.Module):
@@ -235,8 +329,8 @@ class SwiGLU(nn.Module):
 
 class PatternLayer(nn.Module):
     """One layer of a pattern stack.  ``"M"`` (submodule ``ssm``), ``"*"``
-    (``attn``) and ``"E"`` (``moe``): ``x + f(norm(x))`` with ``f`` the one
-    sub-layer ``kind`` names.  ``"L"`` (``lin``) and ``"F"`` (``attn``):
+    and ``"S"`` (``attn``) and ``"E"`` (``moe``): ``x + f(norm(x))`` with
+    ``f`` the one sub-layer ``kind`` names.  ``"L"`` (``lin``) and ``"F"`` (``attn``):
     ``h = x + mixer_norm(f(x))``, then ``h + mlp_norm(mlp(h))`` with
     ``mlp`` a :class:`SwiGLU` ``mlp_hidden`` wide.  ``sub`` holds the
     fields of ``f``."""
@@ -272,12 +366,16 @@ class PatternLayer(nn.Module):
         elif self.kind == "*":
             y = GroupedQueryAttention(**self.sub, dtype=self.dtype,
                                       name="attn")(h)
+        elif self.kind == "S":
+            y = GroupedQueryAttention(**self.sub, dtype=self.dtype,
+                                      norm_eps=self.norm_eps,
+                                      name="attn")(h)
         elif self.kind == "E":
             y, _, _ = DroplessMoE(**self.sub, dtype=self.dtype,
                                   name="moe")(h)
         else:
             raise ValueError(f"unknown layer {self.kind!r} in a pattern: "
-                             "'M', '*', 'E', 'L' or 'F'")
+                             "'M', '*', 'S', 'E', 'L' or 'F'")
         return x + y
 
 
@@ -415,9 +513,12 @@ class TransformerLM(nn.Module):
     moe_hidden: int = 0
     # A hybrid stack (module docstring): one letter a layer, ``depth`` is
     # then the pattern's length whatever it says, and ``pos`` must be
-    # "none" (the mixers carry the order).  ``ssm``: the fields of
-    # Mamba2Mixer; ``*`` layers have num_heads query heads over kv_heads
-    # KV heads of head_dim; ``moe``: DroplessMoE's further fields (router,
+    # "none" (the mixers carry the order) or "rotary" (the ``S`` layers
+    # turn their q and k by rope_theta; no other layer has positions).
+    # ``ssm``: the fields of Mamba2Mixer; ``*`` and ``S`` layers have
+    # num_heads query heads over kv_heads KV heads of head_dim, ``S`` with
+    # the model's qk_norm per head and the ``indexer``
+    # (GroupedQueryAttention's field); ``moe``: DroplessMoE's further fields (router,
     # renormalize, gate_scale, activation, shared_hidden, held); ``lin``:
     # the fields of GatedDeltaNet; ``F`` layers have num_heads heads of
     # dim / num_heads with the model's qk_norm; ``L`` and ``F`` layers end
@@ -429,6 +530,7 @@ class TransformerLM(nn.Module):
     moe: Any = None
     lin: Any = None
     mlp_hidden: int = 0
+    indexer: Any = None
 
     @nn.compact
     def __call__(self, tokens, return_hidden=False):
@@ -444,7 +546,7 @@ class TransformerLM(nn.Module):
                 f"computes the full sequence locally); got {self.attn!r}")
         if self.tp_axis and (self.moe_experts or self.qk_norm
                              or self.pos != "learned" or self.pattern
-                             or self.moe):
+                             or self.moe or self.indexer):
             raise ValueError("tp_axis runs the GPT-2 block only: no "
                              "experts (whole or a held share), QK-norm, "
                              "rotary positions or pattern stack")
@@ -453,9 +555,9 @@ class TransformerLM(nn.Module):
         if self.pattern is not None:
             return self._pattern_stack(tokens, return_hidden)
         if (self.pos == "none" or self.moe or self.ssm or self.lin
-                or self.mlp_hidden):
-            raise ValueError("pos='none', ssm=, moe=, lin= and mlp_hidden= "
-                             "belong to a pattern stack; the block stack "
+                or self.mlp_hidden or self.indexer):
+            raise ValueError("pos='none', ssm=, moe=, lin=, indexer= and "
+                             "mlp_hidden= belong to a pattern stack; the block stack "
                              "takes learned or rotary positions and "
                              "moe_experts")
         rotary = self.pos == "rotary"
@@ -490,14 +592,21 @@ class TransformerLM(nn.Module):
                         param_dtype=jnp.float32, name="head")(x)
 
     def _pattern_stack(self, tokens, return_hidden):
-        if self.attn not in ("full", "flash") or self.pos != "none":
+        if self.attn not in ("full", "flash") or self.pos not in (
+                ("none", "rotary") if "S" in self.pattern else ("none",)):
             raise ValueError("a pattern stack runs whole sequences "
-                             "(attn='full' or 'flash') with pos='none'; got "
+                             "(attn='full' or 'flash') with pos='none', or "
+                             "'rotary' for its 'S' layers; got "
                              f"attn={self.attn!r}, pos={self.pos!r}")
         subs = {
             "M": dict(self.ssm or {}),
             "*": dict(num_heads=self.num_heads, kv_heads=self.kv_heads,
                       head_dim=self.head_dim, attn=self.attn),
+            "S": dict(num_heads=self.num_heads, kv_heads=self.kv_heads,
+                      head_dim=self.head_dim, attn=self.attn,
+                      qk_norm=self.qk_norm, indexer=self.indexer,
+                      rope_theta=(self.rope_theta if self.pos == "rotary"
+                                  else None)),
             "E": dict(num_experts=self.moe_experts, hidden=self.moe_hidden,
                       top_k=self.moe_top_k, **dict(self.moe or {})),
             "L": dict(self.lin or {}),
@@ -547,6 +656,45 @@ def NemotronHLM(**overrides) -> TransformerLM:
         moe_experts=128, moe_top_k=6, moe_hidden=1856,
         moe=dict(router="sigmoid", renormalize=True, gate_scale=2.5,
                  activation="relu2", shared_hidden=3712))
+    fields.update(overrides)
+    return TransformerLM(**fields)
+
+
+def KeyeLM(**overrides) -> TransformerLM:
+    """The language tower that ``Kwai-Keye/Keye-VL-2.0-30B-A3B``'s
+    config.json describes (``model_type`` ``KeyeVL2``), as a
+    :class:`TransformerLM` with a ``pattern``: 48 layers ``SE`` at d 2048,
+    pre-norm RMSNorm eps 1e-6; ``S`` attention of 32 query heads over 4 KV
+    heads of 128, RMSNorm over each head of q and of k, rotary positions
+    (theta 1e7) and ``sa_config``'s indexer — 16 heads of 64 over one key
+    head choose the 2,048 causal keys a query's heads read
+    (DeepSeek Sparse Attention; :mod:`horovod_tpu.ops.sparse_select`);
+    ``E`` 128 SwiGLU experts 768 wide, top-8 by softmax renormalised, no
+    shared expert; vocab 151936, untied head.
+
+    What config.json does not say is the family's convention: per-head
+    QK-norm (the key set is the Qwen3-MoE decoder's, whose layer has it),
+    rotary positions on the indexer's q and k over all 64 channels with
+    the layer's theta, the score's factor ``(16 · 64)^-1/2``,
+    ``q_chunk_size`` 512 as the tile scores and top-k are computed in.
+    Text tokens only: the three sections of ``mrope_section`` carry one
+    position, so multi-axis rotary IS one-axis rotary here.  What is NOT
+    here: the vision tower (the config's language settings are all the
+    catalog holds) and with it any image token.
+
+    Trained like :func:`OLMoELM` through ``make_train_step`` and
+    ``fused_softmax_xent``, the loss ``CE + index_losses(intermediates)``:
+    the indexer's parameters move under its KL term alone and everything
+    else under the cross-entropy alone.  ``overrides`` replace any field:
+    a cut takes the first letters of the pattern, and ``moe={..., "held":
+    (first, count)}`` keeps one chip's share of every layer's experts."""
+    fields = dict(
+        vocab=151936, dim=2048, num_heads=32, kv_heads=4, head_dim=128,
+        max_len=262144, norm="rms", norm_eps=1e-6, pos="rotary",
+        rope_theta=1e7, qk_norm=True, pattern="SE" * 48,
+        indexer=dict(num_heads=16, head_dim=64, topk=2048, tile=512),
+        moe_experts=128, moe_top_k=8, moe_hidden=768,
+        moe=dict(router="softmax", renormalize=True, activation="swiglu"))
     fields.update(overrides)
     return TransformerLM(**fields)
 

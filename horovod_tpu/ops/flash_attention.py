@@ -212,10 +212,22 @@ def _live_block(qi, kj, block_q, block_k, causal, seq_len):
     return live
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
-                m_scr, l_scr, acc_scr, *, scale, causal, block_q, block_k,
-                seq_len):
+def _chosen(ok, sel_ref):
+    """``ok`` (a block pair's validity mask, or None) ANDed with the
+    pair's tile of a selection map (int8, nonzero where the query reads
+    the key); ``ok`` itself where the kernel carries no selection."""
+    if sel_ref is None:
+        return ok
+    chosen = sel_ref[0].astype(jnp.int32) != 0
+    return chosen if ok is None else jnp.logical_and(ok, chosen)
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, *refs, scale, causal, block_q, block_k,
+                seq_len, select=False):
     # Grid (B, H, T/block_q, T/block_k): the head is its own grid axis.
+    # ``select``: a (block_q, block_k) tile of a selection map follows v.
+    sel_ref = refs[0] if select else None
+    o_ref, lse_ref, m_scr, l_scr, acc_scr = refs[int(select):]
     qi = pl.program_id(2)
     kj = pl.program_id(3)
     nk = pl.num_programs(3)
@@ -235,8 +247,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale   # (BQ, BK)
-        ok = (_block_mask(qi, kj, block_q, block_k, causal, seq_len)
-              if masked else None)
+        ok = _chosen(_block_mask(qi, kj, block_q, block_k, causal, seq_len)
+                     if masked else None, sel_ref)
         if ok is not None:
             s = jnp.where(ok, s, _NEG_BIG)
         m_prev = m_scr[...]                            # (BQ, 128)
@@ -421,8 +433,41 @@ def _kv_head(kv_rep: int):
     return (lambda h: h) if kv_rep == 1 else (lambda h: h // kv_rep)
 
 
+# A selection map (``flash_attention``'s ``select``) is one more operand
+# of the grid forward and of the per-head backward pair: an int8 (B, T, T)
+# array read a (block_q, block_k) tile a grid step.  Without one each of
+# these gives nothing, and the call is the one it was.
+_SELECT_VMEM_MB = 32
+
+
+def _select_kw(select) -> dict:
+    return {} if select is None else {"select": True}
+
+
+def _select_spec(select, block_q, block_k, index_map) -> list:
+    return ([] if select is None
+            else [pl.BlockSpec((1, block_q, block_k), index_map)])
+
+
+def _select_operand(select) -> tuple:
+    return () if select is None else (select,)
+
+
+def _select_name(select, which: str) -> dict:
+    return {} if select is None else {"name": f"flash_select_{which}"}
+
+
+def _select_vmem(select) -> dict:
+    """The tile of the map and the mask made of it are held beside the
+    float32 score tiles: at 1024² blocks that is over Mosaic's default
+    16 MB, so the budget the grouped pair has where the device backs it."""
+    return ({} if select is None or not _vmem_headroom_ok()
+            else _vmem_limit(_SELECT_VMEM_MB))
+
+
 def _fwd_packed(q, k, v, H, D, plan, *, scale, causal, block_q, block_k,
-                interpret, seq_len=None, head_base=(0, 0, 0), kv_rep=1):
+                interpret, seq_len=None, head_base=(0, 0, 0), kv_rep=1,
+                select=None):
     """Forward on head-packed (B, T, C) views (C = H*D): the head is a
     grid axis and every BlockSpec offsets its last dim by ``h*D``, so no
     (B, T, H, D) -> (B*H, T, D) transpose copy ever materializes in HBM
@@ -432,7 +477,8 @@ def _fwd_packed(q, k, v, H, D, plan, *, scale, causal, block_q, block_k,
     projection (so the qkv split never copies either).  ``plan`` is
     :func:`_plan`'s: which of the three forms runs.  ``kv_rep`` query
     heads read each KV head (``k``, ``v`` hold ``H // kv_rep`` heads).
-    lse comes back as (B, H, T)."""
+    ``select``: a (B, T, T) int8 selection map (the grid form alone
+    carries one).  lse comes back as (B, H, T)."""
     B, T, _ = q.shape
     nq = T // block_q
     nk = T // block_k
@@ -503,7 +549,7 @@ def _fwd_packed(q, k, v, H, D, plan, *, scale, causal, block_q, block_k,
     grid = (B, H, nq, nk)
     kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
                                block_q=block_q, block_k=block_k,
-                               seq_len=seq_len)
+                               seq_len=seq_len, **_select_kw(select))
     out, lse = pl.pallas_call(
         kernel,
         grid=grid,
@@ -514,7 +560,8 @@ def _fwd_packed(q, k, v, H, D, plan, *, scale, causal, block_q, block_k,
                          lambda b, h, i, j: (b, j, kvh(h) + ok_)),
             pl.BlockSpec((1, block_k, D),
                          lambda b, h, i, j: (b, j, kvh(h) + ov)),
-        ],
+        ] + _select_spec(select, block_q, block_k,
+                         lambda b, h, i, j: (b, i, j)),
         out_specs=[
             pl.BlockSpec((1, block_q, D), lambda b, h, i, j: (b, i, h)),
             pl.BlockSpec((1, 1, block_q, 8),
@@ -531,21 +578,26 @@ def _fwd_packed(q, k, v, H, D, plan, *, scale, causal, block_q, block_k,
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary")),
+                                 "arbitrary"),
+            **_select_vmem(select)),
         interpret=interpret,
-    )(q, k, v)
+        **_select_name(select, "fwd"),
+    )(q, k, v, *_select_operand(select))
     return out, lse[..., 0]
 
 
-def _dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dta_ref,
-                 dk_ref, dv_ref, dk_scr, dv_scr, *,
-                 scale, causal, block_q, block_k, seq_len, kv_rep=1):
+def _dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dta_ref, *refs,
+                 scale, causal, block_q, block_k, seq_len, kv_rep=1,
+                 select=False):
     """Accumulate dk/dv for one KV block while Q blocks stream through
     (grid innermost axis).  The flash-backward identities:
     p = exp(s - lse);  dv += p^T dO;  dS = p * (dO V^T - delta) * scale;
     dk += dS^T Q.  Where ``kv_rep`` query heads read this KV head, the
     innermost axis runs the Q blocks of one of them after another's, and
-    the sums over them are formed here, in the scratch."""
+    the sums over them are formed here, in the scratch.  ``select``: a
+    tile of a selection map follows the row statistics."""
+    sel_ref = refs[0] if select else None
+    dk_ref, dv_ref, dk_scr, dv_scr = refs[int(select):]
     kj = pl.program_id(2)
     step = pl.program_id(3)
     nq = pl.num_programs(3)
@@ -567,8 +619,8 @@ def _dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dta_ref,
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale    # (BQ, BK)
         p = jnp.exp(s - lse)
-        ok = (_block_mask(qi, kj, block_q, block_k, causal, seq_len)
-              if masked else None)
+        ok = _chosen(_block_mask(qi, kj, block_q, block_k, causal, seq_len)
+                     if masked else None, sel_ref)
         if ok is not None:
             p = jnp.where(ok, p, 0.0)
         # dv += p^T @ dO — p cast to the input dtype so the MXU runs at
@@ -594,11 +646,13 @@ def _dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dta_ref,
         dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
 
 
-def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dta_ref,
-               dq_ref, dq_scr, *, scale, causal, block_q, block_k,
-               seq_len):
+def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dta_ref, *refs,
+               scale, causal, block_q, block_k, seq_len, select=False):
     """Accumulate dq for one Q block while KV blocks stream through:
-    dq += dS @ K with dS = p * (dO V^T - delta) * scale."""
+    dq += dS @ K with dS = p * (dO V^T - delta) * scale.  ``select``: a
+    tile of a selection map follows the row statistics."""
+    sel_ref = refs[0] if select else None
+    dq_ref, dq_scr = refs[int(select):]
     qi = pl.program_id(2)
     kj = pl.program_id(3)
     nk = pl.num_programs(3)
@@ -618,8 +672,8 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dta_ref,
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale
         p = jnp.exp(s - lse)
-        ok = (_block_mask(qi, kj, block_q, block_k, causal, seq_len)
-              if masked else None)
+        ok = _chosen(_block_mask(qi, kj, block_q, block_k, causal, seq_len)
+                     if masked else None, sel_ref)
         if ok is not None:
             p = jnp.where(ok, p, 0.0)
         dp = jax.lax.dot_general(
@@ -905,7 +959,7 @@ def _bwd_pallas_packed_grouped(q, k, v, o, lse, do, H, D, group, *, scale,
 
 def _bwd_pallas_packed(q, k, v, o, lse, do, H, D, plan, *, scale, causal,
                        block_q, block_k, interpret, seq_len=None,
-                       head_base=(0, 0, 0), kv_rep=1):
+                       head_base=(0, 0, 0), kv_rep=1, select=None):
     """Split flash backward on head-packed (B, T, C) views (see
     :func:`_fwd_packed`); ``lse`` arrives as (B, H, T) and ``o``/``do``
     are head-merged (B, T, H*D).  ``plan`` is :func:`_plan`'s: the pair
@@ -963,14 +1017,18 @@ def _bwd_pallas_packed(q, k, v, o, lse, do, H, D, plan, *, scale, causal,
     )
     sem4 = pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "parallel",
-                             "arbitrary"))
+                             "arbitrary"),
+        **_select_vmem(select))
     dk, dv = pl.pallas_call(
         functools.partial(_dkdv_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k,
-                          seq_len=seq_len, kv_rep=kv_rep),
+                          seq_len=seq_len, kv_rep=kv_rep,
+                          **_select_kw(select)),
         grid=(B, H // kv_rep, nk, kv_rep * nq),
         in_specs=[kv_specs["q"], kv_specs["k"], kv_specs["v"],
-                  kv_specs["do"], kv_specs["row8"], kv_specs["row8"]],
+                  kv_specs["do"], kv_specs["row8"], kv_specs["row8"]]
+        + _select_spec(select, block_q, block_k,
+                       lambda b, h, j, i: (b, q_block(i), j)),
         out_specs=[kv_specs["out"], kv_specs["out"]],
         out_shape=[_struct((B, T, C // kv_rep), k.dtype, q, k, v, do),
                    _struct((B, T, C // kv_rep), v.dtype, q, k, v, do)],
@@ -978,7 +1036,8 @@ def _bwd_pallas_packed(q, k, v, o, lse, do, H, D, plan, *, scale, causal,
                         pltpu.VMEM((block_k, D), jnp.float32)],
         compiler_params=sem4,
         interpret=interpret,
-    )(q, k, v, do, lse8, delta8)
+        **_select_name(select, "dkdv"),
+    )(q, k, v, do, lse8, delta8, *_select_operand(select))
 
     q_specs = dict(
         q=pl.BlockSpec((1, block_q, D),
@@ -995,16 +1054,19 @@ def _bwd_pallas_packed(q, k, v, o, lse, do, H, D, plan, *, scale, causal,
     dq, = pl.pallas_call(
         functools.partial(_dq_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k,
-                          seq_len=seq_len),
+                          seq_len=seq_len, **_select_kw(select)),
         grid=(B, H, nq, nk),
         in_specs=[q_specs["q"], q_specs["k"], q_specs["v"],
-                  q_specs["do"], q_specs["row8"], q_specs["row8"]],
+                  q_specs["do"], q_specs["row8"], q_specs["row8"]]
+        + _select_spec(select, block_q, block_k,
+                       lambda b, h, i, j: (b, i, j)),
         out_specs=[q_specs["out"]],
         out_shape=[_struct((B, T, C), q.dtype, q, k, v, do)],
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
         compiler_params=sem4,
         interpret=interpret,
-    )(q, k, v, do, lse8, delta8)
+        **_select_name(select, "dq"),
+    )(q, k, v, do, lse8, delta8, *_select_operand(select))
     return dq, dk, dv
 
 
@@ -1056,7 +1118,7 @@ def _bwd_live_share(T, causal, block_q, block_k, sub) -> float:
 
 def _plan(*, T, D, H, head_base, itemsize, causal, block_q, block_k,
           bwd_block_q, bwd_block_k, interpret, manual_axes,
-          vmem_headroom, kv_rep=1) -> _Plan:
+          vmem_headroom, kv_rep=1, select=False) -> _Plan:
     """Which forward form and which backward pair run, and the VMEM limit
     each is compiled with — the one place that chooses, from what the op
     observes at trace time and nothing else.
@@ -1067,10 +1129,13 @@ def _plan(*, T, D, H, head_base, itemsize, causal, block_q, block_k,
     ``manual_axes``: whether the operands vary over manual mesh axes
     (``shard_map``); ``vmem_headroom``: :func:`_vmem_headroom_ok` —
     whether the device backs a scoped budget above Mosaic's default;
-    ``kv_rep``: query heads a KV head (1: multi-head attention)."""
-    if D % 128:
+    ``kv_rep``: query heads a KV head (1: multi-head attention);
+    ``select``: whether the call carries a selection map."""
+    if D % 128 or select:
+        # A selection map is read a (block_q, block_k) tile a grid step,
+        # which only these two forms' grids have.
         # Heads off the lane width arrive merged into the batch (H is 1,
-        # see flash_attention).  Only these two forms have run on a chip
+        # see flash_attention); only these two forms have run on a chip
         # at such a D.
         return _Plan("grid", 0, 0, "per_head", 0, 0, _bwd_live_share(
             T, causal, bwd_block_q, bwd_block_k, sub=0))
@@ -1123,14 +1188,15 @@ def _plan(*, T, D, H, head_base, itemsize, causal, block_q, block_k,
 
 
 def _plan_for(q, H, D, head_base, causal, block_q, block_k, bwd_block_q,
-              bwd_block_k, interpret, kv_rep=1) -> _Plan:
+              bwd_block_k, interpret, kv_rep=1, select=False) -> _Plan:
     """:func:`_plan` for the operand ``q`` of a custom-VJP rule."""
     return _plan(T=q.shape[1], D=D, H=H, head_base=head_base,
                  itemsize=q.dtype.itemsize, causal=causal, block_q=block_q,
                  block_k=block_k, bwd_block_q=bwd_block_q,
                  bwd_block_k=bwd_block_k, interpret=interpret,
                  manual_axes=bool(jax.typeof(q).vma),
-                 vmem_headroom=_vmem_headroom_ok(), kv_rep=kv_rep)
+                 vmem_headroom=_vmem_headroom_ok(), kv_rep=kv_rep,
+                 select=select)
 
 
 @functools.partial(jax.custom_vjp,
@@ -1169,6 +1235,55 @@ def _flash_packed_bwd(H, scale, causal, block_q, block_k, bwd_block_q,
 
 
 _flash_packed.defvjp(_flash_packed_fwd, _flash_packed_bwd)
+
+
+@functools.partial(jax.custom_vjp,
+                   nondiff_argnums=(4, 5, 6, 7, 8, 9, 10, 11, 12))
+def _flash_packed_select(q, k, v, select, H, scale, causal, block_q, block_k,
+                         bwd_block_q, bwd_block_k, interpret, seq_len):
+    """:func:`_flash_packed` under a selection map, ``(out, lse)``: query
+    ``t`` reads key ``s`` only where ``select[b, t, s]`` is nonzero (and
+    the causal mask and the padding allow it).  ``lse`` (B, H, T), the
+    log-sum-exp of each head's selected scores, is for a reader of the
+    probabilities and carries no gradient."""
+    return _flash_packed_select_fwd(q, k, v, select, H, scale, causal,
+                                    block_q, block_k, bwd_block_q,
+                                    bwd_block_k, interpret, seq_len)[0]
+
+
+def _flash_packed_select_fwd(q, k, v, select, H, scale, causal, block_q,
+                             block_k, bwd_block_q, bwd_block_k, interpret,
+                             seq_len):
+    D = q.shape[2] // H
+    kv_rep = q.shape[2] // k.shape[2]
+    plan = _plan_for(q, H, D, (0, 0, 0), causal, block_q, block_k,
+                     bwd_block_q, bwd_block_k, interpret, kv_rep, select=True)
+    with jax.named_scope("flash_select"):
+        out, lse = _fwd_packed(q, k, v, H, D, plan, scale=scale,
+                               causal=causal, block_q=block_q,
+                               block_k=block_k, interpret=interpret,
+                               seq_len=seq_len, kv_rep=kv_rep, select=select)
+    return (out, lse), (q, k, v, select, out, lse)
+
+
+def _flash_packed_select_bwd(H, scale, causal, block_q, block_k, bwd_block_q,
+                             bwd_block_k, interpret, seq_len, res, cts):
+    q, k, v, select, o, lse = res
+    do, _ = cts
+    D = q.shape[2] // H
+    kv_rep = q.shape[2] // k.shape[2]
+    plan = _plan_for(q, H, D, (0, 0, 0), causal, block_q, block_k,
+                     bwd_block_q, bwd_block_k, interpret, kv_rep, select=True)
+    with jax.named_scope("flash_select"):
+        dq, dk, dv = _bwd_pallas_packed(
+            q, k, v, o, lse, do, H, D, plan, scale=scale, causal=causal,
+            block_q=bwd_block_q, block_k=bwd_block_k, interpret=interpret,
+            seq_len=seq_len, kv_rep=kv_rep, select=select)
+    return dq, dk, dv, None
+
+
+_flash_packed_select.defvjp(_flash_packed_select_fwd,
+                            _flash_packed_select_bwd)
 
 
 # Every layer of a model calls the two functions below with the same
@@ -1386,7 +1501,7 @@ def _resolve_blocks(T: int, fn_name: str, block_q, block_k, bwd_block_q,
 
 
 def flash_attention_auto(q, k, v, *, causal: bool = True,
-                         scale: Optional[float] = None):
+                         scale: Optional[float] = None, select=None):
     """:func:`flash_attention` with automatic block sizing and padding —
     the drop-in local attention kernel for models and for
     ``ulysses_attention(attn_fn=...)``.
@@ -1398,23 +1513,30 @@ def flash_attention_auto(q, k, v, *, causal: bool = True,
     O(T^2) dense buffer ever materializes (VERDICT r2 weak #7 — the old
     dense fallback would OOM at exactly the lengths this kernel exists
     for).  Off-TPU the kernel runs in interpret mode so callers stay
-    hermetic.
+    hermetic.  ``select``: :func:`flash_attention`'s; the result is then
+    ``(out, lse)``.
     """
     T = q.shape[1]
     interpret = jax.default_backend() != "tpu"
     blk = auto_block(T)
+    more = {} if select is None else {"select": select}
     if blk >= 64 or blk == T:
         return flash_attention(q, k, v, causal=causal, scale=scale,
                                block_q=blk, block_k=blk,
-                               interpret=interpret)
+                               interpret=interpret, **more)
     unit = 256 if T > 256 else 8
     T_pad = -(-T // unit) * unit
     pad = [(0, 0), (0, T_pad - T), (0, 0), (0, 0)]
     blk = auto_block(T_pad)   # largest block that tiles the padded length
+    if select is not None:
+        more = {"select": jnp.pad(select, [(0, 0), (0, T_pad - T),
+                                           (0, T_pad - T)])}
     out = flash_attention(
         jnp.pad(q, pad), jnp.pad(k, pad), jnp.pad(v, pad),
         causal=causal, scale=scale, block_q=blk,
-        block_k=blk, interpret=interpret, seq_len=T)
+        block_k=blk, interpret=interpret, seq_len=T, **more)
+    if select is not None:
+        return out[0][:, :T], out[1][:, :, :T]
     return out[:, :T]
 
 
@@ -1437,7 +1559,8 @@ def flash_attention(q, k, v, *, causal: bool = True,
                     bwd_block_q: Optional[int] = None,
                     bwd_block_k: Optional[int] = None,
                     interpret: bool = False,
-                    seq_len: Optional[int] = None):
+                    seq_len: Optional[int] = None,
+                    select=None):
     """Fused flash attention for ``(B, T, H, D)`` inputs (same contract as
     :func:`~horovod_tpu.parallel.ring_attention.full_attention`).  ``k`` and
     ``v`` may hold fewer heads, ``(B, T, Hkv, D)`` with ``Hkv`` dividing
@@ -1455,6 +1578,16 @@ def flash_attention(q, k, v, *, causal: bool = True,
     ``seq_len``: real length when the inputs are zero-padded to a
     tileable ``T`` — positions past it are masked statically in forward
     and backward.  Set ``interpret=True`` to run off-TPU (tests).
+
+    ``select``: a data-dependent selection, ``(B, T, T)`` int8, nonzero
+    where query ``t`` reads key ``s`` — one set a query token, shared by
+    its heads; ANDed with the causal mask and the padding's.  The live set
+    is then no function of the positions alone: the map is an operand of
+    the grid forward and of the per-head backward pair, a ``(block_q,
+    block_k)`` tile a grid step (lane-aligned heads only), and every
+    causal tile is visited.  Every query must select a key.  The result
+    is then ``(out, lse)`` with ``lse`` ``(B, H, T)`` the log-sum-exp of
+    each head's selected scores (it carries no gradient).
     """
     B, T, H, D = q.shape
     Hkv = k.shape[2]
@@ -1481,6 +1614,16 @@ def flash_attention(q, k, v, *, causal: bool = True,
     # Lane-aligned head dims run the kernels directly on (B, T, H*D)
     # views via head-offset BlockSpecs — the reshape is free
     # (contiguous), so no transpose copy ever hits HBM.
+    if select is not None:
+        if D % 128 or select.shape != (B, T, T) or select.dtype != jnp.int8:
+            raise ValueError(
+                f"flash_attention: a selection is an int8 (B, T, T) map "
+                f"over lane-aligned heads; got {select.dtype} "
+                f"{select.shape} for q {q.shape}")
+        out, lse = _flash_packed_select(
+            q.reshape(B, T, H * D), k.reshape(B, T, Hkv * D),
+            v.reshape(B, T, Hkv * D), select, int(H), *static)
+        return out.reshape(B, T, H, D), lse
     if D % 128 == 0:
         out = _flash_packed(q.reshape(B, T, H * D),
                             k.reshape(B, T, Hkv * D),

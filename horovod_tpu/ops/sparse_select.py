@@ -1,0 +1,451 @@
+"""Learned sparse attention's selection: an indexer scores every causal key
+of a query, the ``topk`` best are the only keys its attention heads read,
+and a KL term trains the indexer towards the heads' own probabilities
+(DeepSeek Sparse Attention, DeepSeek-V3.2-Exp report, 2025).
+
+With ``qI`` (B, T, H_I, D_I), ``kI`` (B, T, D_I) — one key head — and
+``w`` (B, T, H_I) the indexer's projections of a token::
+
+    I[t, s] = (H_I · D_I)^-1/2 · Σ_j w[t, j] · relu(qI[t, j] · kI[s])   s <= t
+    S_t     = the min(t + 1, topk) keys s <= t of largest I[t, s]
+              (a tie goes to the lower index, as ``lax.top_k`` breaks it)
+    L_I     = mean_t KL(p[t, ·] ‖ softmax_{s ∈ S_t} I[t, ·]),
+              p[t, s] = (1 / H) Σ_h P[t, h, s]  for s ∈ S_t
+
+with ``P`` the selected attention's probabilities, detached.
+
+Three steps, none of which ever holds a (T, T) float32 array of all heads:
+
+* :func:`index_select` — the scores a band of query rows at a time
+  (:func:`index_scores`, a Pallas kernel: the H_I products of a tile are
+  summed in VMEM and one float32 tile leaves it), the exact top ``topk`` of
+  each row on tiles of ``tile`` rows (:func:`select_rows`: the k-th value
+  and the last tie taken, by bisection — no sort, no approximation), and
+  the selection as the kernels read it: an **int8 (B, T, T) map**, 1 where
+  ``s ∈ S_t`` (``I > τ``, or ``I == τ`` up to the last tie: no scatter).
+  It is **kept for the backward pass** (T² bytes a layer: 256 MiB at
+  T 16,384), because making it again costs the scores and the top-k a
+  second time.  Nothing here is differentiated.
+* the selected attention itself is ``flash_attention(..., select=map)``.
+* :func:`index_kl` — ``L_I`` and, in the same pass, its gradient on the
+  indexer's three projections (:func:`_kl_kernel`): a tile's ``p`` is
+  summed over the heads in VMEM, the tile's scores are made again, and
+  ``softmax_S(I) - p`` goes straight into ``dqI``, ``dkI`` and ``dw``.
+  ``L_I`` moves nothing else: ``q``, ``k`` and the statistics arrive
+  detached.
+
+``sparse_attention_reference`` is the same mathematics in plain
+``jax.numpy`` with dense (T, T) arrays, for the models' ``attn="full"``
+path and the tests.
+"""
+
+from __future__ import annotations
+
+import functools
+import math
+from typing import Optional
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from horovod_tpu.ops.flash_attention import (
+    _struct, _vmem_headroom_ok, _vmem_limit)
+
+# Tiles of the two kernels (queries x keys).  The KL kernel holds a tile's
+# query rows of every attention head and the gradient of every indexer
+# head beside its float32 score tiles: 512² needs some 40 MB of scoped
+# VMEM, which v4 and later back.
+_BLOCK = 512
+_KL_VMEM_MB = 96
+
+
+def _block(T: int, cap: int = _BLOCK) -> int:
+    """The largest divisor of ``T`` up to ``cap`` that Mosaic can tile (a
+    multiple of 128, or ``T`` itself when one block covers it)."""
+    if T <= cap:
+        return T
+    for b in range(cap, 127, -128):
+        if T % b == 0:
+            return b
+    raise ValueError(f"the indexer's kernels need a sequence length with a "
+                     f"divisor that is a multiple of 128 (up to {cap}); "
+                     f"got T={T}")
+
+
+# ----------------------------------------------------------- the scores
+
+
+def _scores_kernel(qi_ref, ki_ref, w_ref, out_ref, *, heads, scale, row0,
+                   block_q, block_k):
+    """One (block_q, block_k) tile of ``I``: the heads' products summed
+    here, -inf above the diagonal.  Grid (B, rows / block_q, keys /
+    block_k); ``row0``: the band's first query row."""
+    i, j = pl.program_id(1), pl.program_id(2)
+    first = row0 + i * block_q
+    live = j * block_k <= first + block_q - 1
+
+    @pl.when(live)
+    def _tile():
+        ki, w = ki_ref[0], w_ref[0]
+        acc = jnp.zeros((block_q, block_k), jnp.float32)
+        for h in range(heads):
+            s = lax.dot_general(qi_ref[0, h], ki, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+            acc += w[:, h:h + 1] * jnp.maximum(s, 0.0)
+        rows = first + lax.broadcasted_iota(jnp.int32, acc.shape, 0)
+        cols = j * block_k + lax.broadcasted_iota(jnp.int32, acc.shape, 1)
+        out_ref[0] = jnp.where(cols <= rows, acc * scale, -jnp.inf)
+
+    @pl.when(jnp.logical_not(live))
+    def _future():
+        out_ref[0] = jnp.full((block_q, block_k), -jnp.inf, jnp.float32)
+
+
+def index_scores(qi, ki, w, *, row0: int = 0, rows: Optional[int] = None,
+                 interpret: bool = False):
+    """``I`` for the query rows ``row0 .. row0 + rows - 1`` against the
+    keys ``0 .. row0 + rows - 1`` (no later key is causal for them):
+    (B, rows, row0 + rows) float32, -inf where ``s > t``.  ``qi``
+    (B, H_I, T, D_I) — heads before rows, as the kernel reads them —,
+    ``ki`` (B, T, D_I), ``w`` (B, T, H_I) float32."""
+    B, HI, T, DI = qi.shape
+    rows = T - row0 if rows is None else rows
+    width = row0 + rows
+    bq, bk = _block(rows), _block(width)
+    if row0 % bq:
+        raise ValueError(f"a band starts on a tile: row0={row0}, tile {bq}")
+    r0 = row0 // bq
+    with jax.named_scope("scores"):
+        return pl.pallas_call(
+            functools.partial(_scores_kernel, heads=HI,
+                              scale=1.0 / math.sqrt(HI * DI), row0=row0,
+                              block_q=bq, block_k=bk),
+            grid=(B, rows // bq, width // bk),
+            in_specs=[
+                pl.BlockSpec((1, HI, bq, DI), lambda b, i, j: (b, 0, i + r0, 0)),
+                pl.BlockSpec((1, bk, DI), lambda b, i, j: (b, j, 0)),
+                pl.BlockSpec((1, bq, HI), lambda b, i, j: (b, i + r0, 0)),
+            ],
+            out_specs=pl.BlockSpec((1, bq, bk), lambda b, i, j: (b, i, j)),
+            out_shape=_struct((B, rows, width), jnp.float32, qi, ki, w),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "parallel")),
+            interpret=interpret,
+            name="index_scores",
+        )(qi, ki, w)
+
+
+# -------------------------------------------------------- the selection
+
+
+def select_rows(scores, topk: int):
+    """The selection of some query rows from their scores (..., rows, W),
+    -inf where a key is not causal: ``(chosen, lse)`` with ``chosen`` bool,
+    True on the ``min(topk, causal keys)`` largest of a row — exactly
+    ``lax.top_k``'s set, a tie to the lower index — and ``lse`` the
+    log-sum-exp of the chosen scores.
+
+    Exact, and no sort: the k-th largest score of a row is found by
+    bisection on the scores' bit patterns (32 counts of ``score >= v``),
+    and the ties at it that still have room by bisection on the key index
+    (``log2 W`` counts).  On a v5e a tile of 512 rows of 16,384 takes
+    0.5 ms for the first where ``lax.top_k`` takes 5.0 (PERF.md section 6,
+    PR 36)."""
+    W = scores.shape[-1]
+    k = min(topk, W)
+    with jax.named_scope("topk"):
+        # Bit patterns that order as the floats do (-0 under +0, as XLA's
+        # sort and top-k have them).
+        bits = lax.bitcast_convert_type(scores, jnp.uint32)
+        key = jnp.where(bits >> 31 == 1, ~bits, bits | jnp.uint32(1 << 31))
+
+        def count(mask):
+            return mask.sum(axis=-1, dtype=jnp.int32)[..., None]
+
+        def value_bit(i, v):
+            higher = v | (jnp.uint32(1) << (31 - i).astype(jnp.uint32))
+            return jnp.where(count(key >= higher) >= k, higher, v)
+
+        # The largest v that k keys reach: the k-th largest key.  (The
+        # loops start from zeros made of their data, so that under
+        # shard_map they vary as it does.)
+        kth = lax.fori_loop(0, 32, value_bit, key[..., :1] & jnp.uint32(0))
+        above, tied = key > kth, key == kth
+        room = k - count(above)
+        cols = lax.broadcasted_iota(jnp.int32, scores.shape, scores.ndim - 1)
+        index_bits = max(1, (W - 1).bit_length())
+
+        def index_bit(i, c):
+            later = c | (jnp.int32(1) << (index_bits - 1 - i))
+            return jnp.where(count(tied & (cols < later)) < room, later, c)
+
+        # The largest c with fewer than ``room`` ties before it: where the
+        # last tie that is taken lies.
+        last_tie = lax.fori_loop(0, index_bits, index_bit, room * 0)
+    with jax.named_scope("select"):
+        chosen = (above | (tied & (cols <= last_tie))) & (scores > -jnp.inf)
+        lse = jax.scipy.special.logsumexp(
+            jnp.where(chosen, scores, -jnp.inf), axis=-1)
+    return chosen, lse
+
+
+def _bands(T: int, tile: int, topk: int) -> int:
+    """How many bands of query rows the scores are made in: a band's rows
+    are scored against the keys up to its last row only, so four bands do
+    10/16 of the square's work and two 3/4.  A band is whole tiles."""
+    for n in (4, 2):
+        if T % (n * tile) == 0 and T // n >= max(topk, tile):
+            return n
+    return 1
+
+
+def index_select(qi, ki, w, topk: int, *, tile: int = _BLOCK,
+                 interpret: bool = False):
+    """``(select, lse)``: the int8 (B, T, T) map of ``S_t`` (1 where query
+    ``t`` reads key ``s``) and the log-sum-exp (B, T) of each query's
+    selected scores.  ``qi`` (B, T, H_I, D_I), ``ki`` (B, T, D_I), ``w``
+    (B, T, H_I).  Scores and top-k run ``tile`` query rows at a time
+    (``tile`` leaves ``S_t`` as it is).  Nothing is differentiated."""
+    qi, ki, w = (lax.stop_gradient(a) for a in (qi, ki, w))
+    B, T, HI, DI = qi.shape
+    tile = min(tile, T)
+    if T % tile:
+        raise ValueError(f"index_select: tile {tile} must divide T={T}")
+    qi = qi.transpose(0, 2, 1, 3)
+    w = w.astype(jnp.float32)
+    bands = _bands(T, tile, topk)
+    rows = T // bands
+    maps, lses = [], []
+    for b in range(bands):
+        width = (b + 1) * rows
+        band = index_scores(qi, ki, w, row0=b * rows, rows=rows,
+                            interpret=interpret)            # (B, rows, width)
+        tiles = band.reshape(B, rows // tile, tile, width).swapaxes(0, 1)
+        chosen, lse = lax.map(lambda s: select_rows(s, topk), tiles)
+        chosen = chosen.swapaxes(0, 1).reshape(B, rows, width)
+        with jax.named_scope("select"):
+            maps.append(jnp.pad(chosen.astype(jnp.int8),
+                                [(0, 0), (0, 0), (0, T - width)]))
+        lses.append(lse.swapaxes(0, 1).reshape(B, rows))
+    with jax.named_scope("select"):
+        return jnp.concatenate(maps, axis=1), jnp.concatenate(lses, axis=1)
+
+
+def selection_counters(select, block: int):
+    """What a selection map says of itself: ``(selected_per_query,
+    live_tiles)`` — the mean ``|S_t|``, and the share of the causal
+    (block, block) tiles that hold a selected pair (what a table of live
+    tiles could skip is the rest)."""
+    B, T, _ = select.shape
+    block = min(block, T)
+    per_query = select.astype(jnp.int32).sum(dtype=jnp.int32) / (B * T)
+    if T % block:
+        return per_query, jnp.float32(1.0)
+    n = T // block
+    any_ = select.reshape(B, n, block, n, block).max(axis=(2, 4)) > 0
+    return per_query, any_.sum() / (B * n * (n + 1) // 2)
+
+
+# ----------------------------------------------------- the indexer's loss
+
+
+def _kl_kernel(q_ref, k_ref, lse_ref, sel_ref, qi_ref, ki_ref, w_ref,
+               lsei_ref, kl_ref, dqi_ref, dw_ref, dki_ref,
+               kl_scr, dqi_scr, dw_scr, *, heads, kv_heads, head_dim,
+               index_heads, scale, index_scale, block_q, block_k):
+    """One (block_q, block_k) tile of the KL pass.  Grid (B, T / block_q,
+    T / block_k), the key tiles innermost: a query tile's ``KL`` rows,
+    ``dqI`` and ``dw`` form in scratch across them; a key tile's ``dkI``
+    leaves as one partial a query tile (summed outside)."""
+    i, j, nk = pl.program_id(1), pl.program_id(2), pl.num_programs(2)
+    D = head_dim
+
+    @pl.when(j == 0)
+    def _init():
+        kl_scr[...] = jnp.zeros_like(kl_scr)
+        dqi_scr[...] = jnp.zeros_like(dqi_scr)
+        dw_scr[...] = jnp.zeros_like(dw_scr)
+
+    live = j * block_k <= (i + 1) * block_q - 1
+
+    @pl.when(live)
+    def _tile():
+        ok = sel_ref[0].astype(jnp.int32) != 0
+        # p: the heads' probabilities of this tile, averaged.
+        k = k_ref[0]
+        lse = lse_ref[0]
+        p = jnp.zeros((block_q, block_k), jnp.float32)
+        for h in range(heads):
+            g = h // (heads // kv_heads)
+            s = lax.dot_general(
+                q_ref[0, :, h * D:(h + 1) * D], k[:, g * D:(g + 1) * D],
+                (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+            p += jnp.exp(s - lse[:, h:h + 1])
+        p = jnp.where(ok, p * (1.0 / heads), 0.0)
+        # The indexer's own distribution over the selected keys.
+        ki, w = ki_ref[0], w_ref[0]
+
+        def product(h):
+            return lax.dot_general(qi_ref[0, h], ki, (((1,), (1,)), ((), ())),
+                                   preferred_element_type=jnp.float32)
+
+        scores = jnp.zeros((block_q, block_k), jnp.float32)
+        for h in range(index_heads):
+            scores += w[:, h:h + 1] * jnp.maximum(product(h), 0.0)
+        log_pi = scores * index_scale - lsei_ref[0]
+        pi = jnp.where(ok, jnp.exp(log_pi), 0.0)
+        kl_scr[...] += jnp.sum(
+            jnp.where(p > 0.0,
+                      p * (jnp.log(jnp.maximum(p, 1e-37)) - log_pi), 0.0),
+            axis=1, keepdims=True)
+        # d KL / d I = softmax_S(I) - p, straight into the projections.
+        g = (pi - p) * index_scale
+        dki = jnp.zeros((block_k, ki.shape[1]), jnp.float32)
+        for h in range(index_heads):
+            s = product(h)
+            dw_scr[h] += jnp.sum(g * jnp.maximum(s, 0.0), axis=1,
+                                 keepdims=True)
+            ds = jnp.where(s > 0.0, g * w[:, h:h + 1], 0.0).astype(ki.dtype)
+            dqi_scr[h] += lax.dot_general(
+                ds, ki, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            dki += lax.dot_general(
+                ds, qi_ref[0, h], (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+        dki_ref[0, 0] = dki
+
+    @pl.when(jnp.logical_not(live))
+    def _future():
+        dki_ref[0, 0] = jnp.zeros(dki_ref.shape[2:], jnp.float32)
+
+    @pl.when(j == nk - 1)
+    def _finalize():
+        kl_ref[0] = kl_scr[...]
+        dqi_ref[0] = dqi_scr[...]
+        dw_ref[0] = dw_scr[...]
+
+
+def _kl_pass(qi, ki, w, q, k, lse, select, lse_i, *, scale, interpret):
+    """``(kl rows (B, T), dqI, dkI, dw)`` for a unit cotangent on the SUM
+    of the rows."""
+    B, T, H, D = q.shape
+    Hkv = k.shape[2]
+    HI, DI = qi.shape[2], qi.shape[3]
+    blk = _block(T)
+    n = T // blk
+    qi_t = qi.transpose(0, 2, 1, 3)                          # (B, HI, T, DI)
+    vmem = (_vmem_limit(_KL_VMEM_MB) if _vmem_headroom_ok() else {})
+    kl, dqi, dw, dki = pl.pallas_call(
+        functools.partial(
+            _kl_kernel, heads=H, kv_heads=Hkv, head_dim=D, index_heads=HI,
+            scale=scale, index_scale=1.0 / math.sqrt(HI * DI), block_q=blk,
+            block_k=blk),
+        grid=(B, n, n),
+        in_specs=[
+            pl.BlockSpec((1, blk, H * D), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, blk, Hkv * D), lambda b, i, j: (b, j, 0)),
+            pl.BlockSpec((1, blk, H), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, blk, blk), lambda b, i, j: (b, i, j)),
+            pl.BlockSpec((1, HI, blk, DI), lambda b, i, j: (b, 0, i, 0)),
+            pl.BlockSpec((1, blk, DI), lambda b, i, j: (b, j, 0)),
+            pl.BlockSpec((1, blk, HI), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, blk, 1), lambda b, i, j: (b, i, 0)),
+        ],
+        out_specs=[
+            pl.BlockSpec((1, blk, 1), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, HI, blk, DI), lambda b, i, j: (b, 0, i, 0)),
+            pl.BlockSpec((1, HI, blk, 1), lambda b, i, j: (b, 0, i, 0)),
+            pl.BlockSpec((1, 1, blk, DI), lambda b, i, j: (b, i, j, 0)),
+        ],
+        out_shape=[
+            _struct((B, T, 1), jnp.float32, q, qi),
+            _struct((B, HI, T, DI), jnp.float32, q, qi),
+            _struct((B, HI, T, 1), jnp.float32, q, qi),
+            _struct((B, n, T, DI), jnp.float32, q, qi),
+        ],
+        scratch_shapes=[pltpu.VMEM((blk, 1), jnp.float32),
+                        pltpu.VMEM((HI, blk, DI), jnp.float32),
+                        pltpu.VMEM((HI, blk, 1), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            **vmem),
+        interpret=interpret,
+        name="index_kl",
+    )(q.reshape(B, T, H * D), k.reshape(B, T, Hkv * D),
+      lse.transpose(0, 2, 1), select, qi_t, ki, w.astype(jnp.float32),
+      lse_i[..., None])
+    return (kl[..., 0], dqi.transpose(0, 2, 1, 3), dki.sum(axis=1),
+            dw[..., 0].transpose(0, 2, 1))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9))
+def _index_kl(qi, ki, w, q, k, lse, select, lse_i, scale, interpret):
+    return _index_kl_fwd(qi, ki, w, q, k, lse, select, lse_i, scale,
+                         interpret)[0]
+
+
+def _index_kl_fwd(qi, ki, w, q, k, lse, select, lse_i, scale, interpret):
+    kl, dqi, dki, dw = _kl_pass(qi, ki, w, q, k, lse, select, lse_i,
+                                scale=scale, interpret=interpret)
+    n = kl.size
+    return kl.mean(), (dqi.astype(qi.dtype) / n, dki.astype(ki.dtype) / n,
+                       dw.astype(w.dtype) / n)
+
+
+def _index_kl_bwd(scale, interpret, grads, g):
+    # Nothing reaches q, k or the statistics: L_I moves the indexer alone.
+    return (*((g * d).astype(d.dtype) for d in grads),
+            None, None, None, None, None)
+
+
+_index_kl.defvjp(_index_kl_fwd, _index_kl_bwd)
+
+
+def index_kl(qi, ki, w, q, k, lse, select, lse_i, *,
+             scale: Optional[float] = None, interpret: bool = False):
+    """``L_I``, the mean over the B·T queries of ``KL(p ‖ softmax_S(I))``.
+    ``qi``, ``ki``, ``w``: the indexer's projections, the only arguments a
+    gradient reaches (made in the same pass, kept for the backward one);
+    ``q`` (B, T, H, D), ``k`` (B, T, H_kv, D) and ``lse`` (B, H, T): the
+    selected attention's rotated queries, keys and log-sum-exps, read
+    detached; ``select``, ``lse_i``: :func:`index_select`'s."""
+    if scale is None:
+        scale = 1.0 / math.sqrt(q.shape[-1])
+    q, k, lse, lse_i = (lax.stop_gradient(a) for a in (q, k, lse, lse_i))
+    with jax.named_scope("kl"):
+        return _index_kl(qi, ki, w, q, k, lse, select, lse_i, float(scale),
+                         bool(interpret))
+
+
+# ------------------------------------------------------- plain reference
+
+
+def sparse_attention_reference(q, k, v, qi, ki, w, topk: int):
+    """``(out, L_I, select)`` with dense (T, T) arrays in plain
+    ``jax.numpy``: the selected attention of ``q`` (B, T, H, D) over ``k``,
+    ``v`` (B, T, H_kv, D) and the indexer's loss.  The indexer's inputs are
+    used as they come (the caller detaches what it must)."""
+    B, T, H, D = q.shape
+    rep = H // k.shape[2]
+    HI, DI = qi.shape[2], qi.shape[3]
+    causal = jnp.tril(jnp.ones((T, T), bool))
+    products = jnp.einsum("bthd,bsd->bhts", qi.astype(jnp.float32),
+                          ki.astype(jnp.float32))
+    scores = jnp.einsum("bth,bhts->bts", w.astype(jnp.float32),
+                        jax.nn.relu(products)) / math.sqrt(HI * DI)
+    scores = jnp.where(causal, scores, -jnp.inf)
+    chosen, lse_i = select_rows(lax.stop_gradient(scores), topk)
+    s = jnp.einsum("bthd,bshd->bhts", q, jnp.repeat(k, rep, axis=2),
+                   preferred_element_type=jnp.float32) / math.sqrt(D)
+    probs = jax.nn.softmax(jnp.where(chosen[:, None], s, -jnp.inf), axis=-1)
+    out = jnp.einsum("bhts,bshd->bthd", probs.astype(v.dtype),
+                     jnp.repeat(v, rep, axis=2))
+    p = lax.stop_gradient(probs.mean(axis=1))
+    log_pi = jax.nn.log_softmax(jnp.where(chosen, scores, -jnp.inf), axis=-1)
+    kl = jnp.where(p > 0, p * (jnp.log(jnp.maximum(p, 1e-37))
+                               - jnp.where(chosen, log_pi, 0.0)), 0.0)
+    return out, kl.sum(axis=-1).mean(), chosen.astype(jnp.int8)

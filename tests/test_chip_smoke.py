@@ -127,6 +127,20 @@ def test_experts_reference_phase(smoke):
                                       seed=0)
 
 
+def test_select_reference_phase(smoke):
+    """Learned sparse attention's three steps (interpreted here) against
+    their dense float32 form: the same keys to the last one, the output,
+    ``L_I`` and all six gradients."""
+    out = smoke.select_reference_phase(batch=1, seq=128, heads=2, kv_heads=1,
+                                       head_dim=128, index_heads=2,
+                                       index_dim=64, topk=32, seed=0)
+    assert out["interpret"] and out["pairs_differing"] == 0
+    assert out["selected_pairs"] == sum(min(t + 1, 32) for t in range(128))
+    assert {"out", "index_kl", "dq", "dk", "dv", "dqI", "dkI", "dw"} < set(
+        out)
+    assert smoke.SELECT_REFERENCE["topk"] < smoke.SELECT_REFERENCE["seq"]
+
+
 def test_delta_reference_phase(smoke):
     """The chunked delta rule against its recurrence, and the
     ``delta_plan`` line: one form, plain XLA, at the chunk it was given."""

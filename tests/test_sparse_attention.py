@@ -1,0 +1,288 @@
+"""Learned sparse attention's pieces (``ops/sparse_select.py`` and the
+selection operand of ``ops/flash_attention.py``), interpreted on the CPU:
+
+* the flash kernels under a selection map against a dense masked softmax,
+  forward and gradients — grouped 8Q:1KV, several blocks, a length padded
+  to its blocks — and, under a map of ones, the causal kernels' without
+  one;
+* the exact top-k against a sort, ties included, and the same ``S_t``
+  whatever the tile;
+* ``L_I`` and its gradient against the dense reference, and that it moves
+  the indexer's projections alone.
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from horovod_tpu.ops import flash_attention as fa
+from horovod_tpu.ops import sparse_select as ss
+from horovod_tpu.ops.flash_attention import (
+    flash_attention, flash_attention_auto)
+
+
+def rel(got, want):
+    return float(jnp.linalg.norm(got - want)
+                 / jnp.maximum(jnp.linalg.norm(want), 1e-30))
+
+
+def qkv(B, T, H, Hkv, D, seed=0):
+    keys = jax.random.split(jax.random.PRNGKey(seed), 3)
+    return tuple(jax.random.normal(key, (B, T, h, D))
+                 for key, h in zip(keys, (H, Hkv, Hkv)))
+
+
+def random_selection(B, T, share=0.3, seed=3):
+    """A causal map in which every query reads itself and ``share`` of the
+    earlier keys."""
+    picked = jax.random.uniform(jax.random.PRNGKey(seed), (B, T, T)) < share
+    picked |= jnp.eye(T, dtype=bool)
+    return (picked & jnp.tril(jnp.ones((T, T), bool))).astype(jnp.int8)
+
+
+def dense_oracle(q, k, v, select):
+    H, Hkv, D = q.shape[2], k.shape[2], q.shape[3]
+    s = jnp.einsum("bthd,bshd->bhts", q, jnp.repeat(k, H // Hkv, axis=2))
+    s = jnp.where(select[:, None] != 0, s / np.sqrt(D), -jnp.inf)
+    return jnp.einsum("bhts,bshd->bthd", jax.nn.softmax(s, axis=-1),
+                      jnp.repeat(v, H // Hkv, axis=2))
+
+
+@pytest.mark.parametrize("B,T,H,Hkv,D,block", [
+    (1, 256, 8, 1, 128, 64), (2, 128, 4, 2, 128, 128),
+    (1, 192, 2, 2, 256, 64)],
+    ids=["8Q_1KV_16_tiles", "one_tile", "heads_of_256"])
+def test_selected_flash_equals_the_dense_masked_oracle(B, T, H, Hkv, D,
+                                                       block):
+    q, k, v = qkv(B, T, H, Hkv, D)
+    select = random_selection(B, T)
+    weight = jax.random.normal(jax.random.PRNGKey(5), (B, T, H, D))
+
+    def ours(q, k, v):
+        out, lse = flash_attention(q, k, v, block_q=block, block_k=block,
+                                   interpret=True, select=select)
+        assert lse.shape == (B, H, T)
+        return out
+
+    with jax.default_matmul_precision("highest"):
+        want = dense_oracle(q, k, v, select)
+        assert rel(ours(q, k, v), want) <= 2e-6
+        got = jax.grad(lambda *a: (ours(*a) * weight).sum(), (0, 1, 2))(
+            q, k, v)
+        ref = jax.grad(lambda *a: (dense_oracle(*a, select) * weight).sum(),
+                       (0, 1, 2))(q, k, v)
+    assert max(rel(a, b) for a, b in zip(got, ref)) <= 5e-6
+
+
+@pytest.mark.parametrize("T", [100, 200], ids=["padded_by_auto", "by_hand"])
+def test_a_length_that_is_no_multiple_of_the_block(T):
+    """T 100 through ``flash_attention_auto`` (padded to 104, one block)
+    and T 200 padded by hand to four blocks of 64: the map is padded with
+    zeros, the padding's rows and columns are masked, and the result and
+    the gradients are the unpadded oracle's."""
+    q, k, v = qkv(1, T, 8, 1, 128, seed=2)
+    select = random_selection(1, T, seed=4)
+
+    def ours(q, k, v):
+        if T == 100:
+            return flash_attention_auto(q, k, v, select=select)[0]
+        pad = [(0, 0), (0, 256 - T), (0, 0), (0, 0)]
+        out, _ = flash_attention(
+            jnp.pad(q, pad), jnp.pad(k, pad), jnp.pad(v, pad), block_q=64,
+            block_k=64, interpret=True, seq_len=T,
+            select=jnp.pad(select, [(0, 0), (0, 256 - T), (0, 256 - T)]))
+        return out[:, :T]
+
+    with jax.default_matmul_precision("highest"):
+        assert rel(ours(q, k, v), dense_oracle(q, k, v, select)) <= 2e-6
+        got = jax.grad(lambda *a: (ours(*a) ** 2).sum(), (0, 1, 2))(q, k, v)
+        ref = jax.grad(lambda *a: (dense_oracle(*a, select) ** 2).sum(),
+                       (0, 1, 2))(q, k, v)
+    assert max(rel(a, b) for a, b in zip(got, ref)) <= 5e-6
+
+
+def test_a_map_of_ones_is_causal_flash():
+    """``topk >= T`` selects every causal key.  17 key blocks, so the path
+    without a selection runs the same grid forward and per-head pair: under
+    a map of ones the kernels do its arithmetic, forward and backward —
+    to the last bit but one (interpreted, the two bodies are compiled
+    apart and the CPU contracts them differently: 1.8e-7 of 1)."""
+    B, T, H, Hkv, D, block = 1, 136, 2, 1, 128, 8
+    q, k, v = qkv(B, T, H, Hkv, D, seed=7)
+    ones = jnp.ones((B, T, T), jnp.int8)
+    assert fa._plan_for(q.reshape(B, T, H * D), H, D, (0, 0, 0), True, block,
+                        block, block, block, True, kv_rep=2)[:1] == ("grid",)
+
+    def loss(select):
+        def f(q, k, v):
+            out = flash_attention(q, k, v, block_q=block, block_k=block,
+                                  interpret=True, select=select)
+            out = out if select is None else out[0]
+            return (out ** 2).sum(), out
+        return jax.value_and_grad(f, (0, 1, 2), has_aux=True)
+
+    (_, out), grads = loss(ones)(q, k, v)
+    (_, out0), grads0 = loss(None)(q, k, v)
+    np.testing.assert_allclose(out, out0, rtol=0, atol=5e-7)
+    for a, b in zip(grads, grads0):
+        assert rel(a, b) <= 5e-7
+
+
+def test_a_selection_is_an_int8_map_over_lane_aligned_heads():
+    q, k, v = qkv(1, 64, 2, 1, 128)
+    with pytest.raises(ValueError, match="int8"):
+        flash_attention(q, k, v, interpret=True,
+                        select=jnp.ones((1, 64, 64), jnp.int32))
+    q, k, v = qkv(1, 64, 2, 1, 64)
+    with pytest.raises(ValueError, match="lane-aligned"):
+        flash_attention(q, k, v, interpret=True,
+                        select=jnp.ones((1, 64, 64), jnp.int8))
+
+
+def test_a_selection_takes_the_grid_forward_and_the_per_head_pair():
+    """Every plan without a selection is the one it was (the plan table of
+    ``test_flash_attention.py``); with one, the two forms whose grids have
+    a (block_q, block_k) tile a step, whatever the length."""
+    seen = dict(T=2048, D=128, H=16, head_base=(0, 0, 0), itemsize=2,
+                causal=True, block_q=1024, block_k=1024, bwd_block_q=1024,
+                bwd_block_k=1024, interpret=False, manual_axes=False,
+                vmem_headroom=True)
+    assert fa._plan(**seen)[:4] == ("fullunroll", 512, 0, "grouped")
+    assert fa._plan(**seen, select=True)[:6] == ("grid", 0, 0, "per_head",
+                                                 0, 0)
+
+
+# ------------------------------------------------------------ the top-k
+
+
+@pytest.mark.parametrize("W,k", [(64, 16), (100, 100), (37, 5), (256, 300),
+                                 (512, 100)])
+def test_the_exact_top_k_against_a_sort_ties_included(W, k):
+    """Scores rounded to halves tie by the dozen; a row's second half, and
+    a whole row but three keys, are -inf (not causal).  The set is the
+    first ``k`` of a stable descending sort — a tie to the lower index —
+    without what is not causal, and ``lax.top_k``'s."""
+    rng = np.random.default_rng(W)
+    s = np.round(rng.normal(size=(3, 40, W)).astype(np.float32) * 2) / 2
+    s += 0.0        # no -0: XLA's order has it under +0, numpy's sort beside
+    s[1, :, W // 2:] = -np.inf
+    s[2, 5, 3:] = -np.inf
+    chosen, lse = jax.jit(lambda x: ss.select_rows(x, k))(s)
+    order = np.argsort(-s, axis=-1, kind="stable")[..., :k]
+    want = np.zeros(s.shape, bool)
+    np.put_along_axis(want, order, True, -1)
+    want &= np.isfinite(s)
+    assert (np.asarray(chosen) == want).all()
+    top = np.zeros(s.shape, bool)
+    np.put_along_axis(top, np.asarray(jax.lax.top_k(s, min(k, W))[1]), True,
+                      -1)
+    assert (np.asarray(chosen) == (top & np.isfinite(s))).all()
+    np.testing.assert_allclose(
+        lse, jax.scipy.special.logsumexp(jnp.where(want, s, -jnp.inf), -1),
+        rtol=1e-6)
+
+
+def indexer_inputs(B, T, HI, DI, seed=1):
+    keys = jax.random.split(jax.random.PRNGKey(seed), 3)
+    return (jax.random.normal(keys[0], (B, T, HI, DI)),
+            jax.random.normal(keys[1], (B, T, DI)),
+            jax.random.normal(keys[2], (B, T, HI)))
+
+
+def test_tiles_of_64_and_128_give_the_same_selection():
+    """The tile is where scores and top-k are computed, not a unit of
+    selection: ``S_t`` is the dense reference's whatever it is, each query
+    keeps ``min(t + 1, topk)`` keys, all of them causal."""
+    B, T, topk = 2, 256, 48
+    qi, ki, w = indexer_inputs(B, T, 4, 64)
+    q, k, v = qkv(B, T, 2, 1, 128)
+    maps = [ss.index_select(qi, ki, w, topk, tile=tile, interpret=True)[0]
+            for tile in (64, 128, 256)]
+    want = ss.sparse_attention_reference(q, k, v, qi, ki, w, topk)[2]
+    for got in maps:
+        assert got.dtype == jnp.int8 and (got == want).all()
+    per_query = np.asarray(want.sum(-1))
+    assert (per_query == np.minimum(np.arange(T) + 1, topk)).all()
+    assert not np.triu(np.asarray(want[0]), 1).any()
+    selected, live = ss.selection_counters(want, 64)
+    assert float(selected) == np.minimum(np.arange(T) + 1, topk).mean()
+    assert 0 < float(live) <= 1
+
+
+def test_the_scores_are_made_in_bands_of_the_causal_width():
+    """Four bands at T 16,384 (a band's rows against the keys up to its
+    last row), one where the sequence is no longer than ``topk`` tiles."""
+    assert ss._bands(16384, 512, 2048) == 4
+    assert ss._bands(4096, 512, 2048) == 2
+    assert ss._bands(2048, 512, 2048) == 1
+    B, T = 1, 512
+    qi, ki, w = indexer_inputs(B, T, 2, 64, seed=8)
+    whole = ss.index_scores(qi.transpose(0, 2, 1, 3), ki, w, interpret=True)
+    band = ss.index_scores(qi.transpose(0, 2, 1, 3), ki, w, row0=256,
+                           rows=128, interpret=True)
+    assert band.shape == (B, 128, 384)
+    np.testing.assert_array_equal(band, whole[:, 256:384, :384])
+    products = jnp.einsum("bthd,bsd->bhts", qi, ki)
+    want = jnp.einsum("bth,bhts->bts", w, jax.nn.relu(products)) / np.sqrt(
+        2 * 64)
+    causal = np.tril(np.ones((T, T), bool))
+    np.testing.assert_allclose(np.where(causal, whole[0], 0),
+                               np.where(causal, want[0], 0), atol=1e-5)
+    assert np.isneginf(np.asarray(whole[0])[~causal]).all()
+
+
+# ------------------------------------------------------- the indexer's loss
+
+
+def test_index_kl_and_its_gradient_against_the_reference():
+    """The whole path — scores, selection, selected flash, KL pass — against
+    the dense reference: the output, ``L_I``, and the gradients of ``out ·
+    weight + 3 L_I`` on all six inputs."""
+    B, T, H, Hkv, D, HI, DI, topk = 2, 256, 8, 1, 128, 4, 64, 48
+    q, k, v = qkv(B, T, H, Hkv, D)
+    qi, ki, w = indexer_inputs(B, T, HI, DI)
+    weight = jax.random.normal(jax.random.PRNGKey(9), (B, T, H, D))
+
+    def ours(q, k, v, qi, ki, w):
+        select, lse_i = ss.index_select(qi, ki, w, topk, tile=64,
+                                        interpret=True)
+        out, lse = flash_attention(q, k, v, block_q=64, block_k=64,
+                                   interpret=True, select=select)
+        return out, ss.index_kl(qi, ki, w, q, k, lse, select, lse_i,
+                                interpret=True)
+
+    def theirs(*args):
+        return ss.sparse_attention_reference(*args, topk)[:2]
+
+    def loss(f):
+        def total(*args):
+            out, kl = f(*args)
+            return (out * weight).sum() + 3.0 * kl
+        return total
+
+    with jax.default_matmul_precision("highest"):
+        (out, kl), (out_ref, kl_ref) = ours(q, k, v, qi, ki, w), theirs(
+            q, k, v, qi, ki, w)
+        assert rel(out, out_ref) <= 2e-6
+        assert abs(float(kl) - float(kl_ref)) <= 1e-6 * float(kl_ref) > 0
+        got = jax.grad(loss(ours), range(6))(q, k, v, qi, ki, w)
+        ref = jax.grad(loss(theirs), range(6))(q, k, v, qi, ki, w)
+    errors = {n: rel(a, b) for n, a, b in zip(
+        ("q", "k", "v", "qi", "ki", "w"), got, ref)}
+    assert max(errors.values()) <= 1e-5, errors
+
+
+def test_index_kl_moves_the_indexer_alone():
+    """``L_I``'s gradient reaches ``qI``, ``kI`` and ``w`` and is zero on
+    the attention's own q and k (``p`` is detached)."""
+    B, T, topk = 1, 128, 32
+    q, k, v = qkv(B, T, 2, 1, 128)
+    qi, ki, w = indexer_inputs(B, T, 4, 64)
+    select, lse_i = ss.index_select(qi, ki, w, topk, interpret=True)
+    _, lse = flash_attention(q, k, v, interpret=True, select=select)
+    grads = jax.grad(lambda *a: ss.index_kl(*a, lse, select, lse_i,
+                                            interpret=True),
+                     range(5))(qi, ki, w, q, k)
+    assert all(float(jnp.abs(g).max()) > 0 for g in grads[:3])
+    assert all(float(jnp.abs(g).max()) == 0 for g in grads[3:])

@@ -812,3 +812,58 @@ def test_chunked_delta_rule_fwd_bwd_at_olmo_hybrid_widths(v5e):
     plan = (m.argument_size_in_bytes + m.output_size_in_bytes
             + m.temp_size_in_bytes - m.alias_size_in_bytes)
     assert plan < 3.25 * 2 ** 30, plan / 2 ** 30
+
+
+# --------------------------------------------- learned sparse attention
+# (the keye_1chip cell: 1 sequence of 16,384, Keye-VL-2.0's widths)
+
+
+def test_selected_attention_fwd_bwd_at_keye_widths(v5e):
+    """One layer's sparse attention as ``GroupedQueryAttention(indexer=…)``
+    calls it — 32 query over 4 KV heads of 128 at T 16,384, an indexer of
+    16 heads of 64 that keeps 2,048 keys a query — compiles for the chip:
+    the scores in four bands (``index_scores``), the exact top-k as plain
+    XLA with no sort and no approximate top-k, the flash grid forward and
+    the per-head backward pair each with the int8 (1, T, T) map as an
+    operand (``flash_select_*``), and the KL pass (``index_kl``).  The map
+    is 256 MiB; a band's float32 scores are at most 1 GiB and no (T, T)
+    float32 array of all heads is ever made."""
+    import re
+
+    from horovod_tpu.ops import flash_attention as fa, sparse_select
+
+    one = SingleDeviceSharding(v5e[0])
+    B, T, H, Hkv, D, HI, DI, topk = 1, 16_384, 32, 4, 128, 16, 64, 2048
+
+    def s(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    def loss(q, k, v, qi, ki, w):
+        select, lse_i = sparse_select.index_select(qi, ki, w, topk, tile=512)
+        out, lse = fa.flash_attention(q, k, v, causal=True, block_q=1024,
+                                      block_k=1024, select=select)
+        kl = sparse_select.index_kl(qi, ki, w, q, k, lse, select, lse_i)
+        return out.astype(jnp.float32).sum() + kl
+
+    lowered = jax.jit(jax.value_and_grad(loss, argnums=range(6))).lower(
+        s(B, T, H, D), s(B, T, Hkv, D), s(B, T, Hkv, D), s(B, T, HI, DI),
+        s(B, T, DI), s(B, T, HI))
+    names = re.findall(r'kernel_name = "([^"]+)"', lowered.as_text())
+    assert sorted(set(names)) == [
+        "flash_select_dkdv", "flash_select_dq", "flash_select_fwd",
+        "index_kl", "index_scores"]
+    assert names.count("index_scores") == 4                # the bands
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert "approx" not in text.lower() and " sort(" not in text
+    assert "s8[1,16384,16384]" in text
+    assert "f32[1,16,16384,16384]" not in text
+    assert "f32[1,16384,16,16384]" not in text
+    _, grads = compiled.out_info
+    assert [g.shape for g in grads] == [
+        (B, T, H, D), (B, T, Hkv, D), (B, T, Hkv, D), (B, T, HI, DI),
+        (B, T, DI), (B, T, HI)]
+    m = compiled.memory_analysis()
+    plan = (m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes - m.alias_size_in_bytes)
+    assert plan < 3.0 * 2 ** 30, plan / 2 ** 30
