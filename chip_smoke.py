@@ -112,7 +112,9 @@ DELTA_TOL = 4e-2
 # The selected attention, the selection and the KL pass (bfloat16 operands,
 # float32 scores) against the dense float32 mathematics on the same
 # bfloat16 inputs: the same keys to the last one (chip, PR 36: 0 of
-# 917,760 differ), outputs 2.1e-3 and gradients up to 3.3e-3 of a norm.
+# 917,760 differ), outputs 2.1e-3 and gradients up to 3.3e-3 of a norm;
+# PR 37, seed 0: 2.7e-3 and up to 6.6e-3, a KV group a grid step and a
+# query head a step alike, to the last printed digit).
 SELECT_TOL = 2e-2
 LOSS_TOL = 2e-2          # 4-device vs 1-device loss, same step
 INT8_LOSS_TOL = 2e-2     # int8 wire vs fp32 wire loss, same step
@@ -549,13 +551,21 @@ def select_reference_phase(*, batch: int, seq: int, heads: int,
     int8 map), ``flash_attention(select=map)`` and ``index_kl`` — against
     ``sparse_attention_reference`` (dense float32, ``highest``) on the same
     bfloat16 operands: the selection itself, the output, ``L_I`` and the
-    gradients of a weighted sum on all six operands."""
+    gradients of a weighted sum on all six operands.  ``select_plan`` is
+    what ``flash_attention._plan`` decides under a selection on this
+    device: the group form, its blocks and its scoped-VMEM budget."""
     import jax
     import jax.numpy as jnp
 
     from horovod_tpu.ops import flash_attention as fa, sparse_select
 
     interpret = jax.default_backend() != "tpu"
+    blocks = fa._resolve_blocks(seq, "chip_smoke", None, None, None, None,
+                                None, "")[:4]
+    plan = fa._select_plan_for(
+        jax.ShapeDtypeStruct((batch, seq, heads * head_dim), jnp.bfloat16),
+        jax.ShapeDtypeStruct((batch, seq, kv_heads * head_dim), jnp.bfloat16),
+        heads, head_dim, True, *blocks, interpret)._asdict()
     ks = jax.random.split(jax.random.PRNGKey(seed), 7)
     shapes = ((batch, seq, heads, head_dim), (batch, seq, kv_heads, head_dim),
               (batch, seq, kv_heads, head_dim),
@@ -610,6 +620,7 @@ def select_reference_phase(*, batch: int, seq: int, heads: int,
               f"{name} by {err:.3g} (bound {SELECT_TOL})")
     return {"shape": [batch, seq, heads, kv_heads, head_dim, index_heads,
                       index_dim, topk], "interpret": interpret,
+            "select_plan": plan,
             "selected_pairs": int(want_map.sum()),
             "pairs_differing": differing,
             **{name: round(err, 5) for name, err in errs.items()}}

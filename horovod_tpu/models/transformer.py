@@ -64,7 +64,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from horovod_tpu.ops.flash_attention import (
-    auto_block, flash_attention_auto, flash_qkv_proj)
+    auto_block, flash_attention_auto, flash_qkv_proj, select_tile_fetches)
 from horovod_tpu.parallel.mesh import RANKS_AXIS
 from horovod_tpu.parallel.moe import DroplessMoE, note_layer
 from horovod_tpu.parallel.ring_attention import (
@@ -296,7 +296,9 @@ class GroupedQueryAttention(nn.Module):
             "attn.causal_pairs": B * T * (T + 1) // 2,
             "attn.selected_pairs": B * pairs,
             "attn.index_flops": B * 2 * HI * DI * T * (T + 1) // 2,
-            "attn.select_bytes": B * T * T})
+            "attn.select_bytes": B * T * T,
+            "attn.select_tile_fetches": (
+                0 if self.attn == "full" else select_tile_fetches(q, k))})
         return out
 
 

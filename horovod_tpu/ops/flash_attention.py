@@ -56,6 +56,13 @@ the per-head dk/dv kernel's innermost grid axis runs the Q blocks of the
 ``kv_rep`` query heads of a KV head one after another, so that their sums
 form in its scratch.  The grouped pair stands down there.
 
+Under a selection map (``flash_attention(select=...)``, learned sparse
+attention) a path of its own runs, forward and backward: three kernels
+that take a KV group a grid step (:func:`_select_fwd_kernel`,
+:func:`_select_dq_kernel`, :func:`_select_dkdv_kernel`) — the map's tile
+is fetched and decoded once for the query heads that share a KV head.
+The calls without a map share nothing with it.
+
 Composition: this is the *single-chip* block; for sequences sharded
 across chips use :mod:`horovod_tpu.parallel.ring_attention`, which
 streams K/V between chips with the same online-softmax math.
@@ -212,22 +219,10 @@ def _live_block(qi, kj, block_q, block_k, causal, seq_len):
     return live
 
 
-def _chosen(ok, sel_ref):
-    """``ok`` (a block pair's validity mask, or None) ANDed with the
-    pair's tile of a selection map (int8, nonzero where the query reads
-    the key); ``ok`` itself where the kernel carries no selection."""
-    if sel_ref is None:
-        return ok
-    chosen = sel_ref[0].astype(jnp.int32) != 0
-    return chosen if ok is None else jnp.logical_and(ok, chosen)
-
-
-def _fwd_kernel(q_ref, k_ref, v_ref, *refs, scale, causal, block_q, block_k,
-                seq_len, select=False):
+def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
+                m_scr, l_scr, acc_scr, *, scale, causal, block_q, block_k,
+                seq_len):
     # Grid (B, H, T/block_q, T/block_k): the head is its own grid axis.
-    # ``select``: a (block_q, block_k) tile of a selection map follows v.
-    sel_ref = refs[0] if select else None
-    o_ref, lse_ref, m_scr, l_scr, acc_scr = refs[int(select):]
     qi = pl.program_id(2)
     kj = pl.program_id(3)
     nk = pl.num_programs(3)
@@ -247,8 +242,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *refs, scale, causal, block_q, block_k,
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale   # (BQ, BK)
-        ok = _chosen(_block_mask(qi, kj, block_q, block_k, causal, seq_len)
-                     if masked else None, sel_ref)
+        ok = (_block_mask(qi, kj, block_q, block_k, causal, seq_len)
+              if masked else None)
         if ok is not None:
             s = jnp.where(ok, s, _NEG_BIG)
         m_prev = m_scr[...]                            # (BQ, 128)
@@ -433,41 +428,8 @@ def _kv_head(kv_rep: int):
     return (lambda h: h) if kv_rep == 1 else (lambda h: h // kv_rep)
 
 
-# A selection map (``flash_attention``'s ``select``) is one more operand
-# of the grid forward and of the per-head backward pair: an int8 (B, T, T)
-# array read a (block_q, block_k) tile a grid step.  Without one each of
-# these gives nothing, and the call is the one it was.
-_SELECT_VMEM_MB = 32
-
-
-def _select_kw(select) -> dict:
-    return {} if select is None else {"select": True}
-
-
-def _select_spec(select, block_q, block_k, index_map) -> list:
-    return ([] if select is None
-            else [pl.BlockSpec((1, block_q, block_k), index_map)])
-
-
-def _select_operand(select) -> tuple:
-    return () if select is None else (select,)
-
-
-def _select_name(select, which: str) -> dict:
-    return {} if select is None else {"name": f"flash_select_{which}"}
-
-
-def _select_vmem(select) -> dict:
-    """The tile of the map and the mask made of it are held beside the
-    float32 score tiles: at 1024² blocks that is over Mosaic's default
-    16 MB, so the budget the grouped pair has where the device backs it."""
-    return ({} if select is None or not _vmem_headroom_ok()
-            else _vmem_limit(_SELECT_VMEM_MB))
-
-
 def _fwd_packed(q, k, v, H, D, plan, *, scale, causal, block_q, block_k,
-                interpret, seq_len=None, head_base=(0, 0, 0), kv_rep=1,
-                select=None):
+                interpret, seq_len=None, head_base=(0, 0, 0), kv_rep=1):
     """Forward on head-packed (B, T, C) views (C = H*D): the head is a
     grid axis and every BlockSpec offsets its last dim by ``h*D``, so no
     (B, T, H, D) -> (B*H, T, D) transpose copy ever materializes in HBM
@@ -477,8 +439,7 @@ def _fwd_packed(q, k, v, H, D, plan, *, scale, causal, block_q, block_k,
     projection (so the qkv split never copies either).  ``plan`` is
     :func:`_plan`'s: which of the three forms runs.  ``kv_rep`` query
     heads read each KV head (``k``, ``v`` hold ``H // kv_rep`` heads).
-    ``select``: a (B, T, T) int8 selection map (the grid form alone
-    carries one).  lse comes back as (B, H, T)."""
+    lse comes back as (B, H, T)."""
     B, T, _ = q.shape
     nq = T // block_q
     nk = T // block_k
@@ -549,7 +510,7 @@ def _fwd_packed(q, k, v, H, D, plan, *, scale, causal, block_q, block_k,
     grid = (B, H, nq, nk)
     kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
                                block_q=block_q, block_k=block_k,
-                               seq_len=seq_len, **_select_kw(select))
+                               seq_len=seq_len)
     out, lse = pl.pallas_call(
         kernel,
         grid=grid,
@@ -560,8 +521,7 @@ def _fwd_packed(q, k, v, H, D, plan, *, scale, causal, block_q, block_k,
                          lambda b, h, i, j: (b, j, kvh(h) + ok_)),
             pl.BlockSpec((1, block_k, D),
                          lambda b, h, i, j: (b, j, kvh(h) + ov)),
-        ] + _select_spec(select, block_q, block_k,
-                         lambda b, h, i, j: (b, i, j)),
+        ],
         out_specs=[
             pl.BlockSpec((1, block_q, D), lambda b, h, i, j: (b, i, h)),
             pl.BlockSpec((1, 1, block_q, 8),
@@ -578,26 +538,21 @@ def _fwd_packed(q, k, v, H, D, plan, *, scale, causal, block_q, block_k,
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary"),
-            **_select_vmem(select)),
+                                 "arbitrary")),
         interpret=interpret,
-        **_select_name(select, "fwd"),
-    )(q, k, v, *_select_operand(select))
+    )(q, k, v)
     return out, lse[..., 0]
 
 
-def _dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dta_ref, *refs,
-                 scale, causal, block_q, block_k, seq_len, kv_rep=1,
-                 select=False):
+def _dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dta_ref,
+                 dk_ref, dv_ref, dk_scr, dv_scr, *,
+                 scale, causal, block_q, block_k, seq_len, kv_rep=1):
     """Accumulate dk/dv for one KV block while Q blocks stream through
     (grid innermost axis).  The flash-backward identities:
     p = exp(s - lse);  dv += p^T dO;  dS = p * (dO V^T - delta) * scale;
     dk += dS^T Q.  Where ``kv_rep`` query heads read this KV head, the
     innermost axis runs the Q blocks of one of them after another's, and
-    the sums over them are formed here, in the scratch.  ``select``: a
-    tile of a selection map follows the row statistics."""
-    sel_ref = refs[0] if select else None
-    dk_ref, dv_ref, dk_scr, dv_scr = refs[int(select):]
+    the sums over them are formed here, in the scratch."""
     kj = pl.program_id(2)
     step = pl.program_id(3)
     nq = pl.num_programs(3)
@@ -619,8 +574,8 @@ def _dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dta_ref, *refs,
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale    # (BQ, BK)
         p = jnp.exp(s - lse)
-        ok = _chosen(_block_mask(qi, kj, block_q, block_k, causal, seq_len)
-                     if masked else None, sel_ref)
+        ok = (_block_mask(qi, kj, block_q, block_k, causal, seq_len)
+              if masked else None)
         if ok is not None:
             p = jnp.where(ok, p, 0.0)
         # dv += p^T @ dO — p cast to the input dtype so the MXU runs at
@@ -646,13 +601,11 @@ def _dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dta_ref, *refs,
         dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
 
 
-def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dta_ref, *refs,
-               scale, causal, block_q, block_k, seq_len, select=False):
+def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dta_ref,
+               dq_ref, dq_scr, *, scale, causal, block_q, block_k,
+               seq_len):
     """Accumulate dq for one Q block while KV blocks stream through:
-    dq += dS @ K with dS = p * (dO V^T - delta) * scale.  ``select``: a
-    tile of a selection map follows the row statistics."""
-    sel_ref = refs[0] if select else None
-    dq_ref, dq_scr = refs[int(select):]
+    dq += dS @ K with dS = p * (dO V^T - delta) * scale."""
     qi = pl.program_id(2)
     kj = pl.program_id(3)
     nk = pl.num_programs(3)
@@ -672,8 +625,8 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dta_ref, *refs,
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale
         p = jnp.exp(s - lse)
-        ok = _chosen(_block_mask(qi, kj, block_q, block_k, causal, seq_len)
-                     if masked else None, sel_ref)
+        ok = (_block_mask(qi, kj, block_q, block_k, causal, seq_len)
+              if masked else None)
         if ok is not None:
             p = jnp.where(ok, p, 0.0)
         dp = jax.lax.dot_general(
@@ -959,7 +912,7 @@ def _bwd_pallas_packed_grouped(q, k, v, o, lse, do, H, D, group, *, scale,
 
 def _bwd_pallas_packed(q, k, v, o, lse, do, H, D, plan, *, scale, causal,
                        block_q, block_k, interpret, seq_len=None,
-                       head_base=(0, 0, 0), kv_rep=1, select=None):
+                       head_base=(0, 0, 0), kv_rep=1):
     """Split flash backward on head-packed (B, T, C) views (see
     :func:`_fwd_packed`); ``lse`` arrives as (B, H, T) and ``o``/``do``
     are head-merged (B, T, H*D).  ``plan`` is :func:`_plan`'s: the pair
@@ -1017,18 +970,14 @@ def _bwd_pallas_packed(q, k, v, o, lse, do, H, D, plan, *, scale, causal,
     )
     sem4 = pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "parallel",
-                             "arbitrary"),
-        **_select_vmem(select))
+                             "arbitrary"))
     dk, dv = pl.pallas_call(
         functools.partial(_dkdv_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k,
-                          seq_len=seq_len, kv_rep=kv_rep,
-                          **_select_kw(select)),
+                          seq_len=seq_len, kv_rep=kv_rep),
         grid=(B, H // kv_rep, nk, kv_rep * nq),
         in_specs=[kv_specs["q"], kv_specs["k"], kv_specs["v"],
-                  kv_specs["do"], kv_specs["row8"], kv_specs["row8"]]
-        + _select_spec(select, block_q, block_k,
-                       lambda b, h, j, i: (b, q_block(i), j)),
+                  kv_specs["do"], kv_specs["row8"], kv_specs["row8"]],
         out_specs=[kv_specs["out"], kv_specs["out"]],
         out_shape=[_struct((B, T, C // kv_rep), k.dtype, q, k, v, do),
                    _struct((B, T, C // kv_rep), v.dtype, q, k, v, do)],
@@ -1036,8 +985,7 @@ def _bwd_pallas_packed(q, k, v, o, lse, do, H, D, plan, *, scale, causal,
                         pltpu.VMEM((block_k, D), jnp.float32)],
         compiler_params=sem4,
         interpret=interpret,
-        **_select_name(select, "dkdv"),
-    )(q, k, v, do, lse8, delta8, *_select_operand(select))
+    )(q, k, v, do, lse8, delta8)
 
     q_specs = dict(
         q=pl.BlockSpec((1, block_q, D),
@@ -1054,31 +1002,370 @@ def _bwd_pallas_packed(q, k, v, o, lse, do, H, D, plan, *, scale, causal,
     dq, = pl.pallas_call(
         functools.partial(_dq_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k,
-                          seq_len=seq_len, **_select_kw(select)),
+                          seq_len=seq_len),
         grid=(B, H, nq, nk),
         in_specs=[q_specs["q"], q_specs["k"], q_specs["v"],
-                  q_specs["do"], q_specs["row8"], q_specs["row8"]]
-        + _select_spec(select, block_q, block_k,
-                       lambda b, h, i, j: (b, i, j)),
+                  q_specs["do"], q_specs["row8"], q_specs["row8"]],
         out_specs=[q_specs["out"]],
         out_shape=[_struct((B, T, C), q.dtype, q, k, v, do)],
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
         compiler_params=sem4,
         interpret=interpret,
-        **_select_name(select, "dq"),
-    )(q, k, v, do, lse8, delta8, *_select_operand(select))
+    )(q, k, v, do, lse8, delta8)
     return dq, dk, dv
+
+
+# ------------------------------------------------- under a selection map
+#
+# ``flash_attention(select=map)``: a path of its own, reached only with a
+# map and sharing no kernel with the calls without one.  The selection is
+# one set a query, shared by its heads, and ``G = H / H_kv`` query heads
+# read each KV head; in the packed layout those are adjacent lanes.  So a
+# grid step takes a whole KV group: the (block_q, block_k) int8 tile of
+# the map is fetched once and decoded once, into a float32 bias (0 where
+# the query reads the key, ``_NEG_BIG`` elsewhere; the causal mask and the
+# padding's ANDed in on the tiles they cut), the group's K and V tile is
+# fetched once, and a static loop over the ``G`` lane slices does each
+# head's products against them.  A head pays one add a score element for
+# the selection.  Every causal tile is visited; a step wholly in the
+# causal future fetches nothing (its index maps name the nearest live
+# step's blocks).
+
+
+# The tiling, timed alone at keye_1chip's shape on a v5e (PR 37; one layer,
+# 8 heads a group, key tiles of 1024; forward + dq + dk/dv, ms): Q blocks of
+# 1024 17.8 + 22.5 + 37.6 under 64 MB of scoped VMEM, of 512 18.5 + 22.8 +
+# 28.2 (the forward counts 23.9 MB, the pair fits Mosaic's 16), of 256 20.6
+# + 23.3 + 28.7 with all three inside 16 MB; key tiles of 512 double the
+# forward (34.1: eight accumulators rescaled twice as often).  So the heads
+# of a step hold at most 4,096 query rows between them under a 32 MB
+# budget; where the device backs no more than Mosaic's default, 2,048, and
+# at most 512 a head (one head's (1024, 1024) float32 tiles overrun 16 MB).
+_SELECT_VMEM_MB = 32
+_GROUP_ROWS = 4096
+_GROUP_ROWS_DEFAULT_VMEM = 2048
+_GROUP_HEAD_ROWS_DEFAULT_VMEM = 512
+
+
+def _group_block_q(block_q: int, group: int, vmem_headroom: bool) -> int:
+    """The group form's Q block: ``block_q`` halved (while it stays a
+    multiple of 128) until the ``group`` heads of a grid step hold no more
+    query rows than the budget above allows."""
+    rows, head_rows = ((_GROUP_ROWS, block_q) if vmem_headroom else
+                       (_GROUP_ROWS_DEFAULT_VMEM,
+                        _GROUP_HEAD_ROWS_DEFAULT_VMEM))
+    while ((group * block_q > rows or block_q > head_rows)
+           and block_q % 256 == 0):
+        block_q //= 2
+    return block_q
+
+
+def _select_bias(sel_ref, bias_scr, qi, kj, block_q, block_k, causal,
+                 seq_len):
+    """Decode this block pair's tile of the map into ``bias_scr`` (only on
+    a live pair) and return whether the pair is live."""
+    def decode(masked: bool):
+        chosen = sel_ref[0].astype(jnp.int32) != 0
+        if masked:
+            chosen = jnp.logical_and(chosen, _block_mask(
+                qi, kj, block_q, block_k, causal, seq_len))
+        bias_scr[...] = jnp.where(chosen, 0.0, _NEG_BIG)
+
+    live = _live_block(qi, kj, block_q, block_k, causal, seq_len)
+    _masked_dispatch(decode, live, qi, kj, block_q, block_k, causal, seq_len)
+    return live
+
+
+def _select_fwd_kernel(q_ref, k_ref, v_ref, sel_ref, o_ref, lse_ref,
+                       bias_scr, m_scr, l_scr, acc_scr, *, scale, causal,
+                       block_q, block_k, seq_len, group, head_dim):
+    """Forward of one KV group, grid (B, H_kv, T/block_q, T/block_k) with
+    the KV axis innermost.  The running maximum and sum of head ``g`` are
+    column ``g`` of ``m_scr`` / ``l_scr``, its output ``acc_scr[g]``.
+
+    A key left out has the score ``_NEG_BIG`` exactly (the bias absorbs the
+    product), so its ``exp(s - m)`` is 0 once the row has met a key it
+    reads.  Until then — a row whose selected keys all lie in later tiles
+    — the row's maximum is ``_NEG_BIG`` itself and the tile adds ones; the
+    first real maximum rescales that by ``exp(_NEG_BIG - m) = 0``, so the
+    row comes out exact.  A row that reads no key at all (the padding's)
+    leaves 0 and a log-sum-exp of 0, under which the backward's ``p`` is 0.
+    """
+    qi = pl.program_id(2)
+    kj = pl.program_id(3)
+    nk = pl.num_programs(3)
+    D = head_dim
+
+    @pl.when(kj == 0)
+    def _init():
+        m_scr[...] = jnp.full_like(m_scr, _NEG_BIG)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    live = _select_bias(sel_ref, bias_scr, qi, kj, block_q, block_k, causal,
+                        seq_len)
+
+    @pl.when(live)
+    def _heads():
+        k = k_ref[0]                                      # (BK, D)
+        v = v_ref[0]
+        for g in range(group):
+            s = jax.lax.dot_general(
+                q_ref[0, :, g * D:(g + 1) * D], k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale + bias_scr[...]
+            m_prev = m_scr[:, g:g + 1]                    # (BQ, 1)
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new)                        # (BQ, BK)
+            l_scr[:, g:g + 1] = (l_scr[:, g:g + 1] * alpha
+                                 + jnp.sum(p, axis=1, keepdims=True))
+            acc_scr[g] = acc_scr[g] * alpha + jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_scr[:, g:g + 1] = m_new
+
+    @pl.when(kj == nk - 1)
+    def _finalize():
+        m = m_scr[:, :group]                              # (BQ, G)
+        none = m <= 0.5 * _NEG_BIG
+        l = jnp.where(none, 1.0, jnp.maximum(l_scr[:, :group], 1e-30))
+        for g in range(group):
+            o_ref[0, :, g * D:(g + 1) * D] = jnp.where(
+                none[:, g:g + 1], 0.0,
+                acc_scr[g] / l[:, g:g + 1]).astype(o_ref.dtype)
+        lse_ref[0, 0] = jnp.where(none, 0.0, m + jnp.log(l))
+
+
+def _select_p_ds(q, k, v, do, lse, delta, scale, bias):
+    """:func:`_p_ds` under a decoded tile: ``p = exp(s + bias - lse)``."""
+    s = jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32) * scale + bias
+    p = jnp.exp(s - lse)
+    dp = jax.lax.dot_general(
+        do, v, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    return p, p * (dp - delta) * scale
+
+
+def _select_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dta_ref, sel_ref,
+                      dq_ref, bias_scr, dq_scr, *, scale, causal, block_q,
+                      block_k, seq_len, group, head_dim):
+    """dq of one KV group's query heads while the KV blocks stream through
+    (grid as the forward's): ``dq_scr[g] += dS_g @ K``."""
+    qi = pl.program_id(2)
+    kj = pl.program_id(3)
+    nk = pl.num_programs(3)
+    D = head_dim
+
+    @pl.when(kj == 0)
+    def _init():
+        dq_scr[...] = jnp.zeros_like(dq_scr)
+
+    live = _select_bias(sel_ref, bias_scr, qi, kj, block_q, block_k, causal,
+                        seq_len)
+
+    @pl.when(live)
+    def _heads():
+        k = k_ref[0]
+        v = v_ref[0]
+        lse = lse_ref[0, 0]                               # (BQ, G)
+        delta = dta_ref[0, 0]
+        for g in range(group):
+            sl = slice(g * D, (g + 1) * D)
+            _, ds = _select_p_ds(q_ref[0, :, sl], k, v, do_ref[0, :, sl],
+                                 lse[:, g:g + 1], delta[:, g:g + 1], scale,
+                                 bias_scr[...])
+            dq_scr[g] += jax.lax.dot_general(
+                ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+
+    @pl.when(kj == nk - 1)
+    def _finalize():
+        for g in range(group):
+            dq_ref[0, :, g * D:(g + 1) * D] = dq_scr[g].astype(dq_ref.dtype)
+
+
+def _select_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dta_ref,
+                        sel_ref, dk_ref, dv_ref, bias_scr, dk_scr, dv_scr, *,
+                        scale, causal, block_q, block_k, seq_len, group,
+                        head_dim):
+    """dk/dv of one KV head while the Q blocks of its group stream through
+    (grid (B, H_kv, T/block_k, T/block_q)), summed over the group's heads
+    as they are formed."""
+    kj = pl.program_id(2)
+    qi = pl.program_id(3)
+    nq = pl.num_programs(3)
+    D = head_dim
+
+    @pl.when(qi == 0)
+    def _init():
+        dk_scr[...] = jnp.zeros_like(dk_scr)
+        dv_scr[...] = jnp.zeros_like(dv_scr)
+
+    live = _select_bias(sel_ref, bias_scr, qi, kj, block_q, block_k, causal,
+                        seq_len)
+
+    @pl.when(live)
+    def _heads():
+        k = k_ref[0]
+        v = v_ref[0]
+        lse = lse_ref[0, 0]                               # (BQ, G)
+        delta = dta_ref[0, 0]
+        for g in range(group):
+            sl = slice(g * D, (g + 1) * D)
+            q = q_ref[0, :, sl]
+            do = do_ref[0, :, sl]
+            p, ds = _select_p_ds(q, k, v, do, lse[:, g:g + 1],
+                                 delta[:, g:g + 1], scale, bias_scr[...])
+            dv_scr[...] += jax.lax.dot_general(
+                p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            dk_scr[...] += jax.lax.dot_general(
+                ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+
+    @pl.when(qi == nq - 1)
+    def _finalize():
+        dk_ref[0] = dk_scr[...].astype(dk_ref.dtype)
+        dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
+
+
+def _select_live_k(causal, block_q, block_k):
+    """``(i, j) ->`` the KV block a forward or dq step holds: ``j``, or the
+    last live one of Q block ``i`` where ``j`` lies in the causal future
+    (the pipeline then issues no copy for the dead step)."""
+    if not causal:
+        return lambda i, j: j
+    return lambda i, j: jnp.minimum(j, ((i + 1) * block_q - 1) // block_k)
+
+
+def _select_fwd(q, k, v, select, H, D, *, scale, causal, block_q, block_k,
+                interpret, seq_len, vmem_mb):
+    """``(out (B, T, H*D), lse (B, H, T))`` on head-packed views, a KV
+    group a grid step (:func:`_select_fwd_kernel`)."""
+    B, T, _ = q.shape
+    Hkv = k.shape[2] // D
+    G = H // Hkv
+    live_k = _select_live_k(causal, block_q, block_k)
+    out, lse = pl.pallas_call(
+        functools.partial(_select_fwd_kernel, scale=scale, causal=causal,
+                          block_q=block_q, block_k=block_k, seq_len=seq_len,
+                          group=G, head_dim=D),
+        grid=(B, Hkv, T // block_q, T // block_k),
+        in_specs=[
+            pl.BlockSpec((1, block_q, G * D), lambda b, h, i, j: (b, i, h)),
+            pl.BlockSpec((1, block_k, D),
+                         lambda b, h, i, j: (b, live_k(i, j), h)),
+            pl.BlockSpec((1, block_k, D),
+                         lambda b, h, i, j: (b, live_k(i, j), h)),
+            pl.BlockSpec((1, block_q, block_k),
+                         lambda b, h, i, j: (b, i, live_k(i, j))),
+        ],
+        out_specs=[
+            pl.BlockSpec((1, block_q, G * D), lambda b, h, i, j: (b, i, h)),
+            pl.BlockSpec((1, 1, block_q, G), lambda b, h, i, j: (b, h, i, 0)),
+        ],
+        out_shape=[
+            _struct((B, T, H * D), q.dtype, q, k, v, select),
+            _struct((B, Hkv, T, G), jnp.float32, q, k, v, select),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((block_q, block_k), jnp.float32),
+            pltpu.VMEM((block_q, 128), jnp.float32),
+            pltpu.VMEM((block_q, 128), jnp.float32),
+            pltpu.VMEM((G, block_q, D), jnp.float32),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary"),
+            **_vmem_limit(vmem_mb)),
+        interpret=interpret,
+        name="flash_select_fwd",
+    )(q, k, v, select)
+    return out, lse.transpose(0, 1, 3, 2).reshape(B, H, T)
+
+
+def _select_bwd(q, k, v, select, o, lse, do, H, D, *, scale, causal, block_q,
+                block_k, interpret, seq_len, vmem_mb):
+    """``(dq, dk, dv)`` on head-packed views, a KV group a grid step
+    (:func:`_select_dq_kernel`, :func:`_select_dkdv_kernel`); ``lse``
+    arrives as (B, H, T)."""
+    B, T, _ = q.shape
+    Hkv = k.shape[2] // D
+    G = H // Hkv
+    nq = T // block_q
+    nk = T // block_k
+    # The row statistics a KV group a block: (B, H_kv, T, G).
+    delta = jnp.sum((do.astype(jnp.float32) * o.astype(jnp.float32)
+                     ).reshape(B, T, Hkv, G, D), axis=-1).transpose(0, 2, 1, 3)
+    lse = lse.reshape(B, Hkv, G, T).transpose(0, 1, 3, 2)
+    live_k = _select_live_k(causal, block_q, block_k)
+
+    def live_q(i, j):
+        return jnp.maximum(i, (j * block_k) // block_q) if causal else i
+
+    params = pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
+        **_vmem_limit(vmem_mb))
+    kernel_kw = dict(scale=scale, causal=causal, block_q=block_q,
+                     block_k=block_k, seq_len=seq_len, group=G, head_dim=D)
+
+    kv_q = pl.BlockSpec((1, block_q, G * D),
+                        lambda b, h, j, i: (b, live_q(i, j), h))
+    kv_kv = pl.BlockSpec((1, block_k, D), lambda b, h, j, i: (b, j, h))
+    kv_row = pl.BlockSpec((1, 1, block_q, G),
+                          lambda b, h, j, i: (b, h, live_q(i, j), 0))
+    dk, dv = pl.pallas_call(
+        functools.partial(_select_dkdv_kernel, **kernel_kw),
+        grid=(B, Hkv, nk, nq),
+        in_specs=[kv_q, kv_kv, kv_kv, kv_q, kv_row, kv_row,
+                  pl.BlockSpec((1, block_q, block_k),
+                               lambda b, h, j, i: (b, live_q(i, j), j))],
+        out_specs=[kv_kv, kv_kv],
+        out_shape=[_struct((B, T, Hkv * D), k.dtype, q, k, v, do, select),
+                   _struct((B, T, Hkv * D), v.dtype, q, k, v, do, select)],
+        scratch_shapes=[pltpu.VMEM((block_q, block_k), jnp.float32),
+                        pltpu.VMEM((block_k, D), jnp.float32),
+                        pltpu.VMEM((block_k, D), jnp.float32)],
+        compiler_params=params,
+        interpret=interpret,
+        name="flash_select_dkdv",
+    )(q, k, v, do, lse, delta, select)
+
+    q_q = pl.BlockSpec((1, block_q, G * D), lambda b, h, i, j: (b, i, h))
+    q_kv = pl.BlockSpec((1, block_k, D),
+                        lambda b, h, i, j: (b, live_k(i, j), h))
+    q_row = pl.BlockSpec((1, 1, block_q, G), lambda b, h, i, j: (b, h, i, 0))
+    dq, = pl.pallas_call(
+        functools.partial(_select_dq_kernel, **kernel_kw),
+        grid=(B, Hkv, nq, nk),
+        in_specs=[q_q, q_kv, q_kv, q_q, q_row, q_row,
+                  pl.BlockSpec((1, block_q, block_k),
+                               lambda b, h, i, j: (b, i, live_k(i, j)))],
+        out_specs=[q_q],
+        out_shape=[_struct((B, T, H * D), q.dtype, q, k, v, do, select)],
+        scratch_shapes=[pltpu.VMEM((block_q, block_k), jnp.float32),
+                        pltpu.VMEM((G, block_q, D), jnp.float32)],
+        compiler_params=params,
+        interpret=interpret,
+        name="flash_select_dq",
+    )(q, k, v, do, lse, delta, select)
+    return dq, dk, dv
+
 
 
 class _Plan(NamedTuple):
     """What :func:`_plan` decides for one call of the op."""
-    fwd: str            # "fullunroll" | "unrollkv" | "grid"
+    fwd: str            # "fullunroll" | "unrollkv" | "grid" | "group"
     fwd_tile: int       # the fully-unrolled form's own tile, else 0
     fwd_vmem_mb: int    # scoped-VMEM budget, MB; 0 = Mosaic's default
-    bwd: str            # "grouped" | "per_head"
+    bwd: str            # "grouped" | "per_head" | "group"
     bwd_vmem_mb: int
     bwd_sub: int        # sub-tile of the diagonal block pairs, else 0
     bwd_live_share: float   # of the scores the backward computes
+    # The group form's own (block_q, block_k, bwd_block_q, bwd_block_k).
+    blocks: tuple = ()
 
 
 # The side of the sub-tiles a diagonal block pair of the grouped backward
@@ -1130,12 +1417,20 @@ def _plan(*, T, D, H, head_base, itemsize, causal, block_q, block_k,
     (``shard_map``); ``vmem_headroom``: :func:`_vmem_headroom_ok` —
     whether the device backs a scoped budget above Mosaic's default;
     ``kv_rep``: query heads a KV head (1: multi-head attention);
-    ``select``: whether the call carries a selection map."""
-    if D % 128 or select:
-        # A selection map is read a (block_q, block_k) tile a grid step,
-        # which only these two forms' grids have.
+    ``select``: whether the call carries a selection map — then the group
+    form each way (``"group"``), under the blocks of ``_Plan.blocks``."""
+    if select:
+        # A path of its own (lane-aligned heads only, flash_attention sees
+        # to that): a KV group a grid step, forward and backward.
+        mb = _SELECT_VMEM_MB if vmem_headroom else 0
+        blocks = (_group_block_q(block_q, kv_rep, vmem_headroom), block_k,
+                  _group_block_q(bwd_block_q, kv_rep, vmem_headroom),
+                  bwd_block_k)
+        return _Plan("group", 0, mb, "group", mb, 0, _bwd_live_share(
+            T, causal, blocks[2], blocks[3], sub=0), blocks)
+    if D % 128:
         # Heads off the lane width arrive merged into the batch (H is 1,
-        # see flash_attention); only these two forms have run on a chip
+        # see flash_attention).  Only these two forms have run on a chip
         # at such a D.
         return _Plan("grid", 0, 0, "per_head", 0, 0, _bwd_live_share(
             T, causal, bwd_block_q, bwd_block_k, sub=0))
@@ -1254,15 +1549,9 @@ def _flash_packed_select(q, k, v, select, H, scale, causal, block_q, block_k,
 def _flash_packed_select_fwd(q, k, v, select, H, scale, causal, block_q,
                              block_k, bwd_block_q, bwd_block_k, interpret,
                              seq_len):
-    D = q.shape[2] // H
-    kv_rep = q.shape[2] // k.shape[2]
-    plan = _plan_for(q, H, D, (0, 0, 0), causal, block_q, block_k,
-                     bwd_block_q, bwd_block_k, interpret, kv_rep, select=True)
-    with jax.named_scope("flash_select"):
-        out, lse = _fwd_packed(q, k, v, H, D, plan, scale=scale,
-                               causal=causal, block_q=block_q,
-                               block_k=block_k, interpret=interpret,
-                               seq_len=seq_len, kv_rep=kv_rep, select=select)
+    out, lse = _select_fwd_call(q, k, v, select, H, q.shape[2] // H, scale,
+                                causal, block_q, block_k, bwd_block_q,
+                                bwd_block_k, interpret, seq_len)
     return (out, lse), (q, k, v, select, out, lse)
 
 
@@ -1270,16 +1559,10 @@ def _flash_packed_select_bwd(H, scale, causal, block_q, block_k, bwd_block_q,
                              bwd_block_k, interpret, seq_len, res, cts):
     q, k, v, select, o, lse = res
     do, _ = cts
-    D = q.shape[2] // H
-    kv_rep = q.shape[2] // k.shape[2]
-    plan = _plan_for(q, H, D, (0, 0, 0), causal, block_q, block_k,
-                     bwd_block_q, bwd_block_k, interpret, kv_rep, select=True)
-    with jax.named_scope("flash_select"):
-        dq, dk, dv = _bwd_pallas_packed(
-            q, k, v, o, lse, do, H, D, plan, scale=scale, causal=causal,
-            block_q=bwd_block_q, block_k=bwd_block_k, interpret=interpret,
-            seq_len=seq_len, kv_rep=kv_rep, select=select)
-    return dq, dk, dv, None
+    return (*_select_bwd_call(q, k, v, select, o, lse, do, H,
+                              q.shape[2] // H, scale, causal, block_q,
+                              block_k, bwd_block_q, bwd_block_k, interpret,
+                              seq_len), None)
 
 
 _flash_packed_select.defvjp(_flash_packed_select_fwd,
@@ -1329,6 +1612,41 @@ def _qkv_bwd(qkv, o, lse, do, H, D, scale, causal, block_q, block_k,
         block_q=bwd_block_q, block_k=bwd_block_k, interpret=interpret,
         seq_len=seq_len, head_base=base)
     return jnp.concatenate([dq, dk, dv], axis=-1)          # (B, T, 3C)
+
+
+def _select_plan_for(q, k, H, D, causal, block_q, block_k, bwd_block_q,
+                     bwd_block_k, interpret) -> _Plan:
+    """:func:`_plan_for` under a selection map, on packed ``q`` and ``k``."""
+    return _plan_for(q, H, D, (0, 0, 0), causal, block_q, block_k,
+                     bwd_block_q, bwd_block_k, interpret,
+                     kv_rep=q.shape[2] // k.shape[2], select=True)
+
+
+@_one_trace_a_shape
+def _select_fwd_call(q, k, v, select, H, D, scale, causal, block_q, block_k,
+                     bwd_block_q, bwd_block_k, interpret, seq_len):
+    """(out, lse) under a selection map, as :func:`_plan` has it."""
+    plan = _select_plan_for(q, k, H, D, causal, block_q, block_k,
+                            bwd_block_q, bwd_block_k, interpret)
+    with jax.named_scope("flash_select"):
+        return _select_fwd(q, k, v, select, H, D, scale=scale, causal=causal,
+                           block_q=plan.blocks[0], block_k=plan.blocks[1],
+                           interpret=interpret, seq_len=seq_len,
+                           vmem_mb=plan.fwd_vmem_mb)
+
+
+@_one_trace_a_shape
+def _select_bwd_call(q, k, v, select, o, lse, do, H, D, scale, causal,
+                     block_q, block_k, bwd_block_q, bwd_block_k, interpret,
+                     seq_len):
+    """(dq, dk, dv) under a selection map, as :func:`_plan` has it."""
+    plan = _select_plan_for(q, k, H, D, causal, block_q, block_k,
+                            bwd_block_q, bwd_block_k, interpret)
+    with jax.named_scope("flash_select"):
+        return _select_bwd(q, k, v, select, o, lse, do, H, D, scale=scale,
+                           causal=causal, block_q=plan.blocks[2],
+                           block_k=plan.blocks[3], interpret=interpret,
+                           seq_len=seq_len, vmem_mb=plan.bwd_vmem_mb)
 
 
 @functools.partial(jax.custom_vjp,
@@ -1518,16 +1836,13 @@ def flash_attention_auto(q, k, v, *, causal: bool = True,
     """
     T = q.shape[1]
     interpret = jax.default_backend() != "tpu"
-    blk = auto_block(T)
+    T_pad, blk = _auto_tiling(T)
     more = {} if select is None else {"select": select}
-    if blk >= 64 or blk == T:
+    if T_pad == T:
         return flash_attention(q, k, v, causal=causal, scale=scale,
                                block_q=blk, block_k=blk,
                                interpret=interpret, **more)
-    unit = 256 if T > 256 else 8
-    T_pad = -(-T // unit) * unit
     pad = [(0, 0), (0, T_pad - T), (0, 0), (0, 0)]
-    blk = auto_block(T_pad)   # largest block that tiles the padded length
     if select is not None:
         more = {"select": jnp.pad(select, [(0, 0), (0, T_pad - T),
                                            (0, T_pad - T)])}
@@ -1538,6 +1853,42 @@ def flash_attention_auto(q, k, v, *, causal: bool = True,
     if select is not None:
         return out[0][:, :T], out[1][:, :, :T]
     return out[:, :T]
+
+
+def _auto_tiling(T: int):
+    """``(length run, block)`` of :func:`flash_attention_auto`: ``T`` under
+    :func:`auto_block`'s block, or, where that is degenerate, the next
+    multiple of 256 (of 8 below 256) under the largest block that tiles
+    it."""
+    blk = auto_block(T)
+    if blk >= 64 or blk == T:
+        return T, blk
+    unit = 256 if T > 256 else 8
+    T_pad = -(-T // unit) * unit
+    return T_pad, auto_block(T_pad)
+
+
+def select_tile_fetches(q, k) -> int:
+    """Tiles of its selection map that one causal call of
+    :func:`flash_attention_auto` on ``q`` (B, T, H, D) and ``k`` (B, T,
+    H_kv, D) fetches, forward and backward, as :func:`_plan` has it on this
+    device: a KV group a grid step, so each of the three kernels reads
+    every causal tile once a KV head (a query head a step would read it
+    once a query head)."""
+    B, _, H, D = q.shape
+    Hkv = k.shape[2]
+    T, blk = _auto_tiling(q.shape[1])
+    blocks = _plan_for(
+        jax.ShapeDtypeStruct((B, T, H * D), q.dtype), H, D, (0, 0, 0), True,
+        blk, blk, blk, blk, jax.default_backend() != "tpu",
+        kv_rep=H // Hkv, select=True).blocks
+
+    def causal_tiles(block_q, block_k):
+        return sum(((i + 1) * block_q - 1) // block_k + 1
+                   for i in range(T // block_q))
+
+    return B * Hkv * (causal_tiles(*blocks[:2])
+                      + 2 * causal_tiles(*blocks[2:]))
 
 
 def bwd_kv_block(T: int, block_q: int) -> int:
@@ -1583,11 +1934,14 @@ def flash_attention(q, k, v, *, causal: bool = True,
     where query ``t`` reads key ``s`` — one set a query token, shared by
     its heads; ANDed with the causal mask and the padding's.  The live set
     is then no function of the positions alone: the map is an operand of
-    the grid forward and of the per-head backward pair, a ``(block_q,
-    block_k)`` tile a grid step (lane-aligned heads only), and every
-    causal tile is visited.  Every query must select a key.  The result
-    is then ``(out, lse)`` with ``lse`` ``(B, H, T)`` the log-sum-exp of
-    each head's selected scores (it carries no gradient).
+    three kernels of its own that take a KV group a grid step — a tile of
+    the map is fetched and decoded once for the ``H / Hkv`` query heads
+    that share a KV head (lane-aligned heads only; :func:`_plan` may halve
+    the Q block for a large group) — and every causal tile is visited.
+    Every query must select a key, somewhere in its row (a row that
+    selects none comes out 0).  The result is then ``(out, lse)`` with
+    ``lse`` ``(B, H, T)`` the log-sum-exp of each head's selected scores
+    (it carries no gradient).
     """
     B, T, H, D = q.shape
     Hkv = k.shape[2]

@@ -135,6 +135,8 @@ def test_select_reference_phase(smoke):
                                        head_dim=128, index_heads=2,
                                        index_dim=64, topk=32, seed=0)
     assert out["interpret"] and out["pairs_differing"] == 0
+    assert (out["select_plan"]["fwd"], out["select_plan"]["bwd"],
+            out["select_plan"]["blocks"]) == ("group", "group", (128,) * 4)
     assert out["selected_pairs"] == sum(min(t + 1, 32) for t in range(128))
     assert {"out", "index_kl", "dq", "dk", "dv", "dqI", "dkI", "dw"} < set(
         out)

@@ -108,7 +108,8 @@ def test_tiny_keye_trains_through_make_train_step(hvd):
     want = float(keye_vl2_lm.reference_loss(cfg)(params, aux, tokens))
     step = make_train_step(keye_vl2_lm.loss_fn(cfg), tx, hvd.ranks_mesh())
     names = ("attn.causal_pairs", "attn.selected_pairs", "attn.index_flops",
-             "attn.select_bytes", "moe.assignments", "moe.held_assignments")
+             "attn.select_bytes", "attn.select_tile_fetches",
+             "moe.assignments", "moe.held_assignments")
     before = {n: registry.snapshot()["counters"].get(n, 0) for n in names}
     losses = []
     for _ in range(4):
@@ -127,6 +128,8 @@ def test_tiny_keye_trains_through_make_train_step(hvd):
                    "attn.selected_pairs": 4 * 2 * selected,
                    "attn.index_flops": 4 * 2 * 2 * 4 * 64 * 64 * 65 // 2,
                    "attn.select_bytes": 4 * 2 * 64 * 64,
+                   # One tile of 64, one KV head, three kernels.
+                   "attn.select_tile_fetches": 4 * 2 * 3,
                    "moe.assignments": 4 * 2 * 64 * 3,
                    "moe.held_assignments": 4 * 2 * 64 * 3 // 2}
 

@@ -1,9 +1,11 @@
 """Learned sparse attention's pieces (``ops/sparse_select.py`` and the
-selection operand of ``ops/flash_attention.py``), interpreted on the CPU:
+selected path of ``ops/flash_attention.py``, a KV group a grid step),
+interpreted on the CPU:
 
 * the flash kernels under a selection map against a dense masked softmax,
-  forward and gradients — grouped 8Q:1KV, several blocks, a length padded
-  to its blocks — and, under a map of ones, the causal kernels' without
+  forward, log-sum-exp and gradients — 1, 2 and 8 query heads a KV head,
+  several blocks, a length padded to its blocks, rows whose first key
+  tiles are empty — and, under a map of ones, the causal kernels' without
   one;
 * the exact top-k against a sort, ties included, and the same ``S_t``
   whatever the tile;
@@ -140,17 +142,153 @@ def test_a_selection_is_an_int8_map_over_lane_aligned_heads():
                         select=jnp.ones((1, 64, 64), jnp.int8))
 
 
-def test_a_selection_takes_the_grid_forward_and_the_per_head_pair():
+def selected_lse(q, k, select):
+    """The log-sum-exp of each head's selected scores, (B, H, T)."""
+    H, Hkv, D = q.shape[2], k.shape[2], q.shape[3]
+    s = jnp.einsum("bthd,bshd->bhts", q, jnp.repeat(k, H // Hkv, axis=2))
+    return jax.scipy.special.logsumexp(
+        jnp.where(select[:, None] != 0, s / np.sqrt(D), -jnp.inf), axis=-1)
+
+
+def against_the_oracle(q, k, v, select, block, out_tol=2e-6, grad_tol=5e-6):
+    """Output, log-sum-exp and the three gradients of the selected flash
+    against the dense masked softmax."""
+    weight = jax.random.normal(jax.random.PRNGKey(5), q.shape)
+
+    def ours(q, k, v):
+        return flash_attention(q, k, v, block_q=block, block_k=block,
+                               interpret=True, select=select)
+
+    with jax.default_matmul_precision("highest"):
+        out, lse = ours(q, k, v)
+        assert rel(out, dense_oracle(q, k, v, select)) <= out_tol
+        np.testing.assert_allclose(lse, selected_lse(q, k, select),
+                                   rtol=2e-6, atol=2e-6)
+        got = jax.grad(lambda *a: (ours(*a)[0] * weight).sum(), (0, 1, 2))(
+            q, k, v)
+        ref = jax.grad(lambda *a: (dense_oracle(*a, select) * weight).sum(),
+                       (0, 1, 2))(q, k, v)
+    assert max(rel(a, b) for a, b in zip(got, ref)) <= grad_tol
+
+
+@pytest.mark.parametrize("G", [1, 2, 8])
+def test_the_group_form_at_every_group_size(G):
+    """``G`` query heads a KV head are ``G`` lane slices of one grid step,
+    served from one decoded tile: two KV heads of 1, 2 and 8 query heads,
+    four tiles of 64, with the log-sum-exp of the selected scores as the
+    second output."""
+    B, T, Hkv, D = 1, 128, 2, 128
+    q, k, v = qkv(B, T, G * Hkv, Hkv, D, seed=G)
+    against_the_oracle(q, k, v, random_selection(B, T, seed=10 + G), 64)
+
+
+@pytest.mark.parametrize("which", ["first_tile_empty", "itself_alone"])
+def test_a_row_whose_selected_keys_all_lie_in_later_tiles(which):
+    """"Every query must select a key" holds over the row, not over a
+    tile.  ``first_tile_empty``: the rows of the last two Q blocks read
+    nothing of the first key tile (nor, every other one, of the second),
+    so the running maximum is still ``_NEG_BIG`` when those tiles add
+    their ones — and the first key the row does read wipes them.
+    ``itself_alone``: every third row reads its own position and nothing
+    else, the last key of the last live tile."""
+    B, T, H, Hkv, D, block = 1, 256, 8, 1, 128, 64
+    q, k, v = qkv(B, T, H, Hkv, D, seed=11)
+    select = np.array(random_selection(B, T, share=0.4, seed=12))
+    if which == "first_tile_empty":
+        select[:, 128:, :64] = 0
+        select[:, 128::2, 64:128] = 0
+    else:
+        select[:, ::3, :] = 0
+        select[:, np.arange(0, T, 3), np.arange(0, T, 3)] = 1
+    assert (select.sum(-1) > 0).all()
+    against_the_oracle(q, k, v, jnp.asarray(select), block)
+
+
+def test_a_row_that_selects_nothing_leaves_zeros_and_moves_nothing():
+    """A row without a key (the padding's are such) comes out 0 with a
+    log-sum-exp of 0, and whatever cotangent it is handed reaches no
+    gradient."""
+    B, T, H, Hkv, D = 1, 128, 2, 1, 128
+    q, k, v = qkv(B, T, H, Hkv, D, seed=13)
+    select = np.array(random_selection(B, T, seed=14))
+    select[:, 70] = 0
+
+    def ours(q, k, v):
+        return flash_attention(q, k, v, block_q=64, block_k=64,
+                               interpret=True, select=jnp.asarray(select))
+
+    out, lse = ours(q, k, v)
+    assert not np.asarray(out[:, 70]).any() and not np.asarray(
+        lse[:, :, 70]).any()
+    grads = jax.grad(lambda *a: ours(*a)[0][:, 70].sum(), (0, 1, 2))(q, k, v)
+    assert all(np.isfinite(np.asarray(g)).all() and not np.asarray(g).any()
+               for g in grads)
+
+
+def test_a_selection_takes_a_kv_group_a_grid_step():
     """Every plan without a selection is the one it was (the plan table of
-    ``test_flash_attention.py``); with one, the two forms whose grids have
-    a (block_q, block_k) tile a step, whatever the length."""
+    ``test_flash_attention.py``); with one, the group form each way,
+    whatever the length, with the Q block halved until the group's heads
+    hold at most 4,096 query rows a step under a 32 MB budget — 2,048, and
+    512 a head, under Mosaic's default where the device backs no more."""
     seen = dict(T=2048, D=128, H=16, head_base=(0, 0, 0), itemsize=2,
                 causal=True, block_q=1024, block_k=1024, bwd_block_q=1024,
                 bwd_block_k=1024, interpret=False, manual_axes=False,
                 vmem_headroom=True)
-    assert fa._plan(**seen)[:4] == ("fullunroll", 512, 0, "grouped")
-    assert fa._plan(**seen, select=True)[:6] == ("grid", 0, 0, "per_head",
-                                                 0, 0)
+    assert fa._plan(**seen) == ("fullunroll", 512, 0, "grouped", 32, 256,
+                                0.889, ())
+    assert fa._plan(**seen, kv_rep=8)[:4] == ("fullunroll", 512, 0,
+                                              "per_head")
+    assert fa._plan(**seen, select=True) == (
+        "group", 0, 32, "group", 32, 0, 0.667, (1024,) * 4)
+    keye = dict(seen, T=16_384, H=32, kv_rep=8)
+    assert fa._plan(**keye)[:6] == ("grid", 0, 0, "per_head", 0, 0)
+    assert fa._plan(**keye, select=True) == (
+        "group", 0, 32, "group", 32, 0, 0.941, (512, 1024, 512, 1024))
+    assert fa._plan(**dict(keye, vmem_headroom=False), select=True) == (
+        "group", 0, 0, "group", 0, 0, 0.941, (256, 1024, 256, 1024))
+    for group, block_q in ((1, 1024), (4, 1024), (16, 256)):
+        assert fa._plan(**dict(keye, kv_rep=group), select=True).blocks[0] \
+            == block_q
+    assert fa._plan(**dict(keye, kv_rep=1, vmem_headroom=False),
+                    select=True).blocks == (512, 1024, 512, 1024)
+    # The interpreted tests' blocks are run as they are given.
+    assert fa._plan(**dict(keye, T=256, block_q=64, block_k=64,
+                           bwd_block_q=64, bwd_block_k=64),
+                    select=True).blocks == (64,) * 4
+
+
+def test_a_halved_q_block_is_the_same_attention(monkeypatch):
+    """The plan's own Q block is a schedule detail: with the rows a step
+    cut to 1,024, eight heads run blocks of 128 against key tiles of 256
+    where the caller said 256 — the same output, statistics and gradients
+    as at the caller's blocks, forward and backward."""
+    B, T, H, Hkv, D = 1, 512, 8, 1, 128
+    q, k, v = qkv(B, T, H, Hkv, D, seed=15)
+    select = random_selection(B, T, seed=16)
+    plans = []
+    plan = fa._plan
+    monkeypatch.setattr(
+        fa, "_plan", lambda **seen: plans.append(plan(**seen)) or plans[-1])
+
+    def run():
+        def f(q, k, v):
+            out, lse = flash_attention(q, k, v, block_q=256, block_k=256,
+                                       interpret=True, select=select)
+            return (out ** 2).sum(), (out, lse)
+        jax.clear_caches()
+        return jax.value_and_grad(f, (0, 1, 2), has_aux=True)(q, k, v)
+
+    (_, (out, lse)), grads = run()
+    monkeypatch.setattr(fa, "_GROUP_ROWS", 1024)
+    (_, (out2, lse2)), grads2 = run()
+    assert {p.blocks for p in plans} == {(256,) * 4, (128, 256, 128, 256)}
+    np.testing.assert_allclose(out2, out, rtol=0, atol=2e-6)
+    np.testing.assert_allclose(lse2, lse, rtol=0, atol=2e-6)
+    for a, b in zip(grads2, grads):
+        assert rel(a, b) <= 2e-6
+    with jax.default_matmul_precision("highest"):
+        assert rel(out2, dense_oracle(q, k, v, select)) <= 2e-3
 
 
 # ------------------------------------------------------------ the top-k

@@ -118,7 +118,8 @@ HEAD_KERNEL_EQUATIONS = {"_dkdv_kernel_grouped": 176,
                          "_dq_kernel_grouped": 149}
 
 
-@pytest.mark.parametrize("family", ["flash", "scan", "passes", "experts"])
+@pytest.mark.parametrize("family", ["flash", "scan", "passes", "experts",
+                                    "selected"])
 def test_each_kernel_is_traced_once_a_step_not_once_a_layer(monkeypatch,
                                                             family):
     """The set-up guard, no chip and no compile: tracing ``jax.grad`` of a
@@ -158,11 +159,17 @@ def test_each_kernel_is_traced_once_a_step_not_once_a_layer(monkeypatch,
     shared by the layers: the weight gradient's body is traced twice, the
     other's six times — up and down as the forward that runs, as the
     checkpoint's replay, and read transposed for the input gradients —
-    where a trace a layer would be eight and twenty-four."""
+    where a trace a layer would be eight and twenty-four.
+
+    ``selected``: two sparse-attention layers of a ``KeyeLM`` (8 query
+    heads over one KV head of 128, 32 of up to 256 keys a query).  The
+    drivers ``_select_fwd_call`` / ``_select_bwd_call`` are shared by the
+    layers: each of the three group kernels' bodies — eight unrolled heads
+    each — is traced once, and every layer leaves its three kernels."""
     import collections
     import functools
 
-    from horovod_tpu.models import NemotronHLM, TransformerLM
+    from horovod_tpu.models import KeyeLM, NemotronHLM, TransformerLM
     from horovod_tpu.ops import flash_attention as fa
     from horovod_tpu.ops import grouped_matmul, mixer_passes, ssd
 
@@ -177,7 +184,9 @@ def test_each_kernel_is_traced_once_a_step_not_once_a_layer(monkeypatch,
 
     for name in ("_fwd_kernel", "_fwd_kernel_unrollkv",
                  "_fwd_kernel_fullunroll", "_dq_kernel", "_dkdv_kernel",
-                 "_dq_kernel_grouped", "_dkdv_kernel_grouped"):
+                 "_dq_kernel_grouped", "_dkdv_kernel_grouped",
+                 "_select_fwd_kernel", "_select_dq_kernel",
+                 "_select_dkdv_kernel"):
         monkeypatch.setattr(fa, name, counted(name, getattr(fa, name)))
     if family == "scan":
         for name in ("_fwd_kernel", "_states_kernel", "_bwd_kernel"):
@@ -196,8 +205,18 @@ def test_each_kernel_is_traced_once_a_step_not_once_a_layer(monkeypatch,
 
     # No other test's, nor another case's: a trace made earlier would be
     # shared.
-    batch = {"flash": 3, "scan": 3, "passes": 5, "experts": 2}[family]
-    if family == "flash":
+    batch = {"flash": 3, "scan": 3, "passes": 5, "experts": 2,
+             "selected": 1}[family]
+    if family == "selected":
+        seq = 256
+        model = KeyeLM(vocab=512, dim=256, num_heads=8, kv_heads=1,
+                       pattern="SS", max_len=seq, attn="flash",
+                       dtype=jnp.bfloat16,
+                       indexer=dict(num_heads=2, head_dim=64, topk=32,
+                                    tile=64))
+        want = {"_select_fwd_kernel": 1, "_select_dq_kernel": 1,
+                "_select_dkdv_kernel": 1}
+    elif family == "flash":
         seq = T
         model = TransformerLM(vocab=512, dim=H * D, depth=3, num_heads=H,
                               max_len=T, attn="flash", dtype=jnp.bfloat16)
@@ -223,6 +242,10 @@ def test_each_kernel_is_traced_once_a_step_not_once_a_layer(monkeypatch,
     params = jax.eval_shape(
         lambda key: model.init(key, jnp.zeros((1, seq), jnp.int32))["params"],
         jax.random.PRNGKey(0))
+    if family == "selected":
+        # ``init`` ran the forward with the step's own shapes, and the
+        # forward rule would share that trace.
+        jax.clear_caches()
     calls.clear()
     jaxpr = jax.make_jaxpr(jax.grad(
         lambda p, tokens: model.apply({"params": p}, tokens).astype(
@@ -252,6 +275,12 @@ def test_each_kernel_is_traced_once_a_step_not_once_a_layer(monkeypatch,
 
     found = list(kernels(jaxpr.jaxpr))
     sizes = dict(found)
+    if family == "selected":
+        names = collections.Counter(name for name, _ in found)
+        assert {n: c for n, c in names.items() if "select" in n} == {
+            "flash_select_fwd": 2, "flash_select_dq": 2,
+            "flash_select_dkdv": 2}
+        return
     if family == "experts":
         # Four layers: up and down forward and replayed, their two input
         # gradients, their two weight gradients.
@@ -818,41 +847,115 @@ def test_chunked_delta_rule_fwd_bwd_at_olmo_hybrid_widths(v5e):
 # (the keye_1chip cell: 1 sequence of 16,384, Keye-VL-2.0's widths)
 
 
-def test_selected_attention_fwd_bwd_at_keye_widths(v5e):
+def custom_calls(lowered_text):
+    """``(kernel name, operands)`` of every Pallas TPU kernel in a lowered
+    program, sorted."""
+    import re
+
+    found = []
+    for line in lowered_text.splitlines():
+        call = re.search(r"@tpu_custom_call\(([^)]*)\)", line)
+        if call:
+            name = re.search(r'kernel_name = "([^"]+)"', line).group(1)
+            found.append((name, call.group(1).count("%")))
+    return sorted(found)
+
+
+def pallas_calls(jaxpr):
+    """``(kernel name, grid, operand avals)`` of every ``pallas_call`` in a
+    jaxpr, nested calls included."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield (eqn.params["name"] or
+                   eqn.params["jaxpr"].debug_info.func_name,
+                   tuple(eqn.params["grid_mapping"].grid),
+                   [v.aval for v in eqn.invars])
+        for value in eqn.params.values():
+            for v in value if isinstance(value, (list, tuple)) else [value]:
+                v = getattr(v, "jaxpr", v)
+                if hasattr(v, "eqns"):
+                    yield from pallas_calls(v)
+
+
+@pytest.mark.parametrize("headroom", [True, False],
+                         ids=["512x1024_32MB", "256x1024_default"])
+def test_selected_attention_fwd_bwd_at_keye_widths(v5e, monkeypatch,
+                                                   headroom):
     """One layer's sparse attention as ``GroupedQueryAttention(indexer=…)``
     calls it — 32 query over 4 KV heads of 128 at T 16,384, an indexer of
     16 heads of 64 that keeps 2,048 keys a query — compiles for the chip:
     the scores in four bands (``index_scores``), the exact top-k as plain
-    XLA with no sort and no approximate top-k, the flash grid forward and
-    the per-head backward pair each with the int8 (1, T, T) map as an
-    operand (``flash_select_*``), and the KL pass (``index_kl``).  The map
-    is 256 MiB; a band's float32 scores are at most 1 GiB and no (T, T)
-    float32 array of all heads is ever made."""
+    XLA with no sort and no approximate top-k, the selected attention a KV
+    group a grid step (``flash_select_*``: the int8 (1, T, T) map an
+    operand of each, grids over the 4 KV heads, the eight heads of a group
+    one (block, 1024) block), and the KL pass (``index_kl``).  At both
+    tilings ``_plan`` admits: Q blocks of 512 under a 32 MB budget, and of
+    256 under Mosaic's default where the device backs no more (there the
+    three kernels alone).  The map is 256 MiB; a band's float32 scores are
+    at most 1 GiB and no (T, T) float32 array of all heads is ever made."""
     import re
 
     from horovod_tpu.ops import flash_attention as fa, sparse_select
 
+    monkeypatch.setattr(fa, "_vmem_headroom_ok", lambda: headroom)
+    jax.clear_caches()       # the drivers' traces do not key on the device
     one = SingleDeviceSharding(v5e[0])
     B, T, H, Hkv, D, HI, DI, topk = 1, 16_384, 32, 4, 128, 16, 64, 2048
+    block_q = 512 if headroom else 256
 
     def s(*shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
 
-    def loss(q, k, v, qi, ki, w):
-        select, lse_i = sparse_select.index_select(qi, ki, w, topk, tile=512)
+    def selected(q, k, v, select):
         out, lse = fa.flash_attention(q, k, v, causal=True, block_q=1024,
                                       block_k=1024, select=select)
-        kl = sparse_select.index_kl(qi, ki, w, q, k, lse, select, lse_i)
-        return out.astype(jnp.float32).sum() + kl
+        return out.astype(jnp.float32).sum(), lse
+
+    def loss(q, k, v, qi, ki, w):
+        select, lse_i = sparse_select.index_select(qi, ki, w, topk, tile=512)
+        out, lse = selected(q, k, v, select)
+        return out + sparse_select.index_kl(qi, ki, w, q, k, lse, select,
+                                            lse_i)
+
+    qkv = (s(B, T, H, D), s(B, T, Hkv, D), s(B, T, Hkv, D))
+    alone = jax.value_and_grad(lambda *a: selected(*a)[0], argnums=(0, 1, 2))
+    calls = {name: (grid, avals) for name, grid, avals in pallas_calls(
+        jax.make_jaxpr(alone)(*qkv, s(B, T, T, dtype=jnp.int8)).jaxpr)}
+    n = T // block_q
+    assert {name: grid for name, (grid, _) in calls.items()} == {
+        "flash_select_fwd": (B, Hkv, n, 16),
+        "flash_select_dq": (B, Hkv, n, 16),
+        "flash_select_dkdv": (B, Hkv, 16, n)}
+    for grid, avals in calls.values():
+        assert [(a.shape, str(a.dtype)) for a in avals][-1] == (
+            (B, T, T), "int8")
+    if not headroom:
+        lowered = jax.jit(alone).lower(*qkv, s(B, T, T, dtype=jnp.int8))
+        assert custom_calls(lowered.as_text()) == [
+            ("flash_select_dkdv", 7), ("flash_select_dq", 7),
+            ("flash_select_fwd", 4)]
+        assert "vmem_limit_bytes" not in lowered.as_text()
+        lowered.compile()
+        return
 
     lowered = jax.jit(jax.value_and_grad(loss, argnums=range(6))).lower(
-        s(B, T, H, D), s(B, T, Hkv, D), s(B, T, Hkv, D), s(B, T, HI, DI),
-        s(B, T, DI), s(B, T, HI))
-    names = re.findall(r'kernel_name = "([^"]+)"', lowered.as_text())
+        *qkv, s(B, T, HI, DI), s(B, T, DI), s(B, T, HI))
+    lowered_text = lowered.as_text()
+    names = re.findall(r'kernel_name = "([^"]+)"', lowered_text)
     assert sorted(set(names)) == [
         "flash_select_dkdv", "flash_select_dq", "flash_select_fwd",
         "index_kl", "index_scores"]
     assert names.count("index_scores") == 4                # the bands
+    # The map is an operand of the three kernels, whose row statistics
+    # come a KV head (4), not a query head (32).
+    selected_calls = [line for line in lowered_text.splitlines()
+                      if 'kernel_name = "flash_select_' in line]
+    assert len(selected_calls) == 3
+    for line in selected_calls:
+        operands = line[line.rindex(" : ("):]
+        assert operands.count("tensor<1x16384x16384xi8>") == 1
+        assert "tensor<1x4x16384x8xf32>" in operands
+        assert "x32x16384" not in operands
     compiled = lowered.compile()
     text = compiled.as_text()
     assert "approx" not in text.lower() and " sort(" not in text
@@ -867,3 +970,41 @@ def test_selected_attention_fwd_bwd_at_keye_widths(v5e):
     plan = (m.argument_size_in_bytes + m.output_size_in_bytes
             + m.temp_size_in_bytes - m.alias_size_in_bytes)
     assert plan < 3.0 * 2 ** 30, plan / 2 ** 30
+
+
+@pytest.mark.parametrize("entry,b,t,h,hkv,kernels", [
+    ("proj", 8, 2048, 16, 16, ["_dkdv_kernel_grouped", "_dq_kernel_grouped",
+                               "_fwd_kernel_fullunroll"]),
+    ("split", 4, 4096, 16, 16, ["_dkdv_kernel_grouped", "_dq_kernel_grouped",
+                                "_fwd_kernel_fullunroll"]),
+    ("split", 1, 8192, 30, 30, ["_dkdv_kernel_grouped", "_dq_kernel_grouped",
+                                "_fwd_kernel"]),
+    ("split", 2, 8192, 32, 2, ["_dkdv_kernel", "_dq_kernel", "_fwd_kernel"])],
+    ids=["gpt", "olmoe", "olmo_hybrid", "nemotron_grouped_kv"])
+def test_a_call_without_a_selection_lowers_as_it_did(v5e, entry, b, t, h,
+                                                     hkv, kernels):
+    """The four cells' calls without a map lower to the kernels, and each
+    kernel to the operands, that the parent of PR 37 lowered them to (the
+    literals are its): q, k, v forward; q, k, v, dO and the two row
+    statistics backward.  A plain kernel that still carried a map would
+    read one more."""
+    from horovod_tpu.ops import flash_attention as fa
+
+    one = SingleDeviceSharding(v5e[0])
+
+    def s(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    if entry == "proj":
+        def loss(x, w):
+            return fa.flash_qkv_proj(x, w, h, causal=True).astype(
+                jnp.float32).sum()
+        shapes = (s(b, t, h * D), s(h * D, 3 * h * D, dtype=jnp.float32))
+    else:
+        def loss(q, k, v):
+            return fa.flash_attention(q, k, v, causal=True).astype(
+                jnp.float32).sum()
+        shapes = (s(b, t, h, D), s(b, t, hkv, D), s(b, t, hkv, D))
+    lowered = jax.jit(jax.grad(loss, argnums=range(len(shapes)))).lower(
+        *shapes)
+    assert custom_calls(lowered.as_text()) == list(zip(kernels, (6, 6, 3)))
