@@ -16,6 +16,9 @@ through the entry points a user calls (``hvd.init()`` →
   widths and read out of the projection's one array as the mixer reads
   them, against their XLA forms — forward and every gradient — and
   prints their plan;
+* checks both again at Granite 4.0-H's mixer — ONE group of B and C over
+  the 64 heads in chunks of 256: the scan's heads in tiles, the gated norm
+  over all 4,096 channels (``scan_one_group``, ``passes_one_group``);
 * checks the experts' grouped matmuls as kernels, over a window of
   sorted rows at Nemotron-H's widths and levelled as the layer levels it,
   against ``lax.ragged_dot`` and its transposes — forward, input and
@@ -67,6 +70,13 @@ FLASH_REFERENCE = dict(batch=8, seq=2048, heads=16, head_dim=128)
 SCAN_REFERENCE = dict(batch=2, seq=2048, heads=64, head_dim=64, groups=8,
                       state=128, chunk=128)
 PASSES_REFERENCE = dict(batch=2, seq=2048, heads=64, head_dim=64, groups=8,
+                        state=128, conv_kernel=4)
+# granitehmicro_1chip's mixer: ONE group of B and C over the 64 heads (the
+# scan's heads in 8 tiles, the gated norm over all 4,096 channels), chunks
+# of 256.
+SCAN_ONE_GROUP = dict(batch=1, seq=2048, heads=64, head_dim=64, groups=1,
+                      state=128, chunk=256)
+PASSES_ONE_GROUP = dict(batch=1, seq=2048, heads=64, head_dim=64, groups=1,
                         state=128, conv_kernel=4)
 # One held layer's window of twotower_1chip: 18,432 sorted rows of width
 # 2688 against 8 experts 1856 wide (padded as the plan says).
@@ -1162,6 +1172,10 @@ def main(argv=None) -> int:
             **SCAN_REFERENCE, seed=args.seed))
         emit("passes_reference", **passes_reference_phase(
             **PASSES_REFERENCE, seed=args.seed))
+        emit("scan_one_group", **scan_reference_phase(
+            **SCAN_ONE_GROUP, seed=args.seed))
+        emit("passes_one_group", **passes_reference_phase(
+            **PASSES_ONE_GROUP, seed=args.seed))
         emit("delta_reference", **delta_reference_phase(
             **DELTA_REFERENCE, seed=args.seed))
         emit("experts_reference", **experts_reference_phase(

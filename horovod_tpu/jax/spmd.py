@@ -610,8 +610,9 @@ class _StepInstruments:
     was traced (:func:`noting_expert_layers`) — ``moe.assignments``,
     ``moe.expert_bytes``, ``moe.held_assignments``, ``moe.fused_matmuls``,
     ``ssm.scan_chunks``, ``ssm.state_bytes``, ``ssm.fused_scans``,
-    ``ssm.fused_passes``, ``lin.delta_chunks``, ``lin.state_bytes``; a
-    model without such layers bumps none of those.
+    ``ssm.fused_passes``, ``ssm.head_tiles``, ``ssm.group_channels``,
+    ``lin.delta_chunks``, ``lin.state_bytes``, ``attn.merged_heads``,
+    ``lm.tied_head``; a model without such layers bumps none of those.
     """
 
     _instances = 0

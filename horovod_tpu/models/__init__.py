@@ -6,5 +6,6 @@ from horovod_tpu.models.resnet import (                   # noqa: F401
     ResNet, ResNet50, ResNet101, ResNet152,
 )
 from horovod_tpu.models.transformer import (               # noqa: F401
-    BlockStack, GroupedQueryAttention, KeyeLM, NemotronHLM, OLMoELM,
+    BlockStack, GraniteHybridLM, GroupedQueryAttention, KeyeLM, NemotronHLM,
+    OLMoELM,
     OlmoHybridLM, SwiGLU, TransformerLM, apply_rotary, index_losses)

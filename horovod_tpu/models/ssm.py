@@ -21,13 +21,21 @@ the input projection's one array where rows and channels tile
 (:mod:`horovod_tpu.ops.mixer_passes` chooses; float32 inside, one
 rounding at the store), as XLA on the split arrays otherwise
 (:func:`causal_conv`, in the activations' dtype, and the gate in
-float32).  In a trace the module's scopes are ``in_proj``, ``conv``,
+float32).  What the plans take: groups of B and C from many (8 groups
+of 8 heads, a group a grid step of the scan) down to ONE over every head
+(64 heads of 64: the scan splits the group's heads into tiles of 8, a
+tile a grid step, and sums the tiles' ``dB`` and ``dC``; the gated norm
+holds all 4,096 channels of a row in a block of 128 rows and gathers a
+row's sums 512 channels at a time), chunks of 128 or 256.  In a trace
+the module's scopes are ``in_proj``, ``conv``,
 ``scan``, ``gate_norm`` and ``out_proj``; ``make_train_step`` counts
 ``ssm.scan_chunks``, ``ssm.state_bytes`` (the float32 states passed
 between chunks, in VMEM where the kernels run), ``ssm.fused_scans``
-(scans that took the kernels) and ``ssm.fused_passes`` (of the two
-passes, those that took theirs) from what the module notes of its shapes
-while traced.
+(scans that took the kernels), ``ssm.fused_passes`` (of the two
+passes, those that took theirs), ``ssm.head_tiles`` (the tiles a group's
+heads are split in for the scan: 1 where a group is a grid step) and
+``ssm.group_channels`` (the channels of a group, ``inner / G``) from
+what the module notes of its shapes while traced.
 """
 
 from __future__ import annotations
@@ -252,11 +260,12 @@ class Mamba2Mixer(nn.Module):
         y = gate(conv_and_scan(xBC, dt, conv_w, conv_b, dt_bias, A_log, D),
                  z, scale)
         sizes = scan_sizes(Bsz, T, H, P, N, self.chunk)
-        fused = scan_plan(xBC, dt, heads=H, head_dim=P, groups=G, state=N,
-                          chunk=self.chunk, interpret=interpret
-                          ).form == "kernels"
+        scan = scan_plan(xBC, dt, heads=H, head_dim=P, groups=G, state=N,
+                         chunk=self.chunk, interpret=interpret)
         note_layer(self.path, {"ssm.scan_chunks": sizes["chunks"],
                                "ssm.state_bytes": sizes["state_bytes"],
-                               "ssm.fused_scans": int(fused),
-                               "ssm.fused_passes": 2 * int(fused_passes)})
+                               "ssm.fused_scans": int(scan.form == "kernels"),
+                               "ssm.fused_passes": 2 * int(fused_passes),
+                               "ssm.head_tiles": scan.tiles,
+                               "ssm.group_channels": inner // G})
         return dense(d, "out_proj")(y)

@@ -51,7 +51,18 @@ on its OUTPUT (OLMo 2's residual form: ``h = x + norm(mixer(x))``,
 ``y = h + norm(mlp(h))``): ``L`` a Gated DeltaNet linear-attention mixer
 (:class:`~horovod_tpu.models.linear_attention.GatedDeltaNet`), ``F`` full
 multi-head attention (:class:`Attention`, with the model's ``qk_norm``,
-no positions).  :func:`OlmoHybridLM` is the Olmo-Hybrid setting.
+no positions).  :func:`OlmoHybridLM` is the Olmo-Hybrid setting.  ``m``
+and ``a`` are TWO PRE-norm sub-layers with the model's
+``residual_multiplier`` ``r`` on each one's output (``h = x + r
+mixer(norm(x))``, ``y = h + r mlp(norm(h))``): ``m`` a Mamba-2 mixer,
+``a`` grouped-query attention without positions whose softmax is scaled
+by ``attn_scale``, each followed by a dense :class:`SwiGLU`.  With them
+go ``embedding_multiplier`` (on the embedded tokens),
+``logits_scaling`` (the final hidden states are divided by it) and
+``tie_head`` (no ``head`` parameter: the logits are the hidden states
+times the embedding table transposed, :meth:`TransformerLM.head_kernel`,
+and the table's gradient is the gather's plus the head's).
+:func:`GraniteHybridLM` is the Granite 4.0-H setting.
 """
 
 from __future__ import annotations
@@ -196,6 +207,12 @@ class GroupedQueryAttention(nn.Module):
     over each head's ``head_dim`` channels of q and of k (learned scales
     ``q_norm``, ``k_norm``, epsilon ``norm_eps``), before the positions.
     ``rope_theta``: rotary positions on q and k with this base.
+    ``scale``: the factor on ``q k^T`` (default ``head_dim ** -0.5``).
+    ``make_train_step`` counts ``attn.merged_heads``: the query heads a
+    step sends through the flash family's path for heads off the 128-lane
+    width, which repeats the grouped keys and values and merges the heads
+    into the batch with a transpose each way (0 at a lane-aligned
+    ``head_dim``).
 
     ``indexer`` (``dict(num_heads=, head_dim=, topk=)``, optionally
     ``tile=``): learned sparse attention (:mod:`horovod_tpu.ops.
@@ -217,6 +234,7 @@ class GroupedQueryAttention(nn.Module):
     norm_eps: float = 1e-6
     rope_theta: Optional[float] = None
     indexer: Any = None
+    scale: Optional[float] = None
 
     @nn.compact
     def __call__(self, x):
@@ -240,13 +258,17 @@ class GroupedQueryAttention(nn.Module):
             out = self._selected(x, q, k, v, dense)
             return dense(C, "proj")(out.reshape(B, T, H * D))
         if self.attn == "flash":
-            out = flash_attention_auto(q, k, v, causal=True)
+            out = flash_attention_auto(q, k, v, causal=True,
+                                       scale=self.scale)
         elif self.attn == "full":
             out = full_attention(q, jnp.repeat(k, H // Hkv, axis=2),
-                                 jnp.repeat(v, H // Hkv, axis=2), causal=True)
+                                 jnp.repeat(v, H // Hkv, axis=2), causal=True,
+                                 scale=self.scale)
         else:
             raise ValueError("grouped-query attention runs attn='flash' or "
                              f"'full', not {self.attn!r}")
+        note_layer(self.path, {"attn.merged_heads": (
+            B * H if self.attn == "flash" and D % 128 else 0)})
         return dense(C, "proj")(out.reshape(B, T, H * D))
 
     def _selected(self, x, q, k, v, dense):
@@ -334,8 +356,10 @@ class PatternLayer(nn.Module):
     and ``"S"`` (``attn``) and ``"E"`` (``moe``): ``x + f(norm(x))`` with
     ``f`` the one sub-layer ``kind`` names.  ``"L"`` (``lin``) and ``"F"`` (``attn``):
     ``h = x + mixer_norm(f(x))``, then ``h + mlp_norm(mlp(h))`` with
-    ``mlp`` a :class:`SwiGLU` ``mlp_hidden`` wide.  ``sub`` holds the
-    fields of ``f``."""
+    ``mlp`` a :class:`SwiGLU` ``mlp_hidden`` wide.  ``"m"`` (``ssm``) and
+    ``"a"`` (``attn``): ``h = x + r f(norm(x))``, then ``h + r
+    mlp(mlp_norm(h))`` with ``r`` the ``residual_multiplier``.  ``sub``
+    holds the fields of ``f``."""
     kind: str
     sub: Any
     dtype: Any = jnp.bfloat16
@@ -343,6 +367,7 @@ class PatternLayer(nn.Module):
     norm: str = "rms"
     norm_eps: float = 1e-5
     mlp_hidden: int = 0
+    residual_multiplier: float = 1.0
 
     @nn.compact
     def __call__(self, x):
@@ -361,11 +386,11 @@ class PatternLayer(nn.Module):
             return h + normed(SwiGLU(self.mlp_hidden, self.dtype,
                                      name="mlp")(h), "mlp_norm")
         h = normed(x, "norm")
-        if self.kind == "M":
+        if self.kind in ("M", "m"):
             from horovod_tpu.models.ssm import Mamba2Mixer
             y = Mamba2Mixer(**self.sub, norm_eps=self.norm_eps,
                             dtype=self.dtype, name="ssm")(h)
-        elif self.kind == "*":
+        elif self.kind in ("*", "a"):
             y = GroupedQueryAttention(**self.sub, dtype=self.dtype,
                                       name="attn")(h)
         elif self.kind == "S":
@@ -377,7 +402,12 @@ class PatternLayer(nn.Module):
                                   name="moe")(h)
         else:
             raise ValueError(f"unknown layer {self.kind!r} in a pattern: "
-                             "'M', '*', 'S', 'E', 'L' or 'F'")
+                             "'M', '*', 'S', 'E', 'L', 'F', 'm' or 'a'")
+        if self.kind in ("m", "a"):
+            r = self.residual_multiplier
+            h = x + r * y
+            return h + r * SwiGLU(self.mlp_hidden, self.dtype, name="mlp")(
+                normed(h, "mlp_norm"))
         return x + y
 
 
@@ -523,8 +553,10 @@ class TransformerLM(nn.Module):
     # (GroupedQueryAttention's field); ``moe``: DroplessMoE's further fields (router,
     # renormalize, gate_scale, activation, shared_hidden, held); ``lin``:
     # the fields of GatedDeltaNet; ``F`` layers have num_heads heads of
-    # dim / num_heads with the model's qk_norm; ``L`` and ``F`` layers end
-    # in a SwiGLU mlp_hidden wide.
+    # dim / num_heads with the model's qk_norm; ``L``, ``F``, ``m`` and
+    # ``a`` layers end in a SwiGLU mlp_hidden wide; ``a`` layers scale
+    # their scores by attn_scale (None: head_dim ** -0.5), and ``m`` and
+    # ``a`` layers every sub-layer's output by residual_multiplier.
     pattern: Optional[str] = None
     ssm: Any = None
     kv_heads: Optional[int] = None
@@ -533,6 +565,15 @@ class TransformerLM(nn.Module):
     lin: Any = None
     mlp_hidden: int = 0
     indexer: Any = None
+    attn_scale: Optional[float] = None
+    residual_multiplier: float = 1.0
+    # Of a pattern stack too: the embedded tokens are multiplied by
+    # embedding_multiplier, the final hidden states divided by
+    # logits_scaling, and with tie_head the head is the embedding table
+    # transposed (no ``head`` parameter; head_kernel()).
+    embedding_multiplier: float = 1.0
+    logits_scaling: float = 1.0
+    tie_head: bool = False
 
     @nn.compact
     def __call__(self, tokens, return_hidden=False):
@@ -541,7 +582,9 @@ class TransformerLM(nn.Module):
         :func:`horovod_tpu.ops.losses.fused_softmax_xent` on
         ``params["head"]["kernel"]`` so the (T, vocab) logits are never
         materialized as autodiff residuals (init still uses the default
-        call so the param tree always contains the head)."""
+        call so the param tree always contains the head; with ``tie_head``
+        there is none, and :meth:`head_kernel` gives the matrix).  The
+        hidden states are already divided by ``logits_scaling``."""
         if self.tp_axis and self.attn != "full":
             raise ValueError(
                 "tp_axis composes with attn='full' only (TP attention "
@@ -557,9 +600,14 @@ class TransformerLM(nn.Module):
         if self.pattern is not None:
             return self._pattern_stack(tokens, return_hidden)
         if (self.pos == "none" or self.moe or self.ssm or self.lin
-                or self.mlp_hidden or self.indexer):
-            raise ValueError("pos='none', ssm=, moe=, lin=, indexer= and "
-                             "mlp_hidden= belong to a pattern stack; the block stack "
+                or self.mlp_hidden or self.indexer or self.tie_head
+                or self.attn_scale is not None
+                or (self.residual_multiplier, self.embedding_multiplier,
+                    self.logits_scaling) != (1.0, 1.0, 1.0)):
+            raise ValueError("pos='none', ssm=, moe=, lin=, indexer=, "
+                             "mlp_hidden=, attn_scale=, tie_head= and the "
+                             "three multipliers belong to a pattern stack; "
+                             "the block stack "
                              "takes learned or rotary positions and "
                              "moe_experts")
         rotary = self.pos == "rotary"
@@ -614,20 +662,48 @@ class TransformerLM(nn.Module):
             "L": dict(self.lin or {}),
             "F": dict(num_heads=self.num_heads, attn=self.attn,
                       qk_norm=self.qk_norm),
+            "a": dict(num_heads=self.num_heads, kv_heads=self.kv_heads,
+                      head_dim=self.head_dim, attn=self.attn,
+                      scale=self.attn_scale),
         }
-        x = nn.Embed(self.vocab, self.dim, param_dtype=jnp.float32,
-                     dtype=self.dtype, name="tok_emb")(tokens)
+        subs["m"] = subs["M"]
+        if self.residual_multiplier != 1.0 and set(self.pattern) - {"m", "a"}:
+            raise ValueError("residual_multiplier scales the sub-layers of "
+                             "'m' and 'a' layers only; the pattern "
+                             f"{self.pattern!r} holds others")
+        embed = nn.Embed(self.vocab, self.dim, param_dtype=jnp.float32,
+                         dtype=self.dtype, name="tok_emb")
+        x = embed(tokens)
+        if self.embedding_multiplier != 1.0:
+            x = x * self.embedding_multiplier
         for i, kind in enumerate(self.pattern):
             x = PatternLayer(kind, subs.get(kind), dtype=self.dtype,
                              ln_dtype=self.ln_dtype, norm=self.norm,
                              norm_eps=self.norm_eps,
                              mlp_hidden=self.mlp_hidden,
+                             residual_multiplier=self.residual_multiplier,
                              name=f"layer_{i}")(x)
         x = _norm(self.norm, self.norm_eps, self.ln_dtype, "ln_f")(x)
+        if self.logits_scaling != 1.0:
+            x = x / self.logits_scaling
+        if self.tie_head:
+            note_layer(self.path, {"lm.tied_head": 1})
         if return_hidden:
             return x
+        if self.tie_head:
+            return jnp.dot(x.astype(self.head_dtype),
+                           embed.embedding.astype(self.head_dtype).T)
         return nn.Dense(self.vocab, use_bias=False, dtype=self.head_dtype,
                         param_dtype=jnp.float32, name="head")(x)
+
+    def head_kernel(self, params):
+        """The (dim, vocab) matrix that turns ``return_hidden=True``'s
+        hidden states into logits: ``params["head"]["kernel"]``, or with
+        ``tie_head`` the embedding table transposed — one parameter, whose
+        gradient is then the gather's plus the head's."""
+        if self.tie_head:
+            return params["tok_emb"]["embedding"].T
+        return params["head"]["kernel"]
 
 
 def NemotronHLM(**overrides) -> TransformerLM:
@@ -658,6 +734,37 @@ def NemotronHLM(**overrides) -> TransformerLM:
         moe_experts=128, moe_top_k=6, moe_hidden=1856,
         moe=dict(router="sigmoid", renormalize=True, gate_scale=2.5,
                  activation="relu2", shared_hidden=3712))
+    fields.update(overrides)
+    return TransformerLM(**fields)
+
+
+def GraniteHybridLM(**overrides) -> TransformerLM:
+    """The stack that ``ibm-granite/granite-4.0-h-micro``'s config.json
+    describes (``model_type`` ``granitemoehybrid`` with no experts:
+    ``num_local_experts`` 0, the dense ``shared_intermediate_size`` MLP
+    alone), as a :class:`TransformerLM` with a ``pattern``: 40 layers,
+    ``mmmmmammmm`` four times (``layer_types``), at d 2048, pre-norm
+    RMSNorm eps 1e-5, every sub-layer's output times
+    ``residual_multiplier`` 0.22; ``m`` Mamba-2 mixers of 64 heads of 64
+    whose B and C are ONE group over all heads (state 128, conv 4 with
+    bias, chunks of 256, one gated norm over all 4,096 channels); ``a``
+    attention of 32 query heads over 8 KV heads of 64 with no positions
+    (``position_embedding_type`` "nope") and scores scaled by
+    ``attention_multiplier`` 1/64; a SwiGLU 8192 wide after each; the
+    embedded tokens times ``embedding_multiplier`` 12, the logits the
+    normed hidden states times the embedding table transposed
+    (``tie_word_embeddings``) over ``logits_scaling`` 8; vocab 100352.
+    ``overrides`` replace any field: a cut takes the first letters of the
+    pattern.  Trained like :func:`OLMoELM` through ``make_train_step``
+    and ``fused_softmax_xent`` on ``model.head_kernel(params)``."""
+    fields = dict(
+        vocab=100352, dim=2048, num_heads=32, kv_heads=8, head_dim=64,
+        max_len=131072, norm="rms", norm_eps=1e-5, pos="none",
+        pattern="mmmmmammmm" * 4, attn_scale=0.015625, mlp_hidden=8192,
+        ssm=dict(num_heads=64, head_dim=64, n_groups=1, state_size=128,
+                 conv_kernel=4, chunk=256),
+        embedding_multiplier=12.0, residual_multiplier=0.22,
+        logits_scaling=8.0, tie_head=True)
     fields.update(overrides)
     return TransformerLM(**fields)
 

@@ -37,7 +37,13 @@ option picks a form):
   8-row partial sums a sequence that XLA finishes.  ``ssm_gate_fwd``
   and ``ssm_gate_bwd`` hold whole norm groups; the backward recomputes
   ``g = y silu(z)`` and its norm, writes ``dy`` and ``dz`` in one pass
-  and gathers ``dscale`` the same way.  The drivers are
+  and gathers ``dscale`` the same way.  A norm group wider than a
+  block's 512 columns (one group over all 4,096 channels: the
+  ``granitehmicro_1chip`` cell) is a block by itself, of as many fewer
+  rows, and a strip's sums over it (of ``g^2``; in the backward also of
+  ``dn g``) are gathered 512 columns at a time before each piece is
+  worked through again: the block is in VMEM, so nothing is read twice
+  from HBM, and ``silu(z)`` is computed twice.  The drivers are
   ``jax.jit(inline=True)``: the mixers of a stack share one trace of
   each kernel body.
 * **plain XLA** otherwise (the tiny shapes of the CPU tests, interpreted
@@ -75,6 +81,8 @@ class PassPlan(NamedTuple):
     strip: int           # rows worked on at a time, in registers
     conv_cols: int       # the convolution's channels a block
     gate_cols: int       # the gate's channels a block: whole norm groups
+    #                      (one wider than _MOST_COLS holds as many fewer
+    #                      rows: _gate_rows)
 
 
 # Rows a block (of two-byte activations; half as many of four-byte ones),
@@ -86,6 +94,10 @@ class PassPlan(NamedTuple):
 _ROWS = 1024
 _STRIP = 32
 _MOST_COLS = 512
+# The widest norm group the gate takes: a block of it still holds a strip
+# of rows within a block's bytes (one norm over all 4,096 channels: 128
+# rows of two-byte activations).
+_MOST_GROUP = 16 * _MOST_COLS
 
 
 def _plan(*, T, inner, conv_dim, groups, kernel, itemsize, interpret,
@@ -95,7 +107,10 @@ def _plan(*, T, inner, conv_dim, groups, kernel, itemsize, interpret,
 
     The kernels take a time length in whole strips of rows; norm groups
     of ``inner / groups`` channels in whole 128-lane tiles, no more than
-    a block's most; a convolution whose channels and whose column offset
+    a block's most — or a multiple of that, up to ``_MOST_GROUP``: such a
+    group is a block by itself, of as many fewer rows, and a strip's sums
+    over it are gathered a piece of ``_MOST_COLS`` channels at a time —;
+    a convolution whose channels and whose column offset
     in the packed array (``inner``) are whole 128-lane tiles, with no more
     taps than the rows carried between blocks.  Interpreted Pallas under
     ``shard_map``'s manual axes cannot run in jax 0.9.0 (as in
@@ -104,7 +119,9 @@ def _plan(*, T, inner, conv_dim, groups, kernel, itemsize, interpret,
     if inner % groups or (interpret and manual_axes):
         return xla
     group = inner // groups
-    if (T % _STRIP or group % 128 or group > _MOST_COLS or conv_dim % 128
+    wide = group > _MOST_COLS
+    if (T % _STRIP or group % 128 or conv_dim % 128
+            or (wide and (group % _MOST_COLS or group > _MOST_GROUP))
             or not 1 <= kernel <= _TAIL or itemsize not in (2, 4)):
         return xla
     conv_cols = 128
@@ -249,15 +266,31 @@ def _conv_bwd_kernel(halo_ref, x_ref, dy_ref, w_ref, b_ref, dx_ref, dwb_ref,
         dwb_ref[k] += acc
 
 
-def _gate_parts(y_ref, z_ref, r0, strip, sl, eps):
-    """A strip of one norm group: ``y``, ``z``, ``sigmoid(z)``, the gated
-    ``g = y z sigmoid(z)`` and ``1 / rms(g)``, float32."""
+def _gated(y_ref, z_ref, r0, strip, sl):
+    """A strip of the columns ``sl``: ``y``, ``z``, ``sigmoid(z)`` and the
+    gated ``g = y z sigmoid(z)``, float32."""
     y = y_ref[pl.ds(r0, strip), sl].astype(_F32)
     z = z_ref[pl.ds(r0, strip), sl].astype(_F32)
     sig = _sigmoid(z)
-    g = y * z * sig
+    return y, z, sig, y * z * sig
+
+
+def _gate_parts(y_ref, z_ref, r0, strip, sl, eps):
+    """A strip of one norm group: :func:`_gated` and ``1 / rms(g)``."""
+    y, z, sig, g = _gated(y_ref, z_ref, r0, strip, sl)
     r = lax.rsqrt(jnp.mean(g * g, axis=-1, keepdims=True) + eps)
     return y, z, sig, g, r
+
+
+def _pieces(c, group):
+    """The columns of the norm group that begins at ``c``, a piece of at
+    most ``_MOST_COLS`` at a time: what a strip holds in registers."""
+    piece = min(group, _MOST_COLS)
+    return [slice(p, p + piece) for p in range(c, c + group, piece)]
+
+
+def _row_sum(x):
+    return jnp.sum(x, axis=-1, keepdims=True)
 
 
 def _gate_fwd_kernel(y_ref, z_ref, scale_ref, out_ref, *, group, strip, eps):
@@ -266,10 +299,24 @@ def _gate_fwd_kernel(y_ref, z_ref, scale_ref, out_ref, *, group, strip, eps):
     def body(i, _):
         r0 = pl.multiple_of(i * strip, strip)
         for c in range(0, cols, group):
-            sl = slice(c, c + group)
-            _, _, _, g, r = _gate_parts(y_ref, z_ref, r0, strip, sl, eps)
-            out_ref[pl.ds(r0, strip), sl] = (g * r * scale_ref[:, sl]
-                                             ).astype(out_ref.dtype)
+            pieces = _pieces(c, group)
+            if len(pieces) == 1:
+                _, _, _, g, r = _gate_parts(y_ref, z_ref, r0, strip,
+                                            pieces[0], eps)
+                out_ref[pl.ds(r0, strip), pieces[0]] = (
+                    g * r * scale_ref[:, pieces[0]]).astype(out_ref.dtype)
+                continue
+            # A group wider than a piece: its rows' sums of squares first,
+            # then each piece again (the block is in VMEM: no second read).
+            ss = 0.0
+            for sl in pieces:
+                g = _gated(y_ref, z_ref, r0, strip, sl)[3]
+                ss = ss + _row_sum(g * g)
+            r = lax.rsqrt(ss * (1.0 / group) + eps)
+            for sl in pieces:
+                g = _gated(y_ref, z_ref, r0, strip, sl)[3]
+                out_ref[pl.ds(r0, strip), sl] = (
+                    g * r * scale_ref[:, sl]).astype(out_ref.dtype)
         return 0
 
     lax.fori_loop(0, rows // strip, body, 0)
@@ -286,16 +333,18 @@ def _gate_bwd_kernel(y_ref, z_ref, do_ref, scale_ref, dy_ref, dz_ref,
     def _():
         dscale_ref[...] = jnp.zeros_like(dscale_ref)
 
+    piece = min(group, _MOST_COLS)
+
     def body(i, sums):
         r0 = pl.multiple_of(i * strip, strip)
-        out = []
-        for k, c in enumerate(range(0, cols, group)):
-            sl = slice(c, c + group)
-            y, z, sig, g, r = _gate_parts(y_ref, z_ref, r0, strip, sl, eps)
-            do = do_ref[pl.ds(r0, strip), sl].astype(_F32)
-            n = g * r
-            dn = do * scale_ref[:, sl]
-            dg = r * (dn - n * jnp.mean(dn * n, axis=-1, keepdims=True))
+
+        def cotangent(sl):
+            return do_ref[pl.ds(r0, strip), sl].astype(_F32)
+
+        def finish(sl, y, z, sig, n, r, do, dn, mean_dn_n):
+            """``dy``, ``dz`` of the columns ``sl`` written, and their part
+            of ``dscale`` added to its running sums."""
+            dg = r * (dn - n * mean_dn_n)
             dy_ref[pl.ds(r0, strip), sl] = (dg * z * sig).astype(
                 dy_ref.dtype)
             dz_ref[pl.ds(r0, strip), sl] = (
@@ -304,13 +353,43 @@ def _gate_bwd_kernel(y_ref, z_ref, do_ref, scale_ref, dy_ref, dz_ref,
             if T % rows:
                 ds = jnp.where(_valid_rows(t * rows + r0, strip, T), ds,
                                0.0)
-            out.append(sums[k] + _rows8(ds))
+            return sums[sl.start // piece] + _rows8(ds)
+
+        out = []
+        for c in range(0, cols, group):
+            pieces = _pieces(c, group)
+            if len(pieces) == 1:
+                sl = pieces[0]
+                y, z, sig, g, r = _gate_parts(y_ref, z_ref, r0, strip, sl,
+                                              eps)
+                do = cotangent(sl)
+                n = g * r
+                dn = do * scale_ref[:, sl]
+                out.append(finish(
+                    sl, y, z, sig, n, r, do, dn,
+                    jnp.mean(dn * n, axis=-1, keepdims=True)))
+                continue
+            # A group wider than a piece: its rows' two sums first (of g^2
+            # for the norm, of dn g for the norm's transpose), then each
+            # piece again.
+            ss = sd = 0.0
+            for sl in pieces:
+                g = _gated(y_ref, z_ref, r0, strip, sl)[3]
+                ss = ss + _row_sum(g * g)
+                sd = sd + _row_sum(cotangent(sl) * scale_ref[:, sl] * g)
+            r = lax.rsqrt(ss * (1.0 / group) + eps)
+            mean_dn_n = r * sd * (1.0 / group)
+            for sl in pieces:
+                y, z, sig, g = _gated(y_ref, z_ref, r0, strip, sl)
+                do = cotangent(sl)
+                out.append(finish(sl, y, z, sig, g * r, r, do,
+                                  do * scale_ref[:, sl], mean_dn_n))
         return tuple(out)
 
-    zero = jnp.zeros((8, group), _F32)
-    sums = lax.fori_loop(0, rows // strip, body, (zero,) * (cols // group))
+    zero = jnp.zeros((8, piece), _F32)
+    sums = lax.fori_loop(0, rows // strip, body, (zero,) * (cols // piece))
     for k, acc in enumerate(sums):
-        dscale_ref[:, k * group:(k + 1) * group] += acc
+        dscale_ref[:, k * piece:(k + 1) * piece] += acc
 
 
 # ------------------------------------------------------------- the drivers
@@ -395,9 +474,19 @@ def _gate_specs(plan: PassPlan):
     """Block specs of the gate over (b, T, ...) arrays — ``z`` is the
     packed array's first ``inner`` columns, so one spec serves both —
     and the scale's, grid (batch, channel block, time block)."""
-    rows, cols = plan.rows, plan.gate_cols
+    rows, cols = _gate_rows(plan), plan.gate_cols
     return (pl.BlockSpec((None, rows, cols), lambda i, c, t: (i, t, c)),
             pl.BlockSpec((1, cols), lambda i, c, t: (0, c)))
+
+
+def _gate_rows(plan: PassPlan) -> int:
+    """Time rows a block of the gate: the plan's, or as many fewer as its
+    norm group is wider than ``_MOST_COLS`` (whole strips, one at least):
+    a block's bytes stay what the sweep chose."""
+    if plan.gate_cols <= _MOST_COLS:
+        return plan.rows
+    rows = plan.rows * _MOST_COLS // plan.gate_cols
+    return max(rows // plan.strip, 1) * plan.strip
 
 
 @functools.partial(jax.jit, inline=True,
@@ -408,7 +497,7 @@ def _gate_fwd(y, packed, scale, *, groups, eps, plan: PassPlan, interpret):
     return pl.pallas_call(
         functools.partial(_gate_fwd_kernel, group=inner // groups,
                           strip=plan.strip, eps=eps),
-        grid=(bsz, inner // plan.gate_cols, -(-T // plan.rows)),
+        grid=(bsz, inner // plan.gate_cols, -(-T // _gate_rows(plan))),
         in_specs=[own, own, per_channel],
         out_specs=own,
         out_shape=_struct((bsz, T, inner), y.dtype, y, packed),
@@ -429,7 +518,7 @@ def _gate_bwd(y, packed, scale, do, *, groups, eps, plan: PassPlan,
     dy, dz, dscale = pl.pallas_call(
         functools.partial(_gate_bwd_kernel, group=inner // groups,
                           strip=plan.strip, eps=eps, T=T),
-        grid=(bsz, inner // plan.gate_cols, -(-T // plan.rows)),
+        grid=(bsz, inner // plan.gate_cols, -(-T // _gate_rows(plan))),
         in_specs=[own, own, own, per_channel],
         out_specs=[own, own, sums],
         out_shape=[_struct((bsz, T, inner), y.dtype, *like),

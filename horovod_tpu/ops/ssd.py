@@ -30,7 +30,7 @@ manual mesh axes and the device kind; no option picks a form):
 
 * **Pallas TPU kernels** where the shapes tile (chunks and state in
   multiples of 128, a group's channels in whole 128-lane tiles; the
-  ``twotower_1chip`` cell).  ``ssd_fwd`` walks a sequence's chunks in
+  ``twotower_1chip`` and ``granitehmicro_1chip`` cells).  ``ssd_fwd`` walks a sequence's chunks in
   order — grid ``(batch, group, chunk)``, the chunk axis sequential — with
   the group's running state in VMEM: no chunk-square tile and no chunk
   state goes to HBM.  It reads ``x``, ``B``, ``C`` as column ranges of
@@ -43,7 +43,13 @@ manual mesh axes and the device kind; no option picks a form):
   from the last to the first with the state's gradient carried in VMEM
   and writes ``dx``, ``dB``, ``dC`` (summed over a group's heads in the
   kernel), ``ddt``, and what ``A`` and ``D`` get per position (XLA
-  finishes those sums).  Under the mixer's ``jax.checkpoint`` the
+  finishes those sums).  A group whose blocks would not fit a grid step
+  (one group of B and C over 64 heads of 64 in chunks of 256: the
+  ``granitehmicro_1chip`` cell) is split into head tiles, the fewest whose
+  blocks fit Mosaic's default budget: the grid's second axis then counts
+  tiles, a tile reads its group's ``B`` and ``C`` and writes its part of
+  ``dB`` and ``dC`` in float32, and XLA sums a group's tiles.  Under the
+  mixer's ``jax.checkpoint`` the
   replayed forward leaves no kernel: nothing reads its ``y``.  The
   drivers are ``jax.jit(inline=True)``: the mixers of a stack share one
   trace of each kernel body.
@@ -157,6 +163,14 @@ def _ssd_chunked(x, dt, A, B, C, D, chunk):
 # and only ``B`` (or ``C``) is ever transposed.  The chunk axis is the
 # grid's last and sequential; the state lives in scratch across it.
 #
+# A group too wide for one block (one group over 64 heads: 4,096 channels
+# a row) is split into ``tiles`` head tiles (``_plan``), each a grid step
+# chain of its own along the grid's second axis: the kernels then see R
+# heads of ONE TILE where the text says "group", ``g`` counts tiles, and
+# the tile reads its group's ``B`` and ``C`` (block ``g // tiles``).  What
+# a tile adds to ``dB`` and ``dC`` it writes out in float32, and XLA sums
+# a group's tiles.
+#
 # What a head needs along the sublanes (its running sum as a column, for
 # the decay tile's rows) comes from one small transpose a grid step; what
 # it needs per channel (a column repeated over the head's P lanes) is
@@ -166,19 +180,25 @@ def _ssd_chunked(x, dt, A, B, C, D, chunk):
 
 class _Dims(NamedTuple):
     """The static sizes of a fused scan: heads, channels a head, groups,
-    state width, chunk."""
+    state width, chunk, and the tiles a group's heads are split in (a
+    grid step holds ONE tile of one group: :func:`_plan`)."""
     H: int
     P: int
     G: int
     N: int
     Q: int
+    tiles: int = 1
 
     @property
-    def R(self):                 # heads a group
-        return self.H // self.G
+    def V(self):                 # head tiles a sequence: the grid's axis 1
+        return self.G * self.tiles
 
     @property
-    def RP(self):                # a group's channels
+    def R(self):                 # heads a tile (a whole group's where 1)
+        return self.H // self.V
+
+    @property
+    def RP(self):                # a tile's channels
         return self.R * self.P
 
     @property
@@ -446,19 +466,24 @@ def _bwd_kernel(x_ref, b_ref, c_ref, dt_ref, dy_ref, entering_ref, a_ref,
 
 def _specs(d: _Dims, nc, backward=False):
     """Block specs over ``(b, T, ...)`` arrays: ``x`` and a same-shaped
-    ``y`` / ``dy`` / ``dx``, ``B`` / ``C``: ``cols(width, first)``, group
-    0's block of ``width`` columns being the ``first``-th; ``dt``-shaped
-    (b G, R, T); the states entering the chunks.  ``backward``: chunks
+    ``y`` / ``dy`` / ``dx``, ``B`` / ``C``: ``cols(width, first)``, tile
+    0's block of ``width`` columns being the ``first``-th (``shared``: a
+    group's block, the same for each of its tiles); ``dt``-shaped
+    (b V, R, T); the states entering the chunks.  ``backward``: chunks
     from the last to the first."""
     def chunk(c):
         return nc - 1 - c if backward else c
 
-    def cols(width, first):
+    def cols(width, first, shared=False):
+        if shared and d.tiles > 1:
+            return pl.BlockSpec(
+                (None, d.Q, width),
+                lambda i, g, c: (i, chunk(c), first + g // d.tiles))
         return pl.BlockSpec((None, d.Q, width),
                             lambda i, g, c: (i, chunk(c), first + g))
 
     rows = pl.BlockSpec((None, d.R, d.Q),
-                        lambda i, g, c: (i * d.G + g, 0, chunk(c)))
+                        lambda i, g, c: (i * d.V + g, 0, chunk(c)))
     entering = pl.BlockSpec((None, None, None, d.N, d.RP),
                             lambda i, g, c: (i, g, chunk(c), 0, 0))
     return cols, rows, entering
@@ -476,15 +501,16 @@ def _params(plan, interpret):
 
 
 def _time_minor(dt, d: _Dims):
-    """``dt`` (b, T, H) as (b G, R, T) float32: a chunk's positions on
+    """``dt`` (b, T, H) as (b V, R, T) float32: a chunk's positions on
     the lanes."""
     b, T, _ = dt.shape
-    return dt.astype(_F32).reshape(b, T, d.G, d.R).transpose(
-        0, 2, 3, 1).reshape(b * d.G, d.R, T)
+    return dt.astype(_F32).reshape(b, T, d.V, d.R).transpose(
+        0, 2, 3, 1).reshape(b * d.V, d.R, T)
 
 
 def _bases(d: _Dims):
-    """Column-block offsets of x | B | C in one (b, T, H P + 2 G N) row."""
+    """Column-block offsets of x | B | C in one (b, T, H P + 2 G N) row:
+    x's in blocks of a tile's channels, B's and C's in blocks of N."""
     inner = d.H * d.P
     return 0, inner // d.N, (inner + d.G * d.N) // d.N
 
@@ -500,9 +526,9 @@ def _fused_fwd(xbc, dt, A, D, *, d: _Dims, plan, interpret):
     x0, b0, c0 = _bases(d)
     return pl.pallas_call(
         functools.partial(_fwd_kernel, d=d),
-        grid=(b, d.G, nc),
-        in_specs=[cols(d.RP, x0), cols(d.N, b0), cols(d.N, c0), rows,
-                  _SMEM(), _SMEM()],
+        grid=(b, d.V, nc),
+        in_specs=[cols(d.RP, x0), cols(d.N, b0, True), cols(d.N, c0, True),
+                  rows, _SMEM(), _SMEM()],
         out_specs=cols(d.RP, 0),
         out_shape=_struct((b, T, d.H * d.P), xbc.dtype, xbc, dt),
         scratch_shapes=[pltpu.VMEM((d.N, d.RP), _F32)],
@@ -525,40 +551,46 @@ def _fused_bwd(xbc, dt, A, D, dy, *, d: _Dims, plan, interpret):
     cols, rows, entering = _specs(d, nc)
     states = pl.pallas_call(
         functools.partial(_states_kernel, d=d),
-        grid=(b, d.G, nc),
-        in_specs=[cols(d.RP, x0), cols(d.N, b0), rows, _SMEM()],
+        grid=(b, d.V, nc),
+        in_specs=[cols(d.RP, x0), cols(d.N, b0, True), rows, _SMEM()],
         out_specs=entering,
-        out_shape=_struct((b, d.G, nc, d.N, d.RP), _F32, xbc, dt),
+        out_shape=_struct((b, d.V, nc, d.N, d.RP), _F32, xbc, dt),
         scratch_shapes=[pltpu.VMEM((d.N, d.RP), _F32)],
         interpret=interpret, name="ssd_states", **_params(plan, interpret),
     )(xbc, xbc, dt_rows, A32)
 
     cols, rows, entering = _specs(d, nc, backward=True)
     per_group = pl.BlockSpec((None, d.R, d.Q),
-                             lambda i, g, c: (i * d.G + g, 0, 0))
+                             lambda i, g, c: (i * d.V + g, 0, 0))
     per_channel = pl.BlockSpec((None, d.Q, d.RP),
-                               lambda i, g, c: (i * d.G + g, 0, 0))
+                               lambda i, g, c: (i * d.V + g, 0, 0))
     like = (xbc, dt, dy)
+    # A group's tiles each write their part of dB and dC: float32 where
+    # there is more than one, so that their sum rounds once.
+    part = xbc.dtype if d.tiles == 1 else _F32
     dx, dB, dC, ddt, dA, dD = pl.pallas_call(
         functools.partial(_bwd_kernel, d=d),
-        grid=(b, d.G, nc),
-        in_specs=[cols(d.RP, x0), cols(d.N, b0), cols(d.N, c0), rows,
-                  cols(d.RP, 0), entering, _SMEM(), _SMEM()],
+        grid=(b, d.V, nc),
+        in_specs=[cols(d.RP, x0), cols(d.N, b0, True), cols(d.N, c0, True),
+                  rows, cols(d.RP, 0), entering, _SMEM(), _SMEM()],
         out_specs=[cols(d.RP, 0), cols(d.N, 0), cols(d.N, 0), rows,
                    per_group, per_channel],
         out_shape=[_struct((b, T, d.H * d.P), xbc.dtype, *like),
-                   _struct((b, T, d.G * d.N), xbc.dtype, *like),
-                   _struct((b, T, d.G * d.N), xbc.dtype, *like),
-                   _struct((b * d.G, d.R, T), _F32, *like),
-                   _struct((b * d.G, d.R, d.Q), _F32, *like),
-                   _struct((b * d.G, d.Q, d.RP), _F32, *like)],
+                   _struct((b, T, d.V * d.N), part, *like),
+                   _struct((b, T, d.V * d.N), part, *like),
+                   _struct((b * d.V, d.R, T), _F32, *like),
+                   _struct((b * d.V, d.R, d.Q), _F32, *like),
+                   _struct((b * d.V, d.Q, d.RP), _F32, *like)],
         scratch_shapes=[pltpu.VMEM((d.N, d.RP), _F32)],
         interpret=interpret, name="ssd_bwd", **_params(plan, interpret),
     )(xbc, xbc, xbc, dt_rows, dy, states, A32, D32)
-    ddt = ddt.reshape(b, d.G, d.R, T).transpose(0, 3, 1, 2).reshape(
+    if d.tiles > 1:
+        dB, dC = (a.reshape(b, T, d.G, d.tiles, d.N).sum(3).reshape(
+            b, T, d.G * d.N).astype(xbc.dtype) for a in (dB, dC))
+    ddt = ddt.reshape(b, d.V, d.R, T).transpose(0, 3, 1, 2).reshape(
         b, T, d.H)
-    dA = dA.reshape(b, d.G, d.R, d.Q).sum((0, 3)).reshape(d.H)
-    dD = dD.reshape(b, d.G, d.Q, d.R, d.P).sum((0, 2, 4)).reshape(d.H)
+    dA = dA.reshape(b, d.V, d.R, d.Q).sum((0, 3)).reshape(d.H)
+    dD = dD.reshape(b, d.V, d.Q, d.R, d.P).sum((0, 2, 4)).reshape(d.H)
     return (jnp.concatenate([dx, dB, dC], axis=-1), ddt.astype(dt.dtype),
             dA.astype(A.dtype), dD.astype(D.dtype))
 
@@ -586,10 +618,11 @@ _fused.defvjp(_fused_fwd_rule, _fused_bwd_rule)
 class ScanPlan(NamedTuple):
     """What :func:`_plan` decides for one call of the scan."""
     form: str           # "kernels" | "xla"
-    grid: tuple         # (groups, chunks) a sequence; () in the XLA form
+    grid: tuple         # (head tiles, chunks) a sequence; () in the XLA form
     vmem_bytes: int     # what the largest kernel's blocks, scratch and
     #                     temporaries take, by shapes; 0 in the XLA form
     vmem_mb: int        # scoped-VMEM budget asked, MB; 0 = Mosaic's default
+    tiles: int = 1      # head tiles a group is split in: 1 = a group a step
 
 
 # Mosaic's default scoped-VMEM budget, and the most the kernels ask a
@@ -611,7 +644,13 @@ def _plan(*, T, H, P, G, N, chunk, itemsize, interpret, manual_axes,
     ``shard_map``'s manual axes cannot run in jax 0.9.0 (as in
     ``flash_attention._plan``).  ``vmem_headroom``: whether the device
     backs a scoped budget above Mosaic's default, asked only where the
-    backward kernel's blocks need it."""
+    backward kernel's blocks need it.
+
+    A grid step holds a whole group where its blocks fit (within
+    ``_MOST_VMEM`` on a device with head-room, within Mosaic's default on
+    one without).  A group wider than that (one group over 64 heads: the
+    blocks would ask 92 MB) is split into the fewest head tiles whose
+    blocks fit Mosaic's default budget, each of whole 128-lane tiles."""
     xla = ScanPlan("xla", (), 0, 0)
     if H % G:
         return xla
@@ -621,20 +660,32 @@ def _plan(*, T, H, P, G, N, chunk, itemsize, interpret, manual_axes,
              and (H * P) % N == 0)
     if not tiles or (interpret and manual_axes):
         return xla
-    Q, RP = chunk, d.RP
-    # The backward kernel: x, dy, dx blocks and B, C, dB, dC, each twice
-    # (the pipeline's two buffers); the entering state and dD's gatherer
-    # in float32, twice; the carried gradient; some ten (Q, R P) float32
-    # temporaries and a handful of (Q, Q).
-    asked = (2 * (3 * Q * RP + 4 * Q * N) * itemsize
-             + 2 * (N * RP + Q * RP) * 4 + N * RP * 4
-             + 10 * Q * RP * 4 + 8 * Q * Q * 4)
-    vmem_mb = 0
-    if asked > _DEFAULT_VMEM * 3 // 4:
-        vmem_mb = -(-asked * 4 // 3 // 2 ** 20)
-        if not vmem_headroom or vmem_mb * 2 ** 20 > _MOST_VMEM:
-            return xla
-    return ScanPlan("kernels", (G, -(-T // chunk)), asked, vmem_mb)
+    Q = chunk
+
+    def blocks(RP):
+        # The backward kernel: x, dy, dx blocks and B, C, dB, dC, each
+        # twice (the pipeline's two buffers); the entering state and dD's
+        # gatherer in float32, twice; the carried gradient; some ten
+        # (Q, R P) float32 temporaries and a handful of (Q, Q).
+        return (2 * (3 * Q * RP + 4 * Q * N) * itemsize
+                + 2 * (N * RP + Q * RP) * 4 + N * RP * 4
+                + 10 * Q * RP * 4 + 8 * Q * Q * 4)
+
+    chunks, fits = -(-T // chunk), _DEFAULT_VMEM * 3 // 4
+    asked = blocks(d.RP)
+    if asked <= fits:
+        return ScanPlan("kernels", (G, chunks), asked, 0)
+    vmem_mb = -(-asked * 4 // 3 // 2 ** 20)
+    if vmem_headroom and vmem_mb * 2 ** 20 <= _MOST_VMEM:
+        return ScanPlan("kernels", (G, chunks), asked, vmem_mb)
+    for split in range(2, d.R // d.hp + 1):
+        heads = d.R // split
+        if d.R % split or heads % d.hp or heads * P % 128:
+            continue
+        asked = blocks(heads * P)
+        if asked <= fits:
+            return ScanPlan("kernels", (G * split, chunks), asked, 0, split)
+    return xla
 
 
 def scan_plan(x_like, dt_like, *, heads, head_dim, groups, state, chunk,
@@ -674,7 +725,8 @@ def ssd_scan_packed(xBC, dt, A, D, *, heads: int, groups: int, state: int,
     xBC, dt = _padded((xBC, dt), T, chunk)
     if plan.form == "kernels":
         y = _fused(xBC, dt, A, D, _Dims(heads, inner // heads, groups,
-                                        state, chunk), plan, interpret)
+                                        state, chunk, plan.tiles), plan,
+                   interpret)
     else:
         x, B, C = jnp.split(xBC, [inner, inner + groups * state], axis=-1)
         lead = xBC.shape[:2]

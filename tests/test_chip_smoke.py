@@ -71,11 +71,16 @@ def test_scan_reference_phase(smoke):
                                      groups=2, state=128, chunk=128, seed=0)
     assert out["interpret"]
     assert out["ssd_plan"] == {"form": "kernels", "grid": (2, 2),
-                               "vmem_bytes": 1966080, "vmem_mb": 0}
+                               "vmem_bytes": 1966080, "vmem_mb": 0,
+                               "tiles": 1}
     assert {"out", "grad_xBC", "grad_dt", "grad_A", "grad_D"} < set(out)
     assert smoke.ssd_plan(8192, 64, 64, 8, 128, 128) == {
         "form": "kernels", "grid": (8, 64), "vmem_bytes": 5505024,
-        "vmem_mb": 0}
+        "vmem_mb": 0, "tiles": 1}
+    # One group over 64 heads in chunks of 256: eight head tiles.
+    assert smoke.ssd_plan(8192, 64, 64, 1, 128, 256) == {
+        "form": "kernels", "grid": (8, 32), "vmem_bytes": 11272192,
+        "vmem_mb": 0, "tiles": 8}
     assert smoke.ssd_plan(8192, 64, 64, 8, 128, 16)["form"] == "xla"
     with pytest.raises(RuntimeError, match="plan at the mixer's shape"):
         smoke.scan_reference_phase(batch=1, seq=32, heads=4, head_dim=16,
@@ -98,6 +103,10 @@ def test_passes_reference_phase(smoke):
         "form": "kernels", "rows": 1024, "strip": 32, "conv_cols": 512,
         "gate_cols": 512}
     assert smoke.passes_plan(8200, 64, 64, 8, 128, 4)["form"] == "xla"
+    # One norm group over all 4,096 channels: a gate block of its own.
+    assert smoke.passes_plan(8192, 64, 64, 1, 128, 4) == {
+        "form": "kernels", "rows": 1024, "strip": 32, "conv_cols": 256,
+        "gate_cols": 4096}
     with pytest.raises(RuntimeError, match="plan at the mixer's shape"):
         smoke.passes_reference_phase(batch=1, seq=32, heads=4, head_dim=16,
                                      groups=2, state=16, conv_kernel=4,

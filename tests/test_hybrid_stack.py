@@ -240,8 +240,12 @@ PLAN_TABLE = {
     "groups_do_not_divide": (seen(H=64, G=7), XLA),
     # 64 heads of 64 in one group: blocks past the default budget.
     "one_group_of_4096": (seen(G=1), (KERNELS, (1, 64), 38535168, 49)),
-    "one_group_of_4096_no_headroom": (seen(G=1, vmem_headroom=False), XLA),
-    "one_group_of_8192": (seen(H=128, G=1), XLA),
+    # ... and split into head tiles within the default budget where the
+    # device has no more, or the group is wider (tests/test_ssd_wide_group.py).
+    "one_group_of_4096_no_headroom": (seen(G=1, vmem_headroom=False),
+                                      (KERNELS, (4, 64), 10223616, 0, 4)),
+    "one_group_of_8192": (seen(H=128, G=1),
+                          (KERNELS, (8, 64), 10223616, 0, 8)),
 }
 
 

@@ -38,6 +38,11 @@ SHAPES = {
     "two_blocks_batch_2": (2, 2048, 256, 2, 128, 4),
     "a_block_and_a_tail_batch_2": (2, 1056, 256, 1, 128, 4),
     "one_group_of_512_tiles_of_256": (1, 96, 512, 1, 256, 8),
+    # Norm groups wider than a block's most columns (one group over every
+    # head): a block holds as many fewer rows (64 and a tail of 32 here),
+    # and a strip's sums are gathered a piece of 512 channels at a time.
+    "one_group_of_1024_in_two_pieces_batch_2": (2, 160, 1024, 1, 256, 16),
+    "two_groups_of_1024_in_two_pieces": (1, 64, 2048, 2, 256, 32),
 }
 
 
@@ -244,7 +249,18 @@ PLAN_TABLE = {
     "tiny_preset": (seen(T=64, inner=64, conv_dim=128, groups=2, itemsize=4,
                          interpret=True), XLA),
     "group_of_96": (seen(inner=768, conv_dim=1024, groups=8), XLA),
-    "one_group_of_4096": (seen(groups=1), XLA),
+    # One norm group over all 4,096 channels (one B/C group over 64
+    # heads): a gate block of 128 rows, the convolution's as before.
+    "one_group_of_4096": (seen(groups=1), (KERNELS, 1024, 32, 512, 4096)),
+    "one_group_of_4096_conv_of_4352": (seen(groups=1, conv_dim=4352),
+                                       (KERNELS, 1024, 32, 256, 4096)),
+    "one_group_of_4096_float32": (seen(groups=1, itemsize=4),
+                                  (KERNELS, 512, 32, 512, 4096)),
+    "one_group_wider_than_the_gate_takes": (seen(inner=16384,
+                                                 conv_dim=16640, groups=1),
+                                            XLA),
+    "one_group_of_768_not_in_whole_pieces": (seen(inner=768, conv_dim=1024,
+                                                  groups=1), XLA),
     "groups_do_not_divide": (seen(groups=7), XLA),
     "conv_channels_off_the_tile": (seen(conv_dim=6208), XLA),
     "nine_taps": (seen(kernel=9), XLA),

@@ -541,16 +541,22 @@ def test_chunked_scan_fwd_bwd_at_nemotron_widths(v5e):
 # (b, T, H, P, G, N, chunk, dtype): what else ``ssd._plan`` hands to the
 # kernels, one case a way of tiling — a head of 128 alone in its group,
 # two heads of 64 to a tile, a head wider than a tile, float32 operands at
-# the cell's shape, a wider state, a longer chunk.
+# the cell's shape, a wider state, a longer chunk; and one group over 64
+# heads in chunks of 256 (``granitehmicro_1chip``: the group's heads in 8
+# tiles a grid step each; 16 tiles of float32 operands).
 @pytest.mark.parametrize("b,t,h,p,g,n,chunk,dtype", [
     (2, 1024, 2, 128, 2, 128, 128, "bfloat16"),
     (2, 1024, 4, 64, 2, 128, 128, "bfloat16"),
     (1, 1024, 4, 256, 2, 128, 128, "bfloat16"),
     (2, 8192, 64, 64, 8, 128, 128, "float32"),
     (1, 1024, 16, 64, 2, 256, 128, "bfloat16"),
-    (1, 1024, 16, 64, 2, 128, 256, "bfloat16")],
+    (1, 1024, 16, 64, 2, 128, 256, "bfloat16"),
+    (1, 8192, 64, 64, 1, 128, 256, "bfloat16"),
+    (1, 1024, 64, 64, 1, 128, 256, "float32")],
     ids=["one_head_of_128_a_group", "two_heads_of_64_a_group",
-         "heads_of_256", "cell_float32", "state_256", "chunk_256"])
+         "heads_of_256", "cell_float32", "state_256", "chunk_256",
+         "one_group_of_64_heads_in_8_tiles",
+         "one_group_of_64_heads_float32_in_16_tiles"])
 def test_chunked_scan_compiles_wherever_the_plan_takes_the_kernels(
         v5e, b, t, h, p, g, n, chunk, dtype):
     """A shape ``_plan`` gives the kernels has to compile: interpret mode
@@ -650,7 +656,9 @@ def test_mixer_passes_fwd_bwd_at_nemotron_widths(v5e):
 # float32 activations at the cell's shape (blocks of 512 rows), four norm
 # groups of 128 to a block, an odd count of groups of 256, channels that
 # only tile by 128, a sequence shorter than a block, one that ends inside
-# a block, two taps.
+# a block, two taps; and ONE norm group over all 4,096 channels
+# (``granitehmicro_1chip``: gate blocks of 128 rows, the row's sums
+# gathered 512 channels at a time), in float32, and ending inside a block.
 @pytest.mark.parametrize("b,t,inner,bc,heads,groups,taps,dtype", [
     (2, 8192, 4096, 2048, 64, 8, 4, "float32"),
     (1, 2048, 4096, 2048, 64, 32, 4, "bfloat16"),
@@ -658,10 +666,15 @@ def test_mixer_passes_fwd_bwd_at_nemotron_widths(v5e):
     (1, 2048, 384, 256, 6, 3, 4, "bfloat16"),
     (2, 64, 256, 128, 4, 2, 4, "bfloat16"),
     (2, 1056, 1024, 256, 16, 2, 4, "bfloat16"),
-    (1, 2048, 1024, 256, 16, 2, 2, "bfloat16")],
+    (1, 2048, 1024, 256, 16, 2, 2, "bfloat16"),
+    (1, 8192, 4096, 256, 64, 1, 4, "bfloat16"),
+    (1, 1024, 4096, 256, 64, 1, 4, "float32"),
+    (2, 1056, 1024, 256, 16, 1, 4, "bfloat16")],
     ids=["cell_float32", "groups_of_128", "three_groups_of_256",
          "channels_in_tiles_of_128", "shorter_than_a_block",
-         "ends_inside_a_block", "two_taps"])
+         "ends_inside_a_block", "two_taps", "one_group_of_4096",
+         "one_group_of_4096_float32",
+         "one_group_of_1024_ends_inside_a_block"])
 def test_mixer_passes_compile_wherever_the_plan_takes_the_kernels(
         v5e, b, t, inner, bc, heads, groups, taps, dtype):
     """A shape ``_plan`` gives the kernels has to compile: interpret mode
