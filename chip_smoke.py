@@ -90,6 +90,11 @@ DELTA_REFERENCE = dict(batch=1, seq=512, heads=30, key_dim=96,
 # keeps 512 of up to 2,048 keys a query (a dense float32 (T, T) oracle).
 SELECT_REFERENCE = dict(batch=1, seq=2048, heads=8, kv_heads=1, head_dim=128,
                         index_heads=16, index_dim=64, topk=512)
+# One layer's selected attention at keye_1chip's own shape, kernels alone:
+# 32 query over 4 KV heads of 128 at T 16,384 under a random map of about
+# 2,048 keys a query.
+SELECT_BACKWARD = dict(batch=1, seq=16384, heads=32, kv_heads=4, head_dim=128,
+                       topk=2048)
 ONE_CHIP_LM = dict(**TRANSFORMER, batch=8, steps=3, scan_steps=4)
 ONE_CHIP_RESNET = dict(stage_sizes=(3, 4, 6, 3), num_filters=64,
                        num_classes=1000, image=224, batch=128, steps=3)
@@ -608,9 +613,12 @@ def select_reference_phase(*, batch: int, seq: int, heads: int,
         names = kernels_in(jax.jit(jax.grad(
             lambda *a: program(*a)[0].astype(jnp.float32).sum()
             + program(*a)[1], argnums=range(6))).lower(*operands).as_text())
-        check(names == ["flash_select_dkdv", "flash_select_dq",
-                        "flash_select_fwd", "index_kl", "index_scores"],
-              f"sparse attention lowered to the kernels {names}")
+        backward = (["flash_select_bwd"] if plan["bwd"] == "group_fused"
+                    else ["flash_select_dkdv", "flash_select_dq"])
+        check(names == [*backward, "flash_select_fwd", "index_kl",
+                        "index_scores"],
+              f"sparse attention lowered to the kernels {names}, not to "
+              f"the plan's ({plan['bwd']})")
     got_out, got_kl, got_map = jax.jit(program)(*operands)
     got_grads = scalar(program)(*operands)
     with jax.default_matmul_precision("highest"):
@@ -634,6 +642,88 @@ def select_reference_phase(*, batch: int, seq: int, heads: int,
             "selected_pairs": int(want_map.sum()),
             "pairs_differing": differing,
             **{name: round(err, 5) for name, err in errs.items()}}
+
+
+def select_backward_phase(*, batch: int, seq: int, heads: int, kv_heads: int,
+                          head_dim: int, topk: int, seed: int,
+                          calls: int = 10) -> dict:
+    """The selected attention's kernels alone at one layer's shape, under
+    the blocks ``flash_attention._plan`` gives them on this device: the
+    forward, and the backward in both forms — the dq and the dk-dv kernel
+    of the pair, and the one fused kernel — on the same operands, the
+    fused kernel's three gradients against the pair's.  ``select_plan``
+    says which of the two a call runs here and under which scoped-VMEM
+    budget; ``ms`` is device time a call as the host's clock sees ``calls``
+    of them end (on the chip only: interpreted, no time is reported)."""
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.ops import flash_attention as fa
+
+    interpret = jax.default_backend() != "tpu"
+    B, T, H, Hkv, D = batch, seq, heads, kv_heads, head_dim
+    blocks = fa._resolve_blocks(T, "chip_smoke", None, None, None, None,
+                                None, "")[:4]
+    ks = jax.random.split(jax.random.PRNGKey(seed), 5)
+    q, k, v, do = (jax.random.normal(key, (B, T, h * D)).astype(jnp.bfloat16)
+                   for key, h in zip(ks, (H, Hkv, Hkv, H)))
+    plan = fa._select_plan_for(q, k, H, D, True, *blocks, interpret)
+
+    @jax.jit
+    def random_map(key):
+        t = jnp.arange(T)
+        share = jnp.minimum(1.0, topk / (t + 1.0))[:, None]
+        picked = jax.random.uniform(key, (B, T, T)) < share
+        return ((picked | jnp.eye(T, dtype=bool))
+                & (t[None, :] <= t[:, None])).astype(jnp.int8)
+
+    select = random_map(ks[4])
+
+    def timed(fn, *args):
+        fn = jax.jit(fn)
+        out = jax.block_until_ready(fn(*args))
+        if interpret:
+            return None, out
+        t0 = time.perf_counter()
+        for _ in range(calls):
+            out = fn(*args)
+        jax.block_until_ready(out)
+        return round((time.perf_counter() - t0) / calls * 1e3, 3), out
+
+    common = dict(scale=D ** -0.5, causal=True, interpret=interpret,
+                  seq_len=None)
+    ms = {}
+    ms["forward"], (o, lse) = timed(
+        lambda *a: fa._select_fwd(*a, H, D, block_q=plan.blocks[0],
+                                  block_k=plan.blocks[1],
+                                  vmem_mb=plan.fwd_vmem_mb, **common),
+        q, k, v, select)
+
+    def backward(fused):
+        budget = fa._SELECT_FUSED_VMEM_MB if fused else plan.fwd_vmem_mb
+        return lambda *a: fa._select_bwd(
+            *a, H, D, fused=fused, block_q=plan.blocks[2],
+            block_k=plan.blocks[3], vmem_mb=budget, **common)
+
+    operands = (q, k, v, select, o, lse, do)
+    pair = backward(False)
+    ms["dq"], _ = timed(lambda *a: pair(*a)[0], *operands)
+    ms["dkdv"], _ = timed(lambda *a: pair(*a)[1:], *operands)
+    ms["pair"], want = timed(pair, *operands)
+    errs = {}
+    if plan.fwd_vmem_mb:
+        # (A device at Mosaic's default backs no fused kernel to time.)
+        ms["fused"], got = timed(backward(True), *operands)
+        for name, g, w in zip(("dq", "dk", "dv"), got, want):
+            errs[name] = _rel_err(g, w)
+            check(errs[name] <= SELECT_TOL,
+                  f"the fused selected backward differs from the pair in "
+                  f"{name} by {errs[name]:.3g} (bound {SELECT_TOL})")
+    return {"shape": [B, T, H, Hkv, D, topk], "interpret": interpret,
+            "select_plan": plan._asdict(),
+            "selected_per_query": round(float(select.sum()) / (B * T), 1),
+            "ms_a_layer": ms,
+            "fused_vs_pair": {n: round(e, 6) for n, e in errs.items()}}
 
 
 def delta_reference_phase(*, batch: int, seq: int, heads: int, key_dim: int,
@@ -1182,6 +1272,8 @@ def main(argv=None) -> int:
             **EXPERTS_REFERENCE, seed=args.seed))
         emit("select_reference", **select_reference_phase(
             **SELECT_REFERENCE, seed=args.seed))
+        emit("select_backward", **select_backward_phase(
+            **SELECT_BACKWARD, seed=args.seed))
         emit("transformer_lm", **transformer_phase(
             mesh, events, **ONE_CHIP_LM, seed=args.seed))
         emit("resnet50", **resnet_phase(

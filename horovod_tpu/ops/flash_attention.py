@@ -47,7 +47,13 @@ into 256-wide sub-tiles and leaves those above the diagonal out
 nothing.  The forms that lost to these on the chip (a fused one-pass
 backward, a fully-unrolled backward, a transpose-to-merged backward, a
 chunked XLA backward) left at PR 27; docs/benchmarks.md keeps their
-measurements.
+measurements.  That fused backward took one head a grid step and carried
+``dq`` of the whole sequence in a dynamically indexed scratch through an
+in-kernel loop (T 2048: 4.05 ms against the pair's 2.85); the one that
+runs under a selection map since PR 39 takes a KV group a step, keeps
+``dq`` in a statically indexed scratch and carries the KV head's ``dK``,
+``dV`` across GRID steps — 33.9 ms a layer against the pair's 47.2 at T
+16,384.  The map-less pairs below still form seven products a tile.
 
 Grouped-query attention: ``k`` and ``v`` may hold fewer heads than ``q``.
 At lane-aligned heads nothing is repeated in HBM: the forward's and the dq
@@ -57,11 +63,15 @@ the per-head dk/dv kernel's innermost grid axis runs the Q blocks of the
 form in its scratch.  The grouped pair stands down there.
 
 Under a selection map (``flash_attention(select=...)``, learned sparse
-attention) a path of its own runs, forward and backward: three kernels
-that take a KV group a grid step (:func:`_select_fwd_kernel`,
-:func:`_select_dq_kernel`, :func:`_select_dkdv_kernel`) — the map's tile
-is fetched and decoded once for the query heads that share a KV head.
-The calls without a map share nothing with it.
+attention) a path of its own runs, forward and backward: kernels that
+take a KV group a grid step — the map's tile is fetched and decoded once
+for the query heads that share a KV head.  The forward is
+:func:`_select_fwd_kernel`; the backward is ONE kernel where a KV head's
+``dK`` and ``dV`` may stay in VMEM across its sweep
+(:func:`_select_bwd_kernel`, PR 39: ``S`` and ``dP`` formed once, five
+products a tile) and the pair :func:`_select_dq_kernel`,
+:func:`_select_dkdv_kernel` (seven) where they may not.  The calls
+without a map share nothing with it.
 
 Composition: this is the *single-chip* block; for sequences sharded
 across chips use :mod:`horovod_tpu.parallel.ring_attention`, which
@@ -1029,7 +1039,8 @@ def _bwd_pallas_packed(q, k, v, o, lse, do, H, D, plan, *, scale, causal,
 # head's products against them.  A head pays one add a score element for
 # the selection.  Every causal tile is visited; a step wholly in the
 # causal future fetches nothing (its index maps name the nearest live
-# step's blocks).
+# step's blocks).  `_plan` says whether the backward is one such sweep or
+# two.
 
 
 # The tiling, timed alone at keye_1chip's shape on a v5e (PR 37; one layer,
@@ -1042,6 +1053,22 @@ def _bwd_pallas_packed(q, k, v, o, lse, do, H, D, plan, *, scale, causal,
 # budget; where the device backs no more than Mosaic's default, 2,048, and
 # at most 512 a head (one head's (1024, 1024) float32 tiles overrun 16 MB).
 _SELECT_VMEM_MB = 32
+# The backward as ONE kernel (PR 39; `_select_bwd_kernel`): a KV head's dK
+# and dV, (T, D) float32 each, stay in VMEM across the head's sweep, so the
+# form is taken where those two fit 16 MiB (T 16,384 at D 128: the cell's
+# shape, the longest timed) on a device that backs the budget, and the dq /
+# dk-dv pair runs otherwise.  Alone at keye_1chip's shape on a v5e (ms a
+# layer, key tiles of 1024 / 512): the pair 47.24 / 46.78 at Q blocks of 512
+# and 48.18 / 50.54 at 256; the fused kernel 33.86 / 33.51 and 34.63 / 35.50
+# — 162 TFLOP/s on its five products.  The compiler counts 46.84 MB of
+# scoped VMEM at 512 x 1024 and 8 heads a group (the pair's 14.5, the 16 MiB
+# resident, the two bfloat16 (T, D) output blocks twice over, pipelined),
+# and at the Q blocks `_group_block_q` gives 1, 2, 4 and 16 heads 46.9, 50.3,
+# 55.3 and 43.9: one budget of 64 for all.  Float32 output blocks in place
+# of the scratch read 34.01 ms and count 47.3; a step's sums over its heads
+# formed as values first, 33.90 and 50.1.
+_FUSED_RESIDENT_BYTES = 16 * 2 ** 20
+_SELECT_FUSED_VMEM_MB = 64
 _GROUP_ROWS = 4096
 _GROUP_ROWS_DEFAULT_VMEM = 2048
 _GROUP_HEAD_ROWS_DEFAULT_VMEM = 512
@@ -1231,6 +1258,70 @@ def _select_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dta_ref,
         dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
 
 
+def _select_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dta_ref,
+                       sel_ref, dq_ref, dk_ref, dv_ref, bias_scr, dq_scr,
+                       dk_scr, dv_scr, *, scale, causal, block_q, block_k,
+                       seq_len, group, head_dim):
+    """dq, dk and dv of one KV group in one sweep, grid as the forward's (Q
+    blocks outside, KV blocks innermost): ``p`` and ``dS`` of a head are
+    formed once a tile and feed all three products — five a tile where the
+    dq / dk-dv pair forms seven.  ``dq_scr[g]`` sums over a Q block's KV
+    steps as :func:`_select_dq_kernel`'s does; the KV head's whole ``dK``
+    and ``dV``, (T, D) float32 each, stay in VMEM from the head's first
+    step to its last, and a step adds into their ``kj``-th slice."""
+    qi = pl.program_id(2)
+    kj = pl.program_id(3)
+    nq = pl.num_programs(2)
+    nk = pl.num_programs(3)
+    D = head_dim
+
+    @pl.when(jnp.logical_and(qi == 0, kj == 0))
+    def _init_head():
+        dk_scr[...] = jnp.zeros_like(dk_scr)
+        dv_scr[...] = jnp.zeros_like(dv_scr)
+
+    @pl.when(kj == 0)
+    def _init():
+        dq_scr[...] = jnp.zeros_like(dq_scr)
+
+    live = _select_bias(sel_ref, bias_scr, qi, kj, block_q, block_k, causal,
+                        seq_len)
+
+    @pl.when(live)
+    def _heads():
+        k = k_ref[0]
+        v = v_ref[0]
+        lse = lse_ref[0, 0]                               # (BQ, G)
+        delta = dta_ref[0, 0]
+        rows = pl.ds(pl.multiple_of(kj * block_k, block_k), block_k)
+        for g in range(group):
+            sl = slice(g * D, (g + 1) * D)
+            q = q_ref[0, :, sl]
+            do = do_ref[0, :, sl]
+            p, ds = _select_p_ds(q, k, v, do, lse[:, g:g + 1],
+                                 delta[:, g:g + 1], scale, bias_scr[...])
+            ds = ds.astype(k.dtype)
+            dq_scr[g] += jax.lax.dot_general(
+                ds, k, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            dv_scr[rows, :] += jax.lax.dot_general(
+                p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            dk_scr[rows, :] += jax.lax.dot_general(
+                ds, q, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+
+    @pl.when(kj == nk - 1)
+    def _finalize():
+        for g in range(group):
+            dq_ref[0, :, g * D:(g + 1) * D] = dq_scr[g].astype(dq_ref.dtype)
+
+    @pl.when(jnp.logical_and(qi == nq - 1, kj == nk - 1))
+    def _finalize_head():
+        dk_ref[0] = dk_scr[...].astype(dk_ref.dtype)
+        dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
+
+
 def _select_live_k(causal, block_q, block_k):
     """``(i, j) ->`` the KV block a forward or dq step holds: ``j``, or the
     last live one of Q block ``i`` where ``j`` lies in the causal future
@@ -1286,10 +1377,11 @@ def _select_fwd(q, k, v, select, H, D, *, scale, causal, block_q, block_k,
     return out, lse.transpose(0, 1, 3, 2).reshape(B, H, T)
 
 
-def _select_bwd(q, k, v, select, o, lse, do, H, D, *, scale, causal, block_q,
-                block_k, interpret, seq_len, vmem_mb):
-    """``(dq, dk, dv)`` on head-packed views, a KV group a grid step
-    (:func:`_select_dq_kernel`, :func:`_select_dkdv_kernel`); ``lse``
+def _select_bwd(q, k, v, select, o, lse, do, H, D, *, fused, scale, causal,
+                block_q, block_k, interpret, seq_len, vmem_mb):
+    """``(dq, dk, dv)`` on head-packed views, a KV group a grid step: from
+    one kernel (:func:`_select_bwd_kernel`) where ``fused``, else from the
+    pair (:func:`_select_dq_kernel`, :func:`_select_dkdv_kernel`); ``lse``
     arrives as (B, H, T)."""
     B, T, _ = q.shape
     Hkv = k.shape[2] // D
@@ -1301,6 +1393,43 @@ def _select_bwd(q, k, v, select, o, lse, do, H, D, *, scale, causal, block_q,
                      ).reshape(B, T, Hkv, G, D), axis=-1).transpose(0, 2, 1, 3)
     lse = lse.reshape(B, Hkv, G, T).transpose(0, 1, 3, 2)
     live_k = _select_live_k(causal, block_q, block_k)
+    kernel_kw = dict(scale=scale, causal=causal, block_q=block_q,
+                     block_k=block_k, seq_len=seq_len, group=G, head_dim=D)
+    dq_shape = _struct((B, T, H * D), q.dtype, q, k, v, do, select)
+    dkdv_shapes = [_struct((B, T, Hkv * D), k.dtype, q, k, v, do, select),
+                   _struct((B, T, Hkv * D), v.dtype, q, k, v, do, select)]
+    bias_scr = pltpu.VMEM((block_q, block_k), jnp.float32)
+    dq_scr = pltpu.VMEM((G, block_q, D), jnp.float32)
+
+    # Q blocks outside, KV blocks innermost: the dq kernel's and the fused
+    # kernel's operands.
+    q_q = pl.BlockSpec((1, block_q, G * D), lambda b, h, i, j: (b, i, h))
+    q_kv = pl.BlockSpec((1, block_k, D),
+                        lambda b, h, i, j: (b, live_k(i, j), h))
+    q_row = pl.BlockSpec((1, 1, block_q, G), lambda b, h, i, j: (b, h, i, 0))
+    q_in_specs = [q_q, q_kv, q_kv, q_q, q_row, q_row,
+                  pl.BlockSpec((1, block_q, block_k),
+                               lambda b, h, i, j: (b, i, live_k(i, j)))]
+    if fused:
+        # The KV head's dK and dV: one block a head, written when the head's
+        # sweep ends.
+        head = pl.BlockSpec((1, T, D), lambda b, h, i, j: (b, 0, h))
+        return tuple(pl.pallas_call(
+            functools.partial(_select_bwd_kernel, **kernel_kw),
+            grid=(B, Hkv, nq, nk),
+            in_specs=q_in_specs,
+            out_specs=[q_q, head, head],
+            out_shape=[dq_shape, *dkdv_shapes],
+            scratch_shapes=[bias_scr, dq_scr,
+                            pltpu.VMEM((T, D), jnp.float32),
+                            pltpu.VMEM((T, D), jnp.float32)],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary",
+                                     "arbitrary"),
+                **_vmem_limit(vmem_mb)),
+            interpret=interpret,
+            name="flash_select_bwd",
+        )(q, k, v, do, lse, delta, select))
 
     def live_q(i, j):
         return jnp.maximum(i, (j * block_k) // block_q) if causal else i
@@ -1308,9 +1437,6 @@ def _select_bwd(q, k, v, select, o, lse, do, H, D, *, scale, causal, block_q,
     params = pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         **_vmem_limit(vmem_mb))
-    kernel_kw = dict(scale=scale, causal=causal, block_q=block_q,
-                     block_k=block_k, seq_len=seq_len, group=G, head_dim=D)
-
     kv_q = pl.BlockSpec((1, block_q, G * D),
                         lambda b, h, j, i: (b, live_q(i, j), h))
     kv_kv = pl.BlockSpec((1, block_k, D), lambda b, h, j, i: (b, j, h))
@@ -1323,30 +1449,21 @@ def _select_bwd(q, k, v, select, o, lse, do, H, D, *, scale, causal, block_q,
                   pl.BlockSpec((1, block_q, block_k),
                                lambda b, h, j, i: (b, live_q(i, j), j))],
         out_specs=[kv_kv, kv_kv],
-        out_shape=[_struct((B, T, Hkv * D), k.dtype, q, k, v, do, select),
-                   _struct((B, T, Hkv * D), v.dtype, q, k, v, do, select)],
-        scratch_shapes=[pltpu.VMEM((block_q, block_k), jnp.float32),
+        out_shape=dkdv_shapes,
+        scratch_shapes=[bias_scr,
                         pltpu.VMEM((block_k, D), jnp.float32),
                         pltpu.VMEM((block_k, D), jnp.float32)],
         compiler_params=params,
         interpret=interpret,
         name="flash_select_dkdv",
     )(q, k, v, do, lse, delta, select)
-
-    q_q = pl.BlockSpec((1, block_q, G * D), lambda b, h, i, j: (b, i, h))
-    q_kv = pl.BlockSpec((1, block_k, D),
-                        lambda b, h, i, j: (b, live_k(i, j), h))
-    q_row = pl.BlockSpec((1, 1, block_q, G), lambda b, h, i, j: (b, h, i, 0))
     dq, = pl.pallas_call(
         functools.partial(_select_dq_kernel, **kernel_kw),
         grid=(B, Hkv, nq, nk),
-        in_specs=[q_q, q_kv, q_kv, q_q, q_row, q_row,
-                  pl.BlockSpec((1, block_q, block_k),
-                               lambda b, h, i, j: (b, i, live_k(i, j)))],
+        in_specs=q_in_specs,
         out_specs=[q_q],
-        out_shape=[_struct((B, T, H * D), q.dtype, q, k, v, do, select)],
-        scratch_shapes=[pltpu.VMEM((block_q, block_k), jnp.float32),
-                        pltpu.VMEM((G, block_q, D), jnp.float32)],
+        out_shape=[dq_shape],
+        scratch_shapes=[bias_scr, dq_scr],
         compiler_params=params,
         interpret=interpret,
         name="flash_select_dq",
@@ -1360,7 +1477,7 @@ class _Plan(NamedTuple):
     fwd: str            # "fullunroll" | "unrollkv" | "grid" | "group"
     fwd_tile: int       # the fully-unrolled form's own tile, else 0
     fwd_vmem_mb: int    # scoped-VMEM budget, MB; 0 = Mosaic's default
-    bwd: str            # "grouped" | "per_head" | "group"
+    bwd: str            # "grouped" | "per_head" | "group" | "group_fused"
     bwd_vmem_mb: int
     bwd_sub: int        # sub-tile of the diagonal block pairs, else 0
     bwd_live_share: float   # of the scores the backward computes
@@ -1418,7 +1535,9 @@ def _plan(*, T, D, H, head_base, itemsize, causal, block_q, block_k,
     whether the device backs a scoped budget above Mosaic's default;
     ``kv_rep``: query heads a KV head (1: multi-head attention);
     ``select``: whether the call carries a selection map — then the group
-    form each way (``"group"``), under the blocks of ``_Plan.blocks``."""
+    form each way under the blocks of ``_Plan.blocks``: the backward as one
+    kernel (``"group_fused"``) where a KV head's two gradients may stay in
+    VMEM, else as the dq / dk-dv pair (``"group"``)."""
     if select:
         # A path of its own (lane-aligned heads only, flash_attention sees
         # to that): a KV group a grid step, forward and backward.
@@ -1426,7 +1545,10 @@ def _plan(*, T, D, H, head_base, itemsize, causal, block_q, block_k,
         blocks = (_group_block_q(block_q, kv_rep, vmem_headroom), block_k,
                   _group_block_q(bwd_block_q, kv_rep, vmem_headroom),
                   bwd_block_k)
-        return _Plan("group", 0, mb, "group", mb, 0, _bwd_live_share(
+        fused = vmem_headroom and 2 * T * D * 4 <= _FUSED_RESIDENT_BYTES
+        bwd = ("group_fused", _SELECT_FUSED_VMEM_MB) if fused else (
+            "group", mb)
+        return _Plan("group", 0, mb, *bwd, 0, _bwd_live_share(
             T, causal, blocks[2], blocks[3], sub=0), blocks)
     if D % 128:
         # Heads off the lane width arrive merged into the batch (H is 1,
@@ -1643,7 +1765,8 @@ def _select_bwd_call(q, k, v, select, o, lse, do, H, D, scale, causal,
     plan = _select_plan_for(q, k, H, D, causal, block_q, block_k,
                             bwd_block_q, bwd_block_k, interpret)
     with jax.named_scope("flash_select"):
-        return _select_bwd(q, k, v, select, o, lse, do, H, D, scale=scale,
+        return _select_bwd(q, k, v, select, o, lse, do, H, D,
+                           fused=plan.bwd == "group_fused", scale=scale,
                            causal=causal, block_q=plan.blocks[2],
                            block_k=plan.blocks[3], interpret=interpret,
                            seq_len=seq_len, vmem_mb=plan.bwd_vmem_mb)
@@ -1872,23 +1995,24 @@ def select_tile_fetches(q, k) -> int:
     """Tiles of its selection map that one causal call of
     :func:`flash_attention_auto` on ``q`` (B, T, H, D) and ``k`` (B, T,
     H_kv, D) fetches, forward and backward, as :func:`_plan` has it on this
-    device: a KV group a grid step, so each of the three kernels reads
-    every causal tile once a KV head (a query head a step would read it
-    once a query head)."""
+    device: a KV group a grid step, so each kernel reads every causal tile
+    once a KV head (a query head a step would read it once a query head) —
+    the forward, and the backward's one sweep, or its pair's two."""
     B, _, H, D = q.shape
     Hkv = k.shape[2]
     T, blk = _auto_tiling(q.shape[1])
-    blocks = _plan_for(
+    plan = _plan_for(
         jax.ShapeDtypeStruct((B, T, H * D), q.dtype), H, D, (0, 0, 0), True,
         blk, blk, blk, blk, jax.default_backend() != "tpu",
-        kv_rep=H // Hkv, select=True).blocks
+        kv_rep=H // Hkv, select=True)
 
     def causal_tiles(block_q, block_k):
         return sum(((i + 1) * block_q - 1) // block_k + 1
                    for i in range(T // block_q))
 
-    return B * Hkv * (causal_tiles(*blocks[:2])
-                      + 2 * causal_tiles(*blocks[2:]))
+    sweeps = 1 if plan.bwd == "group_fused" else 2
+    return B * Hkv * (causal_tiles(*plan.blocks[:2])
+                      + sweeps * causal_tiles(*plan.blocks[2:]))
 
 
 def bwd_kv_block(T: int, block_q: int) -> int:
@@ -1934,10 +2058,11 @@ def flash_attention(q, k, v, *, causal: bool = True,
     where query ``t`` reads key ``s`` — one set a query token, shared by
     its heads; ANDed with the causal mask and the padding's.  The live set
     is then no function of the positions alone: the map is an operand of
-    three kernels of its own that take a KV group a grid step — a tile of
-    the map is fetched and decoded once for the ``H / Hkv`` query heads
-    that share a KV head (lane-aligned heads only; :func:`_plan` may halve
-    the Q block for a large group) — and every causal tile is visited.
+    kernels of its own that take a KV group a grid step — a tile of the
+    map is fetched and decoded once for the ``H / Hkv`` query heads that
+    share a KV head (lane-aligned heads only; :func:`_plan` may halve the
+    Q block for a large group, and runs the backward as one kernel or as
+    a pair) — and every causal tile is visited.
     Every query must select a key, somewhere in its row (a row that
     selects none comes out 0).  The result is then ``(out, lse)`` with
     ``lse`` ``(B, H, T)`` the log-sum-exp of each head's selected scores

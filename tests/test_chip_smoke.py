@@ -145,11 +145,32 @@ def test_select_reference_phase(smoke):
                                        index_dim=64, topk=32, seed=0)
     assert out["interpret"] and out["pairs_differing"] == 0
     assert (out["select_plan"]["fwd"], out["select_plan"]["bwd"],
-            out["select_plan"]["blocks"]) == ("group", "group", (128,) * 4)
+            out["select_plan"]["bwd_vmem_mb"],
+            out["select_plan"]["blocks"]) == ("group", "group_fused", 64,
+                                              (128,) * 4)
     assert out["selected_pairs"] == sum(min(t + 1, 32) for t in range(128))
     assert {"out", "index_kl", "dq", "dk", "dv", "dqI", "dkI", "dw"} < set(
         out)
     assert smoke.SELECT_REFERENCE["topk"] < smoke.SELECT_REFERENCE["seq"]
+
+
+def test_select_backward_phase(smoke):
+    """The selected attention's backward in both forms on the same
+    operands (interpreted here: the two agree, and no time is reported),
+    with the plan's choice and budget; on the chip the phase runs at the
+    cell's own shape."""
+    out = smoke.select_backward_phase(batch=1, seq=256, heads=4, kv_heads=2,
+                                      head_dim=128, topk=64, seed=0)
+    assert out["interpret"] and out["ms_a_layer"] == dict.fromkeys(
+        ("forward", "dq", "dkdv", "pair", "fused"))
+    assert (out["select_plan"]["bwd"], out["select_plan"]["bwd_vmem_mb"],
+            out["select_plan"]["blocks"]) == ("group_fused", 64, (256,) * 4)
+    assert set(out["fused_vs_pair"]) == {"dq", "dk", "dv"}
+    assert max(out["fused_vs_pair"].values()) <= 1e-2
+    assert 32 < out["selected_per_query"] <= 64
+    cell = smoke.SELECT_BACKWARD
+    assert (cell["seq"], cell["heads"], cell["kv_heads"], cell["head_dim"],
+            cell["topk"]) == (16_384, 32, 4, 128, 2048)
 
 
 def test_delta_reference_phase(smoke):

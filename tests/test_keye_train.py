@@ -20,6 +20,7 @@ from horovod_tpu.jax.spmd import make_train_step
 from horovod_tpu.metrics import registry
 from horovod_tpu.models import (
     GroupedQueryAttention, KeyeLM, TransformerLM, index_losses)
+from horovod_tpu.ops import flash_attention
 from horovod_tpu.parallel.moe import DroplessMoE
 
 from test_hybrid_stack import share_of
@@ -95,7 +96,7 @@ def test_the_eight_shares_add_up_to_the_uncut_layer():
 # ------------------------------------------------------ the normal path
 
 
-def test_tiny_keye_trains_through_make_train_step(hvd):
+def test_tiny_keye_trains_through_make_train_step(hvd, monkeypatch):
     """The preset through the normal path on the 8-device mesh, the loss
     read every step: the first is the reference's on the global batch, it
     falls, the state stays float32, and each dispatch bumps the attention
@@ -128,10 +129,17 @@ def test_tiny_keye_trains_through_make_train_step(hvd):
                    "attn.selected_pairs": 4 * 2 * selected,
                    "attn.index_flops": 4 * 2 * 2 * 4 * 64 * 64 * 65 // 2,
                    "attn.select_bytes": 4 * 2 * 64 * 64,
-                   # One tile of 64, one KV head, three kernels.
-                   "attn.select_tile_fetches": 4 * 2 * 3,
+                   # One tile of 64, one KV head, two kernels: the
+                   # forward and the fused backward, which the plan takes
+                   # here (the dq / dk-dv pair would read it a third time).
+                   "attn.select_tile_fetches": 4 * 2 * 2,
                    "moe.assignments": 4 * 2 * 64 * 3,
                    "moe.held_assignments": 4 * 2 * 64 * 3 // 2}
+    shard = (jax.ShapeDtypeStruct((1, 64, 2, 128), jnp.bfloat16),
+             jax.ShapeDtypeStruct((1, 64, 1, 128), jnp.bfloat16))
+    assert flash_attention.select_tile_fetches(*shard) == 2
+    monkeypatch.setattr(flash_attention, "_FUSED_RESIDENT_BYTES", 0)
+    assert flash_attention.select_tile_fetches(*shard) == 3
 
 
 def test_what_the_layers_sow_and_the_scopes_they_trace_under():

@@ -3,10 +3,12 @@ selected path of ``ops/flash_attention.py``, a KV group a grid step),
 interpreted on the CPU:
 
 * the flash kernels under a selection map against a dense masked softmax,
-  forward, log-sum-exp and gradients — 1, 2 and 8 query heads a KV head,
+  forward, log-sum-exp and gradients — 1 to 16 query heads a KV head,
   several blocks, a length padded to its blocks, rows whose first key
   tiles are empty — and, under a map of ones, the causal kernels' without
-  one;
+  one; the backward in both forms ``_plan`` knows, the dq / dk-dv pair and
+  the one fused kernel (the ``backward`` fixture), and the fused kernel's
+  float32 parts in its jaxpr;
 * the exact top-k against a sort, ties included, and the same ``S_t``
   whatever the tile;
 * ``L_I`` and its gradient against the dense reference, and that it moves
@@ -41,6 +43,25 @@ def random_selection(B, T, share=0.3, seed=3):
     picked = jax.random.uniform(jax.random.PRNGKey(seed), (B, T, T)) < share
     picked |= jnp.eye(T, dtype=bool)
     return (picked & jnp.tril(jnp.ones((T, T), bool))).astype(jnp.int8)
+
+
+@pytest.fixture(params=["pair", "fused"])
+def backward(request, monkeypatch):
+    """The backward form under a selection, as ``_plan`` picks it: the one
+    fused kernel wherever a KV head's two float32 gradients fit their
+    budget (every size here), the dq / dk-dv pair where they do not — the
+    budget is 0 for ``pair``.  ``seen`` collects the plans of the calls."""
+    if request.param == "pair":
+        monkeypatch.setattr(fa, "_FUSED_RESIDENT_BYTES", 0)
+    seen = []
+    plan = fa._plan
+    monkeypatch.setattr(
+        fa, "_plan", lambda **kw: seen.append(plan(**kw)) or seen[-1])
+    jax.clear_caches()          # the drivers' traces do not key on the budget
+    yield seen
+    assert {p.bwd for p in seen if p.blocks} == {
+        {"pair": "group", "fused": "group_fused"}[request.param]}
+    jax.clear_caches()
 
 
 def dense_oracle(q, k, v, select):
@@ -78,11 +99,12 @@ def test_selected_flash_equals_the_dense_masked_oracle(B, T, H, Hkv, D,
 
 
 @pytest.mark.parametrize("T", [100, 200], ids=["padded_by_auto", "by_hand"])
-def test_a_length_that_is_no_multiple_of_the_block(T):
+def test_a_length_that_is_no_multiple_of_the_block(T, backward):
     """T 100 through ``flash_attention_auto`` (padded to 104, one block)
     and T 200 padded by hand to four blocks of 64: the map is padded with
     zeros, the padding's rows and columns are masked, and the result and
-    the gradients are the unpadded oracle's."""
+    the gradients are the unpadded oracle's — the fused backward's last
+    key tile lies wholly in the padding and stays the zeros it began as."""
     q, k, v = qkv(1, T, 8, 1, 128, seed=2)
     select = random_selection(1, T, seed=4)
 
@@ -171,19 +193,20 @@ def against_the_oracle(q, k, v, select, block, out_tol=2e-6, grad_tol=5e-6):
     assert max(rel(a, b) for a, b in zip(got, ref)) <= grad_tol
 
 
-@pytest.mark.parametrize("G", [1, 2, 8])
-def test_the_group_form_at_every_group_size(G):
+@pytest.mark.parametrize("G", [1, 2, 4, 8, 16])
+def test_the_group_form_at_every_group_size(G, backward):
     """``G`` query heads a KV head are ``G`` lane slices of one grid step,
-    served from one decoded tile: two KV heads of 1, 2 and 8 query heads,
+    served from one decoded tile: two KV heads of 1 to 16 query heads,
     four tiles of 64, with the log-sum-exp of the selected scores as the
-    second output."""
+    second output; backward, the pair's two sweeps or the fused kernel's
+    one, a KV head's dK and dV summed over its ``G`` heads either way."""
     B, T, Hkv, D = 1, 128, 2, 128
     q, k, v = qkv(B, T, G * Hkv, Hkv, D, seed=G)
     against_the_oracle(q, k, v, random_selection(B, T, seed=10 + G), 64)
 
 
 @pytest.mark.parametrize("which", ["first_tile_empty", "itself_alone"])
-def test_a_row_whose_selected_keys_all_lie_in_later_tiles(which):
+def test_a_row_whose_selected_keys_all_lie_in_later_tiles(which, backward):
     """"Every query must select a key" holds over the row, not over a
     tile.  ``first_tile_empty``: the rows of the last two Q blocks read
     nothing of the first key tile (nor, every other one, of the second),
@@ -204,7 +227,7 @@ def test_a_row_whose_selected_keys_all_lie_in_later_tiles(which):
     against_the_oracle(q, k, v, jnp.asarray(select), block)
 
 
-def test_a_row_that_selects_nothing_leaves_zeros_and_moves_nothing():
+def test_a_row_that_selects_nothing_leaves_zeros_and_moves_nothing(backward):
     """A row without a key (the padding's are such) comes out 0 with a
     log-sum-exp of 0, and whatever cotangent it is handed reaches no
     gradient."""
@@ -230,7 +253,9 @@ def test_a_selection_takes_a_kv_group_a_grid_step():
     ``test_flash_attention.py``); with one, the group form each way,
     whatever the length, with the Q block halved until the group's heads
     hold at most 4,096 query rows a step under a 32 MB budget — 2,048, and
-    512 a head, under Mosaic's default where the device backs no more."""
+    512 a head, under Mosaic's default where the device backs no more —,
+    and the backward fused into one kernel where its resident gradients
+    fit.  The map's tiles a call fetches follow the plan."""
     seen = dict(T=2048, D=128, H=16, head_base=(0, 0, 0), itemsize=2,
                 causal=True, block_q=1024, block_k=1024, bwd_block_q=1024,
                 bwd_block_k=1024, interpret=False, manual_axes=False,
@@ -240,13 +265,25 @@ def test_a_selection_takes_a_kv_group_a_grid_step():
     assert fa._plan(**seen, kv_rep=8)[:4] == ("fullunroll", 512, 0,
                                               "per_head")
     assert fa._plan(**seen, select=True) == (
-        "group", 0, 32, "group", 32, 0, 0.667, (1024,) * 4)
+        "group", 0, 32, "group_fused", 64, 0, 0.667, (1024,) * 4)
     keye = dict(seen, T=16_384, H=32, kv_rep=8)
     assert fa._plan(**keye)[:6] == ("grid", 0, 0, "per_head", 0, 0)
+    # The backward is ONE kernel under 64 MB where a KV head's dK and dV,
+    # 2 x T x D float32, fit 16 MiB and the device backs the budget — the
+    # cell's T 16,384 at heads of 128 is the longest that does —, and the
+    # dq / dk-dv pair otherwise: a longer sequence, wider heads at that
+    # length, a device at Mosaic's default.
     assert fa._plan(**keye, select=True) == (
-        "group", 0, 32, "group", 32, 0, 0.941, (512, 1024, 512, 1024))
+        "group", 0, 32, "group_fused", 64, 0, 0.941, (512, 1024, 512, 1024))
+    assert fa._plan(**dict(keye, T=32_768), select=True)[3:5] == ("group", 32)
+    assert fa._plan(**dict(keye, D=256), select=True)[3:5] == ("group", 32)
+    assert fa._plan(**dict(keye, T=8192, D=256), select=True)[3:5] == (
+        "group_fused", 64)
     assert fa._plan(**dict(keye, vmem_headroom=False), select=True) == (
         "group", 0, 0, "group", 0, 0, 0.941, (256, 1024, 256, 1024))
+    for group in (1, 2, 4, 16):
+        assert fa._plan(**dict(keye, kv_rep=group), select=True)[3:5] == (
+            "group_fused", 64)
     for group, block_q in ((1, 1024), (4, 1024), (16, 256)):
         assert fa._plan(**dict(keye, kv_rep=group), select=True).blocks[0] \
             == block_q
@@ -258,18 +295,91 @@ def test_a_selection_takes_a_kv_group_a_grid_step():
                     select=True).blocks == (64,) * 4
 
 
-def test_a_halved_q_block_is_the_same_attention(monkeypatch):
+@pytest.mark.parametrize("form,sweeps", [("fused", 1), ("pair", 2)])
+def test_the_tiles_of_the_map_a_call_fetches_follow_the_plan(monkeypatch,
+                                                             form, sweeps):
+    """``attn.select_tile_fetches`` at the cell's shape: 272 causal tiles
+    of 512 x 1024 a KV head forward, and as many again for each sweep the
+    backward makes — one fused, two as the pair (3,264 a layer until PR
+    39, 2,176 since, on a device that backs the fused kernel's budget)."""
+    if form == "pair":
+        monkeypatch.setattr(fa, "_FUSED_RESIDENT_BYTES", 0)
+    q = jax.ShapeDtypeStruct((1, 16_384, 32, 128), jnp.bfloat16)
+    k = jax.ShapeDtypeStruct((1, 16_384, 4, 128), jnp.bfloat16)
+    assert fa.select_tile_fetches(q, k) == 4 * 272 * (1 + sweeps)
+    monkeypatch.setattr(fa, "_vmem_headroom_ok", lambda: False)
+    # Q blocks of 256 under Mosaic's default, and the pair whatever fits.
+    assert fa.select_tile_fetches(q, k) == 4 * 544 * 3
+
+
+def test_the_fused_backward_keeps_float32_sums_and_multiplies_in_bfloat16():
+    """The cell's ``correct`` does not see a rounded accumulator, so the
+    traced kernel is read: ``p`` and ``dS`` are float32, every one of a
+    head's five products takes the configuration's bfloat16 operands and
+    leaves float32, and ``dq``, ``dK``, ``dV`` are summed in float32
+    scratch — the two resident ones (T, D) — before their one rounding."""
+    B, T, H, Hkv, D, block = 1, 256, 2, 1, 128, 128
+    q, k, v = (a.astype(jnp.bfloat16) for a in qkv(B, T, H, Hkv, D))
+    select = random_selection(B, T)
+    jax.clear_caches()
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda q, k, v: flash_attention(
+            q, k, v, block_q=block, block_k=block, interpret=True,
+            select=select)[0].astype(jnp.float32).sum(), (0, 1, 2)))(q, k, v)
+
+    def equations(jaxpr):
+        for eqn in jaxpr.eqns:
+            yield eqn
+            for value in eqn.params.values():
+                for sub in value if isinstance(value, (list, tuple)) else [
+                        value]:
+                    sub = getattr(sub, "jaxpr", sub)
+                    if hasattr(sub, "eqns"):
+                        yield from equations(sub)
+
+    calls = {eqn.params["name"]: eqn for eqn in equations(jaxpr.jaxpr)
+             if eqn.primitive.name == "pallas_call"}
+    assert sorted(calls) == ["flash_select_bwd", "flash_select_fwd"]
+    call = calls["flash_select_bwd"]
+    assert [(v.aval.shape, str(v.aval.dtype)) for v in call.outvars] == [
+        ((B, T, H * D), "bfloat16"), ((B, T, D), "bfloat16"),
+        ((B, T, D), "bfloat16")]
+    body = call.params["jaxpr"]
+    refs = [(v.aval.shape, str(v.aval.dtype)) for v in body.invars]
+    # The scratch follows 7 operands and 3 results: the decoded tile, dq of
+    # the group's heads, and the KV head's whole dK and dV.
+    assert refs[10:] == [((block, block), "float32"),
+                         ((H // Hkv, block, D), "float32"),
+                         ((T, D), "float32"), ((T, D), "float32")]
+    eqns = list(equations(body))
+    dots = [e for e in eqns if e.primitive.name == "dot_general"]
+    # Two heads, a masked and an unmasked tile body decoded apart from the
+    # heads' loop: five products a head, once.
+    assert len(dots) == 5 * (H // Hkv)
+    for e in dots:
+        assert {str(v.aval.dtype) for v in e.invars} == {"bfloat16"}
+        assert str(e.outvars[0].aval.dtype) == "float32"
+    exps = [e for e in eqns if e.primitive.name == "exp"]
+    assert len(exps) == H // Hkv
+    assert all(str(e.outvars[0].aval.dtype) == "float32" for e in exps)
+    # What is added into the scratch is float32: no accumulator is rounded
+    # on its way.  The only casts to bfloat16 are a head's ``p`` and ``dS``
+    # as operands, its dq's store, and the stores of dK and dV.
+    to_bf16 = [e for e in eqns if e.primitive.name == "convert_element_type"
+               and str(e.outvars[0].aval.dtype) == "bfloat16"]
+    assert len(to_bf16) == 3 * (H // Hkv) + 2
+    jax.clear_caches()
+
+
+def test_a_halved_q_block_is_the_same_attention(monkeypatch, backward):
     """The plan's own Q block is a schedule detail: with the rows a step
     cut to 1,024, eight heads run blocks of 128 against key tiles of 256
     where the caller said 256 — the same output, statistics and gradients
-    as at the caller's blocks, forward and backward."""
+    as at the caller's blocks, forward and backward (the fused kernel then
+    adds two Q blocks' worth into each slice of dK and dV for one)."""
     B, T, H, Hkv, D = 1, 512, 8, 1, 128
     q, k, v = qkv(B, T, H, Hkv, D, seed=15)
     select = random_selection(B, T, seed=16)
-    plans = []
-    plan = fa._plan
-    monkeypatch.setattr(
-        fa, "_plan", lambda **seen: plans.append(plan(**seen)) or plans[-1])
 
     def run():
         def f(q, k, v):
@@ -282,7 +392,7 @@ def test_a_halved_q_block_is_the_same_attention(monkeypatch):
     (_, (out, lse)), grads = run()
     monkeypatch.setattr(fa, "_GROUP_ROWS", 1024)
     (_, (out2, lse2)), grads2 = run()
-    assert {p.blocks for p in plans} == {(256,) * 4, (128, 256, 128, 256)}
+    assert {p.blocks for p in backward} == {(256,) * 4, (128, 256, 128, 256)}
     np.testing.assert_allclose(out2, out, rtol=0, atol=2e-6)
     np.testing.assert_allclose(lse2, lse, rtol=0, atol=2e-6)
     for a, b in zip(grads2, grads):

@@ -119,7 +119,7 @@ HEAD_KERNEL_EQUATIONS = {"_dkdv_kernel_grouped": 176,
 
 
 @pytest.mark.parametrize("family", ["flash", "scan", "passes", "experts",
-                                    "selected"])
+                                    "selected", "selected_pair"])
 def test_each_kernel_is_traced_once_a_step_not_once_a_layer(monkeypatch,
                                                             family):
     """The set-up guard, no chip and no compile: tracing ``jax.grad`` of a
@@ -164,8 +164,11 @@ def test_each_kernel_is_traced_once_a_step_not_once_a_layer(monkeypatch,
     ``selected``: two sparse-attention layers of a ``KeyeLM`` (8 query
     heads over one KV head of 128, 32 of up to 256 keys a query).  The
     drivers ``_select_fwd_call`` / ``_select_bwd_call`` are shared by the
-    layers: each of the three group kernels' bodies — eight unrolled heads
-    each — is traced once, and every layer leaves its three kernels."""
+    layers: the forward's and the fused backward's body — eight unrolled
+    heads each — is traced once, and every layer leaves its two kernels;
+    ``selected_pair``: the same where the plan takes the dq / dk-dv pair
+    (a budget of 0 for the resident gradients): three bodies, once each,
+    three kernels a layer."""
     import collections
     import functools
 
@@ -186,7 +189,7 @@ def test_each_kernel_is_traced_once_a_step_not_once_a_layer(monkeypatch,
                  "_fwd_kernel_fullunroll", "_dq_kernel", "_dkdv_kernel",
                  "_dq_kernel_grouped", "_dkdv_kernel_grouped",
                  "_select_fwd_kernel", "_select_dq_kernel",
-                 "_select_dkdv_kernel"):
+                 "_select_dkdv_kernel", "_select_bwd_kernel"):
         monkeypatch.setattr(fa, name, counted(name, getattr(fa, name)))
     if family == "scan":
         for name in ("_fwd_kernel", "_states_kernel", "_bwd_kernel"):
@@ -206,16 +209,21 @@ def test_each_kernel_is_traced_once_a_step_not_once_a_layer(monkeypatch,
     # No other test's, nor another case's: a trace made earlier would be
     # shared.
     batch = {"flash": 3, "scan": 3, "passes": 5, "experts": 2,
-             "selected": 1}[family]
-    if family == "selected":
+             "selected": 1, "selected_pair": 1}[family]
+    if family.startswith("selected"):
+        if family == "selected_pair":
+            monkeypatch.setattr(fa, "_FUSED_RESIDENT_BYTES", 0)
         seq = 256
         model = KeyeLM(vocab=512, dim=256, num_heads=8, kv_heads=1,
                        pattern="SS", max_len=seq, attn="flash",
                        dtype=jnp.bfloat16,
                        indexer=dict(num_heads=2, head_dim=64, topk=32,
                                     tile=64))
-        want = {"_select_fwd_kernel": 1, "_select_dq_kernel": 1,
-                "_select_dkdv_kernel": 1}
+        want = {"selected": {"_select_fwd_kernel": 1,
+                             "_select_bwd_kernel": 1},
+                "selected_pair": {"_select_fwd_kernel": 1,
+                                  "_select_dq_kernel": 1,
+                                  "_select_dkdv_kernel": 1}}[family]
     elif family == "flash":
         seq = T
         model = TransformerLM(vocab=512, dim=H * D, depth=3, num_heads=H,
@@ -242,7 +250,7 @@ def test_each_kernel_is_traced_once_a_step_not_once_a_layer(monkeypatch,
     params = jax.eval_shape(
         lambda key: model.init(key, jnp.zeros((1, seq), jnp.int32))["params"],
         jax.random.PRNGKey(0))
-    if family == "selected":
+    if family.startswith("selected"):
         # ``init`` ran the forward with the step's own shapes, and the
         # forward rule would share that trace.
         jax.clear_caches()
@@ -275,11 +283,13 @@ def test_each_kernel_is_traced_once_a_step_not_once_a_layer(monkeypatch,
 
     found = list(kernels(jaxpr.jaxpr))
     sizes = dict(found)
-    if family == "selected":
+    if family.startswith("selected"):
         names = collections.Counter(name for name, _ in found)
         assert {n: c for n, c in names.items() if "select" in n} == {
-            "flash_select_fwd": 2, "flash_select_dq": 2,
-            "flash_select_dkdv": 2}
+            "selected": {"flash_select_fwd": 2, "flash_select_bwd": 2},
+            "selected_pair": {"flash_select_fwd": 2, "flash_select_dq": 2,
+                              "flash_select_dkdv": 2}}[family]
+        jax.clear_caches()      # the traces do not key on the budget
         return
     if family == "experts":
         # Four layers: up and down forward and replayed, their two input
@@ -874,6 +884,21 @@ def custom_calls(lowered_text):
     return sorted(found)
 
 
+def scoped_vmem_mb(lowered_text):
+    """``{kernel name: MB}`` of the scoped-VMEM limit each Pallas TPU kernel
+    of a lowered program is compiled under; 0 is Mosaic's default."""
+    import re
+
+    found = {}
+    for line in lowered_text.splitlines():
+        if "@tpu_custom_call(" in line:
+            name = re.search(r'kernel_name = "([^"]+)"', line).group(1)
+            size = re.search(r"scoped_memory_configs[^]]*size\\22: (\d+)",
+                             line)
+            found[name] = int(size.group(1)) >> 20 if size else 0
+    return found
+
+
 def pallas_calls(jaxpr):
     """``(kernel name, grid, operand avals)`` of every ``pallas_call`` in a
     jaxpr, nested calls included."""
@@ -890,10 +915,12 @@ def pallas_calls(jaxpr):
                     yield from pallas_calls(v)
 
 
-@pytest.mark.parametrize("headroom", [True, False],
-                         ids=["512x1024_32MB", "256x1024_default"])
+@pytest.mark.parametrize("headroom,T", [(True, 16_384), (False, 16_384),
+                                        (True, 32_768)],
+                         ids=["512x1024_fused_64MB", "256x1024_pair_default",
+                              "512x1024_pair_32MB_T32768"])
 def test_selected_attention_fwd_bwd_at_keye_widths(v5e, monkeypatch,
-                                                   headroom):
+                                                   headroom, T):
     """One layer's sparse attention as ``GroupedQueryAttention(indexer=…)``
     calls it — 32 query over 4 KV heads of 128 at T 16,384, an indexer of
     16 heads of 64 that keeps 2,048 keys a query — compiles for the chip:
@@ -901,11 +928,15 @@ def test_selected_attention_fwd_bwd_at_keye_widths(v5e, monkeypatch,
     XLA with no sort and no approximate top-k, the selected attention a KV
     group a grid step (``flash_select_*``: the int8 (1, T, T) map an
     operand of each, grids over the 4 KV heads, the eight heads of a group
-    one (block, 1024) block), and the KL pass (``index_kl``).  At both
-    tilings ``_plan`` admits: Q blocks of 512 under a 32 MB budget, and of
-    256 under Mosaic's default where the device backs no more (there the
-    three kernels alone).  The map is 256 MiB; a band's float32 scores are
-    at most 1 GiB and no (T, T) float32 array of all heads is ever made."""
+    one (block, 1024) block), and the KL pass (``index_kl``).  At every
+    tiling ``_plan`` admits: Q blocks of 512 with the backward ONE kernel
+    under its 64 MB budget (``flash_select_fwd`` and ``flash_select_bwd``:
+    two calls, the map read twice); Q blocks of 256 and the dq / dk-dv pair
+    under Mosaic's default where the device backs no more; and the pair at
+    Q blocks of 512 under 32 MB where a KV head's dK and dV no longer fit
+    their 16 MiB (T 32,768; the last two the kernels alone).  The map is
+    256 MiB; a band's float32 scores are at most 1 GiB and no (T, T)
+    float32 array of all heads is ever made."""
     import re
 
     from horovod_tpu.ops import flash_attention as fa, sparse_select
@@ -913,8 +944,9 @@ def test_selected_attention_fwd_bwd_at_keye_widths(v5e, monkeypatch,
     monkeypatch.setattr(fa, "_vmem_headroom_ok", lambda: headroom)
     jax.clear_caches()       # the drivers' traces do not key on the device
     one = SingleDeviceSharding(v5e[0])
-    B, T, H, Hkv, D, HI, DI, topk = 1, 16_384, 32, 4, 128, 16, 64, 2048
+    B, H, Hkv, D, HI, DI, topk = 1, 32, 4, 128, 16, 64, 2048
     block_q = 512 if headroom else 256
+    fused = headroom and T == 16_384
 
     def s(*shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
@@ -934,20 +966,22 @@ def test_selected_attention_fwd_bwd_at_keye_widths(v5e, monkeypatch,
     alone = jax.value_and_grad(lambda *a: selected(*a)[0], argnums=(0, 1, 2))
     calls = {name: (grid, avals) for name, grid, avals in pallas_calls(
         jax.make_jaxpr(alone)(*qkv, s(B, T, T, dtype=jnp.int8)).jaxpr)}
-    n = T // block_q
+    n, nk = T // block_q, T // 1024
+    backward = ({"flash_select_bwd": (B, Hkv, n, nk)} if fused else
+                {"flash_select_dq": (B, Hkv, n, nk),
+                 "flash_select_dkdv": (B, Hkv, nk, n)})
     assert {name: grid for name, (grid, _) in calls.items()} == {
-        "flash_select_fwd": (B, Hkv, n, 16),
-        "flash_select_dq": (B, Hkv, n, 16),
-        "flash_select_dkdv": (B, Hkv, 16, n)}
+        "flash_select_fwd": (B, Hkv, n, nk), **backward}
     for grid, avals in calls.values():
         assert [(a.shape, str(a.dtype)) for a in avals][-1] == (
             (B, T, T), "int8")
-    if not headroom:
+    if not fused:
         lowered = jax.jit(alone).lower(*qkv, s(B, T, T, dtype=jnp.int8))
         assert custom_calls(lowered.as_text()) == [
             ("flash_select_dkdv", 7), ("flash_select_dq", 7),
             ("flash_select_fwd", 4)]
-        assert "vmem_limit_bytes" not in lowered.as_text()
+        assert set(scoped_vmem_mb(lowered.as_text()).values()) == {
+            32 if headroom else 0}
         lowered.compile()
         return
 
@@ -956,19 +990,24 @@ def test_selected_attention_fwd_bwd_at_keye_widths(v5e, monkeypatch,
     lowered_text = lowered.as_text()
     names = re.findall(r'kernel_name = "([^"]+)"', lowered_text)
     assert sorted(set(names)) == [
-        "flash_select_dkdv", "flash_select_dq", "flash_select_fwd",
-        "index_kl", "index_scores"]
+        "flash_select_bwd", "flash_select_fwd", "index_kl", "index_scores"]
     assert names.count("index_scores") == 4                # the bands
-    # The map is an operand of the three kernels, whose row statistics
-    # come a KV head (4), not a query head (32).
+    # The map is an operand of the two kernels, whose row statistics come
+    # a KV head (4), not a query head (32); the backward runs under the
+    # plan's own budget.
     selected_calls = [line for line in lowered_text.splitlines()
                       if 'kernel_name = "flash_select_' in line]
-    assert len(selected_calls) == 3
+    assert len(selected_calls) == 2
     for line in selected_calls:
         operands = line[line.rindex(" : ("):]
         assert operands.count("tensor<1x16384x16384xi8>") == 1
         assert "tensor<1x4x16384x8xf32>" in operands
         assert "x32x16384" not in operands
+    assert custom_calls(lowered_text)[:2] == [
+        ("flash_select_bwd", 7), ("flash_select_fwd", 4)]
+    limits = scoped_vmem_mb(lowered_text)
+    assert (limits["flash_select_fwd"], limits["flash_select_bwd"]) == (
+        32, fa._SELECT_FUSED_VMEM_MB) == (32, 64)
     compiled = lowered.compile()
     text = compiled.as_text()
     assert "approx" not in text.lower() and " sort(" not in text
@@ -983,6 +1022,47 @@ def test_selected_attention_fwd_bwd_at_keye_widths(v5e, monkeypatch,
     plan = (m.argument_size_in_bytes + m.output_size_in_bytes
             + m.temp_size_in_bytes - m.alias_size_in_bytes)
     assert plan < 3.0 * 2 ** 30, plan / 2 ** 30
+
+
+# The scoped VMEM the compiler counts for the fused backward alone at the
+# cell's widths and T 16,384, at the Q block `_group_block_q` gives each group
+# size (MB, found by bisection on the limit in the sandbox, PR 39): G 1 at
+# 1024 rows 46.9, G 2 50.3, G 4 55.3, G 8 at 512 46.8, G 16 at 256 43.9.
+FUSED_BWD_COUNTED_MB = 56
+
+
+@pytest.mark.parametrize("G", [1, 2, 4, 8, 16])
+def test_the_fused_selected_backward_compiles_at_every_group_size(
+        v5e, monkeypatch, G):
+    """``flash_select_fwd`` and ``flash_select_bwd`` at 4 KV heads of 128
+    and T 16,384 with 1 to 16 query heads a KV head, each at the Q block
+    the plan gives it — and the backward under 56 MB, the most the compiler
+    counts at any of them, so that the plan's 64 leaves 8 over."""
+    from horovod_tpu.ops import flash_attention as fa
+
+    monkeypatch.setattr(fa, "_vmem_headroom_ok", lambda: True)
+    assert fa._SELECT_FUSED_VMEM_MB >= FUSED_BWD_COUNTED_MB + 8
+    monkeypatch.setattr(fa, "_SELECT_FUSED_VMEM_MB", FUSED_BWD_COUNTED_MB)
+    jax.clear_caches()
+    one = SingleDeviceSharding(v5e[0])
+    B, T, Hkv, D = 1, 16_384, 4, 128
+
+    def s(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    def loss(q, k, v, select):
+        return fa.flash_attention(q, k, v, causal=True, select=select)[
+            0].astype(jnp.float32).sum()
+
+    lowered = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        s(B, T, Hkv * G, D), s(B, T, Hkv, D), s(B, T, Hkv, D),
+        s(B, T, T, dtype=jnp.int8))
+    assert custom_calls(lowered.as_text()) == [
+        ("flash_select_bwd", 7), ("flash_select_fwd", 4)]
+    assert scoped_vmem_mb(lowered.as_text()) == {
+        "flash_select_fwd": 32, "flash_select_bwd": FUSED_BWD_COUNTED_MB}
+    lowered.compile()
+    jax.clear_caches()
 
 
 @pytest.mark.parametrize("entry,b,t,h,hkv,kernels", [
