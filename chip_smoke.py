@@ -92,9 +92,9 @@ SELECT_REFERENCE = dict(batch=1, seq=2048, heads=8, kv_heads=1, head_dim=128,
                         index_heads=16, index_dim=64, topk=512)
 # One layer's selected attention at keye_1chip's own shape, kernels alone:
 # 32 query over 4 KV heads of 128 at T 16,384 under a random map of about
-# 2,048 keys a query.
+# 2,048 keys a query, and the KL pass of an indexer of 16 heads of 64.
 SELECT_BACKWARD = dict(batch=1, seq=16384, heads=32, kv_heads=4, head_dim=128,
-                       topk=2048)
+                       index_heads=16, index_dim=64, topk=2048)
 ONE_CHIP_LM = dict(**TRANSFORMER, batch=8, steps=3, scan_steps=4)
 ONE_CHIP_RESNET = dict(stage_sizes=(3, 4, 6, 3), num_filters=64,
                        num_classes=1000, image=224, batch=128, steps=3)
@@ -645,8 +645,8 @@ def select_reference_phase(*, batch: int, seq: int, heads: int,
 
 
 def select_backward_phase(*, batch: int, seq: int, heads: int, kv_heads: int,
-                          head_dim: int, topk: int, seed: int,
-                          calls: int = 10) -> dict:
+                          head_dim: int, index_heads: int, index_dim: int,
+                          topk: int, seed: int, calls: int = 10) -> dict:
     """The selected attention's kernels alone at one layer's shape, under
     the blocks ``flash_attention._plan`` gives them on this device: the
     forward, and the backward in both forms — the dq and the dk-dv kernel
@@ -654,11 +654,15 @@ def select_backward_phase(*, batch: int, seq: int, heads: int, kv_heads: int,
     fused kernel's three gradients against the pair's.  ``select_plan``
     says which of the two a call runs here and under which scoped-VMEM
     budget; ``ms`` is device time a call as the host's clock sees ``calls``
-    of them end (on the chip only: interpreted, no time is reported)."""
+    of them end (on the chip only: interpreted, no time is reported).
+    ``kl_alone``: the indexer's KL pass (``index_kl``: ``L_I`` and its three
+    gradients in one kernel) on the same q, k, map and the forward's
+    log-sum-exps, under ``kl_plan`` — the tiling and scoped-VMEM budget
+    ``sparse_select._kl_plan`` gives it on this device."""
     import jax
     import jax.numpy as jnp
 
-    from horovod_tpu.ops import flash_attention as fa
+    from horovod_tpu.ops import flash_attention as fa, sparse_select
 
     interpret = jax.default_backend() != "tpu"
     B, T, H, Hkv, D = batch, seq, heads, kv_heads, head_dim
@@ -719,8 +723,33 @@ def select_backward_phase(*, batch: int, seq: int, heads: int, kv_heads: int,
             check(errs[name] <= SELECT_TOL,
                   f"the fused selected backward differs from the pair in "
                   f"{name} by {errs[name]:.3g} (bound {SELECT_TOL})")
-    return {"shape": [B, T, H, Hkv, D, topk], "interpret": interpret,
+
+    # The KL pass on the same layer: an indexer's projections, its scores'
+    # log-sum-exp over the map's keys, the attention's q, k and statistics.
+    kq, kk, kw = jax.random.split(jax.random.fold_in(ks[4], 1), 3)
+    qi = jax.random.normal(kq, (B, T, index_heads, index_dim)).astype(
+        jnp.bfloat16)
+    ki = jax.random.normal(kk, (B, T, index_dim)).astype(jnp.bfloat16)
+    w = jax.random.normal(kw, (B, T, index_heads))
+    lse_i = jax.jit(lambda qi, ki, w, select: jax.scipy.special.logsumexp(
+        jnp.where(select != 0, sparse_select.index_scores(
+            qi.transpose(0, 2, 1, 3), ki, w, interpret=interpret), -jnp.inf),
+        axis=-1))(qi, ki, w, select)
+    ms["kl_alone"], (kl, *kl_grads) = timed(
+        lambda *a: sparse_select._kl_pass(*a, scale=D ** -0.5,
+                                          interpret=interpret),
+        qi, ki, w, q.reshape(B, T, H, D), k.reshape(B, T, Hkv, D), lse,
+        select, lse_i)
+    check(all(bool(jnp.isfinite(a).all()) for a in (kl, *kl_grads))
+          and float(kl.min()) > -1e-3,
+          "the KL pass alone left a KL row below zero or a value not finite")
+    kl_plan = sparse_select._kl_plan(
+        T, H, Hkv, D, index_heads, index_dim, qi.dtype.itemsize,
+        sparse_select._vmem_headroom_ok())
+    return {"shape": [B, T, H, Hkv, D, index_heads, index_dim, topk],
+            "interpret": interpret,
             "select_plan": plan._asdict(),
+            "kl_plan": dict(zip(("block_q", "block_k", "vmem_mb"), kl_plan)),
             "selected_per_query": round(float(select.sum()) / (B * T), 1),
             "ms_a_layer": ms,
             "fused_vs_pair": {n: round(e, 6) for n, e in errs.items()}}

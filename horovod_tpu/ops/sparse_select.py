@@ -29,8 +29,13 @@ Three steps, none of which ever holds a (T, T) float32 array of all heads:
 * the selected attention itself is ``flash_attention(..., select=map)``.
 * :func:`index_kl` — ``L_I`` and, in the same pass, its gradient on the
   indexer's three projections (:func:`_kl_kernel`): a tile's ``p`` is
-  summed over the heads in VMEM, the tile's scores are made again, and
-  ``softmax_S(I) - p`` goes straight into ``dqI``, ``dkI`` and ``dw``.
+  summed over the heads in VMEM (recomputed: the forward's softmax is
+  online, so the heads' sum cannot leave it), the tile's scores are made
+  again — each head's product ONCE, its sign kept in VMEM for the
+  gradient — and ``g = softmax_S(I) - p`` goes straight into ``dqI``,
+  ``dkI`` and ``dw``: ``H + 3 H_I`` products a tile, ``w`` and ``dw`` as
+  row operations once a query block (``dw_h`` is the row product of
+  ``qI_h`` with ``dqI_h`` before ``w_h`` scales it: no division by ``w``).
   ``L_I`` moves nothing else: ``q``, ``k`` and the statistics arrive
   detached.
 
@@ -55,11 +60,18 @@ from horovod_tpu.ops.flash_attention import (
     _struct, _vmem_headroom_ok, _vmem_limit)
 
 # Tiles of the two kernels (queries x keys).  The KL kernel holds a tile's
-# query rows of every attention head and the gradient of every indexer
-# head beside its float32 score tiles: 512² needs some 40 MB of scoped
-# VMEM, which v4 and later back.
+# query rows of every attention head, the gradient of every indexer head
+# and the heads' (tile, tile) masks beside its float32 score tiles: at the
+# first tiling below and Keye's widths the compiler counts 58 MB of
+# scoped VMEM, which v4 and later back; ``_kl_plan`` takes the first tiling
+# that fits the device's budget by ``_kl_vmem_bytes`` (alone on a v5e, ms a
+# layer at T 16,384: 512 x 512 18.1, 256 x 512 18.6, 512 x 1024 21.9;
+# PERF.md section 6, PR 41).
 _BLOCK = 512
 _KL_VMEM_MB = 96
+_KL_TILINGS = ((512, 512), (256, 512), (256, 256), (128, 256), (128, 128))
+_KL_LIVE_TILES = 8
+_MOSAIC_DEFAULT_VMEM_MB = 16
 
 
 def _block(T: int, cap: int = _BLOCK) -> int:
@@ -254,20 +266,35 @@ def selection_counters(select, block: int):
 
 def _kl_kernel(q_ref, k_ref, lse_ref, sel_ref, qi_ref, ki_ref, w_ref,
                lsei_ref, kl_ref, dqi_ref, dw_ref, dki_ref,
-               kl_scr, dqi_scr, dw_scr, *, heads, kv_heads, head_dim,
-               index_heads, scale, index_scale, block_q, block_k):
+               kl_scr, dqi_scr, pos_scr, wqi_scr, *, heads, kv_heads,
+               head_dim, index_heads, scale, index_scale, block_q, block_k):
     """One (block_q, block_k) tile of the KL pass.  Grid (B, T / block_q,
-    T / block_k), the key tiles innermost: a query tile's ``KL`` rows,
-    ``dqI`` and ``dw`` form in scratch across them; a key tile's ``dkI``
-    leaves as one partial a query tile (summed outside)."""
+    T / block_k), the key tiles innermost: a query tile's ``KL`` rows and
+    ``dqI`` form in scratch across them; a key tile's ``dkI`` leaves as one
+    partial a query tile (summed outside).
+
+    Each product ``s_h = qI_h kIᵀ`` is formed once: what the gradient needs
+    of it is ``1[s_h > 0]``, kept in ``pos_scr`` as all-ones / zero words
+    as wide as an operand, so that ``e_h = 1[s_h > 0] ⊙ g`` is one AND on
+    ``g``'s bits.  ``w`` stays off the (block_q, block_k) elements::
+
+        dqI'_h = e_h kI                      summed in ``dqi_scr``
+        dkI    = Σ_h e_hᵀ (w_h ⊙ qI_h)       ``wqi_scr``, made once a query tile
+        dqI_h  = w_h ⊙ dqI'_h                at the query tile's last step
+        dw_h   = Σ_s g relu(s_h) = <qI_h, dqI'_h>   a row product, there too
+    """
     i, j, nk = pl.program_id(1), pl.program_id(2), pl.num_programs(2)
     D = head_dim
+    dtype = ki_ref.dtype
 
     @pl.when(j == 0)
     def _init():
         kl_scr[...] = jnp.zeros_like(kl_scr)
         dqi_scr[...] = jnp.zeros_like(dqi_scr)
-        dw_scr[...] = jnp.zeros_like(dw_scr)
+        w = w_ref[0]
+        for h in range(index_heads):
+            wqi_scr[h] = (w[:, h:h + 1]
+                          * qi_ref[0, h].astype(jnp.float32)).astype(dtype)
 
     live = j * block_k <= (i + 1) * block_q - 1
 
@@ -288,14 +315,12 @@ def _kl_kernel(q_ref, k_ref, lse_ref, sel_ref, qi_ref, ki_ref, w_ref,
         p = jnp.where(ok, p * (1.0 / heads), 0.0)
         # The indexer's own distribution over the selected keys.
         ki, w = ki_ref[0], w_ref[0]
-
-        def product(h):
-            return lax.dot_general(qi_ref[0, h], ki, (((1,), (1,)), ((), ())),
-                                   preferred_element_type=jnp.float32)
-
         scores = jnp.zeros((block_q, block_k), jnp.float32)
         for h in range(index_heads):
-            scores += w[:, h:h + 1] * jnp.maximum(product(h), 0.0)
+            s = lax.dot_general(qi_ref[0, h], ki, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+            pos_scr[h] = jnp.where(s > 0.0, -1, 0).astype(pos_scr.dtype)
+            scores += w[:, h:h + 1] * jnp.maximum(s, 0.0)
         log_pi = scores * index_scale - lsei_ref[0]
         pi = jnp.where(ok, jnp.exp(log_pi), 0.0)
         kl_scr[...] += jnp.sum(
@@ -303,18 +328,16 @@ def _kl_kernel(q_ref, k_ref, lse_ref, sel_ref, qi_ref, ki_ref, w_ref,
                       p * (jnp.log(jnp.maximum(p, 1e-37)) - log_pi), 0.0),
             axis=1, keepdims=True)
         # d KL / d I = softmax_S(I) - p, straight into the projections.
-        g = (pi - p) * index_scale
+        g = lax.bitcast_convert_type(
+            ((pi - p) * index_scale).astype(dtype), pos_scr.dtype)
         dki = jnp.zeros((block_k, ki.shape[1]), jnp.float32)
         for h in range(index_heads):
-            s = product(h)
-            dw_scr[h] += jnp.sum(g * jnp.maximum(s, 0.0), axis=1,
-                                 keepdims=True)
-            ds = jnp.where(s > 0.0, g * w[:, h:h + 1], 0.0).astype(ki.dtype)
+            e = lax.bitcast_convert_type(g & pos_scr[h], dtype)
             dqi_scr[h] += lax.dot_general(
-                ds, ki, (((1,), (0,)), ((), ())),
+                e, ki, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
             dki += lax.dot_general(
-                ds, qi_ref[0, h], (((0,), (0,)), ((), ())),
+                e, wqi_scr[h], (((0,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
         dki_ref[0, 0] = dki
 
@@ -325,8 +348,50 @@ def _kl_kernel(q_ref, k_ref, lse_ref, sel_ref, qi_ref, ki_ref, w_ref,
     @pl.when(j == nk - 1)
     def _finalize():
         kl_ref[0] = kl_scr[...]
-        dqi_ref[0] = dqi_scr[...]
-        dw_ref[0] = dw_scr[...]
+        w = w_ref[0]
+        for h in range(index_heads):
+            d = dqi_scr[h]
+            dw_ref[0, h] = jnp.sum(qi_ref[0, h].astype(jnp.float32) * d,
+                                   axis=1, keepdims=True)
+            dqi_ref[0, h] = w[:, h:h + 1] * d
+
+
+def _kl_vmem_bytes(bq, bk, H, Hkv, D, HI, DI, itemsize):
+    """What the KL kernel holds in VMEM at a tiling, from shapes: every
+    operand and result block twice (Mosaic's pipeline), the scratch, the
+    float32 (bq, bk) tiles live at once and 2 MB of the compiler's own.
+    At Keye's widths the compiler counts (MB, bisection on the limit for
+    the described v5e, PR 41) 12 / 13 / 23 / 28 / 58 from the smallest
+    tiling to the largest where this says 12.5 / 14.0 / 25.1 / 30.1 / 56.5."""
+    def block(rows, cols, size):
+        # A last axis fills whole 128-lane tiles: (.., 1) weighs as (.., 128).
+        return rows * -(-cols // 128) * 128 * size
+
+    operands = (block(bq, H * D, itemsize) + block(bk, Hkv * D, itemsize)
+                + block(bq, H, 4) + block(bq, bk, 1)
+                + block(HI * bq, DI, itemsize) + block(bk, DI, itemsize)
+                + block(bq, HI, 4) + block(bq, 1, 4))
+    results = (block(bq, 1, 4) + block(HI * bq, DI, 4)
+               + block(HI * bq, 1, 4) + block(bk, DI, 4))
+    scratch = (block(bq, 1, 4) + block(HI * bq, DI, 4)
+               + block(HI * bq, bk, itemsize) + block(HI * bq, DI, itemsize))
+    return (2 * (operands + results) + scratch
+            + _KL_LIVE_TILES * bq * bk * 4 + 2 * 2 ** 20)
+
+
+def _kl_plan(T, H, Hkv, D, HI, DI, itemsize, vmem_headroom):
+    """``(block_q, block_k, vmem_mb)`` of the KL pass: the first of
+    ``_KL_TILINGS`` (cut to ``T``; the last if none) whose VMEM by shapes
+    fits the budget — ``_KL_VMEM_MB`` where the device backs it, Mosaic's
+    default (``vmem_mb`` 0) where it does not.  One algorithm at every
+    tiling."""
+    mb = _KL_VMEM_MB if vmem_headroom else 0
+    budget = (mb or _MOSAIC_DEFAULT_VMEM_MB) * 2 ** 20
+    for bq, bk in _KL_TILINGS:
+        bq, bk = _block(T, bq), _block(T, bk)
+        if _kl_vmem_bytes(bq, bk, H, Hkv, D, HI, DI, itemsize) <= budget:
+            break
+    return bq, bk, mb
 
 
 def _kl_pass(qi, ki, w, q, k, lse, select, lse_i, *, scale, interpret):
@@ -335,44 +400,46 @@ def _kl_pass(qi, ki, w, q, k, lse, select, lse_i, *, scale, interpret):
     B, T, H, D = q.shape
     Hkv = k.shape[2]
     HI, DI = qi.shape[2], qi.shape[3]
-    blk = _block(T)
-    n = T // blk
+    bq, bk, vmem_mb = _kl_plan(T, H, Hkv, D, HI, DI, qi.dtype.itemsize,
+                               _vmem_headroom_ok())
+    nq = T // bq
     qi_t = qi.transpose(0, 2, 1, 3)                          # (B, HI, T, DI)
-    vmem = (_vmem_limit(_KL_VMEM_MB) if _vmem_headroom_ok() else {})
     kl, dqi, dw, dki = pl.pallas_call(
         functools.partial(
             _kl_kernel, heads=H, kv_heads=Hkv, head_dim=D, index_heads=HI,
-            scale=scale, index_scale=1.0 / math.sqrt(HI * DI), block_q=blk,
-            block_k=blk),
-        grid=(B, n, n),
+            scale=scale, index_scale=1.0 / math.sqrt(HI * DI), block_q=bq,
+            block_k=bk),
+        grid=(B, nq, T // bk),
         in_specs=[
-            pl.BlockSpec((1, blk, H * D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, blk, Hkv * D), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, blk, H), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, blk, blk), lambda b, i, j: (b, i, j)),
-            pl.BlockSpec((1, HI, blk, DI), lambda b, i, j: (b, 0, i, 0)),
-            pl.BlockSpec((1, blk, DI), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, blk, HI), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, blk, 1), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, bq, H * D), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, bk, Hkv * D), lambda b, i, j: (b, j, 0)),
+            pl.BlockSpec((1, bq, H), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, bq, bk), lambda b, i, j: (b, i, j)),
+            pl.BlockSpec((1, HI, bq, DI), lambda b, i, j: (b, 0, i, 0)),
+            pl.BlockSpec((1, bk, DI), lambda b, i, j: (b, j, 0)),
+            pl.BlockSpec((1, bq, HI), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, blk, 1), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, HI, blk, DI), lambda b, i, j: (b, 0, i, 0)),
-            pl.BlockSpec((1, HI, blk, 1), lambda b, i, j: (b, 0, i, 0)),
-            pl.BlockSpec((1, 1, blk, DI), lambda b, i, j: (b, i, j, 0)),
+            pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, HI, bq, DI), lambda b, i, j: (b, 0, i, 0)),
+            pl.BlockSpec((1, HI, bq, 1), lambda b, i, j: (b, 0, i, 0)),
+            pl.BlockSpec((1, 1, bk, DI), lambda b, i, j: (b, i, j, 0)),
         ],
         out_shape=[
             _struct((B, T, 1), jnp.float32, q, qi),
             _struct((B, HI, T, DI), jnp.float32, q, qi),
             _struct((B, HI, T, 1), jnp.float32, q, qi),
-            _struct((B, n, T, DI), jnp.float32, q, qi),
+            _struct((B, nq, T, DI), jnp.float32, q, qi),
         ],
-        scratch_shapes=[pltpu.VMEM((blk, 1), jnp.float32),
-                        pltpu.VMEM((HI, blk, DI), jnp.float32),
-                        pltpu.VMEM((HI, blk, 1), jnp.float32)],
+        scratch_shapes=[
+            pltpu.VMEM((bq, 1), jnp.float32),
+            pltpu.VMEM((HI, bq, DI), jnp.float32),
+            pltpu.VMEM((HI, bq, bk), jnp.dtype(f"int{8 * qi.dtype.itemsize}")),
+            pltpu.VMEM((HI, bq, DI), qi.dtype)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
-            **vmem),
+            **_vmem_limit(vmem_mb)),
         interpret=interpret,
         name="index_kl",
     )(q.reshape(B, T, H * D), k.reshape(B, T, Hkv * D),

@@ -157,12 +157,16 @@ def test_select_reference_phase(smoke):
 def test_select_backward_phase(smoke):
     """The selected attention's backward in both forms on the same
     operands (interpreted here: the two agree, and no time is reported),
-    with the plan's choice and budget; on the chip the phase runs at the
-    cell's own shape."""
+    with the plan's choice and budget, and the KL pass alone beside them
+    under its own plan (``kl_alone``, ``kl_plan``); on the chip the phase
+    runs at the cell's own shape."""
     out = smoke.select_backward_phase(batch=1, seq=256, heads=4, kv_heads=2,
-                                      head_dim=128, topk=64, seed=0)
+                                      head_dim=128, index_heads=2,
+                                      index_dim=64, topk=64, seed=0)
     assert out["interpret"] and out["ms_a_layer"] == dict.fromkeys(
-        ("forward", "dq", "dkdv", "pair", "fused"))
+        ("forward", "dq", "dkdv", "pair", "fused", "kl_alone"))
+    assert out["kl_plan"] == {"block_q": 256, "block_k": 256, "vmem_mb": 96}
+    assert out["shape"] == [1, 256, 4, 2, 128, 2, 64, 64]
     assert (out["select_plan"]["bwd"], out["select_plan"]["bwd_vmem_mb"],
             out["select_plan"]["blocks"]) == ("group_fused", 64, (256,) * 4)
     assert set(out["fused_vs_pair"]) == {"dq", "dk", "dv"}
@@ -170,7 +174,8 @@ def test_select_backward_phase(smoke):
     assert 32 < out["selected_per_query"] <= 64
     cell = smoke.SELECT_BACKWARD
     assert (cell["seq"], cell["heads"], cell["kv_heads"], cell["head_dim"],
-            cell["topk"]) == (16_384, 32, 4, 128, 2048)
+            cell["index_heads"], cell["index_dim"], cell["topk"]) == (
+        16_384, 32, 4, 128, 16, 64, 2048)
 
 
 def test_delta_reference_phase(smoke):

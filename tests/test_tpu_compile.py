@@ -1008,6 +1008,7 @@ def test_selected_attention_fwd_bwd_at_keye_widths(v5e, monkeypatch,
     limits = scoped_vmem_mb(lowered_text)
     assert (limits["flash_select_fwd"], limits["flash_select_bwd"]) == (
         32, fa._SELECT_FUSED_VMEM_MB) == (32, 64)
+    assert limits["index_kl"] == sparse_select._KL_VMEM_MB == 96
     compiled = lowered.compile()
     text = compiled.as_text()
     assert "approx" not in text.lower() and " sort(" not in text
@@ -1063,6 +1064,60 @@ def test_the_fused_selected_backward_compiles_at_every_group_size(
         "flash_select_fwd": 32, "flash_select_bwd": FUSED_BWD_COUNTED_MB}
     lowered.compile()
     jax.clear_caches()
+
+
+# The scoped VMEM the compiler counts for the KL pass alone at the cell's
+# widths and T 16,384, a tiling of ``sparse_select._KL_TILINGS`` each (MB,
+# found by bisection on the limit in the sandbox, PR 41; the parent's
+# kernel at 512 x 512: 51).
+KL_COUNTED_MB = {(512, 512): 58, (256, 512): 28, (256, 256): 23,
+                 (128, 256): 13, (128, 128): 12}
+
+
+@pytest.mark.parametrize("tiling,headroom", [
+    *((tiling, True) for tiling in KL_COUNTED_MB),
+    ((128, 256), False), ((128, 128), False)],
+    ids=lambda v: "x".join(map(str, v)) if isinstance(v, tuple) else (
+        "raised" if v else "default"))
+def test_the_kl_pass_compiles_at_every_tiling(v5e, monkeypatch, tiling,
+                                              headroom):
+    """``index_kl`` at 32 query over 4 KV heads of 128, 16 indexer heads of
+    64 and T 16,384 at every tiling ``_kl_plan`` can choose: under the
+    raised limit each compiles within what the compiler counted for it —
+    the most, 58 MB, leaves ``_KL_VMEM_MB`` 38 over —, and the two that
+    ``_kl_vmem_bytes`` admits under Mosaic's default 16 MB compile there,
+    the first of them being the plan's choice without head-room.  One
+    algorithm throughout: ``H + 3 H_I`` products a tile
+    (``tests/test_sparse_attention.py``)."""
+    from horovod_tpu.ops import sparse_select as ss
+
+    assert tuple(KL_COUNTED_MB) == ss._KL_TILINGS
+    B, T, H, Hkv, D, HI, DI = 1, 16_384, 32, 4, 128, 16, 64
+    shape = (T, H, Hkv, D, HI, DI, 2)
+    assert ss._kl_plan(*shape, True) == (512, 512, ss._KL_VMEM_MB)
+    assert ss._kl_plan(*shape, False) == (128, 256, 0)
+    assert max(KL_COUNTED_MB.values()) + 8 <= ss._KL_VMEM_MB
+    limit = KL_COUNTED_MB[tiling] if headroom else 0
+    assert headroom or ss._kl_vmem_bytes(*tiling, *shape[1:]) <= (
+        ss._MOSAIC_DEFAULT_VMEM_MB * 2 ** 20)
+    monkeypatch.setattr(ss, "_vmem_headroom_ok", lambda: headroom)
+    monkeypatch.setattr(ss, "_KL_TILINGS", (tiling,))
+    monkeypatch.setattr(ss, "_KL_VMEM_MB", limit)
+    one = SingleDeviceSharding(v5e[0])
+
+    def s(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    lowered = jax.jit(lambda *a: ss._kl_pass(
+        *a, scale=D ** -0.5, interpret=False)).lower(
+        s(B, T, HI, DI), s(B, T, DI), s(B, T, HI, dtype=jnp.float32),
+        s(B, T, H, D), s(B, T, Hkv, D), s(B, H, T, dtype=jnp.float32),
+        s(B, T, T, dtype=jnp.int8), s(B, T, dtype=jnp.float32))
+    assert custom_calls(lowered.as_text()) == [("index_kl", 8)]
+    assert scoped_vmem_mb(lowered.as_text()) == {"index_kl": limit}
+    kl, dqi, dki, dw = lowered.compile().out_info
+    assert [a.shape for a in (kl, dqi, dki, dw)] == [
+        (B, T), (B, T, HI, DI), (B, T, DI), (B, T, HI)]
 
 
 @pytest.mark.parametrize("entry,b,t,h,hkv,kernels", [
