@@ -1,40 +1,35 @@
-"""Synthetic benchmark harness — prints ONE JSON line for the driver.
+"""Host-plane drills — prints ONE JSON line.
 
-TPU-native counterpart of the reference's benchmark harness
-(``examples/pytorch_synthetic_benchmark.py:93-110``): synthetic data, full
-training step (forward + backward + gradient allreduce + SGD update),
-throughput measured over timed iterations after warmup.
+``python bench.py`` runs the drills of the planes that live on the host
+and touches no accelerator: every process it starts pins jax to the CPU
+platform.
 
-Two legs in the default run, merged into the one JSON line:
+* ``scaling_tcp_2proc`` (``BENCH_SCALING=0`` skips it): the same worker
+  loop at 1 process and at 2 processes under the ``horovod_tpu.run``
+  launcher — the real cross-process eager data plane (negotiation +
+  payload over the native ring), with its wire-compression, overlap and
+  observatory A/Bs, the allreduce-algorithm and transport sweeps, the
+  response cache's counters, and, each behind its own switch, the
+  recovery (``BENCH_RECOVERY``), fleet-policy (``BENCH_POLICY``) and
+  publish-while-training (``BENCH_PUBLISH``) drills;
+* ``ctrl_sweep`` (``BENCH_CTRL=0`` skips it): the flat-vs-hier
+  negotiation tick at 8/32/128 loopback processes.
 
-* ResNet-50 (the judged metric, images/sec/chip) — HBM-bandwidth-bound
-  on v5e, so its MFU ceiling is ~32% regardless of skill;
-* TransformerLM + Pallas flash attention at a compute-bound shape — the
-  leg where MFU is the telling number.
-
-``python bench.py --n-virtual 8`` instead runs the scaling mode on a
-virtual 8-device CPU mesh: per-chip throughput at N devices over the
-1-device number = scaling efficiency (the reference's published metric,
-``docs/benchmarks.md:3-6`` — 90% at 512 GPUs), plus a comm/compute split
-from the profiler where the backend exposes device-side collective spans.
-
-Baseline anchor: the reference publishes 1656.82 images/sec total for
-ResNet-101 on 16 Pascal GPUs = 103.55 img/sec/device
-(``docs/benchmarks.md:22-39``); per BASELINE.json the judged metric is
-images/sec/chip on ResNet-50, so ``vs_baseline`` is img/sec/chip divided
-by that per-device anchor.
+These are host speeds and stand beside no device metric.  How fast the
+device path trains is ``benchmark/run.py``'s to say (``BENCHMARK.json``;
+numbers in ``PERF_LEDGER.jsonl`` and ``PERF.md``); docs/benchmarks.md
+describes both.  The ``--*-worker`` flags are the drills' own
+subprocesses (and what ``tests/`` and the chaos drills start).
 """
 
 from __future__ import annotations
 
 import argparse
-import functools
 import json
 import os
 import sys
 import time
 
-BASELINE_PER_DEVICE = 1656.82 / 16.0   # reference docs/benchmarks.md:22-39
 
 def _cpu_jax():
     """jax pinned to the CPU platform, persistent compile cache on — how
@@ -45,566 +40,6 @@ def _cpu_jax():
     from horovod_tpu import compile_cache
     compile_cache.enable()
     return jax
-
-
-def aot_compile(step, args):
-    """Compile ONCE ahead-of-time and reuse the executable for both the
-    timed run and the cost analysis (lowering again after calling would
-    compile a second identical program).  Returns (callable, flops,
-    bytes_accessed) from XLA's cost model; a compile error is the
-    caller's error.  NOTE: XLA counts a scan body ONCE regardless of
-    trip count — callers scale by steps-per-call.
-    """
-    compiled = step.lower(*args).compile()
-    analysis = compiled.cost_analysis()
-    return (compiled, float(analysis["flops"]),
-            float(analysis["bytes accessed"]))
-
-
-def synth_variables(jax, init_fn, rng):
-    """Benchmark-grade parameter synthesis: flax's ``init`` traces and
-    compiles the model's whole forward pass just to produce parameters.
-    Timing is initializer-independent, so instead compile one trivial
-    RNG program over the ``eval_shape`` tree:
-    scale/var-style leaves get ones, bias/mean get zeros, weights get
-    N(0, 0.02) — values sane enough that the loss is finite and falls.
-    """
-    import jax.numpy as jnp
-    import jax.tree_util as jtu
-
-    shapes = jax.eval_shape(init_fn, rng)
-    leaves, treedef = jtu.tree_flatten_with_path(shapes)
-    paths = [jtu.keystr(p).lower() for p, _ in leaves]
-    leaves = [l for _, l in leaves]
-
-    @jax.jit
-    def make(rng):
-        keys = jax.random.split(rng, len(leaves))
-        out = []
-        for key, path, leaf in zip(keys, paths, leaves):
-            if not jnp.issubdtype(leaf.dtype, jnp.floating):
-                out.append(jnp.zeros(leaf.shape, leaf.dtype))
-            elif "scale" in path or "var" in path:
-                out.append(jnp.ones(leaf.shape, leaf.dtype))
-            elif "bias" in path or "mean" in path:
-                out.append(jnp.zeros(leaf.shape, leaf.dtype))
-            else:
-                out.append(jax.random.normal(key, leaf.shape, leaf.dtype)
-                           * 0.02)
-        return jax.tree.unflatten(treedef, out)
-
-    return make(rng)
-
-
-def _timed(step_fn, state, data, iters, windows):
-    """Best-of-N timing windows, each ended by ``block_until_ready`` on
-    the loss.  Returns (state, best seconds per window)."""
-    best = None
-    for _ in range(windows):
-        t0 = time.perf_counter()
-        for _ in range(iters):
-            state = step_fn(state, data)
-        state[-1].block_until_ready()
-        dt = time.perf_counter() - t0
-        best = dt if best is None else min(best, dt)
-    return state, best
-
-
-def bench_resnet(jax, hvd, mesh, nchips):
-    import jax.numpy as jnp
-    import numpy as np
-    import optax
-
-    from horovod_tpu import profiling
-    from horovod_tpu.jax.spmd import make_train_step
-    from horovod_tpu.models import ResNet50
-
-    # BENCH_MODEL swaps the convnet under test: the reference's scaling
-    # anchors are Inception V3 / ResNet / VGG-16 (docs/benchmarks.md:3-6);
-    # the judged default stays resnet50.
-    model_name = os.environ.get("BENCH_MODEL", "resnet50")
-    default_size = {"inception_v3": 299}.get(model_name, 224)
-    # Model-aware default batch: 128 @299 through V3 would OOM a 16 GB
-    # chip (the documented working config is 32, docs/benchmarks.md);
-    # VGG's fc activations similarly cap lower than ResNet's.
-    default_batch = {"inception_v3": 32, "vgg16": 64}.get(model_name, 128)
-    batch_per_chip = int(os.environ.get("BENCH_BATCH_PER_CHIP",
-                                        str(default_batch)))
-    image_size = int(os.environ.get("BENCH_IMAGE_SIZE", str(default_size)))
-    warmup_iters = int(os.environ.get("BENCH_WARMUP", "5"))
-    timed_batches = int(os.environ.get("BENCH_ITERS", "30"))
-    windows = int(os.environ.get("BENCH_WINDOWS", "4"))
-    batch = batch_per_chip * nchips
-
-    remat = os.environ.get("BENCH_REMAT", "0") == "1"
-    if remat and model_name != "resnet50":
-        raise SystemExit(
-            f"BENCH_REMAT=1 is only plumbed for resnet50, not "
-            f"{model_name!r} — running without remat would report memory "
-            "numbers for a configuration you didn't ask for")
-    if model_name == "resnet50":
-        model = ResNet50(num_classes=1000, dtype=jnp.bfloat16, remat=remat)
-    elif model_name == "inception_v3":
-        from horovod_tpu.models import InceptionV3
-        model = InceptionV3(num_classes=1000, dtype=jnp.bfloat16)
-    elif model_name == "vgg16":
-        from horovod_tpu.models import VGG16
-        model = VGG16(num_classes=1000, dtype=jnp.bfloat16)
-    else:
-        raise SystemExit(f"unknown BENCH_MODEL {model_name!r}")
-    rng = jax.random.PRNGKey(42)
-    # Generate the global batch already sharded over the mesh so no single
-    # chip ever holds it (the reference generates per-rank data locally,
-    # examples/pytorch_synthetic_benchmark.py:60-63).
-    from jax.sharding import NamedSharding, PartitionSpec as P
-    batch_sharding = NamedSharding(mesh, P(tuple(mesh.axis_names)))
-
-    @functools.partial(jax.jit, out_shardings=(batch_sharding, batch_sharding))
-    def make_batch(rng):
-        images = jax.random.normal(
-            rng, (batch, image_size, image_size, 3), jnp.bfloat16)
-        labels = jnp.zeros((batch,), jnp.int32)
-        return images, labels
-
-    images, labels = make_batch(rng)
-    variables = synth_variables(
-        jax, lambda r: model.init(r, images[:1], train=True), rng)
-    params = variables["params"]
-    batch_stats = variables.get("batch_stats", {})
-    has_bn = bool(batch_stats)   # VGG-16 is BN-free
-
-    def loss_fn(params, batch_stats, batch):
-        imgs, lbls = batch
-        if has_bn:
-            logits, mut = model.apply(
-                {"params": params, "batch_stats": batch_stats}, imgs,
-                train=True, mutable=["batch_stats"])
-            batch_stats = mut["batch_stats"]
-        else:
-            logits = model.apply({"params": params}, imgs, train=True)
-        loss = optax.softmax_cross_entropy_with_integer_labels(
-            logits, lbls).mean()
-        return loss, batch_stats
-
-    tx = optax.sgd(0.01, momentum=0.9)
-    opt_state = tx.init(params)
-    # batch_stats are computed per-shard from the micro-batch, so they must
-    # be synced (on one chip the pmean over a size-1 axis is free in XLA).
-    sync_aux = (os.environ.get("BENCH_SYNC_AUX", "1") == "1") and has_bn
-    # steps_per_call > 1 scans several optimizer steps inside one XLA
-    # program, amortizing the host's dispatch latency.
-    spc = int(os.environ.get("BENCH_STEPS_PER_CALL", "5"))
-    step = make_train_step(loss_fn, tx, mesh, sync_aux_state=sync_aux,
-                           steps_per_call=spc)
-    if spc > 1:
-        images = jnp.broadcast_to(images[None], (spc,) + images.shape)
-        labels = jnp.broadcast_to(labels[None], (spc,) + labels.shape)
-
-    data = (images, labels)   # already mesh-sharded
-    step, flops, nbytes = aot_compile(
-        step, (params, batch_stats, opt_state, data))
-    # max(1, ...): one untimed call is always needed to bind `loss` (and
-    # to finish compilation) even when BENCH_WARMUP=0.
-    for _ in range(max(1, warmup_iters)):
-        params, batch_stats, opt_state, loss = step(
-            params, batch_stats, opt_state, data)
-    np.asarray(loss)
-
-    def one(state, data):
-        params, batch_stats, opt_state, _ = state
-        return step(params, batch_stats, opt_state, data)
-
-    state = (params, batch_stats, opt_state, loss)
-    state, dt = _timed(one, state, data, timed_batches, windows)
-    params, batch_stats, opt_state, loss = state
-
-    img_per_sec = batch * spc * timed_batches / dt
-    per_chip = img_per_sec / nchips
-    step_ms = dt / (timed_batches * spc) * 1e3
-
-    # MFU: achieved FLOP/s over the chip's peak bf16 FLOP/s.  FLOPs per
-    # call come from XLA's cost model (scan body scaled by trip count).
-    # All roofline numbers are PER CHIP: XLA's cost analysis describes the
-    # per-device SPMD module.
-    kind = jax.devices()[0].device_kind
-    peaks = profiling.device_peaks(kind)
-    peak = peaks.bf16_flops
-    achieved = flops * spc / (dt / timed_batches)
-    mfu = achieved / peak
-    hbm_util = (nbytes * spc / (dt / timed_batches)) / peaks.hbm_bytes_per_s
-
-    # The Pascal anchor is ResNet-101 throughput; a cross-model ratio
-    # would be meaningless, so only the (comparable) resnet leg reports it.
-    is_resnet = model_name == "resnet50"
-    return {
-        "metric": f"{model_name}_synthetic_images_per_sec_per_chip",
-        "value": round(per_chip, 2),
-        "unit": "images/sec/chip",
-        "vs_baseline": (round(per_chip / BASELINE_PER_DEVICE, 3)
-                        if is_resnet else None),
-        "step_time_ms": round(step_ms, 2),
-        "batch_per_chip": batch_per_chip,
-        "device_kind": kind,
-        "peak_bf16_tflops_per_chip": peak / 1e12,
-        "achieved_tflops_per_chip": round(achieved / 1e12, 2),
-        "mfu": round(mfu, 4),
-        # XLA cost-model bytes over HBM peak: a roofline proxy, not a
-        # measurement — values near/over 1.0 mean the step is bandwidth-
-        # dominated (some of those accesses are served from VMEM).
-        "xla_bytes_over_hbm_peak": round(hbm_util, 4),
-        "baseline": ("resnet101 103.55 img/s/device (16x Pascal, "
-                     "docs/benchmarks.md:22-39 — the reference's only "
-                     "published absolute throughput; no resnet50 number "
-                     "exists)") if is_resnet else None,
-    }
-
-
-def bench_transformer(jax, hvd, mesh, nchips):
-    """Compute-bound leg: TransformerLM + Pallas flash attention.
-
-    ResNet-50 is HBM-bound (MFU capped ~32% on v5e); this shape is where
-    the MXU can actually be fed — d_model 2048, 12 layers, seq 2048,
-    causal flash attention, bf16 — so its MFU is judged against the 0.40
-    bar, not the bandwidth roofline.
-    """
-    import jax.numpy as jnp
-    import numpy as np
-    import optax
-
-    from horovod_tpu import profiling
-    from horovod_tpu.jax.spmd import make_train_step
-    from horovod_tpu.models import TransformerLM
-
-    dim = int(os.environ.get("BENCH_TLM_DIM", "2048"))
-    depth = int(os.environ.get("BENCH_TLM_DEPTH", "12"))
-    heads = int(os.environ.get("BENCH_TLM_HEADS", "16"))
-    vocab = int(os.environ.get("BENCH_TLM_VOCAB", "32768"))
-    seq = int(os.environ.get("BENCH_TLM_SEQ", "2048"))
-    batch_per_chip = int(os.environ.get("BENCH_TLM_BATCH_PER_CHIP", "8"))
-    warmup_iters = int(os.environ.get("BENCH_TLM_WARMUP", "2"))
-    timed_batches = int(os.environ.get("BENCH_TLM_ITERS", "8"))
-    # Best-of-3 like the resnet leg's best-of-4.
-    windows = int(os.environ.get("BENCH_TLM_WINDOWS", "3"))
-    attn = os.environ.get("BENCH_TLM_ATTN", "flash")
-    batch = batch_per_chip * nchips
-
-    # f32 vs bf16 LayerNorm: the per-op device profile attributes ~50
-    # ms/step to the f32 LN converts+stats at this shape
-    # (convert_reduce_fusion, docs/benchmarks.md) — bf16 LN is the bench
-    # default; set BENCH_TLM_LN_DTYPE=f32 for the conservative config.
-    ln_dtype = (jnp.float32
-                if os.environ.get("BENCH_TLM_LN_DTYPE", "bf16") == "f32"
-                else jnp.bfloat16)
-    model = TransformerLM(vocab=vocab, dim=dim, depth=depth,
-                          num_heads=heads, max_len=seq, attn=attn,
-                          dtype=jnp.bfloat16, head_dtype=jnp.bfloat16,
-                          ln_dtype=ln_dtype)
-    from jax.sharding import NamedSharding, PartitionSpec as P
-    sharding = NamedSharding(mesh, P(tuple(mesh.axis_names)))
-
-    @functools.partial(jax.jit, out_shardings=sharding)
-    def make_tokens(rng):
-        return jax.random.randint(rng, (batch, seq + 1), 0, vocab,
-                                  dtype=jnp.int32)
-
-    tokens = make_tokens(jax.random.PRNGKey(0))
-    params = synth_variables(
-        jax, lambda r: model.init(r, jnp.zeros((1, seq), jnp.int32)),
-        jax.random.PRNGKey(1))["params"]
-
-    # Memory-efficient fused CE head (default): never holds the (N, vocab)
-    # f32 logits as residuals, which otherwise pushes peak HBM past the
-    # chip and makes XLA auto-rematerialize one convolution per layer
-    # (~40 ms/step measured; docs/benchmarks.md).
-    fused_head = os.environ.get("BENCH_TLM_FUSED_XENT", "1") == "1"
-
-    def loss_fn(params, aux, batch):
-        if fused_head:
-            from horovod_tpu.ops.losses import fused_softmax_xent
-            h = model.apply({"params": params}, batch[:, :-1],
-                            return_hidden=True)
-            loss = fused_softmax_xent(
-                h.reshape(-1, dim), params["head"]["kernel"],
-                batch[:, 1:].reshape(-1)).mean()
-        else:
-            # bf16 head matmul (full MXU rate), f32 softmax for stability.
-            logits = model.apply({"params": params}, batch[:, :-1])
-            loss = optax.softmax_cross_entropy_with_integer_labels(
-                logits.astype(jnp.float32), batch[:, 1:]).mean()
-        return loss, aux
-
-    tx = optax.sgd(0.01, momentum=0.9)
-    opt_state = tx.init(params)
-    # steps_per_call scans k optimizer steps inside one XLA program,
-    # amortizing the ~2.4 ms host-dispatch gap (same knob as the resnet
-    # leg; ~7 ms/step of wall-vs-device gap measured at spc=1).
-    spc = int(os.environ.get("BENCH_TLM_STEPS_PER_CALL", "4"))
-    step = make_train_step(loss_fn, tx, mesh, sync_aux_state=False,
-                           steps_per_call=spc)
-    if spc > 1:
-        tokens = jnp.broadcast_to(tokens[None], (spc,) + tokens.shape)
-    step, flops, _ = aot_compile(step, (params, {}, opt_state, tokens))
-
-    for _ in range(max(1, warmup_iters)):   # >=1 binds `loss`
-        params, aux, opt_state, loss = step(params, {}, opt_state, tokens)
-    np.asarray(loss)
-
-    def one(state, data):
-        params, opt_state, _ = state
-        params, _, opt_state, loss = step(params, {}, opt_state, data)
-        return params, opt_state, loss
-
-    state = (params, opt_state, loss)
-    state, dt = _timed(one, state, tokens, timed_batches, windows)
-
-    tok_per_sec = batch * seq * spc * timed_batches / dt
-    step_ms = dt / (timed_batches * spc) * 1e3
-    peak = profiling.device_peaks(jax.devices()[0].device_kind).bf16_flops
-    # MFU by the standard model-FLOPs convention (PaLM appendix B /
-    # Megatron): 6 FLOPs per matmul param per token (fwd+bwd) plus
-    # attention's 12*T*d per token per layer — no credit for recompute,
-    # no causal discount.  XLA's cost model is reported alongside as the
-    # executed-FLOPs view (it counts rematerialization and the fused-CE
-    # backward recompute, but not the Pallas kernels' matmuls, so the
-    # two can land on either side of each other).
-    n_matmul = 12 * depth * dim * dim + vocab * dim
-    model_flops = (6 * n_matmul + 12 * depth * seq * dim) * (
-        batch_per_chip * seq)
-    # dt/timed_batches is seconds per CALL (= spc optimizer steps); the
-    # XLA cost model counts a scan body once, so both scale by spc.
-    achieved = model_flops * spc / (dt / timed_batches)
-    mfu = achieved / peak
-    mfu_xla = flops * spc / (dt / timed_batches) / peak
-    mfu_xla_note = None
-    if mfu_xla > 1.0 and spc > 1:
-        # Guard against a jax/XLA change that starts multiplying the
-        # scan-body cost by trip count: >1.0 MFU is physically
-        # impossible, so drop our own spc scaling and say so.
-        mfu_xla = flops / (dt / timed_batches) / peak
-        mfu_xla_note = ("cost model appears to include the scan trip "
-                        "count; spc scaling removed")
-    # In-jit wire A/B (fp32 vs bf16 vs int8 gradient wire): identical
-    # program except for the reduce_gradients compression, so step-time
-    # deltas are the wire's own cost/benefit.  The fp32 row reuses the
-    # main leg above (compression=none IS the fp32 wire).
-    # The A/B legs must not touch `params`: the donating main leg above
-    # consumed that buffer.  state[0] is the last step call's output and
-    # stays live (nothing donates it after the timed windows).
-    ab_params = state[0]
-    wire_ab = None
-    if (os.environ.get("BENCH_TLM_AB", "1") == "1" and nchips > 1):
-        wire_ab = _injit_wire_ab(
-            jax, np, build_step=lambda comp: make_train_step(
-                loss_fn, tx, mesh, sync_aux_state=False,
-                steps_per_call=spc, compression=comp, donate=False),
-            init_state=lambda: (ab_params, {}, tx.init(ab_params)),
-            data=tokens, nchips=nchips,
-            iters=max(2, timed_batches // 2), spc=spc,
-            fp32_sec_per_step=dt / (timed_batches * spc),
-            mfu_of=lambda sec: round(model_flops / sec / peak, 4))
-    elif os.environ.get("BENCH_TLM_AB", "1") == "1":
-        wire_ab = {"note": "single chip: every collective is the "
-                           "identity, so the gradient wire never "
-                           "engages — run the multi-chip leg for the "
-                           "fp32/bf16/int8 comparison"}
-    # In-jit overlap A/B: identical program except reduce_gradients
-    # emits per-bucket collectives in the scheduler's overlap order
-    # (tail bucket first — ready while earlier layers still
-    # differentiate) instead of one fused tail collective.  Bucket
-    # contents are issue-order independent, so any step-time delta is
-    # XLA's latency hiding, not different math.
-    overlap_ab = None
-    if os.environ.get("BENCH_TLM_OVERLAP_AB", "1") == "1" and nchips > 1:
-        ol_iters = max(2, timed_batches // 2)
-
-        def _overlap_leg(ov):
-            ostep = make_train_step(loss_fn, tx, mesh,
-                                    sync_aux_state=False,
-                                    steps_per_call=spc, donate=False,
-                                    overlap=ov)
-            st = (ab_params, {}, tx.init(ab_params))
-            ostep, _, _ = aot_compile(ostep, (*st, tokens))
-            p, aux, o, loss = ostep(*st, tokens)   # warmup binds loss
-            np.asarray(loss)
-
-            def one(s, data):
-                p, aux, o, _ = s
-                return ostep(p, aux, o, data)
-
-            state = (p, aux, o, loss)
-            _, d = _timed(one, state, tokens, ol_iters, 2)
-
-            def target():
-                np.asarray(one(state, tokens)[-1])
-
-            return d / (ol_iters * spc), target
-
-        overlap_ab = {}
-        for mode, ov in (("off", False), ("on", True)):
-            try:
-                sec, target = _overlap_leg(ov)
-            except Exception as exc:   # noqa: BLE001 — per-leg, not fatal
-                overlap_ab[mode] = {"error": f"{type(exc).__name__}: "
-                                             f"{exc}"[:300]}
-                continue
-            overlap_ab[mode] = {
-                "step_time_ms": round(sec * 1e3, 2),
-                "comm_fraction": _comm_fraction(jax, target),
-            }
-        if ("step_time_ms" in overlap_ab.get("on", {})
-                and "step_time_ms" in overlap_ab.get("off", {})):
-            overlap_ab["on_faster_than_off"] = (
-                overlap_ab["on"]["step_time_ms"]
-                < overlap_ab["off"]["step_time_ms"])
-            if overlap_ab["on"]["comm_fraction"] is None:
-                overlap_ab["note"] = (
-                    "hidden/exposed comm seconds live inside XLA's "
-                    "schedule on the in-jit plane (no host-side "
-                    "measurement point); the eager counterpart in "
-                    "scaling_tcp_2proc.overlap_ab reports the measured "
-                    "hidden/exposed split")
-    elif os.environ.get("BENCH_TLM_OVERLAP_AB", "1") == "1":
-        overlap_ab = {"note": "single chip: no collectives to "
-                              "overlap — run the multi-chip leg"}
-    return {
-        "transformer_lm": {
-            "tokens_per_sec_per_chip": round(tok_per_sec / nchips, 1),
-            "step_time_ms": round(step_ms, 2),
-            "mfu": round(mfu, 4),
-            "mfu_xla_cost_model": round(mfu_xla, 4),
-            **({"mfu_xla_note": mfu_xla_note} if mfu_xla_note else {}),
-            "achieved_model_tflops_per_chip": round(achieved / 1e12, 2),
-            "dim": dim, "depth": depth, "seq_len": seq,
-            "batch_per_chip": batch_per_chip, "attn": attn,
-            **({"injit_wire_ab": wire_ab} if wire_ab else {}),
-            **({"overlap_ab": overlap_ab} if overlap_ab else {}),
-        }
-    }
-
-
-def _injit_wire_ab(jax, np, *, build_step, init_state, data, nchips,
-                   iters, spc, fp32_sec_per_step, mfu_of):
-    """Shared fp32/bf16/int8 in-jit wire A/B: per-wire step time, MFU
-    (when the caller can compute one), and the estimated bytes each wire
-    dtype moves per rank per step (the same plan behind the
-    ``injit.bytes#wire_dtype=*`` counters).  Every wire runs the codec
-    the library selects; a leg that fails is recorded as an error and
-    fails the run."""
-    from horovod_tpu.compression import Compression
-    from horovod_tpu.ops import quantized_collectives as qc
-
-    params = init_state()[0]
-
-    def leg_sec(comp):
-        step = build_step(comp)
-        state = init_state()
-        step, _, _ = aot_compile(step, (*state, data))
-        p, aux, o = state
-        p, aux, o, loss = step(p, aux, o, data)   # warmup binds loss
-        np.asarray(loss)
-
-        def one(st, data):
-            p, aux, o, _ = st
-            return step(p, aux, o, data)
-
-        _, d = _timed(one, (p, aux, o, loss), data, iters, 2)
-        return d / (iters * spc)
-
-    out = {}
-    for wire, comp in (("fp32", Compression.none),
-                       ("bf16", Compression.bf16),
-                       ("int8", Compression.int8)):
-        plan = qc.estimate_wire_plan(params, nchips, comp)
-        if wire == "fp32" and fp32_sec_per_step is not None:
-            sec = fp32_sec_per_step
-        else:
-            try:
-                sec = leg_sec(comp)
-            except Exception as exc:   # noqa: BLE001 — main() exits 1
-                out[wire] = {"error": f"{type(exc).__name__}: "
-                                      f"{exc}"[:300]}
-                continue
-        out[wire] = {
-            "step_time_ms": round(sec * 1e3, 2),
-            "mfu": mfu_of(sec),
-            "est_wire_bytes_per_step_per_rank": plan or None,
-        }
-    if ("step_time_ms" in out.get("int8", {})
-            and "step_time_ms" in out.get("fp32", {})):
-        out["int8_faster_than_fp32"] = (out["int8"]["step_time_ms"]
-                                        < out["fp32"]["step_time_ms"])
-    # Autopilot leg (HOROVOD_TPU_PRECISION=auto + compression="auto"):
-    # warm the per-process ladder with the measured int8-grid residual of
-    # each param leaf (the stand-in for its gradient bucket at this
-    # shape), then time the step with the plan the ladder actually chose.
-    # The acceptance bar: within 5% of the best static wire above.
-    if os.environ.get("BENCH_TLM_AUTO", "1") == "1":
-        out["auto"] = _injit_auto_leg(np, params, leg_sec)
-        best = min((leg["step_time_ms"]
-                    for leg in (out.get(w) or {}
-                                for w in ("fp32", "bf16", "int8"))
-                    if "step_time_ms" in leg), default=None)
-        if best and "step_time_ms" in out["auto"]:
-            out["auto_vs_best_static"] = round(
-                out["auto"]["step_time_ms"] / best, 4)
-    return out
-
-
-def _injit_auto_leg(np, params, leg_sec):
-    """One ``compression="auto"`` timing leg for the in-jit wire A/B."""
-    import jax.tree_util as jtu
-    from horovod_tpu import precision as _precision
-    from horovod_tpu.ops import quantized_collectives as qc
-    saved = {k: os.environ.get(k) for k in
-             ("HOROVOD_TPU_PRECISION", "HOROVOD_TPU_PRECISION_TICKS")}
-    os.environ["HOROVOD_TPU_PRECISION"] = "auto"
-    os.environ["HOROVOD_TPU_PRECISION_TICKS"] = "2"
-    _precision.reset_autopilot()
-    try:
-        pilot = _precision.get_autopilot()
-        rng = np.random.RandomState(0)
-        for path, leaf in jtu.tree_flatten_with_path(params)[0]:
-            shape = tuple(getattr(leaf, "shape", ()))
-            dtype = getattr(leaf, "dtype", None)
-            if (dtype is None or np.dtype(dtype) != np.float32
-                    or not qc.int8_eligible(shape, np.float32)):
-                continue
-            try:
-                g = np.asarray(leaf, dtype=np.float32)
-            except RuntimeError:
-                # The fp32 leg donated this buffer; a synthetic gradient
-                # at the same shape stands in — the int8-grid residual
-                # of gaussian data is representative for the codec.
-                g = rng.standard_normal(shape).astype(np.float32)
-            denom = float(np.linalg.norm(g.ravel()))
-            rel = (float(np.linalg.norm(
-                g - np.asarray(qc.snap_to_grid(g), dtype=np.float32)))
-                / denom) if denom > 0 else 0.0
-            name = f"grads{jtu.keystr(path)}"
-            for _ in range(4):   # enough healthy ticks to reach int8
-                pilot.note_residual(name, rel)
-        levels = {}
-        for path, leaf in jtu.tree_flatten_with_path(params)[0]:
-            lv = pilot.level_for(f"grads{jtu.keystr(path)}")
-            key = ("fp32", "bf16", "int8")[lv]
-            levels[key] = levels.get(key, 0) + 1
-        try:
-            sec = leg_sec("auto")
-        except Exception as exc:   # noqa: BLE001 — per-leg, not fatal
-            return {"error": f"{type(exc).__name__}: {exc}"[:300]}
-        return {
-            "step_time_ms": round(sec * 1e3, 2),
-            "buckets_by_wire": levels,
-            "promotions": pilot.promotions,
-            "demotions": pilot.demotions,
-        }
-    finally:
-        for k, v in saved.items():
-            if v is None:
-                os.environ.pop(k, None)
-            else:
-                os.environ[k] = v
-        _precision.reset_autopilot()
 
 
 def _pin_cpu_half(half: int) -> bool:
@@ -1977,7 +1412,7 @@ def bench_scaling_tcp():
     TCP ring).  Efficiency = 2-process per-process throughput over the
     1-process number.  This exercises the REAL cross-process eager data
     plane under load; both processes share one host's cores, so the
-    ceiling is contention-bound like the virtual-mesh mode."""
+    ceiling is contention-bound."""
     import subprocess
     import sys
 
@@ -2070,8 +1505,7 @@ def bench_scaling_tcp():
 
     # Single-shot numbers on a contended host swing run-to-run (±30%
     # observed on the 1-CPU bench container); take the best of N windows
-    # per leg — the same policy as the chip legs' BENCH_WINDOWS — so the
-    # artifact reports capability, not scheduler luck.
+    # per leg, so the artifact reports capability, not scheduler luck.
     windows = max(1, int(os.environ.get("BENCH_TCP_WINDOWS", "3")))
 
     def best_leg(nproc, pin=False):
@@ -2372,342 +1806,38 @@ def bench_scaling_tcp():
     }
 
 
-def bench_scaling(n_virtual: int):
-    """Scaling mode: per-chip throughput at N virtual CPU devices vs 1,
-    plus a comm/compute split from the profiler when device-side spans
-    are exposed.  Plumbs the judged multi-chip metric (reference anchor:
-    90% efficiency at 512 GPUs, docs/benchmarks.md:3-6) so a pod run is
-    `python bench.py` away when hardware arrives."""
-    os.environ["XLA_FLAGS"] = (
-        f"--xla_force_host_platform_device_count={n_virtual} "
-        + os.environ.get("XLA_FLAGS", ""))
-    jax = _cpu_jax()
-    import jax.numpy as jnp
-    import numpy as np
-    import optax
-    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-    from horovod_tpu.jax.spmd import make_train_step
-    from horovod_tpu.models import ConvNet
-
-    batch_per_chip = int(os.environ.get("BENCH_SCALE_BATCH_PER_CHIP", "8"))
-    iters = int(os.environ.get("BENCH_SCALE_ITERS", "10"))
-    windows = int(os.environ.get("BENCH_SCALE_WINDOWS", "3"))
-    model = ConvNet(num_classes=10)
-    tx = optax.sgd(0.01, momentum=0.9)
-
-    from horovod_tpu.compression import Compression
-
-    def run(devices, compression=Compression.none):
-        n = len(devices)
-        mesh = Mesh(np.asarray(devices), ("ranks",))
-        batch = batch_per_chip * n
-        rng = jax.random.PRNGKey(0)
-        images = jax.device_put(
-            jax.random.normal(rng, (batch, 32, 32, 3), jnp.float32),
-            NamedSharding(mesh, P("ranks")))
-        labels = jax.device_put(
-            jnp.zeros((batch,), jnp.int32),
-            NamedSharding(mesh, P("ranks")))
-        params = model.init(rng, images[:1])["params"]
-
-        def loss_fn(params, aux, batch):
-            imgs, lbls = batch
-            logits = model.apply({"params": params}, imgs)
-            return optax.softmax_cross_entropy_with_integer_labels(
-                logits, lbls).mean(), aux
-
-        step = make_train_step(loss_fn, tx, mesh, sync_aux_state=False,
-                               donate=False, compression=compression)
-        opt_state = tx.init(params)
-        data = (images, labels)
-        for _ in range(3):   # warmup/compile
-            *_, loss = step(params, {}, opt_state, data)
-        np.asarray(loss)
-
-        def one(state, data):
-            p, o, _ = state
-            p, _, o, loss = step(p, {}, o, data)
-            return p, o, loss
-
-        (_, _, loss), dt = _timed(one, (params, opt_state, loss), data,
-                                  iters, windows)
-
-        def profile_target():
-            np.asarray(one((params, opt_state, loss), data)[-1])
-
-        return batch * iters / dt / n, profile_target, params
-
-    per_chip_1, _, _ = run(jax.devices()[:1])
-    per_chip_n, profile_target, params = run(jax.devices())
-
-    # In-jit wire A/B at N devices: same ConvNet step, only the gradient
-    # wire changes (the 8 MB dense kernel is int8-eligible under the
-    # default floor).  On a shared-core virtual mesh the psum is a
-    # memcpy while the int8 ring does real codec work, so int8 "losing"
-    # here measures codec compute, not wire savings — the note says so.
-    wire_ab = None
-    if os.environ.get("BENCH_SCALE_AB", "1") == "1":
-        from horovod_tpu.ops import quantized_collectives as qc
-        wire_ab = {}
-        for wire, comp in (("fp32", Compression.none),
-                           ("bf16", Compression.bf16),
-                           ("int8", Compression.int8)):
-            if wire == "fp32":
-                per_chip_c = per_chip_n
-            else:
-                try:
-                    per_chip_c, _, _ = run(jax.devices(), compression=comp)
-                except Exception as exc:   # noqa: BLE001 — per-leg
-                    wire_ab[wire] = {"error": f"{type(exc).__name__}: "
-                                              f"{exc}"[:300]}
-                    continue
-            plan = qc.estimate_wire_plan(params, n_virtual, comp)
-            wire_ab[wire] = {
-                "step_time_ms": round(batch_per_chip / per_chip_c * 1e3,
-                                      2),
-                "images_per_sec_per_chip": round(per_chip_c, 2),
-                "est_wire_bytes_per_step_per_rank": plan or None,
-            }
-        if ("step_time_ms" in wire_ab.get("int8", {})
-                and "step_time_ms" in wire_ab.get("fp32", {})):
-            wire_ab["int8_faster_than_fp32"] = (
-                wire_ab["int8"]["step_time_ms"]
-                < wire_ab["fp32"]["step_time_ms"])
-            wire_ab["note"] = (
-                "virtual CPU mesh: collectives are intra-process "
-                "memcpys, so the int8 leg pays the codec FLOPs without "
-                "any wire to save — see scaling_tcp_2proc."
-                "wire_compression for the cross-process wire where the "
-                "byte savings are real")
-
-    # Comm/compute split measured on the ACTUAL benchmark step (not a
-    # probe), where the backend exposes device-side spans.
-    comm_frac = _comm_fraction(jax, profile_target)
-    out = {
-        "metric": "scaling_efficiency",
-        "n_devices": n_virtual,
-        "images_per_sec_per_chip_1": round(per_chip_1, 2),
-        "images_per_sec_per_chip_n": round(per_chip_n, 2),
-        "scaling_efficiency": round(per_chip_n / per_chip_1, 4),
-        **({"injit_wire_ab": wire_ab} if wire_ab else {}),
-        "comm_fraction": comm_frac,
-        "note": "virtual CPU mesh: the N-device run shares the same host "
-                "cores as the 1-device run, so efficiency ~1/N is the "
-                "expected ceiling here — this mode validates the metric "
-                "plumbing and collective layout; hardware efficiency "
-                "needs a pod slice",
-    }
-    if comm_frac is None:
-        out["comm_fraction_note"] = (
-            "null by backend limitation: the CPU platform's profiler "
-            "emits no device-side spans (verified: trace contains only "
-            "the /host:CPU process), so a trace-based comm/compute "
-            "split cannot exist here — see scaling_tcp_2proc."
-            "comm_fraction for the directly measured value on the "
-            "cross-process data plane")
-    return out
-
-
-def _comm_fraction(jax, run_step):
-    """Fraction of device-side per-op span time in collectives while
-    ``run_step()`` (the actual benchmark step) executes under the
-    profiler; None on the CPU platform, whose profiler writes no device
-    spans.  Capture + parsing come from :mod:`horovod_tpu.profiling` so
-    there is exactly one trace-format implementation in the tree."""
-    if jax.default_backend() == "cpu":
-        return None
-    from horovod_tpu import profiling
-
-    tmp = profiling.capture(run_step, warmup=0, iters=3)
-    rows = profiling.per_op_rooflines(
-        tmp, profiling.device_peaks(jax.devices()[0].device_kind))
-    total = sum(r["ms"] for r in rows)
-    comm = sum(r["ms"] for r in rows
-               if any(k in r["op"].lower() for k in (
-                   "all-reduce", "all_reduce", "allreduce",
-                   "all-gather", "collective", "psum")))
-    return round(comm / total, 4)
-
-
 def _scaling_legs():
-    """Both scaling legs, each in its own subprocess (the parent holds
-    the chip; the legs pin themselves to the CPU platform, which a child
-    can do while its parent holds the chip).  Always returns a dict — a
-    failed leg records its error and main() then exits non-zero."""
-    import subprocess
-    import sys
-
-    legs = {}
-    n_virtual = int(os.environ.get("BENCH_SCALE_VIRTUAL_DEVICES", "8"))
+    """The scaling leg (its worker processes pin themselves to the CPU
+    platform).  Always returns a dict — a failed leg records its error
+    and main() then exits non-zero."""
     try:
-        env = dict(os.environ)
-        env.pop("XLA_FLAGS", None)
-        out = subprocess.run(
-            [sys.executable, os.path.abspath(__file__),
-             "--n-virtual", str(n_virtual)],
-            capture_output=True, text=True, timeout=900, env=env)
-        lines = [l for l in out.stdout.splitlines() if l.startswith("{")]
-        if out.returncode != 0 or not lines:
-            raise RuntimeError(
-                f"virtual leg exited {out.returncode}; "
-                f"stdout: {out.stdout[-800:]!r} "
-                f"stderr: {out.stderr[-800:]!r}")
-        legs[f"scaling_virtual_{n_virtual}dev"] = json.loads(lines[-1])
+        return {"scaling_tcp_2proc": bench_scaling_tcp()}
     except Exception as exc:   # noqa: BLE001 — recorded, not fatal
-        legs[f"scaling_virtual_{n_virtual}dev"] = {
-            "error": f"{type(exc).__name__}: {exc}"[:1000]}
-    try:
-        legs["scaling_tcp_2proc"] = bench_scaling_tcp()
-    except Exception as exc:   # noqa: BLE001
-        legs["scaling_tcp_2proc"] = {
-            "error": f"{type(exc).__name__}: {exc}"[:300]}
-    return legs
+        return {"scaling_tcp_2proc": {
+            "error": f"{type(exc).__name__}: {exc}"[:300]}}
 
 
-def write_bench_summary(report: dict,
-                        path: str = None) -> str | None:
-    """Consolidated headline artifact next to the raw report stream.
+# The drills' own subprocesses, a hidden flag each: ``--tcp-worker`` ...
+WORKERS = (ctrl_worker, tcp_worker, solo_worker, xport_worker,
+           recovery_worker, policy_worker, publish_worker)
 
-    The raw ``BENCH_rNN`` files the growth driver captures are stdout
-    tails — truncated, unparsed, and useless for trend lines.  This
-    writes ``BENCH_r08.json`` (override with ``BENCH_SUMMARY_FILE``; set
-    it empty to skip) holding just the judged numbers: single/virtual
-    step times and MFU, TCP scaling efficiency, the zero-copy transport
-    speedup, the CRC integrity overhead, the observatory's on/off
-    step-time overhead, the adaptive-precision autopilot's A/B against
-    the best static wire on both planes, and the hierarchical control
-    topology's tick speedup at the 128-process sweep point — each pulled
-    from the full report when the producing leg ran, ``None`` when it
-    was skipped or failed."""
-    if path is None:
-        path = os.environ.get("BENCH_SUMMARY_FILE", "BENCH_r08.json")
-    if not path:
-        return None
 
-    def get(*keys):
-        node = report
-        for k in keys:
-            if not isinstance(node, dict) or k not in node:
-                return None
-            node = node[k]
-        return node
-
-    tcp = report.get("scaling_tcp_2proc") or {}
-    summary = {
-        "resnet_step_time_ms": get("step_time_ms"),
-        "resnet_mfu": get("mfu"),
-        "transformer_step_time_ms": get("transformer_lm", "step_time_ms"),
-        "transformer_mfu": get("transformer_lm", "mfu"),
-        "virtual_scaling_efficiency": get(
-            "scaling_virtual_8dev", "scaling_efficiency"),
-        "tcp_scaling_efficiency": tcp.get("scaling_efficiency"),
-        "tcp_step_time_ms": get(
-            "scaling_tcp_2proc", "wire_compression", "fp32",
-            "step_time_ms"),
-        "tcp_comm_fraction": tcp.get("comm_fraction"),
-        "overlap_ab": tcp.get("overlap_ab"),
-        "shm_vs_uds_speedup_256k_plus": get(
-            "scaling_tcp_2proc", "xport_sweep",
-            "shm_vs_uds_speedup_256k_plus"),
-        "crc_overhead_256k_plus": get(
-            "scaling_tcp_2proc", "xport_sweep", "crc_overhead_256k_plus",
-            "max"),
-        # Observatory hot-path cost: off/on step time + overhead fraction
-        # from the TCP leg's A/B (acceptance budget <= 2%).
-        "observe_ab": tcp.get("observe_ab"),
-        # Adaptive-precision autopilot vs the best static wire, both
-        # planes (acceptance bar: ratio <= 1.05).
-        "precision_auto_tcp_vs_best_static": get(
-            "scaling_tcp_2proc", "wire_compression", "auto",
-            "vs_best_static"),
-        "precision_auto_injit_vs_best_static": get(
-            "transformer_lm", "injit_wire_ab", "auto_vs_best_static"),
-        "precision_auto_injit": get(
-            "transformer_lm", "injit_wire_ab", "auto"),
-        # Hierarchical control plane: flat-vs-hier negotiation tick at
-        # the sweep's 128-process point (acceptance bar: > 1, i.e. the
-        # per-host aggregation tier beats the flat O(procs) root gather).
-        "hier_tick_speedup_128p": get(
-            "ctrl_sweep", "hier_tick_speedup_128p"),
-    }
-    try:
-        with open(path, "w") as f:
-            json.dump(summary, f, indent=2, sort_keys=True)
-            f.write("\n")
-    except OSError:
-        return None
-    return path
+def _parser():
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    for worker in WORKERS:
+        ap.add_argument(f"--{worker.__name__.replace('_', '-')}",
+                        action="store_true", help=argparse.SUPPRESS)
+    return ap
 
 
 def main():
-    ap = argparse.ArgumentParser()
-    ap.add_argument("--n-virtual", type=int, default=0,
-                    help="run the scaling mode on N virtual CPU devices")
-    ap.add_argument("--no-transformer", action="store_true",
-                    help="skip the transformer MFU leg")
-    ap.add_argument("--tcp-worker", action="store_true",
-                    help=argparse.SUPPRESS)
-    ap.add_argument("--solo-worker", action="store_true",
-                    help=argparse.SUPPRESS)
-    ap.add_argument("--xport-worker", action="store_true",
-                    help=argparse.SUPPRESS)
-    ap.add_argument("--recovery-worker", action="store_true",
-                    help=argparse.SUPPRESS)
-    ap.add_argument("--policy-worker", action="store_true",
-                    help=argparse.SUPPRESS)
-    ap.add_argument("--publish-worker", action="store_true",
-                    help=argparse.SUPPRESS)
-    ap.add_argument("--ctrl-worker", action="store_true",
-                    help=argparse.SUPPRESS)
-    args = ap.parse_args()
+    args = _parser().parse_args()
+    for worker in WORKERS:
+        if getattr(args, worker.__name__):
+            worker()
+            return
 
-    if args.ctrl_worker:
-        ctrl_worker()
-        return
-
-    if args.tcp_worker:
-        tcp_worker()
-        return
-    if args.solo_worker:
-        solo_worker()
-        return
-    if args.xport_worker:
-        xport_worker()
-        return
-    if args.recovery_worker:
-        recovery_worker()
-        return
-    if args.policy_worker:
-        policy_worker()
-        return
-    if args.publish_worker:
-        publish_worker()
-        return
-    if args.n_virtual:
-        print(json.dumps(bench_scaling(args.n_virtual)))
-        return
-
-    import jax
-    import horovod_tpu as hvd
-    from horovod_tpu import compile_cache
-
-    compile_cache.enable()
-    hvd.init()
-    mesh = hvd.ranks_mesh()
-    nchips = hvd.size()
-
-    if os.environ.get("BENCH_ONLY") == "transformer":
-        report = bench_transformer(jax, hvd, mesh, nchips)
-        print(json.dumps(report))
-        return _failed_legs(report)
-    report = bench_resnet(jax, hvd, mesh, nchips)
-    if not args.no_transformer and os.environ.get(
-            "BENCH_TRANSFORMER", "1") == "1":
-        report.update(bench_transformer(jax, hvd, mesh, nchips))
-    # The reference's headline metric is scaling efficiency
-    # (docs/benchmarks.md:3-6); the default artifact carries both
-    # localhost approximations of it (virtual mesh + 2-process TCP).
+    report = {}
     if os.environ.get("BENCH_SCALING", "1") == "1":
         report.update(_scaling_legs())
     # Control-plane tick sweep: flat-vs-hier negotiation round-trip at
@@ -2719,7 +1849,6 @@ def main():
         except Exception as exc:   # noqa: BLE001 — recorded, not fatal
             report["ctrl_sweep"] = {
                 "error": f"{type(exc).__name__}: {exc}"[:1000]}
-    write_bench_summary(report)
     print(json.dumps(report))
     return _failed_legs(report)
 

@@ -662,7 +662,7 @@ def select_backward_phase(*, batch: int, seq: int, heads: int, kv_heads: int,
     import jax
     import jax.numpy as jnp
 
-    from horovod_tpu.ops import flash_attention as fa, sparse_select
+    from horovod_tpu.ops import _pallas, flash_attention as fa, sparse_select
 
     interpret = jax.default_backend() != "tpu"
     B, T, H, Hkv, D = batch, seq, heads, kv_heads, head_dim
@@ -745,7 +745,7 @@ def select_backward_phase(*, batch: int, seq: int, heads: int, kv_heads: int,
           "the KL pass alone left a KL row below zero or a value not finite")
     kl_plan = sparse_select._kl_plan(
         T, H, Hkv, D, index_heads, index_dim, qi.dtype.itemsize,
-        sparse_select._vmem_headroom_ok())
+        _pallas.vmem_headroom_ok())
     return {"shape": [B, T, H, Hkv, D, index_heads, index_dim, topk],
             "interpret": interpret,
             "select_plan": plan._asdict(),

@@ -2,7 +2,7 @@
 ``examples/pytorch_synthetic_benchmark.py``: synthetic images, full training
 step, img/sec mean ± 1.96σ per device and aggregate (reference ``:93-110``).
 
-Fusion on/off comparison (BASELINE.json config 4): pass
+Fusion on/off comparison (the reference's Tensor Fusion on/off): pass
 ``--no-fusion`` to disable trace-time gradient fusion — gradients are then
 allreduced one XLA collective per tensor instead of letting XLA bucket them,
 mirroring ``HOROVOD_FUSION_THRESHOLD=0``.

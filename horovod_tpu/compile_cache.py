@@ -5,7 +5,7 @@ seconds to minutes to compile, and a fresh machine starts with none of
 them.  jax's persistent compilation cache stores a compiled program under
 a key that includes the cache directory's own path, so a directory that
 moves never hits.  Hence one rule, applied by everything that compiles a
-step (``chip_smoke.py``, ``bench.py`` and its workers, ``tools/``):
+step (``benchmark/run.py``, ``chip_smoke.py``, ``bench.py``'s workers):
 
 * where ``JAX_COMPILATION_CACHE_DIR`` is set, jax's own reading of it
   stands and no other directory is set here — an operator (or a machine

@@ -367,7 +367,7 @@ _ALGO_ALIASES = {
 
 # Payload size at/below which "auto" picks the small-tensor path
 # (kDefaultAlgoCrossoverBytes, cpp/htpu/message_table.h); override with
-# HOROVOD_TPU_ALLREDUCE_CROSSOVER, measure with `bench.py --tcp-allreduce`.
+# HOROVOD_TPU_ALLREDUCE_CROSSOVER, measure with bench.py's algorithm sweep.
 DEFAULT_ALGO_CROSSOVER_BYTES = 64 * 1024
 
 

@@ -38,12 +38,12 @@ from horovod_tpu import scheduler as _sched
 from horovod_tpu import timeline as _timeline
 from horovod_tpu.compression import Compressor, NoneCompressor
 from horovod_tpu.jax import MeshAxisUnboundError
+from horovod_tpu.layer_notes import noting_layers
 from horovod_tpu.ops import injit as _injit
 from horovod_tpu.ops import quantized_collectives as _qc
 from horovod_tpu.parallel._vma import ensure_varying_tree
 from horovod_tpu.parallel.hierarchical import hierarchical_allreduce
 from horovod_tpu.parallel.mesh import DCN_AXIS, ICI_AXIS
-from horovod_tpu.parallel.moe import noting_expert_layers
 
 
 def reduce_gradients(grads, axis_names: Tuple[str, ...], *,
@@ -336,8 +336,8 @@ class _GuardedStage:
     ``.compile()`` re-applies the dispatch-time wrapper (ordering guard /
     watchdog / timeline spans), so the AOT route —
     ``step.lower(...).compile()`` — keeps the same per-call contract as
-    direct dispatch (ADVICE r4: bench.py's own AOT path bypassed the
-    guard and the step watchdog)."""
+    direct dispatch (an AOT caller once bypassed the guard and the step
+    watchdog)."""
 
     def __init__(self, inner, rewrap):
         self._inner = inner
@@ -607,7 +607,7 @@ class _StepInstruments:
     The counters ride here too: ``injit.steps`` on every mesh size, and
     what the ``DroplessMoE``, ``Mamba2Mixer`` and ``GatedDeltaNet``
     layers of the step's ``loss_fn`` noted of their static sizes while it
-    was traced (:func:`noting_expert_layers`) — ``moe.assignments``,
+    was traced (:mod:`horovod_tpu.layer_notes`) — ``moe.assignments``,
     ``moe.expert_bytes``, ``moe.held_assignments``, ``moe.fused_matmuls``,
     ``ssm.scan_chunks``, ``ssm.state_bytes``, ``ssm.fused_scans``,
     ``ssm.fused_passes``, ``ssm.head_tiles``, ``ssm.group_channels``,
@@ -617,7 +617,7 @@ class _StepInstruments:
 
     _instances = 0
 
-    def __init__(self, name: str, expert_layers: dict, steps_per_call: int):
+    def __init__(self, name: str, layer_notes: dict, steps_per_call: int):
         import queue
         import types
         # Unique lane per instance: two instrumented steps sharing a lane
@@ -629,7 +629,7 @@ class _StepInstruments:
         self._execute = types.SimpleNamespace(name=f"{name}{suffix}/execute")
         self._queue: "queue.Queue" = queue.Queue()
         self._watcher = None
-        self._expert_layers = expert_layers
+        self._layer_notes = layer_notes
         self._steps_per_call = steps_per_call
         self._calls = itertools.count()
         self._observed_end_ns = 0
@@ -696,7 +696,7 @@ class _StepInstruments:
                     key=ordinal) as span:
                 out = target(*args, **kwargs)
             registry.inc("injit.steps", self._steps_per_call)
-            for counters in self._expert_layers.values():
+            for counters in self._layer_notes.values():
                 for name, count in counters.items():
                     registry.inc(name, count * self._steps_per_call)
             timeline = self._timeline()
@@ -791,8 +791,8 @@ def make_train_step(
     axes = tuple(mesh.axis_names)
     compression = _qc.resolve_injit_compression(compression)
     overlap = _sched.overlap_enabled(overlap)
-    expert_layers: dict = {}
-    loss_fn = noting_expert_layers(loss_fn, expert_layers)
+    layer_notes: dict = {}
+    loss_fn = noting_layers(loss_fn, layer_notes)
     if steps_per_call < 1:
         raise ValueError(f"steps_per_call must be >= 1, got "
                          f"{steps_per_call}")
@@ -851,7 +851,7 @@ def make_train_step(
         spmd_step = _wire_metrics(spmd_step, mesh, compression,
                                   steps_per_call)
     _timeline.listen_to_jax()
-    instrumented = _StepInstruments("train_step", expert_layers,
+    instrumented = _StepInstruments("train_step", layer_notes,
                                     steps_per_call).instrument
 
     wire_identity = (compression is NoneCompressor

@@ -38,10 +38,10 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from horovod_tpu.layer_notes import note_layer
 from horovod_tpu.models.ssm import (
     CausalConv, a_log_init, causal_conv, dt_bias_init)
 from horovod_tpu.ops.gated_delta import delta_sizes, gated_delta_rule
-from horovod_tpu.parallel.moe import note_layer
 
 
 # ``dt_bias`` starts as the inverse softplus of a log-uniform step in

@@ -48,9 +48,10 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from horovod_tpu.layer_notes import note_layer
+from horovod_tpu.ops import _pallas
 from horovod_tpu.ops.mixer_passes import conv_silu, gated_norm, passes_plan
 from horovod_tpu.ops.ssd import scan_plan, scan_sizes, ssd_scan_packed
-from horovod_tpu.parallel.moe import note_layer
 
 
 def dt_bias_init(dt_min: float, dt_max: float, dt_floor: float):
@@ -201,7 +202,7 @@ class Mamba2Mixer(nn.Module):
             return nn.Dense(features, use_bias=False, dtype=self.dtype,
                             param_dtype=self.param_dtype, name=name)
 
-        interpret = jax.default_backend() != "tpu"
+        interpret = _pallas.interpret()
         passes = passes_plan(u, inner=inner, conv_dim=inner + 2 * gn,
                              groups=G, kernel=self.conv_kernel,
                              interpret=interpret)

@@ -74,10 +74,12 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from horovod_tpu.layer_notes import note_layer
+from horovod_tpu.ops import _pallas
 from horovod_tpu.ops.flash_attention import (
     auto_block, flash_attention_auto, flash_qkv_proj, select_tile_fetches)
 from horovod_tpu.parallel.mesh import RANKS_AXIS
-from horovod_tpu.parallel.moe import DroplessMoE, note_layer
+from horovod_tpu.parallel.moe import DroplessMoE
 from horovod_tpu.parallel.ring_attention import (
     full_attention, ring_attention, zigzag_shard_positions)
 from horovod_tpu.parallel.ulysses import ulysses_attention
@@ -144,14 +146,13 @@ class Attention(nn.Module):
                 and not self.qk_norm and self.rope_theta is None):
             # Fused-projection fast path: one op computes qkv and runs
             # the kernels straight off it through head-offset BlockSpecs
-            # — no split slice, no (B, T, H, D) transpose (measured ~25
-            # ms/step of layout copies at the bench shape), and the
+            # — no split slice, no (B, T, H, D) transpose, and the
             # (B, T, 3C) projection is recomputed in the backward rather
-            # than held as a residual (docs/benchmarks.md).
+            # than held as a residual.
             w = _QKVKernel(3 * C, name="qkv")(C)
             out = flash_qkv_proj(
                 x.astype(self.dtype), w, self.num_heads, causal=True,
-                interpret=jax.default_backend() != "tpu")
+                interpret=_pallas.interpret())
             return nn.Dense(C, use_bias=False, dtype=self.dtype,
                             param_dtype=jnp.float32, name="proj")(out)
         qkv = nn.Dense(3 * C, use_bias=False, dtype=self.dtype,
@@ -295,7 +296,7 @@ class GroupedQueryAttention(nn.Module):
             out, kl, select = sparse_select.sparse_attention_reference(
                 q, k, v, qi, ki, w, topk)
         else:
-            interpret = jax.default_backend() != "tpu"
+            interpret = _pallas.interpret()
             tile = ({"tile": self.indexer["tile"]}
                     if "tile" in self.indexer else {})
             with jax.named_scope("index"):
@@ -528,9 +529,9 @@ class TransformerLM(nn.Module):
     tp_axis: Any = None
     dtype: Any = jnp.bfloat16
     # LM-head matmul compute dtype.  f32 is the safe default; bf16 runs
-    # the (T, d) @ (d, vocab) projection at full MXU rate (measured
-    # ~20% of a d=2048/vocab=32k training step on v5e, docs/benchmarks.md)
-    # — cast the logits back to f32 for the softmax in the loss.
+    # the (T, d) @ (d, vocab) projection at full MXU rate (the head is
+    # about a fifth of a gpt cell's step: PERF.md §5, "The head, op by
+    # op") — cast the logits back to f32 for the softmax in the loss.
     head_dtype: Any = jnp.float32
     # LayerNorm compute dtype (see Block.ln_dtype); bf16 for max MFU.
     ln_dtype: Any = jnp.float32

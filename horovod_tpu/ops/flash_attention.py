@@ -46,14 +46,15 @@ into 256-wide sub-tiles and leaves those above the diagonal out
 (:func:`_diag_sub`, :func:`_diag_regions`), and its dead grid steps fetch
 nothing.  The forms that lost to these on the chip (a fused one-pass
 backward, a fully-unrolled backward, a transpose-to-merged backward, a
-chunked XLA backward) left at PR 27; docs/benchmarks.md keeps their
-measurements.  That fused backward took one head a grid step and carried
-``dq`` of the whole sequence in a dynamically indexed scratch through an
-in-kernel loop (T 2048: 4.05 ms against the pair's 2.85); the one that
-runs under a selection map since PR 39 takes a KV group a step, keeps
-``dq`` in a statically indexed scratch and carries the KV head's ``dK``,
-``dV`` across GRID steps — 33.9 ms a layer against the pair's 47.2 at T
-16,384.  The map-less pairs below still form seven products a tile.
+chunked XLA backward) left at PR 27; docs/benchmarks.md, "Before the
+chip", lists what was tried.  That fused backward took one head a grid
+step and carried ``dq`` of the whole sequence in a dynamically indexed
+scratch through an in-kernel loop, and was slower than the pair; the one
+that runs under a selection map since PR 39 takes a KV group a step,
+keeps ``dq`` in a statically indexed scratch and carries the KV head's
+``dK``, ``dV`` across GRID steps — 33.9 ms a layer against the pair's
+47.2 at T 16,384 (PERF.md §6, PR 39).  The map-less pairs below still
+form seven products a tile.
 
 Grouped-query attention: ``k`` and ``v`` may hold fewer heads than ``q``.
 At lane-aligned heads nothing is repeated in HBM: the forward's and the dq
@@ -95,52 +96,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 # Shared with the oracle/ring implementations so masking stays numerically
 # identical across all attention paths.
+from horovod_tpu.ops import _pallas
 from horovod_tpu.parallel.ring_attention import _NEG_BIG, full_attention
-
-
-
-# TPU generations with only 16 MB of physical VMEM per core — a scoped
-# budget above Mosaic's default cannot be backed there, so the forms that
-# need one (the fully-unrolled forward past T 2048, the grouped backward
-# pair) stand down in _plan.
-_SMALL_VMEM_DEVICE_KINDS = ("v2", "v3")
-
-
-def _vmem_headroom_ok() -> bool:
-    d = jax.local_devices()[0]
-    if d.platform != "tpu":
-        return True   # CPU/interpret: the limit is not enforced
-    try:
-        kind = (d.device_kind or "").lower()
-    except Exception:   # noqa: BLE001 — runtime refused the query
-        kind = ""
-    if not kind:
-        # A TPU whose generation cannot be read could be a v2/v3 with
-        # 16 MB of physical VMEM: fail closed — a stood-down raised
-        # budget costs a slower kernel form, an over-request fails the
-        # whole compile.
-        return False
-    return not any(g in kind for g in _SMALL_VMEM_DEVICE_KINDS)
-
-
-def _vmem_limit(mb: int) -> dict:
-    """``CompilerParams`` keyword for a scoped-VMEM budget of ``mb`` MB;
-    0 leaves Mosaic's default (16 MB) in place."""
-    return {"vmem_limit_bytes": mb * 1024 * 1024} if mb else {}
-
-
-def _struct(shape, dtype, *like):
-    """ShapeDtypeStruct for a pallas output, inheriting the union of the
-    inputs' varying-manual-axes: under ``shard_map(check_vma=True)`` the
-    kernel outputs vary over exactly the axes the inputs do, and jax
-    requires that declared explicitly."""
-    vma = frozenset()
-    for l in like:
-        vma |= jax.typeof(l).vma
-    # Always explicit, even when empty: an output of invariant inputs
-    # (a gathered tensor) is invariant, and under check_vma jax refuses
-    # a struct that does not say so.
-    return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
 
 
 def _block_mask(qi, kj, block_q, block_k, causal, seq_len):
@@ -295,10 +252,8 @@ def _fwd_kernel_unrollkv(q_ref, k_ref, v_ref, o_ref, lse_ref,
     but the s = q k^T matmul of step j+1 depends only on the (invariant)
     q and k tiles — unrolling exposes that to Mosaic's scheduler, which
     overlaps step j's VPU softmax with step j+1's MXU matmul.  The
-    grid-per-KV-block variant cannot (its per-step bodies serialize) and
-    measured ~51% MXU on v5e; this form measured ~70%+
-    (docs/benchmarks.md).  K/V are also fetched once per (b, h) instead
-    of once per Q block."""
+    grid-per-KV-block variant cannot (its per-step bodies serialize).
+    K/V are also fetched once per (b, h) instead of once per Q block."""
     qi = pl.program_id(2)
     m_scr[...] = jnp.full_like(m_scr, _NEG_BIG)
     l_scr[...] = jnp.zeros_like(l_scr)
@@ -362,8 +317,7 @@ def _fwd_kernel_fullunroll(q_ref, k_ref, v_ref, o_ref, lse_ref, *,
     static, and the per-Q-block online-softmax chains are independent
     SSA values with no scratch — Mosaic's scheduler is free to
     interleave one chain's VPU softmax with another's MXU matmul.
-    Measured the fastest forward form on v5e for T <= 4k
-    (docs/benchmarks.md)."""
+    The form every cell of T <= 4096 runs (PERF.md §3, kernels)."""
     # Whole rows read/written ONCE; per-block tiles are value-level
     # static slices (ref-level partial slices trip the interpreter's vma
     # tracking under shard_map, and a single store is also the friendlier
@@ -442,9 +396,8 @@ def _fwd_packed(q, k, v, H, D, plan, *, scale, causal, block_q, block_k,
                 interpret, seq_len=None, head_base=(0, 0, 0), kv_rep=1):
     """Forward on head-packed (B, T, C) views (C = H*D): the head is a
     grid axis and every BlockSpec offsets its last dim by ``h*D``, so no
-    (B, T, H, D) -> (B*H, T, D) transpose copy ever materializes in HBM
-    (measured ~25 ms/step of pure layout copies at the bench shape —
-    docs/benchmarks.md).  ``head_base`` shifts each operand's head-block
+    (B, T, H, D) -> (B*H, T, D) transpose copy ever materializes in HBM.
+    ``head_base`` shifts each operand's head-block
     offset, letting q/k/v be three regions of ONE fused (B, T, 3*H*D)
     projection (so the qkv split never copies either).  ``plan`` is
     :func:`_plan`'s: which of the three forms runs.  ``kv_rep`` query
@@ -475,12 +428,12 @@ def _fwd_packed(q, k, v, H, D, plan, *, scale, causal, block_q, block_k,
                 pl.BlockSpec((1, 1, T, 8), lambda b, h: (b, h, 0, 0)),
             ],
             out_shape=[
-                _struct((B, T, H * D), q.dtype, q, k, v),
-                _struct((B, H, T, 8), jnp.float32, q, k, v),
+                _pallas.struct((B, T, H * D), q.dtype, q, k, v),
+                _pallas.struct((B, H, T, 8), jnp.float32, q, k, v),
             ],
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel"),
-                **_vmem_limit(plan.fwd_vmem_mb)),
+                **_pallas.vmem_limit(plan.fwd_vmem_mb)),
             interpret=interpret,
         )(q, k, v)
         return out, lse[..., 0]
@@ -504,8 +457,8 @@ def _fwd_packed(q, k, v, H, D, plan, *, scale, causal, block_q, block_k,
                              lambda b, h, i: (b, h, i, 0)),
             ],
             out_shape=[
-                _struct((B, T, H * D), q.dtype, q, k, v),
-                _struct((B, H, T, 8), jnp.float32, q, k, v),
+                _pallas.struct((B, T, H * D), q.dtype, q, k, v),
+                _pallas.struct((B, H, T, 8), jnp.float32, q, k, v),
             ],
             scratch_shapes=[
                 pltpu.VMEM((block_q, 128), jnp.float32),
@@ -538,8 +491,8 @@ def _fwd_packed(q, k, v, H, D, plan, *, scale, causal, block_q, block_k,
                          lambda b, h, i, j: (b, h, i, 0)),
         ],
         out_shape=[
-            _struct((B, T, H * D), q.dtype, q, k, v),
-            _struct((B, H, T, 8), jnp.float32, q, k, v),
+            _pallas.struct((B, T, H * D), q.dtype, q, k, v),
+            _pallas.struct((B, H, T, 8), jnp.float32, q, k, v),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, 128), jnp.float32),
@@ -828,8 +781,7 @@ def _bwd_pallas_packed_grouped(q, k, v, o, lse, do, H, D, group, *, scale,
                                causal, block_q, block_k, interpret,
                                seq_len, head_base, vmem_mb=0, sub=0):
     """Head-group blocked split backward on head-packed (B, T, C) views:
-    the strided 256-byte-row tax of the per-head packed kernels
-    (measured ~12 ms/step at the bench shape, docs/benchmarks.md) is
+    the strided 256-byte-row tax of the per-head packed kernels is
     removed by reading ``group`` adjacent heads per tile — contiguous
     ``group*D``-wide rows — while keeping the copies-free packed layout.
     ``sub`` is :func:`_diag_sub`'s: the sub-tile that diagonal block pairs
@@ -873,7 +825,7 @@ def _bwd_pallas_packed_grouped(q, k, v, o, lse, do, H, D, group, *, scale,
     sem4 = pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "parallel",
                              "arbitrary"),
-        **_vmem_limit(vmem_mb))
+        **_pallas.vmem_limit(vmem_mb))
     dk, dv = pl.pallas_call(
         functools.partial(_dkdv_kernel_grouped, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k,
@@ -883,8 +835,8 @@ def _bwd_pallas_packed_grouped(q, k, v, o, lse, do, H, D, group, *, scale,
         in_specs=[kv_specs["q"], kv_specs["k"], kv_specs["v"],
                   kv_specs["do"], kv_specs["row8"], kv_specs["row8"]],
         out_specs=[kv_specs["out"], kv_specs["out"]],
-        out_shape=[_struct((B, T, C), k.dtype, q, k, v, do),
-                   _struct((B, T, C), v.dtype, q, k, v, do)],
+        out_shape=[_pallas.struct((B, T, C), k.dtype, q, k, v, do),
+                   _pallas.struct((B, T, C), v.dtype, q, k, v, do)],
         scratch_shapes=[pltpu.VMEM((group, block_k, D), jnp.float32),
                         pltpu.VMEM((group, block_k, D), jnp.float32)],
         compiler_params=sem4,
@@ -912,7 +864,7 @@ def _bwd_pallas_packed_grouped(q, k, v, o, lse, do, H, D, group, *, scale,
         in_specs=[q_specs["q"], q_specs["k"], q_specs["v"],
                   q_specs["do"], q_specs["row8"], q_specs["row8"]],
         out_specs=[q_specs["out"]],
-        out_shape=[_struct((B, T, C), q.dtype, q, k, v, do)],
+        out_shape=[_pallas.struct((B, T, C), q.dtype, q, k, v, do)],
         scratch_shapes=[pltpu.VMEM((group, block_q, D), jnp.float32)],
         compiler_params=sem4,
         interpret=interpret,
@@ -989,8 +941,8 @@ def _bwd_pallas_packed(q, k, v, o, lse, do, H, D, plan, *, scale, causal,
         in_specs=[kv_specs["q"], kv_specs["k"], kv_specs["v"],
                   kv_specs["do"], kv_specs["row8"], kv_specs["row8"]],
         out_specs=[kv_specs["out"], kv_specs["out"]],
-        out_shape=[_struct((B, T, C // kv_rep), k.dtype, q, k, v, do),
-                   _struct((B, T, C // kv_rep), v.dtype, q, k, v, do)],
+        out_shape=[_pallas.struct((B, T, C // kv_rep), k.dtype, q, k, v, do),
+                   _pallas.struct((B, T, C // kv_rep), v.dtype, q, k, v, do)],
         scratch_shapes=[pltpu.VMEM((block_k, D), jnp.float32),
                         pltpu.VMEM((block_k, D), jnp.float32)],
         compiler_params=sem4,
@@ -1017,7 +969,7 @@ def _bwd_pallas_packed(q, k, v, o, lse, do, H, D, plan, *, scale, causal,
         in_specs=[q_specs["q"], q_specs["k"], q_specs["v"],
                   q_specs["do"], q_specs["row8"], q_specs["row8"]],
         out_specs=[q_specs["out"]],
-        out_shape=[_struct((B, T, C), q.dtype, q, k, v, do)],
+        out_shape=[_pallas.struct((B, T, C), q.dtype, q, k, v, do)],
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
         compiler_params=sem4,
         interpret=interpret,
@@ -1358,8 +1310,8 @@ def _select_fwd(q, k, v, select, H, D, *, scale, causal, block_q, block_k,
             pl.BlockSpec((1, 1, block_q, G), lambda b, h, i, j: (b, h, i, 0)),
         ],
         out_shape=[
-            _struct((B, T, H * D), q.dtype, q, k, v, select),
-            _struct((B, Hkv, T, G), jnp.float32, q, k, v, select),
+            _pallas.struct((B, T, H * D), q.dtype, q, k, v, select),
+            _pallas.struct((B, Hkv, T, G), jnp.float32, q, k, v, select),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, block_k), jnp.float32),
@@ -1370,7 +1322,7 @@ def _select_fwd(q, k, v, select, H, D, *, scale, causal, block_q, block_k,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary"),
-            **_vmem_limit(vmem_mb)),
+            **_pallas.vmem_limit(vmem_mb)),
         interpret=interpret,
         name="flash_select_fwd",
     )(q, k, v, select)
@@ -1395,9 +1347,10 @@ def _select_bwd(q, k, v, select, o, lse, do, H, D, *, fused, scale, causal,
     live_k = _select_live_k(causal, block_q, block_k)
     kernel_kw = dict(scale=scale, causal=causal, block_q=block_q,
                      block_k=block_k, seq_len=seq_len, group=G, head_dim=D)
-    dq_shape = _struct((B, T, H * D), q.dtype, q, k, v, do, select)
-    dkdv_shapes = [_struct((B, T, Hkv * D), k.dtype, q, k, v, do, select),
-                   _struct((B, T, Hkv * D), v.dtype, q, k, v, do, select)]
+    dq_shape = _pallas.struct((B, T, H * D), q.dtype, q, k, v, do, select)
+    dkdv_shapes = [
+        _pallas.struct((B, T, Hkv * D), k.dtype, q, k, v, do, select),
+        _pallas.struct((B, T, Hkv * D), v.dtype, q, k, v, do, select)]
     bias_scr = pltpu.VMEM((block_q, block_k), jnp.float32)
     dq_scr = pltpu.VMEM((G, block_q, D), jnp.float32)
 
@@ -1426,7 +1379,7 @@ def _select_bwd(q, k, v, select, o, lse, do, H, D, *, fused, scale, causal,
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary",
                                      "arbitrary"),
-                **_vmem_limit(vmem_mb)),
+                **_pallas.vmem_limit(vmem_mb)),
             interpret=interpret,
             name="flash_select_bwd",
         )(q, k, v, do, lse, delta, select))
@@ -1436,7 +1389,7 @@ def _select_bwd(q, k, v, select, o, lse, do, H, D, *, fused, scale, causal,
 
     params = pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
-        **_vmem_limit(vmem_mb))
+        **_pallas.vmem_limit(vmem_mb))
     kv_q = pl.BlockSpec((1, block_q, G * D),
                         lambda b, h, j, i: (b, live_q(i, j), h))
     kv_kv = pl.BlockSpec((1, block_k, D), lambda b, h, j, i: (b, j, h))
@@ -1531,7 +1484,7 @@ def _plan(*, T, D, H, head_base, itemsize, causal, block_q, block_k,
     the head offsets of q, k, v inside their packed rows; ``itemsize``:
     bytes an operand element; ``causal``; the four resolved blocks;
     ``manual_axes``: whether the operands vary over manual mesh axes
-    (``shard_map``); ``vmem_headroom``: :func:`_vmem_headroom_ok` —
+    (``shard_map``); ``vmem_headroom``: :func:`_pallas.vmem_headroom_ok` —
     whether the device backs a scoped budget above Mosaic's default;
     ``kv_rep``: query heads a KV head (1: multi-head attention);
     ``select``: whether the call carries a selection map — then the group
@@ -1564,12 +1517,8 @@ def _plan(*, T, D, H, head_base, itemsize, causal, block_q, block_k,
     fwd_vmem_mb = 0 if T <= _DEFAULT_VMEM_MAX_T else _FULL_UNROLL_VMEM_MB
     if (T <= _FULL_UNROLL_MAX_T and T % tile == 0
             and T // tile <= _FULL_UNROLL_MAX_NQ
-            # Under shard_map manual axes IN INTERPRET MODE the generic
-            # HLO interpreter cannot discharge this kernel's loads (its
-            # vma check rejects the block dynamic_slices), so CPU tests
-            # take the unrolled-KV form there; compiled Mosaic is
-            # unaffected.
-            and not (interpret and manual_axes)
+            # CPU tests under shard_map take the unrolled-KV form.
+            and not _pallas.xla_form(interpret, manual_axes)
             and row_fits
             # A budget the device cannot back stands this form down
             # instead of failing the whole compile.
@@ -1612,7 +1561,7 @@ def _plan_for(q, H, D, head_base, causal, block_q, block_k, bwd_block_q,
                  block_k=block_k, bwd_block_q=bwd_block_q,
                  bwd_block_k=bwd_block_k, interpret=interpret,
                  manual_axes=bool(jax.typeof(q).vma),
-                 vmem_headroom=_vmem_headroom_ok(), kv_rep=kv_rep,
+                 vmem_headroom=_pallas.vmem_headroom_ok(), kv_rep=kv_rep,
                  select=select)
 
 
@@ -1817,9 +1766,9 @@ def _flash_qkv_proj_fwd(x, w, H, scale, causal, block_q, block_k,
                         interpret, seq_len)
     # qkv is NOT saved: the backward recomputes it from (x, w) — one
     # extra (B*T, C) @ (C, 3C) matmul in exchange for never holding the
-    # (B, T, 3C) projection as a residual (201 MB/layer at the bench
-    # shape; the dropped ~2.4 GB is what keeps XLA's auto-remat from
-    # re-deriving a convolution per layer, docs/benchmarks.md).
+    # (B, T, 3C) projection as a residual (3C two-byte values a token a
+    # layer: memory that XLA would otherwise win back by rematerialising
+    # whole fusions a layer).
     return out, (x, w, out, lse)
 
 
@@ -1886,10 +1835,9 @@ def auto_block(T: int) -> int:
     blocks' sublane dim divisible by 8 — including a lone block; 128
     fills whole lanes, so when a choice exists the aligned block avoids
     padded-lane waste on the scores tile).  Bigger blocks amortize
-    per-grid-step overhead: on v5e at T=2048 the 1024 block measured 2x
-    faster forward and 1.4x faster grad than 256, and 1024x1024 is the
-    largest square block whose f32 scores tile fits the 16 MB scoped
-    VMEM (2048x1024 exceeds it; docs/benchmarks.md).  0 = cannot tile;
+    per-grid-step overhead, and 1024x1024 is the largest square block
+    whose f32 scores tile fits the 16 MB scoped VMEM (2048x1024 exceeds
+    it).  0 = cannot tile;
     :func:`flash_attention_auto` then pads."""
     if T <= 1024:
         return T if T % 8 == 0 else 0
@@ -1958,7 +1906,7 @@ def flash_attention_auto(q, k, v, *, causal: bool = True,
     ``(out, lse)``.
     """
     T = q.shape[1]
-    interpret = jax.default_backend() != "tpu"
+    interpret = _pallas.interpret()
     T_pad, blk = _auto_tiling(T)
     more = {} if select is None else {"select": select}
     if T_pad == T:
@@ -2003,7 +1951,7 @@ def select_tile_fetches(q, k) -> int:
     T, blk = _auto_tiling(q.shape[1])
     plan = _plan_for(
         jax.ShapeDtypeStruct((B, T, H * D), q.dtype), H, D, (0, 0, 0), True,
-        blk, blk, blk, blk, jax.default_backend() != "tpu",
+        blk, blk, blk, blk, _pallas.interpret(),
         kv_rep=H // Hkv, select=True)
 
     def causal_tiles(block_q, block_k):
@@ -2134,8 +2082,7 @@ def flash_attention_qkv(qkv, num_heads: int, *, causal: bool = True,
     and returns the head-merged ``(B, T, C)`` attention output.  The
     kernels read q/k/v via head-offset BlockSpecs into the SAME array,
     so neither the qkv split nor any (B, T, H, D) transpose ever copies
-    in HBM — at the bench shape those layout copies were ~25 ms/step
-    (docs/benchmarks.md).  Requires lane-aligned heads (``D % 128 ==
+    in HBM.  Requires lane-aligned heads (``D % 128 ==
     0``); use :func:`flash_attention` otherwise.  The qkv cotangent is
     one concatenate.
     """

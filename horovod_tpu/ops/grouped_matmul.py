@@ -50,8 +50,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from horovod_tpu.ops.flash_attention import (
-    _struct, _vmem_headroom_ok, _vmem_limit)
+from horovod_tpu.ops import _pallas
 
 _F32 = jnp.float32
 
@@ -113,11 +112,12 @@ def _plan(*, rows, groups, k, n, itemsize, interpret, manual_axes,
     holds twice the bytes), the contraction whole in VMEM; they ask a
     scoped-VMEM budget above Mosaic's default, which ``vmem_headroom``
     says the device backs, and leave what would not fit it.  Interpreted
-    Pallas under ``shard_map``'s manual axes cannot run in jax 0.9.0 (as in
-    ``ssd._plan``)."""
+    Pallas under ``shard_map``'s manual axes takes ``ragged_dot``
+    (:func:`_pallas.xla_form`)."""
     ragged = GroupedPlan("ragged_dot", 0, 0, 0, 0, _RAGGED_LANES)
     if (k % 128 or n % 128 or rows % _ROWS or groups < 1 or itemsize != 2
-            or not vmem_headroom or (interpret and manual_axes)):
+            or not vmem_headroom
+            or _pallas.xla_form(interpret, manual_axes)):
         return ragged
     if (_vmem_bytes(_ROWS, _STRIP, _MOST_COLS, k, n, itemsize)
             > _VMEM_MB * 2 ** 20 * 3 // 4):
@@ -136,7 +136,7 @@ def grouped_plan(rows_like, groups: int, n: int, *,
                  k=rows_like.shape[1], n=n,
                  itemsize=jnp.dtype(rows_like.dtype).itemsize,
                  interpret=interpret, manual_axes=bool(vma),
-                 vmem_headroom=_vmem_headroom_ok())
+                 vmem_headroom=_pallas.vmem_headroom_ok())
 
 
 # ------------------------------------------------------------- the visits
@@ -250,13 +250,6 @@ def _tgmm_kernel(tiles_ref, groups_ref, offsets_ref, x_ref, dy_ref, o_ref,
         o_ref[...] = acc_ref[...].astype(o_ref.dtype)
 
 
-def _params(plan, interpret, semantics):
-    if interpret:
-        return {}
-    return {"compiler_params": pltpu.CompilerParams(
-        dimension_semantics=semantics, **_vmem_limit(plan.vmem_mb))}
-
-
 @functools.partial(jax.jit, inline=True,
                    static_argnames=("transposed", "plan", "interpret"))
 def _gmm(x, w, group_sizes, *, transposed: bool, plan, interpret):
@@ -284,9 +277,10 @@ def _gmm(x, w, group_sizes, *, transposed: bool, plan, interpret):
                 w_spec],
             out_specs=pl.BlockSpec((plan.rows, cols),
                                    lambda j, v, t, g, o: (t[v], j))),
-        out_shape=_struct((M, N), x.dtype, x, w),
+        out_shape=_pallas.struct((M, N), x.dtype, x, w),
         interpret=interpret, name="moe_gmm_nt" if transposed else "moe_gmm",
-        **_params(plan, interpret, ("parallel", "arbitrary")),
+        **_pallas.compiler_params(interpret, ("parallel", "arbitrary"),
+                                  plan.vmem_mb),
     )(tiles, groups, offsets, x, w)
 
 
@@ -311,9 +305,10 @@ def _tgmm(x, dy, group_sizes, *, plan, interpret):
             out_specs=pl.BlockSpec((None, k_cols, n_cols),
                                    lambda i, j, v, t, g, o: (g[v], i, j)),
             scratch_shapes=[pltpu.VMEM((k_cols, n_cols), _F32)]),
-        out_shape=_struct((group_sizes.shape[0], K, N), x.dtype, x, dy),
+        out_shape=_pallas.struct((group_sizes.shape[0], K, N), x.dtype, x, dy),
         interpret=interpret, name="moe_tgmm",
-        **_params(plan, interpret, ("parallel", "parallel", "arbitrary")),
+        **_pallas.compiler_params(
+            interpret, ("parallel", "parallel", "arbitrary"), plan.vmem_mb),
     )(tiles, group_of, offsets, x, dy)
 
 

@@ -4,8 +4,7 @@ The straightforward ``logits = hidden @ W; optax.softmax_cross_entropy``
 materializes an ``(N, vocab)`` f32 logits tensor *and* keeps it (plus
 softmax intermediates) alive as autodiff residuals — at the benchmark
 shape (N = 16384, vocab = 32768) that is ~2 GB of f32 logits and enough
-peak-HBM pressure that XLA auto-rematerializes convolution fusions
-(measured ~26 ms/step of recompute on v5e, docs/benchmarks.md).
+peak-HBM pressure that XLA auto-rematerializes convolution fusions.
 
 This op computes the same loss with the streamed-head schedule (public
 pattern in every large-LM codebase):
@@ -65,10 +64,9 @@ def _schedule(n: int, chunk: int):
 
     Two chunks where the bound allows: the halved logits transient (1 GB
     instead of 2 GB at the bench shape) drops peak HBM below the point
-    where XLA auto-rematerializes one convolution fusion per layer —
-    measured 547 -> 518 ms/step on v5e (docs/benchmarks.md) — with none
-    of the while-loop and ``dh``-stacking overhead that made a scanned
-    loop slower than one tile.  A smaller ``chunk`` is honoured: it
+    where XLA auto-rematerializes one convolution fusion per layer, with
+    none of the while-loop and ``dh``-stacking overhead that made a
+    scanned loop slower than one tile.  A smaller ``chunk`` is honoured: it
     RAISES the chunk count to the smallest that tiles ``n`` exactly
     within the bound, unrolled up to ``_MAX_UNROLL_CHUNKS`` bodies and
     scanned past that."""

@@ -63,7 +63,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from horovod_tpu.ops.flash_attention import _struct
+from horovod_tpu.ops import _pallas
 
 _F32 = jnp.float32
 
@@ -113,10 +113,10 @@ def _plan(*, T, inner, conv_dim, groups, kernel, itemsize, interpret,
     a convolution whose channels and whose column offset
     in the packed array (``inner``) are whole 128-lane tiles, with no more
     taps than the rows carried between blocks.  Interpreted Pallas under
-    ``shard_map``'s manual axes cannot run in jax 0.9.0 (as in
-    ``ssd._plan``)."""
+    ``shard_map``'s manual axes takes the XLA forms
+    (:func:`_pallas.xla_form`)."""
     xla = PassPlan("xla", 0, 0, 0, 0)
-    if inner % groups or (interpret and manual_axes):
+    if inner % groups or _pallas.xla_form(interpret, manual_axes):
         return xla
     group = inner // groups
     wide = group > _MOST_COLS
@@ -395,11 +395,7 @@ def _gate_bwd_kernel(y_ref, z_ref, do_ref, scale_ref, dy_ref, dz_ref,
 # ------------------------------------------------------------- the drivers
 
 
-def _params(interpret):
-    if interpret:
-        return {}
-    return {"compiler_params": pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "arbitrary"))}
+_SEMANTICS = ("parallel", "parallel", "arbitrary")
 
 
 def _conv_specs(plan: PassPlan, K, first, nt, backward=False):
@@ -440,8 +436,9 @@ def _conv_fwd(packed, w, b, *, first, plan: PassPlan, interpret):
         grid=(bsz, C // plan.conv_cols, nt),
         in_specs=[halo, x_spec, taps, bias],
         out_specs=own,
-        out_shape=_struct((bsz, T, C), packed.dtype, packed),
-        interpret=interpret, name="ssm_conv_fwd", **_params(interpret),
+        out_shape=_pallas.struct((bsz, T, C), packed.dtype, packed),
+        interpret=interpret, name="ssm_conv_fwd",
+        **_pallas.compiler_params(interpret, _SEMANTICS),
     )(packed, packed, w.astype(_F32), b.astype(_F32).reshape(1, C))
 
 
@@ -461,10 +458,11 @@ def _conv_bwd(packed, w, b, dy, *, first, plan: PassPlan, interpret):
         grid=(bsz, C // cols, nt),
         in_specs=[halo, x_spec, own, taps, bias],
         out_specs=[own, sums],
-        out_shape=[_struct((bsz, T, C), packed.dtype, packed, dy),
-                   _struct((bsz, K + 1, 8, C), _F32, packed, dy)],
+        out_shape=[_pallas.struct((bsz, T, C), packed.dtype, packed, dy),
+                   _pallas.struct((bsz, K + 1, 8, C), _F32, packed, dy)],
         scratch_shapes=[pltpu.VMEM((_TAIL, cols), _F32)],
-        interpret=interpret, name="ssm_conv_bwd", **_params(interpret),
+        interpret=interpret, name="ssm_conv_bwd",
+        **_pallas.compiler_params(interpret, _SEMANTICS),
     )(packed, packed, dy, w.astype(_F32), b.astype(_F32).reshape(1, C))
     dwb = dwb.sum((0, 2))
     return dx, dwb[:K].astype(w.dtype), dwb[K].astype(b.dtype)
@@ -500,8 +498,9 @@ def _gate_fwd(y, packed, scale, *, groups, eps, plan: PassPlan, interpret):
         grid=(bsz, inner // plan.gate_cols, -(-T // _gate_rows(plan))),
         in_specs=[own, own, per_channel],
         out_specs=own,
-        out_shape=_struct((bsz, T, inner), y.dtype, y, packed),
-        interpret=interpret, name="ssm_gate_fwd", **_params(interpret),
+        out_shape=_pallas.struct((bsz, T, inner), y.dtype, y, packed),
+        interpret=interpret, name="ssm_gate_fwd",
+        **_pallas.compiler_params(interpret, _SEMANTICS),
     )(y, packed, scale.astype(_F32).reshape(1, inner))
 
 
@@ -521,10 +520,11 @@ def _gate_bwd(y, packed, scale, do, *, groups, eps, plan: PassPlan,
         grid=(bsz, inner // plan.gate_cols, -(-T // _gate_rows(plan))),
         in_specs=[own, own, own, per_channel],
         out_specs=[own, own, sums],
-        out_shape=[_struct((bsz, T, inner), y.dtype, *like),
-                   _struct((bsz, T, inner), packed.dtype, *like),
-                   _struct((bsz, 8, inner), _F32, *like)],
-        interpret=interpret, name="ssm_gate_bwd", **_params(interpret),
+        out_shape=[_pallas.struct((bsz, T, inner), y.dtype, *like),
+                   _pallas.struct((bsz, T, inner), packed.dtype, *like),
+                   _pallas.struct((bsz, 8, inner), _F32, *like)],
+        interpret=interpret, name="ssm_gate_bwd",
+        **_pallas.compiler_params(interpret, _SEMANTICS),
     )(y, packed, do, scale.astype(_F32).reshape(1, inner))
     return dy, dz, dscale.sum((0, 1)).astype(scale.dtype)
 

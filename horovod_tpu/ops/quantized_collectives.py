@@ -44,7 +44,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from horovod_tpu.ops.flash_attention import _struct
+from horovod_tpu.ops import _pallas
 from horovod_tpu.parallel.hierarchical import all_gather_invariant
 
 # Block geometry — MUST match cpp/htpu/quantize.h (kInt8BlockElems,
@@ -162,7 +162,8 @@ def _use_pallas(*operands) -> bool:
     are bit-identical (tests/test_quantized_collectives.py)."""
     if os.environ.get(_ENV_PALLAS, "1") == "0":
         return False
-    return not (_interpret() and any(jax.typeof(o).vma for o in operands))
+    return not _pallas.xla_form(
+        _interpret(), any(jax.typeof(o).vma for o in operands))
 
 
 def _block_scale(absmax):
@@ -204,8 +205,8 @@ def _pallas_quantize(grid):
         in_specs=[pl.BlockSpec((_ROWS, BLOCK_ELEMS), lambda i: (i, 0))],
         out_specs=(pl.BlockSpec((_ROWS, BLOCK_ELEMS), lambda i: (i, 0)),
                    pl.BlockSpec((_ROWS, 1), lambda i: (i, 0))),
-        out_shape=(_struct((blocks, BLOCK_ELEMS), jnp.int8, grid),
-                   _struct((blocks, 1), jnp.float32, grid)),
+        out_shape=(_pallas.struct((blocks, BLOCK_ELEMS), jnp.int8, grid),
+                   _pallas.struct((blocks, 1), jnp.float32, grid)),
         interpret=_interpret(),
     )(grid)
 
@@ -219,7 +220,8 @@ def _pallas_dequantize(q, scales):
         in_specs=[pl.BlockSpec((_ROWS, BLOCK_ELEMS), lambda i: (i, 0)),
                   pl.BlockSpec((_ROWS, 1), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((_ROWS, BLOCK_ELEMS), lambda i: (i, 0)),
-        out_shape=_struct((blocks, BLOCK_ELEMS), jnp.float32, q, scales),
+        out_shape=_pallas.struct((blocks, BLOCK_ELEMS), jnp.float32, q,
+                                 scales),
         interpret=_interpret(),
     )(q, scales)
 

@@ -56,8 +56,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from horovod_tpu.ops.flash_attention import (
-    _struct, _vmem_headroom_ok, _vmem_limit)
+from horovod_tpu.ops import _pallas
 
 # Tiles of the two kernels (queries x keys).  The KL kernel holds a tile's
 # query rows of every attention head, the gradient of every indexer head
@@ -142,11 +141,11 @@ def index_scores(qi, ki, w, *, row0: int = 0, rows: Optional[int] = None,
                 pl.BlockSpec((1, bq, HI), lambda b, i, j: (b, i + r0, 0)),
             ],
             out_specs=pl.BlockSpec((1, bq, bk), lambda b, i, j: (b, i, j)),
-            out_shape=_struct((B, rows, width), jnp.float32, qi, ki, w),
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "parallel", "parallel")),
+            out_shape=_pallas.struct((B, rows, width), jnp.float32, qi, ki, w),
             interpret=interpret,
             name="index_scores",
+            **_pallas.compiler_params(
+                interpret, ("parallel", "parallel", "parallel")),
         )(qi, ki, w)
 
 
@@ -401,7 +400,7 @@ def _kl_pass(qi, ki, w, q, k, lse, select, lse_i, *, scale, interpret):
     Hkv = k.shape[2]
     HI, DI = qi.shape[2], qi.shape[3]
     bq, bk, vmem_mb = _kl_plan(T, H, Hkv, D, HI, DI, qi.dtype.itemsize,
-                               _vmem_headroom_ok())
+                               _pallas.vmem_headroom_ok())
     nq = T // bq
     qi_t = qi.transpose(0, 2, 1, 3)                          # (B, HI, T, DI)
     kl, dqi, dw, dki = pl.pallas_call(
@@ -427,21 +426,20 @@ def _kl_pass(qi, ki, w, q, k, lse, select, lse_i, *, scale, interpret):
             pl.BlockSpec((1, 1, bk, DI), lambda b, i, j: (b, i, j, 0)),
         ],
         out_shape=[
-            _struct((B, T, 1), jnp.float32, q, qi),
-            _struct((B, HI, T, DI), jnp.float32, q, qi),
-            _struct((B, HI, T, 1), jnp.float32, q, qi),
-            _struct((B, nq, T, DI), jnp.float32, q, qi),
+            _pallas.struct((B, T, 1), jnp.float32, q, qi),
+            _pallas.struct((B, HI, T, DI), jnp.float32, q, qi),
+            _pallas.struct((B, HI, T, 1), jnp.float32, q, qi),
+            _pallas.struct((B, nq, T, DI), jnp.float32, q, qi),
         ],
         scratch_shapes=[
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((HI, bq, DI), jnp.float32),
             pltpu.VMEM((HI, bq, bk), jnp.dtype(f"int{8 * qi.dtype.itemsize}")),
             pltpu.VMEM((HI, bq, DI), qi.dtype)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-            **_vmem_limit(vmem_mb)),
         interpret=interpret,
         name="index_kl",
+        **_pallas.compiler_params(
+            interpret, ("parallel", "parallel", "arbitrary"), vmem_mb),
     )(q.reshape(B, T, H * D), k.reshape(B, T, Hkv * D),
       lse.transpose(0, 2, 1), select, qi_t, ki, w.astype(jnp.float32),
       lse_i[..., None])

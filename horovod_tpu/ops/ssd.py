@@ -76,8 +76,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from horovod_tpu.ops.flash_attention import (
-    _struct, _vmem_headroom_ok, _vmem_limit)
+from horovod_tpu.ops import _pallas
 
 _F32 = jnp.float32
 
@@ -490,14 +489,7 @@ def _specs(d: _Dims, nc, backward=False):
 
 
 _SMEM = functools.partial(pl.BlockSpec, memory_space=pltpu.SMEM)
-
-
-def _params(plan, interpret):
-    if interpret:
-        return {}
-    return {"compiler_params": pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "arbitrary"),
-        **_vmem_limit(plan.vmem_mb))}
+_SEMANTICS = ("parallel", "parallel", "arbitrary")
 
 
 def _time_minor(dt, d: _Dims):
@@ -530,9 +522,10 @@ def _fused_fwd(xbc, dt, A, D, *, d: _Dims, plan, interpret):
         in_specs=[cols(d.RP, x0), cols(d.N, b0, True), cols(d.N, c0, True),
                   rows, _SMEM(), _SMEM()],
         out_specs=cols(d.RP, 0),
-        out_shape=_struct((b, T, d.H * d.P), xbc.dtype, xbc, dt),
+        out_shape=_pallas.struct((b, T, d.H * d.P), xbc.dtype, xbc, dt),
         scratch_shapes=[pltpu.VMEM((d.N, d.RP), _F32)],
-        interpret=interpret, name="ssd_fwd", **_params(plan, interpret),
+        interpret=interpret, name="ssd_fwd",
+        **_pallas.compiler_params(interpret, _SEMANTICS, plan.vmem_mb),
     )(xbc, xbc, xbc, _time_minor(dt, d), A.astype(_F32), D.astype(_F32))
 
 
@@ -554,9 +547,10 @@ def _fused_bwd(xbc, dt, A, D, dy, *, d: _Dims, plan, interpret):
         grid=(b, d.V, nc),
         in_specs=[cols(d.RP, x0), cols(d.N, b0, True), rows, _SMEM()],
         out_specs=entering,
-        out_shape=_struct((b, d.V, nc, d.N, d.RP), _F32, xbc, dt),
+        out_shape=_pallas.struct((b, d.V, nc, d.N, d.RP), _F32, xbc, dt),
         scratch_shapes=[pltpu.VMEM((d.N, d.RP), _F32)],
-        interpret=interpret, name="ssd_states", **_params(plan, interpret),
+        interpret=interpret, name="ssd_states",
+        **_pallas.compiler_params(interpret, _SEMANTICS, plan.vmem_mb),
     )(xbc, xbc, dt_rows, A32)
 
     cols, rows, entering = _specs(d, nc, backward=True)
@@ -575,14 +569,15 @@ def _fused_bwd(xbc, dt, A, D, dy, *, d: _Dims, plan, interpret):
                   rows, cols(d.RP, 0), entering, _SMEM(), _SMEM()],
         out_specs=[cols(d.RP, 0), cols(d.N, 0), cols(d.N, 0), rows,
                    per_group, per_channel],
-        out_shape=[_struct((b, T, d.H * d.P), xbc.dtype, *like),
-                   _struct((b, T, d.V * d.N), part, *like),
-                   _struct((b, T, d.V * d.N), part, *like),
-                   _struct((b * d.V, d.R, T), _F32, *like),
-                   _struct((b * d.V, d.R, d.Q), _F32, *like),
-                   _struct((b * d.V, d.Q, d.RP), _F32, *like)],
+        out_shape=[_pallas.struct((b, T, d.H * d.P), xbc.dtype, *like),
+                   _pallas.struct((b, T, d.V * d.N), part, *like),
+                   _pallas.struct((b, T, d.V * d.N), part, *like),
+                   _pallas.struct((b * d.V, d.R, T), _F32, *like),
+                   _pallas.struct((b * d.V, d.R, d.Q), _F32, *like),
+                   _pallas.struct((b * d.V, d.Q, d.RP), _F32, *like)],
         scratch_shapes=[pltpu.VMEM((d.N, d.RP), _F32)],
-        interpret=interpret, name="ssd_bwd", **_params(plan, interpret),
+        interpret=interpret, name="ssd_bwd",
+        **_pallas.compiler_params(interpret, _SEMANTICS, plan.vmem_mb),
     )(xbc, xbc, xbc, dt_rows, dy, states, A32, D32)
     if d.tiles > 1:
         dB, dC = (a.reshape(b, T, d.G, d.tiles, d.N).sum(3).reshape(
@@ -641,8 +636,8 @@ def _plan(*, T, H, P, G, N, chunk, itemsize, interpret, manual_axes,
     state a multiple of 128 wide, a group's channels ``P H / G`` in whole
     tiles of 128 lanes, ``H P`` a multiple of ``N`` (B and C are addressed
     in blocks of N columns behind x).  Interpreted Pallas under
-    ``shard_map``'s manual axes cannot run in jax 0.9.0 (as in
-    ``flash_attention._plan``).  ``vmem_headroom``: whether the device
+    ``shard_map``'s manual axes takes the XLA form
+    (:func:`_pallas.xla_form`).  ``vmem_headroom``: whether the device
     backs a scoped budget above Mosaic's default, asked only where the
     backward kernel's blocks need it.
 
@@ -658,7 +653,7 @@ def _plan(*, T, H, P, G, N, chunk, itemsize, interpret, manual_axes,
     tiles = (chunk % 128 == 0 and N % 128 == 0 and d.RP % 128 == 0
              and (128 % P == 0 or P % 128 == 0) and d.R % d.hp == 0
              and (H * P) % N == 0)
-    if not tiles or (interpret and manual_axes):
+    if not tiles or _pallas.xla_form(interpret, manual_axes):
         return xla
     Q = chunk
 
@@ -697,7 +692,7 @@ def scan_plan(x_like, dt_like, *, heads, head_dim, groups, state, chunk,
     return _plan(T=x_like.shape[1], H=heads, P=head_dim, G=groups, N=state,
                  chunk=chunk, itemsize=x_like.dtype.itemsize,
                  interpret=interpret, manual_axes=bool(vma),
-                 vmem_headroom=_vmem_headroom_ok())
+                 vmem_headroom=_pallas.vmem_headroom_ok())
 
 
 def _padded(arrays, T, chunk):

@@ -136,97 +136,17 @@ class TestPinCpuHalf:
         assert "mask" not in pinned
 
 
-class TestBenchSummary:
-    """write_bench_summary: the consolidated BENCH_rNN.json artifact."""
-
-    REPORT = {
-        "step_time_ms": 123.4,
-        "mfu": 0.33,
-        "transformer_lm": {
-            "step_time_ms": 516.9, "mfu": 0.74,
-            "injit_wire_ab": {
-                "fp32": {"step_time_ms": 50.0},
-                "auto": {"step_time_ms": 49.0,
-                         "buckets_by_wire": {"bf16": 3, "fp32": 1}},
-                "auto_vs_best_static": 1.02,
-            },
-        },
-        "scaling_virtual_8dev": {"scaling_efficiency": 0.12},
-        "ctrl_sweep": {
-            "legs": {"128p": {"flat_tick_us": 900.0,
-                              "hier_tick_us": 300.0,
-                              "hier_tick_speedup": 3.0}},
-            "hier_tick_speedup_128p": 3.0,
-        },
-        "scaling_tcp_2proc": {
-            "scaling_efficiency": 0.33,
-            "comm_fraction": 0.35,
-            "wire_compression": {"fp32": {"step_time_ms": 42.0},
-                                 "auto": {"step_time_ms": 41.0,
-                                          "vs_best_static": 1.01}},
-            "overlap_ab": {"off": {}, "on": {}},
-            "xport_sweep": {"shm_vs_uds_speedup_256k_plus": 1.4,
-                            "crc_overhead_256k_plus": {"max": 0.03}},
-            "observe_ab": {"off": {"step_time_ms": 40.0},
-                           "on": {"step_time_ms": 40.4},
-                           "overhead_fraction": 0.01},
-        },
-    }
-
-    # The r08 artifact schema: trend lines parse these exact keys, so a
-    # rename or drop is an interface break, not a refactor.
-    R08_KEYS = {
-        "resnet_step_time_ms", "resnet_mfu",
-        "transformer_step_time_ms", "transformer_mfu",
-        "virtual_scaling_efficiency", "tcp_scaling_efficiency",
-        "tcp_step_time_ms", "tcp_comm_fraction", "overlap_ab",
-        "shm_vs_uds_speedup_256k_plus", "crc_overhead_256k_plus",
-        "observe_ab", "precision_auto_tcp_vs_best_static",
-        "precision_auto_injit_vs_best_static", "precision_auto_injit",
-        "hier_tick_speedup_128p",
-    }
-
-    def test_headlines_extracted(self, tmp_path, bench_mod):
-        import json
-        path = str(tmp_path / "BENCH_r08.json")
-        assert bench_mod.write_bench_summary(self.REPORT, path) == path
-        s = json.loads(open(path).read())
-        assert s["resnet_step_time_ms"] == 123.4
-        assert s["transformer_mfu"] == 0.74
-        assert s["tcp_scaling_efficiency"] == 0.33
-        assert s["tcp_step_time_ms"] == 42.0
-        assert s["crc_overhead_256k_plus"] == 0.03
-        assert s["observe_ab"]["overhead_fraction"] == 0.01
-        assert s["precision_auto_tcp_vs_best_static"] == 1.01
-        assert s["precision_auto_injit_vs_best_static"] == 1.02
-        assert s["precision_auto_injit"]["buckets_by_wire"] == {
-            "bf16": 3, "fp32": 1}
-        assert s["hier_tick_speedup_128p"] == 3.0
-
-    def test_r08_schema_pinned(self, tmp_path, bench_mod):
-        import json
-        path = str(tmp_path / "BENCH_r08.json")
-        bench_mod.write_bench_summary(self.REPORT, path)
-        assert set(json.loads(open(path).read())) == self.R08_KEYS
-
-    def test_default_artifact_name_is_r08(self, bench_mod, monkeypatch,
-                                          tmp_path):
-        monkeypatch.delenv("BENCH_SUMMARY_FILE", raising=False)
-        monkeypatch.chdir(tmp_path)
-        assert bench_mod.write_bench_summary({}) == "BENCH_r08.json"
-        assert (tmp_path / "BENCH_r08.json").exists()
-
-    def test_missing_legs_become_none_not_errors(self, tmp_path, bench_mod):
-        import json
-        path = str(tmp_path / "s.json")
-        assert bench_mod.write_bench_summary({}, path) == path
-        s = json.loads(open(path).read())
-        assert s["observe_ab"] is None and s["resnet_mfu"] is None
-
-    def test_empty_path_skips(self, bench_mod, monkeypatch):
-        monkeypatch.setenv("BENCH_SUMMARY_FILE", "")
-        assert bench_mod.write_bench_summary({}) is None
-
-    def test_unwritable_path_returns_none(self, bench_mod, tmp_path):
-        assert bench_mod.write_bench_summary(
-            {}, str(tmp_path / "no" / "dir" / "s.json")) is None
+def test_the_device_legs_and_their_knobs_are_gone(bench_mod):
+    """``bench.py`` is the host planes' drills: how fast the device path
+    trains is ``benchmark/run.py``'s to say, so no knob of the legs that
+    said it before the chip, and no virtual-device mode, is left."""
+    import re
+    source = open(bench_mod.__file__).read()
+    assert not re.findall(
+        r"BENCH_TLM_\w*|BENCH_SCALE_\w*|BENCH_SUMMARY_FILE", source)
+    flags = {flag for action in bench_mod._parser()._actions
+             for flag in action.option_strings}
+    assert "--n-virtual" not in flags and "--no-transformer" not in flags
+    assert {"--tcp-worker", "--recovery-worker", "--policy-worker",
+            "--publish-worker", "--ctrl-worker", "--solo-worker",
+            "--xport-worker"} <= flags

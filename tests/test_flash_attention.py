@@ -752,9 +752,9 @@ class TestVmemGates:
             return self._kind
 
     def _probe(self, monkeypatch, dev):
-        from horovod_tpu.ops import flash_attention as fa
-        monkeypatch.setattr(fa.jax, "local_devices", lambda: [dev])
-        return fa._vmem_headroom_ok()
+        from horovod_tpu.ops import _pallas
+        monkeypatch.setattr(_pallas.jax, "local_devices", lambda: [dev])
+        return _pallas.vmem_headroom_ok()
 
     def test_headroom_fails_closed_on_unreadable_tpu_kind(self,
                                                           monkeypatch):

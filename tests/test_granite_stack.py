@@ -18,14 +18,14 @@ from jax.sharding import Mesh
 
 from benchmark.families import granite_hybrid_lm as family
 from horovod_tpu.jax.spmd import make_train_step
+from horovod_tpu.layer_notes import noting_layers
 from horovod_tpu.metrics import registry
 from horovod_tpu.models import GraniteHybridLM, TransformerLM
 from horovod_tpu.models.ssm import Mamba2Mixer
 from horovod_tpu.models.transformer import (
     GroupedQueryAttention, PatternLayer, SwiGLU)
-from horovod_tpu.ops import ssd
+from horovod_tpu.ops import _pallas, ssd
 from horovod_tpu.parallel.mesh import RANKS_AXIS
-from horovod_tpu.parallel.moe import noting_expert_layers
 
 F32 = jnp.float32
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -233,9 +233,11 @@ def compared():
     """Loss and every gradient leaf of the program in float32, of the
     program in bfloat16 and of the reference, on one seeded batch; the
     kernels interpreted, the scan's group split as on a device without
-    VMEM head-room."""
-    original = ssd._vmem_headroom_ok
-    ssd._vmem_headroom_ok = lambda: False
+    VMEM head-room.  The probe is every family's, so the attention
+    layer's plan sees no head-room either (the tiny preset's flash forms
+    ask for none)."""
+    original = _pallas.vmem_headroom_ok
+    _pallas.vmem_headroom_ok = lambda: False
     jax.clear_caches()
     try:
         cfg = family_cfg()
@@ -245,7 +247,7 @@ def compared():
         noted = {}
         out = {"cfg": cfg, "params": params, "tokens": tokens}
         for name, fn in (
-                ("float32", noting_expert_layers(family.loss_fn(cfg), noted)),
+                ("float32", noting_layers(family.loss_fn(cfg), noted)),
                 ("bfloat16", family.loss_fn(family_cfg("bfloat16"))),
                 ("reference", lambda p, a, t: (
                     family.reference_loss(cfg)(p, a, t), a))):
@@ -255,7 +257,7 @@ def compared():
         out["noted"] = noted
         return out
     finally:
-        ssd._vmem_headroom_ok = original
+        _pallas.vmem_headroom_ok = original
         jax.clear_caches()
 
 
@@ -378,7 +380,7 @@ def test_at_the_cell_s_shape_every_mixer_takes_the_kernels(monkeypatch):
                                  jax.random.PRNGKey(0))
     tokens = jax.ShapeDtypeStruct((1, cfg["sequence_length"] + 1), jnp.int32)
     noted = {}
-    jax.eval_shape(noting_expert_layers(family.loss_fn(cfg), noted),
+    jax.eval_shape(noting_layers(family.loss_fn(cfg), noted),
                    params, aux, tokens)
     totals = {}
     for counters in noted.values():

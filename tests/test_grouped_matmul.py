@@ -19,11 +19,12 @@ from jax import lax
 from jax.sharding import Mesh
 
 from horovod_tpu.jax.spmd import make_train_step
+from horovod_tpu.layer_notes import noting_layers
 from horovod_tpu.metrics import registry
 from horovod_tpu.ops import grouped_matmul as gm
 from horovod_tpu.ops.grouped_matmul import (
     GroupedPlan, grouped_matmul, grouped_plan)
-from horovod_tpu.parallel.moe import DroplessMoE, noting_expert_layers
+from horovod_tpu.parallel.moe import DroplessMoE
 
 from test_gated_delta import _equations
 
@@ -321,7 +322,7 @@ class FourHeldLayers(nn.Module):
 
 def fused_matmuls(module, x):
     noted = {}
-    jax.eval_shape(noting_expert_layers(
+    jax.eval_shape(noting_layers(
         lambda x: module.init(jax.random.PRNGKey(0), x), noted), x)
     return sum(c["moe.fused_matmuls"] for c in noted.values())
 

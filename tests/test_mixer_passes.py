@@ -17,13 +17,13 @@ import pytest
 from jax.sharding import Mesh
 
 from horovod_tpu.jax.spmd import make_train_step
+from horovod_tpu.layer_notes import noting_layers
 from horovod_tpu.metrics import registry
 from horovod_tpu.models import NemotronHLM
 from horovod_tpu.models.ssm import Mamba2Mixer, causal_conv, gated_group_norm
 from horovod_tpu.ops import mixer_passes
 from horovod_tpu.ops.mixer_passes import (
     PassPlan, conv_silu, gated_norm, passes_plan)
-from horovod_tpu.parallel.moe import noting_expert_layers
 
 from test_gated_delta import _equations
 
@@ -416,14 +416,14 @@ def test_mixer_with_the_kernels_equals_the_mixer_without():
             {"params": p}, u) ** 2).sum(), argnums=(0, 1))(params, u)
 
     noted = {}
-    got, got_grads = noting_expert_layers(value_grads, noted)()
+    got, got_grads = noting_layers(value_grads, noted)()
     assert [n["ssm.fused_passes"] for n in noted.values()] == [2]
     import horovod_tpu.models.ssm as ssm_module
     refuse = lambda *a, **k: PassPlan(*XLA)                    # noqa: E731
     original, ssm_module.passes_plan = ssm_module.passes_plan, refuse
     try:
         noted = {}
-        want, want_grads = noting_expert_layers(value_grads, noted)()
+        want, want_grads = noting_layers(value_grads, noted)()
     finally:
         ssm_module.passes_plan = original
     assert [n["ssm.fused_passes"] for n in noted.values()] == [0]
@@ -444,7 +444,7 @@ def test_tiny_stacks_count_no_fused_pass(hvd):
     cfg = family_cfg("bfloat16")
     params, aux, tokens = model_inputs(cfg)
     noted = {}
-    jax.eval_shape(noting_expert_layers(nemotron_h_lm.loss_fn(cfg), noted),
+    jax.eval_shape(noting_layers(nemotron_h_lm.loss_fn(cfg), noted),
                    params, aux, tokens)
     passes = [n["ssm.fused_passes"] for n in noted.values()
               if "ssm.fused_passes" in n]

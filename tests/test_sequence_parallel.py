@@ -179,8 +179,8 @@ class TestZigzagRingAttention:
         # Report-only (advisor r2): wall-clock ratios on a shared CI host
         # flake under concurrent load no matter how loose the bound — the
         # correctness of both layouts is asserted by the parity tests
-        # above; the ratio is printed for humans and benchmarked for real
-        # on hardware in docs/benchmarks.md.
+        # above; the ratio is printed for humans, and no cell times the
+        # two layouts on hardware yet (ROADMAP.md R3).
 
 
 class TestUlysses:

@@ -13,7 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from horovod_tpu.ops import ssd
+from horovod_tpu.ops import _pallas, ssd
 from horovod_tpu.ops.ssd import ssd_recurrence, ssd_scan, ssd_scan_packed
 
 
@@ -38,7 +38,7 @@ def inputs(b, T, H, P, G, N, dtype, seed=0):
 def default_budget_only(monkeypatch):
     """The device backs no scoped VMEM above Mosaic's default, so that a
     group of a CPU test's size is already wider than a block."""
-    monkeypatch.setattr(ssd, "_vmem_headroom_ok", lambda: False)
+    monkeypatch.setattr(_pallas, "vmem_headroom_ok", lambda: False)
     # The drivers are jitted on their static arguments alone.
     jax.clear_caches()
     yield

@@ -939,9 +939,12 @@ def test_selected_attention_fwd_bwd_at_keye_widths(v5e, monkeypatch,
     float32 array of all heads is ever made."""
     import re
 
-    from horovod_tpu.ops import flash_attention as fa, sparse_select
+    from horovod_tpu.ops import (
+        _pallas, flash_attention as fa, sparse_select)
 
-    monkeypatch.setattr(fa, "_vmem_headroom_ok", lambda: headroom)
+    # Every family's probe: the KL pass is lowered with head-room only
+    # (the fused case), where it saw the CPU's "True" before as well.
+    monkeypatch.setattr(_pallas, "vmem_headroom_ok", lambda: headroom)
     jax.clear_caches()       # the drivers' traces do not key on the device
     one = SingleDeviceSharding(v5e[0])
     B, H, Hkv, D, HI, DI, topk = 1, 32, 4, 128, 16, 64, 2048
@@ -1039,9 +1042,9 @@ def test_the_fused_selected_backward_compiles_at_every_group_size(
     and T 16,384 with 1 to 16 query heads a KV head, each at the Q block
     the plan gives it — and the backward under 56 MB, the most the compiler
     counts at any of them, so that the plan's 64 leaves 8 over."""
-    from horovod_tpu.ops import flash_attention as fa
+    from horovod_tpu.ops import _pallas, flash_attention as fa
 
-    monkeypatch.setattr(fa, "_vmem_headroom_ok", lambda: True)
+    monkeypatch.setattr(_pallas, "vmem_headroom_ok", lambda: True)
     assert fa._SELECT_FUSED_VMEM_MB >= FUSED_BWD_COUNTED_MB + 8
     monkeypatch.setattr(fa, "_SELECT_FUSED_VMEM_MB", FUSED_BWD_COUNTED_MB)
     jax.clear_caches()
@@ -1089,7 +1092,7 @@ def test_the_kl_pass_compiles_at_every_tiling(v5e, monkeypatch, tiling,
     the first of them being the plan's choice without head-room.  One
     algorithm throughout: ``H + 3 H_I`` products a tile
     (``tests/test_sparse_attention.py``)."""
-    from horovod_tpu.ops import sparse_select as ss
+    from horovod_tpu.ops import _pallas, sparse_select as ss
 
     assert tuple(KL_COUNTED_MB) == ss._KL_TILINGS
     B, T, H, Hkv, D, HI, DI = 1, 16_384, 32, 4, 128, 16, 64
@@ -1100,7 +1103,7 @@ def test_the_kl_pass_compiles_at_every_tiling(v5e, monkeypatch, tiling,
     limit = KL_COUNTED_MB[tiling] if headroom else 0
     assert headroom or ss._kl_vmem_bytes(*tiling, *shape[1:]) <= (
         ss._MOSAIC_DEFAULT_VMEM_MB * 2 ** 20)
-    monkeypatch.setattr(ss, "_vmem_headroom_ok", lambda: headroom)
+    monkeypatch.setattr(_pallas, "vmem_headroom_ok", lambda: headroom)
     monkeypatch.setattr(ss, "_KL_TILINGS", (tiling,))
     monkeypatch.setattr(ss, "_KL_VMEM_MB", limit)
     one = SingleDeviceSharding(v5e[0])
