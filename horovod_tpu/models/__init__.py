@@ -6,6 +6,7 @@ from horovod_tpu.models.resnet import (                   # noqa: F401
     ResNet, ResNet50, ResNet101, ResNet152,
 )
 from horovod_tpu.models.transformer import (               # noqa: F401
-    BlockStack, GraniteHybridLM, GroupedQueryAttention, KeyeLM, NemotronHLM,
-    OLMoELM,
-    OlmoHybridLM, SwiGLU, TransformerLM, apply_rotary, index_losses)
+    BlockStack, CompressedConvAttention, GraniteHybridLM,
+    GroupedQueryAttention, KeyeLM, NemotronHLM, OLMoELM, OlmoHybridLM,
+    ResidualMerge, SwiGLU, TransformerLM, Zaya1LM, apply_rotary,
+    index_losses)

@@ -62,7 +62,13 @@ go ``embedding_multiplier`` (on the embedded tokens),
 ``tie_head`` (no ``head`` parameter: the logits are the hidden states
 times the embedding table transposed, :meth:`TransformerLM.head_kernel`,
 and the table's gradient is the gather's plus the head's).
-:func:`GraniteHybridLM` is the Granite 4.0-H setting.
+:func:`GraniteHybridLM` is the Granite 4.0-H setting.  ``Z`` is TWO
+pre-norm sub-layers joined to the residual by a learned scale and bias on
+each side (:class:`ResidualMerge`): compressed convolutional attention
+(:class:`CompressedConvAttention`, attention inside a latent whose q and k
+pass through two causal convolutions) and then a ``DroplessMoE`` whose
+router is a small network with a state that the stack hands from one ``Z``
+layer to the next.  :func:`Zaya1LM` is the ZAYA1 setting.
 """
 
 from __future__ import annotations
@@ -95,19 +101,29 @@ def _norm(kind: str, eps: float, dtype, name: str):
     raise ValueError(f"unknown norm: {kind!r}")
 
 
-def apply_rotary(x, pos, theta: float = 10000.0):
+def apply_rotary(x, pos, theta: float = 10000.0,
+                 width: Optional[int] = None):
     """Rotary position embedding, rotate-half form, on ``x`` (B, T, H, D)
-    at positions ``pos`` (T,): the pair ``(x[i], x[i + D/2])`` is turned by
-    ``pos · theta^(-2i/D)``.  Angles and the rotation in float32."""
-    half = x.shape[-1] // 2
+    at positions ``pos`` (T,).  ``width`` (even, default ``D``) is the
+    rotated width ``R``: the pair ``(x[i], x[i + R/2])``, ``i < R/2``, is
+    turned by ``pos · theta^(-2i/R)`` and the channels ``R .. D - 1`` pass
+    unchanged (a partial rotary factor of ``R / D``).  Angles and the
+    rotation in float32."""
+    D = x.shape[-1]
+    width = D if width is None else width
+    if width % 2 or not 0 < width <= D:
+        raise ValueError(f"rotated width {width} of a head of {D}")
+    half = width // 2
     freq = 1.0 / theta ** (jnp.arange(half, dtype=jnp.float32) / half)
-    angle = pos.astype(jnp.float32)[:, None] * freq[None]       # (T, D/2)
+    angle = pos.astype(jnp.float32)[:, None] * freq[None]       # (T, R/2)
     cos = jnp.cos(angle)[None, :, None, :]
     sin = jnp.sin(angle)[None, :, None, :]
     x1 = x[..., :half].astype(jnp.float32)
-    x2 = x[..., half:].astype(jnp.float32)
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
-                           axis=-1).astype(x.dtype)
+    x2 = x[..., half:width].astype(jnp.float32)
+    turned = [x1 * cos - x2 * sin, x2 * cos + x1 * sin]
+    if width < D:
+        turned.append(x[..., width:].astype(jnp.float32))
+    return jnp.concatenate(turned, axis=-1).astype(x.dtype)
 
 
 class _QKVKernel(nn.Module):
@@ -325,6 +341,158 @@ class GroupedQueryAttention(nn.Module):
         return out
 
 
+class CompressedConvAttention(nn.Module):
+    """Compressed convolutional attention (CCA; Zyphra, arXiv:2510.04476,
+    as ZAYA1 runs it): causal attention of ``num_heads`` query heads over
+    ``kv_heads`` key-value heads of ``head_dim`` INSIDE a latent narrower
+    than the model — nothing is projected up again before the heads; the
+    output projection ``proj`` takes the heads' ``num_heads · head_dim``
+    channels back to the model's width.  On the layer's normed input ``u``
+    (B, T, C), with ``g = num_heads / kv_heads``:
+
+    * ``cca/project``: ``q~ = u W_q`` (H heads), ``k~ = u W_k`` (G heads),
+      ``u W_v1``, ``u W_v2`` (``G · D / 2`` channels each), no bias.
+    * ``cca/qk_mean``: ``m_q[h] = (q~[h] + k~[h // g]) / 2`` and ``m_k[j]``
+      the mean of its group's ``m_q``, from q~ and k~ BEFORE the
+      convolutions.
+    * ``cca/conv``: over the ``(H + G) · D`` channels ``z = [q~ | k~]``
+      with ``taps[0] - 1 + taps[1] - 1`` zero rows in front (causal), a
+      depth-wise convolution of ``taps[0]`` taps with bias (``conv0``) and
+      then, nothing between, a convolution of ``taps[1]`` taps that mixes
+      the ``D`` channels of each head with a ``(D, D)`` matrix a tap and
+      head, with bias (``conv1``); both valid, so ``T`` rows come out.
+      ``q' = z_q + m_q``, ``k' = z_k + m_k``.
+    * ``cca/shift``: the values ``[u_t W_v1 | u_{t-1} W_v2]`` (``u_{-1} =
+      0``) as ``G`` heads of ``D``: the second half of the value channels
+      reads the previous token.
+    * ``cca/norm_rope``: ``q" = √D q' / ‖q'‖₂`` and ``k" = τ_j √D k' /
+      ‖k'‖₂`` a head (``temp`` ``τ`` (G,), learned, init 1), then rotary
+      positions (``rope_theta``, rotate-half) on the first
+      ``rotary_fraction · D`` channels of each head.
+    * softmax-causal attention at the scale ``D^-1/2``, query head ``h``
+      over KV head ``h // g`` (``attn="flash"``: :func:`flash_attention_auto`
+      reading the grouped keys and values in place; ``"full"``: the dense
+      oracle), then ``proj``.
+
+    Everything around the kernels is ``jax.numpy`` in float32 inside the
+    fusions and ``dtype`` between them.  Sown as the intermediate
+    ``latent``: ``(q", k", v)`` as the kernels read them.
+    ``make_train_step`` counts ``attn.latent_channels`` (q, k and v
+    channels a step) and ``attn.conv_taps`` (channels times taps a step)."""
+    num_heads: int
+    kv_heads: int
+    head_dim: int
+    attn: str = "flash"
+    dtype: Any = jnp.bfloat16
+    taps: Any = (2, 2)
+    rope_theta: float = 10000.0
+    rotary_fraction: float = 0.5
+
+    @nn.compact
+    def __call__(self, u):
+        B, T, C = u.shape
+        H, G, D = self.num_heads, self.kv_heads, self.head_dim
+        t0, t1 = self.taps
+        if H % G or D % 2 or (G * D) % 2 or min(t0, t1) < 1:
+            raise ValueError(f"compressed attention of {H} heads over {G} "
+                             f"of {D} with taps {self.taps}")
+        f32 = jnp.float32
+
+        def dense(features, name):
+            return nn.Dense(features, use_bias=False, dtype=self.dtype,
+                            param_dtype=f32, name=name)
+
+        with jax.named_scope("cca/project"):
+            q0 = dense(H * D, "q")(u).reshape(B, T, H, D)
+            k0 = dense(G * D, "k")(u).reshape(B, T, G, D)
+            v_now = dense(G * D // 2, "v1")(u)
+            v_prev = dense(G * D // 2, "v2")(u)
+
+        with jax.named_scope("cca/qk_mean"):
+            grouped = q0.astype(f32).reshape(B, T, G, H // G, D)
+            m_q = 0.5 * (grouped + k0.astype(f32)[:, :, :, None])
+            m_k = m_q.mean(axis=3)
+            m_q = m_q.reshape(B, T, H, D)
+
+        with jax.named_scope("cca/conv"):
+            w0 = self.param("conv0_kernel", nn.initializers.lecun_normal(
+                in_axis=1, out_axis=0), ((H + G) * D, t0), f32)
+            b0 = self.param("conv0_bias", nn.initializers.zeros,
+                            ((H + G) * D,), f32)
+            w1 = self.param("conv1_kernel", nn.initializers.lecun_normal(
+                in_axis=(1, 2), out_axis=3, batch_axis=(0,)),
+                (H + G, t1, D, D), f32)
+            b1 = self.param("conv1_bias", nn.initializers.zeros,
+                            (H + G, D), f32)
+            z = jnp.concatenate([q0, k0], axis=2).reshape(B, T, (H + G) * D)
+            z = jnp.pad(z, ((0, 0), (t0 + t1 - 2, 0), (0, 0))).astype(f32)
+            rows = T + t1 - 1
+            z1 = b0 + sum(w0[:, i] * z[:, i:i + rows] for i in range(t0))
+            z1 = z1.astype(self.dtype).reshape(B, rows, H + G, D)
+            # The taps of a head side by side: one product of depth t1 · D.
+            taps = jnp.concatenate([z1[:, i:i + T] for i in range(t1)],
+                                   axis=-1)
+            z2 = jnp.einsum(
+                "bthc,hcd->bthd", taps,
+                w1.reshape(H + G, t1 * D, D).astype(self.dtype)).astype(
+                    f32) + b1
+            q1, k1 = z2[:, :, :H] + m_q, z2[:, :, H:] + m_k
+
+        with jax.named_scope("cca/norm_rope"):
+            temp = self.param("temp", nn.initializers.ones, (G,), f32)
+
+            def unit(x):
+                return x * lax.rsqrt(jnp.maximum(
+                    jnp.sum(x * x, axis=-1, keepdims=True), 1e-24))
+
+            width = int(round(self.rotary_fraction * D / 2)) * 2
+            pos = jnp.arange(T)
+            q = apply_rotary(unit(q1) * D ** 0.5, pos, self.rope_theta,
+                             width).astype(self.dtype)
+            k = apply_rotary(unit(k1) * (D ** 0.5 * temp[:, None]), pos,
+                             self.rope_theta, width).astype(self.dtype)
+
+        with jax.named_scope("cca/shift"):
+            v_prev = jnp.pad(v_prev, ((0, 0), (1, 0), (0, 0)))[:, :T]
+            v = jnp.concatenate([v_now, v_prev], axis=-1).reshape(B, T, G, D)
+        self.sow("intermediates", "latent", (q, k, v))
+
+        if self.attn == "flash":
+            out = flash_attention_auto(q, k, v, causal=True)
+        elif self.attn == "full":
+            out = full_attention(q, jnp.repeat(k, H // G, axis=2),
+                                 jnp.repeat(v, H // G, axis=2), causal=True)
+        else:
+            raise ValueError("compressed attention runs attn='flash' or "
+                             f"'full', not {self.attn!r}")
+        note_layer(self.path, {
+            "attn.latent_channels": B * T * (H + 2 * G) * D,
+            "attn.conv_taps": B * T * (H + G) * D * (t0 + t1)})
+        return dense(C, "proj")(out.reshape(B, T, H * D))
+
+
+class ResidualMerge(nn.Module):
+    """``s_x ⊙ (x + b_x) + s_y ⊙ (y + b_y)``: the residual ``x`` and a
+    sub-layer's output ``y`` each under a learned bias and scale a channel
+    (``scale_*`` init 1, ``bias_*`` init 0; float32 arithmetic, the
+    stream's dtype out).  ``residual=False``: ``x`` passes as it is (the
+    first sub-layer of a model, whose residual is the embedding)."""
+    residual: bool = True
+
+    @nn.compact
+    def __call__(self, x, y):
+        def vector(name, init):
+            return self.param(name, init, (x.shape[-1],), jnp.float32)
+
+        kept = x.astype(jnp.float32)
+        if self.residual:
+            kept = vector("scale_x", nn.initializers.ones) * (
+                kept + vector("bias_x", nn.initializers.zeros))
+        return (kept + vector("scale_y", nn.initializers.ones) * (
+            y.astype(jnp.float32) + vector("bias_y", nn.initializers.zeros))
+        ).astype(x.dtype)
+
+
 def index_losses(intermediates):
     """``L_I`` summed over every sparse-attention layer that sowed it into
     ``intermediates`` (what ``model.apply(..., mutable=["intermediates"])``
@@ -360,7 +528,15 @@ class PatternLayer(nn.Module):
     ``mlp`` a :class:`SwiGLU` ``mlp_hidden`` wide.  ``"m"`` (``ssm``) and
     ``"a"`` (``attn``): ``h = x + r f(norm(x))``, then ``h + r
     mlp(mlp_norm(h))`` with ``r`` the ``residual_multiplier``.  ``sub``
-    holds the fields of ``f``."""
+    holds the fields of ``f``.  ``"Z"`` (``attn``, then ``moe``): ``h =
+    merge(x, cca(norm(x)))``, then ``merge(h, moe(moe_norm(h),
+    router_state))`` with ``cca`` a :class:`CompressedConvAttention`,
+    ``moe`` a ``DroplessMoE`` whose router carries a state and ``merge`` a
+    :class:`ResidualMerge` (``merge_attn``, ``merge_moe``; ``first``: the
+    model's first layer, whose ``merge_attn`` leaves the residual alone);
+    ``sub`` holds the two modules' fields under ``"attn"`` and ``"moe"``,
+    the layer is called with the previous ``"Z"`` layer's router state
+    (None for the first) and returns ``(y, router_state)``."""
     kind: str
     sub: Any
     dtype: Any = jnp.bfloat16
@@ -369,12 +545,22 @@ class PatternLayer(nn.Module):
     norm_eps: float = 1e-5
     mlp_hidden: int = 0
     residual_multiplier: float = 1.0
+    first: bool = False
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, router_state=None):
         def normed(y, name):
             return _norm(self.norm, self.norm_eps, self.ln_dtype, name)(y)
 
+        if self.kind == "Z":
+            y = CompressedConvAttention(**self.sub["attn"], dtype=self.dtype,
+                                        name="attn")(normed(x, "norm"))
+            h = ResidualMerge(residual=not self.first,
+                              name="merge_attn")(x, y)
+            y, _, _, router_state = DroplessMoE(
+                **self.sub["moe"], dtype=self.dtype, norm_eps=self.norm_eps,
+                name="moe")(normed(h, "moe_norm"), router_state)
+            return ResidualMerge(name="merge_moe")(h, y), router_state
         if self.kind in ("L", "F"):
             if self.kind == "L":
                 from horovod_tpu.models.linear_attention import GatedDeltaNet
@@ -399,11 +585,10 @@ class PatternLayer(nn.Module):
                                       norm_eps=self.norm_eps,
                                       name="attn")(h)
         elif self.kind == "E":
-            y, _, _ = DroplessMoE(**self.sub, dtype=self.dtype,
-                                  name="moe")(h)
+            y = DroplessMoE(**self.sub, dtype=self.dtype, name="moe")(h)[0]
         else:
             raise ValueError(f"unknown layer {self.kind!r} in a pattern: "
-                             "'M', '*', 'S', 'E', 'L', 'F', 'm' or 'a'")
+                             "'M', '*', 'S', 'E', 'L', 'F', 'm', 'a' or 'Z'")
         if self.kind in ("m", "a"):
             r = self.residual_multiplier
             h = x + r * y
@@ -568,6 +753,7 @@ class TransformerLM(nn.Module):
     indexer: Any = None
     attn_scale: Optional[float] = None
     residual_multiplier: float = 1.0
+    cca: Any = None
     # Of a pattern stack too: the embedded tokens are multiplied by
     # embedding_multiplier, the final hidden states divided by
     # logits_scaling, and with tie_head the head is the embedding table
@@ -592,7 +778,7 @@ class TransformerLM(nn.Module):
                 f"computes the full sequence locally); got {self.attn!r}")
         if self.tp_axis and (self.moe_experts or self.qk_norm
                              or self.pos != "learned" or self.pattern
-                             or self.moe or self.indexer):
+                             or self.moe or self.indexer or self.cca):
             raise ValueError("tp_axis runs the GPT-2 block only: no "
                              "experts (whole or a held share), QK-norm, "
                              "rotary positions or pattern stack")
@@ -601,11 +787,12 @@ class TransformerLM(nn.Module):
         if self.pattern is not None:
             return self._pattern_stack(tokens, return_hidden)
         if (self.pos == "none" or self.moe or self.ssm or self.lin
-                or self.mlp_hidden or self.indexer or self.tie_head
+                or self.mlp_hidden or self.indexer or self.cca
+                or self.tie_head
                 or self.attn_scale is not None
                 or (self.residual_multiplier, self.embedding_multiplier,
                     self.logits_scaling) != (1.0, 1.0, 1.0)):
-            raise ValueError("pos='none', ssm=, moe=, lin=, indexer=, "
+            raise ValueError("pos='none', ssm=, moe=, lin=, indexer=, cca=, "
                              "mlp_hidden=, attn_scale=, tie_head= and the "
                              "three multipliers belong to a pattern stack; "
                              "the block stack "
@@ -643,12 +830,17 @@ class TransformerLM(nn.Module):
                         param_dtype=jnp.float32, name="head")(x)
 
     def _pattern_stack(self, tokens, return_hidden):
+        rotary = self.rope_theta if self.pos == "rotary" else None
         if self.attn not in ("full", "flash") or self.pos not in (
-                ("none", "rotary") if "S" in self.pattern else ("none",)):
+                ("none", "rotary") if set("SZ") & set(self.pattern)
+                else ("none",)) or ("Z" in self.pattern and rotary is None):
             raise ValueError("a pattern stack runs whole sequences "
                              "(attn='full' or 'flash') with pos='none', or "
-                             "'rotary' for its 'S' layers; got "
+                             "'rotary' for its 'S' layers and 'Z' layers "
+                             "('Z' layers have no other); got "
                              f"attn={self.attn!r}, pos={self.pos!r}")
+        experts = dict(num_experts=self.moe_experts, hidden=self.moe_hidden,
+                       top_k=self.moe_top_k, **dict(self.moe or {}))
         subs = {
             "M": dict(self.ssm or {}),
             "*": dict(num_heads=self.num_heads, kv_heads=self.kv_heads,
@@ -656,16 +848,19 @@ class TransformerLM(nn.Module):
             "S": dict(num_heads=self.num_heads, kv_heads=self.kv_heads,
                       head_dim=self.head_dim, attn=self.attn,
                       qk_norm=self.qk_norm, indexer=self.indexer,
-                      rope_theta=(self.rope_theta if self.pos == "rotary"
-                                  else None)),
-            "E": dict(num_experts=self.moe_experts, hidden=self.moe_hidden,
-                      top_k=self.moe_top_k, **dict(self.moe or {})),
+                      rope_theta=rotary),
+            "E": experts,
             "L": dict(self.lin or {}),
             "F": dict(num_heads=self.num_heads, attn=self.attn,
                       qk_norm=self.qk_norm),
             "a": dict(num_heads=self.num_heads, kv_heads=self.kv_heads,
                       head_dim=self.head_dim, attn=self.attn,
                       scale=self.attn_scale),
+            "Z": dict(attn=dict(num_heads=self.num_heads,
+                                kv_heads=self.kv_heads,
+                                head_dim=self.head_dim, attn=self.attn,
+                                rope_theta=rotary, **dict(self.cca or {})),
+                      moe=experts),
         }
         subs["m"] = subs["M"]
         if self.residual_multiplier != 1.0 and set(self.pattern) - {"m", "a"}:
@@ -677,13 +872,18 @@ class TransformerLM(nn.Module):
         x = embed(tokens)
         if self.embedding_multiplier != 1.0:
             x = x * self.embedding_multiplier
+        router_state = None       # of the last 'Z' layer, for the next one
         for i, kind in enumerate(self.pattern):
-            x = PatternLayer(kind, subs.get(kind), dtype=self.dtype,
-                             ln_dtype=self.ln_dtype, norm=self.norm,
-                             norm_eps=self.norm_eps,
-                             mlp_hidden=self.mlp_hidden,
-                             residual_multiplier=self.residual_multiplier,
-                             name=f"layer_{i}")(x)
+            layer = PatternLayer(kind, subs.get(kind), dtype=self.dtype,
+                                 ln_dtype=self.ln_dtype, norm=self.norm,
+                                 norm_eps=self.norm_eps,
+                                 mlp_hidden=self.mlp_hidden,
+                                 residual_multiplier=self.residual_multiplier,
+                                 first=i == 0, name=f"layer_{i}")
+            if kind == "Z":
+                x, router_state = layer(x, router_state)
+            else:
+                x = layer(x)
         x = _norm(self.norm, self.norm_eps, self.ln_dtype, "ln_f")(x)
         if self.logits_scaling != 1.0:
             x = x / self.logits_scaling
@@ -805,6 +1005,49 @@ def KeyeLM(**overrides) -> TransformerLM:
         indexer=dict(num_heads=16, head_dim=64, topk=2048, tile=512),
         moe_experts=128, moe_top_k=8, moe_hidden=768,
         moe=dict(router="softmax", renormalize=True, activation="swiglu"))
+    fields.update(overrides)
+    return TransformerLM(**fields)
+
+
+def Zaya1LM(**overrides) -> TransformerLM:
+    """The stack that ``Zyphra/ZAYA1-8B``'s config.json describes
+    (``model_type`` ``zaya``), as a :class:`TransformerLM` with a
+    ``pattern``: 40 layers ``Z`` (``layer_types`` all ``hybrid``) at d 2048,
+    pre-norm RMSNorm eps 1e-5.  A layer is compressed convolutional
+    attention (:class:`CompressedConvAttention`: 8 query heads over 2 KV
+    heads of 128 in a latent, two causal convolutions of ``cca_time0`` 2
+    and ``cca_time1`` 2 taps over q and k, QK-mean, value shift, L2 norm
+    with a learned temperature a KV head, rotary positions of theta 5e6 on
+    half of each head) and then a top-1 layer of 16 SwiGLU experts 2048
+    wide chosen by a router network 256 wide that carries its state from
+    layer to layer and has a seventeenth choice that computes nothing
+    (``DroplessMoE(router="mlp", skip_choice=True)``); residual and
+    sub-layer output are merged under learned scales and biases
+    (:class:`ResidualMerge`); vocab 262272, the head tied to the embedding.
+
+    What config.json does not say is the family's published form (Zyphra,
+    arXiv:2510.04476 and the ZAYA1 report, arXiv:2511.17127; the switches
+    ``zaya_use_eda``, ``zaya_use_mod``, ``scale_residual_merge`` are on in
+    both sibling configurations): the router's depth and activation, the
+    state's learned scale, the order of the latent's passes.
+    ``benchmark/configs/zaya1-8b.json`` lists each under ``assumed``.  No
+    auxiliary loss: the correction bias the router chooses by is held at
+    zero (nothing here updates it outside the gradient).
+
+    Trained like :func:`GraniteHybridLM` through ``make_train_step`` and
+    ``fused_softmax_xent`` on ``model.head_kernel(params)``.  ``overrides``
+    replace any field: a cut takes the first letters of the pattern, and
+    ``moe={..., "held": (first, count)}`` keeps one chip's share of every
+    layer's experts."""
+    fields = dict(
+        vocab=262272, dim=2048, num_heads=8, kv_heads=2, head_dim=128,
+        max_len=131072, norm="rms", norm_eps=1e-5, pos="rotary",
+        rope_theta=5e6, pattern="Z" * 40,
+        cca=dict(taps=(2, 2), rotary_fraction=0.5),
+        moe_experts=16, moe_top_k=1, moe_hidden=2048,
+        moe=dict(router="mlp", router_hidden=256, skip_choice=True,
+                 activation="swiglu"),
+        tie_head=True)
     fields.update(overrides)
     return TransformerLM(**fields)
 

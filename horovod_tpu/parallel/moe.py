@@ -299,7 +299,28 @@ class DroplessMoE(nn.Module):
       windows of ``R`` rows in turn (scope ``overflowed``; their empty
       rows are skipped), so none is lost.  The expert block is recomputed in the backward
       pass, so no window's rows are kept.  Sown beside the rest:
-      ``held_assignments``, the number that landed here.
+      ``held_assignments``, the number that landed here.  Where ``3 ·
+      top_k · count ≥`` the outputs routed over (top-1 with 8 of 17 held)
+      ``R`` is EVERY assignment: one levelled window over all ``n · k``
+      rows, and no second one exists to overflow into.
+    * ``router="mlp"``: the router is a small network that carries a state
+      from one expert layer to the next, and the layer is called as
+      ``layer(x, router_state)``.  ``r = W_d x + b_d`` (``router_hidden``
+      wide), ``r ← r + γ ⊙ router_state`` where a state is given (``γ``
+      learned, init 1), logits ``W_3 gelu(W_2 gelu(W_1 norm(r) + b_1) +
+      b_2)`` with ``norm`` an RMSNorm (``norm_eps``) and the exact GELU,
+      scores their softmax; the k chosen by ``scores + choice_bias`` (a
+      parameter held at zero and outside the gradient: nothing here updates
+      it), the lower index on a tie, and gated by the scores alone.
+      Parameters ``router_down``, ``router_state_scale``, ``router_norm``,
+      ``router_fc1``, ``router_fc2``, ``router_out``, ``choice_bias``; trace
+      scopes ``route/down``, ``route/eda``, ``route/mlp``.  ``r``, float32,
+      is returned as a fourth result for the next layer.
+    * ``skip_choice``: the router has ONE MORE output than there are
+      experts, a choice that computes nothing.  It is routed over like an
+      expert (``tokens_per_expert`` has ``num_experts + 1`` entries, the
+      gates are normalised with it), belongs to no shard's ``held`` range
+      and is sent to no rows; sown: ``skipped_assignments``.
 
     Input ``(..., d)``.  Returns ``(output, load_balance, router_z)``:
     the load-balancing term ``E · Σ_e f_e · P_e`` (``f_e`` = assignments
@@ -317,36 +338,57 @@ class DroplessMoE(nn.Module):
     top_k: int
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
-    router: str = "softmax"              # "softmax" | "sigmoid"
+    router: str = "softmax"              # "softmax" | "sigmoid" | "mlp"
     renormalize: bool = False
     gate_scale: float = 1.0
     activation: str = "swiglu"           # "swiglu" | "relu2"
     shared_hidden: int = 0
     held: Optional[Tuple[int, int]] = None
+    router_hidden: int = 0               # router="mlp": the state's width
+    skip_choice: bool = False
+    norm_eps: float = 1e-5               # of the mlp router's norm
+
+    @property
+    def routed_over(self) -> int:
+        """Outputs of the router: the experts and the skip choice."""
+        return self.num_experts + bool(self.skip_choice)
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, router_state=None):
         E, k = self.num_experts, self.top_k
         if not 1 <= k <= E:
             raise ValueError(f"top_k={k} out of range for {E} experts")
-        if self.router not in ("softmax", "sigmoid") or (
+        if self.router not in ("softmax", "sigmoid", "mlp") or (
                 self.activation not in ("swiglu", "relu2")):
             raise ValueError(f"unknown router {self.router!r} or "
                              f"activation {self.activation!r}")
+        if router_state is not None and self.router != "mlp":
+            raise ValueError("only router='mlp' carries a state; got "
+                             f"router={self.router!r}")
         lead, d = x.shape[:-1], x.shape[-1]
         x = x.reshape(-1, d)
         n = x.shape[0]
+        routed = self.routed_over
 
         with jax.named_scope("route"):
-            logits = nn.Dense(E, use_bias=False, dtype=jnp.float32,
-                              param_dtype=self.param_dtype,
-                              precision=lax.Precision.HIGHEST,
-                              name="router")(x.astype(jnp.float32))
-            if self.router == "softmax":
-                probs = jax.nn.softmax(logits, axis=-1)           # (N, E)
+            if self.router == "mlp":
+                logits, router_state = self._mlp_router(
+                    x.astype(jnp.float32), router_state)
+                probs = jax.nn.softmax(logits, axis=-1)      # (N, routed)
+                bias = self.param("choice_bias", nn.initializers.zeros,
+                                  (routed,), self.param_dtype)
+                _, expert = lax.top_k(probs + lax.stop_gradient(bias), k)
+                gate = jnp.take_along_axis(probs, expert, axis=-1)
             else:
-                probs = jax.nn.sigmoid(logits)
-            gate, expert = lax.top_k(probs, k)                    # (N, k)
+                logits = nn.Dense(routed, use_bias=False, dtype=jnp.float32,
+                                  param_dtype=self.param_dtype,
+                                  precision=lax.Precision.HIGHEST,
+                                  name="router")(x.astype(jnp.float32))
+                if self.router == "softmax":
+                    probs = jax.nn.softmax(logits, axis=-1)       # (N, E)
+                else:
+                    probs = jax.nn.sigmoid(logits)
+                gate, expert = lax.top_k(probs, k)                # (N, k)
             if self.renormalize:
                 gate = gate / (gate.sum(axis=-1, keepdims=True) + 1e-20)
             if self.gate_scale != 1.0:
@@ -354,18 +396,23 @@ class DroplessMoE(nn.Module):
 
         names = (("w_gate", "w_up", "w_down") if self.activation == "swiglu"
                  else ("w_up", "w_down"))
-        if self.held is None:
+        if self.held is None and not self.skip_choice:
             out, tokens_per_expert, fused = self._all_experts(
                 x, gate, expert, names)
             n_held = E
         else:
-            first, n_held = self.held
+            # With a skip choice and no share named, every expert is held:
+            # the skip choice is then the one output held nowhere.
+            first, n_held = self.held or (0, E)
             if not (0 <= first and n_held >= 1 and first + n_held <= E):
                 raise ValueError(f"held={self.held} is not a range of the "
                                  f"{E} experts")
             out, tokens_per_expert, held_assignments, fused = (
                 self._held_experts(x, gate, expert, names, first, n_held))
             self.sow("intermediates", "held_assignments", held_assignments)
+        if self.skip_choice:
+            self.sow("intermediates", "skipped_assignments",
+                     tokens_per_expert[E])
 
         if self.shared_hidden:
             out = out + _SharedExpert(
@@ -374,7 +421,7 @@ class DroplessMoE(nn.Module):
 
         with jax.named_scope("router_losses"):
             f = lax.stop_gradient(tokens_per_expert / n)
-            balance = E * jnp.sum(f * probs.mean(axis=0))
+            balance = routed * jnp.sum(f * probs.mean(axis=0))
             z_loss = jnp.mean(
                 jax.scipy.special.logsumexp(logits, axis=-1) ** 2)
         self.sow("intermediates", "aux_load_balance", balance)
@@ -390,9 +437,48 @@ class DroplessMoE(nn.Module):
             # What uniform routing sends to the held experts; the number
             # a step's routing did send is on the device (sown as
             # ``held_assignments``).
-            counters["moe.held_assignments"] = n * k * n_held // E
+            counters["moe.held_assignments"] = n * k * n_held // routed
+        if self.router == "mlp":
+            counters["moe.router_hidden"] = self.router_hidden
+        if self.skip_choice:
+            counters["moe.skip_choice"] = 1
         note_layer(self.path, counters)
-        return out.astype(x.dtype).reshape(*lead, d), balance, z_loss
+        out = out.astype(x.dtype).reshape(*lead, d)
+        if self.router == "mlp":
+            return (out, balance, z_loss,
+                    router_state.reshape(*lead, self.router_hidden))
+        return out, balance, z_loss
+
+    def _mlp_router(self, x, state):
+        """``(logits, r)`` of the router network on float32 ``x`` (N, d)
+        and the previous expert layer's ``r`` (class docstring): float32
+        at full matmul precision throughout, as the one-matrix routers
+        are."""
+        R = self.router_hidden
+        if R < 1:
+            raise ValueError("router='mlp' needs router_hidden >= 1")
+
+        def dense(features, name, use_bias=True):
+            return nn.Dense(features, use_bias=use_bias, dtype=jnp.float32,
+                            param_dtype=self.param_dtype,
+                            precision=lax.Precision.HIGHEST, name=name)
+
+        with jax.named_scope("down"):
+            r = dense(R, "router_down")(x)
+        if state is not None:
+            with jax.named_scope("eda"):
+                scale = self.param("router_state_scale",
+                                   nn.initializers.ones, (R,),
+                                   self.param_dtype)
+                r = r + scale * state.reshape(-1, R).astype(jnp.float32)
+        with jax.named_scope("mlp"):
+            h = nn.RMSNorm(epsilon=self.norm_eps, dtype=jnp.float32,
+                           param_dtype=self.param_dtype,
+                           name="router_norm")(r)
+            h = nn.gelu(dense(R, "router_fc1")(h), approximate=False)
+            h = nn.gelu(dense(R, "router_fc2")(h), approximate=False)
+            logits = dense(self.routed_over, "router_out", use_bias=False)(h)
+        return logits, r
 
     def _weights(self, names, n_experts, d):
         init = nn.initializers.lecun_normal(batch_axis=(0,))
@@ -444,7 +530,7 @@ class DroplessMoE(nn.Module):
 
     def _held_experts(self, x, gate, expert, names, first, n_held):
         """``n_held`` of the experts are here (class docstring)."""
-        E, k = self.num_experts, self.top_k
+        E, k = self.routed_over, self.top_k
         n, d = x.shape
         with jax.named_scope("dispatch"):
             flat = expert.reshape(-1)

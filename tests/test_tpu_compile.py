@@ -1159,3 +1159,59 @@ def test_a_call_without_a_selection_lowers_as_it_did(v5e, entry, b, t, h,
     lowered = jax.jit(jax.grad(loss, argnums=range(len(shapes)))).lower(
         *shapes)
     assert custom_calls(lowered.as_text()) == list(zip(kernels, (6, 6, 3)))
+
+
+# ---------------------------------------------- the ZAYA1 layer's parts
+# (the zaya1_1chip cell: 1 sequence of 16,384, ZAYA1-8B's widths)
+
+
+def test_a_zaya_layer_fwd_bwd_at_zaya_widths(v5e, monkeypatch):
+    """One ``Z`` layer as the ``zaya1_1chip`` cell calls it, forward and
+    backward on one chip: compressed convolutional attention's latent in
+    plain XLA around the grouped-KV flash kernels at 8 query over 2 KV
+    heads of 128 and T 16,384, then the router network and 8 held of 16
+    top-1 experts 2,048 wide.  With 3 x 8 held >= the 17 outputs the held
+    window is EVERY assignment: the grouped matmuls run over 16,384 rows,
+    as the family's kernels, and no second window exists."""
+    from horovod_tpu.models.transformer import PatternLayer
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    tokens, d = 16_384, 2048
+    one = SingleDeviceSharding(v5e[0])
+    layer = PatternLayer("Z", dict(
+        attn=dict(num_heads=8, kv_heads=2, head_dim=128, attn="flash",
+                  rope_theta=5e6, taps=(2, 2), rotary_fraction=0.5),
+        moe=dict(num_experts=16, hidden=2048, top_k=1, router="mlp",
+                 router_hidden=256, skip_choice=True, held=(0, 8))))
+    x = jax.ShapeDtypeStruct((1, tokens, d), jnp.bfloat16, sharding=one)
+    state = jax.ShapeDtypeStruct((1, tokens, 256), jnp.float32, sharding=one)
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one),
+        jax.eval_shape(lambda key: layer.init(
+            key, jnp.zeros((1, 256, d), jnp.bfloat16),
+            jnp.zeros((1, 256, 256), jnp.float32))["params"],
+            jax.random.PRNGKey(0)))
+    assert params["moe"]["w_gate"].shape == (8, d, 2048)
+    assert params["moe"]["router_out"]["kernel"].shape == (256, 17)
+    assert params["attn"]["conv1_kernel"].shape == (10, 2, 128, 128)
+
+    def loss(p, x, state):
+        y, r = layer.apply({"params": p}, x, state)
+        return y.astype(jnp.float32).sum() + r.sum()
+
+    lowered = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
+        params, x, state)
+    found = kernels_by_name(lowered)
+    # Up, gate and down forward, their replay in the checkpoint, and the
+    # input and weight gradients: one window, so each exactly once.
+    assert found == {"moe_gmm": 6, "moe_gmm_nt": 3, "moe_tgmm": 3}, found
+    assert custom_calls(lowered.as_text())[:3] == [
+        ("_dkdv_kernel", 6), ("_dq_kernel", 6), ("_fwd_kernel", 3)]
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert "ragged-dot" not in text
+    assert "16384,2048" in text
+    m = compiled.memory_analysis()
+    plan = (m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes - m.alias_size_in_bytes)
+    assert plan < 3.0 * 2 ** 30, plan / 2 ** 30
