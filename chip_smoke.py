@@ -27,6 +27,11 @@ through the entry points a user calls (``hvd.init()`` →
   indexer's scores, the exact top-k and its int8 map, the flash kernels
   under the map and the KL pass — against its dense float32 form: the
   same keys, the output, ``L_I`` and the gradients of all six operands;
+* times the flash kernels of a call with grouped KV heads and no map alone
+  at ``zaya1_1chip``'s and ``twotower_1chip``'s attention shapes — the
+  forward, the per-head pair and the one fused kernel a KV group — checks
+  the fused kernel's gradients against the pair's and prints which of the
+  two ``flash_attention._plan`` takes here (``gqa_plan``);
 * takes optimizer steps with the d=2048/T=2048 TransformerLM (one step
   per call, then four scanned steps per call) and with ResNet-50 at
   batch 128, parameters from each model's own ``init`` under ``--seed``,
@@ -51,6 +56,7 @@ calls them tiny on the CPU mesh.
 from __future__ import annotations
 
 import argparse
+import functools
 import json
 import math
 import os
@@ -95,6 +101,14 @@ SELECT_REFERENCE = dict(batch=1, seq=2048, heads=8, kv_heads=1, head_dim=128,
 # 2,048 keys a query, and the KL pass of an indexer of 16 heads of 64.
 SELECT_BACKWARD = dict(batch=1, seq=16384, heads=32, kv_heads=4, head_dim=128,
                        index_heads=16, index_dim=64, topk=2048)
+# One layer's attention with grouped KV heads and no map, kernels alone, at
+# the two cells that run it: zaya1_1chip (8 query over 2 KV heads of 128,
+# one sequence of 16,384) and twotower_1chip (32 over 2, two of 8,192).
+GROUPED_BACKWARD = {
+    "zaya1_1chip": dict(batch=1, seq=16384, heads=8, kv_heads=2,
+                        head_dim=128),
+    "twotower_1chip": dict(batch=2, seq=8192, heads=32, kv_heads=2,
+                           head_dim=128)}
 ONE_CHIP_LM = dict(**TRANSFORMER, batch=8, steps=3, scan_steps=4)
 ONE_CHIP_RESNET = dict(stage_sizes=(3, 4, 6, 3), num_filters=64,
                        num_classes=1000, image=224, batch=128, steps=3)
@@ -644,6 +658,23 @@ def select_reference_phase(*, batch: int, seq: int, heads: int,
             **{name: round(err, 5) for name, err in errs.items()}}
 
 
+def _timed_ms(calls: int, interpret: bool, fn, *args):
+    """``(ms a call, result)`` of the jitted ``fn(*args)``: device time as
+    the host's clock sees ``calls`` of them end, after one call that
+    compiles; no time where the kernels are interpreted."""
+    import jax
+
+    fn = jax.jit(fn)
+    out = jax.block_until_ready(fn(*args))
+    if interpret:
+        return None, out
+    t0 = time.perf_counter()
+    for _ in range(calls):
+        out = fn(*args)
+    jax.block_until_ready(out)
+    return round((time.perf_counter() - t0) / calls * 1e3, 3), out
+
+
 def select_backward_phase(*, batch: int, seq: int, heads: int, kv_heads: int,
                           head_dim: int, index_heads: int, index_dim: int,
                           topk: int, seed: int, calls: int = 10) -> dict:
@@ -683,16 +714,7 @@ def select_backward_phase(*, batch: int, seq: int, heads: int, kv_heads: int,
 
     select = random_map(ks[4])
 
-    def timed(fn, *args):
-        fn = jax.jit(fn)
-        out = jax.block_until_ready(fn(*args))
-        if interpret:
-            return None, out
-        t0 = time.perf_counter()
-        for _ in range(calls):
-            out = fn(*args)
-        jax.block_until_ready(out)
-        return round((time.perf_counter() - t0) / calls * 1e3, 3), out
+    timed = functools.partial(_timed_ms, calls, interpret)
 
     common = dict(scale=D ** -0.5, causal=True, interpret=interpret,
                   seq_len=None)
@@ -752,6 +774,62 @@ def select_backward_phase(*, batch: int, seq: int, heads: int, kv_heads: int,
             "kl_plan": dict(zip(("block_q", "block_k", "vmem_mb"), kl_plan)),
             "selected_per_query": round(float(select.sum()) / (B * T), 1),
             "ms_a_layer": ms,
+            "fused_vs_pair": {n: round(e, 6) for n, e in errs.items()}}
+
+
+def grouped_backward_phase(*, batch: int, seq: int, heads: int, kv_heads: int,
+                           head_dim: int, seed: int, calls: int = 10) -> dict:
+    """The flash kernels of one attention layer with grouped KV heads and no
+    selection map, alone: the forward ``gqa_plan`` names, and the backward
+    in both forms on the same operands — the per-head pair
+    (``_dq_kernel``, ``_dkdv_kernel``) and the one kernel a KV group
+    (``flash_group_bwd``) under the plan's blocks and budget — the fused
+    kernel's three gradients against the pair's.  ``gqa_plan`` is what
+    ``flash_attention._plan`` decides for the call on this device: the
+    form, its scoped-VMEM budget, its blocks and the live share of what it
+    computes.  ``ms``: as ``select_backward_phase``'s."""
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.ops import flash_attention as fa
+
+    interpret = jax.default_backend() != "tpu"
+    B, T, H, Hkv, D = batch, seq, heads, kv_heads, head_dim
+    blocks = fa._resolve_blocks(T, "chip_smoke", None, None, None, None,
+                                None, "")[:4]
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    q, k, v, do = (jax.random.normal(key, (B, T, h * D)).astype(jnp.bfloat16)
+                   for key, h in zip(ks, (H, Hkv, Hkv, H)))
+    plan = fa._select_plan_for(q, k, H, D, True, *blocks, interpret,
+                               select=False)
+
+    timed = functools.partial(_timed_ms, calls, interpret)
+
+    common = dict(scale=D ** -0.5, causal=True, interpret=interpret)
+    ms = {}
+    ms["forward"], (o, lse) = timed(
+        lambda *a: fa._fwd_packed(*a, H, D, plan, block_q=blocks[0],
+                                  block_k=blocks[1], kv_rep=H // Hkv,
+                                  **common), q, k, v)
+    operands = (q, k, v, o, lse, do)
+    ms["pair"], want = timed(
+        lambda *a: fa._bwd_pallas_packed(
+            *a, H, D, plan._replace(bwd="per_head"), block_q=blocks[2],
+            block_k=blocks[3], kv_rep=H // Hkv, **common), *operands)
+    errs = {}
+    if plan.bwd == "group_fused":
+        ms["fused"], got = timed(
+            lambda q, k, v, *a: fa._select_bwd(
+                q, k, v, None, *a, H, D, fused=True, block_q=plan.blocks[2],
+                block_k=plan.blocks[3], seq_len=None,
+                vmem_mb=plan.bwd_vmem_mb, **common), *operands)
+        for name, g, w in zip(("dq", "dk", "dv"), got, want):
+            errs[name] = _rel_err(g, w)
+            check(errs[name] <= SELECT_TOL,
+                  f"the fused grouped-KV backward differs from the pair in "
+                  f"{name} by {errs[name]:.3g} (bound {SELECT_TOL})")
+    return {"shape": [B, T, H, Hkv, D], "interpret": interpret,
+            "gqa_plan": plan._asdict(), "ms_a_layer": ms,
             "fused_vs_pair": {n: round(e, 6) for n, e in errs.items()}}
 
 
@@ -1303,6 +1381,9 @@ def main(argv=None) -> int:
             **SELECT_REFERENCE, seed=args.seed))
         emit("select_backward", **select_backward_phase(
             **SELECT_BACKWARD, seed=args.seed))
+        for cell, shape in GROUPED_BACKWARD.items():
+            emit("grouped_backward", cell=cell, **grouped_backward_phase(
+                **shape, seed=args.seed))
         emit("transformer_lm", **transformer_phase(
             mesh, events, **ONE_CHIP_LM, seed=args.seed))
         emit("resnet50", **resnet_phase(

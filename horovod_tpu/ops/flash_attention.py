@@ -53,15 +53,22 @@ scratch through an in-kernel loop, and was slower than the pair; the one
 that runs under a selection map since PR 39 takes a KV group a step,
 keeps ``dq`` in a statically indexed scratch and carries the KV head's
 ``dK``, ``dV`` across GRID steps — 33.9 ms a layer against the pair's
-47.2 at T 16,384 (PERF.md §6, PR 39).  The map-less pairs below still
-form seven products a tile.
+47.2 at T 16,384 (PERF.md §6, PR 39).  Since PR 44 a call with grouped KV
+heads and no map runs it too; the two map-less pairs below still form
+seven products a tile.
 
 Grouped-query attention: ``k`` and ``v`` may hold fewer heads than ``q``.
-At lane-aligned heads nothing is repeated in HBM: the forward's and the dq
-kernel's index maps send query head ``h`` to KV head ``h // kv_rep``, and
-the per-head dk/dv kernel's innermost grid axis runs the Q blocks of the
-``kv_rep`` query heads of a KV head one after another, so that their sums
-form in its scratch.  The grouped pair stands down there.
+At lane-aligned heads nothing is repeated in HBM: the forward's index maps
+send query head ``h`` to KV head ``h // kv_rep``.  The backward is that
+one kernel a KV group, without the map (``flash_group_bwd``:
+:func:`_select_bwd_kernel` with ``has_map`` False — a KV head's K and V
+fetched once for the query heads that read it, ``dK`` and ``dV`` summed
+over them in VMEM), wherever :func:`_plan` can see that the KV head's two
+gradients fit there; elsewhere the per-head pair, whose dq kernel's index
+maps do what the forward's do and whose dk/dv kernel's innermost grid axis
+runs the Q blocks of the ``kv_rep`` query heads of a KV head one after
+another, so that their sums form in its scratch.  The grouped pair stands
+down at ``kv_rep > 1``.
 
 Under a selection map (``flash_attention(select=...)``, learned sparse
 attention) a path of its own runs, forward and backward: kernels that
@@ -71,8 +78,8 @@ for the query heads that share a KV head.  The forward is
 ``dK`` and ``dV`` may stay in VMEM across its sweep
 (:func:`_select_bwd_kernel`, PR 39: ``S`` and ``dP`` formed once, five
 products a tile) and the pair :func:`_select_dq_kernel`,
-:func:`_select_dkdv_kernel` (seven) where they may not.  The calls
-without a map share nothing with it.
+:func:`_select_dkdv_kernel` (seven) where they may not.  Of it the calls
+without a map share the one fused backward kernel, at grouped KV heads.
 
 Composition: this is the *single-chip* block; for sequences sharded
 across chips use :mod:`horovod_tpu.parallel.ring_attention`, which
@@ -85,6 +92,7 @@ fall back to ``full_attention``).
 
 from __future__ import annotations
 
+import contextlib
 import functools
 from typing import NamedTuple, Optional
 
@@ -980,7 +988,8 @@ def _bwd_pallas_packed(q, k, v, o, lse, do, H, D, plan, *, scale, causal,
 # ------------------------------------------------- under a selection map
 #
 # ``flash_attention(select=map)``: a path of its own, reached only with a
-# map and sharing no kernel with the calls without one.  The selection is
+# map; a call with grouped KV heads and no map shares its fused backward
+# kernel (``has_map`` False), and nothing else.  The selection is
 # one set a query, shared by its heads, and ``G = H / H_kv`` query heads
 # read each KV head; in the packed layout those are adjacent lanes.  So a
 # grid step takes a whole KV group: the (block_q, block_k) int8 tile of
@@ -1037,6 +1046,38 @@ def _group_block_q(block_q: int, group: int, vmem_headroom: bool) -> int:
            and block_q % 256 == 0):
         block_q //= 2
     return block_q
+
+
+# The fused backward WITHOUT a map emits two bodies a tile, a masked and an
+# unmasked one (`_masked_dispatch`), each unrolled over the group's heads,
+# where the map's one body adds its bias everywhere.  Timed alone on a v5e
+# (PR 44, ms a layer): at the 4 Mi score elements a step that the rows above
+# allow — 4 heads x 1024 x 1024, 8 x 512 x 1024, 16 x 256 x 1024 — the two
+# bodies read 19.19, 37.89 and 41.12 where the one body under an all-ones map
+# reads 8.81, 17.07 and 19.08; at 2 Mi a step they read 8.46 (4 x 512 x
+# 1024; 8.55 at 1024 x 512), 16.59 (8 x 512 x 512; 17.12 at 256 x 1024) and
+# 18.46 (16 x 256 x 512; 19.94 at 128 x 1024), each under the map's form.
+# So without a map a step holds at most 2 Mi score elements, and the longer
+# side of a head's tile is the one halved (the Q block on a tie).
+_GROUP_STEP_ELEMENTS_NO_MAP = 2 * 2 ** 20
+
+
+def _group_bwd_blocks_no_map(block_q: int, block_k: int, group: int):
+    """``(block_q, block_k)`` of the fused backward without a map: the
+    group form's Q block (:func:`_group_block_q` where the device backs the
+    budget, the only place the form runs), then the longer side of a head's
+    tile halved (while it stays a multiple of 128) until the ``group``
+    heads of a grid step hold no more score elements than the bound
+    above."""
+    block_q = _group_block_q(block_q, group, True)
+    while group * block_q * block_k > _GROUP_STEP_ELEMENTS_NO_MAP:
+        if block_q >= block_k and block_q % 256 == 0:
+            block_q //= 2
+        elif block_k % 256 == 0:
+            block_k //= 2
+        else:
+            break
+    return block_q, block_k
 
 
 def _select_bias(sel_ref, bias_scr, qi, kj, block_q, block_k, causal,
@@ -1210,17 +1251,28 @@ def _select_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dta_ref,
         dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
 
 
-def _select_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dta_ref,
-                       sel_ref, dq_ref, dk_ref, dv_ref, bias_scr, dq_scr,
-                       dk_scr, dv_scr, *, scale, causal, block_q, block_k,
-                       seq_len, group, head_dim):
+def _select_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dta_ref, *rest,
+                       scale, causal, block_q, block_k, seq_len, group,
+                       head_dim, has_map):
     """dq, dk and dv of one KV group in one sweep, grid as the forward's (Q
     blocks outside, KV blocks innermost): ``p`` and ``dS`` of a head are
     formed once a tile and feed all three products — five a tile where the
     dq / dk-dv pair forms seven.  ``dq_scr[g]`` sums over a Q block's KV
     steps as :func:`_select_dq_kernel`'s does; the KV head's whole ``dK``
     and ``dV``, (T, D) float32 each, stay in VMEM from the head's first
-    step to its last, and a step adds into their ``kj``-th slice."""
+    step to its last, and a step adds into their ``kj``-th slice.
+
+    ``has_map``: whether the call carries a selection map.  With one, its
+    tile is an operand and its decoded bias a scratch, added to every
+    score.  Without one (grouped KV heads alone) neither exists: an
+    interior tile adds nothing to its scores, and a tile the causal
+    diagonal or the padding cuts is masked as the per-head pair masks it
+    (:func:`_block_mask` through :func:`_masked_dispatch`)."""
+    if has_map:
+        (sel_ref, dq_ref, dk_ref, dv_ref, bias_scr, dq_scr, dk_scr,
+         dv_scr) = rest
+    else:
+        dq_ref, dk_ref, dv_ref, dq_scr, dk_scr, dv_scr = rest
     qi = pl.program_id(2)
     kj = pl.program_id(3)
     nq = pl.num_programs(2)
@@ -1236,22 +1288,24 @@ def _select_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dta_ref,
     def _init():
         dq_scr[...] = jnp.zeros_like(dq_scr)
 
-    live = _select_bias(sel_ref, bias_scr, qi, kj, block_q, block_k, causal,
-                        seq_len)
-
-    @pl.when(live)
-    def _heads():
+    def _heads(masked: bool = False):
         k = k_ref[0]
         v = v_ref[0]
         lse = lse_ref[0, 0]                               # (BQ, G)
         delta = dta_ref[0, 0]
         rows = pl.ds(pl.multiple_of(kj * block_k, block_k), block_k)
+        ok = (_block_mask(qi, kj, block_q, block_k, causal, seq_len)
+              if masked else None)
         for g in range(group):
             sl = slice(g * D, (g + 1) * D)
             q = q_ref[0, :, sl]
             do = do_ref[0, :, sl]
-            p, ds = _select_p_ds(q, k, v, do, lse[:, g:g + 1],
-                                 delta[:, g:g + 1], scale, bias_scr[...])
+            if has_map:
+                p, ds = _select_p_ds(q, k, v, do, lse[:, g:g + 1],
+                                     delta[:, g:g + 1], scale, bias_scr[...])
+            else:
+                p, ds = _p_ds(q, k, v, do, lse[:, g:g + 1],
+                              delta[:, g:g + 1], scale, ok)
             ds = ds.astype(k.dtype)
             dq_scr[g] += jax.lax.dot_general(
                 ds, k, (((1,), (0,)), ((), ())),
@@ -1262,6 +1316,14 @@ def _select_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dta_ref,
             dk_scr[rows, :] += jax.lax.dot_general(
                 ds, q, (((0,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
+
+    if has_map:
+        pl.when(_select_bias(sel_ref, bias_scr, qi, kj, block_q, block_k,
+                             causal, seq_len))(_heads)
+    else:
+        _masked_dispatch(
+            _heads, _live_block(qi, kj, block_q, block_k, causal, seq_len),
+            qi, kj, block_q, block_k, causal, seq_len)
 
     @pl.when(kj == nk - 1)
     def _finalize():
@@ -1334,12 +1396,18 @@ def _select_bwd(q, k, v, select, o, lse, do, H, D, *, fused, scale, causal,
     """``(dq, dk, dv)`` on head-packed views, a KV group a grid step: from
     one kernel (:func:`_select_bwd_kernel`) where ``fused``, else from the
     pair (:func:`_select_dq_kernel`, :func:`_select_dkdv_kernel`); ``lse``
-    arrives as (B, H, T)."""
+    arrives as (B, H, T).  ``select`` is the map, or None for a call with
+    grouped KV heads and no map: the one kernel then, without the map's
+    operand and scratch (the pair without a map is the per-head one,
+    :func:`_bwd_pallas_packed`)."""
     B, T, _ = q.shape
     Hkv = k.shape[2] // D
     G = H // Hkv
     nq = T // block_q
     nk = T // block_k
+    has_map = select is not None
+    maps = (select,) if has_map else ()
+    like = (q, k, v, do, *maps)
     # The row statistics a KV group a block: (B, H_kv, T, G).
     delta = jnp.sum((do.astype(jnp.float32) * o.astype(jnp.float32)
                      ).reshape(B, T, Hkv, G, D), axis=-1).transpose(0, 2, 1, 3)
@@ -1347,10 +1415,10 @@ def _select_bwd(q, k, v, select, o, lse, do, H, D, *, fused, scale, causal,
     live_k = _select_live_k(causal, block_q, block_k)
     kernel_kw = dict(scale=scale, causal=causal, block_q=block_q,
                      block_k=block_k, seq_len=seq_len, group=G, head_dim=D)
-    dq_shape = _pallas.struct((B, T, H * D), q.dtype, q, k, v, do, select)
+    dq_shape = _pallas.struct((B, T, H * D), q.dtype, *like)
     dkdv_shapes = [
-        _pallas.struct((B, T, Hkv * D), k.dtype, q, k, v, do, select),
-        _pallas.struct((B, T, Hkv * D), v.dtype, q, k, v, do, select)]
+        _pallas.struct((B, T, Hkv * D), k.dtype, *like),
+        _pallas.struct((B, T, Hkv * D), v.dtype, *like)]
     bias_scr = pltpu.VMEM((block_q, block_k), jnp.float32)
     dq_scr = pltpu.VMEM((G, block_q, D), jnp.float32)
 
@@ -1367,22 +1435,23 @@ def _select_bwd(q, k, v, select, o, lse, do, H, D, *, fused, scale, causal,
         # The KV head's dK and dV: one block a head, written when the head's
         # sweep ends.
         head = pl.BlockSpec((1, T, D), lambda b, h, i, j: (b, 0, h))
+        resident = [dq_scr, pltpu.VMEM((T, D), jnp.float32),
+                    pltpu.VMEM((T, D), jnp.float32)]
         return tuple(pl.pallas_call(
-            functools.partial(_select_bwd_kernel, **kernel_kw),
+            functools.partial(_select_bwd_kernel, **kernel_kw,
+                              has_map=has_map),
             grid=(B, Hkv, nq, nk),
-            in_specs=q_in_specs,
+            in_specs=q_in_specs if has_map else q_in_specs[:-1],
             out_specs=[q_q, head, head],
             out_shape=[dq_shape, *dkdv_shapes],
-            scratch_shapes=[bias_scr, dq_scr,
-                            pltpu.VMEM((T, D), jnp.float32),
-                            pltpu.VMEM((T, D), jnp.float32)],
+            scratch_shapes=[bias_scr, *resident] if has_map else resident,
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary",
                                      "arbitrary"),
                 **_pallas.vmem_limit(vmem_mb)),
             interpret=interpret,
-            name="flash_select_bwd",
-        )(q, k, v, do, lse, delta, select))
+            name="flash_select_bwd" if has_map else "flash_group_bwd",
+        )(q, k, v, do, lse, delta, *maps))
 
     def live_q(i, j):
         return jnp.maximum(i, (j * block_k) // block_q) if causal else i
@@ -1434,7 +1503,8 @@ class _Plan(NamedTuple):
     bwd_vmem_mb: int
     bwd_sub: int        # sub-tile of the diagonal block pairs, else 0
     bwd_live_share: float   # of the scores the backward computes
-    # The group form's own (block_q, block_k, bwd_block_q, bwd_block_k).
+    # (block_q, block_k, bwd_block_q, bwd_block_k) where a direction runs
+    # in the group form: the group form's own blocks for it.
     blocks: tuple = ()
 
 
@@ -1490,7 +1560,16 @@ def _plan(*, T, D, H, head_base, itemsize, causal, block_q, block_k,
     ``select``: whether the call carries a selection map — then the group
     form each way under the blocks of ``_Plan.blocks``: the backward as one
     kernel (``"group_fused"``) where a KV head's two gradients may stay in
-    VMEM, else as the dq / dk-dv pair (``"group"``)."""
+    VMEM, else as the dq / dk-dv pair (``"group"``).
+
+    The backward of a call without a map: at lane-aligned heads and
+    ``kv_rep > 1`` the same one kernel a KV group (``"group_fused"``, the
+    backward's blocks in ``_Plan.blocks[2:]``) under the same rule — the
+    device backs the budget and ``2 · T · D · 4`` bytes fit
+    ``_FUSED_RESIDENT_BYTES`` —, else the per-head pair; at ``kv_rep`` 1
+    the pair blocked over two heads at its proven shape, else the per-head
+    pair; heads off the lane width (merged into the batch, ``kv_rep`` 1 by
+    then) the per-head pair."""
     if select:
         # A path of its own (lane-aligned heads only, flash_attention sees
         # to that): a KV group a grid step, forward and backward.
@@ -1529,6 +1608,20 @@ def _plan(*, T, D, H, head_base, itemsize, causal, block_q, block_k,
     else:
         fwd = ("grid", 0, 0)
 
+    if (kv_rep > 1 and vmem_headroom
+            and 2 * T * D * 4 <= _FUSED_RESIDENT_BYTES):
+        # Grouped KV heads: the backward as ONE kernel a KV group, the
+        # selected attention's (PR 39) without its map — S and dP formed
+        # once, K and V fetched once for the group's heads — under the
+        # same rule and budget, at the tile its two bodies allow.  Alone
+        # on a v5e (PR 44, ms a layer, against the per-head pair): 8.46 /
+        # 13.73 at 8Q / 2KV and T 16,384, 18.46 / 32.90 at two sequences
+        # of 32Q / 2KV and T 8,192.
+        blocks = (block_q, block_k, *_group_bwd_blocks_no_map(
+            bwd_block_q, bwd_block_k, kv_rep))
+        return _Plan(*fwd, "group_fused", _SELECT_FUSED_VMEM_MB, 0,
+                     _bwd_live_share(T, causal, blocks[2], blocks[3], sub=0),
+                     blocks)
     # Tiles spanning two adjacent heads make the HBM rows twice as wide
     # as the per-head pair's 256-byte strided reads: 11.97 vs 12.18
     # ms/layer-iter on v5e at exactly the proven shape (both blocks 1024,
@@ -1594,6 +1687,10 @@ def _flash_packed_bwd(H, scale, causal, block_q, block_k, bwd_block_q,
     kv_rep = q.shape[2] // k.shape[2]
     plan = _plan_for(q, H, D, (0, 0, 0), causal, block_q, block_k,
                      bwd_block_q, bwd_block_k, interpret, kv_rep)
+    if plan.bwd == "group_fused":
+        return _select_bwd_call(q, k, v, None, o, lse, do, H, D, scale,
+                                causal, block_q, block_k, bwd_block_q,
+                                bwd_block_k, interpret, seq_len)
     return _bwd_pallas_packed(q, k, v, o, lse, do, H, D, plan, scale=scale,
                               causal=causal, block_q=bwd_block_q,
                               block_k=bwd_block_k, interpret=interpret,
@@ -1647,9 +1744,10 @@ _flash_packed_select.defvjp(_flash_packed_select_fwd,
 # traced three times: 42 kernel traces, 2.0 of ``step.lower``'s 4.3 s in
 # the sandbox, now 6 and 0.6 of 3.0); ``inline`` leaves no call behind, so
 # the lowered step is the one without it.  The rules of the split q, k, v
-# entry call the drivers as they are: its one cell has a single layer, and
-# there a kernel traced inside the jit's own trace cost 9 s of set-up on
-# the chip's host (PERF.md §6, PR 29).
+# entry call the drivers as they are but for the fused grouped-KV backward
+# (``zaya1_1chip``'s six layers share its trace): the entry's first cell
+# had a single layer, and there a kernel traced inside the jit's own trace
+# cost 9 s of set-up on the chip's host (PERF.md §6, PR 29).
 _one_trace_a_shape = functools.partial(
     jax.jit, inline=True,
     static_argnames=("H", "D", "scale", "causal", "block_q", "block_k",
@@ -1686,11 +1784,13 @@ def _qkv_bwd(qkv, o, lse, do, H, D, scale, causal, block_q, block_k,
 
 
 def _select_plan_for(q, k, H, D, causal, block_q, block_k, bwd_block_q,
-                     bwd_block_k, interpret) -> _Plan:
-    """:func:`_plan_for` under a selection map, on packed ``q`` and ``k``."""
+                     bwd_block_k, interpret, select=True) -> _Plan:
+    """:func:`_plan_for` on packed ``q`` and ``k`` with their own count of
+    query heads a KV head — under a selection map, or (``select`` False)
+    for grouped KV heads alone."""
     return _plan_for(q, H, D, (0, 0, 0), causal, block_q, block_k,
                      bwd_block_q, bwd_block_k, interpret,
-                     kv_rep=q.shape[2] // k.shape[2], select=True)
+                     kv_rep=q.shape[2] // k.shape[2], select=select)
 
 
 @_one_trace_a_shape
@@ -1710,10 +1810,14 @@ def _select_fwd_call(q, k, v, select, H, D, scale, causal, block_q, block_k,
 def _select_bwd_call(q, k, v, select, o, lse, do, H, D, scale, causal,
                      block_q, block_k, bwd_block_q, bwd_block_k, interpret,
                      seq_len):
-    """(dq, dk, dv) under a selection map, as :func:`_plan` has it."""
+    """(dq, dk, dv) of a KV group a grid step, as :func:`_plan` has it:
+    under a selection map, or (``select`` None) the one kernel of a call
+    with grouped KV heads and no map."""
+    has_map = select is not None
     plan = _select_plan_for(q, k, H, D, causal, block_q, block_k,
-                            bwd_block_q, bwd_block_k, interpret)
-    with jax.named_scope("flash_select"):
+                            bwd_block_q, bwd_block_k, interpret, has_map)
+    with (jax.named_scope("flash_select") if has_map
+          else contextlib.nullcontext()):
         return _select_bwd(q, k, v, select, o, lse, do, H, D,
                            fused=plan.bwd == "group_fused", scale=scale,
                            causal=causal, block_q=plan.blocks[2],
@@ -1989,14 +2093,17 @@ def flash_attention(q, k, v, *, causal: bool = True,
     ``v`` may hold fewer heads, ``(B, T, Hkv, D)`` with ``Hkv`` dividing
     ``H`` (grouped-query attention: query head ``h`` reads KV head
     ``h // (H / Hkv)``); at lane-aligned ``D`` they are read in place, and
-    ``dk``, ``dv`` come back summed over the query heads of a group.
+    ``dk``, ``dv`` come back summed over the query heads of a group — by
+    one backward kernel a KV group where a KV head's ``dk`` and ``dv`` fit
+    VMEM (``T`` to 16,384 at ``D`` 128 on a device that backs the budget),
+    by the per-head pair elsewhere (:func:`_plan`).
 
     Block sizes default to :func:`auto_block` (the largest multiple-of-8
     divisor of ``T`` up to 1024 — the largest square block whose f32
     scores tile fits v5e's 16 MB scoped VMEM); explicit blocks must
     divide ``T`` and be multiples of 8 (Mosaic's sublane constraint).
     Differentiable via the flash-backward identities as VMEM-resident
-    blockwise Pallas kernels; which forward form and which backward pair
+    blockwise Pallas kernels; which forward form and which backward form
     run follows the shapes (:func:`_plan`) and is not an option.
     ``seq_len``: real length when the inputs are zero-padded to a
     tileable ``T`` — positions past it are masked statically in forward

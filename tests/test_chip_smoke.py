@@ -178,6 +178,43 @@ def test_select_backward_phase(smoke):
         16_384, 32, 4, 128, 16, 64, 2048)
 
 
+def test_grouped_backward_phase(smoke):
+    """The flash kernels of a call with grouped KV heads and no map, alone
+    (interpreted here: the fused kernel and the per-head pair agree, and no
+    time is reported), with ``gqa_plan`` — the form, budget, blocks and
+    live share ``flash_attention._plan`` gives the call; on the chip the
+    phase runs at the two cells' own attention shapes, where the plan is
+    the fused kernel under 64 MB at 512 x 1024 and 256 x 512."""
+    from horovod_tpu.ops import flash_attention as fa
+
+    out = smoke.grouped_backward_phase(batch=1, seq=256, heads=8, kv_heads=2,
+                                       head_dim=128, seed=0)
+    assert out["interpret"] and out["shape"] == [1, 256, 8, 2, 128]
+    assert out["ms_a_layer"] == dict.fromkeys(("forward", "pair", "fused"))
+    assert out["gqa_plan"] == {
+        "fwd": "fullunroll", "fwd_tile": 256, "fwd_vmem_mb": 0,
+        "bwd": "group_fused", "bwd_vmem_mb": 64, "bwd_sub": 0,
+        "bwd_live_share": 0.502, "blocks": (256,) * 4}
+    assert set(out["fused_vs_pair"]) == {"dq", "dk", "dv"}
+    assert max(out["fused_vs_pair"].values()) <= 1e-2
+    cells = smoke.GROUPED_BACKWARD
+    assert {name: tuple(c.values()) for name, c in cells.items()} == {
+        "zaya1_1chip": (1, 16_384, 8, 2, 128),
+        "twotower_1chip": (2, 8192, 32, 2, 128)}
+    for name, bwd_blocks, live in (("zaya1_1chip", (512, 1024), 0.941),
+                                   ("twotower_1chip", (256, 512), 0.941)):
+        c = cells[name]
+        plan = fa._plan(T=c["seq"], D=c["head_dim"], H=c["heads"],
+                        head_base=(0, 0, 0), itemsize=2, causal=True,
+                        block_q=1024, block_k=1024, bwd_block_q=1024,
+                        bwd_block_k=1024, interpret=False, manual_axes=False,
+                        vmem_headroom=True,
+                        kv_rep=c["heads"] // c["kv_heads"])
+        assert (plan.fwd, plan.bwd, plan.bwd_vmem_mb, plan.blocks[2:],
+                plan.bwd_live_share) == ("grid", "group_fused", 64,
+                                         bwd_blocks, live)
+
+
 def test_delta_reference_phase(smoke):
     """The chunked delta rule against its recurrence, and the
     ``delta_plan`` line: one form, plain XLA, at the chunk it was given."""

@@ -470,6 +470,115 @@ class TestHeadGroupBwd:
                                        rtol=2e-4, atol=2e-4)
 
 
+class TestGroupedKvFusedBackward:
+    """A call with grouped KV heads at lane-aligned heads and no map: the
+    backward is ONE kernel a KV group (``flash_group_bwd``: the selected
+    attention's fused kernel without its map) wherever ``_plan`` can see
+    that a KV head's ``dK`` and ``dV`` fit VMEM; the per-head pair
+    elsewhere.  Both against ``full_attention``'s gradients, and against
+    each other."""
+
+    B, T, D, BLOCK = 1, 64, 128, 16
+
+    def _problem(self, kv_rep, hkv=2):
+        ks = jax.random.split(jax.random.PRNGKey(61 + kv_rep), 3)
+        return tuple(
+            jax.random.normal(key, (self.B, self.T, h, self.D))
+            for key, h in zip(ks, (hkv * kv_rep, hkv, hkv)))
+
+    def _grads(self, q, k, v, causal, seq_len):
+        def loss(q, k, v):
+            out = flash_attention(q, k, v, causal=causal, block_q=self.BLOCK,
+                                  block_k=self.BLOCK, interpret=True,
+                                  seq_len=seq_len)
+            return (out[:, :seq_len] ** 2).sum()
+
+        return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    @staticmethod
+    def _equations(jaxpr):
+        """Every equation of a jaxpr, nested jaxprs included."""
+        for eqn in jaxpr.eqns:
+            yield eqn
+            for value in eqn.params.values():
+                for v in value if isinstance(value, (list, tuple)) else [
+                        value]:
+                    v = getattr(v, "jaxpr", v)
+                    if hasattr(v, "eqns"):
+                        yield from TestGroupedKvFusedBackward._equations(v)
+
+    def _kernels(self, jaxpr):
+        """``{name: kernel jaxpr}`` of every ``pallas_call`` of a jaxpr."""
+        return {eqn.params["name"]
+                or eqn.params["jaxpr"].debug_info.func_name:
+                eqn.params["jaxpr"] for eqn in self._equations(jaxpr)
+                if eqn.primitive.name == "pallas_call"}
+
+    def _backward_kernels(self, q, k, v, block=16):
+        """The names of the backward's kernels (the forward's left out)."""
+        jaxpr = jax.make_jaxpr(jax.grad(lambda q, k, v: flash_attention(
+            q, k, v, block_q=block, block_k=block, interpret=True).astype(
+                jnp.float32).sum(), argnums=(0, 1, 2)))(q, k, v)
+        return {name: body for name, body in self._kernels(
+            jaxpr.jaxpr).items() if "fwd" not in name}
+
+    @pytest.mark.parametrize("seq_len", [None, 40], ids=["whole", "padded"])
+    @pytest.mark.parametrize("causal", [True, False],
+                             ids=["causal", "non_causal"])
+    @pytest.mark.parametrize("kv_rep,hkv", [(2, 2), (4, 2), (16, 1)],
+                             ids=["2Q_per_KV", "4Q_per_KV", "16Q_per_KV"])
+    def test_fused_matches_oracle(self, hvd, kv_rep, hkv, causal, seq_len):
+        q, k, v = self._problem(kv_rep, hkv)
+        n = seq_len or self.T
+
+        def loss_full(q, k, v):
+            k, v = (jnp.repeat(a[:, :n], kv_rep, axis=2) for a in (k, v))
+            return (full_attention(q[:, :n], k, v, causal=causal) ** 2).sum()
+
+        assert set(self._backward_kernels(q, k, v)) == {"flash_group_bwd"}
+        got = self._grads(q, k, v, causal, seq_len)
+        want = jax.grad(loss_full, argnums=(0, 1, 2))(q, k, v)
+        assert got[1].shape == got[2].shape == (self.B, self.T, hkv, self.D)
+        for g, w in zip(got, want):
+            np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                       rtol=2e-4, atol=2e-4)
+
+    @pytest.mark.parametrize("seq_len", [None, 40], ids=["whole", "padded"])
+    @pytest.mark.parametrize("kv_rep,hkv", [(2, 2), (4, 2), (16, 1)],
+                             ids=["2Q_per_KV", "4Q_per_KV", "16Q_per_KV"])
+    def test_per_head_pair_agrees(self, hvd, monkeypatch, kv_rep, hkv,
+                                  seq_len):
+        """The same call where the device backs no budget above Mosaic's
+        default: the per-head pair, to float32 reassociation."""
+        from horovod_tpu.ops import _pallas
+
+        q, k, v = self._problem(kv_rep, hkv)
+        fused = self._grads(q, k, v, True, seq_len)
+        # (Every family's probe at once; the plan is asked outside any
+        # shared trace, so no cache holds the fused form.)
+        monkeypatch.setattr(_pallas, "vmem_headroom_ok", lambda: False)
+        assert set(self._backward_kernels(q, k, v)) == {"_dq_kernel",
+                                                        "_dkdv_kernel"}
+        pair = self._grads(q, k, v, True, seq_len)
+        for g, w in zip(fused, pair):
+            np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                       rtol=2e-5, atol=2e-5)
+
+    def test_bf16_operands_f32_sums(self, hvd):
+        """The pair's precision: bfloat16 operands into every product,
+        float32 results, ``p`` and ``dS`` cast once a head."""
+        q, k, v = (a.astype(jnp.bfloat16) for a in self._problem(4))
+        kernel = self._backward_kernels(q, k, v, block=64)["flash_group_bwd"]
+        products = [eqn for eqn in self._equations(kernel)
+                    if eqn.primitive.name == "dot_general"]
+        # A masked and an unmasked body, each five products for each of the
+        # four heads of a group (the pair's two kernels form seven).
+        assert len(products) == 2 * 5 * 4
+        for eqn in products:
+            assert all(v_.aval.dtype == jnp.bfloat16 for v_ in eqn.invars)
+            assert eqn.outvars[0].aval.dtype == jnp.float32
+
+
 # The grouped pair with its diagonal block pairs cut into sub-tiles
 # (block, requested sub-tile, T, seq_len): 2, 4 and 8 sub-tiles a block
 # side, one and several blocks a row, and the padding's end inside a
@@ -634,14 +743,50 @@ PLAN_TABLE = {
     # Past a 1 MB K/V row (T 4096 at D 128 bf16) only the grid streams.
     "T8192": (observed(8192), (GRID, 0, 0, "grouped", 32, 256, 0.97)),
     "T32768": (observed(32768), (GRID, 0, 0, "grouped", 32, 256, 0.992)),
-    # Grouped KV heads (twotower_1chip: 32 query heads over 2): two query
-    # heads of a tile would read the same KV head, which only the per-head
-    # pair's index maps do; at T 8192 its whole blocks waste a ninth.
+    # Grouped KV heads (PR 44): the backward as ONE kernel a KV group under
+    # 64 MB where the KV head's dK and dV — 2 x T x D float32 — fit 16 MiB,
+    # a step's heads holding at most 2 Mi score elements between them (the
+    # longer side of a head's tile halved from the group form's 4,096 query
+    # rows by 1024 keys).  twotower_1chip (32 query heads over 2: 256 x 512,
+    # so the diagonal wastes a seventeenth where the pair's whole 1024²
+    # blocks wasted a ninth) and zaya1_1chip (8 over 2 at T 16,384).
     "cell_T8192_16Q_per_KV": (
         observed(8192, H=32, base=(0, 0, 0), kv_rep=16),
-        (GRID, 0, 0, "per_head", 0, 0, 0.889)),
+        (GRID, 0, 0, "group_fused", 64, 0, 0.941, (1024, 1024, 256, 512))),
+    "cell_T16384_4Q_per_KV": (
+        observed(16384, H=8, base=(0, 0, 0), kv_rep=4),
+        (GRID, 0, 0, "group_fused", 64, 0, 0.941, (1024, 1024, 512, 1024))),
+    "T16384_8Q_per_KV": (
+        observed(16384, H=32, base=(0, 0, 0), kv_rep=8),
+        (GRID, 0, 0, "group_fused", 64, 0, 0.97, (1024, 1024, 512, 512))),
     "T2048_2Q_per_KV": (observed(2048, base=(0, 0, 0), kv_rep=2),
-                        (FULL, 512, 0, "per_head", 0, 0, 0.667)),
+                        (FULL, 512, 0, "group_fused", 64, 0, 0.667,
+                         (1024,) * 4)),
+    # ... and the per-head pair where it cannot see that: no budget above
+    # Mosaic's default, a longer sequence or wider heads (32 MiB each);
+    # heads off the lane width never get here grouped (flash_attention
+    # repeats K and V), and would not change.  (Interpreted under
+    # shard_map the fused kernel runs like anywhere.)
+    "T16384_4Q_per_KV_no_headroom": (
+        observed(16384, H=8, base=(0, 0, 0), kv_rep=4, vmem_headroom=False),
+        (GRID, 0, 0, "per_head", 0, 0, 0.941)),
+    "T32768_4Q_per_KV": (observed(32768, H=8, base=(0, 0, 0), kv_rep=4),
+                         (GRID, 0, 0, "per_head", 0, 0, 0.97)),
+    "T16384_D256_4Q_per_KV": (
+        observed(16384, D=256, H=8, base=(0, 0, 0), kv_rep=4),
+        (GRID, 0, 0, "per_head", 0, 0, 0.941)),
+    "T8192_D256_4Q_per_KV": (
+        observed(8192, D=256, H=8, base=(0, 0, 0), kv_rep=4),
+        (GRID, 0, 0, "group_fused", 64, 0, 0.889, (1024, 1024, 512, 1024))),
+    "interpret_shard_map_2Q_per_KV": (
+        observed(64, H=2, itemsize=4, blocks=16, interpret=True,
+                 manual_axes=True, base=(0, 0, 0), kv_rep=2),
+        (KV, 0, 0, "group_fused", 64, 0, 0.812, (16,) * 4)),
+    "D64_4Q_per_KV": (observed(2048, D=64, H=1, base=(0, 0, 0), kv_rep=4),
+                      (GRID, 0, 0, "per_head", 0, 0, 0.667)),
+    # One query head a KV head: the grouped pair with its cut diagonals.
+    "T16384_1Q_per_KV": (observed(16384, H=8, base=(0, 0, 0)),
+                         (GRID, 0, 0, "grouped", 32, 256, 0.985)),
     "T4096_f32": (observed(4096, itemsize=4),
                   (GRID, 0, 0, "grouped", 32, 256, 0.941)),
     # Heads off the lane width, merged into the batch: rows of one head.

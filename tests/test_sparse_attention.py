@@ -275,12 +275,18 @@ def test_a_selection_takes_a_kv_group_a_grid_step():
                 vmem_headroom=True)
     assert fa._plan(**seen) == ("fullunroll", 512, 0, "grouped", 32, 256,
                                 0.889, ())
-    assert fa._plan(**seen, kv_rep=8)[:4] == ("fullunroll", 512, 0,
-                                              "per_head")
+    # (Grouped KV heads without a map: since PR 44 the fused kernel too,
+    # in its own tiling — a step's heads hold 2 Mi score elements, not 4.)
+    assert fa._plan(**seen, kv_rep=8) == (
+        "fullunroll", 512, 0, "group_fused", 64, 0, 0.8,
+        (1024, 1024, 512, 512))
     assert fa._plan(**seen, select=True) == (
         "group", 0, 32, "group_fused", 64, 0, 0.667, (1024,) * 4)
     keye = dict(seen, T=16_384, H=32, kv_rep=8)
-    assert fa._plan(**keye)[:6] == ("grid", 0, 0, "per_head", 0, 0)
+    assert fa._plan(**keye) == ("grid", 0, 0, "group_fused", 64, 0, 0.97,
+                                (1024, 1024, 512, 512))
+    assert fa._plan(**dict(keye, vmem_headroom=False))[:6] == (
+        "grid", 0, 0, "per_head", 0, 0)
     # The backward is ONE kernel under 64 MB where a KV head's dK and dV,
     # 2 x T x D float32, fit 16 MiB and the device backs the budget — the
     # cell's T 16,384 at heads of 128 is the longest that does —, and the

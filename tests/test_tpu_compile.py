@@ -119,7 +119,8 @@ HEAD_KERNEL_EQUATIONS = {"_dkdv_kernel_grouped": 176,
 
 
 @pytest.mark.parametrize("family", ["flash", "scan", "passes", "experts",
-                                    "selected", "selected_pair"])
+                                    "selected", "selected_pair",
+                                    "grouped_kv"])
 def test_each_kernel_is_traced_once_a_step_not_once_a_layer(monkeypatch,
                                                             family):
     """The set-up guard, no chip and no compile: tracing ``jax.grad`` of a
@@ -168,7 +169,14 @@ def test_each_kernel_is_traced_once_a_step_not_once_a_layer(monkeypatch,
     heads each — is traced once, and every layer leaves its two kernels;
     ``selected_pair``: the same where the plan takes the dq / dk-dv pair
     (a budget of 0 for the resident gradients): three bodies, once each,
-    three kernels a layer."""
+    three kernels a layer.
+
+    ``grouped_kv``: three attention layers of 4 query heads over 2 KV
+    heads of 128 and no map (``zaya1_1chip`` has six such, PR 44).  The
+    backward goes through ``_select_bwd_call`` with no map: the fused
+    body — two unrolled heads, masked and unmasked — is traced once and
+    every layer leaves its one kernel; the forward rule calls its driver
+    bare, so its body is traced once a layer."""
     import collections
     import functools
 
@@ -209,7 +217,7 @@ def test_each_kernel_is_traced_once_a_step_not_once_a_layer(monkeypatch,
     # No other test's, nor another case's: a trace made earlier would be
     # shared.
     batch = {"flash": 3, "scan": 3, "passes": 5, "experts": 2,
-             "selected": 1, "selected_pair": 1}[family]
+             "selected": 1, "selected_pair": 1, "grouped_kv": 1}[family]
     if family.startswith("selected"):
         if family == "selected_pair":
             monkeypatch.setattr(fa, "_FUSED_RESIDENT_BYTES", 0)
@@ -224,6 +232,12 @@ def test_each_kernel_is_traced_once_a_step_not_once_a_layer(monkeypatch,
                 "selected_pair": {"_select_fwd_kernel": 1,
                                   "_select_dq_kernel": 1,
                                   "_select_dkdv_kernel": 1}}[family]
+    elif family == "grouped_kv":
+        seq = 256
+        model = NemotronHLM(vocab=512, dim=256, num_heads=4, kv_heads=2,
+                            pattern="***", max_len=seq, attn="flash",
+                            dtype=jnp.bfloat16)
+        want = {"_fwd_kernel_fullunroll": 3, "_select_bwd_kernel": 1}
     elif family == "flash":
         seq = T
         model = TransformerLM(vocab=512, dim=H * D, depth=3, num_heads=H,
@@ -250,7 +264,7 @@ def test_each_kernel_is_traced_once_a_step_not_once_a_layer(monkeypatch,
     params = jax.eval_shape(
         lambda key: model.init(key, jnp.zeros((1, seq), jnp.int32))["params"],
         jax.random.PRNGKey(0))
-    if family.startswith("selected"):
+    if family.startswith("selected") or family == "grouped_kv":
         # ``init`` ran the forward with the step's own shapes, and the
         # forward rule would share that trace.
         jax.clear_caches()
@@ -283,6 +297,10 @@ def test_each_kernel_is_traced_once_a_step_not_once_a_layer(monkeypatch,
 
     found = list(kernels(jaxpr.jaxpr))
     sizes = dict(found)
+    if family == "grouped_kv":
+        assert collections.Counter(name for name, _ in found) == {
+            "_fwd_kernel_fullunroll": 3, "flash_group_bwd": 3}
+        return
     if family.startswith("selected"):
         names = collections.Counter(name for name, _ in found)
         assert {n: c for n, c in names.items() if "select" in n} == {
@@ -466,17 +484,36 @@ def test_dropless_expert_layer_fwd_bwd_at_olmoe_widths(v5e, monkeypatch):
 # (the twotower_1chip cell: 2 sequences of 8,192, Nemotron-H's widths)
 
 
-def test_grouped_kv_flash_fwd_bwd_at_nemotron_widths(v5e, monkeypatch):
-    """32 query heads over 2 KV heads of 128 at T 8192: a K/V row is 2 MB,
-    so the grid forward, and the per-head pair, whose dk/dv kernel's
-    innermost axis runs the 16 query heads of a KV head one after another
-    (the pair grouped over two heads would read two KV heads side by
-    side).  dk and dv come back at the KV heads' width."""
+# The scoped VMEM the compiler counts for the fused backward without a map
+# at the two cells' shapes, under the blocks the plan gives them (MB, found
+# by bisection on the limit in the sandbox, PR 44): zaya1_1chip's 4 heads a
+# group at 512 x 1024 and T 16,384 between 40 and 44 (48–50 at the 1024 x
+# 1024 the map's form would take), twotower_1chip's 16 at 256 x 512 and
+# T 8,192 between 24 and 28.
+GROUP_BWD_COUNTED_MB = 44
+
+
+@pytest.mark.parametrize("b,t,h,blocks", [
+    (2, 8192, 32, (256, 512)), (1, 16_384, 8, (512, 1024))],
+    ids=["twotower_1chip", "zaya1_1chip"])
+def test_grouped_kv_flash_fwd_bwd_at_nemotron_widths(v5e, monkeypatch, b, t,
+                                                     h, blocks):
+    """32 query heads over 2 KV heads of 128 at T 8192 (``twotower_1chip``)
+    and 8 over 2 at T 16,384 (``zaya1_1chip``): a K/V row is 2 MB or more,
+    so the grid forward; and since PR 44 the backward as ONE kernel a KV
+    group (``flash_group_bwd``; the per-head pair before, whose dk/dv
+    kernel ran the query heads of a KV head one after another), under the
+    plan's blocks and 64 MB of scoped VMEM — of which the compiler counts
+    at most 44, so it compiles under that.  dk and dv come back at the KV
+    heads' width."""
     from horovod_tpu.ops import flash_attention as fa
 
+    assert fa._SELECT_FUSED_VMEM_MB >= GROUP_BWD_COUNTED_MB + 8
+    monkeypatch.setattr(fa, "_SELECT_FUSED_VMEM_MB", GROUP_BWD_COUNTED_MB)
+    jax.clear_caches()
     one = SingleDeviceSharding(v5e[0])
-    q = jax.ShapeDtypeStruct((2, 8192, 32, 128), jnp.bfloat16, sharding=one)
-    kv = jax.ShapeDtypeStruct((2, 8192, 2, 128), jnp.bfloat16, sharding=one)
+    q = jax.ShapeDtypeStruct((b, t, h, 128), jnp.bfloat16, sharding=one)
+    kv = jax.ShapeDtypeStruct((b, t, 2, 128), jnp.bfloat16, sharding=one)
     plans = []
     plan = fa._plan
     monkeypatch.setattr(
@@ -486,14 +523,19 @@ def test_grouped_kv_flash_fwd_bwd_at_nemotron_widths(v5e, monkeypatch):
         return fa.flash_attention(q, k, v, causal=True).astype(
             jnp.float32).sum()
 
-    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
-        q, kv, kv).compile()
-    assert compiled.as_text().count("tpu_custom_call") >= 3
-    assert {(p.fwd, p.bwd, p.bwd_sub) for p in plans} == {
-        ("grid", "per_head", 0)}
+    lowered = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
+        q, kv, kv)
+    assert custom_calls(lowered.as_text()) == [
+        ("_fwd_kernel", 3), ("flash_group_bwd", 6)]
+    assert scoped_vmem_mb(lowered.as_text()) == {
+        "_fwd_kernel": 0, "flash_group_bwd": GROUP_BWD_COUNTED_MB}
+    assert {(p.fwd, p.bwd, p.bwd_sub, p.blocks[2:]) for p in plans} == {
+        ("grid", "group_fused", 0, blocks)}
+    compiled = lowered.compile()
     _, (dq, dk, dv) = compiled.out_info
-    assert dq.shape == (2, 8192, 32, 128)
-    assert dk.shape == dv.shape == (2, 8192, 2, 128)
+    assert dq.shape == (b, t, h, 128)
+    assert dk.shape == dv.shape == (b, t, 2, 128)
+    jax.clear_caches()      # the traces do not key on the budget
 
 
 def test_chunked_scan_fwd_bwd_at_nemotron_widths(v5e):
@@ -1130,7 +1172,7 @@ def test_the_kl_pass_compiles_at_every_tiling(v5e, monkeypatch, tiling,
                                 "_fwd_kernel_fullunroll"]),
     ("split", 1, 8192, 30, 30, ["_dkdv_kernel_grouped", "_dq_kernel_grouped",
                                 "_fwd_kernel"]),
-    ("split", 2, 8192, 32, 2, ["_dkdv_kernel", "_dq_kernel", "_fwd_kernel"])],
+    ("split", 2, 8192, 32, 2, ["_fwd_kernel", "flash_group_bwd"])],
     ids=["gpt", "olmoe", "olmo_hybrid", "nemotron_grouped_kv"])
 def test_a_call_without_a_selection_lowers_as_it_did(v5e, entry, b, t, h,
                                                      hkv, kernels):
@@ -1138,7 +1180,9 @@ def test_a_call_without_a_selection_lowers_as_it_did(v5e, entry, b, t, h,
     kernel to the operands, that the parent of PR 37 lowered them to (the
     literals are its): q, k, v forward; q, k, v, dO and the two row
     statistics backward.  A plain kernel that still carried a map would
-    read one more."""
+    read one more.  The three with one query head a KV head stay byte for
+    byte; the grouped-KV call's backward is one kernel since PR 44
+    (``flash_group_bwd``: the pair's six operands, once)."""
     from horovod_tpu.ops import flash_attention as fa
 
     one = SingleDeviceSharding(v5e[0])
@@ -1158,7 +1202,8 @@ def test_a_call_without_a_selection_lowers_as_it_did(v5e, entry, b, t, h,
         shapes = (s(b, t, h, D), s(b, t, hkv, D), s(b, t, hkv, D))
     lowered = jax.jit(jax.grad(loss, argnums=range(len(shapes)))).lower(
         *shapes)
-    assert custom_calls(lowered.as_text()) == list(zip(kernels, (6, 6, 3)))
+    operands = (6, 6, 3) if len(kernels) == 3 else (3, 6)
+    assert custom_calls(lowered.as_text()) == list(zip(kernels, operands))
 
 
 # ---------------------------------------------- the ZAYA1 layer's parts
@@ -1205,8 +1250,10 @@ def test_a_zaya_layer_fwd_bwd_at_zaya_widths(v5e, monkeypatch):
     # Up, gate and down forward, their replay in the checkpoint, and the
     # input and weight gradients: one window, so each exactly once.
     assert found == {"moe_gmm": 6, "moe_gmm_nt": 3, "moe_tgmm": 3}, found
-    assert custom_calls(lowered.as_text())[:3] == [
-        ("_dkdv_kernel", 6), ("_dq_kernel", 6), ("_fwd_kernel", 3)]
+    # The grid forward and, since PR 44, the backward as one kernel a KV
+    # group (the per-head pair ``_dkdv_kernel``, ``_dq_kernel`` before).
+    assert custom_calls(lowered.as_text())[:2] == [
+        ("_fwd_kernel", 3), ("flash_group_bwd", 6)]
     compiled = lowered.compile()
     text = compiled.as_text()
     assert "ragged-dot" not in text
