@@ -87,6 +87,15 @@ PASSES_ONE_GROUP = dict(batch=1, seq=2048, heads=64, head_dim=64, groups=1,
 # One held layer's window of twotower_1chip: 18,432 sorted rows of width
 # 2688 against 8 experts 1856 wide (padded as the plan says).
 EXPERTS_REFERENCE = dict(rows=18432, groups=8, dim=2688, hidden=1856)
+# A layer's tokens and routing in the three cells that hold a share of their
+# experts: which way such a layer's rows move follows from these sizes alone.
+HELD_LAYERS = {
+    "zaya1_1chip": dict(tokens=16384, dim=2048, hidden=2048, num_experts=16,
+                        held=8, top_k=1, skip_choice=True),
+    "twotower_1chip": dict(tokens=16384, dim=2688, hidden=1856,
+                           num_experts=128, held=8, top_k=6),
+    "keye_1chip": dict(tokens=16384, dim=2048, hidden=768, num_experts=128,
+                       held=16, top_k=8)}
 # A sequence of 512: the float32 recurrence's backward keeps three (192, 96)
 # states a head a token, 4.3 GB there and 17.2 of the chip's 15.75 at 2048.
 DELTA_REFERENCE = dict(batch=1, seq=512, heads=30, key_dim=96,
@@ -511,6 +520,31 @@ def moe_plan(rows: int, groups: int, dim: int, hidden: int) -> dict:
         jax.ShapeDtypeStruct((rows, dim), jnp.bfloat16), groups,
         hidden + -hidden % 128,
         interpret=jax.default_backend() != "tpu")._asdict()
+
+
+def held_rows(*, tokens: int, dim: int, hidden: int, num_experts: int,
+              held: int, top_k: int, skip_choice: bool = False) -> dict:
+    """What a ``DroplessMoE(held=...)`` layer of these sizes notes of itself
+    while traced (shapes alone, nothing runs): its assignments, and how many
+    of them move to expert order and back as gathers through the sort's
+    permutation — all of them where the layer's window is every assignment,
+    none where a smaller window gathers its rows and scatter-adds them
+    home."""
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.layer_notes import noting_layers
+    from horovod_tpu.parallel.moe import DroplessMoE
+
+    layer = DroplessMoE(num_experts=num_experts, hidden=hidden, top_k=top_k,
+                        held=(0, held), skip_choice=skip_choice)
+    noted = {}
+    noting_layers(jax.eval_shape, noted)(
+        layer.init, jax.random.PRNGKey(0),
+        jax.ShapeDtypeStruct((tokens, dim), jnp.bfloat16))
+    counters, = noted.values()
+    return {name: counters[f"moe.{name}"] for name in (
+        "assignments", "held_assignments", "permuted_assignments")}
 
 
 def experts_reference_phase(*, rows: int, groups: int, dim: int, hidden: int,
@@ -1377,6 +1411,8 @@ def main(argv=None) -> int:
             **DELTA_REFERENCE, seed=args.seed))
         emit("experts_reference", **experts_reference_phase(
             **EXPERTS_REFERENCE, seed=args.seed))
+        emit("held_rows", **{cell: held_rows(**layer)
+                             for cell, layer in HELD_LAYERS.items()})
         emit("select_reference", **select_reference_phase(
             **SELECT_REFERENCE, seed=args.seed))
         emit("select_backward", **select_backward_phase(

@@ -609,6 +609,7 @@ class _StepInstruments:
     layers of the step's ``loss_fn`` noted of their static sizes while it
     was traced (:mod:`horovod_tpu.layer_notes`) — ``moe.assignments``,
     ``moe.expert_bytes``, ``moe.held_assignments``, ``moe.fused_matmuls``,
+    ``moe.permuted_assignments``,
     ``ssm.scan_chunks``, ``ssm.state_bytes``, ``ssm.fused_scans``,
     ``ssm.fused_passes``, ``ssm.head_tiles``, ``ssm.group_channels``,
     ``lin.delta_chunks``, ``lin.state_bytes``, ``attn.merged_heads``,

@@ -202,6 +202,12 @@ def _pad_hidden(a, axis: int, lanes: int):
 noting_expert_layers = noting_layers
 
 
+# The row moves of a layer whose sorted rows are ALL k·N assignments —
+# every expert here, or a held share whose window is every assignment —:
+# a gather each way, forward and backward, through the sort's ``order`` and
+# its ``inverse``.  A held share with a smaller window moves that window's
+# rows by gather and scatter-add (``_held_experts``): a gather through
+# ``inverse`` would move k·N rows to bring R of them home.
 @jax.custom_vjp
 def _to_expert_order(x, order, inverse):
     """Rows of ``x`` (N, d) as the k·N assignments sorted by expert:
@@ -302,7 +308,18 @@ class DroplessMoE(nn.Module):
       ``held_assignments``, the number that landed here.  Where ``3 ·
       top_k · count ≥`` the outputs routed over (top-1 with 8 of 17 held)
       ``R`` is EVERY assignment: one levelled window over all ``n · k``
-      rows, and no second one exists to overflow into.
+      rows, and no second one exists to overflow into.  How the rows move
+      follows from that shape alone.  A window smaller than ``n · k``
+      gathers its rows by ``x[token]`` and scatter-adds the weighted
+      results onto their tokens (autodiff scatter-adds the gather's
+      cotangent too): ``R`` rows each way, fewer than ``n · k``.  The
+      window that is every assignment sorts a whole permutation, so its
+      rows go to expert order and come back as gathers through it
+      (``_to_expert_order`` / ``_to_token_order``, as where every expert is
+      here) and nothing is scatter-added: the same rows, masks and
+      products.  Noted beside the rest: ``moe.permuted_assignments``,
+      ``n · k`` where the rows moved through the permutation, 0 where a
+      window of them moved by scatter-add.
     * ``router="mlp"``: the router is a small network that carries a state
       from one expert layer to the next, and the layer is called as
       ``layer(x, router_state)``.  ``r = W_d x + b_d`` (``router_hidden``
@@ -399,7 +416,7 @@ class DroplessMoE(nn.Module):
         if self.held is None and not self.skip_choice:
             out, tokens_per_expert, fused = self._all_experts(
                 x, gate, expert, names)
-            n_held = E
+            n_held, permuted = E, True
         else:
             # With a skip choice and no share named, every expert is held:
             # the skip choice is then the one output held nowhere.
@@ -407,7 +424,7 @@ class DroplessMoE(nn.Module):
             if not (0 <= first and n_held >= 1 and first + n_held <= E):
                 raise ValueError(f"held={self.held} is not a range of the "
                                  f"{E} experts")
-            out, tokens_per_expert, held_assignments, fused = (
+            out, tokens_per_expert, held_assignments, fused, permuted = (
                 self._held_experts(x, gate, expert, names, first, n_held))
             self.sow("intermediates", "held_assignments", held_assignments)
         if self.skip_choice:
@@ -431,6 +448,9 @@ class DroplessMoE(nn.Module):
         counters = {
             "moe.assignments": n * k,
             "moe.fused_matmuls": fused * len(names),
+            # Assignments whose rows moved as gathers through the sort's
+            # permutation; 0 where a window of them moved by scatter-add.
+            "moe.permuted_assignments": n * k * permuted,
             "moe.expert_bytes": (len(names) * n_held * d * self.hidden
                                  * jnp.dtype(self.param_dtype).itemsize)}
         if self.held is not None:
@@ -547,7 +567,14 @@ class DroplessMoE(nn.Module):
         w = self._weights(names, n_held, d)
         R = min(n * k, -(-_HELD_WINDOW * n * k * n_held // E // 8) * 8)
         windows = -(-n * k // R)
-        order = jnp.pad(order, (0, windows * R - n * k))
+        # One window is every assignment (``R == n k``): ``order`` is then a
+        # whole permutation, and rows move both ways as gathers through it.
+        permuted = windows == 1
+        if permuted:
+            with jax.named_scope("dispatch"):
+                inverse = jnp.argsort(order)
+        else:
+            order = jnp.pad(order, (0, windows * R - n * k))
         # The grouped matmuls' plan says what the hidden width is padded to.
         plan = grouped_plan(
             jax.ShapeDtypeStruct((R, d), self.dtype, vma=jax.typeof(x).vma),
@@ -558,11 +585,25 @@ class DroplessMoE(nn.Module):
             # Zeros that vary over the mesh axes the tokens vary over.
             return jnp.zeros_like(x, dtype=jnp.float32)
 
+        def experts(rows, here, sizes, w):
+            """The grouped matmuls over a window's ``rows``.  A row past
+            ``landed`` belongs to no expert here and is masked to nothing
+            on its way in and out."""
+            with jax.named_scope("experts"):
+                padded = {
+                    name: _pad_hidden(a.astype(self.dtype),
+                                      1 if name == "w_down" else 2,
+                                      plan.lanes)
+                    for name, a in w.items()}
+                h = jnp.where(here, self._hidden(rows, padded, sizes, plan),
+                              0)
+                return jnp.where(here, self._grouped(h, padded["w_down"],
+                                                     sizes, plan), 0)
+
         def window(i, x, gate, w, level: bool):
             """What the sorted assignments ``[i R, (i + 1) R)`` add to the
             output: the grouped matmuls over those rows, each expert's
-            group cut to the window.  A row past ``landed`` belongs to no
-            expert here and is masked to nothing on its way in and out."""
+            group cut to the window."""
             lo = i * R
             with jax.named_scope("dispatch"):
                 a = lax.dynamic_slice_in_dim(order, lo, R)
@@ -579,25 +620,34 @@ class DroplessMoE(nn.Module):
                     # over R rows, and a step's time does not follow where
                     # its router sends the tokens.
                     sizes = sizes.at[-1].add(R - sizes.sum())
-            with jax.named_scope("experts"):
-                padded = {
-                    name: _pad_hidden(a.astype(self.dtype),
-                                      1 if name == "w_down" else 2,
-                                      plan.lanes)
-                    for name, a in w.items()}
-                h = jnp.where(here, self._hidden(rows, padded, sizes, plan),
-                              0)
-                y = jnp.where(here, self._grouped(h, padded["w_down"], sizes,
-                                                  plan), 0)
+            y = experts(rows, here, sizes, w)
             with jax.named_scope("combine"):
                 return nothing(x).at[token].add(
                     y.astype(jnp.float32) * g[:, None])
 
+        def every_assignment(x, gate, w):
+            """The levelled window over all ``n k`` sorted rows: the same
+            rows, masks and products as ``window(0, ..., level=True)``,
+            moved by ``_to_expert_order`` / ``_to_token_order`` where that
+            gathers ``x[token]`` and scatter-adds the weighted rows home
+            (and autodiff scatter-adds the gather's cotangent)."""
+            with jax.named_scope("dispatch"):
+                here = (jnp.arange(R) < landed)[:, None]
+                rows = jnp.where(here, _to_expert_order(
+                    x.astype(self.dtype), order, inverse), 0)
+                sizes = group_sizes.at[-1].add(R - landed)
+            y = experts(rows, here, sizes, w)
+            with jax.named_scope("combine"):
+                y = _to_token_order(y, order, inverse).reshape(n, k, d)
+                g = jnp.where(inverse.reshape(n, k) < landed, gate, 0.0)
+                # The float32 product the scatter-add lands on its token.
+                return (y.astype(jnp.float32) * g[..., None]).sum(axis=1)
+
         @jax.checkpoint
         def run(x, gate, w):
+            if permuted:
+                return every_assignment(x, gate, w)
             out = window(0, x, gate, w, level=True)
-            if windows == 1:
-                return out
 
             def every_other_window():
                 # Also those past ``landed`` (all masked, their rows
@@ -614,7 +664,7 @@ class DroplessMoE(nn.Module):
                 lambda: nothing(x))
 
         return (run(x, gate, w), tokens_per_expert, landed,
-                plan.form == "kernels")
+                plan.form == "kernels", permuted)
 
 
 def router_losses(intermediates) -> tuple:

@@ -136,6 +136,22 @@ def test_experts_reference_phase(smoke):
                                       seed=0)
 
 
+def test_held_rows_says_which_way_each_cell_s_rows_move(smoke):
+    """From shapes alone: ``zaya1_1chip``'s held layer (top-1, 8 of 17
+    outputs: ``3 · 1 · 8 ≥ 17``) has a window of every assignment and moves
+    all of them through the sort's permutation; ``twotower_1chip``'s
+    (18,432 of 98,304) and ``keye_1chip``'s (49,152 of 131,072) windows are
+    smaller, and gather and scatter-add."""
+    assert {cell: smoke.held_rows(**layer)
+            for cell, layer in smoke.HELD_LAYERS.items()} == {
+        "zaya1_1chip": {"assignments": 16384, "held_assignments": 7710,
+                        "permuted_assignments": 16384},
+        "twotower_1chip": {"assignments": 98304, "held_assignments": 6144,
+                           "permuted_assignments": 0},
+        "keye_1chip": {"assignments": 131072, "held_assignments": 16384,
+                       "permuted_assignments": 0}}
+
+
 def test_select_reference_phase(smoke):
     """Learned sparse attention's three steps (interpreted here) against
     their dense float32 form: the same keys to the last one, the output,

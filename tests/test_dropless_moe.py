@@ -21,8 +21,10 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from benchmark.families import olmoe_lm
 from horovod_tpu.jax.spmd import make_train_step
+from horovod_tpu.layer_notes import noting_layers
 from horovod_tpu.metrics import registry
-from horovod_tpu.parallel.moe import DroplessMoE, router_losses
+from horovod_tpu.parallel.moe import (
+    DroplessMoE, _pad_hidden, router_losses)
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 N, D, HID, E, K = 96, 16, 24, 6, 2
@@ -139,6 +141,134 @@ def test_layer_traces_under_shard_map_with_vma_checks():
         out, jnp.concatenate([h[0] for h in halves]), rtol=1e-5, atol=1e-6)
     np.testing.assert_allclose(
         balance, (halves[0][1] + halves[1][1]) / 2, rtol=1e-6)
+
+
+# ------------- a held share whose one window is every assignment
+
+
+def held_layer(top_k: int, skip: bool, held=(1, 2)):
+    """4 experts (and the choice that computes nothing), ``held`` of them
+    here.  With 2 held the window of three times the uniform load is every
+    assignment (``3 · top_k · 2 ≥ 4 + skip``); with 1 held and top-1 it is
+    216 (skip: 176) of the 288 rows, and a second window follows."""
+    layer = DroplessMoE(num_experts=4, hidden=HID, top_k=top_k,
+                        skip_choice=skip, held=held, dtype=jnp.float32)
+    x = jax.random.normal(jax.random.PRNGKey(2), (3 * N, D))
+    params = layer.init(jax.random.PRNGKey(3), x)["params"]
+    return layer, params, x
+
+
+def window_form(layer, p, x):
+    """The layer's output as the parent formed it when the window held
+    every assignment: rows gathered by ``x[token]`` (autodiff scatter-adds
+    the cotangent home), the weighted results scatter-added onto their
+    tokens.  Same sort, masks, levelled sizes and grouped products."""
+    (first, held), k = layer.held, layer.top_k
+    logits = jnp.dot(x, p["router"]["kernel"],
+                     precision=jax.lax.Precision.HIGHEST)
+    gate, expert = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), k)
+    flat = expert.reshape(-1)
+    local = jnp.where((flat >= first) & (flat < first + held), flat - first,
+                      held)
+    order = jnp.argsort(local, stable=True)
+    sizes = jnp.bincount(local, length=held + 1)[:held].astype(jnp.int32)
+    token = order // k
+    here = (jnp.arange(flat.size) < sizes.sum())[:, None]
+    rows = jnp.where(here, x[token], 0)
+    g = jnp.where(here[:, 0], gate.reshape(-1)[order], 0.0)
+    sizes = sizes.at[-1].add(flat.size - sizes.sum())
+    # What the layer pads the hidden width to where ``lax.ragged_dot`` runs.
+    w = {name: _pad_hidden(p[name], 1 if name == "w_down" else 2, 256)
+         for name in ("w_gate", "w_up", "w_down")}
+    h = jnp.where(here, jax.nn.silu(
+        jax.lax.ragged_dot(rows, w["w_gate"], sizes))
+        * jax.lax.ragged_dot(rows, w["w_up"], sizes), 0)
+    y = jnp.where(here, jax.lax.ragged_dot(h, w["w_down"], sizes), 0)
+    return jnp.zeros_like(x).at[token].add(y * g[:, None])
+
+
+def weighed(out):
+    return (out * jnp.cos(out)).sum()
+
+
+@pytest.mark.parametrize("skip", [False, True], ids=["", "skip_choice"])
+@pytest.mark.parametrize("top_k", [1, 2])
+def test_the_permuted_rows_are_the_window_s(top_k, skip):
+    """Where the window is every assignment the rows go to expert order and
+    come back as gathers through the sort's permutation.  Output, loss and
+    every gradient leaf are the scatter-add form's: at top-1 BIT FOR BIT —
+    a token's one product ``y · g`` lands on a zero and its cotangent is
+    summed over one row —; at top-2 to the order of a two-term sum."""
+    layer, params, x = held_layer(top_k, skip)
+
+    def got_fn(p, x):
+        out = layer.apply({"params": p}, x)[0]
+        return weighed(out), out
+
+    def want_fn(p, x):
+        out = window_form(layer, p, x)
+        return weighed(out), out
+
+    (got_loss, got), got_g = jax.jit(jax.value_and_grad(
+        got_fn, argnums=(0, 1), has_aux=True))(params, x)
+    (want_loss, want), want_g = jax.jit(jax.value_and_grad(
+        want_fn, argnums=(0, 1), has_aux=True))(params, x)
+    assert float(jnp.abs(want).max()) > 0 and not bool(
+        jnp.abs(want).sum(axis=1).all())      # some token's rows are elsewhere
+    leaves = jax.tree_util.tree_leaves_with_path
+    assert [path for path, _ in leaves(got_g)] == [
+        path for path, _ in leaves(want_g)]
+    for (path, g), w in zip(leaves(((got_loss, got), got_g)),
+                            jax.tree.leaves(((want_loss, want), want_g))):
+        assert float(jnp.abs(w).max()) > 0, path
+        if top_k == 1:
+            np.testing.assert_array_equal(g, w, err_msg=str(path))
+        else:
+            np.testing.assert_allclose(g, w, rtol=1e-5, atol=1e-6,
+                                       err_msg=str(path))
+
+
+def equations(jaxpr):
+    """Every equation of a jaxpr, those of nested jaxprs (the checkpointed
+    block, its replay, branches) included."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from equations(sub)
+
+
+def row_scatter_adds(jaxpr, width: int) -> int:
+    """``scatter-add``s into a floating array of rows ``(·, width)``."""
+    operands = [eqn.invars[0].aval for eqn in equations(jaxpr)
+                if eqn.primitive.name == "scatter-add"]
+    return sum(a.ndim == 2 and a.shape[1] == width
+               and jnp.issubdtype(a.dtype, jnp.floating) for a in operands)
+
+
+@pytest.mark.parametrize("held,permuted", [((1, 2), True), ((1, 1), False)],
+                         ids=["every_assignment", "a_smaller_window"])
+def test_only_a_smaller_window_scatter_adds_rows(held, permuted):
+    """Forward, replay and backward of a layer whose window is every
+    assignment hold no scatter-add of rows (``bincount``'s integer one
+    and the levelling's stay); a window smaller than ``n · k`` keeps the
+    parent's: the combine's, and the transpose of the dispatch's gather.
+    The layers' notes say which form ran."""
+    layer, params, x = held_layer(1, True, held)
+    noted = {}
+
+    def loss(p, x):
+        return weighed(layer.apply({"params": p}, x)[0])
+
+    jaxpr = jax.make_jaxpr(noting_layers(
+        jax.value_and_grad(loss, argnums=(0, 1)), noted))(params, x)
+    found = row_scatter_adds(jaxpr.jaxpr, D)
+    assert (found == 0) if permuted else (found >= 2), found
+    counters, = noted.values()
+    assert counters["moe.assignments"] == 3 * N
+    assert counters["moe.permuted_assignments"] == (3 * N if permuted else 0)
 
 
 # ------------------------------------------------------- the whole model
