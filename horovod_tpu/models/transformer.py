@@ -69,6 +69,15 @@ each side (:class:`ResidualMerge`): compressed convolutional attention
 pass through two causal convolutions) and then a ``DroplessMoE`` whose
 router is a small network with a state that the stack hands from one ``Z``
 layer to the next.  :func:`Zaya1LM` is the ZAYA1 setting.
+
+``mtp`` adds a multi-token-prediction module behind a pattern stack
+(:class:`MultiTokenPrediction`): from the stack's final hidden states and
+the NEXT token's embedding, through the shared table, it makes a second
+hidden state that the shared head turns into a prediction of the token
+after the next; :func:`horovod_tpu.ops.losses.multi_token_xent` is the
+loss of both.  ``moe=dict(latent=...)`` puts an ``E`` layer's routed
+experts in a latent between a shared down- and up-projection
+(``DroplessMoE``).  :func:`Nemotron3SuperLM` sets both.
 """
 
 from __future__ import annotations
@@ -597,6 +606,45 @@ class PatternLayer(nn.Module):
         return x + y
 
 
+class MultiTokenPrediction(nn.Module):
+    """One multi-token-prediction module (DeepSeek-V3 report, section 2.2,
+    in Megatron-core's ``MultiTokenPredictionLayer`` form): from the
+    stack's final hidden states ``h`` (B, T, d) — position ``t`` predicts
+    token ``t + 1`` — and the embeddings ``e`` (B, T, d) of those very
+    tokens ``t + 1``, through the model's own table::
+
+        h' = [ n_e(e) | n_h(h) ] W_eh          W_eh (2 d, d), no bias
+        h' = layer(h')  for each letter of ``pattern``
+        out = n_m(h')
+
+    whose logits through the model's own head predict token ``t + 2``.
+    ``n_e``, ``n_h``, ``n_m`` are the model's norm; the layers are
+    :class:`PatternLayer` ``layer_{i}`` with the stack's own ``subs`` (the
+    module's parameters are its own, the table and the head are shared).
+    ``make_train_step`` counts ``mtp.depth`` (prediction modules a step:
+    1) and ``mtp.positions`` (positions a step the module runs)."""
+    pattern: str
+    subs: Any
+    layer_fields: Any
+
+    @nn.compact
+    def __call__(self, h, e):
+        f = self.layer_fields
+
+        def normed(y, name):
+            return _norm(f["norm"], f["norm_eps"], f["ln_dtype"], name)(y)
+
+        x = nn.Dense(h.shape[-1], use_bias=False, dtype=f["dtype"],
+                     param_dtype=jnp.float32, name="eh_proj")(
+            jnp.concatenate([normed(e, "n_e"), normed(h, "n_h")], axis=-1))
+        for i, kind in enumerate(self.pattern):
+            x = PatternLayer(kind, self.subs.get(kind), name=f"layer_{i}",
+                             **f)(x)
+        note_layer(self.path, {"mtp.depth": 1,
+                               "mtp.positions": h.shape[0] * h.shape[1]})
+        return normed(x, "n_m")
+
+
 class Block(nn.Module):
     num_heads: int
     mlp_ratio: int = 4
@@ -754,6 +802,14 @@ class TransformerLM(nn.Module):
     attn_scale: Optional[float] = None
     residual_multiplier: float = 1.0
     cca: Any = None
+    # ``mtp=dict(pattern="*E")``: a multi-token-prediction module named
+    # ``mtp`` behind the stack (MultiTokenPrediction), its layers the
+    # letters of its own pattern with this stack's ssm=, moe=, heads.  The
+    # call then takes ONE MORE id a sequence than the stack runs, (B, T +
+    # 1): the stack reads ``tokens[:, :-1]``, the module the embeddings of
+    # ``tokens[:, 1:]``, and the call returns a pair — the stack's hidden
+    # states (or logits) and the module's (__call__).
+    mtp: Any = None
     # Of a pattern stack too: the embedded tokens are multiplied by
     # embedding_multiplier, the final hidden states divided by
     # logits_scaling, and with tie_head the head is the embedding table
@@ -771,28 +827,35 @@ class TransformerLM(nn.Module):
         materialized as autodiff residuals (init still uses the default
         call so the param tree always contains the head; with ``tie_head``
         there is none, and :meth:`head_kernel` gives the matrix).  The
-        hidden states are already divided by ``logits_scaling``."""
+        hidden states are already divided by ``logits_scaling``.  With
+        ``mtp`` the ids are (B, T + 1) and the result is a pair, the
+        stack's over ``tokens[:, :-1]`` and the prediction module's (the
+        field's comment): :func:`horovod_tpu.ops.losses.multi_token_xent`
+        takes the pair of hidden states."""
         if self.tp_axis and self.attn != "full":
             raise ValueError(
                 "tp_axis composes with attn='full' only (TP attention "
                 f"computes the full sequence locally); got {self.attn!r}")
         if self.tp_axis and (self.moe_experts or self.qk_norm
                              or self.pos != "learned" or self.pattern
-                             or self.moe or self.indexer or self.cca):
+                             or self.moe or self.indexer or self.cca
+                             or self.mtp):
             raise ValueError("tp_axis runs the GPT-2 block only: no "
-                             "experts (whole or a held share), QK-norm, "
-                             "rotary positions or pattern stack")
+                             "experts (whole, a held share or in a latent), "
+                             "QK-norm, rotary positions, pattern stack or "
+                             "multi-token prediction (mtp=)")
         if self.pos not in ("learned", "rotary", "none"):
             raise ValueError(f"unknown pos: {self.pos!r}")
         if self.pattern is not None:
             return self._pattern_stack(tokens, return_hidden)
         if (self.pos == "none" or self.moe or self.ssm or self.lin
                 or self.mlp_hidden or self.indexer or self.cca
-                or self.tie_head
+                or self.tie_head or self.mtp
                 or self.attn_scale is not None
                 or (self.residual_multiplier, self.embedding_multiplier,
                     self.logits_scaling) != (1.0, 1.0, 1.0)):
-            raise ValueError("pos='none', ssm=, moe=, lin=, indexer=, cca=, "
+            raise ValueError("pos='none', ssm=, moe= (its latent= too), lin=, "
+                             "indexer=, cca=, mtp=, "
                              "mlp_hidden=, attn_scale=, tie_head= and the "
                              "three multipliers belong to a pattern stack; "
                              "the block stack "
@@ -867,19 +930,35 @@ class TransformerLM(nn.Module):
             raise ValueError("residual_multiplier scales the sub-layers of "
                              "'m' and 'a' layers only; the pattern "
                              f"{self.pattern!r} holds others")
+        layer_fields = dict(dtype=self.dtype, ln_dtype=self.ln_dtype,
+                            norm=self.norm, norm_eps=self.norm_eps,
+                            mlp_hidden=self.mlp_hidden,
+                            residual_multiplier=self.residual_multiplier)
+        mtp = None
+        if self.mtp:
+            letters = dict(self.mtp).get("pattern")
+            if set(self.mtp) != {"pattern"} or not letters or (
+                    "Z" in letters) or self.tie_head:
+                raise ValueError(
+                    "mtp= is dict(pattern=<letters>): ONE prediction module "
+                    "of those layers (no 'Z': nothing hands it a router "
+                    "state) behind a stack with an untied head; got "
+                    f"{self.mtp!r}, tie_head={self.tie_head}")
+            mtp = MultiTokenPrediction(letters, subs, layer_fields,
+                                       name="mtp")
         embed = nn.Embed(self.vocab, self.dim, param_dtype=jnp.float32,
                          dtype=self.dtype, name="tok_emb")
         x = embed(tokens)
         if self.embedding_multiplier != 1.0:
             x = x * self.embedding_multiplier
+        if mtp is not None:
+            # One table serves both: the stack reads all ids but the last,
+            # the module all but the first.
+            x, next_emb = x[:, :-1], x[:, 1:]
         router_state = None       # of the last 'Z' layer, for the next one
         for i, kind in enumerate(self.pattern):
-            layer = PatternLayer(kind, subs.get(kind), dtype=self.dtype,
-                                 ln_dtype=self.ln_dtype, norm=self.norm,
-                                 norm_eps=self.norm_eps,
-                                 mlp_hidden=self.mlp_hidden,
-                                 residual_multiplier=self.residual_multiplier,
-                                 first=i == 0, name=f"layer_{i}")
+            layer = PatternLayer(kind, subs.get(kind), first=i == 0,
+                                 name=f"layer_{i}", **layer_fields)
             if kind == "Z":
                 x, router_state = layer(x, router_state)
             else:
@@ -889,13 +968,16 @@ class TransformerLM(nn.Module):
             x = x / self.logits_scaling
         if self.tie_head:
             note_layer(self.path, {"lm.tied_head": 1})
+        if mtp is not None:
+            x = (x, mtp(x, next_emb))
         if return_hidden:
             return x
         if self.tie_head:
             return jnp.dot(x.astype(self.head_dtype),
                            embed.embedding.astype(self.head_dtype).T)
-        return nn.Dense(self.vocab, use_bias=False, dtype=self.head_dtype,
-                        param_dtype=jnp.float32, name="head")(x)
+        head = nn.Dense(self.vocab, use_bias=False, dtype=self.head_dtype,
+                        param_dtype=jnp.float32, name="head")
+        return jax.tree.map(head, x)
 
     def head_kernel(self, params):
         """The (dim, vocab) matrix that turns ``return_hidden=True``'s
@@ -918,7 +1000,13 @@ def NemotronHLM(**overrides) -> TransformerLM:
     expert 3712 wide; vocab 131072, untied head.  ``overrides`` replace
     any field: a cut takes the first letters of the pattern, and
     ``moe={..., "held": (first, count)}`` keeps one chip's share of every
-    layer's experts (``parallel.moe.DroplessMoE``).
+    layer's experts (``parallel.moe.DroplessMoE``).  Two further fields
+    this model leaves off and :func:`Nemotron3SuperLM` sets: ``moe={...,
+    "latent": w}`` (the routed experts work ``w`` wide between a shared
+    down- and up-projection) and ``mtp=dict(pattern=...)`` (a
+    multi-token-prediction module behind the stack).  A tensor-parallel
+    rank's share of a mixer is ``Mamba2Mixer`` at ONE group: ``ssm=dict(
+    num_heads=H / n_groups, n_groups=1, ...)``.
 
     What is NOT here: the model card's second, denoising tower, its
     conditioning on this one, in-block bidirectional attention and the
@@ -935,6 +1023,54 @@ def NemotronHLM(**overrides) -> TransformerLM:
         moe_experts=128, moe_top_k=6, moe_hidden=1856,
         moe=dict(router="sigmoid", renormalize=True, gate_scale=2.5,
                  activation="relu2", shared_hidden=3712))
+    fields.update(overrides)
+    return TransformerLM(**fields)
+
+
+def Nemotron3SuperLM(**overrides) -> TransformerLM:
+    """The stack that ``nvidia/NVIDIA-Nemotron-3-Super-120B-A12B-BF16``'s
+    config.json describes (``model_type`` ``nemotron_h``), as a
+    :class:`TransformerLM` with a ``pattern``: 88 layers (40 ``M``, 40
+    ``E``, 8 ``*``) at d 4096, RMSNorm eps 1e-5, no positions; ``M``
+    Mamba-2 mixers of 128 heads of 64 in 8 groups, state 128, conv 4,
+    chunks of 128; ``*`` attention of 32 query heads over 2 KV heads of
+    128; ``E`` LatentMoE — 512 relu² experts 2688 wide that work in a
+    latent of 1024 between a down- and an up-projection all of them share
+    (``DroplessMoE(latent=1024)``), top-22 by sigmoid scores renormalised
+    and scaled by 5, and one shared expert 5376 wide on the layer's input
+    itself; a multi-token-prediction module of one ``*`` and one ``E``
+    layer behind the stack (``mtp=dict(pattern="*E")``,
+    :class:`MultiTokenPrediction`); vocab 131072, untied head.
+
+    What config.json does not say is the family's published form
+    (Megatron-core's latent projections and ``MultiTokenPredictionLayer``):
+    ``benchmark/configs/nemotron-3-super-120b-a12b.json`` lists each under
+    ``assumed``.  Not built: a prediction depth past 1 with shared weights,
+    the router's correction bias (zero until something trains it, as in
+    :func:`NemotronHLM`), any exchange between chips.
+
+    ``overrides`` replace any field.  A cut takes the first letters of the
+    pattern.  One chip's share of a layer is overrides alone, no other
+    code: a tensor-parallel rank's share of a mixer is the mixer of its ONE
+    group (``n_groups`` 8 is the degree the mixer is built for: B, C and
+    the gated norm are a group's own, so ``ssm=dict(num_heads=16,
+    n_groups=1, ...)`` is rank ``g``'s heads and the eight shares add up);
+    of attention ``num_heads=4, kv_heads=1``; of the experts ``moe={...,
+    "held": (first, count)}``.  Trained through ``make_train_step`` with
+    the loss :func:`horovod_tpu.ops.losses.multi_token_xent` of the two
+    hidden states ``model.apply(..., tokens[:, :-1], return_hidden=True)``
+    gives for sequences of T + 2 ids."""
+    fields = dict(
+        vocab=131072, dim=4096, num_heads=32, kv_heads=2, head_dim=128,
+        max_len=262144, norm="rms", norm_eps=1e-5, pos="none",
+        pattern=("MEMEMEM*EMEMEMEM*EMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*"
+                 "EMEMEMEMEM*EMEMEMEMEM*EMEMEMEM*EMEMEMEME"),
+        ssm=dict(num_heads=128, head_dim=64, n_groups=8, state_size=128,
+                 conv_kernel=4, chunk=128),
+        moe_experts=512, moe_top_k=22, moe_hidden=2688,
+        moe=dict(router="sigmoid", renormalize=True, gate_scale=5.0,
+                 activation="relu2", shared_hidden=5376, latent=1024),
+        mtp=dict(pattern="*E"))
     fields.update(overrides)
     return TransformerLM(**fields)
 

@@ -20,7 +20,8 @@ the device backs a scoped-VMEM budget above Mosaic's default; no option
 picks a form):
 
 * **Pallas TPU kernels** where the widths are whole 128-lane tiles and the
-  rows whole tiles (``olmoe_1chip``, ``twotower_1chip``).  The rows are cut
+  rows whole tiles (``olmoe_1chip``, ``twotower_1chip``,
+  ``nemo3super_1chip``).  The rows are cut
   into tiles of ``plan.rows``; a *visit* is one (row tile, group) pair
   whose rows meet, listed in row order by :func:`_visits` from the group
   sizes and read by the kernels' index maps (scalar prefetch): a tile that
@@ -68,6 +69,8 @@ class GroupedPlan(NamedTuple):
 
 # Rows a tile, rows a strip and the most columns a block: the best of a
 # sweep on the chip at the two cells' shapes (PERF.md section 6, PR 35).
+# Rows that are whole strips and not whole tiles (a window of 8,448 = 33 x
+# 256) take tiles of one strip (PERF.md section 6, PR 46).
 _ROWS = 512
 _STRIP = 256
 _MOST_COLS = 1024
@@ -108,21 +111,23 @@ def _plan(*, rows, groups, k, n, itemsize, interpret, manual_axes,
     function of what the op observes at trace time.
 
     The kernels take widths in whole 128-lane tiles and rows in whole
-    tiles of ``_ROWS`` (two-byte operands: a float32 tile of as many rows
+    tiles of ``_ROWS``, or of ``_STRIP`` where only that cuts them evenly
+    (two-byte operands: a float32 tile of as many rows
     holds twice the bytes), the contraction whole in VMEM; they ask a
     scoped-VMEM budget above Mosaic's default, which ``vmem_headroom``
     says the device backs, and leave what would not fit it.  Interpreted
     Pallas under ``shard_map``'s manual axes takes ``ragged_dot``
     (:func:`_pallas.xla_form`)."""
     ragged = GroupedPlan("ragged_dot", 0, 0, 0, 0, _RAGGED_LANES)
-    if (k % 128 or n % 128 or rows % _ROWS or groups < 1 or itemsize != 2
+    tile = _STRIP if rows % _ROWS else _ROWS
+    if (k % 128 or n % 128 or rows % tile or groups < 1 or itemsize != 2
             or not vmem_headroom
             or _pallas.xla_form(interpret, manual_axes)):
         return ragged
-    if (_vmem_bytes(_ROWS, _STRIP, _MOST_COLS, k, n, itemsize)
+    if (_vmem_bytes(tile, _STRIP, _MOST_COLS, k, n, itemsize)
             > _VMEM_MB * 2 ** 20 * 3 // 4):
         return ragged
-    return GroupedPlan("kernels", _ROWS, _STRIP, _MOST_COLS, _VMEM_MB, 128)
+    return GroupedPlan("kernels", tile, _STRIP, _MOST_COLS, _VMEM_MB, 128)
 
 
 def grouped_plan(rows_like, groups: int, n: int, *,

@@ -46,6 +46,7 @@ heads); cited by SURVEY §5.7's long-context mandate.
 
 from __future__ import annotations
 
+import contextlib
 import functools
 
 import jax
@@ -251,3 +252,30 @@ def _xent_bwd(chunk, res, g):
 
 
 fused_softmax_xent.defvjp(_xent_fwd, _xent_bwd)
+
+
+def multi_token_xent(hiddens, w, tokens, weights):
+    """The loss of a model with multi-token prediction: ``Σ_k weights[k] ·
+    mean CE(hiddens[k] w, tokens[:, 1 + k : 1 + k + T])``.
+
+    ``hiddens``: the (B, T, d) hidden states a ``TransformerLM(mtp=...)``
+    returns with ``return_hidden=True`` — the stack's, whose position ``t``
+    predicts token ``t + 1``, then each prediction module's, one token
+    further each; ``tokens`` (B, T + len(hiddens)) the ids they were made
+    from and are scored on; ``w`` the one (d, V) head all share, whose
+    gradient is then the sum of every term's.  Each term is a
+    :func:`fused_softmax_xent` pass; those past the first run under the
+    trace scope ``mtp``."""
+    T, d = hiddens[0].shape[1], hiddens[0].shape[-1]
+    if tokens.shape[1] != T + len(hiddens) or len(weights) != len(hiddens):
+        raise ValueError(
+            f"{len(hiddens)} hidden states of {T} positions are scored on "
+            f"sequences of {T + len(hiddens)} ids with a weight each; got "
+            f"{tokens.shape[1]} ids and {len(weights)} weights")
+    total = 0.0
+    for k, (h, weight) in enumerate(zip(hiddens, weights)):
+        labels = tokens[:, 1 + k:1 + k + T].reshape(-1)
+        with jax.named_scope("mtp") if k else contextlib.nullcontext():
+            term = fused_softmax_xent(h.reshape(-1, d), w, labels).mean()
+        total = total + weight * term
+    return total

@@ -338,6 +338,17 @@ class DroplessMoE(nn.Module):
       expert (``tokens_per_expert`` has ``num_experts + 1`` entries, the
       gates are normalised with it), belongs to no shard's ``held`` range
       and is sent to no rows; sown: ``skipped_assignments``.
+    * ``latent > 0``: the routed experts work in a latent that wide
+      (LatentMoE): ``z = W_down x`` once for every token (submodule and
+      trace scope ``latent_down``; no bias, norm or activation), the
+      experts' matrices are ``(latent, hidden)`` and ``(hidden, latent)``,
+      the sorted rows, the grouped matmuls' plan, the held share's window
+      and the float32 combine are all ``latent`` wide, and ``W_up`` takes
+      the combined rows back to the model's width (``latent_up``) —
+      linear, so the shares of a layer's experts still add up.  The router
+      and the shared expert read ``x`` itself.  Noted beside the rest:
+      ``moe.latent`` (the width) and ``moe.row_bytes`` (bytes a gathered
+      row: what an expert-parallel exchange would move an assignment).
 
     Input ``(..., d)``.  Returns ``(output, load_balance, router_z)``:
     the load-balancing term ``E · Σ_e f_e · P_e`` (``f_e`` = assignments
@@ -364,6 +375,7 @@ class DroplessMoE(nn.Module):
     router_hidden: int = 0               # router="mlp": the state's width
     skip_choice: bool = False
     norm_eps: float = 1e-5               # of the mlp router's norm
+    latent: int = 0                      # the routed experts' width; 0: d
 
     @property
     def routed_over(self) -> int:
@@ -413,9 +425,13 @@ class DroplessMoE(nn.Module):
 
         names = (("w_gate", "w_up", "w_down") if self.activation == "swiglu"
                  else ("w_up", "w_down"))
+        # What the routed experts read: the tokens, or their latent.
+        rows, width = x, self.latent or d
+        if self.latent:
+            rows = self._latent(width, "latent_down")(x.astype(self.dtype))
         if self.held is None and not self.skip_choice:
             out, tokens_per_expert, fused = self._all_experts(
-                x, gate, expert, names)
+                rows, gate, expert, names)
             n_held, permuted = E, True
         else:
             # With a skip choice and no share named, every expert is held:
@@ -425,11 +441,14 @@ class DroplessMoE(nn.Module):
                 raise ValueError(f"held={self.held} is not a range of the "
                                  f"{E} experts")
             out, tokens_per_expert, held_assignments, fused, permuted = (
-                self._held_experts(x, gate, expert, names, first, n_held))
+                self._held_experts(rows, gate, expert, names, first, n_held))
             self.sow("intermediates", "held_assignments", held_assignments)
         if self.skip_choice:
             self.sow("intermediates", "skipped_assignments",
                      tokens_per_expert[E])
+        if self.latent:
+            out = self._latent(d, "latent_up")(
+                out.astype(self.dtype)).astype(jnp.float32)
 
         if self.shared_hidden:
             out = out + _SharedExpert(
@@ -451,8 +470,11 @@ class DroplessMoE(nn.Module):
             # Assignments whose rows moved as gathers through the sort's
             # permutation; 0 where a window of them moved by scatter-add.
             "moe.permuted_assignments": n * k * permuted,
-            "moe.expert_bytes": (len(names) * n_held * d * self.hidden
-                                 * jnp.dtype(self.param_dtype).itemsize)}
+            "moe.expert_bytes": (len(names) * n_held * width * self.hidden
+                                 * jnp.dtype(self.param_dtype).itemsize),
+            "moe.row_bytes": width * jnp.dtype(self.dtype).itemsize}
+        if self.latent:
+            counters["moe.latent"] = self.latent
         if self.held is not None:
             # What uniform routing sends to the held experts; the number
             # a step's routing did send is on the device (sown as
@@ -499,6 +521,11 @@ class DroplessMoE(nn.Module):
             h = nn.gelu(dense(R, "router_fc2")(h), approximate=False)
             logits = dense(self.routed_over, "router_out", use_bias=False)(h)
         return logits, r
+
+    def _latent(self, features, name):
+        """One of the two projections every routed expert shares."""
+        return nn.Dense(features, use_bias=False, dtype=self.dtype,
+                        param_dtype=self.param_dtype, name=name)
 
     def _weights(self, names, n_experts, d):
         init = nn.initializers.lecun_normal(batch_axis=(0,))

@@ -217,24 +217,30 @@ def test_the_readers_read_a_trace_and_nothing_without_the_layer(cfg):
 
 
 def test_benchmark_json_names_the_cell_its_config_and_its_metrics():
+    """The cell's entries as PR 43 appended them; later PRs append behind
+    them (PR 46: a ninth configuration, a tenth cell, its name behind this
+    one's in the lists both report)."""
     with open(os.path.join(ROOT, "BENCHMARK.json")) as fh:
         spec = json.load(fh)
-    assert spec["configs"][-1]["name"] == "zaya1-8b"
-    assert spec["configs"][-1]["reduced"] == [
+    config = {c["name"]: c for c in spec["configs"]}["zaya1-8b"]
+    assert config["reduced"] == [
         "num_hidden_layers", "num_experts", "vocab_size"]
-    assert spec["workloads"][-1] == {
-        **spec["workloads"][-1], "name": "zaya1_1chip", "config": "zaya1-8b",
-        "traffic": "dp1_b1", "chips": 1}
-    assert len(spec["configs"]) == 8 and len(spec["workloads"]) == 9
+    cell = {w["name"]: w for w in spec["workloads"]}["zaya1_1chip"]
+    assert cell == {**cell, "config": "zaya1-8b", "traffic": "dp1_b1",
+                    "chips": 1}
+    assert len(spec["configs"]) >= 8 and len(spec["workloads"]) >= 9
     metrics = {m["name"]: m for m in spec["per_layer"]}
-    assert [m["name"] for m in spec["per_layer"][-3:]] == [
-        "cca_mix_ms", "cca_mix_roofline", "route_ms"]
-    for name in ("cca_mix_ms", "cca_mix_roofline", "route_ms"):
+    names = [m["name"] for m in spec["per_layer"]]
+    at = names.index("cca_mix_ms")
+    assert names[at:at + 3] == ["cca_mix_ms", "cca_mix_roofline", "route_ms"]
+    for name in ("cca_mix_ms", "cca_mix_roofline"):
         assert metrics[name]["workloads"] == ["zaya1_1chip"]
+    assert metrics["route_ms"]["workloads"][0] == "zaya1_1chip"
+    for name in ("cca_mix_ms", "cca_mix_roofline", "route_ms"):
         assert metrics[name]["moves"] == "step_ms"
     for name in ("gqa_flash_ms", "gqa_flash_roofline", "moe_ms",
-                 "moe_roofline"):
-        assert metrics[name]["workloads"][-1] == "zaya1_1chip"
+                 "moe_roofline", "route_ms"):
+        assert "zaya1_1chip" in metrics[name]["workloads"]
     throughput = {m["name"]: m for m in spec["end_to_end"]}[
         "tokens_per_s_chip"]
-    assert throughput["workloads"][-1] == "zaya1_1chip"
+    assert "zaya1_1chip" in throughput["workloads"]

@@ -235,6 +235,11 @@ PLAN_TABLE = {
                  "kernels"),
     "olmoe_down": ((131_072, 64, 1024, 2048, 2, False, False, True),
                    "kernels"),
+    # A window of 33 strips, not whole tiles: tiles of one strip.
+    "latent_window_up": ((8_448, 8, 1024, 2688, 2, False, False, True),
+                         "kernels"),
+    "latent_window_down": ((8_448, 8, 2688, 1024, 2, False, False, True),
+                           "kernels"),
     "interpreted_off_the_mesh": ((512, 4, 128, 128, 2, True, False, True),
                                  "kernels"),
     "interpreted_under_manual_axes": (
@@ -269,7 +274,8 @@ def test_plan_table(case):
     plan = gm._plan(**dict(zip(names, args)))
     assert plan.form == form
     if form == "kernels":
-        assert plan == GroupedPlan("kernels", gm._ROWS, gm._STRIP,
+        tile = gm._STRIP if args[0] % gm._ROWS else gm._ROWS
+        assert plan == GroupedPlan("kernels", tile, gm._STRIP,
                                    gm._MOST_COLS, gm._VMEM_MB, 128)
         assert args[0] % plan.rows == 0 and plan.rows % plan.strip == 0
     else:

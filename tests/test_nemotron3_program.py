@@ -1,0 +1,190 @@
+"""What ``latent`` and ``mtp`` add to the program and what they leave
+alone: a ``TransformerLM(mtp=...)`` takes one more id a sequence and gives a
+pair; ``multi_token_xent`` is the weighted sum of two plain cross-entropies;
+the refusals name the new fields; a latent layer is the same in every
+form a held share's window takes; and without a latent and a prediction
+module tiny stacks of every other family the benchmark runs lower to the
+text the parent commit lowered them to.  The reference comparisons are
+``tests/test_nemotron3_stack.py``'s, the shares ``test_nemotron3_shares.py``'s.
+"""
+
+import hashlib
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from horovod_tpu.models import (
+    GraniteHybridLM, KeyeLM, Nemotron3SuperLM, NemotronHLM, OLMoELM,
+    OlmoHybridLM, TransformerLM, Zaya1LM)
+from horovod_tpu.ops.losses import multi_token_xent
+from horovod_tpu.parallel.moe import DroplessMoE
+
+F32 = jnp.float32
+
+
+def rel(got, want):
+    got, want = jnp.asarray(got, F32), jnp.asarray(want, F32)
+    return float(jnp.linalg.norm(got - want)
+                 / jnp.maximum(jnp.linalg.norm(want), 1e-30))
+
+
+# ------------------------------- the other families' programs are unmoved
+
+SSM = dict(num_heads=4, head_dim=8, n_groups=2, state_size=8, conv_kernel=4,
+           chunk=8)
+OTHERS = {
+    "nemotron_h": (lambda: NemotronHLM(
+        vocab=64, dim=32, pattern="M*E", num_heads=2, kv_heads=1,
+        head_dim=16, attn="full", ssm=SSM, moe_experts=8, moe_top_k=3,
+        moe_hidden=16, dtype=F32,
+        moe=dict(router="sigmoid", renormalize=True, gate_scale=2.5,
+                 activation="relu2", shared_hidden=32, held=(2, 2))),
+        "e89fac7c61fa231a"),
+    "granite_hybrid": (lambda: GraniteHybridLM(
+        vocab=64, dim=32, pattern="ma", num_heads=2, kv_heads=1, head_dim=16,
+        attn="full", mlp_hidden=48, ssm={**SSM, "n_groups": 1}, dtype=F32),
+        "18b1b81f01a61398"),
+    "keye": (lambda: KeyeLM(
+        vocab=64, dim=32, pattern="SE", num_heads=2, kv_heads=1, head_dim=16,
+        attn="full", indexer=dict(num_heads=2, head_dim=8, topk=4),
+        moe_experts=8, moe_top_k=2, moe_hidden=16, dtype=F32,
+        moe=dict(router="softmax", renormalize=True, activation="swiglu",
+                 held=(0, 2))), "44be2750a10d541d"),
+    "zaya1": (lambda: Zaya1LM(
+        vocab=64, dim=32, pattern="ZZ", num_heads=2, kv_heads=1, head_dim=16,
+        attn="full", moe_experts=4, moe_top_k=1, moe_hidden=16, dtype=F32,
+        moe=dict(router="mlp", router_hidden=8, skip_choice=True,
+                 activation="swiglu", held=(0, 2))), "4b67b295f7c079d0"),
+    "olmo_hybrid": (lambda: OlmoHybridLM(
+        vocab=64, dim=32, pattern="LF", num_heads=2, attn="full",
+        mlp_hidden=48, dtype=F32,
+        lin=dict(num_heads=2, key_dim=8, value_dim=16, conv_kernel=4,
+                 chunk=8, allow_neg_eigval=True)), "016a39b5859ce892"),
+    "olmoe": (lambda: OLMoELM(
+        vocab=64, dim=32, depth=1, num_heads=2, attn="full", moe_experts=8,
+        moe_top_k=2, moe_hidden=16, dtype=F32), "ea43dcfe9dba1e8a"),
+}
+
+
+@pytest.mark.parametrize("name", OTHERS)
+def test_without_a_latent_and_a_prediction_module_the_stacks_lower_as_they_did(
+        name):
+    """A tiny stack of each family the benchmark's other cells run, loss
+    and gradients, lowers to the text — to the letter — that the commit
+    before ``latent`` and ``mtp`` lowered it to (SHA-256 taken there,
+    43bb0d2, PR 46): with ``latent=0`` and ``mtp=None`` neither field
+    leaves a trace."""
+    build, digest = OTHERS[name]
+    model = build()
+    assert model.mtp is None and not dict(model.moe or {}).get("latent")
+    tokens = jnp.arange(2 * 16, dtype=jnp.int32).reshape(2, 16) % 64
+    params = model.init(jax.random.PRNGKey(0), tokens)["params"]
+
+    def loss(p):
+        return model.apply({"params": p}, tokens,
+                           return_hidden=True).astype(F32).sum()
+
+    text = jax.jit(jax.value_and_grad(loss)).lower(params).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == digest, name
+
+
+# ------------------------------------------------ the call and its refusals
+
+
+def tiny(**over):
+    fields = dict(vocab=64, dim=32, pattern="ME", num_heads=2, kv_heads=1,
+                  head_dim=16, attn="full", ssm=SSM, moe_experts=8,
+                  moe_top_k=3, moe_hidden=16, dtype=F32,
+                  moe=dict(router="sigmoid", renormalize=True,
+                           gate_scale=5.0, activation="relu2",
+                           shared_hidden=32, latent=8))
+    fields.update(over)
+    return Nemotron3SuperLM(**fields)
+
+
+def test_the_call_takes_one_more_id_and_gives_a_pair():
+    model = tiny()
+    tokens = jax.random.randint(jax.random.PRNGKey(0), (2, 19), 0, 64)
+    params = model.init(jax.random.PRNGKey(1), tokens[:, :-1])["params"]
+    h, h2 = model.apply({"params": params}, tokens[:, :-1],
+                        return_hidden=True)
+    assert h.shape == h2.shape == (2, 17, 32)
+    logits, logits2 = model.apply({"params": params}, tokens[:, :-1])
+    assert logits.shape == logits2.shape == (2, 17, 64)
+    np.testing.assert_allclose(logits2, h2 @ params["head"]["kernel"],
+                               rtol=2e-5, atol=2e-5)
+    # The stack's half is the model without the module on the same ids.
+    plain = tiny(mtp=None)
+    stack_only = {k: v for k, v in params.items() if k != "mtp"}
+    np.testing.assert_array_equal(
+        h, plain.apply({"params": stack_only}, tokens[:, :-2],
+                       return_hidden=True))
+    # The loss of both: the plain means, weighted.
+    loss = multi_token_xent((h, h2), params["head"]["kernel"], tokens,
+                            (1.0, 0.1))
+
+    def ce(lg, labels):
+        lg = lg.reshape(-1, 64)
+        return (jax.nn.logsumexp(lg, -1) - jnp.take_along_axis(
+            lg, labels.reshape(-1, 1), -1)[:, 0]).mean()
+
+    want = ce(logits, tokens[:, 1:-1]) + 0.1 * ce(logits2, tokens[:, 2:])
+    np.testing.assert_allclose(loss, want, rtol=1e-5)
+    with pytest.raises(ValueError, match="scored on"):
+        multi_token_xent((h, h2), params["head"]["kernel"], tokens[:, :-1],
+                         (1.0, 0.1))
+    with pytest.raises(ValueError, match="scored on"):
+        multi_token_xent((h, h2), params["head"]["kernel"], tokens, (1.0,))
+
+
+def test_the_refusals_name_the_new_fields():
+    tokens = jnp.zeros((1, 9), jnp.int32)
+    key = jax.random.PRNGKey(0)
+    with pytest.raises(ValueError, match="multi-token prediction"):
+        TransformerLM(vocab=64, dim=32, depth=1, num_heads=2, tp_axis="tp",
+                      mtp=dict(pattern="*")).init(key, tokens)
+    with pytest.raises(ValueError, match="mtp=.*belong to a pattern stack"):
+        TransformerLM(vocab=64, dim=32, depth=1, num_heads=2,
+                      mtp=dict(pattern="*")).init(key, tokens)
+    with pytest.raises(ValueError, match="ONE prediction module"):
+        tiny(mtp=dict(pattern="Z")).init(key, tokens)
+    with pytest.raises(ValueError, match="ONE prediction module"):
+        tiny(mtp=dict(pattern="*E", depth=2)).init(key, tokens)
+    with pytest.raises(ValueError, match="untied head"):
+        tiny(tie_head=True).init(key, tokens)
+
+
+@pytest.mark.parametrize("window", [1, 2, 6])
+def test_a_latent_layer_is_the_same_in_every_form_of_the_held_window(
+        window, monkeypatch):
+    """The held share's rows move in three forms — one levelled window, the
+    ``overflowed`` loop over further ones, and, where a window is every
+    assignment, gathers through the sort's permutation.  With the experts
+    in a latent each gives the output and gradients of the library's
+    window (``_HELD_WINDOW`` 3): at 1 this routing overflows, at 6 the
+    window is every assignment; the lowered program holds the window's
+    rows at the LATENT's width."""
+    from horovod_tpu.parallel import moe
+
+    fields = dict(num_experts=8, hidden=16, top_k=3, router="sigmoid",
+                  renormalize=True, gate_scale=5.0, activation="relu2",
+                  shared_hidden=32, latent=8, held=(2, 2), dtype=F32)
+    x = jax.random.normal(jax.random.PRNGKey(0), (2, 24, 12), F32)
+    layer = DroplessMoE(**fields)
+    params = layer.init(jax.random.PRNGKey(1), x)
+
+    def value_and_grads():
+        return jax.value_and_grad(
+            lambda p: (layer.apply(p, x)[0] ** 2).sum())(params)
+
+    assert moe._HELD_WINDOW == 3
+    want = value_and_grads()
+    monkeypatch.setattr(moe, "_HELD_WINDOW", window)
+    got = value_and_grads()
+    assert max(jax.tree.leaves(jax.tree.map(rel, got, want))) < 1e-5
+    # 48 tokens x top-3 x 2 of 8 held = 36 rows a uniform load.
+    rows = min(144, -(-36 * window // 8) * 8)
+    text = jax.jit(lambda p: layer.apply(p, x)[0]).lower(params).as_text()
+    assert f"tensor<{rows}x8xf32>" in text

@@ -747,15 +747,17 @@ def test_mixer_passes_compile_wherever_the_plan_takes_the_kernels(
 # (rows, groups, K, N): what ``grouped_matmul._plan`` hands to the kernels,
 # one case a way of tiling — the two cells' products both ways, widths that
 # cut into blocks of 384 and 640 only, one group, more groups than row
-# tiles, and the widest contraction the plan still holds whole in VMEM.
+# tiles, the widest contraction the plan still holds whole in VMEM, and
+# (PR 46) a contraction of 1,024 against 21 lane tiles: a held window of
+# 8,448 rows (33 tiles of 256) of a 1,024-wide latent, both ways.
 @pytest.mark.parametrize("rows,groups,k,n", [
     (18_432, 8, 2688, 1920), (18_432, 8, 1920, 2688),
     (131_072, 64, 2048, 1024), (131_072, 64, 1024, 2048),
     (1024, 4, 1152, 640), (512, 1, 128, 128), (512, 64, 256, 384),
-    (1024, 2, 4096, 1024)],
+    (1024, 2, 4096, 1024), (8_448, 8, 1024, 2688), (8_448, 8, 2688, 1024)],
     ids=["twotower_up", "twotower_down", "olmoe_up", "olmoe_down",
          "blocks_of_384_and_640", "one_group", "more_groups_than_tiles",
-         "widest_contraction"])
+         "widest_contraction", "latent_window_up", "latent_window_down"])
 def test_grouped_matmuls_compile_wherever_the_plan_takes_the_kernels(
         v5e, rows, groups, k, n):
     """A shape ``_plan`` gives the kernels has to compile, the product and
@@ -1262,3 +1264,110 @@ def test_a_zaya_layer_fwd_bwd_at_zaya_widths(v5e, monkeypatch):
     plan = (m.argument_size_in_bytes + m.output_size_in_bytes
             + m.temp_size_in_bytes - m.alias_size_in_bytes)
     assert plan < 3.0 * 2 ** 30, plan / 2 ** 30
+
+
+# ------------------------------------- the Nemotron-3-Super cell's parts
+# (the nemo3super_1chip cell: 1 sequence of 8,192 (+2), one chip's share)
+
+
+def test_a_latent_expert_layer_fwd_bwd_at_nemotron3_widths(v5e, monkeypatch):
+    """One ``E`` layer as the ``nemo3super_1chip`` cell calls it, forward
+    and backward on one chip: 8,192 tokens of width 4,096 routed over 512
+    experts, top-22, 8 of them held, in a latent of 1,024 between the two
+    projections every expert shares, beside a shared expert 5,376 wide.
+    The grouped matmuls run over a window of 8,448 sorted rows OF THE
+    LATENT (three times the 2,816 that uniform routing sends here: 33
+    tiles of 256), as the family's kernels, not over the 180,224
+    assignments and never at the model's width."""
+    from horovod_tpu.models.transformer import PatternLayer
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    tokens, d = 8_192, 4096
+    one = SingleDeviceSharding(v5e[0])
+    layer = PatternLayer("E", dict(
+        num_experts=512, hidden=2688, top_k=22, router="sigmoid",
+        renormalize=True, gate_scale=5.0, activation="relu2",
+        shared_hidden=5376, latent=1024, held=(0, 8)))
+    x = jax.ShapeDtypeStruct((1, tokens, d), jnp.bfloat16, sharding=one)
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one),
+        jax.eval_shape(lambda key: layer.init(
+            key, jnp.zeros((1, 256, d), jnp.bfloat16))["params"],
+            jax.random.PRNGKey(0)))
+    assert params["moe"]["w_up"].shape == (8, 1024, 2688)
+    assert params["moe"]["w_down"].shape == (8, 2688, 1024)
+    assert params["moe"]["router"]["kernel"].shape == (d, 512)
+    assert params["moe"]["latent_down"]["kernel"].shape == (d, 1024)
+
+    def loss(p, x):
+        return layer.apply({"params": p}, x).astype(jnp.float32).sum()
+
+    lowered = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+        params, x)
+    found = kernels_by_name(lowered)
+    # Up and down forward, the checkpoint's replay of both, two input and
+    # two weight gradients, and the same again in the overflow's windows.
+    assert found["moe_gmm"] >= 4 and found["moe_gmm_nt"] >= 2, found
+    assert found["moe_tgmm"] >= 2, found
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert "ragged-dot" not in text
+    assert "8448,1024" in text and "8448,2688" in text
+    assert "8448,4096" not in text and "180224,1024" not in text
+    m = compiled.memory_analysis()
+    plan = (m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes - m.alias_size_in_bytes)
+    assert plan < 3.0 * 2 ** 30, plan / 2 ** 30
+
+
+def test_the_nemo3super_cell_s_step_lowers_with_every_kernel_family(
+        v5e, monkeypatch):
+    """The cell's whole step, built as ``benchmark/run.py`` builds it (the
+    family's ``loss_fn`` and optimizer through ``make_train_step``) from
+    shapes alone, lowers for the described chip with every kernel family
+    on its path: the scan's three at 16 heads in ONE group, the mixer's
+    two passes at an input projection padded from 2,320 to 2,432 columns,
+    the grouped-KV flash forward and its one-kernel backward at 4 query
+    heads over 1 KV head, and the grouped matmuls at the latent's window.
+    A lowering, not a compile (``benchmark/compile_check.py`` compiles it:
+    13.645 GiB planned, PR 46)."""
+    import importlib
+    import json
+
+    from horovod_tpu.jax.spmd import make_train_step
+    from horovod_tpu.parallel.mesh import RANKS_AXIS
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "nemotron-3-super-120b-a12b.json")) as fh:
+        cfg = json.load(fh)
+    family = importlib.import_module(f"benchmark.families.{cfg['family']}")
+    mesh = Mesh(np.asarray(v5e[:1]), (RANKS_AXIS,))
+    replicated = NamedSharding(mesh, P())
+
+    def shaped(tree, sharding=replicated):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=sharding), tree)
+
+    tx = family.optimizer(cfg)
+    params, aux = jax.eval_shape(lambda k: family.init(cfg, k),
+                                 jax.random.PRNGKey(0))
+    assert sum(int(np.prod(p.shape))
+               for p in jax.tree.leaves(params)) == 838_246_896
+    batch = family.host_batch(cfg, np.random.default_rng(0), 1)
+    assert batch.shape == (1, 8194)
+    step = make_train_step(family.loss_fn(cfg), tx, mesh,
+                           sync_aux_state=family.SYNC_AUX_STATE)
+    lowered = step.lower(shaped(params), shaped(aux),
+                         shaped(jax.eval_shape(tx.init, params)),
+                         shaped(batch, NamedSharding(mesh, P(RANKS_AXIS))))
+    text = lowered.as_text()
+    import re
+    assert set(re.findall(r'kernel_name = "([^"]+)"', text)) == {
+        "_fwd_kernel", "flash_group_bwd", "moe_gmm", "moe_gmm_nt",
+        "moe_tgmm", "ssd_bwd", "ssd_fwd", "ssd_states", "ssm_conv_bwd",
+        "ssm_conv_fwd", "ssm_gate_bwd", "ssm_gate_fwd"}
+    assert "stablehlo.all_reduce" not in text
+    assert "8192x2432xbf16" in text            # the padded input projection
+    assert "8448x1024xbf16" in text            # the window, in the latent
