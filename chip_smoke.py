@@ -616,7 +616,11 @@ def select_reference_phase(*, batch: int, seq: int, heads: int,
     bfloat16 operands: the selection itself, the output, ``L_I`` and the
     gradients of a weighted sum on all six operands.  ``select_plan`` is
     what ``flash_attention._plan`` decides under a selection on this
-    device: the group form, its blocks and its scoped-VMEM budget."""
+    device: the group form, its blocks and its scoped-VMEM budget;
+    ``threshold_plan`` what ``sparse_select._threshold_plan`` gives the
+    top-k (strip rows, bands, strips a band, scoped MB, MB by shapes; 0
+    rows: the XLA form); ``tie_tiles`` the share of the top-k's
+    strips whose tie bisection ran."""
     import jax
     import jax.numpy as jnp
 
@@ -639,16 +643,19 @@ def select_reference_phase(*, batch: int, seq: int, heads: int,
     weight = jax.random.normal(ks[6], shapes[0])
 
     def program(q, k, v, qi, ki, w):
-        select, lse_i = sparse_select.index_select(qi, ki, w, topk,
-                                                   interpret=interpret)
+        select, lse_i, ties = sparse_select.index_select_counted(
+            qi, ki, w, topk, interpret=interpret)
         out, lse = fa.flash_attention(q, k, v, causal=True, select=select,
                                       interpret=interpret)
         return out, sparse_select.index_kl(
-            qi, ki, w, q, k, lse, select, lse_i, interpret=interpret), select
+            qi, ki, w, q, k, lse, select, lse_i, interpret=interpret), (
+                select, ties)
 
     def reference(*operands):
         return sparse_select.sparse_attention_reference(
             *(a.astype(jnp.float32) for a in operands), topk)
+
+    threshold = threshold_plan(seq, topk)
 
     def scalar(fn):
         def f(*operands):
@@ -664,10 +671,11 @@ def select_reference_phase(*, batch: int, seq: int, heads: int,
         backward = (["flash_select_bwd"] if plan["bwd"] == "group_fused"
                     else ["flash_select_dkdv", "flash_select_dq"])
         check(names == [*backward, "flash_select_fwd", "index_kl",
-                        "index_scores"],
+                        "index_scores",
+                        *(["index_threshold"] if threshold["rows"] else [])],
               f"sparse attention lowered to the kernels {names}, not to "
               f"the plan's ({plan['bwd']})")
-    got_out, got_kl, got_map = jax.jit(program)(*operands)
+    got_out, got_kl, (got_map, tie_tiles) = jax.jit(program)(*operands)
     got_grads = scalar(program)(*operands)
     with jax.default_matmul_precision("highest"):
         want_out, want_kl, want_map = jax.jit(reference)(*operands)
@@ -686,10 +694,28 @@ def select_reference_phase(*, batch: int, seq: int, heads: int,
               f"{name} by {err:.3g} (bound {SELECT_TOL})")
     return {"shape": [batch, seq, heads, kv_heads, head_dim, index_heads,
                       index_dim, topk], "interpret": interpret,
-            "select_plan": plan,
+            "select_plan": plan, "threshold_plan": threshold,
+            "tie_tiles": round(float(tie_tiles), 4),
             "selected_pairs": int(want_map.sum()),
             "pairs_differing": differing,
             **{name: round(err, 5) for name, err in errs.items()}}
+
+
+def threshold_plan(seq: int, topk: int) -> dict:
+    """What ``sparse_select._threshold_plan`` gives a sequence's top-k on
+    this device: the strip's rows (0: the XLA form), the strips a band, the
+    scoped-VMEM budget and the MB the strip takes by shapes."""
+    from horovod_tpu.ops import _pallas, sparse_select
+
+    tile = min(sparse_select._BLOCK, seq)
+    bands = sparse_select._bands(seq, tile, topk)
+    rows = seq // bands
+    block_rows, vmem_mb = sparse_select._threshold_plan(
+        rows, bands, tile, _pallas.vmem_headroom_ok())
+    return {"rows": block_rows, "vmem_mb": vmem_mb, "bands": bands,
+            "strips_a_band": rows // block_rows if block_rows else 0,
+            "mb_by_shapes": round(sparse_select._threshold_vmem_bytes(
+                block_rows, rows, bands) / 2 ** 20, 1) if block_rows else 0}
 
 
 def _timed_ms(calls: int, interpret: bool, fn, *args):
@@ -723,7 +749,15 @@ def select_backward_phase(*, batch: int, seq: int, heads: int, kv_heads: int,
     ``kl_alone``: the indexer's KL pass (``index_kl``: ``L_I`` and its three
     gradients in one kernel) on the same q, k, map and the forward's
     log-sum-exps, under ``kl_plan`` — the tiling and scoped-VMEM budget
-    ``sparse_select._kl_plan`` gives it on this device."""
+    ``sparse_select._kl_plan`` gives it on this device.
+    ``select_rows_alone``: the indexer's exact top-k of the layer in its XLA
+    form — ``select_rows`` on tiles of 512 rows of the four bands' scores,
+    padded and concatenated into the map — and ``threshold_alone``: the same
+    selection by the one kernel ``index_threshold``, which writes the map,
+    at every strip ``_THRESHOLD_ROWS`` names that fits this device's budget
+    (``{rows: ms}``); ``threshold_vs_rows``: the pairs of the map that
+    differ (none may) and the log-sum-exps' largest relative difference,
+    ``tie_tiles`` the share of the plan's strips whose tie bisection ran."""
     import jax
     import jax.numpy as jnp
 
@@ -802,10 +836,50 @@ def select_backward_phase(*, batch: int, seq: int, heads: int, kv_heads: int,
     kl_plan = sparse_select._kl_plan(
         T, H, Hkv, D, index_heads, index_dim, qi.dtype.itemsize,
         _pallas.vmem_headroom_ok())
+
+    # The top-k alone, both forms, on the layer's own scores.
+    chosen = threshold_plan(T, topk)
+    tile, n_bands = min(sparse_select._BLOCK, T), chosen["bands"]
+    rows = T // n_bands
+    bands = jax.block_until_ready(jax.jit(lambda qi, ki, w: [
+        sparse_select.index_scores(qi.transpose(0, 2, 1, 3), ki, w,
+                                   row0=b * rows, rows=rows,
+                                   interpret=interpret)
+        for b in range(n_bands)])(qi, ki, w))
+
+    def selection(plan):
+        return lambda *bands: sparse_select.select_bands(
+            bands, topk, T, plan, tile=tile, interpret=interpret)
+
+    ms["select_rows_alone"], (want_map, want_lse, _) = timed(
+        selection((0, 0)), *bands)
+    budget = (chosen["vmem_mb"]
+              or sparse_select._MOSAIC_DEFAULT_VMEM_MB) * 2 ** 20
+    ms["threshold_alone"], threshold_vs_rows = {}, {}
+    for strip in sparse_select._THRESHOLD_ROWS:
+        if (not chosen["rows"] or rows % strip or strip > tile
+                or sparse_select._threshold_vmem_bytes(strip, rows, n_bands)
+                > budget):
+            continue
+        ms["threshold_alone"][strip], (got_map, got_lse, ties) = timed(
+            selection((strip, chosen["vmem_mb"])), *bands)
+        differing = int((got_map != want_map).sum())
+        check(differing == 0, f"index_threshold at strips of {strip} rows "
+              f"differs from select_rows in {differing} pairs of the map")
+        threshold_vs_rows[strip] = {
+            "pairs_differing": differing,
+            "lse": float(jnp.max(jnp.abs(got_lse - want_lse)
+                                 / jnp.abs(want_lse))),
+            "tie_tiles": round(float(ties), 4)}
+        check(threshold_vs_rows[strip]["lse"] <= 1e-5,
+              f"index_threshold's log-sum-exps differ from select_rows' by "
+              f"{threshold_vs_rows[strip]['lse']:.3g} of their value")
     return {"shape": [B, T, H, Hkv, D, index_heads, index_dim, topk],
             "interpret": interpret,
             "select_plan": plan._asdict(),
             "kl_plan": dict(zip(("block_q", "block_k", "vmem_mb"), kl_plan)),
+            "threshold_plan": chosen,
+            "threshold_vs_rows": threshold_vs_rows,
             "selected_per_query": round(float(select.sum()) / (B * T), 1),
             "ms_a_layer": ms,
             "fused_vs_pair": {n: round(e, 6) for n, e in errs.items()}}

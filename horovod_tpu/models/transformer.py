@@ -248,9 +248,13 @@ class GroupedQueryAttention(nn.Module):
     scores every causal key of a query; the ``topk`` best are the only keys
     the query's heads read; and ``L_I``, the KL term that is the indexer's
     only gradient, is sown as the intermediate ``index_kl``
-    (:func:`index_losses` sums a model's), beside ``selected_per_query``
-    and ``live_tiles``.  Trace scopes ``index/project``, ``index/scores``,
-    ``index/topk``, ``index/select``, ``index/kl`` and ``flash_select``."""
+    (:func:`index_losses` sums a model's), beside ``selected_per_query``,
+    ``live_tiles`` and ``tie_tiles`` (the share of the top-k's strips whose
+    tie bisection ran: the kernel ``index_threshold`` runs it only where a
+    row has more keys at its k-th score than room for them).  Trace scopes
+    ``index/project``, ``index/scores``, ``index/topk`` (the kernel
+    ``index_threshold`` writes the map there), ``index/select``,
+    ``index/kl`` and ``flash_select``."""
     num_heads: int
     kv_heads: int
     head_dim: int
@@ -320,12 +324,13 @@ class GroupedQueryAttention(nn.Module):
         if self.attn == "full":
             out, kl, select = sparse_select.sparse_attention_reference(
                 q, k, v, qi, ki, w, topk)
+            ties = jnp.float32(1.0)
         else:
             interpret = _pallas.interpret()
             tile = ({"tile": self.indexer["tile"]}
                     if "tile" in self.indexer else {})
             with jax.named_scope("index"):
-                select, lse_i = sparse_select.index_select(
+                select, lse_i, ties = sparse_select.index_select_counted(
                     qi, ki, w, topk, interpret=interpret, **tile)
             out, lse = flash_attention_auto(q, k, v, causal=True,
                                             select=select)
@@ -338,6 +343,7 @@ class GroupedQueryAttention(nn.Module):
         self.sow("intermediates", "index_kl", kl)
         self.sow("intermediates", "selected_per_query", per_query)
         self.sow("intermediates", "live_tiles", live)
+        self.sow("intermediates", "tie_tiles", ties)
         self.sow("intermediates", "select", select)
         pairs = sum(min(t + 1, topk) for t in range(T))
         note_layer(self.path, {

@@ -19,13 +19,20 @@ Three steps, none of which ever holds a (T, T) float32 array of all heads:
 * :func:`index_select` — the scores a band of query rows at a time
   (:func:`index_scores`, a Pallas kernel: the H_I products of a tile are
   summed in VMEM and one float32 tile leaves it), the exact top ``topk`` of
-  each row on tiles of ``tile`` rows (:func:`select_rows`: the k-th value
-  and the last tie taken, by bisection — no sort, no approximation), and
-  the selection as the kernels read it: an **int8 (B, T, T) map**, 1 where
-  ``s ∈ S_t`` (``I > τ``, or ``I == τ`` up to the last tie: no scatter).
-  It is **kept for the backward pass** (T² bytes a layer: 256 MiB at
-  T 16,384), because making it again costs the scores and the top-k a
-  second time.  Nothing here is differentiated.
+  each row (the k-th value and the last tie taken, by bisection — no sort,
+  no approximation), and the selection as the kernels read it: an **int8
+  (B, T, T) map**, 1 where ``s ∈ S_t`` (``I > τ``, or ``I == τ`` up to the
+  last tie: no scatter).  The top-k is :func:`index_threshold`, ONE
+  Pallas kernel over the strips of every band: a strip of a band's scores
+  comes into VMEM once, both bisections run on it there — the one on the
+  key index only where ties at the k-th outnumber their room — and the
+  strip's rows of the map leave once, with the log-sum-exp of the chosen
+  scores (``_threshold_plan`` picks the strip from shapes and the device's
+  VMEM).  Where no strip fits, :func:`select_rows` — the same algorithm as
+  XLA loops over tiles of ``tile`` rows, and the tests' oracle — and a pad
+  and a concatenate make the map.  It is **kept for the backward pass**
+  (T² bytes a layer: 256 MiB at T 16,384), because making it again costs
+  the scores and the top-k a second time.  Nothing here is differentiated.
 * the selected attention itself is ``flash_attention(..., select=map)``.
 * :func:`index_kl` — ``L_I`` and, in the same pass, its gradient on the
   indexer's three projections (:func:`_kl_kernel`): a tile's ``p`` is
@@ -213,13 +220,298 @@ def _bands(T: int, tile: int, topk: int) -> int:
     return 1
 
 
-def index_select(qi, ki, w, topk: int, *, tile: int = _BLOCK,
+# The selection's kernel.  A grid step owns a strip of ``block_rows`` whole
+# rows of one band's scores and bisects them ``_THRESHOLD_GROUP`` rows at a
+# time, a lane tile of columns a step of its loops (``_THRESHOLD_UNROLL`` of
+# them unrolled): a strip of EVERY band and the strip's rows of the map are
+# in VMEM twice (Mosaic's pipeline), a group's keys once.
+# ``_threshold_plan`` takes the first of ``_THRESHOLD_ROWS`` that fits the
+# device's budget by ``_threshold_vmem_bytes``.  (Alone on a v5e, ms a layer
+# at T 16,384 where ``select_rows`` takes 14.7: groups of 64 rows 4.7, of
+# 32 5.5, of 128 4.7, of 256 7.2; 32 lane tiles a loop step 4.7, 16 4.8,
+# and at groups of 32 rows 32, 16, 4 tiles 5.7, 6.0, 7.7; the same at
+# strips of 256, 128 and 64 rows: PERF.md section 6, PR 47.)
+_THRESHOLD_ROWS = (256, 128, 64)
+_THRESHOLD_GROUP = 64
+_THRESHOLD_UNROLL = 32
+_THRESHOLD_VMEM_MB = 64
+_LANES = 128
+_INT_MIN = -2 ** 31
+# -inf's bit pattern in the keys' order: what lies above it is a score.
+_NO_SCORE = _INT_MIN + 0x7FFFFF
+
+
+def _threshold_strip(s_ref, map_ref, lse_ref, tie_ref, key_scr, *, k):
+    """A strip's rows of the selection: its scores ``s_ref`` (1,
+    block_rows, width) are in VMEM once and its rows of the map ``map_ref``
+    (1, block_rows, T) leave once.  A group of rows at a time:
+
+    * the keys: the scores' bit patterns as int32 that order as the floats
+      do, ``bits ^ (bits >> 31 & 0x7fffffff)`` — ``select_rows``' unsigned
+      keys with the top bit flipped, so that every compare is a signed
+      one — into ``key_scr`` (T / 128, group, 128), and the row's maximum;
+    * the k-th largest key by 32 counts of ``key >= v``, a count summed
+      lane-wise over the columns and across the lanes once;
+    * the last tie taken by ``log2 width`` counts of ``tied & col < c``,
+      ONLY where some row of the group has more keys at its k-th than room
+      for them (a row whose k-th is not a score takes every score);
+    * ``chosen`` as int8 and the log-sum-exp of the chosen scores in one
+      pass over scores and keys; zeros past ``width``.
+
+    ``tie_ref``: whether a group of the strip ran the tie bisection."""
+    block_rows, width = s_ref.shape[1:]
+    T = map_ref.shape[2]
+    group, tiles = key_scr.shape[1], width // _LANES
+    index_bits = max(1, (width - 1).bit_length())
+    unroll = max(u for u in range(1, _THRESHOLD_UNROLL + 1) if tiles % u == 0)
+    i32 = jnp.int32
+    lane = lax.broadcasted_iota(i32, (group, _LANES), 1)
+
+    # The steps of the three sweeps are unrolled ``unroll`` times a trace,
+    # and written with ``lax`` calls: every ``jax.numpy`` operator on a
+    # tracer is a jitted function of its own, whose trace jax reports (the
+    # span ring would hold 5,600 ``jax/trace`` spans a trace of this
+    # kernel, of the 16,384 it keeps).
+    def sweep(step, carry):
+        """``step(lane tile's index, carry)`` over the group's columns,
+        ``unroll`` tiles a loop step (Mosaic unrolls a loop whole or not at
+        all)."""
+        def steps(c, carry):
+            for u in range(unroll):
+                carry = step(lax.add(lax.mul(c, unroll), u), carry)
+            return carry
+        return lax.fori_loop(0, tiles // unroll, steps, carry)
+
+    def lanes(x):
+        return jnp.broadcast_to(x, (group, _LANES))
+
+    def count(hit):
+        """Rows' counts (group, 1) of ``hit(keys, lane tile's index)``."""
+        return sweep(
+            lambda c, n: lax.add(
+                n, lax.convert_element_type(hit(key_scr[c], c), i32)),
+            jnp.zeros((group, _LANES), i32)).sum(axis=1, keepdims=True)
+
+    def columns(c):
+        return pl.ds(pl.multiple_of(lax.mul(c, _LANES), _LANES), _LANES)
+
+    def column_index(c):
+        return lax.add(lane, lax.broadcast(lax.mul(c, _LANES), lane.shape))
+
+    def rows_of(g):
+        rows = pl.ds(pl.multiple_of(g * group, group), group)
+
+        def form(c, top):
+            s = s_ref[0, rows, columns(c)]
+            bits = lax.bitcast_convert_type(s, i32)
+            key_scr[c] = lax.bitwise_xor(bits, lax.bitwise_and(
+                lax.shift_right_arithmetic(bits, i32(31)), i32(0x7FFFFFFF)))
+            return lax.max(top, s)
+
+        top = sweep(form, jnp.full((group, _LANES), -jnp.inf, jnp.float32)
+                    ).max(axis=1, keepdims=True)
+
+        def value_bit(i, carry):
+            # ``v``: the bits of select_rows' unsigned key found so far;
+            # ``reach``: how many keys reach it.
+            v, reach = carry
+            higher = v | (i32(1) << (31 - i))
+            least = lanes(higher ^ i32(_INT_MIN))
+            n = count(lambda key, _: lax.ge(key, least))
+            return (jnp.where(n >= k, higher, v), jnp.where(n >= k, n, reach))
+
+        v, reach = lax.fori_loop(
+            0, 32, value_bit,
+            (jnp.zeros((group, 1), i32), jnp.full((group, 1), width, i32)))
+        kth = lanes(v ^ i32(_INT_MIN))
+        room = k - count(lambda key, _: lax.gt(key, kth))
+        tied = reach - (k - room)
+        crowded = jnp.max(
+            ((kth[:, :1] > _NO_SCORE) & (tied > room)).astype(i32))
+
+        def index_bit(i, c):
+            later = c | (i32(1) << (index_bits - 1 - i))
+            before = lanes(later)
+            n = count(lambda key, tile: lax.bitwise_and(
+                lax.eq(key, kth), lax.lt(column_index(tile), before)))
+            return jnp.where(n < room, later, c)
+
+        # The largest c with fewer than ``room`` ties before it; every tie
+        # is taken where none is short of room.
+        last_tie = lanes(lax.cond(
+            crowded > 0,
+            lambda: lax.fori_loop(0, index_bits, index_bit,
+                                  jnp.zeros((group, 1), i32)),
+            lambda: jnp.full((group, 1), width, i32)))
+        shift = lanes(jnp.where(top > -jnp.inf, top, 0.0))
+        nothing = jnp.zeros((group, _LANES), jnp.float32)
+
+        def emit(c, total):
+            s, key = s_ref[0, rows, columns(c)], key_scr[c]
+            tie = lax.bitwise_and(lax.eq(key, kth),
+                                  lax.le(column_index(c), last_tie))
+            chosen = lax.bitwise_and(lax.bitwise_or(lax.gt(key, kth), tie),
+                                     lax.gt(s, -jnp.inf))
+            map_ref[0, rows, columns(c)] = lax.convert_element_type(
+                lax.convert_element_type(chosen, i32), jnp.int8)
+            return lax.add(total, lax.select(
+                chosen, lax.exp(lax.sub(s, shift)), nothing))
+
+        total = sweep(emit, nothing)
+        lse_ref[0, rows, :] = shift[:, :1] + jnp.log(
+            total.sum(axis=1, keepdims=True))
+        if T > width:
+            map_ref[0, rows, width:] = jnp.zeros((group, T - width), jnp.int8)
+        return crowded
+
+    ran = lax.fori_loop(0, block_rows // group,
+                        lambda g, ran: ran | rows_of(g), i32(0))
+    tie_ref[...] = jnp.full(tie_ref.shape, ran, i32)
+
+
+def _threshold_kernel(*refs, topk):
+    """Grid (B, bands, rows / block_rows), a band's strips one after the
+    other: the step's strip is the band's, ``_threshold_strip`` at that
+    band's static width."""
+    *s_refs, map_ref, lse_ref, tie_ref, key_scr = refs
+    for b, s_ref in enumerate(s_refs):
+        @pl.when(pl.program_id(1) == b)
+        def _band(s_ref=s_ref):
+            _threshold_strip(s_ref, map_ref, lse_ref, tie_ref, key_scr,
+                             k=min(topk, s_ref.shape[2]))
+
+
+def _threshold_vmem_bytes(block_rows, rows, bands):
+    """What the selection's kernel holds in VMEM at a strip, from shapes: a
+    strip of every band's scores (the bands' widths are ``rows`` to ``bands
+    · rows`` = T), the strip's rows of the map, its log-sum-exps (a lane
+    tile a row) and its flag twice (Mosaic's pipeline), a group's keys, and
+    2 MB of the compiler's own."""
+    T = bands * rows
+    blocks = (block_rows * rows * (bands * (bands + 1) // 2) * 4
+              + block_rows * T + block_rows * _LANES * 4 + 8 * _LANES * 4)
+    return 2 * blocks + _THRESHOLD_GROUP * T * 4 + 2 * 2 ** 20
+
+
+def _threshold_plan(rows, bands, tile, vmem_headroom):
+    """``(block_rows, vmem_mb)`` of the selection's kernel for ``bands``
+    bands of ``rows`` query rows, band ``b`` against ``(b + 1) · rows``
+    keys: the first strip of ``_THRESHOLD_ROWS``, at most the caller's
+    ``tile``, that divides a band and fits the budget —
+    ``_THRESHOLD_VMEM_MB`` where the device backs it, Mosaic's default
+    (``vmem_mb`` 0) where it does not.  ``block_rows`` 0: no strip does, or
+    the widths are not whole lane tiles, and the selection keeps its XLA
+    form (:func:`select_rows`)."""
+    mb = _THRESHOLD_VMEM_MB if vmem_headroom else 0
+    budget = (mb or _MOSAIC_DEFAULT_VMEM_MB) * 2 ** 20
+    if rows % _LANES:
+        return 0, mb
+    for block_rows in _THRESHOLD_ROWS:
+        if (block_rows <= tile and rows % block_rows == 0
+                and _threshold_vmem_bytes(block_rows, rows, bands) <= budget):
+            return block_rows, mb
+    return 0, mb
+
+
+@functools.partial(jax.jit, inline=True, static_argnames=(
+    "topk", "block_rows", "vmem_mb", "interpret"))
+def index_threshold(*bands, topk: int, block_rows: int, vmem_mb: int = 0,
+                    interpret: bool = False):
+    """The selection from the bands' scores — band ``b`` (B, rows, (b + 1)
+    · rows), -inf where a key is not causal, the last band's width T —:
+    ``(select, lse, ties)`` with ``select`` the int8 (B, T, T) map and
+    ``lse`` (B, T) :func:`select_rows`' set and log-sum-exp, and ``ties``
+    (B, T / block_rows) 1 where a strip's tie bisection ran.  ONE kernel
+    (:func:`_threshold_kernel`) over every band's strips: a strip's scores
+    are read once and its rows of the map written once, zeros past the
+    band's width included — no pad, no concatenate, no second buffer.  (A
+    band's block index holds still while the other bands' strips run, so
+    nothing is fetched twice.)  The body is traced once a shape, not once
+    a layer."""
+    B, rows, _ = bands[0].shape
+    if rows % block_rows or block_rows % _THRESHOLD_GROUP or rows % _LANES:
+        raise ValueError(
+            f"index_threshold: bands of {rows} rows in strips of {block_rows} "
+            f"(whole strips of whole groups of {_THRESHOLD_GROUP} rows, and "
+            f"widths of whole lane tiles)")
+    n, strips = len(bands), rows // block_rows
+    T = n * rows
+
+    def strip_of(b):
+        # The band's own strip while it runs; its first before, its last
+        # after: an index that holds still is not fetched again.
+        return lambda bt, band, i: (
+            bt, jnp.clip(i + (band - b) * strips, 0, strips - 1), 0)
+
+    def row_block(bt, band, i):
+        return bt, band * strips + i, 0
+
+    with jax.named_scope("topk"):
+        select, lse, ties = pl.pallas_call(
+            functools.partial(_threshold_kernel, topk=topk),
+            grid=(B, n, strips),
+            in_specs=[pl.BlockSpec((1, block_rows, (b + 1) * rows),
+                                   strip_of(b)) for b in range(n)],
+            out_specs=[
+                pl.BlockSpec((1, block_rows, T), row_block),
+                pl.BlockSpec((1, block_rows, 1), row_block),
+                pl.BlockSpec((1, 1, 8, _LANES),
+                             lambda bt, band, i: (*row_block(bt, band, i), 0)),
+            ],
+            out_shape=[
+                _pallas.struct((B, T, T), jnp.int8, *bands),
+                _pallas.struct((B, T, 1), jnp.float32, *bands),
+                _pallas.struct((B, n * strips, 8, _LANES), jnp.int32, *bands),
+            ],
+            scratch_shapes=[pltpu.VMEM(
+                (T // _LANES, _THRESHOLD_GROUP, _LANES), jnp.int32)],
+            interpret=interpret,
+            name="index_threshold",
+            **_pallas.compiler_params(
+                interpret, ("parallel", "arbitrary", "arbitrary"), vmem_mb),
+        )(*bands)
+    return select, lse[..., 0], ties[..., 0, 0]
+
+
+def _select_band_rows(band, topk: int, tile: int, T: int):
+    """The XLA form of a band's selection: :func:`select_rows` on tiles of
+    ``tile`` rows, ``(int8 (B, rows, T) rows of the map, lse (B, rows))``."""
+    B, rows, width = band.shape
+    tiles = band.reshape(B, rows // tile, tile, width).swapaxes(0, 1)
+    chosen, lse = lax.map(lambda s: select_rows(s, topk), tiles)
+    chosen = chosen.swapaxes(0, 1).reshape(B, rows, width)
+    with jax.named_scope("select"):
+        padded = jnp.pad(chosen.astype(jnp.int8),
+                         [(0, 0), (0, 0), (0, T - width)])
+    return padded, lse.swapaxes(0, 1).reshape(B, rows)
+
+
+def select_bands(bands, topk: int, T: int, plan, *, tile: int = _BLOCK,
                  interpret: bool = False):
-    """``(select, lse)``: the int8 (B, T, T) map of ``S_t`` (1 where query
-    ``t`` reads key ``s``) and the log-sum-exp (B, T) of each query's
-    selected scores.  ``qi`` (B, T, H_I, D_I), ``ki`` (B, T, D_I), ``w``
-    (B, T, H_I).  Scores and top-k run ``tile`` query rows at a time
-    (``tile`` leaves ``S_t`` as it is).  Nothing is differentiated."""
+    """``(select, lse, tie_tiles)`` from the bands' scores, first band
+    first, under ``plan``, a ``_threshold_plan``: :func:`index_threshold`
+    where it names a strip, else :func:`select_rows` a band, padded and
+    concatenated.  ``tie_tiles``: the share of strips whose tie bisection
+    ran (every one in the XLA form, which runs it always).  ``bands`` may
+    be a generator: the XLA form then makes a band's scores when it
+    selects from them, as it always did."""
+    block_rows, vmem_mb = plan
+    if block_rows:
+        select, lse, ties = index_threshold(
+            *bands, topk=topk, block_rows=block_rows, vmem_mb=vmem_mb,
+            interpret=interpret)
+        return select, lse, ties.mean(dtype=jnp.float32)
+    maps, lses = zip(*(_select_band_rows(band, topk, tile, T)
+                       for band in bands))
+    with jax.named_scope("select"):
+        return (jnp.concatenate(maps, axis=1), jnp.concatenate(lses, axis=1),
+                jnp.float32(1.0))
+
+
+def index_select_counted(qi, ki, w, topk: int, *, tile: int = _BLOCK,
+                         interpret: bool = False):
+    """:func:`index_select`'s ``(select, lse)`` and ``tie_tiles``
+    (:func:`select_bands`)."""
     qi, ki, w = (lax.stop_gradient(a) for a in (qi, ki, w))
     B, T, HI, DI = qi.shape
     tile = min(tile, T)
@@ -229,20 +521,27 @@ def index_select(qi, ki, w, topk: int, *, tile: int = _BLOCK,
     w = w.astype(jnp.float32)
     bands = _bands(T, tile, topk)
     rows = T // bands
-    maps, lses = [], []
-    for b in range(bands):
-        width = (b + 1) * rows
-        band = index_scores(qi, ki, w, row0=b * rows, rows=rows,
-                            interpret=interpret)            # (B, rows, width)
-        tiles = band.reshape(B, rows // tile, tile, width).swapaxes(0, 1)
-        chosen, lse = lax.map(lambda s: select_rows(s, topk), tiles)
-        chosen = chosen.swapaxes(0, 1).reshape(B, rows, width)
-        with jax.named_scope("select"):
-            maps.append(jnp.pad(chosen.astype(jnp.int8),
-                                [(0, 0), (0, 0), (0, T - width)]))
-        lses.append(lse.swapaxes(0, 1).reshape(B, rows))
-    with jax.named_scope("select"):
-        return jnp.concatenate(maps, axis=1), jnp.concatenate(lses, axis=1)
+    plan = ((0, 0) if _pallas.xla_form(interpret, bool(jax.typeof(qi).vma))
+            else _threshold_plan(rows, bands, tile,
+                                 _pallas.vmem_headroom_ok()))
+    scores = (index_scores(qi, ki, w, row0=b * rows, rows=rows,
+                           interpret=interpret)             # (B, rows, width)
+              for b in range(bands))
+    return select_bands(scores, topk, T, plan, tile=tile,
+                        interpret=interpret)
+
+
+def index_select(qi, ki, w, topk: int, *, tile: int = _BLOCK,
+                 interpret: bool = False):
+    """``(select, lse)``: the int8 (B, T, T) map of ``S_t`` (1 where query
+    ``t`` reads key ``s``) and the log-sum-exp (B, T) of each query's
+    selected scores.  ``qi`` (B, T, H_I, D_I), ``ki`` (B, T, D_I), ``w``
+    (B, T, H_I).  Scores and top-k run a band of query rows at a time, the
+    top-k in strips of at most ``tile`` rows (``tile`` leaves ``S_t`` as it
+    is): :func:`index_threshold` where ``_threshold_plan`` finds a strip,
+    else :func:`select_rows`.  Nothing is differentiated."""
+    return index_select_counted(qi, ki, w, topk, tile=tile,
+                                interpret=interpret)[:2]
 
 
 def selection_counters(select, block: int):

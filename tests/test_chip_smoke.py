@@ -167,6 +167,11 @@ def test_select_reference_phase(smoke):
     assert out["selected_pairs"] == sum(min(t + 1, 32) for t in range(128))
     assert {"out", "index_kl", "dq", "dk", "dv", "dqI", "dkI", "dw"} < set(
         out)
+    # One band of 128 rows and keys: a strip of 128 rows, its ties (two
+    # indexer heads' relus leave a quarter of the scores exactly zero).
+    assert out["threshold_plan"] == {"rows": 128, "vmem_mb": 64, "bands": 1,
+                                     "strips_a_band": 1, "mb_by_shapes": 2.3}
+    assert out["tie_tiles"] == 1.0
     assert smoke.SELECT_REFERENCE["topk"] < smoke.SELECT_REFERENCE["seq"]
 
 
@@ -174,13 +179,21 @@ def test_select_backward_phase(smoke):
     """The selected attention's backward in both forms on the same
     operands (interpreted here: the two agree, and no time is reported),
     with the plan's choice and budget, and the KL pass alone beside them
-    under its own plan (``kl_alone``, ``kl_plan``); on the chip the phase
-    runs at the cell's own shape."""
+    under its own plan (``kl_alone``, ``kl_plan``), and the exact top-k
+    alone in its XLA form and by ``index_threshold`` at every strip that
+    fits — the same map to the last pair —; on the chip the phase runs at
+    the cell's own shape."""
     out = smoke.select_backward_phase(batch=1, seq=256, heads=4, kv_heads=2,
                                       head_dim=128, index_heads=2,
                                       index_dim=64, topk=64, seed=0)
-    assert out["interpret"] and out["ms_a_layer"] == dict.fromkeys(
-        ("forward", "dq", "dkdv", "pair", "fused", "kl_alone"))
+    assert out["interpret"] and out["ms_a_layer"] == {
+        **dict.fromkeys(("forward", "dq", "dkdv", "pair", "fused",
+                         "kl_alone", "select_rows_alone")),
+        "threshold_alone": dict.fromkeys((256, 128, 64))}
+    assert out["threshold_plan"]["rows"] == 256
+    assert set(out["threshold_vs_rows"]) == {256, 128, 64}
+    for strip in out["threshold_vs_rows"].values():
+        assert strip["pairs_differing"] == 0 and strip["lse"] <= 1e-6
     assert out["kl_plan"] == {"block_q": 256, "block_k": 256, "vmem_mb": 96}
     assert out["shape"] == [1, 256, 4, 2, 128, 2, 64, 64]
     assert (out["select_plan"]["bwd"], out["select_plan"]["bwd_vmem_mb"],

@@ -413,31 +413,119 @@ def test_a_halved_q_block_is_the_same_attention(monkeypatch, backward):
 # ------------------------------------------------------------ the top-k
 
 
-@pytest.mark.parametrize("W,k", [(64, 16), (100, 100), (37, 5), (256, 300),
-                                 (512, 100)])
-def test_the_exact_top_k_against_a_sort_ties_included(W, k):
+def tied_scores(rng, rows, W):
     """Scores rounded to halves tie by the dozen; a row's second half, and
-    a whole row but three keys, are -inf (not causal).  The set is the
-    first ``k`` of a stable descending sort — a tie to the lower index —
-    without what is not causal, and ``lax.top_k``'s."""
-    rng = np.random.default_rng(W)
-    s = np.round(rng.normal(size=(3, 40, W)).astype(np.float32) * 2) / 2
+    a whole row but three keys, are -inf (not causal)."""
+    s = np.round(rng.normal(size=(3, rows, W)).astype(np.float32) * 2) / 2
     s += 0.0        # no -0: XLA's order has it under +0, numpy's sort beside
     s[1, :, W // 2:] = -np.inf
     s[2, 5, 3:] = -np.inf
-    chosen, lse = jax.jit(lambda x: ss.select_rows(x, k))(s)
-    order = np.argsort(-s, axis=-1, kind="stable")[..., :k]
-    want = np.zeros(s.shape, bool)
-    np.put_along_axis(want, order, True, -1)
-    want &= np.isfinite(s)
-    assert (np.asarray(chosen) == want).all()
-    top = np.zeros(s.shape, bool)
-    np.put_along_axis(top, np.asarray(jax.lax.top_k(s, min(k, W))[1]), True,
-                      -1)
-    assert (np.asarray(chosen) == (top & np.isfinite(s))).all()
-    np.testing.assert_allclose(
-        lse, jax.scipy.special.logsumexp(jnp.where(want, s, -jnp.inf), -1),
-        rtol=1e-6)
+    return s
+
+
+@pytest.mark.parametrize("W,k,bands", [
+    (64, 16, None), (100, 100, None), (37, 5, None), (256, 300, None),
+    (512, 100, None),
+    # The kernel (interpreted) on whole lane tiles, the last of ``bands``
+    # bands ``W`` wide: ``k >= W``, one band, two, and four — three of them
+    # a band's width and not ``T``.
+    (256, 300, 1), (512, 100, 1), (1024, 64, 2), (512, 48, 4)],
+    ids=lambda v: "rows" if v is None else str(v))
+def test_the_exact_top_k_against_a_sort_ties_included(W, k, bands):
+    """The set is the first ``k`` of a stable descending sort — a tie to
+    the lower index — without what is not causal, and ``lax.top_k``'s.
+    ``bands``: through ``index_threshold``, which writes every band's rows
+    of the (T, T) map — zeros past the band's width — and must give
+    ``select_rows``' set bit for bit."""
+    rng = np.random.default_rng(W)
+    if bands is None:
+        scores, select, lses = [tied_scores(rng, 40, W)], None, None
+    else:
+        rows = W // bands
+        scores = [tied_scores(rng, rows, (b + 1) * rows)
+                  for b in range(bands)]
+        select, lses, _ = ss.index_threshold(
+            *map(jnp.asarray, scores), topk=k, block_rows=64, interpret=True)
+        assert select.dtype == jnp.int8 and select.shape == (3, W, W)
+    for b, s in enumerate(scores):
+        rows, width = s.shape[1:]
+        chosen, lse = jax.jit(lambda x: ss.select_rows(x, k))(s)
+        if select is not None:
+            band = np.asarray(select)[:, b * rows:(b + 1) * rows]
+            assert (band[..., :width] == np.asarray(chosen)).all()
+            assert not band[..., width:].any()
+            chosen, lse = band[..., :width] != 0, lses[:, b * rows:width]
+        order = np.argsort(-s, axis=-1, kind="stable")[..., :k]
+        want = np.zeros(s.shape, bool)
+        np.put_along_axis(want, order, True, -1)
+        want &= np.isfinite(s)
+        assert (np.asarray(chosen) == want).all()
+        top = np.zeros(s.shape, bool)
+        np.put_along_axis(
+            top, np.asarray(jax.lax.top_k(s, min(k, width))[1]), True, -1)
+        assert (np.asarray(chosen) == (top & np.isfinite(s))).all()
+        np.testing.assert_allclose(
+            lse, jax.scipy.special.logsumexp(jnp.where(want, s, -jnp.inf),
+                                             -1), rtol=1e-6)
+
+
+def test_the_tie_bisection_runs_only_where_ties_outnumber_their_room():
+    """A strip's flag, and ``tie_tiles`` from it: 0 on scores that do not
+    tie, 0 where the keys at the k-th score all have room (each is taken),
+    1 where they outnumber it — the lower indices are taken, as
+    ``lax.top_k`` breaks a tie — and ``select_rows``' set every time.  A
+    row with no more scores than ``k`` takes them all and asks for no tie
+    bisection whatever ties at -inf."""
+    W, k = 128, 8
+    rng = np.random.default_rng(3)
+    free = rng.permutation(W * W).reshape(1, W, W).astype(np.float32)
+    room = free.copy()
+    room[0, 7] = -9.0
+    room[0, 7, :4] = 1e6 + np.arange(4)   # four above the k-th ...
+    room[0, 7, 100:104] = -5.0            # ... and four AT it: 8 = k
+    crowded = free.copy()
+    crowded[0, 100, 10::16] = 1e6         # eight at the top ...
+    crowded[0, 100, 3] = 1e6              # ... and a ninth, for k = 8
+    short = free.copy()
+    short[0, :, 5:] = -np.inf             # five scores a row, k = 8
+    for scores, flags in ((free, [0, 0]), (room, [0, 0]),
+                          (crowded, [0, 1]), (short, [0, 0])):
+        select, lse, ties = ss.index_threshold(
+            jnp.asarray(scores), topk=k, block_rows=64, interpret=True)
+        assert np.asarray(ties).tolist() == [flags]
+        chosen, want_lse = ss.select_rows(jnp.asarray(scores), k)
+        assert (np.asarray(select) == np.asarray(chosen)).all()
+        np.testing.assert_allclose(lse, want_lse, rtol=1e-6)
+    assert np.flatnonzero(np.asarray(select)[0, 3]).tolist() == [0, 1, 2, 3, 4]
+    taken = np.asarray(ss.index_threshold(
+        jnp.asarray(room), topk=k, block_rows=64, interpret=True)[0])[0, 7]
+    assert np.flatnonzero(taken).tolist() == [0, 1, 2, 3, 100, 101, 102, 103]
+    taken = np.asarray(ss.index_threshold(
+        jnp.asarray(crowded), topk=k, block_rows=64,
+        interpret=True)[0])[0, 100]
+    assert np.flatnonzero(taken).tolist() == [3, *range(10, 10 + 16 * 7, 16)]
+
+
+def test_the_selection_s_plan_follows_the_strip_s_bytes():
+    """``_threshold_plan``, of shapes and the device's budget only: a strip
+    of EVERY band is in VMEM, so the cell's four bands of 4,096 rows take
+    strips of 128 rows under the stated 64 MB (256 would ask 94) and none
+    under Mosaic's default; fewer or narrower bands take more rows; a
+    caller's smaller ``tile`` caps the strip; rows that are not whole lane
+    tiles, and a band that is not whole strips, keep the XLA form."""
+    assert ss._threshold_plan(4096, 4, 512, True) == (
+        128, ss._THRESHOLD_VMEM_MB)
+    assert ss._threshold_plan(4096, 4, 512, False) == (0, 0)
+    assert ss._threshold_plan(2048, 4, 512, True)[0] == 256
+    assert ss._threshold_plan(2048, 4, 512, False) == (64, 0)
+    assert ss._threshold_plan(4096, 1, 512, False) == (256, 0)
+    assert ss._threshold_plan(2048, 4, 64, True)[0] == 64
+    assert ss._threshold_plan(128, 4, 128, True)[0] == 128
+    assert ss._threshold_plan(64, 4, 64, True)[0] == 0
+    assert ss._threshold_plan(384, 1, 256, True)[0] == 128
+    assert ss._threshold_plan(16384, 4, 512, True)[0] == 0
+    for rows in ss._THRESHOLD_ROWS:
+        assert rows % ss._THRESHOLD_GROUP == 0 == rows % 32   # int8 tiles
 
 
 def indexer_inputs(B, T, HI, DI, seed=1):
@@ -487,6 +575,64 @@ def test_the_scores_are_made_in_bands_of_the_causal_width():
     np.testing.assert_allclose(np.where(causal, whole[0], 0),
                                np.where(causal, want[0], 0), atol=1e-5)
     assert np.isneginf(np.asarray(whole[0])[~causal]).all()
+
+
+def test_the_bands_write_one_map_no_pad_no_concatenate(monkeypatch):
+    """``index_select`` lowered for the TPU where the plan finds a strip:
+    a scores kernel a band, then ONE ``index_threshold`` whose operands are
+    the four bands' scores and whose first result is the whole int8 map,
+    and no ``pad`` or ``concatenate`` with an int8 array of ``T`` columns
+    on either side — which the XLA form, taken where there is no strip,
+    does hold.  The share of strips whose tie bisection ran comes with
+    both forms."""
+    import re
+
+    B, T, topk = 1, 512, 48
+    shapes = (jax.ShapeDtypeStruct((B, T, 2, 64), jnp.bfloat16),
+              jax.ShapeDtypeStruct((B, T, 64), jnp.bfloat16),
+              jax.ShapeDtypeStruct((B, T, 2), jnp.float32))
+
+    def lowered():
+        jax.clear_caches()
+        return jax.jit(lambda *a: ss.index_select_counted(
+            *a, topk, tile=128)).trace(*shapes).lower(
+            lowering_platforms=("tpu",)).as_text()
+
+    def map_moves(text):
+        return [line for line in text.splitlines()
+                if re.search(r"stablehlo\.(pad|concatenate)", line)
+                and f"x{T}xi8>" in line]
+
+    text = lowered()
+    assert re.findall(r'kernel_name = "([^"]+)"', text) == [
+        *["index_scores"] * 4, "index_threshold"]
+    call, = [line for line in text.splitlines()
+             if 'kernel_name = "index_threshold"' in line]
+    assert call[call.rindex(" : ("):].startswith(
+        " : (tensor<1x128x128xf32>, tensor<1x128x256xf32>, "
+        "tensor<1x128x384xf32>, tensor<1x128x512xf32>) -> "
+        "(tensor<1x512x512xi8>, ")
+    assert "output_operand_alias" not in call and not map_moves(text)
+    monkeypatch.setattr(ss, "_threshold_plan", lambda *a: (0, 0))
+    text = lowered()
+    assert re.findall(r'kernel_name = "([^"]+)"', text) == ["index_scores"] * 4
+    assert len(map_moves(text)) >= 2          # the pads, the concatenate
+    jax.clear_caches()
+
+    # (Positive products: no relu makes a score exactly zero, so none tie.)
+    qi, ki, w = (jnp.abs(a) for a in indexer_inputs(B, T, 2, 64, seed=9))
+    want = ss.index_select_counted(qi, ki, w, topk, tile=128, interpret=True)
+    assert float(want[2]) == 1.0
+    monkeypatch.undo()
+    got = ss.index_select_counted(qi, ki, w, topk, tile=128, interpret=True)
+    assert (got[0] == want[0]).all() and float(got[2]) == 0.0
+    np.testing.assert_allclose(got[1], want[1], rtol=1e-6)
+    # Halves tie by the dozen: every strip past the first rows' runs the
+    # bisection on the key index.
+    tied = ss.index_select_counted(jnp.round(qi), jnp.round(ki),
+                                   jnp.round(w), topk, tile=128,
+                                   interpret=True)
+    assert float(tied[2]) == 1.0
 
 
 # ------------------------------------------------------- the indexer's loss

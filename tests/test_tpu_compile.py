@@ -120,7 +120,7 @@ HEAD_KERNEL_EQUATIONS = {"_dkdv_kernel_grouped": 176,
 
 @pytest.mark.parametrize("family", ["flash", "scan", "passes", "experts",
                                     "selected", "selected_pair",
-                                    "grouped_kv"])
+                                    "threshold", "grouped_kv"])
 def test_each_kernel_is_traced_once_a_step_not_once_a_layer(monkeypatch,
                                                             family):
     """The set-up guard, no chip and no compile: tracing ``jax.grad`` of a
@@ -169,7 +169,11 @@ def test_each_kernel_is_traced_once_a_step_not_once_a_layer(monkeypatch,
     heads each — is traced once, and every layer leaves its two kernels;
     ``selected_pair``: the same where the plan takes the dq / dk-dv pair
     (a budget of 0 for the resident gradients): three bodies, once each,
-    three kernels a layer.
+    three kernels a layer.  ``threshold``: the same two layers at a
+    sequence of 512 and tiles of 128, where ``_threshold_plan`` takes the
+    selection's kernel: ``index_threshold``'s driver is shared by the
+    layers, so the kernel is traced once — its strip body once a band's
+    width, four — and every layer leaves its one kernel.
 
     ``grouped_kv``: three attention layers of 4 query heads over 2 KV
     heads of 128 and no map (``zaya1_1chip`` has six such, PR 44).  The
@@ -182,7 +186,8 @@ def test_each_kernel_is_traced_once_a_step_not_once_a_layer(monkeypatch,
 
     from horovod_tpu.models import KeyeLM, NemotronHLM, TransformerLM
     from horovod_tpu.ops import flash_attention as fa
-    from horovod_tpu.ops import grouped_matmul, mixer_passes, ssd
+    from horovod_tpu.ops import (
+        grouped_matmul, mixer_passes, sparse_select, ssd)
 
     calls = collections.Counter()
 
@@ -199,6 +204,10 @@ def test_each_kernel_is_traced_once_a_step_not_once_a_layer(monkeypatch,
                  "_select_fwd_kernel", "_select_dq_kernel",
                  "_select_dkdv_kernel", "_select_bwd_kernel"):
         monkeypatch.setattr(fa, name, counted(name, getattr(fa, name)))
+    if family == "threshold":
+        for name in ("_threshold_kernel", "_threshold_strip"):
+            monkeypatch.setattr(sparse_select, name,
+                                counted(name, getattr(sparse_select, name)))
     if family == "scan":
         for name in ("_fwd_kernel", "_states_kernel", "_bwd_kernel"):
             monkeypatch.setattr(ssd, name,
@@ -217,18 +226,23 @@ def test_each_kernel_is_traced_once_a_step_not_once_a_layer(monkeypatch,
     # No other test's, nor another case's: a trace made earlier would be
     # shared.
     batch = {"flash": 3, "scan": 3, "passes": 5, "experts": 2,
-             "selected": 1, "selected_pair": 1, "grouped_kv": 1}[family]
-    if family.startswith("selected"):
+             "selected": 1, "selected_pair": 1, "threshold": 1,
+             "grouped_kv": 1}[family]
+    if family.startswith("selected") or family == "threshold":
         if family == "selected_pair":
             monkeypatch.setattr(fa, "_FUSED_RESIDENT_BYTES", 0)
-        seq = 256
+        seq, tile = (512, 128) if family == "threshold" else (256, 64)
         model = KeyeLM(vocab=512, dim=256, num_heads=8, kv_heads=1,
                        pattern="SS", max_len=seq, attn="flash",
                        dtype=jnp.bfloat16,
                        indexer=dict(num_heads=2, head_dim=64, topk=32,
-                                    tile=64))
+                                    tile=tile))
         want = {"selected": {"_select_fwd_kernel": 1,
                              "_select_bwd_kernel": 1},
+                "threshold": {"_select_fwd_kernel": 1,
+                              "_select_bwd_kernel": 1,
+                              "_threshold_kernel": 1,
+                              "_threshold_strip": 4},
                 "selected_pair": {"_select_fwd_kernel": 1,
                                   "_select_dq_kernel": 1,
                                   "_select_dkdv_kernel": 1}}[family]
@@ -264,7 +278,8 @@ def test_each_kernel_is_traced_once_a_step_not_once_a_layer(monkeypatch,
     params = jax.eval_shape(
         lambda key: model.init(key, jnp.zeros((1, seq), jnp.int32))["params"],
         jax.random.PRNGKey(0))
-    if family.startswith("selected") or family == "grouped_kv":
+    if family.startswith("selected") or family in ("threshold",
+                                                   "grouped_kv"):
         # ``init`` ran the forward with the step's own shapes, and the
         # forward rule would share that trace.
         jax.clear_caches()
@@ -300,6 +315,11 @@ def test_each_kernel_is_traced_once_a_step_not_once_a_layer(monkeypatch,
     if family == "grouped_kv":
         assert collections.Counter(name for name, _ in found) == {
             "_fwd_kernel_fullunroll": 3, "flash_group_bwd": 3}
+        return
+    if family == "threshold":
+        names = collections.Counter(name for name, _ in found)
+        assert (names["index_threshold"], names["index_scores"]) == (2, 8)
+        jax.clear_caches()
         return
     if family.startswith("selected"):
         names = collections.Counter(name for name, _ in found)
@@ -968,8 +988,11 @@ def test_selected_attention_fwd_bwd_at_keye_widths(v5e, monkeypatch,
     """One layer's sparse attention as ``GroupedQueryAttention(indexer=…)``
     calls it — 32 query over 4 KV heads of 128 at T 16,384, an indexer of
     16 heads of 64 that keeps 2,048 keys a query — compiles for the chip:
-    the scores in four bands (``index_scores``), the exact top-k as plain
-    XLA with no sort and no approximate top-k, the selected attention a KV
+    the scores in four bands (``index_scores``), the exact top-k ONE kernel
+    over the four bands' strips (``index_threshold``: strips of 128 rows
+    under its stated 64 MB, each writing its rows of the one int8 map, so
+    the compiled step holds no pad, concatenate or copy of it), with no
+    sort and no approximate top-k, the selected attention a KV
     group a grid step (``flash_select_*``: the int8 (1, T, T) map an
     operand of each, grids over the 4 KV heads, the eight heads of a group
     one (block, 1024) block), and the KL pass (``index_kl``).  At every
@@ -1037,8 +1060,12 @@ def test_selected_attention_fwd_bwd_at_keye_widths(v5e, monkeypatch,
     lowered_text = lowered.as_text()
     names = re.findall(r'kernel_name = "([^"]+)"', lowered_text)
     assert sorted(set(names)) == [
-        "flash_select_bwd", "flash_select_fwd", "index_kl", "index_scores"]
+        "flash_select_bwd", "flash_select_fwd", "index_kl", "index_scores",
+        "index_threshold"]
     assert names.count("index_scores") == 4                # the bands
+    assert names.count("index_threshold") == 1
+    assert not re.search(r"stablehlo\.(pad|concatenate)[^\n]*x16384xi8>",
+                         lowered_text)
     # The map is an operand of the two kernels, whose row statistics come
     # a KV head (4), not a query head (32); the backward runs under the
     # plan's own budget.
@@ -1056,10 +1083,15 @@ def test_selected_attention_fwd_bwd_at_keye_widths(v5e, monkeypatch,
     assert (limits["flash_select_fwd"], limits["flash_select_bwd"]) == (
         32, fa._SELECT_FUSED_VMEM_MB) == (32, 64)
     assert limits["index_kl"] == sparse_select._KL_VMEM_MB == 96
+    assert sparse_select._threshold_plan(T // 4, 4, 512, True) == (
+        128, limits["index_threshold"])
+    assert limits["index_threshold"] == sparse_select._THRESHOLD_VMEM_MB == 64
     compiled = lowered.compile()
     text = compiled.as_text()
     assert "approx" not in text.lower() and " sort(" not in text
     assert "s8[1,16384,16384]" in text
+    assert not re.search(
+        r"= s8\[1,16384,16384\]\S* (copy|pad|concatenate|fusion)\(", text)
     assert "f32[1,16,16384,16384]" not in text
     assert "f32[1,16384,16,16384]" not in text
     _, grads = compiled.out_info
@@ -1165,6 +1197,109 @@ def test_the_kl_pass_compiles_at_every_tiling(v5e, monkeypatch, tiling,
     kl, dqi, dki, dw = lowered.compile().out_info
     assert [a.shape for a in (kl, dqi, dki, dw)] == [
         (B, T), (B, T, HI, DI), (B, T, DI), (B, T, HI)]
+
+
+# The scoped VMEM the compiler counts for the selection's kernel alone:
+# {(rows a band, bands): {strip rows: MB}} (found by bisection on the limit
+# in the sandbox, PR 47).  The first is the cell's.
+THRESHOLD_COUNTED_MB = {
+    (4096, 4): {256: 93, 128: 49, 64: 27},
+    (2048, 4): {256: 47, 128: 25, 64: 14},
+    (4096, 2): {256: 31, 128: 17, 64: 10},
+    (4096, 1): {256: 12, 128: 7, 64: 5}}
+
+
+@pytest.mark.parametrize("rows,bands", THRESHOLD_COUNTED_MB,
+                         ids=lambda v: str(v))
+def test_the_selection_compiles_at_every_strip(v5e, rows, bands):
+    """``index_threshold`` over one, two and four bands at T 4,096 to
+    16,384, at every strip ``_threshold_plan`` can take (256 to 64 rows; a
+    strip of every band in VMEM, the keys of one group of 64 rows in
+    scratch): each compiles within what the compiler counted for it,
+    ``_threshold_vmem_bytes`` says no less and at most 2 MB more, and
+    whatever the formula admits under the stated 64 MB or under Mosaic's
+    default 16 MB compiles there — at the cell's shape strips of 128 rows
+    with 14 MB to spare, and nothing under the default."""
+    from horovod_tpu.ops import sparse_select as ss
+
+    counted_mb = THRESHOLD_COUNTED_MB[rows, bands]
+    assert tuple(counted_mb) == ss._THRESHOLD_ROWS
+    one = SingleDeviceSharding(v5e[0])
+    operands = [jax.ShapeDtypeStruct((1, rows, (b + 1) * rows), jnp.float32,
+                                     sharding=one) for b in range(bands)]
+    T = rows * bands
+
+    def first_under(mb):
+        return next((r for r in ss._THRESHOLD_ROWS
+                     if ss._threshold_vmem_bytes(r, rows, bands)
+                     <= mb * 2 ** 20), 0)
+
+    assert ss._threshold_plan(rows, bands, 512, True) == (
+        first_under(ss._THRESHOLD_VMEM_MB), ss._THRESHOLD_VMEM_MB)
+    assert ss._threshold_plan(rows, bands, 512, False) == (
+        first_under(ss._MOSAIC_DEFAULT_VMEM_MB), 0)
+    if (rows, bands) == (4096, 4):
+        assert (first_under(64), first_under(16)) == (128, 0)
+    for block_rows, counted in counted_mb.items():
+        said = ss._threshold_vmem_bytes(block_rows, rows, bands) / 2 ** 20
+        assert counted - 1 <= said <= counted + 2, (block_rows, said)
+        limits = {counted}
+        if said <= ss._MOSAIC_DEFAULT_VMEM_MB:
+            limits.add(0)
+        if said <= ss._THRESHOLD_VMEM_MB:
+            limits.add(ss._THRESHOLD_VMEM_MB)
+        for limit in limits:
+            lowered = jax.jit(lambda *a, strip=block_rows, mb=limit:
+                              ss.index_threshold(*a, topk=2048,
+                                                 block_rows=strip, vmem_mb=mb)
+                              ).lower(*operands)
+            text = lowered.as_text()
+            assert custom_calls(text) == [("index_threshold", bands)]
+            assert scoped_vmem_mb(text) == {"index_threshold": limit}
+            assert "output_operand_alias" not in text
+            select, lse, ties = lowered.compile().out_info
+            assert (select.shape, lse.shape, ties.shape) == (
+                (1, T, T), (1, T), (1, T // block_rows))
+            assert str(select.dtype) == "int8"
+    jax.clear_caches()
+
+
+def test_the_selection_keeps_its_xla_form_where_no_strip_fits(v5e,
+                                                              monkeypatch):
+    """Without head-room the indexer's selection still lowers: at the
+    cell's T 16,384 as ``select_rows`` — the parent's pads and concatenate,
+    no ``index_threshold``: a strip of 64 rows of every band is 28 MB by
+    ``_threshold_vmem_bytes``, over Mosaic's default 16 —, at half that
+    length with strips of 64 rows under the default (``vmem`` 0 on
+    ``index_threshold``); at a T of 65,536 no strip fits the stated budget
+    either."""
+    import re
+
+    from horovod_tpu.ops import _pallas, sparse_select as ss
+
+    one = SingleDeviceSharding(v5e[0])
+
+    def lowered(T, headroom):
+        monkeypatch.setattr(_pallas, "vmem_headroom_ok", lambda: headroom)
+        jax.clear_caches()
+        shapes = ((1, T, 16, 64), (1, T, 64), (1, T, 16))
+        text = jax.jit(lambda *a: ss.index_select(*a, 2048)).lower(*(
+            jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one)
+            for shape in shapes)).as_text()
+        return (re.findall(r'kernel_name = "([^"]+)"', text),
+                scoped_vmem_mb(text),
+                bool(re.search(r"stablehlo\.concatenate[^\n]*xi8>", text)))
+
+    names, _, concatenated = lowered(16_384, False)
+    assert names == ["index_scores"] * 4 and concatenated
+    assert ss._threshold_plan(4096, 4, 512, False) == (0, 0)
+    names, limits, concatenated = lowered(8192, False)
+    assert names.count("index_threshold") == 1 and not concatenated
+    assert limits["index_threshold"] == 0
+    assert ss._threshold_plan(2048, 4, 512, False) == (64, 0)
+    names, _, concatenated = lowered(65_536, True)
+    assert names == ["index_scores"] * 4 and concatenated
+    jax.clear_caches()
 
 
 @pytest.mark.parametrize("entry,b,t,h,hkv,kernels", [
