@@ -36,7 +36,12 @@ through the entry points a user calls (``hvd.init()`` →
   per call, then four scanned steps per call) and with ResNet-50 at
   batch 128, parameters from each model's own ``init`` under ``--seed``,
   and requires finite, falling losses and the Pallas kernels compiled
-  (``tpu_custom_call``) in the step that ran.
+  (``tpu_custom_call``) in the step that ran;
+* traces five more steps of that TransformerLM, fed by a
+  ``ShardedLoader``, with ``horovod_tpu.profiling.capture`` and reads the
+  one file it writes: the device's ops and the span ring's spans on one
+  clock, no traced call ending before the device did, and what the
+  writing cost (``one_file``).
 
 ``--chips 4`` runs instead, and only, what exists across chips: the
 launcher giving four children a chip each, the mesh order, eager and
@@ -1137,6 +1142,83 @@ def transformer_phase(mesh, events, *, vocab, dim, depth, heads, seq,
             "memory": memory_peaks(mesh.devices.flat)}
 
 
+def one_file_phase(mesh, *, vocab, dim, depth, heads, seq, batch, steps,
+                   seed, lr=0.01, **_) -> dict:
+    """``steps`` optimizer steps of the TransformerLM, each batch through a
+    ``ShardedLoader``, under ``profiling.capture``: the one file it leaves
+    holds the profiler's processes and the ring's spans of the capture on
+    the device's clock.  Says where the shift came from and how late the
+    host's reads were, which spans and which of the step's scopes the file
+    holds, and what reading, merging and writing it took (the ring's
+    ``profile/one_file`` span)."""
+    import gzip
+    import itertools
+
+    import jax
+    import numpy as np
+    import optax
+    from jax.sharding import PartitionSpec as P
+
+    from horovod_tpu import profiling, timeline
+    from horovod_tpu.data import ShardedLoader
+    from horovod_tpu.jax.spmd import STEP_SCOPES, make_train_step
+
+    init, loss_fn, make_tokens = transformer_problem(
+        vocab=vocab, dim=dim, depth=depth, heads=heads, seq=seq, seed=seed)
+    tx = optax.sgd(lr, momentum=0.9)
+    params = put(init(), mesh, P())
+    state = [params, {}, tx.init(params)]
+    step = make_train_step(loss_fn, tx, mesh, sync_aux_state=False)
+    loader = iter(ShardedLoader(
+        itertools.cycle([np.asarray(make_tokens(batch))]), mesh, prefetch=2))
+    losses = []
+
+    def run():
+        out = step(*state, next(loader))
+        state[:] = out[:3]
+        losses.append(float(out[3]))    # the read that ends the call
+
+    path = profiling.one_file(
+        profiling.capture(run, warmup=2, iters=steps))
+    loader.close()
+    with gzip.open(path) as fh:
+        one = json.load(fh)
+    note = one["metadata"]["horovod_tpu"]
+    events = one["traceEvents"]
+    host_pid = next(e["pid"] for e in events
+                    if e.get("name") == "process_name" and e["args"]["name"]
+                    == timeline.SpanRing.PROCESS_NAME)
+    host = [e for e in events if e.get("ph") == "X" and e["pid"] == host_pid]
+    names = sorted({e["name"] for e in host})
+    check({"profile/run", "step/dispatch", "step/enqueue"} <= set(names)
+          and any(n.startswith("loader/") for n in names),
+          f"the one file lacks spans of the ring: {names}")
+    check(all(e["ts"] + e["dur"] >= 0.0 for e in host),
+          "a span from before the session is in the one file")
+    stacks = [e["args"]["tf_op"] for e in events
+              if e.get("ph") == "X" and "tf_op" in e.get("args", {})]
+    if jax.default_backend() == "tpu":
+        check(note["shift_from"] == "device_ends"
+              and note["device_processes"] == mesh.size,
+              f"the shift is not the device's: {note}")
+    written = [s for s in timeline.ring.snapshot()
+               if s.name == "profile/one_file"][-1]
+    return {"steps": steps, "losses": [round(l, 5) for l in losses[-steps:]],
+            "shift_from": note["shift_from"],
+            "residual_us": note.get("residual_us"),
+            "host_after_device_us": note.get("host_after_device_us"),
+            "session_opened_before_shift_us": (
+                note["shift_ns"] - note["session_opened_shift_ns"]) / 1e3,
+            "device_processes": note["device_processes"],
+            "events": len(events), "host_spans": len(host),
+            "host_span_names": names,
+            "device_ops_under_scope": {
+                scope: sum(f"/{scope}/" in s for s in stacks)
+                for scope in STEP_SCOPES},
+            "one_file_s": round((written.end_ns - written.start_ns) / 1e9, 3),
+            "one_file_bytes": os.path.getsize(path), "one_file": path}
+
+
 def resnet_phase(mesh, events, *, stage_sizes, num_filters, num_classes,
                  image, batch, steps, seed, lr=0.01) -> dict:
     """ResNet through make_train_step on ``mesh`` with batch statistics
@@ -1498,6 +1580,8 @@ def main(argv=None) -> int:
             mesh, events, **ONE_CHIP_LM, seed=args.seed))
         emit("resnet50", **resnet_phase(
             mesh, events, **ONE_CHIP_RESNET, seed=args.seed))
+        emit("one_file", **one_file_phase(
+            mesh, **{**ONE_CHIP_LM, "steps": 5}, seed=args.seed))
     else:
         emit("mesh", **mesh_phase(hvd, 4))
         emit("eager_collectives", **eager_phase(hvd, 4))

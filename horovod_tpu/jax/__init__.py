@@ -261,23 +261,30 @@ def allreduce_gradients(grads, *, axis_name=RANKS_AXIS, average: bool = True,
                 red = (lax.pmean(c, axis_name) if average
                        else lax.psum(c, axis_name))
             return leaf_comp.decompress(red, ctx)
+        import jax.tree_util as jtu
+        from horovod_tpu.jax.spmd import step_scope
         if auto:
             # Per-leaf wire dtype from the autopilot mirror, read at
             # TRACE time (the compiled program bakes the plan in; the
             # caller retraces when the mirror's plan_version moves —
             # make_train_step(compression="auto") does this itself).
-            import jax.tree_util as jtu
             from horovod_tpu import precision as _precision
             from horovod_tpu.compression import compressor_for_wire
             pilot = _precision.get_autopilot()
+
+            def comp_of(path):
+                return compressor_for_wire(pilot.wire_dtype_for(
+                    f"{name_prefix}{jtu.keystr(path)}"))
+        else:
+            def comp_of(path):
+                return compression
+        # Under the step's ``grad_reduce`` scope, as reduce_gradients is:
+        # inside DistributedOptimizer.update a trace reads
+        # ``optimizer/grad_reduce/...``.
+        with step_scope("grad_reduce"):
             return jtu.tree_map_with_path(
-                lambda path, g: one(g, compressor_for_wire(
-                    pilot.wire_dtype_for(
-                        f"{name_prefix}{jtu.keystr(path)}"))),
-                grads, is_leaf=_is_sparse)
-        comp = compression
-        return jax.tree.map(lambda g: one(g, comp), grads,
-                            is_leaf=_is_sparse)
+                lambda path, g: one(g, comp_of(path)), grads,
+                is_leaf=_is_sparse)
     # Eager path: compression is applied per-leaf around the negotiated op.
     leaves, treedef = jax.tree.flatten(grads, is_leaf=_is_sparse)
     flat_arrays = [a for l in leaves

@@ -45,7 +45,37 @@ from horovod_tpu.parallel._vma import ensure_varying_tree
 from horovod_tpu.parallel.hierarchical import hierarchical_allreduce
 from horovod_tpu.parallel.mesh import DCN_AXIS, ICI_AXIS
 
+#: The phases of the step that no flax module names, as trace scopes
+#: (``jax.named_scope``): a profiler's trace then reads
+#: ``grad_reduce/psum``, ``optimizer/add``, ``aux_sync/pmax`` where it read
+#: a bare primitive.  The forward and backward pass carry the model's own
+#: names and stand under none of these.  A reader of a device trace looks
+#: for this constant to tell a program that has the scopes, and ran nothing
+#: alone under one, from a program that has none.
+STEP_SCOPES = ("grad_reduce", "optimizer", "aux_sync")
 
+
+def step_scope(name: str):
+    """One of :data:`STEP_SCOPES` as a context manager.  Metadata only: the
+    lowered step's text, the compile cache's key and the compiled program
+    are what they were without it — so a program cached before the scopes
+    existed keeps its old names until it is compiled again."""
+    if name not in STEP_SCOPES:
+        raise ValueError(f"{name!r} is none of {STEP_SCOPES}")
+    return jax.named_scope(name)
+
+
+def _under_step_scope(name: str):
+    def wrap(fn):
+        @functools.wraps(fn)
+        def scoped(*args, **kwargs):
+            with step_scope(name):
+                return fn(*args, **kwargs)
+        return scoped
+    return wrap
+
+
+@_under_step_scope("grad_reduce")
 def reduce_gradients(grads, axis_names: Tuple[str, ...], *,
                      average: bool = True,
                      compression: Compressor = NoneCompressor,
@@ -56,7 +86,10 @@ def reduce_gradients(grads, axis_names: Tuple[str, ...], *,
 
     Uses the hierarchical two-tier path when the mesh is ('dcn', 'ici'),
     else a flat psum/pmean.  ``compression`` casts to the wire dtype around
-    the collective (reference ``Compression.fp16``).
+    the collective (reference ``Compression.fp16``).  All of it — the wire
+    casts, the bucket staging of the hierarchical and int8 paths, the
+    collectives, the division by the mesh size — is traced under the
+    ``grad_reduce`` scope (:data:`STEP_SCOPES`), whoever calls.
 
     Fusion story (the in-jit analogue of the reference's fusion buffer,
     ``operations.cc:1807-1842``): on a FLAT mesh, one pmean/psum
@@ -585,8 +618,10 @@ class _StepInstruments:
     ``step/first_call``), keyed by the call ordinal; below it are the
     guard's ``step/enqueue`` and whatever jax reports while it runs
     (:func:`horovod_tpu.timeline.listen_to_jax`), so the span's self time
-    is the wrappers'.  That one pair of clock reads is what the others
-    read:
+    is the wrappers'.  (Those are the host's side; what the device does
+    in a step is named by the model's scopes and :data:`STEP_SCOPES`, and
+    :func:`horovod_tpu.profiling.capture` writes both into one file.)
+    That one pair of clock reads is what the others read:
 
     * the Horovod-style timeline, when one is configured
       (``HOROVOD_TPU_TIMELINE``, rank 0): the ``DISPATCH`` lane
@@ -709,6 +744,16 @@ class _StepInstruments:
         return _wrap_with_stages(fn, around)
 
 
+def _apply_update(optimizer, grads, opt_state, params):
+    """The optimizer's update and its application to ``params``, under the
+    ``optimizer`` scope: ``(params, opt_state)``.  Where the optimizer
+    reduces for itself (``DistributedOptimizer``) that reads
+    ``optimizer/grad_reduce/…`` and counts as reduction."""
+    with step_scope("optimizer"):
+        updates, opt_state = optimizer.update(grads, opt_state, params)
+        return optax.apply_updates(params, updates), opt_state
+
+
 def make_train_step(
     loss_fn: Callable,
     optimizer: optax.GradientTransformation,
@@ -740,6 +785,15 @@ def make_train_step(
     (params, aux_state, opt_state, loss)`` — one XLA program containing
     forward, backward, gradient allreduce, and the optimizer update (the
     whole of SURVEY §3.2's multi-thread hot path, statically scheduled).
+    In a device trace the first two carry the model's own names (flax
+    modules, the layers' scopes) and the rest :data:`STEP_SCOPES`:
+    ``grad_reduce`` (:func:`reduce_gradients`), ``optimizer``
+    (``optimizer.update`` and ``optax.apply_updates``) and ``aux_sync``
+    (the aux state's sync and the loss's ``pmean``; the ``shard_map``
+    program only).  A fusion takes its root's name, so an update that the
+    compiler fuses into a weight-gradient fusion stays under the
+    gradient's name.  On the host the step is the span ring's ``step/*``
+    (:class:`_StepInstruments`).
 
     ``steps_per_call > 1`` runs that many optimizer steps per dispatch with
     a ``lax.scan``: every batch leaf gains a leading ``steps_per_call``
@@ -823,10 +877,11 @@ def make_train_step(
         grads = reduce_gradients(grads, axes, average=average,
                                  compression=compression, fuse=fuse,
                                  overlap=overlap)
-        updates, opt_state = optimizer.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
-        new_aux = _sync_or_check_aux(new_aux, axes, sync_aux_state)
-        loss = lax.pmean(loss, axes)
+        params, opt_state = _apply_update(optimizer, grads, opt_state,
+                                          params)
+        with step_scope("aux_sync"):
+            new_aux = _sync_or_check_aux(new_aux, axes, sync_aux_state)
+            loss = lax.pmean(loss, axes)
         return params, new_aux, opt_state, loss
 
     replicated = P()
@@ -868,8 +923,8 @@ def make_train_step(
     def plain_one(params, aux_state, opt_state, batch):
         (loss, new_aux), grads = jax.value_and_grad(
             loss_fn, has_aux=True)(params, aux_state, batch)
-        updates, opt_state = optimizer.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
+        params, opt_state = _apply_update(optimizer, grads, opt_state,
+                                          params)
         return params, new_aux, opt_state, loss
 
     if steps_per_call > 1:

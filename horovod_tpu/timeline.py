@@ -318,10 +318,13 @@ class SpanRing:
     annotations: the host tracer's lowest level that keeps annotations
     also keeps the runtime's per-tile ``Transpose`` spans, a million of
     them in five steps of a 38.5 MB batch (``PERF.md`` section 6, PR 34).
-    :meth:`events` carries one ``(perf_counter_ns, time_ns)`` pair taken
-    together instead, which sets the spans on the wall clock.
+    :meth:`events` takes the shift between this clock and another
+    trace's instead, and :func:`horovod_tpu.profiling.capture`, which
+    finds that shift from the trace it records, writes the device's ops
+    and these spans into one file on one clock.
     """
 
+    PROCESS_NAME = "host (horovod_tpu ring)"
     SLACK_NS = 1_000_000    # between jax's clocks and ours, for add()
 
     def __init__(self, maxlen: int = 16384):
@@ -384,24 +387,24 @@ class SpanRing:
         """The kept spans, oldest first."""
         return [Span(*record) for record in self._spans.copy()]
 
-    def events(self) -> List[dict]:
-        """The kept spans as Chrome-trace complete events (``ts`` and
-        ``dur`` in microseconds of ``time.perf_counter_ns()``), with their
-        threads' names: ``json.dump`` them for Perfetto.  The first event,
-        ``clock_pair``, holds one reading of that clock and of
-        ``time.time_ns()`` taken together: what moves the spans onto the
-        wall clock, beside a trace that is on it."""
-        pid = os.getpid()
+    def events(self, shift_ns: int = 0,
+               pid: Optional[int] = None) -> List[dict]:
+        """The kept spans as Chrome-trace complete events of one process,
+        :attr:`PROCESS_NAME`, with their threads' names: ``json.dump``
+        them for Perfetto.  ``ts`` and ``dur`` are microseconds of
+        ``time.perf_counter_ns()`` less ``shift_ns``: the shift is what
+        sets the spans on another trace's clock, and ``pid`` what keeps
+        them clear of its processes (this process's id by default)."""
+        pid = os.getpid() if pid is None else pid
         spans = self.snapshot()
-        out = [{"ph": "M", "pid": pid, "name": "clock_pair",
-                "args": {"perf_counter_ns": time.perf_counter_ns(),
-                         "time_ns": time.time_ns()}}]
+        out = [{"ph": "M", "pid": pid, "name": "process_name",
+                "args": {"name": self.PROCESS_NAME}}]
         out.extend({"ph": "M", "pid": pid, "tid": ident,
                     "name": "thread_name", "args": {
                         "name": self._thread_names.get(ident, str(ident))}}
                    for ident in sorted({s.thread for s in spans}))
         out.extend({"ph": "X", "pid": pid, "tid": s.thread, "name": s.name,
-                    "ts": s.start_ns / 1e3,
+                    "ts": (s.start_ns - shift_ns) / 1e3,
                     "dur": (s.end_ns - s.start_ns) / 1e3,
                     "args": {"id": s.id, "parent": s.parent, "key": s.key}}
                    for s in spans)

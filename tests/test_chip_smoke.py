@@ -268,6 +268,22 @@ def test_transformer_phase(smoke, one_device_mesh):
     assert out["steps_per_call_2"]["losses"][0] < single["losses"][0]
 
 
+def test_one_file_phase(smoke, one_device_mesh, monkeypatch, tmp_path):
+    """On the CPU the one file holds the host's process alone, on the
+    session's clock; the phase still finds the ring's spans in it."""
+    import tempfile
+    monkeypatch.setattr(tempfile, "tempdir", str(tmp_path))
+    out = smoke.one_file_phase(one_device_mesh, **TINY_LM, batch=2, steps=3,
+                               seed=0, lr=0.1, scan_steps=2)
+    assert out["shift_from"] == "session_opened"
+    assert out["device_processes"] == 0 and out["residual_us"] is None
+    assert {"profile/run", "step/dispatch", "step/enqueue",
+            "loader/stage"} <= set(out["host_span_names"])
+    assert len(out["losses"]) == 3 and out["losses"][-1] < out["losses"][0]
+    assert out["one_file"].startswith(str(tmp_path))
+    assert out["one_file_s"] >= 0 and out["one_file_bytes"] > 0
+
+
 def test_resnet_phase(smoke, one_device_mesh):
     out = smoke.resnet_phase(one_device_mesh, NoCache(), stage_sizes=(1, 1),
                              num_filters=8, num_classes=10, image=32,

@@ -8,6 +8,7 @@ takes (``since``), so tests that share a worker do not see each other's.
 
 import gc
 import json
+import os
 import threading
 import time
 
@@ -175,13 +176,16 @@ def test_events_are_chrome_trace_complete_events_with_thread_names():
     (m,) = [e for e in events if e.get("name") == "thread_name"]
     assert m["args"]["name"] == "horovod_tpu-data-prefetch"
     assert m["tid"] == x["tid"]
-    # One reading of both clocks, taken together: the spans' clock and the
-    # wall clock a profiler's trace is on.
-    pair = events[0]
-    assert pair["name"] == "clock_pair"
-    wall_of_span = pair["args"]["time_ns"] - (
-        pair["args"]["perf_counter_ns"] - x["ts"] * 1e3)
-    assert abs(wall_of_span - time.time_ns()) < 5e9
+    # One process, named, under this process's id unless told another.
+    (proc,) = [e for e in events if e.get("name") == "process_name"]
+    assert proc["args"]["name"] == "host (horovod_tpu ring)"
+    assert proc["pid"] == x["pid"] == os.getpid()
+    # The shift sets the spans on another trace's clock: that much earlier,
+    # the same length; the pid keeps them clear of its processes.
+    (moved,) = [e for e in r.events(shift_ns=2_000_000, pid=7)
+                if e["ph"] == "X"]
+    assert moved["ts"] == pytest.approx(x["ts"] - 2_000.0)
+    assert moved["dur"] == x["dur"] and moved["pid"] == 7
 
 
 def test_a_full_collection_is_a_span_and_a_young_one_is_not():
