@@ -32,6 +32,11 @@ through the entry points a user calls (``hvd.init()`` →
   forward, the per-head pair and the one fused kernel a KV group — checks
   the fused kernel's gradients against the pair's and prints which of the
   two ``flash_attention._plan`` takes here (``gqa_plan``);
+* checks the latent's passes of compressed convolutional attention as
+  kernels at ``zaya1_1chip``'s layer — 8 query over 2 KV heads of 128, one
+  sequence of 16,384 — against the module's ``jax.numpy`` form: q", k" and
+  the gradients of every operand, with each kernel's time alone and the
+  plan (``cca_reference``, ``cca_plan``);
 * takes optimizer steps with the d=2048/T=2048 TransformerLM (one step
   per call, then four scanned steps per call) and with ResNet-50 at
   batch 128, parameters from each model's own ``init`` under ``--seed``,
@@ -123,6 +128,11 @@ GROUPED_BACKWARD = {
                         head_dim=128),
     "twotower_1chip": dict(batch=2, seq=8192, heads=32, kv_heads=2,
                            head_dim=128)}
+# The latent's passes of compressed convolutional attention at the
+# zaya1_1chip cell's layer (ZAYA1-8B: two taps and two, half of each head
+# rotated at theta 5e6).
+CCA_REFERENCE = dict(batch=1, seq=16384, heads=8, kv_heads=2, head_dim=128,
+                     taps=(2, 2), rotary_width=64, rope_theta=5e6)
 ONE_CHIP_LM = dict(**TRANSFORMER, batch=8, steps=3, scan_steps=4)
 ONE_CHIP_RESNET = dict(stage_sizes=(3, 4, 6, 3), num_filters=64,
                        num_classes=1000, image=224, batch=128, steps=3)
@@ -145,6 +155,12 @@ SCAN_GRAD_TOL = 4e-2
 # PR 33, and the further side from the definition).  What is left is the
 # one rounding of a bfloat16 result, 2^-9 of a value.
 PASSES_TOL = 1e-2
+# The latent's kernels against the module's form on the same bfloat16
+# operands: the module rounds the grouped convolution's sums and its
+# gradients to bfloat16 where the kernels keep float32, so they stand a
+# few bfloat16 steps apart (chip, PR 49: 3.1e-3 of the largest value
+# forward, 6.3e-3 in the latents' gradients, 1.9e-3 in a parameter's).
+CCA_TOL = 2e-2
 # The grouped matmuls' kernels against ``lax.ragged_dot`` and its
 # transposes on the same bfloat16 operands: both accumulate in float32 and
 # round once (chip, PR 35: the two products equal, the weight gradient
@@ -508,6 +524,94 @@ def passes_reference_phase(*, batch: int, seq: int, heads: int,
               f"{PASSES_TOL})")
     return {"shape": [batch, seq, inner, conv_dim, groups],
             "interpret": interpret, "passes_plan": plan_dict,
+            **{k: round(e, 5) for k, e in errs.items()}}
+
+
+def cca_plan(seq: int, heads: int, kv_heads: int, head_dim: int,
+             taps=(2, 2)) -> dict:
+    """What ``cca_passes._plan`` decides on this device for the latent's
+    passes of compressed convolutional attention (bfloat16): the kernels or
+    the module's XLA form, the rows a block and a strip."""
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.ops import cca_passes
+
+    return cca_passes.cca_plan(
+        jax.ShapeDtypeStruct((1, seq, heads, head_dim), jnp.bfloat16),
+        kv_heads=kv_heads, taps=taps,
+        interpret=jax.default_backend() != "tpu")._asdict()
+
+
+def cca_reference_phase(*, batch: int, seq: int, heads: int, kv_heads: int,
+                        head_dim: int, taps, rotary_width: int,
+                        rope_theta: float, seed: int, calls: int = 10) -> dict:
+    """The latent's passes as the module calls them (``cca_mix``: one kernel
+    forward, one backward) against the form it keeps (``_cca_mix_xla``) on
+    the same bfloat16 operands, biases and temperatures off 0 and 1: q", k"
+    and the gradients of all seven operands; and each kernel's time
+    alone."""
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.models.transformer import _cca_mix_xla
+    from horovod_tpu.ops import cca_passes
+
+    interpret = jax.default_backend() != "tpu"
+    H, G, D, (t0, t1) = heads, kv_heads, head_dim, taps
+    ks = jax.random.split(jax.random.PRNGKey(seed), 9)
+    args = (jax.random.normal(ks[0], (batch, seq, H, D), jnp.bfloat16),
+            jax.random.normal(ks[1], (batch, seq, G, D), jnp.bfloat16),
+            0.7 * jax.random.normal(ks[2], ((H + G) * D, t0)),
+            0.3 * jax.random.normal(ks[3], ((H + G) * D,)),
+            jax.random.normal(ks[4], (H + G, t1, D, D)) / D ** 0.5,
+            0.3 * jax.random.normal(ks[5], (H + G, D)),
+            1.0 + 0.3 * jax.random.normal(ks[6], (G,)))
+    cotangents = (jax.random.normal(ks[7], args[0].shape, jnp.bfloat16),
+                  jax.random.normal(ks[8], args[1].shape, jnp.bfloat16))
+    plan_dict = cca_plan(seq, H, G, D, taps)
+    check(plan_dict["form"] == "kernels",
+          f"the latent's plan at the layer's shape is {plan_dict}")
+    plan = cca_passes.CcaPlan(**plan_dict)
+    rope = (float(rope_theta), int(rotary_width))
+
+    def forward(*a):
+        return cca_passes._mix_fwd(*a, rope=rope, plan=plan,
+                                   interpret=interpret)
+
+    def backward(*a):
+        return cca_passes._mix_bwd(*a, *cotangents, rope=rope, plan=plan,
+                                   interpret=interpret)
+
+    def module_form(*a):
+        out, pull = jax.vjp(lambda *a: _cca_mix_xla(
+            *a, taps=taps, dtype=jnp.bfloat16, rope_theta=rope_theta,
+            width=rotary_width), *a)
+        return out, pull(cotangents)
+
+    if not interpret:
+        names = kernels_in(jax.jit(lambda *a: (forward(*a), backward(*a)))
+                           .lower(*args).as_text())
+        check(names == ["cca_mix_bwd", "cca_mix_fwd"],
+              f"the latent's passes lowered to the kernels {names}")
+    fwd_ms, got_out = _timed_ms(calls, interpret, forward, *args)
+    bwd_ms, got_grads = _timed_ms(calls, interpret, backward, *args)
+    want_out, want_grads = jax.jit(module_form)(*args)
+    named = dict(zip(("q", "k"), zip(got_out, want_out)))
+    named.update(zip(("grad_q", "grad_k", "grad_w0", "grad_b0", "grad_w1",
+                      "grad_b1", "grad_temp"), zip(got_grads, want_grads)))
+    errs = {}
+    for name, (g, r) in named.items():
+        check(g.dtype == r.dtype and g.shape == r.shape,
+              f"the latent's kernels give {name} as {g.dtype}{g.shape}")
+        errs[name] = _rel_err(g, r)
+        check(errs[name] <= CCA_TOL,
+              f"the latent's kernels differ from the module's form in "
+              f"{name} by {errs[name]:.3g} of its largest value (bound "
+              f"{CCA_TOL})")
+    return {"shape": [batch, seq, H, G, D], "interpret": interpret,
+            "cca_plan": plan_dict,
+            "ms_a_layer": {"fwd_alone": fwd_ms, "bwd_alone": bwd_ms},
             **{k: round(e, 5) for k, e in errs.items()}}
 
 
@@ -1563,6 +1667,8 @@ def main(argv=None) -> int:
             **SCAN_ONE_GROUP, seed=args.seed))
         emit("passes_one_group", **passes_reference_phase(
             **PASSES_ONE_GROUP, seed=args.seed))
+        emit("cca_reference", **cca_reference_phase(
+            **CCA_REFERENCE, seed=args.seed))
         emit("delta_reference", **delta_reference_phase(
             **DELTA_REFERENCE, seed=args.seed))
         emit("experts_reference", **experts_reference_phase(

@@ -90,7 +90,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from horovod_tpu.layer_notes import note_layer
-from horovod_tpu.ops import _pallas
+from horovod_tpu.ops import _pallas, cca_passes
 from horovod_tpu.ops.flash_attention import (
     auto_block, flash_attention_auto, flash_qkv_proj, select_tile_fetches)
 from horovod_tpu.parallel.mesh import RANKS_AXIS
@@ -356,6 +356,49 @@ class GroupedQueryAttention(nn.Module):
         return out
 
 
+def _cca_mix_xla(q0, k0, w0, b0, w1, b1, temp, *, taps, dtype, rope_theta,
+                 width):
+    """``q"`` and ``k"`` from ``q~`` and ``k~`` in ``jax.numpy``: the
+    form :func:`~horovod_tpu.ops.cca_passes.cca_mix`'s kernels are held
+    to."""
+    B, T, H, D = q0.shape
+    G = k0.shape[2]
+    t0, t1 = taps
+    f32 = jnp.float32
+    with jax.named_scope("cca/qk_mean"):
+        grouped = q0.astype(f32).reshape(B, T, G, H // G, D)
+        m_q = 0.5 * (grouped + k0.astype(f32)[:, :, :, None])
+        m_k = m_q.mean(axis=3)
+        m_q = m_q.reshape(B, T, H, D)
+
+    with jax.named_scope("cca/conv"):
+        z = jnp.concatenate([q0, k0], axis=2).reshape(B, T, (H + G) * D)
+        z = jnp.pad(z, ((0, 0), (t0 + t1 - 2, 0), (0, 0))).astype(f32)
+        rows = T + t1 - 1
+        z1 = b0 + sum(w0[:, i] * z[:, i:i + rows] for i in range(t0))
+        z1 = z1.astype(dtype).reshape(B, rows, H + G, D)
+        # The taps of a head side by side: one product of depth t1 · D.
+        taps = jnp.concatenate([z1[:, i:i + T] for i in range(t1)],
+                               axis=-1)
+        z2 = jnp.einsum(
+            "bthc,hcd->bthd", taps,
+            w1.reshape(H + G, t1 * D, D).astype(dtype)).astype(
+                f32) + b1
+        q1, k1 = z2[:, :, :H] + m_q, z2[:, :, H:] + m_k
+
+    with jax.named_scope("cca/norm_rope"):
+        def unit(x):
+            return x * lax.rsqrt(jnp.maximum(
+                jnp.sum(x * x, axis=-1, keepdims=True), 1e-24))
+
+        pos = jnp.arange(T)
+        q = apply_rotary(unit(q1) * D ** 0.5, pos, rope_theta,
+                         width).astype(dtype)
+        k = apply_rotary(unit(k1) * (D ** 0.5 * temp[:, None]), pos,
+                         rope_theta, width).astype(dtype)
+    return q, k
+
+
 class CompressedConvAttention(nn.Module):
     """Compressed convolutional attention (CCA; Zyphra, arXiv:2510.04476,
     as ZAYA1 runs it): causal attention of ``num_heads`` query heads over
@@ -389,11 +432,23 @@ class CompressedConvAttention(nn.Module):
       reading the grouped keys and values in place; ``"full"``: the dense
       oracle), then ``proj``.
 
-    Everything around the kernels is ``jax.numpy`` in float32 inside the
-    fusions and ``dtype`` between them.  Sown as the intermediate
-    ``latent``: ``(q", k", v)`` as the kernels read them.
+    Between ``cca/project`` and the flash kernels, everything but the
+    value shift — the QK-mean, both convolutions, the norm with its
+    temperature and the rotation — is one Pallas kernel forward and one
+    backward (:func:`horovod_tpu.ops.cca_passes.cca_mix`, under the scope
+    ``cca/conv``: ``q~`` and ``k~`` read once, ``q"`` and ``k"`` written
+    once, float32 inside with the two roundings ``z1`` and the store)
+    where ``cca_passes._plan`` takes the shapes: ``attn="flash"``,
+    ``head_dim`` in whole 128-lane tiles, two-byte activations, a sequence
+    in whole strips of rows.  Otherwise — the tiny float32 shapes of the
+    CPU tests, ``attn="full"``, the oracle — it is ``jax.numpy``
+    (:func:`_cca_mix_xla`), float32 inside the fusions and ``dtype`` between
+    them; the value shift always is.  No option picks a form.  Sown as the
+    intermediate ``latent``: ``(q", k", v)`` as the kernels read them.
     ``make_train_step`` counts ``attn.latent_channels`` (q, k and v
-    channels a step) and ``attn.conv_taps`` (channels times taps a step)."""
+    channels a step), ``attn.conv_taps`` (channels times taps a step) and
+    ``attn.cca_kernel_rows`` (token rows a step whose passes ran as the
+    kernels: ``B · T`` a layer, 0 in the ``jax.numpy`` form)."""
     num_heads: int
     kv_heads: int
     head_dim: int
@@ -423,49 +478,34 @@ class CompressedConvAttention(nn.Module):
             v_now = dense(G * D // 2, "v1")(u)
             v_prev = dense(G * D // 2, "v2")(u)
 
-        with jax.named_scope("cca/qk_mean"):
-            grouped = q0.astype(f32).reshape(B, T, G, H // G, D)
-            m_q = 0.5 * (grouped + k0.astype(f32)[:, :, :, None])
-            m_k = m_q.mean(axis=3)
-            m_q = m_q.reshape(B, T, H, D)
+        width = int(round(self.rotary_fraction * D / 2)) * 2
+        w0 = self.param("conv0_kernel", nn.initializers.lecun_normal(
+            in_axis=1, out_axis=0), ((H + G) * D, t0), f32)
+        b0 = self.param("conv0_bias", nn.initializers.zeros,
+                        ((H + G) * D,), f32)
+        w1 = self.param("conv1_kernel", nn.initializers.lecun_normal(
+            in_axis=(1, 2), out_axis=3, batch_axis=(0,)),
+            (H + G, t1, D, D), f32)
+        b1 = self.param("conv1_bias", nn.initializers.zeros, (H + G, D), f32)
+        temp = self.param("temp", nn.initializers.ones, (G,), f32)
 
-        with jax.named_scope("cca/conv"):
-            w0 = self.param("conv0_kernel", nn.initializers.lecun_normal(
-                in_axis=1, out_axis=0), ((H + G) * D, t0), f32)
-            b0 = self.param("conv0_bias", nn.initializers.zeros,
-                            ((H + G) * D,), f32)
-            w1 = self.param("conv1_kernel", nn.initializers.lecun_normal(
-                in_axis=(1, 2), out_axis=3, batch_axis=(0,)),
-                (H + G, t1, D, D), f32)
-            b1 = self.param("conv1_bias", nn.initializers.zeros,
-                            (H + G, D), f32)
-            z = jnp.concatenate([q0, k0], axis=2).reshape(B, T, (H + G) * D)
-            z = jnp.pad(z, ((0, 0), (t0 + t1 - 2, 0), (0, 0))).astype(f32)
-            rows = T + t1 - 1
-            z1 = b0 + sum(w0[:, i] * z[:, i:i + rows] for i in range(t0))
-            z1 = z1.astype(self.dtype).reshape(B, rows, H + G, D)
-            # The taps of a head side by side: one product of depth t1 · D.
-            taps = jnp.concatenate([z1[:, i:i + T] for i in range(t1)],
-                                   axis=-1)
-            z2 = jnp.einsum(
-                "bthc,hcd->bthd", taps,
-                w1.reshape(H + G, t1 * D, D).astype(self.dtype)).astype(
-                    f32) + b1
-            q1, k1 = z2[:, :, :H] + m_q, z2[:, :, H:] + m_k
-
-        with jax.named_scope("cca/norm_rope"):
-            temp = self.param("temp", nn.initializers.ones, (G,), f32)
-
-            def unit(x):
-                return x * lax.rsqrt(jnp.maximum(
-                    jnp.sum(x * x, axis=-1, keepdims=True), 1e-24))
-
-            width = int(round(self.rotary_fraction * D / 2)) * 2
-            pos = jnp.arange(T)
-            q = apply_rotary(unit(q1) * D ** 0.5, pos, self.rope_theta,
-                             width).astype(self.dtype)
-            k = apply_rotary(unit(k1) * (D ** 0.5 * temp[:, None]), pos,
-                             self.rope_theta, width).astype(self.dtype)
+        interpret = _pallas.interpret()
+        plan = cca_passes.cca_plan(q0, kv_heads=G, taps=self.taps,
+                                   interpret=interpret)
+        if self.attn == "flash" and plan.form == "kernels":
+            # One kernel each way for everything between the projections
+            # and the flash kernels but the value shift.
+            with jax.named_scope("cca/conv"):
+                q, k = cca_passes.cca_mix(
+                    q0, k0, w0, b0, w1, b1, temp,
+                    rope_theta=self.rope_theta, rotary_width=width,
+                    plan=plan, interpret=interpret)
+            kernel_rows = B * T
+        else:
+            q, k = _cca_mix_xla(q0, k0, w0, b0, w1, b1, temp, taps=self.taps,
+                                dtype=self.dtype,
+                                rope_theta=self.rope_theta, width=width)
+            kernel_rows = 0
 
         with jax.named_scope("cca/shift"):
             v_prev = jnp.pad(v_prev, ((0, 0), (1, 0), (0, 0)))[:, :T]
@@ -482,7 +522,8 @@ class CompressedConvAttention(nn.Module):
                              f"'full', not {self.attn!r}")
         note_layer(self.path, {
             "attn.latent_channels": B * T * (H + 2 * G) * D,
-            "attn.conv_taps": B * T * (H + G) * D * (t0 + t1)})
+            "attn.conv_taps": B * T * (H + G) * D * (t0 + t1),
+            "attn.cca_kernel_rows": kernel_rows})
         return dense(C, "proj")(out.reshape(B, T, H * D))
 
 

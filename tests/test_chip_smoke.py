@@ -113,6 +113,30 @@ def test_passes_reference_phase(smoke):
                                      seed=0)
 
 
+def test_cca_reference_phase(smoke):
+    """The latent's passes of compressed convolutional attention as kernels
+    against the module's form at a shape that tiles, and the ``cca_plan``
+    line: the ``zaya1_1chip`` layer's shape takes the kernels, on any
+    device that runs them."""
+    out = smoke.cca_reference_phase(batch=2, seq=64, heads=4, kv_heads=2,
+                                    head_dim=128, taps=(2, 2),
+                                    rotary_width=64, rope_theta=5e6, seed=0)
+    assert out["interpret"]
+    assert out["cca_plan"] == {"form": "kernels", "rows": 64, "strip": 64}
+    assert out["ms_a_layer"] == {"fwd_alone": None, "bwd_alone": None}
+    assert {"q", "k", "grad_q", "grad_k", "grad_w0", "grad_b0", "grad_w1",
+            "grad_b1", "grad_temp"} < set(out)
+    layer = {k: v for k, v in smoke.CCA_REFERENCE.items()
+             if k in ("seq", "heads", "kv_heads", "head_dim", "taps")}
+    assert smoke.cca_plan(**layer) == {"form": "kernels", "rows": 1024,
+                                       "strip": 512}
+    assert smoke.cca_plan(**{**layer, "head_dim": 64})["form"] == "xla"
+    with pytest.raises(RuntimeError, match="plan at the layer's shape"):
+        smoke.cca_reference_phase(batch=1, seq=64, heads=4, kv_heads=2,
+                                  head_dim=16, taps=(2, 2), rotary_width=8,
+                                  rope_theta=5e6, seed=0)
+
+
 def test_experts_reference_phase(smoke):
     """The grouped matmuls' kernels against ``lax.ragged_dot`` at a shape
     that tiles, and the ``moe_plan`` line: both cells' shapes take the

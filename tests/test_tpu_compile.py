@@ -120,7 +120,7 @@ HEAD_KERNEL_EQUATIONS = {"_dkdv_kernel_grouped": 176,
 
 @pytest.mark.parametrize("family", ["flash", "scan", "passes", "experts",
                                     "selected", "selected_pair",
-                                    "threshold", "grouped_kv"])
+                                    "threshold", "grouped_kv", "latent"])
 def test_each_kernel_is_traced_once_a_step_not_once_a_layer(monkeypatch,
                                                             family):
     """The set-up guard, no chip and no compile: tracing ``jax.grad`` of a
@@ -184,10 +184,11 @@ def test_each_kernel_is_traced_once_a_step_not_once_a_layer(monkeypatch,
     import collections
     import functools
 
-    from horovod_tpu.models import KeyeLM, NemotronHLM, TransformerLM
+    from horovod_tpu.models import (
+        KeyeLM, NemotronHLM, TransformerLM, Zaya1LM)
     from horovod_tpu.ops import flash_attention as fa
     from horovod_tpu.ops import (
-        grouped_matmul, mixer_passes, sparse_select, ssd)
+        cca_passes, grouped_matmul, mixer_passes, sparse_select, ssd)
 
     calls = collections.Counter()
 
@@ -218,6 +219,10 @@ def test_each_kernel_is_traced_once_a_step_not_once_a_layer(monkeypatch,
             monkeypatch.setattr(mixer_passes, name,
                                 counted(name, getattr(mixer_passes, name)))
 
+    if family == "latent":
+        for name in ("_fwd_kernel", "_bwd_kernel"):
+            monkeypatch.setattr(cca_passes, name, counted(
+                "cca." + name, getattr(cca_passes, name)))
     if family == "experts":
         for name in ("_gmm_kernel", "_tgmm_kernel"):
             monkeypatch.setattr(grouped_matmul, name,
@@ -227,7 +232,7 @@ def test_each_kernel_is_traced_once_a_step_not_once_a_layer(monkeypatch,
     # shared.
     batch = {"flash": 3, "scan": 3, "passes": 5, "experts": 2,
              "selected": 1, "selected_pair": 1, "threshold": 1,
-             "grouped_kv": 1}[family]
+             "grouped_kv": 1, "latent": 7}[family]
     if family.startswith("selected") or family == "threshold":
         if family == "selected_pair":
             monkeypatch.setattr(fa, "_FUSED_RESIDENT_BYTES", 0)
@@ -252,6 +257,16 @@ def test_each_kernel_is_traced_once_a_step_not_once_a_layer(monkeypatch,
                             pattern="***", max_len=seq, attn="flash",
                             dtype=jnp.bfloat16)
         want = {"_fwd_kernel_fullunroll": 3, "_select_bwd_kernel": 1}
+    elif family == "latent":
+        seq = 128
+        model = Zaya1LM(vocab=512, dim=256, num_heads=4, kv_heads=2,
+                        head_dim=128, pattern="ZZZ", max_len=seq,
+                        attn="flash", dtype=jnp.bfloat16, moe_experts=4,
+                        moe_hidden=128,
+                        moe=dict(router="mlp", router_hidden=16,
+                                 skip_choice=True, activation="swiglu"))
+        want = {"_fwd_kernel_fullunroll": 3, "_select_bwd_kernel": 1,
+                "cca._fwd_kernel": 1, "cca._bwd_kernel": 1}
     elif family == "flash":
         seq = T
         model = TransformerLM(vocab=512, dim=H * D, depth=3, num_heads=H,
@@ -279,7 +294,7 @@ def test_each_kernel_is_traced_once_a_step_not_once_a_layer(monkeypatch,
         lambda key: model.init(key, jnp.zeros((1, seq), jnp.int32))["params"],
         jax.random.PRNGKey(0))
     if family.startswith("selected") or family in ("threshold",
-                                                   "grouped_kv"):
+                                                   "grouped_kv", "latent"):
         # ``init`` ran the forward with the step's own shapes, and the
         # forward rule would share that trace.
         jax.clear_caches()
@@ -315,6 +330,10 @@ def test_each_kernel_is_traced_once_a_step_not_once_a_layer(monkeypatch,
     if family == "grouped_kv":
         assert collections.Counter(name for name, _ in found) == {
             "_fwd_kernel_fullunroll": 3, "flash_group_bwd": 3}
+        return
+    if family == "latent":
+        names = collections.Counter(name for name, _ in found)
+        assert (names["cca_mix_fwd"], names["cca_mix_bwd"]) == (3, 3), names
         return
     if family == "threshold":
         names = collections.Counter(name for name, _ in found)
@@ -1349,8 +1368,9 @@ def test_a_call_without_a_selection_lowers_as_it_did(v5e, entry, b, t, h,
 
 def test_a_zaya_layer_fwd_bwd_at_zaya_widths(v5e, monkeypatch):
     """One ``Z`` layer as the ``zaya1_1chip`` cell calls it, forward and
-    backward on one chip: compressed convolutional attention's latent in
-    plain XLA around the grouped-KV flash kernels at 8 query over 2 KV
+    backward on one chip: compressed convolutional attention's latent as
+    the two kernels of ``ops/cca_passes.py`` (PR 49; plain XLA before)
+    around the grouped-KV flash kernels at 8 query over 2 KV
     heads of 128 and T 16,384, then the router network and 8 held of 16
     top-1 experts 2,048 wide.  With 3 x 8 held >= the 17 outputs the held
     window is EVERY assignment: the grouped matmuls run over 16,384 rows,
@@ -1389,8 +1409,13 @@ def test_a_zaya_layer_fwd_bwd_at_zaya_widths(v5e, monkeypatch):
     assert found == {"moe_gmm": 6, "moe_gmm_nt": 3, "moe_tgmm": 3}, found
     # The grid forward and, since PR 44, the backward as one kernel a KV
     # group (the per-head pair ``_dkdv_kernel``, ``_dq_kernel`` before).
-    assert custom_calls(lowered.as_text())[:2] == [
-        ("_fwd_kernel", 3), ("flash_group_bwd", 6)]
+    # The latent's passes: the forward reads the two projections' arrays
+    # (each also as its halo), two packed vectors, two sets of matrices and
+    # the rotation's table; the backward the two cotangents and the
+    # matrices turned besides.
+    assert custom_calls(lowered.as_text())[:4] == [
+        ("_fwd_kernel", 3), ("cca_mix_bwd", 13), ("cca_mix_fwd", 9),
+        ("flash_group_bwd", 6)]
     compiled = lowered.compile()
     text = compiled.as_text()
     assert "ragged-dot" not in text
@@ -1399,6 +1424,62 @@ def test_a_zaya_layer_fwd_bwd_at_zaya_widths(v5e, monkeypatch):
     plan = (m.argument_size_in_bytes + m.output_size_in_bytes
             + m.temp_size_in_bytes - m.alias_size_in_bytes)
     assert plan < 3.0 * 2 ** 30, plan / 2 ** 30
+
+
+# (b, T, query heads, KV heads, head_dim, taps) -> rows a block, rows a
+# strip: what ``cca_passes._plan`` hands to the kernels, one case a way of
+# tiling — the cell's shape, two sequences, a sequence of three strips of
+# 128 in one block, blocks of three strips of 256, 43 strips of 16 a block,
+# one KV group of eight query heads (nine heads a step: half the rows),
+# heads of two lane tiles, and taps that reach as far as the halo's kept
+# rows.
+@pytest.mark.parametrize("b,t,h,g,d,taps,rows,strip", [
+    (1, 16_384, 8, 2, 128, (2, 2), 1024, 512),
+    (2, 2048, 8, 2, 128, (2, 2), 1024, 512),
+    (1, 384, 8, 2, 128, (2, 2), 384, 128),
+    (1, 2304, 8, 2, 128, (2, 2), 768, 256),
+    (1, 2064, 8, 2, 128, (2, 2), 688, 16),
+    (1, 2048, 8, 1, 128, (2, 2), 512, 512),
+    (1, 2048, 4, 2, 256, (2, 2), 512, 512),
+    (1, 2048, 4, 2, 128, (5, 5), 1024, 512)],
+    ids=["zaya1_1chip", "two_sequences", "three_strips_of_128_one_block",
+         "blocks_of_three_strips_of_256", "strips_of_16",
+         "one_group_of_eight", "heads_of_256", "taps_as_far_as_the_halo"])
+def test_cca_passes_compile_wherever_the_plan_takes_the_kernels(
+        v5e, b, t, h, g, d, taps, rows, strip):
+    """A shape ``cca_passes._plan`` gives the kernels has to compile,
+    forward and backward: interpret mode refuses nothing of what Mosaic
+    refuses.  Two kernels by name; the parameters' gradients float32, the
+    latents' in their dtype."""
+    from horovod_tpu.ops import cca_passes
+
+    one = SingleDeviceSharding(v5e[0])
+
+    def s(shape, dtype="float32"):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    args = (s((b, t, h, d), "bfloat16"), s((b, t, g, d), "bfloat16"),
+            s(((h + g) * d, taps[0])), s(((h + g) * d,)),
+            s((h + g, taps[1], d, d)), s((h + g, d)), s((g,)))
+    plan = cca_passes.cca_plan(args[0], kv_heads=g, taps=taps,
+                               interpret=False)
+    assert plan == cca_passes.CcaPlan("kernels", rows, strip)
+
+    def loss(*a):
+        q, k = cca_passes.cca_mix(*a, rope_theta=5e6, rotary_width=d // 2,
+                                  plan=plan)
+        return q.astype(jnp.float32).sum() + k.astype(jnp.float32).sum()
+
+    compiled = jax.jit(jax.value_and_grad(
+        loss, argnums=tuple(range(7)))).lower(*args).compile()
+    kernels = [line.split(" = ")[0] for line in compiled.as_text().splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(kernels) == 2, kernels
+    assert sum("cca_mix_fwd" in k for k in kernels) == 1, kernels
+    assert sum("cca_mix_bwd" in k for k in kernels) == 1, kernels
+    _, grads = compiled.out_info
+    assert [(x.shape, x.dtype) for x in grads] == [
+        (a.shape, a.dtype) for a in args]
 
 
 # ------------------------------------- the Nemotron-3-Super cell's parts

@@ -587,8 +587,10 @@ def test_the_drawn_temperatures_are_seen_through_the_query_projection(
 def test_the_layers_note_their_sizes(compared):
     noted = compared["noted"]
     attn = [n for n in noted.values() if "attn.latent_channels" in n]
+    # Float32 is the module's own form: no row's passes ran as kernels.
     assert attn == 2 * [{"attn.latent_channels": 64 * (4 + 2 * 2) * 128,
-                         "attn.conv_taps": 64 * 6 * 128 * 4}]
+                         "attn.conv_taps": 64 * 6 * 128 * 4,
+                         "attn.cca_kernel_rows": 0}]
     moe = [n for n in noted.values() if "moe.router_hidden" in n]
     assert len(moe) == 2 and all(
         n["moe.router_hidden"] == 16 and n["moe.skip_choice"] == 1
@@ -598,6 +600,27 @@ def test_the_layers_note_their_sizes(compared):
         and n["moe.held_assignments"] == 64 * 4 // 9 for n in moe)
     assert [n for n in noted.values() if "lm.tied_head" in n] == [
         {"lm.tied_head": 1}]
+
+
+@pytest.mark.parametrize("seq,dtype,attn,rows", [
+    (128, jnp.bfloat16, "flash", 2 * 128), (128, jnp.bfloat16, "full", 0),
+    (128, F32, "flash", 0), (72, jnp.bfloat16, "flash", 0)],
+    ids=["kernels", "the_dense_oracle", "float32", "no_whole_strip"])
+def test_the_layer_counts_the_rows_its_passes_ran_as_kernels(seq, dtype,
+                                                             attn, rows):
+    """``attn.cca_kernel_rows`` is ``B · T`` a layer where
+    ``cca_passes._plan`` takes the kernels (lane-aligned heads, two-byte
+    activations, a sequence in whole strips of rows, ``attn="flash"``) and
+    0 where the module's ``jax.numpy`` runs."""
+    layer = CompressedConvAttention(num_heads=4, kv_heads=2, head_dim=128,
+                                    attn=attn, dtype=dtype)
+    u = jax.ShapeDtypeStruct((2, seq, 32), dtype)
+    noted = {}
+    jax.eval_shape(noting_layers(
+        lambda u: layer.init(jax.random.PRNGKey(0), u), noted), u)
+    (counters,) = noted.values()
+    assert counters["attn.cca_kernel_rows"] == rows
+    assert counters["attn.latent_channels"] == 2 * seq * 8 * 128
 
 
 def test_the_reference_takes_the_program_s_choice_inside_the_margin_only():
