@@ -32,6 +32,12 @@ through the entry points a user calls (``hvd.init()`` →
   forward, the per-head pair and the one fused kernel a KV group — checks
   the fused kernel's gradients against the pair's and prints which of the
   two ``flash_attention._plan`` takes here (``gqa_plan``);
+* times the flash kernels of one latent-attention layer alone at
+  ``joyaiflash_1chip``'s shape — keys of 192 against values of 128 — with
+  every operand padded to one width, with values at their own width
+  through the per-head pair, and through the one fused backward kernel,
+  checks that kernel's gradients against the pair's and prints the plan
+  (``latent_backward``, ``mla_plan``);
 * checks the latent's passes of compressed convolutional attention as
   kernels at ``zaya1_1chip``'s layer — 8 query over 2 KV heads of 128, one
   sequence of 16,384 — against the module's ``jax.numpy`` form: q", k" and
@@ -128,6 +134,10 @@ GROUPED_BACKWARD = {
                         head_dim=128),
     "twotower_1chip": dict(batch=2, seq=8192, heads=32, kv_heads=2,
                            head_dim=128)}
+# One latent-attention layer's flash kernels alone at joyaiflash_1chip's
+# shape: 32 heads, keys of 192 (128 | 64) against values of 128, two
+# sequences of 8,192.
+LATENT_BACKWARD = dict(batch=2, seq=8192, heads=32, qk_dim=192, v_dim=128)
 # The latent's passes of compressed convolutional attention at the
 # zaya1_1chip cell's layer (ZAYA1-8B: two taps and two, half of each head
 # rotated at theta 5e6).
@@ -1050,6 +1060,74 @@ def grouped_backward_phase(*, batch: int, seq: int, heads: int, kv_heads: int,
             "fused_vs_pair": {n: round(e, 6) for n, e in errs.items()}}
 
 
+def latent_backward_phase(*, batch: int, seq: int, heads: int, qk_dim: int,
+                          v_dim: int, seed: int, calls: int = 10) -> dict:
+    """The flash kernels of one latent-attention layer alone — keys of
+    ``qk_dim`` (192 = 128 | 64) against values of ``v_dim`` (128), one
+    query head a KV head — in the forms timed before one was shipped:
+    ``padded_all``, q, k AND v zero-padded to whole 128-lane tiles of one
+    width (256) through the kernels as they were (grid forward, per-head
+    pair at Q blocks of 512: at 1024 the compiler refuses its dk/dv
+    kernel); ``pair``, q and k padded and v, o, dv at their own width through
+    the per-head pair; ``fused``, the same widths through the one kernel a
+    KV group (``flash_group_bwd`` at a group of one head), whose three
+    gradients are checked against the pair's.  ``mla_plan`` is what
+    ``flash_attention._plan`` decides for the call on this device."""
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.ops import flash_attention as fa
+
+    interpret = jax.default_backend() != "tpu"
+    B, T, H = batch, seq, heads
+    D, Dv = qk_dim + -qk_dim % 128, v_dim + -v_dim % 128
+    blocks = fa._resolve_blocks(T, "chip_smoke", None, None, None, None,
+                                None, "")[:4]
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+
+    def operand(key, width, lanes):
+        a = jax.random.normal(key, (B, T, H, width)).astype(jnp.bfloat16)
+        return jnp.pad(a, [(0, 0)] * 3 + [(0, lanes - width)]).reshape(
+            B, T, H * lanes)
+
+    q, k = operand(ks[0], qk_dim, D), operand(ks[1], qk_dim, D)
+    timed = functools.partial(_timed_ms, calls, interpret)
+    common = dict(scale=qk_dim ** -0.5, causal=True, interpret=interpret)
+    ms, kept = {}, {}
+    for name, lanes in (("padded_all", D), ("own_width", Dv)):
+        v, do = operand(ks[2], v_dim, lanes), operand(ks[3], v_dim, lanes)
+        plan = fa._plan_for(q, H, D, (0, 0, 0), True, *blocks, interpret,
+                            Dv=lanes)
+        ms[name + ".forward"], (o, lse) = timed(
+            lambda *a: fa._fwd_packed(*a, H, D, plan, block_q=blocks[0],
+                                      block_k=blocks[1], Dv=lanes, **common),
+            q, k, v)
+        # With dV as wide as dK the pair's dk/dv kernel overruns Mosaic's
+        # 16 MB of scoped VMEM at 1024 x 1024 blocks: Q blocks of 512.
+        bwd_q = blocks[2] if lanes == Dv else min(blocks[2], 512)
+        ms[name + ".pair"], kept[name] = timed(
+            lambda *a: fa._bwd_pallas_packed(
+                *a, H, D, plan._replace(bwd="per_head"), block_q=bwd_q,
+                block_k=blocks[3], Dv=lanes, **common), q, k, v, o, lse, do)
+    errs = {}
+    if plan.bwd == "group_fused":
+        ms["own_width.fused"], got = timed(
+            lambda q, k, v, *a: fa._select_bwd(
+                q, k, v, None, *a, H, D, fused=True, block_q=plan.blocks[2],
+                block_k=plan.blocks[3], seq_len=None,
+                vmem_mb=plan.bwd_vmem_mb, Dv=Dv, **common),
+            q, k, v, o, lse, do)
+        for name, g, w in zip(("dq", "dk", "dv"), got, kept["own_width"]):
+            errs[name] = _rel_err(g, w)
+            check(errs[name] <= SELECT_TOL,
+                  f"the fused backward at {qk_dim} | {v_dim} differs from "
+                  f"the pair in {name} by {errs[name]:.3g} (bound "
+                  f"{SELECT_TOL})")
+    return {"shape": [B, T, H, qk_dim, v_dim], "interpret": interpret,
+            "mla_plan": plan._asdict(), "ms_a_layer": ms,
+            "fused_vs_pair": {n: round(e, 6) for n, e in errs.items()}}
+
+
 def delta_reference_phase(*, batch: int, seq: int, heads: int, key_dim: int,
                           value_dim: int, chunk: int, seed: int) -> dict:
     """The gated delta rule as the linear-attention mixer calls it
@@ -1682,6 +1760,8 @@ def main(argv=None) -> int:
         for cell, shape in GROUPED_BACKWARD.items():
             emit("grouped_backward", cell=cell, **grouped_backward_phase(
                 **shape, seed=args.seed))
+        emit("latent_backward", **latent_backward_phase(
+            **LATENT_BACKWARD, seed=args.seed))
         emit("transformer_lm", **transformer_phase(
             mesh, events, **ONE_CHIP_LM, seed=args.seed))
         emit("resnet50", **resnet_phase(

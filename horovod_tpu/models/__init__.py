@@ -7,6 +7,7 @@ from horovod_tpu.models.resnet import (                   # noqa: F401
 )
 from horovod_tpu.models.transformer import (               # noqa: F401
     BlockStack, CompressedConvAttention, GraniteHybridLM,
-    GroupedQueryAttention, KeyeLM, MultiTokenPrediction, Nemotron3SuperLM,
+    GroupedQueryAttention, JoyAIFlashLM, KeyeLM, LatentAttention,
+    MultiTokenPrediction, Nemotron3SuperLM,
     NemotronHLM, OLMoELM, OlmoHybridLM, ResidualMerge, SwiGLU, TransformerLM,
     Zaya1LM, apply_rotary, index_losses)

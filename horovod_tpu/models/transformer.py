@@ -68,7 +68,14 @@ each side (:class:`ResidualMerge`): compressed convolutional attention
 (:class:`CompressedConvAttention`, attention inside a latent whose q and k
 pass through two causal convolutions) and then a ``DroplessMoE`` whose
 router is a small network with a state that the stack hands from one ``Z``
-layer to the next.  :func:`Zaya1LM` is the ZAYA1 setting.
+layer to the next.  :func:`Zaya1LM` is the ZAYA1 setting.  ``d`` and ``x``
+are TWO pre-norm sub-layers (``h = x + f(norm(x))``, ``y = h +
+g(norm(h))``) whose first is multi-head latent attention
+(:class:`LatentAttention`: queries, keys and values projected UP from
+low-rank latents, one rotary key all heads share, heads wider than their
+values; ``mla=`` holds its widths) and whose second is a dense
+:class:`SwiGLU` ``mlp_hidden`` wide (``d``) or a ``DroplessMoE`` (``x``).
+:func:`JoyAIFlashLM` is the JoyAI-LLM-Flash setting.
 
 ``mtp`` adds a multi-token-prediction module behind a pattern stack
 (:class:`MultiTokenPrediction`): from the stack's final hidden states and
@@ -82,6 +89,7 @@ experts in a latent between a shared down- and up-projection
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Optional
 
 import flax.linen as nn
@@ -224,7 +232,8 @@ class GroupedQueryAttention(nn.Module):
     """Causal attention of ``num_heads`` query heads over ``kv_heads``
     key-value heads of ``head_dim`` (query head ``h`` reads KV head
     ``h // (num_heads / kv_heads)``), no bias; the heads' total width need
-    not be the model's.  Parameters ``q``, ``kv`` (keys | values) and
+    not be the model's.  Keys and values are of one width here (the flash
+    family takes values of another: :class:`LatentAttention`).  Parameters ``q``, ``kv`` (keys | values) and
     ``proj``.  ``attn="flash"`` reads the grouped keys and values in place
     (:func:`~horovod_tpu.ops.flash_attention.flash_attention`); ``"full"``
     repeats them for the dense oracle.
@@ -527,6 +536,139 @@ class CompressedConvAttention(nn.Module):
         return dense(C, "proj")(out.reshape(B, T, H * D))
 
 
+def _kernel_outputs_saveable(prim, *_, **__) -> bool:
+    """The ``jax.checkpoint`` policy of :class:`LatentAttention`: what a
+    Pallas kernel wrote is kept (the attention's output and row
+    statistics), everything else between the latents and the output
+    projection is computed again in the backward pass."""
+    return prim.name == "pallas_call"
+
+
+class LatentAttention(nn.Module):
+    """Multi-head latent attention (DeepSeek-V2 report, arXiv:2405.04434,
+    section 2.1; V3 report, arXiv:2412.19437, section 2.1.1) of
+    ``num_heads`` heads, causal, no bias.  On the layer's normed input
+    ``x`` (B, T, C), with ``n`` an RMSNorm (``norm_eps``, learned scale):
+
+    * ``mla/q_down``: ``c_q = x W_qa`` (``q_latent`` wide, ``q_a``);
+      ``mla/kv_down``: ``[c_kv | k_r] = x W_kva`` (``kv_latent`` |
+      ``rope_dim``, ``kv_a``); ``mla/norm``: ``c_q ← n(c_q)``
+      (``q_norm``), ``c_kv ← n(c_kv)`` (``kv_norm``) — a norm BETWEEN the
+      two projections of each side.
+    * ``mla/q_up``: ``[q_nope,h | q_rope,h] = c_q W_qb`` (``nope_dim`` |
+      ``rope_dim`` a head, ``q_b``); ``mla/kv_up``: ``[k_nope,h | v_h] =
+      c_kv W_kvb`` (``nope_dim`` | ``v_dim`` a head, ``kv_b``).
+    * ``mla/rope``: rotary positions (``rope_theta``, all ``rope_dim``
+      channels) on every ``q_rope,h`` and on the ONE ``k_r`` a token, which
+      all heads read; a head's key is ``[k_nope,h | k_r]``.
+    * ``mla/attend``: softmax-causal attention at the scale ``(nope_dim +
+      rope_dim)^-1/2`` — scores ``q_nope,h · k_nope,h + q_rope,h · k_r`` —
+      over values ``v_dim`` wide; ``mla/out``: ``[o_1 … o_H] W_o``
+      (``proj``, ``num_heads · v_dim`` to the model's width).
+
+    The rotation runs in the rotate-half form (:func:`apply_rotary`: the
+    pair ``(i, i + rope_dim / 2)``).  A model published with adjacent pairs
+    (``rope_interleave``: ``(2i, 2i + 1)``) gives the same scores under a
+    fixed permutation of the ``rope_dim`` rotary columns — of each head of
+    ``q_b`` and of the last ``rope_dim`` columns of ``kv_a`` — which a
+    loader of its weights applies: published column ``2i`` to column ``i``,
+    ``2i + 1`` to ``i + rope_dim / 2``.
+
+    ``attn="flash"``: the flash family's kernels
+    (:func:`~horovod_tpu.ops.flash_attention.flash_attention`, which takes
+    values narrower than keys), q and k built in whole 128-lane tiles (192
+    → 256: zeros behind the rotary part) under ``mla/rope`` so that nothing
+    but the kernels runs under ``mla/attend``; ``"full"``: the dense oracle
+    at the published widths.  What lies between the latents and the output
+    projection is a ``jax.checkpoint``: the backward pass keeps ``c_q``,
+    ``c_kv``, ``k_r`` and what the forward kernel wrote (``o`` and the row
+    statistics) and projects up, rotates and pads again — q, k, v and o of
+    32 heads are 40 KiB a token a layer in bfloat16 where the latents are
+    4.1.  ``make_train_step`` counts ``attn.q_latent``, ``attn.kv_latent``,
+    ``attn.qk_head_dim``, ``attn.v_head_dim``, ``attn.padded_lanes`` (lanes
+    a head the kernels multiply beyond the published widths, q·k side + v
+    side: 64 at 192 | 128, one pass of a 128-wide MXU more either way; 0
+    for ``"full"``) and ``attn.latent_residual_bytes`` (bytes a token the
+    layer keeps for the backward pass between its latents and ``proj``)."""
+    num_heads: int
+    q_latent: int
+    kv_latent: int
+    nope_dim: int
+    rope_dim: int
+    v_dim: int
+    attn: str = "flash"
+    dtype: Any = jnp.bfloat16
+    norm_eps: float = 1e-6
+    rope_theta: float = 10000.0
+
+    @nn.compact
+    def __call__(self, x):
+        B, T, C = x.shape
+        H, N, R, V = self.num_heads, self.nope_dim, self.rope_dim, self.v_dim
+        if self.attn not in ("flash", "full"):
+            raise ValueError("latent attention runs attn='flash' or 'full', "
+                             f"not {self.attn!r}")
+        flash = self.attn == "flash"
+        # The kernels' q·k width: whole 128-lane tiles.
+        lanes = -(N + R) % 128 if flash else 0
+
+        def dense(features, name):
+            return nn.Dense(features, use_bias=False, dtype=self.dtype,
+                            param_dtype=jnp.float32, name=name)
+
+        def normed(y, name):
+            return nn.RMSNorm(epsilon=self.norm_eps, dtype=self.dtype,
+                              name=name)(y)
+
+        with jax.named_scope("mla/q_down"):
+            c_q = dense(self.q_latent, "q_a")(x)
+        with jax.named_scope("mla/kv_down"):
+            c_kv, k_r = jnp.split(dense(self.kv_latent + R, "kv_a")(x),
+                                  [self.kv_latent], axis=-1)
+        with jax.named_scope("mla/norm"):
+            c_q, c_kv = normed(c_q, "q_norm"), normed(c_kv, "kv_norm")
+        w_qb = _QKVKernel(H * (N + R), name="q_b")(self.q_latent)
+        w_kvb = _QKVKernel(H * (N + V), name="kv_b")(self.kv_latent)
+
+        @functools.partial(jax.checkpoint, policy=_kernel_outputs_saveable)
+        def attend(c_q, c_kv, k_r, w_qb, w_kvb):
+            with jax.named_scope("mla/q_up"):
+                q = (c_q @ w_qb.astype(self.dtype)).reshape(B, T, H, N + R)
+            with jax.named_scope("mla/kv_up"):
+                kv = (c_kv @ w_kvb.astype(self.dtype)).reshape(
+                    B, T, H, N + V)
+            with jax.named_scope("mla/rope"):
+                pos = jnp.arange(T)
+                q_r = apply_rotary(q[..., N:], pos, self.rope_theta)
+                k_r = apply_rotary(k_r[:, :, None], pos, self.rope_theta)
+                zeros = jnp.zeros((B, T, H, lanes), self.dtype)
+                q = jnp.concatenate([q[..., :N], q_r, zeros], axis=-1)
+                k = jnp.concatenate(
+                    [kv[..., :N], jnp.broadcast_to(k_r, (B, T, H, R)),
+                     zeros], axis=-1)
+                v = kv[..., N:]
+            with jax.named_scope("mla/attend"):
+                scale = (N + R) ** -0.5
+                if flash:
+                    return flash_attention_auto(q, k, v, causal=True,
+                                                scale=scale)
+                return full_attention(q, k, v, causal=True, scale=scale)
+
+        out = attend(c_q, c_kv, k_r, w_qb, w_kvb)
+        itemsize = jnp.dtype(self.dtype).itemsize
+        note_layer(self.path, {
+            "attn.q_latent": self.q_latent, "attn.kv_latent": self.kv_latent,
+            "attn.qk_head_dim": N + R, "attn.v_head_dim": V,
+            "attn.padded_lanes": lanes + (-V % 128 if flash else 0),
+            # c_q, c_kv and k_r; o and the (8-wide, float32) row statistics
+            # where a kernel wrote them.
+            "attn.latent_residual_bytes": (
+                itemsize * (self.q_latent + self.kv_latent + R)
+                + (itemsize * H * V + 4 * 8 * H if flash else 0))})
+        with jax.named_scope("mla/out"):
+            return dense(C, "proj")(out.reshape(B, T, H * V))
+
+
 class ResidualMerge(nn.Module):
     """``s_x ⊙ (x + b_x) + s_y ⊙ (y + b_y)``: the residual ``x`` and a
     sub-layer's output ``y`` each under a learned bias and scale a channel
@@ -592,7 +734,12 @@ class PatternLayer(nn.Module):
     model's first layer, whose ``merge_attn`` leaves the residual alone);
     ``sub`` holds the two modules' fields under ``"attn"`` and ``"moe"``,
     the layer is called with the previous ``"Z"`` layer's router state
-    (None for the first) and returns ``(y, router_state)``."""
+    (None for the first) and returns ``(y, router_state)``.  ``"d"`` and
+    ``"x"`` (``attn``, then ``mlp`` or ``moe``): ``h = x + mla(norm(x))``
+    with ``mla`` a :class:`LatentAttention`, then ``h + mlp(mlp_norm(h))``
+    with ``mlp`` a :class:`SwiGLU` ``mlp_hidden`` wide (``"d"``) or ``h +
+    moe(moe_norm(h))`` with ``moe`` a ``DroplessMoE`` (``"x"``); ``sub``
+    holds the two modules' fields under ``"attn"`` and ``"moe"``."""
     kind: str
     sub: Any
     dtype: Any = jnp.bfloat16
@@ -617,6 +764,15 @@ class PatternLayer(nn.Module):
                 **self.sub["moe"], dtype=self.dtype, norm_eps=self.norm_eps,
                 name="moe")(normed(h, "moe_norm"), router_state)
             return ResidualMerge(name="merge_moe")(h, y), router_state
+        if self.kind in ("d", "x"):
+            h = x + LatentAttention(
+                **self.sub["attn"], dtype=self.dtype, norm_eps=self.norm_eps,
+                name="attn")(normed(x, "norm"))
+            if self.kind == "d":
+                return h + SwiGLU(self.mlp_hidden, self.dtype, name="mlp")(
+                    normed(h, "mlp_norm"))
+            return h + DroplessMoE(**self.sub["moe"], dtype=self.dtype,
+                                   name="moe")(normed(h, "moe_norm"))[0]
         if self.kind in ("L", "F"):
             if self.kind == "L":
                 from horovod_tpu.models.linear_attention import GatedDeltaNet
@@ -644,7 +800,8 @@ class PatternLayer(nn.Module):
             y = DroplessMoE(**self.sub, dtype=self.dtype, name="moe")(h)[0]
         else:
             raise ValueError(f"unknown layer {self.kind!r} in a pattern: "
-                             "'M', '*', 'S', 'E', 'L', 'F', 'm', 'a' or 'Z'")
+                             "'M', '*', 'S', 'E', 'L', 'F', 'm', 'a' or 'Z', "
+                             "or 'd' or 'x'")
         if self.kind in ("m", "a"):
             r = self.residual_multiplier
             h = x + r * y
@@ -849,6 +1006,10 @@ class TransformerLM(nn.Module):
     attn_scale: Optional[float] = None
     residual_multiplier: float = 1.0
     cca: Any = None
+    # ``d`` and ``x`` layers: LatentAttention's widths (q_latent,
+    # kv_latent, nope_dim, rope_dim, v_dim) for num_heads heads, rotary
+    # positions of rope_theta (pos="rotary"; they have no other).
+    mla: Any = None
     # ``mtp=dict(pattern="*E")``: a multi-token-prediction module named
     # ``mtp`` behind the stack (MultiTokenPrediction), its layers the
     # letters of its own pattern with this stack's ssm=, moe=, heads.  The
@@ -886,7 +1047,7 @@ class TransformerLM(nn.Module):
         if self.tp_axis and (self.moe_experts or self.qk_norm
                              or self.pos != "learned" or self.pattern
                              or self.moe or self.indexer or self.cca
-                             or self.mtp):
+                             or self.mla or self.mtp):
             raise ValueError("tp_axis runs the GPT-2 block only: no "
                              "experts (whole, a held share or in a latent), "
                              "QK-norm, rotary positions, pattern stack or "
@@ -896,13 +1057,13 @@ class TransformerLM(nn.Module):
         if self.pattern is not None:
             return self._pattern_stack(tokens, return_hidden)
         if (self.pos == "none" or self.moe or self.ssm or self.lin
-                or self.mlp_hidden or self.indexer or self.cca
+                or self.mlp_hidden or self.indexer or self.cca or self.mla
                 or self.tie_head or self.mtp
                 or self.attn_scale is not None
                 or (self.residual_multiplier, self.embedding_multiplier,
                     self.logits_scaling) != (1.0, 1.0, 1.0)):
             raise ValueError("pos='none', ssm=, moe= (its latent= too), lin=, "
-                             "indexer=, cca=, mtp=, "
+                             "indexer=, cca=, mla=, mtp=, "
                              "mlp_hidden=, attn_scale=, tie_head= and the "
                              "three multipliers belong to a pattern stack; "
                              "the block stack "
@@ -942,12 +1103,14 @@ class TransformerLM(nn.Module):
     def _pattern_stack(self, tokens, return_hidden):
         rotary = self.rope_theta if self.pos == "rotary" else None
         if self.attn not in ("full", "flash") or self.pos not in (
-                ("none", "rotary") if set("SZ") & set(self.pattern)
-                else ("none",)) or ("Z" in self.pattern and rotary is None):
+                ("none", "rotary") if set("SZdx") & set(self.pattern)
+                else ("none",)) or (set("Zdx") & set(self.pattern)
+                                    and rotary is None):
             raise ValueError("a pattern stack runs whole sequences "
                              "(attn='full' or 'flash') with pos='none', or "
-                             "'rotary' for its 'S' layers and 'Z' layers "
-                             "('Z' layers have no other); got "
+                             "'rotary' for its 'S' layers, 'Z' layers ('Z' "
+                             "layers have no other) and 'd' and 'x' layers "
+                             "(nor have they); got "
                              f"attn={self.attn!r}, pos={self.pos!r}")
         experts = dict(num_experts=self.moe_experts, hidden=self.moe_hidden,
                        top_k=self.moe_top_k, **dict(self.moe or {}))
@@ -971,8 +1134,11 @@ class TransformerLM(nn.Module):
                                 head_dim=self.head_dim, attn=self.attn,
                                 rope_theta=rotary, **dict(self.cca or {})),
                       moe=experts),
+            "d": dict(attn=dict(num_heads=self.num_heads, attn=self.attn,
+                                rope_theta=rotary, **dict(self.mla or {})),
+                      moe=experts),
         }
-        subs["m"] = subs["M"]
+        subs["m"], subs["x"] = subs["M"], subs["d"]
         if self.residual_multiplier != 1.0 and set(self.pattern) - {"m", "a"}:
             raise ValueError("residual_multiplier scales the sub-layers of "
                              "'m' and 'a' layers only; the pattern "
@@ -1051,7 +1217,10 @@ def NemotronHLM(**overrides) -> TransformerLM:
     this model leaves off and :func:`Nemotron3SuperLM` sets: ``moe={...,
     "latent": w}`` (the routed experts work ``w`` wide between a shared
     down- and up-projection) and ``mtp=dict(pattern=...)`` (a
-    multi-token-prediction module behind the stack).  A tensor-parallel
+    multi-token-prediction module behind the stack); a third it leaves
+    off too: ``moe={..., "choice_bias": γ}``, the sigmoid router's
+    balancing bias with its update (:func:`JoyAIFlashLM` sets it; this
+    config has no speed for it).  A tensor-parallel
     rank's share of a mixer is ``Mamba2Mixer`` at ONE group: ``ssm=dict(
     num_heads=H / n_groups, n_groups=1, ...)``.
 
@@ -1093,8 +1262,9 @@ def Nemotron3SuperLM(**overrides) -> TransformerLM:
     (Megatron-core's latent projections and ``MultiTokenPredictionLayer``):
     ``benchmark/configs/nemotron-3-super-120b-a12b.json`` lists each under
     ``assumed``.  Not built: a prediction depth past 1 with shared weights,
-    the router's correction bias (zero until something trains it, as in
-    :func:`NemotronHLM`), any exchange between chips.
+    any exchange between chips.  Left off: the router's correction bias
+    (``moe["choice_bias"]``, an option of ``DroplessMoE`` since
+    :func:`JoyAIFlashLM`; its update's speed is not in this config).
 
     ``overrides`` replace any field.  A cut takes the first letters of the
     pattern.  One chip's share of a layer is overrides alone, no other
@@ -1188,6 +1358,61 @@ def KeyeLM(**overrides) -> TransformerLM:
         indexer=dict(num_heads=16, head_dim=64, topk=2048, tile=512),
         moe_experts=128, moe_top_k=8, moe_hidden=768,
         moe=dict(router="softmax", renormalize=True, activation="swiglu"))
+    fields.update(overrides)
+    return TransformerLM(**fields)
+
+
+def JoyAIFlashLM(**overrides) -> TransformerLM:
+    """The stack that ``jdopensource/JoyAI-LLM-Flash``'s config.json
+    describes (``model_type`` ``joyai_llm_flash``; the key set is
+    DeepSeek-V3's), as a :class:`TransformerLM` with a ``pattern``: 40
+    layers at d 2048, pre-norm RMSNorm eps 1e-6, every layer multi-head
+    latent attention (:class:`LatentAttention`: 32 heads, a q latent of
+    1,536 and a k | v latent of 512, each normed before it is projected up;
+    keys of 128 + 64 rotary channels — ONE rotary key a token for all heads,
+    theta 3.2e7 — against values of 128) and then, in layer 0
+    (``first_k_dense_replace`` 1, letter ``d``), a dense SwiGLU 7,168 wide
+    and, in the 39 after it (letter ``x``), 256 SwiGLU experts 768 wide,
+    top-8 by sigmoid scores plus a balancing bias that chooses and never
+    gates (``topk_method`` ``noaux_tc``: ``DroplessMoE(choice_bias=γ)``,
+    state in the collection ``"balance"``, moved by the layer itself after
+    each step's counts and by no gradient), gates renormalised over the
+    chosen and scaled by 2.5, and one shared SwiGLU expert 768 wide; a
+    multi-token-prediction module of one ``x`` layer behind the stack
+    (``num_nextn_predict_layers`` 1); vocab 129,280, untied head.
+
+    What config.json has no key for is the DeepSeek-V3 report's: the bias
+    update's speed γ 1e-3 (``moe["choice_bias"]``), the prediction loss's
+    weight (the caller's).  The rotary pairing run is rotate-half; the
+    published ``rope_interleave`` pairs adjacent channels, and a loader of
+    published weights permutes the rotary columns of ``q_b`` and ``kv_a``
+    (:class:`LatentAttention`).  No exchange between chips is built.
+
+    ``overrides`` replace any field.  A cut takes the first letters of the
+    pattern (the dense layer and then expert layers: ``pattern="dxxxx"``);
+    one chip's share of the experts is ``moe={..., "held": (first,
+    count)}`` and of the vocabulary a smaller ``vocab``; the heads are not
+    divided (the latents' down-projections and norms would be replicated on
+    every rank that holds some).  Trained through ``make_train_step`` with
+    the bias in its ``aux_state``::
+
+        def loss_fn(params, aux, tokens):          # tokens (B, T + 2)
+            hiddens, moved = model.apply(
+                {"params": params, **aux}, tokens[:, :-1],
+                return_hidden=True, mutable=["balance"])
+            return multi_token_xent(hiddens, model.head_kernel(params),
+                                    tokens, (1.0, 0.3)), moved
+    """
+    fields = dict(
+        vocab=129280, dim=2048, num_heads=32, max_len=131072, norm="rms",
+        norm_eps=1e-6, pos="rotary", rope_theta=3.2e7,
+        pattern="d" + "x" * 39, mlp_hidden=7168,
+        mla=dict(q_latent=1536, kv_latent=512, nope_dim=128, rope_dim=64,
+                 v_dim=128),
+        moe_experts=256, moe_top_k=8, moe_hidden=768,
+        moe=dict(router="sigmoid", renormalize=True, gate_scale=2.5,
+                 activation="swiglu", shared_hidden=768, choice_bias=1e-3),
+        mtp=dict(pattern="x"))
     fields.update(overrides)
     return TransformerLM(**fields)
 

@@ -81,6 +81,13 @@ products a tile) and the pair :func:`_select_dq_kernel`,
 :func:`_select_dkdv_kernel` (seven) where they may not.  Of it the calls
 without a map share the one fused backward kernel, at grouped KV heads.
 
+Values of another width than the keys (``flash_attention(q, k, v)`` with
+``v`` narrower or wider a head: multi-head latent attention's 192 against
+128) run on the forms whose bodies never ask a width — the grid forward and,
+backward, that one fused kernel (``dK`` and ``dV`` resident at their own
+widths) or the per-head pair — with each side in whole 128-lane tiles;
+:func:`_plan` says which, from the shapes.
+
 Composition: this is the *single-chip* block; for sequences sharded
 across chips use :mod:`horovod_tpu.parallel.ring_attention`, which
 streams K/V between chips with the same online-softmax math.
@@ -401,7 +408,8 @@ def _kv_head(kv_rep: int):
 
 
 def _fwd_packed(q, k, v, H, D, plan, *, scale, causal, block_q, block_k,
-                interpret, seq_len=None, head_base=(0, 0, 0), kv_rep=1):
+                interpret, seq_len=None, head_base=(0, 0, 0), kv_rep=1,
+                Dv=None):
     """Forward on head-packed (B, T, C) views (C = H*D): the head is a
     grid axis and every BlockSpec offsets its last dim by ``h*D``, so no
     (B, T, H, D) -> (B*H, T, D) transpose copy ever materializes in HBM.
@@ -410,12 +418,15 @@ def _fwd_packed(q, k, v, H, D, plan, *, scale, causal, block_q, block_k,
     projection (so the qkv split never copies either).  ``plan`` is
     :func:`_plan`'s: which of the three forms runs.  ``kv_rep`` query
     heads read each KV head (``k``, ``v`` hold ``H // kv_rep`` heads).
+    ``Dv``: the width of a head of ``v`` and of the output where it is not
+    ``D`` (the grid form alone: its body never asks a width).
     lse comes back as (B, H, T)."""
     B, T, _ = q.shape
     nq = T // block_q
     nk = T // block_k
     oq, ok_, ov = head_base
     kvh = _kv_head(kv_rep)
+    Dv = D if Dv is None else Dv
     if plan.fwd == "fullunroll":
         # This form re-tiles internally (the tile size is a schedule
         # detail — flash results are block-size independent up to f32
@@ -490,22 +501,22 @@ def _fwd_packed(q, k, v, H, D, plan, *, scale, causal, block_q, block_k,
                          lambda b, h, i, j: (b, i, h + oq)),
             pl.BlockSpec((1, block_k, D),
                          lambda b, h, i, j: (b, j, kvh(h) + ok_)),
-            pl.BlockSpec((1, block_k, D),
+            pl.BlockSpec((1, block_k, Dv),
                          lambda b, h, i, j: (b, j, kvh(h) + ov)),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, D), lambda b, h, i, j: (b, i, h)),
+            pl.BlockSpec((1, block_q, Dv), lambda b, h, i, j: (b, i, h)),
             pl.BlockSpec((1, 1, block_q, 8),
                          lambda b, h, i, j: (b, h, i, 0)),
         ],
         out_shape=[
-            _pallas.struct((B, T, H * D), q.dtype, q, k, v),
+            _pallas.struct((B, T, H * Dv), q.dtype, q, k, v),
             _pallas.struct((B, H, T, 8), jnp.float32, q, k, v),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, 128), jnp.float32),
             pltpu.VMEM((block_q, 128), jnp.float32),
-            pltpu.VMEM((block_q, D), jnp.float32),
+            pltpu.VMEM((block_q, Dv), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
@@ -882,11 +893,13 @@ def _bwd_pallas_packed_grouped(q, k, v, o, lse, do, H, D, group, *, scale,
 
 def _bwd_pallas_packed(q, k, v, o, lse, do, H, D, plan, *, scale, causal,
                        block_q, block_k, interpret, seq_len=None,
-                       head_base=(0, 0, 0), kv_rep=1):
+                       head_base=(0, 0, 0), kv_rep=1, Dv=None):
     """Split flash backward on head-packed (B, T, C) views (see
     :func:`_fwd_packed`); ``lse`` arrives as (B, H, T) and ``o``/``do``
     are head-merged (B, T, H*D).  ``plan`` is :func:`_plan`'s: the pair
-    blocked over two heads, or the per-head pair below.
+    blocked over two heads, or the per-head pair below.  ``Dv``: the width
+    of a head of ``v``, ``o`` and ``do`` where it is not ``D`` (the
+    per-head pair alone: its bodies never ask a width).
 
     The per-head kernels read strided 256-byte rows (measured ~+1 ms/layer
     over contiguous tiles on v5e at the bench shape, vs ~+0.8 ms/layer of
@@ -903,6 +916,7 @@ def _bwd_pallas_packed(q, k, v, o, lse, do, H, D, plan, *, scale, causal,
     nk = T // block_k
     oq, ok_, ov = head_base
     kvh = _kv_head(kv_rep)
+    Dv = D if Dv is None else Dv
     # The dk/dv kernel's grid runs over the KV heads; its innermost axis
     # holds the Q blocks of each of the kv_rep query heads that read one.
     if kv_rep == 1:
@@ -919,7 +933,7 @@ def _bwd_pallas_packed(q, k, v, o, lse, do, H, D, plan, *, scale, causal,
             return i % nq
     # Per-head delta = rowsum(dO * O): reduce D inside each head.
     delta = jnp.sum((do.astype(jnp.float32)
-                     * o.astype(jnp.float32)).reshape(B, T, H, D),
+                     * o.astype(jnp.float32)).reshape(B, T, H, Dv),
                     axis=-1).transpose(0, 2, 1)               # (B, H, T)
     lse8 = jnp.broadcast_to(lse[..., None], (B, H, T, 8))
     delta8 = jnp.broadcast_to(delta[..., None], (B, H, T, 8))
@@ -929,11 +943,12 @@ def _bwd_pallas_packed(q, k, v, o, lse, do, H, D, plan, *, scale, causal,
                        lambda b, h, j, i: (b, q_block(i), q_head(h, i) + oq)),
         k=pl.BlockSpec((1, block_k, D),
                        lambda b, h, j, i: (b, j, h + ok_)),
-        v=pl.BlockSpec((1, block_k, D),
+        v=pl.BlockSpec((1, block_k, Dv),
                        lambda b, h, j, i: (b, j, h + ov)),
-        do=pl.BlockSpec((1, block_q, D),
+        do=pl.BlockSpec((1, block_q, Dv),
                         lambda b, h, j, i: (b, q_block(i), q_head(h, i))),
         out=pl.BlockSpec((1, block_k, D), lambda b, h, j, i: (b, j, h)),
+        out_v=pl.BlockSpec((1, block_k, Dv), lambda b, h, j, i: (b, j, h)),
         row8=pl.BlockSpec((1, 1, block_q, 8),
                           lambda b, h, j, i: (b, q_head(h, i), q_block(i),
                                               0)),
@@ -948,11 +963,12 @@ def _bwd_pallas_packed(q, k, v, o, lse, do, H, D, plan, *, scale, causal,
         grid=(B, H // kv_rep, nk, kv_rep * nq),
         in_specs=[kv_specs["q"], kv_specs["k"], kv_specs["v"],
                   kv_specs["do"], kv_specs["row8"], kv_specs["row8"]],
-        out_specs=[kv_specs["out"], kv_specs["out"]],
+        out_specs=[kv_specs["out"], kv_specs["out_v"]],
         out_shape=[_pallas.struct((B, T, C // kv_rep), k.dtype, q, k, v, do),
-                   _pallas.struct((B, T, C // kv_rep), v.dtype, q, k, v, do)],
+                   _pallas.struct((B, T, H * Dv // kv_rep), v.dtype, q, k, v,
+                                  do)],
         scratch_shapes=[pltpu.VMEM((block_k, D), jnp.float32),
-                        pltpu.VMEM((block_k, D), jnp.float32)],
+                        pltpu.VMEM((block_k, Dv), jnp.float32)],
         compiler_params=sem4,
         interpret=interpret,
     )(q, k, v, do, lse8, delta8)
@@ -962,9 +978,9 @@ def _bwd_pallas_packed(q, k, v, o, lse, do, H, D, plan, *, scale, causal,
                        lambda b, h, i, j: (b, i, h + oq)),
         k=pl.BlockSpec((1, block_k, D),
                        lambda b, h, i, j: (b, j, kvh(h) + ok_)),
-        v=pl.BlockSpec((1, block_k, D),
+        v=pl.BlockSpec((1, block_k, Dv),
                        lambda b, h, i, j: (b, j, kvh(h) + ov)),
-        do=pl.BlockSpec((1, block_q, D), lambda b, h, i, j: (b, i, h)),
+        do=pl.BlockSpec((1, block_q, Dv), lambda b, h, i, j: (b, i, h)),
         out=pl.BlockSpec((1, block_q, D), lambda b, h, i, j: (b, i, h)),
         row8=pl.BlockSpec((1, 1, block_q, 8),
                           lambda b, h, i, j: (b, h, i, 0)),
@@ -1253,7 +1269,7 @@ def _select_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dta_ref,
 
 def _select_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dta_ref, *rest,
                        scale, causal, block_q, block_k, seq_len, group,
-                       head_dim, has_map):
+                       head_dim, has_map, v_dim):
     """dq, dk and dv of one KV group in one sweep, grid as the forward's (Q
     blocks outside, KV blocks innermost): ``p`` and ``dS`` of a head are
     formed once a tile and feed all three products — five a tile where the
@@ -1267,7 +1283,11 @@ def _select_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dta_ref, *rest,
     score.  Without one (grouped KV heads alone) neither exists: an
     interior tile adds nothing to its scores, and a tile the causal
     diagonal or the padding cuts is masked as the per-head pair masks it
-    (:func:`_block_mask` through :func:`_masked_dispatch`)."""
+    (:func:`_block_mask` through :func:`_masked_dispatch`).
+
+    ``v_dim``: the width of a head of ``v`` and ``do``, ``head_dim`` or
+    another (``dV`` and the two products that read ``v`` and ``do`` run at
+    it)."""
     if has_map:
         (sel_ref, dq_ref, dk_ref, dv_ref, bias_scr, dq_scr, dk_scr,
          dv_scr) = rest
@@ -1277,7 +1297,7 @@ def _select_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dta_ref, *rest,
     kj = pl.program_id(3)
     nq = pl.num_programs(2)
     nk = pl.num_programs(3)
-    D = head_dim
+    D, Dv = head_dim, v_dim
 
     @pl.when(jnp.logical_and(qi == 0, kj == 0))
     def _init_head():
@@ -1297,9 +1317,8 @@ def _select_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dta_ref, *rest,
         ok = (_block_mask(qi, kj, block_q, block_k, causal, seq_len)
               if masked else None)
         for g in range(group):
-            sl = slice(g * D, (g + 1) * D)
-            q = q_ref[0, :, sl]
-            do = do_ref[0, :, sl]
+            q = q_ref[0, :, g * D:(g + 1) * D]
+            do = do_ref[0, :, g * Dv:(g + 1) * Dv]
             if has_map:
                 p, ds = _select_p_ds(q, k, v, do, lse[:, g:g + 1],
                                      delta[:, g:g + 1], scale, bias_scr[...])
@@ -1392,17 +1411,20 @@ def _select_fwd(q, k, v, select, H, D, *, scale, causal, block_q, block_k,
 
 
 def _select_bwd(q, k, v, select, o, lse, do, H, D, *, fused, scale, causal,
-                block_q, block_k, interpret, seq_len, vmem_mb):
+                block_q, block_k, interpret, seq_len, vmem_mb, Dv=None):
     """``(dq, dk, dv)`` on head-packed views, a KV group a grid step: from
     one kernel (:func:`_select_bwd_kernel`) where ``fused``, else from the
     pair (:func:`_select_dq_kernel`, :func:`_select_dkdv_kernel`); ``lse``
     arrives as (B, H, T).  ``select`` is the map, or None for a call with
     grouped KV heads and no map: the one kernel then, without the map's
     operand and scratch (the pair without a map is the per-head one,
-    :func:`_bwd_pallas_packed`)."""
+    :func:`_bwd_pallas_packed`).  ``Dv``: the width of a head of ``v``,
+    ``o`` and ``do`` where it is not ``D`` (the one kernel without a map
+    alone)."""
     B, T, _ = q.shape
     Hkv = k.shape[2] // D
     G = H // Hkv
+    Dv = D if Dv is None else Dv
     nq = T // block_q
     nk = T // block_k
     has_map = select is not None
@@ -1410,7 +1432,7 @@ def _select_bwd(q, k, v, select, o, lse, do, H, D, *, fused, scale, causal,
     like = (q, k, v, do, *maps)
     # The row statistics a KV group a block: (B, H_kv, T, G).
     delta = jnp.sum((do.astype(jnp.float32) * o.astype(jnp.float32)
-                     ).reshape(B, T, Hkv, G, D), axis=-1).transpose(0, 2, 1, 3)
+                     ).reshape(B, T, Hkv, G, Dv), axis=-1).transpose(0, 2, 1, 3)
     lse = lse.reshape(B, Hkv, G, T).transpose(0, 1, 3, 2)
     live_k = _select_live_k(causal, block_q, block_k)
     kernel_kw = dict(scale=scale, causal=causal, block_q=block_q,
@@ -1418,7 +1440,7 @@ def _select_bwd(q, k, v, select, o, lse, do, H, D, *, fused, scale, causal,
     dq_shape = _pallas.struct((B, T, H * D), q.dtype, *like)
     dkdv_shapes = [
         _pallas.struct((B, T, Hkv * D), k.dtype, *like),
-        _pallas.struct((B, T, Hkv * D), v.dtype, *like)]
+        _pallas.struct((B, T, Hkv * Dv), v.dtype, *like)]
     bias_scr = pltpu.VMEM((block_q, block_k), jnp.float32)
     dq_scr = pltpu.VMEM((G, block_q, D), jnp.float32)
 
@@ -1428,21 +1450,25 @@ def _select_bwd(q, k, v, select, o, lse, do, H, D, *, fused, scale, causal,
     q_kv = pl.BlockSpec((1, block_k, D),
                         lambda b, h, i, j: (b, live_k(i, j), h))
     q_row = pl.BlockSpec((1, 1, block_q, G), lambda b, h, i, j: (b, h, i, 0))
-    q_in_specs = [q_q, q_kv, q_kv, q_q, q_row, q_row,
+    q_v = pl.BlockSpec((1, block_k, Dv),
+                       lambda b, h, i, j: (b, live_k(i, j), h))
+    q_do = pl.BlockSpec((1, block_q, G * Dv), lambda b, h, i, j: (b, i, h))
+    q_in_specs = [q_q, q_kv, q_v, q_do, q_row, q_row,
                   pl.BlockSpec((1, block_q, block_k),
                                lambda b, h, i, j: (b, i, live_k(i, j)))]
     if fused:
         # The KV head's dK and dV: one block a head, written when the head's
         # sweep ends.
         head = pl.BlockSpec((1, T, D), lambda b, h, i, j: (b, 0, h))
+        head_v = pl.BlockSpec((1, T, Dv), lambda b, h, i, j: (b, 0, h))
         resident = [dq_scr, pltpu.VMEM((T, D), jnp.float32),
-                    pltpu.VMEM((T, D), jnp.float32)]
+                    pltpu.VMEM((T, Dv), jnp.float32)]
         return tuple(pl.pallas_call(
             functools.partial(_select_bwd_kernel, **kernel_kw,
-                              has_map=has_map),
+                              has_map=has_map, v_dim=Dv),
             grid=(B, Hkv, nq, nk),
             in_specs=q_in_specs if has_map else q_in_specs[:-1],
-            out_specs=[q_q, head, head],
+            out_specs=[q_q, head, head_v],
             out_shape=[dq_shape, *dkdv_shapes],
             scratch_shapes=[bias_scr, *resident] if has_map else resident,
             compiler_params=pltpu.CompilerParams(
@@ -1545,7 +1571,7 @@ def _bwd_live_share(T, causal, block_q, block_k, sub) -> float:
 
 def _plan(*, T, D, H, head_base, itemsize, causal, block_q, block_k,
           bwd_block_q, bwd_block_k, interpret, manual_axes,
-          vmem_headroom, kv_rep=1, select=False) -> _Plan:
+          vmem_headroom, kv_rep=1, select=False, Dv=None) -> _Plan:
     """Which forward form and which backward pair run, and the VMEM limit
     each is compiled with — the one place that chooses, from what the op
     observes at trace time and nothing else.
@@ -1569,7 +1595,28 @@ def _plan(*, T, D, H, head_base, itemsize, causal, block_q, block_k,
     ``_FUSED_RESIDENT_BYTES`` —, else the per-head pair; at ``kv_rep`` 1
     the pair blocked over two heads at its proven shape, else the per-head
     pair; heads off the lane width (merged into the batch, ``kv_rep`` 1 by
-    then) the per-head pair."""
+    then) the per-head pair.
+
+    ``Dv``: the width of a head of ``v`` where it is not ``D`` (latent
+    attention's keys of 192 = 128 | 64 against values of 128;
+    ``flash_attention`` pads each side to whole 128-lane tiles, so ``D``
+    256 and ``Dv`` 128 arrive).  Only the forms whose bodies never ask a
+    width take such a call: the grid forward with its accumulator ``Dv``
+    wide, and backward the one kernel a KV group (``"group_fused"``,
+    whatever ``kv_rep``: ``dK`` (T, D) and ``dV`` (T, Dv) float32 resident,
+    ``T (D + Dv) 4`` bytes under the same rule — 12 MiB at T 8,192 — and
+    ``PV``, ``dP`` and ``dV`` at ``Dv``) or, where the device backs no
+    such budget or they do not fit, the per-head pair."""
+    if Dv is not None and Dv != D:
+        fits = (vmem_headroom
+                and T * (D + Dv) * 4 <= _FUSED_RESIDENT_BYTES)
+        blocks = (block_q, block_k, *(_group_bwd_blocks_no_map(
+            bwd_block_q, bwd_block_k, kv_rep) if fits
+            else (bwd_block_q, bwd_block_k)))
+        bwd = ("group_fused", _SELECT_FUSED_VMEM_MB) if fits else (
+            "per_head", 0)
+        return _Plan("grid", 0, 0, *bwd, 0, _bwd_live_share(
+            T, causal, blocks[2], blocks[3], sub=0), blocks)
     if select:
         # A path of its own (lane-aligned heads only, flash_attention sees
         # to that): a KV group a grid step, forward and backward.
@@ -1647,7 +1694,8 @@ def _plan(*, T, D, H, head_base, itemsize, causal, block_q, block_k,
 
 
 def _plan_for(q, H, D, head_base, causal, block_q, block_k, bwd_block_q,
-              bwd_block_k, interpret, kv_rep=1, select=False) -> _Plan:
+              bwd_block_k, interpret, kv_rep=1, select=False,
+              Dv=None) -> _Plan:
     """:func:`_plan` for the operand ``q`` of a custom-VJP rule."""
     return _plan(T=q.shape[1], D=D, H=H, head_base=head_base,
                  itemsize=q.dtype.itemsize, causal=causal, block_q=block_q,
@@ -1655,7 +1703,13 @@ def _plan_for(q, H, D, head_base, causal, block_q, block_k, bwd_block_q,
                  bwd_block_k=bwd_block_k, interpret=interpret,
                  manual_axes=bool(jax.typeof(q).vma),
                  vmem_headroom=_pallas.vmem_headroom_ok(), kv_rep=kv_rep,
-                 select=select)
+                 select=select, Dv=Dv)
+
+
+def _v_width(k, v, D: int) -> int:
+    """The width of a head of packed ``v``, whose heads are as many as
+    those of packed ``k`` at ``D`` a head."""
+    return v.shape[2] // (k.shape[2] // D)
 
 
 @functools.partial(jax.custom_vjp,
@@ -1671,12 +1725,13 @@ def _flash_packed_fwd(q, k, v, H, scale, causal, block_q, block_k,
                       bwd_block_q, bwd_block_k, interpret, seq_len):
     D = q.shape[2] // H
     kv_rep = q.shape[2] // k.shape[2]
+    Dv = _v_width(k, v, D)
     plan = _plan_for(q, H, D, (0, 0, 0), causal, block_q, block_k,
-                     bwd_block_q, bwd_block_k, interpret, kv_rep)
+                     bwd_block_q, bwd_block_k, interpret, kv_rep, Dv=Dv)
     out, lse = _fwd_packed(q, k, v, H, D, plan, scale=scale, causal=causal,
                            block_q=block_q, block_k=block_k,
                            interpret=interpret, seq_len=seq_len,
-                           kv_rep=kv_rep)
+                           kv_rep=kv_rep, Dv=Dv)
     return out, (q, k, v, out, lse)
 
 
@@ -1685,8 +1740,9 @@ def _flash_packed_bwd(H, scale, causal, block_q, block_k, bwd_block_q,
     q, k, v, o, lse = res
     D = q.shape[2] // H
     kv_rep = q.shape[2] // k.shape[2]
+    Dv = _v_width(k, v, D)
     plan = _plan_for(q, H, D, (0, 0, 0), causal, block_q, block_k,
-                     bwd_block_q, bwd_block_k, interpret, kv_rep)
+                     bwd_block_q, bwd_block_k, interpret, kv_rep, Dv=Dv)
     if plan.bwd == "group_fused":
         return _select_bwd_call(q, k, v, None, o, lse, do, H, D, scale,
                                 causal, block_q, block_k, bwd_block_q,
@@ -1694,7 +1750,7 @@ def _flash_packed_bwd(H, scale, causal, block_q, block_k, bwd_block_q,
     return _bwd_pallas_packed(q, k, v, o, lse, do, H, D, plan, scale=scale,
                               causal=causal, block_q=bwd_block_q,
                               block_k=bwd_block_k, interpret=interpret,
-                              seq_len=seq_len, kv_rep=kv_rep)
+                              seq_len=seq_len, kv_rep=kv_rep, Dv=Dv)
 
 
 _flash_packed.defvjp(_flash_packed_fwd, _flash_packed_bwd)
@@ -1784,13 +1840,13 @@ def _qkv_bwd(qkv, o, lse, do, H, D, scale, causal, block_q, block_k,
 
 
 def _select_plan_for(q, k, H, D, causal, block_q, block_k, bwd_block_q,
-                     bwd_block_k, interpret, select=True) -> _Plan:
+                     bwd_block_k, interpret, select=True, Dv=None) -> _Plan:
     """:func:`_plan_for` on packed ``q`` and ``k`` with their own count of
     query heads a KV head — under a selection map, or (``select`` False)
     for grouped KV heads alone."""
     return _plan_for(q, H, D, (0, 0, 0), causal, block_q, block_k,
                      bwd_block_q, bwd_block_k, interpret,
-                     kv_rep=q.shape[2] // k.shape[2], select=select)
+                     kv_rep=q.shape[2] // k.shape[2], select=select, Dv=Dv)
 
 
 @_one_trace_a_shape
@@ -1814,15 +1870,16 @@ def _select_bwd_call(q, k, v, select, o, lse, do, H, D, scale, causal,
     under a selection map, or (``select`` None) the one kernel of a call
     with grouped KV heads and no map."""
     has_map = select is not None
+    Dv = _v_width(k, v, D)
     plan = _select_plan_for(q, k, H, D, causal, block_q, block_k,
-                            bwd_block_q, bwd_block_k, interpret, has_map)
+                            bwd_block_q, bwd_block_k, interpret, has_map, Dv)
     with (jax.named_scope("flash_select") if has_map
           else contextlib.nullcontext()):
         return _select_bwd(q, k, v, select, o, lse, do, H, D,
                            fused=plan.bwd == "group_fused", scale=scale,
                            causal=causal, block_q=plan.blocks[2],
                            block_k=plan.blocks[3], interpret=interpret,
-                           seq_len=seq_len, vmem_mb=plan.bwd_vmem_mb)
+                           seq_len=seq_len, vmem_mb=plan.bwd_vmem_mb, Dv=Dv)
 
 
 @functools.partial(jax.custom_vjp,
@@ -2079,6 +2136,12 @@ def bwd_kv_block(T: int, block_q: int) -> int:
                default=block_q)
 
 
+def _pad_lanes(a):
+    """``a`` (B, T, H, D) with zeros behind its last axis up to a whole
+    number of 128-lane tiles."""
+    return jnp.pad(a, [(0, 0)] * 3 + [(0, -a.shape[-1] % 128)])
+
+
 def flash_attention(q, k, v, *, causal: bool = True,
                     scale: Optional[float] = None,
                     block_q: Optional[int] = None,
@@ -2096,7 +2159,14 @@ def flash_attention(q, k, v, *, causal: bool = True,
     ``dk``, ``dv`` come back summed over the query heads of a group — by
     one backward kernel a KV group where a KV head's ``dk`` and ``dv`` fit
     VMEM (``T`` to 16,384 at ``D`` 128 on a device that backs the budget),
-    by the per-head pair elsewhere (:func:`_plan`).
+    by the per-head pair elsewhere (:func:`_plan`).  ``v`` may be of
+    another width than ``q`` and ``k``, ``(B, T, Hkv, Dv)`` — latent
+    attention's keys of 192 = 128 | 64 against values of 128: each side is
+    zero-padded to whole 128-lane tiles (the scale stays the published
+    width's), the grid forward accumulates ``Dv`` wide, the backward is
+    that one kernel a KV group with ``dV`` and the two products that read
+    ``v`` and ``dO`` at ``Dv`` (whatever ``H / Hkv``, under the same rule)
+    or the per-head pair, and the output is ``(B, T, H, Dv)``.
 
     Block sizes default to :func:`auto_block` (the largest multiple-of-8
     divisor of ``T`` up to 1024 — the largest square block whose f32
@@ -2124,11 +2194,25 @@ def flash_attention(q, k, v, *, causal: bool = True,
     (it carries no gradient).
     """
     B, T, H, D = q.shape
-    Hkv = k.shape[2]
-    if k.shape != v.shape or H % Hkv:
+    Hkv, Dv = k.shape[2], v.shape[3]
+    out_width = Dv
+    if k.shape[:3] != v.shape[:3] or k.shape[3] != D or H % Hkv:
         raise ValueError(
-            f"flash_attention: k {k.shape} and v {v.shape} must agree, in "
-            f"heads that divide q's {H}")
+            f"flash_attention: k {k.shape} must have q's head width {D} and "
+            f"v {v.shape} k's batch, length and heads, in heads that divide "
+            f"q's {H}")
+    if Dv != D:
+        if select is not None:
+            raise ValueError(
+                "flash_attention: a selection map needs keys and values of "
+                f"one width; got {D} and {Dv}")
+        # Each side in whole 128-lane tiles: a zero lane of q and k adds
+        # nothing to a score, and a zero lane of v is a zero lane of the
+        # output; the scale is the published width's.
+        if scale is None:
+            scale = 1.0 / (D ** 0.5)
+        q, k, v = (_pad_lanes(a) for a in (q, k, v))
+        out_width, D, Dv = Dv, q.shape[3], v.shape[3]
     if D % 128 and Hkv != H:
         # Heads off the lane width are merged into the batch below, one
         # (T, D) slab a head: only there are grouped keys and values
@@ -2161,8 +2245,8 @@ def flash_attention(q, k, v, *, causal: bool = True,
     if D % 128 == 0:
         out = _flash_packed(q.reshape(B, T, H * D),
                             k.reshape(B, T, Hkv * D),
-                            v.reshape(B, T, Hkv * D), int(H), *static)
-        return out.reshape(B, T, H, D)
+                            v.reshape(B, T, Hkv * Dv), int(H), *static)
+        return out.reshape(B, T, H, Dv)[..., :out_width]
 
     # A head off the lane width cannot be addressed inside a packed row:
     # the heads are merged into the batch — a transpose copy each way —

@@ -249,19 +249,28 @@ _to_token_order.defvjp(_to_token_order_fwd, _to_token_order_bwd)
 
 
 class _SharedExpert(nn.Module):
-    """One relu² expert that every token runs: ``W_down relu(W_up x)²``."""
+    """One expert that every token runs, of the layer's own activation:
+    ``W_down relu(W_up x)²``, or ``W_down (silu(W_gate x) ⊙ W_up x)``."""
     hidden: int
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
+    activation: str = "relu2"
 
     @nn.compact
     def __call__(self, x):
         d = x.shape[-1]
         init = nn.initializers.lecun_normal()
+        if self.activation == "swiglu":
+            w_gate = self.param("w_gate", init, (d, self.hidden),
+                                self.param_dtype)
         w_up = self.param("w_up", init, (d, self.hidden), self.param_dtype)
         w_down = self.param("w_down", init, (self.hidden, d),
                             self.param_dtype)
-        h = jnp.square(nn.relu(x @ w_up.astype(self.dtype)))
+        up = x @ w_up.astype(self.dtype)
+        if self.activation == "swiglu":
+            h = nn.silu(x @ w_gate.astype(self.dtype)) * up
+        else:
+            h = jnp.square(nn.relu(up))
         return h @ w_down.astype(self.dtype)
 
 
@@ -283,14 +292,29 @@ class DroplessMoE(nn.Module):
     The other settings (Nemotron-H's layer sets all of them):
 
     * ``router="sigmoid"``: scores ``s = sigmoid(W_r x)``, the k largest
-      chosen (a correction bias added for the choice alone would go here;
-      none is kept: it is zero until something trains it);
-      ``renormalize``: gates ``s_e / Σ_chosen s``; ``gate_scale``
+      chosen; ``renormalize``: gates ``s_e / Σ_chosen s``; ``gate_scale``
       multiplies them.
+    * ``choice_bias=γ > 0`` (``router="sigmoid"``): the k are the largest
+      of ``s + b`` — the bias chooses and never gates — with ``b`` (one
+      entry a router output, float32, zeros at first) NO parameter: it is
+      the variable ``choice_bias`` of the collection ``"balance"``, so it
+      carries no gradient, and where the caller makes that collection
+      mutable the layer moves it itself, under the trace scope
+      ``route/bias_update``: ``b_e ← b_e + γ · sign(mean load − load_e)``
+      with ``load`` the ``tokens_per_expert`` this call counted (DeepSeek-V3's
+      auxiliary-loss-free balancing; this shard's tokens over all the
+      router's outputs — a deployment would all-reduce the counts first).
+      ``make_train_step``'s ``aux_state`` is where it lives: ``loss_fn``
+      applies the model with ``{"params": params, **aux_state}`` and
+      ``mutable=["balance"]`` and returns what comes back.  Sown beside
+      ``tokens_per_expert``: ``choice_bias_absmax`` (of the bias the choice
+      read); noted: ``moe.bias_updates`` (1 a layer and step).  0: no
+      variable, and the layer of the commits before.
     * ``activation="relu2"``: experts of two matrices,
       ``W_down,e relu(W_up,e x)²``.
-    * ``shared_hidden > 0``: one more relu² expert that wide, run by every
-      token with gate 1 (submodule and trace scope ``shared``).
+    * ``shared_hidden > 0``: one more expert that wide and of the same
+      ``activation``, run by every token with gate 1 (submodule and trace
+      scope ``shared``).
     * ``held=(first, count)``: this shard HOLDS experts ``first ..
       first + count - 1`` of the ``num_experts`` it routes over — one
       chip's share of an expert-parallel layer, without its exchange.
@@ -376,6 +400,7 @@ class DroplessMoE(nn.Module):
     skip_choice: bool = False
     norm_eps: float = 1e-5               # of the mlp router's norm
     latent: int = 0                      # the routed experts' width; 0: d
+    choice_bias: float = 0.0             # router="sigmoid": the update's γ
 
     @property
     def routed_over(self) -> int:
@@ -394,6 +419,12 @@ class DroplessMoE(nn.Module):
         if router_state is not None and self.router != "mlp":
             raise ValueError("only router='mlp' carries a state; got "
                              f"router={self.router!r}")
+        if self.choice_bias and (self.router != "sigmoid"
+                                 or self.choice_bias < 0):
+            raise ValueError("choice_bias is the step γ > 0 of the sigmoid "
+                             "router's balancing bias (router='mlp' holds "
+                             "its own, untrained); got "
+                             f"{self.choice_bias} for {self.router!r}")
         lead, d = x.shape[:-1], x.shape[-1]
         x = x.reshape(-1, d)
         n = x.shape[0]
@@ -417,7 +448,14 @@ class DroplessMoE(nn.Module):
                     probs = jax.nn.softmax(logits, axis=-1)       # (N, E)
                 else:
                     probs = jax.nn.sigmoid(logits)
-                gate, expert = lax.top_k(probs, k)                # (N, k)
+                if self.choice_bias:
+                    bias = self.variable("balance", "choice_bias", jnp.zeros,
+                                         (routed,), jnp.float32)
+                    _, expert = lax.top_k(
+                        probs + lax.stop_gradient(bias.value), k)
+                    gate = jnp.take_along_axis(probs, expert, axis=-1)
+                else:
+                    gate, expert = lax.top_k(probs, k)            # (N, k)
             if self.renormalize:
                 gate = gate / (gate.sum(axis=-1, keepdims=True) + 1e-20)
             if self.gate_scale != 1.0:
@@ -453,6 +491,7 @@ class DroplessMoE(nn.Module):
         if self.shared_hidden:
             out = out + _SharedExpert(
                 self.shared_hidden, self.dtype, self.param_dtype,
+                self.activation,
                 name="shared")(x.astype(self.dtype)).astype(jnp.float32)
 
         with jax.named_scope("router_losses"):
@@ -464,6 +503,15 @@ class DroplessMoE(nn.Module):
         self.sow("intermediates", "aux_router_z", z_loss)
         self.sow("intermediates", "tokens_per_expert", tokens_per_expert)
         self.sow("intermediates", "expert_index", expert)
+        if self.choice_bias:
+            self.sow("intermediates", "choice_bias_absmax",
+                     jnp.abs(bias.value).max())
+            if not self.is_initializing() and self.is_mutable_collection(
+                    "balance"):
+                with jax.named_scope("route/bias_update"):
+                    load = tokens_per_expert.astype(jnp.float32)
+                    bias.value = bias.value + self.choice_bias * jnp.sign(
+                        load.mean() - load)
         counters = {
             "moe.assignments": n * k,
             "moe.fused_matmuls": fused * len(names),
@@ -484,6 +532,8 @@ class DroplessMoE(nn.Module):
             counters["moe.router_hidden"] = self.router_hidden
         if self.skip_choice:
             counters["moe.skip_choice"] = 1
+        if self.choice_bias:
+            counters["moe.bias_updates"] = 1
         note_layer(self.path, counters)
         out = out.astype(x.dtype).reshape(*lead, d)
         if self.router == "mlp":

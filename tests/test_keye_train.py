@@ -23,7 +23,7 @@ from horovod_tpu.models import (
 from horovod_tpu.ops import flash_attention
 from horovod_tpu.parallel.moe import DroplessMoE
 
-from test_hybrid_stack import share_of
+from test_hybrid_experts import share_of
 from test_keye_stack import family_cfg, model_inputs
 
 

@@ -64,7 +64,7 @@ def test_a_group_in_head_tiles_equals_the_xla_form(case, default_budget_only):
     most 3e-7 on ``dx``, ``dB``, ``dC``, 1.2e-6 on ``dt`` and ``D``, 1.5e-5
     on ``A``, a sum of terms of both signs over every position).
     bfloat16 against the XLA form at the same precisions, as
-    ``test_hybrid_stack.py`` holds the one-tile kernels: the backward
+    ``test_hybrid_scan.py`` holds the one-tile kernels: the backward
     rounds its cotangent operands to bfloat16 where autodiff on the CPU
     keeps them float32."""
     b, T, H, P, G, N, chunk, dtype, tiles = WIDE[case]

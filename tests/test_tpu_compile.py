@@ -577,6 +577,49 @@ def test_grouped_kv_flash_fwd_bwd_at_nemotron_widths(v5e, monkeypatch, b, t,
     jax.clear_caches()      # the traces do not key on the budget
 
 
+def test_latent_attention_s_kernels_fwd_bwd_at_the_joyai_cell_s_shape(
+        v5e, monkeypatch):
+    """``joyaiflash_1chip``'s call (PR 50): 32 heads, keys of 192 (128 | 64)
+    against values of 128, two sequences of 8,192.  ``flash_attention``
+    pads q and k to 256 lanes and leaves v, o and dv at 128; the grid
+    forward under Mosaic's default budget and the backward as ONE kernel a
+    head (``flash_group_bwd`` at a group of one: ``dK`` (T, 256) and ``dV``
+    (T, 128) float32 resident, 12 MiB) under the plan's 1024 x 1024 tiles
+    and 64 MB of scoped VMEM — of which the compiler counts at most 40, so
+    it compiles under that.  The gradients come back at the published
+    widths."""
+    from horovod_tpu.ops import flash_attention as fa
+
+    counted_mb = 40
+    assert fa._SELECT_FUSED_VMEM_MB >= counted_mb + 8
+    monkeypatch.setattr(fa, "_SELECT_FUSED_VMEM_MB", counted_mb)
+    jax.clear_caches()
+    one = SingleDeviceSharding(v5e[0])
+    qk = jax.ShapeDtypeStruct((2, 8192, 32, 192), jnp.bfloat16, sharding=one)
+    v = jax.ShapeDtypeStruct((2, 8192, 32, 128), jnp.bfloat16, sharding=one)
+    plans = []
+    plan = fa._plan
+    monkeypatch.setattr(
+        fa, "_plan", lambda **seen: plans.append(plan(**seen)) or plans[-1])
+
+    def loss(q, k, v):
+        return fa.flash_attention(q, k, v, causal=True).astype(
+            jnp.float32).sum()
+
+    lowered = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
+        qk, qk, v)
+    assert custom_calls(lowered.as_text()) == [
+        ("_fwd_kernel", 3), ("flash_group_bwd", 6)]
+    assert scoped_vmem_mb(lowered.as_text()) == {
+        "_fwd_kernel": 0, "flash_group_bwd": counted_mb}
+    assert {(p.fwd, p.bwd, p.blocks) for p in plans} == {
+        ("grid", "group_fused", (1024,) * 4)}
+    _, (dq, dk, dv) = lowered.compile().out_info
+    assert dq.shape == dk.shape == (2, 8192, 32, 192)
+    assert dv.shape == (2, 8192, 32, 128)
+    jax.clear_caches()      # the traces do not key on the budget
+
+
 def test_chunked_scan_fwd_bwd_at_nemotron_widths(v5e):
     """``ssd_scan_packed`` as the mixer calls it — 2 sequences of 8,192, 64
     heads of 64, 8 groups, state 128, chunks of 128, x | B | C as the
