@@ -38,6 +38,12 @@ through the entry points a user calls (``hvd.init()`` →
   through the per-head pair, and through the one fused backward kernel,
   checks that kernel's gradients against the pair's and prints the plan
   (``latent_backward``, ``mla_plan``);
+* times the forward of that call alone in every form that was timed
+  before one shipped — today's grid form, the grid form with its dead
+  steps' fetches clamped, the head's K and V rows resident with the KV loop
+  unrolled, and resident with the loop rolled over the live tiles in one,
+  two and four chains — each output and ``lse`` against the grid form's
+  (``latent_forward``);
 * checks the latent's passes of compressed convolutional attention as
   kernels at ``zaya1_1chip``'s layer — 8 query over 2 KV heads of 128, one
   sequence of 16,384 — against the module's ``jax.numpy`` form: q", k" and
@@ -1038,6 +1044,14 @@ def grouped_backward_phase(*, batch: int, seq: int, heads: int, kv_heads: int,
         lambda *a: fa._fwd_packed(*a, H, D, plan, block_q=blocks[0],
                                   block_k=blocks[1], kv_rep=H // Hkv,
                                   **common), q, k, v)
+    if plan.fwd == "grid":
+        # The control of PR 51: the dead steps' K/V fetches clamped.
+        ms["forward_live"], (o_live, lse_live) = timed(
+            lambda *a: fa._fwd_packed(
+                *a, H, D, plan._replace(fwd="grid_live"), block_q=blocks[0],
+                block_k=blocks[1], kv_rep=H // Hkv, **common), q, k, v)
+        check(bool((o_live == o).all()) and bool((lse_live == lse).all()),
+              "the grid forward with its dead fetches clamped differs")
     operands = (q, k, v, o, lse, do)
     ms["pair"], want = timed(
         lambda *a: fa._bwd_pallas_packed(
@@ -1060,6 +1074,17 @@ def grouped_backward_phase(*, batch: int, seq: int, heads: int, kv_heads: int,
             "fused_vs_pair": {n: round(e, 6) for n, e in errs.items()}}
 
 
+def _lane_padded_heads(key, shape, width: int, lanes: int):
+    """Packed bfloat16 ``(B, T, H * lanes)`` of normal heads ``width`` wide
+    with zeros behind them up to ``lanes``; ``shape`` is ``(B, T, H)``."""
+    import jax
+    import jax.numpy as jnp
+
+    a = jax.random.normal(key, (*shape, width)).astype(jnp.bfloat16)
+    return jnp.pad(a, [(0, 0)] * 3 + [(0, lanes - width)]).reshape(
+        *shape[:2], shape[2] * lanes)
+
+
 def latent_backward_phase(*, batch: int, seq: int, heads: int, qk_dim: int,
                           v_dim: int, seed: int, calls: int = 10) -> dict:
     """The flash kernels of one latent-attention layer alone — keys of
@@ -1074,7 +1099,6 @@ def latent_backward_phase(*, batch: int, seq: int, heads: int, qk_dim: int,
     gradients are checked against the pair's.  ``mla_plan`` is what
     ``flash_attention._plan`` decides for the call on this device."""
     import jax
-    import jax.numpy as jnp
 
     from horovod_tpu.ops import flash_attention as fa
 
@@ -1084,18 +1108,13 @@ def latent_backward_phase(*, batch: int, seq: int, heads: int, qk_dim: int,
     blocks = fa._resolve_blocks(T, "chip_smoke", None, None, None, None,
                                 None, "")[:4]
     ks = jax.random.split(jax.random.PRNGKey(seed), 4)
-
-    def operand(key, width, lanes):
-        a = jax.random.normal(key, (B, T, H, width)).astype(jnp.bfloat16)
-        return jnp.pad(a, [(0, 0)] * 3 + [(0, lanes - width)]).reshape(
-            B, T, H * lanes)
-
-    q, k = operand(ks[0], qk_dim, D), operand(ks[1], qk_dim, D)
+    operand = functools.partial(_lane_padded_heads, shape=(B, T, H))
+    q, k = (operand(key, width=qk_dim, lanes=D) for key in ks[:2])
     timed = functools.partial(_timed_ms, calls, interpret)
     common = dict(scale=qk_dim ** -0.5, causal=True, interpret=interpret)
     ms, kept = {}, {}
     for name, lanes in (("padded_all", D), ("own_width", Dv)):
-        v, do = operand(ks[2], v_dim, lanes), operand(ks[3], v_dim, lanes)
+        v, do = (operand(key, width=v_dim, lanes=lanes) for key in ks[2:])
         plan = fa._plan_for(q, H, D, (0, 0, 0), True, *blocks, interpret,
                             Dv=lanes)
         ms[name + ".forward"], (o, lse) = timed(
@@ -1126,6 +1145,64 @@ def latent_backward_phase(*, batch: int, seq: int, heads: int, qk_dim: int,
     return {"shape": [B, T, H, qk_dim, v_dim], "interpret": interpret,
             "mla_plan": plan._asdict(), "ms_a_layer": ms,
             "fused_vs_pair": {n: round(e, 6) for n, e in errs.items()}}
+
+
+def latent_forward_phase(*, batch: int, seq: int, heads: int, qk_dim: int,
+                         v_dim: int, seed: int, calls: int = 10) -> dict:
+    """The forward of one latent-attention layer alone — keys of ``qk_dim``
+    in whole 128-lane tiles against values of ``v_dim`` — in the forms timed
+    before one was shipped (PR 51), ``ms_a_layer`` by form and ``block_q x
+    block_k``: ``grid``, the form every such call ran before; ``grid_live``,
+    it with the K/V index of a step in the causal future held at the last
+    live block; ``unrollkv``, the head's K and V rows resident and the KV
+    loop unrolled under ``pl.when``; ``resident.<rows>``, resident with the
+    loop rolled over the live tiles and the Q block in chains of ``rows``
+    rows, the diagonal tile as the chains' triangles.  ``vs_grid``: the
+    largest difference of each form's output and ``lse`` from the grid
+    form's, of the largest value.  ``mla_plan`` is what
+    ``flash_attention._plan`` decides for the call on this device."""
+    import jax
+
+    from horovod_tpu.ops import flash_attention as fa
+
+    interpret = jax.default_backend() != "tpu"
+    B, T, H = batch, seq, heads
+    D, Dv = qk_dim + -qk_dim % 128, v_dim + -v_dim % 128
+    block = fa._resolve_blocks(T, "chip_smoke", None, None, None, None,
+                               None, "")[0]
+    half = max(block // 2, 128)
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    q, k, v = (_lane_padded_heads(key, (B, T, H), width, lanes)
+               for key, width, lanes in zip(ks, (qk_dim, qk_dim, v_dim),
+                                            (D, D, Dv)))
+    plan = fa._plan_for(q, H, D, (0, 0, 0), True, *(block,) * 4, interpret,
+                        Dv=Dv)
+    mb = fa._RESIDENT_VMEM_MB
+    # (form, rows a chain, scoped MB, block_q, block_k)
+    forms = [("grid", 0, 0, block, block),
+             ("grid_live", 0, 0, block, block),
+             ("grid_live", 0, 0, half, block),
+             ("unrollkv", 0, mb, block, block),
+             ("unrollkv", 0, mb, half, block)]
+    forms += [("resident", rows, mb, bq, bq)
+              for bq in (block, half) for rows in (bq, bq // 2, bq // 4)]
+    ms, errs, want = {}, {}, None
+    for fwd, rows, vmem_mb, bq, bk in forms:
+        name = f"{fwd}.{rows}.{bq}x{bk}" if rows else f"{fwd}.{bq}x{bk}"
+        form = plan._replace(fwd=fwd, fwd_tile=rows, fwd_vmem_mb=vmem_mb)
+        ms[name], got = _timed_ms(
+            calls, interpret, lambda *a: fa._fwd_packed(
+                *a, H, D, form, scale=qk_dim ** -0.5, causal=True,
+                block_q=bq, block_k=bk, interpret=interpret, Dv=Dv), q, k, v)
+        if want is None:
+            want = got
+            continue
+        errs[name] = [round(_rel_err(g, w), 6) for g, w in zip(got, want)]
+        check(max(errs[name]) <= SELECT_TOL,
+              f"the {name} forward at {qk_dim} | {v_dim} differs from the "
+              f"grid form in (o, lse) by {errs[name]} (bound {SELECT_TOL})")
+    return {"shape": [B, T, H, qk_dim, v_dim], "interpret": interpret,
+            "mla_plan": plan._asdict(), "ms_a_layer": ms, "vs_grid": errs}
 
 
 def delta_reference_phase(*, batch: int, seq: int, heads: int, key_dim: int,
@@ -1761,6 +1838,8 @@ def main(argv=None) -> int:
             emit("grouped_backward", cell=cell, **grouped_backward_phase(
                 **shape, seed=args.seed))
         emit("latent_backward", **latent_backward_phase(
+            **LATENT_BACKWARD, seed=args.seed))
+        emit("latent_forward", **latent_forward_phase(
             **LATENT_BACKWARD, seed=args.seed))
         emit("transformer_lm", **transformer_phase(
             mesh, events, **ONE_CHIP_LM, seed=args.seed))

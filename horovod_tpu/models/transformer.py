@@ -100,7 +100,8 @@ from jax import lax
 from horovod_tpu.layer_notes import note_layer
 from horovod_tpu.ops import _pallas, cca_passes
 from horovod_tpu.ops.flash_attention import (
-    auto_block, flash_attention_auto, flash_qkv_proj, select_tile_fetches)
+    auto_block, flash_attention_auto, flash_qkv_proj, kv_resident_bytes,
+    select_tile_fetches)
 from horovod_tpu.parallel.mesh import RANKS_AXIS
 from horovod_tpu.parallel.moe import DroplessMoE
 from horovod_tpu.parallel.ring_attention import (
@@ -588,8 +589,12 @@ class LatentAttention(nn.Module):
     ``attn.qk_head_dim``, ``attn.v_head_dim``, ``attn.padded_lanes`` (lanes
     a head the kernels multiply beyond the published widths, q·k side + v
     side: 64 at 192 | 128, one pass of a 128-wide MXU more either way; 0
-    for ``"full"``) and ``attn.latent_residual_bytes`` (bytes a token the
-    layer keeps for the backward pass between its latents and ``proj``)."""
+    for ``"full"``), ``attn.kv_resident_bytes`` (bytes of a head's K and V
+    that a step of the forward kernel holds in VMEM: the whole rows, 6 MiB
+    at T 8,192, where the flash family's plan takes its resident form; 0
+    where it streams them) and ``attn.latent_residual_bytes`` (bytes a
+    token the layer keeps for the backward pass between its latents and
+    ``proj``)."""
     num_heads: int
     q_latent: int
     kv_latent: int
@@ -650,16 +655,21 @@ class LatentAttention(nn.Module):
             with jax.named_scope("mla/attend"):
                 scale = (N + R) ** -0.5
                 if flash:
+                    # What the kernels' plan keeps of a head's K and V in
+                    # VMEM, asked of the operands the kernels see.
+                    resident[0] = kv_resident_bytes(q, k, v)
                     return flash_attention_auto(q, k, v, causal=True,
                                                 scale=scale)
                 return full_attention(q, k, v, causal=True, scale=scale)
 
+        resident = [0]
         out = attend(c_q, c_kv, k_r, w_qb, w_kvb)
         itemsize = jnp.dtype(self.dtype).itemsize
         note_layer(self.path, {
             "attn.q_latent": self.q_latent, "attn.kv_latent": self.kv_latent,
             "attn.qk_head_dim": N + R, "attn.v_head_dim": V,
             "attn.padded_lanes": lanes + (-V % 128 if flash else 0),
+            "attn.kv_resident_bytes": resident[0],
             # c_q, c_kv and k_r; o and the (8-wide, float32) row statistics
             # where a kernel wrote them.
             "attn.latent_residual_bytes": (

@@ -31,7 +31,14 @@ order of preference:
   across it), causal block pairs that are entirely masked skipped with
   ``pl.when`` — once a K/V row no longer fits in VMEM, and for heads
   off the lane width (``flash_attention`` merges those into the batch:
-  packed rows of one head).
+  packed rows of one head);
+* resident (:func:`_fwd_kernel_resident`, PR 51), for values of another
+  width than the keys: a head's K and V rows in VMEM under a stated budget
+  (to 6 MiB: T 8,192 at 256 + 128 lanes), grid ``(B, H, T/block_q)``, the
+  KV loop ROLLED inside the grid step over the live tiles alone, the Q
+  block in independent chains of 256 rows carried as values, the diagonal
+  tile as each chain's triangle.  Where the device backs no such budget,
+  the rows do not fit or the tiles are off the lanes: the grid form.
 
 Backward: ``jax.custom_vjp`` saving (o, logsumexp); gradients use the
 standard flash-backward identities (dS = P * (dP - rowsum(dO*o))) as two
@@ -83,10 +90,10 @@ without a map share the one fused backward kernel, at grouped KV heads.
 
 Values of another width than the keys (``flash_attention(q, k, v)`` with
 ``v`` narrower or wider a head: multi-head latent attention's 192 against
-128) run on the forms whose bodies never ask a width — the grid forward and,
-backward, that one fused kernel (``dK`` and ``dV`` resident at their own
-widths) or the per-head pair — with each side in whole 128-lane tiles;
-:func:`_plan` says which, from the shapes.
+128) run on the forms whose bodies never ask a width — forward the resident
+form or the grid form, backward that one fused kernel (``dK`` and ``dV``
+resident at their own widths) or the per-head pair — with each side in
+whole 128-lane tiles; :func:`_plan` says which, from the shapes.
 
 Composition: this is the *single-chip* block; for sequences sharded
 across chips use :mod:`horovod_tpu.parallel.ring_attention`, which
@@ -323,6 +330,126 @@ _UNROLL_KV_MAX_BYTES = 1 << 20
 _UNROLL_KV_MAX_NK = 16
 
 
+def _fold_tile(q, k, v, ok, m, l, acc, scale):
+    """One K/V tile folded into a chain of the online softmax: ``m``, ``l``
+    (rows, 1) and ``acc`` (rows, Dv), float32, after it.  ``ok``: the
+    tile's validity mask, or None where every position is valid.  In
+    ``lax`` calls (a ``jax.numpy`` operator on a tracer is a jitted
+    function whose trace jax reports to the span ring; PERF.md §6, PR 47)."""
+    f32 = jnp.float32
+    s = lax.mul(lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=f32), f32(scale))
+    if ok is not None:
+        s = lax.select(ok, s, lax.full_like(s, _NEG_BIG))
+    m_new = lax.max(m, lax.expand_dims(lax.reduce_max(s, (1,)), (1,)))
+    alpha = lax.exp(lax.sub(m, m_new))
+    p = lax.exp(lax.sub(s, lax.broadcast_in_dim(m_new, s.shape, (0, 1))))
+    if ok is not None:
+        p = lax.select(ok, p, lax.full_like(p, 0.0))
+    l = lax.add(lax.mul(l, alpha),
+                lax.expand_dims(lax.reduce_sum(p, (1,)), (1,)))
+    acc = lax.add(
+        lax.mul(acc, lax.broadcast_in_dim(alpha, acc.shape, (0, 1))),
+        lax.dot_general(lax.convert_element_type(p, v.dtype), v,
+                        (((1,), (0,)), ((), ())), preferred_element_type=f32))
+    return m_new, l, acc
+
+
+def _fwd_kernel_resident(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale,
+                         causal, block_q, block_k, seq_len, nk, rows):
+    """Forward with the WHOLE K and V rows of a head resident in VMEM (grid
+    (B, H, nq): fetched once a head, the values at their own width) and the
+    KV loop inside the grid step, over the live tiles alone: a rolled loop
+    of dynamic length over the tiles no mask touches, then the tiles one
+    does.  The Q block runs as ``block_q / rows`` independent chains of the
+    online softmax, carried as values — inside a loop step one chain's
+    ``exp``/max/sum overlaps another's ``q k^T`` —, and under the causal
+    mask at square blocks the tile on the diagonal is each chain's own
+    triangle: the columns left of its rows whole, then a ``rows`` x
+    ``rows`` tile under a static mask, and nothing right of it.
+
+    Alone on a v5e at latent attention's call (PR 51; 2 x 8,192 x 32 heads,
+    keys 256 lanes, values 128; ms a layer): 11.7 at 1024 x 1024 in chains
+    of 256 rows against the grid form's 15.3 (14.2 with its dead steps'
+    fetches clamped); chains of 512 rows 12.1, one chain 13.2, two chains
+    with the diagonal tile whole under an ``iota`` mask 13.7; the same rows
+    with the loop UNROLLED under ``pl.when`` (the unrolled-KV form's body)
+    24.2 at 1024 x 1024 and 12.9 at 512 x 1024."""
+    qi = pl.program_id(2)
+    chains = block_q // rows
+    # Tiles [0, n_int) need no mask; [n_int, n_live) do; the rest are dead.
+    n_int = n_live = nk
+    if causal:
+        n_int = jnp.minimum(nk, (qi * block_q + 1) // block_k)
+        n_live = jnp.minimum(nk, ((qi + 1) * block_q - 1) // block_k + 1)
+    if seq_len is not None:
+        n_int = jnp.where((qi + 1) * block_q <= seq_len,
+                          jnp.minimum(n_int, seq_len // block_k), 0)
+        n_live = jnp.where(qi * block_q < seq_len,
+                           jnp.minimum(n_live, -(-seq_len // block_k)), 0)
+    qs = [q_ref[0, c * rows:(c + 1) * rows, :] for c in range(chains)]
+
+    def tile(j, carry, masked):
+        at = pl.ds(pl.multiple_of(j * block_k, block_k), block_k)
+        k, v = k_ref[0, at, :], v_ref[0, at, :]
+        return tuple(_fold_tile(
+            qs[c], k, v,
+            _block_mask(qi * chains + c, j, rows, block_k, causal, seq_len)
+            if masked else None, *carry[c], scale) for c in range(chains))
+
+    carry = lax.fori_loop(
+        0, n_int, functools.partial(tile, masked=False),
+        ((lax.full((rows, 1), _NEG_BIG, jnp.float32),
+          lax.full((rows, 1), 0.0, jnp.float32),
+          lax.full((rows, v_ref.shape[2]), 0.0, jnp.float32)),) * chains)
+    if causal and block_q == block_k and seq_len is None:
+        on_diagonal = _block_mask(0, 0, rows, rows, True, None)
+        first = qi * block_k
+        triangles = []
+        for c, (q, chain) in enumerate(zip(qs, carry)):
+            if c:
+                at = pl.ds(pl.multiple_of(first, block_k), c * rows)
+                chain = _fold_tile(q, k_ref[0, at, :], v_ref[0, at, :],
+                                   None, *chain, scale)
+            at = pl.ds(pl.multiple_of(first + c * rows, rows), rows)
+            triangles.append(_fold_tile(q, k_ref[0, at, :], v_ref[0, at, :],
+                                        on_diagonal, *chain, scale))
+        carry = triangles
+    else:
+        carry = lax.fori_loop(n_int, n_live,
+                              functools.partial(tile, masked=True), carry)
+    outs, lses = [], []
+    for m, l, acc in carry:
+        l = lax.max(l, jnp.float32(1e-30))
+        outs.append(lax.convert_element_type(
+            lax.div(acc, lax.broadcast_in_dim(l, acc.shape, (0, 1))),
+            o_ref.dtype))
+        lses.append(lax.broadcast_in_dim(lax.add(m, lax.log(l)), (rows, 8),
+                                         (0, 1)))
+    o_ref[0] = outs[0] if chains == 1 else lax.concatenate(outs, 0)
+    lse_ref[0, 0] = lses[0] if chains == 1 else lax.concatenate(lses, 0)
+
+
+# The resident forward: K (T, D) and V (T, Dv) of a head in VMEM, twice
+# (the pipeline's two buffers), under this budget (MB).  6 MiB — T 8,192 at
+# 256 + 128 lanes in bfloat16 — is what has run on a chip (v5e, PR 51: the
+# compiler counts 24 MB at 1024 x 1024 tiles in chains of 256 rows, 28 with
+# a padded tail's masked loop); past it, on a device that backs no such
+# budget, or at tiles off the lanes or over 1024 (a chain's scores:
+# ``rows`` x ``block_k`` float32), the grid form.
+_RESIDENT_KV_BYTES = 6 * 2 ** 20
+_RESIDENT_VMEM_MB = 64
+_RESIDENT_CHAIN_ROWS = 256
+
+
+def _resident_chain_rows(block_q: int) -> int:
+    """Rows of one chain of the resident forward's Q block: 256 (four
+    chains at 1024: 11.7 ms a layer against 12.1 at two and 13.2 at one,
+    PR 51), or the block where 256 does not divide it."""
+    return (block_q if block_q % _RESIDENT_CHAIN_ROWS
+            else _RESIDENT_CHAIN_ROWS)
+
+
 def _fwd_kernel_fullunroll(q_ref, k_ref, v_ref, o_ref, lse_ref, *,
                            scale, causal, block, seq_len, nq, nk):
     """Forward with BOTH loops unrolled inside one (B, H) grid step:
@@ -416,10 +543,10 @@ def _fwd_packed(q, k, v, H, D, plan, *, scale, causal, block_q, block_k,
     ``head_base`` shifts each operand's head-block
     offset, letting q/k/v be three regions of ONE fused (B, T, 3*H*D)
     projection (so the qkv split never copies either).  ``plan`` is
-    :func:`_plan`'s: which of the three forms runs.  ``kv_rep`` query
+    :func:`_plan`'s: which of the four forms runs.  ``kv_rep`` query
     heads read each KV head (``k``, ``v`` hold ``H // kv_rep`` heads).
     ``Dv``: the width of a head of ``v`` and of the output where it is not
-    ``D`` (the grid form alone: its body never asks a width).
+    ``D`` (not the fully unrolled form: its accumulator is ``D`` wide).
     lse comes back as (B, H, T)."""
     B, T, _ = q.shape
     nq = T // block_q
@@ -456,39 +583,52 @@ def _fwd_packed(q, k, v, H, D, plan, *, scale, causal, block_q, block_k,
             interpret=interpret,
         )(q, k, v)
         return out, lse[..., 0]
-    if plan.fwd == "unrollkv":
+    if plan.fwd in ("unrollkv", "resident"):
+        # The K and V rows of a head resident, fetched once a head.
+        if plan.fwd == "resident":
+            kernel = functools.partial(_fwd_kernel_resident,
+                                       rows=plan.fwd_tile)
+            scratch, name = [], "flash_resident_fwd"
+        else:
+            kernel, name = _fwd_kernel_unrollkv, None
+            scratch = [pltpu.VMEM((block_q, 128), jnp.float32),
+                       pltpu.VMEM((block_q, 128), jnp.float32),
+                       pltpu.VMEM((block_q, Dv), jnp.float32)]
         out, lse = pl.pallas_call(
-            functools.partial(_fwd_kernel_unrollkv, scale=scale,
-                              causal=causal, block_q=block_q,
-                              block_k=block_k, seq_len=seq_len, nk=nk),
+            functools.partial(kernel, scale=scale, causal=causal,
+                              block_q=block_q, block_k=block_k,
+                              seq_len=seq_len, nk=nk),
             grid=(B, H, nq),
             in_specs=[
                 pl.BlockSpec((1, block_q, D),
                              lambda b, h, i: (b, i, h + oq)),
                 pl.BlockSpec((1, T, D),
                              lambda b, h, i: (b, 0, kvh(h) + ok_)),
-                pl.BlockSpec((1, T, D),
+                pl.BlockSpec((1, T, Dv),
                              lambda b, h, i: (b, 0, kvh(h) + ov)),
             ],
             out_specs=[
-                pl.BlockSpec((1, block_q, D), lambda b, h, i: (b, i, h)),
+                pl.BlockSpec((1, block_q, Dv), lambda b, h, i: (b, i, h)),
                 pl.BlockSpec((1, 1, block_q, 8),
                              lambda b, h, i: (b, h, i, 0)),
             ],
             out_shape=[
-                _pallas.struct((B, T, H * D), q.dtype, q, k, v),
+                _pallas.struct((B, T, H * Dv), q.dtype, q, k, v),
                 _pallas.struct((B, H, T, 8), jnp.float32, q, k, v),
             ],
-            scratch_shapes=[
-                pltpu.VMEM((block_q, 128), jnp.float32),
-                pltpu.VMEM((block_q, 128), jnp.float32),
-                pltpu.VMEM((block_q, D), jnp.float32),
-            ],
+            scratch_shapes=scratch,
             compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "parallel", "parallel")),
+                dimension_semantics=("parallel", "parallel", "parallel"),
+                **_pallas.vmem_limit(plan.fwd_vmem_mb)),
             interpret=interpret,
+            name=name,
         )(q, k, v)
         return out, lse[..., 0]
+    # "grid_live" is the grid form with the K/V index of a step in the
+    # causal future held at the last live block (no copy for a dead step):
+    # chip_smoke.py's control, which no plan gives (PERF.md §7, S4 (b)).
+    live_k = _select_live_k(causal and plan.fwd == "grid_live", block_q,
+                            block_k)
     grid = (B, H, nq, nk)
     kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
                                block_q=block_q, block_k=block_k,
@@ -500,9 +640,9 @@ def _fwd_packed(q, k, v, H, D, plan, *, scale, causal, block_q, block_k,
             pl.BlockSpec((1, block_q, D),
                          lambda b, h, i, j: (b, i, h + oq)),
             pl.BlockSpec((1, block_k, D),
-                         lambda b, h, i, j: (b, j, kvh(h) + ok_)),
+                         lambda b, h, i, j: (b, live_k(i, j), kvh(h) + ok_)),
             pl.BlockSpec((1, block_k, Dv),
-                         lambda b, h, i, j: (b, j, kvh(h) + ov)),
+                         lambda b, h, i, j: (b, live_k(i, j), kvh(h) + ov)),
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, Dv), lambda b, h, i, j: (b, i, h)),
@@ -1522,8 +1662,10 @@ def _select_bwd(q, k, v, select, o, lse, do, H, D, *, fused, scale, causal,
 
 class _Plan(NamedTuple):
     """What :func:`_plan` decides for one call of the op."""
-    fwd: str            # "fullunroll" | "unrollkv" | "grid" | "group"
-    fwd_tile: int       # the fully-unrolled form's own tile, else 0
+    fwd: str      # "fullunroll" | "unrollkv" | "resident" | "grid" | "group"
+    # The fully-unrolled form's own tile; the resident form's rows a chain;
+    # else 0.
+    fwd_tile: int
     fwd_vmem_mb: int    # scoped-VMEM budget, MB; 0 = Mosaic's default
     bwd: str            # "grouped" | "per_head" | "group" | "group_fused"
     bwd_vmem_mb: int
@@ -1601,8 +1743,13 @@ def _plan(*, T, D, H, head_base, itemsize, causal, block_q, block_k,
     attention's keys of 192 = 128 | 64 against values of 128;
     ``flash_attention`` pads each side to whole 128-lane tiles, so ``D``
     256 and ``Dv`` 128 arrive).  Only the forms whose bodies never ask a
-    width take such a call: the grid forward with its accumulator ``Dv``
-    wide, and backward the one kernel a KV group (``"group_fused"``,
+    width take such a call: forward the resident form (``"resident"``,
+    PR 51: a head's K and V rows in VMEM, ``T (D + Dv) itemsize`` bytes to
+    ``_RESIDENT_KV_BYTES`` — 6 MiB at T 8,192 —, the KV loop inside the
+    grid step in chains of ``fwd_tile`` rows under ``fwd_vmem_mb``, at
+    compiled or plainly interpreted tiles of whole lanes to 1024 on a
+    device that backs the budget) or the grid form with its accumulator
+    ``Dv`` wide, and backward the one kernel a KV group (``"group_fused"``,
     whatever ``kv_rep``: ``dK`` (T, D) and ``dV`` (T, Dv) float32 resident,
     ``T (D + Dv) 4`` bytes under the same rule — 12 MiB at T 8,192 — and
     ``PV``, ``dP`` and ``dV`` at ``Dv``) or, where the device backs no
@@ -1615,7 +1762,19 @@ def _plan(*, T, D, H, head_base, itemsize, causal, block_q, block_k,
             else (bwd_block_q, bwd_block_k)))
         bwd = ("group_fused", _SELECT_FUSED_VMEM_MB) if fits else (
             "per_head", 0)
-        return _Plan("grid", 0, 0, *bwd, 0, _bwd_live_share(
+        # At 256 + 128 lanes a tile's MXU and vector work balance, and the
+        # grid form, one chain a step and a fetch a dead step, runs at 64%
+        # of the MXU over the area it executes: the resident form where
+        # the head's K and V rows fit (PR 51: 11.7 against 15.3 ms a layer).
+        resident = (
+            vmem_headroom
+            and T * (D + Dv) * itemsize <= _RESIDENT_KV_BYTES
+            and all(b % 128 == 0 and b <= 1024 for b in (block_q, block_k))
+            # Interpreted under shard_map its dynamic slices stand down.
+            and not _pallas.xla_form(interpret, manual_axes))
+        fwd = ("resident", _resident_chain_rows(block_q),
+               _RESIDENT_VMEM_MB) if resident else ("grid", 0, 0)
+        return _Plan(*fwd, *bwd, 0, _bwd_live_share(
             T, causal, blocks[2], blocks[3], sub=0), blocks)
     if select:
         # A path of its own (lane-aligned heads only, flash_attention sees
@@ -2124,6 +2283,30 @@ def select_tile_fetches(q, k) -> int:
                       + sweeps * causal_tiles(*plan.blocks[2:]))
 
 
+def kv_resident_bytes(q, k, v) -> int:
+    """Bytes of K and V that a forward grid step of one causal call of
+    :func:`flash_attention_auto` on ``q`` (B, T, H, D), ``k`` and ``v``
+    (B, T, H_kv, D | Dv) holds resident in VMEM, as :func:`_plan` has it on
+    this device: a KV head's whole rows — ``T (D + Dv)`` elements, each
+    side in whole 128-lane tiles — in the forms that keep them there (the
+    resident, the unrolled-KV and the fully unrolled), 0 in those that
+    stream K and V a tile a step."""
+    B, _, H, D = q.shape
+    Dv = v.shape[3]
+    if Dv != D:
+        D, Dv = D + -D % 128, Dv + -Dv % 128
+    T, blk = _auto_tiling(q.shape[1])
+    plan = _plan(
+        T=T, D=D, H=H, head_base=(0, 0, 0), itemsize=q.dtype.itemsize,
+        causal=True, block_q=blk, block_k=blk, bwd_block_q=blk,
+        bwd_block_k=blk, interpret=_pallas.interpret(),
+        manual_axes=bool(jax.typeof(q).vma),
+        vmem_headroom=_pallas.vmem_headroom_ok(), kv_rep=H // k.shape[2],
+        Dv=Dv)
+    resident = plan.fwd in ("resident", "unrollkv", "fullunroll")
+    return T * (D + Dv) * q.dtype.itemsize if resident else 0
+
+
 def bwd_kv_block(T: int, block_q: int) -> int:
     """Widest backward KV block within the f32 scores-tile budget
     block_q*block_k <= 2^20 — a helper for EXPLICIT ``bwd_block_k``
@@ -2163,7 +2346,8 @@ def flash_attention(q, k, v, *, causal: bool = True,
     another width than ``q`` and ``k``, ``(B, T, Hkv, Dv)`` — latent
     attention's keys of 192 = 128 | 64 against values of 128: each side is
     zero-padded to whole 128-lane tiles (the scale stays the published
-    width's), the grid forward accumulates ``Dv`` wide, the backward is
+    width's), the forward keeps a head's K and V rows in VMEM where they
+    fit (else the grid form) and accumulates ``Dv`` wide, the backward is
     that one kernel a KV group with ``dV`` and the two products that read
     ``v`` and ``dO`` at ``Dv`` (whatever ``H / Hkv``, under the same rule)
     or the per-head pair, and the output is ``(B, T, H, Dv)``.

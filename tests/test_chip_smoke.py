@@ -268,6 +268,30 @@ def test_grouped_backward_phase(smoke):
                                          bwd_blocks, live)
 
 
+def test_latent_forward_phase(smoke):
+    """The forward forms of a call with values narrower than keys, alone
+    (interpreted here: no time, every form's ``o`` and ``lse`` against the
+    grid form's): the grid form, it with its dead fetches clamped, the
+    unrolled-KV form at two widths and the resident form in one, two and
+    four chains, at the family's block and at half of it; ``mla_plan`` is
+    the plan of the call at the family's block."""
+    out = smoke.latent_forward_phase(batch=1, seq=256, heads=2, qk_dim=192,
+                                     v_dim=128, seed=0)
+    assert out["interpret"] and out["shape"] == [1, 256, 2, 192, 128]
+    assert list(out["ms_a_layer"]) == [
+        "grid.256x256", "grid_live.256x256", "grid_live.128x256",
+        "unrollkv.256x256", "unrollkv.128x256", "resident.256.256x256",
+        "resident.128.256x256", "resident.64.256x256",
+        "resident.128.128x128", "resident.64.128x128", "resident.32.128x128"]
+    assert set(out["vs_grid"]) == set(out["ms_a_layer"]) - {"grid.256x256"}
+    assert out["vs_grid"]["grid_live.256x256"] == [0.0, 0.0]
+    assert max(max(e) for e in out["vs_grid"].values()) <= 2e-3
+    assert (out["mla_plan"]["fwd"], out["mla_plan"]["fwd_tile"],
+            out["mla_plan"]["fwd_vmem_mb"]) == ("resident", 256, 64)
+    assert smoke.LATENT_BACKWARD == dict(batch=2, seq=8192, heads=32,
+                                         qk_dim=192, v_dim=128)
+
+
 def test_delta_reference_phase(smoke):
     """The chunked delta rule against its recurrence, and the
     ``delta_plan`` line: one form, plain XLA, at the chunk it was given."""

@@ -1,7 +1,8 @@
 """The flash family at values narrower than keys (latent attention's heads
 of 192 = 128 | 64 against values of 128): ``flash_attention`` against the
 dense oracle — output, dq, dk, dv — in both backward forms ``_plan`` can
-take, the plan's rows for such a call, and every row of the table the other
+take, the resident forward (PR 51) against the grid form and the oracle,
+the plan's rows for such a call, and every row of the table the other
 calls read unchanged.  Interpreted kernels at the smallest T that tiles.
 """
 
@@ -68,6 +69,75 @@ def test_values_narrower_than_keys_equal_full_attention(
             ("grid", "group_fused" if headroom else "per_head")}
 
 
+# (T, block, causal, seq_len, chain rows): the resident forward — the head's
+# K and V rows in VMEM, a rolled loop over the live tiles, the Q block in
+# chains — at lane-wide tiles: the causal triangle in four chains, in two
+# and in one; a padded tail that leaves the last Q block partly dead (the
+# masked loop, and a tile wholly in the padding); no mask at all; padding
+# alone.
+@pytest.mark.parametrize("T,block,causal,seq_len,rows", [
+    (256, 128, True, None, 32), (256, 128, True, None, 64),
+    (256, 128, True, None, 128), (384, 128, True, 300, 32),
+    (256, 128, False, None, 32), (256, 128, False, 200, 64),
+    (256, 256, True, None, 64)],
+    ids=["causal_4_chains", "causal_2_chains", "causal_1_chain",
+         "causal_padded_tail", "no_mask", "padding_alone", "one_q_block"])
+def test_resident_forward_equals_the_grid_form_and_full_attention(
+        monkeypatch, T, block, causal, seq_len, rows):
+    monkeypatch.setattr(fa, "_RESIDENT_CHAIN_ROWS", rows)
+    q, k, v, _ = operands(1, T, 2, 2, 192, 128)
+    n = seq_len or T
+    plans = []
+    plan = fa._plan
+    monkeypatch.setattr(
+        fa, "_plan", lambda **seen: plans.append(plan(**seen)) or plans[-1])
+    out = fa.flash_attention(q, k, v, causal=causal, block_q=block,
+                             block_k=block, seq_len=seq_len, interpret=True)
+    assert {(p.fwd, p.fwd_tile, p.fwd_vmem_mb) for p in plans} == {
+        ("resident", rows, 64)}
+    want = full_attention(q[:, :n], k[:, :n], v[:, :n], causal=causal)
+    np.testing.assert_allclose(out[:, :n], want, rtol=2e-5, atol=2e-5)
+
+    # o AND lse against the grid form on the same packed, padded operands.
+    packed = [fa._pad_lanes(a).reshape(1, T, -1) for a in (q, k, v)]
+    forms = {
+        fwd: fa._fwd_packed(
+            *packed, 2, 256, plans[0]._replace(fwd=fwd), scale=192 ** -0.5,
+            causal=causal, block_q=block, block_k=block, interpret=True,
+            seq_len=seq_len, Dv=128)
+        for fwd in ("resident", "grid", "grid_live")}
+    o, lse = forms["resident"]
+    assert o.shape == (1, T, 2 * 128) and lse.shape == (1, 2, T)
+    for other in ("grid", "grid_live"):
+        np.testing.assert_allclose(o[:, :n], forms[other][0][:, :n],
+                                   rtol=2e-5, atol=2e-5)
+        np.testing.assert_allclose(lse[..., :n], forms[other][1][..., :n],
+                                   rtol=1e-6, atol=1e-5)
+    # The control shares the grid form's arithmetic to the bit.
+    assert (forms["grid"][0] == forms["grid_live"][0]).all()
+
+
+def test_resident_forward_at_grouped_kv_heads_and_its_gradients(monkeypatch):
+    """Two query heads a KV head: the rows of a KV head are fetched for
+    both, and the backward reads the ``o`` and ``lse`` the form wrote."""
+    monkeypatch.setattr(fa, "_RESIDENT_CHAIN_ROWS", 64)
+    q, k, v, w = operands(1, 256, 4, 2, 192, 128)
+
+    def flash(q, k, v):
+        return (fa.flash_attention(q, k, v, block_q=128, block_k=128,
+                                   interpret=True) * w).sum()
+
+    def dense(q, k, v):
+        return (full_attention(q, jnp.repeat(k, 2, axis=2),
+                               jnp.repeat(v, 2, axis=2), causal=True)
+                * w).sum()
+
+    got = jax.grad(flash, (0, 1, 2))(q, k, v)
+    want = jax.grad(dense, (0, 1, 2))(q, k, v)
+    for g, w_ in zip(got, want):
+        np.testing.assert_allclose(g, w_, rtol=2e-4, atol=2e-4)
+
+
 def test_what_the_widths_must_agree_in():
     q, k, v, _ = operands(1, 64, 2, 2, 192, 128)
     with pytest.raises(ValueError, match="head width 192"):
@@ -80,31 +150,54 @@ def test_what_the_widths_must_agree_in():
 
 
 # What _plan answers a call whose values are not as wide as its keys (both
-# in whole 128-lane tiles by then): the grid forward, and the one kernel a
-# KV group where dK (T, D) and dV (T, Dv) float32 fit 16 MiB on a device
-# that backs the budget — joyaiflash_1chip's call: 12 MiB, 1024 x 1024
-# tiles — else the per-head pair.
+# in whole 128-lane tiles by then).  Forward: the head's K and V rows
+# resident (PR 51) on a device that backs the budget where T (D + Dv) 2
+# bytes fit 6 MiB — joyaiflash_1chip's call: 6 MiB exactly, 1024 x 1024
+# tiles in chains of 256 rows — at tiles of whole lanes to 1024, compiled
+# Mosaic or interpreted off a mesh's manual axes; else the grid form.
+# Backward: the one kernel a KV group where dK (T, D) and dV (T, Dv) float32
+# fit 16 MiB on such a device — 12 MiB there — else the per-head pair.
+RESIDENT = ("resident", 256, 64)
 SPLIT_ROWS = {
     "cell_T8192_256_128": (
         observed(8192, D=256, H=32, base=(0, 0, 0), Dv=128),
-        ("grid", 0, 0, "group_fused", 64, 0, 0.889, (1024,) * 4)),
+        (*RESIDENT, "group_fused", 64, 0, 0.889, (1024,) * 4)),
     "cell_no_headroom": (
         observed(8192, D=256, H=32, base=(0, 0, 0), Dv=128,
                  vmem_headroom=False),
         ("grid", 0, 0, "per_head", 0, 0, 0.889, (1024,) * 4)),
-    # 16,384 x (256 + 128) x 4 = 24 MiB: the pair.
+    # 16,384 x (256 + 128) x 2 = 12 MiB of rows, x 4 = 24 MiB of gradients:
+    # the grid form and the pair.
     "T16384_256_128": (
         observed(16384, D=256, H=32, base=(0, 0, 0), Dv=128),
         ("grid", 0, 0, "per_head", 0, 0, 0.941, (1024,) * 4)),
-    # Short sequences too: no other forward has run at two widths.
     "T2048_256_128": (
         observed(2048, D=256, H=32, base=(0, 0, 0), Dv=128),
-        ("grid", 0, 0, "group_fused", 64, 0, 0.667, (1024,) * 4)),
+        (*RESIDENT, "group_fused", 64, 0, 0.667, (1024,) * 4)),
     "T8192_4Q_per_KV_256_128": (
         observed(8192, D=256, H=8, base=(0, 0, 0), kv_rep=4, Dv=128),
-        ("grid", 0, 0, "group_fused", 64, 0, 0.889, (1024, 1024, 512, 1024))),
+        (*RESIDENT, "group_fused", 64, 0, 0.889, (1024, 1024, 512, 1024))),
     "values_wider_128_256": (
         observed(8192, D=128, H=32, base=(0, 0, 0), Dv=256),
+        (*RESIDENT, "group_fused", 64, 0, 0.889, (1024,) * 4)),
+    # Float32 operands: the rows are 12 MiB.
+    "cell_float32": (
+        observed(8192, D=256, H=32, base=(0, 0, 0), Dv=128, itemsize=4),
+        ("grid", 0, 0, "group_fused", 64, 0, 0.889, (1024,) * 4)),
+    # One chain where 256 rows do not divide the Q block; tiles off the
+    # lanes, and interpreted Pallas under shard_map, stay on the grid.
+    "blocks_of_128": (
+        observed(8192, D=256, H=32, base=(0, 0, 0), Dv=128, blocks=128),
+        ("resident", 128, 64, "group_fused", 64, 0, 0.985, (128,) * 4)),
+    "blocks_of_384": (
+        observed(1536, D=256, H=32, base=(0, 0, 0), Dv=128, blocks=384),
+        ("resident", 384, 64, "group_fused", 64, 0, 0.801, (384,) * 4)),
+    "blocks_off_the_lanes": (
+        observed(64, D=256, H=2, base=(0, 0, 0), Dv=128, blocks=32),
+        ("grid", 0, 0, "group_fused", 64, 0, 0.677, (32,) * 4)),
+    "interpreted_under_shard_map": (
+        observed(8192, D=256, H=32, base=(0, 0, 0), Dv=128, interpret=True,
+                 manual_axes=True),
         ("grid", 0, 0, "group_fused", 64, 0, 0.889, (1024,) * 4)),
 }
 

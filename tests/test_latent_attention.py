@@ -109,7 +109,33 @@ def test_shapes_counters_and_what_is_kept():
             "attn.q_latent": 48, "attn.kv_latent": 32,
             "attn.qk_head_dim": 192, "attn.v_head_dim": 128,
             "attn.padded_lanes": padded,
+            # T 64 tiles in blocks of 64, off the lanes: the grid form,
+            # which streams K and V.
+            "attn.kv_resident_bytes": 0,
             "attn.latent_residual_bytes": kept}
+
+
+@pytest.mark.parametrize("headroom,kept", [
+    (True, 256 * (256 + 128) * 4), (False, 0)],
+    ids=["resident_rows", "no_headroom_streams"])
+def test_kv_resident_bytes_are_the_kernels_plan_s(monkeypatch, headroom,
+                                                  kept):
+    """At a T that tiles in whole lanes the counter is what the flash
+    family's ``_plan`` holds of a head's K and V in VMEM — the rows at 256
+    and 128 lanes (float32 here) in the resident form — and 0 where the
+    device backs no budget for it and the grid form streams them."""
+    from horovod_tpu.ops import flash_attention as fa
+
+    monkeypatch.setattr(fa._pallas, "vmem_headroom_ok", lambda: headroom)
+    x = jax.ShapeDtypeStruct((1, 256, D_MODEL), jnp.float32)
+    params = jax.eval_shape(
+        lambda x: module("full").init(jax.random.PRNGKey(0), x)["params"], x)
+    notes = {}
+    jax.eval_shape(noting_layers(
+        lambda p, x: module("flash").apply({"params": p}, x), notes),
+        params, x)
+    (noted,) = notes.values()
+    assert noted["attn.kv_resident_bytes"] == kept
 
 
 def test_the_backward_pass_keeps_the_latents_and_the_kernel_s_outputs():

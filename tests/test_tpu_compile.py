@@ -581,18 +581,23 @@ def test_latent_attention_s_kernels_fwd_bwd_at_the_joyai_cell_s_shape(
         v5e, monkeypatch):
     """``joyaiflash_1chip``'s call (PR 50): 32 heads, keys of 192 (128 | 64)
     against values of 128, two sequences of 8,192.  ``flash_attention``
-    pads q and k to 256 lanes and leaves v, o and dv at 128; the grid
-    forward under Mosaic's default budget and the backward as ONE kernel a
-    head (``flash_group_bwd`` at a group of one: ``dK`` (T, 256) and ``dV``
+    pads q and k to 256 lanes and leaves v, o and dv at 128.  Forward (PR
+    51): a head's K and V rows resident — 6 MiB, twice for the pipeline —,
+    the KV loop inside the grid step, 1024 x 1024 tiles in four chains of
+    256 rows under 64 MB of scoped VMEM, of which the compiler counts at
+    most 24 (it refuses 20).  Backward: ONE kernel a head
+    (``flash_group_bwd`` at a group of one: ``dK`` (T, 256) and ``dV``
     (T, 128) float32 resident, 12 MiB) under the plan's 1024 x 1024 tiles
-    and 64 MB of scoped VMEM — of which the compiler counts at most 40, so
-    it compiles under that.  The gradients come back at the published
+    and 64 MB — of which the compiler counts at most 40.  Both compile
+    under what it counts.  The gradients come back at the published
     widths."""
     from horovod_tpu.ops import flash_attention as fa
 
-    counted_mb = 40
+    counted_mb, counted_fwd_mb = 40, 24
     assert fa._SELECT_FUSED_VMEM_MB >= counted_mb + 8
+    assert fa._RESIDENT_VMEM_MB >= counted_fwd_mb + 8
     monkeypatch.setattr(fa, "_SELECT_FUSED_VMEM_MB", counted_mb)
+    monkeypatch.setattr(fa, "_RESIDENT_VMEM_MB", counted_fwd_mb)
     jax.clear_caches()
     one = SingleDeviceSharding(v5e[0])
     qk = jax.ShapeDtypeStruct((2, 8192, 32, 192), jnp.bfloat16, sharding=one)
@@ -609,15 +614,77 @@ def test_latent_attention_s_kernels_fwd_bwd_at_the_joyai_cell_s_shape(
     lowered = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
         qk, qk, v)
     assert custom_calls(lowered.as_text()) == [
-        ("_fwd_kernel", 3), ("flash_group_bwd", 6)]
+        ("flash_group_bwd", 6), ("flash_resident_fwd", 3)]
     assert scoped_vmem_mb(lowered.as_text()) == {
-        "_fwd_kernel": 0, "flash_group_bwd": counted_mb}
-    assert {(p.fwd, p.bwd, p.blocks) for p in plans} == {
-        ("grid", "group_fused", (1024,) * 4)}
+        "flash_resident_fwd": counted_fwd_mb, "flash_group_bwd": counted_mb}
+    assert {(p.fwd, p.fwd_tile, p.bwd, p.blocks) for p in plans} == {
+        ("resident", 256, "group_fused", (1024,) * 4)}
     _, (dq, dk, dv) = lowered.compile().out_info
     assert dq.shape == dk.shape == (2, 8192, 32, 192)
     assert dv.shape == (2, 8192, 32, 128)
     jax.clear_caches()      # the traces do not key on the budget
+
+
+# (T, block_q, block_k, causal, seq_len, chain rows): every kind of tiling
+# the two-width branch of _plan admits for the resident forward — whole
+# lanes to 1024 a side, the rows to 6 MiB — compiles under the stated 64
+# MB: the cell's own; square tiles of 512 and of 128 (one chain); Q blocks
+# narrower and wider than the K tile (the masked loop in place of the
+# triangles); a block 256 does not divide; a padded tail; no mask; and a
+# shorter sequence.
+@pytest.mark.parametrize("t,block_q,block_k,causal,seq_len,rows", [
+    (8192, 1024, 1024, True, None, 256), (8192, 512, 512, True, None, 256),
+    (8192, 128, 128, True, None, 128), (8192, 512, 1024, True, None, 256),
+    (8192, 1024, 128, True, None, 256), (1536, 384, 384, True, None, 384),
+    (8192, 1024, 1024, True, 8000, 256),
+    (8192, 1024, 1024, False, None, 256),
+    (2048, 1024, 1024, True, None, 256)],
+    ids=["cell", "square_512", "square_128", "q_narrower", "q_wider",
+         "block_of_384", "padded_tail", "no_mask", "T2048"])
+def test_resident_forward_compiles_at_every_tiling_the_plan_admits(
+        v5e, monkeypatch, t, block_q, block_k, causal, seq_len, rows):
+    from horovod_tpu.ops import flash_attention as fa
+
+    one = SingleDeviceSharding(v5e[0])
+    qk = jax.ShapeDtypeStruct((1, t, 2, 192), jnp.bfloat16, sharding=one)
+    v = jax.ShapeDtypeStruct((1, t, 2, 128), jnp.bfloat16, sharding=one)
+    plans = []
+    plan = fa._plan
+    monkeypatch.setattr(
+        fa, "_plan", lambda **seen: plans.append(plan(**seen)) or plans[-1])
+    compiled = jax.jit(lambda q, k, v: fa.flash_attention(
+        q, k, v, causal=causal, block_q=block_q, block_k=block_k,
+        seq_len=seq_len)).lower(qk, qk, v).compile()
+    assert {(p.fwd, p.fwd_tile, p.fwd_vmem_mb) for p in plans} == {
+        ("resident", rows, 64)}
+    assert "flash_resident_fwd" in compiled.as_text()
+    assert compiled.out_info.shape == (1, t, 2, 128)
+
+
+@pytest.mark.parametrize("why", ["no_headroom", "rows_over_the_bound",
+                                 "tiles_off_the_lanes"])
+def test_where_the_resident_forward_stands_down_the_grid_form_lowers(
+        v5e, monkeypatch, why):
+    """A device that backs no budget above Mosaic's default, K and V rows
+    past 6 MiB (T 16,384) and tiles off the lanes all lower to the grid
+    forward as it was, under Mosaic's default budget."""
+    from horovod_tpu.ops import flash_attention as fa
+
+    t, block = {"no_headroom": (8192, 1024),
+                "rows_over_the_bound": (16384, 1024),
+                "tiles_off_the_lanes": (8192, 64)}[why]
+    monkeypatch.setattr(fa._pallas, "vmem_headroom_ok",
+                        lambda: why != "no_headroom")
+    jax.clear_caches()
+    one = SingleDeviceSharding(v5e[0])
+    qk = jax.ShapeDtypeStruct((1, t, 2, 192), jnp.bfloat16, sharding=one)
+    v = jax.ShapeDtypeStruct((1, t, 2, 128), jnp.bfloat16, sharding=one)
+    text = jax.jit(lambda q, k, v: fa.flash_attention(
+        q, k, v, causal=True, block_q=block, block_k=block)).lower(
+            qk, qk, v).as_text()
+    assert custom_calls(text) == [("_fwd_kernel", 3)]
+    assert scoped_vmem_mb(text) == {"_fwd_kernel": 0}
+    jax.clear_caches()      # the traces do not key on the device
 
 
 def test_chunked_scan_fwd_bwd_at_nemotron_widths(v5e):
