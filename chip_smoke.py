@@ -32,6 +32,12 @@ through the entry points a user calls (``hvd.init()`` →
   forward, the per-head pair and the one fused kernel a KV group — checks
   the fused kernel's gradients against the pair's and prints which of the
   two ``flash_attention._plan`` takes here (``gqa_plan``);
+* times the flash kernels under the block-diffusion mask alone at
+  ``sdar_1chip``'s attention shape — a clean and a noised copy of 8,192
+  tokens, 32 query heads over 4 KV heads —, prints the plan and the tiles
+  visited against the tiles live (``block_mask``, ``bd_plan``), times the
+  causal mask on the same rows beside them, and checks output and gradients
+  against the dense oracle under the boolean mask at 1,024 tokens;
 * times the flash kernels of one latent-attention layer alone at
   ``joyaiflash_1chip``'s shape — keys of 192 against values of 128 — with
   every operand padded to one width, with values at their own width
@@ -140,6 +146,12 @@ GROUPED_BACKWARD = {
                         head_dim=128),
     "twotower_1chip": dict(batch=2, seq=8192, heads=32, kv_heads=2,
                            head_dim=128)}
+# One attention layer's flash kernels under the block-diffusion mask alone
+# at sdar_1chip's shape: a clean and a noised copy of 8,192 tokens, 32 query
+# heads over 4 KV heads of 128, blocks of 4; ``check_seq``: the length at
+# which they are checked against the dense oracle under the boolean mask.
+BLOCK_MASK = dict(batch=1, seq=8192, heads=32, kv_heads=4, head_dim=128,
+                  block=4, check_seq=1024)
 # One latent-attention layer's flash kernels alone at joyaiflash_1chip's
 # shape: 32 heads, keys of 192 (128 | 64) against values of 128, two
 # sequences of 8,192.
@@ -1074,6 +1086,83 @@ def grouped_backward_phase(*, batch: int, seq: int, heads: int, kv_heads: int,
             "fused_vs_pair": {n: round(e, 6) for n, e in errs.items()}}
 
 
+def block_mask_phase(*, batch: int, seq: int, heads: int, kv_heads: int,
+                     head_dim: int, block: int, check_seq: int, seed: int,
+                     calls: int = 10) -> dict:
+    """The flash kernels under the block-diffusion mask, alone, at one
+    layer's shape: ``bd_plan`` — what ``flash_attention._plan`` decides for
+    the ``2 * seq`` rows on this device —, the tiles a query head's forward
+    visits against those that hold a live pair, the forward's and the
+    backward's time (``ms_a_layer``), beside them the same operands under
+    the CAUSAL mask (136 live tiles of 1024 squared a head where the block
+    mask leaves 80 — what two plain causal passes of ``2 * seq`` rows would
+    cost), and, at ``check_seq`` tokens, output and gradients against the
+    dense oracle under the mask as a boolean matrix."""
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.ops import flash_attention as fa
+    from horovod_tpu.parallel.ring_attention import full_attention
+
+    interpret = jax.default_backend() != "tpu"
+    B, H, Hkv, D = batch, heads, kv_heads, head_dim
+    mask = ("block_diffusion", block)
+
+    def operands(T):
+        ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+        return [jax.random.normal(key, (B, 2 * T, h, D)).astype(jnp.bfloat16)
+                for key, h in zip(ks, (H, Hkv, Hkv, H))]
+
+    def flash(masked):
+        def run(q, k, v):
+            return fa.flash_attention(q, k, v, interpret=interpret, **masked)
+        return run
+
+    def backward(fn):
+        return lambda q, k, v, do: jax.vjp(fn, q, k, v)[1](do)
+
+    q, k, v, do = operands(seq)
+    blk = fa._mask_auto_block(2 * seq, mask)
+    plan = fa._plan_for(q.reshape(B, 2 * seq, -1), H, D, (0, 0, 0),
+                        fa.BlockDiffusion(block, seq), blk, blk, blk, blk,
+                        interpret, kv_rep=H // Hkv)
+    counts = fa.mask_tile_counts(q, k, mask)
+    check(counts["visited_tiles"] == counts["live_tiles"],
+          f"the forward visits {counts['visited_tiles']} tiles, "
+          f"{counts['live_tiles']} hold a live pair")
+    timed = functools.partial(_timed_ms, calls, interpret)
+    ms = {}
+    for name, masked in (("block_mask", {"mask": mask}),
+                         ("causal", {"causal": True})):
+        ms[f"{name}.forward"], _ = timed(flash(masked), q, k, v)
+        both, _ = timed(backward(flash(masked)), q, k, v, do)
+        ms[f"{name}.backward"] = (
+            None if both is None else round(both - ms[f"{name}.forward"], 3))
+    # Against the dense oracle, where its (2T, 2T) scores fit.
+    q, k, v, do = operands(check_seq)
+    rep = H // Hkv
+
+    def dense(q, k, v):
+        return full_attention(q, jnp.repeat(k, rep, 2), jnp.repeat(v, rep, 2),
+                              mask=mask)
+
+    errs = {}
+    got = jax.jit(lambda *a: (flash({"mask": mask})(*a[:3]),
+                              *backward(flash({"mask": mask}))(*a)))(
+        q, k, v, do)
+    want = jax.jit(lambda *a: (dense(*a[:3]), *backward(dense)(*a)))(
+        q, k, v, do)
+    for name, g, w in zip(("o", "dq", "dk", "dv"), got, want):
+        errs[name] = _rel_err(g, w)
+        check(errs[name] <= SELECT_TOL,
+              f"flash under the block mask differs from the dense oracle in "
+              f"{name} by {errs[name]:.3g} (bound {SELECT_TOL})")
+    return {"shape": [B, 2 * seq, H, Hkv, D], "block": block,
+            "interpret": interpret, "bd_plan": plan._asdict(),
+            "tiles": counts, "ms_a_layer": ms,
+            "against_dense": {n: round(e, 6) for n, e in errs.items()}}
+
+
 def _lane_padded_heads(key, shape, width: int, lanes: int):
     """Packed bfloat16 ``(B, T, H * lanes)`` of normal heads ``width`` wide
     with zeros behind them up to ``lanes``; ``shape`` is ``(B, T, H)``."""
@@ -1837,6 +1926,7 @@ def main(argv=None) -> int:
         for cell, shape in GROUPED_BACKWARD.items():
             emit("grouped_backward", cell=cell, **grouped_backward_phase(
                 **shape, seed=args.seed))
+        emit("block_mask", **block_mask_phase(**BLOCK_MASK, seed=args.seed))
         emit("latent_backward", **latent_backward_phase(
             **LATENT_BACKWARD, seed=args.seed))
         emit("latent_forward", **latent_forward_phase(

@@ -9,5 +9,5 @@ from horovod_tpu.models.transformer import (               # noqa: F401
     BlockStack, CompressedConvAttention, GraniteHybridLM,
     GroupedQueryAttention, JoyAIFlashLM, KeyeLM, LatentAttention,
     MultiTokenPrediction, Nemotron3SuperLM,
-    NemotronHLM, OLMoELM, OlmoHybridLM, ResidualMerge, SwiGLU, TransformerLM,
-    Zaya1LM, apply_rotary, index_losses)
+    NemotronHLM, OLMoELM, OlmoHybridLM, ResidualMerge, SDARLM, SwiGLU,
+    TransformerLM, Zaya1LM, apply_rotary, index_losses)

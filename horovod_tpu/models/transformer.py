@@ -43,9 +43,13 @@ norm, ``S`` grouped-query attention with per-head QK-norm, rotary
 positions and the keys a learned indexer selects
 (:mod:`horovod_tpu.ops.sparse_select`), ``E`` a ``DroplessMoE``.
 :func:`NemotronHLM` is the Nemotron-H setting, :func:`KeyeLM` the
-Keye-VL-2.0 language tower's.  One tower, causal,
-trained under next-token cross-entropy: no denoising objective and no
-conditioning between towers.  ``L`` and ``F`` are TWO sub-layers, a
+Keye-VL-2.0 language tower's.  Those are one causal tower under
+next-token cross-entropy.  ``diffusion=dict(block=L, mask_id=...)`` trains
+a stack of ``S`` and ``E`` layers under the block-diffusion objective
+instead: a clean and a noised copy of every sequence in one pass of ``2 T``
+rows, positions repeated, the flash family's positional block mask in
+every ``S`` layer, the noised half returned (:func:`SDARLM`); there is no
+conditioning between towers and no sampler.  ``L`` and ``F`` are TWO sub-layers, a
 mixer and then a dense SwiGLU MLP (:class:`SwiGLU`), each with the norm
 on its OUTPUT (OLMo 2's residual form: ``h = x + norm(mixer(x))``,
 ``y = h + norm(mlp(h))``): ``L`` a Gated DeltaNet linear-attention mixer
@@ -101,7 +105,7 @@ from horovod_tpu.layer_notes import note_layer
 from horovod_tpu.ops import _pallas, cca_passes
 from horovod_tpu.ops.flash_attention import (
     auto_block, flash_attention_auto, flash_qkv_proj, kv_resident_bytes,
-    select_tile_fetches)
+    mask_tile_counts, select_tile_fetches)
 from horovod_tpu.parallel.mesh import RANKS_AXIS
 from horovod_tpu.parallel.moe import DroplessMoE
 from horovod_tpu.parallel.ring_attention import (
@@ -230,9 +234,10 @@ class Attention(nn.Module):
 
 
 class GroupedQueryAttention(nn.Module):
-    """Causal attention of ``num_heads`` query heads over ``kv_heads``
+    """Attention of ``num_heads`` query heads over ``kv_heads``
     key-value heads of ``head_dim`` (query head ``h`` reads KV head
-    ``h // (num_heads / kv_heads)``), no bias; the heads' total width need
+    ``h // (num_heads / kv_heads)``) under the call's mask — the causal one
+    unless the call names another —, no bias; the heads' total width need
     not be the model's.  Keys and values are of one width here (the flash
     family takes values of another: :class:`LatentAttention`).  Parameters ``q``, ``kv`` (keys | values) and
     ``proj``.  ``attn="flash"`` reads the grouped keys and values in place
@@ -242,7 +247,17 @@ class GroupedQueryAttention(nn.Module):
     As it stands: no position encoding, no norm.  ``qk_norm``: RMSNorm
     over each head's ``head_dim`` channels of q and of k (learned scales
     ``q_norm``, ``k_norm``, epsilon ``norm_eps``), before the positions.
-    ``rope_theta``: rotary positions on q and k with this base.
+    ``rope_theta``: rotary positions on q and k with this base, at the
+    call's ``pos`` ((T,), the rows' positions in their sequence; default
+    ``arange(T)``, a row's index).  The call's ``mask``: the flash family's
+    positional block mask in the causal mask's place (``("block_diffusion",
+    L)``: the rows are a clean and a noised copy of one sequence, ``pos``
+    then ``[0 .. T/2 - 1]`` twice; :func:`~horovod_tpu.ops.flash_attention.
+    flash_attention`), the kernels under the trace scope ``bd/attend`` and
+    the counters ``attn.bd_block``, ``attn.bd_live_pairs``,
+    ``attn.bd_live_tiles``, ``attn.bd_visited_tiles`` (the forward's tiles,
+    all query heads: :func:`~horovod_tpu.ops.flash_attention.
+    mask_tile_counts`); not with an ``indexer``.
     ``scale``: the factor on ``q k^T`` (default ``head_dim ** -0.5``).
     ``make_train_step`` counts ``attn.merged_heads``: the query heads a
     step sends through the flash family's path for heads off the 128-lane
@@ -277,7 +292,7 @@ class GroupedQueryAttention(nn.Module):
     scale: Optional[float] = None
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, pos=None, mask=None):
         B, T, C = x.shape
         H, Hkv, D = self.num_heads, self.kv_heads, self.head_dim
 
@@ -292,24 +307,41 @@ class GroupedQueryAttention(nn.Module):
             q = _norm("rms", self.norm_eps, self.dtype, "q_norm")(q)
             k = _norm("rms", self.norm_eps, self.dtype, "k_norm")(k)
         if self.rope_theta is not None:
-            q = apply_rotary(q, jnp.arange(T), self.rope_theta)
-            k = apply_rotary(k, jnp.arange(T), self.rope_theta)
+            q, k = (apply_rotary(a, jnp.arange(T) if pos is None else pos,
+                                 self.rope_theta) for a in (q, k))
         if self.indexer is not None:
+            if mask is not None:
+                raise ValueError("the indexer selects among causal keys: "
+                                 f"no mask={mask!r} with it")
             out = self._selected(x, q, k, v, dense)
             return dense(C, "proj")(out.reshape(B, T, H * D))
-        if self.attn == "flash":
-            out = flash_attention_auto(q, k, v, causal=True,
-                                       scale=self.scale)
-        elif self.attn == "full":
-            out = full_attention(q, jnp.repeat(k, H // Hkv, axis=2),
-                                 jnp.repeat(v, H // Hkv, axis=2), causal=True,
-                                 scale=self.scale)
-        else:
+        if self.attn not in ("flash", "full"):
             raise ValueError("grouped-query attention runs attn='flash' or "
                              f"'full', not {self.attn!r}")
-        note_layer(self.path, {"attn.merged_heads": (
-            B * H if self.attn == "flash" and D % 128 else 0)})
+        counters = {"attn.merged_heads": (
+            B * H if self.attn == "flash" and D % 128 else 0)}
+        if mask is not None:
+            with jax.named_scope("bd/attend"):
+                out = self._attend(q, k, v, mask)
+            tiles = (mask_tile_counts(q, k, mask) if self.attn == "flash"
+                     else {"live_pairs": B * (T // 2) * (T // 2 + mask[1])})
+            counters.update({"attn.bd_block": mask[1], **{
+                f"attn.bd_{name}": count for name, count in tiles.items()}})
+        else:
+            out = self._attend(q, k, v, None)
+        note_layer(self.path, counters)
         return dense(C, "proj")(out.reshape(B, T, H * D))
+
+    def _attend(self, q, k, v, mask):
+        """The heads' output under the causal mask, or ``mask``."""
+        masked = {} if mask is None else {"mask": mask}
+        if self.attn == "flash":
+            return flash_attention_auto(q, k, v, causal=True,
+                                        scale=self.scale, **masked)
+        rep = self.num_heads // self.kv_heads
+        return full_attention(q, jnp.repeat(k, rep, axis=2),
+                              jnp.repeat(v, rep, axis=2), causal=True,
+                              scale=self.scale, **masked)
 
     def _selected(self, x, q, k, v, dense):
         """The heads' output over the keys the indexer selects, with
@@ -749,7 +781,10 @@ class PatternLayer(nn.Module):
     with ``mla`` a :class:`LatentAttention`, then ``h + mlp(mlp_norm(h))``
     with ``mlp`` a :class:`SwiGLU` ``mlp_hidden`` wide (``"d"``) or ``h +
     moe(moe_norm(h))`` with ``moe`` a ``DroplessMoE`` (``"x"``); ``sub``
-    holds the two modules' fields under ``"attn"`` and ``"moe"``."""
+    holds the two modules' fields under ``"attn"`` and ``"moe"``.
+    The call's ``pos`` and ``mask`` are an ``"S"`` layer's
+    (:class:`GroupedQueryAttention`'s call); with a mask every other kind
+    but ``"E"`` is refused."""
     kind: str
     sub: Any
     dtype: Any = jnp.bfloat16
@@ -761,9 +796,14 @@ class PatternLayer(nn.Module):
     first: bool = False
 
     @nn.compact
-    def __call__(self, x, router_state=None):
+    def __call__(self, x, router_state=None, pos=None, mask=None):
         def normed(y, name):
             return _norm(self.norm, self.norm_eps, self.ln_dtype, name)(y)
+
+        if mask is not None and self.kind not in ("S", "E"):
+            raise ValueError("positions handed in and a mask in the causal "
+                             "one's place reach 'S' layers ('E' layers read "
+                             f"neither); the layer is {self.kind!r}")
 
         if self.kind == "Z":
             y = CompressedConvAttention(**self.sub["attn"], dtype=self.dtype,
@@ -805,7 +845,7 @@ class PatternLayer(nn.Module):
         elif self.kind == "S":
             y = GroupedQueryAttention(**self.sub, dtype=self.dtype,
                                       norm_eps=self.norm_eps,
-                                      name="attn")(h)
+                                      name="attn")(h, pos, mask)
         elif self.kind == "E":
             y = DroplessMoE(**self.sub, dtype=self.dtype, name="moe")(h)[0]
         else:
@@ -1035,9 +1075,17 @@ class TransformerLM(nn.Module):
     embedding_multiplier: float = 1.0
     logits_scaling: float = 1.0
     tie_head: bool = False
+    # ``diffusion=dict(block=L, mask_id=id)``: a pattern stack of ``S`` and
+    # ``E`` layers trained under the block-diffusion objective.  The call
+    # takes ``masked`` (B, T) bool beside the ids: the stack runs the 2 T
+    # rows ``[tokens ; where(masked, mask_id, tokens)]`` — a clean and a
+    # noised copy, both at positions 0 .. T - 1 — with the flash family's
+    # ``("block_diffusion", L)`` mask in every ``S`` layer, and the call
+    # returns the NOISED half's hidden states (or logits), (B, T, ...).
+    diffusion: Any = None
 
     @nn.compact
-    def __call__(self, tokens, return_hidden=False):
+    def __call__(self, tokens, return_hidden=False, masked=None):
         """``return_hidden=True`` skips the LM-head matmul and returns the
         final-LN hidden states — pair it with
         :func:`horovod_tpu.ops.losses.fused_softmax_xent` on
@@ -1049,7 +1097,14 @@ class TransformerLM(nn.Module):
         ``mtp`` the ids are (B, T + 1) and the result is a pair, the
         stack's over ``tokens[:, :-1]`` and the prediction module's (the
         field's comment): :func:`horovod_tpu.ops.losses.multi_token_xent`
-        takes the pair of hidden states."""
+        takes the pair of hidden states.  With ``diffusion`` the call takes
+        ``masked`` (B, T) bool — the positions the noised copy replaces by
+        the mask token; None: none — and returns the noised half's (the
+        field's comment); position ``t`` of it predicts token ``t`` itself,
+        so the labels are not shifted."""
+        if masked is not None and not self.diffusion:
+            raise ValueError("masked= is the block-diffusion call's "
+                             "(diffusion=dict(block=, mask_id=))")
         if self.tp_axis and self.attn != "full":
             raise ValueError(
                 "tp_axis composes with attn='full' only (TP attention "
@@ -1057,7 +1112,7 @@ class TransformerLM(nn.Module):
         if self.tp_axis and (self.moe_experts or self.qk_norm
                              or self.pos != "learned" or self.pattern
                              or self.moe or self.indexer or self.cca
-                             or self.mla or self.mtp):
+                             or self.mla or self.mtp or self.diffusion):
             raise ValueError("tp_axis runs the GPT-2 block only: no "
                              "experts (whole, a held share or in a latent), "
                              "QK-norm, rotary positions, pattern stack or "
@@ -1065,15 +1120,15 @@ class TransformerLM(nn.Module):
         if self.pos not in ("learned", "rotary", "none"):
             raise ValueError(f"unknown pos: {self.pos!r}")
         if self.pattern is not None:
-            return self._pattern_stack(tokens, return_hidden)
+            return self._pattern_stack(tokens, return_hidden, masked)
         if (self.pos == "none" or self.moe or self.ssm or self.lin
                 or self.mlp_hidden or self.indexer or self.cca or self.mla
-                or self.tie_head or self.mtp
+                or self.tie_head or self.mtp or self.diffusion
                 or self.attn_scale is not None
                 or (self.residual_multiplier, self.embedding_multiplier,
                     self.logits_scaling) != (1.0, 1.0, 1.0)):
             raise ValueError("pos='none', ssm=, moe= (its latent= too), lin=, "
-                             "indexer=, cca=, mla=, mtp=, "
+                             "indexer=, cca=, mla=, mtp=, diffusion=, "
                              "mlp_hidden=, attn_scale=, tie_head= and the "
                              "three multipliers belong to a pattern stack; "
                              "the block stack "
@@ -1110,8 +1165,18 @@ class TransformerLM(nn.Module):
         return nn.Dense(self.vocab, use_bias=False, dtype=self.head_dtype,
                         param_dtype=jnp.float32, name="head")(x)
 
-    def _pattern_stack(self, tokens, return_hidden):
+    def _pattern_stack(self, tokens, return_hidden, masked=None):
         rotary = self.rope_theta if self.pos == "rotary" else None
+        bd = dict(self.diffusion) if self.diffusion else None
+        if bd is not None and (set(bd) != {"block", "mask_id"}
+                               or set(self.pattern) - {"S", "E"}
+                               or self.indexer or self.mtp
+                               or tokens.shape[1] % bd["block"]):
+            raise ValueError(
+                "diffusion= is dict(block=L, mask_id=id) on a pattern of 'S' "
+                "and 'E' layers without indexer= or mtp=, called with whole "
+                f"blocks of tokens; got {self.diffusion!r}, pattern "
+                f"{self.pattern!r}, {tokens.shape[1]} tokens")
         if self.attn not in ("full", "flash") or self.pos not in (
                 ("none", "rotary") if set("SZdx") & set(self.pattern)
                 else ("none",)) or (set("Zdx") & set(self.pattern)
@@ -1171,7 +1236,20 @@ class TransformerLM(nn.Module):
                                        name="mtp")
         embed = nn.Embed(self.vocab, self.dim, param_dtype=jnp.float32,
                          dtype=self.dtype, name="tok_emb")
-        x = embed(tokens)
+        where = {}                # an 'S' layer's positions and mask
+        if bd is None:
+            x = embed(tokens)
+        else:
+            B, T = tokens.shape
+            with jax.named_scope("bd/assemble"):
+                if masked is None:
+                    masked = jnp.zeros(tokens.shape, jnp.bool_)
+                x = embed(jnp.concatenate(
+                    [tokens, jnp.where(masked, bd["mask_id"], tokens)],
+                    axis=1))
+                where = dict(pos=jnp.tile(jnp.arange(T), 2),
+                             mask=("block_diffusion", bd["block"]))
+            self.sow("intermediates", "masked_tokens", masked.sum())
         if self.embedding_multiplier != 1.0:
             x = x * self.embedding_multiplier
         if mtp is not None:
@@ -1185,12 +1263,18 @@ class TransformerLM(nn.Module):
             if kind == "Z":
                 x, router_state = layer(x, router_state)
             else:
-                x = layer(x)
+                x = layer(x, **where)
+        if bd is not None:
+            with jax.named_scope("bd/split"):
+                x = x[:, T:]
         x = _norm(self.norm, self.norm_eps, self.ln_dtype, "ln_f")(x)
         if self.logits_scaling != 1.0:
             x = x / self.logits_scaling
-        if self.tie_head:
-            note_layer(self.path, {"lm.tied_head": 1})
+        counters = {"lm.tied_head": 1} if self.tie_head else {}
+        if bd is not None:
+            counters["lm.bd_rows"] = 2 * B * T
+        if counters:
+            note_layer(self.path, counters)
         if mtp is not None:
             x = (x, mtp(x, next_emb))
         if return_hidden:
@@ -1369,6 +1453,47 @@ def KeyeLM(**overrides) -> TransformerLM:
         moe_experts=128, moe_top_k=8, moe_hidden=768,
         moe=dict(router="softmax", renormalize=True, activation="swiglu"))
     fields.update(overrides)
+    return TransformerLM(**fields)
+
+
+def SDARLM(**overrides) -> TransformerLM:
+    """The stack that ``JetLM/SDAR-30B-A3B-Chat``'s config.json describes
+    (``model_type`` ``sdar_moe``; the key set is the Qwen3-MoE decoder's),
+    as a :class:`TransformerLM` with a ``pattern``: 48 layers ``SE`` at d
+    2048, pre-norm RMSNorm eps 1e-6; ``S`` attention of 32 query heads over
+    4 KV heads of 128, RMSNorm over each head of q and of k, rotary
+    positions (theta 1e6); ``E`` 128 SwiGLU experts 768 wide, top-8 by
+    softmax renormalised, no shared expert; vocab 151936, untied head —
+    :func:`KeyeLM`'s layer without its indexer.  What is its own is the
+    OBJECTIVE, block diffusion (``diffusion=dict(block=4, mask_id=...)``;
+    :class:`TransformerLM`'s field): the model is called with the ids and
+    ``masked``, runs a clean and a noised copy of every sequence in one
+    pass of 2 T rows — positions 0 .. T - 1 twice, the flash family's
+    ``("block_diffusion", 4)`` mask — and returns the noised half's hidden
+    states, whose cross-entropy against the SAME positions' clean ids,
+    weighted ``1 / t_b`` on the masked positions of block ``b`` (mask rate
+    ``t_b``) and 0 elsewhere, is the loss (the noise is the batch's, the
+    weighting the caller's, around ONE ``fused_softmax_xent`` pass).
+
+    config.json gives neither the block length nor the schedule: 4 is the
+    released Chat models' block length, ``mask_id`` defaults to the
+    vocabulary's last row (a cut sets its own slice's), and
+    ``benchmark/configs/sdar-30b-a3b-chat.json`` lists both under
+    ``assumed`` beside per-head QK-norm.  Not here: a sampler that
+    generates by denoising blocks (there is no serving path).
+
+    ``overrides`` replace any field: a cut takes the first letters of the
+    pattern, and ``moe={..., "held": (first, count)}`` keeps one chip's
+    share of every layer's experts."""
+    fields = dict(
+        vocab=151936, dim=2048, num_heads=32, kv_heads=4, head_dim=128,
+        max_len=32768, norm="rms", norm_eps=1e-6, pos="rotary",
+        rope_theta=1e6, qk_norm=True, pattern="SE" * 48,
+        moe_experts=128, moe_top_k=8, moe_hidden=768,
+        moe=dict(router="softmax", renormalize=True, activation="swiglu"))
+    fields.update(overrides)
+    fields.setdefault("diffusion",
+                      dict(block=4, mask_id=fields["vocab"] - 1))
     return TransformerLM(**fields)
 
 

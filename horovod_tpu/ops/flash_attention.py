@@ -2,7 +2,7 @@
 
 The transformer family's attention math (`full_attention`) leaves XLA to
 materialize the (T, T) logits in HBM.  These kernels compute the same
-causal softmax-attention with the flash schedule instead: Q blocks stay
+masked softmax-attention with the flash schedule instead: Q blocks stay
 resident in VMEM while K/V stream through, the online-softmax
 accumulators (running max / sum / output, all f32) never leave VMEM, and
 the MXU sees back-to-back (block_q x d) @ (d x block_k) matmuls.  HBM
@@ -95,6 +95,19 @@ form or the grid form, backward that one fused kernel (``dK`` and ``dV``
 resident at their own widths) or the per-head pair — with each side in
 whole 128-lane tiles; :func:`_plan` says which, from the shapes.
 
+The mask: causal, none, or — since PR 52 — a positional block mask known
+at trace time (``flash_attention(mask=("block_diffusion", L))``,
+:class:`BlockDiffusion`: a clean and a noised copy of a sequence in one
+call, the block-diffusion objective's four rules).  Inside the kernels it
+rides in the ``causal`` slot, and the five mask helpers (``_block_mask``,
+``_interior``, ``_static_dead``, ``_static_interior``, ``_live_block``)
+dispatch on it: every form that reaches the mask through them alone — the
+three one-width forwards, the per-head pair, the one fused kernel a KV
+group — runs under it and visits no tile it leaves nothing of, with no map
+fetched; a dead grid step holds a live block (:func:`_bd_live_k`).  The
+pair blocked over two heads and the resident forward carry the causal
+mask's own arithmetic and are never planned for it.
+
 Composition: this is the *single-chip* block; for sequences sharded
 across chips use :mod:`horovod_tpu.parallel.ring_attention`, which
 streams K/V between chips with the same online-softmax math.
@@ -122,12 +135,86 @@ from horovod_tpu.ops import _pallas
 from horovod_tpu.parallel.ring_attention import _NEG_BIG, full_attention
 
 
-def _block_mask(qi, kj, block_q, block_k, causal, seq_len):
+class BlockDiffusion(NamedTuple):
+    """The block-diffusion mask, as the kernels carry it in their ``causal``
+    slot (``flash_attention(mask=("block_diffusion", L))``): the call's rows
+    are a clean copy of a sequence of ``half`` tokens and then a noised
+    copy, both at positions ``0 .. half - 1``, cut into blocks of ``block``
+    tokens.  With ``qb`` and ``kb`` the blocks of a query's and a key's
+    position, a clean query reads the clean keys with ``kb <= qb`` and no
+    noised key; a noised query reads the clean keys with ``kb < qb`` and the
+    noised keys with ``kb == qb`` (its own block, in both directions).  The
+    live set is a function of the positions alone, so no map is fetched:
+    every tile edge is a multiple of ``block`` and divides ``half``, so a
+    tile lies in one stream a side and the mask differs from the causal one
+    only inside the tiles on the streams' diagonals."""
+    block: int
+    half: int
+
+
+def _positional(mask) -> bool:
+    """Whether a kernel's ``causal`` is a positional block mask and not the
+    causal mask's flag."""
+    return isinstance(mask, BlockDiffusion)
+
+
+_FAR = 1 << 30
+
+
+def _bd_bounds(mask, qi, kj, block_q, block_k):
+    """Block pair ``(qi, kj)`` under the block-diffusion mask: ``(lo, hi,
+    dmin, dmax, qp, kp)``.  A query of the pair reads a key iff ``lo <= kb -
+    qb <= hi`` (:class:`BlockDiffusion`; the bounds are the pair's own: its
+    tiles lie in one stream each); ``dmin`` and ``dmax`` are the least and
+    the largest ``kb - qb`` inside the pair, ``qp`` and ``kp`` the first
+    rows' positions in their sequence.  Python integers in, python integers
+    out (the fully unrolled form); traced indices in, traced scalars out."""
+    L, half = mask
+    q0, k0 = qi * block_q, kj * block_k
+    qn, kn = (q0 >= half) * 1, (k0 >= half) * 1      # the noised stream?
+    qp, kp = q0 - half * qn, k0 - half * kn
+    hi = -qn * (1 - kn) - _FAR * (1 - qn) * kn
+    lo = -_FAR * (1 - qn * kn)
+    apart = kp // L - qp // L
+    return (lo, hi, apart - (block_q // L - 1), apart + (block_k // L - 1),
+            qp, kp)
+
+
+def _bd_live_interior(mask, qi, kj, block_q, block_k):
+    """``(live, interior)`` of a block pair under the block-diffusion mask:
+    whether any of its positions is valid, and whether every one is."""
+    lo, hi, dmin, dmax, _, _ = _bd_bounds(mask, qi, kj, block_q, block_k)
+    return ((dmin <= hi) & (dmax >= lo)), ((dmin >= lo) & (dmax <= hi))
+
+
+def _bd_block_mask(mask, qi, kj, block_q, block_k):
+    """(BQ, BK) validity of a block pair under the block-diffusion mask:
+    two compares on the difference of the columns' and the rows' blocks."""
+    lo, hi, _, _, qp, kp = _bd_bounds(mask, qi, kj, block_q, block_k)
+    L = mask.block
+
+    def blocks(first, axis):
+        at = lax.broadcasted_iota(jnp.int32, (block_q, block_k), axis)
+        if L & (L - 1):
+            return lax.div(first + at, jnp.int32(L))
+        return lax.shift_right_logical(first + at,
+                                       jnp.int32(L.bit_length() - 1))
+
+    apart = blocks(kp, 1) - blocks(qp, 0)
+    return jnp.logical_and(apart <= hi, apart >= lo)
+
+
+def _block_mask(qi, kj, block_q, block_k, mask, seq_len):
     """(BQ, BK) validity mask for this block pair, or None when every
-    position is valid.  ``seq_len``: real sequence length when the array
+    position is valid.  ``mask``: the causal flag, or a positional block
+    mask (:class:`BlockDiffusion`, never with padding).  ``seq_len``: real
+    sequence length when the array
     is zero-padded to a tileable T (positions >= seq_len are masked on
     both the row and column side, keeping padded-row softmax grads from
     producing inf*0 NaNs in the backward)."""
+    if _positional(mask):
+        return _bd_block_mask(mask, qi, kj, block_q, block_k)
+    causal = mask
     if not causal and seq_len is None:
         return None
     rows = qi * block_q + lax.broadcasted_iota(
@@ -143,12 +230,14 @@ def _block_mask(qi, kj, block_q, block_k, causal, seq_len):
     return ok
 
 
-def _interior(qi, kj, block_q, block_k, causal, seq_len):
+def _interior(qi, kj, block_q, block_k, mask, seq_len):
     """True when every position of this block pair is valid, so the
     masked code path (iota + two selects per block) can be skipped.
     Returns the literal ``True`` when no masking can ever apply."""
+    if _positional(mask):
+        return _bd_live_interior(mask, qi, kj, block_q, block_k)[1]
     ok = True
-    if causal:
+    if mask:
         # Fully visible iff the last key column <= the first query row.
         ok = jnp.logical_and(ok, (kj + 1) * block_k - 1 <= qi * block_q)
     if seq_len is not None:
@@ -175,33 +264,41 @@ def _masked_dispatch(compute, live, qi, kj, block_q, block_k, causal,
         functools.partial(compute, masked=True))
 
 
-def _static_dead(qi: int, kj: int, block: int, causal, seq_len) -> bool:
+def _static_dead(qi: int, kj: int, block: int, mask, seq_len) -> bool:
     """Trace-time dead test for the fully-unrolled kernels (python-int
-    block pair): causal-future pairs and pairs entirely inside the
+    block pair): causal-future pairs, pairs a positional mask leaves no
+    position of, and pairs entirely inside the
     padding tail emit no code at all."""
-    if causal and kj * block > (qi + 1) * block - 1:
+    if _positional(mask):
+        return not _bd_live_interior(mask, qi, kj, block, block)[0]
+    if mask and kj * block > (qi + 1) * block - 1:
         return True
     return seq_len is not None and (kj * block >= seq_len
                                     or qi * block >= seq_len)
 
 
-def _static_interior(qi: int, kj: int, block: int, causal,
+def _static_interior(qi: int, kj: int, block: int, mask,
                      seq_len) -> bool:
     """Trace-time interior test (python-int block pair): True when no
     element of the pair can be masked, so the where/iota path is
     skipped statically."""
-    return ((not causal or (kj + 1) * block - 1 <= qi * block)
+    if _positional(mask):
+        return _bd_live_interior(mask, qi, kj, block, block)[1]
+    return ((not mask or (kj + 1) * block - 1 <= qi * block)
             and (seq_len is None
                  or (max(qi, kj) + 1) * block <= seq_len))
 
 
-def _live_block(qi, kj, block_q, block_k, causal, seq_len):
+def _live_block(qi, kj, block_q, block_k, mask, seq_len):
     """Whether this block pair contributes at all: causal-future KV
-    blocks and block rows/columns entirely inside the padding tail are
+    blocks, pairs a positional mask leaves no position of, and block
+    rows/columns entirely inside the padding tail are
     skipped outright."""
+    if _positional(mask):
+        return _bd_live_interior(mask, qi, kj, block_q, block_k)[0]
     q_last = (qi + 1) * block_q - 1
     k_first = kj * block_k
-    live = jnp.logical_or(not causal, k_first <= q_last)
+    live = jnp.logical_or(not mask, k_first <= q_last)
     if seq_len is not None:
         live = jnp.logical_and(live, k_first < seq_len)
         live = jnp.logical_and(live, qi * block_q < seq_len)
@@ -627,8 +724,10 @@ def _fwd_packed(q, k, v, H, D, plan, *, scale, causal, block_q, block_k,
     # "grid_live" is the grid form with the K/V index of a step in the
     # causal future held at the last live block (no copy for a dead step):
     # chip_smoke.py's control, which no plan gives (PERF.md §7, S4 (b)).
-    live_k = _select_live_k(causal and plan.fwd == "grid_live", block_q,
-                            block_k)
+    # Under a positional mask most steps are dead, and every plan holds.
+    live_k = _select_live_k(
+        causal if plan.fwd == "grid_live" or _positional(causal) else False,
+        block_q, block_k)
     grid = (B, H, nq, nk)
     kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
                                block_q=block_q, block_k=block_k,
@@ -1501,7 +1600,25 @@ def _select_live_k(causal, block_q, block_k):
     (the pipeline then issues no copy for the dead step)."""
     if not causal:
         return lambda i, j: j
+    if _positional(causal):
+        return functools.partial(_bd_live_k, causal, block_q, block_k)
     return lambda i, j: jnp.minimum(j, ((i + 1) * block_q - 1) // block_k)
+
+
+def _bd_live_k(mask, block_q, block_k, i, j):
+    """:func:`_select_live_k` under the block-diffusion mask.  The live KV
+    blocks of Q block ``i`` are the clean ones up to its last row's block
+    (a noised row's own block left out) and, for a noised Q block, the
+    noised ones that hold its own positions; a dead ``j`` between the two
+    runs holds the noised run's first block, one behind them its last."""
+    L, half = mask
+    q0 = i * block_q
+    noised = q0 >= half
+    qp = q0 - half * noised
+    last_clean = (qp + block_q - 1 - L * noised) // block_k
+    first, last = (half + qp) // block_k, (half + qp + block_q - 1) // block_k
+    return jnp.where(noised & (j > last_clean), jnp.clip(j, first, last),
+                     jnp.minimum(j, last_clean))
 
 
 def _select_fwd(q, k, v, select, H, D, *, scale, causal, block_q, block_k,
@@ -1693,14 +1810,27 @@ def _diag_sub(causal, block_q, block_k, sub=_DIAG_SUB) -> int:
     return sub if fits else 0
 
 
+def _bd_tiles(mask, T, block_q, block_k) -> int:
+    """Block pairs of one head that a kernel of these blocks computes under
+    the block-diffusion mask: those :func:`_live_block` lets through."""
+    return sum(_bd_live_interior(mask, qi, kj, block_q, block_k)[0]
+               for qi in range(T // block_q) for kj in range(T // block_k))
+
+
 def _bwd_live_share(T, causal, block_q, block_k, sub) -> float:
     """Share of the score elements the backward pair computes that the
     mask leaves standing (padding not counted): 1 without a mask; under
     the causal one, ``T (T + 1) / 2`` over the area of the block pairs not
     wholly in the future — a diagonal pair cut into ``sub``-wide sub-tiles
-    counting only those at or under the diagonal."""
+    counting only those at or under the diagonal; under the block-diffusion
+    mask its ``half (half + block)`` pairs over the area of the block pairs
+    it leaves a position of."""
     if not causal:
         return 1.0
+    if _positional(causal):
+        half, L = causal.half, causal.block
+        return round(half * (half + L) / (
+            _bd_tiles(causal, T, block_q, block_k) * block_q * block_k), 3)
     computed = 0
     for qi in range(T // block_q):
         pairs = min(T // block_k, ((qi + 1) * block_q - 1) // block_k + 1)
@@ -1724,6 +1854,11 @@ def _plan(*, T, D, H, head_base, itemsize, causal, block_q, block_k,
     ``manual_axes``: whether the operands vary over manual mesh axes
     (``shard_map``); ``vmem_headroom``: :func:`_pallas.vmem_headroom_ok` —
     whether the device backs a scoped budget above Mosaic's default;
+    ``causal`` may be a positional block mask (:class:`BlockDiffusion`):
+    the forward forms and the backward forms below take it through the
+    five mask helpers and visit no pair it leaves nothing of — all but the
+    pair blocked over two heads (the per-head pair in its place) and the
+    forms of values of another width, which such a call never reaches.
     ``kv_rep``: query heads a KV head (1: multi-head attention);
     ``select``: whether the call carries a selection map — then the group
     form each way under the blocks of ``_Plan.blocks``: the backward as one
@@ -1800,7 +1935,12 @@ def _plan(*, T, D, H, head_base, itemsize, causal, block_q, block_k,
     # of 8 beyond the tile, else the other forms take over.
     tile = min(_FULL_UNROLL_BLOCK, block_q, block_k, T)
     fwd_vmem_mb = 0 if T <= _DEFAULT_VMEM_MAX_T else _FULL_UNROLL_VMEM_MB
+    positional = _positional(causal)
     if (T <= _FULL_UNROLL_MAX_T and T % tile == 0
+            # A positional mask's tiles lie in one stream and hold whole
+            # blocks of it.
+            and not (positional and (causal.half % tile
+                                     or tile % causal.block))
             and T // tile <= _FULL_UNROLL_MAX_NQ
             # CPU tests under shard_map take the unrolled-KV form.
             and not _pallas.xla_form(interpret, manual_axes)
@@ -1841,6 +1981,9 @@ def _plan(*, T, D, H, head_base, itemsize, causal, block_q, block_k,
             # as adjacent lanes; under grouped KV heads the two read the
             # same ones, which only the per-head pair's index maps do.
             and kv_rep == 1
+            # Its dead steps' index maps and its diagonal sub-tiles are the
+            # causal mask's own.
+            and not positional
             and vmem_headroom):
         # Only the grouped pair has been timed with its diagonal blocks
         # cut into sub-tiles.
@@ -2210,7 +2353,8 @@ def _resolve_blocks(T: int, fn_name: str, block_q, block_k, bwd_block_q,
 
 
 def flash_attention_auto(q, k, v, *, causal: bool = True,
-                         scale: Optional[float] = None, select=None):
+                         scale: Optional[float] = None, select=None,
+                         mask=None):
     """:func:`flash_attention` with automatic block sizing and padding —
     the drop-in local attention kernel for models and for
     ``ulysses_attention(attn_fn=...)``.
@@ -2223,10 +2367,16 @@ def flash_attention_auto(q, k, v, *, causal: bool = True,
     dense fallback would OOM at exactly the lengths this kernel exists
     for).  Off-TPU the kernel runs in interpret mode so callers stay
     hermetic.  ``select``: :func:`flash_attention`'s; the result is then
-    ``(out, lse)``.
+    ``(out, lse)``.  ``mask``: :func:`flash_attention`'s positional block
+    mask; the block is :func:`auto_block`'s of ONE stream's length, and a
+    length that would need padding is refused (``ValueError``).
     """
     T = q.shape[1]
     interpret = _pallas.interpret()
+    if mask is not None:
+        blk = _mask_auto_block(T, mask)
+        return flash_attention(q, k, v, mask=mask, scale=scale, block_q=blk,
+                               block_k=blk, interpret=interpret)
     T_pad, blk = _auto_tiling(T)
     more = {} if select is None else {"select": select}
     if T_pad == T:
@@ -2244,6 +2394,53 @@ def flash_attention_auto(q, k, v, *, causal: bool = True,
     if select is not None:
         return out[0][:, :T], out[1][:, :, :T]
     return out[:, :T]
+
+
+def _mask_auto_block(rows: int, mask) -> int:
+    """:func:`flash_attention_auto`'s block for ``rows`` rows under a
+    positional mask: :func:`auto_block`'s of one stream's length, which has
+    to hold whole blocks of the mask."""
+    half = rows // 2
+    blk = auto_block(half)
+    if rows % 2 or not blk or blk % _block_diffusion(mask):
+        raise ValueError(
+            f"flash_attention_auto: {rows} rows are no two streams that tile "
+            f"in whole blocks of the mask {mask!r} (no padding under a "
+            "positional mask)")
+    return blk
+
+
+def _block_diffusion(mask) -> int:
+    """The block length of ``mask = ("block_diffusion", L)``."""
+    if (not isinstance(mask, tuple) or len(mask) != 2
+            or mask[0] != "block_diffusion" or int(mask[1]) < 1):
+        raise ValueError('flash_attention: a mask is ("block_diffusion", L), '
+                         f"L a block length; got {mask!r}")
+    return int(mask[1])
+
+
+def mask_tile_counts(q, k, mask) -> dict:
+    """What one call of :func:`flash_attention_auto` on ``q`` (B, 2T, H, D)
+    and ``k`` under the block-diffusion ``mask`` reads and does, forward, as
+    :func:`_plan` has it on this device: ``live_pairs`` — the (query, key)
+    pairs the mask leaves, ``T (T + L)`` a sequence —, ``live_tiles`` — the
+    forward form's tiles that hold one of them, a query head (from the
+    mask's definition: with ``n`` tiles a stream, ``n (n + 1) / 2`` of the
+    clean rows, as many of the noised rows over the clean keys but for the
+    ``n`` on the diagonal where the tile is one block, and ``n`` over their
+    own) — and ``visited_tiles`` — the tiles its kernel computes (the dead
+    test its grid steps run)."""
+    B, rows, H, D = q.shape
+    L, blk = _block_diffusion(mask), _mask_auto_block(rows, mask)
+    bd = BlockDiffusion(L, rows // 2)
+    plan = _plan_for(
+        jax.ShapeDtypeStruct((B, rows, H * D), q.dtype), H, D, (0, 0, 0), bd,
+        blk, blk, blk, blk, _pallas.interpret(), kv_rep=H // k.shape[2])
+    tile = plan.fwd_tile if plan.fwd == "fullunroll" else blk
+    n = bd.half // tile
+    return {"live_pairs": B * bd.half * (bd.half + L),
+            "live_tiles": B * H * (n * n + n + (n if tile > L else 0)),
+            "visited_tiles": B * H * _bd_tiles(bd, rows, tile, tile)}
 
 
 def _auto_tiling(T: int):
@@ -2333,8 +2530,9 @@ def flash_attention(q, k, v, *, causal: bool = True,
                     bwd_block_k: Optional[int] = None,
                     interpret: bool = False,
                     seq_len: Optional[int] = None,
-                    select=None):
-    """Fused flash attention for ``(B, T, H, D)`` inputs (same contract as
+                    select=None, mask=None):
+    """Fused flash attention for ``(B, T, H, D)`` inputs under the call's
+    mask — causal (the default), none, or ``mask`` — (same contract as
     :func:`~horovod_tpu.parallel.ring_attention.full_attention`).  ``k`` and
     ``v`` may hold fewer heads, ``(B, T, Hkv, D)`` with ``Hkv`` dividing
     ``H`` (grouped-query attention: query head ``h`` reads KV head
@@ -2376,6 +2574,18 @@ def flash_attention(q, k, v, *, causal: bool = True,
     selects none comes out 0).  The result is then ``(out, lse)`` with
     ``lse`` ``(B, H, T)`` the log-sum-exp of each head's selected scores
     (it carries no gradient).
+
+    ``mask``: a positional block mask in the causal mask's place (``causal``
+    is then not read), known at trace time, so no map is fetched and no pair
+    it leaves nothing of is visited, forward or backward (:func:`_plan`).
+    ``("block_diffusion", L)``: the ``T`` rows are a clean copy of a sequence
+    and then a noised copy (``T`` even), in blocks of ``L`` tokens; a clean
+    query reads the clean keys of its own and earlier blocks, a noised query
+    the clean keys of EARLIER blocks and the noised keys of its own block
+    (:class:`BlockDiffusion`).  Every block size has to divide ``T / 2`` in
+    multiples of ``L``; no padding (``seq_len``), no ``select``, keys and
+    values of one width.  The rows' positions are the caller's: the op
+    rotates nothing.
     """
     B, T, H, D = q.shape
     Hkv, Dv = k.shape[2], v.shape[3]
@@ -2385,6 +2595,11 @@ def flash_attention(q, k, v, *, causal: bool = True,
             f"flash_attention: k {k.shape} must have q's head width {D} and "
             f"v {v.shape} k's batch, length and heads, in heads that divide "
             f"q's {H}")
+    if mask is not None and (select is not None or Dv != D):
+        raise ValueError(
+            "flash_attention: a positional mask runs without a selection "
+            f"map, on keys and values of one width; got {D} and {Dv}, "
+            f"select={'a map' if select is not None else None}")
     if Dv != D:
         if select is not None:
             raise ValueError(
@@ -2405,13 +2620,26 @@ def flash_attention(q, k, v, *, causal: bool = True,
         Hkv = H
     if scale is None:
         scale = 1.0 / (D ** 0.5)
+    if mask is not None and block_q is None and block_k is None:
+        block_q = block_k = _mask_auto_block(T, mask)
     block_q, block_k, bwd_block_q, bwd_block_k, seq_len = _resolve_blocks(
         T, "flash_attention", block_q, block_k, bwd_block_q, bwd_block_k,
         seq_len, "T divisible by the blocks is required — use "
         "flash_attention_auto (pads and masks) or full_attention for "
         "ragged lengths")
+    causal = bool(causal)
+    if mask is not None:
+        causal = BlockDiffusion(_block_diffusion(mask), T // 2)
+        blocks = (block_q, block_k, bwd_block_q, bwd_block_k)
+        if T % 2 or seq_len is not None or any(
+                causal.half % b or b % causal.block for b in blocks):
+            raise ValueError(
+                f"flash_attention: under the mask {mask!r} the {T} rows are "
+                "two streams of one length, unpadded, and every block "
+                f"divides a stream in whole blocks of the mask; got blocks "
+                f"{blocks}, seq_len={seq_len}")
 
-    static = (float(scale), bool(causal), block_q, block_k, bwd_block_q,
+    static = (float(scale), causal, block_q, block_k, bwd_block_q,
               bwd_block_k, bool(interpret), seq_len)
     # Lane-aligned head dims run the kernels directly on (B, T, H*D)
     # views via head-offset BlockSpecs — the reshape is free

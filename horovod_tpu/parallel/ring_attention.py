@@ -241,12 +241,32 @@ def _ring_attention_zigzag(q, k, v, *, axis_name, scale):
     return _ring_scan(q, k, v, axis_name, hop)
 
 
+def block_diffusion_allowed(rows: int, block: int):
+    """The block-diffusion mask as a plain (rows, rows) boolean matrix,
+    ``[query, key]``: the rows are a clean copy of a sequence of ``rows /
+    2`` tokens and then a noised copy, in blocks of ``block`` tokens.  A
+    clean query reads the clean keys of its own and earlier blocks; a
+    noised query the clean keys of earlier blocks and the noised keys of
+    its own block."""
+    half = rows // 2
+    noised = jnp.arange(rows) >= half
+    blk = (jnp.arange(rows) % half) // block
+    q_noised, k_noised = noised[:, None], noised[None, :]
+    q_blk, k_blk = blk[:, None], blk[None, :]
+    return jnp.where(
+        q_noised,
+        jnp.where(k_noised, k_blk == q_blk, k_blk < q_blk),
+        ~k_noised & (k_blk <= q_blk))
+
+
 def full_attention(q, k, v, *, causal: bool = True,
                    scale: Optional[float] = None,
-                   q_offset: int = 0, k_offset: int = 0):
+                   q_offset: int = 0, k_offset: int = 0, mask=None):
     """Single-device reference attention (same math, no ring) — used by the
     tests as the oracle and by the transformer when sequence parallelism is
-    off."""
+    off.  ``mask=("block_diffusion", L)``: the flash family's positional
+    block mask in the causal mask's place, as a plain boolean matrix
+    (:func:`block_diffusion_allowed`)."""
     B, Tq, H, D = q.shape
     Tk = k.shape[1]
     if scale is None:
@@ -255,7 +275,10 @@ def full_attention(q, k, v, *, causal: bool = True,
     pos_k = k_offset + jnp.arange(Tk)
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k,
                         preferred_element_type=jnp.float32) * scale
-    if causal:
+    if mask is not None:
+        logits = jnp.where(block_diffusion_allowed(Tq, mask[1])[None, None],
+                           logits, _NEG_BIG)
+    elif causal:
         allowed = pos_k[None, :] <= pos_q[:, None]
         logits = jnp.where(allowed[None, None, :, :], logits, _NEG_BIG)
     p = jax.nn.softmax(logits, axis=-1)

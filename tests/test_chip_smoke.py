@@ -268,6 +268,24 @@ def test_grouped_backward_phase(smoke):
                                          bwd_blocks, live)
 
 
+def test_block_mask_phase(smoke):
+    """The flash kernels under the block-diffusion mask, alone (interpreted
+    here: no time is reported): the plan, the tiles visited against the
+    tiles live, and the kernels against the dense oracle; on the chip the
+    phase runs at ``sdar_1chip``'s attention shape."""
+    out = smoke.block_mask_phase(batch=1, seq=64, heads=8, kv_heads=1,
+                                 head_dim=128, block=4, check_seq=32, seed=0)
+    assert out["interpret"] and out["shape"] == [1, 128, 8, 1, 128]
+    assert set(out["ms_a_layer"]) == {
+        "block_mask.forward", "block_mask.backward", "causal.forward",
+        "causal.backward"} and not any(out["ms_a_layer"].values())
+    assert out["bd_plan"]["bwd"] == "group_fused"
+    assert out["tiles"] == {"live_pairs": 64 * 68, "live_tiles": 8 * 3,
+                            "visited_tiles": 8 * 3}
+    assert max(out["against_dense"].values()) <= 2e-2
+    assert tuple(smoke.BLOCK_MASK.values()) == (1, 8192, 32, 4, 128, 4, 1024)
+
+
 def test_latent_forward_phase(smoke):
     """The forward forms of a call with values narrower than keys, alone
     (interpreted here: no time, every form's ``o`` and ``lse`` against the

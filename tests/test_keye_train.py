@@ -58,25 +58,30 @@ def test_keye_stack_s_tree_and_the_published_count():
 # ------------------------------------------------------- the held share
 
 
-def test_the_eight_shares_add_up_to_the_uncut_layer():
+@pytest.mark.parametrize("lead", [(96,), (1, 2 * 96)],
+                         ids=["a_row_a_token", "two_rows_a_token"])
+def test_the_eight_shares_add_up_to_the_uncut_layer(lead):
     """Keye's expert layer (softmax router over 16, top-3 renormalised,
     SwiGLU, no shared expert) cut as the configuration cuts it: eight
     chips hold two experts each, every one routes over all 16, and their
     partial outputs add up to the uncut layer's, which is the loop over
     all experts.  No assignment is lost: the counts that landed add up
-    to k N."""
-    N, D_, E, K = 96, 16, 16, 3
+    to k N.  SDAR's layer is the same one at the ``2 T`` rows a sequence
+    of its two-stream pass (``sdar_1chip``, PR 52): one sequence's clean and
+    noised rows, twice the assignments a token."""
+    D_, E, K = 16, 16, 3
+    N = int(np.prod(lead))
     fields = dict(num_experts=E, hidden=24, top_k=K, dtype=jnp.float32,
                   router="softmax", renormalize=True, activation="swiglu")
     whole = DroplessMoE(**fields)
-    x = jax.random.normal(jax.random.PRNGKey(0), (N, D_))
+    x = jax.random.normal(jax.random.PRNGKey(0), (*lead, D_))
     params = whole.init(jax.random.PRNGKey(1), x)["params"]
     with jax.default_matmul_precision("highest"):
         want, _, _ = whole.apply({"params": params}, x)
         s = jax.nn.softmax(x @ params["router"]["kernel"], axis=-1)
-        gates = jnp.where(s >= jnp.sort(s, axis=-1)[:, -K, None], s, 0.0)
+        gates = jnp.where(s >= jnp.sort(s, axis=-1)[..., -K, None], s, 0.0)
         gates = gates / gates.sum(-1, keepdims=True)
-        oracle = sum(gates[:, e:e + 1] * (
+        oracle = sum(gates[..., e:e + 1] * (
             (jax.nn.silu(x @ params["w_gate"][e]) * (x @ params["w_up"][e]))
             @ params["w_down"][e]) for e in range(E))
         np.testing.assert_allclose(want, oracle, rtol=1e-5, atol=1e-5)

@@ -577,6 +577,40 @@ def test_grouped_kv_flash_fwd_bwd_at_nemotron_widths(v5e, monkeypatch, b, t,
     jax.clear_caches()      # the traces do not key on the budget
 
 
+def test_block_mask_flash_fwd_bwd_at_the_sdar_cell_s_shape(v5e, monkeypatch):
+    """``sdar_1chip``'s call: a clean and a noised copy of 8,192 tokens,
+    16,384 rows, 32 query heads over 4 KV heads of 128 under the
+    block-diffusion mask in blocks of 4.  The grid forward and the one
+    backward kernel a KV group at 512 x 512 — eight heads a step, dK and dV
+    of 16,384 rows resident (16 MiB: the rule's limit) — compile for the
+    v5e under the budget the compiler counts for the causal call plus the
+    masked body's tile; no map is an operand."""
+    from horovod_tpu.ops import flash_attention as fa
+
+    counted = GROUP_BWD_COUNTED_MB + 8
+    assert fa._SELECT_FUSED_VMEM_MB >= counted + 8
+    monkeypatch.setattr(fa, "_SELECT_FUSED_VMEM_MB", counted)
+    jax.clear_caches()
+    one = SingleDeviceSharding(v5e[0])
+    q = jax.ShapeDtypeStruct((1, 16_384, 32, 128), jnp.bfloat16, sharding=one)
+    kv = jax.ShapeDtypeStruct((1, 16_384, 4, 128), jnp.bfloat16, sharding=one)
+
+    def loss(q, k, v):
+        return fa.flash_attention(q, k, v, mask=("block_diffusion", 4)
+                                  ).astype(jnp.float32).sum()
+
+    lowered = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
+        q, kv, kv)
+    assert custom_calls(lowered.as_text()) == [
+        ("_fwd_kernel", 3), ("flash_group_bwd", 6)]
+    assert scoped_vmem_mb(lowered.as_text()) == {
+        "_fwd_kernel": 0, "flash_group_bwd": counted}
+    _, (dq, dk, dv) = lowered.compile().out_info
+    assert dq.shape == (1, 16_384, 32, 128)
+    assert dk.shape == dv.shape == (1, 16_384, 4, 128)
+    jax.clear_caches()      # the traces do not key on the budget
+
+
 def test_latent_attention_s_kernels_fwd_bwd_at_the_joyai_cell_s_shape(
         v5e, monkeypatch):
     """``joyaiflash_1chip``'s call (PR 50): 32 heads, keys of 192 (128 | 64)
