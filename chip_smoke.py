@@ -20,9 +20,13 @@ through the entry points a user calls (``hvd.init()`` →
   the 64 heads in chunks of 256: the scan's heads in tiles, the gated norm
   over all 4,096 channels (``scan_one_group``, ``passes_one_group``);
 * checks the experts' grouped matmuls as kernels, over a window of
-  sorted rows at Nemotron-H's widths and levelled as the layer levels it,
-  against ``lax.ragged_dot`` and its transposes — forward, input and
-  weight gradient — and prints their plan (``moe_plan``);
+  sorted rows at Nemotron-H's widths of which a third landed, as the layer
+  runs one, against ``lax.ragged_dot`` and its transposes — forward, input
+  and weight gradient — and prints their plan (``moe_plan``);
+* prints the rows of a held expert layer's window in the six cells that
+  hold a share (``held_rows``) and times one such layer alone, forward and
+  backward, at four loads under the plan's window (``held_windows``;
+  ``--held-windows`` runs that table alone, under every candidate window);
 * checks learned sparse attention at Keye-VL-2.0's head widths — the
   indexer's scores, the exact top-k and its int8 map, the flash kernels
   under the map and the KL pass — against its dense float32 form: the
@@ -112,18 +116,33 @@ SCAN_ONE_GROUP = dict(batch=1, seq=2048, heads=64, head_dim=64, groups=1,
                       state=128, chunk=256)
 PASSES_ONE_GROUP = dict(batch=1, seq=2048, heads=64, head_dim=64, groups=1,
                         state=128, conv_kernel=4)
-# One held layer's window of twotower_1chip: 18,432 sorted rows of width
+# One held layer's window of twotower_1chip: 7,680 sorted rows of width
 # 2688 against 8 experts 1856 wide (padded as the plan says).
-EXPERTS_REFERENCE = dict(rows=18432, groups=8, dim=2688, hidden=1856)
-# A layer's tokens and routing in the three cells that hold a share of their
-# experts: which way such a layer's rows move follows from these sizes alone.
+EXPERTS_REFERENCE = dict(rows=7680, groups=8, dim=2688, hidden=1856)
+# A layer's tokens, widths and routing in the six cells that hold a share of
+# their experts: the rows of such a layer's window and which way they move
+# follow from these sizes alone.
 HELD_LAYERS = {
     "zaya1_1chip": dict(tokens=16384, dim=2048, hidden=2048, num_experts=16,
                         held=8, top_k=1, skip_choice=True),
     "twotower_1chip": dict(tokens=16384, dim=2688, hidden=1856,
-                           num_experts=128, held=8, top_k=6),
+                           num_experts=128, held=8, top_k=6,
+                           activation="relu2"),
     "keye_1chip": dict(tokens=16384, dim=2048, hidden=768, num_experts=128,
-                       held=16, top_k=8)}
+                       held=16, top_k=8),
+    "sdar_1chip": dict(tokens=16384, dim=2048, hidden=768, num_experts=128,
+                       held=16, top_k=8),
+    "joyaiflash_1chip": dict(tokens=16384, dim=2048, hidden=768,
+                             num_experts=256, held=16, top_k=8),
+    "nemo3super_1chip": dict(tokens=8192, dim=4096, hidden=2688,
+                             num_experts=512, held=8, top_k=22,
+                             activation="relu2", latent=1024)}
+# What a held layer is timed at: the assignments that land on the held
+# experts, in units of what uniform routing sends them, and the candidate
+# rows of a window in the same units (``moe._window_plan`` was fitted to
+# this table: PERF.md section 6, PR 53).
+HELD_LOADS = (0.5, 1.0, 1.7, 3.2)
+HELD_WINDOWS = (0.5, 1.0, 1.5, 3.0)
 # A sequence of 512: the float32 recurrence's backward keeps three (192, 96)
 # states a head a token, 4.3 GB there and 17.2 of the chip's 15.75 at 2048.
 DELTA_REFERENCE = dict(batch=1, seq=512, heads=30, key_dim=96,
@@ -659,38 +678,133 @@ def moe_plan(rows: int, groups: int, dim: int, hidden: int) -> dict:
         interpret=jax.default_backend() != "tpu")._asdict()
 
 
-def held_rows(*, tokens: int, dim: int, hidden: int, num_experts: int,
-              held: int, top_k: int, skip_choice: bool = False) -> dict:
-    """What a ``DroplessMoE(held=...)`` layer of these sizes notes of itself
-    while traced (shapes alone, nothing runs): its assignments, and how many
-    of them move to expert order and back as gathers through the sort's
-    permutation — all of them where the layer's window is every assignment,
-    none where a smaller window gathers its rows and scatter-adds them
-    home."""
+def held_layer(*, tokens: int, dim: int, hidden: int, num_experts: int,
+               held: int, top_k: int, **settings):
+    """A ``DroplessMoE`` that holds the first ``held`` of its experts, and
+    the shape of its input."""
     import jax
     import jax.numpy as jnp
 
-    from horovod_tpu.layer_notes import noting_layers
     from horovod_tpu.parallel.moe import DroplessMoE
 
-    layer = DroplessMoE(num_experts=num_experts, hidden=hidden, top_k=top_k,
-                        held=(0, held), skip_choice=skip_choice)
+    return (DroplessMoE(num_experts=num_experts, hidden=hidden, top_k=top_k,
+                        held=(0, held), **settings),
+            jax.ShapeDtypeStruct((tokens, dim), jnp.bfloat16))
+
+
+def held_rows(**sizes) -> dict:
+    """What a ``DroplessMoE(held=...)`` layer of these sizes notes of itself
+    while traced (shapes alone, nothing runs): its assignments, what uniform
+    routing sends to the held experts, the rows ``W`` of a window
+    (``moe._window_plan``: a step runs ``ceil(landed / W)`` of them), and
+    how many assignments move to expert order and back as gathers through
+    the sort's permutation — all of them where the layer's window is every
+    assignment, none where a smaller window gathers its rows and
+    scatter-adds them home."""
+    import jax
+
+    from horovod_tpu.layer_notes import noting_layers
+
+    layer, x = held_layer(**sizes)
     noted = {}
-    noting_layers(jax.eval_shape, noted)(
-        layer.init, jax.random.PRNGKey(0),
-        jax.ShapeDtypeStruct((tokens, dim), jnp.bfloat16))
+    noting_layers(jax.eval_shape, noted)(layer.init, jax.random.PRNGKey(0), x)
     counters, = noted.values()
     return {name: counters[f"moe.{name}"] for name in (
-        "assignments", "held_assignments", "permuted_assignments")}
+        "assignments", "held_assignments", "window_rows",
+        "permuted_assignments")}
+
+
+def held_layer_ms(sizes: dict, loads, *, seed: int, calls: int = 10) -> dict:
+    """``{load: ms a layer}`` of one held layer's forward and backward pass
+    alone, as the package plans it: ``calls`` of them chained in one
+    program (each call's input is the one before's plus a millionth of its
+    gradient; the weight gradients are summed into the result), timed by
+    the host's clock around that program (on the chip only).  At load ``l`` a share ``p`` of
+    the tokens send every choice they can to the held experts and the rest
+    none, ``p`` such that ``l`` times the uniform load lands: a feature of
+    the input that the router's matrix reads alone decides which."""
+    import jax
+    import jax.numpy as jnp
+
+    layer, like = held_layer(**sizes)
+    n, d = like.shape
+    held, k = sizes["held"], sizes["top_k"]
+    routed = sizes["num_experts"] + bool(sizes.get("skip_choice"))
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    x = jax.random.normal(ks[0], like.shape, like.dtype)
+    params = layer.init(ks[1], x)["params"]
+    kernel = params["router"]["kernel"].at[0].set(
+        jnp.where(jnp.arange(routed) < held, 20.0, 0.0))
+    params = {**params, "router": {"kernel": kernel}}
+
+    def program(params, x):
+        def loss(p, x):
+            out = layer.apply({"params": p}, x)[0].astype(jnp.float32)
+            return (out * out).sum()
+
+        def call(carry, _):
+            x, total = carry
+            d_params, d_x = jax.grad(loss, (0, 1))(params, x)
+            total += sum(a.astype(jnp.float32).sum()
+                         for a in jax.tree.leaves(d_params))
+            moved = (x + 1e-6 * d_x).astype(x.dtype)
+            return (moved.at[:, 0].set(x[:, 0]), total), None
+
+        return jax.lax.scan(call, (x, jnp.float32(0)), None, length=calls)[0]
+
+    ms = {}
+    program = jax.jit(program)
+    for load in loads:
+        share = min(1.0, load * held / routed * k / min(k, held))
+        sends = jax.random.uniform(ks[2], (n,)) < share
+        at = x.at[:, 0].set(jnp.where(sends, 4.0, -4.0).astype(x.dtype))
+        jax.block_until_ready(program(params, at))
+        if jax.default_backend() != "tpu":      # interpreted: no time
+            ms[str(load)] = None
+            continue
+        t0 = time.perf_counter()
+        jax.block_until_ready(program(params, at))
+        ms[str(load)] = round((time.perf_counter() - t0) / calls * 1e3, 3)
+    return ms
+
+
+def held_windows_phase(sizes: dict, *, seed: int, loads=HELD_LOADS,
+                       windows=HELD_WINDOWS, calls: int = 10) -> dict:
+    """One held layer alone at ``loads`` under each candidate window of
+    ``windows`` times the uniform load (``ms_a_layer``: ``{rows a window:
+    {load: ms}}``, forward and backward), beside what ``moe._window_plan``
+    gives it (``planned``).  A layer whose one window is every assignment
+    has no other candidate."""
+    from horovod_tpu.parallel import moe
+
+    planned = held_rows(**sizes)
+    candidates = {planned["window_rows"]}
+    if not planned["permuted_assignments"]:
+        candidates |= {
+            -(-int(c * planned["held_assignments"]) // 256) * 256
+            for c in windows}
+    plan, ms = moe._window_plan, {}
+    for rows in sorted(candidates):
+        def forced(*, assignments, **shapes):
+            return moe.WindowPlan(rows, -(-assignments // rows),
+                                  planned["held_assignments"])
+        moe._window_plan = forced
+        try:
+            ms[str(rows)] = held_layer_ms(sizes, loads, seed=seed,
+                                          calls=calls)
+        finally:
+            moe._window_plan = plan
+    return {"planned": planned, "ms_a_layer": ms}
 
 
 def experts_reference_phase(*, rows: int, groups: int, dim: int, hidden: int,
                             seed: int) -> dict:
     """The grouped matmul as the expert layer calls it (``grouped_matmul``
     under its custom VJP, the hidden width padded as the plan says, group
-    sizes uneven with an empty group and levelled: the last group takes
-    the window's empty rows) against ``lax.ragged_dot`` on the same
-    operands: the product and both gradients."""
+    sizes uneven with an empty group, two thirds of the window's rows past
+    the last group: the kernels skip their strips) against
+    ``lax.ragged_dot`` on the same operands: the product and both
+    gradients."""
     import jax
     import jax.numpy as jnp
     from jax import lax
@@ -712,7 +826,6 @@ def experts_reference_phase(*, rows: int, groups: int, dim: int, hidden: int,
     share = jax.random.dirichlet(ks[3], jnp.full((groups - 1,), 4.0))
     sizes = jnp.concatenate([jnp.zeros((1,), jnp.int32), jnp.floor(
         share * (rows // 3)).astype(jnp.int32)])
-    sizes = sizes.at[-1].add(rows - sizes.sum())
 
     def value_and_grads(product):
         def weighted(x, w):
@@ -1852,6 +1965,10 @@ def main(argv=None) -> int:
     ap.add_argument("--out", default=os.path.join(ROOT, "chiprun_out",
                                                   "chip_smoke"),
                     help="directory for the runtime's logs")
+    ap.add_argument("--held-windows", action="store_true",
+                    help="only the held expert layers' table: one layer "
+                         "alone at four loads under every candidate window "
+                         "(the default run times the plan's window alone)")
     ap.add_argument("--launcher-worker", action="store_true",
                     help=argparse.SUPPRESS)
     args = ap.parse_args(argv)
@@ -1900,7 +2017,14 @@ def main(argv=None) -> int:
     emit("init", device=device, versions=versions(), controller="native",
          size=hvd.size(), compile_cache_dir=cache_dir)
 
-    if args.chips == 1:
+    # One layer of each cell that holds a share; sdar_1chip's is keye_1chip's.
+    held_layers = {cell: layer for cell, layer in HELD_LAYERS.items()
+                   if cell != "sdar_1chip"}
+    if args.held_windows:
+        for cell, layer in held_layers.items():
+            emit("held_windows", cell=cell, **held_windows_phase(
+                layer, seed=args.seed))
+    elif args.chips == 1:
         emit("flash_reference", **flash_reference_phase(
             **FLASH_REFERENCE, seed=args.seed))
         emit("scan_reference", **scan_reference_phase(
@@ -1919,6 +2043,9 @@ def main(argv=None) -> int:
             **EXPERTS_REFERENCE, seed=args.seed))
         emit("held_rows", **{cell: held_rows(**layer)
                              for cell, layer in HELD_LAYERS.items()})
+        for cell, layer in held_layers.items():
+            emit("held_windows", cell=cell, **held_windows_phase(
+                layer, seed=args.seed, windows=()))
         emit("select_reference", **select_reference_phase(
             **SELECT_REFERENCE, seed=args.seed))
         emit("select_backward", **select_backward_phase(

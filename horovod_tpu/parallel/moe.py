@@ -41,7 +41,9 @@ folded into one (ROADMAP D11): :class:`MoELayer` where each chip of an
 
 from __future__ import annotations
 
-from typing import Any, Optional, Tuple
+import functools
+import math
+from typing import Any, NamedTuple, Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -51,6 +53,7 @@ from jax import lax
 from horovod_tpu.layer_notes import note_layer, noting_layers
 from horovod_tpu.ops import _pallas
 from horovod_tpu.ops.grouped_matmul import grouped_matmul, grouped_plan
+from horovod_tpu.parallel._vma import ensure_varying_tree
 from horovod_tpu.parallel._vma import per_shard_init as _expert_init
 
 EP_AXIS = "ep"
@@ -173,15 +176,64 @@ class MoELayer(nn.Module):
 # ------------------------------------------------------------ dropless
 
 
-# Rows in a window of a layer that holds a share of its experts, as a
-# multiple of what uniform routing sends to that share: what the layer
-# provisions for, as an expert-parallel layer sizes its receive buffer.
-# Random weights put up to 1.7 times the uniform load on a share, and
-# training without a balancing term moved one between 0 and 3.6 times
-# within 40 steps (PERF.md section 6, PR 30): at 2 a fifth of those steps
-# overflowed, at 3 one in forty.  Memory is the other side: the window's
-# rows live in HBM while a layer runs.
-_HELD_WINDOW = 3
+class WindowPlan(NamedTuple):
+    """What :func:`_window_plan` decides for a layer that holds a share of
+    its experts."""
+    rows: int       # W: sorted rows a window
+    windows: int    # the most a step can run, ceil(n k / W); 1: permuted
+    uniform: int    # U: what uniform routing sends to the held experts
+
+
+# A held share that takes this part or more of a layer's assignments under
+# uniform routing sorts them ALL as one window: its rows then move as
+# gathers through the sort's permutation, which cost less than a window's
+# scatter-add of a third of them (PERF.md section 6, PR 45).
+_PERMUTED_SHARE = 3
+# Rows of a window the kernels cut evenly (``grouped_matmul._ROWS``), and
+# the sublanes of a row tile below that.
+_WINDOW_TILE = 512
+# What a window of a held share costs on a v5e, fitted to ``chip_smoke.py``'s
+# ``held_windows`` table at five cells' layers (PERF.md section 6, PR 53).
+# A part that does not follow its rows, by the byte of the experts' float32
+# weight gradient (its accumulate over the windows, each kernel's pass over
+# every expert's matrix): 1.4 to 4.5 ms.  And a part by the row: its moves
+# by the byte (gather, masks, casts, the float32 scatter-adds both ways) and
+# its products by the FLOP, forward, again in the backward pass and both
+# gradients: 0.38 to 0.77 us.
+_WINDOW_S_PER_EXPERT_BYTE = 11e-12
+_ROW_S_PER_BYTE = 75e-12
+_ROW_S_PER_FLOP = 1 / 197e12
+
+
+def _window_plan(*, assignments: int, held: int, routed: int,
+                 row_bytes: int, expert_bytes: int) -> WindowPlan:
+    """The rows ``W`` of a window of a layer that holds ``held`` of the
+    ``routed`` outputs its ``assignments`` (n k) are routed over — the one
+    place that chooses, a pure function of the layer's shapes
+    (``row_bytes`` a gathered row, ``expert_bytes`` the held experts'
+    weight gradient in float32).
+
+    A step runs ``ceil(landed / W)`` windows for the ``landed`` assignments
+    its routing sent here (the device reads the count), so a window is the
+    unit its work is rounded up to: half a window's rows are gathered,
+    masked and scatter-added for nothing a layer on average, and every
+    window that runs pays once for what does not follow its rows.  The two
+    balance at ``W = sqrt(2 U fixed / row)`` for a load near the uniform
+    ``U`` — and ``W`` is never under ``U``: a router that balances sends
+    ``U``, and a window a little smaller would run two for it."""
+    uniform = assignments * held // routed
+    if _PERMUTED_SHARE * uniform >= assignments:
+        return WindowPlan(assignments, 1, uniform)
+    fixed = _WINDOW_S_PER_EXPERT_BYTE * expert_bytes
+    # A row meets one expert's matrices four times, 2 FLOPs a parameter
+    # (4 bytes of ``expert_bytes``) each.
+    row = (_ROW_S_PER_BYTE * row_bytes
+           + _ROW_S_PER_FLOP * 2 * expert_bytes / held)
+    tile = _WINDOW_TILE if uniform >= _WINDOW_TILE else 8
+    rows = max(uniform, math.sqrt(2 * uniform * fixed / row))
+    rows = min(assignments, -(-int(rows) // tile) * tile)
+    return WindowPlan(rows, -(-assignments // rows), uniform)
+
 
 def _pad_hidden(a, axis: int, lanes: int):
     """``a`` with zeros behind its ``axis`` up to a multiple of ``lanes``:
@@ -205,9 +257,9 @@ noting_expert_layers = noting_layers
 # The row moves of a layer whose sorted rows are ALL k·N assignments —
 # every expert here, or a held share whose window is every assignment —:
 # a gather each way, forward and backward, through the sort's ``order`` and
-# its ``inverse``.  A held share with a smaller window moves that window's
-# rows by gather and scatter-add (``_held_experts``): a gather through
-# ``inverse`` would move k·N rows to bring R of them home.
+# its ``inverse``.  A held share with a smaller window moves each window's
+# rows by gather and scatter-add (``_held_windows``): a gather through
+# ``inverse`` would move k·N rows to bring W of them home.
 @jax.custom_vjp
 def _to_expert_order(x, order, inverse):
     """Rows of ``x`` (N, d) as the k·N assignments sorted by expert:
@@ -246,6 +298,146 @@ def _to_token_order_bwd(order, g):
 
 
 _to_token_order.defvjp(_to_token_order_fwd, _to_token_order_bwd)
+
+
+# ------------------------------------------------- a held share's windows
+
+
+class _Held(NamedTuple):
+    """What is static of a held share's windows."""
+    top_k: int
+    rows: int           # W
+    activation: str
+    dtype: Any
+    plan: Any           # the grouped matmuls' plan over W rows
+    interpret: bool
+
+
+def _hidden(rows, w, group_sizes, activation, plan, interpret):
+    """The experts' hidden activations on sorted ``rows``."""
+    up = grouped_matmul(rows, w["w_up"], group_sizes, plan,
+                        interpret=interpret)
+    if activation == "swiglu":
+        return nn.silu(grouped_matmul(rows, w["w_gate"], group_sizes, plan,
+                                      interpret=interpret)) * up
+    return jnp.square(nn.relu(up))
+
+
+def _held_rows(rows, w, here, sizes, held: _Held):
+    """The grouped matmuls over sorted ``rows``, ``sizes`` of them an
+    expert.  A row where ``here`` is false belongs to no expert here and
+    is masked to nothing on its way in and out."""
+    with jax.named_scope("experts"):
+        rows = jnp.where(here, rows.astype(held.dtype), 0)
+        h = jnp.where(here, _hidden(rows, w, sizes, held.activation,
+                                    held.plan, held.interpret), 0)
+        return jnp.where(here, grouped_matmul(
+            h, w["w_down"], sizes, held.plan, interpret=held.interpret), 0)
+
+
+def _padded(w, held: _Held):
+    """The held experts' matrices as the grouped matmuls read them: in the
+    activations' dtype, the hidden width padded as their plan says."""
+    with jax.named_scope("experts"):
+        return {name: _pad_hidden(a.astype(held.dtype),
+                                  1 if name == "w_down" else 2,
+                                  held.plan.lanes)
+                for name, a in w.items()}
+
+
+def _window(i, x, gate, order, ends, group_sizes, landed, held: _Held):
+    """Window ``i`` of the sorted assignments, ``[i W, (i + 1) W)``: the
+    assignments, their tokens, which of the rows landed here, each expert's
+    group cut to the window, and the rows and gates gathered."""
+    W = held.rows
+    lo = i * W
+    with jax.named_scope("dispatch"):
+        a = lax.dynamic_slice_in_dim(order, lo, W)
+        token = a // held.top_k
+        here = ((lo + jnp.arange(W)) < landed)[:, None]
+        sizes = jnp.clip(jnp.minimum(ends, lo + W)
+                         - jnp.maximum(ends - group_sizes, lo), 0, W)
+        return a, token, here, sizes, x[token], gate.reshape(-1)[a]
+
+
+def _weighted(rows, g, w, here, sizes, held: _Held):
+    """What a window's gathered ``rows`` add to their tokens: the experts'
+    results in float32, each times its gate ``g``."""
+    y = _held_rows(rows, w, here, sizes, held)
+    with jax.named_scope("combine"):
+        return y.astype(jnp.float32) * jnp.where(here, g[:, None], 0.0)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _held_windows(held: _Held, x, gate, w, order, ends, group_sizes,
+                  landed):
+    """The held experts' share of the output, (n, d) float32: the sorted
+    assignments a window of ``W`` rows at a time, ``ceil(landed / W)`` of
+    them — a trip count the device reads, so the work follows the load.
+    Each window gathers its rows by ``x[token]``, runs the grouped matmuls
+    with each expert's group cut to the window, and scatter-adds the
+    weighted results onto their tokens in float32.
+
+    A loop of a data-dependent length has no reverse-mode rule, so this
+    carries its own: the backward pass keeps ``(x, gate, w)`` and the
+    integer operands, no window's rows, and runs the same windows again,
+    each through ``jax.vjp``, with ``dx``, ``dgate`` and the experts'
+    ``dW`` summed over them in float32."""
+    padded = _padded(w, held)
+
+    def body(i, out):
+        _, token, here, sizes, rows, g = _window(
+            i, x, gate, order, ends, group_sizes, landed, held)
+        y = _weighted(rows, g, padded, here, sizes, held)
+        with jax.named_scope("combine"):
+            return out.at[token].add(y)
+
+    return lax.fori_loop(0, -(-landed // held.rows), body,
+                         jnp.zeros_like(x, dtype=jnp.float32))
+
+
+def _held_windows_fwd(held, x, gate, w, order, ends, group_sizes, landed):
+    return (_held_windows(held, x, gate, w, order, ends, group_sizes, landed),
+            (x, gate, w, order, ends, group_sizes, landed))
+
+
+def _held_windows_bwd(held, res, ct):
+    x, gate, w, order, ends, group_sizes, landed = res
+    padded = _padded(w, held)
+
+    def body(i, carry):
+        dx, dgate, dw = carry
+        a, token, here, sizes, rows, g = _window(
+            i, x, gate, order, ends, group_sizes, landed, held)
+        _, pull = jax.vjp(
+            lambda rows, g, w: _weighted(rows, g, w, here, sizes, held),
+            rows, g, padded)
+        with jax.named_scope("combine"):
+            d_y = ct[token]
+        d_rows, d_g, d_w = pull(d_y)
+        with jax.named_scope("dispatch"):
+            dx = dx.at[token].add(d_rows.astype(jnp.float32))
+            dgate = dgate.at[a].add(d_g)
+        with jax.named_scope("experts"):
+            dw = {name: dw[name] + d_w[name].astype(jnp.float32)
+                  for name in dw}
+        return dx, dgate, dw
+
+    def zeros(like):
+        # Zeros that vary over the mesh axes the operand varies over.
+        return jnp.zeros_like(like, dtype=jnp.float32)
+
+    dx, dgate, dw = lax.fori_loop(
+        0, -(-landed // held.rows), body,
+        (zeros(x), zeros(gate.reshape(-1)), jax.tree.map(zeros, padded)))
+    with jax.named_scope("experts"):
+        dw = {name: lax.slice(a, (0, 0, 0), w[name].shape).astype(
+            w[name].dtype) for name, a in dw.items()}
+    return (dx.astype(x.dtype), dgate.reshape(gate.shape).astype(gate.dtype),
+            dw, None, None, None, None)
+
+
+_held_windows.defvjp(_held_windows_fwd, _held_windows_bwd)
 
 
 class _SharedExpert(nn.Module):
@@ -320,30 +512,33 @@ class DroplessMoE(nn.Module):
       chip's share of an expert-parallel layer, without its exchange.
       The k are chosen and the gates normalised over all experts;
       assignments to experts held elsewhere add nothing here, and the
-      parameters are the held experts' alone.  Still no capacity: the
-      held experts' assignments are sorted to the front and the grouped
-      matmuls run over the first ``R`` sorted rows, ``R`` three times what
-      uniform routing would send here (rows past the last assignment are
-      zeros: a step costs the same wherever its tokens go); a step whose
-      routing sends more (the device sees the count) runs the further
-      windows of ``R`` rows in turn (scope ``overflowed``; their empty
-      rows are skipped), so none is lost.  The expert block is recomputed in the backward
-      pass, so no window's rows are kept.  Sown beside the rest:
-      ``held_assignments``, the number that landed here.  Where ``3 ·
-      top_k · count ≥`` the outputs routed over (top-1 with 8 of 17 held)
-      ``R`` is EVERY assignment: one levelled window over all ``n · k``
-      rows, and no second one exists to overflow into.  How the rows move
-      follows from that shape alone.  A window smaller than ``n · k``
-      gathers its rows by ``x[token]`` and scatter-adds the weighted
-      results onto their tokens (autodiff scatter-adds the gather's
-      cotangent too): ``R`` rows each way, fewer than ``n · k``.  The
-      window that is every assignment sorts a whole permutation, so its
+      parameters are the held experts' alone.  Still no capacity, and
+      the work follows the load: the held experts' assignments are sorted
+      to the front and the grouped matmuls run over them a window of ``W``
+      sorted rows at a time, ``ceil(landed / W)`` windows for the
+      ``landed`` assignments this step's routing sent here — a trip count
+      the device reads, so a step costs what its routing asks and none is
+      lost at any load.  ``W`` is :func:`_window_plan`'s, a function of the
+      layer's shapes (what uniform routing sends here or somewhat more,
+      in whole row tiles of the kernels).  A window gathers its rows by ``x[token]``
+      and scatter-adds the weighted results onto their tokens in float32
+      (:func:`_held_windows`, a ``jax.custom_vjp``: the backward pass
+      keeps the layer's inputs, no window's rows, and runs the same
+      windows again with ``dx``, ``dgate`` and the experts' ``dW`` summed
+      over them in float32).  Sown beside the rest: ``held_assignments``,
+      the number that landed here, and ``held_windows``, the windows that
+      ran; noted: ``moe.held_assignments`` (what uniform routing sends)
+      and ``moe.window_rows`` (``W``).  Where the held experts take a third
+      or more of the outputs routed over (top-1 with 8 of 17 held) the one
+      window is EVERY assignment.  It sorts a whole permutation, so its
       rows go to expert order and come back as gathers through it
       (``_to_expert_order`` / ``_to_token_order``, as where every expert is
       here) and nothing is scatter-added: the same rows, masks and
-      products.  Noted beside the rest: ``moe.permuted_assignments``,
-      ``n · k`` where the rows moved through the permutation, 0 where a
-      window of them moved by scatter-add.
+      products, the grouped matmuls skipping the strips past ``landed``,
+      the expert block recomputed in the backward pass.  Noted beside the
+      rest: ``moe.permuted_assignments``, ``n · k`` where the rows moved
+      through the permutation, 0 where windows of them moved by
+      scatter-add.
     * ``router="mlp"``: the router is a small network that carries a state
       from one expert layer to the next, and the layer is called as
       ``layer(x, router_state)``.  ``r = W_d x + b_d`` (``router_hidden``
@@ -470,7 +665,7 @@ class DroplessMoE(nn.Module):
         if self.held is None and not self.skip_choice:
             out, tokens_per_expert, fused = self._all_experts(
                 rows, gate, expert, names)
-            n_held, permuted = E, True
+            n_held, window = E, None
         else:
             # With a skip choice and no share named, every expert is held:
             # the skip choice is then the one output held nowhere.
@@ -478,9 +673,11 @@ class DroplessMoE(nn.Module):
             if not (0 <= first and n_held >= 1 and first + n_held <= E):
                 raise ValueError(f"held={self.held} is not a range of the "
                                  f"{E} experts")
-            out, tokens_per_expert, held_assignments, fused, permuted = (
-                self._held_experts(rows, gate, expert, names, first, n_held))
+            (out, tokens_per_expert, held_assignments, held_windows, fused,
+             window) = self._held_experts(rows, gate, expert, names, first,
+                                          n_held)
             self.sow("intermediates", "held_assignments", held_assignments)
+            self.sow("intermediates", "held_windows", held_windows)
         if self.skip_choice:
             self.sow("intermediates", "skipped_assignments",
                      tokens_per_expert[E])
@@ -517,17 +714,20 @@ class DroplessMoE(nn.Module):
             "moe.fused_matmuls": fused * len(names),
             # Assignments whose rows moved as gathers through the sort's
             # permutation; 0 where a window of them moved by scatter-add.
-            "moe.permuted_assignments": n * k * permuted,
+            "moe.permuted_assignments": n * k * (
+                window is None or window.windows == 1),
             "moe.expert_bytes": (len(names) * n_held * width * self.hidden
                                  * jnp.dtype(self.param_dtype).itemsize),
             "moe.row_bytes": width * jnp.dtype(self.dtype).itemsize}
         if self.latent:
             counters["moe.latent"] = self.latent
         if self.held is not None:
-            # What uniform routing sends to the held experts; the number
-            # a step's routing did send is on the device (sown as
-            # ``held_assignments``).
-            counters["moe.held_assignments"] = n * k * n_held // routed
+            # What uniform routing sends to the held experts, and the rows
+            # of a window; the number a step's routing did send and the
+            # windows it ran are on the device (sown as ``held_assignments``
+            # and ``held_windows``).
+            counters["moe.held_assignments"] = window.uniform
+            counters["moe.window_rows"] = window.rows
         if self.router == "mlp":
             counters["moe.router_hidden"] = self.router_hidden
         if self.skip_choice:
@@ -585,20 +785,6 @@ class DroplessMoE(nn.Module):
                 else (n_experts, d, self.hidden), self.param_dtype)
             for name in names}
 
-    def _grouped(self, rows, w, group_sizes, plan):
-        """``rows`` of every expert times its matrix of ``w``, in
-        ``dtype``."""
-        return grouped_matmul(rows, w, group_sizes, plan,
-                              interpret=_pallas.interpret())
-
-    def _hidden(self, rows, w, group_sizes, plan):
-        """The experts' hidden activations on sorted ``rows``."""
-        up = self._grouped(rows, w["w_up"], group_sizes, plan)
-        if self.activation == "swiglu":
-            return nn.silu(self._grouped(rows, w["w_gate"], group_sizes,
-                                         plan)) * up
-        return jnp.square(nn.relu(up))
-
     def _all_experts(self, x, gate, expert, names):
         """Every expert is here: all k·N assignments, in expert order."""
         E, k = self.num_experts, self.top_k
@@ -616,9 +802,10 @@ class DroplessMoE(nn.Module):
                                 interpret=_pallas.interpret())
             w = {name: a.astype(self.dtype)
                  for name, a in self._weights(names, E, d).items()}
-            h = self._hidden(rows, w, tokens_per_expert, plan)
-            y = self._grouped(h, w["w_down"], tokens_per_expert,
-                              plan)                               # (k·N, d)
+            h = _hidden(rows, w, tokens_per_expert, self.activation, plan,
+                        _pallas.interpret())
+            y = grouped_matmul(h, w["w_down"], tokens_per_expert, plan,
+                               interpret=_pallas.interpret())     # (k·N, d)
 
         with jax.named_scope("combine"):
             y = _to_token_order(y, order, inverse).reshape(n, k, d)
@@ -642,106 +829,51 @@ class DroplessMoE(nn.Module):
             ends = jnp.cumsum(group_sizes)
             landed = ends[-1]
         w = self._weights(names, n_held, d)
-        R = min(n * k, -(-_HELD_WINDOW * n * k * n_held // E // 8) * 8)
-        windows = -(-n * k // R)
-        # One window is every assignment (``R == n k``): ``order`` is then a
-        # whole permutation, and rows move both ways as gathers through it.
-        permuted = windows == 1
-        if permuted:
-            with jax.named_scope("dispatch"):
-                inverse = jnp.argsort(order)
-        else:
-            order = jnp.pad(order, (0, windows * R - n * k))
+        window = _window_plan(
+            assignments=n * k, held=n_held, routed=E,
+            row_bytes=d * jnp.dtype(self.dtype).itemsize,
+            expert_bytes=4 * sum(a.size for a in w.values()))
+        W = window.rows
         # The grouped matmuls' plan says what the hidden width is padded to.
-        plan = grouped_plan(
-            jax.ShapeDtypeStruct((R, d), self.dtype, vma=jax.typeof(x).vma),
+        held = _Held(k, W, self.activation, self.dtype, grouped_plan(
+            jax.ShapeDtypeStruct((W, d), self.dtype, vma=jax.typeof(x).vma),
             n_held, self.hidden + -self.hidden % 128,
-            interpret=_pallas.interpret())
+            interpret=_pallas.interpret()), _pallas.interpret())
+        fused = held.plan.form == "kernels"
+        held_windows = -(-landed // W)
+        # One window is every assignment (``W == n k``): ``order`` is then a
+        # whole permutation, and rows move both ways as gathers through it.
+        if window.windows > 1:
+            # What the experts' matrices' cotangent varies over, the tokens
+            # do: the sum over the mesh is their ``pcast``'s transpose.
+            w = ensure_varying_tree(w, tuple(jax.typeof(x).vma))
+            order = jnp.pad(order, (0, window.windows * W - n * k))
+            return (_held_windows(held, x, gate, w, order, ends, group_sizes,
+                                  landed),
+                    tokens_per_expert, landed, held_windows, fused, window)
 
-        def nothing(x):
-            # Zeros that vary over the mesh axes the tokens vary over.
-            return jnp.zeros_like(x, dtype=jnp.float32)
+        with jax.named_scope("dispatch"):
+            inverse = jnp.argsort(order)
 
-        def experts(rows, here, sizes, w):
-            """The grouped matmuls over a window's ``rows``.  A row past
-            ``landed`` belongs to no expert here and is masked to nothing
-            on its way in and out."""
-            with jax.named_scope("experts"):
-                padded = {
-                    name: _pad_hidden(a.astype(self.dtype),
-                                      1 if name == "w_down" else 2,
-                                      plan.lanes)
-                    for name, a in w.items()}
-                h = jnp.where(here, self._hidden(rows, padded, sizes, plan),
-                              0)
-                return jnp.where(here, self._grouped(h, padded["w_down"],
-                                                     sizes, plan), 0)
-
-        def window(i, x, gate, w, level: bool):
-            """What the sorted assignments ``[i R, (i + 1) R)`` add to the
-            output: the grouped matmuls over those rows, each expert's
-            group cut to the window."""
-            lo = i * R
-            with jax.named_scope("dispatch"):
-                a = lax.dynamic_slice_in_dim(order, lo, R)
-                token = a // k
-                here = ((lo + jnp.arange(R)) < landed)[:, None]
-                rows = jnp.where(here, x[token].astype(self.dtype), 0)
-                g = jnp.where(here[:, 0], gate.reshape(-1)[a], 0.0)
-                sizes = jnp.clip(
-                    jnp.minimum(ends, lo + R)
-                    - jnp.maximum(ends - group_sizes, lo), 0, R)
-                if level:
-                    # The window's empty rows go to the last expert (zeros
-                    # in, zeros out): the grouped matmuls then always run
-                    # over R rows, and a step's time does not follow where
-                    # its router sends the tokens.
-                    sizes = sizes.at[-1].add(R - sizes.sum())
-            y = experts(rows, here, sizes, w)
-            with jax.named_scope("combine"):
-                return nothing(x).at[token].add(
-                    y.astype(jnp.float32) * g[:, None])
-
+        @jax.checkpoint
         def every_assignment(x, gate, w):
-            """The levelled window over all ``n k`` sorted rows: the same
-            rows, masks and products as ``window(0, ..., level=True)``,
-            moved by ``_to_expert_order`` / ``_to_token_order`` where that
-            gathers ``x[token]`` and scatter-adds the weighted rows home
-            (and autodiff scatter-adds the gather's cotangent)."""
+            """The one window over all ``n k`` sorted rows: a window's
+            rows, masks and products, moved by ``_to_expert_order`` /
+            ``_to_token_order`` where a window gathers ``x[token]`` and
+            scatter-adds the weighted rows home.  The grouped matmuls skip
+            the strips past ``landed``."""
             with jax.named_scope("dispatch"):
-                here = (jnp.arange(R) < landed)[:, None]
-                rows = jnp.where(here, _to_expert_order(
-                    x.astype(self.dtype), order, inverse), 0)
-                sizes = group_sizes.at[-1].add(R - landed)
-            y = experts(rows, here, sizes, w)
+                here = (jnp.arange(W) < landed)[:, None]
+                rows = _to_expert_order(x.astype(self.dtype), order, inverse)
+            y = _held_rows(rows, _padded(w, held), here, group_sizes, held)
             with jax.named_scope("combine"):
                 y = _to_token_order(y, order, inverse).reshape(n, k, d)
                 g = jnp.where(inverse.reshape(n, k) < landed, gate, 0.0)
                 # The float32 product the scatter-add lands on its token.
                 return (y.astype(jnp.float32) * g[..., None]).sum(axis=1)
 
-        @jax.checkpoint
-        def run(x, gate, w):
-            if permuted:
-                return every_assignment(x, gate, w)
-            out = window(0, x, gate, w, level=True)
-
-            def every_other_window():
-                # Also those past ``landed`` (all masked, their rows
-                # skipped by the grouped matmuls): a branch a window would
-                # keep a copy of the rows and the weights each for the
-                # backward pass.
-                def body(out, i):
-                    return out + jax.checkpoint(window, static_argnums=4)(
-                        i, x, gate, w, False), None
-                return lax.scan(body, nothing(x), jnp.arange(1, windows))[0]
-
-            return out + lax.cond(
-                landed > R, jax.named_scope("overflowed")(every_other_window),
-                lambda: nothing(x))
-
-        return (run(x, gate, w), tokens_per_expert, landed,
-                plan.form == "kernels", permuted)
+        return (every_assignment(x, gate, w), tokens_per_expert, landed,
+                held_windows, fused, window)
 
 
 def router_losses(intermediates) -> tuple:

@@ -150,7 +150,7 @@ def test_experts_reference_phase(smoke):
     assert out["moe_plan"] == kernels
     assert out["shape"] == [1024, 4, 128, 128]
     assert {"out", "grad_rows", "grad_w"} < set(out)
-    assert smoke.moe_plan(18432, 8, 2688, 1856) == kernels
+    assert smoke.moe_plan(7680, 8, 2688, 1856) == kernels
     assert smoke.moe_plan(131072, 64, 2048, 1024) == kernels
     assert smoke.moe_plan(56, 2, 16, 48) == {
         "form": "ragged_dot", "rows": 0, "strip": 0, "cols": 0,
@@ -162,18 +162,53 @@ def test_experts_reference_phase(smoke):
 
 def test_held_rows_says_which_way_each_cell_s_rows_move(smoke):
     """From shapes alone: ``zaya1_1chip``'s held layer (top-1, 8 of 17
-    outputs: ``3 · 1 · 8 ≥ 17``) has a window of every assignment and moves
-    all of them through the sort's permutation; ``twotower_1chip``'s
-    (18,432 of 98,304) and ``keye_1chip``'s (49,152 of 131,072) windows are
-    smaller, and gather and scatter-add."""
-    assert {cell: smoke.held_rows(**layer)
-            for cell, layer in smoke.HELD_LAYERS.items()} == {
-        "zaya1_1chip": {"assignments": 16384, "held_assignments": 7710,
-                        "permuted_assignments": 16384},
-        "twotower_1chip": {"assignments": 98304, "held_assignments": 6144,
-                           "permuted_assignments": 0},
-        "keye_1chip": {"assignments": 131072, "held_assignments": 16384,
-                       "permuted_assignments": 0}}
+    outputs: ``3 · 1 · 8 ≥ 17``) has one window of every assignment and
+    moves all of them through the sort's permutation; the five other
+    cells' windows are ``_window_plan``'s ``W`` rows of their assignments,
+    as many as a step's routing fills, and gather and scatter-add."""
+    rows = {cell: smoke.held_rows(**layer)
+            for cell, layer in smoke.HELD_LAYERS.items()}
+    assert rows["zaya1_1chip"] == {
+        "assignments": 16384, "held_assignments": 7710, "window_rows": 16384,
+        "permuted_assignments": 16384}
+    assert rows["keye_1chip"] == rows["sdar_1chip"]
+    assert {cell: (r["assignments"], r["held_assignments"], r["window_rows"])
+            for cell, r in rows.items() if not r["permuted_assignments"]} == {
+        "twotower_1chip": (98304, 6144, WINDOW_ROWS["twotower_1chip"]),
+        "keye_1chip": (131072, 16384, WINDOW_ROWS["keye_1chip"]),
+        "sdar_1chip": (131072, 16384, WINDOW_ROWS["keye_1chip"]),
+        "joyaiflash_1chip": (131072, 8192, WINDOW_ROWS["joyaiflash_1chip"]),
+        "nemo3super_1chip": (180224, 2816, WINDOW_ROWS["nemo3super_1chip"])}
+
+
+# ``moe._window_plan`` at the cells' layers (the table it was fitted to is
+# ``held_windows``'s, PERF.md section 6, PR 53).
+WINDOW_ROWS = {"twotower_1chip": 7680, "keye_1chip": 16384,
+               "joyaiflash_1chip": 10752, "nemo3super_1chip": 5632}
+
+
+def test_held_windows_phase_times_every_candidate_window(smoke):
+    """The timed case at a small layer: one entry a candidate window (the
+    plan's own among them), one time a load (none here, interpreted: the
+    chip gives them), and the plan put back when it ends."""
+    from horovod_tpu.parallel import moe
+
+    plan = moe._window_plan
+    sizes = dict(tokens=512, dim=128, hidden=128, num_experts=16, held=2,
+                 top_k=3, activation="relu2")
+    out = smoke.held_windows_phase(sizes, seed=0, loads=(1.0, 3.2),
+                                   windows=(1.0, 3.0), calls=2)
+    assert moe._window_plan is plan
+    assert out["planned"] == {
+        "assignments": 1536, "held_assignments": 192, "window_rows": 232,
+        "permuted_assignments": 0}
+    assert set(out["ms_a_layer"]) == {"232", "256", "768"}
+    assert all(set(ms) == {"1.0", "3.2"}
+               for ms in out["ms_a_layer"].values())
+    permuted = smoke.held_windows_phase(
+        dict(tokens=256, dim=128, hidden=128, num_experts=16, held=8,
+             top_k=1, skip_choice=True), seed=0, loads=(1.0,), calls=2)
+    assert set(permuted["ms_a_layer"]) == {"256"}
 
 
 def test_select_reference_phase(smoke):

@@ -148,9 +148,10 @@ def test_layer_traces_under_shard_map_with_vma_checks():
 
 def held_layer(top_k: int, skip: bool, held=(1, 2)):
     """4 experts (and the choice that computes nothing), ``held`` of them
-    here.  With 2 held the window of three times the uniform load is every
-    assignment (``3 · top_k · 2 ≥ 4 + skip``); with 1 held and top-1 it is
-    216 (skip: 176) of the 288 rows, and a second window follows."""
+    here.  With 2 held the share is a third of the outputs or more and
+    the one window is every assignment (``3 · top_k · 2 ≥ 4 + skip``);
+    with 1 held and top-1 the windows are ``_window_plan``'s rows of the
+    288, as many as the routing fills."""
     layer = DroplessMoE(num_experts=4, hidden=HID, top_k=top_k,
                         skip_choice=skip, held=held, dtype=jnp.float32)
     x = jax.random.normal(jax.random.PRNGKey(2), (3 * N, D))
@@ -162,7 +163,7 @@ def window_form(layer, p, x):
     """The layer's output as the parent formed it when the window held
     every assignment: rows gathered by ``x[token]`` (autodiff scatter-adds
     the cotangent home), the weighted results scatter-added onto their
-    tokens.  Same sort, masks, levelled sizes and grouped products."""
+    tokens.  Same sort, masks, sizes and grouped products."""
     (first, held), k = layer.held, layer.top_k
     logits = jnp.dot(x, p["router"]["kernel"],
                      precision=jax.lax.Precision.HIGHEST)
@@ -176,7 +177,6 @@ def window_form(layer, p, x):
     here = (jnp.arange(flat.size) < sizes.sum())[:, None]
     rows = jnp.where(here, x[token], 0)
     g = jnp.where(here[:, 0], gate.reshape(-1)[order], 0.0)
-    sizes = sizes.at[-1].add(flat.size - sizes.sum())
     # What the layer pads the hidden width to where ``lax.ragged_dot`` runs.
     w = {name: _pad_hidden(p[name], 1 if name == "w_down" else 2, 256)
          for name in ("w_gate", "w_up", "w_down")}
@@ -253,8 +253,8 @@ def row_scatter_adds(jaxpr, width: int) -> int:
 def test_only_a_smaller_window_scatter_adds_rows(held, permuted):
     """Forward, replay and backward of a layer whose window is every
     assignment hold no scatter-add of rows (``bincount``'s integer one
-    and the levelling's stay); a window smaller than ``n · k`` keeps the
-    parent's: the combine's, and the transpose of the dispatch's gather.
+    stays); windows smaller than ``n · k`` keep theirs: the combine's, and
+    in the backward loop the one that lands the gathered rows' cotangent.
     The layers' notes say which form ran."""
     layer, params, x = held_layer(1, True, held)
     noted = {}

@@ -47,7 +47,7 @@ SIZES = {
     "trailing_empty_rows_past_whole_tiles": [64, 0, 30],
     "one_group_takes_all": [256, 0, 0],
     "no_group_has_a_row": [0, 0, 0],
-    "levelled_the_last_takes_the_rest": [17, 5, 0, 9, 225],
+    "the_last_takes_the_rest": [17, 5, 0, 9, 225],
     "more_groups_than_tiles": [3] * 40 + [136],
 }
 
@@ -113,7 +113,7 @@ def test_the_three_products_equal_the_loop_over_groups(case):
 
 @pytest.mark.parametrize("case", ["uneven", "trailing_empty_rows",
                                   "a_zero_group_first_inside_and_last",
-                                  "levelled_the_last_takes_the_rest"])
+                                  "the_last_takes_the_rest"])
 def test_the_custom_vjp_equals_the_loop_s_gradients(case):
     """``jax.vjp`` of the op the layer calls: value, input gradient and
     weight gradient against the loop; the group sizes take no gradient."""

@@ -26,8 +26,7 @@ from horovod_tpu.ops.flash_attention import flash_attention
 from horovod_tpu.ops import ssd
 from horovod_tpu.ops.ssd import (
     scan_sizes, ssd_recurrence, ssd_scan, ssd_scan_packed)
-from horovod_tpu.parallel.moe import (
-    _HELD_WINDOW, DroplessMoE, _SharedExpert)
+from horovod_tpu.parallel.moe import DroplessMoE, _SharedExpert
 from horovod_tpu.parallel.ring_attention import full_attention
 
 from test_gated_delta import _equations

@@ -41,7 +41,7 @@ OTHERS = {
         moe_hidden=16, dtype=F32,
         moe=dict(router="sigmoid", renormalize=True, gate_scale=2.5,
                  activation="relu2", shared_hidden=32, held=(2, 2))),
-        "e89fac7c61fa231a"),
+        "d0d8eb827e799a93"),
     "granite_hybrid": (lambda: GraniteHybridLM(
         vocab=64, dim=32, pattern="ma", num_heads=2, kv_heads=1, head_dim=16,
         attn="full", mlp_hidden=48, ssm={**SSM, "n_groups": 1}, dtype=F32),
@@ -51,12 +51,12 @@ OTHERS = {
         attn="full", indexer=dict(num_heads=2, head_dim=8, topk=4),
         moe_experts=8, moe_top_k=2, moe_hidden=16, dtype=F32,
         moe=dict(router="softmax", renormalize=True, activation="swiglu",
-                 held=(0, 2))), "44be2750a10d541d"),
+                 held=(0, 2))), "c0f6f52a244180f3"),
     "zaya1": (lambda: Zaya1LM(
         vocab=64, dim=32, pattern="ZZ", num_heads=2, kv_heads=1, head_dim=16,
         attn="full", moe_experts=4, moe_top_k=1, moe_hidden=16, dtype=F32,
         moe=dict(router="mlp", router_hidden=8, skip_choice=True,
-                 activation="swiglu", held=(0, 2))), "4b67b295f7c079d0"),
+                 activation="swiglu", held=(0, 2))), "5fc191a9efbe46a3"),
     "olmo_hybrid": (lambda: OlmoHybridLM(
         vocab=64, dim=32, pattern="LF", num_heads=2, attn="full",
         mlp_hidden=48, dtype=F32,
@@ -75,7 +75,10 @@ def test_without_a_latent_and_a_prediction_module_the_stacks_lower_as_they_did(
     and gradients, lowers to the text — to the letter — that the commit
     before ``latent`` and ``mtp`` lowered it to (SHA-256 taken there,
     43bb0d2, PR 46): with ``latent=0`` and ``mtp=None`` neither field
-    leaves a trace."""
+    leaves a trace.  The three stacks that hold a share of their experts
+    (``nemotron_h``, ``keye``, ``zaya1``) lower to what PR 53 made of the
+    held share — windows that follow the load, nothing levelled — and
+    their digests were taken at its tree, still with neither field."""
     build, digest = OTHERS[name]
     model = build()
     assert model.mtp is None and not dict(model.moe or {}).get("latent")
@@ -156,14 +159,15 @@ def test_the_refusals_name_the_new_fields():
         tiny(tie_head=True).init(key, tokens)
 
 
-@pytest.mark.parametrize("window", [1, 2, 6])
-def test_a_latent_layer_is_the_same_in_every_form_of_the_held_window(
-        window, monkeypatch):
-    """The held share's rows move in three forms — one levelled window, the
-    ``overflowed`` loop over further ones, and, where a window is every
-    assignment, gathers through the sort's permutation.  With the experts
-    in a latent each gives the output and gradients of the library's
-    window (``_HELD_WINDOW`` 3): at 1 this routing overflows, at 6 the
+@pytest.mark.parametrize("rows", [16, 24, 144])
+def test_a_latent_layer_is_the_same_at_every_window_of_the_held_share(
+        rows, monkeypatch):
+    """The held share's rows move in two forms — windows of ``W`` sorted
+    rows, as many as the landed assignments fill, and, where a window is
+    every assignment, gathers through the sort's permutation.  With the
+    experts in a latent each gives the output and gradients of the
+    library's own window (``_window_plan``: 40 rows, the uniform load of
+    36 in whole sublanes): at 16 and 24 more windows run, at 144 the
     window is every assignment; the lowered program holds the window's
     rows at the LATENT's width."""
     from horovod_tpu.parallel import moe
@@ -179,12 +183,19 @@ def test_a_latent_layer_is_the_same_in_every_form_of_the_held_window(
         return jax.value_and_grad(
             lambda p: (layer.apply(p, x)[0] ** 2).sum())(params)
 
-    assert moe._HELD_WINDOW == 3
+    # 48 tokens x top-3 x 2 of 8 held = 36 rows a uniform load.
+    plan = moe._window_plan(assignments=144, held=2, routed=8, row_bytes=32,
+                            expert_bytes=2 * 2 * 8 * 16 * 4)
+    assert plan == moe.WindowPlan(40, 4, 36)
     want = value_and_grads()
-    monkeypatch.setattr(moe, "_HELD_WINDOW", window)
+    monkeypatch.setattr(
+        moe, "_window_plan",
+        lambda **shapes: moe.WindowPlan(rows, -(-144 // rows), 36))
     got = value_and_grads()
     assert max(jax.tree.leaves(jax.tree.map(rel, got, want))) < 1e-5
-    # 48 tokens x top-3 x 2 of 8 held = 36 rows a uniform load.
-    rows = min(144, -(-36 * window // 8) * 8)
+    (_, sown) = layer.apply(params, x, mutable=["intermediates"])
+    sown = sown["intermediates"]
+    assert int(sown["held_windows"][0]) == -(
+        -int(sown["held_assignments"][0]) // rows)
     text = jax.jit(lambda p: layer.apply(p, x)[0]).lower(params).as_text()
     assert f"tensor<{rows}x8xf32>" in text

@@ -119,8 +119,9 @@ HEAD_KERNEL_EQUATIONS = {"_dkdv_kernel_grouped": 176,
 
 
 @pytest.mark.parametrize("family", ["flash", "scan", "passes", "experts",
-                                    "selected", "selected_pair",
-                                    "threshold", "grouped_kv", "latent"])
+                                    "held_windows", "selected",
+                                    "selected_pair", "threshold",
+                                    "grouped_kv", "latent"])
 def test_each_kernel_is_traced_once_a_step_not_once_a_layer(monkeypatch,
                                                             family):
     """The set-up guard, no chip and no compile: tracing ``jax.grad`` of a
@@ -161,6 +162,12 @@ def test_each_kernel_is_traced_once_a_step_not_once_a_layer(monkeypatch,
     other's six times — up and down as the forward that runs, as the
     checkpoint's replay, and read transposed for the input gradients —
     where a trace a layer would be eight and twenty-four.
+    ``held_windows``: two layers that each hold 2 of 16 (2,048 tokens,
+    top-2: windows of 512 sorted rows, as many as the landed assignments
+    fill).  The loop's body is traced once each way: up and down forward,
+    and in the backward loop again, read transposed for the input
+    gradients and the weight gradient's twice — and every layer leaves
+    those eight kernels, whatever the windows a step runs.
 
     ``selected``: two sparse-attention layers of a ``KeyeLM`` (8 query
     heads over one KV head of 128, 32 of up to 256 keys a query).  The
@@ -223,7 +230,7 @@ def test_each_kernel_is_traced_once_a_step_not_once_a_layer(monkeypatch,
         for name in ("_fwd_kernel", "_bwd_kernel"):
             monkeypatch.setattr(cca_passes, name, counted(
                 "cca." + name, getattr(cca_passes, name)))
-    if family == "experts":
+    if family in ("experts", "held_windows"):
         for name in ("_gmm_kernel", "_tgmm_kernel"):
             monkeypatch.setattr(grouped_matmul, name,
                                 counted(name, getattr(grouped_matmul, name)))
@@ -231,7 +238,7 @@ def test_each_kernel_is_traced_once_a_step_not_once_a_layer(monkeypatch,
     # No other test's, nor another case's: a trace made earlier would be
     # shared.
     batch = {"flash": 3, "scan": 3, "passes": 5, "experts": 2,
-             "selected": 1, "selected_pair": 1, "threshold": 1,
+             "held_windows": 2, "selected": 1, "selected_pair": 1, "threshold": 1,
              "grouped_kv": 1, "latent": 7}[family]
     if family.startswith("selected") or family == "threshold":
         if family == "selected_pair":
@@ -280,6 +287,14 @@ def test_each_kernel_is_traced_once_a_step_not_once_a_layer(monkeypatch,
             dtype=jnp.bfloat16, moe_experts=16, moe_top_k=2, moe_hidden=128,
             moe=dict(router="sigmoid", renormalize=True, activation="relu2",
                      held=(0, 8)))
+        want = {"_gmm_kernel": 6, "_tgmm_kernel": 2}
+    elif family == "held_windows":
+        seq = 1024
+        model = NemotronHLM(
+            vocab=512, dim=256, pattern="EE", max_len=seq,
+            dtype=jnp.bfloat16, moe_experts=16, moe_top_k=2, moe_hidden=128,
+            moe=dict(router="sigmoid", renormalize=True, activation="relu2",
+                     held=(0, 2)))
         want = {"_gmm_kernel": 6, "_tgmm_kernel": 2}
     else:
         seq = 256
@@ -353,6 +368,10 @@ def test_each_kernel_is_traced_once_a_step_not_once_a_layer(monkeypatch,
         # gradients, their two weight gradients.
         assert collections.Counter(name for name, _ in found) == {
             "moe_gmm": 16, "moe_gmm_nt": 8, "moe_tgmm": 8}
+        return
+    if family == "held_windows":
+        assert collections.Counter(name for name, _ in found) == {
+            "moe_gmm": 8, "moe_gmm_nt": 4, "moe_tgmm": 4}
         return
     if family != "flash":
         # Four mixers: the scan's forward, and in the backward its states
@@ -931,16 +950,21 @@ def test_mixer_passes_compile_wherever_the_plan_takes_the_kernels(
 # one case a way of tiling — the two cells' products both ways, widths that
 # cut into blocks of 384 and 640 only, one group, more groups than row
 # tiles, the widest contraction the plan still holds whole in VMEM, and
-# (PR 46) a contraction of 1,024 against 21 lane tiles: a held window of
-# 8,448 rows (33 tiles of 256) of a 1,024-wide latent, both ways.
+# (PR 46) a contraction of 1,024 against 21 lane tiles: a held window of a
+# 1,024-wide latent, up at its 5,632 rows and down at 8,448 (33 tiles of
+# 256: rows in whole strips only).  The held cells' rows are their windows'
+# (``moe._window_plan``, PR 53): 7,680, 5,632 and, at ``keye_1chip``'s
+# widths, ``joyaiflash_1chip``'s 10,752 (21 tiles of 512).
 @pytest.mark.parametrize("rows,groups,k,n", [
-    (18_432, 8, 2688, 1920), (18_432, 8, 1920, 2688),
+    (7_680, 8, 2688, 1920), (7_680, 8, 1920, 2688),
     (131_072, 64, 2048, 1024), (131_072, 64, 1024, 2048),
     (1024, 4, 1152, 640), (512, 1, 128, 128), (512, 64, 256, 384),
-    (1024, 2, 4096, 1024), (8_448, 8, 1024, 2688), (8_448, 8, 2688, 1024)],
+    (1024, 2, 4096, 1024), (5_632, 8, 1024, 2688), (8_448, 8, 2688, 1024),
+    (10_752, 16, 2048, 768)],
     ids=["twotower_up", "twotower_down", "olmoe_up", "olmoe_down",
          "blocks_of_384_and_640", "one_group", "more_groups_than_tiles",
-         "widest_contraction", "latent_window_up", "latent_window_down"])
+         "widest_contraction", "latent_window_up",
+         "latent_rows_in_whole_strips_only", "joyaiflash_window"])
 def test_grouped_matmuls_compile_wherever_the_plan_takes_the_kernels(
         v5e, rows, groups, k, n):
     """A shape ``_plan`` gives the kernels has to compile, the product and
@@ -973,55 +997,84 @@ def test_grouped_matmuls_compile_wherever_the_plan_takes_the_kernels(
     assert y.dtype == dx.dtype == dw.dtype == jnp.bfloat16
 
 
-def test_held_expert_layer_fwd_bwd_at_nemotron_widths(v5e, monkeypatch):
-    """``DroplessMoE`` as the cell calls it: 16,384 tokens of width 2688
-    routed over 128 experts, top-6, 8 of them held, a shared expert 3712
-    wide.  The grouped matmuls run over a window of 18,432 sorted rows
-    (three times the 6,144 that uniform routing sends here), not over the
-    98,304 assignments (0.5 GiB a tensor of their rows), as the family's
-    kernels (``grouped_matmul._plan`` takes them: up and down forward, the
-    checkpoint's replay of both, two input and two weight gradients, and
-    the same again in the ``overflowed`` branch's windows), with the
-    experts' hidden width padded from 1856 to the kernels' 1920, not to
-    ``ragged_dot``'s 2048; the plan, with 0.8 GiB of float32 weights and
-    gradients, stays under 4.5."""
-    from horovod_tpu.parallel.moe import DroplessMoE
+# A held layer of three cells as its family calls it: (tokens, width, the
+# layer's fields, GiB the plan stays under).  ``zaya1_1chip``'s and
+# ``nemo3super_1chip``'s stand further down, inside their own layers;
+# ``joyaiflash_1chip``'s is ``keye_1chip``'s at windows of 10,752 rows,
+# whose kernels compile above (``joyaiflash_window``).
+HELD_LAYERS = {
+    "twotower_1chip": (16_384, 2688, dict(
+        num_experts=128, hidden=1856, top_k=6, router="sigmoid",
+        renormalize=True, gate_scale=2.5, activation="relu2",
+        shared_hidden=3712, held=(0, 8)), 4.5),
+    "keye_and_sdar_1chip": (16_384, 2048, dict(
+        num_experts=128, hidden=768, top_k=8, renormalize=True,
+        held=(0, 16)), 3.0)}
+
+
+@pytest.mark.parametrize("cell", HELD_LAYERS)
+def test_held_expert_layer_fwd_bwd_at_the_cells_widths(v5e, monkeypatch,
+                                                       cell):
+    """``DroplessMoE(held=...)`` as the cells call it (``twotower_1chip``:
+    16,384 tokens of width 2688 routed over 128 experts, top-6, 8 of them
+    held, a shared expert 3712 wide).  The grouped matmuls run over
+    windows of the ``W`` sorted rows that ``_window_plan`` gives the
+    shapes, inside ONE loop each way whose trip count the device reads,
+    not over the layer's assignments (98,304 there: 0.5 GiB a tensor of
+    their rows), as the family's kernels (``grouped_matmul._plan`` takes
+    them at every such ``W``: each projection forward, again in the
+    backward loop, an input and a weight gradient each), with the
+    experts' hidden width padded to the kernels' whole lane tiles (1856
+    to 1920), not to ``ragged_dot``'s 2048; the weight gradients are
+    carried in float32; the plan, with the float32 weights and gradients,
+    stays under its bound."""
+    from horovod_tpu.parallel.moe import DroplessMoE, _window_plan
 
     # The layer asks jax.default_backend() whether to lower interpreted.
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
 
-    tokens, d = 16_384, 2688
+    tokens, d, fields, gib = HELD_LAYERS[cell]
     one = SingleDeviceSharding(v5e[0])
-    layer = DroplessMoE(num_experts=128, hidden=1856, top_k=6,
-                        router="sigmoid", renormalize=True, gate_scale=2.5,
-                        activation="relu2", shared_hidden=3712, held=(0, 8))
+    layer = DroplessMoE(**fields)
     x = jax.ShapeDtypeStruct((tokens, d), jnp.bfloat16, sharding=one)
     params = jax.tree.map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one),
         jax.eval_shape(lambda key: layer.init(
             key, jnp.zeros((8, d), jnp.bfloat16))["params"],
             jax.random.PRNGKey(0)))
-    assert params["w_up"].shape == (8, d, 1856)
-    assert params["router"]["kernel"].shape == (d, 128)
+    held, hidden = fields["held"][1], fields["hidden"]
+    matrices = 2 if fields.get("activation") == "relu2" else 3
+    assert params["w_up"].shape == (held, d, hidden)
+    assert params["router"]["kernel"].shape == (d, fields["num_experts"])
+    assignments = tokens * fields["top_k"]
+    window = _window_plan(
+        assignments=assignments, held=held, routed=fields["num_experts"],
+        row_bytes=2 * d, expert_bytes=4 * matrices * held * d * hidden)
+    assert 1 < window.windows and window.rows % 256 == 0, window
 
     def loss(p, x):
         return layer.apply({"params": p}, x)[0].astype(jnp.float32).sum()
 
     lowered = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
         params, x)
-    found = kernels_by_name(lowered)
-    assert found["moe_gmm"] >= 4 and found["moe_gmm_nt"] >= 2, found
-    assert found["moe_tgmm"] >= 2, found
+    assert kernels_by_name(lowered) == {
+        "moe_gmm": 2 * matrices, "moe_gmm_nt": matrices,
+        "moe_tgmm": matrices}
+    assert "stablehlo.case" not in lowered.as_text()
     compiled = lowered.compile()
     text = compiled.as_text()
+    W, padded = window.rows, hidden + -hidden % 128
     assert "ragged-dot" not in text
-    assert "18432,2688" in text and "98304,2688" not in text
-    assert "18432,1920" in text and "18432,1856" not in text
-    assert "18432,2048" not in text
+    assert f"{W},{d}" in text and f"{assignments},{d}" not in text
+    assert f"bf16[{W},{padded}]" in text
+    assert f"f32[{held},{d},{padded}]" in text
+    if padded != hidden:
+        assert f"{W},{hidden}]" not in text
+        assert f"{W},{hidden + -hidden % 256}]" not in text
     m = compiled.memory_analysis()
     plan = (m.argument_size_in_bytes + m.output_size_in_bytes
             + m.temp_size_in_bytes - m.alias_size_in_bytes)
-    assert plan < 4.5 * 2 ** 30, plan / 2 ** 30
+    assert plan < gib * 2 ** 30, plan / 2 ** 30
 
 
 # ------------------------------------- the linear-attention hybrid's parts
@@ -1630,15 +1683,20 @@ def test_cca_passes_compile_wherever_the_plan_takes_the_kernels(
 # (the nemo3super_1chip cell: 1 sequence of 8,192 (+2), one chip's share)
 
 
+# Rows of a window of the cell's expert layers (``moe._window_plan``; the
+# table of the six cells is in ``tests/test_hybrid_experts.py``).
+NEMO3_WINDOW = 5632
+
+
 def test_a_latent_expert_layer_fwd_bwd_at_nemotron3_widths(v5e, monkeypatch):
     """One ``E`` layer as the ``nemo3super_1chip`` cell calls it, forward
     and backward on one chip: 8,192 tokens of width 4,096 routed over 512
     experts, top-22, 8 of them held, in a latent of 1,024 between the two
     projections every expert shares, beside a shared expert 5,376 wide.
-    The grouped matmuls run over a window of 8,448 sorted rows OF THE
-    LATENT (three times the 2,816 that uniform routing sends here: 33
-    tiles of 256), as the family's kernels, not over the 180,224
-    assignments and never at the model's width."""
+    The grouped matmuls run over windows of ``W`` sorted rows OF THE
+    LATENT (``_window_plan``'s, for the 2,816 that uniform routing sends
+    here), as the family's kernels, not over the 180,224 assignments and
+    never at the model's width."""
     from horovod_tpu.models.transformer import PatternLayer
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
@@ -1664,16 +1722,16 @@ def test_a_latent_expert_layer_fwd_bwd_at_nemotron3_widths(v5e, monkeypatch):
 
     lowered = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
         params, x)
-    found = kernels_by_name(lowered)
-    # Up and down forward, the checkpoint's replay of both, two input and
-    # two weight gradients, and the same again in the overflow's windows.
-    assert found["moe_gmm"] >= 4 and found["moe_gmm_nt"] >= 2, found
-    assert found["moe_tgmm"] >= 2, found
+    # Up and down in the forward loop and again in the backward one, two
+    # input and two weight gradients.
+    assert kernels_by_name(lowered) == {
+        "moe_gmm": 4, "moe_gmm_nt": 2, "moe_tgmm": 2}
+    W = NEMO3_WINDOW
     compiled = lowered.compile()
     text = compiled.as_text()
     assert "ragged-dot" not in text
-    assert "8448,1024" in text and "8448,2688" in text
-    assert "8448,4096" not in text and "180224,1024" not in text
+    assert f"{W},1024" in text and f"{W},2688" in text
+    assert f"{W},4096" not in text and "180224,1024" not in text
     m = compiled.memory_analysis()
     plan = (m.argument_size_in_bytes + m.output_size_in_bytes
             + m.temp_size_in_bytes - m.alias_size_in_bytes)
@@ -1730,4 +1788,4 @@ def test_the_nemo3super_cell_s_step_lowers_with_every_kernel_family(
         "ssm_conv_fwd", "ssm_gate_bwd", "ssm_gate_fwd"}
     assert "stablehlo.all_reduce" not in text
     assert "8192x2432xbf16" in text            # the padded input projection
-    assert "8448x1024xbf16" in text            # the window, in the latent
+    assert f"{NEMO3_WINDOW}x1024xbf16" in text  # a window, in the latent

@@ -414,22 +414,24 @@ def test_the_stack_hands_the_state_from_z_layer_to_z_layer():
     ("olmoe", dict(num_experts=8, hidden=32, top_k=2), "320956d894be7186"),
     ("nemotron", dict(num_experts=8, hidden=32, top_k=3, router="sigmoid",
                       renormalize=True, gate_scale=2.5, activation="relu2",
-                      shared_hidden=64, held=(2, 2)), "f0722bfccb01785a"),
+                      shared_hidden=64, held=(2, 2)), "549bb5fe39a76bc8"),
     ("keye", dict(num_experts=8, hidden=32, top_k=2, router="softmax",
-                  renormalize=True, held=(0, 2)), "c99ac8134e550d3d"),
+                  renormalize=True, held=(0, 2)), "3b1ff08a4f14498a"),
 ])
 def test_the_existing_routers_programs_are_the_parent_s(name, settings,
                                                         digest):
     """The layer's lowered program, loss and gradients, under the three
     settings the benchmark's other expert cells run is, to the letter, the
     one the commit before the ``mlp`` router lowered (``olmoe``'s SHA-256
-    taken there, PR 43) and the commit before the permuted rows still did
-    (the two held ones' taken there, acb1388, PR 45): same program, same
-    bits out.  The held shares are 2 of 8 — windows of 112 of 144 and 72
-    of 96 assignments, smaller than ``n · k`` as ``twotower_1chip``'s and
-    ``keye_1chip``'s are —, so what is pinned is the window form those
-    cells run; at 4 of 8 the window is every assignment, whose rows no
-    longer scatter-add (``tests/test_dropless_moe.py``)."""
+    taken there, PR 43: every expert here, which no later PR has moved)
+    and, for the two held ones, the one PR 53 lowers, whose windows follow
+    the load (taken at its tree; the digests of PR 45, acb1388, pinned the
+    levelled window and its ``overflowed`` branch before): same program,
+    same bits out.  The held shares are 2 of 8 — windows of 40 of 144 and
+    40 of 96 assignments, smaller than ``n · k`` as ``twotower_1chip``'s
+    and ``keye_1chip``'s are —, so what is pinned is the window form those
+    cells run; at 4 of 8 the window is every assignment, whose rows do not
+    scatter-add (``tests/test_dropless_moe.py``)."""
     x = jax.random.normal(jax.random.PRNGKey(0), (2, 24, 16), F32)
     layer = DroplessMoE(**settings, dtype=F32)
     params = layer.init(jax.random.PRNGKey(1), x)
