@@ -319,7 +319,8 @@ def flops_per_unit(cfg) -> float:
     values 128) over the causal half of the (T, T) square, forward and twice
     again backward, in every layer and the module's.  Recomputation (the
     up-projections' in the backward pass, the kernels' second pass over the
-    scores), the lanes the kernels pad, the window's padding, the embedding
+    scores), the lanes the kernels pad, the rows that round a load up to
+    whole windows (``ceil(landed / W)``, ``moe._window_plan``), the embedding
     lookups, the top-k, the sort and the combine are not counted."""
     s = _sizes(cfg)
     n_matmul = sum(k * n * count for _, k, n, count in matmuls(cfg))
@@ -401,8 +402,9 @@ def moe_cost(cfg, batch_per_chip: int) -> dict:
     at one pass of the bf16 peak (it runs several: that counts against the
     share).  Bytes, per matmul, in bf16 as in ``nemotron3_super_lm.moe_cost``.
     The top-k, the sort, the gathers, the scatter of the combine, the
-    activation, the bias update and the window's levelling rows (the window
-    is 3 x the uniform load) are left out: what the layer takes for them
+    activation, the bias update and the rows that round the load up to whole
+    windows (a layer runs ``ceil(landed / W)`` of them, ``W`` from
+    ``moe._window_plan``) are left out: what the layer takes for them
     counts against its roofline share."""
     d, eh = cfg["hidden_size"], cfg["moe_intermediate_size"]
     sh = cfg["n_shared_experts"] * eh

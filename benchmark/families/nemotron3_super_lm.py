@@ -315,7 +315,8 @@ def flops_per_unit(cfg) -> float:
     uniform routing sends here —, of attention's two products over the
     causal half of the (T, T) square in the stack's and the module's
     attention layer, and of the mixers' chunked scans
-    (:func:`scan_flops_per_token`).  Recomputation, the window's padding,
+    (:func:`scan_flops_per_token`).  Recomputation, the rows that round a load up
+    to whole windows (``ceil(landed / W)``, ``moe._window_plan``),
     the embedding lookups, the top-k, the sort and the combine are not
     counted."""
     s = _sizes(cfg)
@@ -408,8 +409,9 @@ def moe_cost(cfg, batch_per_chip: int) -> dict:
     forward its rows in and out and the weights; the input-gradient
     product the same again; the weight-gradient product both sets of rows
     and the gradient in float32.  The top-k, the sort, the gathers, the
-    scatter of the combine, the activation and the window's levelling rows
-    (the window is 3 x the uniform load) are left out: what the layer takes for them
+    scatter of the combine, the activation and the rows that round the load
+    up to whole windows (a layer runs ``ceil(landed / W)`` of them, ``W``
+    from ``moe._window_plan``) are left out: what the layer takes for them
     counts against its roofline share."""
     d, lat = cfg["hidden_size"], cfg["moe_latent_size"]
     eh, sh = (cfg["moe_intermediate_size"],

@@ -145,8 +145,11 @@ def init(cfg, key):
     of random values, nearly the same for every row — and a third of this
     objective's rows are one mask token besides —, every router sees one
     vector, and where three of its eight choices happen to be held here the
-    layer's levelled window (three times the uniform load) overflows on some
-    of the pool's batches and not on others: +55 ms on those steps."""
+    layer's load sat at the levelled window of three times the uniform load
+    that the library ran until PR 53, which overflowed on some of the pool's
+    batches and not on others: +55 ms on those steps.  A layer now runs
+    ``ceil(landed / W)`` windows, ``W`` from ``moe._window_plan``; the scale
+    stays, as part of the weights every reading was taken on."""
     import jax.numpy as jnp
     params = _model(cfg).init(
         key, jnp.zeros((1, min(cfg["sequence_length"], 256)),

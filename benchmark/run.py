@@ -5,8 +5,13 @@
 
 A new process that holds the cell's chips and trains as a user of the
 library does: ``hvd.init()`` -> ``hvd.ranks_mesh()`` -> ``make_train_step``
-with no ``HOROVOD_TPU_*`` knob set, weights made on the device from
-``--seed``, a pool of seeded host batches fed through ``ShardedLoader``.
+with no ``HOROVOD_TPU_*`` knob set and a pool of host batches fed through
+``ShardedLoader``.  What is random comes from two places.  The WEIGHTS
+are the configuration's: made on the device from a key that is a hash of
+the configuration's name (``weights_key``), so every run of every cell of
+a configuration trains the same model, as every user of a published
+checkpoint starts from one set of weights; no flag, field or variable
+picks that key.  The BATCHES are ``--seed``'s: the pool is drawn from it.
 It checks the program against the family's plain reference, warms up the
 cell's one step program, measures for ``--seconds`` and prints one JSON
 object as its last line.  ``--trace 1`` then profiles a short slice and
@@ -38,6 +43,7 @@ import statistics
 import sys
 import threading
 import time
+import zlib
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
@@ -181,6 +187,23 @@ class JaxEvents:
 
 
 # ------------------------------------------------------------ set-up
+
+
+def weights_key(cfg: dict) -> int:
+    """The key a configuration's weights are drawn from: a hash of its
+    name and of nothing else.  Random weights stand in for a published
+    checkpoint, which is one set of weights, so they are one set too:
+    every run starts from the same routers' loads (PERF.md section 6,
+    PR 55).  The batches stay ``--seed``'s."""
+    return zlib.crc32(cfg["name"].encode())
+
+
+def float32_bit_sums(jax, tree):
+    """A wrap-around sum of the bits of each float32 leaf of ``tree``."""
+    import jax.numpy as jnp
+    return jnp.stack([
+        jax.lax.bitcast_convert_type(x, jnp.uint32).sum(dtype=jnp.uint32)
+        for x in jax.tree.leaves(tree) if x.dtype == jnp.float32])
 
 
 def use_compile_cache(jax, rehearse: bool):
@@ -328,14 +351,11 @@ def replicas_identical(jax, mesh, params) -> bool:
     """Whether every device holds the same parameter bits: a wrap-around
     sum of each float32 leaf's bits on each device, largest minus
     smallest over the mesh."""
-    import jax.numpy as jnp
     import numpy as np
     from jax.sharding import PartitionSpec as P
 
     def bit_spread(tree):
-        sums = jnp.stack([
-            jax.lax.bitcast_convert_type(x, jnp.uint32).sum(dtype=jnp.uint32)
-            for x in jax.tree.leaves(tree) if x.dtype == jnp.float32])
+        sums = float32_bit_sums(jax, tree)
         return (jax.lax.pmax(sums, mesh.axis_names)
                 - jax.lax.pmin(sums, mesh.axis_names))
 
@@ -475,19 +495,31 @@ def main(argv=None) -> int:
     controller = "native" if basics.controller().native else "python"
     phases.mark("init_s")
 
-    # Weights on the device in one jitted call; host batches from the seed.
+    # The configuration's weights, on the device in one jitted call from
+    # the configuration's key, with a checksum for the result line.
     replicated = NamedSharding(mesh, P())
-    params, aux = jax.jit(lambda key: family.init(cfg, key),
-                          out_shardings=replicated)(
-                              jax.random.PRNGKey(args.seed))
-    jax.block_until_ready(params)
+
+    def init(key):
+        params, aux = family.init(cfg, key)
+        return params, aux, float32_bit_sums(jax, params).sum(
+            dtype=jnp.uint32)
+
+    key = weights_key(cfg)
+    params, aux, weights_sum = jax.jit(init, out_shardings=replicated)(
+        jax.random.PRNGKey(key))
+    weights_sum = int(weights_sum)
     n_params = sum(p.size for p in jax.tree.leaves(params))
     phases.mark("weights_s")
+    # Host batches from the seed, with a checksum of the first for the
+    # result line.
     rng = np.random.default_rng(args.seed)
     global_batch = job["batch_per_chip"] * chips
     spc = int(job["steps_per_call"])
     pool = [family.host_batch(cfg, rng, global_batch)
             for _ in range(job["pool"])]
+    pool_crc = 0
+    for a in jax.tree.leaves(pool[0]):
+        pool_crc = zlib.crc32(np.ascontiguousarray(a), pool_crc)
     phases.mark("pool_s")
 
     # Against the reference, before optimizer state takes its room.
@@ -630,7 +662,8 @@ def main(argv=None) -> int:
         step_first_call=first_call, cache_dir=cache_dir,
         cache_bytes=dir_bytes(cache_dir), **events.snapshot())
     say("job", cell=args.workload, config=cfg["name"], family=cfg["family"],
-        traffic=cell["traffic"], seed=args.seed, parameters=n_params,
+        traffic=cell["traffic"], seed=args.seed,
+        weights_key=key, parameters=n_params,
         global_batch=global_batch, steps_per_call=spc,
         knobs_taken_out_of_environment=knobs, unknown_arguments=unknown_args,
         host_batch_bytes=sum(a.nbytes for a in jax.tree.leaves(pool[0])),
@@ -692,6 +725,9 @@ def main(argv=None) -> int:
         device["window_s"] = statistics.fmean(
             d["window_s"] for d in reduced["devices"])
         line["breakdown"] = tracered.breakdown(reduced)
+    # What weights and pool were drawn from, with a checksum of each.
+    line.update(seed=args.seed, weights_key=key, weights_sum=weights_sum,
+                pool_crc=pool_crc)
     print(json.dumps(line), flush=True)
     return 0
 

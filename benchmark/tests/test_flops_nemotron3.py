@@ -138,7 +138,8 @@ def test_moe_cost_at_the_benchmark_shape(cfg):
         2 * HELD * LAT * EH + 2 * d * LAT + 2 * d * SH)
     # FLOP-bound: 84.5 ms a step in six layers, the shared expert 65.9 of
     # them, the latent projections 12.6, the router 3.1, the held experts
-    # 2.8 (at the uniform load; the window runs three times the rows).
+    # 2.8 (at the uniform load; a layer runs ceil(landed / W) windows of
+    # W = 5,632 rows, twice that load: moe._window_plan).
     assert cost["flops"] / 197e12 == pytest.approx(84.5e-3, rel=0.01)
     assert cost["bytes"] / 819e9 < 0.5 * cost["flops"] / 197e12
     assert cost["latent_flops"] / 197e12 == pytest.approx(12.6e-3, rel=0.01)
