@@ -25,12 +25,16 @@ tsan:
 	$(MAKE) -C cpp tsan
 
 # Tier-1 test suite, as the driver runs it (six xdist workers, a file to a
-# worker, 1470 s for the whole; tests/conftest.py limits each test).
+# worker, 1470 s for the whole; tests/conftest.py limits each test), then
+# where its time went: seconds a file, the long cases, the cap's share.
+T1_XML ?= /tmp/_t1.xml
 test:
+	rm -f $(T1_XML); \
 	timeout -k 10 1470 env JAX_PLATFORMS=cpu ALLOW_MULTIPLE_LIBTPU_LOAD=1 \
 		$(PYTHON) -m pytest tests/ -q -m 'not slow' \
 		--continue-on-collection-errors -p no:cacheprovider -p no:randomly \
-		-p xdist -n 6 --dist loadfile
+		-p xdist -n 6 --dist loadfile --junitxml=$(T1_XML); rc=$$?; \
+	$(PYTHON) tools/tier1_times.py $(T1_XML); exit $$rc
 
 clean:
 	$(MAKE) -C cpp clean

@@ -283,7 +283,8 @@ def tiny_cfg(compute_dtype="bfloat16"):
 
 
 def model_inputs(cfg, n=4, seed=5):
-    params, aux = olmoe_lm.init(cfg, jax.random.PRNGKey(seed))
+    params, aux = jax.jit(lambda k: olmoe_lm.init(cfg, k))(
+        jax.random.PRNGKey(seed))
     tokens = olmoe_lm.host_batch(cfg, np.random.default_rng(seed), n)
     return params, aux, tokens
 
@@ -304,11 +305,12 @@ def test_model_against_reference_loss(compute_dtype, loss_tol, grad_tol,
     cfg = tiny_cfg(compute_dtype)
     params, aux, tokens = model_inputs(cfg)
     loss_fn, ref_fn = olmoe_lm.loss_fn(cfg), olmoe_lm.reference_loss(cfg)
+    # Each side ONE program, not differentiated eagerly op by op (PR 56).
     with jax.default_matmul_precision("highest"):
-        got, got_g = jax.value_and_grad(
-            lambda p: loss_fn(p, aux, tokens)[0])(params)
-    want, want_g = jax.value_and_grad(
-        lambda p: ref_fn(p, aux, tokens))(params)
+        got, got_g = jax.jit(jax.value_and_grad(
+            lambda p: loss_fn(p, aux, tokens)[0]))(params)
+    want, want_g = jax.jit(jax.value_and_grad(
+        lambda p: ref_fn(p, aux, tokens)))(params)
     assert abs(float(got) - float(want)) / float(want) <= loss_tol
     flat_got = dict(jax.tree_util.tree_leaves_with_path(got_g))
     flat_want = dict(jax.tree_util.tree_leaves_with_path(want_g))

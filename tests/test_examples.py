@@ -141,7 +141,7 @@ def test_word2vec_example(hvd, monkeypatch, capsys):
 
 
 @pytest.mark.time_limit(
-    600, "runs the ResNet-50 example twice on 8 virtual devices: 132 s "
+    600, "runs the ResNet-50 example twice on 8 virtual devices: 138 s "
          "beside the five other workers of the driver's command on the "
          "sandbox")
 def test_imagenet_example_resume(hvd, monkeypatch, tmp_path, capsys,

@@ -1,6 +1,10 @@
 """Pallas flash-attention tests (interpret mode off-TPU): outputs and
 gradients must match the dense oracle exactly, and the TransformerLM
-flash path must match the full-attention twin."""
+flash path must match the full-attention twin.  The backward kernels' own
+tests are ``test_flash_backward.py`` (the per-head pair off the lane width,
+the one fused kernel a KV group) and ``test_flash_sub_tiles.py`` (the pair
+grouped over heads, its diagonal sub-tiles): one file until PR 56, three
+since, because a file is one worker's job under ``--dist loadfile``."""
 
 import jax
 import jax.numpy as jnp
@@ -270,88 +274,6 @@ class TestAutoBlock:
                                        rtol=1e-4, atol=1e-4)
 
 
-class TestPallasBackward:
-    """D off the lane width: the merged layout's grid forward and
-    per-head pair."""
-
-    @pytest.mark.parametrize("causal", [True, False])
-    def test_grads_match_dense_oracle(self, hvd, causal):
-        q, k, v = make_qkv(jax.random.PRNGKey(11), 2, 64, 2, 16)
-
-        def loss(q, k, v):
-            out = flash_attention(q, k, v, causal=causal, block_q=16,
-                                  block_k=16, interpret=True)
-            return (out ** 2).sum()
-
-        def loss_full(q, k, v):
-            return (full_attention(q, k, v, causal=causal) ** 2).sum()
-
-        got = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
-        want = jax.grad(loss_full, argnums=(0, 1, 2))(q, k, v)
-        for g, w in zip(got, want):
-            np.testing.assert_allclose(np.asarray(g), np.asarray(w),
-                                       rtol=1e-5, atol=1e-5)
-
-    def test_bf16_grads(self, hvd):
-        q, k, v = make_qkv(jax.random.PRNGKey(12), 1, 64, 2, 16,
-                           jnp.bfloat16)
-
-        def loss(q, k, v):
-            out = flash_attention(q, k, v, causal=True, block_q=32,
-                                  block_k=32, interpret=True)
-            return (out.astype(jnp.float32) ** 2).sum()
-
-        def loss_full(q, k, v):
-            return (full_attention(q, k, v, causal=True)
-                    .astype(jnp.float32) ** 2).sum()
-
-        got = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
-        want = jax.grad(loss_full, argnums=(0, 1, 2))(q, k, v)
-        for g, w in zip(got, want):
-            np.testing.assert_allclose(
-                np.asarray(g, np.float32), np.asarray(w, np.float32),
-                rtol=1e-2, atol=1e-2)
-
-    def test_uneven_blocks_pallas_bwd(self, hvd):
-        q, k, v = make_qkv(jax.random.PRNGKey(13), 1, 48, 2, 8)
-
-        def loss(q, k, v):
-            return (flash_attention(q, k, v, causal=True, block_q=16,
-                                    block_k=8, interpret=True) ** 2).sum()
-
-        def loss_full(q, k, v):
-            return (full_attention(q, k, v, causal=True) ** 2).sum()
-
-        got = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
-        want = jax.grad(loss_full, argnums=(0, 1, 2))(q, k, v)
-        for g, w in zip(got, want):
-            np.testing.assert_allclose(np.asarray(g), np.asarray(w),
-                                       rtol=1e-5, atol=1e-5)
-
-    def test_padded_seq_len_grads(self, hvd):
-        """Zero-padded inputs with seq_len masking: the backward pair
-        must mask the padding tail."""
-        T, T_pad = 40, 64
-        q, k, v = make_qkv(jax.random.PRNGKey(14), 1, T, 2, 8)
-        pad = [(0, 0), (0, T_pad - T), (0, 0), (0, 0)]
-
-        def loss(q, k, v):
-            out = flash_attention(
-                jnp.pad(q, pad), jnp.pad(k, pad), jnp.pad(v, pad),
-                causal=True, block_q=16, block_k=16, interpret=True,
-                seq_len=T)
-            return (out[:, :T] ** 2).sum()
-
-        def loss_full(q, k, v):
-            return (full_attention(q, k, v, causal=True) ** 2).sum()
-
-        got = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
-        want = jax.grad(loss_full, argnums=(0, 1, 2))(q, k, v)
-        for g, w in zip(got, want):
-            np.testing.assert_allclose(np.asarray(g), np.asarray(w),
-                                       rtol=1e-5, atol=1e-5)
-
-
 class TestFlashUnderShardMap:
     def test_flash_model_trains_under_make_train_step(self, hvd):
         """attn='flash' (qkv-proj fused path) inside the multi-device
@@ -392,316 +314,6 @@ class TestFlashUnderShardMap:
             params, _, opt_state, loss = step(params, {}, opt_state, toks)
             losses.append(float(np.asarray(loss)))
         assert losses[-1] < losses[0]
-
-
-def packed_problem(seed, B, T, H, D, qkv, seq_len=None, block=8,
-                   causal=True):
-    """Operands of the packed backward drivers as the custom-VJP rules
-    hand them over: (q, k, v, o, lse, do), head bases."""
-    from horovod_tpu.ops import flash_attention as fa
-
-    scale = 1.0 / D ** 0.5
-    ks = jax.random.split(jax.random.PRNGKey(seed), 2)
-    if qkv:
-        base = (0, H, 2 * H)
-        q = k = v = jax.random.normal(ks[0], (B, T, 3 * H * D))
-    else:
-        base = (0, 0, 0)
-        q, k, v = (x.reshape(B, T, H * D)
-                   for x in make_qkv(ks[0], B, T, H, D))
-    plan = fa._Plan("grid", 0, 0, "per_head", 0, 0, 0.0)
-    o, lse = fa._fwd_packed(q, k, v, H, D, plan, scale=scale, causal=causal,
-                            block_q=block, block_k=block, interpret=True,
-                            seq_len=seq_len, head_base=base)
-    do = jax.random.normal(ks[1], o.shape)
-    return (q, k, v, o, lse, do), base, plan, scale
-
-
-class TestHeadGroupBwd:
-    """The pair blocked over adjacent heads (contiguous group*D-wide
-    tiles) against the per-head pair, which the classes above hold to the
-    dense oracle: per-head math is identical, so the gradients must match
-    EXACTLY.  _plan selects the grouped pair only at 1024² blocks, which
-    no interpreted test can afford, so the driver is called directly at
-    the small shapes."""
-
-    @pytest.mark.parametrize("qkv,seq_len,H,group", [
-        (False, None, 4, 2), (False, 24, 2, 2), (True, None, 4, 2),
-        (True, 24, 4, 2), (True, None, 4, 4)],
-        ids=["qkv_apart", "qkv_apart-padded", "fused_qkv",
-             "fused_qkv-padded", "fused_qkv-group4"])
-    def test_grouped_matches_per_head_exactly(self, hvd, qkv, seq_len, H,
-                                              group):
-        from horovod_tpu.ops import flash_attention as fa
-
-        ops, base, plan, scale = packed_problem(41, 2, 32, H, 128, qkv,
-                                                seq_len)
-        kw = dict(scale=scale, causal=True, block_q=8, block_k=8,
-                  interpret=True, seq_len=seq_len, head_base=base)
-        want = fa._bwd_pallas_packed(*ops, H, 128, plan, **kw)
-        got = fa._bwd_pallas_packed_grouped(*ops, H, 128, group, **kw)
-        for g, w in zip(got, want):
-            np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
-
-    @pytest.mark.parametrize("seq_len", [None, 24], ids=["whole", "padded"])
-    def test_grouped_matches_oracle(self, hvd, seq_len):
-        """And against ``full_attention`` itself."""
-        from horovod_tpu.ops import flash_attention as fa
-
-        B, T, H, D = 1, 32, 2, 128
-        (q, k, v, o, lse, _), base, _, scale = packed_problem(
-            45, B, T, H, D, False, seq_len)
-        n = seq_len or T
-
-        def loss_full(q, k, v):
-            return (full_attention(*(x.reshape(B, T, H, D)[:, :n]
-                                     for x in (q, k, v)),
-                                   causal=True) ** 2).sum()
-
-        want = jax.grad(loss_full, argnums=(0, 1, 2))(q, k, v)
-        valid = (jnp.arange(T) < n)[None, :, None]
-        do = jnp.where(valid, 2 * o, 0.0)      # d(sum o^2) on real rows
-        got = fa._bwd_pallas_packed_grouped(
-            q, k, v, o, lse, do, H, D, 2, scale=scale, causal=True,
-            block_q=8, block_k=8, interpret=True, seq_len=seq_len,
-            head_base=base)
-        for g, w in zip(got, want):
-            np.testing.assert_allclose(np.asarray(g), np.asarray(w),
-                                       rtol=2e-4, atol=2e-4)
-
-
-class TestGroupedKvFusedBackward:
-    """A call with grouped KV heads at lane-aligned heads and no map: the
-    backward is ONE kernel a KV group (``flash_group_bwd``: the selected
-    attention's fused kernel without its map) wherever ``_plan`` can see
-    that a KV head's ``dK`` and ``dV`` fit VMEM; the per-head pair
-    elsewhere.  Both against ``full_attention``'s gradients, and against
-    each other."""
-
-    B, T, D, BLOCK = 1, 64, 128, 16
-
-    def _problem(self, kv_rep, hkv=2):
-        ks = jax.random.split(jax.random.PRNGKey(61 + kv_rep), 3)
-        return tuple(
-            jax.random.normal(key, (self.B, self.T, h, self.D))
-            for key, h in zip(ks, (hkv * kv_rep, hkv, hkv)))
-
-    def _grads(self, q, k, v, causal, seq_len):
-        def loss(q, k, v):
-            out = flash_attention(q, k, v, causal=causal, block_q=self.BLOCK,
-                                  block_k=self.BLOCK, interpret=True,
-                                  seq_len=seq_len)
-            return (out[:, :seq_len] ** 2).sum()
-
-        return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
-
-    @staticmethod
-    def _equations(jaxpr):
-        """Every equation of a jaxpr, nested jaxprs included."""
-        for eqn in jaxpr.eqns:
-            yield eqn
-            for value in eqn.params.values():
-                for v in value if isinstance(value, (list, tuple)) else [
-                        value]:
-                    v = getattr(v, "jaxpr", v)
-                    if hasattr(v, "eqns"):
-                        yield from TestGroupedKvFusedBackward._equations(v)
-
-    def _kernels(self, jaxpr):
-        """``{name: kernel jaxpr}`` of every ``pallas_call`` of a jaxpr."""
-        return {eqn.params["name"]
-                or eqn.params["jaxpr"].debug_info.func_name:
-                eqn.params["jaxpr"] for eqn in self._equations(jaxpr)
-                if eqn.primitive.name == "pallas_call"}
-
-    def _backward_kernels(self, q, k, v, block=16):
-        """The names of the backward's kernels (the forward's left out)."""
-        jaxpr = jax.make_jaxpr(jax.grad(lambda q, k, v: flash_attention(
-            q, k, v, block_q=block, block_k=block, interpret=True).astype(
-                jnp.float32).sum(), argnums=(0, 1, 2)))(q, k, v)
-        return {name: body for name, body in self._kernels(
-            jaxpr.jaxpr).items() if "fwd" not in name}
-
-    @pytest.mark.parametrize("seq_len", [None, 40], ids=["whole", "padded"])
-    @pytest.mark.parametrize("causal", [True, False],
-                             ids=["causal", "non_causal"])
-    @pytest.mark.parametrize("kv_rep,hkv", [(2, 2), (4, 2), (16, 1)],
-                             ids=["2Q_per_KV", "4Q_per_KV", "16Q_per_KV"])
-    def test_fused_matches_oracle(self, hvd, kv_rep, hkv, causal, seq_len):
-        q, k, v = self._problem(kv_rep, hkv)
-        n = seq_len or self.T
-
-        def loss_full(q, k, v):
-            k, v = (jnp.repeat(a[:, :n], kv_rep, axis=2) for a in (k, v))
-            return (full_attention(q[:, :n], k, v, causal=causal) ** 2).sum()
-
-        assert set(self._backward_kernels(q, k, v)) == {"flash_group_bwd"}
-        got = self._grads(q, k, v, causal, seq_len)
-        want = jax.grad(loss_full, argnums=(0, 1, 2))(q, k, v)
-        assert got[1].shape == got[2].shape == (self.B, self.T, hkv, self.D)
-        for g, w in zip(got, want):
-            np.testing.assert_allclose(np.asarray(g), np.asarray(w),
-                                       rtol=2e-4, atol=2e-4)
-
-    @pytest.mark.parametrize("seq_len", [None, 40], ids=["whole", "padded"])
-    @pytest.mark.parametrize("kv_rep,hkv", [(2, 2), (4, 2), (16, 1)],
-                             ids=["2Q_per_KV", "4Q_per_KV", "16Q_per_KV"])
-    def test_per_head_pair_agrees(self, hvd, monkeypatch, kv_rep, hkv,
-                                  seq_len):
-        """The same call where the device backs no budget above Mosaic's
-        default: the per-head pair, to float32 reassociation."""
-        from horovod_tpu.ops import _pallas
-
-        q, k, v = self._problem(kv_rep, hkv)
-        fused = self._grads(q, k, v, True, seq_len)
-        # (Every family's probe at once; the plan is asked outside any
-        # shared trace, so no cache holds the fused form.)
-        monkeypatch.setattr(_pallas, "vmem_headroom_ok", lambda: False)
-        assert set(self._backward_kernels(q, k, v)) == {"_dq_kernel",
-                                                        "_dkdv_kernel"}
-        pair = self._grads(q, k, v, True, seq_len)
-        for g, w in zip(fused, pair):
-            np.testing.assert_allclose(np.asarray(g), np.asarray(w),
-                                       rtol=2e-5, atol=2e-5)
-
-    def test_bf16_operands_f32_sums(self, hvd):
-        """The pair's precision: bfloat16 operands into every product,
-        float32 results, ``p`` and ``dS`` cast once a head."""
-        q, k, v = (a.astype(jnp.bfloat16) for a in self._problem(4))
-        kernel = self._backward_kernels(q, k, v, block=64)["flash_group_bwd"]
-        products = [eqn for eqn in self._equations(kernel)
-                    if eqn.primitive.name == "dot_general"]
-        # A masked and an unmasked body, each five products for each of the
-        # four heads of a group (the pair's two kernels form seven).
-        assert len(products) == 2 * 5 * 4
-        for eqn in products:
-            assert all(v_.aval.dtype == jnp.bfloat16 for v_ in eqn.invars)
-            assert eqn.outvars[0].aval.dtype == jnp.float32
-
-
-# The grouped pair with its diagonal block pairs cut into sub-tiles
-# (block, requested sub-tile, T, seq_len): 2, 4 and 8 sub-tiles a block
-# side, one and several blocks a row, and the padding's end inside a
-# sub-tile on the diagonal, inside an interior block, and on a block edge.
-SUB_TILE_CASES = {
-    "2_a_side-one_block": (32, 16, 32, None),
-    "4_a_side-one_block": (32, 8, 32, None),
-    "4_a_side-three_blocks": (32, 8, 96, None),
-    "8_a_side-two_blocks": (64, 8, 128, None),
-    "ends_in_diagonal_sub_tile": (32, 8, 96, 90),
-    "ends_in_interior_block": (32, 8, 96, 50),
-    "ends_on_block_edge": (32, 8, 96, 64),
-    # 16 does not divide 24: no sub-tile, the whole-block bodies.
-    "sub_tile_does_not_divide": (24, 16, 72, None),
-}
-
-
-class TestDiagonalSubTiles:
-    """Only products whose every element the causal mask sets to zero are
-    left out, so the gradients are those of the per-head pair and of
-    ``full_attention`` up to the order of the float32 sums."""
-
-    @pytest.mark.parametrize("case", sorted(SUB_TILE_CASES))
-    @pytest.mark.parametrize("qkv", [False, True],
-                             ids=["qkv_apart", "fused_qkv"])
-    def test_matches_per_head_and_oracle(self, hvd, case, qkv):
-        from horovod_tpu.ops import flash_attention as fa
-
-        block, want_sub, T, seq_len = SUB_TILE_CASES[case]
-        sub = fa._diag_sub(True, block, block, want_sub)
-        assert sub == (0 if "not_divide" in case else want_sub)
-        B, H, D = 1, 2, 128
-        ops, base, plan, scale = packed_problem(51, B, T, H, D, qkv,
-                                                seq_len, block=block)
-        kw = dict(scale=scale, causal=True, block_q=block, block_k=block,
-                  interpret=True, seq_len=seq_len, head_base=base)
-        per_head = fa._bwd_pallas_packed(*ops, H, D, plan, **kw)
-        whole = fa._bwd_pallas_packed_grouped(*ops, H, D, 2, **kw)
-        got = fa._bwd_pallas_packed_grouped(*ops, H, D, 2, sub=sub, **kw)
-        for g, w, p in zip(got, whole, per_head):
-            np.testing.assert_array_equal(np.asarray(w), np.asarray(p))
-            if sub:
-                np.testing.assert_allclose(np.asarray(g), np.asarray(w),
-                                           rtol=2e-5, atol=2e-5)
-            else:
-                np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
-        if qkv:
-            return
-        # Against the oracle: forward value, then dq, dk, dv of sum(o^2).
-        q, k, v, o, lse, _ = ops
-        n = seq_len or T
-
-        def heads(x):
-            return x.reshape(B, T, H, D)[:, :n]
-
-        dense = full_attention(heads(q), heads(k), heads(v), causal=True)
-        np.testing.assert_allclose(np.asarray(heads(o)), np.asarray(dense),
-                                   rtol=2e-5, atol=2e-5)
-        want = jax.grad(lambda q, k, v: (full_attention(
-            heads(q), heads(k), heads(v), causal=True) ** 2).sum(),
-            argnums=(0, 1, 2))(q, k, v)
-        valid = (jnp.arange(T) < n)[None, :, None]
-        got = fa._bwd_pallas_packed_grouped(
-            q, k, v, o, lse, jnp.where(valid, 2 * o, 0.0), H, D, 2, sub=sub,
-            **kw)
-        for g, w in zip(got, want):
-            np.testing.assert_allclose(np.asarray(g), np.asarray(w),
-                                       rtol=2e-4, atol=2e-4)
-
-    def test_non_causal_is_unchanged(self, hvd):
-        """No mask, no diagonal: ``_diag_sub`` answers 0 and the pair is
-        the per-head pair's, bit for bit."""
-        from horovod_tpu.ops import flash_attention as fa
-
-        assert fa._diag_sub(False, 32, 32, 8) == 0
-        ops, base, plan, scale = packed_problem(52, 1, 64, 2, 128, True,
-                                                block=32, causal=False)
-        kw = dict(scale=scale, causal=False, block_q=32, block_k=32,
-                  interpret=True, seq_len=None, head_base=base)
-        want = fa._bwd_pallas_packed(*ops, 2, 128, plan, **kw)
-        got = fa._bwd_pallas_packed_grouped(*ops, 2, 128, 2, sub=0, **kw)
-        for g, w in zip(got, want):
-            np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
-
-    @pytest.mark.parametrize("causal,block_q,block_k,sub,want", [
-        (True, 1024, 1024, 256, 256), (True, 1024, 1024, 512, 512),
-        (False, 1024, 1024, 256, 0),      # nothing is masked
-        (True, 1024, 512, 256, 0),        # the diagonal is not qi == kj
-        (True, 768, 768, 512, 0),         # 512 does not divide the block
-        (True, 256, 256, 256, 0)],        # one sub-tile is the block
-        ids=["cell", "sub_512", "non_causal", "oblong_blocks",
-             "does_not_divide", "one_sub_tile"])
-    def test_diag_sub_rule(self, causal, block_q, block_k, sub, want):
-        from horovod_tpu.ops import flash_attention as fa
-
-        assert fa._diag_sub(causal, block_q, block_k, sub) == want
-
-    def test_whole_model_through_the_sub_tile_pair(self, hvd, monkeypatch):
-        """The rules hand the plan's sub-tile to the pair: with the plan
-        steered to the grouped pair at a size the interpreter can afford,
-        ``jax.grad`` of ``flash_attention_qkv`` is the oracle's."""
-        from horovod_tpu.ops import flash_attention as fa
-
-        plan = fa._plan
-        monkeypatch.setattr(fa, "_plan", lambda **seen: plan(**seen)._replace(
-            bwd="grouped", bwd_sub=8))
-        B, T, H, D = 1, 64, 2, 128
-        qkv = jax.random.normal(jax.random.PRNGKey(53), (B, T, 3 * H * D))
-
-        def loss(qkv):
-            return (fa.flash_attention_qkv(qkv, H, causal=True, block_q=32,
-                                           block_k=32, interpret=True)
-                    ** 2).sum()
-
-        def loss_full(qkv):
-            q, k, v = (x.reshape(B, T, H, D)
-                       for x in jnp.split(qkv, 3, axis=-1))
-            return (full_attention(q, k, v, causal=True) ** 2).sum()
-
-        jax.clear_caches()        # the steered plan must be asked
-        np.testing.assert_allclose(np.asarray(jax.grad(loss)(qkv)),
-                                   np.asarray(jax.grad(loss_full)(qkv)),
-                                   rtol=2e-4, atol=2e-4)
 
 
 # One row of the selection table: what the op observes, and what _plan

@@ -201,9 +201,9 @@ def test_the_tied_table_s_gradient_is_the_gather_s_plus_the_head_s():
                                   tokens[:, 1:].reshape(-1)).mean()
 
     table = params["tok_emb"]["embedding"]
-    d_gather, d_head = jax.grad(two_uses, argnums=(0, 1))(table, table,
-                                                          params)
-    d_tied = jax.grad(tied)(params)["tok_emb"]["embedding"]
+    d_gather, d_head = jax.jit(jax.grad(two_uses, argnums=(0, 1)))(
+        table, table, params)
+    d_tied = jax.jit(jax.grad(tied))(params)["tok_emb"]["embedding"]
     assert rel(d_tied, d_gather + d_head) <= 1e-6
     assert rel(d_gather, d_tied) > 0.1 and rel(d_head, d_tied) > 0.1
 
@@ -241,7 +241,8 @@ def compared():
     jax.clear_caches()
     try:
         cfg = family_cfg()
-        params, aux = family.init(cfg, jax.random.PRNGKey(11))
+        params, aux = jax.jit(lambda k: family.init(cfg, k))(
+            jax.random.PRNGKey(11))
         tokens = jnp.asarray(family.host_batch(
             cfg, np.random.default_rng(5), 1))
         noted = {}
@@ -251,8 +252,10 @@ def compared():
                 ("bfloat16", family.loss_fn(family_cfg("bfloat16"))),
                 ("reference", lambda p, a, t: (
                     family.reference_loss(cfg)(p, a, t), a))):
-            (loss, _), grads = jax.value_and_grad(fn, has_aux=True)(
-                params, aux, tokens)
+            # ONE program each: differentiated eagerly, op by op, the
+            # three cost this fixture 94 s (PR 56).
+            (loss, _), grads = jax.jit(jax.value_and_grad(
+                fn, has_aux=True))(params, aux, tokens)
             out[name] = (float(loss), grads)
         out["noted"] = noted
         return out
@@ -318,8 +321,8 @@ def test_a_changed_multiplier_fails_the_comparison(compared, key, value):
     12 -> 11, 8 -> 7, 1/64 -> 1/8) reads outside both bounds: the program
     has the multipliers where the reference has them."""
     cfg = family_cfg(**{key: value})
-    loss, grads = jax.value_and_grad(
-        lambda p: family.reference_loss(cfg)(p, {}, compared["tokens"]))(
+    loss, grads = jax.jit(jax.value_and_grad(
+        lambda p: family.reference_loss(cfg)(p, {}, compared["tokens"])))(
             compared["params"])
     want_loss, want = compared["reference"]
     assert abs(float(loss) - want_loss) > LOSS_TOL * abs(want_loss)
@@ -330,7 +333,7 @@ def test_reference_dual_form_equals_its_recurrence():
     """The reference's mixer two ways, in float32: the (T, T) dual form
     the comparison uses and the recurrence written as the recurrence."""
     cfg = family_cfg(sequence_length=64)
-    params, _ = family.init(cfg, jax.random.PRNGKey(2))
+    params, _ = jax.jit(lambda k: family.init(cfg, k))(jax.random.PRNGKey(2))
     u = jax.random.normal(jax.random.PRNGKey(3), (64, 128))
     p = params["layer_0"]["ssm"]
     with jax.default_matmul_precision("highest"):
@@ -346,7 +349,7 @@ def test_counters_reach_the_registry_through_make_train_step():
     import optax
 
     cfg = {**published(), **family.TINY}
-    params, aux = family.init(cfg, jax.random.PRNGKey(0))
+    params, aux = jax.jit(lambda k: family.init(cfg, k))(jax.random.PRNGKey(0))
     tokens = jnp.asarray(family.host_batch(cfg, np.random.default_rng(0), 2))
     tx = optax.sgd(0.1)
     step = make_train_step(family.loss_fn(cfg), tx, Mesh(

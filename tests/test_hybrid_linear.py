@@ -29,6 +29,7 @@ from horovod_tpu.ops.ssd import (
 from horovod_tpu.parallel.moe import DroplessMoE, _SharedExpert
 from horovod_tpu.parallel.ring_attention import full_attention
 
+from _once import out_and_grads
 from test_gated_delta import _equations
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -56,7 +57,8 @@ ONE_PERIOD = dict(num_hidden_layers=4, layer_types=[
 
 
 def hybrid_inputs(cfg, n=2, seed=5):
-    params, aux = olmo_hybrid_lm.init(cfg, jax.random.PRNGKey(seed))
+    params, aux = jax.jit(lambda k: olmo_hybrid_lm.init(cfg, k))(
+        jax.random.PRNGKey(seed))
     tokens = olmo_hybrid_lm.host_batch(cfg, np.random.default_rng(seed), n)
     return params, aux, tokens
 
@@ -91,16 +93,14 @@ def test_delta_mixer_module_equals_the_reference_recurrence(T, chunk, neg):
     def theirs(p, x):
         return jax.vmap(lambda s: reference(p, s))(x)
 
+    weight = jnp.sin(jnp.arange(x.size, dtype=jnp.float32)).reshape(x.shape)
     with jax.default_matmul_precision("highest"):
-        got, want = ours(params, x), theirs(params, x)
-        assert float(jnp.abs(got - want).max()) <= 1e-5 * float(
-            jnp.abs(want).max())
-        weight = jnp.sin(jnp.arange(want.size, dtype=jnp.float32)).reshape(
-            want.shape)
-        g = jax.grad(lambda p, x: (ours(p, x) * weight).sum(), (0, 1))(
-            params, x)
-        w = jax.grad(lambda p, x: (theirs(p, x) * weight).sum(), (0, 1))(
-            params, x)
+        (got, g), (want, w) = (
+            out_and_grads(f, lambda y: (y * weight).sum(), params, x,
+                          jit=True) for f in (ours, theirs))
+    assert got.shape == want.shape == x.shape
+    assert float(jnp.abs(got - want).max()) <= 1e-5 * float(
+        jnp.abs(want).max())
     errors = {jax.tree_util.keystr(path): rel(a, b) for (path, a), b in zip(
         jax.tree_util.tree_leaves_with_path(g), jax.tree.leaves(w))}
     assert len(errors) == 12 and max(errors.values()) <= 2e-4, errors
@@ -132,11 +132,12 @@ def test_hybrid_model_against_reference_loss(compute_dtype, layers,
     params, aux, tokens = hybrid_inputs(cfg)
     loss_fn = olmo_hybrid_lm.loss_fn(cfg)
     ref_fn = olmo_hybrid_lm.reference_loss(cfg)
+    # Each side ONE program, not differentiated eagerly op by op (PR 56).
     with jax.default_matmul_precision("highest"):
-        got, got_g = jax.value_and_grad(
-            lambda p: loss_fn(p, aux, tokens)[0])(params)
-    want, want_g = jax.value_and_grad(
-        lambda p: ref_fn(p, aux, tokens))(params)
+        got, got_g = jax.jit(jax.value_and_grad(
+            lambda p: loss_fn(p, aux, tokens)[0]))(params)
+    want, want_g = jax.jit(jax.value_and_grad(
+        lambda p: ref_fn(p, aux, tokens)))(params)
     assert abs(float(got) - float(want)) / float(want) <= loss_tol
     flat_got = dict(jax.tree_util.tree_leaves_with_path(got_g))
     flat_want = dict(jax.tree_util.tree_leaves_with_path(want_g))
@@ -155,7 +156,8 @@ def test_hybrid_reference_in_bfloat16_is_another_number():
     in bfloat16 is not the reference."""
     cfg = hybrid_cfg()
     params, aux, tokens = hybrid_inputs(cfg)
-    want = float(olmo_hybrid_lm.reference_loss(cfg)(params, aux, tokens))
+    want = float(jax.jit(olmo_hybrid_lm.reference_loss(cfg))(
+        params, aux, tokens))
     low = float(olmo_hybrid_lm.reference_loss(cfg, dtype="bfloat16")(
         params, aux, tokens))
     assert abs(low - want) > 1e-4 * want
@@ -224,7 +226,8 @@ def test_tiny_hybrid_trains_through_make_train_step(hvd):
     tokens = olmo_hybrid_lm.host_batch(cfg, np.random.default_rng(7), 8)
     tx = olmo_hybrid_lm.optimizer(cfg)
     opt_state = tx.init(params)
-    want = float(olmo_hybrid_lm.reference_loss(cfg)(params, aux, tokens))
+    want = float(jax.jit(olmo_hybrid_lm.reference_loss(cfg))(
+        params, aux, tokens))
     step = make_train_step(olmo_hybrid_lm.loss_fn(cfg), tx, hvd.ranks_mesh())
     names = ("lin.delta_chunks", "lin.state_bytes", "ssm.scan_chunks",
              "moe.assignments")

@@ -30,6 +30,7 @@ from horovod_tpu.ops.ssd import (
 from horovod_tpu.parallel.moe import DroplessMoE, _SharedExpert
 from horovod_tpu.parallel.ring_attention import full_attention
 
+from _once import out_and_grads
 from test_gated_delta import _equations
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -62,19 +63,16 @@ def test_chunked_scan_equals_the_recurrence(T, chunk):
     every gradient (x, dt, A, B, C, D) to 1e-4 of its norm (observed
     6e-5 / 14 and 8e-6)."""
     args = scan_inputs(T)
+    weight = jnp.cos(jnp.arange(args[0].size, dtype=jnp.float32)).reshape(
+        args[0].shape)
     with jax.default_matmul_precision("highest"):
-        got = ssd_scan(*args, chunk=chunk)
-        want = ssd_recurrence(*args)
-        assert got.shape == want.shape == args[0].shape
-        assert float(jnp.abs(got - want).max()) <= 1e-5 * float(
-            jnp.abs(want).max())
-        weight = jnp.cos(jnp.arange(want.size, dtype=jnp.float32)).reshape(
-            want.shape)
-        grads = [jax.grad(lambda *a: (f(*a) * weight).sum(),
-                          argnums=tuple(range(6)))(*args)
-                 for f in (lambda *a: ssd_scan(*a, chunk=chunk),
-                           ssd_recurrence)]
-    for g, w in zip(*grads):
+        (got, grads), (want, ref) = (
+            out_and_grads(f, lambda y: (y * weight).sum(), *args)
+            for f in (lambda *a: ssd_scan(*a, chunk=chunk), ssd_recurrence))
+    assert got.shape == want.shape == args[0].shape
+    assert float(jnp.abs(got - want).max()) <= 1e-5 * float(
+        jnp.abs(want).max())
+    for g, w in zip(grads, ref):
         assert rel(g, w) <= 1e-4
 
 
@@ -142,19 +140,18 @@ def test_scan_kernels_equal_their_oracles(case, dtype):
     weight = jnp.cos(jnp.arange(args[0].size, dtype=jnp.float32)).reshape(
         args[0].shape)
 
-    def loss(f):
-        return lambda *a: (f(*a).astype(jnp.float32) * weight).sum()
+    def weighted(y):
+        return (y.astype(jnp.float32) * weight).sum()
 
     with jax.default_matmul_precision("highest"):
-        got = ssd_scan(*args, chunk=128, interpret=True)
-        want = oracle(*args)
-        assert got.shape == want.shape and got.dtype == args[0].dtype
-        assert rel(got.astype(jnp.float32),
-                   want.astype(jnp.float32)) <= value_tol
-        grads = [jax.grad(loss(f), argnums=tuple(range(6)))(*args)
-                 for f in (lambda *a: ssd_scan(*a, chunk=128,
-                                               interpret=True), oracle)]
-    for i, (g, w) in enumerate(zip(*grads)):
+        (got, grads), (want, ref) = (
+            out_and_grads(f, weighted, *args)
+            for f in (lambda *a: ssd_scan(*a, chunk=128, interpret=True),
+                      oracle))
+    assert got.shape == want.shape and got.dtype == args[0].dtype
+    assert rel(got.astype(jnp.float32),
+               want.astype(jnp.float32)) <= value_tol
+    for i, (g, w) in enumerate(zip(grads, ref)):
         assert g.dtype == w.dtype and g.shape == w.shape
         assert rel(g.astype(jnp.float32), w.astype(jnp.float32)) <= (
             a_tol if i == 2 else grad_tol), "x dt A B C D".split()[i]
@@ -184,9 +181,10 @@ def test_packed_entry_reads_x_B_C_out_of_one_array():
                                   C.reshape(b, T, G, N_), D).reshape(b, T, -1)
 
         with jax.default_matmul_precision("highest"):
-            assert rel(ours(packed), split(packed)) <= 1e-5
-            got = jax.grad(lambda p: (ours(p) ** 2).sum())(packed)
-            want = jax.grad(lambda p: (split(p) ** 2).sum())(packed)
+            (out, (got,)), (ref, (want,)) = (
+                out_and_grads(f, lambda y: (y ** 2).sum(), packed)
+                for f in (ours, split))
+        assert rel(out, ref) <= 1e-5
         assert rel(got, want) <= 1e-4
     with pytest.raises(ValueError, match="groups"):
         ssd_scan_packed(packed, dt, A, D, heads=H, groups=3, state=N_)
@@ -299,16 +297,14 @@ def test_mixer_module_equals_the_reference_recurrence(T, chunk):
     def theirs(p, u):
         return jax.vmap(lambda s: reference(p, s))(u)
 
+    weight = jnp.sin(jnp.arange(u.size, dtype=jnp.float32)).reshape(u.shape)
     with jax.default_matmul_precision("highest"):
-        got, want = ours(params, u), theirs(params, u)
-        assert float(jnp.abs(got - want).max()) <= 1e-5 * float(
-            jnp.abs(want).max())
-        weight = jnp.sin(jnp.arange(want.size, dtype=jnp.float32)).reshape(
-            want.shape)
-        g = jax.grad(lambda p, u: (ours(p, u) * weight).sum(), (0, 1))(
-            params, u)
-        w = jax.grad(lambda p, u: (theirs(p, u) * weight).sum(), (0, 1))(
-            params, u)
+        (got, g), (want, w) = (
+            out_and_grads(f, lambda y: (y * weight).sum(), params, u,
+                          jit=True) for f in (ours, theirs))
+    assert got.shape == want.shape == u.shape
+    assert float(jnp.abs(got - want).max()) <= 1e-5 * float(
+        jnp.abs(want).max())
     errors = {jax.tree_util.keystr(path): rel(a, b) for (path, a), b in zip(
         jax.tree_util.tree_leaves_with_path(g), jax.tree.leaves(w))}
     assert max(errors.values()) <= 2e-4, errors
@@ -325,9 +321,9 @@ def test_the_two_reference_forms_agree():
     dual = nemotron_h_lm.reference_mixer(cfg, "dual")
     step = nemotron_h_lm.reference_mixer(cfg, "recurrence")
     with jax.default_matmul_precision("highest"):
-        a, b = dual(params, u[0]), step(params, u[0])
-        assert float(jnp.abs(a - b).max()) <= 1e-5 * float(jnp.abs(b).max())
-        ga = jax.grad(lambda p: (dual(p, u[0]) ** 2).sum())(params)
-        gb = jax.grad(lambda p: (step(p, u[0]) ** 2).sum())(params)
+        (a, (ga,)), (b, (gb,)) = (
+            out_and_grads(lambda p: f(p, u[0]), lambda y: (y ** 2).sum(),
+                          params, jit=True) for f in (dual, step))
+    assert float(jnp.abs(a - b).max()) <= 1e-5 * float(jnp.abs(b).max())
     for x, y in zip(jax.tree.leaves(ga), jax.tree.leaves(gb)):
         assert rel(x, y) <= 1e-4

@@ -39,7 +39,8 @@ def family_cfg(compute_dtype="float32", **override):
 
 
 def model_inputs(cfg, n=2, seed=5):
-    params, aux = keye_vl2_lm.init(cfg, jax.random.PRNGKey(seed))
+    params, aux = jax.jit(lambda k: keye_vl2_lm.init(cfg, k))(
+        jax.random.PRNGKey(seed))
     # Norm scales off 1, so that a scale left out shows.
     keys = iter(jax.random.split(jax.random.PRNGKey(seed + 1), 64))
     params = jax.tree_util.tree_map_with_path(

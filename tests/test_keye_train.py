@@ -111,7 +111,8 @@ def test_tiny_keye_trains_through_make_train_step(hvd, monkeypatch):
     tokens = keye_vl2_lm.host_batch(cfg, np.random.default_rng(7), 8)
     tx = keye_vl2_lm.optimizer(cfg)
     opt_state = tx.init(params)
-    want = float(keye_vl2_lm.reference_loss(cfg)(params, aux, tokens))
+    want = float(jax.jit(keye_vl2_lm.reference_loss(cfg))(
+        params, aux, tokens))
     step = make_train_step(keye_vl2_lm.loss_fn(cfg), tx, hvd.ranks_mesh())
     names = ("attn.causal_pairs", "attn.selected_pairs", "attn.index_flops",
              "attn.select_bytes", "attn.select_tile_fetches",
@@ -154,8 +155,9 @@ def test_what_the_layers_sow_and_the_scopes_they_trace_under():
     cfg = family_cfg("float32")
     params, aux, tokens = model_inputs(cfg)
     model = keye_vl2_lm._model(cfg)
-    _, state = model.apply({"params": params}, tokens[:, :-1],
-                           return_hidden=True, mutable=["intermediates"])
+    _, state = jax.jit(lambda p, ids: model.apply(
+        {"params": p}, ids, return_hidden=True, mutable=["intermediates"]))(
+            params, tokens[:, :-1])
     sown = state["intermediates"]["layer_2"]["attn"]
     assert float(sown["selected_per_query"][0]) == sum(
         min(t + 1, 16) for t in range(64)) / 64

@@ -50,8 +50,9 @@ def test_vgg16_canonical_shape():
 
 @pytest.mark.parametrize("model_cls,size", [
     pytest.param(InceptionV3, 75, marks=pytest.mark.time_limit(
-        600, "compiles InceptionV3 for 8 virtual devices: 117 s beside the "
-             "five other workers of the driver's command on the sandbox")),
+        600, "compiles InceptionV3 for 8 virtual devices: 71 s beside the "
+             "five other workers of the driver's command on the sandbox "
+             "(117 s until PR 56 made its init one program)")),
     (VGG16, 32)])
 def test_benchmark_models_train_data_parallel(hvd, model_cls, size):
     """One real DP train step at reduced resolution: finite falling loss,
@@ -62,7 +63,9 @@ def test_benchmark_models_train_data_parallel(hvd, model_cls, size):
     rng = jax.random.PRNGKey(0)
     images = jax.random.normal(rng, (2 * n, size, size, 3), jnp.float32)
     labels = jnp.tile(jnp.arange(2), (n,)).astype(jnp.int32)
-    variables = model.init(rng, images[:1], train=True)
+    # (ONE program: an eager ``init`` compiles every layer's ops one by one.)
+    variables = jax.jit(lambda k, x: model.init(k, x, train=True))(
+        rng, images[:1])
     params = variables["params"]
     batch_stats = variables.get("batch_stats", {})
     has_bn = bool(batch_stats)

@@ -8,6 +8,7 @@ text the parent commit lowered them to.  The reference comparisons are
 ``tests/test_nemotron3_stack.py``'s, the shares ``test_nemotron3_shares.py``'s.
 """
 
+import functools
 import hashlib
 
 import jax
@@ -83,7 +84,10 @@ def test_without_a_latent_and_a_prediction_module_the_stacks_lower_as_they_did(
     model = build()
     assert model.mtp is None and not dict(model.moe or {}).get("latent")
     tokens = jnp.arange(2 * 16, dtype=jnp.int32).reshape(2, 16) % 64
-    params = model.init(jax.random.PRNGKey(0), tokens)["params"]
+    # A lowering reads the parameters' shapes alone: none is drawn (an eager
+    # ``init`` runs the stack op by op, 20 to 39 s a case; PR 56).
+    params = jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), tokens)["params"])
 
     def loss(p):
         return model.apply({"params": p}, tokens,
@@ -159,6 +163,30 @@ def test_the_refusals_name_the_new_fields():
         tiny(tie_head=True).init(key, tokens)
 
 
+LATENT_LAYER = dict(num_experts=8, hidden=16, top_k=3, router="sigmoid",
+                    renormalize=True, gate_scale=5.0, activation="relu2",
+                    shared_hidden=32, latent=8, held=(2, 2), dtype=F32)
+
+
+@functools.cache
+def latent_layer_at_its_own_window():
+    """The layer, its parameters, its input, and its value and gradients
+    under the library's own window — what every case below compares with,
+    made once (the same arrays a case: 18 s each before PR 56)."""
+    from horovod_tpu.parallel import moe
+
+    x = jax.random.normal(jax.random.PRNGKey(0), (2, 24, 12), F32)
+    layer = DroplessMoE(**LATENT_LAYER)
+    params = layer.init(jax.random.PRNGKey(1), x)
+    # 48 tokens x top-3 x 2 of 8 held = 36 rows a uniform load.
+    plan = moe._window_plan(assignments=144, held=2, routed=8, row_bytes=32,
+                            expert_bytes=2 * 2 * 8 * 16 * 4)
+    assert plan == moe.WindowPlan(40, 4, 36)
+    want = jax.value_and_grad(
+        lambda p: (layer.apply(p, x)[0] ** 2).sum())(params)
+    return layer, params, x, want
+
+
 @pytest.mark.parametrize("rows", [16, 24, 144])
 def test_a_latent_layer_is_the_same_at_every_window_of_the_held_share(
         rows, monkeypatch):
@@ -172,22 +200,12 @@ def test_a_latent_layer_is_the_same_at_every_window_of_the_held_share(
     rows at the LATENT's width."""
     from horovod_tpu.parallel import moe
 
-    fields = dict(num_experts=8, hidden=16, top_k=3, router="sigmoid",
-                  renormalize=True, gate_scale=5.0, activation="relu2",
-                  shared_hidden=32, latent=8, held=(2, 2), dtype=F32)
-    x = jax.random.normal(jax.random.PRNGKey(0), (2, 24, 12), F32)
-    layer = DroplessMoE(**fields)
-    params = layer.init(jax.random.PRNGKey(1), x)
+    layer, params, x, want = latent_layer_at_its_own_window()
 
     def value_and_grads():
         return jax.value_and_grad(
             lambda p: (layer.apply(p, x)[0] ** 2).sum())(params)
 
-    # 48 tokens x top-3 x 2 of 8 held = 36 rows a uniform load.
-    plan = moe._window_plan(assignments=144, held=2, routed=8, row_bytes=32,
-                            expert_bytes=2 * 2 * 8 * 16 * 4)
-    assert plan == moe.WindowPlan(40, 4, 36)
-    want = value_and_grads()
     monkeypatch.setattr(
         moe, "_window_plan",
         lambda **shapes: moe.WindowPlan(rows, -(-144 // rows), 36))

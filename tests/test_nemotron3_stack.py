@@ -127,7 +127,8 @@ def compared():
     program in bfloat16 and of the reference, on one seeded batch; the
     kernels interpreted."""
     cfg = family_cfg()
-    params, aux = family.init(cfg, jax.random.PRNGKey(11))
+    params, aux = jax.jit(lambda k: family.init(cfg, k))(
+        jax.random.PRNGKey(11))
     tokens = jnp.asarray(family.host_batch(cfg, np.random.default_rng(5), 1))
     noted = {}
     out = {"cfg": cfg, "params": params, "tokens": tokens}
@@ -231,7 +232,7 @@ def test_the_reference_takes_the_program_s_choice_inside_the_margin_only():
     it is given; with a margin of 1 it takes whatever it is given: the held
     experts for every token are another loss."""
     cfg = family_cfg(sequence_length=32)
-    params, _ = family.init(cfg, jax.random.PRNGKey(3))
+    params, _ = jax.jit(lambda k: family.init(cfg, k))(jax.random.PRNGKey(3))
     tokens = jnp.asarray(family.host_batch(cfg, np.random.default_rng(1), 1))
     given = family.reference_given_choices(cfg)
     own = family.program_expert_choices(cfg, params, tokens)

@@ -112,7 +112,7 @@ def test_model_against_reference_loss(loss_tol=1e-5, grad_tol=2e-3):
     four rules (in bfloat16 the rehearsal compares them:
     ``benchmark/tests/test_rehearse.py``)."""
     cfg = family_cfg()
-    params, aux = family.init(cfg, jax.random.PRNGKey(0))
+    params, aux = jax.jit(lambda k: family.init(cfg, k))(jax.random.PRNGKey(0))
     batch = family.host_batch(cfg, np.random.default_rng(1), 2)
     assert batch["masked"].any() and not batch["masked"].all()
     loss_fn, ref_fn = family.loss_fn(cfg), family.reference_loss(cfg)
@@ -136,7 +136,7 @@ def test_model_against_reference_loss(loss_tol=1e-5, grad_tol=2e-3):
 
 def test_nothing_masked_is_no_loss_and_no_nan():
     cfg = family_cfg()
-    params, aux = family.init(cfg, jax.random.PRNGKey(0))
+    params, aux = jax.jit(lambda k: family.init(cfg, k))(jax.random.PRNGKey(0))
     batch = family.host_batch(cfg, np.random.default_rng(1), 2)
     batch = {"tokens": batch["tokens"],
              "masked": np.zeros_like(batch["masked"]),
@@ -186,7 +186,7 @@ def test_sdarlm_is_the_published_stack_and_counts_its_rows():
     assert m.max_len == 32768 and m.norm_eps == 1e-6
 
     cfg = family_cfg()
-    params, aux = family.init(cfg, jax.random.PRNGKey(0))
+    params, aux = jax.jit(lambda k: family.init(cfg, k))(jax.random.PRNGKey(0))
     batch = family.host_batch(cfg, np.random.default_rng(1), 2)
     notes = {}
     jax.eval_shape(noting_layers(family.loss_fn(cfg), notes), params, aux,

@@ -5,7 +5,7 @@ group's heads into tiles, each a grid step chain of its own that reads
 the group's B and C and writes its part of ``dB`` and ``dC`` in float32,
 and XLA sums a group's tiles.  The kernels run interpreted here, against
 the XLA form (``_ssd_chunked``) and the recurrence; what Mosaic makes of
-the same shapes is ``tests/test_tpu_compile.py``'s.
+the same shapes is ``tests/test_mixer_compile.py``'s.
 """
 
 import jax

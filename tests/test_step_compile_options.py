@@ -2,8 +2,8 @@
 (``spmd._step_compiler_options``): chosen from the mesh, the platform and
 the parameter tree, empty everywhere else.  The compile-only leg — that
 the options put the gradient all-reduces inside async collective fusions
-on a described ``v5e:2x2`` — lives in ``tests/test_tpu_compile.py``, the one
-file that loads the TPU compiler.
+on a described ``v5e:2x2`` — lives in ``tests/test_step_compile.py``, one of
+the five files that load the TPU compiler (``tests/_v5e.py``).
 """
 
 import re

@@ -20,6 +20,8 @@ import horovod_tpu.jax as hvd_jax
 from horovod_tpu.compression import Compression
 from horovod_tpu.parallel._vma import ensure_varying_tree
 
+from _once import out_and_grads
+
 
 def _init_params(key, sizes):
     params = []
@@ -169,8 +171,9 @@ def test_broadcast_optimizer_state(hvd):
 
 
 @pytest.mark.time_limit(
-    600, "compiles ResNet-50 twice, with and without remat: 101 s beside "
-         "the five other workers of the driver's command on the sandbox")
+    600, "compiles ResNet-50 twice, with and without remat: 41 s beside "
+         "the five other workers of the driver's command on the sandbox "
+         "(101 s until PR 56: four eager passes, now one program a model)")
 def test_resnet_remat_is_semantics_preserving(hvd):
     """ResNet(remat=True) must share the param tree with remat=False (the
     knob trades HBM traffic for recompute, nothing else) — forward and
@@ -180,25 +183,24 @@ def test_resnet_remat_is_semantics_preserving(hvd):
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 32, 32, 3))
     plain = ResNet50(num_classes=10, dtype=jnp.float32, remat=False)
     ckpt = ResNet50(num_classes=10, dtype=jnp.float32, remat=True)
-    variables = plain.init(jax.random.PRNGKey(0), x, train=True)
+    variables = jax.jit(lambda k: plain.init(k, x, train=True))(
+        jax.random.PRNGKey(0))
 
-    def loss_with(model):
-        def loss(p):
-            out, _ = model.apply(
+    def out_and_grad(model):
+        """The output and the gradient of ``mean(out^2)``, ONE program a
+        model (run eagerly, op by op, the four passes took 98 s)."""
+        def out(p):
+            return model.apply(
                 {"params": p, "batch_stats": variables["batch_stats"]},
-                x, train=True, mutable=["batch_stats"])
-            return (out ** 2).mean()
-        return loss
+                x, train=True, mutable=["batch_stats"])[0]
+        return out_and_grads(out, lambda o: (o ** 2).mean(),
+                             variables["params"], jit=True)
 
     # Same param tree: apply each model with the OTHER's init.
-    out_plain, _ = plain.apply(x=x, train=True, mutable=["batch_stats"],
-                               variables=variables)
-    out_ckpt, _ = ckpt.apply(x=x, train=True, mutable=["batch_stats"],
-                             variables=variables)
+    (out_plain, (g_plain,)), (out_ckpt, (g_ckpt,)) = map(
+        out_and_grad, (plain, ckpt))
     np.testing.assert_allclose(np.asarray(out_plain), np.asarray(out_ckpt),
                                rtol=1e-5, atol=1e-5)
-    g_plain = jax.grad(loss_with(plain))(variables["params"])
-    g_ckpt = jax.grad(loss_with(ckpt))(variables["params"])
     for a, b in zip(jax.tree.leaves(g_plain), jax.tree.leaves(g_ckpt)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-4, atol=1e-5)
