@@ -24,7 +24,10 @@ through the entry points a user calls (``hvd.init()`` →
   runs one, against ``lax.ragged_dot`` and its transposes — forward, input
   and weight gradient — and prints their plan (``moe_plan``);
 * prints the rows of a held expert layer's window in the six cells that
-  hold a share (``held_rows``) and times one such layer alone, forward and
+  hold a share, and which way they move — through the sort's permutation,
+  landed by a grouped transposed product, or scatter-added
+  (``held_rows``: ``form``, ``landed_by_product``) — and times one such
+  layer alone, forward and
   backward, at four loads under the plan's window (``held_windows``;
   ``--held-windows`` runs that table alone, under every candidate window);
 * checks learned sparse attention at Keye-VL-2.0's head widths — the
@@ -696,11 +699,15 @@ def held_rows(**sizes) -> dict:
     """What a ``DroplessMoE(held=...)`` layer of these sizes notes of itself
     while traced (shapes alone, nothing runs): its assignments, what uniform
     routing sends to the held experts, the rows ``W`` of a window
-    (``moe._window_plan``: a step runs ``ceil(landed / W)`` of them), and
-    how many assignments move to expert order and back as gathers through
+    (``moe._window_plan``: a step runs ``ceil(landed / W)`` of them), how
+    many assignments move to expert order and back as gathers through
     the sort's permutation — all of them where the layer's window is every
-    assignment, none where a smaller window gathers its rows and
-    scatter-adds them home."""
+    assignment, none where a smaller window gathers its rows — and how
+    many rows of such a window land on their tokens through a grouped
+    transposed product (``moe._lands_by_product``: ``W`` where the grouped
+    matmuls' plan takes the kernels and the tokens cut into the landing's
+    tiles, 0 where they are scatter-added); ``form`` says the same in a
+    word."""
     import jax
 
     from horovod_tpu.layer_notes import noting_layers
@@ -709,9 +716,12 @@ def held_rows(**sizes) -> dict:
     noted = {}
     noting_layers(jax.eval_shape, noted)(layer.init, jax.random.PRNGKey(0), x)
     counters, = noted.values()
-    return {name: counters[f"moe.{name}"] for name in (
+    # A package from before the landing's counter notes none.
+    rows = {name: counters.get(f"moe.{name}", 0) for name in (
         "assignments", "held_assignments", "window_rows",
-        "permuted_assignments")}
+        "permuted_assignments", "landed_by_product")}
+    return {**rows, "form": "permuted" if rows["permuted_assignments"]
+            else "products" if rows["landed_by_product"] else "scatter_add"}
 
 
 def held_layer_ms(sizes: dict, loads, *, seed: int, calls: int = 10) -> dict:
@@ -851,9 +861,37 @@ def experts_reference_phase(*, rows: int, groups: int, dim: int, hidden: int,
               f"the grouped matmuls' kernels differ from lax.ragged_dot in "
               f"{name} by {errs[name]:.3g} of its largest value (bound "
               f"{EXPERTS_TOL})")
+    # The same walk handed a float32 block (PR 57): the weight gradient
+    # summed onto a carry in place, and the window's rows landed on their
+    # tokens under a float32 gate, against the pass and the scatter-add
+    # they replace.
+    carry = jax.random.normal(ks[0], (groups, dim, width), jnp.float32)
+    tokens = 2 * rows
+    token = jnp.sort(jnp.where(jnp.arange(rows) < rows // 3, jax.random.randint(
+        ks[1], (rows,), 0, tokens), tokens))
+    gate = jax.random.uniform(ks[2], (rows,), jnp.float32)
+    block = jax.random.normal(ks[3], (tokens, dim), jnp.float32)
+    handed = jax.jit(lambda: (
+        grouped_matmul.grouped_gradients(
+            x, w, dy, sizes, plan, interpret=interpret, block=carry)[1],
+        grouped_matmul.landed_rows(block, x, token, gate, plan=plan,
+                                   interpret=interpret)))()
+    wanted = jax.jit(lambda: (
+        carry + grouped_matmul.grouped_gradients(
+            x, w, dy, sizes, plan, interpret=interpret)[1].astype(
+                jnp.float32),
+        block.at[token].add(x.astype(jnp.float32) * gate[:, None],
+                            mode="drop")))()
+    for name, g, r, bound in zip(("handed_grad_w", "landed_rows"), handed,
+                                 wanted, (EXPERTS_TOL, 2e-6)):
+        errs[name] = _rel_err(g, r)
+        check(errs[name] <= bound,
+              f"the grouped transposed product handed a float32 block "
+              f"differs in {name} by {errs[name]:.3g} of its largest value "
+              f"(bound {bound})")
     return {"shape": [rows, groups, dim, width], "interpret": interpret,
             "moe_plan": plan_dict,
-            **{k: round(e, 5) for k, e in errs.items()}}
+            **{k: float(f"{e:.3g}") for k, e in errs.items()}}
 
 
 def select_reference_phase(*, batch: int, seq: int, heads: int,

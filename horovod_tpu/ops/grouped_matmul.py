@@ -32,9 +32,17 @@ picks a form):
   of rows at a time — a strip the group has no row in is skipped, so a
   boundary costs one strip twice, not a tile.  ``moe_tgmm`` keeps a group's
   (K block, N block) of the weight gradient in float32 in VMEM over the
-  group's visits and writes it once, zeros for an empty group.  The
-  drivers are ``jax.jit(inline=True)``: the layers of a stack share one
-  trace of each kernel body.
+  group's visits and writes it once, zeros for an empty group — or, handed
+  a float32 block, starts from it and writes float32 back into its buffer
+  (``input_output_aliases``): a sum over calls with no pass of its own
+  (:func:`grouped_gradients`).  The same walk LANDS sorted rows on their
+  tokens (``moe_land``, :func:`landed_rows`): the groups are tiles of
+  tokens, the handed block the (tokens, N) float32 accumulator, the left
+  operand the selection the kernel forms from the rows' token ids —
+  ``S.T @ rows`` is a scatter-add done by the MXU, a float32 gate taken
+  apart into three bfloat16 parts whose products are exact.  The drivers
+  are ``jax.jit(inline=True)``: the layers of a stack share one trace of
+  each kernel body.
 * **``lax.ragged_dot``** otherwise (the tiny shapes of the CPU tests,
   interpreted Pallas under ``shard_map``'s manual axes): the caller's own
   form.
@@ -78,8 +86,9 @@ _MOST_COLS = 1024
 # shapes whose blocks would take more than three quarters of it are
 # ``lax.ragged_dot``'s.  The cells' largest kernels take 15.7 MB by shapes
 # (OLMoE's forward: a (512, 2048) and a (2048, 1024) block twice over, the
-# pipeline's two buffers, beside the output's) and 16.8 (a weight
-# gradient's (1024, 1024) block in float32, its product and its output).
+# pipeline's two buffers, beside the output's) and 28 (a weight gradient's
+# (1024, 1024) block in float32: handed in and written back, two buffers
+# each, its scratch and its product; 16.8 with no block handed).
 _VMEM_MB = 48
 # On the v5e ``lax.ragged_dot`` ran at 33 TFLOP/s at a width of 1856 or
 # 1920 and at 63-93 at 2048 (PERF.md section 6, PR 30).
@@ -101,7 +110,9 @@ def _vmem_bytes(rows, strip, cols, k, n, itemsize) -> int:
     gmm = max(2 * (rows * whole + whole * block + rows * block) * itemsize
               + strip * block * 4
               for whole, block in ((k, nc), (n, kc)))
-    tgmm = (2 * rows * (kc + nc) + 2 * kc * nc) * itemsize + 2 * kc * nc * 4
+    # The weight gradient handed a float32 block: the block coming in and
+    # going out, two buffers each, the scratch and a product.
+    tgmm = 2 * rows * (kc + nc) * itemsize + 6 * kc * nc * 4
     return max(gmm, tgmm)
 
 
@@ -221,34 +232,70 @@ def _gmm_kernel(tiles_ref, groups_ref, offsets_ref, x_ref, w_ref, o_ref, *,
                 o_ref[rows, :].astype(_F32)).astype(o_ref.dtype)
 
 
-def _tgmm_kernel(tiles_ref, groups_ref, offsets_ref, x_ref, dy_ref, o_ref,
-                 acc_ref, *, tile: int):
-    """One visit of ``rows_g.T @ dy_g``: the tile's share of the group's
-    block, summed in float32 over the group's visits."""
+def _gate_parts(gate):
+    """A float32 gate as three bfloat16 values that sum to it exactly (its
+    24 bits, eight a part), each kept in float32: a part times a bfloat16
+    row is then exact in a float32 product."""
+    parts = []
+    for _ in range(3):
+        part = gate.astype(jnp.bfloat16).astype(_F32)
+        parts.append(part)
+        gate = gate - part
+    return parts
+
+
+def _tgmm_kernel(tiles_ref, groups_ref, offsets_ref, *refs, tile: int,
+                 landing: str, handed: bool):
+    """One visit of ``left_g.T @ dy_g``: the tile's share of the group's
+    block, summed in float32 over the group's visits — onto the block the
+    call was ``handed``, or onto zeros.
+
+    The left operand is the tile's rows of ``x`` or, ``landing``, the
+    SELECTION of the rows' tokens: the groups are tiles of as many tokens
+    as the block has rows, ``S[r, c] = 1`` where row ``r`` belongs to the
+    tile's token ``c``, so ``S.T @ dy`` lands every row on its token —
+    ``"gated"``: times its float32 gate, as three exact bfloat16
+    products."""
+    left_ref, (dy_ref, *block, o_ref, acc_ref) = refs[0], refs[-3 - handed:]
     v = pl.program_id(2)
     g, _, again, lo, hi = _visit(tiles_ref, groups_ref, offsets_ref, v, tile)
     last = pl.num_programs(2) - 1
 
     @pl.when((v == 0) | (groups_ref[jnp.maximum(v - 1, 0)] != g))
     def _():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        acc_ref[...] = block[0][...] if handed else jnp.zeros_like(acc_ref)
 
-    def add(dy):
+    def add(dy, selection=None):
+        """``x.T @ dy``, or ``selection @ dy``, onto the scratch."""
         acc_ref[...] += lax.dot_general(
-            x_ref[...], dy, (((0,), (0,)), ((), ())),
+            left_ref[...] if selection is None else selection, dy,
+            (((0 if selection is None else 1,), (0,)), ((), ())),
             preferred_element_type=_F32)
 
-    whole = (lo == 0) & (hi == tile)
+    if landing:
+        # A row matches no token of another tile, and one that landed
+        # nowhere (its token past the last) none at all: nothing to mask.
+        @pl.when(jnp.logical_not(again) & (hi > lo))
+        def _():
+            tokens = acc_ref.shape[0]
+            match = (left_ref[...] - g * tokens
+                     == lax.broadcasted_iota(jnp.int32, (tokens, tile), 0))
+            for part in (_gate_parts(refs[1][...]) if landing == "gated"
+                         else [1.0]):
+                add(dy_ref[...],
+                    jnp.where(match, part, 0.0).astype(dy_ref.dtype))
+    else:
+        whole = (lo == 0) & (hi == tile)
 
-    @pl.when(jnp.logical_not(again) & whole)
-    def _():
-        add(dy_ref[...])
+        @pl.when(jnp.logical_not(again) & whole)
+        def _():
+            add(dy_ref[...])
 
-    @pl.when(jnp.logical_not(again | whole) & (hi > lo))
-    def _():
-        row = lax.broadcasted_iota(jnp.int32, (tile, 1), 0)
-        add(jnp.where((row >= lo) & (row < hi), dy_ref[...].astype(_F32),
-                      0.0).astype(dy_ref.dtype))
+        @pl.when(jnp.logical_not(again | whole) & (hi > lo))
+        def _():
+            row = lax.broadcasted_iota(jnp.int32, (tile, 1), 0)
+            add(jnp.where((row >= lo) & (row < hi), dy_ref[...].astype(_F32),
+                          0.0).astype(dy_ref.dtype))
 
     @pl.when((v == last) | (groups_ref[jnp.minimum(v + 1, last)] != g))
     def _():
@@ -289,32 +336,94 @@ def _gmm(x, w, group_sizes, *, transposed: bool, plan, interpret):
     )(tiles, groups, offsets, x, w)
 
 
-@functools.partial(jax.jit, inline=True,
-                   static_argnames=("plan", "interpret"))
-def _tgmm(x, dy, group_sizes, *, plan, interpret):
-    """``x_g.T @ dy_g`` for every group: (G, K, N)."""
-    M, K = x.shape
-    N = dy.shape[1]
-    k_cols, n_cols = _block(K, plan.cols), _block(N, plan.cols)
-    tiles, group_of, offsets = _visits(group_sizes, M, plan.rows)
+def _tgmm_call(left, left_specs, dy, group_sizes, block, *, k: int,
+               k_cols: int, n_cols: int, tile: int, landing: str, plan,
+               interpret):
+    """The walk of (row tile, group) visits that ``_tgmm`` and ``landed_rows``
+    share: ``left`` (arrays and their block specs) against ``dy`` (M, N),
+    one (k, N) block a group, float32 and summed onto ``block`` in place
+    where one is handed, in ``dy``'s dtype onto zeros otherwise."""
+    M, N = dy.shape
+    G = group_sizes.shape[0]
+    tiles, group_of, offsets = _visits(group_sizes, M, tile)
+    out_spec = pl.BlockSpec((None, k_cols, n_cols),
+                            lambda i, j, v, t, g, o: (g[v], i, j))
+    handed = block is not None
     return pl.pallas_call(
-        functools.partial(_tgmm_kernel, tile=plan.rows),
+        functools.partial(_tgmm_kernel, tile=tile, landing=landing,
+                          handed=handed),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
-            grid=(K // k_cols, N // n_cols, tiles.shape[0]),
+            grid=(k // k_cols, N // n_cols, tiles.shape[0]),
             in_specs=[
-                pl.BlockSpec((plan.rows, k_cols),
-                             lambda i, j, v, t, g, o: (t[v], i)),
-                pl.BlockSpec((plan.rows, n_cols),
-                             lambda i, j, v, t, g, o: (t[v], j))],
-            out_specs=pl.BlockSpec((None, k_cols, n_cols),
-                                   lambda i, j, v, t, g, o: (g[v], i, j)),
+                *left_specs,
+                pl.BlockSpec((tile, n_cols),
+                             lambda i, j, v, t, g, o: (t[v], j)),
+                *[out_spec] * handed],
+            out_specs=out_spec,
             scratch_shapes=[pltpu.VMEM((k_cols, n_cols), _F32)]),
-        out_shape=_pallas.struct((group_sizes.shape[0], K, N), x.dtype, x, dy),
-        interpret=interpret, name="moe_tgmm",
+        out_shape=_pallas.struct((G, k, N), _F32 if handed else dy.dtype,
+                                 *left, dy, *[block] * handed),
+        # The handed block is the output: the three prefetched operands,
+        # the left ones and ``dy`` stand before it.
+        input_output_aliases={4 + len(left): 0} if handed else {},
+        interpret=interpret, name="moe_land" if landing else "moe_tgmm",
         **_pallas.compiler_params(
             interpret, ("parallel", "parallel", "arbitrary"), plan.vmem_mb),
-    )(tiles, group_of, offsets, x, dy)
+    )(tiles, group_of, offsets, *left, dy, *[block] * handed)
+
+
+@functools.partial(jax.jit, inline=True,
+                   static_argnames=("plan", "interpret"))
+def _tgmm(x, dy, group_sizes, block=None, *, plan, interpret):
+    """``x_g.T @ dy_g`` for every group: (G, K, N) — added to the float32
+    ``block`` (G, K, N) where one is handed, in place."""
+    K = x.shape[1]
+    k_cols, n_cols = _block(K, plan.cols), _block(dy.shape[1], plan.cols)
+    return _tgmm_call(
+        [x], [pl.BlockSpec((plan.rows, k_cols),
+                           lambda i, j, v, t, g, o: (t[v], i))],
+        dy, group_sizes, block, k=K, k_cols=k_cols, n_cols=n_cols,
+        tile=plan.rows, landing="", plan=plan, interpret=interpret)
+
+
+# Tokens a tile of the accumulator that a window's rows land on, which is
+# also the rows a tile of the landing's walk: a row tile meets every token
+# tile it has a row of at a whole tile's cost, three times over under a
+# gate, so both are small.  On a v5e 128 beat 256 by 2-4% over a window's
+# two landings at the four cells' shapes, 17% at the narrowest (PERF.md
+# section 6, PR 57).
+LANDING_TOKENS = 128
+_LANDING_MOST_COLS = 4096
+
+
+@functools.partial(jax.jit, inline=True,
+                   static_argnames=("plan", "interpret"))
+def landed_rows(block, dy, token, gate=None, *, plan, interpret=False):
+    """``block`` (n, N) float32 with every row of ``dy`` (M, N) added to
+    the row of its ``token`` (M,) — times its float32 ``gate`` (M,) where
+    one is given, the product in float32 —, in place and with no scatter.
+    ``dy`` is sorted by token; a row whose token is ``n`` or more lands
+    nowhere.
+
+    The same walk as the weight gradient's under the kernels' ``plan``,
+    with the groups tiles of ``LANDING_TOKENS`` tokens (``n`` a multiple),
+    the block seen as (n / tokens, tokens, N) and the left operand the
+    selection the kernel forms from the token ids (:func:`_tgmm_kernel`)."""
+    n, N = block.shape
+    tokens = LANDING_TOKENS
+    tile = min(tokens, plan.rows)
+    token = token.astype(jnp.int32)
+    ends = jnp.searchsorted(token, jnp.arange(1, n // tokens + 1) * tokens)
+    sizes = jnp.diff(ends, prepend=0).astype(jnp.int32)
+    left = [token[None]] + ([] if gate is None else [gate.astype(_F32)[None]])
+    return _tgmm_call(
+        left, [pl.BlockSpec((1, tile), lambda i, j, v, t, g, o: (0, t[v]))
+               ] * len(left),
+        dy, sizes, block.reshape(n // tokens, tokens, N), k=tokens,
+        k_cols=tokens, n_cols=_block(N, _LANDING_MOST_COLS), tile=tile,
+        landing="rows" if gate is None else "gated", plan=plan,
+        interpret=interpret).reshape(n, N)
 
 
 # ---------------------------------------------------------------- the op
@@ -332,9 +441,8 @@ def _fused_fwd_rule(x, w, group_sizes, plan, interpret):
 
 def _fused_bwd_rule(plan, interpret, res, dy):
     x, w, group_sizes = res
-    return (_gmm(dy, w, group_sizes, transposed=True, plan=plan,
-                 interpret=interpret),
-            _tgmm(x, dy, group_sizes, plan=plan, interpret=interpret), None)
+    return (*grouped_gradients(x, w, dy, group_sizes, plan,
+                               interpret=interpret), None)
 
 
 _fused.defvjp(_fused_fwd_rule, _fused_bwd_rule)
@@ -353,3 +461,18 @@ def grouped_matmul(x, w, group_sizes, plan: GroupedPlan, *,
         return lax.ragged_dot(x, w, group_sizes)
     return _fused(x, w.astype(x.dtype), group_sizes.astype(jnp.int32), plan,
                   interpret)
+
+
+def grouped_gradients(x, w, dy, group_sizes, plan: GroupedPlan, *,
+                      interpret: bool = False, block=None):
+    """The two transposes of :func:`grouped_matmul` under the kernels'
+    ``plan``, called directly: ``(dy_g @ w_g.T, x_g.T @ dy_g)`` for the
+    cotangent ``dy`` (M, N) of ``x_g @ w_g``.  With a float32 ``block``
+    (G, K, N) the weight gradient is added to it, in place and in float32,
+    by the kernel that forms it; with none it is what the op's own
+    backward rule gives, in the operands' dtype."""
+    w, dy = w.astype(x.dtype), dy.astype(x.dtype)
+    group_sizes = group_sizes.astype(jnp.int32)
+    return (_gmm(dy, w, group_sizes, transposed=True, plan=plan,
+                 interpret=interpret),
+            _tgmm(x, dy, group_sizes, block, plan=plan, interpret=interpret))

@@ -52,7 +52,9 @@ from jax import lax
 
 from horovod_tpu.layer_notes import note_layer, noting_layers
 from horovod_tpu.ops import _pallas
-from horovod_tpu.ops.grouped_matmul import grouped_matmul, grouped_plan
+from horovod_tpu.ops.grouped_matmul import (
+    LANDING_TOKENS, grouped_gradients, grouped_matmul, grouped_plan,
+    landed_rows)
 from horovod_tpu.parallel._vma import ensure_varying_tree
 from horovod_tpu.parallel._vma import per_shard_init as _expert_init
 
@@ -197,9 +199,13 @@ _WINDOW_TILE = 512
 # A part that does not follow its rows, by the byte of the experts' float32
 # weight gradient (its accumulate over the windows, each kernel's pass over
 # every expert's matrix): 1.4 to 4.5 ms.  And a part by the row: its moves
-# by the byte (gather, masks, casts, the float32 scatter-adds both ways) and
+# by the byte (gather, masks, casts, the float32 sums both ways) and
 # its products by the FLOP, forward, again in the backward pass and both
-# gradients: 0.38 to 0.77 us.
+# gradients: 0.38 to 0.77 us.  Since the sums are products that accumulate
+# in place the table reads 1.0 to 2.8 ms and 0.34 to 0.65 us (PERF.md
+# section 6, PR 57); the constants stand, because ``W`` comes out the same
+# or within a row tile or two, and moves with the margin over ``U`` that is
+# the rule's next change (ROADMAP S2 (e)).
 _WINDOW_S_PER_EXPERT_BYTE = 11e-12
 _ROW_S_PER_BYTE = 75e-12
 _ROW_S_PER_FLOP = 1 / 197e12
@@ -216,7 +222,7 @@ def _window_plan(*, assignments: int, held: int, routed: int,
     A step runs ``ceil(landed / W)`` windows for the ``landed`` assignments
     its routing sent here (the device reads the count), so a window is the
     unit its work is rounded up to: half a window's rows are gathered,
-    masked and scatter-added for nothing a layer on average, and every
+    masked and landed for nothing a layer on average, and every
     window that runs pays once for what does not follow its rows.  The two
     balance at ``W = sqrt(2 U fixed / row)`` for a load near the uniform
     ``U`` — and ``W`` is never under ``U``: a router that balances sends
@@ -233,6 +239,18 @@ def _window_plan(*, assignments: int, held: int, routed: int,
     rows = max(uniform, math.sqrt(2 * uniform * fixed / row))
     rows = min(assignments, -(-int(rows) // tile) * tile)
     return WindowPlan(rows, -(-assignments // rows), uniform)
+
+
+def _lands_by_product(form: str, tokens: int) -> bool:
+    """Whether a window's rows land on their tokens, and its weight
+    gradients on their carry, as grouped transposed products that
+    accumulate in place (``grouped_matmul.landed_rows``, ``grouped_gradients``
+    handed its block) — the one place that chooses, by shapes alone: where
+    the grouped matmuls' plan takes the kernels and the tokens cut into the
+    landing's tiles.  ``lax.ragged_dot``'s form (the CPU under
+    ``shard_map``, odd widths) scatter-adds the rows and adds the weight
+    gradients in a pass of their own."""
+    return form == "kernels" and tokens % LANDING_TOKENS == 0
 
 
 def _pad_hidden(a, axis: int, lanes: int):
@@ -257,9 +275,11 @@ noting_expert_layers = noting_layers
 # The row moves of a layer whose sorted rows are ALL k·N assignments —
 # every expert here, or a held share whose window is every assignment —:
 # a gather each way, forward and backward, through the sort's ``order`` and
-# its ``inverse``.  A held share with a smaller window moves each window's
-# rows by gather and scatter-add (``_held_windows``): a gather through
-# ``inverse`` would move k·N rows to bring W of them home.
+# its ``inverse``.  A held share with a smaller window gathers each
+# window's rows and lands them home (``_held_windows``: by a grouped
+# transposed product where the kernels run, ``_land``; by scatter-add under
+# ``lax.ragged_dot``): a gather through ``inverse`` would move k·N rows to
+# bring W of them home.
 @jax.custom_vjp
 def _to_expert_order(x, order, inverse):
     """Rows of ``x`` (N, d) as the k·N assignments sorted by expert:
@@ -311,16 +331,26 @@ class _Held(NamedTuple):
     dtype: Any
     plan: Any           # the grouped matmuls' plan over W rows
     interpret: bool
+    products: bool      # ``_lands_by_product``: no scatter-add of rows
+
+
+def _activate(activation, up, gate=None):
+    """The experts' hidden activations of their projections."""
+    if activation == "swiglu":
+        return nn.silu(gate) * up
+    return jnp.square(nn.relu(up))
+
+
+def _projections(activation):
+    """The matrices whose products ``_activate`` reads, in its order."""
+    return ("w_up", "w_gate") if activation == "swiglu" else ("w_up",)
 
 
 def _hidden(rows, w, group_sizes, activation, plan, interpret):
     """The experts' hidden activations on sorted ``rows``."""
-    up = grouped_matmul(rows, w["w_up"], group_sizes, plan,
-                        interpret=interpret)
-    if activation == "swiglu":
-        return nn.silu(grouped_matmul(rows, w["w_gate"], group_sizes, plan,
-                                      interpret=interpret)) * up
-    return jnp.square(nn.relu(up))
+    return _activate(activation, *(
+        grouped_matmul(rows, w[name], group_sizes, plan, interpret=interpret)
+        for name in _projections(activation)))
 
 
 def _held_rows(rows, w, here, sizes, held: _Held):
@@ -360,12 +390,67 @@ def _window(i, x, gate, order, ends, group_sizes, landed, held: _Held):
         return a, token, here, sizes, x[token], gate.reshape(-1)[a]
 
 
+def _gated(y, g, here):
+    """What a window's results ``y`` add to their tokens: float32, each
+    row times its gate ``g``."""
+    return y.astype(jnp.float32) * jnp.where(here, g[:, None], 0.0)
+
+
 def _weighted(rows, g, w, here, sizes, held: _Held):
     """What a window's gathered ``rows`` add to their tokens: the experts'
     results in float32, each times its gate ``g``."""
     y = _held_rows(rows, w, here, sizes, held)
     with jax.named_scope("combine"):
-        return y.astype(jnp.float32) * jnp.where(here, g[:, None], 0.0)
+        return _gated(y, g, here)
+
+
+def _land(block, rows, token, here, gate, held: _Held):
+    """``block`` (n, d) float32 with a window's ``rows`` — each times its
+    float32 ``gate`` where one is given — added to their tokens, in place
+    and with no scatter: the rows are put in token order (one sort of W
+    tokens, one gather of W rows in the activations' dtype) and landed by
+    ``grouped_matmul.landed_rows``.  A row that is not ``here`` takes a
+    token past the last and lands nowhere."""
+    token = jnp.where(here[:, 0], token, block.shape[0])
+    by_token = jnp.argsort(token)
+    return landed_rows(
+        block, rows[by_token], token[by_token],
+        None if gate is None else gate[by_token], plan=held.plan,
+        interpret=held.interpret)
+
+
+def _window_gradients(rows, g, w, here, sizes, d_out, dw, held: _Held):
+    """A window's backward pass written out: ``(d_rows, d_g, dw)`` for the
+    cotangent ``d_out`` of what ``_weighted`` gives — its products again,
+    then each product's two transposes called directly
+    (``grouped_matmul.grouped_gradients``), so that every weight gradient
+    is summed onto its float32 carry ``dw[name]`` by the kernel that forms
+    it.  The activation's and the gate's derivatives are ``jax.vjp``'s."""
+    names = _projections(held.activation)
+
+    def gradients(x, name, dy):
+        d_x, dw[name] = grouped_gradients(
+            x, w[name], dy, sizes, held.plan, interpret=held.interpret,
+            block=dw[name])
+        return d_x
+
+    with jax.named_scope("experts"):
+        rows = jnp.where(here, rows.astype(held.dtype), 0)
+        h, pull_h = jax.vjp(
+            functools.partial(_activate, held.activation),
+            *(grouped_matmul(rows, w[name], sizes, held.plan,
+                             interpret=held.interpret) for name in names))
+        h = jnp.where(here, h, 0)
+        y = jnp.where(here, grouped_matmul(
+            h, w["w_down"], sizes, held.plan, interpret=held.interpret), 0)
+    with jax.named_scope("combine"):
+        d_y, d_g = jax.vjp(lambda y, g: _gated(y, g, here), y, g)[1](d_out)
+    with jax.named_scope("experts"):
+        dw = dict(dw)
+        d_h = gradients(h, "w_down", jnp.where(here, d_y, 0))
+        d_rows = sum(gradients(rows, name, d) for name, d in zip(
+            names, pull_h(jnp.where(here, d_h, 0))))
+        return jnp.where(here, d_rows, 0), d_g, dw
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
@@ -375,19 +460,28 @@ def _held_windows(held: _Held, x, gate, w, order, ends, group_sizes,
     assignments a window of ``W`` rows at a time, ``ceil(landed / W)`` of
     them — a trip count the device reads, so the work follows the load.
     Each window gathers its rows by ``x[token]``, runs the grouped matmuls
-    with each expert's group cut to the window, and scatter-adds the
-    weighted results onto their tokens in float32.
+    with each expert's group cut to the window, and adds the weighted
+    results to their tokens in float32: landed by a grouped transposed
+    product that accumulates in place where ``held.products``
+    (:func:`_land`), scatter-added otherwise.
 
     A loop of a data-dependent length has no reverse-mode rule, so this
     carries its own: the backward pass keeps ``(x, gate, w)`` and the
-    integer operands, no window's rows, and runs the same windows again,
-    each through ``jax.vjp``, with ``dx``, ``dgate`` and the experts'
-    ``dW`` summed over them in float32."""
+    integer operands, no window's rows, and runs the same windows again
+    with ``dx``, ``dgate`` and the experts' ``dW`` summed over them in
+    float32 — ``dx`` landed as the output is and each ``dW`` summed onto
+    its carry by the kernel that forms it (:func:`_window_gradients`)
+    where ``held.products``; each window through ``jax.vjp``, ``dx``
+    scatter-added and ``dW`` added in a pass of its own, otherwise."""
     padded = _padded(w, held)
 
     def body(i, out):
         _, token, here, sizes, rows, g = _window(
             i, x, gate, order, ends, group_sizes, landed, held)
+        if held.products:
+            y = _held_rows(rows, padded, here, sizes, held)
+            with jax.named_scope("combine"):
+                return _land(out, y, token, here, g, held)
         y = _weighted(rows, g, padded, here, sizes, held)
         with jax.named_scope("combine"):
             return out.at[token].add(y)
@@ -409,12 +503,20 @@ def _held_windows_bwd(held, res, ct):
         dx, dgate, dw = carry
         a, token, here, sizes, rows, g = _window(
             i, x, gate, order, ends, group_sizes, landed, held)
+        if held.products:
+            with jax.named_scope("combine"):
+                d_out = ct[token]
+            d_rows, d_g, dw = _window_gradients(
+                rows, g, padded, here, sizes, d_out, dw, held)
+            with jax.named_scope("dispatch"):
+                return (_land(dx, d_rows, token, here, None, held),
+                        dgate.at[a].add(d_g), dw)
         _, pull = jax.vjp(
             lambda rows, g, w: _weighted(rows, g, w, here, sizes, held),
             rows, g, padded)
         with jax.named_scope("combine"):
-            d_y = ct[token]
-        d_rows, d_g, d_w = pull(d_y)
+            d_out = ct[token]
+        d_rows, d_g, d_w = pull(d_out)
         with jax.named_scope("dispatch"):
             dx = dx.at[token].add(d_rows.astype(jnp.float32))
             dgate = dgate.at[a].add(d_g)
@@ -520,12 +622,24 @@ class DroplessMoE(nn.Module):
       the device reads, so a step costs what its routing asks and none is
       lost at any load.  ``W`` is :func:`_window_plan`'s, a function of the
       layer's shapes (what uniform routing sends here or somewhat more,
-      in whole row tiles of the kernels).  A window gathers its rows by ``x[token]``
-      and scatter-adds the weighted results onto their tokens in float32
+      in whole row tiles of the kernels).  A window gathers its rows by
+      ``x[token]`` and adds the weighted results to their tokens in float32
       (:func:`_held_windows`, a ``jax.custom_vjp``: the backward pass
       keeps the layer's inputs, no window's rows, and runs the same
       windows again with ``dx``, ``dgate`` and the experts' ``dW`` summed
-      over them in float32).  Sown beside the rest: ``held_assignments``,
+      over them in float32).  Where the grouped matmuls' plan takes the
+      kernels and the tokens cut into the landing's tiles
+      (:func:`_lands_by_product`, shapes alone) none of the three float32
+      sums is a scatter or a pass of its own: the window's rows are put in
+      token order and landed — ``out`` under the float32 gate, ``dx`` bare —
+      by the weight gradient's own walk over tiles of tokens, its left
+      operand the selection of each row's token, on the loop's carry in
+      place (``grouped_matmul.landed_rows``), and every ``dW`` is summed
+      onto its float32 carry by the kernel that forms it
+      (``grouped_gradients`` handed its block; the window's backward is
+      written out, :func:`_window_gradients`).  Under ``lax.ragged_dot``
+      the rows are scatter-added and ``dW`` is added in a pass.  Sown
+      beside the rest: ``held_assignments``,
       the number that landed here, and ``held_windows``, the windows that
       ran; noted: ``moe.held_assignments`` (what uniform routing sends)
       and ``moe.window_rows`` (``W``).  Where the held experts take a third
@@ -537,8 +651,10 @@ class DroplessMoE(nn.Module):
       products, the grouped matmuls skipping the strips past ``landed``,
       the expert block recomputed in the backward pass.  Noted beside the
       rest: ``moe.permuted_assignments``, ``n · k`` where the rows moved
-      through the permutation, 0 where windows of them moved by
-      scatter-add.
+      through the permutation, 0 where windows of them were gathered and
+      landed, and ``moe.landed_by_product``, ``W`` where a window's rows
+      land through the product, 0 where they are scatter-added or the one
+      window is every assignment.
     * ``router="mlp"``: the router is a small network that carries a state
       from one expert layer to the next, and the layer is called as
       ``layer(x, router_state)``.  ``r = W_d x + b_d`` (``router_hidden``
@@ -665,7 +781,7 @@ class DroplessMoE(nn.Module):
         if self.held is None and not self.skip_choice:
             out, tokens_per_expert, fused = self._all_experts(
                 rows, gate, expert, names)
-            n_held, window = E, None
+            n_held, window, products = E, None, False
         else:
             # With a skip choice and no share named, every expert is held:
             # the skip choice is then the one output held nowhere.
@@ -674,8 +790,8 @@ class DroplessMoE(nn.Module):
                 raise ValueError(f"held={self.held} is not a range of the "
                                  f"{E} experts")
             (out, tokens_per_expert, held_assignments, held_windows, fused,
-             window) = self._held_experts(rows, gate, expert, names, first,
-                                          n_held)
+             window, products) = self._held_experts(
+                 rows, gate, expert, names, first, n_held)
             self.sow("intermediates", "held_assignments", held_assignments)
             self.sow("intermediates", "held_windows", held_windows)
         if self.skip_choice:
@@ -713,9 +829,13 @@ class DroplessMoE(nn.Module):
             "moe.assignments": n * k,
             "moe.fused_matmuls": fused * len(names),
             # Assignments whose rows moved as gathers through the sort's
-            # permutation; 0 where a window of them moved by scatter-add.
+            # permutation; 0 where a window of them is gathered and landed.
             "moe.permuted_assignments": n * k * (
                 window is None or window.windows == 1),
+            # Rows of a window that land on their tokens through a grouped
+            # transposed product; 0 where they are scatter-added or the one
+            # window is every assignment.
+            "moe.landed_by_product": window.rows if products else 0,
             "moe.expert_bytes": (len(names) * n_held * width * self.hidden
                                  * jnp.dtype(self.param_dtype).itemsize),
             "moe.row_bytes": width * jnp.dtype(self.dtype).itemsize}
@@ -835,11 +955,13 @@ class DroplessMoE(nn.Module):
             expert_bytes=4 * sum(a.size for a in w.values()))
         W = window.rows
         # The grouped matmuls' plan says what the hidden width is padded to.
-        held = _Held(k, W, self.activation, self.dtype, grouped_plan(
+        plan = grouped_plan(
             jax.ShapeDtypeStruct((W, d), self.dtype, vma=jax.typeof(x).vma),
             n_held, self.hidden + -self.hidden % 128,
-            interpret=_pallas.interpret()), _pallas.interpret())
-        fused = held.plan.form == "kernels"
+            interpret=_pallas.interpret())
+        held = _Held(k, W, self.activation, self.dtype, plan,
+                     _pallas.interpret(), _lands_by_product(plan.form, n))
+        fused = plan.form == "kernels"
         held_windows = -(-landed // W)
         # One window is every assignment (``W == n k``): ``order`` is then a
         # whole permutation, and rows move both ways as gathers through it.
@@ -850,7 +972,8 @@ class DroplessMoE(nn.Module):
             order = jnp.pad(order, (0, window.windows * W - n * k))
             return (_held_windows(held, x, gate, w, order, ends, group_sizes,
                                   landed),
-                    tokens_per_expert, landed, held_windows, fused, window)
+                    tokens_per_expert, landed, held_windows, fused, window,
+                    held.products)
 
         with jax.named_scope("dispatch"):
             inverse = jnp.argsort(order)
@@ -873,7 +996,7 @@ class DroplessMoE(nn.Module):
                 return (y.astype(jnp.float32) * g[..., None]).sum(axis=1)
 
         return (every_assignment(x, gate, w), tokens_per_expert, landed,
-                held_windows, fused, window)
+                held_windows, fused, window, False)
 
 
 def router_losses(intermediates) -> tuple:

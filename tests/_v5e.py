@@ -63,11 +63,13 @@ def compile_text(fn, *shapes):
 
 def kernels_by_name(lowered):
     """How often each of the grouped matmuls' kernels stands in a lowered
-    program (a compiled one names a custom call after its scopes)."""
+    program (a compiled one names a custom call after its scopes);
+    ``moe_land`` is the weight gradient's walk landing a held window's
+    rows on their tokens."""
     found = collections.Counter(re.findall(r'kernel_name = "([^"]+)"',
                                            lowered.as_text()))
     return {name: found[name]
-            for name in ("moe_gmm", "moe_gmm_nt", "moe_tgmm")}
+            for name in ("moe_gmm", "moe_gmm_nt", "moe_tgmm", "moe_land")}
 
 
 def custom_calls(lowered_text):

@@ -165,12 +165,16 @@ def test_held_rows_says_which_way_each_cell_s_rows_move(smoke):
     outputs: ``3 · 1 · 8 ≥ 17``) has one window of every assignment and
     moves all of them through the sort's permutation; the five other
     cells' windows are ``_window_plan``'s ``W`` rows of their assignments,
-    as many as a step's routing fills, and gather and scatter-add."""
+    as many as a step's routing fills, gathered and — their widths taking
+    the kernels and their tokens cutting into the landing's tiles —
+    landed on their tokens by a grouped transposed product, all ``W`` of
+    them (``moe._lands_by_product``)."""
     rows = {cell: smoke.held_rows(**layer)
             for cell, layer in smoke.HELD_LAYERS.items()}
     assert rows["zaya1_1chip"] == {
         "assignments": 16384, "held_assignments": 7710, "window_rows": 16384,
-        "permuted_assignments": 16384}
+        "permuted_assignments": 16384, "landed_by_product": 0,
+        "form": "permuted"}
     assert rows["keye_1chip"] == rows["sdar_1chip"]
     assert {cell: (r["assignments"], r["held_assignments"], r["window_rows"])
             for cell, r in rows.items() if not r["permuted_assignments"]} == {
@@ -179,6 +183,10 @@ def test_held_rows_says_which_way_each_cell_s_rows_move(smoke):
         "sdar_1chip": (131072, 16384, WINDOW_ROWS["keye_1chip"]),
         "joyaiflash_1chip": (131072, 8192, WINDOW_ROWS["joyaiflash_1chip"]),
         "nemo3super_1chip": (180224, 2816, WINDOW_ROWS["nemo3super_1chip"])}
+    assert {cell: (r["form"], r["landed_by_product"])
+            for cell, r in rows.items() if not r["permuted_assignments"]} == {
+        cell: ("products", WINDOW_ROWS.get(cell, WINDOW_ROWS["keye_1chip"]))
+        for cell in rows if cell != "zaya1_1chip"}
 
 
 # ``moe._window_plan`` at the cells' layers (the table it was fitted to is
@@ -201,7 +209,8 @@ def test_held_windows_phase_times_every_candidate_window(smoke):
     assert moe._window_plan is plan
     assert out["planned"] == {
         "assignments": 1536, "held_assignments": 192, "window_rows": 232,
-        "permuted_assignments": 0}
+        "permuted_assignments": 0, "landed_by_product": 0,
+        "form": "scatter_add"}
     assert set(out["ms_a_layer"]) == {"232", "256", "768"}
     assert all(set(ms) == {"1.0", "3.2"}
                for ms in out["ms_a_layer"].values())

@@ -8,6 +8,8 @@ QK-norm and experts) is held against the benchmark family's
 ``reference_loss``, which is written the same way.
 """
 
+import collections
+import inspect
 import json
 import os
 
@@ -23,6 +25,8 @@ from benchmark.families import olmoe_lm
 from horovod_tpu.jax.spmd import make_train_step
 from horovod_tpu.layer_notes import noting_layers
 from horovod_tpu.metrics import registry
+from horovod_tpu.ops.grouped_matmul import GroupedPlan, grouped_plan
+from horovod_tpu.parallel import moe
 from horovod_tpu.parallel.moe import (
     DroplessMoE, _pad_hidden, router_losses)
 
@@ -248,27 +252,159 @@ def row_scatter_adds(jaxpr, width: int) -> int:
                and jnp.issubdtype(a.dtype, jnp.floating) for a in operands)
 
 
-@pytest.mark.parametrize("held,permuted", [((1, 2), True), ((1, 1), False)],
-                         ids=["every_assignment", "a_smaller_window"])
-def test_only_a_smaller_window_scatter_adds_rows(held, permuted):
+def tiling_held_layer():
+    """A held layer whose windows tile — 2,048 tokens of width 128, top-2
+    of 16 experts 128 wide, 2 of them held: windows of 512 sorted rows of
+    the 4,096, bfloat16 — so the grouped matmuls' plan takes the
+    (interpreted) kernels."""
+    layer = DroplessMoE(num_experts=16, hidden=128, top_k=2, held=(0, 2))
+    x = jax.random.normal(jax.random.PRNGKey(2), (2048, 128), jnp.bfloat16)
+    return layer, layer.init(jax.random.PRNGKey(3), x)["params"], x
+
+
+def ragged_dot_plan(*args, **kwargs):
+    return GroupedPlan("ragged_dot", 0, 0, 0, 0, 256)
+
+
+@pytest.mark.parametrize("case", ["every_assignment",
+                                  "a_smaller_window_under_the_kernels",
+                                  "a_smaller_window_under_ragged_dot"])
+def test_only_a_smaller_window_scatter_adds_rows(case, monkeypatch):
     """Forward, replay and backward of a layer whose window is every
     assignment hold no scatter-add of rows (``bincount``'s integer one
-    stays); windows smaller than ``n · k`` keep theirs: the combine's, and
-    in the backward loop the one that lands the gathered rows' cotangent.
-    The layers' notes say which form ran."""
-    layer, params, x = held_layer(1, True, held)
+    stays).  Nor do windows smaller than ``n · k`` where the grouped
+    matmuls' plan takes the kernels: their rows land on their tokens, both
+    ways, through a grouped transposed product (``moe_land``, twice), and
+    every weight gradient is summed onto its carry by ``moe_tgmm`` handed
+    it.  Under ``lax.ragged_dot`` such windows keep their scatter-adds: the
+    combine's, and in the backward loop the one that lands the gathered
+    rows' cotangent.  The layers' notes say which form ran."""
+    if case == "every_assignment":
+        layer, params, x = held_layer(1, True, (1, 2))
+    else:
+        layer, params, x = tiling_held_layer()
+        if case.endswith("ragged_dot"):
+            monkeypatch.setattr("horovod_tpu.parallel.moe.grouped_plan",
+                                ragged_dot_plan)
     noted = {}
 
     def loss(p, x):
-        return weighed(layer.apply({"params": p}, x)[0])
+        return weighed(layer.apply({"params": p}, x)[0].astype(jnp.float32))
 
     jaxpr = jax.make_jaxpr(noting_layers(
         jax.value_and_grad(loss, argnums=(0, 1)), noted))(params, x)
-    found = row_scatter_adds(jaxpr.jaxpr, D)
-    assert (found == 0) if permuted else (found >= 2), found
+    found = row_scatter_adds(jaxpr.jaxpr, x.shape[1])
+    kernels = collections.Counter(
+        eqn.params["name"] for eqn in equations(jaxpr.jaxpr)
+        if eqn.primitive.name == "pallas_call")
     counters, = noted.values()
-    assert counters["moe.assignments"] == 3 * N
-    assert counters["moe.permuted_assignments"] == (3 * N if permuted else 0)
+    n, k = x.shape[0], layer.top_k
+    assert counters["moe.assignments"] == n * k
+    if case == "every_assignment":
+        assert found == 0 and not kernels
+        assert counters["moe.permuted_assignments"] == n * k
+        assert counters["moe.landed_by_product"] == 0
+        return
+    assert counters["moe.permuted_assignments"] == 0
+    assert counters["moe.window_rows"] == 512
+    if case.endswith("kernels"):
+        assert found == 0
+        # Gate, up and down forward and in the backward loop again, their
+        # input gradients, their weight gradients handed their carries, and
+        # the landing of ``out`` and of ``dx``.
+        assert kernels == {"moe_gmm": 6, "moe_gmm_nt": 3, "moe_tgmm": 3,
+                           "moe_land": 2}
+        handed = [eqn.params["input_output_aliases"]
+                  for eqn in equations(jaxpr.jaxpr)
+                  if eqn.primitive.name == "pallas_call"
+                  and eqn.params["name"] in ("moe_tgmm", "moe_land")]
+        assert all(len(aliases) == 1 for aliases in handed), handed
+        assert counters["moe.landed_by_product"] == 512
+    else:
+        assert found == 2 and not kernels
+        assert counters["moe.landed_by_product"] == 0
+
+
+def window_operands(landed: int, activation: str, products: bool):
+    """``_held_windows``' operands at the tiling layer's shapes with
+    ``landed`` of the 2,048 assignments on the 2 held experts, and what is
+    static of it: windows of 512 rows under the interpreted kernels."""
+    n, d, hid, held, k, W = 1024, 128, 128, 2, 2, 512
+    ks = jax.random.split(jax.random.PRNGKey(landed), 6)
+    flat = jnp.full((n * k,), held).at[
+        jax.random.permutation(ks[0], n * k)[:landed]].set(
+            jax.random.randint(ks[1], (landed,), 0, held))
+    order = jnp.argsort(flat, stable=True)
+    group_sizes = jnp.bincount(flat, length=held + 1)[:held].astype(jnp.int32)
+    ends = jnp.cumsum(group_sizes)
+    x = jax.random.normal(ks[2], (n, d), jnp.bfloat16)
+    gate = jax.random.uniform(ks[3], (n, k), jnp.float32)
+    names = ("w_gate", "w_up", "w_down") if activation == "swiglu" else (
+        "w_up", "w_down")
+    w = {name: jax.random.normal(
+        key, (held, hid, d) if name == "w_down" else (held, d, hid),
+        jnp.float32) / 12 for name, key in zip(names, jax.random.split(ks[4], 3))}
+    plan = grouped_plan(jax.ShapeDtypeStruct((W, d), jnp.bfloat16), held,
+                        hid, interpret=True)
+    assert plan.form == "kernels" and moe._lands_by_product(plan.form, n)
+    static = moe._Held(k, W, activation, jnp.bfloat16, plan, True, products)
+    ct = jax.random.normal(ks[5], (n, d), jnp.float32)
+    return static, (x, gate, w, order, ends, group_sizes, ends[-1]), ct
+
+
+@pytest.mark.parametrize("activation", ["swiglu", "relu2"])
+@pytest.mark.parametrize("landed", [0, 300, 512, 1300],
+                         ids=["nothing_landed", "under_a_window",
+                              "exactly_a_window", "three_windows"])
+def test_the_products_give_what_the_scatter_adds_give(landed, activation):
+    """``_held_windows`` both ways on the same operands, the same kernels
+    under both: ``out`` (float32) and ``dgate`` to the order of a float32
+    sum; ``dx`` to a bfloat16 step of its largest value (it leaves in the
+    tokens' dtype); every ``dW`` to a bfloat16 step — the scatter form
+    rounds a window's share to bfloat16 before its float32 pass, the
+    product form never does."""
+    results = []
+    for products in (True, False):
+        static, operands, ct = window_operands(landed, activation, products)
+        out, pull = jax.vjp(
+            lambda x, gate, w: moe._held_windows(static, x, gate, w,
+                                                 *operands[3:]),
+            *operands[:3])
+        results.append((out, *pull(ct)))
+    (out, dx, dgate, dw), (want_out, want_dx, want_dgate, want_dw) = results
+    assert out.dtype == jnp.float32 and dx.dtype == jnp.bfloat16
+    if landed == 0:
+        for leaf in jax.tree.leaves(results):
+            assert not np.asarray(leaf).any()
+        return
+    assert float(jnp.abs(want_out).max()) > 0
+
+    def rel(got, want):
+        got, want = (np.asarray(a, np.float64) for a in (got, want))
+        return np.abs(got - want).max() / np.abs(want).max()
+
+    assert rel(out, want_out) < 2e-6
+    assert rel(dgate, want_dgate) < 2e-6
+    assert rel(dx, want_dx) <= 2.0 ** -8
+    assert set(dw) == set(want_dw)
+    for name in dw:
+        assert dw[name].dtype == jnp.float32
+        assert rel(dw[name], want_dw[name]) <= 2.0 ** -8, name
+
+
+def test_which_windows_land_by_product_is_a_function_of_shapes():
+    """``_lands_by_product``: the kernels' form and tokens in whole tiles
+    of the landing; no option, environment variable or model's name."""
+    from horovod_tpu.ops.grouped_matmul import LANDING_TOKENS
+
+    assert list(inspect.signature(moe._lands_by_product).parameters) == [
+        "form", "tokens"]
+    assert moe._lands_by_product("kernels", 16_384)
+    assert moe._lands_by_product("kernels", 8_192)
+    assert moe._lands_by_product("kernels", LANDING_TOKENS)
+    assert not moe._lands_by_product("kernels", 16_384 + 8)
+    assert not moe._lands_by_product("ragged_dot", 16_384)
+    assert "environ" not in inspect.getsource(moe)
 
 
 # ------------------------------------------------------- the whole model

@@ -6,6 +6,8 @@ at the cells' widths.  The interpreted tests of the same code are
 ``test_hybrid_experts.py``.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -13,6 +15,15 @@ from jax.sharding import SingleDeviceSharding
 
 from _v5e import (  # noqa: F401
     NEMO3_WINDOW, custom_calls, kernels_by_name, v5e)
+
+
+def body_computations(compiled_text):
+    """The text of every ``while`` body of a compiled program."""
+    names = set(re.findall(r"body=%?([\w.\-]+)", compiled_text))
+    for block in re.split(r"\n\n", compiled_text):
+        head = re.match(r"\s*%?([\w.\-]+) ", block)
+        if head and head.group(1) in names:
+            yield block
 
 
 def test_dropless_expert_layer_fwd_bwd_at_olmoe_widths(v5e, monkeypatch):
@@ -46,7 +57,7 @@ def test_dropless_expert_layer_fwd_bwd_at_olmoe_widths(v5e, monkeypatch):
     lowered = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
         params, x)
     assert kernels_by_name(lowered) == {"moe_gmm": 3, "moe_gmm_nt": 3,
-                                        "moe_tgmm": 3}
+                                        "moe_tgmm": 3, "moe_land": 0}
     compiled = lowered.compile()
     text = compiled.as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 9
@@ -103,12 +114,65 @@ def test_grouped_matmuls_compile_wherever_the_plan_takes_the_kernels(
     lowered = jax.jit(product_and_gradients).lower(
         x, w, dy, shape(groups, dtype=jnp.int32))
     assert kernels_by_name(lowered) == {
-        "moe_gmm": 1, "moe_gmm_nt": 1, "moe_tgmm": 1}
+        "moe_gmm": 1, "moe_gmm_nt": 1, "moe_tgmm": 1, "moe_land": 0}
     compiled = lowered.compile()
     y, (dx, dw) = compiled.out_info
     assert (y.shape, dx.shape, dw.shape) == ((rows, n), (rows, k),
                                              (groups, k, n))
     assert y.dtype == dx.dtype == dw.dtype == jnp.bfloat16
+
+
+# (W rows a window, n tokens, d width, padded hidden width, held experts):
+# the windows of the five cells whose rows land by product.
+LANDINGS = {"keye_and_sdar_1chip": (16_384, 16_384, 2048, 768, 16),
+            "joyaiflash_1chip": (10_752, 16_384, 2048, 768, 16),
+            "twotower_1chip": (7_680, 16_384, 2688, 1920, 8),
+            "nemo3super_1chip": (5_632, 8_192, 1024, 2688, 8)}
+
+
+@pytest.mark.parametrize("cell", LANDINGS)
+def test_the_landing_and_the_handed_block_compile_at_the_cells_windows(
+        v5e, cell):
+    """What a held window adds to the family at the four shapes the cells
+    run: the landing of a window's rows on (n, d) float32 — gated, as the
+    output's, and bare, as ``dx``'s — and ``moe_tgmm`` handed a float32
+    carry, up and down.  Each writes the buffer it was handed (the
+    program aliases every donated block and plans no temporary of a
+    block's size)."""
+    from horovod_tpu.ops import grouped_matmul as gm
+
+    W, n, d, hidden, held = LANDINGS[cell]
+    one = SingleDeviceSharding(v5e[0])
+
+    def shape(*dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one)
+
+    plan = gm.grouped_plan(shape(W, d), held, hidden, interpret=False)
+    assert plan.form == "kernels" and n % gm.LANDING_TOKENS == 0
+
+    def sums(out, dx, up, down, rows, token, gate, x, h, sizes):
+        return (gm.landed_rows(out, rows, token, gate, plan=plan),
+                gm.landed_rows(dx, rows, token, plan=plan),
+                gm._tgmm(x, h, sizes, up, plan=plan, interpret=False),
+                gm._tgmm(h, x, sizes, down, plan=plan, interpret=False))
+
+    f32 = jnp.float32
+    blocks = (shape(n, d, dtype=f32), shape(n, d, dtype=f32),
+              shape(held, d, hidden, dtype=f32),
+              shape(held, hidden, d, dtype=f32))
+    lowered = jax.jit(sums, donate_argnums=(0, 1, 2, 3)).lower(
+        *blocks, shape(W, d), shape(W, dtype=jnp.int32),
+        shape(W, dtype=f32), shape(W, d), shape(W, hidden),
+        shape(held, dtype=jnp.int32))
+    assert kernels_by_name(lowered) == {
+        "moe_gmm": 0, "moe_gmm_nt": 0, "moe_tgmm": 2, "moe_land": 2}
+    compiled = lowered.compile()
+    assert [(o.shape, o.dtype) for o in compiled.out_info] == [
+        (b.shape, f32) for b in blocks]
+    m = compiled.memory_analysis()
+    handed = sum(4 * b.size for b in blocks)
+    assert m.alias_size_in_bytes == handed, (m.alias_size_in_bytes, handed)
+    assert m.temp_size_in_bytes < min(4 * b.size for b in blocks)
 
 
 # A held layer of three cells as its family calls it: (tokens, width, the
@@ -171,14 +235,25 @@ def test_held_expert_layer_fwd_bwd_at_the_cells_widths(v5e, monkeypatch,
 
     lowered = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
         params, x)
+    # The landing of ``out`` in the forward loop and of ``dx`` in the
+    # backward one; each weight gradient's kernel is handed its carry.
     assert kernels_by_name(lowered) == {
         "moe_gmm": 2 * matrices, "moe_gmm_nt": matrices,
-        "moe_tgmm": matrices}
+        "moe_tgmm": matrices, "moe_land": 2}
     assert "stablehlo.case" not in lowered.as_text()
     compiled = lowered.compile()
     text = compiled.as_text()
     W, padded = window.rows, hidden + -hidden % 128
     assert "ragged-dot" not in text
+    # No scatter-add of rows is left, and the loops update their float32
+    # carries in place: no copy of the (n, d) accumulator nor of a weight
+    # gradient's carry inside a loop's body.
+    assert not re.search(rf"f32\[{tokens},{d}\]\S* scatter\(", text)
+    bodies = "\n".join(body_computations(text))
+    assert "tpu_custom_call" in bodies
+    for carry in (f"f32[{tokens},{d}]", f"f32[{held},{d},{padded}]",
+                  f"f32[{held},{padded},{d}]"):
+        assert not re.search(re.escape(carry) + r"\S* copy\(", bodies), carry
     assert f"{W},{d}" in text and f"{assignments},{d}" not in text
     assert f"bf16[{W},{padded}]" in text
     assert f"f32[{held},{d},{padded}]" in text
@@ -235,7 +310,8 @@ def test_a_zaya_layer_fwd_bwd_at_zaya_widths(v5e, monkeypatch):
     found = kernels_by_name(lowered)
     # Up, gate and down forward, their replay in the checkpoint, and the
     # input and weight gradients: one window, so each exactly once.
-    assert found == {"moe_gmm": 6, "moe_gmm_nt": 3, "moe_tgmm": 3}, found
+    assert found == {"moe_gmm": 6, "moe_gmm_nt": 3, "moe_tgmm": 3,
+                     "moe_land": 0}, found
     # The grid forward and, since PR 44, the backward as one kernel a KV
     # group (the per-head pair ``_dkdv_kernel``, ``_dq_kernel`` before).
     # The latent's passes: the forward reads the two projections' arrays
@@ -296,7 +372,7 @@ def test_a_latent_expert_layer_fwd_bwd_at_nemotron3_widths(v5e, monkeypatch):
     # Up and down in the forward loop and again in the backward one, two
     # input and two weight gradients.
     assert kernels_by_name(lowered) == {
-        "moe_gmm": 4, "moe_gmm_nt": 2, "moe_tgmm": 2}
+        "moe_gmm": 4, "moe_gmm_nt": 2, "moe_tgmm": 2, "moe_land": 2}
     W = NEMO3_WINDOW
     compiled = lowered.compile()
     text = compiled.as_text()

@@ -3,8 +3,10 @@
 the custom VJP against a float32 loop over the groups on seeded rows, for
 group sizes that meet every edge of the tiling; bfloat16 against the same
 loop beside what ``lax.ragged_dot`` reads there; the float32 accumulators,
-held in the kernels' jaxprs; the one function that chooses a form, as a
-table; and the ``moe.fused_matmuls`` counter.
+held in the kernels' jaxprs; the weight gradient handed a float32 block to
+add to, and the same walk landing rows on their tokens, against the
+scatter-add; the one function that chooses a form, as a table; and the
+``moe.fused_matmuls`` counter.
 """
 
 import inspect
@@ -125,8 +127,10 @@ def test_the_custom_vjp_equals_the_loop_s_gradients(case):
         assert rel(got, ref) < 2e-6, (case, name)
 
 
-@pytest.mark.parametrize("rows,strip,cols", [(64, 64, 128), (128, 32, 256),
-                                             (256, 128, 128), (32, 16, 128)])
+TILINGS = [(64, 64, 128), (128, 32, 256), (256, 128, 128), (32, 16, 128)]
+
+
+@pytest.mark.parametrize("rows,strip,cols", TILINGS)
 def test_every_tiling_gives_the_same_products(rows, strip, cols):
     """Rows a tile, rows a strip and columns a block change the walk, not
     the answer."""
@@ -135,6 +139,119 @@ def test_every_tiling_gives_the_same_products(rows, strip, cols):
     for got, ref in zip(three_products(x, w, dy, sizes, plan),
                         loop(x, w, dy, sizes)):
         assert rel(got, ref) < 2e-6
+
+
+def handed_block(sizes, seed=7):
+    return jax.random.normal(jax.random.PRNGKey(seed),
+                             (len(sizes), K, N), F32)
+
+
+@pytest.mark.parametrize("case", [
+    "uneven", "even_on_the_tiles", "a_zero_group_first_inside_and_last",
+    "trailing_empty_rows", "one_group_takes_all", "no_group_has_a_row",
+    "more_groups_than_tiles"])
+def test_a_handed_block_is_added_to(case):
+    """``_tgmm`` handed a float32 block gives the block plus what it gives
+    with none — ragged, empty and whole groups; an empty group's block
+    comes back as it went in — in float32 whatever the operands' dtype,
+    where the block-less call rounds to theirs."""
+    for dtype in (F32, jnp.bfloat16):
+        x, _, dy, sizes = operands(SIZES[case], dtype)
+        block = handed_block(SIZES[case])
+        got = gm._tgmm(x, dy, sizes, block, plan=SMALL, interpret=True)
+        bare = gm._tgmm(x, dy, sizes, plan=SMALL, interpret=True)
+        assert (got.dtype, bare.dtype) == (F32, dtype)
+        x64, dy64 = (np.asarray(a.astype(F32), np.float64) for a in (x, dy))
+        want, start = np.asarray(block, np.float64).copy(), 0
+        for g, size in enumerate(SIZES[case]):
+            want[g] += x64[start:start + size].T @ dy64[start:start + size]
+            start += size
+        assert rel(got, want) < 2e-6, case
+        if dtype == F32:
+            assert rel(got, block + bare) < 2e-6, case
+        for g, size in enumerate(SIZES[case]):
+            if size == 0:
+                np.testing.assert_array_equal(got[g], block[g])
+
+
+@pytest.mark.parametrize("rows,strip,cols", TILINGS)
+def test_every_tiling_adds_to_the_handed_block_alike(rows, strip, cols):
+    x, _, dy, sizes = operands(SIZES["a_group_shorter_than_a_strip"], F32)
+    plan = GroupedPlan("kernels", rows, strip, cols, 0, 128)
+    block = handed_block(sizes)
+    assert rel(gm._tgmm(x, dy, sizes, block, plan=plan, interpret=True),
+               block + gm._tgmm(x, dy, sizes, plan=plan, interpret=True)
+               ) < 2e-6
+
+
+# Tokens of the 256 rows of a window over 512 tokens in tiles of 128; a
+# token past the last (512) is a row that landed nowhere.
+def landing_tokens(case):
+    key = jax.random.PRNGKey(11)
+    if case == "tokens_repeat_inside_the_window":
+        token = jax.random.randint(key, (M,), 0, 40)
+    elif case == "token_tiles_with_no_row":
+        token = jnp.where(jax.random.bernoulli(key, 0.5, (M,)),
+                          jax.random.randint(key, (M,), 0, 128),
+                          jax.random.randint(key, (M,), 384, 512))
+    elif case == "rows_past_landed":
+        token = jax.random.randint(key, (M,), 0, 512).at[150:].set(512)
+    elif case == "no_row_landed":
+        token = jnp.full((M,), 512)
+    else:
+        assert case == "one_token_takes_every_row"
+        token = jnp.full((M,), 129)
+    return jnp.sort(token)
+
+
+@pytest.mark.parametrize("gated", [True, False], ids=["gated", "rows"])
+@pytest.mark.parametrize("case", [
+    "tokens_repeat_inside_the_window", "token_tiles_with_no_row",
+    "rows_past_landed", "no_row_landed", "one_token_takes_every_row"])
+def test_the_landing_equals_the_scatter_add(case, gated):
+    """The walk with the selection as its left operand, over token tiles:
+    a window's bfloat16 rows, sorted by token, land on the float32 block
+    as ``block.at[token].add(rows.astype(float32) * gate)`` lands them, to
+    float32 rounding (the order of a sum apart) — the gate never rounded
+    below float32."""
+    n = 512
+    ks = jax.random.split(jax.random.PRNGKey(3), 3)
+    rows = jax.random.normal(ks[0], (M, N), F32).astype(jnp.bfloat16)
+    # Gates with all 24 bits in use.
+    gate = jax.random.uniform(ks[1], (M,), F32, 0.01, 1.0) if gated else None
+    block = jax.random.normal(ks[2], (n, N), F32)
+    token = landing_tokens(case)
+    scaled = np.asarray(rows.astype(F32), np.float64) * (
+        np.asarray(gate, np.float64)[:, None] if gated else 1.0)
+    want = np.asarray(block, np.float64).copy()
+    np.add.at(want, np.asarray(token)[np.asarray(token) < n],
+              scaled[np.asarray(token) < n])
+    got = gm.landed_rows(block, rows, token, gate, plan=SMALL, interpret=True)
+    assert got.dtype == F32 and got.shape == (n, N)
+    assert rel(got, want) < 2e-6, case
+    theirs = block.at[token].add(
+        rows.astype(F32) * (gate[:, None] if gated else 1.0), mode="drop")
+    assert rel(got, theirs) < 2e-6, case
+    untouched = np.setdiff1d(np.arange(n), np.asarray(token))
+    np.testing.assert_array_equal(np.asarray(got)[untouched],
+                                  np.asarray(block)[untouched])
+
+
+def test_the_gradients_called_directly_are_the_op_s_own():
+    """``grouped_gradients`` is the op's backward rule: the same two
+    transposes, the weight gradient's handed its block where one is
+    given."""
+    x, w, dy, sizes = operands(SIZES["uneven"], jnp.bfloat16)
+    block = handed_block(sizes)
+    dx, dw = gm.grouped_gradients(x, w, dy, sizes, SMALL, interpret=True,
+                                  block=block)
+    _, theirs = jax.vjp(lambda x, w: gm._fused(x, w, sizes, SMALL, True),
+                        x, w)
+    want_dx, want_dw = theirs(dy)
+    np.testing.assert_array_equal(dx, want_dx)
+    assert dw.dtype == F32 and rel(dw, block + want_dw.astype(F32)) < 2.0 ** -8
+    bare = gm.grouped_gradients(x, w, dy, sizes, SMALL, interpret=True)[1]
+    np.testing.assert_array_equal(bare, want_dw)
 
 
 @pytest.mark.parametrize("case", ["uneven", "more_groups_than_tiles",
@@ -198,6 +315,78 @@ def test_the_accumulators_are_float32_and_the_operands_stay_narrow():
     assert (scratch.shape, scratch.dtype) == ((128, 128), F32)
     # The weight gradient leaves in the operands' dtype: one rounding.
     assert calls["moe_tgmm"].outvars[0].aval.dtype == jnp.bfloat16
+
+
+def landing_calls():
+    ks = jax.random.split(jax.random.PRNGKey(9), 3)
+    rows = jax.random.normal(ks[0], (M, N), jnp.bfloat16)
+    gate = jax.random.uniform(ks[1], (M,), F32)
+    token = jnp.sort(jax.random.randint(ks[2], (M,), 0, 512))
+    calls = {}
+    for name, g in (("gated", gate), ("rows", None)):
+        jaxpr = jax.make_jaxpr(lambda block: gm.landed_rows(
+            block, rows, token, g, plan=SMALL, interpret=True))(
+                jnp.zeros((512, N), F32))
+        calls[name], = (e for e in _equations(jaxpr.jaxpr)
+                        if e.primitive.name == "pallas_call")
+    return calls
+
+
+def test_the_landing_s_gate_and_accumulator_are_float32():
+    """Twin of the test above for the landing and for the weight gradient
+    handed its block: the gate enters the kernel in float32 and is taken
+    apart there into three bfloat16 parts that sum to it exactly (no
+    ``mul`` rounds a gated row: a part is SELECTED into the left operand,
+    and a bfloat16 part times a bfloat16 row is exact in float32); every
+    product takes bfloat16 operands and gives float32; the handed block,
+    the scratch and what is written are float32, the block the output's own
+    buffer."""
+    calls = landing_calls()
+    x, _, dy, sizes = operands(SIZES["uneven"], jnp.bfloat16)
+    jaxpr = jax.make_jaxpr(lambda block: gm._tgmm(
+        x, dy, sizes, block, plan=SMALL, interpret=True))(
+            jnp.zeros((4, K, N), F32))
+    calls["handed"], = (e for e in _equations(jaxpr.jaxpr)
+                        if e.primitive.name == "pallas_call")
+    assert [c.params["name"] for c in calls.values()] == [
+        "moe_land", "moe_land", "moe_tgmm"]
+    for name, call in calls.items():
+        eqns = list(_equations(call.params["jaxpr"]))
+        dots = [e for e in eqns if e.primitive.name == "dot_general"]
+        assert len(dots) == {"gated": 3, "rows": 1, "handed": 2}[name]
+        for e in dots:
+            assert all(v.aval.dtype == jnp.bfloat16 for v in e.invars), name
+            assert e.outvars[0].aval.dtype == F32, name
+        for e in eqns:
+            if e.primitive.name in ("add", "add_any", "sub", "mul"):
+                assert all(getattr(v.aval, "dtype", F32) != jnp.bfloat16
+                           for v in (*e.invars, *e.outvars)), (name, e)
+        # No floating product but the MXU's (the walk multiplies indices).
+        assert not any(e.primitive.name == "mul" and jnp.issubdtype(
+            e.outvars[0].aval.dtype, jnp.floating) for e in eqns), name
+        # The block is the operand before the output; they share a buffer.
+        operands_, out = call.invars, call.outvars[0].aval
+        assert call.params["input_output_aliases"] == (
+            (len(operands_) - 1, 0),)
+        assert operands_[-1].aval.dtype == out.dtype == F32
+        assert operands_[-1].aval.shape == out.shape
+        scratch = call.params["jaxpr"].invars[-1].aval
+        assert scratch.dtype == F32 and scratch.shape == (
+            (128, 128) if name == "handed" else out.shape[1:]), name
+    gate = calls["gated"].invars[4].aval
+    assert (gate.shape, gate.dtype) == ((1, M), F32)
+
+
+def test_three_bfloat16_parts_hold_every_bit_of_a_gate():
+    gate = jnp.concatenate([
+        jax.random.uniform(jax.random.PRNGKey(0), (4096,), F32),
+        jnp.asarray([0.0, 1.0, 2.0 ** -20, 1 - 2.0 ** -24, 5.0, 1e-30])])
+    parts = gm._gate_parts(gate)
+    assert all((p.astype(jnp.bfloat16).astype(F32) == p).all()
+               for p in parts)
+    np.testing.assert_array_equal(
+        np.sum([np.asarray(p, np.float64) for p in parts], axis=0),
+        np.asarray(gate, np.float64))
 
 
 def test_the_walk_lists_every_pair_once_and_every_group():

@@ -75,8 +75,12 @@ def test_each_kernel_is_traced_once_a_step_not_once_a_layer(monkeypatch,
     top-2: windows of 512 sorted rows, as many as the landed assignments
     fill).  The loop's body is traced once each way: up and down forward,
     and in the backward loop again, read transposed for the input
-    gradients and the weight gradient's twice — and every layer leaves
-    those eight kernels, whatever the windows a step runs.
+    gradients; the weight gradient's body four times — up and down handed
+    their float32 carries, and as the landing of a window's rows on their
+    tokens, the output's under its gate and ``dx``'s bare (PR 57: the
+    same walk, the selection of the rows' tokens its left operand) — and
+    every layer leaves those ten kernels (``moe_gmm`` 4, ``moe_gmm_nt``
+    2, ``moe_tgmm`` 2, ``moe_land`` 2), whatever the windows a step runs.
 
     ``selected``: two sparse-attention layers of a ``KeyeLM`` (8 query
     heads over one KV head of 128, 32 of up to 256 keys a query).  The
@@ -204,7 +208,9 @@ def test_each_kernel_is_traced_once_a_step_not_once_a_layer(monkeypatch,
             dtype=jnp.bfloat16, moe_experts=16, moe_top_k=2, moe_hidden=128,
             moe=dict(router="sigmoid", renormalize=True, activation="relu2",
                      held=(0, 2)))
-        want = {"_gmm_kernel": 6, "_tgmm_kernel": 2}
+        # The weight gradient's body besides as the landing: the output's
+        # (gated) and ``dx``'s.
+        want = {"_gmm_kernel": 6, "_tgmm_kernel": 4}
     else:
         seq = 256
         model = NemotronHLM(vocab=512, dim=256, pattern="MMMM", max_len=seq,
@@ -280,7 +286,7 @@ def test_each_kernel_is_traced_once_a_step_not_once_a_layer(monkeypatch,
         return
     if family == "held_windows":
         assert collections.Counter(name for name, _ in found) == {
-            "moe_gmm": 8, "moe_gmm_nt": 4, "moe_tgmm": 4}
+            "moe_gmm": 8, "moe_gmm_nt": 4, "moe_tgmm": 4, "moe_land": 4}
         return
     if family != "flash":
         # Four mixers: the scan's forward, and in the backward its states
@@ -440,8 +446,8 @@ def test_the_nemo3super_cell_s_step_lowers_with_every_kernel_family(
     import re
     assert set(re.findall(r'kernel_name = "([^"]+)"', text)) == {
         "_fwd_kernel", "flash_group_bwd", "moe_gmm", "moe_gmm_nt",
-        "moe_tgmm", "ssd_bwd", "ssd_fwd", "ssd_states", "ssm_conv_bwd",
-        "ssm_conv_fwd", "ssm_gate_bwd", "ssm_gate_fwd"}
+        "moe_land", "moe_tgmm", "ssd_bwd", "ssd_fwd", "ssd_states",
+        "ssm_conv_bwd", "ssm_conv_fwd", "ssm_gate_bwd", "ssm_gate_fwd"}
     assert "stablehlo.all_reduce" not in text
     assert "8192x2432xbf16" in text            # the padded input projection
     assert f"{NEMO3_WINDOW}x1024xbf16" in text  # a window, in the latent
