@@ -45,6 +45,14 @@ through the entry points a user calls (``hvd.init()`` →
   visited against the tiles live (``block_mask``, ``bd_plan``), times the
   causal mask on the same rows beside them, and checks output and gradients
   against the dense oracle under the boolean mask at 1,024 tokens;
+* times the flash kernels under the causal window alone at
+  ``lagunaxs2_1chip``'s windowed layer — one sequence of 8,192, 64 query
+  heads over 8 KV heads, 512 keys a query — at tiles of 256, 512 and 1,024
+  (``window_mask``, ``win_plan``; ``--window-mask`` runs this phase alone),
+  the global kind's causal call at 6 query heads a KV head beside them,
+  checks output and gradients against the dense oracle under the boolean
+  window at 1,024 tokens and, at the layer's own shape and tiles, the
+  window's two edges on scores peaked there (``edges``);
 * times the flash kernels of one latent-attention layer alone at
   ``joyaiflash_1chip``'s shape — keys of 192 against values of 128 — with
   every operand padded to one width, with values at their own width
@@ -139,7 +147,9 @@ HELD_LAYERS = {
                              num_experts=256, held=16, top_k=8),
     "nemo3super_1chip": dict(tokens=8192, dim=4096, hidden=2688,
                              num_experts=512, held=8, top_k=22,
-                             activation="relu2", latent=1024)}
+                             activation="relu2", latent=1024),
+    "lagunaxs2_1chip": dict(tokens=8192, dim=2048, hidden=512,
+                            num_experts=256, held=32, top_k=8)}
 # What a held layer is timed at: the assignments that land on the held
 # experts, in units of what uniform routing sends them, and the candidate
 # rows of a window in the same units (``moe._window_plan`` was fitted to
@@ -174,6 +184,11 @@ GROUPED_BACKWARD = {
 # which they are checked against the dense oracle under the boolean mask.
 BLOCK_MASK = dict(batch=1, seq=8192, heads=32, kv_heads=4, head_dim=128,
                   block=4, check_seq=1024)
+# The two kinds of attention layer of lagunaxs2_1chip alone: one sequence of
+# 8,192, 8 KV heads of 128; the windowed kind's 64 query heads under a window
+# of 512 keys, the global kind's 48 under the causal mask (6 a KV head).
+WINDOW_MASK = dict(batch=1, seq=8192, heads=64, global_heads=48, kv_heads=8,
+                   head_dim=128, window=512, check_seq=1024)
 # One latent-attention layer's flash kernels alone at joyaiflash_1chip's
 # shape: 32 heads, keys of 192 (128 | 64) against values of 128, two
 # sequences of 8,192.
@@ -225,6 +240,10 @@ DELTA_TOL = 4e-2
 # PR 37, seed 0: 2.7e-3 and up to 6.6e-3, a KV group a grid step and a
 # query head a step alike, to the last printed digit).
 SELECT_TOL = 2e-2
+# The window's edges under peaked scores (``_window_edges``): bfloat16
+# probabilities of a near one-hot softmax; a window one key off reads 0.5 or
+# more.
+EDGE_TOL = 4e-2
 LOSS_TOL = 2e-2          # 4-device vs 1-device loss, same step
 INT8_LOSS_TOL = 2e-2     # int8 wire vs fp32 wire loss, same step
 
@@ -1314,6 +1333,180 @@ def block_mask_phase(*, batch: int, seq: int, heads: int, kv_heads: int,
             "against_dense": {n: round(e, 6) for n, e in errs.items()}}
 
 
+def _window_edges(flash, *, batch: int, seq: int, heads: int, kv_heads: int,
+                  head_dim: int, window: int, step: int, seed: int) -> dict:
+    """The window's two edges at the timed shape: ``flash`` (q, k, v) under
+    ``("window", window)`` on scores PEAKED on four keys of every query
+    ``i`` — ``i`` and ``i - window + 1``, the last keys inside, and ``i + 1``
+    and ``i - window``, the first ones outside — so that a window one key
+    too wide or too narrow, or a causal edge one key off, moves the output
+    by its own size.  The oracle is the dense one under the boolean window
+    on slices of ``2 step`` rows (a row's keys lie within ``step`` rows
+    before it), whole sequence: ``o``, ``dq``, ``dk``, ``dv`` against it, and
+    ``o`` against the oracles of ``window + 1`` and ``window - 1`` keys,
+    which it has to MISS (``missed``)."""
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.parallel.ring_attention import full_attention
+
+    B, T, H, Hkv, D, W = batch, seq, heads, kv_heads, head_dim, window
+    rep = H // Hkv
+    check(W <= step and T % step == 0, f"slices of {step} rows under a "
+          f"window of {W} over {T} rows")
+    ks = jax.random.split(jax.random.PRNGKey(seed + 1), 3)
+    code = jax.random.normal(ks[0], (B, T + W + 1, Hkv, D))
+    code = code / jnp.linalg.norm(code, axis=-1, keepdims=True)
+    # A peak scores 12, the other keys 2 apart: the four hold 98% or more.
+    size = (12.0 * D ** 0.5) ** 0.5
+    k = (size * code[:, W:W + T]).astype(jnp.bfloat16)
+    q = size * sum(code[:, at:at + T] for at in (W, W + 1, 1, 0))
+    q = jnp.repeat(q, rep, axis=2).astype(jnp.bfloat16)
+    v = jax.random.normal(ks[1], (B, T, Hkv, D)).astype(jnp.bfloat16)
+    do = jax.random.normal(ks[2], (B, T, H, D)).astype(jnp.bfloat16)
+
+    def dense(w):
+        def run(q, k, v):
+            return full_attention(q, jnp.repeat(k, rep, 2),
+                                  jnp.repeat(v, rep, 2), mask=("window", w))
+        return run
+
+    @functools.partial(jax.jit, static_argnames=("w", "fresh"))
+    def of_slice(q, k, v, do, w, fresh):
+        # ``fresh`` rows at the slice's end are this slice's own.
+        own = (jnp.arange(q.shape[1]) >= q.shape[1] - fresh)[None, :, None,
+                                                              None]
+        o, pull = jax.vjp(dense(w), q, k, v)
+        return (o, *pull(jnp.where(own, do, 0)))
+
+    def oracle(w, grads: bool):
+        o = jnp.zeros((B, T, H, D), jnp.float32)
+        dq = jnp.zeros((B, T, H, D), jnp.float32)
+        dk = jnp.zeros((B, T, Hkv, D), jnp.float32)
+        dv = jnp.zeros((B, T, Hkv, D), jnp.float32)
+        for a in range(0, T, step):
+            lo = max(a - step, 0)
+            rows = slice(lo, a + step)
+            got = of_slice(q[:, rows], k[:, rows], v[:, rows], do[:, rows],
+                           w=w, fresh=step)
+            o = o.at[:, a:a + step].set(got[0][:, a - lo:])
+            if not grads:
+                continue
+            dq = dq.at[:, a:a + step].set(got[1][:, a - lo:])
+            dk = dk.at[:, rows].add(got[2].astype(jnp.float32))
+            dv = dv.at[:, rows].add(got[3].astype(jnp.float32))
+        return o, dq, dk, dv
+
+    got = jax.jit(lambda *a: (flash(*a[:3]),
+                              *jax.vjp(flash, *a[:3])[1](a[3])))(q, k, v, do)
+    errs = {name: _rel_err(g, w) for name, g, w in zip(
+        ("o", "dq", "dk", "dv"), got, oracle(W, True))}
+    missed = {f"window_{w}": _rel_err(got[0], oracle(w, False)[0])
+              for w in (W + 1, W - 1)}
+    for name, e in errs.items():
+        check(e <= EDGE_TOL, f"flash under the window, scores peaked on its "
+              f"edges, differs from the dense oracle in {name} by {e:.3g} "
+              f"(bound {EDGE_TOL})")
+    for name, e in missed.items():
+        check(e >= 5 * EDGE_TOL, f"the edge check would not tell {name}: the "
+              f"output differs from that oracle's by {e:.3g} only")
+    return {"against_dense": {n: round(e, 6) for n, e in errs.items()},
+            "missed": {n: round(e, 4) for n, e in missed.items()}}
+
+
+def window_mask_phase(*, batch: int, seq: int, heads: int, global_heads: int,
+                      kv_heads: int, head_dim: int, window: int,
+                      check_seq: int, seed: int, calls: int = 10,
+                      blocks=(256, 512, 1024), edge_step: int = 1024) -> dict:
+    """The flash kernels under the causal window, alone, at one windowed
+    layer's shape: ``win_plan`` — what ``flash_attention._plan`` decides on
+    this device under the block ``_mask_auto_block`` gives —, the tiles a
+    query head's forward visits against those that hold a live pair, the
+    forward's and the backward's time at each of ``blocks`` (``ms_a_layer``:
+    ``window.<block>``), beside them the GLOBAL kind's call —
+    ``global_heads`` query heads under the causal mask, ``global_plan`` —,
+    at ``check_seq`` tokens output and gradients against the dense oracle
+    under the window as a boolean matrix, and AT THE LAYER'S OWN SHAPE AND
+    TILES the window's two edges (``edges``: :func:`_window_edges`)."""
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.ops import flash_attention as fa
+    from horovod_tpu.parallel.ring_attention import full_attention
+
+    interpret = jax.default_backend() != "tpu"
+    B, H, Hkv, D = batch, heads, kv_heads, head_dim
+    mask = ("window", window)
+
+    def operands(T, heads_):
+        ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+        return [jax.random.normal(key, (B, T, h, D)).astype(jnp.bfloat16)
+                for key, h in zip(ks, (heads_, Hkv, Hkv, heads_))]
+
+    def flash(**how):
+        def run(q, k, v):
+            return fa.flash_attention(q, k, v, interpret=interpret, **how)
+        return run
+
+    def backward(fn):
+        return lambda q, k, v, do: jax.vjp(fn, q, k, v)[1](do)
+
+    def plan_of(held, blk, heads_):
+        return fa._plan_for(
+            jax.ShapeDtypeStruct((B, seq, heads_ * D), jnp.bfloat16), heads_,
+            D, (0, 0, 0), held, blk, blk, blk, blk, interpret,
+            kv_rep=heads_ // Hkv)._asdict()
+
+    q, k, v, do = operands(seq, H)
+    blk = fa._mask_auto_block(seq, mask)
+    counts = fa.mask_tile_counts(q, k, mask)
+    check(counts["visited_tiles"] >= counts["live_tiles"],
+          f"the forward visits {counts['visited_tiles']} tiles, "
+          f"{counts['live_tiles']} hold a live pair")
+    timed = functools.partial(_timed_ms, calls, interpret)
+    ms = {}
+
+    def time_both(name, fn, *args):
+        ms[f"{name}.forward"], _ = timed(fn, *args[:3])
+        both, _ = timed(backward(fn), *args)
+        ms[f"{name}.backward"] = (
+            None if both is None else round(both - ms[f"{name}.forward"], 3))
+
+    for b in blocks:
+        if seq % b == 0:
+            time_both(f"window.{b}", flash(mask=mask, block_q=b, block_k=b),
+                      q, k, v, do)
+    wide = operands(seq, global_heads)
+    time_both("global", flash(causal=True), *wide)
+    # Against the dense oracle, where its (T, T) scores fit.
+    q, k, v, do = operands(check_seq, H)
+    rep = H // Hkv
+
+    def dense(q, k, v):
+        return full_attention(q, jnp.repeat(k, rep, 2), jnp.repeat(v, rep, 2),
+                              mask=mask)
+
+    errs = {}
+    got = jax.jit(lambda *a: (flash(mask=mask)(*a[:3]),
+                              *backward(flash(mask=mask))(*a)))(q, k, v, do)
+    want = jax.jit(lambda *a: (dense(*a[:3]), *backward(dense)(*a)))(
+        q, k, v, do)
+    for name, g, w in zip(("o", "dq", "dk", "dv"), got, want):
+        errs[name] = _rel_err(g, w)
+        check(errs[name] <= SELECT_TOL,
+              f"flash under the window differs from the dense oracle in "
+              f"{name} by {errs[name]:.3g} (bound {SELECT_TOL})")
+    return {"shape": [B, seq, H, Hkv, D], "window": window, "block": blk,
+            "interpret": interpret,
+            "win_plan": plan_of(fa.Window(window), blk, H),
+            "global_plan": plan_of(True, fa.auto_block(seq), global_heads),
+            "tiles": counts, "ms_a_layer": ms,
+            "against_dense": {n: round(e, 6) for n, e in errs.items()},
+            "edges": _window_edges(
+                flash(mask=mask), batch=B, seq=seq, heads=H, kv_heads=Hkv,
+                head_dim=D, window=window, step=edge_step, seed=seed)}
+
+
 def _lane_padded_heads(key, shape, width: int, lanes: int):
     """Packed bfloat16 ``(B, T, H * lanes)`` of normal heads ``width`` wide
     with zeros behind them up to ``lanes``; ``shape`` is ``(B, T, H)``."""
@@ -2007,6 +2200,10 @@ def main(argv=None) -> int:
                     help="only the held expert layers' table: one layer "
                          "alone at four loads under every candidate window "
                          "(the default run times the plan's window alone)")
+    ap.add_argument("--window-mask", action="store_true",
+                    help="only the flash kernels under the causal window, "
+                         "at every candidate block, beside the global "
+                         "kind's causal call")
     ap.add_argument("--launcher-worker", action="store_true",
                     help=argparse.SUPPRESS)
     args = ap.parse_args(argv)
@@ -2062,6 +2259,9 @@ def main(argv=None) -> int:
         for cell, layer in held_layers.items():
             emit("held_windows", cell=cell, **held_windows_phase(
                 layer, seed=args.seed))
+    elif args.window_mask:
+        emit("window_mask", **window_mask_phase(**WINDOW_MASK,
+                                                seed=args.seed))
     elif args.chips == 1:
         emit("flash_reference", **flash_reference_phase(
             **FLASH_REFERENCE, seed=args.seed))
@@ -2092,6 +2292,8 @@ def main(argv=None) -> int:
             emit("grouped_backward", cell=cell, **grouped_backward_phase(
                 **shape, seed=args.seed))
         emit("block_mask", **block_mask_phase(**BLOCK_MASK, seed=args.seed))
+        emit("window_mask", **window_mask_phase(**WINDOW_MASK,
+                                                seed=args.seed))
         emit("latent_backward", **latent_backward_phase(
             **LATENT_BACKWARD, seed=args.seed))
         emit("latent_forward", **latent_forward_phase(

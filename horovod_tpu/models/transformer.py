@@ -79,7 +79,15 @@ g(norm(h))``) whose first is multi-head latent attention
 low-rank latents, one rotary key all heads share, heads wider than their
 values; ``mla=`` holds its widths) and whose second is a dense
 :class:`SwiGLU` ``mlp_hidden`` wide (``d``) or a ``DroplessMoE`` (``x``).
-:func:`JoyAIFlashLM` is the JoyAI-LLM-Flash setting.
+:func:`JoyAIFlashLM` is the JoyAI-LLM-Flash setting.  ``W`` and ``D`` are
+ONE pre-norm sub-layer like ``S`` and ``E``: ``W`` the stack's WINDOWED
+attention — ``S``'s :class:`GroupedQueryAttention` under a causal window,
+with the head count and rotary table ``window=`` gives it in place of the
+stack's — and ``D`` a dense :class:`SwiGLU` ``mlp_hidden`` wide; with them
+``attn_gate`` (a sigmoid gate on the heads' output), ``rope_width`` (a
+partial rotary factor) and ``rope_scaling`` (YaRN).  :func:`LagunaLM` is the
+Laguna-XS.2 setting: global and windowed attention layers with their own
+head counts in one pattern.
 
 ``mtp`` adds a multi-token-prediction module behind a pattern stack
 (:class:`MultiTokenPrediction`): from the stack's final hidden states and
@@ -93,19 +101,22 @@ experts in a latent between a shared down- and up-projection
 
 from __future__ import annotations
 
+import contextlib
 import functools
+import math
 from typing import Any, Optional
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from horovod_tpu.layer_notes import note_layer
 from horovod_tpu.ops import _pallas, cca_passes
 from horovod_tpu.ops.flash_attention import (
     auto_block, flash_attention_auto, flash_qkv_proj, kv_resident_bytes,
-    mask_tile_counts, select_tile_fetches)
+    mask_tile_counts, select_tile_fetches, window_pairs)
 from horovod_tpu.parallel.mesh import RANKS_AXIS
 from horovod_tpu.parallel.moe import DroplessMoE
 from horovod_tpu.parallel.ring_attention import (
@@ -123,29 +134,72 @@ def _norm(kind: str, eps: float, dtype, name: str):
     raise ValueError(f"unknown norm: {kind!r}")
 
 
+def yarn_frequencies(width: int, theta: float, *, factor: float,
+                     original_max_len: int, beta_fast: float = 32.0,
+                     beta_slow: float = 1.0):
+    """YaRN's rotary frequencies (Peng et al., arXiv:2309.00071, the
+    "NTK-by-parts" table) for a rotated width ``R = width``: ``(R/2,)``
+    float32.  With ``f_m = theta^(-2m/R)`` the plain table and ``c(r) = R
+    ln(original_max_len / (2 pi r)) / (2 ln theta)`` the channel whose
+    wavelength turns ``r`` times over the original context, ``low =
+    floor(c(beta_fast))`` and ``high = ceil(c(beta_slow))`` clamped to ``[0,
+    R - 1]``, ``ramp_m = clip((m - low) / (high - low), 0, 1)``, the table is
+    ``f_m (1 - ramp_m) + (f_m / factor) ramp_m``: the fast channels as they
+    were, the slow ones stretched ``factor`` times, a linear blend between.
+    Made at trace time in float64, so the table is a constant."""
+    m = np.arange(width // 2, dtype=np.float64)
+    plain = float(theta) ** (-2.0 * m / width)
+
+    def channel(turns):
+        return (width * math.log(original_max_len / (2 * math.pi * turns))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(channel(beta_fast)), 0)
+    high = min(math.ceil(channel(beta_slow)), width - 1)
+    ramp = np.clip((m - low) / max(high - low, 1e-3), 0.0, 1.0)
+    return (plain * (1 - ramp) + plain / factor * ramp).astype(np.float32)
+
+
 def apply_rotary(x, pos, theta: float = 10000.0,
-                 width: Optional[int] = None):
+                 width: Optional[int] = None, scaling=None):
     """Rotary position embedding, rotate-half form, on ``x`` (B, T, H, D)
     at positions ``pos`` (T,).  ``width`` (even, default ``D``) is the
     rotated width ``R``: the pair ``(x[i], x[i + R/2])``, ``i < R/2``, is
     turned by ``pos · theta^(-2i/R)`` and the channels ``R .. D - 1`` pass
     unchanged (a partial rotary factor of ``R / D``).  Angles and the
-    rotation in float32."""
+    rotation in float32.  ``scaling`` (``dict(factor=, original_max_len=,
+    beta_fast=, beta_slow=, attention_factor=)``): YaRN — the frequencies
+    are :func:`yarn_frequencies`' and cos and sin are multiplied by
+    ``attention_factor`` (default ``0.1 ln(factor) + 1``), so that a rotated
+    ``q · k`` carries its square; under the trace scope ``rope/yarn``."""
     D = x.shape[-1]
     width = D if width is None else width
     if width % 2 or not 0 < width <= D:
         raise ValueError(f"rotated width {width} of a head of {D}")
     half = width // 2
-    freq = 1.0 / theta ** (jnp.arange(half, dtype=jnp.float32) / half)
-    angle = pos.astype(jnp.float32)[:, None] * freq[None]       # (T, R/2)
-    cos = jnp.cos(angle)[None, :, None, :]
-    sin = jnp.sin(angle)[None, :, None, :]
-    x1 = x[..., :half].astype(jnp.float32)
-    x2 = x[..., half:width].astype(jnp.float32)
-    turned = [x1 * cos - x2 * sin, x2 * cos + x1 * sin]
-    if width < D:
-        turned.append(x[..., width:].astype(jnp.float32))
-    return jnp.concatenate(turned, axis=-1).astype(x.dtype)
+    if scaling is None:
+        freq = 1.0 / theta ** (jnp.arange(half, dtype=jnp.float32) / half)
+        scope, factor = contextlib.nullcontext(), None
+    else:
+        table = dict(scaling)
+        factor = jnp.float32(table.pop(
+            "attention_factor", 0.1 * math.log(table["factor"]) + 1.0))
+        freq = yarn_frequencies(width, theta, **table)
+        scope = jax.named_scope("rope/yarn")
+    with scope:
+        angle = pos.astype(jnp.float32)[:, None] * freq[None]   # (T, R/2)
+        def over_heads(wave):
+            wave = wave if factor is None else wave * factor
+            return wave[None, :, None, :]
+
+        cos = over_heads(jnp.cos(angle))
+        sin = over_heads(jnp.sin(angle))
+        x1 = x[..., :half].astype(jnp.float32)
+        x2 = x[..., half:width].astype(jnp.float32)
+        turned = [x1 * cos - x2 * sin, x2 * cos + x1 * sin]
+        if width < D:
+            turned.append(x[..., width:].astype(jnp.float32))
+        return jnp.concatenate(turned, axis=-1).astype(x.dtype)
 
 
 class _QKVKernel(nn.Module):
@@ -259,6 +313,20 @@ class GroupedQueryAttention(nn.Module):
     all query heads: :func:`~horovod_tpu.ops.flash_attention.
     mask_tile_counts`); not with an ``indexer``.
     ``scale``: the factor on ``q k^T`` (default ``head_dim ** -0.5``).
+    ``rope_width`` and ``rope_scaling``: :func:`apply_rotary`'s rotated
+    width (a partial rotary factor) and YaRN table, on q and k alike.
+    ``window``: a causal window in the causal mask's place — a query reads
+    itself and the ``window - 1`` keys before it (the flash family's
+    ``("window", W)`` mask; not with a call's ``mask`` nor an ``indexer``) —,
+    the kernels under the trace scope ``swa/attend`` and the counters
+    ``attn.window``, ``attn.win_live_pairs``, ``attn.win_live_tiles``,
+    ``attn.win_visited_tiles`` (the forward's tiles, all query heads).
+    ``out_gate``: a sigmoid gate on the heads' output before ``proj``, one
+    value a head from the layer's input through the parameter ``gate``
+    (``dim -> num_heads``), under the trace scope ``attn/gate``.
+    ``heads_kind``: the label under which the layer counts
+    its query heads, ``attn.heads#kind=<label>`` (a stack whose attention
+    layers differ in their heads sets it).
     ``make_train_step`` counts ``attn.merged_heads``: the query heads a
     step sends through the flash family's path for heads off the 128-lane
     width, which repeats the grouped keys and values and merges the heads
@@ -290,11 +358,21 @@ class GroupedQueryAttention(nn.Module):
     rope_theta: Optional[float] = None
     indexer: Any = None
     scale: Optional[float] = None
+    rope_width: Optional[int] = None
+    rope_scaling: Any = None
+    window: Optional[int] = None
+    out_gate: bool = False
+    heads_kind: Optional[str] = None
 
     @nn.compact
     def __call__(self, x, pos=None, mask=None):
         B, T, C = x.shape
         H, Hkv, D = self.num_heads, self.kv_heads, self.head_dim
+        if self.window is not None and (mask is not None
+                                        or self.indexer is not None):
+            raise ValueError(
+                f"a layer with window={self.window} runs under its own "
+                f"mask: no call's mask={mask!r}, no indexer")
 
         def dense(features, name):
             return nn.Dense(features, use_bias=False, dtype=self.dtype,
@@ -307,8 +385,11 @@ class GroupedQueryAttention(nn.Module):
             q = _norm("rms", self.norm_eps, self.dtype, "q_norm")(q)
             k = _norm("rms", self.norm_eps, self.dtype, "k_norm")(k)
         if self.rope_theta is not None:
+            table = {name: value for name, value in (
+                ("width", self.rope_width), ("scaling", self.rope_scaling))
+                if value is not None}
             q, k = (apply_rotary(a, jnp.arange(T) if pos is None else pos,
-                                 self.rope_theta) for a in (q, k))
+                                 self.rope_theta, **table) for a in (q, k))
         if self.indexer is not None:
             if mask is not None:
                 raise ValueError("the indexer selects among causal keys: "
@@ -320,7 +401,17 @@ class GroupedQueryAttention(nn.Module):
                              f"'full', not {self.attn!r}")
         counters = {"attn.merged_heads": (
             B * H if self.attn == "flash" and D % 128 else 0)}
-        if mask is not None:
+        if self.heads_kind is not None:
+            counters[f"attn.heads#kind={self.heads_kind}"] = H
+        if self.window is not None:
+            mask = ("window", self.window)
+            with jax.named_scope("swa/attend"):
+                out = self._attend(q, k, v, mask)
+            tiles = (mask_tile_counts(q, k, mask) if self.attn == "flash"
+                     else {"live_pairs": B * window_pairs(T, self.window)})
+            counters.update({"attn.window": self.window, **{
+                f"attn.win_{name}": count for name, count in tiles.items()}})
+        elif mask is not None:
             with jax.named_scope("bd/attend"):
                 out = self._attend(q, k, v, mask)
             tiles = (mask_tile_counts(q, k, mask) if self.attn == "flash"
@@ -329,6 +420,10 @@ class GroupedQueryAttention(nn.Module):
                 f"attn.bd_{name}": count for name, count in tiles.items()}})
         else:
             out = self._attend(q, k, v, None)
+        if self.out_gate:
+            with jax.named_scope("attn/gate"):
+                gate = nn.sigmoid(dense(H, "gate")(x))
+                out = out * gate[..., None]
         note_layer(self.path, counters)
         return dense(C, "proj")(out.reshape(B, T, H * D))
 
@@ -761,8 +856,11 @@ class SwiGLU(nn.Module):
 
 
 class PatternLayer(nn.Module):
-    """One layer of a pattern stack.  ``"M"`` (submodule ``ssm``), ``"*"``
-    and ``"S"`` (``attn``) and ``"E"`` (``moe``): ``x + f(norm(x))`` with
+    """One layer of a pattern stack.  ``"M"`` (submodule ``ssm``), ``"*"``,
+    ``"S"`` and ``"W"`` (``attn``; ``"W"`` is ``"S"`` with a ``sub`` of its
+    own — the stack's windowed attention layers, their own heads and rotary
+    table), ``"E"`` (``moe``) and ``"D"`` (``mlp``, a dense :class:`SwiGLU`
+    ``mlp_hidden`` wide): ``x + f(norm(x))`` with
     ``f`` the one sub-layer ``kind`` names.  ``"L"`` (``lin``) and ``"F"`` (``attn``):
     ``h = x + mixer_norm(f(x))``, then ``h + mlp_norm(mlp(h))`` with
     ``mlp`` a :class:`SwiGLU` ``mlp_hidden`` wide.  ``"m"`` (``ssm``) and
@@ -842,16 +940,18 @@ class PatternLayer(nn.Module):
         elif self.kind in ("*", "a"):
             y = GroupedQueryAttention(**self.sub, dtype=self.dtype,
                                       name="attn")(h)
-        elif self.kind == "S":
+        elif self.kind in ("S", "W"):
             y = GroupedQueryAttention(**self.sub, dtype=self.dtype,
                                       norm_eps=self.norm_eps,
                                       name="attn")(h, pos, mask)
         elif self.kind == "E":
             y = DroplessMoE(**self.sub, dtype=self.dtype, name="moe")(h)[0]
+        elif self.kind == "D":
+            y = SwiGLU(self.mlp_hidden, self.dtype, name="mlp")(h)
         else:
             raise ValueError(f"unknown layer {self.kind!r} in a pattern: "
-                             "'M', '*', 'S', 'E', 'L', 'F', 'm', 'a' or 'Z', "
-                             "or 'd' or 'x'")
+                             "'M', '*', 'S', 'W', 'E', 'D', 'L', 'F', 'm', "
+                             "'a' or 'Z', or 'd' or 'x'")
         if self.kind in ("m", "a"):
             r = self.residual_multiplier
             h = x + r * y
@@ -1083,6 +1183,19 @@ class TransformerLM(nn.Module):
     # ``("block_diffusion", L)`` mask in every ``S`` layer, and the call
     # returns the NOISED half's hidden states (or logits), (B, T, ...).
     diffusion: Any = None
+    # Of a pattern stack's ``S`` layers, and of its ``W`` layers unless
+    # ``window`` says otherwise: a sigmoid gate on the heads' output, one
+    # value a head (attn_gate), the rotated width of a head and YaRN's table
+    # (GroupedQueryAttention's out_gate, rope_width, rope_scaling).
+    attn_gate: bool = False
+    rope_width: Optional[int] = None
+    rope_scaling: Any = None
+    # ``W`` layers: ``window=dict(window=W, ...)`` — ``S``'s attention under
+    # a causal window of W keys, with whichever of num_heads, rope_theta,
+    # rope_width and rope_scaling the dict gives in place of the stack's
+    # (a stack of windowed and global layers with their own head counts and
+    # rotary tables).  ``D`` layers are a dense SwiGLU mlp_hidden wide.
+    window: Any = None
 
     @nn.compact
     def __call__(self, tokens, return_hidden=False, masked=None):
@@ -1112,7 +1225,8 @@ class TransformerLM(nn.Module):
         if self.tp_axis and (self.moe_experts or self.qk_norm
                              or self.pos != "learned" or self.pattern
                              or self.moe or self.indexer or self.cca
-                             or self.mla or self.mtp or self.diffusion):
+                             or self.mla or self.mtp or self.diffusion
+                             or self.window or self.attn_gate):
             raise ValueError("tp_axis runs the GPT-2 block only: no "
                              "experts (whole, a held share or in a latent), "
                              "QK-norm, rotary positions, pattern stack or "
@@ -1124,11 +1238,15 @@ class TransformerLM(nn.Module):
         if (self.pos == "none" or self.moe or self.ssm or self.lin
                 or self.mlp_hidden or self.indexer or self.cca or self.mla
                 or self.tie_head or self.mtp or self.diffusion
+                or self.window or self.attn_gate or self.rope_scaling
+                or self.rope_width is not None
                 or self.attn_scale is not None
                 or (self.residual_multiplier, self.embedding_multiplier,
                     self.logits_scaling) != (1.0, 1.0, 1.0)):
             raise ValueError("pos='none', ssm=, moe= (its latent= too), lin=, "
                              "indexer=, cca=, mla=, mtp=, diffusion=, "
+                             "window=, attn_gate=, rope_width=, "
+                             "rope_scaling=, "
                              "mlp_hidden=, attn_scale=, tie_head= and the "
                              "three multipliers belong to a pattern stack; "
                              "the block stack "
@@ -1178,13 +1296,14 @@ class TransformerLM(nn.Module):
                 f"blocks of tokens; got {self.diffusion!r}, pattern "
                 f"{self.pattern!r}, {tokens.shape[1]} tokens")
         if self.attn not in ("full", "flash") or self.pos not in (
-                ("none", "rotary") if set("SZdx") & set(self.pattern)
+                ("none", "rotary") if set("SWZdx") & set(self.pattern)
                 else ("none",)) or (set("Zdx") & set(self.pattern)
                                     and rotary is None):
             raise ValueError("a pattern stack runs whole sequences "
                              "(attn='full' or 'flash') with pos='none', or "
-                             "'rotary' for its 'S' layers, 'Z' layers ('Z' "
-                             "layers have no other) and 'd' and 'x' layers "
+                             "'rotary' for its 'S' layers, 'W' layers, 'Z' "
+                             "layers ('Z' layers have no other) and 'd' and "
+                             "'x' layers "
                              "(nor have they); got "
                              f"attn={self.attn!r}, pos={self.pos!r}")
         experts = dict(num_experts=self.moe_experts, hidden=self.moe_hidden,
@@ -1214,6 +1333,29 @@ class TransformerLM(nn.Module):
                       moe=experts),
         }
         subs["m"], subs["x"] = subs["M"], subs["d"]
+        if self.attn_gate:
+            subs["S"]["out_gate"] = True
+        for name, value in (("rope_width", self.rope_width),
+                            ("rope_scaling", self.rope_scaling)):
+            if value is not None:
+                subs["S"][name] = value
+        if "W" in self.pattern:
+            own = dict(self.window or {})
+            if "window" not in own or set(own) - {
+                    "window", "num_heads", "rope_theta", "rope_width",
+                    "rope_scaling"} or self.indexer or rotary is None:
+                raise ValueError(
+                    "'W' layers take window=dict(window=W) and, of their "
+                    "own, num_heads, rope_theta, rope_width and "
+                    "rope_scaling, in a stack with pos='rotary' and no "
+                    f"indexer=; got {self.window!r}")
+            # Two kinds of attention layer in one stack: each counts its
+            # query heads under its kind.
+            subs["S"]["heads_kind"] = "global"
+            subs["W"] = {**subs["S"], **own, "heads_kind": "window"}
+        elif self.window:
+            raise ValueError("window= is the 'W' layers'; the pattern "
+                             f"{self.pattern!r} holds none")
         if self.residual_multiplier != 1.0 and set(self.pattern) - {"m", "a"}:
             raise ValueError("residual_multiplier scales the sub-layers of "
                              "'m' and 'a' layers only; the pattern "
@@ -1452,6 +1594,56 @@ def KeyeLM(**overrides) -> TransformerLM:
         indexer=dict(num_heads=16, head_dim=64, topk=2048, tile=512),
         moe_experts=128, moe_top_k=8, moe_hidden=768,
         moe=dict(router="softmax", renormalize=True, activation="swiglu"))
+    fields.update(overrides)
+    return TransformerLM(**fields)
+
+
+def LagunaLM(**overrides) -> TransformerLM:
+    """The stack that ``poolside/Laguna-XS.2``'s config.json describes
+    (``model_type`` ``laguna``), as a :class:`TransformerLM` with a
+    ``pattern``: 40 layers at d 2048, pre-norm RMSNorm eps 1e-6, two
+    sub-layers each.  The attention sub-layer is GLOBAL in every fourth
+    layer (``layer_types`` ``full_attention``, letter ``S``: 48 query heads
+    over 8 KV heads of 128 under the causal mask; rotary positions on the
+    first 64 channels of a head — ``partial_rotary_factor`` 0.5 — from
+    YaRN's table, theta 500,000, factor 64 over 4,096 original positions,
+    ``beta_fast`` 64, ``beta_slow`` 1, cos and sin times the published
+    ``attention_factor``) and WINDOWED in the three after it
+    (``sliding_attention``, letter ``W``: 64 query heads over the 8 KV
+    heads, a query reads itself and the 511 keys before it —
+    ``sliding_window`` 512 —, plain rotary positions of theta 10,000 over
+    the whole head); ``num_attention_heads_per_layer`` is the two head
+    counts.  ``gating: true``: a sigmoid gate on the heads' output before
+    the output projection, ONE value a head (the sibling ``Laguna-S-2.1``'s
+    config.json says ``gating: "per-head"``; the benchmark's configuration
+    has the count of parameters that agrees).  The
+    second sub-layer is a dense SwiGLU 8,192 wide in layer 0
+    (``mlp_layer_types`` ``dense``, letter ``D``) and, in the 39 after it,
+    256 SwiGLU experts 512 wide (letter ``E``), top-8 by sigmoid scores,
+    gates renormalised over the chosen and scaled by
+    ``moe_routed_scaling_factor`` 2.5, one shared expert 512 wide; vocab
+    100,352, untied head.  No bias anywhere, no QK-norm, no auxiliary loss.
+
+    ``overrides`` replace any field: a cut takes the first letters of the
+    pattern (``"SD" + "WE" * 3 + "SE"``: a whole period behind the dense
+    layer), ``moe={..., "held": (first, count)}`` keeps one chip's share of
+    every layer's experts, a smaller ``vocab`` its share of the
+    vocabulary."""
+    period = "WE" * 3 + "SE"
+    fields = dict(
+        vocab=100352, dim=2048, num_heads=48, kv_heads=8, head_dim=128,
+        max_len=262144, norm="rms", norm_eps=1e-6, pos="rotary",
+        rope_theta=500000.0, rope_width=64,
+        rope_scaling=dict(factor=64.0, original_max_len=4096,
+                          beta_fast=64.0, beta_slow=1.0,
+                          attention_factor=1.4158883083359672),
+        attn_gate=True,
+        window=dict(window=512, num_heads=64, rope_theta=10000.0,
+                    rope_width=None, rope_scaling=None),
+        pattern=("SD" + period * 10)[:80], mlp_hidden=8192,
+        moe_experts=256, moe_top_k=8, moe_hidden=512,
+        moe=dict(router="sigmoid", renormalize=True, gate_scale=2.5,
+                 activation="swiglu", shared_hidden=512))
     fields.update(overrides)
     return TransformerLM(**fields)
 

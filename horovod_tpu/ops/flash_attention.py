@@ -95,18 +95,23 @@ form or the grid form, backward that one fused kernel (``dK`` and ``dV``
 resident at their own widths) or the per-head pair — with each side in
 whole 128-lane tiles; :func:`_plan` says which, from the shapes.
 
-The mask: causal, none, or — since PR 52 — a positional block mask known
-at trace time (``flash_attention(mask=("block_diffusion", L))``,
+The mask: causal, none, or a positional mask known at trace time —
+``flash_attention(mask=("block_diffusion", L))`` (PR 52,
 :class:`BlockDiffusion`: a clean and a noised copy of a sequence in one
-call, the block-diffusion objective's four rules).  Inside the kernels it
-rides in the ``causal`` slot, and the five mask helpers (``_block_mask``,
-``_interior``, ``_static_dead``, ``_static_interior``, ``_live_block``)
-dispatch on it: every form that reaches the mask through them alone — the
-three one-width forwards, the per-head pair, the one fused kernel a KV
-group — runs under it and visits no tile it leaves nothing of, with no map
-fetched; a dead grid step holds a live block (:func:`_bd_live_k`).  The
-pair blocked over two heads and the resident forward carry the causal
-mask's own arithmetic and are never planned for it.
+call, the block-diffusion objective's four rules) or ``mask=("window", W)``
+(PR 58, :class:`Window`: a query reads itself and the ``W - 1`` keys before
+it).  Inside the kernels it rides in the ``causal`` slot, and the five mask
+helpers (``_block_mask``, ``_interior``, ``_static_dead``,
+``_static_interior``, ``_live_block``) dispatch on it: every form that
+reaches the mask through them alone — the three one-width forwards, the
+per-head pair, the one fused kernel a KV group — runs under it and visits
+no tile it leaves nothing of, with no map fetched; a dead grid step holds a
+live block (:func:`_bd_live_k`, :func:`_win_live_k`; under a window the
+per-head pair's too, :func:`_win_live_q`).  The grid still has a step for
+every block pair: under a window of 512 at T 8,192 fourteen of a Q block's
+sixteen steps at 512² are dead ones (PERF.md §7).  The pair blocked over two
+heads and the resident forward carry the causal mask's own arithmetic and
+are never planned for a positional mask.
 
 Composition: this is the *single-chip* block; for sequences sharded
 across chips use :mod:`horovod_tpu.parallel.ring_attention`, which
@@ -152,10 +157,20 @@ class BlockDiffusion(NamedTuple):
     half: int
 
 
+class Window(NamedTuple):
+    """The causal window, as the kernels carry it in their ``causal`` slot
+    (``flash_attention(mask=("window", W))``): query ``i`` reads key ``j``
+    iff ``0 <= i - j < window`` — itself and the ``window - 1`` keys before
+    it.  A function of the positions alone, so no map is fetched; a block
+    pair is judged by the least and the largest ``i - j`` inside it, whatever
+    the blocks are to the window."""
+    window: int
+
+
 def _positional(mask) -> bool:
-    """Whether a kernel's ``causal`` is a positional block mask and not the
+    """Whether a kernel's ``causal`` is a positional mask and not the
     causal mask's flag."""
-    return isinstance(mask, BlockDiffusion)
+    return isinstance(mask, (BlockDiffusion, Window))
 
 
 _FAR = 1 << 30
@@ -204,14 +219,45 @@ def _bd_block_mask(mask, qi, kj, block_q, block_k):
     return jnp.logical_and(apart <= hi, apart >= lo)
 
 
+def _win_live_interior(mask, qi, kj, block_q, block_k):
+    """``(live, interior)`` of a block pair under the causal window: with
+    ``dmin`` and ``dmax`` the least and the largest ``query - key`` inside
+    it, whether some pair has ``0 <= d < window``, and whether every one
+    has.  Python integers in, python booleans out; traced indices in,
+    traced scalars out."""
+    dmin = qi * block_q - (kj + 1) * block_k + 1
+    dmax = (qi + 1) * block_q - 1 - kj * block_k
+    return ((dmax >= 0) & (dmin < mask.window),
+            (dmin >= 0) & (dmax < mask.window))
+
+
+def _win_block_mask(mask, qi, kj, block_q, block_k):
+    """(BQ, BK) validity of a block pair under the causal window: two
+    compares on the rows' less the columns' positions."""
+    apart = (qi * block_q - kj * block_k
+             + lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
+             - lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1))
+    return jnp.logical_and(apart >= 0, apart < mask.window)
+
+
+def _pos_live_interior(mask, qi, kj, block_q, block_k):
+    """``(live, interior)`` of a block pair under a positional mask."""
+    judge = (_win_live_interior if isinstance(mask, Window)
+             else _bd_live_interior)
+    return judge(mask, qi, kj, block_q, block_k)
+
+
 def _block_mask(qi, kj, block_q, block_k, mask, seq_len):
     """(BQ, BK) validity mask for this block pair, or None when every
-    position is valid.  ``mask``: the causal flag, or a positional block
-    mask (:class:`BlockDiffusion`, never with padding).  ``seq_len``: real
+    position is valid.  ``mask``: the causal flag, or a positional mask
+    (:class:`BlockDiffusion`, :class:`Window`; never with padding).
+    ``seq_len``: real
     sequence length when the array
     is zero-padded to a tileable T (positions >= seq_len are masked on
     both the row and column side, keeping padded-row softmax grads from
     producing inf*0 NaNs in the backward)."""
+    if isinstance(mask, Window):
+        return _win_block_mask(mask, qi, kj, block_q, block_k)
     if _positional(mask):
         return _bd_block_mask(mask, qi, kj, block_q, block_k)
     causal = mask
@@ -235,7 +281,7 @@ def _interior(qi, kj, block_q, block_k, mask, seq_len):
     masked code path (iota + two selects per block) can be skipped.
     Returns the literal ``True`` when no masking can ever apply."""
     if _positional(mask):
-        return _bd_live_interior(mask, qi, kj, block_q, block_k)[1]
+        return _pos_live_interior(mask, qi, kj, block_q, block_k)[1]
     ok = True
     if mask:
         # Fully visible iff the last key column <= the first query row.
@@ -270,7 +316,7 @@ def _static_dead(qi: int, kj: int, block: int, mask, seq_len) -> bool:
     position of, and pairs entirely inside the
     padding tail emit no code at all."""
     if _positional(mask):
-        return not _bd_live_interior(mask, qi, kj, block, block)[0]
+        return not _pos_live_interior(mask, qi, kj, block, block)[0]
     if mask and kj * block > (qi + 1) * block - 1:
         return True
     return seq_len is not None and (kj * block >= seq_len
@@ -283,7 +329,7 @@ def _static_interior(qi: int, kj: int, block: int, mask,
     element of the pair can be masked, so the where/iota path is
     skipped statically."""
     if _positional(mask):
-        return _bd_live_interior(mask, qi, kj, block, block)[1]
+        return _pos_live_interior(mask, qi, kj, block, block)[1]
     return ((not mask or (kj + 1) * block - 1 <= qi * block)
             and (seq_len is None
                  or (max(qi, kj) + 1) * block <= seq_len))
@@ -295,7 +341,7 @@ def _live_block(qi, kj, block_q, block_k, mask, seq_len):
     rows/columns entirely inside the padding tail are
     skipped outright."""
     if _positional(mask):
-        return _bd_live_interior(mask, qi, kj, block_q, block_k)[0]
+        return _pos_live_interior(mask, qi, kj, block_q, block_k)[0]
     q_last = (qi + 1) * block_q - 1
     k_first = kj * block_k
     live = jnp.logical_or(not mask, k_first <= q_last)
@@ -1170,6 +1216,16 @@ def _bwd_pallas_packed(q, k, v, o, lse, do, H, D, plan, *, scale, causal,
 
         def q_block(i):
             return i % nq
+    # Under a causal window most steps of either grid are dead: such a step
+    # holds the nearest live block of its row (no copy is issued for it).
+    windowed = isinstance(causal, Window)
+    live_k = _select_live_k(causal if windowed else False, block_q, block_k)
+
+    def q_at(j, i):
+        if windowed:
+            return _win_live_q(causal, block_q, block_k, nq, j, q_block(i))
+        return q_block(i)
+
     # Per-head delta = rowsum(dO * O): reduce D inside each head.
     delta = jnp.sum((do.astype(jnp.float32)
                      * o.astype(jnp.float32)).reshape(B, T, H, Dv),
@@ -1179,17 +1235,17 @@ def _bwd_pallas_packed(q, k, v, o, lse, do, H, D, plan, *, scale, causal,
 
     kv_specs = dict(
         q=pl.BlockSpec((1, block_q, D),
-                       lambda b, h, j, i: (b, q_block(i), q_head(h, i) + oq)),
+                       lambda b, h, j, i: (b, q_at(j, i), q_head(h, i) + oq)),
         k=pl.BlockSpec((1, block_k, D),
                        lambda b, h, j, i: (b, j, h + ok_)),
         v=pl.BlockSpec((1, block_k, Dv),
                        lambda b, h, j, i: (b, j, h + ov)),
         do=pl.BlockSpec((1, block_q, Dv),
-                        lambda b, h, j, i: (b, q_block(i), q_head(h, i))),
+                        lambda b, h, j, i: (b, q_at(j, i), q_head(h, i))),
         out=pl.BlockSpec((1, block_k, D), lambda b, h, j, i: (b, j, h)),
         out_v=pl.BlockSpec((1, block_k, Dv), lambda b, h, j, i: (b, j, h)),
         row8=pl.BlockSpec((1, 1, block_q, 8),
-                          lambda b, h, j, i: (b, q_head(h, i), q_block(i),
+                          lambda b, h, j, i: (b, q_head(h, i), q_at(j, i),
                                               0)),
     )
     sem4 = pltpu.CompilerParams(
@@ -1216,9 +1272,9 @@ def _bwd_pallas_packed(q, k, v, o, lse, do, H, D, plan, *, scale, causal,
         q=pl.BlockSpec((1, block_q, D),
                        lambda b, h, i, j: (b, i, h + oq)),
         k=pl.BlockSpec((1, block_k, D),
-                       lambda b, h, i, j: (b, j, kvh(h) + ok_)),
+                       lambda b, h, i, j: (b, live_k(i, j), kvh(h) + ok_)),
         v=pl.BlockSpec((1, block_k, Dv),
-                       lambda b, h, i, j: (b, j, kvh(h) + ov)),
+                       lambda b, h, i, j: (b, live_k(i, j), kvh(h) + ov)),
         do=pl.BlockSpec((1, block_q, Dv), lambda b, h, i, j: (b, i, h)),
         out=pl.BlockSpec((1, block_q, D), lambda b, h, i, j: (b, i, h)),
         row8=pl.BlockSpec((1, 1, block_q, 8),
@@ -1600,9 +1656,30 @@ def _select_live_k(causal, block_q, block_k):
     (the pipeline then issues no copy for the dead step)."""
     if not causal:
         return lambda i, j: j
+    if isinstance(causal, Window):
+        return functools.partial(_win_live_k, causal, block_q, block_k)
     if _positional(causal):
         return functools.partial(_bd_live_k, causal, block_q, block_k)
     return lambda i, j: jnp.minimum(j, ((i + 1) * block_q - 1) // block_k)
+
+
+def _win_live_k(mask, block_q, block_k, i, j):
+    """:func:`_select_live_k` under the causal window: the live KV blocks of
+    Q block ``i`` run from the block of its first row's oldest key to the
+    block of its last row; a dead ``j`` before them holds the first, one
+    behind them the last."""
+    first = jnp.maximum(i * block_q - mask.window + 1, 0) // block_k
+    return jnp.clip(j, first, ((i + 1) * block_q - 1) // block_k)
+
+
+def _win_live_q(mask, block_q, block_k, nq, j, i):
+    """The Q block a dk/dv step of the per-head pair holds under the causal
+    window, for KV block ``j``: ``i``, or the nearest of the Q blocks that
+    read it — from the block of its first key's row to the block of the last
+    row that reads its last key."""
+    last = jnp.minimum(((j + 1) * block_k + mask.window - 2) // block_q,
+                       nq - 1)
+    return jnp.clip(i, (j * block_k) // block_q, last)
 
 
 def _bd_live_k(mask, block_q, block_k, i, j):
@@ -1812,9 +1889,16 @@ def _diag_sub(causal, block_q, block_k, sub=_DIAG_SUB) -> int:
 
 def _bd_tiles(mask, T, block_q, block_k) -> int:
     """Block pairs of one head that a kernel of these blocks computes under
-    the block-diffusion mask: those :func:`_live_block` lets through."""
-    return sum(_bd_live_interior(mask, qi, kj, block_q, block_k)[0]
+    a positional mask: those :func:`_live_block` lets through."""
+    return sum(_pos_live_interior(mask, qi, kj, block_q, block_k)[0]
                for qi in range(T // block_q) for kj in range(T // block_k))
+
+
+def window_pairs(T: int, window: int) -> int:
+    """(query, key) pairs the causal window leaves of a sequence of ``T``:
+    row ``i`` reads ``min(i + 1, window)`` keys."""
+    w = min(window, T)
+    return w * (w + 1) // 2 + (T - w) * w
 
 
 def _bwd_live_share(T, causal, block_q, block_k, sub) -> float:
@@ -1823,10 +1907,14 @@ def _bwd_live_share(T, causal, block_q, block_k, sub) -> float:
     the causal one, ``T (T + 1) / 2`` over the area of the block pairs not
     wholly in the future — a diagonal pair cut into ``sub``-wide sub-tiles
     counting only those at or under the diagonal; under the block-diffusion
-    mask its ``half (half + block)`` pairs over the area of the block pairs
-    it leaves a position of."""
+    mask its ``half (half + block)`` pairs, and under the causal window its
+    ``min(i + 1, window)`` a row, over the area of the block pairs the mask
+    leaves a position of."""
     if not causal:
         return 1.0
+    if isinstance(causal, Window):
+        return round(window_pairs(T, causal.window) / (
+            _bd_tiles(causal, T, block_q, block_k) * block_q * block_k), 3)
     if _positional(causal):
         half, L = causal.half, causal.block
         return round(half * (half + L) / (
@@ -1854,7 +1942,8 @@ def _plan(*, T, D, H, head_base, itemsize, causal, block_q, block_k,
     ``manual_axes``: whether the operands vary over manual mesh axes
     (``shard_map``); ``vmem_headroom``: :func:`_pallas.vmem_headroom_ok` —
     whether the device backs a scoped budget above Mosaic's default;
-    ``causal`` may be a positional block mask (:class:`BlockDiffusion`):
+    ``causal`` may be a positional mask (:class:`BlockDiffusion`,
+    :class:`Window`):
     the forward forms and the backward forms below take it through the
     five mask helpers and visit no pair it leaves nothing of — all but the
     pair blocked over two heads (the per-head pair in its place) and the
@@ -1937,10 +2026,10 @@ def _plan(*, T, D, H, head_base, itemsize, causal, block_q, block_k,
     fwd_vmem_mb = 0 if T <= _DEFAULT_VMEM_MAX_T else _FULL_UNROLL_VMEM_MB
     positional = _positional(causal)
     if (T <= _FULL_UNROLL_MAX_T and T % tile == 0
-            # A positional mask's tiles lie in one stream and hold whole
-            # blocks of it.
-            and not (positional and (causal.half % tile
-                                     or tile % causal.block))
+            # The block-diffusion mask's tiles lie in one stream and hold
+            # whole blocks of it.
+            and not (isinstance(causal, BlockDiffusion)
+                     and (causal.half % tile or tile % causal.block))
             and T // tile <= _FULL_UNROLL_MAX_NQ
             # CPU tests under shard_map take the unrolled-KV form.
             and not _pallas.xla_form(interpret, manual_axes)
@@ -2367,9 +2456,11 @@ def flash_attention_auto(q, k, v, *, causal: bool = True,
     dense fallback would OOM at exactly the lengths this kernel exists
     for).  Off-TPU the kernel runs in interpret mode so callers stay
     hermetic.  ``select``: :func:`flash_attention`'s; the result is then
-    ``(out, lse)``.  ``mask``: :func:`flash_attention`'s positional block
-    mask; the block is :func:`auto_block`'s of ONE stream's length, and a
-    length that would need padding is refused (``ValueError``).
+    ``(out, lse)``.  ``mask``: :func:`flash_attention`'s positional
+    mask; the block is :func:`_mask_auto_block`'s (block diffusion:
+    :func:`auto_block`'s of ONE stream's length; a window: of the rows), and
+    a length that would need padding is refused
+    (``ValueError``).
     """
     T = q.shape[1]
     interpret = _pallas.interpret()
@@ -2398,11 +2489,22 @@ def flash_attention_auto(q, k, v, *, causal: bool = True,
 
 def _mask_auto_block(rows: int, mask) -> int:
     """:func:`flash_attention_auto`'s block for ``rows`` rows under a
-    positional mask: :func:`auto_block`'s of one stream's length, which has
-    to hold whole blocks of the mask."""
+    positional mask, from shapes alone.  Block diffusion: :func:`auto_block`'s
+    of one stream's length, which has to hold whole blocks of the mask.  A
+    causal window: :func:`auto_block`'s of the rows (the grid's steps, not a
+    tile's dead area, decide a windowed call's time today: ``chip_smoke.py
+    --window-mask``)."""
+    kind, n = _mask_arg(mask)
+    if kind == "window":
+        blk = auto_block(rows)
+        if not blk:
+            raise ValueError(
+                f"flash_attention_auto: {rows} rows do not tile (no padding "
+                f"under a positional mask, {mask!r})")
+        return blk
     half = rows // 2
     blk = auto_block(half)
-    if rows % 2 or not blk or blk % _block_diffusion(mask):
+    if rows % 2 or not blk or blk % n:
         raise ValueError(
             f"flash_attention_auto: {rows} rows are no two streams that tile "
             f"in whole blocks of the mask {mask!r} (no padding under a "
@@ -2410,37 +2512,51 @@ def _mask_auto_block(rows: int, mask) -> int:
     return blk
 
 
-def _block_diffusion(mask) -> int:
-    """The block length of ``mask = ("block_diffusion", L)``."""
+def _mask_arg(mask):
+    """``(kind, n)`` of ``mask = ("block_diffusion", L)`` or ``("window",
+    W)``."""
     if (not isinstance(mask, tuple) or len(mask) != 2
-            or mask[0] != "block_diffusion" or int(mask[1]) < 1):
+            or mask[0] not in ("block_diffusion", "window")
+            or int(mask[1]) < 1):
         raise ValueError('flash_attention: a mask is ("block_diffusion", L), '
-                         f"L a block length; got {mask!r}")
-    return int(mask[1])
+                         'L a block length, or ("window", W), W the keys a '
+                         f"query reads; got {mask!r}")
+    return mask[0], int(mask[1])
 
 
 def mask_tile_counts(q, k, mask) -> dict:
-    """What one call of :func:`flash_attention_auto` on ``q`` (B, 2T, H, D)
-    and ``k`` under the block-diffusion ``mask`` reads and does, forward, as
-    :func:`_plan` has it on this device: ``live_pairs`` — the (query, key)
-    pairs the mask leaves, ``T (T + L)`` a sequence —, ``live_tiles`` — the
-    forward form's tiles that hold one of them, a query head (from the
-    mask's definition: with ``n`` tiles a stream, ``n (n + 1) / 2`` of the
-    clean rows, as many of the noised rows over the clean keys but for the
-    ``n`` on the diagonal where the tile is one block, and ``n`` over their
-    own) — and ``visited_tiles`` — the tiles its kernel computes (the dead
-    test its grid steps run)."""
+    """What one call of :func:`flash_attention_auto` on ``q`` and ``k``
+    under the positional ``mask`` reads and does, forward, as :func:`_plan`
+    has it on this device: ``live_pairs`` — the (query, key) pairs the mask
+    leaves —, ``live_tiles`` — the forward form's tiles that hold one of
+    them, a query head, from the mask's definition — and ``visited_tiles``
+    — the tiles its kernel computes (the dead test its grid steps run).
+
+    Block diffusion, ``q`` (B, 2T, H, D): ``T (T + L)`` pairs a sequence;
+    with ``n`` tiles a stream, ``n (n + 1) / 2`` tiles of the clean rows, as
+    many of the noised rows over the clean keys but for the ``n`` on the
+    diagonal where the tile is one block, and ``n`` over their own.  A
+    causal window, ``q`` (B, T, H, D): ``min(i + 1, W)`` pairs a row; Q tile
+    ``i`` holds the tiles from its first row's oldest key's to its own."""
     B, rows, H, D = q.shape
-    L, blk = _block_diffusion(mask), _mask_auto_block(rows, mask)
-    bd = BlockDiffusion(L, rows // 2)
+    (kind, n), blk = _mask_arg(mask), _mask_auto_block(rows, mask)
+    held = Window(n) if kind == "window" else BlockDiffusion(n, rows // 2)
     plan = _plan_for(
-        jax.ShapeDtypeStruct((B, rows, H * D), q.dtype), H, D, (0, 0, 0), bd,
-        blk, blk, blk, blk, _pallas.interpret(), kv_rep=H // k.shape[2])
+        jax.ShapeDtypeStruct((B, rows, H * D), q.dtype), H, D, (0, 0, 0),
+        held, blk, blk, blk, blk, _pallas.interpret(),
+        kv_rep=H // k.shape[2])
     tile = plan.fwd_tile if plan.fwd == "fullunroll" else blk
-    n = bd.half // tile
-    return {"live_pairs": B * bd.half * (bd.half + L),
-            "live_tiles": B * H * (n * n + n + (n if tile > L else 0)),
-            "visited_tiles": B * H * _bd_tiles(bd, rows, tile, tile)}
+    visited = B * H * _bd_tiles(held, rows, tile, tile)
+    if kind == "window":
+        return {"live_pairs": B * window_pairs(rows, n),
+                "live_tiles": B * H * sum(
+                    i - max(i * tile - n + 1, 0) // tile + 1
+                    for i in range(rows // tile)),
+                "visited_tiles": visited}
+    t = held.half // tile
+    return {"live_pairs": B * held.half * (held.half + n),
+            "live_tiles": B * H * (t * t + t + (t if tile > n else 0)),
+            "visited_tiles": visited}
 
 
 def _auto_tiling(T: int):
@@ -2575,9 +2691,13 @@ def flash_attention(q, k, v, *, causal: bool = True,
     ``lse`` ``(B, H, T)`` the log-sum-exp of each head's selected scores
     (it carries no gradient).
 
-    ``mask``: a positional block mask in the causal mask's place (``causal``
+    ``mask``: a positional mask in the causal mask's place (``causal``
     is then not read), known at trace time, so no map is fetched and no pair
     it leaves nothing of is visited, forward or backward (:func:`_plan`).
+    ``("window", W)``: query ``i`` reads the keys ``i - W + 1 .. i``
+    (:class:`Window`; the causal mask is ``W >= T``); the blocks need only
+    divide ``T``; no padding (``seq_len``), no ``select``, keys and values of
+    one width.
     ``("block_diffusion", L)``: the ``T`` rows are a clean copy of a sequence
     and then a noised copy (``T`` even), in blocks of ``L`` tokens; a clean
     query reads the clean keys of its own and earlier blocks, a noised query
@@ -2629,15 +2749,23 @@ def flash_attention(q, k, v, *, causal: bool = True,
         "ragged lengths")
     causal = bool(causal)
     if mask is not None:
-        causal = BlockDiffusion(_block_diffusion(mask), T // 2)
+        kind, n = _mask_arg(mask)
         blocks = (block_q, block_k, bwd_block_q, bwd_block_k)
-        if T % 2 or seq_len is not None or any(
-                causal.half % b or b % causal.block for b in blocks):
-            raise ValueError(
-                f"flash_attention: under the mask {mask!r} the {T} rows are "
-                "two streams of one length, unpadded, and every block "
-                f"divides a stream in whole blocks of the mask; got blocks "
-                f"{blocks}, seq_len={seq_len}")
+        if kind == "window":
+            causal = Window(n)
+            if seq_len is not None:
+                raise ValueError(
+                    f"flash_attention: under the mask {mask!r} the rows are "
+                    f"unpadded; got seq_len={seq_len}")
+        else:
+            causal = BlockDiffusion(n, T // 2)
+            if T % 2 or seq_len is not None or any(
+                    causal.half % b or b % causal.block for b in blocks):
+                raise ValueError(
+                    f"flash_attention: under the mask {mask!r} the {T} rows "
+                    "are two streams of one length, unpadded, and every "
+                    "block divides a stream in whole blocks of the mask; got "
+                    f"blocks {blocks}, seq_len={seq_len}")
 
     static = (float(scale), causal, block_q, block_k, bwd_block_q,
               bwd_block_k, bool(interpret), seq_len)

@@ -259,14 +259,22 @@ def block_diffusion_allowed(rows: int, block: int):
         ~k_noised & (k_blk <= q_blk))
 
 
+def window_allowed(rows: int, window: int):
+    """The causal window as a plain (rows, rows) boolean matrix, ``[query,
+    key]``: query ``i`` reads key ``j`` iff ``0 <= i - j < window`` — itself
+    and the ``window - 1`` keys before it."""
+    apart = jnp.arange(rows)[:, None] - jnp.arange(rows)[None, :]
+    return (apart >= 0) & (apart < window)
+
+
 def full_attention(q, k, v, *, causal: bool = True,
                    scale: Optional[float] = None,
                    q_offset: int = 0, k_offset: int = 0, mask=None):
     """Single-device reference attention (same math, no ring) — used by the
     tests as the oracle and by the transformer when sequence parallelism is
-    off.  ``mask=("block_diffusion", L)``: the flash family's positional
-    block mask in the causal mask's place, as a plain boolean matrix
-    (:func:`block_diffusion_allowed`)."""
+    off.  ``mask=("block_diffusion", L)`` or ``("window", W)``: the flash
+    family's positional mask in the causal mask's place, as a plain boolean
+    matrix (:func:`block_diffusion_allowed`, :func:`window_allowed`)."""
     B, Tq, H, D = q.shape
     Tk = k.shape[1]
     if scale is None:
@@ -276,8 +284,9 @@ def full_attention(q, k, v, *, causal: bool = True,
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k,
                         preferred_element_type=jnp.float32) * scale
     if mask is not None:
-        logits = jnp.where(block_diffusion_allowed(Tq, mask[1])[None, None],
-                           logits, _NEG_BIG)
+        allowed = (window_allowed if mask[0] == "window"
+                   else block_diffusion_allowed)(Tq, mask[1])
+        logits = jnp.where(allowed[None, None], logits, _NEG_BIG)
     elif causal:
         allowed = pos_k[None, :] <= pos_q[:, None]
         logits = jnp.where(allowed[None, None, :, :], logits, _NEG_BIG)

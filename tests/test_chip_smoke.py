@@ -163,7 +163,7 @@ def test_experts_reference_phase(smoke):
 def test_held_rows_says_which_way_each_cell_s_rows_move(smoke):
     """From shapes alone: ``zaya1_1chip``'s held layer (top-1, 8 of 17
     outputs: ``3 · 1 · 8 ≥ 17``) has one window of every assignment and
-    moves all of them through the sort's permutation; the five other
+    moves all of them through the sort's permutation; the six other
     cells' windows are ``_window_plan``'s ``W`` rows of their assignments,
     as many as a step's routing fills, gathered and — their widths taking
     the kernels and their tokens cutting into the landing's tiles —
@@ -182,7 +182,8 @@ def test_held_rows_says_which_way_each_cell_s_rows_move(smoke):
         "keye_1chip": (131072, 16384, WINDOW_ROWS["keye_1chip"]),
         "sdar_1chip": (131072, 16384, WINDOW_ROWS["keye_1chip"]),
         "joyaiflash_1chip": (131072, 8192, WINDOW_ROWS["joyaiflash_1chip"]),
-        "nemo3super_1chip": (180224, 2816, WINDOW_ROWS["nemo3super_1chip"])}
+        "nemo3super_1chip": (180224, 2816, WINDOW_ROWS["nemo3super_1chip"]),
+        "lagunaxs2_1chip": (65536, 8192, WINDOW_ROWS["lagunaxs2_1chip"])}
     assert {cell: (r["form"], r["landed_by_product"])
             for cell, r in rows.items() if not r["permuted_assignments"]} == {
         cell: ("products", WINDOW_ROWS.get(cell, WINDOW_ROWS["keye_1chip"]))
@@ -192,7 +193,8 @@ def test_held_rows_says_which_way_each_cell_s_rows_move(smoke):
 # ``moe._window_plan`` at the cells' layers (the table it was fitted to is
 # ``held_windows``'s, PERF.md section 6, PR 53).
 WINDOW_ROWS = {"twotower_1chip": 7680, "keye_1chip": 16384,
-               "joyaiflash_1chip": 10752, "nemo3super_1chip": 5632}
+               "joyaiflash_1chip": 10752, "nemo3super_1chip": 5632,
+               "lagunaxs2_1chip": 13312}
 
 
 def test_held_windows_phase_times_every_candidate_window(smoke):
@@ -484,3 +486,34 @@ class TestCompileCachePlacement:
         jax.monitoring.record_event("/jax/compilation_cache/cache_misses")
         jax.monitoring.record_event("/jax/compilation_cache/cache_misses")
         assert (events.hits, events.writes) == (1, 2)
+
+
+def test_window_mask_phase(smoke):
+    """The flash kernels under the causal window, alone (interpreted here:
+    no time is reported): the plan under the block the shapes give, the
+    tiles visited against the tiles live, the global kind's plan at six
+    query heads a KV head, the kernels against the dense oracle, and the
+    window's two edges on peaked scores — met to ``EDGE_TOL``, a window one
+    key wider or narrower missed by the output's own size; on the chip the
+    phase runs at ``lagunaxs2_1chip``'s two attention shapes."""
+    out = smoke.window_mask_phase(batch=1, seq=64, heads=8, global_heads=6,
+                                  kv_heads=1, head_dim=128, window=16,
+                                  check_seq=32, seed=0, blocks=(16, 32),
+                                  edge_step=32)
+    assert out["interpret"] and out["shape"] == [1, 64, 8, 1, 128]
+    assert set(out["ms_a_layer"]) == {
+        f"{name}.{way}" for name in ("window.16", "window.32", "global")
+        for way in ("forward", "backward")}
+    assert not any(out["ms_a_layer"].values())
+    assert out["win_plan"]["bwd"] == out["global_plan"]["bwd"] == (
+        "group_fused")
+    # One tile of 64 rows: the fully unrolled form's.
+    assert out["tiles"] == {"live_pairs": 16 * 17 // 2 + 48 * 16,
+                            "live_tiles": 8, "visited_tiles": 8}
+    assert max(out["against_dense"].values()) <= 2e-2
+    edges = out["edges"]
+    assert max(edges["against_dense"].values()) <= smoke.EDGE_TOL
+    assert set(edges["missed"]) == {"window_17", "window_15"}
+    assert min(edges["missed"].values()) >= 0.5
+    assert tuple(smoke.WINDOW_MASK.values()) == (1, 8192, 64, 48, 8, 128,
+                                                 512, 1024)

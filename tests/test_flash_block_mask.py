@@ -161,7 +161,7 @@ def test_refusals():
     with pytest.raises(ValueError, match="no padding under"):
         fa.flash_attention_auto(q[:, :63], k[:, :63], v[:, :63], mask=mask)
     with pytest.raises(ValueError, match="block_diffusion"):
-        fa.flash_attention_auto(q, k, v, mask=("window", 4))
+        fa.flash_attention_auto(q, k, v, mask=("segment", 4))
     with pytest.raises(ValueError, match="one width"):
         fa.flash_attention(q, k, v[..., :64], mask=mask, interpret=True)
     with pytest.raises(ValueError, match="without a selection"):

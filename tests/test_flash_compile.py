@@ -1,6 +1,6 @@
 """The flash-attention family compiled for a described TPU v5e (see
-``tests/_v5e.py``): dense, grouped-KV, resident, block-mask and split-width
-calls at the cells' shapes and at every tiling ``_plan`` admits.  The
+``tests/_v5e.py``): dense, grouped-KV, resident, block-mask, window and
+split-width calls at the cells' shapes and at every tiling ``_plan`` admits.  The
 interpreted tests of the same kernels are ``test_flash_attention.py``,
 ``test_flash_backward.py``, ``test_flash_block_mask.py``,
 ``test_flash_split_widths.py`` and ``test_latent_attention.py``.
@@ -162,6 +162,39 @@ def test_block_mask_flash_fwd_bwd_at_the_sdar_cell_s_shape(v5e, monkeypatch):
     assert dq.shape == (1, 16_384, 32, 128)
     assert dk.shape == dv.shape == (1, 16_384, 4, 128)
     jax.clear_caches()      # the traces do not key on the budget
+
+
+@pytest.mark.parametrize("kind,heads,how", [
+    ("windowed", 64, {"mask": ("window", 512)}),
+    ("global", 48, {"causal": True}),
+])
+def test_the_laguna_cell_s_two_attention_calls_fwd_bwd(v5e, kind, heads, how):
+    """``lagunaxs2_1chip``'s calls: one sequence of 8,192 over 8 KV heads of
+    128 — the windowed layers' 64 query heads under 512 keys a query
+    (forward tiles of 1,024, the block the shapes choose) and the global
+    layers' 48 under the causal mask (six query heads a KV head, which no
+    other cell runs).  The grid forward and the one backward kernel a KV
+    group, dK and dV of 8,192 rows resident, compile for the v5e under the
+    budgets the plan gives; no map is an operand."""
+    from horovod_tpu.ops import flash_attention as fa
+
+    one = SingleDeviceSharding(v5e[0])
+    q = jax.ShapeDtypeStruct((1, 8192, heads, 128), jnp.bfloat16,
+                             sharding=one)
+    kv = jax.ShapeDtypeStruct((1, 8192, 8, 128), jnp.bfloat16, sharding=one)
+
+    def loss(q, k, v):
+        return fa.flash_attention(q, k, v, **how).astype(jnp.float32).sum()
+
+    lowered = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
+        q, kv, kv)
+    assert custom_calls(lowered.as_text()) == [
+        ("_fwd_kernel", 3), ("flash_group_bwd", 6)]
+    assert scoped_vmem_mb(lowered.as_text()) == {
+        "_fwd_kernel": 0, "flash_group_bwd": fa._SELECT_FUSED_VMEM_MB}
+    _, (dq, dk, dv) = lowered.compile().out_info
+    assert dq.shape == (1, 8192, heads, 128)
+    assert dk.shape == dv.shape == (1, 8192, 8, 128)
 
 
 def test_latent_attention_s_kernels_fwd_bwd_at_the_joyai_cell_s_shape(
