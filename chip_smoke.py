@@ -48,7 +48,10 @@ through the entry points a user calls (``hvd.init()`` →
 * times the flash kernels under the causal window alone at
   ``lagunaxs2_1chip``'s windowed layer — one sequence of 8,192, 64 query
   heads over 8 KV heads, 512 keys a query — at tiles of 256, 512 and 1,024
-  (``window_mask``, ``win_plan``; ``--window-mask`` runs this phase alone),
+  on the band's short KV axis, the backward with its block pairs on the
+  window's edges cut into sub-tiles and whole, read twice, beside each
+  block's grid steps and pairs computed (``window_mask``, ``win_plan``,
+  ``schedule``; ``--window-mask`` runs this phase alone),
   the global kind's causal call at 6 query heads a KV head beside them,
   checks output and gradients against the dense oracle under the boolean
   window at 1,024 tokens and, at the layer's own shape and tiles, the
@@ -99,6 +102,7 @@ calls them tiny on the CPU mesh.
 from __future__ import annotations
 
 import argparse
+import contextlib
 import functools
 import json
 import math
@@ -1420,14 +1424,21 @@ def window_mask_phase(*, batch: int, seq: int, heads: int, global_heads: int,
                       blocks=(256, 512, 1024), edge_step: int = 1024) -> dict:
     """The flash kernels under the causal window, alone, at one windowed
     layer's shape: ``win_plan`` — what ``flash_attention._plan`` decides on
-    this device under the block ``_mask_auto_block`` gives —, the tiles a
-    query head's forward visits against those that hold a live pair, the
-    forward's and the backward's time at each of ``blocks`` (``ms_a_layer``:
-    ``window.<block>``), beside them the GLOBAL kind's call —
-    ``global_heads`` query heads under the causal mask, ``global_plan`` —,
-    at ``check_seq`` tokens output and gradients against the dense oracle
-    under the window as a boolean matrix, and AT THE LAYER'S OWN SHAPE AND
-    TILES the window's two edges (``edges``: :func:`_window_edges`)."""
+    this device under the block ``_mask_auto_block`` gives —, ``tiles`` —
+    ``mask_tile_counts``: the forward's grid steps against those that
+    compute a tile, the tiles visited against those that hold a live pair,
+    the pairs computed against the live ones —, the forward's and the
+    backward's time at each of ``blocks`` (``ms_a_layer``: ``window.<block>``
+    as the plan has it, the backward's block pairs on the window's edges
+    cut into sub-tiles where it says so, and ``window.<block>.whole.backward``
+    with every visited pair computed whole and masked; each read twice,
+    ``[first, second]``) with that block's ``schedule`` (grid steps, live
+    steps and pairs computed over pairs live, forward and backward), beside
+    them the GLOBAL kind's call — ``global_heads`` query heads under the
+    causal mask, ``global_plan`` —, at ``check_seq`` tokens output and
+    gradients against the dense oracle under the window as a boolean matrix,
+    and AT THE LAYER'S OWN SHAPE AND TILES the window's two edges
+    (``edges``: :func:`_window_edges`)."""
     import jax
     import jax.numpy as jnp
 
@@ -1436,7 +1447,7 @@ def window_mask_phase(*, batch: int, seq: int, heads: int, global_heads: int,
 
     interpret = jax.default_backend() != "tpu"
     B, H, Hkv, D = batch, heads, kv_heads, head_dim
-    mask = ("window", window)
+    mask, held = ("window", window), fa.Window(window)
 
     def operands(T, heads_):
         ks = jax.random.split(jax.random.PRNGKey(seed), 4)
@@ -1451,31 +1462,77 @@ def window_mask_phase(*, batch: int, seq: int, heads: int, global_heads: int,
     def backward(fn):
         return lambda q, k, v, do: jax.vjp(fn, q, k, v)[1](do)
 
-    def plan_of(held, blk, heads_):
+    def plan_of(causal, blk, heads_):
         return fa._plan_for(
             jax.ShapeDtypeStruct((B, seq, heads_ * D), jnp.bfloat16), heads_,
-            D, (0, 0, 0), held, blk, blk, blk, blk, interpret,
-            kv_rep=heads_ // Hkv)._asdict()
+            D, (0, 0, 0), causal, blk, blk, blk, blk, interpret,
+            kv_rep=heads_ // Hkv)
+
+    @contextlib.contextmanager
+    def whole_pairs():
+        """The plan without the backward's sub-tiles (the traces do not key
+        on it)."""
+        plan = fa._plan
+        fa._plan = lambda **seen: plan(**seen)._replace(bwd_sub=0)
+        jax.clear_caches()
+        try:
+            yield
+        finally:
+            fa._plan = plan
+            jax.clear_caches()
+
+    def schedule(blk):
+        """A block's grids under the window in the grid forms, from
+        shapes."""
+        plan = plan_of(held, blk, H)
+        bq, bk = plan.blocks[2:] if plan.blocks else (blk, blk)
+        live = fa.window_pairs(seq, window)
+        fwd_tiles, bwd_tiles = (fa._bd_tiles(held, seq, *t)
+                                for t in ((blk, blk), (bq, bk)))
+        return {
+            "bwd_sub": plan.bwd_sub, "bwd_blocks": [bq, bk],
+            "fwd_grid_steps": B * H * (seq // blk) * fa._win_steps(
+                held, seq, blk, blk),
+            "fwd_live_steps": B * H * fwd_tiles,
+            "bwd_grid_steps": B * Hkv * (seq // bq) * fa._win_steps(
+                held, seq, bq, bk),
+            "bwd_live_steps": B * Hkv * bwd_tiles,
+            "fwd_pairs_over_live": round(fwd_tiles * blk * blk / live, 3),
+            "bwd_pairs_over_live": [round(fa._win_visited(
+                held, seq, bq, bk, sub) / live, 3)
+                for sub in (plan.bwd_sub, 0)]}
 
     q, k, v, do = operands(seq, H)
     blk = fa._mask_auto_block(seq, mask)
     counts = fa.mask_tile_counts(q, k, mask)
-    check(counts["visited_tiles"] >= counts["live_tiles"],
-          f"the forward visits {counts['visited_tiles']} tiles, "
-          f"{counts['live_tiles']} hold a live pair")
+    check(counts["visited_tiles"] >= counts["live_tiles"]
+          and counts["grid_steps"] >= counts["live_steps"]
+          and counts["visited_pairs"] >= counts["live_pairs"],
+          f"the forward's counts under the window: {counts}")
     timed = functools.partial(_timed_ms, calls, interpret)
     ms = {}
 
     def time_both(name, fn, *args):
-        ms[f"{name}.forward"], _ = timed(fn, *args[:3])
-        both, _ = timed(backward(fn), *args)
-        ms[f"{name}.backward"] = (
-            None if both is None else round(both - ms[f"{name}.forward"], 3))
+        fwd = [timed(fn, *args[:3])[0] for _ in range(2)]
+        ms[f"{name}.forward"] = fwd
+        time_backward(name, fn, fwd, *args)
 
+    def time_backward(name, fn, fwd, *args):
+        both = [timed(backward(fn), *args)[0] for _ in range(2)]
+        ms[f"{name}.backward"] = [
+            None if b is None else round(b - f, 3) for b, f in zip(both, fwd)]
+
+    schedules = {}
     for b in blocks:
-        if seq % b == 0:
-            time_both(f"window.{b}", flash(mask=mask, block_q=b, block_k=b),
-                      q, k, v, do)
+        if seq % b:
+            continue
+        schedules[b] = schedule(b)
+        at = flash(mask=mask, block_q=b, block_k=b)
+        time_both(f"window.{b}", at, q, k, v, do)
+        if schedules[b]["bwd_sub"]:
+            with whole_pairs():
+                time_backward(f"window.{b}.whole", at,
+                              ms[f"window.{b}.forward"], q, k, v, do)
     wide = operands(seq, global_heads)
     time_both("global", flash(causal=True), *wide)
     # Against the dense oracle, where its (T, T) scores fit.
@@ -1498,9 +1555,10 @@ def window_mask_phase(*, batch: int, seq: int, heads: int, global_heads: int,
               f"{name} by {errs[name]:.3g} (bound {SELECT_TOL})")
     return {"shape": [B, seq, H, Hkv, D], "window": window, "block": blk,
             "interpret": interpret,
-            "win_plan": plan_of(fa.Window(window), blk, H),
-            "global_plan": plan_of(True, fa.auto_block(seq), global_heads),
-            "tiles": counts, "ms_a_layer": ms,
+            "win_plan": plan_of(held, blk, H)._asdict(),
+            "global_plan": plan_of(True, fa.auto_block(seq),
+                                   global_heads)._asdict(),
+            "tiles": counts, "schedule": schedules, "ms_a_layer": ms,
             "against_dense": {n: round(e, 6) for n, e in errs.items()},
             "edges": _window_edges(
                 flash(mask=mask), batch=B, seq=seq, heads=H, kv_heads=Hkv,

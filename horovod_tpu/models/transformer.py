@@ -320,7 +320,12 @@ class GroupedQueryAttention(nn.Module):
     ``("window", W)`` mask; not with a call's ``mask`` nor an ``indexer``) —,
     the kernels under the trace scope ``swa/attend`` and the counters
     ``attn.window``, ``attn.win_live_pairs``, ``attn.win_live_tiles``,
-    ``attn.win_visited_tiles`` (the forward's tiles, all query heads).
+    ``attn.win_visited_tiles`` (the forward's tiles, all query heads),
+    ``attn.win_grid_steps``, ``attn.win_live_steps`` (the forward's grid and
+    the steps of it that compute a tile, all query heads) and
+    ``attn.win_visited_pairs`` (the pairs a head's forward computes scores
+    of, against ``attn.win_live_pairs``: :func:`~horovod_tpu.ops.
+    flash_attention.mask_tile_counts`).
     ``out_gate``: a sigmoid gate on the heads' output before ``proj``, one
     value a head from the layer's input through the parameter ``gate``
     (``dim -> num_heads``), under the trace scope ``attn/gate``.

@@ -107,11 +107,22 @@ reaches the mask through them alone — the three one-width forwards, the
 per-head pair, the one fused kernel a KV group — runs under it and visits
 no tile it leaves nothing of, with no map fetched; a dead grid step holds a
 live block (:func:`_bd_live_k`, :func:`_win_live_k`; under a window the
-per-head pair's too, :func:`_win_live_q`).  The grid still has a step for
-every block pair: under a window of 512 at T 8,192 fourteen of a Q block's
-sixteen steps at 512² are dead ones (PERF.md §7).  The pair blocked over two
-heads and the resident forward carry the causal mask's own arithmetic and
-are never planned for a positional mask.
+per-head pair's too, :func:`_win_live_q`).  Under a causal window the grid
+forward and the one backward kernel a KV group walk the band and nothing
+else (PR 59), a schedule read off ``(T, W, blocks)``: their KV axis is as
+long as a Q block's live run (:func:`_win_steps`: 2 steps at 512² under 512
+keys where the grid had 16; a step stands on the run's block of its number,
+:func:`_kv_step`), the forward's block follows the window
+(:func:`_mask_auto_block`), and the backward takes a block pair that one of
+the window's two edges crosses as 256-wide sub-tiles — dead, under an edge's
+triangle, or whole and unmasked: static given the pair's distance from the
+diagonal (:func:`_diag_sub`, :func:`_win_regions`, :func:`_window_dispatch`;
+the causal pair's sub-tiles of PR 29 with a second diagonal) — where the
+blocks and the window are multiples of the sub-tile.  The forward computes
+its visited pairs whole: cut, its folds of the running maximum multiply and
+it read slower (PERF.md §6, PR 59).  The per-head pair keeps a step a block
+pair.  The pair blocked over two heads and the resident forward carry the
+causal mask's own arithmetic and are never planned for a positional mask.
 
 Composition: this is the *single-chip* block; for sequences sharded
 across chips use :mod:`horovod_tpu.parallel.ring_attention`, which
@@ -126,6 +137,7 @@ from __future__ import annotations
 
 import contextlib
 import functools
+import math
 from typing import NamedTuple, Optional
 
 import jax
@@ -238,6 +250,105 @@ def _win_block_mask(mask, qi, kj, block_q, block_k):
              + lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
              - lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1))
     return jnp.logical_and(apart >= 0, apart < mask.window)
+
+
+def _win_first_k(mask, block_q, block_k, i):
+    """The first KV block Q block ``i`` reads under the causal window: the
+    block of its first row's oldest key."""
+    return jnp.maximum(i * block_q - mask.window + 1, 0) // block_k
+
+
+def _win_steps(mask, T, block_q, block_k) -> int:
+    """KV steps of a Q block's grid row under the causal window: the longest
+    run of live KV blocks any Q block has — from the block of its first
+    row's oldest key to the block of its last row (2 at 512 x 512 under 512
+    keys; at most ``ceil((block_q + window - 1) / block_k) + 1``, and never
+    more than ``T / block_k``)."""
+    return max(((i + 1) * block_q - 1) // block_k
+               - max(i * block_q - mask.window + 1, 0) // block_k + 1
+               for i in range(T // block_q))
+
+
+def _kv_step(mask, block_q, block_k, qi, step):
+    """The KV block that step ``step`` of Q block ``qi``'s grid row stands
+    for: ``step`` itself, but under a causal window — whose grid rows hold
+    :func:`_win_steps` steps, not a step a KV block — the Q block's first
+    live block plus ``step``.  The index is the true one, so the mask
+    helpers judge the pair by it; a step past the run's end (a Q block with
+    a shorter run than the longest) is dead by them, and its index maps hold
+    the run's last block (:func:`_win_live_k`)."""
+    if isinstance(mask, Window):
+        return _win_first_k(mask, block_q, block_k, qi) + step
+    return step
+
+
+def _win_regions(apart, block_q, block_k, window, sub):
+    """The ``sub``-wide sub-tiles that hold a live pair of a block pair whose
+    first row lies ``apart`` positions after its first column (``qi *
+    block_q - kj * block_k``), as products ``(row0, row1, col, edge)`` over
+    the sub-tile rows ``[row0, row1)`` of column ``col``:
+    :func:`_diag_regions` with a second diagonal.  Sub-tile ``(a, b)`` holds
+    ``query - key`` from ``e - sub + 1`` to ``e + sub - 1`` around ``e =
+    apart + (a - b) sub``, so with ``apart`` and ``window`` multiples of
+    ``sub`` it is dead for ``e < 0`` or ``e > window``, on the causal edge at
+    ``e == 0`` (``edge`` "near": its pairs at or under its diagonal live), on
+    the window's far edge at ``e == window`` ("far": those above its
+    diagonal) and whole between them (None; one tall product a column, the
+    backward's sums over rows)."""
+    rows = block_q // sub
+    out = []
+    for col in range(block_k // sub):
+        # Down a column ``e`` grows with the row.
+        near, far = col - apart // sub, col + (window - apart) // sub
+        for row0, row1, edge in ((near, near + 1, "near"),
+                                 (max(near + 1, 0), min(far, rows), None),
+                                 (far, far + 1, "far")):
+            if 0 <= row0 < row1 <= rows:
+                out.append((row0, row1, col, edge))
+    return out
+
+
+def _win_edge_tiles(window, T, block_q, block_k):
+    """The values ``qi * block_q - kj * block_k`` of the block pairs that an
+    edge of the causal window crosses (live, not interior) in a sequence of
+    ``T``: the pairs :func:`_window_dispatch` cuts into sub-tiles."""
+    step = math.gcd(block_q, block_k)
+    return tuple(
+        apart for apart in range(
+            step - block_q, min(window + block_k - 1, T - block_q + 1), step)
+        if apart - block_k + 1 < 0 or apart + block_q - 1 >= window)
+
+
+def _window_dispatch(region, qi, kj, block_q, block_k, mask, cut):
+    """Which products of the one backward kernel a KV group run on this
+    block pair under the causal window where it is cut (``cut``: ``(sub,``
+    :func:`_win_edge_tiles` ``)``): a pair inside the window
+    whole and unmasked; a pair one of the window's two edges crosses as
+    the ``sub``-wide sub-tiles that hold a live pair (:func:`_win_regions`
+    — static given the pair's distance from the diagonal, one body a
+    distance), the sub-tile on an edge under that edge's triangle and the
+    others unmasked; every other pair, a step past the run's end among them,
+    nothing.  ``region(rows, cols, ok)`` does the kernel's work on the static
+    slices ``rows`` x ``cols`` of the pair under the mask ``ok()`` (None:
+    every position valid)."""
+    sub, edge_tiles = cut
+    _, interior = _win_live_interior(mask, qi, kj, block_q, block_k)
+    pl.when(interior)(lambda: region(slice(None), slice(None), None))
+    apart = qi * block_q - kj * block_k
+
+    def sub_tiles(at):
+        def near():
+            return _block_mask(0, 0, sub, sub, True, None)
+
+        edges = {"near": near, "far": lambda: jnp.logical_not(near()),
+                 None: None}
+        for row0, row1, col, edge in _win_regions(
+                at, block_q, block_k, mask.window, sub):
+            region(slice(row0 * sub, row1 * sub),
+                   slice(col * sub, (col + 1) * sub), edges[edge])
+
+    for at in edge_tiles:
+        pl.when(apart == at)(functools.partial(sub_tiles, at))
 
 
 def _pos_live_interior(mask, qi, kj, block_q, block_k):
@@ -354,12 +465,15 @@ def _live_block(qi, kj, block_q, block_k, mask, seq_len):
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
                 m_scr, l_scr, acc_scr, *, scale, causal, block_q, block_k,
                 seq_len):
-    # Grid (B, H, T/block_q, T/block_k): the head is its own grid axis.
+    # Grid (B, H, T/block_q, KV steps): the head is its own grid axis.  A
+    # step is a KV block, but under a causal window, where it is the j-th
+    # block of the Q block's live run (:func:`_kv_step`).
     qi = pl.program_id(2)
-    kj = pl.program_id(3)
-    nk = pl.num_programs(3)
+    step = pl.program_id(3)
+    steps = pl.num_programs(3)
+    kj = _kv_step(causal, block_q, block_k, qi, step)
 
-    @pl.when(kj == 0)
+    @pl.when(step == 0)
     def _init():
         m_scr[...] = jnp.full_like(m_scr, _NEG_BIG)
         l_scr[...] = jnp.zeros_like(l_scr)
@@ -398,7 +512,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
     _masked_dispatch(_compute, live, qi, kj, block_q, block_k, causal,
                      seq_len)
 
-    @pl.when(kj == nk - 1)
+    @pl.when(step == steps - 1)
     def _finalize():
         l = jnp.maximum(l_scr[:, :1], 1e-30)
         o_ref[0] = (acc_scr[...] / l).astype(o_ref.dtype)
@@ -774,7 +888,13 @@ def _fwd_packed(q, k, v, H, D, plan, *, scale, causal, block_q, block_k,
     live_k = _select_live_k(
         causal if plan.fwd == "grid_live" or _positional(causal) else False,
         block_q, block_k)
-    grid = (B, H, nq, nk)
+    steps = nk
+    if isinstance(causal, Window):
+        # The KV axis is as long as a Q block's live run, and a step stands
+        # on the run's block of its number (:func:`_kv_step`).
+        steps = _win_steps(causal, T, block_q, block_k)
+        live_k = functools.partial(_win_run_k, causal, block_q, block_k)
+    grid = (B, H, nq, steps)
     kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
                                block_q=block_q, block_k=block_k,
                                seq_len=seq_len)
@@ -1564,7 +1684,7 @@ def _select_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dta_ref,
 
 def _select_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dta_ref, *rest,
                        scale, causal, block_q, block_k, seq_len, group,
-                       head_dim, has_map, v_dim):
+                       head_dim, has_map, v_dim, cut=None):
     """dq, dk and dv of one KV group in one sweep, grid as the forward's (Q
     blocks outside, KV blocks innermost): ``p`` and ``dS`` of a head are
     formed once a tile and feed all three products — five a tile where the
@@ -1588,63 +1708,78 @@ def _select_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dta_ref, *rest,
          dv_scr) = rest
     else:
         dq_ref, dk_ref, dv_ref, dq_scr, dk_scr, dv_scr = rest
+    # A step is a KV block, but under a causal window, where it is the j-th
+    # block of the Q block's live run (:func:`_kv_step`).
     qi = pl.program_id(2)
-    kj = pl.program_id(3)
+    step = pl.program_id(3)
     nq = pl.num_programs(2)
-    nk = pl.num_programs(3)
+    steps = pl.num_programs(3)
+    kj = _kv_step(causal, block_q, block_k, qi, step)
     D, Dv = head_dim, v_dim
 
-    @pl.when(jnp.logical_and(qi == 0, kj == 0))
+    @pl.when(jnp.logical_and(qi == 0, step == 0))
     def _init_head():
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
-    @pl.when(kj == 0)
+    @pl.when(step == 0)
     def _init():
         dq_scr[...] = jnp.zeros_like(dq_scr)
 
-    def _heads(masked: bool = False):
-        k = k_ref[0]
-        v = v_ref[0]
+    def _region(rows, cols, ok):
+        # ``rows`` x ``cols``: static slices of the block pair (all of it
+        # but where the window cuts it into sub-tiles).
+        k = k_ref[0, cols]
+        v = v_ref[0, cols]
         lse = lse_ref[0, 0]                               # (BQ, G)
         delta = dta_ref[0, 0]
-        rows = pl.ds(pl.multiple_of(kj * block_k, block_k), block_k)
-        ok = (_block_mask(qi, kj, block_q, block_k, causal, seq_len)
-              if masked else None)
+        first, width = kj * block_k, k.shape[0]
+        if cols.start:
+            first = first + cols.start
+        at = pl.ds(pl.multiple_of(first, width), width)
+        ok = ok() if ok else None
         for g in range(group):
-            q = q_ref[0, :, g * D:(g + 1) * D]
-            do = do_ref[0, :, g * Dv:(g + 1) * Dv]
+            q = q_ref[0, rows, g * D:(g + 1) * D]
+            do = do_ref[0, rows, g * Dv:(g + 1) * Dv]
             if has_map:
-                p, ds = _select_p_ds(q, k, v, do, lse[:, g:g + 1],
-                                     delta[:, g:g + 1], scale, bias_scr[...])
+                p, ds = _select_p_ds(q, k, v, do, lse[rows, g:g + 1],
+                                     delta[rows, g:g + 1], scale,
+                                     bias_scr[...])
             else:
-                p, ds = _p_ds(q, k, v, do, lse[:, g:g + 1],
-                              delta[:, g:g + 1], scale, ok)
+                p, ds = _p_ds(q, k, v, do, lse[rows, g:g + 1],
+                              delta[rows, g:g + 1], scale, ok)
             ds = ds.astype(k.dtype)
-            dq_scr[g] += jax.lax.dot_general(
+            dq_scr[g, rows] += jax.lax.dot_general(
                 ds, k, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
-            dv_scr[rows, :] += jax.lax.dot_general(
+            dv_scr[at, :] += jax.lax.dot_general(
                 p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
-            dk_scr[rows, :] += jax.lax.dot_general(
+            dk_scr[at, :] += jax.lax.dot_general(
                 ds, q, (((0,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
+
+    def _heads(masked: bool = False):
+        _region(slice(None), slice(None), functools.partial(
+            _block_mask, qi, kj, block_q, block_k, causal, seq_len)
+            if masked else None)
 
     if has_map:
         pl.when(_select_bias(sel_ref, bias_scr, qi, kj, block_q, block_k,
                              causal, seq_len))(_heads)
+    elif cut:
+        _window_dispatch(_region, qi, kj, block_q, block_k, causal, cut)
     else:
         _masked_dispatch(
             _heads, _live_block(qi, kj, block_q, block_k, causal, seq_len),
             qi, kj, block_q, block_k, causal, seq_len)
 
-    @pl.when(kj == nk - 1)
+    @pl.when(step == steps - 1)
     def _finalize():
         for g in range(group):
             dq_ref[0, :, g * D:(g + 1) * D] = dq_scr[g].astype(dq_ref.dtype)
 
-    @pl.when(jnp.logical_and(qi == nq - 1, kj == nk - 1))
+    @pl.when(jnp.logical_and(qi == nq - 1, step == steps - 1))
     def _finalize_head():
         dk_ref[0] = dk_scr[...].astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
@@ -1668,8 +1803,16 @@ def _win_live_k(mask, block_q, block_k, i, j):
     Q block ``i`` run from the block of its first row's oldest key to the
     block of its last row; a dead ``j`` before them holds the first, one
     behind them the last."""
-    first = jnp.maximum(i * block_q - mask.window + 1, 0) // block_k
-    return jnp.clip(j, first, ((i + 1) * block_q - 1) // block_k)
+    return jnp.clip(j, _win_first_k(mask, block_q, block_k, i),
+                    ((i + 1) * block_q - 1) // block_k)
+
+
+def _win_run_k(mask, block_q, block_k, i, step):
+    """The KV block that step ``step`` of Q block ``i``'s short grid row
+    holds (:func:`_kv_step`): the ``step``-th of its live run, and past the
+    run's end its last (no copy is issued for that dead step)."""
+    return _win_live_k(mask, block_q, block_k, i,
+                       _kv_step(mask, block_q, block_k, i, step))
 
 
 def _win_live_q(mask, block_q, block_k, nq, j, i):
@@ -1745,7 +1888,8 @@ def _select_fwd(q, k, v, select, H, D, *, scale, causal, block_q, block_k,
 
 
 def _select_bwd(q, k, v, select, o, lse, do, H, D, *, fused, scale, causal,
-                block_q, block_k, interpret, seq_len, vmem_mb, Dv=None):
+                block_q, block_k, interpret, seq_len, vmem_mb, Dv=None,
+                sub=0):
     """``(dq, dk, dv)`` on head-packed views, a KV group a grid step: from
     one kernel (:func:`_select_bwd_kernel`) where ``fused``, else from the
     pair (:func:`_select_dq_kernel`, :func:`_select_dkdv_kernel`); ``lse``
@@ -1754,7 +1898,10 @@ def _select_bwd(q, k, v, select, o, lse, do, H, D, *, fused, scale, causal,
     operand and scratch (the pair without a map is the per-head one,
     :func:`_bwd_pallas_packed`).  ``Dv``: the width of a head of ``v``,
     ``o`` and ``do`` where it is not ``D`` (the one kernel without a map
-    alone)."""
+    alone).  ``sub``: :func:`_diag_sub`'s under a causal window (the one
+    kernel without a map alone), the sub-tile a block pair on one of the
+    window's edges is cut into, 0 for none; its KV axis is then as long as
+    a Q block's live run (:func:`_win_steps`), whatever ``sub``."""
     B, T, _ = q.shape
     Hkv = k.shape[2] // D
     G = H // Hkv
@@ -1769,6 +1916,12 @@ def _select_bwd(q, k, v, select, o, lse, do, H, D, *, fused, scale, causal,
                      ).reshape(B, T, Hkv, G, Dv), axis=-1).transpose(0, 2, 1, 3)
     lse = lse.reshape(B, Hkv, G, T).transpose(0, 1, 3, 2)
     live_k = _select_live_k(causal, block_q, block_k)
+    steps, cut = nk, None
+    if isinstance(causal, Window):
+        steps = _win_steps(causal, T, block_q, block_k)
+        live_k = functools.partial(_win_run_k, causal, block_q, block_k)
+        cut = sub and (sub, _win_edge_tiles(causal.window, T, block_q,
+                                            block_k))
     kernel_kw = dict(scale=scale, causal=causal, block_q=block_q,
                      block_k=block_k, seq_len=seq_len, group=G, head_dim=D)
     dq_shape = _pallas.struct((B, T, H * D), q.dtype, *like)
@@ -1799,8 +1952,8 @@ def _select_bwd(q, k, v, select, o, lse, do, H, D, *, fused, scale, causal,
                     pltpu.VMEM((T, Dv), jnp.float32)]
         return tuple(pl.pallas_call(
             functools.partial(_select_bwd_kernel, **kernel_kw,
-                              has_map=has_map, v_dim=Dv),
-            grid=(B, Hkv, nq, nk),
+                              has_map=has_map, v_dim=Dv, cut=cut),
+            grid=(B, Hkv, nq, steps),
             in_specs=q_in_specs if has_map else q_in_specs[:-1],
             out_specs=[q_q, head, head_v],
             out_shape=[dq_shape, *dkdv_shapes],
@@ -1863,7 +2016,10 @@ class _Plan(NamedTuple):
     fwd_vmem_mb: int    # scoped-VMEM budget, MB; 0 = Mosaic's default
     bwd: str            # "grouped" | "per_head" | "group" | "group_fused"
     bwd_vmem_mb: int
-    bwd_sub: int        # sub-tile of the diagonal block pairs, else 0
+    # Sub-tile of the block pairs on the causal diagonal (the pair blocked
+    # over two heads) or on one of a causal window's two edges (the one
+    # kernel a KV group), else 0.
+    bwd_sub: int
     bwd_live_share: float   # of the scores the backward computes
     # (block_q, block_k, bwd_block_q, bwd_block_k) where a direction runs
     # in the group form: the group form's own blocks for it.
@@ -1877,14 +2033,46 @@ _DIAG_SUB = 256
 
 
 def _diag_sub(causal, block_q, block_k, sub=_DIAG_SUB) -> int:
-    """``sub`` where the grouped pair can cut its diagonal blocks into
-    sub-tiles of that side, else 0: causal, square blocks (so that the
-    diagonal pairs are those with ``qi == kj``) that ``sub`` divides into
-    at least two.  Only the interpreted tests, at their small blocks, ask
-    for another ``sub`` than the one timed on the chip."""
-    fits = (causal and block_q == block_k and block_q % sub == 0
-            and block_q > sub)
+    """``sub`` where a backward kernel can cut the block pairs its mask's
+    edge crosses into sub-tiles of that side, else 0.  The pair blocked over
+    two heads, causal: square blocks (so that the diagonal pairs are those
+    with ``qi == kj``) that ``sub`` divides into at least two.  The one
+    kernel a KV group under a causal window: both blocks and the window whole
+    multiples of ``sub`` (a sub-tile then lies dead, on one edge or inside:
+    :func:`_win_regions`), a block pair of at least two and at most
+    ``_WIN_SUB_TILES`` sub-tiles (a body a product, a head); anything else
+    keeps the whole masked pair.  No other positional mask is cut.  Only the
+    interpreted tests, at their small blocks, ask for another ``sub`` than
+    the one timed on the chip."""
+    if isinstance(causal, Window):
+        tiles = (block_q // sub) * (block_k // sub)
+        fits = (not any(n % sub for n in (block_q, block_k, causal.window))
+                and 1 < tiles <= _WIN_SUB_TILES)
+        return sub if fits else 0
+    fits = (causal and not _positional(causal) and block_q == block_k
+            and block_q % sub == 0 and block_q > sub)
     return sub if fits else 0
+
+
+# The most sub-tiles a block pair under a causal window is cut into: a
+# 1024² pair at 256.
+_WIN_SUB_TILES = 16
+
+
+def _win_visited(mask, T, block_q, block_k, sub) -> int:
+    """Score elements a kernel of these blocks computes for one head under
+    the causal window: the area of the block pairs that hold a live pair,
+    less — with a sub-tile — the dead sub-tiles of those an edge crosses."""
+    edge = {apart: sum(row1 - row0 for row0, row1, _, _ in
+                       _win_regions(apart, block_q, block_k, mask.window,
+                                    sub)) * sub * sub
+            for apart in (_win_edge_tiles(mask.window, T, block_q, block_k)
+                          if sub else ())}
+    return sum(edge.get(qi * block_q - kj * block_k, block_q * block_k)
+               for qi in range(T // block_q)
+               for kj in range(max(qi * block_q - mask.window + 1, 0)
+                               // block_k,
+                               ((qi + 1) * block_q - 1) // block_k + 1))
 
 
 def _bd_tiles(mask, T, block_q, block_k) -> int:
@@ -1909,12 +2097,13 @@ def _bwd_live_share(T, causal, block_q, block_k, sub) -> float:
     counting only those at or under the diagonal; under the block-diffusion
     mask its ``half (half + block)`` pairs, and under the causal window its
     ``min(i + 1, window)`` a row, over the area of the block pairs the mask
-    leaves a position of."""
+    leaves a position of (under the window less the dead sub-tiles of a
+    pair cut into ``sub``-wide ones: :func:`_win_visited`)."""
     if not causal:
         return 1.0
     if isinstance(causal, Window):
-        return round(window_pairs(T, causal.window) / (
-            _bd_tiles(causal, T, block_q, block_k) * block_q * block_k), 3)
+        return round(window_pairs(T, causal.window) / _win_visited(
+            causal, T, block_q, block_k, sub), 3)
     if _positional(causal):
         half, L = causal.half, causal.block
         return round(half * (half + L) / (
@@ -2024,7 +2213,7 @@ def _plan(*, T, D, H, head_base, itemsize, causal, block_q, block_k,
     # of 8 beyond the tile, else the other forms take over.
     tile = min(_FULL_UNROLL_BLOCK, block_q, block_k, T)
     fwd_vmem_mb = 0 if T <= _DEFAULT_VMEM_MAX_T else _FULL_UNROLL_VMEM_MB
-    positional = _positional(causal)
+    positional, windowed = _positional(causal), isinstance(causal, Window)
     if (T <= _FULL_UNROLL_MAX_T and T % tile == 0
             # The block-diffusion mask's tiles lie in one stream and hold
             # whole blocks of it.
@@ -2054,8 +2243,15 @@ def _plan(*, T, D, H, head_base, itemsize, causal, block_q, block_k,
         # of 32Q / 2KV and T 8,192.
         blocks = (block_q, block_k, *_group_bwd_blocks_no_map(
             bwd_block_q, bwd_block_k, kv_rep))
-        return _Plan(*fwd, "group_fused", _SELECT_FUSED_VMEM_MB, 0,
-                     _bwd_live_share(T, causal, blocks[2], blocks[3], sub=0),
+        # Under a causal window its block pairs on one of the window's two
+        # edges are cut into sub-tiles, where the blocks and the window
+        # allow: 6.27 against 6.78 ms a layer at 512 x 512 under 512 keys
+        # (PR 59; the grid forward's cut read SLOWER than whole pairs, 6.34
+        # against 4.72: a fold of the running maximum costs ~0.5 us
+        # whatever its size).
+        sub = _diag_sub(causal, blocks[2], blocks[3]) if windowed else 0
+        return _Plan(*fwd, "group_fused", _SELECT_FUSED_VMEM_MB, sub,
+                     _bwd_live_share(T, causal, blocks[2], blocks[3], sub),
                      blocks)
     # Tiles spanning two adjacent heads make the HBM rows twice as wide
     # as the per-head pair's 256-byte strided reads: 11.97 vs 12.18
@@ -2270,7 +2466,8 @@ def _select_bwd_call(q, k, v, select, o, lse, do, H, D, scale, causal,
                            fused=plan.bwd == "group_fused", scale=scale,
                            causal=causal, block_q=plan.blocks[2],
                            block_k=plan.blocks[3], interpret=interpret,
-                           seq_len=seq_len, vmem_mb=plan.bwd_vmem_mb, Dv=Dv)
+                           seq_len=seq_len, vmem_mb=plan.bwd_vmem_mb, Dv=Dv,
+                           sub=plan.bwd_sub)
 
 
 @functools.partial(jax.custom_vjp,
@@ -2487,13 +2684,24 @@ def flash_attention_auto(q, k, v, *, causal: bool = True,
     return out[:, :T]
 
 
+# The least side the window asks a forward tile to have: alone on a v5e (PR
+# 59, one layer of 64Q / 8KV at T 8,192 under 512 keys, whole pairs on the
+# short KV axis) tiles of 256 read 7.14 ms, of 512 4.72, of 1,024 6.05 — a
+# grid step costs ~0.4 us and a fold of the running maximum ~0.5 whatever the
+# tile, a score element ~5 ps: under the window's width the steps decide,
+# over it the dead area.
+_WINDOW_MIN_BLOCK = 512
+
+
 def _mask_auto_block(rows: int, mask) -> int:
     """:func:`flash_attention_auto`'s block for ``rows`` rows under a
     positional mask, from shapes alone.  Block diffusion: :func:`auto_block`'s
     of one stream's length, which has to hold whole blocks of the mask.  A
-    causal window: :func:`auto_block`'s of the rows (the grid's steps, not a
-    tile's dead area, decide a windowed call's time today: ``chip_smoke.py
-    --window-mask``)."""
+    causal window: :func:`auto_block`'s of the rows, but no wider than the
+    window (or ``_WINDOW_MIN_BLOCK``, if that is more) where a lane-aligned
+    divisor of the rows is: since the KV axis is as long as a Q block's live
+    run (PR 59) a tile's dead area decides, not the grid's steps
+    (``chip_smoke.py --window-mask``)."""
     kind, n = _mask_arg(mask)
     if kind == "window":
         blk = auto_block(rows)
@@ -2501,7 +2709,9 @@ def _mask_auto_block(rows: int, mask) -> int:
             raise ValueError(
                 f"flash_attention_auto: {rows} rows do not tile (no padding "
                 f"under a positional mask, {mask!r})")
-        return blk
+        width = max(n, _WINDOW_MIN_BLOCK)
+        return max((d for d in range(128, min(blk, width) + 1, 128)
+                    if rows % d == 0), default=blk)
     half = rows // 2
     blk = auto_block(half)
     if rows % 2 or not blk or blk % n:
@@ -2531,6 +2741,11 @@ def mask_tile_counts(q, k, mask) -> dict:
     leaves —, ``live_tiles`` — the forward form's tiles that hold one of
     them, a query head, from the mask's definition — and ``visited_tiles``
     — the tiles its kernel computes (the dead test its grid steps run).
+    Under a causal window also ``grid_steps`` — the steps of the forward's
+    grid, all query heads —, ``live_steps`` — those of them that compute a
+    tile — and ``visited_pairs`` — the pairs a head's forward computes
+    scores of, to set against ``live_pairs``: the visited tiles' area (the
+    backward's share is ``_Plan.bwd_live_share``, its sub-tiles left out).
 
     Block diffusion, ``q`` (B, 2T, H, D): ``T (T + L)`` pairs a sequence;
     with ``n`` tiles a stream, ``n (n + 1) / 2`` tiles of the clean rows, as
@@ -2548,11 +2763,17 @@ def mask_tile_counts(q, k, mask) -> dict:
     tile = plan.fwd_tile if plan.fwd == "fullunroll" else blk
     visited = B * H * _bd_tiles(held, rows, tile, tile)
     if kind == "window":
+        nq = rows // tile
+        steps = B * H * {"fullunroll": 1, "unrollkv": nq}.get(
+            plan.fwd, nq * _win_steps(held, rows, tile, tile))
         return {"live_pairs": B * window_pairs(rows, n),
                 "live_tiles": B * H * sum(
                     i - max(i * tile - n + 1, 0) // tile + 1
-                    for i in range(rows // tile)),
-                "visited_tiles": visited}
+                    for i in range(nq)),
+                "visited_tiles": visited,
+                "grid_steps": steps,
+                "live_steps": visited if plan.fwd == "grid" else steps,
+                "visited_pairs": visited // H * tile * tile}
     t = held.half // tile
     return {"live_pairs": B * held.half * (held.half + n),
             "live_tiles": B * H * (t * t + t + (t if tile > n else 0)),

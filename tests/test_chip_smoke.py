@@ -488,28 +488,61 @@ class TestCompileCachePlacement:
         assert (events.hits, events.writes) == (1, 2)
 
 
-def test_window_mask_phase(smoke):
+def test_window_mask_phase(smoke, monkeypatch):
     """The flash kernels under the causal window, alone (interpreted here:
     no time is reported): the plan under the block the shapes give, the
-    tiles visited against the tiles live, the global kind's plan at six
-    query heads a KV head, the kernels against the dense oracle, and the
-    window's two edges on peaked scores — met to ``EDGE_TOL``, a window one
-    key wider or narrower missed by the output's own size; on the chip the
-    phase runs at ``lagunaxs2_1chip``'s two attention shapes."""
+    grid's steps, the tiles and the pairs visited against the live ones, a
+    block's schedule forward and backward, the backward with and without the
+    cut (PR 59; here with sub-tiles of 8 for the chip's 256, and the plan put
+    back),
+    the global kind's plan at six query heads a KV head, the kernels
+    against the dense oracle, and the window's two edges on peaked scores
+    — met to ``EDGE_TOL``, a window one key wider or narrower missed by the
+    output's own size; on the chip the phase runs at ``lagunaxs2_1chip``'s
+    two attention shapes."""
+    from horovod_tpu.ops import flash_attention as fa
+
+    for limit in ("_FULL_UNROLL_MAX_T", "_UNROLL_KV_MAX_NK"):
+        monkeypatch.setattr(fa, limit, 0)           # the grid forward
+    plan = fa._plan
+
+    def cut(**seen):
+        p = plan(**seen)
+        if not isinstance(seen["causal"], fa.Window):
+            return p
+        return p._replace(
+            bwd_sub=fa._diag_sub(seen["causal"], *p.blocks[2:], 8))
+
+    monkeypatch.setattr(fa, "_plan", cut)
     out = smoke.window_mask_phase(batch=1, seq=64, heads=8, global_heads=6,
                                   kv_heads=1, head_dim=128, window=16,
                                   check_seq=32, seed=0, blocks=(16, 32),
                                   edge_step=32)
+    assert fa._plan is cut
     assert out["interpret"] and out["shape"] == [1, 64, 8, 1, 128]
     assert set(out["ms_a_layer"]) == {
         f"{name}.{way}" for name in ("window.16", "window.32", "global")
-        for way in ("forward", "backward")}
-    assert not any(out["ms_a_layer"].values())
+        for way in ("forward", "backward")} | {
+        "window.16.whole.backward", "window.32.whole.backward"}
+    assert all(read == [None, None] for read in out["ms_a_layer"].values())
     assert out["win_plan"]["bwd"] == out["global_plan"]["bwd"] == (
         "group_fused")
-    # One tile of 64 rows: the fully unrolled form's.
-    assert out["tiles"] == {"live_pairs": 16 * 17 // 2 + 48 * 16,
-                            "live_tiles": 8, "visited_tiles": 8}
+    # One tile of 64 rows a head, too many sub-tiles to cut: whole.
+    assert (out["win_plan"]["fwd"], out["win_plan"]["bwd_sub"]) == ("grid", 0)
+    pairs = 16 * 17 // 2 + 48 * 16
+    assert out["tiles"] == {
+        "live_pairs": pairs, "live_tiles": 8, "visited_tiles": 8,
+        "grid_steps": 8, "live_steps": 8, "visited_pairs": 64 * 64}
+    # Blocks of 16: 21 of the 64 sub-tiles of 8 hold a live pair, 7 of the
+    # 16 tiles.
+    assert out["schedule"][16] == {
+        "bwd_sub": 8, "bwd_blocks": [16, 16],
+        "fwd_grid_steps": 8 * 4 * 2, "fwd_live_steps": 8 * 7,
+        "bwd_grid_steps": 4 * 2, "bwd_live_steps": 7,
+        "fwd_pairs_over_live": round(7 * 256 / pairs, 3),
+        "bwd_pairs_over_live": [round(21 * 64 / pairs, 3),
+                                round(7 * 256 / pairs, 3)]}
+    assert out["schedule"][32]["fwd_grid_steps"] == 8 * 2 * 2
     assert max(out["against_dense"].values()) <= 2e-2
     edges = out["edges"]
     assert max(edges["against_dense"].values()) <= smoke.EDGE_TOL

@@ -11,7 +11,8 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from _v5e import compile_text, custom_calls, scoped_vmem_mb, v5e  # noqa: F401
+from _v5e import (compile_text, custom_calls, pallas_calls,  # noqa: F401
+                  scoped_vmem_mb, v5e)
 
 # The benchmark TransformerLM: d=2048, 16 heads of 128, T=2048, batch 8.
 B, T, H, D = 8, 2048, 16, 128
@@ -164,37 +165,90 @@ def test_block_mask_flash_fwd_bwd_at_the_sdar_cell_s_shape(v5e, monkeypatch):
     jax.clear_caches()      # the traces do not key on the budget
 
 
-@pytest.mark.parametrize("kind,heads,how", [
-    ("windowed", 64, {"mask": ("window", 512)}),
-    ("global", 48, {"causal": True}),
+@pytest.mark.parametrize("kind,heads,how,grids", [
+    ("windowed", 64, {"mask": ("window", 512)},
+     [(1, 64, 16, 2), (1, 8, 16, 2)]),
+    ("global", 48, {"causal": True}, [(1, 48, 8, 8), (1, 8, 16, 16)]),
 ])
-def test_the_laguna_cell_s_two_attention_calls_fwd_bwd(v5e, kind, heads, how):
+def test_the_laguna_cell_s_two_attention_calls_fwd_bwd(v5e, monkeypatch,
+                                                       kind, heads, how,
+                                                       grids):
     """``lagunaxs2_1chip``'s calls: one sequence of 8,192 over 8 KV heads of
     128 — the windowed layers' 64 query heads under 512 keys a query
-    (forward tiles of 1,024, the block the shapes choose) and the global
+    (tiles of 512, the block the shapes choose) and the global
     layers' 48 under the causal mask (six query heads a KV head, which no
     other cell runs).  The grid forward and the one backward kernel a KV
     group, dK and dV of 8,192 rows resident, compile for the v5e under the
-    budgets the plan gives; no map is an operand."""
+    budgets the plan gives; no map is an operand.  Under the window both
+    walk the band (PR 59): a KV axis of the live run's 2 steps where the
+    causal call's has 8 and 16, and the backward's block pairs on the
+    window's edges in sub-tiles of 256."""
     from horovod_tpu.ops import flash_attention as fa
 
     one = SingleDeviceSharding(v5e[0])
     q = jax.ShapeDtypeStruct((1, 8192, heads, 128), jnp.bfloat16,
                              sharding=one)
     kv = jax.ShapeDtypeStruct((1, 8192, 8, 128), jnp.bfloat16, sharding=one)
+    plans = []
+    plan = fa._plan
+    monkeypatch.setattr(
+        fa, "_plan", lambda **seen: plans.append(plan(**seen)) or plans[-1])
 
     def loss(q, k, v):
         return fa.flash_attention(q, k, v, **how).astype(jnp.float32).sum()
 
-    lowered = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
-        q, kv, kv)
+    grad = jax.value_and_grad(loss, argnums=(0, 1, 2))
+    lowered = jax.jit(grad).lower(q, kv, kv)
     assert custom_calls(lowered.as_text()) == [
         ("_fwd_kernel", 3), ("flash_group_bwd", 6)]
     assert scoped_vmem_mb(lowered.as_text()) == {
         "_fwd_kernel": 0, "flash_group_bwd": fa._SELECT_FUSED_VMEM_MB}
+    assert [grid for _, grid, _ in pallas_calls(
+        jax.make_jaxpr(grad)(q, kv, kv).jaxpr)] == grids
+    assert {(p.fwd, p.bwd, p.bwd_sub, p.blocks) for p in plans} == {
+        ("grid", "group_fused", 256, (512,) * 4) if kind == "windowed" else
+        ("grid", "group_fused", 0, (1024, 1024, 512, 512))}
     _, (dq, dk, dv) = lowered.compile().out_info
     assert dq.shape == (1, 8192, heads, 128)
     assert dk.shape == dv.shape == (1, 8192, 8, 128)
+
+
+# (window, block_q, block_k, KV steps, sub-tile): the tilings ``_plan``
+# admits for a windowed call beside the cell's own, at two query heads a KV
+# head to keep the compiles short — a pair of one sub-tile is never cut;
+# oblong blocks, three distances on an edge; a window of three sub-tiles; one
+# no multiple of the sub-tile keeps whole masked pairs on its short axis.
+@pytest.mark.parametrize("window,block_q,block_k,steps,sub", [
+    (512, 256, 256, 3, 0), (512, 512, 512, 2, 256), (512, 1024, 512, 3, 256),
+    (768, 512, 512, 3, 256), (500, 512, 512, 2, 0)])
+def test_the_window_s_band_compiles_at_every_tiling(v5e, monkeypatch, window,
+                                                    block_q, block_k, steps,
+                                                    sub):
+    from horovod_tpu.ops import flash_attention as fa
+
+    one = SingleDeviceSharding(v5e[0])
+    q = jax.ShapeDtypeStruct((1, 8192, 16, 128), jnp.bfloat16, sharding=one)
+    kv = jax.ShapeDtypeStruct((1, 8192, 8, 128), jnp.bfloat16, sharding=one)
+    plans = []
+    plan = fa._plan
+    monkeypatch.setattr(
+        fa, "_plan", lambda **seen: plans.append(plan(**seen)) or plans[-1])
+
+    def loss(q, k, v):
+        return fa.flash_attention(
+            q, k, v, mask=("window", window), block_q=block_q,
+            block_k=block_k).astype(jnp.float32).sum()
+
+    grad = jax.value_and_grad(loss, argnums=(0, 1, 2))
+    lowered = jax.jit(grad).lower(q, kv, kv)
+    assert custom_calls(lowered.as_text()) == [
+        ("_fwd_kernel", 3), ("flash_group_bwd", 6)]
+    assert [grid for _, grid, _ in pallas_calls(
+        jax.make_jaxpr(grad)(q, kv, kv).jaxpr)] == [
+        (1, 16, 8192 // block_q, steps), (1, 8, 8192 // block_q, steps)]
+    assert {(p.fwd, p.bwd, p.bwd_sub, p.blocks[2:]) for p in plans} == {
+        ("grid", "group_fused", sub, (block_q, block_k))}
+    lowered.compile()
 
 
 def test_latent_attention_s_kernels_fwd_bwd_at_the_joyai_cell_s_shape(

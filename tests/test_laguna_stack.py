@@ -335,6 +335,9 @@ def test_lagunalm_is_the_published_stack_and_counts_its_kinds():
     # One tile of 64 rows a head: the fully unrolled form's.
     assert win["attn.win_live_tiles"] == win["attn.win_visited_tiles"] == (
         2 * 16)
+    # A step a head, each computing its one tile whole.
+    assert win["attn.win_grid_steps"] == win["attn.win_live_steps"] == 2 * 16
+    assert win["attn.win_visited_pairs"] == 2 * T * T
     assert notes[("layer_3", "moe")]["moe.assignments"] == 2 * T * 3
 
 
