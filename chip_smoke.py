@@ -39,6 +39,16 @@ through the entry points a user calls (``hvd.init()`` →
   forward, the per-head pair and the one fused kernel a KV group — checks
   the fused kernel's gradients against the pair's and prints which of the
   two ``flash_attention._plan`` takes here (``gqa_plan``);
+* times the forward of a call with grouped KV heads at one width and no
+  map alone, in every form timed before ``_plan``'s rule for it was set —
+  the grid form, it with its dead fetches clamped, and a KV head's K and V
+  rows resident with the loop rolled over the live run, in chains of 256
+  and 512 rows at tiles of 1,024 and 512 — at the calls of the four cells
+  that run it (``sdar_1chip`` under the block mask, ``zaya1_1chip``,
+  ``lagunaxs2_1chip``'s global and windowed kinds, ``twotower_1chip``),
+  each read twice, with the plan and what each form visits
+  (``grouped_forward``, ``fwd_plan``, ``notes``; ``--grouped-forward``
+  runs this table alone);
 * times the flash kernels under the block-diffusion mask alone at
   ``sdar_1chip``'s attention shape — a clean and a noised copy of 8,192
   tokens, 32 query heads over 4 KV heads —, prints the plan and the tiles
@@ -182,6 +192,22 @@ GROUPED_BACKWARD = {
                         head_dim=128),
     "twotower_1chip": dict(batch=2, seq=8192, heads=32, kv_heads=2,
                            head_dim=128)}
+# The forward of a call with grouped KV heads at one width and no map, alone,
+# at the calls of the four cells that run it past the fully-unrolled form's
+# reach: sdar_1chip (a clean and a noised copy of 8,192 tokens under the
+# block mask), zaya1_1chip, lagunaxs2_1chip's global kind and its windowed
+# kind, twotower_1chip.
+GROUPED_FORWARD = {
+    "sdar_1chip": dict(batch=1, seq=16384, heads=32, kv_heads=4,
+                       head_dim=128, mask=("block_diffusion", 4)),
+    "zaya1_1chip": dict(batch=1, seq=16384, heads=8, kv_heads=2,
+                        head_dim=128, mask=None),
+    "lagunaxs2_1chip.global": dict(batch=1, seq=8192, heads=48, kv_heads=8,
+                                   head_dim=128, mask=None),
+    "lagunaxs2_1chip.window": dict(batch=1, seq=8192, heads=64, kv_heads=8,
+                                   head_dim=128, mask=("window", 512)),
+    "twotower_1chip": dict(batch=2, seq=8192, heads=32, kv_heads=2,
+                           head_dim=128, mask=None)}
 # One attention layer's flash kernels under the block-diffusion mask alone
 # at sdar_1chip's shape: a clean and a noised copy of 8,192 tokens, 32 query
 # heads over 4 KV heads of 128, blocks of 4; ``check_seq``: the length at
@@ -1230,14 +1256,6 @@ def grouped_backward_phase(*, batch: int, seq: int, heads: int, kv_heads: int,
         lambda *a: fa._fwd_packed(*a, H, D, plan, block_q=blocks[0],
                                   block_k=blocks[1], kv_rep=H // Hkv,
                                   **common), q, k, v)
-    if plan.fwd == "grid":
-        # The control of PR 51: the dead steps' K/V fetches clamped.
-        ms["forward_live"], (o_live, lse_live) = timed(
-            lambda *a: fa._fwd_packed(
-                *a, H, D, plan._replace(fwd="grid_live"), block_q=blocks[0],
-                block_k=blocks[1], kv_rep=H // Hkv, **common), q, k, v)
-        check(bool((o_live == o).all()) and bool((lse_live == lse).all()),
-              "the grid forward with its dead fetches clamped differs")
     operands = (q, k, v, o, lse, do)
     ms["pair"], want = timed(
         lambda *a: fa._bwd_pallas_packed(
@@ -1260,13 +1278,99 @@ def grouped_backward_phase(*, batch: int, seq: int, heads: int, kv_heads: int,
             "fused_vs_pair": {n: round(e, 6) for n, e in errs.items()}}
 
 
+def grouped_forward_phase(*, batch: int, seq: int, heads: int, kv_heads: int,
+                          head_dim: int, mask, seed: int, calls: int = 10,
+                          blocks=(1024, 512), chain_rows=(256, 512)) -> dict:
+    """The forward of one attention layer with grouped KV heads at one width
+    and no map, alone, under the causal mask or the positional ``mask``, in
+    every form timed before ``flash_attention._plan``'s rule for such a
+    call was set (PR 60), ``ms_a_layer`` by form and tile, each read twice
+    (``[first, second]``): ``grid`` at the block the shapes give — the form
+    every such call ran before; ``grid_live``, it with the K/V index of a
+    step in the causal future held at the last live block (under a
+    positional mask every grid plan holds it: no second entry);
+    ``resident.<rows>.<block>``, a KV head's K and V rows in VMEM, the KV
+    loop rolled inside the grid step over the live run, the Q block in
+    chains of ``rows`` rows, the tiles on the mask's edges as the chains'
+    sub-tiles (under a causal window, which no plan gives this form, the
+    run's tiles whole and masked: ROADMAP S17 (a)'s one step a Q block).
+    ``notes``: by form and tile the score elements a head's forward
+    computes over the pairs the mask leaves (``pairs_over_live``), the same
+    in tiles (``visited_tiles``) and its grid steps a head.  ``vs_grid``:
+    each form's ``o`` and ``lse`` against the grid form's, of the largest
+    value.  ``fwd_plan`` is what ``_plan`` decides for the call on this
+    device."""
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.ops import flash_attention as fa
+
+    interpret = jax.default_backend() != "tpu"
+    B, T, H, Hkv, D = batch, seq, heads, kv_heads, head_dim
+    held, auto, live = True, fa.auto_block(T), T * (T + 1) // 2
+    if mask is not None:
+        kind, n = fa._mask_arg(mask)
+        auto = fa._mask_auto_block(T, mask)
+        held, live = ((fa.Window(n), fa.window_pairs(T, n))
+                      if kind == "window" else
+                      (fa.BlockDiffusion(n, T // 2), T // 2 * (T // 2 + n)))
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    q, k, v = (jax.random.normal(key, (B, T, h * D)).astype(jnp.bfloat16)
+               for key, h in zip(ks, (H, Hkv, Hkv)))
+    plan = fa._plan_for(q, H, D, (0, 0, 0), held, *(auto,) * 4, interpret,
+                        kv_rep=H // Hkv)
+    # (form, rows a chain, scoped MB, block)
+    forms = [("grid", 0, 0, auto)]
+    if not fa._positional(held):
+        forms.append(("grid_live", 0, 0, auto))
+    forms += [("resident", rows, fa._RESIDENT_VMEM_MB, blk)
+              for blk in blocks for rows in chain_rows
+              if T % blk == 0 and blk % rows == 0
+              and not (isinstance(held, fa.BlockDiffusion)
+                       and held.half % blk)]
+    ms, notes, errs, want = {}, {}, {}, None
+    for fwd, rows, vmem_mb, blk in forms:
+        name = f"{fwd}.{rows}.{blk}" if rows else f"{fwd}.{blk}"
+        form = plan._replace(fwd=fwd, fwd_tile=rows, fwd_vmem_mb=vmem_mb)
+
+        def run(*a, form=form, blk=blk):
+            return fa._fwd_packed(*a, H, D, form, scale=D ** -0.5,
+                                  causal=held, block_q=blk, block_k=blk,
+                                  interpret=interpret, kv_rep=H // Hkv)
+
+        (first, got), (second, _) = (_timed_ms(calls, interpret, run, q, k, v)
+                                     for _ in range(2))
+        ms[name] = [first, second]
+        pairs = fa._fwd_visited_pairs(
+            form._replace(fwd="grid" if fwd == "grid_live" else fwd), held,
+            T, blk)
+        steps = T // blk * (1 if fwd == "resident" else (
+            fa._win_steps(held, T, blk, blk)
+            if isinstance(held, fa.Window) else T // blk))
+        notes[name] = {"pairs_over_live": round(pairs / live, 3),
+                       "visited_tiles": round(pairs / blk ** 2, 3),
+                       "grid_steps_a_head": steps}
+        if want is None:
+            want = got
+            continue
+        errs[name] = [round(_rel_err(g, w), 6) for g, w in zip(got, want)]
+        check(max(errs[name]) <= SELECT_TOL,
+              f"the {name} forward differs from the grid form in (o, lse) "
+              f"by {errs[name]} (bound {SELECT_TOL})")
+    return {"shape": [B, T, H, Hkv, D], "mask": mask, "interpret": interpret,
+            "fwd_plan": plan._asdict(), "ms_a_layer": ms, "notes": notes,
+            "vs_grid": errs}
+
+
 def block_mask_phase(*, batch: int, seq: int, heads: int, kv_heads: int,
                      head_dim: int, block: int, check_seq: int, seed: int,
                      calls: int = 10) -> dict:
     """The flash kernels under the block-diffusion mask, alone, at one
     layer's shape: ``bd_plan`` — what ``flash_attention._plan`` decides for
-    the ``2 * seq`` rows on this device —, the tiles a query head's forward
-    visits against those that hold a live pair, the forward's and the
+    the ``2 * seq`` rows on this device —, ``tiles`` — ``mask_tile_counts``:
+    the forward's tile-areas visited against the tiles that hold a live
+    pair, its grid steps against those that compute, the pairs computed
+    against the live ones —, the forward's and the
     backward's time (``ms_a_layer``), beside them the same operands under
     the CAUSAL mask (136 live tiles of 1024 squared a head where the block
     mask leaves 80 — what two plain causal passes of ``2 * seq`` rows would
@@ -1301,9 +1405,12 @@ def block_mask_phase(*, batch: int, seq: int, heads: int, kv_heads: int,
                         fa.BlockDiffusion(block, seq), blk, blk, blk, blk,
                         interpret, kv_rep=H // Hkv)
     counts = fa.mask_tile_counts(q, k, mask)
-    check(counts["visited_tiles"] == counts["live_tiles"],
-          f"the forward visits {counts['visited_tiles']} tiles, "
-          f"{counts['live_tiles']} hold a live pair")
+    # The resident form's edge tiles are sub-tiles: less area than the
+    # tiles that hold a live pair, never less than the pairs.
+    check(counts["visited_tiles"] <= counts["live_tiles"]
+          and counts["visited_pairs"] >= counts["live_pairs"]
+          and counts["grid_steps"] >= counts["live_steps"],
+          f"the forward's counts under the block mask: {counts}")
     timed = functools.partial(_timed_ms, calls, interpret)
     ms = {}
     for name, masked in (("block_mask", {"mask": mask}),
@@ -2262,6 +2369,11 @@ def main(argv=None) -> int:
                     help="only the flash kernels under the causal window, "
                          "at every candidate block, beside the global "
                          "kind's causal call")
+    ap.add_argument("--grouped-forward", action="store_true",
+                    help="only the forward table of a call with grouped KV "
+                         "heads at one width: grid, grid_live and the "
+                         "resident form at two chains and two tiles, at the "
+                         "four cells' calls")
     ap.add_argument("--launcher-worker", action="store_true",
                     help=argparse.SUPPRESS)
     args = ap.parse_args(argv)
@@ -2320,6 +2432,10 @@ def main(argv=None) -> int:
     elif args.window_mask:
         emit("window_mask", **window_mask_phase(**WINDOW_MASK,
                                                 seed=args.seed))
+    elif args.grouped_forward:
+        for cell, shape in GROUPED_FORWARD.items():
+            emit("grouped_forward", cell=cell, **grouped_forward_phase(
+                **shape, seed=args.seed))
     elif args.chips == 1:
         emit("flash_reference", **flash_reference_phase(
             **FLASH_REFERENCE, seed=args.seed))
@@ -2348,6 +2464,9 @@ def main(argv=None) -> int:
             **SELECT_BACKWARD, seed=args.seed))
         for cell, shape in GROUPED_BACKWARD.items():
             emit("grouped_backward", cell=cell, **grouped_backward_phase(
+                **shape, seed=args.seed))
+        for cell, shape in GROUPED_FORWARD.items():
+            emit("grouped_forward", cell=cell, **grouped_forward_phase(
                 **shape, seed=args.seed))
         emit("block_mask", **block_mask_phase(**BLOCK_MASK, seed=args.seed))
         emit("window_mask", **window_mask_phase(**WINDOW_MASK,

@@ -310,8 +310,11 @@ class GroupedQueryAttention(nn.Module):
     flash_attention`), the kernels under the trace scope ``bd/attend`` and
     the counters ``attn.bd_block``, ``attn.bd_live_pairs``,
     ``attn.bd_live_tiles``, ``attn.bd_visited_tiles`` (the forward's tiles,
-    all query heads: :func:`~horovod_tpu.ops.flash_attention.
-    mask_tile_counts`); not with an ``indexer``.
+    all query heads; in the resident form the area of its sub-tiles),
+    ``attn.bd_grid_steps``, ``attn.bd_live_steps`` (the forward's grid and
+    the steps of it that compute a tile) and ``attn.bd_visited_pairs`` (the
+    pairs a head's forward computes scores of: :func:`~horovod_tpu.ops.
+    flash_attention.mask_tile_counts`); not with an ``indexer``.
     ``scale``: the factor on ``q k^T`` (default ``head_dim ** -0.5``).
     ``rope_width`` and ``rope_scaling``: :func:`apply_rotary`'s rotated
     width (a partial rotary factor) and YaRN table, on q and k alike.

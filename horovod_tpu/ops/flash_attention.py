@@ -33,12 +33,16 @@ order of preference:
   off the lane width (``flash_attention`` merges those into the batch:
   packed rows of one head);
 * resident (:func:`_fwd_kernel_resident`, PR 51), for values of another
-  width than the keys: a head's K and V rows in VMEM under a stated budget
-  (to 6 MiB: T 8,192 at 256 + 128 lanes), grid ``(B, H, T/block_q)``, the
-  KV loop ROLLED inside the grid step over the live tiles alone, the Q
-  block in independent chains of 256 rows carried as values, the diagonal
-  tile as each chain's triangle.  Where the device backs no such budget,
-  the rows do not fit or the tiles are off the lanes: the grid form.
+  width than the keys and, since PR 60, for grouped KV heads at one width
+  past the fully-unrolled form's reach: a KV head's K and V rows in VMEM
+  under a stated budget (to 8 MiB: T 16,384 at D 128, or T 8,192 at 256 +
+  128 lanes), grid ``(B, H, T/block_q)``, the KV loop ROLLED inside the
+  grid step over the live run alone (:func:`_resident_run`), the Q block in
+  independent chains of 256 rows carried as values, a tile on the mask's
+  edge — the causal diagonal, the block-diffusion mask's two — as each
+  chain's own sub-tiles under a static mask.  Where the device backs no
+  such budget, the rows do not fit, the tiles are off the lanes, at one
+  query head a KV head or under a causal window: the grid form.
 
 Backward: ``jax.custom_vjp`` saving (o, logsumexp); gradients use the
 standard flash-backward identities (dS = P * (dP - rowsum(dO*o))) as two
@@ -121,8 +125,10 @@ the causal pair's sub-tiles of PR 29 with a second diagonal) — where the
 blocks and the window are multiples of the sub-tile.  The forward computes
 its visited pairs whole: cut, its folds of the running maximum multiply and
 it read slower (PERF.md §6, PR 59).  The per-head pair keeps a step a block
-pair.  The pair blocked over two heads and the resident forward carry the
-causal mask's own arithmetic and are never planned for a positional mask.
+pair.  The pair blocked over two heads carries the causal mask's own
+arithmetic and is never planned for a positional mask; the resident forward
+walks the block-diffusion mask's run and edge tiles (PR 60) and is not
+planned under a window.
 
 Composition: this is the *single-chip* block; for sequences sharded
 across chips use :mod:`horovod_tpu.parallel.ring_attention`, which
@@ -612,17 +618,76 @@ def _fold_tile(q, k, v, ok, m, l, acc, scale):
     return m_new, l, acc
 
 
+def _resident_run(mask, qi, block_q, block_k, nk, seq_len, rows):
+    """Q block ``qi``'s live tiles in the resident forward: ``(first, n_int,
+    n_live, edges)``.  Tiles ``[first, n_int)`` need no mask.  ``edges``: the
+    tiles behind them where the mask's edge is static in a chain's own
+    ``rows`` x ``rows`` sub-tile — square tiles without padding under the
+    causal mask (the tile on the diagonal) or the block-diffusion mask (at
+    most two, every edge a multiple of ``mask.block``) — as ``(tile, left,
+    ok, when)``: a chain folds, of tile ``tile``, the columns left of its
+    rows whole (``left``), then its own sub-tile under ``ok()``, and nothing
+    right of it; ``when`` (None: always) says whether the Q block reads the
+    tile at all.  ``edges`` None: the tiles ``[n_int, n_live)`` whole under
+    the mask's ``iota`` form.  Python integers in, python integers out (the
+    tests enumerate it); traced ``qi`` in, traced scalars out.
+
+    Under the block-diffusion mask Q block ``qi`` stands at tile ``at`` of
+    its stream and reads the clean tiles ``[0, at)`` whole, the clean tile
+    ``at`` on its diagonal — a clean query the keys with ``kb <= qb``, a
+    noised one those with ``kb < qb`` — and, noised, its own tile ``qi``
+    (``kb == qb``: the sub-tile alone).  By area 0.625 + 0.25 of a tile
+    where the grid form runs two whole (``rows`` a quarter of the tile).
+    Under a causal window (which no plan gives this form: ``chip_smoke.py``
+    times it — 3.65 against the band's grid form's 4.19 ms a layer at 512 x
+    512 under 512 keys, PR 60; ROADMAP S17 (a)) the run from the tile of the
+    first row's oldest key to the diagonal, every tile masked: exact work
+    only for a window no wider than a tile."""
+    if isinstance(mask, BlockDiffusion):
+        assert block_q == block_k and rows % mask.block == 0, (mask, block_q,
+                                                               block_k, rows)
+        noised = (qi * block_q >= mask.half) * 1
+        at = qi - mask.half // block_q * noised
+
+        def apart():                                # kb - qb in a sub-tile
+            qb, kb = (lax.broadcasted_iota(jnp.int32, (rows, rows), axis)
+                      // mask.block for axis in (0, 1))
+            return kb - qb
+
+        return 0, at, None, ((at, True, lambda: apart() <= -noised, None),
+                             (qi, False, lambda: apart() == 0, noised == 1))
+    if isinstance(mask, Window):
+        first = _win_first_k(mask, block_q, block_k, qi)
+        return first, first, ((qi + 1) * block_q - 1) // block_k + 1, None
+    # Tiles [0, n_int) need no mask; [n_int, n_live) do; the rest are dead.
+    n_int = n_live = nk
+    if mask:
+        n_int = jnp.minimum(nk, (qi * block_q + 1) // block_k)
+        n_live = jnp.minimum(nk, ((qi + 1) * block_q - 1) // block_k + 1)
+    if seq_len is not None:
+        n_int = jnp.where((qi + 1) * block_q <= seq_len,
+                          jnp.minimum(n_int, seq_len // block_k), 0)
+        n_live = jnp.where(qi * block_q < seq_len,
+                           jnp.minimum(n_live, -(-seq_len // block_k)), 0)
+    edges = None
+    if mask and block_q == block_k and seq_len is None:
+        edges = ((qi, True,
+                  lambda: _block_mask(0, 0, rows, rows, True, None), None),)
+    return 0, n_int, n_live, edges
+
+
 def _fwd_kernel_resident(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale,
                          causal, block_q, block_k, seq_len, nk, rows):
     """Forward with the WHOLE K and V rows of a head resident in VMEM (grid
-    (B, H, nq): fetched once a head, the values at their own width) and the
-    KV loop inside the grid step, over the live tiles alone: a rolled loop
-    of dynamic length over the tiles no mask touches, then the tiles one
-    does.  The Q block runs as ``block_q / rows`` independent chains of the
-    online softmax, carried as values — inside a loop step one chain's
-    ``exp``/max/sum overlaps another's ``q k^T`` —, and under the causal
-    mask at square blocks the tile on the diagonal is each chain's own
-    triangle: the columns left of its rows whole, then a ``rows`` x
+    (B, H, nq): fetched once a KV head, the values at their own width) and
+    the KV loop inside the grid step, over the live tiles alone
+    (:func:`_resident_run`): a rolled loop of dynamic length over the tiles
+    no mask touches, then the tiles one does.  The Q block runs as ``block_q
+    / rows`` independent chains of the online softmax, carried as values —
+    inside a loop step one chain's ``exp``/max/sum overlaps another's ``q
+    k^T`` —, and at square blocks a tile on the mask's edge (the causal
+    diagonal; the block-diffusion mask's two diagonals) is each chain's own
+    sub-tiles: the columns left of its rows whole, then a ``rows`` x
     ``rows`` tile under a static mask, and nothing right of it.
 
     Alone on a v5e at latent attention's call (PR 51; 2 x 8,192 x 32 heads,
@@ -634,16 +699,8 @@ def _fwd_kernel_resident(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale,
     24.2 at 1024 x 1024 and 12.9 at 512 x 1024."""
     qi = pl.program_id(2)
     chains = block_q // rows
-    # Tiles [0, n_int) need no mask; [n_int, n_live) do; the rest are dead.
-    n_int = n_live = nk
-    if causal:
-        n_int = jnp.minimum(nk, (qi * block_q + 1) // block_k)
-        n_live = jnp.minimum(nk, ((qi + 1) * block_q - 1) // block_k + 1)
-    if seq_len is not None:
-        n_int = jnp.where((qi + 1) * block_q <= seq_len,
-                          jnp.minimum(n_int, seq_len // block_k), 0)
-        n_live = jnp.where(qi * block_q < seq_len,
-                           jnp.minimum(n_live, -(-seq_len // block_k)), 0)
+    first, n_int, n_live, edges = _resident_run(
+        causal, qi, block_q, block_k, nk, seq_len, rows)
     qs = [q_ref[0, c * rows:(c + 1) * rows, :] for c in range(chains)]
 
     def tile(j, carry, masked):
@@ -654,27 +711,31 @@ def _fwd_kernel_resident(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale,
             _block_mask(qi * chains + c, j, rows, block_k, causal, seq_len)
             if masked else None, *carry[c], scale) for c in range(chains))
 
+    def edge_tile(at, left, ok, carry):
+        ok, first_col = ok(), at * block_k
+        out = []
+        for c, (q, chain) in enumerate(zip(qs, carry)):
+            if c and left:
+                cols = pl.ds(pl.multiple_of(first_col, block_k), c * rows)
+                chain = _fold_tile(q, k_ref[0, cols, :], v_ref[0, cols, :],
+                                   None, *chain, scale)
+            cols = pl.ds(pl.multiple_of(first_col + c * rows, rows), rows)
+            out.append(_fold_tile(q, k_ref[0, cols, :], v_ref[0, cols, :],
+                                  ok, *chain, scale))
+        return tuple(out)
+
     carry = lax.fori_loop(
-        0, n_int, functools.partial(tile, masked=False),
+        first, n_int, functools.partial(tile, masked=False),
         ((lax.full((rows, 1), _NEG_BIG, jnp.float32),
           lax.full((rows, 1), 0.0, jnp.float32),
           lax.full((rows, v_ref.shape[2]), 0.0, jnp.float32)),) * chains)
-    if causal and block_q == block_k and seq_len is None:
-        on_diagonal = _block_mask(0, 0, rows, rows, True, None)
-        first = qi * block_k
-        triangles = []
-        for c, (q, chain) in enumerate(zip(qs, carry)):
-            if c:
-                at = pl.ds(pl.multiple_of(first, block_k), c * rows)
-                chain = _fold_tile(q, k_ref[0, at, :], v_ref[0, at, :],
-                                   None, *chain, scale)
-            at = pl.ds(pl.multiple_of(first + c * rows, rows), rows)
-            triangles.append(_fold_tile(q, k_ref[0, at, :], v_ref[0, at, :],
-                                        on_diagonal, *chain, scale))
-        carry = triangles
-    else:
+    if edges is None:
         carry = lax.fori_loop(n_int, n_live,
                               functools.partial(tile, masked=True), carry)
+    for at, left, ok, when in edges or ():
+        fold = functools.partial(edge_tile, at, left, ok)
+        carry = (fold(carry) if when is None
+                 else lax.cond(when, fold, lambda held: held, carry))
     outs, lses = [], []
     for m, l, acc in carry:
         l = lax.max(l, jnp.float32(1e-30))
@@ -688,13 +749,17 @@ def _fwd_kernel_resident(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale,
 
 
 # The resident forward: K (T, D) and V (T, Dv) of a head in VMEM, twice
-# (the pipeline's two buffers), under this budget (MB).  6 MiB — T 8,192 at
-# 256 + 128 lanes in bfloat16 — is what has run on a chip (v5e, PR 51: the
-# compiler counts 24 MB at 1024 x 1024 tiles in chains of 256 rows, 28 with
-# a padded tail's masked loop); past it, on a device that backs no such
-# budget, or at tiles off the lanes or over 1024 (a chain's scores:
+# (the pipeline's two buffers), under this budget (MB).  8 MiB of rows — T
+# 16,384 at D 128 in bfloat16, ``sdar_1chip``'s and ``zaya1_1chip``'s call —
+# is the most that has run on a chip (v5e, PR 60; 6 MiB, T 8,192 at 256 +
+# 128 lanes, since PR 51).  The compiler for the described v5e counts, at
+# 1024 x 1024 tiles in chains of 256 rows: 26.62 MB at T 16,384 / D 128
+# under the block-diffusion mask, 26.27 under the causal one (17.0 at 512 x
+# 512), 18.27 at T 8,192 / D 128, and at 256 + 128 lanes 24 (28 with a
+# padded tail's masked loop).  Past the bound, on a device that backs no
+# such budget, or at tiles off the lanes or over 1024 (a chain's scores:
 # ``rows`` x ``block_k`` float32), the grid form.
-_RESIDENT_KV_BYTES = 6 * 2 ** 20
+_RESIDENT_KV_BYTES = 8 * 2 ** 20
 _RESIDENT_VMEM_MB = 64
 _RESIDENT_CHAIN_ROWS = 256
 
@@ -702,7 +767,9 @@ _RESIDENT_CHAIN_ROWS = 256
 def _resident_chain_rows(block_q: int) -> int:
     """Rows of one chain of the resident forward's Q block: 256 (four
     chains at 1024: 11.7 ms a layer against 12.1 at two and 13.2 at one,
-    PR 51), or the block where 256 does not divide it."""
+    PR 51; at one width 9.55 against 10.19 under the block mask, 3.96
+    against 4.19 and 6.54 against 6.76 under the causal one, PR 60), or the
+    block where 256 does not divide it."""
     return (block_q if block_q % _RESIDENT_CHAIN_ROWS
             else _RESIDENT_CHAIN_ROWS)
 
@@ -2082,6 +2149,32 @@ def _bd_tiles(mask, T, block_q, block_k) -> int:
                for qi in range(T // block_q) for kj in range(T // block_k))
 
 
+def _fwd_visited_pairs(plan, mask, T, block) -> int:
+    """Score elements one head's forward computes under ``mask`` at square
+    tiles of ``block`` in the form ``plan`` gives: the area of the tiles its
+    dead test lets through, but in the resident form, whose tiles on the
+    mask's edge are the chains' sub-tiles (:func:`_resident_run`,
+    enumerated: an edge tile read by ``n`` chains is ``n`` sub-tiles and,
+    with the columns left of them, ``n (n - 1) / 2`` more)."""
+    n = T // block
+    if plan.fwd != "resident":
+        return block * block * sum(
+            not _static_dead(qi, kj, block, mask, None)
+            for qi in range(n) for kj in range(n))
+    rows = plan.fwd_tile
+    chains = block // rows
+    pairs = 0
+    for qi in range(n):
+        first, n_int, n_live, edges = _resident_run(
+            mask, qi, block, block, n, None, rows)
+        whole = int(n_int) - int(first) + (
+            int(n_live) - int(n_int) if edges is None else 0)
+        pairs += whole * block * block + sum(
+            rows * rows * (chains + left * chains * (chains - 1) // 2)
+            for _, left, _, when in edges or () if when is None or when)
+    return pairs
+
+
 def window_pairs(T: int, window: int) -> int:
     """(query, key) pairs the causal window leaves of a sequence of ``T``:
     row ``i`` reads ``min(i + 1, window)`` keys."""
@@ -2136,12 +2229,26 @@ def _plan(*, T, D, H, head_base, itemsize, causal, block_q, block_k,
     the forward forms and the backward forms below take it through the
     five mask helpers and visit no pair it leaves nothing of — all but the
     pair blocked over two heads (the per-head pair in its place) and the
-    forms of values of another width, which such a call never reaches.
+    resident forward, which walks the block-diffusion mask by a run of its
+    own (:func:`_resident_run`) and stands down under a window.
     ``kv_rep``: query heads a KV head (1: multi-head attention);
     ``select``: whether the call carries a selection map — then the group
     form each way under the blocks of ``_Plan.blocks``: the backward as one
     kernel (``"group_fused"``) where a KV head's two gradients may stay in
     VMEM, else as the dq / dk-dv pair (``"group"``).
+
+    The forward of a call without a map at one width on the lanes: the
+    fully-unrolled form to T 4,096; past it, at ``kv_rep > 1``, the
+    resident form (``"resident"``, PR 60: the rows a KV head's query heads
+    share fetched once for them, a step a Q block over its live run, in
+    chains of ``fwd_tile`` rows under ``fwd_vmem_mb``) where the device
+    backs the budget, ``T · 2 D · itemsize`` bytes fit
+    ``_RESIDENT_KV_BYTES`` (8 MiB: T 16,384 at D 128), the tiles are whole
+    lanes to 1024, compiled or plainly interpreted, and the mask is the
+    causal one, none, or block diffusion at square tiles whose chains hold
+    whole blocks of it; else the unrolled-KV form where a row fits 1 MB,
+    else the grid form — at one query head a KV head always
+    (``olmohybrid_1chip``), and under a causal window (PR 59's band).
 
     The backward of a call without a map: at lane-aligned heads and
     ``kv_rep > 1`` the same one kernel a KV group (``"group_fused"``, the
@@ -2158,7 +2265,7 @@ def _plan(*, T, D, H, head_base, itemsize, causal, block_q, block_k,
     256 and ``Dv`` 128 arrive).  Only the forms whose bodies never ask a
     width take such a call: forward the resident form (``"resident"``,
     PR 51: a head's K and V rows in VMEM, ``T (D + Dv) itemsize`` bytes to
-    ``_RESIDENT_KV_BYTES`` — 6 MiB at T 8,192 —, the KV loop inside the
+    ``_RESIDENT_KV_BYTES`` — 6 of its 8 MiB at T 8,192 —, the KV loop inside the
     grid step in chains of ``fwd_tile`` rows under ``fwd_vmem_mb``, at
     compiled or plainly interpreted tiles of whole lanes to 1024 on a
     device that backs the budget) or the grid form with its accumulator
@@ -2167,6 +2274,20 @@ def _plan(*, T, D, H, head_base, itemsize, causal, block_q, block_k,
     ``T (D + Dv) 4`` bytes under the same rule — 12 MiB at T 8,192 — and
     ``PV``, ``dP`` and ``dV`` at ``Dv``) or, where the device backs no
     such budget or they do not fit, the per-head pair."""
+    rows = _resident_chain_rows(block_q)
+    # The resident forward, where a KV head's K and V rows fit its budget
+    # and the mask is one its body walks: the causal one, none, or block
+    # diffusion at square tiles whose chains hold whole blocks of it.
+    resident = (
+        vmem_headroom
+        and T * (D + (Dv or D)) * itemsize <= _RESIDENT_KV_BYTES
+        and all(b % 128 == 0 and b <= 1024 for b in (block_q, block_k))
+        # Interpreted under shard_map its dynamic slices stand down.
+        and not _pallas.xla_form(interpret, manual_axes)
+        and not isinstance(causal, Window)
+        and not (isinstance(causal, BlockDiffusion)
+                 and (block_q != block_k or rows % causal.block))
+    ) and ("resident", rows, _RESIDENT_VMEM_MB)
     if Dv is not None and Dv != D:
         fits = (vmem_headroom
                 and T * (D + Dv) * 4 <= _FUSED_RESIDENT_BYTES)
@@ -2179,14 +2300,7 @@ def _plan(*, T, D, H, head_base, itemsize, causal, block_q, block_k,
         # grid form, one chain a step and a fetch a dead step, runs at 64%
         # of the MXU over the area it executes: the resident form where
         # the head's K and V rows fit (PR 51: 11.7 against 15.3 ms a layer).
-        resident = (
-            vmem_headroom
-            and T * (D + Dv) * itemsize <= _RESIDENT_KV_BYTES
-            and all(b % 128 == 0 and b <= 1024 for b in (block_q, block_k))
-            # Interpreted under shard_map its dynamic slices stand down.
-            and not _pallas.xla_form(interpret, manual_axes))
-        fwd = ("resident", _resident_chain_rows(block_q),
-               _RESIDENT_VMEM_MB) if resident else ("grid", 0, 0)
+        fwd = resident or ("grid", 0, 0)
         return _Plan(*fwd, *bwd, 0, _bwd_live_share(
             T, causal, blocks[2], blocks[3], sub=0), blocks)
     if select:
@@ -2231,6 +2345,22 @@ def _plan(*, T, D, H, head_base, itemsize, causal, block_q, block_k,
         fwd = ("unrollkv", 0, 0)
     else:
         fwd = ("grid", 0, 0)
+    if T > _FULL_UNROLL_MAX_T and kv_rep > 1 and resident:
+        # Grouped KV heads past the fully-unrolled form's reach: the rows a
+        # KV head's query heads share are fetched once for them, and the
+        # grid form pays ~0.4 us a live step, ~0.5 a fold through its
+        # scratch and a fetch a dead step.  Forward alone on a v5e (PR 60,
+        # ``chip_smoke.py --grouped-forward``; ms a layer, grid / its dead
+        # fetches clamped / resident in chains of 256 rows at 1024 x 1024):
+        # 12.97 / - / 9.55 under the block mask at 32Q / 4KV and T 16,384
+        # (80 tile-areas a head visited against 68), 5.07 / 4.59 / 3.96 at
+        # 8Q / 2KV and T 16,384, 8.40 / 7.88 / 6.54 at 48Q / 8KV and T
+        # 8,192, 11.13 / 10.48 / 8.69 at two sequences of 32Q / 2KV; chains
+        # of 512 rows 3-7% behind, tiles of 512 15-16%.  Under a causal
+        # window of 512 keys the grid form on the band (PR 59) reads 4.19 at
+        # 512 x 512 and this body, its run's two tiles whole and masked,
+        # 3.65: not given yet, see ``_resident_run``.
+        fwd = resident
 
     if (kv_rep > 1 and vmem_headroom
             and 2 * T * D * 4 <= _FUSED_RESIDENT_BYTES):
@@ -2739,11 +2869,14 @@ def mask_tile_counts(q, k, mask) -> dict:
     under the positional ``mask`` reads and does, forward, as :func:`_plan`
     has it on this device: ``live_pairs`` — the (query, key) pairs the mask
     leaves —, ``live_tiles`` — the forward form's tiles that hold one of
-    them, a query head, from the mask's definition — and ``visited_tiles``
-    — the tiles its kernel computes (the dead test its grid steps run).
-    Under a causal window also ``grid_steps`` — the steps of the forward's
-    grid, all query heads —, ``live_steps`` — those of them that compute a
-    tile — and ``visited_pairs`` — the pairs a head's forward computes
+    them, a query head, from the mask's definition —, ``visited_tiles`` —
+    what its kernel computes, in tiles: the tiles its dead test lets
+    through, and in the resident form, whose tiles on the mask's edges are
+    the chains' sub-tiles, their area (68 of 1024 squared a head at
+    ``sdar_1chip``'s shape where 80 hold a live pair) —, ``grid_steps`` —
+    the steps of the forward's grid, all query heads —, ``live_steps`` —
+    those of them that compute a tile (the resident form: a step a Q block,
+    all live) — and ``visited_pairs`` — the pairs a head's forward computes
     scores of, to set against ``live_pairs``: the visited tiles' area (the
     backward's share is ``_Plan.bwd_live_share``, its sub-tiles left out).
 
@@ -2761,23 +2894,26 @@ def mask_tile_counts(q, k, mask) -> dict:
         held, blk, blk, blk, blk, _pallas.interpret(),
         kv_rep=H // k.shape[2])
     tile = plan.fwd_tile if plan.fwd == "fullunroll" else blk
-    visited = B * H * _bd_tiles(held, rows, tile, tile)
+    nq = rows // tile
+    pairs = _fwd_visited_pairs(plan, held, rows, tile)
+    steps = B * H * {"fullunroll": 1, "unrollkv": nq, "resident": nq}.get(
+        plan.fwd, nq * (_win_steps(held, rows, tile, tile)
+                        if kind == "window" else nq))
     if kind == "window":
-        nq = rows // tile
-        steps = B * H * {"fullunroll": 1, "unrollkv": nq}.get(
-            plan.fwd, nq * _win_steps(held, rows, tile, tile))
-        return {"live_pairs": B * window_pairs(rows, n),
-                "live_tiles": B * H * sum(
-                    i - max(i * tile - n + 1, 0) // tile + 1
-                    for i in range(nq)),
-                "visited_tiles": visited,
-                "grid_steps": steps,
-                "live_steps": visited if plan.fwd == "grid" else steps,
-                "visited_pairs": visited // H * tile * tile}
-    t = held.half // tile
-    return {"live_pairs": B * held.half * (held.half + n),
-            "live_tiles": B * H * (t * t + t + (t if tile > n else 0)),
-            "visited_tiles": visited}
+        live_pairs = B * window_pairs(rows, n)
+        live_tiles = sum(i - max(i * tile - n + 1, 0) // tile + 1
+                         for i in range(nq))
+    else:
+        t = held.half // tile
+        live_pairs = B * held.half * (held.half + n)
+        live_tiles = t * t + t + (t if tile > n else 0)
+    visited = B * H * pairs / (tile * tile)
+    return {"live_pairs": live_pairs, "live_tiles": B * H * live_tiles,
+            "visited_tiles": int(visited) if visited % 1 == 0 else visited,
+            "grid_steps": steps,
+            "live_steps": (B * H * pairs // (tile * tile)
+                           if plan.fwd == "grid" else steps),
+            "visited_pairs": B * pairs}
 
 
 def _auto_tiling(T: int):
@@ -2877,7 +3013,10 @@ def flash_attention(q, k, v, *, causal: bool = True,
     ``dk``, ``dv`` come back summed over the query heads of a group — by
     one backward kernel a KV group where a KV head's ``dk`` and ``dv`` fit
     VMEM (``T`` to 16,384 at ``D`` 128 on a device that backs the budget),
-    by the per-head pair elsewhere (:func:`_plan`).  ``v`` may be of
+    by the per-head pair elsewhere; past ``T`` 4,096 the forward keeps the
+    KV head's K and V rows in VMEM for the query heads that share them
+    under the same bound, else streams them a tile a grid step
+    (:func:`_plan`).  ``v`` may be of
     another width than ``q`` and ``k``, ``(B, T, Hkv, Dv)`` — latent
     attention's keys of 192 = 128 | 64 against values of 128: each side is
     zero-padded to whole 128-lane tiles (the scale stays the published

@@ -283,7 +283,8 @@ def test_grouped_backward_phase(smoke):
     time is reported), with ``gqa_plan`` — the form, budget, blocks and
     live share ``flash_attention._plan`` gives the call; on the chip the
     phase runs at the two cells' own attention shapes, where the plan is
-    the fused kernel under 64 MB at 512 x 1024 and 256 x 512."""
+    the fused kernel under 64 MB at 512 x 1024 and 256 x 512 behind the
+    resident forward (PR 60)."""
     from horovod_tpu.ops import flash_attention as fa
 
     out = smoke.grouped_backward_phase(batch=1, seq=256, heads=8, kv_heads=2,
@@ -310,7 +311,7 @@ def test_grouped_backward_phase(smoke):
                         vmem_headroom=True,
                         kv_rep=c["heads"] // c["kv_heads"])
         assert (plan.fwd, plan.bwd, plan.bwd_vmem_mb, plan.blocks[2:],
-                plan.bwd_live_share) == ("grid", "group_fused", 64,
+                plan.bwd_live_share) == ("resident", "group_fused", 64,
                                          bwd_blocks, live)
 
 
@@ -327,9 +328,54 @@ def test_block_mask_phase(smoke):
         "causal.backward"} and not any(out["ms_a_layer"].values())
     assert out["bd_plan"]["bwd"] == "group_fused"
     assert out["tiles"] == {"live_pairs": 64 * 68, "live_tiles": 8 * 3,
-                            "visited_tiles": 8 * 3}
+                            "visited_tiles": 8 * 3, "grid_steps": 8,
+                            "live_steps": 8, "visited_pairs": 3 * 64 * 64}
     assert max(out["against_dense"].values()) <= 2e-2
     assert tuple(smoke.BLOCK_MASK.values()) == (1, 8192, 32, 4, 128, 4, 1024)
+
+
+@pytest.mark.parametrize("mask,forms,plan", [
+    (None, ["grid.512", "grid_live.512", "resident.64.256",
+            "resident.128.256", "resident.64.128", "resident.128.128"],
+     "resident"),
+    (("block_diffusion", 4), ["grid.256", "resident.64.256",
+                              "resident.128.256", "resident.64.128",
+                              "resident.128.128"], "resident"),
+    (("window", 128), ["grid.512", "resident.64.256", "resident.128.256",
+                       "resident.64.128", "resident.128.128"], "grid")],
+    ids=["causal", "block_mask", "window"])
+def test_grouped_forward_phase(smoke, monkeypatch, mask, forms, plan):
+    """The forward forms of a call with grouped KV heads at one width, alone
+    (interpreted here: no time, every form's ``o`` and ``lse`` against the
+    grid form's): the grid form, it with its dead fetches clamped (the
+    causal mask only), and the resident form at two chains and two tiles;
+    ``fwd_plan`` is the plan of the call at the block the shapes give, and
+    ``notes`` what each form visits."""
+    from horovod_tpu.ops import flash_attention as fa
+
+    monkeypatch.setattr(fa, "_FULL_UNROLL_MAX_T", 0)
+    out = smoke.grouped_forward_phase(
+        batch=1, seq=512, heads=4, kv_heads=2, head_dim=128, mask=mask,
+        seed=0, blocks=(256, 128), chain_rows=(64, 128))
+    assert out["interpret"] and out["shape"] == [1, 512, 4, 2, 128]
+    assert list(out["ms_a_layer"]) == forms == list(out["notes"])
+    assert set(out["vs_grid"]) == set(forms[1:])
+    assert max(max(e) for e in out["vs_grid"].values()) <= 2e-3
+    assert out["fwd_plan"]["fwd"] == ("unrollkv" if plan == "grid" else plan)
+    notes = out["notes"]
+    assert all(n["pairs_over_live"] >= 1 for n in notes.values())
+    assert notes["resident.64.256"]["grid_steps_a_head"] == 2
+    if mask is None:
+        # Four chains: ten of a diagonal tile's sixteen sub-tiles.
+        assert notes["resident.64.256"]["visited_tiles"] == 1 + 2 * 0.625
+        assert notes["grid.512"]["visited_tiles"] == 1
+    if mask == ("block_diffusion", 4):
+        # One tile a stream: 3 whole in the grid form, 0.625 + 0.625 + 0.25.
+        assert notes["grid.256"]["visited_tiles"] == 3
+        assert notes["resident.64.256"]["visited_tiles"] == 1.5
+    assert set(smoke.GROUPED_FORWARD) == {
+        "sdar_1chip", "zaya1_1chip", "lagunaxs2_1chip.global",
+        "lagunaxs2_1chip.window", "twotower_1chip"}
 
 
 def test_latent_forward_phase(smoke):

@@ -312,15 +312,16 @@ def test_a_zaya_layer_fwd_bwd_at_zaya_widths(v5e, monkeypatch):
     # input and weight gradients: one window, so each exactly once.
     assert found == {"moe_gmm": 6, "moe_gmm_nt": 3, "moe_tgmm": 3,
                      "moe_land": 0}, found
-    # The grid forward and, since PR 44, the backward as one kernel a KV
-    # group (the per-head pair ``_dkdv_kernel``, ``_dq_kernel`` before).
+    # The resident forward since PR 60 (the grid forward before) and,
+    # since PR 44, the backward as one kernel a KV group (the per-head pair
+    # ``_dkdv_kernel``, ``_dq_kernel`` before).
     # The latent's passes: the forward reads the two projections' arrays
     # (each also as its halo), two packed vectors, two sets of matrices and
     # the rotation's table; the backward the two cotangents and the
     # matrices turned besides.
     assert custom_calls(lowered.as_text())[:4] == [
-        ("_fwd_kernel", 3), ("cca_mix_bwd", 13), ("cca_mix_fwd", 9),
-        ("flash_group_bwd", 6)]
+        ("cca_mix_bwd", 13), ("cca_mix_fwd", 9), ("flash_group_bwd", 6),
+        ("flash_resident_fwd", 3)]
     compiled = lowered.compile()
     text = compiled.as_text()
     assert "ragged-dot" not in text

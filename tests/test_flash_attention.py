@@ -11,6 +11,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from horovod_tpu.ops import flash_attention as fa_masks
 from horovod_tpu.ops.flash_attention import flash_attention
 from horovod_tpu.parallel.ring_attention import full_attention
 
@@ -331,7 +332,7 @@ def observed(T, D=128, H=16, itemsize=2, blocks=1024, base=None,
                 manual_axes=manual_axes, vmem_headroom=vmem_headroom, **more)
 
 
-FULL, KV, GRID = "fullunroll", "unrollkv", "grid"
+FULL, KV, GRID, RES = "fullunroll", "unrollkv", "grid", "resident"
 # (forward, its tile, its VMEM MB, backward pair, its VMEM MB, the
 # sub-tile of its diagonal blocks, the live share of what it computes).
 PLAN_TABLE = {
@@ -362,15 +363,41 @@ PLAN_TABLE = {
     # rows by 1024 keys).  twotower_1chip (32 query heads over 2: 256 x 512,
     # so the diagonal wastes a seventeenth where the pair's whole 1024²
     # blocks wasted a ninth) and zaya1_1chip (8 over 2 at T 16,384).
+    # The forward (PR 60), past the fully-unrolled form's reach: the
+    # resident form in chains of 256 rows under 64 MB where the KV head's K
+    # and V rows — T x 2 D operand bytes — fit 8 MiB: the four cells' calls.
     "cell_T8192_16Q_per_KV": (
         observed(8192, H=32, base=(0, 0, 0), kv_rep=16),
-        (GRID, 0, 0, "group_fused", 64, 0, 0.941, (1024, 1024, 256, 512))),
+        (RES, 256, 64, "group_fused", 64, 0, 0.941, (1024, 1024, 256, 512))),
     "cell_T16384_4Q_per_KV": (
         observed(16384, H=8, base=(0, 0, 0), kv_rep=4),
-        (GRID, 0, 0, "group_fused", 64, 0, 0.941, (1024, 1024, 512, 1024))),
+        (RES, 256, 64, "group_fused", 64, 0, 0.941, (1024, 1024, 512, 1024))),
     "T16384_8Q_per_KV": (
         observed(16384, H=32, base=(0, 0, 0), kv_rep=8),
-        (GRID, 0, 0, "group_fused", 64, 0, 0.97, (1024, 1024, 512, 512))),
+        (RES, 256, 64, "group_fused", 64, 0, 0.97, (1024, 1024, 512, 512))),
+    # sdar_1chip: those heads under the block-diffusion mask, a clean and a
+    # noised copy of 8,192 tokens (square tiles, chains of whole blocks).
+    "cell_sdar_block_mask": (
+        observed(16384, H=32, base=(0, 0, 0), kv_rep=8,
+                 causal=fa_masks.BlockDiffusion(4, 8192)),
+        (RES, 256, 64, "group_fused", 64, 0, 0.889, (1024, 1024, 512, 512))),
+    # lagunaxs2_1chip's two calls: the global kind's 48 heads over 8, and
+    # the windowed kind's 64 under 512 keys — the band's grid form (PR 59),
+    # the backward's edge pairs in sub-tiles of 256.
+    "cell_laguna_global": (
+        observed(8192, H=48, base=(0, 0, 0), kv_rep=6),
+        (RES, 256, 64, "group_fused", 64, 0, 0.941, (1024, 1024, 512, 512))),
+    "cell_laguna_window": (
+        observed(8192, H=64, base=(0, 0, 0), kv_rep=8, blocks=512,
+                 causal=fa_masks.Window(512)),
+        (GRID, 0, 0, "group_fused", 64, 256, 0.667, (512,) * 4)),
+    # olmohybrid_1chip: 30 heads, one query head a KV head — the grid
+    # forward and the pair blocked over two heads, as before (its family
+    # admits the flash three by kernel name).  gpt13b_1chip is
+    # "cell_T2048"; joyaiflash_1chip's two widths are
+    # tests/test_flash_split_widths.py's "cell_T8192_256_128".
+    "cell_olmohybrid": (observed(8192, H=30, base=(0, 0, 0)),
+                        (GRID, 0, 0, "grouped", 32, 256, 0.97)),
     "T2048_2Q_per_KV": (observed(2048, base=(0, 0, 0), kv_rep=2),
                         (FULL, 512, 0, "group_fused", 64, 0, 0.667,
                          (1024,) * 4)),
@@ -389,7 +416,7 @@ PLAN_TABLE = {
         (GRID, 0, 0, "per_head", 0, 0, 0.941)),
     "T8192_D256_4Q_per_KV": (
         observed(8192, D=256, H=8, base=(0, 0, 0), kv_rep=4),
-        (GRID, 0, 0, "group_fused", 64, 0, 0.889, (1024, 1024, 512, 1024))),
+        (RES, 256, 64, "group_fused", 64, 0, 0.889, (1024, 1024, 512, 1024))),
     "interpret_shard_map_2Q_per_KV": (
         observed(64, H=2, itemsize=4, blocks=16, interpret=True,
                  manual_axes=True, base=(0, 0, 0), kv_rep=2),

@@ -1,8 +1,9 @@
 """The flash family at values narrower than keys (latent attention's heads
 of 192 = 128 | 64 against values of 128): ``flash_attention`` against the
 dense oracle — output, dq, dk, dv — in both backward forms ``_plan`` can
-take, the resident forward (PR 51) against the grid form and the oracle,
-the plan's rows for such a call, and every row of the table the other
+take, the resident forward (PR 51) against the grid form and the oracle —
+at two widths and, under grouped KV heads, at one (PR 60) —, the plan's
+rows for such a call, and every row of the table the other
 calls read unchanged.  Interpreted kernels at the smallest T that tiles.
 """
 
@@ -138,6 +139,67 @@ def test_resident_forward_at_grouped_kv_heads_and_its_gradients(monkeypatch):
         np.testing.assert_allclose(g, w_, rtol=2e-4, atol=2e-4)
 
 
+# (T, block, causal, seq_len, chain rows, H, Hkv): the resident forward at ONE
+# width under grouped KV heads (PR 60), past the fully-unrolled form's reach
+# (brought down to 0 here): the causal triangles in four chains and in one
+# at four and eight query heads a KV head, a padded tail, no mask.
+@pytest.mark.parametrize("T,block,causal,seq_len,rows,H,Hkv", [
+    (256, 128, True, None, 32, 4, 1), (256, 128, True, None, 128, 8, 1),
+    (384, 128, True, 300, 32, 4, 2), (256, 128, False, None, 64, 2, 1)],
+    ids=["causal_4_chains_kv4", "causal_1_chain_kv8", "padded_tail_kv2",
+         "no_mask_kv2"])
+def test_resident_forward_at_one_width_and_its_gradients(
+        monkeypatch, T, block, causal, seq_len, rows, H, Hkv):
+    """The plan gives the call the resident forward; its output equals full
+    attention's, its ``o`` and ``lse`` the grid form's on the same operands,
+    and its gradients — through ``flash_group_bwd``, which reads the ``o``
+    and ``lse`` this form wrote — the grid call's and the oracle's."""
+    monkeypatch.setattr(fa, "_FULL_UNROLL_MAX_T", 0)
+    monkeypatch.setattr(fa, "_RESIDENT_CHAIN_ROWS", rows)
+    q, k, v, w = operands(1, T, H, Hkv, 128, 128)
+    n, rep = seq_len or T, H // Hkv
+    plans = []
+    planned = fa._plan
+    monkeypatch.setattr(fa, "_plan", lambda **seen: plans.append(
+        planned(**seen)) or plans[-1])
+
+    def flash(q, k, v):
+        out = fa.flash_attention(q, k, v, causal=causal, block_q=block,
+                                 block_k=block, seq_len=seq_len,
+                                 interpret=True)
+        return (out[:, :n] * w[:, :n]).sum(), out
+
+    def dense(q, k, v):
+        out = full_attention(q[:, :n], jnp.repeat(k[:, :n], rep, axis=2),
+                             jnp.repeat(v[:, :n], rep, axis=2), causal=causal)
+        return (out * w[:, :n]).sum(), out
+
+    grad = jax.value_and_grad(flash, (0, 1, 2), has_aux=True)
+    (_, out), got = grad(q, k, v)
+    assert {(p.fwd, p.fwd_tile, p.fwd_vmem_mb, p.bwd) for p in plans} == {
+        ("resident", rows, 64, "group_fused")}
+    (_, want_out), want = jax.value_and_grad(dense, (0, 1, 2), has_aux=True)(
+        q, k, v)
+    np.testing.assert_allclose(out[:, :n], want_out, rtol=2e-5, atol=2e-5)
+    packed = [a.reshape(1, T, -1) for a in (q, k, v)]
+    (o, lse), (o_grid, lse_grid) = (
+        fa._fwd_packed(*packed, H, 128, plans[0]._replace(fwd=fwd),
+                       scale=128 ** -0.5, causal=causal, block_q=block,
+                       block_k=block, interpret=True, seq_len=seq_len,
+                       kv_rep=rep) for fwd in ("resident", "grid"))
+    np.testing.assert_allclose(o[:, :n], o_grid[:, :n], rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(lse[..., :n], lse_grid[..., :n], rtol=1e-6,
+                               atol=1e-5)
+    monkeypatch.setattr(fa, "_plan", lambda **seen: planned(**seen)._replace(
+        fwd="grid", fwd_tile=0, fwd_vmem_mb=0))
+    jax.clear_caches()
+    _, grid = grad(q, k, v)
+    jax.clear_caches()          # the traces do not key on the plan
+    for g, g_grid, w_ in zip(got, grid, want):
+        np.testing.assert_allclose(g, g_grid, rtol=2e-5, atol=2e-5)
+        np.testing.assert_allclose(g[:, :n], w_[:, :n], rtol=2e-4, atol=2e-4)
+
+
 def test_what_the_widths_must_agree_in():
     q, k, v, _ = operands(1, 64, 2, 2, 192, 128)
     with pytest.raises(ValueError, match="head width 192"):
@@ -152,7 +214,7 @@ def test_what_the_widths_must_agree_in():
 # What _plan answers a call whose values are not as wide as its keys (both
 # in whole 128-lane tiles by then).  Forward: the head's K and V rows
 # resident (PR 51) on a device that backs the budget where T (D + Dv) 2
-# bytes fit 6 MiB — joyaiflash_1chip's call: 6 MiB exactly, 1024 x 1024
+# bytes fit 8 MiB — joyaiflash_1chip's call: 6 MiB, 1024 x 1024
 # tiles in chains of 256 rows — at tiles of whole lanes to 1024, compiled
 # Mosaic or interpreted off a mesh's manual axes; else the grid form.
 # Backward: the one kernel a KV group where dK (T, D) and dV (T, Dv) float32

@@ -186,7 +186,9 @@ def test_plan_at_the_benchmark_s_shapes():
     the window's edges in sub-tiles of 256: 31 of a KV head's 256 backward
     tiles hold a live pair, and two thirds of the scores it computes are
     live where half were.  GLOBAL, 48 query heads
-    under the causal mask at 1024²: the same two forms, the backward at 512
+    under the causal mask at 1024²: the resident forward since PR 60 (a KV
+    head's rows fetched once for its six query heads, chains of 256 rows;
+    never under the window) and the same backward at 512
     x 512 (six heads a step), nothing cut.  Without
     the budget, and at one query head a KV head, the per-head pair, whole
     tiles — never the pair blocked over two heads under a window."""
@@ -214,8 +216,8 @@ def test_plan_at_the_benchmark_s_shapes():
         fa.window_pairs(8192, 512) / (93 * 256 * 256), 3) == 0.667
     g = plan(H=48, kv_rep=6, causal=True, block_q=1024, block_k=1024,
              bwd_block_q=1024, bwd_block_k=1024)
-    assert (g.fwd, g.bwd, g.blocks, g.bwd_sub) == (
-        "grid", "group_fused", (1024, 1024, 512, 512), 0)
+    assert (g.fwd, g.fwd_tile, g.bwd, g.blocks, g.bwd_sub) == (
+        "resident", 256, "group_fused", (1024, 1024, 512, 512), 0)
     for whole in (plan(vmem_headroom=False), plan(kv_rep=1)):
         assert (whole.bwd, whole.bwd_sub, whole.bwd_live_share) == (
             "per_head", 0, 0.5)

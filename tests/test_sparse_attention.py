@@ -284,8 +284,9 @@ def test_a_selection_takes_a_kv_group_a_grid_step():
     assert fa._plan(**seen, select=True) == (
         "group", 0, 32, "group_fused", 64, 0, 0.667, (1024,) * 4)
     keye = dict(seen, T=16_384, H=32, kv_rep=8)
-    assert fa._plan(**keye) == ("grid", 0, 0, "group_fused", 64, 0, 0.97,
-                                (1024, 1024, 512, 512))
+    # (... and since PR 60 the resident forward, past T 4,096.)
+    assert fa._plan(**keye) == ("resident", 256, 64, "group_fused", 64, 0,
+                                0.97, (1024, 1024, 512, 512))
     assert fa._plan(**dict(keye, vmem_headroom=False))[:6] == (
         "grid", 0, 0, "per_head", 0, 0)
     # The backward is ONE kernel under 64 MB where a KV head's dK and dV,

@@ -445,7 +445,7 @@ def test_the_nemo3super_cell_s_step_lowers_with_every_kernel_family(
     text = lowered.as_text()
     import re
     assert set(re.findall(r'kernel_name = "([^"]+)"', text)) == {
-        "_fwd_kernel", "flash_group_bwd", "moe_gmm", "moe_gmm_nt",
+        "flash_resident_fwd", "flash_group_bwd", "moe_gmm", "moe_gmm_nt",
         "moe_land", "moe_tgmm", "ssd_bwd", "ssd_fwd", "ssd_states",
         "ssm_conv_bwd", "ssm_conv_fwd", "ssm_gate_bwd", "ssm_gate_fwd"}
     assert "stablehlo.all_reduce" not in text
