@@ -647,7 +647,8 @@ class _StepInstruments:
     ``moe.permuted_assignments``,
     ``ssm.scan_chunks``, ``ssm.state_bytes``, ``ssm.fused_scans``,
     ``ssm.fused_passes``, ``ssm.head_tiles``, ``ssm.group_channels``,
-    ``lin.delta_chunks``, ``lin.state_bytes``, ``attn.merged_heads``,
+    ``lin.delta_chunks``, ``lin.state_bytes``, ``lin.decay_bytes``,
+    ``lin.sub_chunks`` (``KimiDeltaAttention``), ``attn.merged_heads``,
     ``lm.tied_head``; a model without such layers bumps none of those.
     """
 

@@ -7,7 +7,8 @@ from horovod_tpu.models.resnet import (                   # noqa: F401
 )
 from horovod_tpu.models.transformer import (               # noqa: F401
     BlockStack, CompressedConvAttention, GraniteHybridLM,
-    GroupedQueryAttention, JoyAIFlashLM, KeyeLM, LagunaLM, LatentAttention,
+    GroupedQueryAttention, JoyAIFlashLM, KeyeLM, KimiLinearLM, LagunaLM,
+    LatentAttention,
     MultiTokenPrediction, Nemotron3SuperLM,
     NemotronHLM, OLMoELM, OlmoHybridLM, ResidualMerge, SDARLM, SwiGLU,
     TransformerLM, Zaya1LM, apply_rotary, index_losses)

@@ -89,6 +89,16 @@ partial rotary factor) and ``rope_scaling`` (YaRN).  :func:`LagunaLM` is the
 Laguna-XS.2 setting: global and windowed attention layers with their own
 head counts in one pattern.
 
+``k`` and ``K`` are TWO pre-norm sub-layers like ``d`` and ``x`` whose first
+is a linear-attention mixer with a decay a key channel
+(:class:`~horovod_tpu.models.linear_attention.KimiDeltaAttention`, ``lin=``
+its fields) and whose second is a dense :class:`SwiGLU` (``k``) or a
+``DroplessMoE`` (``K``).  With ``pos="none"`` the ``d`` and ``x`` layers'
+latent attention runs without positions, and ``mla=dict(q_latent=None,
+...)`` without a query latent (:class:`LatentAttention`).
+:func:`KimiLinearLM` is the Kimi-Linear setting: three ``K`` layers to one
+``x``.
+
 ``mtp`` adds a multi-token-prediction module behind a pattern stack
 (:class:`MultiTokenPrediction`): from the stack's final hidden states and
 the NEXT token's embedding, through the shared table, it makes a second
@@ -731,7 +741,7 @@ class LatentAttention(nn.Module):
     token the layer keeps for the backward pass between its latents and
     ``proj``)."""
     num_heads: int
-    q_latent: int
+    q_latent: Optional[int]
     kv_latent: int
     nope_dim: int
     rope_dim: int
@@ -739,7 +749,7 @@ class LatentAttention(nn.Module):
     attn: str = "flash"
     dtype: Any = jnp.bfloat16
     norm_eps: float = 1e-6
-    rope_theta: float = 10000.0
+    rope_theta: Optional[float] = 10000.0
 
     @nn.compact
     def __call__(self, x):
@@ -760,14 +770,18 @@ class LatentAttention(nn.Module):
             return nn.RMSNorm(epsilon=self.norm_eps, dtype=self.dtype,
                               name=name)(y)
 
-        with jax.named_scope("mla/q_down"):
-            c_q = dense(self.q_latent, "q_a")(x)
+        c_q = x
+        if self.q_latent:
+            with jax.named_scope("mla/q_down"):
+                c_q = dense(self.q_latent, "q_a")(x)
         with jax.named_scope("mla/kv_down"):
             c_kv, k_r = jnp.split(dense(self.kv_latent + R, "kv_a")(x),
                                   [self.kv_latent], axis=-1)
         with jax.named_scope("mla/norm"):
-            c_q, c_kv = normed(c_q, "q_norm"), normed(c_kv, "kv_norm")
-        w_qb = _QKVKernel(H * (N + R), name="q_b")(self.q_latent)
+            if self.q_latent:
+                c_q = normed(c_q, "q_norm")
+            c_kv = normed(c_kv, "kv_norm")
+        w_qb = _QKVKernel(H * (N + R), name="q_b")(c_q.shape[-1])
         w_kvb = _QKVKernel(H * (N + V), name="kv_b")(self.kv_latent)
 
         @functools.partial(jax.checkpoint, policy=_kernel_outputs_saveable)
@@ -777,10 +791,14 @@ class LatentAttention(nn.Module):
             with jax.named_scope("mla/kv_up"):
                 kv = (c_kv @ w_kvb.astype(self.dtype)).reshape(
                     B, T, H, N + V)
-            with jax.named_scope("mla/rope"):
-                pos = jnp.arange(T)
-                q_r = apply_rotary(q[..., N:], pos, self.rope_theta)
-                k_r = apply_rotary(k_r[:, :, None], pos, self.rope_theta)
+            rotated = self.rope_theta is not None
+            with jax.named_scope("mla/rope" if rotated else "mla/lanes"):
+                if rotated:
+                    pos = jnp.arange(T)
+                    q_r = apply_rotary(q[..., N:], pos, self.rope_theta)
+                    k_r = apply_rotary(k_r[:, :, None], pos, self.rope_theta)
+                else:
+                    q_r, k_r = q[..., N:], k_r[:, :, None]
                 zeros = jnp.zeros((B, T, H, lanes), self.dtype)
                 q = jnp.concatenate([q[..., :N], q_r, zeros], axis=-1)
                 k = jnp.concatenate(
@@ -801,14 +819,15 @@ class LatentAttention(nn.Module):
         out = attend(c_q, c_kv, k_r, w_qb, w_kvb)
         itemsize = jnp.dtype(self.dtype).itemsize
         note_layer(self.path, {
-            "attn.q_latent": self.q_latent, "attn.kv_latent": self.kv_latent,
+            "attn.q_latent": self.q_latent or 0,
+            "attn.kv_latent": self.kv_latent,
             "attn.qk_head_dim": N + R, "attn.v_head_dim": V,
             "attn.padded_lanes": lanes + (-V % 128 if flash else 0),
             "attn.kv_resident_bytes": resident[0],
             # c_q, c_kv and k_r; o and the (8-wide, float32) row statistics
             # where a kernel wrote them.
             "attn.latent_residual_bytes": (
-                itemsize * (self.q_latent + self.kv_latent + R)
+                itemsize * (c_q.shape[-1] + self.kv_latent + R)
                 + (itemsize * H * V + 4 * 8 * H if flash else 0))})
         with jax.named_scope("mla/out"):
             return dense(C, "proj")(out.reshape(B, T, H * V))
@@ -888,6 +907,11 @@ class PatternLayer(nn.Module):
     with ``mlp`` a :class:`SwiGLU` ``mlp_hidden`` wide (``"d"``) or ``h +
     moe(moe_norm(h))`` with ``moe`` a ``DroplessMoE`` (``"x"``); ``sub``
     holds the two modules' fields under ``"attn"`` and ``"moe"``.
+    ``"k"`` and ``"K"`` (``lin``, then ``mlp`` or ``moe``): the same two
+    pre-norm sub-layers with a
+    :class:`~horovod_tpu.models.linear_attention.KimiDeltaAttention` first,
+    ``h = x + kda(norm(x))``; ``sub`` holds the modules' fields under
+    ``"lin"`` and ``"moe"``.
     The call's ``pos`` and ``mask`` are an ``"S"`` layer's
     (:class:`GroupedQueryAttention`'s call); with a mask every other kind
     but ``"E"`` is refused."""
@@ -920,11 +944,18 @@ class PatternLayer(nn.Module):
                 **self.sub["moe"], dtype=self.dtype, norm_eps=self.norm_eps,
                 name="moe")(normed(h, "moe_norm"), router_state)
             return ResidualMerge(name="merge_moe")(h, y), router_state
-        if self.kind in ("d", "x"):
-            h = x + LatentAttention(
-                **self.sub["attn"], dtype=self.dtype, norm_eps=self.norm_eps,
-                name="attn")(normed(x, "norm"))
-            if self.kind == "d":
+        if self.kind in ("d", "x", "k", "K"):
+            if self.kind in ("d", "x"):
+                mixer = LatentAttention(**self.sub["attn"], dtype=self.dtype,
+                                        norm_eps=self.norm_eps, name="attn")
+            else:
+                from horovod_tpu.models.linear_attention import (
+                    KimiDeltaAttention)
+                mixer = KimiDeltaAttention(**self.sub["lin"],
+                                           norm_eps=self.norm_eps,
+                                           dtype=self.dtype, name="lin")
+            h = x + mixer(normed(x, "norm"))
+            if self.kind in ("d", "k"):
                 return h + SwiGLU(self.mlp_hidden, self.dtype, name="mlp")(
                     normed(h, "mlp_norm"))
             return h + DroplessMoE(**self.sub["moe"], dtype=self.dtype,
@@ -959,7 +990,7 @@ class PatternLayer(nn.Module):
         else:
             raise ValueError(f"unknown layer {self.kind!r} in a pattern: "
                              "'M', '*', 'S', 'W', 'E', 'D', 'L', 'F', 'm', "
-                             "'a' or 'Z', or 'd' or 'x'")
+                             "'a' or 'Z', or 'd', 'x', 'k' or 'K'")
         if self.kind in ("m", "a"):
             r = self.residual_multiplier
             h = x + r * y
@@ -1164,9 +1195,11 @@ class TransformerLM(nn.Module):
     attn_scale: Optional[float] = None
     residual_multiplier: float = 1.0
     cca: Any = None
-    # ``d`` and ``x`` layers: LatentAttention's widths (q_latent,
-    # kv_latent, nope_dim, rope_dim, v_dim) for num_heads heads, rotary
-    # positions of rope_theta (pos="rotary"; they have no other).
+    # ``d`` and ``x`` layers: LatentAttention's widths (q_latent — None:
+    # no query latent —, kv_latent, nope_dim, rope_dim, v_dim) for
+    # num_heads heads, rotary positions of rope_theta (pos="rotary") or
+    # none at all (pos="none").  ``k`` and ``K`` layers: ``lin`` holds the
+    # fields of KimiDeltaAttention.
     mla: Any = None
     # ``mtp=dict(pattern="*E")``: a multi-token-prediction module named
     # ``mtp`` behind the stack (MultiTokenPrediction), its layers the
@@ -1305,14 +1338,12 @@ class TransformerLM(nn.Module):
                 f"{self.pattern!r}, {tokens.shape[1]} tokens")
         if self.attn not in ("full", "flash") or self.pos not in (
                 ("none", "rotary") if set("SWZdx") & set(self.pattern)
-                else ("none",)) or (set("Zdx") & set(self.pattern)
-                                    and rotary is None):
+                else ("none",)) or ("Z" in self.pattern and rotary is None):
             raise ValueError("a pattern stack runs whole sequences "
                              "(attn='full' or 'flash') with pos='none', or "
                              "'rotary' for its 'S' layers, 'W' layers, 'Z' "
                              "layers ('Z' layers have no other) and 'd' and "
-                             "'x' layers "
-                             "(nor have they); got "
+                             "'x' layers; got "
                              f"attn={self.attn!r}, pos={self.pos!r}")
         experts = dict(num_experts=self.moe_experts, hidden=self.moe_hidden,
                        top_k=self.moe_top_k, **dict(self.moe or {}))
@@ -1341,6 +1372,7 @@ class TransformerLM(nn.Module):
                       moe=experts),
         }
         subs["m"], subs["x"] = subs["M"], subs["d"]
+        subs["k"] = subs["K"] = dict(lin=dict(self.lin or {}), moe=experts)
         if self.attn_gate:
             subs["S"]["out_gate"] = True
         for name, value in (("rope_width", self.rope_width),
@@ -1748,6 +1780,70 @@ def JoyAIFlashLM(**overrides) -> TransformerLM:
         moe=dict(router="sigmoid", renormalize=True, gate_scale=2.5,
                  activation="swiglu", shared_hidden=768, choice_bias=1e-3),
         mtp=dict(pattern="x"))
+    fields.update(overrides)
+    return TransformerLM(**fields)
+
+
+def KimiLinearLM(**overrides) -> TransformerLM:
+    """The stack that ``moonshotai/Kimi-Linear-48B-A3B-Instruct``'s
+    config.json describes (``model_type`` ``kimi_linear``), as a
+    :class:`TransformerLM` with a ``pattern``: 27 layers at d 2304, pre-norm
+    RMSNorm eps 1e-5, two sub-layers each.  The first is Kimi Delta
+    Attention in 20 layers (``linear_attn_config.kda_layers``; letters
+    ``k`` and ``K``:
+    :class:`~horovod_tpu.models.linear_attention.KimiDeltaAttention`, 32
+    heads with keys and values of 128, conv 4, a decay a key channel from a
+    low-rank gate, beta in (0, 1), a sigmoid-gated norm) and multi-head
+    latent attention in the 7 others (``full_attn_layers``, 1-based 4, 8,
+    ..., 24 and 27; letter ``x``: :class:`LatentAttention`, 32 heads, NO
+    query latent — ``q_lora_rank`` null —, a k | v latent of 512 and 64
+    shared key channels that are NOT rotated — ``mla_use_nope``; keys of
+    192 against values of 128).  The second is a dense SwiGLU 9,216 wide in
+    layer 1 (``first_k_dense_replace`` 1, letter ``k``) and, in the 26
+    after it, 256 SwiGLU experts 1,024 wide, top-8 by sigmoid scores plus a
+    balancing bias that chooses and never gates (``use_grouped_topk`` with
+    ONE group is plain top-8; ``DroplessMoE(choice_bias=...)``, state in
+    the collection ``"balance"``), gates renormalised over the chosen and
+    scaled by 2.446, and one shared SwiGLU expert 1,024 wide; vocab
+    163,840, untied head.  No layer has positions: the KDA layers carry
+    the order.
+
+    What config.json has no key for is the report's (arXiv:2510.26692) and
+    the public ``flash-linear-attention`` KDA layer's: the low-rank pairs'
+    inner width (the head's 128), ``A_log`` a head and ``dt_bias`` a
+    channel with Mamba's initialisers, the gate's sigmoid, no bias on the
+    gate's up-projection, chunks of 64; the bias update's speed 1e-3 is the
+    DeepSeek-V3 report's.  ``linear_attn_config.head_dim`` 128 is ``d_k``
+    and ``d_v``; the top-level ``head_dim`` 72 is d / 32 and nothing reads
+    it.  No exchange between chips is built.
+
+    ``overrides`` replace any field.  A cut takes the first letters of the
+    pattern (``pattern="kKKxK"``: the dense layer and one period); one
+    chip's share of the experts is ``moe={..., "held": (first, count)}``
+    and of the vocabulary a smaller ``vocab``; the heads are not divided.
+    Trained through ``make_train_step`` with the bias in its
+    ``aux_state``::
+
+        def loss_fn(params, aux, tokens):          # tokens (B, T + 1)
+            h, moved = model.apply(
+                {"params": params, **aux}, tokens[:, :-1],
+                return_hidden=True, mutable=["balance"])
+            ce = fused_softmax_xent(h.reshape(-1, model.dim),
+                                    params["head"]["kernel"],
+                                    tokens[:, 1:].reshape(-1)).mean()
+            return ce, moved
+    """
+    fields = dict(
+        vocab=163840, dim=2304, num_heads=32, max_len=1048576, norm="rms",
+        norm_eps=1e-5, pos="none",
+        pattern="kKKx" + "KKKx" * 5 + "KKx", mlp_hidden=9216,
+        lin=dict(num_heads=32, key_dim=128, value_dim=128, conv_kernel=4,
+                 chunk=64),
+        mla=dict(q_latent=None, kv_latent=512, nope_dim=128, rope_dim=64,
+                 v_dim=128),
+        moe_experts=256, moe_top_k=8, moe_hidden=1024,
+        moe=dict(router="sigmoid", renormalize=True, gate_scale=2.446,
+                 activation="swiglu", shared_hidden=1024, choice_bias=1e-3))
     fields.update(overrides)
     return TransformerLM(**fields)
 

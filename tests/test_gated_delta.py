@@ -190,10 +190,147 @@ def test_the_float32_parts_are_float32_in_the_traced_program(differentiated):
 
 
 def test_sizes_and_plan():
-    """What a call passes between chunks, and the one form there is."""
+    """What a call passes between chunks, and the form the rank of ``g``
+    gives it: nothing else picks one."""
     assert delta_sizes(1, 8192, 30, 96, 192, 64) == {
-        "chunks": 128, "state_bytes": 128 * 30 * 192 * 96 * 4}
+        "chunks": 128, "state_bytes": 128 * 30 * 192 * 96 * 4,
+        "decay_bytes": 0, "sub_chunks": 0}
     assert delta_sizes(2, 100, 3, 8, 16, 64)["chunks"] == 4
+    # A decay a key channel: the log-decays of every (padded) token in
+    # float32, and 63 pairs of sub-chunks a chunk of 64 (1 + 2 + ... + 32).
+    assert delta_sizes(1, 8192, 32, 128, 128, 64, g_rank=4) == {
+        "chunks": 128, "state_bytes": 128 * 32 * 128 * 128 * 4,
+        "decay_bytes": 8192 * 32 * 128 * 4, "sub_chunks": 128 * 63}
     plan = delta_plan(64)
     assert plan.form == "xla_chunked" and plan.chunk == 64
-    assert delta_plan.__code__.co_varnames == ("chunk",)
+    assert delta_plan(64, g_rank=4).form == "xla_chunked_halved"
+    assert delta_plan.__code__.co_varnames == ("chunk", "g_rank")
+
+
+# ------------------------------------------------- a decay a key channel
+
+
+def channel_inputs(T, b=2, H=3, dk=8, dv=16, seed=0, fast=0.0):
+    """:func:`delta_inputs` with ``g`` (b, T, H, d_k) and ``beta`` in (0,
+    1).  ``fast``: three channels in ten decay by ``g = -fast`` a token —
+    ``exp(-20)`` a token passes float32's range inside a chunk of 64 — and
+    the others by under half a percent, so that a tile holds entries of
+    every size."""
+    q, k, v, _, _ = delta_inputs(T, b, H, dk, dv, seed)
+    r = np.random.default_rng(seed + 100)
+    g = -r.uniform(0.001, 0.5, (b, T, H, dk))
+    if fast:
+        g = np.where(r.uniform(size=g.shape) < 0.3, -fast, g * 0.01)
+    beta = r.uniform(0.05, 0.95, (b, T, H))
+    return q, k, v, jnp.asarray(g, jnp.float32), jnp.asarray(beta,
+                                                             jnp.float32)
+
+
+@pytest.mark.parametrize("T,chunk,fast", [
+    (37, 16, 0.0), (96, 32, 0.0), (128, 64, 0.0),
+    (96, 16, 20.0), (96, 32, 20.0), (96, 64, 20.0)],
+    ids=["c16_T_not_a_multiple", "c32", "c64", "c16_g_to_minus_20",
+         "c32_g_to_minus_20", "c64_g_to_minus_20"])
+def test_per_channel_chunked_rule_equals_the_recurrence(T, chunk, fast):
+    """``g`` of rank 4 at three chunk lengths, with decays of a few percent
+    a token and with channels at ``g = -20`` a token: values to 2e-6 and
+    the gradients of all five inputs to 5e-6 of the recurrence's in
+    float32, as the rank-3 form is held, and finite throughout — every
+    exponent is a sum of ``g`` itself, so nothing cancels where the running
+    sums reach -1,280 a chunk."""
+    x = channel_inputs(T, fast=fast)
+
+    def value_and_grads(rule):       # one compile a side, not one an op
+        def f(*a):
+            o = rule(*a)
+            weight = jnp.cos(jnp.arange(o.size, dtype=jnp.float32))
+            return (o.astype(jnp.float32) * weight.reshape(o.shape)).sum(), o
+        return jax.jit(jax.value_and_grad(f, argnums=range(5),
+                                          has_aux=True))(*x)
+
+    (_, want), theirs = value_and_grads(gated_delta_recurrence)
+    (_, got), ours = value_and_grads(
+        lambda *a: gated_delta_rule(*a, chunk=chunk))
+    assert got.shape == want.shape and got.dtype == jnp.float32
+    assert bool(jnp.isfinite(got).all()) and rel(got, want) <= 2e-6
+    assert all(bool(jnp.isfinite(a).all()) for a in ours)
+    errors = {n: rel(a, b) for n, a, b in zip(NAMES, ours, theirs)}
+    assert max(errors.values()) <= 5e-6, errors
+
+
+def test_groups_of_chunks_change_nothing(monkeypatch):
+    """The tiles of a long call are made a group of chunks at a time
+    (``_TILE_GROUP_ELEMENTS``) and again in the backward pass: walked in
+    four groups or at once, values and gradients are the same numbers."""
+    from horovod_tpu.ops import gated_delta
+
+    x = channel_inputs(128, b=1, H=2, fast=20.0, seed=5)
+
+    def value_and_grads():
+        f = lambda *a: (gated_delta_rule(*a, chunk=16) ** 2).sum()
+        return jax.jit(jax.value_and_grad(f, argnums=range(5)))(*x)
+
+    at_once = value_and_grads()
+    monkeypatch.setattr(gated_delta, "_TILE_GROUP_ELEMENTS",
+                        2 * 1 * 2 * 16 * 8)         # two chunks a group
+    jaxpr = jax.make_jaxpr(lambda *a: gated_delta_rule(*a, chunk=16))(*x)
+    scans = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "scan"]
+    assert [e.params["length"] for e in scans] == [4, 8]
+    grouped = value_and_grads()
+    assert max(rel(a, b) for a, b in zip(jax.tree.leaves(grouped),
+                                         jax.tree.leaves(at_once))) <= 1e-6
+
+
+def test_one_decay_for_every_channel_is_the_rank_3_rule():
+    """``g`` broadcast over the key channels is the decay a head: the two
+    forms give one answer, and a chunk that is no power of two is refused
+    by the halved form alone."""
+    q, k, v, g, beta = delta_inputs(80, seed=3, beta_range=(0.05, 0.95))
+    wide = jnp.broadcast_to(g[..., None], q.shape)
+    a, b = jax.jit(lambda g_, w_: tuple(
+        gated_delta_rule(q, k, v, u, beta, chunk=16) for u in (g_, w_)))(
+            g, wide)
+    assert rel(b, a) <= 2e-6
+    assert jax.eval_shape(lambda: gated_delta_rule(
+        q, k, v, g, beta, chunk=24)).shape == a.shape
+    with pytest.raises(ValueError, match="power of two"):
+        gated_delta_rule(q, k, v, wide, beta, chunk=24)
+
+
+def test_the_halved_form_holds_no_tile_a_channel_and_no_positive_exponent():
+    """In the traced program of a rank-4 call: no array with two chunk axes
+    AND the key axis ((C, C, d_k) in any order), no (T, T) array, and every
+    ``exp`` reads either a ``min(., 0)`` or the carried total of a chunk (a
+    sum of ``g``); the running sums, the solve and the carried state are
+    float32 with bfloat16 operands."""
+    T, C, dk, dv = 128, 32, 8, 16
+    x = channel_inputs(T, b=1, H=2, dk=dk, dv=dv)
+    x = tuple(a.astype(jnp.bfloat16) for a in x[:3]) + x[3:]
+    jaxpr = jax.make_jaxpr(lambda *a: gated_delta_rule(*a, chunk=C))(*x)
+
+    def walk(j):
+        for e in j.eqns:
+            yield e
+            for sub in jax.core.jaxprs_in_params(e.params):
+                yield from walk(sub)
+
+    eqns = list(walk(jaxpr.jaxpr))
+    for e in eqns:
+        for v_ in e.outvars:
+            shape = v_.aval.shape
+            assert shape.count(T) < 2, (e.primitive, shape)
+            assert not (shape.count(C) >= 2 and dk in shape
+                        and len(shape) >= 3 and shape[-1] == dk), (
+                            e.primitive, shape)
+    made_by = {v_: e for e in eqns for v_ in e.outvars}
+    exps = [e for e in eqns if e.primitive.name == "exp"]
+    assert len(exps) >= 3
+    for e in exps:
+        source = made_by.get(e.invars[0])
+        assert source is None or source.primitive.name == "min" or (
+            e.invars[0].aval.shape[-1] == dk
+            and C not in e.invars[0].aval.shape), source
+    scans = [e for e in eqns if e.primitive.name == "scan"]
+    assert len(scans) == 1
+    carry = scans[0].params["jaxpr"].jaxpr.invars[0].aval
+    assert carry.shape[-2:] == (dv, dk) and carry.dtype == jnp.float32
