@@ -83,6 +83,16 @@ through the entry points a user calls (``hvd.init()`` →
   sequence of 16,384 — against the module's ``jax.numpy`` form: q", k" and
   the gradients of every operand, with each kernel's time alone and the
   plan (``cca_reference``, ``cca_plan``);
+* times the gated delta rule with a decay a key channel alone at
+  ``kimilinear_1chip``'s Kimi Delta Attention layer — one sequence of
+  8,192, 32 heads of 128 | 128 in chunks of 64 — in each form: the XLA
+  halved form and the tile kernels at 1, 2, 4, 8 and 16 chunks a grid
+  step, the rule forward and forward-and-backward under a
+  ``jax.checkpoint`` and the kernel pair alone, each read twice; checks
+  the kernels' ``o`` and five gradients against the XLA form's there and
+  against the recurrence at 512 tokens, and prints the plan and what the
+  mixer notes at that shape (``kda_tiles``, ``delta_plan``, ``notes``;
+  ``--kda-tiles`` runs this phase alone);
 * takes optimizer steps with the d=2048/T=2048 TransformerLM (one step
   per call, then four scanned steps per call) and with ResNet-50 at
   batch 128, parameters from each model's own ``init`` under ``--seed``,
@@ -174,6 +184,11 @@ HELD_WINDOWS = (0.5, 1.0, 1.5, 3.0)
 # states a head a token, 4.3 GB there and 17.2 of the chip's 15.75 at 2048.
 DELTA_REFERENCE = dict(batch=1, seq=512, heads=30, key_dim=96,
                        value_dim=192, chunk=64)
+# One Kimi Delta Attention layer of ``kimilinear_1chip``: one sequence of
+# 8,192, 32 heads, keys and values 128 wide, chunks of 64, a model 2,304
+# wide with low-rank pairs of 128.
+KDA_TILES = dict(batch=1, seq=8192, heads=32, key_dim=128, value_dim=128,
+                 chunk=64, dim=2304, short_seq=512)
 # Learned sparse attention at Keye-VL-2.0's widths over a shorter sequence:
 # 8 query heads over one KV head of 128, an indexer of 16 heads of 64 that
 # keeps 512 of up to 2,048 keys a query (a dense float32 (T, T) oracle).
@@ -1854,6 +1869,143 @@ def delta_reference_phase(*, batch: int, seq: int, heads: int, key_dim: int,
             **{k_: round(e, 5) for k_, e in errs.items()}}
 
 
+def kda_tiles_phase(*, batch: int, seq: int, heads: int, key_dim: int,
+                    value_dim: int, chunk: int, dim: int, short_seq: int,
+                    seed: int, calls: int = 5,
+                    chunks_a_step=(1, 2, 4, 8, 16)) -> dict:
+    """The gated delta rule handed a decay a key channel (``g`` of rank 4),
+    alone, in each of its forms — ``xla_halved`` and ``kernels.<chunks a
+    grid step>`` — with bfloat16 operands as the mixer calls it.
+    ``ms_a_layer``: by form ``forward`` (the rule) and ``forward_backward``
+    (its value and five gradients under a ``jax.checkpoint``), each
+    ``[first, second]``; ``tiles_alone``: the same two for what the kernel
+    pair replaces and nothing else (``_tiles``: the two tiles, the decayed
+    operands and ``total``), by chunks a grid step.  ``vs_xla``: the
+    kernels' ``o`` and gradients of q, k, v, g, beta against the XLA
+    form's, at the plan's tiling; ``vs_recurrence``: the same against the
+    recurrence in float32 at ``short_seq`` tokens.  ``delta_plan`` is what
+    the shapes give on this device, ``notes`` what a
+    ``KimiDeltaAttention`` of these sizes notes while traced."""
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.layer_notes import noting_layers
+    from horovod_tpu.models.linear_attention import KimiDeltaAttention
+    from horovod_tpu.ops import gated_delta as gd
+
+    interpret = jax.default_backend() != "tpu"
+    b, H, C = batch, heads, chunk
+    names = ("q", "k", "v", "g", "beta")
+
+    def inputs(T):
+        ks = jax.random.split(jax.random.PRNGKey(seed), 7)
+
+        def unit(key, width):
+            u = jax.random.normal(key, (b, T, H, width))
+            return u / jnp.linalg.norm(u, axis=-1, keepdims=True)
+
+        q, k = unit(ks[0], key_dim) * key_dim ** -0.5, unit(ks[1], key_dim)
+        v = jax.random.normal(ks[2], (b, T, H, value_dim))
+        # Decays of a part in a thousand to a few percent a token, and one
+        # channel in sixteen that forgets within a token or two.
+        g = -jnp.exp(jax.random.uniform(
+            ks[3], (H, 1), minval=0.0, maxval=2.7)) * jax.nn.softplus(
+                jax.random.normal(ks[4], (b, T, H, key_dim)) - 4.0)
+        g = jnp.where(jax.random.uniform(ks[6], g.shape) < 1 / 16, -8.0, g)
+        beta = jax.nn.sigmoid(jax.random.normal(ks[5], (b, T, H)))
+        do = jax.random.normal(ks[0], (b, T, H, value_dim))
+        return (q, k, v, g, beta), do
+
+    def rule(plan):
+        return lambda *a: gd._per_channel_rule(*a, plan, interpret)
+
+    def value_out_grads(fn, do):
+        def weighted(*a):
+            out = jax.checkpoint(fn)(*a)
+            return (out.astype(jnp.float32) * do).sum(), out
+        return jax.value_and_grad(weighted, argnums=range(5), has_aux=True)
+
+    full, do = inputs(seq)
+    low = tuple(a.astype(jnp.bfloat16) for a in full[:3]) + full[3:]
+    planned = gd.rule_plan(low[0], low[3], C, interpret)
+    xla = gd.DeltaPlan("xla_chunked_halved", C)
+    forms = {"xla_halved": xla}
+    forms.update({f"kernels.{per}": gd.DeltaPlan("tile_kernels", C, per)
+                  for per in chunks_a_step if (seq // C) % per == 0})
+    def twice(fn, *args):
+        """``([first, second] ms, result)``; interpreted, one call."""
+        first, out = _timed_ms(calls, interpret, fn, *args)
+        second = None if interpret else _timed_ms(calls, interpret, fn,
+                                                  *args)[0]
+        return [first, second], out
+
+    ms, alone, results = {}, {}, {}
+    for name, plan in forms.items():
+        forward, _ = twice(rule(plan), *low)
+        both, got = twice(value_out_grads(rule(plan), do), *low)
+        ms[name] = {"forward": forward, "forward_backward": both}
+        if plan in (xla, planned):
+            results[plan.form] = got
+        if plan.form != "tile_kernels":
+            continue
+
+        def tiles(q, k, g, per=plan.chunks_a_step):
+            return gd._tiles(q, k, g, C, per, interpret)
+
+        def tiles_grads(q, k, g):
+            out, back = jax.vjp(tiles, q, k, g)
+            return back(out)
+
+        tile_args = (low[0], low[1], low[3])
+        alone[str(plan.chunks_a_step)] = {
+            "forward": twice(tiles, *tile_args)[0],
+            "forward_backward": twice(tiles_grads, *tile_args)[0]}
+
+    def errors(got, want):
+        (_, got_out), got_grads = got
+        (_, want_out), want_grads = want
+        errs = {"out": _rel_err(got_out, want_out)}
+        errs.update({f"grad_{n}": _rel_err(a, w)
+                     for n, a, w in zip(names, got_grads, want_grads)})
+        return errs
+
+    vs_xla = {}
+    if planned.form == "tile_kernels":
+        vs_xla = errors(results["tile_kernels"], results[xla.form])
+        for name, err in vs_xla.items():
+            check(err <= DELTA_TOL,
+                  f"the tile kernels' {name} differs from the XLA halved "
+                  f"form's by {err:.3g} of its largest value (bound "
+                  f"{DELTA_TOL})")
+
+    short, do = inputs(short_seq)
+    low = tuple(a.astype(jnp.bfloat16) for a in short[:3]) + short[3:]
+    vs_recurrence = errors(
+        jax.jit(value_out_grads(lambda *a: gd.gated_delta_rule(
+            *a, chunk=C, interpret=interpret), do))(*low),
+        jax.jit(value_out_grads(gd.gated_delta_recurrence, do))(*short))
+    for name, err in vs_recurrence.items():
+        check(err <= DELTA_TOL,
+              f"the rank-4 rule's {name} differs from the recurrence's by "
+              f"{err:.3g} of its largest value (bound {DELTA_TOL})")
+
+    mixer = KimiDeltaAttention(num_heads=H, key_dim=key_dim,
+                               value_dim=value_dim, chunk=C,
+                               low_rank=value_dim)
+    x = jax.ShapeDtypeStruct((b, seq, dim), jnp.bfloat16)
+    noted = {}
+    jax.eval_shape(noting_layers(
+        lambda x_: mixer.init(jax.random.PRNGKey(0), x_), noted), x)
+    (notes,) = noted.values()
+    return {"shape": [b, seq, H, key_dim, value_dim], "chunk": C,
+            "interpret": interpret, "delta_plan": planned._asdict(),
+            "ms_a_layer": ms, "tiles_alone": alone,
+            "vs_xla": {k_: round(e, 5) for k_, e in vs_xla.items()},
+            "vs_recurrence": {k_: round(e, 5)
+                              for k_, e in vs_recurrence.items()},
+            "notes": notes}
+
+
 def _rel_err(got, want) -> float:
     import jax.numpy as jnp
     got = got.astype(jnp.float32)
@@ -2374,6 +2526,10 @@ def main(argv=None) -> int:
                          "heads at one width: grid, grid_live and the "
                          "resident form at two chains and two tiles, at the "
                          "four cells' calls")
+    ap.add_argument("--kda-tiles", action="store_true",
+                    help="only the delta rule with a decay a key channel "
+                         "at kimilinear_1chip's layer: the XLA halved form "
+                         "and the tile kernels at every tiling, alone")
     ap.add_argument("--launcher-worker", action="store_true",
                     help=argparse.SUPPRESS)
     args = ap.parse_args(argv)
@@ -2436,6 +2592,8 @@ def main(argv=None) -> int:
         for cell, shape in GROUPED_FORWARD.items():
             emit("grouped_forward", cell=cell, **grouped_forward_phase(
                 **shape, seed=args.seed))
+    elif args.kda_tiles:
+        emit("kda_tiles", **kda_tiles_phase(**KDA_TILES, seed=args.seed))
     elif args.chips == 1:
         emit("flash_reference", **flash_reference_phase(
             **FLASH_REFERENCE, seed=args.seed))
@@ -2451,6 +2609,7 @@ def main(argv=None) -> int:
             **CCA_REFERENCE, seed=args.seed))
         emit("delta_reference", **delta_reference_phase(
             **DELTA_REFERENCE, seed=args.seed))
+        emit("kda_tiles", **kda_tiles_phase(**KDA_TILES, seed=args.seed))
         emit("experts_reference", **experts_reference_phase(
             **EXPERTS_REFERENCE, seed=args.seed))
         emit("held_rows", **{cell: held_rows(**layer)

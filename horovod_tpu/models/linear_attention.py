@@ -64,8 +64,10 @@ the norm; matmul operands ``dtype``.  Kept for the backward pass, as
 ``k~``, ``v~``, ``W_b x`` and the two low-rank activations ``W_f_down x``,
 ``W_g_down x`` (``r`` wide: the up-projections to ``H d_k`` and ``H d_v``
 run again), the rule's ``o`` and the normed, gated ``o``; the convolution,
-the decays, the rule and the gate run again, the rule's tiles a group of
-chunks at a time.
+the decays, the rule and the gate run again — where the rule's tile
+kernels take the shape (``delta_plan``: keys in whole 128-lane tiles,
+chunks of 64 or more) their residuals are their inputs and every chunk's
+tiles are made at once; in the XLA form a group of chunks at a time.
 
 Its scopes are ``in_proj`` (q, k, v, b), ``conv``, ``decay`` (the
 low-rank decay projection, ``softplus`` and the log-decays; the rule's
@@ -73,8 +75,10 @@ running sums and the factors made of them are ``delta/decay``), ``delta``
 (``solve``, ``states``, ``inter``, ``intra``), ``gate_norm`` (the
 low-rank gate and the gated norm) and ``out_proj``; beside
 ``lin.delta_chunks`` and ``lin.state_bytes`` it notes ``lin.decay_bytes``
-(float32 bytes of per-channel log-decays a call keeps) and
-``lin.sub_chunks`` (pairs of sub-chunks whose products make the tiles).
+(float32 bytes of per-channel log-decays a call keeps),
+``lin.sub_chunks`` (pairs of sub-chunks whose products make the tiles) and
+``lin.tile_kernel_chunks`` (the chunks whose tiles the kernels made in
+VMEM: ``lin.delta_chunks`` where they engage, 0 where the plan stood down).
 """
 
 from __future__ import annotations
@@ -88,7 +92,9 @@ import jax.numpy as jnp
 from horovod_tpu.layer_notes import note_layer
 from horovod_tpu.models.ssm import (
     CausalConv, a_log_init, causal_conv, dt_bias_init)
-from horovod_tpu.ops.gated_delta import delta_sizes, gated_delta_rule
+from horovod_tpu.ops import _pallas
+from horovod_tpu.ops.gated_delta import (
+    delta_plan, delta_sizes, gated_delta_rule)
 
 
 # ``dt_bias`` starts as the inverse softplus of a log-uniform step in
@@ -251,6 +257,7 @@ class KimiDeltaAttention(nn.Module):
         A_log = self.param("A_log", a_log_init, (H,), self.param_dtype)
         scale = self.param("gate_norm", nn.initializers.ones, (dv,),
                            self.param_dtype)
+        interpret = _pallas.interpret()
 
         @jax.checkpoint
         def conv_and_delta(q, k, v, f, b, conv_w, w_f, dt_bias, A_log):
@@ -265,7 +272,8 @@ class KimiDeltaAttention(nn.Module):
                                    self.dtype)
                 beta = jax.nn.sigmoid(b.astype(f32))
                 return gated_delta_rule(q, k, v.reshape(Bsz, T, H, dv), g,
-                                        beta, chunk=self.chunk)
+                                        beta, chunk=self.chunk,
+                                        interpret=interpret)
 
         @jax.checkpoint
         def gate_norm(o, gate, w_g, scale):
@@ -283,9 +291,15 @@ class KimiDeltaAttention(nn.Module):
             conv_and_delta(q, k, v, f, b, conv_w, w_f, dt_bias, A_log),
             gate, w_g, scale)
         sizes = delta_sizes(Bsz, T, H, dk, dv, self.chunk, g_rank=4)
-        note_layer(self.path, {"lin.delta_chunks": sizes["chunks"],
-                               "lin.state_bytes": sizes["state_bytes"],
-                               "lin.decay_bytes": sizes["decay_bytes"],
-                               "lin.sub_chunks": sizes["sub_chunks"]})
+        plan = delta_plan(self.chunk, 4, seq_len=T, key_dim=dk,
+                          itemsize=q.dtype.itemsize, interpret=interpret,
+                          manual_axes=bool(jax.typeof(q).vma))
+        note_layer(self.path, {
+            "lin.delta_chunks": sizes["chunks"],
+            "lin.state_bytes": sizes["state_bytes"],
+            "lin.decay_bytes": sizes["decay_bytes"],
+            "lin.sub_chunks": sizes["sub_chunks"],
+            "lin.tile_kernel_chunks": (
+                sizes["chunks"] if plan.form == "tile_kernels" else 0)})
         with jax.named_scope("out_proj"):
             return dense(d, "out")(y)

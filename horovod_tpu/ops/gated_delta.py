@@ -73,22 +73,49 @@ left are single tokens, ``q_i . k_i`` with no decay, formed directly.  No
 (T, T) and no (C, C, d_k) array is formed, ``exp`` is never raised to a
 positive power, and the chunk (a power of two) changes nothing.  Float32:
 ``g``, its sums, every ``E_s`` before it multiplies an operand, the tiles'
-sums, the solve, the carried state; matmul operands ``v.dtype``.  What
-comes before the sequential pass is made for ``_TILE_GROUP_ELEMENTS`` of a
-call's chunks at a time and again in the backward pass, which keeps the
-chunked ``q``, ``k``, ``v``, ``g``, ``beta`` and what the pass and the
-read-outs are handed (``W``, ``U``, the decayed ``k`` and ``q``, the
-``Q K^T`` tile).
+sums, the solve, the carried state; matmul operands ``v.dtype``.
 
-Plain XLA in both ranks, differentiated as it stands (the solve alone has
-its own rule, ``-T^T dT T^T``, so that the powers of ``A`` are not
-kept): a caller at training sizes wraps it in a ``jax.checkpoint``, as
+Two forms of the rank-4 tiles, and one place that chooses
+(:func:`delta_plan`, a pure function of the chunk, the keys' width, the
+operands' bytes, ``interpret`` and manual mesh axes; no option picks one):
+
+* **Pallas TPU kernels** (``kda_tiles_fwd``, ``kda_tiles_bwd``; form
+  ``"tile_kernels"``) where the shapes tile — keys in whole 128-lane
+  tiles, chunks of 64 or 128: the ``kimilinear_1chip`` cell.  A grid step
+  holds a few chunks of one head's ``q``, ``k`` and ``g`` in VMEM, read as
+  column ranges of the (b, T, H d_k) arrays as they stand; a chunk at a
+  time it makes every level's exponent from ``g`` by float32 ADDS (halves'
+  totals exchanged between siblings, level by level: sums only, never a
+  difference), ``exp(min(., 0))`` of them, a level's two products as one
+  with float32 accumulation, masks and adds them in VMEM, and writes what
+  the solve, the pass and the read-outs are handed and nothing a level
+  made: the two (C, C) tiles, ``K o exp(gamma)``, ``K o exp(gamma_C -
+  gamma)``, ``Q o exp(gamma)`` and ``gamma_C``.  The backward kernel reads
+  the same three inputs and those six cotangents, makes the levels again
+  and writes ``dq``, ``dk``, ``dg``: the pair is a ``jax.custom_vjp`` whose
+  residuals are its inputs, so every chunk's tiles are made at once, with
+  no groups.  The inverse, ``W``, ``U``, the sequential pass and the
+  read-outs are the XLA text below, under the same scopes (the kernels
+  under ``solve``).
+* **plain XLA** (:func:`_halved_tiles`; form ``"xla_chunked_halved"``)
+  otherwise — heads narrower than a lane tile (the CPU rehearsal's 16),
+  interpreted Pallas under ``shard_map``'s manual axes — and as the
+  kernels' oracle.  Differentiated as it stands a level keeps a float32
+  factor, two scaled operands and two products a chunk, so what comes
+  before the sequential pass is made for ``_TILE_GROUP_ELEMENTS`` of a
+  call's chunks at a time and again in the backward pass, which keeps the
+  chunked ``q``, ``k``, ``v``, ``g``, ``beta`` and what the pass and the
+  read-outs are handed (``W``, ``U``, the decayed ``k`` and ``q``, the
+  ``Q K^T`` tile).
+
+The rank-3 rule is plain XLA, differentiated as it stands; in every form
+the solve has its own rule, ``-T^T dT T^T``, so that the powers of ``A``
+are not kept, and a caller at training sizes wraps the rule in a
+``jax.checkpoint``, as
 :class:`~horovod_tpu.models.linear_attention.GatedDeltaNet` and
-:class:`~horovod_tpu.models.linear_attention.KimiDeltaAttention` do.
-:func:`delta_plan` names the form, as ``flash_attention._plan`` and
-``ssd._plan`` name theirs; a fused kernel would be chosen there.  The
-rank of ``g`` picks it and nothing else does; a rank-3 call lowers to the
-text it lowered to before the second form was written
+:class:`~horovod_tpu.models.linear_attention.KimiDeltaAttention` do.  The
+rank of ``g`` picks the body and nothing else does; a rank-3 call lowers
+to the text it lowered to before the second body was written
 (``tests/test_kimi_program.py``).
 
 :func:`gated_delta_recurrence` is the definition, token by token in
@@ -97,12 +124,17 @@ float32, for tests at small sizes.
 
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from horovod_tpu.ops import _pallas
 
 _F32 = jnp.float32
 _HIGHEST = lax.Precision.HIGHEST
@@ -110,18 +142,45 @@ _HIGHEST = lax.Precision.HIGHEST
 
 class DeltaPlan(NamedTuple):
     """How :func:`gated_delta_rule` runs: ``form`` — ``"xla_chunked"``
-    for a decay a head (``g`` of rank 3), ``"xla_chunked_halved"`` for a
-    decay a key channel (rank 4: the tiles from halved sub-chunks) — and
-    the chunk length."""
+    for a decay a head (``g`` of rank 3); for a decay a key channel (rank
+    4) ``"tile_kernels"`` (the tiles from halved sub-chunks made in VMEM,
+    ``chunks_a_step`` chunks of one head a grid step) or
+    ``"xla_chunked_halved"`` (the same tiles by plain XLA) — and the chunk
+    length."""
     form: str
     chunk: int
+    chunks_a_step: int = 0
 
 
-def delta_plan(chunk: int = 64, g_rank: int = 3) -> DeltaPlan:
-    """The form a call takes: the rank of its ``g`` decides, and no option
-    picks another."""
-    return DeltaPlan("xla_chunked" if g_rank == 3 else "xla_chunked_halved",
-                     chunk)
+# The most chunks of one head that a grid step of the tile kernels holds
+# (timed alone at 1, 2, 4, 8 and 16 chunks of 64 on a v5e, PERF.md §6
+# "PR 63").
+_CHUNKS_A_STEP = 8
+
+
+def delta_plan(chunk: int = 64, g_rank: int = 3, *, seq_len: int = 0,
+               key_dim: int = 0, itemsize: int = 2, interpret: bool = False,
+               manual_axes: bool = False) -> DeltaPlan:
+    """The form a call takes, a pure function of what the op observes at
+    trace time; no option picks another.  The rank of ``g`` chooses the
+    body.  Inside rank 4 the tile kernels take a shape that tiles: a chunk
+    of 64 or 128 tokens (a chunk's two cotangent tiles side by side fill
+    whole 128-lane tiles; at 256 the levels' masks alone pass Mosaic's
+    default scoped VMEM) and keys in whole 128-lane tiles; anything else — the CPU rehearsal's heads of 16, interpreted
+    Pallas under ``shard_map``'s manual axes (:func:`_pallas.xla_form`) —
+    stands down to the XLA form.  ``chunks_a_step``: the largest power of
+    two up to ``_CHUNKS_A_STEP`` that divides the call's chunks."""
+    if g_rank == 3:
+        return DeltaPlan("xla_chunked", chunk)
+    tiles = (chunk in (64, 128) and key_dim > 0 and key_dim % 128 == 0
+             and itemsize in (2, 4))
+    if not tiles or _pallas.xla_form(interpret, manual_axes):
+        return DeltaPlan("xla_chunked_halved", chunk)
+    chunks = max(1, -(-seq_len // chunk))
+    per = _CHUNKS_A_STEP
+    while chunks % per:
+        per //= 2
+    return DeltaPlan("tile_kernels", chunk, per)
 
 
 def delta_sizes(batch: int, seq_len: int, heads: int, key_dim: int,
@@ -175,15 +234,21 @@ def _unit_lower_inverse_bwd(inverse, g):
 unit_lower_inverse.defvjp(_unit_lower_inverse_fwd, _unit_lower_inverse_bwd)
 
 
-def gated_delta_rule(q, k, v, g, beta, *, chunk: int = 64):
+def gated_delta_rule(q, k, v, g, beta, *, chunk: int = 64, interpret=None):
     """``o`` (b, T, H, d_v) of the module docstring's recurrence for ``q``,
     ``k`` (b, T, H, d_k), ``v`` (b, T, H, d_v), ``beta`` (b, T, H) and
     ``g`` (b, T, H) — a decay a head — or (b, T, H, d_k) — a decay a key
     channel, in chunks that are a power of two —, each sequence from a
     zero state.  A ``T`` that is no multiple of ``chunk`` is padded with
-    tokens that change nothing (``beta`` 0, ``g`` 0)."""
+    tokens that change nothing (``beta`` 0, ``g`` 0).  Which form a rank-4
+    call takes follows its shapes (:func:`delta_plan`) and is not an
+    option; ``interpret`` (None: off the TPU) runs its kernels
+    interpreted."""
     if g.ndim == 4:
-        return _per_channel_rule(q, k, v, g, beta, chunk)
+        if interpret is None:
+            interpret = _pallas.interpret()
+        return _per_channel_rule(q, k, v, g, beta,
+                                 rule_plan(q, g, chunk, interpret), interpret)
     b, T, H, dk = q.shape
     dv = v.shape[-1]
     C = chunk
@@ -319,12 +384,333 @@ def _halved_tiles(qc, kc, gc, dtype):
 _TILE_GROUP_ELEMENTS = 1 << 22
 
 
-def _per_channel_rule(q, k, v, g, beta, chunk):
+# ------------------------------------------------------- the tile kernels
+#
+# One grid step of either kernel holds ``per`` chunks of one head of one
+# sequence: ``q``, ``k`` (per C, d_k) in the operands' dtype and ``g``
+# float32, read as a column range of the (b, T, H d_k) arrays the mixer
+# leaves, so that nothing is chunked or transposed on the way in.  A chunk
+# at a time (a ``fori_loop``: the body is lowered once) it makes the
+# exponents of all ``log2 C`` levels, of ``into`` and of ``out_of`` from
+# ``g`` by float32 adds (:func:`_exponents`: each a sum of ``g`` itself
+# over the rows between a token and its level's reference row, as
+# ``_halved_tiles`` has it), ``exp(min(., 0))`` of them, and a level's two
+# products as one, ``[Q o E; K o E] (K o E)^T``, masked and added in
+# float32 in VMEM.  What leaves the core is what the solve, the sequential
+# pass and the read-outs are handed: ``kk`` (C, C) float32, ``qk`` (C, C)
+# and the decayed ``k_in``, ``k_out``, ``q_in`` in the operands' dtype,
+# ``total`` (d_k) float32, in the chunked layout (b, chunks, H, C, .).  The
+# backward kernel reads the same three inputs and those six cotangents,
+# makes the levels again, and writes ``dq``, ``dk``, ``dg``: a level's four
+# transposed products are one (2 C, 2 C) x (2 C, d_k) product with the two
+# cotangent tiles and their transposes laid in one square (``G = H + H^T``,
+# ``H = [[tril(dkk, -1), 0], [dqk, 0]]``), and the exponents' cotangents go
+# back through the same adds (:func:`_exponents_transposed`).
+
+
+def _level_masks(C: int) -> np.ndarray:
+    """(levels, C, C) float32 of 0 and 1: :func:`_halving_masks`."""
+    return np.stack([m for _, m in _halving_masks(C)]).astype(np.float32)
+
+
+def _cotangent_masks(C: int) -> np.ndarray:
+    """(levels, 2 C, 2 C): a level's pairs in the square ``[[dkk + dkk^T,
+    dqk^T], [dqk, 0]]``."""
+    m = _level_masks(C)
+    t = np.swapaxes(m, 1, 2)
+    return np.concatenate([np.concatenate([m + t, t], axis=2),
+                           np.concatenate([m, np.zeros_like(m)], axis=2)],
+                          axis=1)
+
+
+def _nt(a, b):
+    """``a b^T`` with float32 accumulation."""
+    return lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                           preferred_element_type=_F32)
+
+
+def _row_col(C: int):
+    """Row and column numbers of a (C, C) tile."""
+    return (lax.broadcasted_iota(jnp.int32, (C, C), 0),
+            lax.broadcasted_iota(jnp.int32, (C, C), 1))
+
+
+def _second_half(C: int, s: int, width: int):
+    """Which rows of a chunk lie in the second half of their block of
+    ``2 s`` rows: (C, width) bool."""
+    row = lax.broadcasted_iota(jnp.int32, (C, width), 0)
+    return lax.bitwise_and(row, s) != 0
+
+
+def _siblings(x, s: int):
+    """``x`` (C, width) with the two halves (``s`` rows each) of every
+    block of ``2 s`` rows exchanged: whole sublane tiles renamed where
+    ``s`` is a multiple of 8, two rotations and a select below."""
+    C = x.shape[0]
+    if s % 8 == 0:
+        return lax.concatenate(
+            [x[first + half:first + half + s]
+             for first in range(0, C, 2 * s) for half in (s, 0)], 0)
+    return lax.select(_second_half(C, s, x.shape[1]),
+                      pltpu.roll(x, s, 0), pltpu.roll(x, C - s, 0))
+
+
+def _exponents(g):
+    """Every level's exponent of a chunk ``g`` (C, d_k) float32, then
+    ``into``'s and ``out_of``'s: ``[e_{C/2}, ..., e_1, into, out_of]``,
+    each (C, d_k) and a sum of ``g`` itself by float32 adds.  With ``a_s``
+    the sum over the rows of a token's half of ``s`` rows up to it and
+    ``b_s`` over those behind it, ``a_2s = a_s + [second half] t_s'`` and
+    ``b_2s = b_s + [first half] t_s'`` for ``t_s'`` the other half's total
+    (``t_2s = t_s + t_s'``): a level's exponent is ``a_s`` in a second
+    half and ``b_s`` in a first, ``into = a_C``, ``out_of = b_C`` — no
+    difference of two sums anywhere."""
+    C, dk = g.shape
+    zero = jnp.zeros_like(g)
+    a, b, t = g, zero, g
+    levels, s = [], 1
+    while s < C:
+        second = _second_half(C, s, dk)
+        levels.append(lax.select(second, a, b))
+        other = _siblings(t, s)
+        a = lax.add(a, lax.select(second, other, zero))
+        b = lax.add(b, lax.select(second, zero, other))
+        t = lax.add(t, other)
+        s *= 2
+    return levels[::-1] + [a, b]
+
+
+def _exponents_transposed(de):
+    """The cotangent of ``g`` from those of :func:`_exponents`' blocks
+    (the same list): the same adds walked back, float32."""
+    C, dk = de[0].shape
+    count = len(de) - 2
+    zero = jnp.zeros_like(de[0])
+    da, db, dt = de[-2], de[-1], None
+    for level in range(count):                  # s = C/2, ..., 1
+        s = C >> (level + 1)
+        second = _second_half(C, s, dk)
+        dother = lax.select(second, da, db)
+        if dt is not None:
+            dother = lax.add(dother, dt)
+        back = _siblings(dother, s)
+        dt = back if dt is None else lax.add(dt, back)
+        da = lax.add(da, lax.select(second, de[level], zero))
+        db = lax.add(db, lax.select(second, zero, de[level]))
+    return lax.add(da, dt)
+
+
+def _decays(e):
+    """``exp(min(., 0))`` of an exponent: the guard changes nothing."""
+    return lax.exp(lax.min(e, jnp.zeros_like(e)))
+
+
+def _tiles_fwd_kernel(q_ref, k_ref, g_ref, mask_ref, kk_ref, qk_ref,
+                      kin_ref, kout_ref, qin_ref, total_ref, *, C: int,
+                      per: int):
+    """``per`` chunks of one head: the six outputs of each (the comment
+    above).  ``mask_ref`` (levels, C, C): a level's pairs, 0 and 1."""
+    levels = C.bit_length() - 1
+    dtype = q_ref.dtype
+    row, col = _row_col(C)
+    eye = row == col
+
+    def chunk(j, carry):
+        rows = pl.ds(pl.multiple_of(j * C, C), C)
+        qf = q_ref[rows, :].astype(_F32)
+        kf = k_ref[rows, :].astype(_F32)
+        e = _exponents(g_ref[rows, :])
+        qk = jnp.where(eye, jnp.sum(qf * kf, axis=1, keepdims=True), 0.0)
+        kk = jnp.zeros((C, C), _F32)
+        for l in range(levels):
+            El = _decays(e[l])
+            ks = lax.convert_element_type(lax.mul(kf, El), dtype)
+            qs = lax.convert_element_type(lax.mul(qf, El), dtype)
+            both = _nt(lax.concatenate([qs, ks], 0), ks)     # (2 C, C)
+            mask = mask_ref[l]
+            qk = lax.add(qk, lax.mul(both[:C], mask))
+            kk = lax.add(kk, lax.mul(both[C:], mask))
+        into, out_of = _decays(e[levels]), _decays(e[levels + 1])
+        kk_ref[j] = kk
+        qk_ref[j] = qk.astype(dtype)
+        kin_ref[j] = (kf * into).astype(dtype)
+        kout_ref[j] = (kf * out_of).astype(dtype)
+        qin_ref[j] = (qf * into).astype(dtype)
+        total_ref[j] = e[levels][C - 1:]
+        return carry
+
+    lax.fori_loop(0, per, chunk, 0)
+
+
+def _tiles_bwd_kernel(q_ref, k_ref, g_ref, dkk_ref, dqk_ref, dkin_ref,
+                      dkout_ref, dqin_ref, dtotal_ref, mask_ref, dq_ref,
+                      dk_ref, dg_ref, h_ref, *, C: int, per: int):
+    """``per`` chunks of one head, transposed.  ``mask_ref`` (levels, 2 C,
+    2 C): a level's pairs in the square ``G``; ``h_ref`` (2 C, 2 C)
+    float32: the two cotangent tiles of a chunk beside zeros."""
+    levels = C.bit_length() - 1
+    dtype = q_ref.dtype
+    row, col = _row_col(C)
+    eye, below = row == col, row > col
+    last = lax.broadcasted_iota(jnp.int32, (C, 1), 0) == C - 1
+    h_ref[:, C:] = jnp.zeros((2 * C, C), _F32)
+
+    def chunk(j, carry):
+        rows = pl.ds(pl.multiple_of(j * C, C), C)
+        qf = q_ref[rows, :].astype(_F32)
+        kf = k_ref[rows, :].astype(_F32)
+        e = _exponents(g_ref[rows, :])
+        dqk = dqk_ref[j].astype(_F32)
+        # kk is zero on and above its diagonal whatever its cotangent holds.
+        h_ref[:C, :C] = jnp.where(below, dkk_ref[j], 0.0)
+        h_ref[C:, :C] = dqk
+        H = h_ref[...]
+        G = H + H.T
+        own = jnp.sum(jnp.where(eye, dqk, 0.0), axis=1, keepdims=True)
+        into, out_of = _decays(e[levels]), _decays(e[levels + 1])
+        dkin = dkin_ref[j].astype(_F32)
+        dkout = dkout_ref[j].astype(_F32)
+        dqin = dqin_ref[j].astype(_F32)
+        dk = dkin * into + dkout * out_of + own * qf
+        dq = dqin * into + own * kf
+        de = [None] * levels + [
+            (dkin * kf + dqin * qf) * into
+            + jnp.where(last, dtotal_ref[j], 0.0), dkout * kf * out_of]
+        for l in range(levels):
+            El = _decays(e[l])
+            ks = lax.convert_element_type(lax.mul(kf, El), dtype)
+            qs = lax.convert_element_type(lax.mul(qf, El), dtype)
+            pairs = lax.convert_element_type(lax.mul(G, mask_ref[l]), dtype)
+            d = lax.dot_general(pairs, lax.concatenate([ks, qs], 0),
+                                (((1,), (0,)), ((), ())),
+                                preferred_element_type=_F32)  # (2 C, d_k)
+            dks, dqs = d[:C], d[C:]
+            dk = lax.add(dk, lax.mul(dks, El))
+            dq = lax.add(dq, lax.mul(dqs, El))
+            de[l] = lax.mul(
+                lax.add(lax.mul(dks, kf), lax.mul(dqs, qf)), El)
+        dq_ref[rows, :] = dq.astype(dq_ref.dtype)
+        dk_ref[rows, :] = dk.astype(dk_ref.dtype)
+        dg_ref[rows, :] = _exponents_transposed(de)
+        return carry
+
+    lax.fori_loop(0, per, chunk, 0)
+
+
+_PARALLEL = ("parallel", "parallel", "parallel")
+
+
+def _tile_specs(C: int, per: int, dk: int):
+    """Block specs of a grid ``(sequence, chunks / per, head)``: a head's
+    columns of ``per`` chunks of a (b, T, H d_k) array; ``per`` chunks of
+    one head of a chunked (b, chunks, H, rows, cols) array; a whole
+    constant."""
+    flat = pl.BlockSpec((None, per * C, dk), lambda i, c, h: (i, c, h))
+
+    def chunked(rows, cols):
+        return pl.BlockSpec((None, per, None, rows, cols),
+                            lambda i, c, h: (i, c, h, 0, 0))
+
+    def whole(a):
+        return pl.BlockSpec(a.shape, lambda i, c, h: (0,) * a.ndim)
+
+    return flat, chunked, whole
+
+
+@functools.partial(jax.jit, inline=True,
+                   static_argnames=("C", "per", "interpret"))
+def _tiles_fwd(q, k, g, *, C, per, interpret):
+    """``kk``, ``qk``, ``k_in``, ``k_out``, ``q_in``, ``total`` of every
+    chunk, (b, chunks, H, ...), from ``q``, ``k``, ``g`` (b, T, H, d_k)."""
+    b, T, H, dk = q.shape
+    nc = T // C
+    flat, chunked, whole = _tile_specs(C, per, dk)
+    masks = jnp.asarray(_level_masks(C))
+
+    def out(rows, cols, dtype):
+        return _pallas.struct((b, nc, H, rows, cols), dtype, q, k, g)
+
+    kk, qk, k_in, k_out, q_in, total = pl.pallas_call(
+        functools.partial(_tiles_fwd_kernel, C=C, per=per),
+        grid=(b, nc // per, H),
+        in_specs=[flat, flat, flat, whole(masks)],
+        out_specs=[chunked(C, C), chunked(C, C), chunked(C, dk),
+                   chunked(C, dk), chunked(C, dk), chunked(1, dk)],
+        out_shape=[out(C, C, _F32), out(C, C, q.dtype), out(C, dk, q.dtype),
+                   out(C, dk, q.dtype), out(C, dk, q.dtype),
+                   out(1, dk, _F32)],
+        interpret=interpret, name="kda_tiles_fwd",
+        **_pallas.compiler_params(interpret, _PARALLEL),
+    )(*(a.reshape(b, T, H * dk) for a in (q, k, g)), masks)
+    return kk, qk, k_in, k_out, q_in, total.reshape(b, nc, H, dk)
+
+
+@functools.partial(jax.jit, inline=True,
+                   static_argnames=("C", "per", "interpret"))
+def _tiles_bwd(q, k, g, cotangents, *, C, per, interpret):
+    """``dq``, ``dk``, ``dg`` (b, T, H, d_k) from the inputs and the
+    cotangents of :func:`_tiles_fwd`'s six outputs."""
+    b, T, H, dk = q.shape
+    nc = T // C
+    flat, chunked, whole = _tile_specs(C, per, dk)
+    masks = jnp.asarray(_cotangent_masks(C))
+    dkk, dqk, dkin, dkout, dqin, dtotal = cotangents
+    like = (q, k, g, *cotangents)
+    dq, dk_, dg = pl.pallas_call(
+        functools.partial(_tiles_bwd_kernel, C=C, per=per),
+        grid=(b, nc // per, H),
+        in_specs=[flat, flat, flat, chunked(C, C), chunked(C, C),
+                  chunked(C, dk), chunked(C, dk), chunked(C, dk),
+                  chunked(1, dk), whole(masks)],
+        out_specs=[flat, flat, flat],
+        out_shape=[_pallas.struct((b, T, H * dk), q.dtype, *like),
+                   _pallas.struct((b, T, H * dk), k.dtype, *like),
+                   _pallas.struct((b, T, H * dk), _F32, *like)],
+        scratch_shapes=[pltpu.VMEM((2 * C, 2 * C), _F32)],
+        interpret=interpret, name="kda_tiles_bwd",
+        **_pallas.compiler_params(interpret, _PARALLEL),
+    )(*(a.reshape(b, T, H * dk) for a in (q, k, g)), dkk.astype(_F32), dqk,
+      dkin, dkout, dqin, dtotal.astype(_F32).reshape(b, nc, H, 1, dk), masks)
+    return tuple(a.reshape(b, T, H, dk) for a in (dq, dk_, dg))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _tiles(q, k, g, C, per, interpret):
+    return _tiles_fwd(q, k, g, C=C, per=per, interpret=interpret)
+
+
+def _tiles_fwd_rule(q, k, g, C, per, interpret):
+    # The inputs alone: nothing a level made is kept, so the tiles need no
+    # groups of chunks under a second jax.checkpoint.
+    return (_tiles_fwd(q, k, g, C=C, per=per, interpret=interpret),
+            (q, k, g))
+
+
+def _tiles_bwd_rule(C, per, interpret, res, cotangents):
+    return _tiles_bwd(*res, cotangents, C=C, per=per, interpret=interpret)
+
+
+_tiles.defvjp(_tiles_fwd_rule, _tiles_bwd_rule)
+
+
+def rule_plan(q_like, g_like, chunk: int, interpret: bool) -> DeltaPlan:
+    """:func:`delta_plan` for a call whose ``q`` and ``g`` are, or are
+    shaped like, ``q_like`` (b, T, H, d_k) and ``g_like``: what the rule,
+    ``chip_smoke.py`` and the tests ask (the mixer, whose ``q`` is not yet
+    cut into heads, asks :func:`delta_plan` itself)."""
+    vma = jax.typeof(q_like).vma | jax.typeof(g_like).vma
+    return delta_plan(chunk, len(g_like.shape), seq_len=q_like.shape[1],
+                      key_dim=q_like.shape[-1],
+                      itemsize=q_like.dtype.itemsize, interpret=interpret,
+                      manual_axes=bool(vma))
+
+
+def _per_channel_rule(q, k, v, g, beta, plan: DeltaPlan, interpret):
     """:func:`gated_delta_rule` for ``g`` (b, T, H, d_k): the module
-    docstring's second form."""
+    docstring's second form, its tiles made as ``plan`` says."""
     b, T, H, dk = q.shape
     dv = v.shape[-1]
-    C = chunk
+    C = plan.chunk
     if C & (C - 1):
         raise ValueError("a decay a key channel halves its chunks: chunk "
                          f"has to be a power of two, not {C}")
@@ -340,6 +726,13 @@ def _per_channel_rule(q, k, v, g, beta, chunk):
         a = a.reshape(b, nc, C, *a.shape[2:])
         return jnp.moveaxis(a, 2, 3)
 
+    def solved(kk, k_in, vc, bc):
+        """``W`` and ``U`` of the WY form from the ``K K^T`` tile."""
+        Tm = (unit_lower_inverse(bc[..., :, None] * kk)
+              * bc[..., None, :]).astype(dtype)
+        return (jnp.einsum("bnhij,bnhjd->bnhid", Tm, k_in),
+                jnp.einsum("bnhij,bnhjv->bnhiv", Tm, vc))
+
     def before_the_states(qc, kc, vc, gc, bc):
         """What a group of chunks hands the sequential pass and the
         read-outs: every factor is ``exp`` of a sum of ``g`` (the guard
@@ -351,31 +744,45 @@ def _per_channel_rule(q, k, v, g, beta, chunk):
             total = gc.sum(axis=-2)                        # (b, n, H, d_k)
         with jax.named_scope("solve"):
             kk, qk = _halved_tiles(qc, kc, gc, dtype)
-            Tm = (unit_lower_inverse(bc[..., :, None] * kk)
-                  * bc[..., None, :]).astype(dtype)
             k_in = (kc.astype(_F32) * into).astype(dtype)
-            W = jnp.einsum("bnhij,bnhjd->bnhid", Tm, k_in)
-            U = jnp.einsum("bnhij,bnhjv->bnhiv", Tm, vc)
+            W, U = solved(kk, k_in, vc, bc)
         k_out = (kc.astype(_F32) * out_of).astype(dtype)
         q_in = (qc.astype(_F32) * into).astype(dtype)
         return W, U, k_out, total, q_in, qk.astype(dtype)
 
-    operands = (chunked(q), chunked(k), chunked(v),
-                chunked(g.astype(_F32)), chunked(beta.astype(_F32)))
-    per = max(1, _TILE_GROUP_ELEMENTS // (b * H * C * dk))
-    per = max(n for n in range(1, min(per, nc) + 1) if nc % n == 0)
-    if per == nc:
-        W, U, k_out, total, q_in, qk = before_the_states(*operands)
-    else:
+    def after_the_kernels(q, k, v, g, beta):
+        """The same six from the tile kernels: every chunk at once, the
+        kernels' residuals being their inputs."""
+        vc, bc = chunked(v), chunked(beta.astype(_F32))
+        with jax.named_scope("solve"):
+            kk, qk, k_in, k_out, q_in, total = _tiles(
+                q, k, g.astype(_F32), C, plan.chunks_a_step, interpret)
+            W, U = solved(kk, k_in, vc, bc)
+        return W, U, k_out, total, q_in, qk
+
+    def in_groups(operands):
+        """:func:`before_the_states` for ``_TILE_GROUP_ELEMENTS`` of the
+        chunks at a time, each group's made again in the backward pass."""
+        per = max(1, _TILE_GROUP_ELEMENTS // (b * H * C * dk))
+        per = max(n for n in range(1, min(per, nc) + 1) if nc % n == 0)
+        if per == nc:
+            return before_the_states(*operands)
+
         def grouped(a):              # (b, nc, ...) -> (groups, b, per, ...)
             return jnp.moveaxis(
                 a.reshape(b, nc // per, per, *a.shape[2:]), 1, 0)
 
-        W, U, k_out, total, q_in, qk = (
-            jnp.moveaxis(a, 0, 1).reshape(b, nc, *a.shape[3:])
-            for a in lax.map(
-                jax.checkpoint(lambda group: before_the_states(*group)),
-                tuple(grouped(a) for a in operands)))
+        return (jnp.moveaxis(a, 0, 1).reshape(b, nc, *a.shape[3:])
+                for a in lax.map(
+                    jax.checkpoint(lambda group: before_the_states(*group)),
+                    tuple(grouped(a) for a in operands)))
+
+    if plan.form == "tile_kernels":
+        W, U, k_out, total, q_in, qk = after_the_kernels(q, k, v, g, beta)
+    else:
+        W, U, k_out, total, q_in, qk = in_groups(
+            (chunked(q), chunked(k), chunked(v), chunked(g.astype(_F32)),
+             chunked(beta.astype(_F32))))
 
     with jax.named_scope("states"):
         def step(S, chunk_in):       # S (b, H, d_v, d_k), float32

@@ -407,11 +407,37 @@ def test_delta_reference_phase(smoke):
     ``delta_plan`` line: one form, plain XLA, at the chunk it was given."""
     out = smoke.delta_reference_phase(batch=1, seq=40, heads=2, key_dim=16,
                                       value_dim=32, chunk=16, seed=0)
-    assert out["delta_plan"] == {"form": "xla_chunked", "chunk": 16}
+    assert out["delta_plan"] == {"form": "xla_chunked", "chunk": 16,
+                                 "chunks_a_step": 0}
     assert out["shape"] == [1, 40, 2, 16, 32]
     assert {"out", "grad_q", "grad_k", "grad_v", "grad_g",
             "grad_beta"} < set(out)
     assert smoke.DELTA_REFERENCE["chunk"] == 64
+
+
+def test_kda_tiles_phase(smoke):
+    """The rank-4 rule's forms alone (interpreted here: no time): the XLA
+    halved form and the tile kernels at two tilings, the kernels' ``o`` and
+    five gradients against the XLA form's and the recurrence's, the plan
+    and what the mixer notes — every chunk's tiles made by the kernels."""
+    out = smoke.kda_tiles_phase(
+        batch=1, seq=128, heads=2, key_dim=128, value_dim=16, chunk=64,
+        dim=24, short_seq=128, seed=0, calls=1, chunks_a_step=(1, 2, 8))
+    assert out["interpret"] and out["shape"] == [1, 128, 2, 128, 16]
+    assert out["delta_plan"] == {"form": "tile_kernels", "chunk": 64,
+                                 "chunks_a_step": 2}
+    assert list(out["ms_a_layer"]) == ["xla_halved", "kernels.1",
+                                      "kernels.2"]
+    assert list(out["tiles_alone"]) == ["1", "2"]
+    names = {"out", "grad_q", "grad_k", "grad_v", "grad_g", "grad_beta"}
+    assert set(out["vs_xla"]) == set(out["vs_recurrence"]) == names
+    assert max(out["vs_xla"].values()) <= 2e-2
+    assert max(out["vs_recurrence"].values()) <= smoke.DELTA_TOL
+    assert out["notes"]["lin.tile_kernel_chunks"] == out["notes"][
+        "lin.delta_chunks"] == 2
+    assert smoke.KDA_TILES == dict(batch=1, seq=8192, heads=32, key_dim=128,
+                                   value_dim=128, chunk=64, dim=2304,
+                                   short_seq=512)
 
 
 def test_transformer_phase(smoke, one_device_mesh):

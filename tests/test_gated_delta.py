@@ -8,9 +8,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from horovod_tpu.ops import gated_delta
 from horovod_tpu.ops.gated_delta import (
-    delta_plan, delta_sizes, gated_delta_recurrence, gated_delta_rule,
-    unit_lower_inverse)
+    DeltaPlan, delta_plan, delta_sizes, gated_delta_recurrence,
+    gated_delta_rule, rule_plan, unit_lower_inverse)
 
 NAMES = ("q", "k", "v", "g", "beta")
 
@@ -204,7 +205,61 @@ def test_sizes_and_plan():
     plan = delta_plan(64)
     assert plan.form == "xla_chunked" and plan.chunk == 64
     assert delta_plan(64, g_rank=4).form == "xla_chunked_halved"
-    assert delta_plan.__code__.co_varnames == ("chunk", "g_rank")
+    # What the plan reads, and nothing that an option could set: the chunk,
+    # the rank of g, the call's length, the keys' width, the operands'
+    # bytes, whether Pallas is interpreted and whether axes are manual.
+    assert delta_plan.__code__.co_varnames[
+        :delta_plan.__code__.co_argcount
+        + delta_plan.__code__.co_kwonlyargcount] == (
+            "chunk", "g_rank", "seq_len", "key_dim", "itemsize",
+            "interpret", "manual_axes")
+
+
+CELL = dict(seq_len=8192, key_dim=128)
+
+
+@pytest.mark.parametrize("chunk,g_rank,seen,want", [
+    # kimilinear_1chip's layer: 128 chunks of 64, eight a grid step; in
+    # float32 and interpreted the same.
+    (64, 4, CELL, ("tile_kernels", 64, 8)),
+    (64, 4, {**CELL, "itemsize": 4}, ("tile_kernels", 64, 8)),
+    (64, 4, {**CELL, "interpret": True}, ("tile_kernels", 64, 8)),
+    # Chunks a step: the largest power of two up to 8 that divides them.
+    (64, 4, {**CELL, "seq_len": 200}, ("tile_kernels", 64, 4)),
+    (64, 4, {**CELL, "seq_len": 64 * 6}, ("tile_kernels", 64, 2)),
+    (64, 4, {**CELL, "seq_len": 64 * 7}, ("tile_kernels", 64, 1)),
+    (128, 4, CELL, ("tile_kernels", 128, 8)),
+    (64, 4, {**CELL, "key_dim": 256}, ("tile_kernels", 64, 8)),
+    # What stands down: the rehearsal's heads of 16 and any key width off
+    # the lanes, chunks the kernels do not tile, interpreted Pallas under
+    # manual axes (compiled Mosaic is unaffected); a decay a head never
+    # asks.
+    (64, 4, {**CELL, "key_dim": 16}, ("xla_chunked_halved", 64, 0)),
+    (64, 4, {**CELL, "key_dim": 96}, ("xla_chunked_halved", 64, 0)),
+    (16, 4, CELL, ("xla_chunked_halved", 16, 0)),
+    (32, 4, CELL, ("xla_chunked_halved", 32, 0)),
+    (256, 4, CELL, ("xla_chunked_halved", 256, 0)),
+    (64, 4, {**CELL, "interpret": True, "manual_axes": True},
+     ("xla_chunked_halved", 64, 0)),
+    (64, 4, {**CELL, "manual_axes": True}, ("tile_kernels", 64, 8)),
+    (64, 4, {}, ("xla_chunked_halved", 64, 0)),
+    (64, 3, CELL, ("xla_chunked", 64, 0)),
+])
+def test_the_plan_of_a_decay_a_key_channel(chunk, g_rank, seen, want):
+    """``delta_plan`` is a pure function of what the call shows: the tile
+    kernels where the shape tiles, the XLA halved form where it does not."""
+    assert delta_plan(chunk, g_rank, **seen) == DeltaPlan(*want)
+
+
+def test_rule_plan_reads_the_call():
+    def s(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype)
+
+    wide, narrow = s(1, 8192, 32, 128), s(2, 64, 2, 16)
+    assert rule_plan(wide, s(1, 8192, 32, 128, dtype=jnp.float32), 64,
+                     False) == DeltaPlan("tile_kernels", 64, 8)
+    assert rule_plan(narrow, narrow, 16, True).form == "xla_chunked_halved"
+    assert rule_plan(wide, s(1, 8192, 32), 64, False).form == "xla_chunked"
 
 
 # ------------------------------------------------- a decay a key channel
@@ -226,19 +281,41 @@ def channel_inputs(T, b=2, H=3, dk=8, dv=16, seed=0, fast=0.0):
                                                              jnp.float32)
 
 
-@pytest.mark.parametrize("T,chunk,fast", [
-    (37, 16, 0.0), (96, 32, 0.0), (128, 64, 0.0),
-    (96, 16, 20.0), (96, 32, 20.0), (96, 64, 20.0)],
+# The two forms of a rank-4 call, by the sizes that give them: the XLA
+# halved form at keys of 8 (any chunk), the tile kernels — interpreted
+# here — at keys of 128 in chunks of 64, two heads of one sequence.
+FORMS = {"xla_chunked_halved": dict(b=2, H=3, dk=8, dv=16),
+         "tile_kernels": dict(b=1, H=2, dk=128, dv=16)}
+
+
+def xla_halved(chunk):
+    """The rank-4 rule in its XLA form whatever the shapes would take."""
+    return lambda *a: gated_delta._per_channel_rule(
+        *a, DeltaPlan("xla_chunked_halved", chunk), True)
+
+
+@pytest.mark.parametrize("form,T,chunk,fast", [
+    ("xla_chunked_halved", 37, 16, 0.0), ("xla_chunked_halved", 96, 32, 0.0),
+    ("xla_chunked_halved", 128, 64, 0.0),
+    ("xla_chunked_halved", 96, 16, 20.0),
+    ("xla_chunked_halved", 96, 32, 20.0),
+    ("xla_chunked_halved", 96, 64, 20.0),
+    ("tile_kernels", 200, 64, 0.0), ("tile_kernels", 128, 64, 20.0),
+    ("tile_kernels", 256, 128, 20.0)],
     ids=["c16_T_not_a_multiple", "c32", "c64", "c16_g_to_minus_20",
-         "c32_g_to_minus_20", "c64_g_to_minus_20"])
-def test_per_channel_chunked_rule_equals_the_recurrence(T, chunk, fast):
-    """``g`` of rank 4 at three chunk lengths, with decays of a few percent
-    a token and with channels at ``g = -20`` a token: values to 2e-6 and
-    the gradients of all five inputs to 5e-6 of the recurrence's in
-    float32, as the rank-3 form is held, and finite throughout — every
-    exponent is a sum of ``g`` itself, so nothing cancels where the running
-    sums reach -1,280 a chunk."""
-    x = channel_inputs(T, fast=fast)
+         "c32_g_to_minus_20", "c64_g_to_minus_20",
+         "kernels_c64_T_not_a_multiple", "kernels_c64_g_to_minus_20",
+         "kernels_c128_g_to_minus_20"])
+def test_per_channel_chunked_rule_equals_the_recurrence(form, T, chunk, fast):
+    """``g`` of rank 4 in both forms, with decays of a few percent a token
+    and with channels at ``g = -20`` a token: values to 2e-6 and the
+    gradients of all five inputs to 5e-6 of the recurrence's in float32,
+    as the rank-3 form is held, and finite throughout — every exponent is
+    a sum of ``g`` itself, so nothing cancels where the running sums reach
+    -1,280 a chunk.  The kernels' gradient of ``g`` is the XLA form's to
+    1e-5 besides."""
+    x = channel_inputs(T, fast=fast, **FORMS[form])
+    assert rule_plan(x[0], x[3], chunk, True).form == form
 
     def value_and_grads(rule):       # one compile a side, not one an op
         def f(*a):
@@ -256,6 +333,62 @@ def test_per_channel_chunked_rule_equals_the_recurrence(T, chunk, fast):
     assert all(bool(jnp.isfinite(a).all()) for a in ours)
     errors = {n: rel(a, b) for n, a, b in zip(NAMES, ours, theirs)}
     assert max(errors.values()) <= 5e-6, errors
+    if form == "tile_kernels":
+        (_, other), halved = value_and_grads(xla_halved(chunk))
+        assert rel(got, other) <= 2e-6
+        assert rel(ours[3], halved[3]) <= 1e-5
+
+
+@pytest.mark.parametrize("differentiated", [False, True],
+                         ids=["forward", "forward_and_backward"])
+@pytest.mark.parametrize("form,T,C,dk", [
+    ("xla_chunked_halved", 64, 16, 32), ("tile_kernels", 128, 64, 128)])
+def test_the_float32_parts_of_a_decay_a_key_channel(form, T, C, dk,
+                                                    differentiated):
+    """The rank-3 jaxpr test's reading of a rank-4 call in both forms, the
+    kernels' bodies included, with bfloat16 operands: the state carried from
+    chunk to chunk (and its gradient, carried back) is float32; every
+    product that takes float32 operands — the solve's, its rule's, the XLA
+    form's sums of ``g`` — takes them at HIGHEST and the kernels' bodies
+    hold none (their sums are adds); no product takes an operand narrower
+    than bfloat16; and every ``exp`` reads float32."""
+    b, H, dv = 1, 2, 48
+    x = channel_inputs(T, b=b, H=H, dk=dk, dv=dv)
+    low = tuple(a.astype(jnp.bfloat16) for a in x[:3]) + x[3:]
+    assert rule_plan(low[0], low[3], C, True).form == form
+
+    def f(*a):
+        return gated_delta_rule(*a, chunk=C).astype(jnp.float32).sum()
+
+    traced = jax.make_jaxpr(jax.grad(f, argnums=range(5)) if differentiated
+                            else f)(*low)
+    eqns = list(_equations(traced.jaxpr))
+    carried = [v.aval for e in eqns if e.primitive.name == "scan"
+               for v in e.outvars[:e.params["num_carry"]]
+               if v.aval.shape == (b, H, dv, dk)]
+    assert len(carried) == (2 if differentiated else 1)
+    assert all(a.dtype == jnp.float32 for a in carried)
+    highest = (jax.lax.Precision.HIGHEST, jax.lax.Precision.HIGHEST)
+    dots = [e for e in eqns if e.primitive.name == "dot_general"]
+    float32 = [e for e in dots
+               if all(v.aval.dtype == jnp.float32 for v in e.invars)]
+    assert len(float32) >= 2 * (C.bit_length() - 2)     # the doubling
+    assert all(e.params["precision"] == highest for e in float32)
+    for e in dots:
+        assert all(v.aval.dtype in (jnp.bfloat16, jnp.float32)
+                   for v in e.invars)
+    assert all(e.invars[0].aval.dtype == jnp.float32
+               for e in eqns if e.primitive.name == "exp")
+    bodies = [e.params["jaxpr"] for e in eqns
+              if e.primitive.name == "pallas_call"]
+    assert len(bodies) == {("tile_kernels", False): 1,
+                           ("tile_kernels", True): 2}.get(
+                               (form, differentiated), 0)
+    for body in bodies:
+        for e in _equations(body):
+            if e.primitive.name == "dot_general":
+                assert all(v.aval.dtype == jnp.bfloat16 for v in e.invars)
+                assert e.outvars[0].aval.dtype == jnp.float32
 
 
 def test_groups_of_chunks_change_nothing(monkeypatch):
@@ -281,14 +414,18 @@ def test_groups_of_chunks_change_nothing(monkeypatch):
                                          jax.tree.leaves(at_once))) <= 1e-6
 
 
-def test_one_decay_for_every_channel_is_the_rank_3_rule():
+@pytest.mark.parametrize("form,chunk", [("xla_chunked_halved", 16),
+                                        ("tile_kernels", 64)])
+def test_one_decay_for_every_channel_is_the_rank_3_rule(form, chunk):
     """``g`` broadcast over the key channels is the decay a head: the two
-    forms give one answer, and a chunk that is no power of two is refused
-    by the halved form alone."""
-    q, k, v, g, beta = delta_inputs(80, seed=3, beta_range=(0.05, 0.95))
+    ranks give one answer in either form of the second, and a chunk that is
+    no power of two is refused by the second rank alone."""
+    q, k, v, g, beta = delta_inputs(80, seed=3, beta_range=(0.05, 0.95),
+                                    **FORMS[form])
     wide = jnp.broadcast_to(g[..., None], q.shape)
+    assert rule_plan(q, wide, chunk, True).form == form
     a, b = jax.jit(lambda g_, w_: tuple(
-        gated_delta_rule(q, k, v, u, beta, chunk=16) for u in (g_, w_)))(
+        gated_delta_rule(q, k, v, u, beta, chunk=chunk) for u in (g_, w_)))(
             g, wide)
     assert rel(b, a) <= 2e-6
     assert jax.eval_shape(lambda: gated_delta_rule(
@@ -297,15 +434,20 @@ def test_one_decay_for_every_channel_is_the_rank_3_rule():
         gated_delta_rule(q, k, v, wide, beta, chunk=24)
 
 
-def test_the_halved_form_holds_no_tile_a_channel_and_no_positive_exponent():
-    """In the traced program of a rank-4 call: no array with two chunk axes
-    AND the key axis ((C, C, d_k) in any order), no (T, T) array, and every
-    ``exp`` reads either a ``min(., 0)`` or the carried total of a chunk (a
-    sum of ``g``); the running sums, the solve and the carried state are
-    float32 with bfloat16 operands."""
-    T, C, dk, dv = 128, 32, 8, 16
+@pytest.mark.parametrize("form,T,C,dk", [
+    ("xla_chunked_halved", 128, 32, 8), ("tile_kernels", 192, 64, 128)])
+def test_the_halved_form_holds_no_tile_a_channel_and_no_positive_exponent(
+        form, T, C, dk):
+    """In the traced program of a rank-4 call, the kernels' bodies
+    included: no array with two chunk axes AND the key axis ((C, C, d_k) in
+    any order), no (T, T) array, and every ``exp`` reads either a ``min(.,
+    0)`` or the carried total of a chunk (a sum of ``g``); the running
+    sums, the solve and the carried state are float32 with bfloat16
+    operands."""
+    dv = 16
     x = channel_inputs(T, b=1, H=2, dk=dk, dv=dv)
     x = tuple(a.astype(jnp.bfloat16) for a in x[:3]) + x[3:]
+    assert rule_plan(x[0], x[3], C, True).form == form
     jaxpr = jax.make_jaxpr(lambda *a: gated_delta_rule(*a, chunk=C))(*x)
 
     def walk(j):
@@ -324,13 +466,21 @@ def test_the_halved_form_holds_no_tile_a_channel_and_no_positive_exponent():
                             e.primitive, shape)
     made_by = {v_: e for e in eqns for v_ in e.outvars}
     exps = [e for e in eqns if e.primitive.name == "exp"]
-    assert len(exps) >= 3
+    # The kernel makes every level's factor by one ``exp``; the pass its own.
+    assert len(exps) >= (2 if form == "tile_kernels" else 3)
     for e in exps:
         source = made_by.get(e.invars[0])
         assert source is None or source.primitive.name == "min" or (
             e.invars[0].aval.shape[-1] == dk
             and C not in e.invars[0].aval.shape), source
-    scans = [e for e in eqns if e.primitive.name == "scan"]
+    kernels = [e.params["name"] for e in eqns
+               if e.primitive.name == "pallas_call"]
+    assert kernels == (["kda_tiles_fwd"] if form == "tile_kernels" else [])
+    # The one pass over the chunks (the kernel's walk over a step's chunks
+    # carries nothing).
+    scans = [e for e in eqns if e.primitive.name == "scan"
+             and e.params["num_carry"]
+             and e.params["jaxpr"].jaxpr.invars[0].aval.ndim == 4]
     assert len(scans) == 1
     carry = scans[0].params["jaxpr"].jaxpr.invars[0].aval
     assert carry.shape[-2:] == (dv, dk) and carry.dtype == jnp.float32

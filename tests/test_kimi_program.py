@@ -149,10 +149,13 @@ def test_the_mixer_names_its_parts_and_notes_its_sizes():
         assert scope in text, scope
     # Two sequences of 64 tokens in chunks of 16: 8 chunks, a (16, 16)
     # float32 state a head entering each, the log-decays of 2 heads of 16
-    # channels a token, 15 pairs of sub-chunks a chunk.
+    # channels a token, 15 pairs of sub-chunks a chunk; heads of 16 are no
+    # shape the tile kernels take, so the plan stood down.
     assert list(noted.values()) == [{
         "lin.delta_chunks": 8, "lin.state_bytes": 8 * 2 * 16 * 16 * 4,
-        "lin.decay_bytes": 2 * 64 * 2 * 16 * 4, "lin.sub_chunks": 8 * 15}]
+        "lin.decay_bytes": 2 * 64 * 2 * 16 * 4, "lin.sub_chunks": 8 * 15,
+        "lin.tile_kernel_chunks": 0}]
+    assert "kda_tiles" not in text
     # The cell's readers find them under those names.
     from benchmark.metrics import (kda_decay_ms, kda_intra_ms, linattn_ms)
     stack = "transpose(jvp(TransformerLM))/layer_*/lin/"
@@ -163,6 +166,40 @@ def test_the_mixer_names_its_parts_and_notes_its_sizes():
     assert kda_intra_ms.in_tiles(stack + "delta/intra/dot_general")
     assert not kda_intra_ms.in_tiles(stack + "delta/states/while")
     assert linattn_ms.in_delta(stack + "delta/decay/exp")
+
+
+def test_the_mixer_at_the_kernels_widths_notes_their_chunks():
+    """Keys 128 wide in chunks of 64: the tile kernels (interpreted here)
+    make every chunk's tiles, under ``delta/solve`` with the inverse, and
+    the XLA form's ``delta/decay`` is gone — the decayed operands leave the
+    same kernel."""
+    x = jnp.ones((1, 128, 24), BF16)
+    mixer = KimiDeltaAttention(num_heads=2, key_dim=128, value_dim=16,
+                               chunk=64, low_rank=8)
+    params = jax.eval_shape(
+        lambda: mixer.init(jax.random.PRNGKey(0), x)["params"])
+    noted = {}
+    jaxpr = jax.make_jaxpr(jax.grad(noting_layers(
+        lambda p: mixer.apply({"params": p}, x).astype(F32).sum(),
+        noted)))(params)
+    (counters,) = noted.values()
+    assert counters["lin.tile_kernel_chunks"] == counters[
+        "lin.delta_chunks"] == 2
+
+    def walk(j):
+        for e in j.eqns:
+            yield e
+            for sub in jax.core.jaxprs_in_params(e.params):
+                yield from walk(sub)
+
+    kernels = [(e.params["name"], str(e.source_info.name_stack))
+               for e in walk(jaxpr.jaxpr) if e.primitive.name == "pallas_call"]
+    # Forward, the forward again under the mixer's checkpoint, backward.
+    assert sorted(n for n, _ in kernels) == [
+        "kda_tiles_bwd", "kda_tiles_fwd", "kda_tiles_fwd"]
+    assert all("delta/solve" in scope for _, scope in kernels), kernels
+    assert not any("delta/decay" in str(e.source_info.name_stack)
+                   for e in walk(jaxpr.jaxpr))
 
 
 def test_the_mixer_s_float32_parts_are_float32_in_the_traced_program():

@@ -77,7 +77,8 @@ def test_tiny_stack_trains_through_make_train_step(hvd):
     step = make_train_step(family.loss_fn(cfg), tx, hvd.ranks_mesh(),
                            sync_aux_state=family.SYNC_AUX_STATE)
     names = ("lin.delta_chunks", "lin.state_bytes", "lin.decay_bytes",
-             "lin.sub_chunks", "attn.q_latent", "attn.kv_latent")
+             "lin.sub_chunks", "lin.tile_kernel_chunks", "attn.q_latent",
+             "attn.kv_latent")
     before = {n: registry.snapshot()["counters"].get(n, 0) for n in names}
     losses = []
     for _ in range(4):
@@ -90,14 +91,56 @@ def test_tiny_stack_trains_through_make_train_step(hvd):
     after = registry.snapshot()["counters"]
     # A shard's step, four dispatches: one sequence of 64 tokens through
     # two mixers (4 chunks of 16; 2 heads of 16 x 16 float32 a state, 2 x
-    # 16 log-decays a token, 15 pairs of sub-chunks a chunk) and one latent
-    # layer with no query latent.
+    # 16 log-decays a token, 15 pairs of sub-chunks a chunk; heads of 16
+    # under manual axes: no tile kernel) and one latent layer with no query
+    # latent.
     assert {n: after.get(n, 0) - before[n] for n in names} == {
         "lin.delta_chunks": 4 * 2 * 4,
         "lin.state_bytes": 4 * 2 * 4 * 2 * 16 * 16 * 4,
         "lin.decay_bytes": 4 * 2 * 64 * 2 * 16 * 4,
-        "lin.sub_chunks": 4 * 2 * 4 * 15,
+        "lin.sub_chunks": 4 * 2 * 4 * 15, "lin.tile_kernel_chunks": 0,
         "attn.q_latent": 0, "attn.kv_latent": 4 * 32}
+
+
+def test_the_step_counts_the_chunks_whose_tiles_the_kernels_made(hvd):
+    """Two mixers at a shape the tile kernels take (keys of 128 in chunks of
+    64, one device, so no manual axes) through ``make_train_step``: the
+    step's ``lin.tile_kernel_chunks`` is its ``lin.delta_chunks`` — every
+    chunk's tiles were made in VMEM (interpreted here) — and the loss falls."""
+    import flax.linen as nn
+    import optax
+    from jax.sharding import Mesh
+
+    from horovod_tpu.models.linear_attention import KimiDeltaAttention
+
+    class Two(nn.Module):
+        @nn.compact
+        def __call__(self, x):
+            for _ in range(2):
+                x = x + KimiDeltaAttention(
+                    num_heads=1, key_dim=128, value_dim=16, chunk=64,
+                    low_rank=8, dtype=F32)(x)
+            return x
+
+    x = jax.random.normal(jax.random.PRNGKey(2), (1, 128, 16), F32)
+    params = Two().init(jax.random.PRNGKey(3), x)["params"]
+    tx = optax.sgd(0.05)
+
+    def loss_fn(p, aux, batch):
+        return (Two().apply({"params": p}, batch) ** 2).mean(), aux
+
+    step = make_train_step(loss_fn, tx,
+                           Mesh(np.asarray(jax.devices()[:1]), ("ranks",)))
+    names = ("lin.delta_chunks", "lin.tile_kernel_chunks")
+    before = {n: registry.snapshot()["counters"].get(n, 0) for n in names}
+    opt_state, aux, losses = tx.init(params), {}, []
+    for _ in range(2):
+        params, aux, opt_state, loss = step(params, aux, opt_state, x)
+        losses.append(float(loss))
+    assert losses[1] < losses[0]
+    after = registry.snapshot()["counters"]
+    assert {n: after.get(n, 0) - before[n] for n in names} == {
+        "lin.delta_chunks": 2 * 2 * 2, "lin.tile_kernel_chunks": 2 * 2 * 2}
 
 
 # ------------------------------------------------- the ring, off the chip
